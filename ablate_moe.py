@@ -6,7 +6,7 @@ through the full engine on the 8-device CPU mesh and records:
 - **measured** CPU wall per step for both — honestly labeled: on the
   emulated mesh the all-to-all is memcpy, so the delta exercises the
   dispatch/bucketing/exchange STRUCTURE, not ICI latency (the
-  ZERO3_BENCH/OFFLOAD_BENCH convention). Measured drop fraction and
+  ZERO3_BENCH convention). Measured drop fraction and
   expert load imbalance ride along (bench_gate parses the drop p95).
 - the **params-per-step-FLOP headline** — the reason MoE exists: total
   trainable parameters grow ~E x on the FFN tree while per-token step
@@ -242,7 +242,7 @@ def main():
             "the emulated interconnect is memcpy. Wire bytes are the "
             "analytic ring model COMM_AUDIT.json verifies against the "
             "compiled program; params/FLOP ratios are exact tree "
-            "arithmetic. Same convention as ZERO3_BENCH/OFFLOAD_BENCH."),
+            "arithmetic. Same convention as ZERO3_BENCH."),
         "config": {"model": "gpt2-tiny", "num_experts": E, "top_k": K,
                    "capacity_factor": CF, "ep": EP, "batch": B,
                    "seq": SEQ, "steps": STEPS},

@@ -14,10 +14,15 @@ import jax.numpy as jnp
 import numpy as np
 
 import deepspeed_tpu.ops.sparse_flash as sf
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 from deepspeed_tpu.ops.flash_attention import _flash
 from deepspeed_tpu.ops.sparse_attention.sparsity_config import (
     BigBirdSparsityConfig)
 
+if jax.devices()[0].platform != "tpu":
+    sys.exit("bench_sparse.py needs a TPU (interpret-mode timings measure "
+             "the interpreter)")
+enable_compile_cache()
 S = int(sys.argv[1]) if len(sys.argv) > 1 else 32768
 
 

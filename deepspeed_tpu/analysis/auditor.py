@@ -27,16 +27,16 @@ def _trace_program(fn: Callable, args: Tuple, kwargs: Dict
                    ) -> Tuple[Any, Tuple[bool, ...], Tuple[Any, ...]]:
     """(body ClosedJaxpr, donated_invars, flat in_avals) of one program.
 
-    Tracing the JITTED callable yields an outer jaxpr with a single pjit
+    Tracing the JITTED callable yields an outer jaxpr with a single jit
     eqn whose params carry the donation declaration — the jit-level truth
     the donation pass diffs against the compiled alias table. A plain
-    callable (no pjit eqn) traces with an empty donation vector.
+    callable (no jit eqn) traces with an empty donation vector.
     """
     import jax
     closed = jax.make_jaxpr(lambda *a: fn(*a, **kwargs))(*args)
     outer = closed.jaxpr
     in_avals = tuple(v.aval for v in outer.invars)
-    if len(outer.eqns) == 1 and outer.eqns[0].primitive.name == "pjit" \
+    if len(outer.eqns) == 1 and outer.eqns[0].primitive.name == "jit" \
             and len(outer.eqns[0].invars) == len(outer.invars):
         eqn = outer.eqns[0]
         donated = tuple(eqn.params.get("donated_invars") or

@@ -46,6 +46,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from . import kv_cache
@@ -54,7 +55,9 @@ from ..ops import paged_attention as paged_attn_ops
 from ..models.transformer import (dense, gelu_dense_fn, layer_norm,
                                   layer_norm_fn)
 
-NEG_INF = jnp.float32(-1e9)    # same masking constant as dense_attention
+# Same masking constant as dense_attention. A NumPy scalar: a jnp one
+# would initialise a JAX backend (and take the chip) at import.
+NEG_INF = np.float32(-1e9)
 
 
 def _check_cfg(cfg: GPT2Config) -> None:

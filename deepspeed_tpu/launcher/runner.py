@@ -224,6 +224,24 @@ def _collect_exports(env) -> Dict[str, str]:
     return exports
 
 
+def local_chip_count() -> int:
+    """Chips on this host, asked of a short-lived CHILD process.
+
+    A chip belongs to one process at a time: a runner that initialised
+    a JAX backend itself would hold the chip while the worker it spawns
+    (and then waits for) tries to open it.  The child has exited — and
+    released the chip — before anything is launched."""
+    probe = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; print(jax.local_device_count())"],
+        stdout=subprocess.PIPE, text=True)
+    if probe.returncode != 0:
+        raise RuntimeError(
+            f"local chip-count probe exited with {probe.returncode}; "
+            "pass a hostfile or fix the JAX installation")
+    return int(probe.stdout.split()[-1])
+
+
 def main(args=None) -> int:
     args = parse_args(args)
 
@@ -255,13 +273,9 @@ def main(args=None) -> int:
             for i in range(args.num_nodes))
     multi_node_exec = resource_pool is not None and len(resource_pool) > 0
     if not resource_pool:
-        # local fallback: all chips of this host
-        try:
-            import jax
-            device_count = jax.local_device_count()
-        except Exception:
-            device_count = 1
-        resource_pool = collections.OrderedDict(localhost=max(1, device_count))
+        # no hostfile: all chips of this host
+        resource_pool = collections.OrderedDict(
+            localhost=local_chip_count())
         args.coordinator_addr = args.coordinator_addr or "127.0.0.1"
         multi_node_exec = False
 

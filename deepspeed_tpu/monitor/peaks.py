@@ -25,20 +25,29 @@ The figures are rough public per-chip specs by TPU generation:
 They are CEILINGS for roofline verdicts and utilisation fractions, not
 measurements — real programs see lower effective bandwidth (stride
 patterns, link contention), and the DCN column doubly so (it depends on
-the NIC provisioning of the actual pod). On non-TPU backends (CPU dev
-meshes) there is no meaningful peak; ``chip_peaks()`` returns the v5e
-row flagged ``assumed=True`` so downstream math stays total-ordered and
-every consumer can say "vs an ASSUMED v5e peak" instead of crashing or
-silently printing garbage.
+the NIC provisioning of the actual pod).
+
+Rows are found by the ``device_kind`` string the hardware reports
+(``"TPU v5 lite"`` is a v5e).  A TPU kind with no row RAISES: a
+utilisation against a guessed peak on a real chip is a wrong number,
+not a default.  Non-TPU platforms (the CPU dev mesh) have no
+meaningful peak; they get the v5e row flagged ``assumed=True`` so the
+cost model's arithmetic stays total-ordered in CPU tests, and nothing
+that prints a utilisation may use an assumed row.
+
+Sources: Google Cloud TPU documentation, the per-generation system
+architecture pages ("TPU v4", "TPU v5e", "TPU v5p", "TPU v6e"): peak
+bf16 compute, HBM bandwidth and inter-chip interconnect bandwidth per
+chip (ICI published in Gbit/s: 2400 / 1600 / 4800 / 3584).
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional
+from typing import Dict
 
 
-# Rough bf16 peak TFLOPs per chip by TPU generation (public figures);
-# the utilisation denominator (lifted from bench.py, now shared).
+# bf16 peak TFLOPs per chip by TPU generation (sources in the module
+# docstring); the utilisation denominator.
 TPU_PEAK_TFLOPS: Dict[str, float] = {
     "v4": 275.0, "v5e": 197.0, "v5p": 459.0, "v6e": 918.0,
 }
@@ -64,6 +73,15 @@ TPU_DCN_GBS: Dict[str, float] = {
 }
 
 _DEFAULT_GEN = "v5e"
+
+# ``jax.Device.device_kind`` as the hardware reports it (lower-cased) ->
+# generation row.  Both spellings JAX itself knows are listed.
+DEVICE_KIND_TO_GEN: Dict[str, str] = {
+    "tpu v4": "v4",
+    "tpu v5 lite": "v5e", "tpu v5e": "v5e",
+    "tpu v5": "v5p", "tpu v5p": "v5p",
+    "tpu v6 lite": "v6e", "tpu v6e": "v6e",
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,18 +114,18 @@ class ChipPeaks:
         return dataclasses.asdict(self)
 
 
-def _resolve_gen(device_kind: str) -> Optional[str]:
-    kind = (device_kind or "").lower()
-    for key in TPU_PEAK_TFLOPS:
-        if key in kind:
-            return key
-    return None
-
-
 def peaks_for_kind(device_kind: str) -> ChipPeaks:
-    """ChipPeaks for a device-kind string; unknown kinds (CPU, GPU, future
-    TPUs) get the v5e row flagged ``assumed``."""
-    gen = _resolve_gen(device_kind)
+    """ChipPeaks for a ``device_kind`` string (or a bare generation key
+    such as ``"v5e"``).  A TPU kind without a row raises; non-TPU kinds
+    (CPU, GPU) get the v5e row flagged ``assumed``."""
+    kind = (device_kind or "").strip().lower()
+    gen = DEVICE_KIND_TO_GEN.get(kind) or \
+        (kind if kind in TPU_PEAK_TFLOPS else None)
+    if gen is None and kind.startswith("tpu"):
+        raise KeyError(
+            f"no peak row for TPU device_kind {device_kind!r}; add it to "
+            f"monitor/peaks.py with its source (known: "
+            f"{sorted(DEVICE_KIND_TO_GEN)})")
     key, assumed = (gen, False) if gen else (_DEFAULT_GEN, True)
     return ChipPeaks(name=key, bf16_tflops=TPU_PEAK_TFLOPS[key],
                      hbm_gbs=TPU_HBM_GBS[key], ici_gbs=TPU_ICI_GBS[key],
@@ -123,11 +141,16 @@ def chip_peaks(device=None) -> ChipPeaks:
 
 
 def chip_peak_tflops() -> float:
-    """bf16 peak TFLOPs of the first visible chip (bench.py's historical
-    API: defaults to v5e when the kind is unknown; CPU runs report vs
-    that assumed peak too)."""
-    return chip_peaks().bf16_tflops
+    """bf16 peak TFLOPs of the first visible chip — the utilisation
+    denominator.  Raises off-TPU: there is no peak to divide by."""
+    pk = chip_peaks()
+    if pk.assumed:
+        raise RuntimeError(
+            "no chip peak on this platform (the v5e row is only ASSUMED "
+            "here); a utilisation is printed from a real TPU's row or "
+            "not at all")
+    return pk.bf16_tflops
 
 
 __all__ = ["TPU_PEAK_TFLOPS", "TPU_HBM_GBS", "TPU_ICI_GBS", "TPU_DCN_GBS",
-           "ChipPeaks", "peaks_for_kind", "chip_peaks", "chip_peak_tflops"]
+           "DEVICE_KIND_TO_GEN", "ChipPeaks", "peaks_for_kind", "chip_peaks", "chip_peak_tflops"]

@@ -20,18 +20,28 @@ Semantics (the determinism contract, in priority order):
    what a TPU session recorded (``DS_AUTOTUNE_FORCE=1`` is the explicit
    test/tooling escape hatch).
 3. On TPU, the first resolve of a new (kernel, abstract shape, dtype,
-   chip-kind) key times the candidate grid ONCE — powers of two bounded
-   by the same VMEM budget math the heuristics used — and records the
-   winner; every later resolve of that key (this process or the next)
-   hits the registry with zero search.
+   chip-kind) key OUTSIDE any trace times the candidate grid ONCE —
+   powers of two bounded by the same VMEM budget math the heuristics
+   used — and records the winner; every later resolve of that key (this
+   process or the next, traced or not) hits the registry with zero
+   search.
+4. Under a trace (``jit``/``shard_map``/``grad`` of the training or
+   serving step) nothing is ever timed: a runner's arrays are tracers
+   there and ``block_until_ready`` returns at once, so the clock would
+   read tracing, not the device.  The registry answers, else the
+   heuristic.  A search therefore happens where a kernel entry point is
+   called eagerly on concrete arrays (``chip_smoke.py``'s kernel phase,
+   ``ablate_autotune.py``).
 
 The registry is keyed like the recompile sentinel's abstract signatures
 (``kernel|dtype[dims]|chip``, host metadata only — never tracers) and
 written like the async checkpoint's commit: process 0 only, tmp file +
 ``os.replace`` so a preempted writer can never leave a torn file.  A
 corrupt registry (killed mid-copy, hand-edited) degrades to empty with a
-warning — the heuristic still stands underneath.  Path override:
-``DS_AUTOTUNE_REGISTRY`` (default ``~/.cache/deepspeed_tpu/autotune.json``).
+warning — the heuristic still stands underneath.  The registry sits
+beside the compile cache (``utils.compile_cache.cache_dir()``:
+``$JAX_COMPILATION_CACHE_DIR`` or ``<checkout>/.jax_cache``); path
+override: ``DS_AUTOTUNE_REGISTRY``.
 
 Tiles move the SCHEDULE, not the arithmetic: every kernel computes the
 same per-row/per-block fp32 expressions under any tile choice, so an
@@ -50,10 +60,18 @@ import warnings
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
+
+from ..utils.compile_cache import cache_dir
 
 _ENV_KNOB = "DS_AUTOTUNE"
 _ENV_PATH = "DS_AUTOTUNE_REGISTRY"
 _ENV_FORCE = "DS_AUTOTUNE_FORCE"
+
+# How an entry's timings were taken; an entry recorded any other way
+# (the pre-PR-21 eager clock read host re-tracing, not the device) is
+# ignored and searched again.
+_METHOD = "jit-best-of-3"
 
 # Observability for tests/tooling: how many resolves searched, hit the
 # registry, or fell back to the heuristic since import (or reset()).
@@ -96,8 +114,13 @@ def registry_path() -> str:
     env = os.environ.get(_ENV_PATH)
     if env:
         return env
-    return os.path.join(os.path.expanduser("~"), ".cache",
-                        "deepspeed_tpu", "autotune.json")
+    return os.path.join(cache_dir(), "autotune.json")
+
+
+def _under_trace() -> bool:
+    """True while a jit/shard_map/grad trace is open: a fresh array
+    created here is then a tracer, and so would a runner's be."""
+    return isinstance(jnp.zeros(()), jax.core.Tracer)
 
 
 def reset() -> None:
@@ -192,9 +215,12 @@ def resolve(kernel: str, shape: Sequence[int], dtype: Any, heuristic,
     no ``measure`` was provided.  ``candidates`` is the legal grid the
     call site's VMEM budget math admits (the heuristic is appended if
     missing).  ``measure(tile) -> seconds`` times one candidate; a
-    candidate that raises is discarded.  The winner is recorded in the
-    on-disk registry so the search runs once per (kernel, shape, dtype,
-    chip) key — across processes.
+    candidate that raises is discarded, and a search in which EVERY
+    candidate raised is an error (the kernel cannot run here at all —
+    the heuristic would only fail later, with less to go on).  The
+    winner is recorded in the on-disk registry so the search runs once
+    per (kernel, shape, dtype, chip) key — across processes.  Under a
+    trace no search runs (see the module docstring).
     """
     if not search_allowed():
         counters["heuristic"] += 1
@@ -206,27 +232,31 @@ def resolve(kernel: str, shape: Sequence[int], dtype: Any, heuristic,
     path = registry_path()
     reg = _load(path)
     ent = reg.get(key)
-    if isinstance(ent, dict):
+    if isinstance(ent, dict) and ent.get("method") == _METHOD:
         tile = _decode(ent.get("tile"), heuristic)
         if tile is not None and tile in cands:
             counters["hit"] += 1
             return tile
         # Entry exists but is outside today's legal grid (budget math or
         # candidate set changed since it was recorded): ignore it.
-    if measure is None or len(cands) < 2:
+    if measure is None or len(cands) < 2 or _under_trace():
         counters["heuristic"] += 1
         return heuristic
     counters["search"] += 1
     timings: Dict[Any, float] = {}
+    errors: Dict[Any, str] = {}
     for c in cands:
         try:
             t = float(measure(c))
-        except Exception:  # candidate fails to compile/run: not a winner
+        except Exception as e:  # fails to compile/run: not a winner
+            errors[c] = f"{type(e).__name__}: {e}"
             continue
         if math.isfinite(t):
             timings[c] = t
     if not timings:
-        return heuristic
+        raise RuntimeError(
+            f"autotune search for {key}: every candidate failed — "
+            + "; ".join(f"{c}: {m[:200]}" for c, m in errors.items()))
     best = min(timings, key=lambda c: timings[c])
     t_h = timings.get(heuristic)
     ent = {
@@ -236,6 +266,7 @@ def resolve(kernel: str, shape: Sequence[int], dtype: Any, heuristic,
         "speedup_vs_heuristic":
             round(t_h / timings[best], 4) if t_h else None,
         "recorded_unix": int(time.time()),
+        "method": _METHOD,
     }
     reg[key] = ent
     _write(path, reg)
@@ -245,14 +276,24 @@ def resolve(kernel: str, shape: Sequence[int], dtype: Any, heuristic,
 def measure_from_runner(runner: Callable[[Any], Any],
                         repeats: int = 3) -> Callable[[Any], float]:
     """Wrap ``runner(tile) -> jax value(s)`` into a wall-clock measure:
-    one warmup call (compile), then best-of-``repeats`` with
-    ``block_until_ready`` fencing both sides."""
+    the runner is JITTED per tile — an eager ``pallas_call`` re-traces
+    and re-lowers its kernel on every call, ~0.1 s of host work that
+    buried the device time (first chip run, PR 21: a 4x larger GELU
+    timed the same) — then one warm-up call (compile) and
+    best-of-``repeats`` with ``block_until_ready`` fencing both sides.
+    The runner must hand back concrete arrays (``resolve`` never
+    searches under a trace)."""
     def measure(tile) -> float:
-        jax.block_until_ready(runner(tile))  # compile + warm
+        fn = jax.jit(lambda: runner(tile))
+        out = jax.block_until_ready(fn())  # compile + warm
+        if any(isinstance(a, jax.core.Tracer)
+               for a in jax.tree_util.tree_leaves(out)):
+            raise RuntimeError("autotune runner returned tracers: the "
+                               "clock would read tracing, not the device")
         best = float("inf")
         for _ in range(repeats):
             t0 = time.perf_counter()
-            jax.block_until_ready(runner(tile))
+            jax.block_until_ready(fn())
             best = min(best, time.perf_counter() - t0)
         return best
     return measure

@@ -367,6 +367,7 @@ def _flash_fwd(q, k, v, layout, scale: float, causal: bool,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
+        name="_fwd_kernel",
         interpret=_interpret(),
     )(*args)
     return o, lse
@@ -540,6 +541,7 @@ def _flash_bwd_fused(q, k, v, lse, do, delta, scale, causal, dropout, seed):
         out_shape=[jax.ShapeDtypeStruct((BH, S, D), q.dtype),
                    jax.ShapeDtypeStruct((BH, S, D), k.dtype),
                    jax.ShapeDtypeStruct((BH, S, D), v.dtype)],
+        name="_bwd_fused_kernel",
         interpret=_interpret(),
     )(*args)
 
@@ -597,6 +599,7 @@ def _flash_bwd(q, k, v, o, lse, do, layout, scale: float, causal: bool,
         out_specs=_qkv_spec(bq, D, "q"),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        name="_bwd_dq_kernel",
         interpret=_interpret(),
     )(*dq_args)
 
@@ -633,6 +636,7 @@ def _flash_bwd(q, k, v, o, lse, do, layout, scale: float, causal: bool,
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ],
+        name="_bwd_dkv_kernel",
         interpret=_interpret(),
     )(*dkv_args)
     return dq, dk, dv
@@ -785,6 +789,9 @@ def _layout_to_mask(layout, seq_len: int, mask):
     return add if mask is None else add + mask
 
 
+_SAID_DENSE = False
+
+
 def auto_attention(q, k, v, mask=None, causal=False, attn_dropout=0.0,
                    rng=None, deterministic=True):
     """Best attention for the current backend: flash kernels on TPU, plain
@@ -795,6 +802,12 @@ def auto_attention(q, k, v, mask=None, causal=False, attn_dropout=0.0,
                                attn_dropout=attn_dropout, rng=rng,
                                deterministic=deterministic)
     from ..models.transformer import dense_attention
+    global _SAID_DENSE
+    if not _SAID_DENSE:
+        _SAID_DENSE = True
+        from ..utils.logging import logger
+        logger.info(f"auto_attention: backend is {jax.default_backend()!r}, "
+                    "not tpu — dense XLA attention, no flash kernel")
     return dense_attention(q, k, v, mask=mask, causal=causal,
                            attn_dropout=attn_dropout, rng=rng,
                            deterministic=deterministic)

@@ -136,9 +136,8 @@ def _row_spec(rb: int, Hpad: int):
 
 
 def _whole_spec(Hpad: int):
-    """(1, Hpad) broadcast block (scale/bias rows, per-grid partials) —
-    the same sublane-1 block shape the fused-optimizer sqnorm kernel
-    ships on TPU."""
+    """(1, Hpad) broadcast block over a (1, Hpad) array (scale/bias
+    rows): the block spans the array, which the TPU tiling accepts."""
     if pltpu is not None and jax.default_backend() == "tpu":
         return pl.BlockSpec((1, Hpad), lambda i: (0, 0),
                             memory_space=pltpu.VMEM)
@@ -146,10 +145,14 @@ def _whole_spec(Hpad: int):
 
 
 def _part_spec(Hpad: int):
+    """Per-grid-step partial row: the partials array is [grid, 1, Hpad]
+    so the block's last two dims span the array's (a (1, Hpad) block
+    over [grid, Hpad] breaks the TPU (8, 128) tiling rule); the leading
+    grid dim is squeezed and the kernel still sees (1, Hpad)."""
     if pltpu is not None and jax.default_backend() == "tpu":
-        return pl.BlockSpec((1, Hpad), lambda i: (i, 0),
+        return pl.BlockSpec((None, 1, Hpad), lambda i: (i, 0, 0),
                             memory_space=pltpu.VMEM)
-    return pl.BlockSpec((1, Hpad), lambda i: (i, 0))
+    return pl.BlockSpec((None, 1, Hpad), lambda i: (i, 0, 0))
 
 
 def _pad2(x2: jax.Array, rows_pad: int, Hpad: int) -> jax.Array:
@@ -270,6 +273,7 @@ def _ln_forward(x, delta, scale, bias, eps: float, _rb: int = None):
                   _whole_spec(Hpad), _whole_spec(Hpad)],
         out_specs=[_row_spec(rb, Hpad)] * n_out,
         out_shape=[jax.ShapeDtypeStruct((rows_pad, Hpad), dtype)] * n_out,
+        name="_ln_fwd_kernel",
         interpret=_interpret(),
     )(*args)
     def unpad(a):
@@ -311,13 +315,14 @@ def _ln_backward(s, scale, dy, gs, eps: float, _rb: int = None):
         out_specs=[_row_spec(rb, Hpad), _part_spec(Hpad),
                    _part_spec(Hpad)],
         out_shape=[jax.ShapeDtypeStruct((rows_pad, Hpad), dtype),
-                   jax.ShapeDtypeStruct((grid, Hpad), jnp.float32),
-                   jax.ShapeDtypeStruct((grid, Hpad), jnp.float32)],
+                   jax.ShapeDtypeStruct((grid, 1, Hpad), jnp.float32),
+                   jax.ShapeDtypeStruct((grid, 1, Hpad), jnp.float32)],
+        name="_ln_bwd_kernel",
         interpret=_interpret(),
     )(s2, _pad_row(scale.astype(jnp.float32), Hpad), dy2, gs2)
     ds = dx[:rows, :H].reshape(shape)
-    dscale = jnp.sum(dsc, axis=0)[:H].astype(scale.dtype)
-    dbias = jnp.sum(dbi, axis=0)[:H].astype(scale.dtype)
+    dscale = jnp.sum(dsc[:, 0], axis=0)[:H].astype(scale.dtype)
+    dbias = jnp.sum(dbi[:, 0], axis=0)[:H].astype(scale.dtype)
     return ds, dscale, dbias
 
 
@@ -434,6 +439,7 @@ def _gelu_apply(y, bias, exact, _rb: int = None):
         in_specs=[_row_spec(rb, Fpad), _whole_spec(Fpad)],
         out_specs=_row_spec(rb, Fpad),
         out_shape=jax.ShapeDtypeStruct((rows_pad, Fpad), dtype),
+        name="_gelu_fwd_kernel",
         interpret=_interpret(),
     )(y2, _pad_row(bias.astype(jnp.float32), Fpad))
     return out[:rows, :F].reshape(shape)
@@ -470,10 +476,11 @@ def _fbg_bwd_impl(y, bias, g, exact, _rb: int = None):
                   _row_spec(rb, Fpad)],
         out_specs=[_row_spec(rb, Fpad), _part_spec(Fpad)],
         out_shape=[jax.ShapeDtypeStruct((rows_pad, Fpad), dtype),
-                   jax.ShapeDtypeStruct((grid, Fpad), jnp.float32)],
+                   jax.ShapeDtypeStruct((grid, 1, Fpad), jnp.float32)],
+        name="_gelu_bwd_kernel",
         interpret=_interpret(),
     )(y2, _pad_row(bias.astype(jnp.float32), Fpad), g2)
-    dbias = jnp.sum(dbp, axis=0)[:F].astype(bias.dtype)
+    dbias = jnp.sum(dbp[:, 0], axis=0)[:F].astype(bias.dtype)
     return dy[:rows, :F].reshape(shape), dbias
 
 

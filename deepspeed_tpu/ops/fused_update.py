@@ -96,15 +96,25 @@ from . import autotune
 
 ScheduleOrFloat = Union[Callable, float]
 
-# Kernel geometry: W lanes wide (128-multiple), up to _R sublane rows per
-# grid step. One (128, 1024) f32 block is 512 KiB; with 4 inputs + up to
-# 4 outputs double buffered that is ~8 MiB of VMEM — inside the ~16
-# MiB/core budget.
-_W = 1024
-_R = 128
-# Group rows pad to a multiple of 8*_W elements so the per-shard row
-# count is always a multiple of the f32 minimum sublane tile (8).
-_ROW_QUANTUM = 8 * _W
+# Kernel geometry: blocks of up to _R sublane rows x _W = 128 lanes. The
+# lane width is exactly ONE vreg row on purpose: a flat f32/bf16 buffer
+# (1-D tile of 1024 contiguous elements) and its [n/128, 128] view (one
+# (8, 128) tile = 8 full rows = the same 1024 contiguous elements) share
+# a memory order, so the reshape between the stored flat moment buffers
+# and the kernel's 2-D view is a BITCAST and the in-place aliasing holds.
+# A wider view ([n/1024, 1024]) is a physical relayout on the TPU: XLA
+# then copies m and v in AND out every step (+8.6 GB of live temps at
+# gpt2-large — the step no longer fits a 16 GB chip; measured with the
+# chip's compiler, tools/compile_rehearsal.py). One (1024, 128) f32
+# block is 512 KiB; with 4 inputs + up to 4 outputs double buffered that
+# is ~8 MiB of VMEM — inside the ~16 MiB/core budget.
+_W = 128
+_R = 1024
+# Group rows pad to a multiple of 8192 elements so the per-shard row
+# count is always a multiple of the f32 minimum sublane tile (8) at any
+# kernel lane width up to 1024 (the moment-buffer layout — part of the
+# checkpoint format — does not depend on _W).
+_ROW_QUANTUM = 8192
 
 # Virtual shard count: the flat layout interleaves every leaf over _V
 # rows, so any dp <= _V owns whole rows (= contiguous flat ranges) and
@@ -161,7 +171,7 @@ def _leaf_rows(n: int, shards: int) -> int:
 
 def _group_row_len(sizes, shards: int) -> int:
     """Padded per-row length L of a group buffer: sum of leaf rows,
-    padded so every 1/V row is a whole number of (8, _W) f32 tiles."""
+    padded so every 1/V row is a whole number of _ROW_QUANTUM elements."""
     L = sum(_leaf_rows(n, shards) for n in sizes)
     return max(_ROW_QUANTUM, -(-L // _ROW_QUANTUM) * _ROW_QUANTUM)
 
@@ -172,49 +182,86 @@ def group_nbytes(sizes, shards: int = _V, itemsize: int = 4) -> int:
     return virtual_shards(shards) * _group_row_len(sizes, shards) * itemsize
 
 
+def _flat_1d(x: jax.Array) -> jax.Array:
+    """``x.reshape(-1)`` pinned as a real 1-D intermediate.
+
+    XLA merges consecutive reshapes, and the merged relayouts this
+    module would otherwise ask for — leaf [a, b] <-> [V, r], [V, L] <->
+    [rows, _W] with r, L in the millions — cost the TPU compiler time
+    PROPORTIONAL to the array (13-29 s per 64M elements against a
+    described v5e; gpt2-large's step had not compiled after 25 min).
+    Either half through 1-D compiles in well under a second; the barrier
+    keeps XLA from fusing the halves back together."""
+    if x.ndim == 1:
+        return x
+    return lax.optimization_barrier(x.reshape(-1))
+
+
+def _padded_flat(leaf, dtype, shards: int, pin: bool):
+    """(1-D leaf in ``dtype``, zero-padded to ``shards * r``; r).
+    ``pin`` keeps the 1-D form a real intermediate (``_flat_1d``) — for
+    when the next op is another reshape XLA would merge it with."""
+    f = (_flat_1d(leaf) if pin else leaf.reshape(-1)).astype(dtype)
+    r = _leaf_rows(f.size, shards)
+    if r * shards > f.size:
+        f = jnp.concatenate([f, jnp.zeros((r * shards - f.size,), dtype)])
+    return f, r
+
+
 def _flatten_group(leaves, idxs, dtype, shards: int, Lpad: int,
                    constrain=None) -> jax.Array:
-    """Leaves -> the [shards, Lpad] V-interleaved group buffer.
+    """Leaves -> the V-interleaved group buffer (row v = the v-th 1/V
+    slice of every leaf, then the pad).
 
-    Each leaf reshapes to [shards, r_leaf] and the rows concatenate along
-    axis 1 — the concat axis is NOT the sharded axis, so GSPMD partitions
-    the assembly row-locally (no full-buffer materialization; the per-
-    leaf reshard is bounded by that leaf's size). ``constrain`` is the
-    optional NamedSharding pinning rows to the dp axis."""
-    cols = []
-    for i in idxs:
-        f = leaves[i].reshape(-1).astype(dtype)
-        r = _leaf_rows(f.size, shards)
-        if r * shards > f.size:
-            f = jnp.concatenate([f, jnp.zeros((r * shards - f.size,),
-                                              dtype)])
-        a = f.reshape(shards, r)
-        if constrain is not None:
-            a = lax.with_sharding_constraint(a, constrain)
-        cols.append(a)
-    L = sum(a.shape[1] for a in cols)
+    Sharded (``constrain`` = the NamedSharding pinning rows to the dp
+    axis): a ``[shards, Lpad]`` array — each leaf reshapes to
+    [shards, r_leaf] and the rows concatenate along axis 1; the concat
+    axis is NOT the sharded axis, so GSPMD partitions the assembly
+    row-locally (no full-buffer materialization; the per-leaf reshard is
+    bounded by that leaf's size).
+
+    Unsharded: the SAME element order assembled directly as the flat
+    ``[shards * Lpad]`` buffer the kernels read, from 1-D slices — no
+    [V, L] intermediate, whose relayout to 1-D is one more full pass
+    over the buffer (2.88 GB of f32 grads at gpt2-large): on the v5e
+    those passes were 250 ms of a 557 ms step, and under gradient
+    accumulation put the step over a 16 GB chip (PERF.md, PR 21)."""
+    flats = [_padded_flat(leaves[i], dtype, shards,
+                          pin=constrain is not None) for i in idxs]
+    L = sum(r for _, r in flats)
+    if constrain is None:
+        tail = [jnp.zeros((Lpad - L,), dtype)] if Lpad > L else []
+        rows = [piece for v in range(shards)
+                for piece in [lax.slice(f, (v * r,), ((v + 1) * r,))
+                              for f, r in flats] + tail]
+        return jnp.concatenate(rows) if len(rows) > 1 else rows[0]
+    cols = [lax.with_sharding_constraint(f.reshape(shards, r), constrain)
+            for f, r in flats]
     if Lpad > L:
-        tail = jnp.zeros((shards, Lpad - L), dtype)
-        if constrain is not None:
-            tail = lax.with_sharding_constraint(tail, constrain)
-        cols.append(tail)
+        cols.append(lax.with_sharding_constraint(
+            jnp.zeros((shards, Lpad - L), dtype), constrain))
     buf = jnp.concatenate(cols, axis=1) if len(cols) > 1 else cols[0]
-    if constrain is not None:
-        buf = lax.with_sharding_constraint(buf, constrain)
-    return buf
+    return lax.with_sharding_constraint(buf, constrain)
 
 
 def _unflatten_group(buf: jax.Array, like_leaves, idxs,
                      shards: int) -> Dict[int, jax.Array]:
-    """[shards, Lpad] group buffer -> {leaf idx: leaf-shaped array}.
-    Slices stay on the (sharded-safe) row axis; each leaf re-gathers at
-    most its own size downstream."""
+    """Group buffer -> {leaf idx: leaf-shaped array}. ``buf`` is the
+    ``[shards, Lpad]`` array (slices stay on the sharded-safe row axis;
+    each leaf re-gathers at most its own size downstream) or the flat
+    ``[shards * Lpad]`` form (1-D slices, no 2-D intermediate)."""
     out: Dict[int, jax.Array] = {}
     off = 0
+    Lpad = buf.size // shards
     for i in idxs:
         n = int(like_leaves[i].size)
         r = _leaf_rows(n, shards)
-        piece = lax.slice(buf, (0, off), (shards, off + r)).reshape(-1)
+        if buf.ndim == 1:
+            piece = jnp.concatenate(
+                [lax.slice(buf, (v * Lpad + off,), (v * Lpad + off + r,))
+                 for v in range(shards)])
+        else:
+            piece = _flat_1d(lax.slice(buf, (0, off), (shards, off + r)))
         out[i] = piece[:n].reshape(like_leaves[i].shape)
         off += r
     return out
@@ -383,11 +430,14 @@ def _run_sqnorm(gflat: jax.Array, _rb: int = None) -> jax.Array:
         _sqnorm_kernel,
         grid=(grid,),
         in_specs=[_chunk_spec(rb)],
-        out_specs=pl.BlockSpec((1, 128), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((grid, 128), jnp.float32),
+        # [grid, 1, 128] partials: the (1, 128) block spans the array's
+        # last two dims (the TPU (8, 128) tiling rule), grid dim squeezed.
+        out_specs=pl.BlockSpec((None, 1, 128), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((grid, 1, 128), jnp.float32),
+        name="_sqnorm_kernel",
         interpret=_interpret(),
     )(gflat.reshape(rows, _W))
-    return jnp.sum(out[:, 0])
+    return jnp.sum(out[:, 0, 0])
 
 
 def _run_group(gflat, pflat, m, v, scalars, seed, *, b1, b2, eps, wd,
@@ -438,13 +488,14 @@ def _run_group(gflat, pflat, m, v, scalars, seed, *, b1, b2, eps, wd,
         input_output_aliases=(
             {3: 0, 4: 1, 5: 2} if pflat.dtype == out_dtype
             else {4: 1, 5: 2}),
+        name="_fused_adam_kernel",
         interpret=_interpret(),
     )(scalars, seed, gflat.reshape(shape2), pflat.reshape(shape2),
       m.reshape(shape2), v.reshape(shape2))
     p_new, m_new, v_new = outs[0], outs[1], outs[2]
     cast_new = outs[3] if cast else None
-    return (p_new.reshape(-1), m_new.reshape(-1), v_new.reshape(-1),
-            None if cast_new is None else cast_new.reshape(-1))
+    return (_flat_1d(p_new), _flat_1d(m_new), _flat_1d(v_new),
+            None if cast_new is None else _flat_1d(cast_new))
 
 
 def apply_hbm_bytes(params: Any, *, one_pass: bool = True,
@@ -609,7 +660,7 @@ def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
         if compute_norm:
             nsq = jnp.float32(0.0)
             for g in gbufs:
-                nsq = nsq + _run_sqnorm(g.reshape(-1))
+                nsq = nsq + _run_sqnorm(_flat_1d(g))
             if axis is not None:
                 nsq = lax.psum(nsq, axis)
             # norm of the UNSCALED grads: ||g*inv|| == inv * ||g||.
@@ -652,8 +703,8 @@ def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
                 off = jnp.int32(0)
             seed = jnp.stack([seed0 + jnp.int32(gi), off])[None]
             pf, mn, vn, cf = _run_group(
-                gbufs[k].reshape(-1), pbufs[k].reshape(-1),
-                ms[k].reshape(-1), vs[k].reshape(-1), scalars, seed,
+                _flat_1d(gbufs[k]), _flat_1d(pbufs[k]),
+                _flat_1d(ms[k]), _flat_1d(vs[k]), scalars, seed,
                 b1=b1, b2=b2, eps=eps, wd=weight_decay,
                 coupled=not adam_w_mode, use_inv=use_inv,
                 use_coeff=use_coeff, one_pass=one_pass, sr=sr,
@@ -694,11 +745,12 @@ def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
                                         shards, Lpad, constrain))
             pbufs.append(_flatten_group(p_leaves, idxs, dt, shards,
                                         Lpad, constrain))
-            m2 = state.m[gi].reshape(shards, Lpad)
-            v2 = state.v[gi].reshape(shards, Lpad)
+            m2, v2 = state.m[gi], state.v[gi]
             if constrain is not None:
-                m2 = lax.with_sharding_constraint(m2, constrain)
-                v2 = lax.with_sharding_constraint(v2, constrain)
+                m2 = lax.with_sharding_constraint(
+                    m2.reshape(shards, Lpad), constrain)
+                v2 = lax.with_sharding_constraint(
+                    v2.reshape(shards, Lpad), constrain)
             ms.append(m2)
             vs.append(v2)
             sr = sr_key is not None and dt == jnp.dtype(jnp.bfloat16)
@@ -730,7 +782,11 @@ def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
                           (row,) * nbuf, (row,) * nbuf),
                 out_specs=((row,) * nbuf, (row,) * nbuf, (row,) * nbuf,
                            (row,) * ncast, P(), P()),
-                axis_names={shard_axis}, check_vma=False)
+                # Manual over EVERY mesh axis (the others are size 1 —
+                # the engine only takes this path on a pure-dp mesh): a
+                # Mosaic kernel under a partly-auto shard_map is refused
+                # ("cannot be automatically partitioned").
+                axis_names=set(mesh.axis_names), check_vma=False)
             out = fn(base, seed0, pre_coeff_arr, extra_skip_arr,
                      tuple(gbufs), tuple(pbufs), tuple(ms), tuple(vs))
         else:
@@ -804,7 +860,7 @@ def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
                                     Lpad)
                 pf = _flatten_group(p_leaves, [i], dt, shards, Lpad)
                 pn, mn, vn, _ = _run_group(
-                    gf.reshape(-1), pf.reshape(-1), state.m[gi][j],
+                    _flat_1d(gf), _flat_1d(pf), state.m[gi][j],
                     state.v[gi][j], scalars, seed, b1=b1, b2=b2,
                     eps=eps, wd=weight_decay, coupled=not adam_w_mode,
                     use_inv=False, use_coeff=pre_coeff is not None,

@@ -138,7 +138,7 @@ def _gg_kernel(a_ref, b_ref, bias_ref, o_ref, *, act: Optional[str],
         a_ref[0], b_ref[0], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)            # [bm, bn] f32
     if has_bias:
-        z = (acc + bias_ref[...].astype(jnp.float32)).astype(out_dtype)
+        z = (acc + bias_ref[0].astype(jnp.float32)).astype(out_dtype)
     else:
         z = acc.astype(out_dtype)
     if act is not None:
@@ -193,12 +193,15 @@ def _grouped_matmul(a: jax.Array, b: jax.Array,
     if (Kp, Np) != (K, N):
         b = jnp.pad(b, ((0, 0), (0, Kp - K), (0, Np - N)))
     has_bias = bias is not None
+    # Bias rides as [E, 1, Np]: its (1, bn) block then spans the
+    # second-minor dim (a (1, bn) block over [E, Np] breaks the TPU
+    # (8, 128) tiling rule).
     if has_bias:
-        bias2 = bias.astype(jnp.float32)
+        bias2 = bias.astype(jnp.float32)[:, None, :]
         if Np != N:
-            bias2 = jnp.pad(bias2, ((0, 0), (0, Np - N)))
+            bias2 = jnp.pad(bias2, ((0, 0), (0, 0), (0, Np - N)))
     else:  # dummy broadcast row (the _ln_forward no-residual idiom)
-        bias2 = jnp.zeros((E, Np), jnp.float32)
+        bias2 = jnp.zeros((E, 1, Np), jnp.float32)
 
     kernel = functools.partial(_gg_kernel, act=act, has_bias=has_bias,
                                out_dtype=out_dtype)
@@ -208,10 +211,11 @@ def _grouped_matmul(a: jax.Array, b: jax.Array,
         in_specs=[
             _spec((1, bm, Kp), lambda e, i, j: (e, i, 0)),
             _spec((1, Kp, bn), lambda e, i, j: (e, 0, j)),
-            _spec((1, bn), lambda e, i, j: (e, j)),
+            _spec((1, 1, bn), lambda e, i, j: (e, 0, j)),
         ],
         out_specs=_spec((1, bm, bn), lambda e, i, j: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, Mp, Np), out_dtype),
+        name="_gg_kernel",
         interpret=_interpret(),
     )(a, b, bias2)
     return out[:, :M, :N]

@@ -146,16 +146,22 @@ def _pattn_kernel(bt_ref, pos_ref, nlive_ref, q_ref, k_ref, v_ref, o_ref,
         # position j*bs + t; row k attends it iff it is <= pos[k]. The
         # final partial block contributes exactly its written rows, and
         # verify's K=k+1 rows get their per-row causal offsets here.
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1) + j * bs
-        allowed = jnp.concatenate(
-            [col <= pos_ref[s_idx, kk] for kk in range(K)], axis=0)
-        qs = q_ref[0]       # [K, bh, D]
+        # (The per-row positions are SMEM scalars; they are laid into
+        # an int32 [K, bs] tile by row select — Mosaic cannot
+        # concatenate K boolean rows.)
+        col = jax.lax.broadcasted_iota(jnp.int32, (K, bs), 1) + j * bs
+        row = jax.lax.broadcasted_iota(jnp.int32, (K, bs), 0)
+        pos = jnp.zeros((K, bs), jnp.int32)
+        for kk in range(K):
+            pos = jnp.where(row == kk, pos_ref[s_idx, kk], pos)
+        allowed = col <= pos
+        qs = q_ref[0]       # [bh, K, D]
         ks = k_ref[0, 0]    # [bh, bs, D]
         vs = v_ref[0, 0]
         for h2 in range(bh):
             # In-VMEM dequant: bf16 pool tiles upcast at the registers,
             # scores and the accumulator stay fp32 throughout.
-            q_h = qs[:, h2, :].astype(jnp.float32)
+            q_h = qs[h2].astype(jnp.float32)
             k_h = ks[h2].astype(jnp.float32)
             v_h = vs[h2].astype(jnp.float32)
             s = jax.lax.dot_general(
@@ -185,8 +191,7 @@ def _pattn_kernel(bt_ref, pos_ref, nlive_ref, q_ref, k_ref, v_ref, o_ref,
             rows = slice(h2 * K, (h2 + 1) * K)
             l_fin = l_scr[rows, 0:1]
             l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
-            o_ref[0, :, h2, :] = (acc_scr[rows] / l_safe).astype(
-                o_ref.dtype)
+            o_ref[0, h2] = (acc_scr[rows] / l_safe).astype(o_ref.dtype)
 
 
 def _heuristic_bh(num_heads: int, K: int) -> int:
@@ -204,8 +209,14 @@ def _heuristic_bh(num_heads: int, K: int) -> int:
 def _paged_call(q, pool_k, pool_v, bt, pos, nlive, *, scale, bh):
     """The pallas_call on flattened streams: q [GQ, K, nH, D], pools
     [G, B, nH, bs, D], scalar-prefetch bt [GQ, J] / pos [GQ, K] /
-    nlive [GQ, 1] (all int32, group-LOCAL block ids)."""
+    nlive [GQ, 1] (all int32, group-LOCAL block ids).
+
+    q and the output ride head-major ([GQ, nH, K, D]) so a (bh, K, D)
+    tile's last two dims span the array's: a head block in the
+    second-minor position must be a multiple of 8 or all of nH on the
+    TPU, which nH=20 / bh=4 is not."""
     GQ, K, nH, D = q.shape
+    q = jnp.swapaxes(q, 1, 2)
     G, B, _, bs, _ = pool_k.shape
     J = bt.shape[1]
     Q = GQ // G
@@ -225,26 +236,27 @@ def _paged_call(q, pool_k, pool_v, bt, pos, nlive, *, scale, bh):
             num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, K, bh, D),
+                pl.BlockSpec((1, bh, K, D),
                              lambda s, h, j, bt_p, pos_p, nl_p:
-                             (s, 0, h, 0)),
+                             (s, h, 0, 0)),
                 pl.BlockSpec((1, 1, bh, bs, D), _kv_map),
                 pl.BlockSpec((1, 1, bh, bs, D), _kv_map),
             ],
             out_specs=[
-                pl.BlockSpec((1, K, bh, D),
+                pl.BlockSpec((1, bh, K, D),
                              lambda s, h, j, bt_p, pos_p, nl_p:
-                             (s, 0, h, 0)),
+                             (s, h, 0, 0)),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bh * K, 128), jnp.float32),
                 pltpu.VMEM((bh * K, 128), jnp.float32),
                 pltpu.VMEM((bh * K, D), jnp.float32),
             ]),
-        out_shape=[jax.ShapeDtypeStruct((GQ, K, nH, D), q.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((GQ, nH, K, D), q.dtype)],
+        name="_pattn_kernel",
         interpret=_interpret(),
     )(bt, pos, nlive, q, pool_k, pool_v)
-    return out[0]
+    return jnp.swapaxes(out[0], 1, 2)
 
 
 def _paged_local(q, pool_k, pool_v, block_tables, positions, *, scale,
@@ -275,8 +287,16 @@ def _paged_local(q, pool_k, pool_v, block_tables, positions, *, scale,
         measure = None
         if autotune.search_allowed():
             def run_at(v):
-                return _paged_call(q2, pool_k, pool_v, bt2, pos2, nlive,
-                                   scale=scale, bh=v)
+                # Concrete stand-ins, never the (possibly traced)
+                # operands: every table slot live, so all J blocks load.
+                return _paged_call(
+                    jnp.zeros(q2.shape, q2.dtype),
+                    jnp.zeros(pool_k.shape, pool_k.dtype),
+                    jnp.zeros(pool_v.shape, pool_v.dtype),
+                    jnp.zeros(bt2.shape, jnp.int32),
+                    jnp.full(pos2.shape, J * bs - 1, jnp.int32),
+                    jnp.full(nlive.shape, J, jnp.int32),
+                    scale=scale, bh=v)
             measure = autotune.measure_from_runner(run_at)
         bh = autotune.resolve("paged_attn", (GQ, K, nH, D, B, bs, J),
                               str(q.dtype), heur, cands, measure)
@@ -317,7 +337,10 @@ def paged_attention(q, pool_k, pool_v, block_tables, positions, *, scale,
                       P(dpn, None, mpn, None, None),
                       P(dpn), P(dpn)),
             out_specs=P(dpn, None, None, mpn, None),
-            axis_names=set(mesh.axis_names))
+            axis_names=set(mesh.axis_names),
+            # No collective inside: nothing for the vma checker to
+            # check, and a pallas out_shape carries no vma.
+            check_vma=False)
         return fn(q, pool_k, pool_v, block_tables, positions)
     return _paged_local(q, pool_k, pool_v, block_tables, positions,
                         scale=scale, block_heads=block_heads)

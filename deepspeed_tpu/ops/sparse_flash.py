@@ -396,6 +396,7 @@ def _sparse_fwd(q, k, v, qid, kid, nnz, kmask, seed, scale, causal, nH, bq,
             jax.ShapeDtypeStruct((BH, S, D), q.dtype),
             jax.ShapeDtypeStruct((BH, 1, S), jnp.float32),
         ],
+        name="_sfwd_kernel",
         interpret=_interpret(),
     )(qid, kid, nnz, kmask, q, k, v, seed)
     return o, lse
@@ -460,6 +461,7 @@ def _sparse_bwd_fused(q, k, v, o, lse, do, luts, seed, scale, causal, nH,
             jax.ShapeDtypeStruct((BH, v.shape[1], D), v.dtype),
             jax.ShapeDtypeStruct((BH, NNZT, bq, D), jnp.float32),
         ],
+        name="_sfused_bwd_kernel",
         interpret=_interpret(),
     )(kidT, qidT, nnzT, kmaskT, q, k, v, do, lse, delta, seed)
 
@@ -526,6 +528,7 @@ def _sparse_bwd(q, k, v, o, lse, do, luts, seed, scale, causal, nH, bq, bk,
                 lambda b, n, qi, ki, nz, km: (b, qi[b % nH, n], 0)),
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
+        name="_sdq_kernel",
         interpret=_interpret(),
     )(qid, kid, nnz, kmask, q, k, v, do, lse, delta, seed)
 
@@ -573,6 +576,7 @@ def _sparse_bwd(q, k, v, o, lse, do, luts, seed, scale, causal, nH, bq, bk,
             jax.ShapeDtypeStruct((BH, k.shape[1], D), k.dtype),
             jax.ShapeDtypeStruct((BH, v.shape[1], D), v.dtype),
         ],
+        name="_sdkv_kernel",
         interpret=_interpret(),
     )(kidT, qidT, nnzT, kmaskT, q, k, v, do, lse, delta, seed)
     return dq, dk, dv

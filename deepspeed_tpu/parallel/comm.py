@@ -30,81 +30,47 @@ _INITIALIZED = False
 
 
 def shard_map(f, mesh=None, in_specs=None, out_specs=None, check_vma=None,
-              axis_names=None, **kw):
-    """Version-portable ``shard_map``: newer jax exposes ``jax.shard_map``
-    (kwargs ``check_vma`` and ``axis_names`` = the manual axes); older
-    releases ship it under ``jax.experimental.shard_map`` where the same
-    knobs are ``check_rep`` and the complementary ``auto`` set. Every
-    in-repo caller routes through here."""
-    if hasattr(jax, "shard_map"):
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    # Older jax's replication checker (check_rep) predates pcast/pvary, so
-    # kernels that mark varying carries with the new API can never satisfy
-    # it — disable it by default there (it is a static analysis only).
-    kw["check_rep"] = bool(check_vma) if check_vma is not None else False
+              axis_names=None):
+    """``jax.shard_map`` with ``None`` meaning "the default" for the two
+    optional knobs (``check_vma``; ``axis_names`` = the manual axes).
+    Every in-repo caller routes through here."""
+    kw = {}
+    if check_vma is not None:
+        kw["check_vma"] = check_vma
     if axis_names is not None:
-        auto = {a for a in frozenset(mesh.axis_names) - frozenset(axis_names)
-                if mesh.shape[a] > 1}
-        if auto:
-            # Partial-auto (manual pipe/seq axis + GSPMD dp/mp inside) is
-            # where old-jax support ends: its experimental `auto=` path
-            # CHECK-fails in XLA on these programs. Fail with a real
-            # message instead of aborting the interpreter.
-            raise NotImplementedError(
-                f"this jax ({jax.__version__}) cannot run a partially-"
-                f"manual shard_map (manual {sorted(axis_names)} + auto "
-                f"{sorted(auto)} axes); upgrade jax or set the auto axes "
-                "to size 1")
-        # All residual axes are size 1: run fully manual (equivalent).
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+        kw["axis_names"] = axis_names
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def axis_in_scope(axis_name: str) -> bool:
     """True when ``axis_name`` is bound as a MANUAL axis at the current
-    trace point (i.e. we are inside a shard_map/pmap over it, so
+    trace point (i.e. we are inside a shard_map over it, so
     ``lax.psum(axis_name)`` / ``lax.all_to_all(axis_name)`` are legal
     directly). Layers that normally wrap themselves in their own
     shard_map (the MoE FFN) use this to detect they are ALREADY inside
     one — the engine's factored explicit-gradient path runs the whole
     loss under a fully-manual shard_map over (expert, data) — and run
-    their collectives bare instead of nesting. Version-portable: probes
-    the axis env through whichever introspection this jax exposes;
-    an un-probe-able jax answers False (callers then take the
-    self-wrapping path, which is always correct outside a shard_map)."""
-    try:
-        from jax import core
-        if hasattr(core, "axis_frame"):            # jax <= 0.4.x
-            core.axis_frame(axis_name)
-            return True
-        if hasattr(core, "get_axis_env"):          # newer jax
-            return core.get_axis_env().axis_exists(axis_name)
-    except NameError:
-        return False
-    except Exception:
-        pass
-    try:
-        from jax import core
-        return axis_name in core.unsafe_get_axis_names_DO_NOT_USE()
-    except Exception:
-        return False
+    their collectives bare instead of nesting."""
+    return axis_name in jax.core.unsafe_get_axis_names_DO_NOT_USE()
 
 
 def pvary(x, axis_name):
-    """Mark ``x`` as varying over a manual mesh axis. New jax spells this
-    ``lax.pcast(..., to="varying")``; older releases have no such marking
-    (their shard_map rep-checker is disabled above), so it is identity."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axis_name, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axis_name)
-    return x
+    """Mark ``x`` as varying over manual mesh axis/axes ``axis_name``.
+    Only the axes ``x`` does not already vary over are cast: the vma
+    checker rejects a cast of an already-varying value.  Under a
+    ``check_vma=False`` shard_map nothing is tracked (even
+    ``axis_index`` reads as invariant) and the cast is skipped — there
+    its transpose would be a psum the untracked cotangent cannot pass."""
+    names = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
+    if names[0] not in jax.typeof(lax.axis_index(names[0])).vma:
+        return x
+
+    def mark(a):
+        missing = tuple(n for n in names if n not in jax.typeof(a).vma)
+        return lax.pcast(a, missing, to="varying") if missing else a
+
+    return jax.tree_util.tree_map(mark, x)
 
 
 def init_distributed(dist_backend: str = "xla", distributed_port: int = 29500,
@@ -184,11 +150,9 @@ def all_to_all(x: Any, axis_name: str, split_axis: int, concat_axis: int,
     an involution — the identity the MoE combine path relies on
     (deepspeed_tpu/moe/layer.py).
 
-    The operand is marked varying over the axis first (``pvary`` — the
-    same shard_map rep-checker shim its collective siblings got):
-    new-jax's vma analysis requires an all-to-all input to be
-    per-member-varying, and a replicated-marked operand would be
-    rejected; on old jax the marking is identity."""
+    The operand is marked varying over the axis first (``pvary``): the
+    vma analysis requires an all-to-all input to be per-member-varying,
+    and a replicated-marked operand would be rejected."""
     return lax.all_to_all(pvary(x, axis_name), axis_name,
                           split_axis=split_axis, concat_axis=concat_axis,
                           tiled=tiled)

@@ -65,6 +65,7 @@ COLLECTIVE_KINDS = ("all-reduce", "reduce-scatter", "all-gather",
 _IOTA_GROUPS_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]<=\[")
 _LIST_GROUPS_RE = re.compile(r"replica_groups=\{(\{[^}]*\}(?:,\{[^}]*\})*)\}")
 _PAIRS_RE = re.compile(r"source_target_pairs=\{(\{[^}]*\}(?:,\{[^}]*\})*)\}")
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
 _OPNAME_RE = re.compile(r'op_name="([^"]*)"')
 
 
@@ -141,10 +142,13 @@ def parse_hlo_collectives(hlo_text: str) -> List[CollectiveOp]:
 
     ops: List[CollectiveOp] = []
     for computation, lines in comp_lines.items():
-        for line in lines:
-            m = _INSTR_RE.match(line)
-            if not m:
-                continue
+        instrs = [(line, m) for line in lines
+                  for m in [_INSTR_RE.match(line)] if m]
+        # Operands print as bare `%name` references; their shapes are
+        # the defining instructions' (parameters included) in the same
+        # computation.
+        shape_of = {m.group("name"): m.group("shape") for _, m in instrs}
+        for line, m in instrs:
             op = m.group("op")
             is_async = op.endswith("-start")
             kind = op[:-6] if is_async else op
@@ -153,7 +157,10 @@ def parse_hlo_collectives(hlo_text: str) -> List[CollectiveOp]:
             out_bytes, out_shapes = _parse_shapes(m.group("shape"),
                                                   largest_only=is_async)
             # Operands: everything inside the call parens up to the
-            # matching close — `dtype[dims]{layout} %operand` pairs.
+            # matching close. XLA prints them as bare `%name` references
+            # (shape = the defining instruction's); HLO text printed with
+            # operand shapes (`dtype[dims]{layout} %operand`) reads
+            # directly.
             rest = line[m.end():]
             depth, i = 1, 0
             while i < len(rest) and depth:
@@ -162,7 +169,12 @@ def parse_hlo_collectives(hlo_text: str) -> List[CollectiveOp]:
                 elif rest[i] == ")":
                     depth -= 1
                 i += 1
-            in_bytes, in_shapes = _parse_shapes(rest[:i - 1])
+            operands = rest[:i - 1]
+            in_bytes, in_shapes = _parse_shapes(operands)
+            if not in_shapes:
+                in_bytes, in_shapes = _parse_shapes(" ".join(
+                    shape_of.get(ref, "")
+                    for ref in _OPERAND_RE.findall(operands)))
             attrs = rest[i:]
 
             group_size, num_groups = 1, 1

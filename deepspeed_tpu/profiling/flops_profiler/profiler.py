@@ -145,7 +145,7 @@ def _sub_jaxprs(eqn) -> List[Tuple[Any, int]]:
         if branches:
             best = max(branches, key=lambda b: _jaxpr_total(b)[0])
             out.append((best, 1))
-    elif name in ("pjit", "jit"):
+    elif name == "jit":
         out.append((p["jaxpr"], 1))
     elif name in ("custom_vjp_call", "custom_jvp_call",
                   "custom_vjp_call_jaxpr", "custom_jvp_call_jaxpr"):

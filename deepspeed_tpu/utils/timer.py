@@ -115,10 +115,10 @@ class ThroughputTimer:
     """Samples/sec tracker with warm-up, parity with timer.py:106-183.
 
     TPU-native delta: per-step device fences would serialize the async
-    dispatch pipeline (each fence is a full host↔device round trip — ruinous
-    on a tunneled backend), so by default the timer syncs only at reporting
-    windows and averages over the window. ``synchronized=True`` restores the
-    reference's fence-every-step behavior (wall_clock_breakdown).
+    dispatch pipeline (each fence is a full host↔device round trip), so by
+    default the timer syncs only at reporting windows and averages over
+    the window. ``synchronized=True`` restores the reference's
+    fence-every-step behavior (wall_clock_breakdown).
     """
 
     def __init__(self, batch_size: int, num_workers: int = 1, start_step: int = 2,

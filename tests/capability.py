@@ -1,60 +1,8 @@
-"""Capability probes for jax-version-dependent features.
-
-Some tier-1 tests need a *partially-manual* shard_map — a manual pipe/seq
-axis wrapped around GSPMD-auto dp/mp axes of size > 1. Old jax (< the
-`jax.shard_map` API, e.g. 0.4.37) cannot compile these programs: its
-experimental `auto=` path CHECK-fails inside XLA, so `parallel/comm.py`
-raises NotImplementedError instead of aborting the interpreter.
-
-These probes TRY the feature once (build + trace + compile a minimal
-program) and cache the answer, so the skip tracks actual capability, not a
-version string — upgrading jax un-skips the tests with no edits here.
-"""
+"""Capability probe for backend-dependent features: the skip tracks what
+the backend can actually compile, not a platform or version string."""
 import functools
 
 import jax
-import jax.numpy as jnp
-import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-
-
-@functools.lru_cache(maxsize=None)
-def partial_auto_skip_reason():
-    """None when this jax can compile a shard_map with one manual axis and
-    one auto (GSPMD) axis of size > 1 — the shape every pp>1 x dp>1 /
-    sp>1 x dp>1 program in this repo lowers to. Otherwise the skip reason,
-    naming the ACTUAL blocker (device count vs jax capability)."""
-    if len(jax.devices()) < 4:
-        return ("partial-auto shard_map probe needs >= 4 devices (a 2x2 "
-                f"manual x auto mesh); only {len(jax.devices())} visible — "
-                "run under the 8-device CPU mesh (tests/conftest.py)")
-    from deepspeed_tpu.parallel import comm
-
-    devs = np.asarray(jax.devices()[:4]).reshape(2, 2)
-    mesh = Mesh(devs, ("manual", "auto"))
-    x = jnp.arange(8, dtype=jnp.float32).reshape(2, 4)
-    try:
-        f = comm.shard_map(
-            lambda a: jax.lax.psum(a, "manual"), mesh=mesh,
-            in_specs=P("manual"), out_specs=P(),
-            axis_names={"manual"}, check_vma=False)
-        jax.jit(f).lower(x).compile()
-        return None
-    except NotImplementedError:
-        return ("this jax cannot compile a partially-manual shard_map "
-                "(manual pipe/seq axis + auto dp/mp axes > 1); capability "
-                "probe failed — upgrade jax (the newer jax.shard_map API) "
-                "to run this test")
-    except Exception as e:   # pragma: no cover - any other failure
-        return ("partial-auto shard_map capability probe failed with "
-                f"{type(e).__name__}: {e}")
-
-
-def partial_auto_shard_map_supported() -> bool:
-    return partial_auto_skip_reason() is None
-
-
-PARTIAL_AUTO_SKIP_REASON = partial_auto_skip_reason() or ""
 
 
 @functools.lru_cache(maxsize=None)

@@ -188,6 +188,61 @@ class TestRegistry:
         assert got == 64
         assert sorted(meas.calls) == [32, 64]
 
+    def test_every_candidate_failing_is_an_error(self, registry):
+        """A search in which nothing ran is not a quiet heuristic."""
+        meas = CountingMeasure({})           # every candidate raises
+        with pytest.raises(RuntimeError, match="every candidate failed"):
+            autotune.resolve("k", (7, 7), "float32", 64, (32, 64), meas)
+        assert sorted(meas.calls) == [32, 64]
+        assert not os.path.exists(registry)
+
+    def test_no_search_under_a_trace(self, registry):
+        """Under jit a runner's arrays are tracers and the clock would
+        read tracing: the registry or the heuristic answers, nothing is
+        timed; the same call made eagerly searches."""
+        meas = CountingMeasure({32: 0.01, 64: 0.05})
+        picked = []
+
+        @jax.jit
+        def traced(x):
+            picked.append(autotune.resolve("k", (5, 5), "float32", 64,
+                                           (32, 64), meas))
+            return x
+
+        traced(jnp.zeros(()))
+        assert picked == [64] and meas.calls == []
+        assert autotune.counters["search"] == 0
+        assert autotune.resolve("k", (5, 5), "float32", 64, (32, 64),
+                                meas) == 32
+        assert autotune.counters["search"] == 1
+        picked.clear()
+        jax.jit(lambda x: traced.__wrapped__(x))(jnp.zeros(()))
+        assert picked == [32]                # traced resolve HITS it
+
+    def test_runner_returning_tracers_is_refused(self, registry):
+        measure = autotune.measure_from_runner(lambda tile: jnp.zeros(()))
+
+        @jax.jit
+        def traced(x):
+            with pytest.raises(RuntimeError, match="tracers"):
+                measure(32)
+            return x
+
+        traced(jnp.zeros(()))
+
+    def test_registry_sits_beside_the_compile_cache(self, monkeypatch,
+                                                    tmp_path):
+        from deepspeed_tpu.utils import compile_cache
+        monkeypatch.delenv("DS_AUTOTUNE_REGISTRY", raising=False)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert compile_cache.cache_dir() == os.path.join(repo, ".jax_cache")
+        assert autotune.registry_path() == os.path.join(
+            repo, ".jax_cache", "autotune.json")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.cache_dir() == str(tmp_path)
+        assert autotune.registry_path() == str(tmp_path / "autotune.json")
+
     def test_no_measure_returns_heuristic_without_record(self, registry):
         got = autotune.resolve("k", (3, 3), "float32", 64, (32, 64), None)
         assert got == 64

@@ -46,7 +46,10 @@ class TestPeakTable:
 
     @pytest.mark.parametrize("kind,gen", [
         ("TPU v4", "v4"), ("TPU v5e", "v5e"), ("TPU v5p", "v5p"),
-        ("TPU v6e", "v6e")])
+        ("TPU v6e", "v6e"),
+        # device_kind as the hardware reports it
+        ("TPU v5 lite", "v5e"), ("TPU v5", "v5p"), ("TPU v6 lite", "v6e"),
+        ("v5e", "v5e")])                 # bare generation key
     def test_kind_resolution(self, kind, gen):
         pk = peaks_for_kind(kind)
         assert pk.name == gen and not pk.assumed
@@ -54,10 +57,21 @@ class TestPeakTable:
         assert pk.hbm_gbs == TPU_HBM_GBS[gen]
         assert pk.ici_gbs == TPU_ICI_GBS[gen]
 
-    def test_unknown_kind_is_assumed_v5e(self):
+    def test_non_tpu_kind_is_assumed_v5e(self):
         for kind in ("cpu", "", "NVIDIA H100", None):
             pk = peaks_for_kind(kind or "")
             assert pk.name == "v5e" and pk.assumed
+
+    @pytest.mark.parametrize("kind", ["TPU v9", "TPU7x", "tpu v5 ultra"])
+    def test_unknown_tpu_kind_raises(self, kind):
+        """A real chip with no row is an error, never a default."""
+        with pytest.raises(KeyError, match="no peak row"):
+            peaks_for_kind(kind)
+
+    def test_the_described_chip_is_a_measured_v5e_row(self):
+        pk = peaks_for_kind("TPU v5 lite")
+        assert (pk.name, pk.assumed) == ("v5e", False)
+        assert (pk.bf16_tflops, pk.hbm_gbs) == (197.0, 819.0)
 
     def test_unit_conversions(self):
         pk = peaks_for_kind("TPU v4")
@@ -70,7 +84,10 @@ class TestPeakTable:
         of truth for every MFU denominator."""
         assert bench.TPU_PEAK_TFLOPS is TPU_PEAK_TFLOPS
         assert bench.chip_peak_tflops is chip_peak_tflops
-        assert chip_peak_tflops() > 0
+        # ...and off-TPU there is no peak to divide by: no utilisation
+        # is ever printed against the assumed row.
+        with pytest.raises(RuntimeError, match="ASSUMED"):
+            chip_peak_tflops()
 
 
 # --------------------------------------------------------------------- #
@@ -732,15 +749,15 @@ class TestBenchGateKernels:
                            "kernels": {"fused_speedup": 1.03}})
         assert bg.main([old, new]) == 0
 
-    def test_recorded_r06_gates_against_r05(self):
-        """The in-tree BENCH_r05 -> BENCH_r06 pair must pass the gate
-        (r06 is the honestly-labeled projected kernel round)."""
+    def test_recorded_r07_gates_against_r06(self):
+        """The in-tree BENCH_r06 -> BENCH_r07 pair must pass the gate
+        (both are honestly-labeled projected kernel rounds)."""
         import json as _json
         bg = load_bench_gate()
-        r5 = os.path.join(REPO, "BENCH_r05.json")
         r6 = os.path.join(REPO, "BENCH_r06.json")
-        assert os.path.exists(r6), "run ablate_fused_ln.py --record"
-        assert bg.main([r5, r6]) == 0
-        rec = _json.load(open(r6))["parsed"]
-        assert rec.get("projected") is True      # honesty label
-        assert rec["kernels"]["fused_speedup"] > 1.0
+        r7 = os.path.join(REPO, "BENCH_r07.json")
+        assert bg.main([r6, r7]) == 0
+        for path in (r6, r7):
+            rec = _json.load(open(path))["parsed"]
+            assert rec.get("projected") is True      # honesty label
+            assert rec["kernels"]["fused_speedup"] > 1.0

@@ -95,12 +95,6 @@ def test_gpt2_example_onebit_real_text():
 
 
 def test_gpt2_example_pipeline_1f1b_real_text():
-    from capability import partial_auto_skip_reason
-    reason = partial_auto_skip_reason()
-    if reason:
-        # pp=2 x dp=4 lowers to a partially-manual shard_map this jax
-        # cannot compile — the same capability gate the pipe tier uses.
-        pytest.skip(reason)
     loss, curve = run_example("examples/gpt2/train.py",
                               "--config", "ds_config_pipeline.json",
                               "--pipeline", "--data", CORPUS,

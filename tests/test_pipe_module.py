@@ -9,8 +9,6 @@ import deepspeed_tpu
 from deepspeed_tpu.runtime.pipe.module import (PipelineModule, LayerSpec,
                                                TiedLayerSpec)
 
-from capability import (PARTIAL_AUTO_SKIP_REASON,
-                        partial_auto_shard_map_supported)
 
 
 class Dense:
@@ -126,8 +124,6 @@ class TestPipelineEngineSingleStage:
 
 
 class TestToPipeSpec:
-    @pytest.mark.skipif(not partial_auto_shard_map_supported(),
-                        reason=PARTIAL_AUTO_SKIP_REASON)
     def test_uniform_module_runs_pp2(self):
         """to_pipe_spec: a uniform PipelineModule trains on a pp=2 mesh via
         the compiled SPMD pipeline and matches the pp=1 fused trajectory."""

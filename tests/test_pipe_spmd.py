@@ -16,8 +16,6 @@ from deepspeed_tpu.models.gpt2 import gpt2_loss_fn
 from deepspeed_tpu.models.gpt2_pipe import gpt2_pipe_spec
 from deepspeed_tpu.parallel.topology import build_mesh
 
-from capability import (PARTIAL_AUTO_SKIP_REASON,
-                        partial_auto_shard_map_supported)
 
 
 @pytest.fixture(scope="module")
@@ -33,8 +31,6 @@ def _flat_params(spec):
 
 
 class TestSpmdPipeline:
-    @pytest.mark.skipif(not partial_auto_shard_map_supported(),
-                        reason=PARTIAL_AUTO_SKIP_REASON)
     def test_pipeline_loss_matches_sequential(self, cfg):
         """pp=4 pipelined loss == plain gpt2 loss on identical params."""
         spec = gpt2_pipe_spec(cfg, rng=jax.random.PRNGKey(0))

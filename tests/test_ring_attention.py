@@ -9,13 +9,9 @@ from deepspeed_tpu.ops.ring_attention import ring_attention, ring_attention_fn
 from deepspeed_tpu.models.transformer import dense_attention
 from deepspeed_tpu.parallel.topology import build_mesh
 
-from capability import (PARTIAL_AUTO_SKIP_REASON,
-                        partial_auto_shard_map_supported)
 
 # The sp>1 meshes below all carry a dp axis > 1 alongside the manual seq
 # axis — a partially-manual shard_map old jax cannot compile.
-needs_partial_auto = pytest.mark.skipif(
-    not partial_auto_shard_map_supported(), reason=PARTIAL_AUTO_SKIP_REASON)
 
 
 def _qkv(seed, B=2, S=32, nH=2, D=16):
@@ -24,7 +20,6 @@ def _qkv(seed, B=2, S=32, nH=2, D=16):
                  for k in ks)
 
 
-@needs_partial_auto
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("sp", [2, 4])
 def test_ring_matches_dense(causal, sp):
@@ -37,7 +32,6 @@ def test_ring_matches_dense(causal, sp):
                                rtol=2e-5, atol=2e-5)
 
 
-@needs_partial_auto
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_grads_match_dense(causal):
     mesh = build_mesh(sp=4, devices=jax.devices()[:8])
@@ -60,7 +54,6 @@ def test_ring_grads_match_dense(causal):
                                    err_msg=f"d{n}")
 
 
-@needs_partial_auto
 def test_ring_in_transformer_block():
     """ring_attention_fn plugs into apply_blocks as the attention_fn."""
     from deepspeed_tpu.models.transformer import (TransformerConfig,

@@ -960,8 +960,7 @@ class TestPipelineCostModel:
         rng = np.random.default_rng(0)
         x = rng.normal(size=(4, 4, D)).astype(np.float32)
         y = x.sum(axis=(-1, -2))
-        # pp=2 x dp=1: stays inside this jax's shard_map capability
-        # envelope (pp>1 x dp>1 needs partial-auto — see capability.py)
+        # pp=2 x dp=1
         mesh_pp = build_mesh(pp=2, devices=jax.devices()[:2])
         engine = PipelineEngine(model=spec, config=cfg, mesh=mesh_pp)
         engine.train_batch((x, y))

@@ -27,7 +27,7 @@ tokens/s regresses on a relative drop beyond ``--serve-drop`` (default
 25% — latency percentiles on a CPU mesh are noisy; the gate catches
 step changes, not jitter); the fused-kernel ablation speedup (the
 ``kernels.fused_speedup`` field a DS_BENCH_KERNELS=1 bench or
-``ablate_fused_ln.py`` records) regresses on a relative drop beyond
+the BENCH_r06/r07 projections record) regresses on a relative drop beyond
 ``--kernel-drop`` (default 10%); the autotuned-tile speedup (the
 ``kernels.tile_speedup`` field ``ablate_autotune.py --record`` writes
 — geomean of the per-kernel winner-over-heuristic ratios) regresses on
@@ -134,7 +134,7 @@ def extract_metrics(doc: Dict[str, Any]) -> Dict[str, Optional[float]]:
         if z3.get("dcn_param_bytes_per_step") is not None:
             z3_dcn_param = float(z3["dcn_param_bytes_per_step"])
     # DS_BENCH_KERNELS ablation record: the fused-over-unfused step
-    # speedup (bench.py bench_kernels_ablation / ablate_fused_ln.py).
+    # speedup (bench.py bench_kernels_ablation).
     krn = doc.get("kernels")
     if isinstance(krn, dict) and krn.get("fused_speedup") is not None:
         kernel_speedup = float(krn["fused_speedup"])
@@ -368,7 +368,7 @@ def latest_rounds(directory: str) -> Optional[Tuple[str, str]]:
     number order), or None when fewer than two exist."""
     rounds = sorted(glob.glob(os.path.join(directory, "BENCH_r*.json")),
                     key=_round_key)
-    # Driver side files like BENCH_r04_builder.json are not rounds.
+    # Side files like BENCH_r09_builder.json are not rounds.
     rounds = [p for p in rounds
               if re.fullmatch(r"BENCH_r\d+\.json", os.path.basename(p))]
     if len(rounds) < 2:

@@ -275,10 +275,8 @@ def ladder():
         ("moe", "MOE_BENCH.json", run_moe),
         ("multislice", "MULTISLICE_BENCH.json", run_multislice),
         ("serving_attend", "SERVE_BENCH.json", run_serving),
-        # No profiled runner: these price host-side wall clock
-        # (resilience goodput) or an analytic transfer tunnel
-        # (offload) — a device trace does not back them either way.
-        ("offload", "OFFLOAD_BENCH.json", None),
+        # No profiled runner: this prices host-side wall clock
+        # (resilience goodput) — a device trace does not back it.
         ("resilience", "RESILIENCE_BENCH.json", None),
     ]
 
@@ -394,6 +392,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     backend = jax.default_backend()
+    if backend == "tpu":
+        from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+        enable_compile_cache()
     print(f"tpu_truth: backend={backend}, devices={jax.device_count()}"
           f" -> new labels are "
           f"{'measured' if backend == 'tpu' else 'cpu-structural'}")
