@@ -68,6 +68,7 @@ def _check_cfg(cfg: GPT2Config) -> None:
             "autoregressive serving story")
 
 
+@jax.named_scope("mlp")
 def _ffn(p: Dict[str, jax.Array], x: jax.Array, cfg: GPT2Config
          ) -> jax.Array:
     # layer_norm_fn / gelu_dense_fn resolve to the fused Pallas kernels
@@ -79,6 +80,22 @@ def _ffn(p: Dict[str, jax.Array], x: jax.Array, cfg: GPT2Config
     h = gelu_dense_fn(cfg)(h, p["fc_kernel"], p["fc_bias"])
     h = dense(h, p["fc_out_kernel"], p["fc_out_bias"])
     return x + h
+
+
+@jax.named_scope("embed")
+def _embed(params: Dict[str, Any], tokens: jax.Array, pos,
+           cfg: GPT2Config) -> jax.Array:
+    """Token + position embedding; ``pos`` indexes the position table
+    (an int array shaped like ``tokens``, or a slice)."""
+    return params["wte"].astype(cfg.dtype)[tokens] + \
+        params["wpe"].astype(cfg.dtype)[pos]
+
+
+@jax.named_scope("lm_head")
+def _unembed(params: Dict[str, Any], h: jax.Array, cfg: GPT2Config
+             ) -> jax.Array:
+    """Tied unembedding: h [..., H] -> fp32 logits [..., V]."""
+    return (h @ params["wte"].astype(cfg.dtype).T).astype(jnp.float32)
 
 
 def _qkv(p: Dict[str, jax.Array], x: jax.Array, cfg: GPT2Config
@@ -102,17 +119,20 @@ def _decode_block(p, x, kc, vc, lengths, cfg: GPT2Config):
     exactly the causal row the full forward computes at that position.
     """
     S, H = x.shape
-    q, k, v = _qkv(p, x, cfg)                       # [S, nH, D] each
-    kc = kv_cache.write_token(kc, k, lengths)
-    vc = kv_cache.write_token(vc, v, lengths)
-    s = jnp.einsum("snd,sntd->snt", q, kc).astype(jnp.float32)
-    s = s / math.sqrt(cfg.head_dim)
-    mask = kv_cache.length_mask(lengths, kc.shape[2])   # [S, T]
-    s = jnp.where(mask[:, None, :], s, NEG_INF)
-    w = jax.nn.softmax(s, axis=-1)
-    attn = jnp.einsum("snt,sntd->snd", w.astype(vc.dtype), vc)
-    attn = attn.reshape(S, H).astype(x.dtype)
-    x = x + dense(attn, p["proj_kernel"], p["proj_bias"])
+    with jax.named_scope("attn"):
+        q, k, v = _qkv(p, x, cfg)                   # [S, nH, D] each
+        with jax.named_scope("kv_write"):
+            kc = kv_cache.write_token(kc, k, lengths)
+            vc = kv_cache.write_token(vc, v, lengths)
+        with jax.named_scope("attend"):
+            s = jnp.einsum("snd,sntd->snt", q, kc).astype(jnp.float32)
+            s = s / math.sqrt(cfg.head_dim)
+            mask = kv_cache.length_mask(lengths, kc.shape[2])   # [S, T]
+            s = jnp.where(mask[:, None, :], s, NEG_INF)
+            w = jax.nn.softmax(s, axis=-1)
+            attn = jnp.einsum("snt,sntd->snd", w.astype(vc.dtype), vc)
+        attn = attn.reshape(S, H).astype(x.dtype)
+        x = x + dense(attn, p["proj_kernel"], p["proj_bias"])
     return _ffn(p, x, cfg), kc, vc
 
 
@@ -123,8 +143,7 @@ def gpt2_decode(params: Dict[str, Any], kc: jax.Array, vc: jax.Array,
     [S, V] fp32, kc', vc'). The caller advances lengths for the slots it
     considers active; position = lengths[s] by construction."""
     _check_cfg(cfg)
-    x = params["wte"].astype(cfg.dtype)[tokens] + \
-        params["wpe"].astype(cfg.dtype)[lengths]
+    x = _embed(params, tokens, lengths, cfg)
 
     def body(h, layer):
         p, kcl, vcl = layer
@@ -133,7 +152,7 @@ def gpt2_decode(params: Dict[str, Any], kc: jax.Array, vc: jax.Array,
 
     x, (kc, vc) = lax.scan(body, x, (params["blocks"], kc, vc))
     x = layer_norm_fn(cfg)(x, params["ln_f_scale"], params["ln_f_bias"])
-    logits = (x @ params["wte"].astype(cfg.dtype).T).astype(jnp.float32)
+    logits = _unembed(params, x, cfg)
     return logits, kc, vc
 
 
@@ -145,21 +164,24 @@ def _prefill_block(p, x, kc, vc, slot, start, cfg: GPT2Config):
     the chunk against the slot's whole cache row under the global causal
     mask (col <= start + row)."""
     C, H = x.shape
-    q, k, v = _qkv(p, x, cfg)                       # [C, nH, D]
-    kc = kv_cache.write_chunk(kc, k, slot, start)
-    vc = kv_cache.write_chunk(vc, v, slot, start)
-    krow = kv_cache.slot_rows(kc, slot)             # [nH, T, D]
-    vrow = kv_cache.slot_rows(vc, slot)
-    s = jnp.einsum("cnd,ntd->nct", q, krow).astype(jnp.float32)
-    s = s / math.sqrt(cfg.head_dim)
-    T = krow.shape[1]
-    rows = start + lax.broadcasted_iota(jnp.int32, (C, 1), 0)
-    cols = lax.broadcasted_iota(jnp.int32, (1, T), 1)
-    s = jnp.where((cols <= rows)[None], s, NEG_INF)
-    w = jax.nn.softmax(s, axis=-1)
-    attn = jnp.einsum("nct,ntd->cnd", w.astype(vrow.dtype), vrow)
-    attn = attn.reshape(C, H).astype(x.dtype)
-    x = x + dense(attn, p["proj_kernel"], p["proj_bias"])
+    with jax.named_scope("attn"):
+        q, k, v = _qkv(p, x, cfg)                   # [C, nH, D]
+        with jax.named_scope("kv_write"):
+            kc = kv_cache.write_chunk(kc, k, slot, start)
+            vc = kv_cache.write_chunk(vc, v, slot, start)
+        with jax.named_scope("attend"):
+            krow = kv_cache.slot_rows(kc, slot)     # [nH, T, D]
+            vrow = kv_cache.slot_rows(vc, slot)
+            s = jnp.einsum("cnd,ntd->nct", q, krow).astype(jnp.float32)
+            s = s / math.sqrt(cfg.head_dim)
+            T = krow.shape[1]
+            rows = start + lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+            cols = lax.broadcasted_iota(jnp.int32, (1, T), 1)
+            s = jnp.where((cols <= rows)[None], s, NEG_INF)
+            w = jax.nn.softmax(s, axis=-1)
+            attn = jnp.einsum("nct,ntd->cnd", w.astype(vrow.dtype), vrow)
+        attn = attn.reshape(C, H).astype(x.dtype)
+        x = x + dense(attn, p["proj_kernel"], p["proj_bias"])
     return _ffn(p, x, cfg), kc, vc
 
 
@@ -183,8 +205,7 @@ def gpt2_prefill_chunk(params: Dict[str, Any], kc: jax.Array,
     _check_cfg(cfg)
     C = tokens.shape[0]
     pos = start + jnp.arange(C, dtype=jnp.int32)
-    x = params["wte"].astype(cfg.dtype)[tokens] + \
-        params["wpe"].astype(cfg.dtype)[pos]
+    x = _embed(params, tokens, pos, cfg)
 
     def body(h, layer):
         p, kcl, vcl = layer
@@ -195,8 +216,7 @@ def gpt2_prefill_chunk(params: Dict[str, Any], kc: jax.Array,
     x = layer_norm_fn(cfg)(x, params["ln_f_scale"], params["ln_f_bias"])
     h_last = lax.dynamic_slice(x, (last_idx.astype(jnp.int32),
                                    jnp.int32(0)), (1, x.shape[1]))[0]
-    logits = (h_last @ params["wte"].astype(cfg.dtype).T
-              ).astype(jnp.float32)
+    logits = _unembed(params, h_last, cfg)
     return logits, kc, vc
 
 
@@ -220,31 +240,32 @@ def gpt2_prefill_full(params: Dict[str, Any], kc: jax.Array,
         from ..ops.flash_attention import auto_attention
         attention_fn = auto_attention
     T = tokens.shape[0]
-    x = (params["wte"].astype(cfg.dtype)[tokens] +
-         params["wpe"].astype(cfg.dtype)[:T])[None]        # [1, T, H]
+    x = _embed(params, tokens, slice(T), cfg)[None]        # [1, T, H]
 
     def body(h, p):
-        q, k, v = _qkv(p, h, cfg)                  # [1, T, nH, D]
-        attn = attention_fn(q, k, v, mask=None, causal=True,
-                            deterministic=True)
-        attn = attn.reshape(h.shape).astype(h.dtype)
-        h = h + dense(attn, p["proj_kernel"], p["proj_bias"])
+        with jax.named_scope("attn"):
+            q, k, v = _qkv(p, h, cfg)              # [1, T, nH, D]
+            with jax.named_scope("attend"):
+                attn = attention_fn(q, k, v, mask=None, causal=True,
+                                    deterministic=True)
+            attn = attn.reshape(h.shape).astype(h.dtype)
+            h = h + dense(attn, p["proj_kernel"], p["proj_bias"])
         return _ffn(p, h, cfg), (k[0], v[0])       # ys: [T, nH, D]
 
     x, (ks, vs) = lax.scan(body, x, params["blocks"])
     # ks/vs [L, T, nH, D] → cache block [L, 1, nH, T, D] at slot.
     zero = jnp.int32(0)
     at = (zero, slot.astype(jnp.int32), zero, zero, zero)
-    kc = lax.dynamic_update_slice(
-        kc, ks.transpose(0, 2, 1, 3)[:, None].astype(kc.dtype), at)
-    vc = lax.dynamic_update_slice(
-        vc, vs.transpose(0, 2, 1, 3)[:, None].astype(vc.dtype), at)
+    with jax.named_scope("kv_write"):
+        kc = lax.dynamic_update_slice(
+            kc, ks.transpose(0, 2, 1, 3)[:, None].astype(kc.dtype), at)
+        vc = lax.dynamic_update_slice(
+            vc, vs.transpose(0, 2, 1, 3)[:, None].astype(vc.dtype), at)
     x = layer_norm_fn(cfg)(x[0], params["ln_f_scale"],
                            params["ln_f_bias"])
     h_last = lax.dynamic_slice(x, (last_idx.astype(jnp.int32),
                                    jnp.int32(0)), (1, x.shape[1]))[0]
-    logits = (h_last @ params["wte"].astype(cfg.dtype).T
-              ).astype(jnp.float32)
+    logits = _unembed(params, h_last, cfg)
     return logits, kc, vc
 
 
@@ -282,24 +303,30 @@ def _paged_attn_block(p, x, kc, vc, bt_g, cfg: GPT2Config,
     Sg = S // G
     R = Sg * K
     nH, D = cfg.num_heads, cfg.head_dim
-    q, k, v = _qkv(p, x, cfg)                        # [S, K, nH, D]
-    bs = kc.shape[3]
-    bt_rows = jnp.broadcast_to(bt_g[:, :, None, :],
-                               (G, Sg, K, bt_g.shape[-1])
-                               ).reshape(G, R, -1)
-    blk, off = kv_cache.positions_to_blocks(bt_rows, write_pos, bs)
-    kc = kv_cache.paged_write_rows(kc, k.reshape(G, R, nH, D), blk, off)
-    vc = kv_cache.paged_write_rows(vc, v.reshape(G, R, nH, D), blk, off)
-    if paged_kernel:
-        attn = paged_attn_ops.paged_attention(
-            q.reshape(G, Sg, K, nH, D), kc, vc, bt_g, pos_g,
-            scale=1.0 / math.sqrt(D), mesh=mesh)
-    else:
-        attn = kv_cache.paged_attend(q.reshape(G, Sg, K, nH, D), kc, vc,
-                                     sel, pos_mask, 1.0 / math.sqrt(D),
-                                     NEG_INF)
-    attn = attn.reshape(S, K, H).astype(x.dtype)
-    x = x + dense(attn, p["proj_kernel"], p["proj_bias"])
+    with jax.named_scope("attn"):
+        q, k, v = _qkv(p, x, cfg)                    # [S, K, nH, D]
+        bs = kc.shape[3]
+        with jax.named_scope("kv_write"):
+            bt_rows = jnp.broadcast_to(bt_g[:, :, None, :],
+                                       (G, Sg, K, bt_g.shape[-1])
+                                       ).reshape(G, R, -1)
+            blk, off = kv_cache.positions_to_blocks(bt_rows, write_pos,
+                                                    bs)
+            kc = kv_cache.paged_write_rows(kc, k.reshape(G, R, nH, D),
+                                           blk, off)
+            vc = kv_cache.paged_write_rows(vc, v.reshape(G, R, nH, D),
+                                           blk, off)
+        with jax.named_scope("attend"):
+            if paged_kernel:
+                attn = paged_attn_ops.paged_attention(
+                    q.reshape(G, Sg, K, nH, D), kc, vc, bt_g, pos_g,
+                    scale=1.0 / math.sqrt(D), mesh=mesh)
+            else:
+                attn = kv_cache.paged_attend(
+                    q.reshape(G, Sg, K, nH, D), kc, vc, sel, pos_mask,
+                    1.0 / math.sqrt(D), NEG_INF)
+        attn = attn.reshape(S, K, H).astype(x.dtype)
+        x = x + dense(attn, p["proj_kernel"], p["proj_bias"])
     return _ffn(p, x, cfg), kc, vc
 
 
@@ -328,8 +355,7 @@ def gpt2_verify_paged(params: Dict[str, Any], kc: jax.Array,
     J = block_tables.shape[-1]
     bs = kc.shape[4]
     pos = lengths[:, None] + jnp.arange(K, dtype=jnp.int32)[None]  # [S,K]
-    x = params["wte"].astype(cfg.dtype)[tokens] + \
-        params["wpe"].astype(cfg.dtype)[pos]
+    x = _embed(params, tokens, pos, cfg)
     bt_g = _group_shape(block_tables, G)             # [G, Sg, J]
     pos_g = _group_shape(pos, G)                     # [G, Sg, K]
     sel = pos_mask = None
@@ -348,7 +374,7 @@ def gpt2_verify_paged(params: Dict[str, Any], kc: jax.Array,
 
     x, (kc, vc) = lax.scan(body, x, (params["blocks"], kc, vc))
     x = layer_norm_fn(cfg)(x, params["ln_f_scale"], params["ln_f_bias"])
-    logits = (x @ params["wte"].astype(cfg.dtype).T).astype(jnp.float32)
+    logits = _unembed(params, x, cfg)
     return logits, kc, vc
 
 
@@ -391,8 +417,7 @@ def gpt2_prefill_chunk_paged(params: Dict[str, Any], kc: jax.Array,
     J = bt_rows.shape[-1]
     bs = kc.shape[4]
     pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]  # [G, C]
-    x = params["wte"].astype(cfg.dtype)[tokens] + \
-        params["wpe"].astype(cfg.dtype)[pos]         # [G, C, H]
+    x = _embed(params, tokens, pos, cfg)         # [G, C, H]
     bt_g = jnp.where(active[:, None, None] > 0, bt_rows[:, None],
                      kv_cache.DEAD_BLOCK)            # [G, 1, J]
     pos_g = pos[:, None, :]                          # [G, 1, C]
@@ -415,8 +440,7 @@ def gpt2_prefill_chunk_paged(params: Dict[str, Any], kc: jax.Array,
     oh = (lax.broadcasted_iota(jnp.int32, (G, C), 1) ==
           last_idx[:, None]).astype(x.dtype)
     h_last = jnp.einsum("gc,gch->gh", oh, x)
-    logits = (h_last @ params["wte"].astype(cfg.dtype).T
-              ).astype(jnp.float32)
+    logits = _unembed(params, h_last, cfg)
     return logits, kc, vc
 
 
@@ -441,39 +465,42 @@ def gpt2_prefill_full_paged(params: Dict[str, Any], kc: jax.Array,
     T = tokens.shape[0]
     G = bt_rows.shape[0]
     bs = kc.shape[4]
-    x = (params["wte"].astype(cfg.dtype)[tokens] +
-         params["wpe"].astype(cfg.dtype)[:T])[None]        # [1, T, H]
+    x = _embed(params, tokens, slice(T), cfg)[None]        # [1, T, H]
 
     def body(h, p):
-        q, k, v = _qkv(p, h, cfg)                  # [1, T, nH, D]
-        attn = attention_fn(q, k, v, mask=None, causal=True,
-                            deterministic=True)
-        attn = attn.reshape(h.shape).astype(h.dtype)
-        h = h + dense(attn, p["proj_kernel"], p["proj_bias"])
+        with jax.named_scope("attn"):
+            q, k, v = _qkv(p, h, cfg)              # [1, T, nH, D]
+            with jax.named_scope("attend"):
+                attn = attention_fn(q, k, v, mask=None, causal=True,
+                                    deterministic=True)
+            attn = attn.reshape(h.shape).astype(h.dtype)
+            h = h + dense(attn, p["proj_kernel"], p["proj_bias"])
         return _ffn(p, h, cfg), (k[0], v[0])       # ys: [T, nH, D]
 
     x, (ks, vs) = lax.scan(body, x, params["blocks"])
-    pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (G, T))
-    bt_per_row = jnp.broadcast_to(bt_rows[:, None, :],
-                                  (G, T, bt_rows.shape[-1]))
-    blk, off = kv_cache.positions_to_blocks(bt_per_row, pos, bs)
+    with jax.named_scope("kv_write"):
+        pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None],
+                               (G, T))
+        bt_per_row = jnp.broadcast_to(bt_rows[:, None, :],
+                                      (G, T, bt_rows.shape[-1]))
+        blk, off = kv_cache.positions_to_blocks(bt_per_row, pos, bs)
 
-    def splice(pool, rows):
-        return kv_cache.paged_write_rows(
-            pool, jnp.broadcast_to(rows[None], (G,) + rows.shape),
-            blk, off)
+        def splice(pool, rows):
+            return kv_cache.paged_write_rows(
+                pool, jnp.broadcast_to(rows[None], (G,) + rows.shape),
+                blk, off)
 
-    kc = jax.vmap(splice)(kc, ks)
-    vc = jax.vmap(splice)(vc, vs)
+        kc = jax.vmap(splice)(kc, ks)
+        vc = jax.vmap(splice)(vc, vs)
     x = layer_norm_fn(cfg)(x[0], params["ln_f_scale"],
                            params["ln_f_bias"])
     h_last = lax.dynamic_slice(x, (last_idx.astype(jnp.int32),
                                    jnp.int32(0)), (1, x.shape[1]))[0]
-    logits = (h_last @ params["wte"].astype(cfg.dtype).T
-              ).astype(jnp.float32)
+    logits = _unembed(params, h_last, cfg)
     return logits, kc, vc
 
 
+@jax.named_scope("sample")
 def spec_accept(logits: jax.Array, tokens: jax.Array, key: jax.Array,
                 temperature: jax.Array) -> jax.Array:
     """In-graph draft acceptance: the longest agreeing prefix rule.
@@ -500,6 +527,7 @@ def spec_accept(logits: jax.Array, tokens: jax.Array, key: jax.Array,
 # --------------------------------------------------------------------- #
 # Sampling (in-graph; PRNG threaded by the engine per iteration)
 # --------------------------------------------------------------------- #
+@jax.named_scope("sample")
 def sample_tokens(logits: jax.Array, key: jax.Array,
                   temperature: jax.Array) -> jax.Array:
     """Greedy (temperature == 0) or temperature sampling; logits

@@ -48,6 +48,7 @@ from .spec import NGramDrafter
 from .. import constants as C
 from ..models.gpt2 import GPT2Config
 from ..monitor import Telemetry
+from ..monitor.telemetry import ids_arg
 from ..monitor.memory import analytic_state_bytes
 from ..monitor.serving import ServingAggregator
 from ..monitor.serving_slo import ServingGoodputLedger, SLOTracker
@@ -383,6 +384,7 @@ class InferenceEngine:
     def _build_copy_block(self) -> Callable:
         """The device half of copy-on-write: duplicate one block's K/V
         rows (all layers) into a private block of the same group."""
+        @jax.named_scope("cow_copy")
         def copy_block(kc, vc, src_onehot, dst_onehot):
             return (kv_cache.paged_copy_block(kc, src_onehot, dst_onehot),
                     kv_cache.paged_copy_block(vc, src_onehot, dst_onehot))
@@ -546,7 +548,7 @@ class InferenceEngine:
     # ------------------------------------------------------------------ #
     def prefill(self, prompt: Sequence[int], slot: int,
                 temperature: float = 0.0, return_logits: bool = False,
-                max_new_tokens: Optional[int] = None
+                max_new_tokens: Optional[int] = None, rid: Any = None
                 ) -> Tuple[int, Optional[np.ndarray]]:
         """Prefill one prompt into ``slot`` and sample its first output
         token. Returns (token, final-position logits [V] when asked —
@@ -561,8 +563,17 @@ class InferenceEngine:
         write, and ``max_new_tokens`` (the scheduler passes the
         request's) books the worst-case HBM reservation so mid-flight
         appends can never strand the slot. Direct calls without it
-        reserve nothing and draw from the free pool lazily."""
+        reserve nothing and draw from the free pool lazily.
+
+        ``rid`` only labels the ``prefill`` host span (see
+        ``prefill_many``)."""
+        if self.paged and self.prefill_chunk > 0:
+            return self.prefill_many(
+                [(slot, prompt, int(max_new_tokens or 0))], temperature,
+                return_logits=return_logits,
+                rids=None if rid is None else [rid])[0]
         t0 = time.perf_counter()
+        tl = self.telemetry
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         plen = int(prompt.shape[0])
         if plen < 1:
@@ -573,66 +584,59 @@ class InferenceEngine:
                 f"{self.max_len}-token slot")
         kc, vc = self.cache["k"], self.cache["v"]
         temp = np.float32(temperature)
-        if not self.paged:
-            if self.prefill_chunk == 0:
-                padded = np.zeros(self.max_len, np.int32)
-                padded[:plen] = prompt
-                kc, vc, tok, logits = self._prefill_fn(
-                    self._params, kc, vc, padded, np.int32(slot),
-                    np.int32(0), np.int32(plen - 1), self._next_key(),
-                    temp)
-            else:
-                chunk = self.prefill_chunk
-                n_chunks = -(-plen // chunk)
-                padded = np.zeros(n_chunks * chunk, np.int32)
-                padded[:plen] = prompt
+        chunk = self.prefill_chunk or self.max_len
+        n_chunks = -(-plen // chunk) if self.prefill_chunk else 1
+        with tl.span("prefill", slots=1, prompt_tokens=plen,
+                     cached_tokens=0, chunks=n_chunks,
+                     rids=ids_arg(None if rid is None else [rid])):
+            padded = np.zeros(n_chunks * chunk, np.int32)
+            padded[:plen] = prompt
+            if not self.paged:
                 tok = logits = None
                 for ci in range(n_chunks):
                     start = ci * chunk
                     last = ci == n_chunks - 1
                     last_idx = (plen - 1 - start) if last else 0
+                    with tl.span("prefill_chunk", ci=ci, active_groups=1):
+                        kc, vc, tok, logits = self._prefill_fn(
+                            self._params, kc, vc,
+                            padded[start:start + chunk], np.int32(slot),
+                            np.int32(start), np.int32(last_idx),
+                            self._next_key(), temp)
+            else:
+                G = self.dp
+                J = self.cache_spec.max_blocks_per_slot
+                group = slot // self.cache_spec.slots_per_group
+                with tl.span("prefill_plan"):
+                    plan = self.allocator.admit_prompt(
+                        slot, group, prompt, int(max_new_tokens or 0),
+                        self.spec_k, share=False)
+                    row = np.full(J, kv_cache.DEAD_BLOCK, np.int32)
+                    row[:len(plan.table)] = plan.table
+                    self.block_tables[slot] = row
+                    bt_rows = np.full((G, J), kv_cache.DEAD_BLOCK, np.int32)
+                    bt_rows[group] = row
+                with tl.span("prefill_chunk", ci=0, active_groups=1):
                     kc, vc, tok, logits = self._prefill_fn(
-                        self._params, kc, vc, padded[start:start + chunk],
-                        np.int32(slot), np.int32(start),
-                        np.int32(last_idx), self._next_key(), temp)
-        elif self.prefill_chunk == 0:
-            G = self.dp
-            J = self.cache_spec.max_blocks_per_slot
-            group = slot // self.cache_spec.slots_per_group
-            plan = self.allocator.admit_prompt(
-                slot, group, prompt, int(max_new_tokens or 0),
-                self.spec_k, share=False)
-            row = np.full(J, kv_cache.DEAD_BLOCK, np.int32)
-            row[:len(plan.table)] = plan.table
-            self.block_tables[slot] = row
-            padded = np.zeros(self.max_len, np.int32)
-            padded[:plen] = prompt
-            bt_rows = np.full((G, J), kv_cache.DEAD_BLOCK, np.int32)
-            bt_rows[group] = row
-            kc, vc, tok, logits = self._prefill_fn(
-                self._params, kc, vc, padded, bt_rows,
-                np.int32(plen - 1), self._next_key(), temp)
-            if self.drafter is not None:
-                self.drafter.begin(slot, prompt)
-            self.serving.note_admit(plen, 0)
-        else:
+                        self._params, kc, vc, padded, bt_rows,
+                        np.int32(plen - 1), self._next_key(), temp)
+                if self.drafter is not None:
+                    self.drafter.begin(slot, prompt)
+                self.serving.note_admit(plen, 0)
             self.cache["k"], self.cache["v"] = kc, vc
-            tok, logits = self.prefill_many(
-                [(slot, prompt, int(max_new_tokens or 0))], temperature,
-                return_logits=return_logits)[0]
-            return tok, logits
-        self.cache["k"], self.cache["v"] = kc, vc
-        self.telemetry.raise_pending()
-        out_logits = np.asarray(jax.device_get(logits)) \
-            if return_logits else None
-        tok = int(jax.device_get(tok))
+            tl.raise_pending()
+            with tl.span("prefill_fetch"):
+                out_logits = np.asarray(jax.device_get(logits)) \
+                    if return_logits else None
+                tok = int(jax.device_get(tok))
         if self.serving.ledger is not None:
             self.serving.ledger.note("prefill", time.perf_counter() - t0)
         return tok, out_logits
 
     def prefill_many(self, admissions: Sequence[Tuple[int, Any, int]],
                      temperature: float = 0.0,
-                     return_logits: bool = False
+                     return_logits: bool = False,
+                     rids: Optional[Sequence[Any]] = None
                      ) -> "list[Tuple[int, Optional[np.ndarray]]]":
         """Batched admission: prefill up to ONE slot per dp group in a
         single pass of group-batched chunk programs.
@@ -645,16 +649,57 @@ class InferenceEngine:
         grows: G admissions cost one admission's wall. Copy-on-write
         forks across the batch merge into ONE block-copy call (distinct
         groups can't collide). Returns [(first token, logits|None)] in
-        admission order."""
+        admission order.
+
+        Host spans: ``prefill`` (args ``slots``, ``prompt_tokens``,
+        ``rids`` — the scheduler's request ids, so a request can be
+        followed through a trace — and, once planned, ``cached_tokens``,
+        ``chunks``) > ``prefill_plan`` (allocator admission + the
+        copy-on-write fork), one ``prefill_chunk`` (``ci``,
+        ``active_groups``) per chunk program dispatched, and
+        ``prefill_fetch`` (the first tokens' ``device_get``)."""
         if not (self.paged and self.prefill_chunk > 0):
             raise RuntimeError("prefill_many needs the paged cache and "
                                "chunked prefill")
         t_pf0 = time.perf_counter()
+        tl = self.telemetry
+        with tl.span("prefill", slots=len(admissions),
+                     prompt_tokens=sum(len(p) for _, p, _ in admissions),
+                     rids=ids_arg(rids)) as span:
+            with tl.span("prefill_plan"):
+                kc, vc, plans, tails = self._plan_prefill(admissions)
+            steps, held = self._run_prefill_chunks(kc, vc, plans, tails,
+                                                   np.float32(temperature))
+            tl.raise_pending()
+            out = []
+            with tl.span("prefill_fetch"):
+                for slot, group, plan, prompt, plen in plans:
+                    ci, g = held[slot]
+                    tok = int(jax.device_get(steps[ci][0][g]))
+                    logits = np.asarray(jax.device_get(steps[ci][1][g])) \
+                        if return_logits else None
+                    if self.drafter is not None:
+                        self.drafter.begin(slot, prompt)
+                    self.serving.note_admit(plen, plan.matched)
+                    out.append((tok, logits))
+            span.set_metadata(
+                cached_tokens=sum(int(p[2].matched) for p in plans),
+                chunks=len(steps))
+        if self.serving.ledger is not None:
+            self.serving.ledger.note("prefill",
+                                     time.perf_counter() - t_pf0)
+        return out
+
+    def _plan_prefill(self, admissions):
+        """prefill_many's ``prefill_plan``: admit every prompt through
+        the block allocator, run the merged copy-on-write fork, and lay
+        out each admission's unshared tail in chunks. Returns (kc, vc,
+        [(slot, group, plan, prompt, plen)], [(padded tail, chunks,
+        tail length)])."""
         G = self.dp
         J = self.cache_spec.max_blocks_per_slot
         Sg = self.cache_spec.slots_per_group
         chunk = self.prefill_chunk
-        temp = np.float32(temperature)
         kc, vc = self.cache["k"], self.cache["v"]
         plans = []
         seen_groups = set()
@@ -702,10 +747,19 @@ class InferenceEngine:
             self._last_admit[slot] = {
                 "cached_tokens": int(plan.matched), "chunks": n_chunks,
                 "cow_fork": plan.cow_src is not None}
-        max_chunks = max(n for _, n, _ in tails)
-        held = {}                       # slot -> (ci, group) of its last chunk
-        steps = []                      # per-ci (tok_g, logits_g) device arrays
-        for ci in range(max_chunks):
+        return kc, vc, plans, tails
+
+    def _run_prefill_chunks(self, kc, vc, plans, tails, temp):
+        """Dispatch one group-batched chunk program per chunk index (a
+        ``prefill_chunk`` span each) and store the cache. Returns
+        ([(tok_g, logits_g) device arrays per chunk index], {slot: (ci,
+        group) of its last chunk})."""
+        G = self.dp
+        J = self.cache_spec.max_blocks_per_slot
+        chunk = self.prefill_chunk
+        held = {}
+        steps = []
+        for ci in range(max(n for _, n, _ in tails)):
             toks = np.zeros((G, chunk), np.int32)
             bt_rows = np.full((G, J), kv_cache.DEAD_BLOCK, np.int32)
             starts = np.zeros(G, np.int32)
@@ -722,36 +776,26 @@ class InferenceEngine:
                 if ci == n_chunks - 1:
                     last_idxs[group] = tlen - 1 - ci * chunk
                     held[slot] = (ci, group)
-            kc, vc, tok_g, logits_g = self._prefill_fn(
-                self._params, kc, vc, toks, bt_rows, starts, last_idxs,
-                act, self._next_key(), temp)
+            with self.telemetry.span("prefill_chunk", ci=ci,
+                                     active_groups=int(act.sum())):
+                kc, vc, tok_g, logits_g = self._prefill_fn(
+                    self._params, kc, vc, toks, bt_rows, starts,
+                    last_idxs, act, self._next_key(), temp)
             steps.append((tok_g, logits_g))
         self.cache["k"], self.cache["v"] = kc, vc
-        self.telemetry.raise_pending()
-        out = []
-        for slot, group, plan, prompt, plen in plans:
-            ci, g = held[slot]
-            tok = int(jax.device_get(steps[ci][0][g]))
-            logits = np.asarray(jax.device_get(steps[ci][1][g])) \
-                if return_logits else None
-            if self.drafter is not None:
-                self.drafter.begin(slot, prompt)
-            self.serving.note_admit(plen, plan.matched)
-            out.append((tok, logits))
-        if self.serving.ledger is not None:
-            self.serving.ledger.note("prefill",
-                                     time.perf_counter() - t_pf0)
-        return out
+        return steps, held
 
-    def _cache_accounting(self) -> Tuple[int, int]:
-        """(cache bytes held, context tokens cached) this iteration —
-        the hbm_bytes_per_token sample. Slot-major reserves the full
-        cache whatever the contexts hold; paged holds only live
-        blocks."""
+    def _cache_accounting(self) -> Tuple[int, int, int]:
+        """(live blocks, cache bytes held, context tokens cached) this
+        iteration — the hbm_bytes_per_token sample, and the ``decode``
+        span's ``live_blocks`` / ``context_tokens``. Slot-major reserves
+        the full cache whatever the contexts hold (no blocks: 0); paged
+        holds only live blocks."""
         tokens = int(self.lengths[self.active].sum())
         if self.paged:
-            return self.allocator.bytes_in_use(), tokens
-        return self.cache_spec.nbytes(), tokens
+            live = self.allocator.blocks_in_use()
+            return live, live * self.cache_spec.block_nbytes(), tokens
+        return 0, self.cache_spec.nbytes(), tokens
 
     def _attend_work(self, k_rows: int) -> Tuple[int, int, int, int]:
         """Analytic attend work of the iteration just run, priced BOTH
@@ -789,47 +833,58 @@ class InferenceEngine:
         (and the [S, V] logits when asked — tests only; the extra fetch
         is not part of the serving loop)."""
         t0 = time.perf_counter()
-        self.telemetry.profiler_tick(self.iterations)
-        n_active = self.active_slots
-        if self.paged:
-            for s in np.flatnonzero(self.active):
-                self._ensure_blocks(int(s), int(self.lengths[s]))
-            bt = self.block_tables
-        else:
-            bt = np.int32(0)            # unused by the slot-major path
-        kc, vc, sampled, logits = self._decode_fn(
-            self._params, self.cache["k"], self.cache["v"],
-            self.last_tokens, self.lengths, bt, self._next_key(),
-            np.float32(temperature))
-        self.cache["k"], self.cache["v"] = kc, vc
-        self.telemetry.raise_pending()
-        # THE serving sync: the host needs the tokens (EOS detection +
-        # next step's inputs). One batched [S] fetch per iteration.
-        sampled = np.asarray(jax.device_get(sampled))
-        adv = self.active
-        self.lengths[adv] += 1
-        self.last_tokens[adv] = sampled[adv]
-        if self.drafter is not None:
-            for s in np.flatnonzero(adv):
-                self.drafter.observe(int(s), [int(sampled[s])])
-        wall = time.perf_counter() - t0
-        self.iterations += 1
-        cache_bytes, ctx_tokens = self._cache_accounting()
-        self.serving.note_iteration(n_active, wall,
-                                    cache_bytes=cache_bytes,
-                                    context_tokens=ctx_tokens)
-        if self.serving.ledger is not None:
-            self.serving.ledger.note("decode_useful", wall)
-        if self.paged and n_active:
-            self.serving.note_attend(*self._attend_work(1), n_active)
         tl = self.telemetry
-        if tl.enabled:
-            tl.record_step(self.iterations, {},
-                           wall_ms=wall * 1e3,
-                           active_slots=n_active,
-                           occupancy=round(n_active / self.max_slots, 4),
-                           tokens=n_active)
-            tl.maybe_drain(self.iterations, extra_fn=self._report_extra)
+        tl.profiler_tick(self.iterations)
+        n_active = self.active_slots
+        with tl.span("decode", iteration=self.iterations,
+                     active=n_active) as span:
+            with tl.span("decode_tables"):
+                if self.paged:
+                    for s in np.flatnonzero(self.active):
+                        self._ensure_blocks(int(s), int(self.lengths[s]))
+                    bt = self.block_tables
+                else:
+                    bt = np.int32(0)    # unused by the slot-major path
+            with tl.span("decode_dispatch"):
+                kc, vc, sampled, logits = self._decode_fn(
+                    self._params, self.cache["k"], self.cache["v"],
+                    self.last_tokens, self.lengths, bt, self._next_key(),
+                    np.float32(temperature))
+                self.cache["k"], self.cache["v"] = kc, vc
+                tl.raise_pending()
+            # THE serving sync: the host needs the tokens (EOS detection
+            # + next step's inputs). One batched [S] fetch per iteration.
+            with tl.span("decode_fetch"):
+                sampled = np.asarray(jax.device_get(sampled))
+            with tl.span("decode_advance"):
+                adv = self.active
+                self.lengths[adv] += 1
+                self.last_tokens[adv] = sampled[adv]
+                if self.drafter is not None:
+                    for s in np.flatnonzero(adv):
+                        self.drafter.observe(int(s), [int(sampled[s])])
+                wall = time.perf_counter() - t0
+                self.iterations += 1
+                live_blocks, cache_bytes, ctx_tokens = \
+                    self._cache_accounting()
+                self.serving.note_iteration(n_active, wall,
+                                            cache_bytes=cache_bytes,
+                                            context_tokens=ctx_tokens)
+                if self.serving.ledger is not None:
+                    self.serving.ledger.note("decode_useful", wall)
+                if self.paged and n_active:
+                    self.serving.note_attend(*self._attend_work(1),
+                                             n_active)
+                if tl.enabled:
+                    tl.record_step(
+                        self.iterations, {}, wall_ms=wall * 1e3,
+                        active_slots=n_active,
+                        occupancy=round(n_active / self.max_slots, 4),
+                        tokens=n_active)
+                    tl.maybe_drain(self.iterations,
+                                   extra_fn=self._report_extra)
+            span.set_metadata(live_blocks=live_blocks,
+                              context_tokens=ctx_tokens)
         out_logits = np.asarray(jax.device_get(logits)) \
             if return_logits else None
         return sampled, out_logits
@@ -856,72 +911,83 @@ class InferenceEngine:
                 "decode_once for temperature > 0 — the scheduler falls "
                 "back automatically")
         t0 = time.perf_counter()
-        self.telemetry.profiler_tick(self.iterations)
+        tl = self.telemetry
+        tl.profiler_tick(self.iterations)
         k = self.spec_k
         n_active = self.active_slots
-        toks = np.zeros((self.max_slots, k + 1), np.int32)
-        toks[:, 0] = self.last_tokens
-        live = np.flatnonzero(self.active)
-        for s in live:
-            s = int(s)
-            toks[s, 1:] = self.drafter.propose(s)
-            self._ensure_blocks(
-                s, min(int(self.lengths[s]) + k, self.max_len - 1))
-        kc, vc, out, logits = self._verify_fn(
-            self._params, self.cache["k"], self.cache["v"], toks,
-            self.lengths, self.block_tables, self._next_key(),
-            np.float32(temperature))
-        self.cache["k"], self.cache["v"] = kc, vc
-        self.telemetry.raise_pending()
-        out = np.asarray(jax.device_get(out))        # [S, k+2]
-        n_new = out[:, 0].copy()
-        emitted = out[:, 1:]
-        n_new[~self.active] = 0
-        accepted = 0
-        for s in live:
-            s = int(s)
-            n = max(0, min(int(n_new[s]),
-                           self.max_len - int(self.lengths[s])))
-            n_new[s] = n
-            if n == 0:
-                continue
-            self.lengths[s] += n
-            self.last_tokens[s] = int(emitted[s, n - 1])
-            self.drafter.observe(s, emitted[s, :n])
-            accepted += n - 1
-        emitted_total = int(n_new.sum())
-        self._spec_proposed += k * len(live)
-        self._spec_accepted += accepted
-        wall = time.perf_counter() - t0
-        self.iterations += 1
-        cache_bytes, ctx_tokens = self._cache_accounting()
-        self.serving.note_iteration(n_active, wall,
-                                    cache_bytes=cache_bytes,
-                                    context_tokens=ctx_tokens,
-                                    emitted_tokens=emitted_total)
-        if self.serving.ledger is not None:
-            # Split the verify wall by row share: of the (k+1) verify
-            # rows per live slot, the emitted tokens (accepted drafts +
-            # the correction/bonus) are useful work; the rejected drafts
-            # are wall the draft caused and the target threw away.
-            rows = (k + 1) * len(live)
-            wasted = wall * (k * len(live) - accepted) / rows \
-                if rows else 0.0
-            self.serving.ledger.note("spec_wasted", wasted)
-            self.serving.ledger.note("decode_useful", wall - wasted)
-        if n_active and emitted_total:
-            self.serving.note_attend(*self._attend_work(k + 1),
-                                     emitted_total)
-        self.serving.note_spec(k * len(live), accepted)
-        tl = self.telemetry
-        if tl.enabled:
-            tl.record_step(self.iterations, {},
-                           wall_ms=wall * 1e3,
-                           active_slots=n_active,
-                           occupancy=round(n_active / self.max_slots, 4),
-                           tokens=emitted_total,
-                           spec_accepted=accepted)
-            tl.maybe_drain(self.iterations, extra_fn=self._report_extra)
+        with tl.span("decode", iteration=self.iterations,
+                     active=n_active) as span:
+            with tl.span("decode_tables"):
+                toks = np.zeros((self.max_slots, k + 1), np.int32)
+                toks[:, 0] = self.last_tokens
+                live = np.flatnonzero(self.active)
+                for s in live:
+                    s = int(s)
+                    toks[s, 1:] = self.drafter.propose(s)
+                    self._ensure_blocks(
+                        s, min(int(self.lengths[s]) + k, self.max_len - 1))
+            with tl.span("decode_dispatch"):
+                kc, vc, out, logits = self._verify_fn(
+                    self._params, self.cache["k"], self.cache["v"], toks,
+                    self.lengths, self.block_tables, self._next_key(),
+                    np.float32(temperature))
+                self.cache["k"], self.cache["v"] = kc, vc
+                tl.raise_pending()
+            with tl.span("decode_fetch"):
+                out = np.asarray(jax.device_get(out))    # [S, k+2]
+            with tl.span("decode_advance"):
+                n_new = out[:, 0].copy()
+                emitted = out[:, 1:]
+                n_new[~self.active] = 0
+                accepted = 0
+                for s in live:
+                    s = int(s)
+                    n = max(0, min(int(n_new[s]),
+                                   self.max_len - int(self.lengths[s])))
+                    n_new[s] = n
+                    if n == 0:
+                        continue
+                    self.lengths[s] += n
+                    self.last_tokens[s] = int(emitted[s, n - 1])
+                    self.drafter.observe(s, emitted[s, :n])
+                    accepted += n - 1
+                emitted_total = int(n_new.sum())
+                self._spec_proposed += k * len(live)
+                self._spec_accepted += accepted
+                wall = time.perf_counter() - t0
+                self.iterations += 1
+                live_blocks, cache_bytes, ctx_tokens = \
+                    self._cache_accounting()
+                self.serving.note_iteration(n_active, wall,
+                                            cache_bytes=cache_bytes,
+                                            context_tokens=ctx_tokens,
+                                            emitted_tokens=emitted_total)
+                if self.serving.ledger is not None:
+                    # Split the verify wall by row share: of the (k+1)
+                    # verify rows per live slot, the emitted tokens
+                    # (accepted drafts + the correction/bonus) are useful
+                    # work; the rejected drafts are wall the draft caused
+                    # and the target threw away.
+                    rows = (k + 1) * len(live)
+                    wasted = wall * (k * len(live) - accepted) / rows \
+                        if rows else 0.0
+                    self.serving.ledger.note("spec_wasted", wasted)
+                    self.serving.ledger.note("decode_useful",
+                                             wall - wasted)
+                if n_active and emitted_total:
+                    self.serving.note_attend(*self._attend_work(k + 1),
+                                             emitted_total)
+                self.serving.note_spec(k * len(live), accepted)
+                if tl.enabled:
+                    tl.record_step(
+                        self.iterations, {}, wall_ms=wall * 1e3,
+                        active_slots=n_active,
+                        occupancy=round(n_active / self.max_slots, 4),
+                        tokens=emitted_total, spec_accepted=accepted)
+                    tl.maybe_drain(self.iterations,
+                                   extra_fn=self._report_extra)
+            span.set_metadata(live_blocks=live_blocks,
+                              context_tokens=ctx_tokens)
         return emitted, n_new
 
     def _attach_slo_overlays(self) -> None:

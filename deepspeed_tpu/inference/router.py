@@ -230,27 +230,19 @@ class ReplicaRouter:
                             break
                     if not batch:
                         break
-                    t_now = time.perf_counter()
+                    # The engine opens the ``prefill`` host span itself.
                     if batched:
-                        with eng.telemetry.span(
-                                "prefill", slots=len(batch),
-                                tokens=sum(len(r.prompt)
-                                           for r, _ in batch)):
-                            results = eng.prefill_many(
-                                [(slot, req.prompt, req.max_new_tokens)
-                                 for req, slot in batch],
-                                self.temperature)
-                        t_now = time.perf_counter()
+                        results = eng.prefill_many(
+                            [(slot, req.prompt, req.max_new_tokens)
+                             for req, slot in batch],
+                            self.temperature,
+                            rids=[req.rid for req, _ in batch])
                     else:
-                        results = []
-                        for req, slot in batch:
-                            with eng.telemetry.span(
-                                    "prefill", slot=slot,
-                                    tokens=len(req.prompt)):
-                                results.append(eng.prefill(
-                                    req.prompt, slot, self.temperature,
-                                    max_new_tokens=req.max_new_tokens))
-                        t_now = time.perf_counter()
+                        results = [eng.prefill(
+                            req.prompt, slot, self.temperature,
+                            max_new_tokens=req.max_new_tokens,
+                            rid=req.rid) for req, slot in batch]
+                    t_now = time.perf_counter()
                     for (req, slot), (tok, _) in zip(batch, results):
                         req.slot = slot
                         req.t_first = req.t_last = t_now

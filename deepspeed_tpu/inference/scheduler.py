@@ -24,6 +24,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..monitor.telemetry import ids_arg
+
 
 @dataclasses.dataclass
 class Request:
@@ -167,9 +169,10 @@ class ContinuousBatchingScheduler:
             self.trace.complete(req.rid, t=req.t_last,
                                 telemetry=self.engine.telemetry)
 
-    def _reject(self, req: Request, queue_len: int) -> None:
+    def _reject(self, req: Request, queue_len: int) -> str:
         """Head-of-queue admission rejection: per-request attempt count,
-        aggregator total, first-rejection event, trace mark."""
+        aggregator total, first-rejection event, trace mark. Returns the
+        reason (the ``admit`` span's ``rejected``)."""
         eng = self.engine
         req.admission_attempts += 1
         reason = getattr(eng, "last_admit_block", None) or "no_slot"
@@ -178,6 +181,7 @@ class ContinuousBatchingScheduler:
         note = getattr(eng, "note_admission_reject", None)
         if note is not None:
             note(req.rid, reason, req.admission_attempts, queue_len)
+        return reason
 
     def _admit_trace(self, req: Request, slot: int) -> None:
         if self.trace is None:
@@ -199,6 +203,7 @@ class ContinuousBatchingScheduler:
         """Run the stream to completion; returns the serving report
         (the aggregator snapshot + per-request records)."""
         eng = self.engine
+        tel = eng.telemetry
         t0 = time.perf_counter()
         trace = self.trace
         ledger = getattr(eng.serving, "ledger", None)
@@ -251,7 +256,11 @@ class ContinuousBatchingScheduler:
                     if abort is not None:
                         abort(req.rid, "starved")
                 break
-            # 1. open-loop arrivals join the queue on schedule.
+            # 1. open-loop arrivals join the queue on schedule. How late
+            # this pass polled for the oldest of them rides on its
+            # ``admit`` spans (``late_ms``: the generator's lateness).
+            late_ms = max(0.0, now - pending[0].arrival_s) * 1e3 \
+                if pending else 0.0
             while pending and pending[0].arrival_s <= now:
                 req = pending.popleft()
                 req.t_arrival = t0 + req.arrival_s
@@ -269,33 +278,38 @@ class ContinuousBatchingScheduler:
                 getattr(eng, "paged", False) and eng.prefill_chunk > 0
             while queue:
                 if batched:
-                    batch = []
-                    used: set = set()
-                    while queue:
-                        req = queue[0]
-                        slot = select(req.prompt, req.max_new_tokens,
-                                      exclude_groups=used)
-                        if slot is None:
-                            # Only a rejection with NO exclusions is the
-                            # gate refusing the head (with exclusions it
-                            # may just be this batch's one-slot-per-group
-                            # shape).
-                            if not used:
-                                self._reject(req, len(queue))
-                            break
-                        queue.popleft()
-                        req.t_admit = time.perf_counter()
-                        used.add(eng.group_of(slot))
-                        batch.append((req, slot))
+                    with tel.span("admit", queued=len(queue),
+                                  late_ms=late_ms) as span:
+                        batch = []
+                        used: set = set()
+                        rejected = ""
+                        while queue:
+                            req = queue[0]
+                            slot = select(req.prompt, req.max_new_tokens,
+                                          exclude_groups=used)
+                            if slot is None:
+                                # Only a rejection with NO exclusions is
+                                # the gate refusing the head (with
+                                # exclusions it may just be this batch's
+                                # one-slot-per-group shape).
+                                if not used:
+                                    rejected = self._reject(req,
+                                                            len(queue))
+                                break
+                            queue.popleft()
+                            req.t_admit = time.perf_counter()
+                            used.add(eng.group_of(slot))
+                            batch.append((req, slot))
+                        rids = [req.rid for req, _ in batch]
+                        span.set_metadata(admitted=len(batch),
+                                          rejected=rejected,
+                                          rids=ids_arg(rids))
                     if not batch:
                         break
-                    with eng.telemetry.span(
-                            "prefill", slots=len(batch),
-                            tokens=sum(len(r.prompt)
-                                       for r, _ in batch)):
-                        results = eng.prefill_many(
-                            [(slot, req.prompt, req.max_new_tokens)
-                             for req, slot in batch], self.temperature)
+                    results = eng.prefill_many(
+                        [(slot, req.prompt, req.max_new_tokens)
+                         for req, slot in batch], self.temperature,
+                        rids=rids)
                     t_now = time.perf_counter()
                     for (req, slot), (tok, _) in zip(batch, results):
                         req.slot = slot
@@ -311,22 +325,26 @@ class ContinuousBatchingScheduler:
                             active[slot] = req
                     continue
                 req = queue[0]
-                if select is not None:
-                    slot = select(req.prompt, req.max_new_tokens)
-                    if slot is None:
-                        self._reject(req, len(queue))
-                        break
-                elif free:
-                    slot = free.popleft()
-                else:
+                with tel.span("admit", queued=len(queue),
+                              late_ms=late_ms) as span:
+                    rejected = ""
+                    if select is not None:
+                        slot = select(req.prompt, req.max_new_tokens)
+                        if slot is None:
+                            rejected = self._reject(req, len(queue))
+                    else:
+                        slot = free.popleft() if free else None
+                        if slot is None:
+                            rejected = "no_slot"
+                    span.set_metadata(admitted=int(slot is not None),
+                                      rejected=rejected, rids=str(req.rid))
+                if slot is None:
                     break
                 queue.popleft()
                 req.t_admit = time.perf_counter()
-                with eng.telemetry.span("prefill", slot=slot,
-                                        tokens=len(req.prompt)):
-                    tok, _ = eng.prefill(
-                        req.prompt, slot, self.temperature,
-                        max_new_tokens=req.max_new_tokens)
+                tok, _ = eng.prefill(
+                    req.prompt, slot, self.temperature,
+                    max_new_tokens=req.max_new_tokens, rid=req.rid)
                 req.slot = slot
                 req.t_first = req.t_last = time.perf_counter()
                 req.out_tokens = [tok]
@@ -342,51 +360,61 @@ class ContinuousBatchingScheduler:
             # live slot.
             if active and spec:
                 emitted, n_new = eng.spec_decode_once(self.temperature)
-                t_now = time.perf_counter()
-                occ = len(active)
-                for slot in list(active):
-                    req = active[slot]
-                    budget = req.max_new_tokens - len(req.out_tokens)
-                    n = int(n_new[slot])
-                    toks = [int(t) for t in emitted[slot, :n]]
-                    if self.eos_token is not None and \
-                            self.eos_token in toks:
-                        toks = toks[:toks.index(self.eos_token) + 1]
-                    req.out_tokens.extend(toks[:max(budget, 0)])
-                    req.t_last = t_now
-                    if trace is not None:
-                        trace.tick(req.rid, occ, n, t=t_now,
-                                   proposed=eng.spec_k,
-                                   accepted=max(n - 1, 0))
-                    if self._finished(req, eng.context_len(slot)):
-                        self._complete(req)
-                        _release(slot)
-                        del active[slot]
+                with tel.span("emit") as span:
+                    t_now = time.perf_counter()
+                    occ = len(active)
+                    finished = []
+                    for slot in list(active):
+                        req = active[slot]
+                        budget = req.max_new_tokens - len(req.out_tokens)
+                        n = int(n_new[slot])
+                        toks = [int(t) for t in emitted[slot, :n]]
+                        if self.eos_token is not None and \
+                                self.eos_token in toks:
+                            toks = toks[:toks.index(self.eos_token) + 1]
+                        req.out_tokens.extend(toks[:max(budget, 0)])
+                        req.t_last = t_now
+                        if trace is not None:
+                            trace.tick(req.rid, occ, n, t=t_now,
+                                       proposed=eng.spec_k,
+                                       accepted=max(n - 1, 0))
+                        if self._finished(req, eng.context_len(slot)):
+                            self._complete(req)
+                            _release(slot)
+                            del active[slot]
+                            finished.append(req.rid)
+                    span.set_metadata(finished=ids_arg(finished))
             elif active:
                 sampled, _ = eng.decode_once(self.temperature)
-                t_now = time.perf_counter()
-                occ = len(active)
-                for slot in list(active):
-                    req = active[slot]
-                    req.out_tokens.append(int(sampled[slot]))
-                    req.t_last = t_now
-                    if trace is not None:
-                        trace.tick(req.rid, occ, 1, t=t_now)
-                    if self._finished(req, eng.context_len(slot)):
-                        self._complete(req)
-                        _release(slot)
-                        del active[slot]
+                with tel.span("emit") as span:
+                    t_now = time.perf_counter()
+                    occ = len(active)
+                    finished = []
+                    for slot in list(active):
+                        req = active[slot]
+                        req.out_tokens.append(int(sampled[slot]))
+                        req.t_last = t_now
+                        if trace is not None:
+                            trace.tick(req.rid, occ, 1, t=t_now)
+                        if self._finished(req, eng.context_len(slot)):
+                            self._complete(req)
+                            _release(slot)
+                            del active[slot]
+                            finished.append(req.rid)
+                    span.set_metadata(finished=ids_arg(finished))
             elif pending and not queue:
                 # Idle ahead of the next arrival — open-loop wait. The
                 # watchdog heartbeat says "idle, not hung": a sparse
                 # arrival stream must not read as a decode-loop stall.
-                eng.telemetry.heartbeat()
+                tel.heartbeat()
                 gap = pending[0].arrival_s - (time.perf_counter() - t0)
                 if gap > 0:
-                    t_sl = time.perf_counter()
-                    time.sleep(min(gap, self.idle_sleep_s))
-                    if ledger is not None:
-                        ledger.note("idle", time.perf_counter() - t_sl)
+                    with tel.span("serve_idle", why="no_arrival"):
+                        t_sl = time.perf_counter()
+                        time.sleep(min(gap, self.idle_sleep_s))
+                        if ledger is not None:
+                            ledger.note("idle",
+                                        time.perf_counter() - t_sl)
             elif queue:
                 # Queued work but no free slot and nothing decoding:
                 # capacity is held outside this serve (caller-activated
@@ -402,12 +430,13 @@ class ContinuousBatchingScheduler:
                         f"{len(req.prompt)} prompt + "
                         f"{req.max_new_tokens} new tokens exceeds the "
                         "block pool's per-group capacity")
-                eng.telemetry.heartbeat()
-                t_sl = time.perf_counter()
-                time.sleep(self.idle_sleep_s)
-                if ledger is not None:
-                    ledger.note("admission_blocked",
-                                time.perf_counter() - t_sl)
+                tel.heartbeat()
+                with tel.span("serve_idle", why="admission_blocked"):
+                    t_sl = time.perf_counter()
+                    time.sleep(self.idle_sleep_s)
+                    if ledger is not None:
+                        ledger.note("admission_blocked",
+                                    time.perf_counter() - t_sl)
 
         wall = time.perf_counter() - t0
         # Final drain with a SERVE-WALL-anchored snapshot: a run shorter
@@ -417,8 +446,8 @@ class ContinuousBatchingScheduler:
         # would carry nulls; the last report record wins there, so this
         # also pins the figure benches compare to the same wall
         # SERVE_BENCH.json uses.
-        if eng.telemetry.enabled:
-            eng.telemetry.drain({"serving": eng.serving.snapshot(
+        if tel.enabled:
+            tel.drain({"serving": eng.serving.snapshot(
                 wall_s=wall)})
         report = dict(eng.serving.snapshot(wall_s=wall))
         report["recompiles"] = eng.telemetry.recompile_count

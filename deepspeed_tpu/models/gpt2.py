@@ -90,8 +90,9 @@ def gpt2_hidden(params: Dict[str, Any], tokens: jnp.ndarray, cfg: GPT2Config,
     keep the plain return (the stats are dropped, the routed compute is
     identical). ``mesh`` feeds the MoE ep > 1 shard_map."""
     B, S = tokens.shape
-    x = params["wte"].astype(cfg.dtype)[tokens] + \
-        params["wpe"].astype(cfg.dtype)[None, :S]
+    with jax.named_scope("embed"):
+        x = params["wte"].astype(cfg.dtype)[tokens] + \
+            params["wpe"].astype(cfg.dtype)[None, :S]
     out = apply_blocks(params["blocks"], x, cfg, mask=None, rng=rng,
                        deterministic=deterministic, attention_fn=attention_fn,
                        pld_theta=pld_theta, zero3=zero3, mesh=mesh)
@@ -110,7 +111,8 @@ def gpt2_apply(params: Dict[str, Any], tokens: jnp.ndarray, cfg: GPT2Config,
                     attention_fn=attention_fn)
     # Tied unembedding (the reference ties via TiedLayerSpec in pipeline
     # models; here it is structural).
-    logits = x @ params["wte"].astype(cfg.dtype).T
+    with jax.named_scope("lm_head"):
+        logits = x @ params["wte"].astype(cfg.dtype).T
     return logits
 
 
@@ -138,8 +140,10 @@ def gpt2_logits_at(params: Dict[str, Any], tokens: jnp.ndarray,
         # Traced scalar: dynamic_index_in_dim would CLAMP a negative
         # index to 0 (silent wrong position) — normalize in-graph.
         index = jnp.where(index < 0, index + tokens.shape[1], index)
-    h = lax.dynamic_index_in_dim(x, index, axis=1, keepdims=False)  # [B, H]
-    return h @ params["wte"].astype(h.dtype).T
+    with jax.named_scope("lm_head"):
+        h = lax.dynamic_index_in_dim(x, index, axis=1,
+                                     keepdims=False)         # [B, H]
+        return h @ params["wte"].astype(h.dtype).T
 
 
 def gpt2_loss_fn(cfg: GPT2Config, attention_fn=None, zero3=None, mesh=None):
@@ -188,9 +192,10 @@ def gpt2_loss_fn(cfg: GPT2Config, attention_fn=None, zero3=None, mesh=None):
                                    pld_theta=pld_theta, zero3=zero3,
                                    mesh=mesh, with_moe_stats=True)
         B, S = tokens.shape
-        loss = chunked_softmax_xent(x.reshape(B * S, -1),
-                                    params["wte"].astype(cfg.dtype),
-                                    targets.reshape(-1))
+        with jax.named_scope("lm_head"):
+            loss = chunked_softmax_xent(x.reshape(B * S, -1),
+                                        params["wte"].astype(cfg.dtype),
+                                        targets.reshape(-1))
         if moe_stats is None:
             return loss
         moe = cfg.moe

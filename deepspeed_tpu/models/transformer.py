@@ -326,41 +326,44 @@ def transformer_block(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
     gelu_up = gelu_dense_fn(cfg)
 
     # --- attention sublayer ---
-    h = ln(x, params["ln1_scale"], params["ln1_bias"]) \
-        if cfg.pre_layer_norm else x
-    qkv = dense(h, params["qkv_kernel"], params["qkv_bias"])
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(B, S, nH, dH)
-    k = k.reshape(B, S, nH, dH)
-    v = v.reshape(B, S, nH, dH)
-    attn = attention_fn(q, k, v, mask=mask, causal=cfg.causal,
-                        attn_dropout=cfg.attn_dropout, rng=r1,
-                        deterministic=deterministic)
-    attn = attn.reshape(B, S, H)
-    attn = dense(attn, params["proj_kernel"], params["proj_bias"])
-    attn = dropout(attn, cfg.hidden_dropout, r2, deterministic)
-    if cfg.pre_layer_norm:
-        # Fused residual-add + next sublayer's LN: x continues the
-        # residual stream from s, h feeds the FFN.
-        x, h = res_ln(x, attn, params["ln2_scale"], params["ln2_bias"])
-    else:
-        # Post-LN: the normalized value IS the residual stream.
-        _, x = res_ln(x, attn, params["ln1_scale"], params["ln1_bias"])
-        h = x
+    with jax.named_scope("attn"):
+        h = ln(x, params["ln1_scale"], params["ln1_bias"]) \
+            if cfg.pre_layer_norm else x
+        qkv = dense(h, params["qkv_kernel"], params["qkv_bias"])
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q = q.reshape(B, S, nH, dH)
+        k = k.reshape(B, S, nH, dH)
+        v = v.reshape(B, S, nH, dH)
+        attn = attention_fn(q, k, v, mask=mask, causal=cfg.causal,
+                            attn_dropout=cfg.attn_dropout, rng=r1,
+                            deterministic=deterministic)
+        attn = attn.reshape(B, S, H)
+        attn = dense(attn, params["proj_kernel"], params["proj_bias"])
+        attn = dropout(attn, cfg.hidden_dropout, r2, deterministic)
+    with jax.named_scope("mlp"):
+        if cfg.pre_layer_norm:
+            # Fused residual-add + next sublayer's LN: x continues the
+            # residual stream from s, h feeds the FFN.
+            x, h = res_ln(x, attn, params["ln2_scale"], params["ln2_bias"])
+        else:
+            # Post-LN: the normalized value IS the residual stream.
+            _, x = res_ln(x, attn, params["ln1_scale"], params["ln1_bias"])
+            h = x
 
     # --- FFN sublayer (dense, or the expert-parallel MoE FFN) ---
     moe_stats = None
-    if "moe_fc_kernel" in params:
-        from ..moe.layer import moe_ffn
-        h, moe_stats = moe_ffn(params, h, cfg, mesh=mesh)
-    else:
-        h = gelu_up(h, params["fc_kernel"], params["fc_bias"])
-        h = dense(h, params["fc_out_kernel"], params["fc_out_bias"])
-    h = dropout(h, cfg.hidden_dropout, r3, deterministic)
-    if cfg.pre_layer_norm:
-        x = x + h
-    else:
-        _, x = res_ln(x, h, params["ln2_scale"], params["ln2_bias"])
+    with jax.named_scope("mlp"):
+        if "moe_fc_kernel" in params:
+            from ..moe.layer import moe_ffn
+            h, moe_stats = moe_ffn(params, h, cfg, mesh=mesh)
+        else:
+            h = gelu_up(h, params["fc_kernel"], params["fc_bias"])
+            h = dense(h, params["fc_out_kernel"], params["fc_out_bias"])
+        h = dropout(h, cfg.hidden_dropout, r3, deterministic)
+        if cfg.pre_layer_norm:
+            x = x + h
+        else:
+            _, x = res_ln(x, h, params["ln2_scale"], params["ln2_bias"])
     if cfg.moe is not None:
         return x, moe_stats
     return x

@@ -1,8 +1,11 @@
 """XLA profile-trace ingestion: per-op records and step wall decomposition.
 
-Parses a captured ``jax.profiler`` trace directory (the gzipped
+Parses a captured ``jax.profiler`` trace directory — the session's
+``.xplane.pb`` when it holds a TPU plane (``monitor/xplane_reader.py``:
+instruction names, and the ``jax.named_scope`` path of every device
+operation, so the window also reports time BY SCOPE), else the gzipped
 Chrome-trace JSON that ``jax.profiler.start_trace``/``stop_trace`` write
-under ``<dir>/plugins/profile/<timestamp>/<host>.trace.json.gz``) — or any
+under ``<dir>/plugins/profile/<timestamp>/<host>.trace.json.gz`` — or any
 trace-event JSON, including ``monitor/trace.py::TraceWriter``'s
 incremental array form — into structured :class:`OpRecord` rows, then
 classifies every device op into one of the measurement buckets:
@@ -46,6 +49,7 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 from ..parallel.axis_algebra import DCN_AXES
+from .xplane_reader import device_op_events
 
 __all__ = [
     "OpRecord", "BUCKETS", "BUCKET_PRIORITY", "PALLAS_KERNEL_PATTERNS",
@@ -116,6 +120,7 @@ class OpRecord:
     dur_us: float
     kernel_family: Optional[str] = None  # set for bucket == "pallas"
     args: Dict[str, Any] = field(default_factory=dict)
+    scope: str = ""     # named-scope path, "/"-joined (xplane captures)
 
     @property
     def end_us(self) -> float:
@@ -126,14 +131,16 @@ class OpRecord:
 # Trace discovery + parsing
 # --------------------------------------------------------------------- #
 def find_trace_files(trace_dir: str) -> List[str]:
-    """All trace-event JSON files under ``trace_dir``, newest profile
-    session first. Understands the ``jax.profiler`` layout
-    (``plugins/profile/<ts>/*.trace.json.gz``) and bare ``*.json`` /
-    ``*.json.gz`` drops (e.g. a TraceWriter host trace)."""
+    """All trace files under ``trace_dir``, newest profile session
+    first. Understands the ``jax.profiler`` layout
+    (``plugins/profile/<ts>/*.xplane.pb`` and ``*.trace.json.gz``) and
+    bare ``*.json`` / ``*.json.gz`` drops (e.g. a TraceWriter host
+    trace)."""
     if not trace_dir or not os.path.isdir(trace_dir):
         return []
     hits: List[str] = []
-    for pat in ("plugins/profile/*/*.trace.json.gz",
+    for pat in ("plugins/profile/*/*.xplane.pb",
+                "plugins/profile/*/*.trace.json.gz",
                 "plugins/profile/*/*.trace.json",
                 "*.trace.json.gz", "*.trace.json", "*.json.gz", "*.json"):
         hits.extend(glob.glob(os.path.join(trace_dir, pat)))
@@ -210,8 +217,12 @@ def classify_op(name: str, args: Optional[Dict[str, Any]] = None
     if _COLLECTIVE_RE.search(low):
         tier = "dcn" if _DCN_MARKER_RE.search(probe) else "ici"
         return f"collective_{tier}", None
-    if _HOST_RE.search(probe):
+    # On a device plane's op line a ``copy`` is device data movement,
+    # not a host transfer.
+    if not args.get("device_plane") and _HOST_RE.search(probe):
         return "host", None
+    if "convolution" in str(args.get("hlo_category", "")):
+        return "gemm", None     # the TPU compiler's category for dots
     target = hlo_op or name
     if _GEMM_RE.search(target) or _GEMM_RE.search(
             target.split("(")[0].strip()):
@@ -314,7 +325,8 @@ def ingest_events(events: List[Dict[str, Any]], n_steps: int = 1,
         records.append(OpRecord(
             name=name, bucket=bucket, pid=key[0], tid=key[1],
             ts_us=float(e.get("ts", 0.0)), dur_us=float(e.get("dur", 0.0)),
-            kernel_family=fam, args=args))
+            kernel_family=fam, args=args,
+            scope=str(args.get("scope", "") or "")))
     if records:
         t0 = min(r.ts_us for r in records)
         t1 = max(r.end_us for r in records)
@@ -352,7 +364,7 @@ def ingest_events(events: List[Dict[str, Any]], n_steps: int = 1,
         {"bucket": b, "op": op, "total_ms": round(us / 1e3, 6)}
         for (b, op), us in sorted(op_dur.items(), key=lambda kv: -kv[1])
     ][:top_k]
-    return {
+    out = {
         "n_events": n_span_events,
         "n_device_ops": len(records),
         "n_device_lanes": len(lanes),
@@ -372,6 +384,44 @@ def ingest_events(events: List[Dict[str, Any]], n_steps: int = 1,
             "unattributed_ms": buckets_ms["unattributed"],
         },
     }
+    scopes_ms = _scope_self_ms(records)
+    if scopes_ms:
+        # Device SELF time per profiled step by named-scope path ("" =
+        # no scope at all); backward and recomputed operations apart.
+        out["scopes_ms"] = {k: round(v / n, 6)
+                            for k, v in scopes_ms.items()}
+    return out
+
+
+def _scope_self_ms(records: List[OpRecord]) -> Dict[str, float]:
+    """{scope path (+ ":bwd" for transposed or recomputed operations):
+    self ms}, most time first. An op nested in another on its lane (a
+    loop body's ops inside their ``while``) is taken out of the outer
+    one's time, so the sum is the lanes' busy time. Empty when no record
+    carries a scope (a Chrome-JSON capture)."""
+    if not any(r.scope for r in records):
+        return {}
+    out: Dict[str, float] = {}
+    by_lane: Dict[Tuple[int, int], List[OpRecord]] = {}
+    for r in records:
+        by_lane.setdefault((r.pid, r.tid), []).append(r)
+    for lane in by_lane.values():
+        stack: List[List[Any]] = []          # [key, end_us, self_us]
+
+        def close(upto: float) -> None:
+            while stack and stack[-1][1] <= upto:
+                key, _, self_us = stack.pop()
+                out[key] = out.get(key, 0.0) + max(self_us, 0.0) / 1e3
+
+        for r in sorted(lane, key=lambda r: (r.ts_us, -r.dur_us)):
+            close(r.ts_us)
+            if stack:
+                stack[-1][2] -= min(r.dur_us, stack[-1][1] - r.ts_us)
+            bwd = r.args.get("backward") or r.args.get("recomputed")
+            stack.append([r.scope + (":bwd" if bwd else ""), r.end_us,
+                          r.dur_us])
+        close(float("inf"))
+    return dict(sorted(out.items(), key=lambda kv: -kv[1]))
 
 
 def ingest(trace_dir: str, n_steps: int = 1, top_k: int = 12
@@ -395,9 +445,18 @@ def ingest(trace_dir: str, n_steps: int = 1, top_k: int = 12
         chosen = [f for f in sessions if os.path.dirname(f) == newest_dir]
     else:
         chosen = [files[0]]
+    # The session's .xplane.pb wins where it holds a TPU plane: only it
+    # carries instruction names and scope paths. A capture without one
+    # (the CPU backend) is read from its Chrome-trace JSON as before.
     events: List[Dict[str, Any]] = []
-    for f in chosen:
-        events.extend(load_trace_events(f))
+    for f in [f for f in chosen if f.endswith(".xplane.pb")]:
+        events.extend(device_op_events(f))
+    if events:
+        chosen = [f for f in chosen if f.endswith(".xplane.pb")]
+    else:
+        chosen = [f for f in chosen if not f.endswith(".xplane.pb")]
+        for f in chosen:
+            events.extend(load_trace_events(f))
     out = ingest_events(events, n_steps=n_steps, top_k=top_k)
     out["trace_files"] = [os.path.relpath(f, trace_dir) for f in chosen]
     out["trace_dir"] = trace_dir

@@ -298,14 +298,24 @@ class RequestTrace:
                                      for tl in self.completed)}
 
 
+# One tick of the host clock (``perf_counter``: 1 ns), in ms. Span
+# boundaries are shared instants, but offsets and durations are float
+# differences of them, so ``t + dur`` may miss the next ``t`` by a few
+# ulp (1e-10 ms at a minute's offsets): under load, when waits grow to
+# seconds, exact equality flagged a quarter of all correct timelines.
+# Anything a clock can tell apart is still a gap or an overlap.
+CLOCK_TICK_MS = 1e-6
+
+
 def validate_timeline(tl: dict) -> List[str]:
     """Check one drained timeline for structural defects.
 
     Returns a list of problems (empty = valid): spans must be present,
-    start at offset 0, be contiguous (each span ends exactly where the
-    next begins — shared instants, so equality is exact), and a
-    completed request must carry the enqueue→admit→first-token→complete
-    chain (queued/prefill/decode with ttft and queue_wait split).
+    start at offset 0, be contiguous (each span ends where the next
+    begins — shared instants, so they agree to under one clock tick),
+    and a completed request must carry the
+    enqueue→admit→first-token→complete chain (queued/prefill/decode
+    with ttft and queue_wait split).
     """
     problems: List[str] = []
     spans = tl.get("spans") or []
@@ -315,14 +325,15 @@ def validate_timeline(tl: dict) -> List[str]:
         problems.append(f"first span starts at {spans[0]['t_ms']}, not 0")
     for a, b in zip(spans, spans[1:]):
         end = a["t_ms"] + a["dur_ms"]
-        if end != b["t_ms"]:
+        if abs(end - b["t_ms"]) >= CLOCK_TICK_MS:
             kind = "gap" if end < b["t_ms"] else "overlap"
             problems.append(
                 f"{kind} between {a['phase']} and {b['phase']}: "
                 f"{end} != {b['t_ms']}")
     last = spans[-1]
     total = tl.get("total_ms")
-    if total is not None and last["t_ms"] + last["dur_ms"] != total:
+    if total is not None and \
+            abs(last["t_ms"] + last["dur_ms"] - total) >= CLOCK_TICK_MS:
         problems.append("last span does not end at total_ms")
     if tl.get("outcome") == "complete":
         phases = [s["phase"] for s in spans]
@@ -335,6 +346,6 @@ def validate_timeline(tl: dict) -> List[str]:
                 and tl.get("queue_wait_ms") is not None \
                 and tl.get("service_ttft_ms") is not None:
             if abs(tl["queue_wait_ms"] + tl["service_ttft_ms"]
-                   - tl["ttft_ms"]) > 1e-6:
+                   - tl["ttft_ms"]) >= CLOCK_TICK_MS:
                 problems.append("queue_wait + service_ttft != ttft")
     return problems

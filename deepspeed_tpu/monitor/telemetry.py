@@ -51,10 +51,11 @@ import os
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from .cost_model import mfu as _mfu_formula
 from .flight import FlightRecorder
@@ -156,6 +157,26 @@ class JsonlSink:
             except Exception:
                 pass
             self.writer = None
+
+
+def ids_arg(ids) -> str:
+    """Request ids as ONE span arg: space-joined (a comma would end the
+    annotation's value), "" for none."""
+    return " ".join(map(str, ids)) if ids else ""
+
+
+class _SpanHandle:
+    """What ``with telemetry.span(...) as sp`` binds when the span also
+    feeds the Chrome-trace writer: ``set_metadata`` (the annotation's own
+    method for args known only at the span's end) reaches both."""
+    __slots__ = ("ann", "args")
+
+    def __init__(self, ann, args: Dict[str, Any]):
+        self.ann, self.args = ann, args
+
+    def set_metadata(self, **args) -> None:
+        self.ann.set_metadata(**args)
+        self.args.update(args)
 
 
 class Telemetry:
@@ -374,23 +395,40 @@ class Telemetry:
             else:
                 self.event("profile", payload)
 
-    def span(self, name: str, **args):
-        """Host-span context manager. Feeds the trace writer (when a
-        trace_path is set) and, for ``checkpoint_*`` spans, the goodput
-        ledger's checkpoint bucket — outermost span only, so the
-        pipeline engine's nested per-layer spans don't double-count.
-        The async save path's ``checkpoint_snapshot`` span additionally
-        files its wall under the ledger's ``checkpoint_snapshot``
-        sub-figure — the exposed part of an async save."""
+    def span(self, name: str, step_num: Optional[int] = None, **args):
+        """Host-span context manager — ALWAYS a ``jax.profiler``
+        annotation, so the span lands in whatever profiler session is
+        open (the ``telemetry.profile`` window, a benchmark's trace, an
+        operator's TensorBoard capture) on the profiler's clock, beside
+        the device's operations; outside a session it is a flag test.
+        ``step_num`` makes it a ``StepTraceAnnotation`` (the profiler's
+        step view). ``args`` ride on the annotation: keep them cheap
+        scalars, and strings free of ``,`` ``=`` ``#`` (the annotation's
+        own encoding); ``with ... as sp: sp.set_metadata(**late)`` adds
+        what is known only at the span's end. With nothing else
+        configured — telemetry off, or no ``trace_path`` and no ledger
+        bucket — the bare annotation is all that is allocated.
+
+        It additionally feeds the Chrome-trace writer (when a trace_path
+        is set) and, for ``checkpoint_*`` spans, the goodput ledger's
+        checkpoint bucket — outermost span only, so the pipeline
+        engine's nested per-layer spans don't double-count. The async
+        save path's ``checkpoint_snapshot`` span additionally files its
+        wall under the ledger's ``checkpoint_snapshot`` sub-figure — the
+        exposed part of an async save."""
+        ann = TraceAnnotation(name, **args) if step_num is None \
+            else StepTraceAnnotation(name, step_num=step_num, **args)
         bucket = "checkpoint" if name.startswith("checkpoint_") else None
         if self.tracer is None and (bucket is None or self.ledger is None):
-            return nullcontext()
+            return ann
         sub = "checkpoint_snapshot" if name == "checkpoint_snapshot" \
             else None
-        return self._span_ctx(name, bucket, args, sub=sub)
+        if step_num is not None:
+            args = dict(args, step=step_num)
+        return self._span_ctx(ann, name, bucket, args, sub=sub)
 
     @contextmanager
-    def _span_ctx(self, name: str, bucket: Optional[str],
+    def _span_ctx(self, ann, name: str, bucket: Optional[str],
                   args: Dict[str, Any], sub: Optional[str] = None):
         outermost = False
         if bucket is not None and self.ledger is not None:
@@ -398,17 +436,16 @@ class Telemetry:
             self._ckpt_depth += 1
         t0 = time.perf_counter()
         try:
-            if self.tracer is not None:
-                with self.tracer.span(name, **args):
-                    yield
-            else:
-                yield
+            with ann:
+                yield _SpanHandle(ann, args)
         finally:
+            dur = time.perf_counter() - t0
+            if self.tracer is not None:
+                self.tracer.add_span(name, t0, dur, args=args or None)
             if bucket is not None and self.ledger is not None:
                 self._ckpt_depth -= 1
                 if outermost:
-                    self.ledger.note(bucket, time.perf_counter() - t0,
-                                     sub=sub)
+                    self.ledger.note(bucket, dur, sub=sub)
 
     def note_checkpoint_write_bg(self, seconds: float) -> None:
         """Background checkpoint-writer wall (called from the writer
@@ -416,11 +453,6 @@ class Telemetry:
         figure, never charged against the window."""
         if self.ledger is not None:
             self.ledger.note_background("checkpoint_write", seconds)
-
-    def add_span(self, name: str, t_start: float, dur_s: float,
-                 args: Optional[Dict[str, Any]] = None) -> None:
-        if self.tracer is not None:
-            self.tracer.add_span(name, t_start, dur_s, args=args)
 
     def instrument_step_fn(self, name: str, fn: Callable) -> Callable:
         """Recompile-sentinel wrapping for a compiled step function;
@@ -759,5 +791,5 @@ class Telemetry:
             self.sink.close()
 
 
-__all__ = ["Telemetry", "JsonlSink", "analytic_state_bytes",
+__all__ = ["Telemetry", "JsonlSink", "ids_arg", "analytic_state_bytes",
            "device_memory_stats"]

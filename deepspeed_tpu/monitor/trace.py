@@ -6,7 +6,12 @@ fence. On the fused jitted paths the device-side phases (grad compute /
 grad sync / optimizer apply) live inside one XLA program and are not
 host-observable without fences; the honest device-side view is the
 optional ``jax.profiler`` window (``ProfilerWindow``), which captures the
-XLA execution trace for N configured steps.
+XLA execution trace for N configured steps — and, since every
+``Telemetry.span`` is also a profiler annotation, the same host spans on
+the profiler's clock. The first event of the JSON is ``clock_sync``: the
+writer's time origin as Unix nanoseconds, to lay this file against an
+``.xplane.pb`` of the same run (whose ``Task Environment`` plane carries
+``profile_start_time``).
 
 The output is the Chrome Trace Event format ("traceEvents" array of
 complete/instant events), loadable in Perfetto (ui.perfetto.dev) or
@@ -58,6 +63,9 @@ class TraceWriter:
         self._pid = os.getpid()
         self._lock = threading.Lock()
         self.closed = False
+        # ts 0 of this file on the wall clock.
+        self.instant("clock_sync", {"unix_ns": time.time_ns()},
+                     t_abs=self._t0)
 
     # ------------------------------------------------------------------ #
     def _ts_us(self, t_abs: float) -> float:

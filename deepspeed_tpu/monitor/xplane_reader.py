@@ -1,0 +1,236 @@
+"""Read a ``jax.profiler`` capture's ``.xplane.pb`` with the named-scope
+path of every device operation.
+
+A TPU capture has one plane per chip (``/device:TPU:<n>``) whose line
+``XLA Ops`` holds one event per executed HLO instruction. The event
+carries times only; its event METADATA carries the whole HLO line as its
+name (the instruction's name is what stands before `` = ``) and the stat
+``tf_op``: the instruction's ``op_name`` — the ``jax.named_scope`` path
+the program's steps carry (``SCOPES``; docs/tutorials/telemetry.md) —
+plus a ``:``. ``jax.profiler.ProfileData`` hands out an event's own
+stats and not its metadata's, so the scope path is only reachable by
+reading the protobuf: a small wire-format reader (field numbers from
+tsl/profiler/protobuf/xplane.proto), no dependency beyond the standard
+library. Host planes hold the ``Telemetry.span`` annotations by name,
+their args as event stats.
+
+Pure host-side parsing; no jax import.
+"""
+from __future__ import annotations
+
+import re
+import struct
+from typing import Any, Dict, Iterator, List, Tuple
+
+__all__ = ["SCOPES", "SPANS", "read_xspace", "event_args", "scope_of",
+           "instruction_name",
+           "device_op_events", "DEVICE_PLANE", "OPS_LINE"]
+
+# The program's named scopes — a stable interface (the benchmark's
+# readers and operators' dashboards key on them).
+SCOPES = ("fwd_bwd", "grad_sync", "health_tap", "optimizer", "flatten",
+          "norm", "kernel", "unflatten", "embed", "attn", "mlp", "lm_head",
+          "kv_write", "attend", "sample", "cow_copy")
+# The host spans ``Telemetry.span`` opens (runtime/engine.py,
+# inference/engine.py, inference/scheduler.py), same contract.
+SPANS = ("train_batch", "data_prep", "step_dispatch", "offload_step",
+         "step_log", "admit", "prefill", "prefill_plan", "prefill_chunk",
+         "prefill_fetch", "decode", "decode_tables", "decode_dispatch",
+         "decode_fetch", "decode_advance", "emit", "serve_idle")
+DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+OPS_LINE = "XLA Ops"
+_INNER = re.compile(r"^(?:[\w.-]+\()*([\w.-]*)\)*$")
+
+
+def _varint(buf, i: int) -> Tuple[int, int]:
+    val = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        val |= (b & 0x7F) << shift
+        if b < 0x80:
+            return val, i
+        shift += 7
+
+
+def _fields(buf) -> Iterator[Tuple[int, Any]]:
+    """(field number, value) of one protobuf message: an int for a
+    varint, a memoryview for a length-delimited or fixed field."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        kind = key & 7
+        if kind == 0:
+            val, i = _varint(buf, i)
+        elif kind == 2:
+            size, i = _varint(buf, i)
+            val, i = buf[i:i + size], i + size
+        elif kind == 1:
+            val, i = buf[i:i + 8], i + 8
+        elif kind == 5:
+            val, i = buf[i:i + 4], i + 4
+        else:
+            raise ValueError(f"wire type {kind} in an xplane file")
+        yield key >> 3, val
+
+
+def _signed(v: int) -> int:
+    return v - (1 << 64) if v >= 1 << 63 else v
+
+
+def _stat(buf, stat_names: Dict[int, str]) -> Tuple[str, Any]:
+    """One XStat -> (name, value); a ``ref_value`` names another stat
+    metadata entry whose name is the string."""
+    name, value = "", None
+    for f, v in _fields(buf):
+        if f == 1:
+            name = stat_names.get(v, str(v))
+        elif f == 2:
+            value = struct.unpack("<d", v)[0]
+        elif f == 3:
+            value = v
+        elif f == 4:
+            value = _signed(v)
+        elif f == 5:
+            value = bytes(v).decode("utf-8", "replace")
+        elif f == 6:
+            value = bytes(v)
+        elif f == 7:
+            value = stat_names.get(v, "")
+    return name, value
+
+
+def _map_entry(buf) -> Tuple[Any, Any]:
+    key = val = None
+    for f, v in _fields(buf):
+        if f == 1:
+            key = v
+        elif f == 2:
+            val = v
+    return key, val
+
+
+def _plane(buf, keep: frozenset) -> Tuple[str, Dict[str, Any]]:
+    name, lines, emeta, smeta = "", [], [], []
+    for f, v in _fields(buf):
+        if f == 2:
+            name = bytes(v).decode()
+        elif f == 3:
+            lines.append(v)
+        elif f == 4:
+            emeta.append(v)
+        elif f == 5:
+            smeta.append(v)
+    stat_names: Dict[int, str] = {}
+    for entry in smeta:
+        key, val = _map_entry(entry)
+        stat_names[key] = next(
+            (bytes(v).decode() for f, v in _fields(val) if f == 2), "")
+    metadata: Dict[int, Tuple[str, Dict[str, Any]]] = {}
+    for entry in emeta:
+        key, val = _map_entry(entry)
+        mname, stats = "", {}
+        for f, v in _fields(val):
+            if f == 2:
+                mname = bytes(v).decode("utf-8", "replace")
+            elif f == 5:
+                sname, sval = _stat(v, stat_names)
+                if sname in keep:
+                    stats[sname] = sval
+        metadata[key] = (mname, stats)
+    out_lines: Dict[str, List[Tuple[int, float, float, Any]]] = {}
+    for lbuf in lines:
+        lname, t0_ns, events = "", 0, []
+        for f, v in _fields(lbuf):
+            if f == 2:
+                lname = bytes(v).decode()
+            elif f == 3:
+                t0_ns = _signed(v)
+            elif f == 4:
+                events.append(v)
+        rows = out_lines.setdefault(lname, [])
+        for ebuf in events:
+            mid = off_ps = dur_ps = 0
+            stats = None
+            for f, v in _fields(ebuf):
+                if f == 1:
+                    mid = v
+                elif f == 2:
+                    off_ps = _signed(v)
+                elif f == 3:
+                    dur_ps = _signed(v)
+                elif f == 4:
+                    stats = stats or []
+                    stats.append(v)
+            rows.append((mid, t0_ns + off_ps / 1e3, dur_ps / 1e3, stats))
+    return name, {"lines": out_lines, "metadata": metadata,
+                  "stat_names": stat_names}
+
+
+def read_xspace(path: str, keep_metadata_stats=("tf_op", "hlo_category")
+                ) -> Dict[str, Dict[str, Any]]:
+    """{plane name: {"lines": {line name: [(metadata id, start_ns,
+    duration_ns, raw stats or None)]}, "metadata": {id: (name, {stat:
+    value})}, "stat_names": {id: name}}}. Times are on the line's clock
+    (``timestamp_ns`` + offset): the one ``ProfileData`` reports, shared
+    by the device and host planes of a session."""
+    with open(path, "rb") as f:
+        data = memoryview(f.read())
+    keep = frozenset(keep_metadata_stats)
+    return dict(_plane(v, keep) for f, v in _fields(data) if f == 1)
+
+
+def event_args(raw_stats, stat_names: Dict[int, str]) -> Dict[str, Any]:
+    """An event's own stats (a ``Telemetry.span``'s args) as a dict."""
+    return dict(_stat(s, stat_names) for s in raw_stats or ())
+
+
+def scope_of(tf_op: str) -> Tuple[Tuple[str, ...], bool, bool]:
+    """``tf_op`` -> (scope path: the SCOPES names in order, backward?,
+    recomputed?). JAX wraps a scope's name in the transforms applied
+    under it (``transpose(jvp(attn))``); a repeat of the scope before it
+    (``fwd_bwd/transpose(fwd_bwd)``) is one scope. Backward operations
+    carry ``transpose(``, recomputed ones ``rematted_computation``."""
+    op = (tf_op or "").split(";", 1)[0].rstrip(":")
+    path: List[str] = []
+    for part in op.split("/"):
+        m = _INNER.match(part)
+        if m and m.group(1) in SCOPES and path[-1:] != [m.group(1)]:
+            path.append(m.group(1))
+    return tuple(path), "transpose(" in op, "rematted_computation" in op
+
+
+def instruction_name(event_name: str) -> str:
+    """``%fusion.12 = bf16[...] fusion(...)`` -> ``fusion.12``: a TPU
+    trace names an op by its whole HLO line."""
+    return event_name.split(" = ", 1)[0].lstrip("%")
+
+
+def device_op_events(path: str) -> List[Dict[str, Any]]:
+    """The capture's device operations as Chrome-trace-shaped events
+    (``ph: "X"``, microseconds; one ``pid`` per device plane) for
+    ``profile_ingest.ingest_events``, each with ``args``: ``hlo_op`` (the
+    instruction's name), ``hlo_category``, ``tf_op``, ``scope`` (the
+    named-scope path, ``/``-joined), ``backward``, ``recomputed``, and
+    ``device_plane``. Empty for a capture without a TPU plane."""
+    events: List[Dict[str, Any]] = []
+    for pname, plane in read_xspace(path).items():
+        m = DEVICE_PLANE.match(pname)
+        if not m:
+            continue
+        pid = int(m.group(1))
+        events.append({"ph": "M", "name": "thread_name", "pid": pid,
+                       "tid": 0, "args": {"name": f"{pname} {OPS_LINE}"}})
+        for mid, start, dur, _ in plane["lines"].get(OPS_LINE, []):
+            name, stats = plane["metadata"].get(mid, ("", {}))
+            tf_op = stats.get("tf_op", "")
+            scope, backward, recomputed = scope_of(tf_op)
+            op = instruction_name(name)
+            events.append({
+                "ph": "X", "name": op, "pid": pid, "tid": 0,
+                "ts": start / 1e3, "dur": dur / 1e3,
+                "args": {"hlo_op": op, "tf_op": tf_op,
+                         "hlo_category": stats.get("hlo_category", ""),
+                         "scope": "/".join(scope), "backward": backward,
+                         "recomputed": recomputed, "device_plane": True}})
+    return events

@@ -208,6 +208,7 @@ def _padded_flat(leaf, dtype, shards: int, pin: bool):
     return f, r
 
 
+@jax.named_scope("flatten")
 def _flatten_group(leaves, idxs, dtype, shards: int, Lpad: int,
                    constrain=None) -> jax.Array:
     """Leaves -> the V-interleaved group buffer (row v = the v-th 1/V
@@ -244,6 +245,7 @@ def _flatten_group(leaves, idxs, dtype, shards: int, Lpad: int,
     return lax.with_sharding_constraint(buf, constrain)
 
 
+@jax.named_scope("unflatten")
 def _unflatten_group(buf: jax.Array, like_leaves, idxs,
                      shards: int) -> Dict[int, jax.Array]:
     """Group buffer -> {leaf idx: leaf-shaped array}. ``buf`` is the
@@ -416,6 +418,7 @@ def _block_rows(rows: int, kernel: str = None, runner=None) -> int:
     return rb
 
 
+@jax.named_scope("norm")
 def _run_sqnorm(gflat: jax.Array, _rb: int = None) -> jax.Array:
     """Squared norm of one flat group buffer via per-chunk partials."""
     rows = gflat.size // _W
@@ -440,6 +443,7 @@ def _run_sqnorm(gflat: jax.Array, _rb: int = None) -> jax.Array:
     return jnp.sum(out[:, 0, 0])
 
 
+@jax.named_scope("kernel")
 def _run_group(gflat, pflat, m, v, scalars, seed, *, b1, b2, eps, wd,
                coupled, use_inv, use_coeff, one_pass, sr, cast,
                out_dtype, cast_dtype, _rb: int = None):

@@ -2455,6 +2455,7 @@ class DeepSpeedEngine:
             return lax.psum_scatter(g, DP_AXIS, scatter_dimension=d,
                                     tiled=True)
 
+        @jax.named_scope("grad_sync")
         def reduce_grads(g):
             if zero3:
                 # Already reduced: gather_cast's transpose scattered the
@@ -2489,6 +2490,7 @@ class DeepSpeedEngine:
         skip_leaves = [bool(s) for s in
                        jax.tree_util.tree_leaves(outer_skip)]
 
+        @jax.named_scope("grad_sync")
         def outer_reduce(g, err, scale):
             """The once-per-step outer hop on the accumulated 1/dp
             residual: slices all-reduce over DCN (optionally 1-bit-
@@ -2643,7 +2645,11 @@ class DeepSpeedEngine:
             if self._direct_grads_fn is not None:
                 raise ValueError("grads_fn does not compose with OnebitAdam")
             return self._build_onebit_train_step()
-        direct_grads = self._direct_grads_fn
+        # Device scopes (compile-time HLO metadata; docs/tutorials/
+        # telemetry.md): inside fwd_bwd JAX itself marks the backward ops
+        # transpose(jvp(...)) and the recomputed ones rematted_computation.
+        fwd_bwd = jax.named_scope("fwd_bwd")
+        direct_grads = self._direct_grads_fn and fwd_bwd(self._direct_grads_fn)
         gas = self._scan_microbatches()
         # Single-chip/single-process: the step consumes the user's flat
         # batch directly and splits micro-batches device-side.
@@ -2698,7 +2704,7 @@ class DeepSpeedEngine:
                 else _cast_floats(params, compute_dtype)
             return raw_scaled_loss(cparams, mb, key, scale, theta)
 
-        grad_fn = jax.value_and_grad(scaled_loss, has_aux=True)
+        grad_fn = fwd_bwd(jax.value_and_grad(scaled_loss, has_aux=True))
         if self._grad_sync_mode == "explicit" and grad_sh is not None \
                 and direct_grads is None:
             # Stage 3 hands the builder the CAST-FREE loss: the gather
@@ -2706,9 +2712,9 @@ class DeepSpeedEngine:
             # and Zero3Scan-covered leaves must reach the model's layer
             # scan as fp32 shards (its custom transpose widens before the
             # per-layer reduce-scatter).
-            explicit_grads_fn = self._build_explicit_zero2_grads(
+            explicit_grads_fn = fwd_bwd(self._build_explicit_zero2_grads(
                 raw_scaled_loss if self._zero3 else scaled_loss,
-                grad_sh, gas)
+                grad_sh, gas))
 
         def train_step(state: EngineState, micro_batches, rng):
             # Derive the per-step key INSIDE jit (a host-side fold_in would
@@ -2795,68 +2801,72 @@ class DeepSpeedEngine:
             # (non-)finiteness either way.
             tap = None
             if health_taps is not None:
-                tap = health_taps(grads)
-                if fp16:
-                    tap = tap / (scale * scale)
+                with jax.named_scope("health_tap"):
+                    tap = health_taps(grads)
+                    if fp16:
+                        tap = tap / (scale * scale)
 
             sr_key = jax.random.fold_in(rng, 0x5352) if master_free \
                 else None
-            if fused_step is not None:
-                # One-pass clipped update: the norm reduction (which
-                # doubles as the fp16 overflow vote — inf/nan in any grad
-                # surfaces as a non-finite sum of squares), the unscale
-                # multiply, the clip coefficient, the overflow-skip
-                # select, and the compute-dtype cast-cache refresh ALL
-                # ride the fused kernels' single read/write of
-                # grad+param+m+v. No separate global_norm pass, no
-                # full-tree unscale, no post-apply jnp.where select, no
-                # standalone cast pass.
-                out = fused_step(
-                    grads, state.opt_state, state.params, clip=clip,
-                    inv_scale=(1.0 / scale) if fp16 else None, fp16=fp16,
-                    compute_norm=bool(clip and clip > 0) or fp16,
-                    sr_key=sr_key,
-                    cast_dtype=compute_dtype if use_cache else None)
-                new_params, new_opt_state = out.params, out.state
-                new_cast = out.cast_params if use_cache else None
-                grad_norm, overflow = out.grad_norm, out.overflow
-            else:
-                # Two-pass path (optax chain / per-leaf fused ablation):
-                # unscale the loss-scaled gradients. Non-fp16 runs at a
-                # static scale of 1.0 — skip the full-tree multiply.
-                if fp16:
-                    inv = 1.0 / scale
-                    grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
-
-                overflow = tree_has_inf_or_nan(grads) if fp16 \
-                    else jnp.asarray(False)
-
-                if (clip and clip > 0) or fp16:
-                    grad_norm = global_norm(grads)
+            with jax.named_scope("optimizer"):
+                if fused_step is not None:
+                    # One-pass clipped update: the norm reduction (which
+                    # doubles as the fp16 overflow vote — inf/nan in any grad
+                    # surfaces as a non-finite sum of squares), the unscale
+                    # multiply, the clip coefficient, the overflow-skip
+                    # select, and the compute-dtype cast-cache refresh ALL
+                    # ride the fused kernels' single read/write of
+                    # grad+param+m+v. No separate global_norm pass, no
+                    # full-tree unscale, no post-apply jnp.where select, no
+                    # standalone cast pass.
+                    out = fused_step(
+                        grads, state.opt_state, state.params, clip=clip,
+                        inv_scale=(1.0 / scale) if fp16 else None, fp16=fp16,
+                        compute_norm=bool(clip and clip > 0) or fp16,
+                        sr_key=sr_key,
+                        cast_dtype=compute_dtype if use_cache else None)
+                    new_params, new_opt_state = out.params, out.state
+                    new_cast = out.cast_params if use_cache else None
+                    grad_norm, overflow = out.grad_norm, out.overflow
                 else:
-                    # Full-tree norm is an extra HBM pass; only pay for it
-                    # when something consumes it (clipping / overflow
-                    # diagnostics).
-                    grad_norm = jnp.asarray(-1.0, jnp.float32)
-                new_params, new_opt_state = _clipped_update(
-                    grads, state, grad_norm, tx=tx, fused_apply=fused_apply,
-                    clip=clip, master_free=master_free, sr_key=sr_key)
-                # Refresh the compute-dtype cache in the same fused pass as
-                # the param update (one extra compute-dtype write instead
-                # of next step's full fp32 re-read + cast).
-                new_cast = _cast_floats(new_params, compute_dtype) \
-                    if use_cache else None
+                    # Two-pass path (optax chain / per-leaf fused ablation):
+                    # unscale the loss-scaled gradients. Non-fp16 runs at a
+                    # static scale of 1.0 — skip the full-tree multiply.
+                    if fp16:
+                        inv = 1.0 / scale
+                        grads = jax.tree_util.tree_map(
+                            lambda g: g * inv, grads)
 
-                # Overflow-skip (reference step semantics
-                # engine.py:1000-1085): keep old params/opt state, don't
-                # advance step (so LR holds).
-                keep = overflow
-                new_params = _tree_select(keep, state.params, new_params)
-                new_opt_state = _tree_select(keep, state.opt_state,
-                                             new_opt_state)
-                if use_cache:
-                    new_cast = _tree_select(keep, state.cast_params,
-                                            new_cast)
+                    overflow = tree_has_inf_or_nan(grads) if fp16 \
+                        else jnp.asarray(False)
+
+                    if (clip and clip > 0) or fp16:
+                        grad_norm = global_norm(grads)
+                    else:
+                        # Full-tree norm is an extra HBM pass; only pay for it
+                        # when something consumes it (clipping / overflow
+                        # diagnostics).
+                        grad_norm = jnp.asarray(-1.0, jnp.float32)
+                    new_params, new_opt_state = _clipped_update(
+                        grads, state, grad_norm, tx=tx,
+                        fused_apply=fused_apply, clip=clip,
+                        master_free=master_free, sr_key=sr_key)
+                    # Refresh the compute-dtype cache in the same fused pass as
+                    # the param update (one extra compute-dtype write instead
+                    # of next step's full fp32 re-read + cast).
+                    new_cast = _cast_floats(new_params, compute_dtype) \
+                        if use_cache else None
+
+                    # Overflow-skip (reference step semantics
+                    # engine.py:1000-1085): keep old params/opt state, don't
+                    # advance step (so LR holds).
+                    keep = overflow
+                    new_params = _tree_select(keep, state.params, new_params)
+                    new_opt_state = _tree_select(keep, state.opt_state,
+                                                 new_opt_state)
+                    if use_cache:
+                        new_cast = _tree_select(keep, state.cast_params,
+                                                new_cast)
 
             # Shared overflow-vote resolution: step/skip bookkeeping +
             # loss-scale state machine. DCN-compression error feedback
@@ -2955,10 +2965,31 @@ class DeepSpeedEngine:
 
         ``batch``: pytree with leading dim ``gas * micro * dp_local``; or pull
         ``gas`` micro-batches from ``data_iter`` / the engine's dataloader.
+
+        Host spans (``Telemetry.span`` — profiler annotations, a flag
+        test outside a profiler session): ``train_batch`` > ``data_prep``,
+        ``step_dispatch`` (``offload_step`` when offloading), ``step_log``.
         """
         tl = self.telemetry
         t_wall0 = time.perf_counter()
-        tl.profiler_tick(self.global_steps)
+        step = self.global_steps
+        tl.profiler_tick(step)
+        with tl.span("train_batch", step_num=step):
+            with tl.span("data_prep", step=step):
+                micro_batches = self._prepare_batch(batch, data_iter)
+            with tl.span("offload_step" if self._offload is not None
+                         else "step_dispatch", step=step):
+                metrics = self._dispatch_step(micro_batches)
+            with tl.span("step_log", step=step):
+                self._record_telemetry(metrics, t_wall0)
+                self._maybe_log(metrics)
+                self._maybe_auto_save()
+        return metrics["loss"]
+
+    def _prepare_batch(self, batch, data_iter):
+        """train_batch's ``data_prep``: the iterator pull, the micro-batch
+        layout and the ``device_put``; builds the step on first use."""
+        tl = self.telemetry
         sparse_path = self._sparse_mask is not None and self.dp_size > 1
         if self._train_step_fn is None and self._offload is None \
                 and not sparse_path:
@@ -3016,16 +3047,16 @@ class DeepSpeedEngine:
         if (self.flops_profiler is not None and
                 self.global_steps == self.config.flops_profiler_config.profile_step):
             self._run_flops_profiler(micro_batches)
-        if tl.tracer is not None:
-            tl.add_span("data_prep", t_wall0,
-                        time.perf_counter() - t_wall0)
-        self._maybe_refresh_moe_wire(micro_batches)
+        return micro_batches
 
+    def _dispatch_step(self, micro_batches):
+        """train_batch's ``step_dispatch``: the compiled step's call (it
+        returns without waiting for the device) and the step counters."""
+        self._maybe_refresh_moe_wire(micro_batches)
         self.tput_timer.start()
-        t_dispatch = time.perf_counter()
         if self._offload is not None:
             metrics = self._train_batch_offload(micro_batches)
-        elif sparse_path:
+        elif self._sparse_mask is not None and self.dp_size > 1:
             metrics = self._train_batch_sparse(micro_batches)
         else:
             self.state, metrics = self._train_step_fn(
@@ -3039,10 +3070,7 @@ class DeepSpeedEngine:
         if self.lr_scheduler is not None and hasattr(self.lr_scheduler, "step"):
             self.lr_scheduler.last_batch_iteration = self.global_steps - 1
         self.tput_timer.stop()
-        self._record_telemetry(metrics, t_wall0, t_dispatch)
-        self._maybe_log(metrics)
-        self._maybe_auto_save()
-        return metrics["loss"]
+        return metrics
 
     def _maybe_auto_save(self) -> None:
         """Auto-save (checkpoint.snapshot_every): tag global_stepN into
@@ -3057,7 +3085,7 @@ class DeepSpeedEngine:
     # Alias matching common JAX naming.
     train_step = train_batch
 
-    def _record_telemetry(self, metrics, t0: float, t_dispatch: float) -> None:
+    def _record_telemetry(self, metrics, t0: float) -> None:
         """Buffer this step's telemetry record — append-only, no device
         access (the metrics dict's jax scalars ride as futures and sync
         at the next report-boundary drain). ``wall_ms`` is host wall from
@@ -3089,13 +3117,6 @@ class DeepSpeedEngine:
             off["overlapped"] = bool(t.get("overlapped", False))
             host["offload"] = off
             tl.add_offload_trace(t)
-        if tl.tracer is not None:
-            name = "offload_step" if self._offload is not None \
-                else "step_dispatch"
-            tl.add_span(name, t_dispatch, t_now - t_dispatch,
-                        args={"step": self.global_steps})
-            tl.add_span("train_batch", t0, t_now - t0,
-                        args={"step": self.global_steps})
         tl.record_step(self.global_steps, metrics, **host)
 
     def _report_extra(self) -> Dict[str, Any]:
@@ -3594,10 +3615,9 @@ class DeepSpeedEngine:
         if self.micro_steps % self.gradient_accumulation_steps() != 0:
             return  # not at boundary; parity with reference gating
         assert self._accum_grads is not None, "no gradients accumulated"
-        t_apply = time.perf_counter()
         # Window wall from the first forward() of this accumulation cycle
         # (fallback: apply-only, when step() is driven without forward).
-        t0 = getattr(self, "_trio_t0", None) or t_apply
+        t0 = getattr(self, "_trio_t0", None) or time.perf_counter()
         self._trio_t0 = None
         with self.telemetry.span("optimizer_apply"):
             self.state, metrics = self._apply_grads_fn(self.state,
@@ -3605,7 +3625,7 @@ class DeepSpeedEngine:
         self._accum_grads = None
         self.global_steps += 1
         self.global_samples += self.train_batch_size()
-        self._record_telemetry(metrics, t0, t_apply)
+        self._record_telemetry(metrics, t0)
         self._maybe_log(metrics)
         self._maybe_auto_save()
 
@@ -3631,7 +3651,9 @@ class DeepSpeedEngine:
                 else _cast_floats(params, compute_dtype)
             return raw_scaled_loss(cparams, mb, key, scale, theta)
 
-        vg = jax.value_and_grad(scaled_loss, has_aux=True)
+        # Same device scopes as _build_train_step.
+        fwd_bwd = jax.named_scope("fwd_bwd")
+        vg = fwd_bwd(jax.value_and_grad(scaled_loss, has_aux=True))
 
         grad_sh = self._grad_shardings()
         # Resolved-explicit engines route the trio's backward through the
@@ -3642,9 +3664,9 @@ class DeepSpeedEngine:
         # reduce-scatter bytes, every micro-step).
         explicit_fn = None
         if self._grad_sync_mode == "explicit" and grad_sh is not None:
-            explicit_fn = self._build_explicit_zero2_grads(
+            explicit_fn = fwd_bwd(self._build_explicit_zero2_grads(
                 raw_scaled_loss if self._zero3 else scaled_loss,
-                grad_sh, gas=1)
+                grad_sh, gas=1))
 
         def grad_step(params, mb, key, scale, theta=None):
             if explicit_fn is not None:
@@ -3681,38 +3703,41 @@ class DeepSpeedEngine:
             # when not fp16).
             tap = None
             if health_taps is not None:
-                tap = health_taps(grads) / (scale * scale)
-            if fused_step is not None:
-                # One-pass clipped update, same contract as the main
-                # train step: unscale (scale is a traced 1.0 when not
-                # fp16 — the kernel's scalar multiply replaces the
-                # historical full-tree g/scale pass either way), norm,
-                # overflow vote, clip, skip-select and cast-cache
-                # refresh inside the single optimizer-state HBM pass.
-                out = fused_step(
-                    grads, state.opt_state, state.params, clip=clip,
-                    inv_scale=1.0 / scale, fp16=fp16, compute_norm=True,
-                    cast_dtype=compute_dtype if use_cache else None)
-                new_params, new_opt = out.params, out.state
-                new_cast = out.cast_params if use_cache else None
-                grad_norm, overflow = out.grad_norm, out.overflow
-            else:
-                grads = jax.tree_util.tree_map(lambda g: g / scale, grads)
-                overflow = tree_has_inf_or_nan(grads) if fp16 \
-                    else jnp.asarray(False)
-                grad_norm = global_norm(grads)
-                new_params, new_opt = _clipped_update(
-                    grads, state, grad_norm, tx=tx, fused_apply=fused_apply,
-                    clip=clip)
-                # Same cache refresh as the fused train step: the next
-                # train_batch reads state.cast_params.
-                new_cast = None
-                if state.cast_params is not None:
-                    new_cast = _tree_select(
-                        overflow, state.cast_params,
-                        _cast_floats(new_params, compute_dtype))
-                new_params = _tree_select(overflow, state.params, new_params)
-                new_opt = _tree_select(overflow, state.opt_state, new_opt)
+                with jax.named_scope("health_tap"):
+                    tap = health_taps(grads) / (scale * scale)
+            with jax.named_scope("optimizer"):
+                if fused_step is not None:
+                    # One-pass clipped update, same contract as the main
+                    # train step: unscale (scale is a traced 1.0 when not
+                    # fp16 — the kernel's scalar multiply replaces the
+                    # historical full-tree g/scale pass either way), norm,
+                    # overflow vote, clip, skip-select and cast-cache
+                    # refresh inside the single optimizer-state HBM pass.
+                    out = fused_step(
+                        grads, state.opt_state, state.params, clip=clip,
+                        inv_scale=1.0 / scale, fp16=fp16, compute_norm=True,
+                        cast_dtype=compute_dtype if use_cache else None)
+                    new_params, new_opt = out.params, out.state
+                    new_cast = out.cast_params if use_cache else None
+                    grad_norm, overflow = out.grad_norm, out.overflow
+                else:
+                    grads = jax.tree_util.tree_map(lambda g: g / scale, grads)
+                    overflow = tree_has_inf_or_nan(grads) if fp16 \
+                        else jnp.asarray(False)
+                    grad_norm = global_norm(grads)
+                    new_params, new_opt = _clipped_update(
+                        grads, state, grad_norm, tx=tx,
+                        fused_apply=fused_apply, clip=clip)
+                    # Same cache refresh as the fused train step: the next
+                    # train_batch reads state.cast_params.
+                    new_cast = None
+                    if state.cast_params is not None:
+                        new_cast = _tree_select(
+                            overflow, state.cast_params,
+                            _cast_floats(new_params, compute_dtype))
+                    new_params = _tree_select(overflow, state.params,
+                                              new_params)
+                    new_opt = _tree_select(overflow, state.opt_state, new_opt)
             new_state = state.replace(
                 params=new_params, opt_state=new_opt, cast_params=new_cast,
                 **_overflow_resolution(state, overflow, **scaler_kw))

@@ -589,3 +589,113 @@ class TestRealCaptureRoundTrip:
             1.0, abs=0.05)
         assert glob.glob(os.path.join(
             events[-1]["path"], "plugins", "profile", "*", "*"))
+
+
+# --------------------------------------------------------------------- #
+# The .xplane.pb front end, on recorded chip traces (one v5e; two steps
+# of a two-layer toy): instruction names, kernel families and named
+# scopes from the profiler's own file.
+# --------------------------------------------------------------------- #
+_DATA = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "perfbench", "tests", "data")
+
+
+def _capture(tmp_path, name):
+    """A ``jax.profiler`` capture dir holding the recorded trace."""
+    src = os.path.join(_DATA, name)
+    if not os.path.exists(src):
+        pytest.skip(f"no recorded trace {name} in this checkout")
+    import shutil
+    d = tmp_path / "plugins" / "profile" / "2026_01_01"
+    d.mkdir(parents=True)
+    shutil.copy(src, d / "host.xplane.pb")
+    return str(tmp_path)
+
+
+class TestXplaneFrontEnd:
+    def test_instruction_name_rule(self):
+        from deepspeed_tpu.monitor.xplane_reader import instruction_name
+        assert instruction_name(
+            "%_fused_adam_kernel.1 = (bf16[8,128]{1,0}) custom-call("
+            "f32[1,8] %x), custom_call_target=\"tpu_custom_call\""
+        ) == "_fused_adam_kernel.1"
+        assert instruction_name("fusion.12") == "fusion.12"
+
+    @pytest.mark.parametrize("tf_op,want", [
+        ("jit(train_step)/fwd_bwd/transpose(jvp())/while/body/closed_call/"
+         "checkpoint/rematted_computation/attn/dot_general:",
+         (("fwd_bwd", "attn"), True, True)),
+        ("jit(train_step)/fwd_bwd/transpose(fwd_bwd)/jvp(lm_head)/mul:",
+         (("fwd_bwd", "lm_head"), True, False)),
+        ("jit(decode_step)/while/body/attn/kv_write/scatter:",
+         (("attn", "kv_write"), False, False)),
+        ("jit(train_step)/jit(_threefry_split)/concatenate:",
+         ((), False, False)),
+    ])
+    def test_scope_of(self, tf_op, want):
+        from deepspeed_tpu.monitor.xplane_reader import scope_of
+        assert scope_of(tf_op) == want
+
+    def test_chip_trace_names_families_and_buckets(self, tmp_path):
+        """PR 23's trace (no scopes yet): the kernel-family patterns
+        match a CHIP trace once the instruction's name is cut out of its
+        HLO line; device copies are not host transfers; buckets + idle
+        still partition the window."""
+        out = ingest(_capture(tmp_path, "tiny_train.xplane.pb"), n_steps=2)
+        assert out["trace_files"] == [os.path.join(
+            "plugins", "profile", "2026_01_01", "host.xplane.pb")]
+        assert out["n_device_ops"] > 500 and out["n_device_lanes"] == 1
+        assert set(out["pallas_families_ms"]) == {
+            "flash_attention", "fused_gelu", "fused_ln", "fused_update"}
+        assert all(v > 0 for v in out["pallas_families_ms"].values())
+        b = out["buckets_ms"]
+        assert b["pallas"] > 0 and b["gemm"] > 0 and b["host"] == 0.0
+        assert out["sum_check"]["explained_frac"] == pytest.approx(1.0)
+        assert {"op": "_fused_adam_kernel", "bucket": "pallas"}.items() <= \
+            next(t for t in out["top_ops"]
+                 if t["op"] == "_fused_adam_kernel").items()
+        assert "scopes_ms" not in out          # nothing was scoped then
+
+    def test_chip_trace_reports_time_by_scope(self, tmp_path):
+        out = ingest(_capture(tmp_path, "toy_train_scoped.xplane.pb"),
+                     n_steps=2)
+        scopes = out["scopes_ms"]
+        for key in ("fwd_bwd/attn", "fwd_bwd/mlp", "fwd_bwd/attn:bwd",
+                    "fwd_bwd/mlp:bwd", "fwd_bwd/lm_head",
+                    "optimizer/flatten", "optimizer/kernel",
+                    "optimizer/unflatten"):
+            assert scopes.get(key, 0) > 0, (key, sorted(scopes))
+        # self times by scope are the device's busy time, per step
+        busy = sum(v for k, v in out["per_step_ms"].items() if k != "idle")
+        assert sum(scopes.values()) == pytest.approx(busy, rel=0.01)
+        # the kernel scope holds the fused optimizer's kernel time
+        assert scopes["optimizer/kernel"] * 2 >= \
+            out["pallas_families_ms"]["fused_update"] * 0.5
+
+    def test_device_events_carry_the_scope(self, tmp_path):
+        from deepspeed_tpu.monitor.xplane_reader import device_op_events
+        path = os.path.join(_DATA, "toy_train_scoped.xplane.pb")
+        if not os.path.exists(path):
+            pytest.skip("no recorded trace in this checkout")
+        ops = [e for e in device_op_events(path) if e["ph"] == "X"]
+        adam = [e for e in ops if e["name"].startswith("_fused_adam_kernel")]
+        assert adam and all(e["args"]["scope"] == "optimizer/kernel"
+                            for e in adam)
+        assert any(e["args"]["recomputed"] for e in ops)
+        assert any(e["args"]["backward"] and not e["args"]["recomputed"]
+                   for e in ops)
+
+    def test_capture_without_a_tpu_plane_is_read_from_its_json(
+            self, tmp_path):
+        """The CPU backend's capture has no device plane: the xplane is
+        passed over and the Chrome-trace JSON ingested as before."""
+        d = tmp_path / "plugins" / "profile" / "t"
+        d.mkdir(parents=True)
+        (d / "host.xplane.pb").write_bytes(b"")
+        with gzip.open(d / "host.trace.json.gz", "wt") as f:
+            json.dump({"traceEvents": [
+                _ev("dot.1", 0, 10, args={"hlo_op": "dot.1"})]}, f)
+        out = ingest(str(tmp_path))
+        assert out["trace_files"] == [os.path.join(
+            "plugins", "profile", "t", "host.trace.json.gz")]
+        assert out["buckets_ms"]["gemm"] > 0

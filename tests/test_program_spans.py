@@ -1,0 +1,327 @@
+"""The program's own spans and scopes in the JAX profiler's trace.
+
+- ``Telemetry.span`` always opens a ``jax.profiler`` annotation: bare when
+  nothing else is configured, and as well as the Chrome-trace span / the
+  checkpoint ledger bucket when they are.
+- The compiled train, decode and prefill programs carry the
+  ``jax.named_scope`` names in their ``op_name`` metadata: one case per
+  scope, so a refactor that drops one fails by name.
+- A profiler session round serve iterations and ``train_batch`` calls
+  holds every host span of docs/tutorials/telemetry.md with its args,
+  each once per occurrence.
+
+Span and scope names are a contract with the benchmark's readers
+(``perfbench/lib/program_trace.py``) and with operators' dashboards.
+"""
+import collections
+import glob
+import json
+import os
+import re
+
+import jax
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from deepspeed_tpu.inference import (InferenceEngine, Request,
+                                     synthetic_requests)
+from deepspeed_tpu.models.gpt2 import (GPT2_CONFIGS, gpt2_init,
+                                       gpt2_loss_fn)
+from deepspeed_tpu.monitor.telemetry import Telemetry
+from deepspeed_tpu.parallel.topology import build_mesh
+from deepspeed_tpu.runtime.config import DeepSpeedConfig
+
+from simple_model import base_config
+
+CFG = GPT2_CONFIGS["gpt2-tiny"]
+
+
+def _annotations(trace_dir):
+    """{span name: [{arg: value}]} of the profiler session's host
+    events, in time order per name."""
+    from jax.profiler import ProfileData
+    path = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                  "*.xplane.pb"))[-1]
+    out = collections.defaultdict(list)
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                out[ev.name].append((ev.start_ns, ev.duration_ns,
+                                     dict(ev.stats)))
+    return {k: sorted(v, key=lambda e: e[0]) for k, v in out.items()}
+
+
+def _session(trace_dir, fn):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    return _annotations(str(trace_dir))
+
+
+# --------------------------------------------------------------------- #
+# (a) Telemetry.span
+# --------------------------------------------------------------------- #
+def _telemetry(tmp_path, kind):
+    knobs = {"disabled": {"enabled": False},
+             "tracer": {"enabled": True, "output_path": str(tmp_path),
+                        "trace_path": str(tmp_path / "host.trace.json")},
+             "checkpoint-bucket": {"enabled": True,
+                                   "output_path": str(tmp_path)}}[kind]
+    cfg = DeepSpeedConfig(base_config(telemetry=knobs)).telemetry_config
+    return Telemetry(cfg, default_report_steps=1, is_writer=True)
+
+
+@pytest.mark.parametrize("kind", ["disabled", "tracer", "checkpoint-bucket"])
+def test_span_always_opens_a_profiler_annotation(tmp_path, kind):
+    tel = _telemetry(tmp_path, kind)
+    name = "checkpoint_save" if kind == "checkpoint-bucket" else "decode"
+    if kind == "disabled":
+        # nothing but the annotation is allocated
+        assert type(tel.span(name, iteration=3)) is \
+            jax.profiler.TraceAnnotation
+        assert tel.tracer is None and tel.ledger is None
+        assert type(tel.span("train_batch", step_num=5)) is \
+            jax.profiler.StepTraceAnnotation
+    if kind == "checkpoint-bucket":
+        assert tel.tracer is None
+        # a span with no bucket stays the bare annotation
+        assert type(tel.span("decode")) is jax.profiler.TraceAnnotation
+
+    def use():
+        with tel.span(name, iteration=3) as sp:
+            sp.set_metadata(live_blocks=7)
+    found = _session(tmp_path / "prof", use)
+    assert [a for _, _, a in found[name]] == \
+        [{"iteration": 3, "live_blocks": 7}]
+    if kind == "tracer":
+        tel.tracer.flush()
+        tel.close()
+        events = json.load(open(tmp_path / "host.trace.json"))
+        assert events[0]["name"] == "clock_sync"
+        ours = [e for e in events if e["name"] == name]
+        assert len(ours) == 1 and ours[0]["ph"] == "X"
+        assert ours[0]["args"] == {"iteration": 3, "live_blocks": 7}
+    elif kind == "checkpoint-bucket":
+        assert tel.ledger.peek()["noted_s"]["checkpoint"] > 0
+        tel.close()
+
+
+# --------------------------------------------------------------------- #
+# (b) device scopes in the compiled programs
+# --------------------------------------------------------------------- #
+@pytest.fixture(scope="module")
+def params():
+    return gpt2_init(jax.random.PRNGKey(1), CFG)
+
+
+@pytest.fixture(scope="module")
+def train_engine(params):
+    ds = {"train_batch_size": 2, "train_micro_batch_size_per_gpu": 2,
+          "gradient_accumulation_steps": 1, "gradient_clipping": 1.0,
+          "bf16": {"enabled": True}, "zero_optimization": {"stage": 2},
+          "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+          "steps_per_print": 10 ** 9}
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        config=ds, model=gpt2_loss_fn(CFG), model_params=params,
+        mesh=build_mesh(devices=jax.devices()[:1]))
+    return engine
+
+
+def _batch(seed=0):
+    return np.random.default_rng(seed).integers(
+        0, CFG.vocab_size, (2, 33)).astype(np.int32)
+
+
+def _op_names(jitted, *args):
+    text = jitted.lower(*args).compile().as_text()
+    return set(re.findall(r'op_name="([^"]*)"', text))
+
+
+@pytest.fixture(scope="module")
+def train_op_names(train_engine):
+    train_engine.train_batch(_batch())          # builds the step
+    return _op_names(train_engine._train_step_fn, train_engine.state,
+                     _batch(), train_engine._base_rng)
+
+
+@pytest.mark.parametrize("scope", [
+    "fwd_bwd", "transpose(", "fwd_bwd/transpose(", "embed", "attn", "mlp",
+    "lm_head", "optimizer/flatten", "optimizer/norm", "optimizer/kernel",
+    "optimizer/unflatten"])
+def test_train_step_carries_scope(train_op_names, scope):
+    assert any(scope in n for n in train_op_names), scope
+
+
+def test_backward_ops_sit_under_fwd_bwd_and_optimizer_ops_do_not(
+        train_op_names):
+    assert not any("transpose(" in n and "fwd_bwd" not in n
+                   for n in train_op_names)
+    assert not any("optimizer" in n and "fwd_bwd" in n
+                   for n in train_op_names)
+
+
+@pytest.fixture(scope="module")
+def serve_engine(params):
+    eng = InferenceEngine(CFG, params, config={"inference": {
+        "max_slots": 4, "max_seq_len": 64, "prefill_chunk": 8,
+        "block_size": 16}}, mesh=build_mesh(devices=jax.devices()[:1]))
+    yield eng
+    eng.close()
+
+
+@pytest.fixture(scope="module")
+def serve_op_names(serve_engine):
+    eng = serve_engine
+    G, J = eng.dp, eng.cache_spec.max_blocks_per_slot
+    key, temp = eng._next_key(), np.float32(0.0)
+    k, v = eng.cache["k"], eng.cache["v"]
+    decode = _op_names(eng._decode_fn, eng._params, k, v, eng.last_tokens,
+                       eng.lengths, eng.block_tables, key, temp)
+    prefill = _op_names(
+        eng._prefill_fn, eng._params, k, v,
+        np.zeros((G, eng.prefill_chunk), np.int32),
+        np.zeros((G, J), np.int32), np.zeros(G, np.int32),
+        np.zeros(G, np.int32), np.ones(G, np.int32), key, temp)
+    B = eng.cache_spec.blocks_per_group
+    copy = _op_names(eng._copy_fn, k, v, np.zeros((G, B), np.float32),
+                     np.zeros((G, B), bool))
+    return {"decode": decode, "prefill": prefill, "copy": copy}
+
+
+@pytest.mark.parametrize("program,scope", [
+    ("decode", "kv_write"), ("decode", "attend"), ("decode", "sample"),
+    ("decode", "embed"), ("decode", "mlp"), ("decode", "lm_head"),
+    ("prefill", "kv_write"), ("prefill", "attend"), ("prefill", "sample"),
+    ("copy", "cow_copy")])
+def test_serve_program_carries_scope(serve_op_names, program, scope):
+    assert any(f"/{scope}" in n for n in serve_op_names[program]), \
+        (program, scope)
+
+
+def test_kv_write_and_attend_nest_under_attn(serve_op_names):
+    for program in ("decode", "prefill"):
+        for scope in ("kv_write", "attend"):
+            assert any(f"attn/{scope}" in n
+                       for n in serve_op_names[program]), (program, scope)
+
+
+# --------------------------------------------------------------------- #
+# (c) host spans in a profiler session
+# --------------------------------------------------------------------- #
+SERVE_SPANS = {
+    "admit": {"queued", "admitted", "late_ms", "rids"},
+    "prefill": {"slots", "prompt_tokens", "cached_tokens", "chunks",
+                "rids"},
+    "prefill_plan": set(), "prefill_chunk": {"ci", "active_groups"},
+    "prefill_fetch": set(),
+    "decode": {"iteration", "active", "live_blocks", "context_tokens"},
+    "decode_tables": set(), "decode_dispatch": set(),
+    "decode_fetch": set(), "decode_advance": set(),
+    "emit": set(), "serve_idle": {"why"}}
+TRAIN_SPANS = {"train_batch": {"step_num"}, "data_prep": {"step"},
+               "step_dispatch": {"step"}, "step_log": {"step"}}
+
+
+@pytest.fixture(scope="module")
+def serve_annotations(serve_engine, tmp_path_factory):
+    eng = serve_engine
+    rng = np.random.default_rng(3)
+    # warm up (compiles) outside the session
+    eng.serve(synthetic_requests(2, prompt_len=(10, 12), max_new_tokens=3,
+                                 vocab_size=CFG.vocab_size))
+    # two requests at once (the second arrival late enough for an idle
+    # sleep), 3 tokens each: one prefill token + two decode iterations
+    # for the first batch, then the same for the late one.
+    reqs = [Request(rid=100 + i, max_new_tokens=3, arrival_s=a,
+                    prompt=rng.integers(0, CFG.vocab_size, 12,
+                                        dtype=np.int32))
+            for i, a in enumerate((0.0, 0.0, 0.3))]
+    eng.reset_serving_stats()
+    report = {}
+    found = _session(tmp_path_factory.mktemp("serve_prof"),
+                     lambda: report.update(eng.serve(reqs)))
+    assert report["completed"] == 3
+    return found, report
+
+
+@pytest.mark.parametrize("span", sorted(SERVE_SPANS))
+def test_serve_span_is_in_the_profile_with_its_args(serve_annotations,
+                                                    span):
+    found, _ = serve_annotations
+    assert found.get(span), f"no {span!r} span in the profiler session"
+    for _, _, args in found[span]:
+        assert SERVE_SPANS[span] <= set(args), (span, args)
+
+
+def test_serve_spans_occur_once_per_occurrence(serve_annotations):
+    found, report = serve_annotations
+    iters = report["iterations"]
+    assert iters >= 3
+    for name in ("decode", "decode_tables", "decode_dispatch",
+                 "decode_fetch", "decode_advance", "emit"):
+        assert len(found[name]) == iters, (name, len(found[name]), iters)
+    # one prefill per admission batch (one chip: one slot per batch)
+    admitted = [a for _, _, a in found["admit"] if a["admitted"]]
+    assert len(admitted) == len(found["prefill"]) == 3
+    assert len(found["prefill_plan"]) == len(found["prefill_fetch"]) == 3
+    assert len(found["prefill_chunk"]) == \
+        sum(a["chunks"] for _, _, a in found["prefill"])
+    # the benchmark's own wrapper names are not the program's
+    assert not {"prefill_many", "decode_once", "data", "wait"} & set(found)
+
+
+def test_serve_spans_nest_and_follow_a_request(serve_annotations):
+    found, _ = serve_annotations
+
+    def within(inner, outer):
+        return any(o[0] <= inner[0] and
+                   inner[0] + inner[1] <= o[0] + o[1] for o in outer)
+    for child in ("decode_tables", "decode_dispatch", "decode_fetch",
+                  "decode_advance"):
+        assert all(within(e, found["decode"]) for e in found[child]), child
+    for child in ("prefill_plan", "prefill_chunk", "prefill_fetch"):
+        assert all(within(e, found["prefill"]) for e in found[child]), child
+    # rid 102 is admitted, prefilled and finished under its own id
+    rid = 102
+    assert any(str(rid) in str(a.get("rids", "")).split()
+               for _, _, a in found["admit"])
+    assert any(str(rid) in str(a.get("rids", "")).split()
+               for _, _, a in found["prefill"])
+    assert any(str(rid) in str(a.get("finished", "")).split()
+               for _, _, a in found["emit"])
+    # the late arrival was polled for at or after its due time
+    assert all(a["late_ms"] >= 0 for _, _, a in found["admit"])
+    assert {a["why"] for _, _, a in found["serve_idle"]} == {"no_arrival"}
+    # decode's end-of-span args: what _cache_accounting read
+    assert all(a["live_blocks"] > 0 and a["context_tokens"] > 0
+               for _, _, a in found["decode"])
+
+
+@pytest.fixture(scope="module")
+def train_annotations(train_engine, tmp_path_factory):
+    train_engine.train_batch(_batch())          # compiled before
+    first = train_engine.global_steps
+
+    def two_steps():
+        for i in range(2):
+            train_engine.train_batch(_batch(i))
+    return _session(tmp_path_factory.mktemp("train_prof"),
+                    two_steps), first
+
+
+@pytest.mark.parametrize("span", sorted(TRAIN_SPANS))
+def test_train_span_is_in_the_profile_once_per_step(train_annotations,
+                                                    span):
+    found, first = train_annotations
+    assert len(found.get(span, [])) == 2, (span, found.get(span))
+    key = "step_num" if span == "train_batch" else "step"
+    assert [a[key] for _, _, a in found[span]] == [first, first + 1]
+    if span != "train_batch":
+        outer = found["train_batch"]
+        assert all(any(o[0] <= e[0] and e[0] + e[1] <= o[0] + o[1]
+                       for o in outer) for e in found[span])

@@ -476,7 +476,10 @@ class TestLifetime:
         tw.add_span("b", t, 0.001)
         tw.close()
         evs = json.load(open(path))
-        assert [e["name"] for e in evs[:2]] == ["a", "b"]
+        # The first event lays the file's ts 0 on the wall clock.
+        assert [e["name"] for e in evs[:3]] == ["clock_sync", "a", "b"]
+        assert evs[0]["ts"] == 0
+        assert abs(evs[0]["args"]["unix_ns"] - _time.time_ns()) < 60e9
 
     def test_trace_writer_non_writer_buffers_nothing(self, tmp_path):
         from deepspeed_tpu.monitor import TraceWriter
