@@ -5,8 +5,8 @@ Times ONLY the optimizer apply (grads fixed, full train step excluded) for
 a GPT-2-shaped param tree, across:
 
     optax           — optax.adamw update + apply_updates (XLA's own fusion)
-    fused           — Pallas multi-tensor chunked apply (ops/fused_update)
-    fused_per_leaf  — same kernel, one launch per leaf (no chunking)
+    fused           — the Pallas apply (ops/fused_update): large leaves
+                      updated in place, the rest through one packed buffer
 
 and, under --sr, the master-free bf16 variants (stochastic-rounding write).
 
@@ -15,12 +15,10 @@ per-op cost = (t(scan N) - t(scan 1)) / (N - 1), so the fixed per-call
 dispatch cost cancels.
 
 Also prints the roofline: minimum HBM bytes an apply must move per param
-element (read g+p+m+v, write p+m+v), the bytes each variant actually
-moves (the chunked front end adds flatten/unflatten passes over g and p),
-and the implied HBM bandwidth — if the fused apply's achieved GB/s sits
-at the chip's HBM ceiling, the optimizer step is provably
-bandwidth-bound and no further kernel work can buy more
-(the acceptance alternative in ISSUE.md).
+element (read g+p+m+v, write p+m+v) and the implied HBM bandwidth — if
+the fused apply's achieved GB/s sits at the chip's HBM ceiling, the
+optimizer step is provably bandwidth-bound and no further kernel work
+can buy more.
 
 Usage: python ablate_fused_update.py [model] [--sr]
 """
@@ -103,9 +101,6 @@ def main():
     gsize = 4
     # One apply must at minimum read g+p+m+v and write p+m+v (m/v f32).
     min_bytes = n_elems * (gsize + psize + 4 + 4 + psize + 4 + 4)
-    # The chunked front end adds flatten (read+write g and p) and
-    # unflatten (read+write p) passes.
-    chunk_bytes = min_bytes + n_elems * (2 * gsize + 3 * psize)
 
     sched = lambda c: jnp.asarray(1e-4, jnp.float32)
     key = jax.random.PRNGKey(7)
@@ -128,14 +123,11 @@ def main():
         lambda x: x.astype(jnp.float32), p))) if SR else tx.init
     variants["optax"] = (optax_apply, opt_init(params))
 
-    for name, mt in (("fused", True), ("fused_per_leaf", False)):
-        ftx = fused_adam(sched, weight_decay=0.01, multi_tensor=mt)
+    ftx = fused_adam(sched, weight_decay=0.01)
 
-        def fused_apply(g, p, s, _ftx=ftx):
-            new_p, new_s = _ftx.fused_apply(
-                g, s, p, sr_key=key if SR else None)
-            return new_p, new_s
-        variants[name] = (fused_apply, ftx.init(params))
+    def fused_apply(g, p, s):
+        return ftx.fused_apply(g, s, p, sr_key=key if SR else None)
+    variants["fused"] = (fused_apply, ftx.init(params))
 
     results = {}
     for name, (fn, st) in variants.items():
@@ -147,17 +139,14 @@ def main():
         "model": f"{MODEL} ({n_elems/1e6:.1f}M params, {n_leaves} leaves)",
         "mode": "master-free bf16 + SR" if SR else "fp32 params",
         "ms_per_apply": results,
-        "per_leaf_vs_chunked": round(
-            results["fused_per_leaf"] / max(fused_ms, 1e-9), 2),
         "optax_vs_fused": round(results["optax"] / max(fused_ms, 1e-9), 2),
         "roofline": {
             "min_bytes_per_apply": min_bytes,
-            "chunked_front_end_bytes": chunk_bytes,
             "fused_achieved_gb_s": round(
-                chunk_bytes / max(fused_ms, 1e-9) / 1e6, 1),
+                min_bytes / max(fused_ms, 1e-9) / 1e6, 1),
             "hbm_peak_gb_s": chip_hbm_gbs(),
             "hbm_bound_fraction": round(
-                chunk_bytes / max(fused_ms, 1e-9) / 1e6 / chip_hbm_gbs(),
+                min_bytes / max(fused_ms, 1e-9) / 1e6 / chip_hbm_gbs(),
                 3),
         },
     }

@@ -23,11 +23,13 @@ Two entry points share the kernel:
   unscale, overflow vote, clip, overflow-skip select, and the
   compute-dtype cast-cache refresh ALL ride inside the fused pass:
 
-      kernel 1 (per chunk): sq-norm partials of the flat grads
+      kernel 1 (per chunk): sq-norm partials of the packed grads
+                            (+ one XLA reduction per in-place leaf)
       scalar carry:         norm = sqrt(psum partials) / scale
                             overflow = !isfinite(norm)   [fp16]
                             coeff = min(1, clip/(norm+1e-6))
-      kernel 2 (per chunk): read g,p,m,v; g = (g*inv)*coeff
+      kernel 2 (per chunk / per in-place leaf):
+                            read g,p,m,v; g = (g*inv)*coeff
                             m',v' Adam update (f32 moments)
                             skip-select (overflow holds the step)
                             write p' (+ optional compute-dtype cast copy,
@@ -37,10 +39,56 @@ Two entry points share the kernel:
   step: no separate norm pass, no full-tree unscale multiply, no
   post-apply jnp.where overflow select, no post-apply cast pass.
 
-Multi-tensor layout (V-interleaved, ZeRO-shard-local)
+The plan: in place or packed
+----------------------------
+
+Where a leaf's moments live, and how the kernel reaches the leaf, is
+decided per float leaf from its SHAPE alone (``update_plan``; no option,
+no model name):
+
+- **In place.** A leaf of at least ``_INPLACE_MIN_ELEMS`` (2**19)
+  elements whose collapsed 2-D view ``[prod(leading dims), last dim]``
+  is a bitcast on the TPU — last dim a multiple of 128 lanes, the row
+  count and the second-to-last dim multiples of the widest sublane tile
+  (32, whatever the dtype) — is updated WHERE IT LIES. Its moments are
+  f32 arrays of the leaf's own shape (``FusedAdamState.leaf_m/leaf_v``),
+  ZeRO-sharded like the leaf's gradient (zero/partition.py's
+  first-divisible-dim rule; stage 3's spec under ZeRO-3). The kernel
+  reads the f32 gradient, the parameter and both moments through the 2-D
+  view with ``input_output_aliases`` on parameter and moments: nothing
+  is copied, concatenated or relaid. The squared norm of these leaves is
+  a plain f32 reduction per leaf (XLA runs it at bandwidth).
+- **Packed.** Every other float leaf (biases, LayerNorm vectors,
+  anything small or off the tiling) goes through ONE flat group buffer
+  per dtype with its own flat moments — the layout below. For the
+  scanned GPT-2 that is 0.6M of gpt2-large's 774M elements; gpt2-xl
+  (width 1600, off the 128-lane tiling) keeps all but one of its
+  matrices there and is no slower than before the plan.
+
+Assembling flat gradient and parameter buffers for the large leaves was
+63-72 ms of gpt2-large's 347 ms step on a v5e around a 28 ms kernel
+(PERF.md, PR 24-26); the plan removes it.
+
+**Start-up rule: one lowered program per leaf geometry.** Pallas lowers a
+kernel to Mosaic while JAX lowers the step — before the compile-cache
+key exists, so on EVERY process start, cached executable or not. Each
+``pallas_call`` site costs 30-50 ms of host work (kernel body traced,
+lowered, serialised; PERF.md, PR 26). ``_update_leaf`` is therefore an
+inner ``jit``: traced and lowered once per distinct (shape, dtype,
+options), called from every leaf of that geometry — six programs for the
+scanned GPT-2's six matrices, not one per layer for an unrolled model.
+The in-place norm needs no kernel at all, and the plan, the state and
+the checkpoint-layout check run no per-leaf device work outside the
+engine's one start-up ``jit``.
+
+The checkpoint layout tag (``fused_moment_layout`` in engine_meta.json)
+is 3 for this state; 2 (every leaf in the flat buffers) and 1
+(end-to-end concatenation) are refused at load.
+
+Packed-group layout (V-interleaved, ZeRO-shard-local)
 -----------------------------------------------------
 
-The pytree's float leaves flatten into contiguous same-dtype buffers.
+The PACKED leaves flatten into contiguous same-dtype buffers.
 PR-1 concatenated leaves end to end, which made every per-device flat
 chunk a FULL-tree buffer under ZeRO sharding (GSPMD gathered the
 dp-sharded moments around the opaque kernel — COMM_AUDIT.json's
@@ -51,30 +99,32 @@ and reshaped to ``[V, r_leaf]``; leaves concatenate along axis 1 into a
 v-th 1/V slice of every leaf, so:
 
 - a contiguous 1/dp range of the flat buffer == ``V/dp`` whole rows ==
-  the dp-shard of every leaf (any dp dividing V);
-- the kernels run under ``shard_map`` over the dp axis on LOCAL rows —
-  the moments are never gathered, each device updates exactly its ZeRO
-  shard, and the updated params leave the region dp-sharded (the
-  engine's replicated out_shardings turn that into the per-leaf ZeRO-2
-  param all-gather);
-- the layout does not depend on dp (``V`` is a constant 8, widened to
-  dp only above 8 devices), so checkpoints stay elastic across dp
-  resizes exactly like PR-1's;
+  the dp-shard of every packed leaf (any dp dividing V);
+- the kernels run under ``shard_map`` over the dp axis on LOCAL rows
+  (and on each in-place leaf's own dp shard) — the moments are never
+  gathered, each device updates exactly its ZeRO shard, and the updated
+  params leave the region dp-sharded (the engine's replicated
+  out_shardings turn that into the per-leaf ZeRO-2 param all-gather);
+- neither layout depends on dp (``V`` is a constant 8, widened to dp
+  only above 8 devices; in-place moments have the leaf's shape), so
+  checkpoints stay elastic across dp resizes exactly like PR-1's;
 - under ZeRO-3 (params THEMSELVES dp-sharded, runtime/zero/stage3.py)
   the apply needs NO new gather: a leaf sharded on its leading dim over
   dp owns contiguous flat ranges, which are exactly whole virtual rows
   (``V/dp`` rows = the d-th 1/dp of every leaf), so the
   ``_flatten_group`` row constraint is a local reshape and the kernels
   consume grad, param AND moments as the same dp shard — verified by
-  COMM_AUDIT.json's zero3 config (zero apply-time collectives). Leaves
-  the stage-3 layer scan shards on a non-leading dim relayout at region
-  entry (still 1/dp per device, never a gather to full).
+  COMM_AUDIT.json's zero3 config (zero apply-time collectives). Packed
+  leaves the stage-3 layer scan shards on a non-leading dim relayout at
+  region entry (still 1/dp per device, never a gather to full);
+  in-place leaves enter by their stage-3 spec and relayout nothing.
 
 The deterministic math is bit-exact with ``optax.adamw`` / the engine's
 coupled-Adam chain: every multiply-add is written in optax's association
 order (see ``tests/test_fused_update.py``). The one-pass norm is the
-same sum-of-squares at a different association (chunk partials instead
-of per-leaf sums), so clip coefficients agree to f32 ulp — the same
+same sum-of-squares at a different association (chunk partials of the
+packed group, one sum per in-place leaf), so clip coefficients agree to
+f32 ulp — the same
 cross-program tolerance class PR-1 documented for FMA contraction.
 """
 from __future__ import annotations
@@ -125,15 +175,19 @@ _V = 8
 
 
 class FusedAdamState(NamedTuple):
-    """Fused optimizer state: one flat f32 moment buffer per dtype group,
-    stored in the V-interleaved layout (see module docstring). ZeRO
-    shardings (zero/partition.py) split the flat axis over dp; any dp
-    dividing V lands on whole virtual rows, so shards are element-aligned
-    with the grads/params the kernel reads and checkpoint shards stay
-    elastic across dp resizes."""
+    """Fused optimizer state. In-place leaves (``update_plan``) keep f32
+    moments of their own shape in ``leaf_m`` / ``leaf_v``, in leaf order,
+    ZeRO-sharded like the leaf's gradient. Every other float leaf shares
+    one flat f32 moment buffer per dtype group, ``m`` / ``v``, in the
+    V-interleaved layout (module docstring): ZeRO shardings
+    (zero/partition.py) split the flat axis over dp, any dp dividing V
+    lands on whole virtual rows. Neither layout depends on dp, so
+    checkpoint shards stay elastic across dp resizes."""
     count: jax.Array                 # int32 scalar, number of updates
     m: Tuple[jax.Array, ...]
     v: Tuple[jax.Array, ...]
+    leaf_m: Tuple[jax.Array, ...] = ()
+    leaf_v: Tuple[jax.Array, ...] = ()
 
 
 class FusedStepOut(NamedTuple):
@@ -180,6 +234,85 @@ def group_nbytes(sizes, shards: int = _V, itemsize: int = 4) -> int:
     """Padded group-buffer bytes (one moment buffer) — the analytic
     footprint tools use."""
     return virtual_shards(shards) * _group_row_len(sizes, shards) * itemsize
+
+
+# --------------------------------------------------------------------- #
+# The plan: which leaves are updated where they lie
+# --------------------------------------------------------------------- #
+# A leaf is updated IN PLACE when its collapsed 2-D view
+# [prod(leading dims), last dim] is a bitcast on the TPU — last dim a
+# whole number of 128-lane vregs, and the rows that collapse into one
+# another (and the row count itself) whole sublane tiles. The tile is
+# taken at its widest (32 rows: 8-bit; bf16 needs 16, f32 8) whatever
+# the leaf's dtype, so the plan — and with it the moments' layout, a
+# checkpoint format — is the same for a model kept in f32 and in bf16
+# (the master-free engine creates its state from an f32 view).
+_ROW_TILE = 32
+# ... and large enough that one more kernel launch is noise: at 2**19
+# elements the update moves 11.5 MB (22 B an element), 14 us at the
+# v5e's 819 GB/s, against a few us of launch and pipeline warm-up. Every
+# GPT-2 matrix is over 1.0M elements (wpe of gpt2-medium: 1024 x 1024),
+# every bias and LayerNorm leaf of the scanned model under 0.2M. What
+# the threshold also buys is START-UP: each distinct in-place geometry
+# is one more pallas_call to trace and lower on every process start.
+_INPLACE_MIN_ELEMS = 1 << 19
+# Block budget of the in-place kernel, in elements: the packed kernel's
+# (1024, 128) block. Views wider than _MAX_COLS are cut in columns too.
+_BLOCK_ELEMS = _R * _W
+_MAX_COLS = 4096
+
+
+def _in_place(shape, dtype) -> bool:
+    if not jnp.issubdtype(dtype, jnp.floating) or len(shape) < 2:
+        return False
+    n = 1
+    for d in shape:
+        n *= int(d)
+    if n < _INPLACE_MIN_ELEMS or shape[-1] % _W:
+        return False
+    return (n // shape[-1]) % _ROW_TILE == 0 and \
+        (len(shape) == 2 or shape[-2] % _ROW_TILE == 0)
+
+
+class UpdatePlan(NamedTuple):
+    """Where each float leaf of a parameter tree is updated — a pure
+    function of the leaves' shapes (``update_plan``)."""
+    inplace: Tuple[int, ...]        # leaf indices updated where they lie
+    packed: Tuple[Tuple[Any, Tuple[int, ...]], ...]   # (dtype, leaf idxs)
+
+
+def update_plan(leaves) -> UpdatePlan:
+    """In place / packed, per float leaf (see ``_in_place``); packed
+    leaves group by dtype as ``_float_groups`` orders them."""
+    inplace = tuple(
+        i for i, leaf in enumerate(leaves)
+        if hasattr(leaf, "dtype") and _in_place(leaf.shape, leaf.dtype))
+    taken = set(inplace)
+    rest = [None if i in taken else leaf for i, leaf in enumerate(leaves)]
+    return UpdatePlan(inplace, tuple(
+        (dt, tuple(idxs)) for dt, idxs in _float_groups(rest)))
+
+
+def plan_summary(params: Any) -> Dict[str, Any]:
+    """What the engine reports at start: leaves and optimizer bytes
+    (parameter + both f32 moments) in place and packed, and the number
+    of distinct Adam kernel programs the step lowers."""
+    leaves = jax.tree_util.tree_leaves(params)
+    plan = update_plan(leaves)
+
+    def nbytes(idxs):
+        return sum(int(leaves[i].size) *
+                   (jnp.dtype(leaves[i].dtype).itemsize + 8) for i in idxs)
+    packed = [i for _, idxs in plan.packed for i in idxs]
+    b_in, b_pk = nbytes(plan.inplace), nbytes(packed)
+    return {
+        "leaves_in_place": len(plan.inplace), "bytes_in_place": b_in,
+        "leaves_packed": len(packed), "bytes_packed": b_pk,
+        "share_in_place": b_in / max(1, b_in + b_pk),
+        "kernel_programs": len(plan.packed) + len(
+            {(tuple(leaves[i].shape), jnp.dtype(leaves[i].dtype))
+             for i in plan.inplace}),
+    }
 
 
 def _flat_1d(x: jax.Array) -> jax.Array:
@@ -271,14 +404,17 @@ def _unflatten_group(buf: jax.Array, like_leaves, idxs,
 
 def leaf_moment_views(state: "FusedAdamState", params: Any,
                       shards: int = _V) -> Tuple[Any, Any]:
-    """Per-leaf views of the fused moment buffers (tests / debugging):
-    returns (m_tree, v_tree) shaped like ``params``' float leaves (None
-    at non-float positions)."""
+    """Per-leaf views of the fused moments (tests / debugging): returns
+    (m_tree, v_tree) shaped like ``params``' float leaves (None at
+    non-float positions), whichever way the plan keeps each leaf's."""
     p_leaves, treedef = jax.tree_util.tree_flatten(params)
     shards = virtual_shards(shards)
+    plan = update_plan(p_leaves)
     m_out: List[Any] = [None] * len(p_leaves)
     v_out: List[Any] = [None] * len(p_leaves)
-    for gi, (dt, idxs) in enumerate(_float_groups(p_leaves)):
+    for k, i in enumerate(plan.inplace):
+        m_out[i], v_out[i] = state.leaf_m[k], state.leaf_v[k]
+    for gi, (dt, idxs) in enumerate(plan.packed):
         Lpad = _group_row_len([p_leaves[i].size for i in idxs], shards)
         m2 = state.m[gi].reshape(shards, Lpad)
         v2 = state.v[gi].reshape(shards, Lpad)
@@ -317,12 +453,13 @@ def _fused_adam_kernel(scal_ref, seed_ref, g_ref, p_ref, m_ref, v_ref,
                        *out_refs, b1: float, b2: float, eps: float,
                        wd: float, coupled: bool, use_inv: bool,
                        use_coeff: bool, one_pass: bool, sr: bool,
-                       cast: bool, out_dtype, cast_dtype):
-    """One chunk of the fused apply.
+                       cast: bool, out_dtype, cast_dtype, ncols: int):
+    """One block of the fused apply (grid: row blocks x column blocks
+    of a 2-D view ``ncols`` wide).
 
     scal_ref (SMEM, f32 [1,8]): [neg_lr, bias_corr1, bias_corr2, coeff,
     inv_scale, skip, 0, 0]; seed_ref (SMEM, int32 [1,2]): [sr seed,
-    global base element index]. Math follows optax's association order
+    base element index]. Math follows optax's association order
     exactly (bit parity on the deterministic path); the fp16 unscale and
     the clip multiply are SEPARATE multiplies, preserving the historical
     ``(g*inv)*coeff`` association of the two-pass engine path."""
@@ -370,7 +507,8 @@ def _fused_adam_kernel(scal_ref, seed_ref, g_ref, p_ref, m_ref, v_ref,
         cols = lax.broadcasted_iota(jnp.uint32, (R, W), 1)
         idx = seed_ref[0, 1].astype(jnp.uint32) + \
             (pl.program_id(0).astype(jnp.uint32) * jnp.uint32(R) + rows) \
-            * jnp.uint32(W) + cols
+            * jnp.uint32(ncols) + \
+            pl.program_id(1).astype(jnp.uint32) * jnp.uint32(W) + cols
         noise = _hash_u32(idx ^ seed_ref[0, 0].astype(jnp.uint32)) \
             & jnp.uint32(0xFFFF)
         bits = lax.bitcast_convert_type(new_p, jnp.uint32)
@@ -385,17 +523,24 @@ def _fused_adam_kernel(scal_ref, seed_ref, g_ref, p_ref, m_ref, v_ref,
         p_out[...] = new_p.astype(out_dtype)
 
 
+def _on_tpu() -> bool:
+    return pltpu is not None and jax.default_backend() == "tpu"
+
+
 def _smem_spec(shape):
-    if pltpu is not None and jax.default_backend() == "tpu":
+    if _on_tpu():
         return pl.BlockSpec(memory_space=pltpu.SMEM)
-    return pl.BlockSpec(shape, lambda i: (0, 0))
+    return pl.BlockSpec(shape, lambda *_: (0, 0))
+
+
+def _block_spec(rb: int, cb: int):
+    kw = dict(memory_space=pltpu.VMEM) if _on_tpu() else {}
+    return pl.BlockSpec((rb, cb), lambda i, j: (i, j), **kw)
 
 
 def _chunk_spec(rb: int):
-    if pltpu is not None and jax.default_backend() == "tpu":
-        return pl.BlockSpec((rb, _W), lambda i: (i, 0),
-                            memory_space=pltpu.VMEM)
-    return pl.BlockSpec((rb, _W), lambda i: (i, 0))
+    kw = dict(memory_space=pltpu.VMEM) if _on_tpu() else {}
+    return pl.BlockSpec((rb, _W), lambda i: (i, 0), **kw)
 
 
 def _block_rows(rows: int, kernel: str = None, runner=None) -> int:
@@ -444,33 +589,40 @@ def _run_sqnorm(gflat: jax.Array, _rb: int = None) -> jax.Array:
 
 
 @jax.named_scope("kernel")
-def _run_group(gflat, pflat, m, v, scalars, seed, *, b1, b2, eps, wd,
-               coupled, use_inv, use_coeff, one_pass, sr, cast,
-               out_dtype, cast_dtype, _rb: int = None):
+def _run_group(gflat, pflat, m, v, scalars, seed, *, _rb: int = None,
+               **static):
     """Run the fused kernel over one flat group buffer (local shard when
-    shard-mapped). Returns (p_new, m_new, v_new, cast_new_or_None)."""
+    shard-mapped); ``static`` are ``_adam_call``'s options. Returns
+    (p_new, m_new, v_new, cast_new_or_None)."""
     rows = gflat.size // _W
 
     def runner(rb_):
-        return _run_group(
-            jnp.zeros(gflat.shape, gflat.dtype),
-            jnp.zeros(pflat.shape, pflat.dtype),
-            jnp.zeros(m.shape, m.dtype), jnp.zeros(v.shape, v.dtype),
-            jnp.zeros(scalars.shape, scalars.dtype),
-            jnp.zeros(seed.shape, seed.dtype),
-            b1=b1, b2=b2, eps=eps, wd=wd, coupled=coupled,
-            use_inv=use_inv, use_coeff=use_coeff, one_pass=one_pass,
-            sr=sr, cast=cast, out_dtype=out_dtype,
-            cast_dtype=cast_dtype, _rb=rb_)
+        zeros = [jnp.zeros(a.shape, a.dtype)
+                 for a in (gflat, pflat, m, v, scalars, seed)]
+        return _run_group(*zeros, _rb=rb_, **static)
 
     rb = _rb or _block_rows(rows, kernel="fused_update_apply",
                             runner=runner)
     shape2 = (rows, _W)
+    outs = _adam_call(
+        gflat.reshape(shape2), pflat.reshape(shape2), m.reshape(shape2),
+        v.reshape(shape2), scalars, seed, rb, _W, **static)
+    outs = tuple(_flat_1d(o) for o in outs)
+    return outs if static["cast"] else outs + (None,)
+
+
+def _adam_call(g2, p2, m2, v2, scalars, seed, rb: int, cb: int, *,
+               cast: bool, out_dtype, cast_dtype, **static):
+    """The ONE ``pallas_call`` of the Adam kernel, over 2-D operands of
+    one shape in blocks of ``(rb, cb)``: the packed group's
+    ``[n/128, 128]`` view and every in-place leaf's collapsed view go
+    through it. A ragged last block (``rb`` not dividing the rows) is
+    masked by Pallas; the kernel is elementwise, so nothing leaks."""
+    shape2 = p2.shape
     kernel = functools.partial(
-        _fused_adam_kernel, b1=b1, b2=b2, eps=eps, wd=wd, coupled=coupled,
-        use_inv=use_inv, use_coeff=use_coeff, one_pass=one_pass, sr=sr,
-        cast=cast, out_dtype=out_dtype, cast_dtype=cast_dtype)
-    out_specs = [_chunk_spec(rb)] * (4 if cast else 3)
+        _fused_adam_kernel, cast=cast, out_dtype=out_dtype,
+        cast_dtype=cast_dtype, ncols=shape2[1], **static)
+    spec = _block_spec(rb, cb)
     out_shape = [
         jax.ShapeDtypeStruct(shape2, out_dtype),
         jax.ShapeDtypeStruct(shape2, jnp.float32),
@@ -478,28 +630,66 @@ def _run_group(gflat, pflat, m, v, scalars, seed, *, b1, b2, eps, wd,
     ]
     if cast:
         out_shape.append(jax.ShapeDtypeStruct(shape2, cast_dtype))
-    outs = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
-        grid=(rows // rb,),
+        grid=(pl.cdiv(shape2[0], rb), pl.cdiv(shape2[1], cb)),
         in_specs=[_smem_spec((1, 8)), _smem_spec((1, 2)),
-                  _chunk_spec(rb), _chunk_spec(rb), _chunk_spec(rb),
-                  _chunk_spec(rb)],
-        out_specs=out_specs,
+                  spec, spec, spec, spec],
+        out_specs=[spec] * len(out_shape),
         out_shape=out_shape,
         # In-place update: p/m/v inputs alias the outputs (same
         # shape+dtype when the param dtype matches; m/v always), so the
         # kernel never holds two copies of the moments in HBM.
         input_output_aliases=(
-            {3: 0, 4: 1, 5: 2} if pflat.dtype == out_dtype
+            {3: 0, 4: 1, 5: 2} if p2.dtype == out_dtype
             else {4: 1, 5: 2}),
         name="_fused_adam_kernel",
         interpret=_interpret(),
-    )(scalars, seed, gflat.reshape(shape2), pflat.reshape(shape2),
-      m.reshape(shape2), v.reshape(shape2))
-    p_new, m_new, v_new = outs[0], outs[1], outs[2]
-    cast_new = outs[3] if cast else None
-    return (_flat_1d(p_new), _flat_1d(m_new), _flat_1d(v_new),
-            None if cast_new is None else _flat_1d(cast_new))
+    )(scalars, seed, g2, p2, m2, v2)
+
+
+def _leaf_blocks(rows: int, cols: int) -> Tuple[int, int]:
+    """(row block, column block) of an in-place leaf's 2-D view: about
+    ``_BLOCK_ELEMS`` elements, whole ``_ROW_TILE`` x 128 tiles, dividing
+    the view where something near the budget does (every shape the plan
+    admits on one device; a dp shard may leave a ragged, masked tail)."""
+    cb = cols
+    if cols > _MAX_COLS and cols % _W == 0:
+        cb = max(c for c in range(_W, _MAX_COLS + 1, _W) if cols % c == 0)
+    target = max(_ROW_TILE, _BLOCK_ELEMS // cb // _ROW_TILE * _ROW_TILE)
+    if rows <= target:
+        return rows, cb             # one block spans the rows
+    rb = target
+    while rb > _ROW_TILE and rows % rb:
+        rb -= _ROW_TILE
+    if rows % rb or 2 * rb < target:
+        rb = target                 # ragged tail
+    return rb, cb
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "b1", "b2", "eps", "wd", "coupled", "use_inv", "use_coeff", "one_pass",
+    "sr", "cast", "out_dtype", "cast_dtype"))
+def _update_leaf(g, p, m, v, scalars, seed, **static):
+    """The Adam kernel over ONE leaf where it lies (its shard, under
+    ``shard_map``): operands enter through the collapsed 2-D view, a
+    bitcast for every leaf the plan admits, and parameter and moments
+    alias their outputs. Returns (p, m, v[, cast]) in the leaf's shape.
+
+    An inner ``jit`` on purpose: JAX traces and lowers it once per
+    distinct (shape, dtype, options) and CALLS it from every leaf of that
+    geometry, so an unrolled model's hundred same-shaped matrices cost
+    one pallas_call's tracing and Mosaic lowering, not a hundred — host
+    work that precedes the compile-cache key and is paid on every
+    process start (docs/tutorials/kernels.md)."""
+    shape = p.shape
+    cols = int(shape[-1])
+    rows = int(p.size) // cols
+    rb, cb = _leaf_blocks(rows, cols)
+    outs = _adam_call(
+        g.reshape(rows, cols), p.reshape(rows, cols), m.reshape(rows, cols),
+        v.reshape(rows, cols), scalars, seed, rb, cb, **static)
+    return tuple(o.reshape(shape) for o in outs)
 
 
 def apply_hbm_bytes(params: Any, *, one_pass: bool = True,
@@ -562,26 +752,39 @@ def apply_hbm_bytes(params: Any, *, one_pass: bool = True,
     return out
 
 
+def _modes(dt, sr_on: bool, cast_dtype) -> Tuple[bool, bool]:
+    """(stochastic-rounding write?, separate compute-dtype cast output?)
+    of a buffer of dtype ``dt`` — one rule for packed groups and in-place
+    leaves. A bf16 SR write (or an equal dtype) IS the compute-dtype
+    value, so no cast output then."""
+    sr = sr_on and dt == jnp.dtype(jnp.bfloat16)
+    return sr, (cast_dtype is not None and not sr and
+                jnp.dtype(cast_dtype) != dt)
+
+
 def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
                b2: float = 0.999, eps: float = 1e-8,
                weight_decay: float = 0.0, adam_w_mode: bool = True,
-               multi_tensor: bool = True, mesh=None,
-               shard_axis: Optional[str] = None
+               mesh=None, shard_axis: Optional[str] = None,
+               leaf_specs: Optional[Callable[[], Any]] = None
                ) -> "FusedGradientTransformation":
     """Build the fused-apply transformation.
 
     ``adam_w_mode=True`` matches ``optax.adamw`` (decoupled decay);
     ``False`` matches the engine's coupled-L2 chain (decay folded into
-    the gradient before the moments). ``multi_tensor=False`` runs one
-    kernel launch per leaf instead of chunked fused buffers — kept for
-    the ablation ladder (``ablate_fused_update.py``), not production.
+    the gradient before the moments).
 
     ``mesh`` + ``shard_axis`` (engine-provided under ZeRO stage >= 1 on
     a pure-dp mesh) run the kernels under ``shard_map`` over the dp
-    axis: every buffer enters as its LOCAL virtual-shard rows, the
-    moments are never gathered, and the norm partials ``psum`` into the
-    global norm. Without them the kernels run on the full buffers (dp=1,
-    or bare transform use).
+    axis: every packed buffer enters as its LOCAL virtual-shard rows and
+    every in-place leaf as its own dp shard, the moments are never
+    gathered, and the norm partials ``psum`` into the global norm.
+    ``leaf_specs()`` (called when a step is traced) gives the parameter
+    tree's PartitionSpecs where they are not the first-divisible-dim
+    rule (ZeRO-3's, whose scanned leaves keep the layer axis whole);
+    in-place leaves and their moments enter and leave the region by
+    them. Without a mesh the kernels run on the full buffers (dp=1, or
+    bare transform use).
 
     Returned object is optax-compatible (``init``/``update``) and
     carries two fused entry points: ``fused_apply`` (PR-1 API: caller
@@ -605,27 +808,26 @@ def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
     def _leaves(params):
         return jax.tree_util.tree_flatten(params)
 
+    def _group_plan(p_leaves, plan):
+        """[(group idx, dtype, leaf idxs, sizes, Lpad)] of the packed
+        groups."""
+        out = []
+        for gi, (dt, idxs) in enumerate(plan.packed):
+            sizes = [int(p_leaves[i].size) for i in idxs]
+            out.append((gi, dt, idxs, sizes, _group_row_len(sizes, shards)))
+        return out
+
     def init_fn(params):
         leaves, _ = _leaves(params)
-        groups = _float_groups(leaves)
-        bufs = []
-        for _, idxs in groups:
-            sizes = [int(leaves[i].size) for i in idxs]
-            if multi_tensor:
-                Lpad = _group_row_len(sizes, shards)
-                bufs.append(jnp.zeros((shards * Lpad,), jnp.float32))
-            else:
-                # per-leaf mode: one moment buffer per leaf, each padded
-                # to its own whole-row quantum (tiny leaves burn a full
-                # quantum — the launch-amortization problem multi-tensor
-                # mode fixes).
-                bufs.append(tuple(
-                    jnp.zeros((shards * _group_row_len([n], shards),),
-                              jnp.float32) for n in sizes))
-        return FusedAdamState(count=jnp.zeros([], jnp.int32),
-                              m=tuple(bufs),
-                              v=jax.tree_util.tree_map(jnp.zeros_like,
-                                                       tuple(bufs)))
+        plan = update_plan(leaves)
+        bufs = tuple(jnp.zeros((shards * Lpad,), jnp.float32)
+                     for _, _, _, _, Lpad in _group_plan(leaves, plan))
+        lm = tuple(jnp.zeros(leaves[i].shape, jnp.float32)
+                   for i in plan.inplace)
+        return FusedAdamState(
+            count=jnp.zeros([], jnp.int32), m=bufs,
+            v=jax.tree_util.tree_map(jnp.zeros_like, bufs), leaf_m=lm,
+            leaf_v=jax.tree_util.tree_map(jnp.zeros_like, lm))
 
     def _base_scalars(count, inv_scale):
         """The scalar carry every path shares: [neg_lr, bc1, bc2, inv].
@@ -642,29 +844,28 @@ def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
             else jnp.asarray(inv_scale, jnp.float32)
         return jnp.stack([neg_lr, bc1, bc2, inv])
 
-    def _group_plan(p_leaves):
-        """[(group idx, dtype, leaf idxs, sizes, Lpad)] for the tree."""
-        plan = []
-        for gi, (dt, idxs) in enumerate(_float_groups(p_leaves)):
-            sizes = [int(p_leaves[i].size) for i in idxs]
-            plan.append((gi, dt, idxs, sizes,
-                         _group_row_len(sizes, shards)))
-        return plan
-
     def _kernel_region(base, seed0, pre_coeff, extra_skip, gbufs, pbufs,
-                       ms, vs, *, plan, clip, fp16, use_inv, one_pass,
-                       compute_norm, has_pre_coeff, use_extra_skip,
-                       sr_groups, cast_groups, cast_dtype, local):
-        """Norm + apply kernels over (possibly shard-local) group
-        buffers. Runs inside shard_map when ``local``; all inputs are
-        then the device's own virtual rows. ``cast_groups`` marks which
-        groups emit a compute-dtype cast output (static, so the cast
-        tuple's pytree shape is fixed)."""
+                       ms, vs, lgs, lps, lms, lvs, *, groups, clip, fp16,
+                       use_inv, one_pass, compute_norm, has_pre_coeff,
+                       use_extra_skip, group_modes, leaf_modes,
+                       cast_dtype, local):
+        """Norm + apply kernels over (possibly shard-local) packed group
+        buffers and in-place leaves. Runs inside shard_map when
+        ``local``; all inputs are then the device's own virtual rows /
+        leaf shards. ``group_modes`` / ``leaf_modes`` are static
+        ``(sr, cast)`` per group and ``(dtype, sr, cast, sharded)`` per
+        in-place leaf, so the cast tuples' pytree shape is fixed."""
         axis = shard_axis if local else None
         if compute_norm:
             nsq = jnp.float32(0.0)
             for g in gbufs:
                 nsq = nsq + _run_sqnorm(_flat_1d(g))
+            with jax.named_scope("norm"):
+                # In-place leaves: a plain f32 reduction per leaf, which
+                # XLA runs at bandwidth — no second pallas_call per
+                # geometry to trace and lower at start-up.
+                for g in lgs:
+                    nsq = nsq + jnp.sum(g * g)
             if axis is not None:
                 nsq = lax.psum(nsq, axis)
             # norm of the UNSCALED grads: ||g*inv|| == inv * ||g||.
@@ -696,31 +897,72 @@ def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
         scalars = jnp.stack(
             [base[0], base[1], base[2], coeff, base[3], skip,
              jnp.float32(0.0), jnp.float32(0.0)])[None]
+        static = dict(b1=b1, b2=b2, eps=eps, wd=weight_decay,
+                      coupled=not adam_w_mode, use_inv=use_inv,
+                      use_coeff=use_coeff, one_pass=one_pass,
+                      cast_dtype=cast_dtype)
+
+        def first_index(nloc: int, sharded: bool = True):
+            """This device's first element in the stochastic-rounding
+            counter; replicas of an unsharded leaf must round alike. (A
+            leaf sharded on a non-leading dim counts per shard, so its
+            noise stream — never its distribution — depends on dp.)"""
+            if axis is None or not sharded:
+                return jnp.int32(0)
+            return lax.axis_index(axis).astype(jnp.int32) * jnp.int32(nloc)
+
         new_p, new_m, new_v, new_cast = [], [], [], []
-        for k, (gi, dt, idxs, sizes, Lpad) in enumerate(plan):
-            sr = sr_groups[k]
-            nloc = int(gbufs[k].size)
-            if axis is not None:
-                off = lax.axis_index(axis).astype(jnp.int32) * \
-                    jnp.int32(nloc)
-            else:
-                off = jnp.int32(0)
-            seed = jnp.stack([seed0 + jnp.int32(gi), off])[None]
+        for k, (gi, dt, idxs, sizes, Lpad) in enumerate(groups):
+            sr, cast = group_modes[k]
+            seed = jnp.stack([seed0 + jnp.int32(gi),
+                              first_index(int(gbufs[k].size))])[None]
             pf, mn, vn, cf = _run_group(
                 _flat_1d(gbufs[k]), _flat_1d(pbufs[k]),
                 _flat_1d(ms[k]), _flat_1d(vs[k]), scalars, seed,
-                b1=b1, b2=b2, eps=eps, wd=weight_decay,
-                coupled=not adam_w_mode, use_inv=use_inv,
-                use_coeff=use_coeff, one_pass=one_pass, sr=sr,
-                cast=cast_groups[k], out_dtype=dt, cast_dtype=cast_dtype)
+                sr=sr, cast=cast, out_dtype=dt, **static)
             shape = gbufs[k].shape
             new_p.append(pf.reshape(shape))
             new_m.append(mn.reshape(shape))
             new_v.append(vn.reshape(shape))
-            if cast_groups[k]:
+            if cast:
                 new_cast.append(cf.reshape(shape))
+        leaf_p, leaf_m, leaf_v, leaf_cast = [], [], [], []
+        with jax.named_scope("kernel"):
+            for k, (dt, sr, cast, sharded) in enumerate(leaf_modes):
+                # The counter hash mixes seed and element index by XOR:
+                # a seed per leaf, itself hashed, keeps two leaves' noise
+                # streams from being shifts of one another.
+                seed = jnp.stack([
+                    _hash_u32((seed0 + jnp.int32(len(groups) + k))
+                              .astype(jnp.uint32)).astype(jnp.int32),
+                    first_index(int(lps[k].size), sharded)])[None]
+                outs = _update_leaf(lgs[k], lps[k], lms[k], lvs[k], scalars,
+                                    seed, sr=sr, cast=cast, out_dtype=dt,
+                                    **static)
+                leaf_p.append(outs[0])
+                leaf_m.append(outs[1])
+                leaf_v.append(outs[2])
+                if cast:
+                    leaf_cast.append(outs[3])
         return (tuple(new_p), tuple(new_m), tuple(new_v),
-                tuple(new_cast), grad_norm, overflow)
+                tuple(new_cast), tuple(leaf_p), tuple(leaf_m),
+                tuple(leaf_v), tuple(leaf_cast), grad_norm, overflow)
+
+    def _inplace_specs(p_leaves, treedef, plan):
+        """(PartitionSpec, sharded over dp?) of each in-place leaf under
+        ``shard_map``: the engine's (``leaf_specs``) or the
+        first-divisible-dim rule its gradient and moment shardings
+        follow (zero/partition.py)."""
+        from ..runtime.zero.partition import _leaf_spec, spec_dp_dim
+        tree = leaf_specs() if leaf_specs is not None else None
+        if tree is not None:
+            flat = treedef.flatten_up_to(tree)
+            specs = [flat[i] for i in plan.inplace]
+        else:
+            specs = [_leaf_spec(p_leaves[i].shape, dp, shard_axis)
+                     for i in plan.inplace]
+        return [(sp, spec_dp_dim(sp, shard_axis) is not None)
+                for sp in specs]
 
     def _apply_impl(grads, state, params, *, pre_coeff=None,
                     inv_scale=None, clip=0.0, fp16=False,
@@ -728,19 +970,17 @@ def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
                     sr_key=None, cast_dtype=None):
         if params is None:
             raise ValueError("fused_adam requires params")
-        if not multi_tensor:
-            return _apply_per_leaf(grads, state, params,
-                                   pre_coeff=pre_coeff, sr_key=sr_key)
         p_leaves, treedef = _leaves(params)
         g_leaves = treedef.flatten_up_to(grads)
-        plan = _group_plan(p_leaves)
+        plan = update_plan(p_leaves)
+        groups = _group_plan(p_leaves, plan)
         base = _base_scalars(state.count, inv_scale)
         seed0 = jax.random.bits(sr_key, (), jnp.uint32).astype(jnp.int32) \
             if sr_key is not None else jnp.zeros((), jnp.int32)
         constrain = _row_sharding() if use_shard_map else None
         gbufs, pbufs, ms, vs = [], [], [], []
-        sr_groups, cast_groups = [], []
-        for gi, dt, idxs, sizes, Lpad in plan:
+        group_modes = []
+        for gi, dt, idxs, sizes, Lpad in groups:
             # Grads flatten in f32, NOT the param dtype: master-free
             # engines hand in f32-accumulated grads over bf16 params,
             # and truncating them here would defeat the kernel's
@@ -757,57 +997,68 @@ def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
                     v2.reshape(shards, Lpad), constrain)
             ms.append(m2)
             vs.append(v2)
-            sr = sr_key is not None and dt == jnp.dtype(jnp.bfloat16)
-            sr_groups.append(sr)
-            cast_groups.append(cast_dtype is not None and not sr and
-                               jnp.dtype(cast_dtype) != dt)
+            group_modes.append(_modes(dt, sr_key is not None, cast_dtype))
+        # In-place leaves enter as they are: the f32 gradient, the
+        # parameter and both moments, no assembly.
+        lgs = tuple(g_leaves[i].astype(jnp.float32) for i in plan.inplace)
+        lps = tuple(p_leaves[i] for i in plan.inplace)
+        specs = _inplace_specs(p_leaves, treedef, plan) if use_shard_map \
+            else [(None, False)] * len(plan.inplace)
+        leaf_modes = tuple(
+            (jnp.dtype(p.dtype),) +
+            _modes(jnp.dtype(p.dtype), sr_key is not None, cast_dtype) +
+            (sharded,) for p, (_, sharded) in zip(lps, specs))
         pre_coeff_arr = jnp.asarray(
             1.0 if pre_coeff is None else pre_coeff, jnp.float32)
         extra_skip_arr = jnp.asarray(
             False if extra_skip is None else extra_skip)
         region = functools.partial(
-            _kernel_region, plan=plan, clip=clip, fp16=fp16,
+            _kernel_region, groups=groups, clip=clip, fp16=fp16,
             use_inv=inv_scale is not None, one_pass=one_pass,
             compute_norm=compute_norm,
             has_pre_coeff=pre_coeff is not None,
             use_extra_skip=extra_skip is not None,
-            sr_groups=tuple(sr_groups), cast_groups=tuple(cast_groups),
+            group_modes=tuple(group_modes), leaf_modes=leaf_modes,
             cast_dtype=cast_dtype, local=use_shard_map)
         if use_shard_map:
             from jax.sharding import PartitionSpec as P
             from ..parallel.comm import shard_map
             row = P(shard_axis, None)
-            nbuf = len(plan)
-            ncast = sum(1 for c in cast_groups if c)
+            nbuf = len(groups)
+            ncast = sum(1 for _, c in group_modes if c)
+            lsp = tuple(sp for sp, _ in specs)
+            lcast = tuple(sp for sp, md in zip(lsp, leaf_modes) if md[2])
             fn = shard_map(
                 region, mesh=mesh,
                 in_specs=(P(), P(), P(), P(),
                           (row,) * nbuf, (row,) * nbuf,
-                          (row,) * nbuf, (row,) * nbuf),
+                          (row,) * nbuf, (row,) * nbuf,
+                          lsp, lsp, lsp, lsp),
                 out_specs=((row,) * nbuf, (row,) * nbuf, (row,) * nbuf,
-                           (row,) * ncast, P(), P()),
+                           (row,) * ncast, lsp, lsp, lsp, lcast,
+                           P(), P()),
                 # Manual over EVERY mesh axis (the others are size 1 —
                 # the engine only takes this path on a pure-dp mesh): a
                 # Mosaic kernel under a partly-auto shard_map is refused
                 # ("cannot be automatically partitioned").
                 axis_names=set(mesh.axis_names), check_vma=False)
-            out = fn(base, seed0, pre_coeff_arr, extra_skip_arr,
-                     tuple(gbufs), tuple(pbufs), tuple(ms), tuple(vs))
         else:
-            out = region(base, seed0, pre_coeff_arr, extra_skip_arr,
-                         tuple(gbufs), tuple(pbufs), tuple(ms),
-                         tuple(vs))
-        new_pb, new_mb, new_vb, new_cb, grad_norm, overflow = out
+            fn = region
+        (new_pb, new_mb, new_vb, new_cb, leaf_p, leaf_m, leaf_v, leaf_c,
+         grad_norm, overflow) = fn(
+            base, seed0, pre_coeff_arr, extra_skip_arr, tuple(gbufs),
+            tuple(pbufs), tuple(ms), tuple(vs), lgs, lps,
+            tuple(state.leaf_m), tuple(state.leaf_v))
 
         new_leaves = list(p_leaves)
         cast_leaves = list(p_leaves) if cast_dtype is not None else None
         ci = 0
-        for k, (gi, dt, idxs, sizes, Lpad) in enumerate(plan):
+        for k, (gi, dt, idxs, sizes, Lpad) in enumerate(groups):
             for i, a in _unflatten_group(new_pb[k], p_leaves, idxs,
                                          shards).items():
                 new_leaves[i] = a
             if cast_leaves is not None:
-                if cast_groups[k]:
+                if group_modes[k][1]:
                     src = new_cb[ci]
                     ci += 1
                     for i, a in _unflatten_group(src, p_leaves, idxs,
@@ -816,9 +1067,17 @@ def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
                 else:
                     # Same dtype (or SR bf16 write): the param output IS
                     # the compute-dtype value — alias, don't copy.
-                    for i, a in _unflatten_group(new_pb[k], p_leaves,
-                                                 idxs, shards).items():
-                        cast_leaves[i] = a
+                    for i in idxs:
+                        cast_leaves[i] = new_leaves[i]
+        ci = 0
+        for k, i in enumerate(plan.inplace):
+            new_leaves[i] = leaf_p[k]
+            if cast_leaves is not None:
+                if leaf_modes[k][2]:
+                    cast_leaves[i] = leaf_c[ci]
+                    ci += 1
+                else:
+                    cast_leaves[i] = leaf_p[k]
         if cast_leaves is not None:
             # Non-float leaves mirror _cast_floats: passed through as-is.
             cast_params = jax.tree_util.tree_unflatten(
@@ -834,53 +1093,9 @@ def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
         new_state = FusedAdamState(
             count=count_inc,
             m=tuple(b.reshape(-1) for b in new_mb),
-            v=tuple(b.reshape(-1) for b in new_vb))
+            v=tuple(b.reshape(-1) for b in new_vb),
+            leaf_m=leaf_m, leaf_v=leaf_v)
         return new_params, new_state, cast_params, grad_norm, overflow
-
-    def _apply_per_leaf(grads, state, params, *, pre_coeff=None,
-                       sr_key=None):
-        """Ablation mode: one kernel launch per leaf."""
-        p_leaves, treedef = _leaves(params)
-        g_leaves = treedef.flatten_up_to(grads)
-        base = _base_scalars(state.count, None)
-        seed0 = jax.random.bits(sr_key, (), jnp.uint32).astype(jnp.int32) \
-            if sr_key is not None else jnp.zeros((), jnp.int32)
-        coeff = jnp.asarray(1.0 if pre_coeff is None else pre_coeff,
-                            jnp.float32)
-        scalars = jnp.stack(
-            [base[0], base[1], base[2], coeff, base[3],
-             jnp.float32(0.0), jnp.float32(0.0), jnp.float32(0.0)])[None]
-        new_leaves = list(p_leaves)
-        new_m, new_v = [], []
-        for gi, (dt, idxs) in enumerate(_float_groups(p_leaves)):
-            sr = sr_key is not None and dt == jnp.dtype(jnp.bfloat16)
-            ms, vs = [], []
-            for j, i in enumerate(idxs):
-                n = int(p_leaves[i].size)
-                Lpad = _group_row_len([n], shards)
-                seed = jnp.stack([seed0 + jnp.int32(gi),
-                                  jnp.int32(0)])[None]
-                gf = _flatten_group(g_leaves, [i], jnp.float32, shards,
-                                    Lpad)
-                pf = _flatten_group(p_leaves, [i], dt, shards, Lpad)
-                pn, mn, vn, _ = _run_group(
-                    _flat_1d(gf), _flat_1d(pf), state.m[gi][j],
-                    state.v[gi][j], scalars, seed, b1=b1, b2=b2,
-                    eps=eps, wd=weight_decay, coupled=not adam_w_mode,
-                    use_inv=False, use_coeff=pre_coeff is not None,
-                    one_pass=False, sr=sr, cast=False, out_dtype=dt,
-                    cast_dtype=None)
-                new_leaves[i] = _unflatten_group(
-                    pn.reshape(shards, Lpad), p_leaves, [i], shards)[i]
-                ms.append(mn)
-                vs.append(vn)
-            new_m.append(tuple(ms))
-            new_v.append(tuple(vs))
-        new_params = jax.tree_util.tree_unflatten(treedef, new_leaves)
-        return new_params, FusedAdamState(count=state.count + 1,
-                                          m=tuple(new_m),
-                                          v=tuple(new_v)), None, \
-            jnp.asarray(-1.0, jnp.float32), jnp.asarray(False)
 
     def _apply(grads, state, params, clip_coeff=None, sr_key=None):
         """PR-1 two-pass API: the caller resolved clip/overflow."""
@@ -916,14 +1131,9 @@ def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
             new_params, params)
         return deltas, new_state
 
-    # Per-leaf ablation mode has no one-pass story (it ignores the
-    # norm/clip/overflow/cast machinery) — expose fused_step=None so the
-    # engine falls back to the two-pass apply instead of silently
-    # dropping clipping.
     return FusedGradientTransformation(init=init_fn, update=update_fn,
                                        fused_apply=_apply,
-                                       fused_step=_step if multi_tensor
-                                       else None)
+                                       fused_step=_step)
 
 
 class FusedGradientTransformation(NamedTuple):

@@ -61,14 +61,15 @@ def _scale_by_clamped_trust_ratio(min_coeff: float, max_coeff: float):
 
 def build_optimizer(name: str, params: Dict[str, Any],
                     schedule_fn: ScheduleOrFloat = None, mesh=None,
-                    shard_axis=None) -> optax.GradientTransformation:
+                    shard_axis=None, leaf_specs=None
+                    ) -> optax.GradientTransformation:
     """Build an optax transformation from a ds_config optimizer section.
 
     ``schedule_fn`` (step -> lr) overrides the static ``lr`` param, matching
     how the reference's scheduler mutates param_group lr each step.
-    ``mesh``/``shard_axis`` (engine-provided under ZeRO on a pure-dp mesh)
-    make the fused apply run shard-local over the dp axis; ignored by the
-    per-leaf optax chains (their leaves shard declaratively).
+    ``mesh``/``shard_axis``/``leaf_specs`` (engine-provided under ZeRO on a
+    pure-dp mesh) make the fused apply run shard-local over the dp axis;
+    ignored by the per-leaf optax chains (their leaves shard declaratively).
     """
     name = name.lower()
     lr, betas, eps, weight_decay = _common(params)
@@ -88,7 +89,7 @@ def build_optimizer(name: str, params: Dict[str, Any],
             return fused_adam(learning_rate, b1=betas[0], b2=betas[1],
                               eps=eps, weight_decay=weight_decay,
                               adam_w_mode=adam_w_mode, mesh=mesh,
-                              shard_axis=shard_axis)
+                              shard_axis=shard_axis, leaf_specs=leaf_specs)
         if adam_w_mode:
             return optax.adamw(learning_rate, b1=betas[0], b2=betas[1], eps=eps,
                                weight_decay=weight_decay)

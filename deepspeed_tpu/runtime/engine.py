@@ -73,6 +73,8 @@ MODEL_FILE = "mp_rank_00_model_states.msgpack"
 MODEL_FILE_FMT = "mp_rank_{:02d}_model_states.msgpack"
 OPTIM_FILE_FMT = "zero_pp_rank_0_mp_rank_00_optim_states.msgpack"
 OPTIM_SHARD_FMT = "zero_pp_rank_{}_mp_rank_00_optim_states.msgpack"
+# engine_meta.json's fused_moment_layout (save_checkpoint says which is which).
+FUSED_MOMENT_LAYOUT = 3
 
 
 def _spec_axis(sharding, axis_name: str):
@@ -776,6 +778,20 @@ class DeepSpeedEngine:
                 "async": bool(ckcfg.async_save),
                 "snapshot_every": self._ckpt_every})
 
+        if type(self.state.opt_state).__name__ == "FusedAdamState":
+            # How often the in-place mechanism engages, from shapes only.
+            # Last in start-up: an event writes the stream's meta record,
+            # which everything above has been adding to.
+            from ..ops.fused_update import plan_summary
+            plan = plan_summary(self.state.params)
+            log_dist(
+                "fused optimizer plan: {leaves_in_place} leaves "
+                "({bytes_in_place:,} B, {pct:.2f}% of optimizer bytes) "
+                "updated in place, {leaves_packed} leaves "
+                "({bytes_packed:,} B) packed, {kernel_programs} Adam "
+                "kernel programs".format(
+                    pct=100.0 * plan["share_in_place"], **plan), ranks=[0])
+            self.telemetry.event("fused_update_plan", plan)
         log_dist(f"DeepSpeedEngine initialized: dp={self.dp_size}, "
                  f"dtype={self.compute_dtype.__name__}, "
                  f"zero_stage={self.zero_optimization_stage()}", ranks=[0])
@@ -925,7 +941,17 @@ class DeepSpeedEngine:
         # its ZeRO shard). Meshes with live pipe/seq/model axes keep the
         # plain lowering (partial-auto shard_map is outside this jax's
         # capability envelope — tests/capability.py).
-        mesh_kw = dict(mesh=self.mesh, shard_axis=DP_AXIS) \
+        # In-place leaves enter the region by their ZeRO spec: stage 3's
+        # (made after the optimizer, hence the late lookup) or, where
+        # there is none, the first-divisible-dim rule. Weakref, not a
+        # bound closure: self.tx would hold the engine in a cycle and
+        # `del engine` would leave its device state to the garbage
+        # collector's next pass.
+        import weakref
+        _engine_ref = weakref.ref(self)
+        mesh_kw = dict(mesh=self.mesh, shard_axis=DP_AXIS,
+                       leaf_specs=lambda: getattr(
+                           _engine_ref(), "_stage3_specs", None)) \
             if self._fused_shard_local() else {}
         return build_optimizer(name, dict(self.config.optimizer_params or {}),
                                self._schedule_fn, **mesh_kw)
@@ -3890,12 +3916,15 @@ class DeepSpeedEngine:
         }
         if type(getattr(self.state, "opt_state", None)).__name__ == \
                 "FusedAdamState":
-            # Moment-buffer layout version: 2 = V-interleaved shard-local
-            # rows (ISSUE 8). Pre-v2 checkpoints stored end-to-end leaf
-            # concatenation — same flat dtype, sometimes the same padded
-            # SIZE, so a silent restore would scramble moments across
-            # leaves; the load path refuses them instead.
-            meta["fused_moment_layout"] = 2
+            # Moment layout version (FUSED_MOMENT_LAYOUT): 3 = in-place
+            # leaves keep moments of their own shape, the rest share
+            # V-interleaved flat buffers (ops/fused_update.update_plan);
+            # 2 = every leaf in the V-interleaved buffers (ISSUE 8);
+            # 1 = end-to-end leaf concatenation. Flat sizes can coincide
+            # between versions, so a silent restore would scramble
+            # moments across leaves; the load path refuses another
+            # version instead.
+            meta["fused_moment_layout"] = FUSED_MOMENT_LAYOUT
         if self.lr_scheduler is not None and \
                 hasattr(self.lr_scheduler, "state_dict"):
             meta["lr_scheduler"] = self.lr_scheduler.state_dict()
@@ -4149,18 +4178,22 @@ class DeepSpeedEngine:
                                                        dcn_error=None))
         if load_optimizer_states and \
                 type(host_state.opt_state).__name__ == "FusedAdamState" \
-                and int(meta.get("fused_moment_layout", 1)) != 2:
-            # The fused moment buffers changed layout (end-to-end leaf
-            # concatenation -> V-interleaved rows, ISSUE 8). The flat
-            # sizes can coincide, so a structural restore would SILENTLY
+                and int(meta.get("fused_moment_layout", 1)) != \
+                FUSED_MOMENT_LAYOUT:
+            # The fused moments changed layout twice (end-to-end leaf
+            # concatenation -> V-interleaved rows, ISSUE 8 -> in-place
+            # leaves out of the flat buffers, ISSUE 26). The flat sizes
+            # can coincide, so a structural restore would SILENTLY
             # scramble Adam moments across leaves — refuse loudly,
             # BEFORE any engine state (params, counters) is touched so a
             # caller catching the error keeps a consistent engine.
             raise ValueError(
-                f"checkpoint {path} stores fused optimizer moments in the "
-                "pre-ISSUE-8 flat layout (no fused_moment_layout=2 marker "
-                "in engine_meta.json) which is incompatible with the "
-                "V-interleaved buffers this engine runs; load with "
+                f"checkpoint {path} stores fused optimizer moments in "
+                f"layout {int(meta.get('fused_moment_layout', 1))} "
+                f"(fused_moment_layout in engine_meta.json; none = 1), "
+                f"incompatible with the layout {FUSED_MOMENT_LAYOUT} this "
+                "engine runs (large leaves keep moments of their own "
+                "shape, ops/fused_update.py); load with "
                 "load_optimizer_states=False (params restore fine, "
                 "moments re-initialize) or re-save from the writing "
                 "version")

@@ -207,7 +207,7 @@ def stage3_state_shardings(opt_state: Any, mesh: Mesh, axis_name: str,
     moment), and non-param-structured leaves (the fused optimizer's flat
     moment buffers) fall back to the plain ``_leaf_spec`` dp rule —
     their V-interleaved rows stay dp-sharded exactly as under stage
-    1/2."""
+    1/2. The fused optimizer's per-leaf moments mirror their leaf."""
     axis_size = int(mesh.shape[axis_name])
     bases = base_spec_leaves(opt_state, params, stage3_specs,
                              default=_NO_BASE)
@@ -221,7 +221,18 @@ def stage3_state_shardings(opt_state: Any, mesh: Mesh, axis_name: str,
         else:
             out.append(NamedSharding(
                 mesh, _leaf_spec(leaf.shape, axis_size, axis_name)))
-    return jax.tree_util.tree_unflatten(treedef, out)
+    shardings = jax.tree_util.tree_unflatten(treedef, out)
+    if getattr(opt_state, "leaf_m", None):
+        # The fused optimizer's in-place leaves: moments of the leaf's
+        # own shape, so they take the leaf's own stage-3 spec (a scanned
+        # leaf's is NOT the plain rule's).
+        from ...ops.fused_update import update_plan
+        p_leaves, p_def = jax.tree_util.tree_flatten(params)
+        specs = p_def.flatten_up_to(stage3_specs)
+        own = tuple(NamedSharding(mesh, specs[i])
+                    for i in update_plan(p_leaves).inplace)
+        shardings = shardings._replace(leaf_m=own, leaf_v=own)
+    return shardings
 
 
 def spec_dp_dim(spec: P, axis_name: str) -> Optional[int]:

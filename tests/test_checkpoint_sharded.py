@@ -200,14 +200,14 @@ def test_legacy_single_file_checkpoint_still_loads(tmp_path):
             "hysteresis": np.asarray(host.hysteresis),
             "skipped": np.asarray(host.skipped_steps)}))
     with open(path / "engine_meta.json", "w") as f:
-        # fused_moment_layout=2: the blob above snapshots the CURRENT
-        # engine's (V-interleaved) moment buffers — the legacy part
-        # under test is the single-blob FILE layout, not the moment
-        # layout (a truly pre-interleave moment blob is refused; see
-        # test_fused_update.test_pre_interleave_checkpoint_refused).
+        # fused_moment_layout=3: the blob above snapshots the CURRENT
+        # engine's moment layout — the legacy part under test is the
+        # single-blob FILE layout, not the moment layout (a moment blob
+        # of an older layout is refused; see
+        # test_fused_update.test_older_layout_checkpoint_refused).
         json.dump({"global_steps": 1, "global_samples": 16,
                    "skipped_steps": 0, "dp_world_size": 2,
-                   "fused_moment_layout": 2,
+                   "fused_moment_layout": 3,
                    "client_state": {}}, f)
     eng2 = _engine(dp=2, seed=3)
     p, _ = eng2.load_checkpoint(str(tmp_path), tag="old")

@@ -23,6 +23,7 @@ import numpy as np
 import optax
 import pytest
 
+from deepspeed_tpu.ops import fused_update
 from deepspeed_tpu.ops.fused_update import (fused_adam, FusedAdamState,
                                             leaf_moment_views)
 from deepspeed_tpu.runtime.engine import DeepSpeedEngine
@@ -32,12 +33,30 @@ B1, B2, EPS, WD = 0.9, 0.999, 1e-8, 0.01
 
 
 def _tree(seed=0, dtype=np.float32):
+    """Off-tile leaves (always packed) plus two the plan can update in
+    place — a 2-D matrix and a stacked 3-D one — once ``layout`` lowers
+    the size threshold to them."""
     r = np.random.default_rng(seed)
     return {
         "w": jnp.asarray(r.standard_normal((37, 5)).astype(dtype)),
         "big": jnp.asarray(r.standard_normal(140001).astype(dtype)),
         "b": jnp.asarray(r.standard_normal(()).astype(dtype)),
+        "mat": jnp.asarray(r.standard_normal((64, 256)).astype(dtype)),
+        "stack": jnp.asarray(r.standard_normal((3, 32, 128)).astype(dtype)),
     }
+
+
+@pytest.fixture(params=["packed", "inplace"])
+def layout(request, monkeypatch):
+    """Every transform-level test runs twice: all leaves through the
+    packed group buffer (the default threshold is far above this tree),
+    and with ``mat`` and ``stack`` updated where they lie."""
+    if request.param == "inplace":
+        monkeypatch.setattr(fused_update, "_INPLACE_MIN_ELEMS", 1 << 12)
+    n_inplace = len(fused_update.update_plan(
+        jax.tree_util.tree_leaves(_tree())).inplace)
+    assert n_inplace == (2 if request.param == "inplace" else 0)
+    return request.param
 
 
 def _grads(i, like):
@@ -66,6 +85,7 @@ def _assert_moments_bitexact(ref_state, fs, params, step=0):
             err_msg=f"second moment diverged at step {step} leaf {k}")
 
 
+@pytest.mark.usefixtures("layout")
 class TestTransformParity:
     def test_adamw_moments_bitexact_params_ulp(self):
         params = _tree()
@@ -141,19 +161,6 @@ class TestTransformParity:
                                        np.asarray(direct[k]),
                                        rtol=1e-6, atol=1e-7)
 
-    def test_per_leaf_mode_matches_chunked(self):
-        params = _tree(6)
-        chunked = fused_adam(_sched, B1, B2, EPS, WD)
-        per_leaf = fused_adam(_sched, B1, B2, EPS, WD, multi_tensor=False)
-        cs, ps = chunked.init(params), per_leaf.init(params)
-        g = _grads(0, params)
-        p_c, _ = jax.jit(chunked.fused_apply)(g, cs, params)
-        p_l, _ = jax.jit(per_leaf.fused_apply)(g, ps, params)
-        for k in params:
-            np.testing.assert_allclose(np.asarray(p_c[k]),
-                                       np.asarray(p_l[k]),
-                                       rtol=1e-6, atol=1e-7)
-
     def test_bf16_params_keep_f32_grads(self):
         """Master-free regression: the front end must flatten grads in f32
         — the engine accumulates them in f32 over bf16 params, and a cast
@@ -204,6 +211,7 @@ class TestTransformParity:
         assert fs_sr.m[0].dtype == jnp.float32
 
 
+@pytest.mark.usefixtures("layout")
 class TestOnePassStep:
     """fused_step: norm + clip + overflow + cast all inside the single
     HBM pass, vs the historical two-pass sequencing."""
@@ -315,10 +323,6 @@ class TestOnePassStep:
             np.testing.assert_array_equal(np.asarray(out.params[k]),
                                           np.asarray(p_ref[k]))
 
-    def test_per_leaf_mode_has_no_one_pass(self):
-        fus = fused_adam(_sched, B1, B2, EPS, WD, multi_tensor=False)
-        assert fus.fused_step is None
-
 
 # ------------------------------------------------------------------ #
 # Engine tier — 8-device CPU mesh, ZeRO-2
@@ -420,20 +424,26 @@ def test_engine_parity_master_free_sr():
     assert l_f[-1] < 0.5 * l_f[0]
 
 
-def test_pre_interleave_checkpoint_refused(tmp_path):
-    """A fused-optimizer checkpoint WITHOUT the fused_moment_layout=2
-    marker (pre-ISSUE-8: end-to-end leaf concatenation) must be refused
-    loudly — the flat sizes can coincide and a structural restore would
-    silently scramble moments across leaves."""
+@pytest.mark.parametrize("old_tag", [None, 2])
+def test_older_layout_checkpoint_refused(tmp_path, old_tag):
+    """A fused-optimizer checkpoint of an older moment layout — no
+    fused_moment_layout marker (pre-ISSUE-8: end-to-end leaf
+    concatenation) or 2 (every leaf in the V-interleaved buffers) — must
+    be refused loudly: the flat sizes can coincide and a structural
+    restore would silently scramble moments across leaves."""
     import json as _json
     import os as _os
+    from deepspeed_tpu.runtime.engine import FUSED_MOMENT_LAYOUT
     eng, _ = _run(_cfg(True), steps=1)
     eng.save_checkpoint(str(tmp_path), tag="t")
     mf = _os.path.join(str(tmp_path), "t", "engine_meta.json")
     with open(mf) as f:
         meta = _json.load(f)
-    assert meta["fused_moment_layout"] == 2
-    del meta["fused_moment_layout"]
+    assert meta["fused_moment_layout"] == FUSED_MOMENT_LAYOUT == 3
+    if old_tag is None:
+        del meta["fused_moment_layout"]
+    else:
+        meta["fused_moment_layout"] = old_tag
     with open(mf, "w") as f:
         _json.dump(meta, f)
     eng2, _ = _run(_cfg(True), steps=1)

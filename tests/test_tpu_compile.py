@@ -154,6 +154,24 @@ def _case_apply(_):
                 _sds((1, 8), jnp.float32), _sds((1, 2), jnp.int32))
 
 
+def _case_update_leaf(shape):
+    """The same kernel over ONE leaf where it lies (the plan's in-place
+    leaves): gpt2-large's matrices through their collapsed 2-D view,
+    fp32 masters with the bf16 cast output, and a dp=8 shard of wte whose
+    rows leave a ragged last block."""
+    from deepspeed_tpu.ops.fused_update import _update_leaf
+    sr = shape != (36, 1280, 1280)
+    pdt = jnp.bfloat16 if sr else jnp.float32
+    fn = functools.partial(
+        _update_leaf, b1=0.9, b2=0.999, eps=1e-8, wd=0.01, coupled=False,
+        use_inv=False, use_coeff=True, one_pass=True, sr=sr, cast=not sr,
+        out_dtype=jnp.dtype(pdt),
+        cast_dtype=None if sr else jnp.dtype(jnp.bfloat16))
+    f32 = _sds(shape, jnp.float32)
+    return fn, (f32, _sds(shape, pdt), f32, f32,
+                _sds((1, 8), jnp.float32), _sds((1, 2), jnp.int32))
+
+
 def _case_paged(K):
     """Serving attend: 8 streams, block_size 16, a 1024-token table."""
     from deepspeed_tpu.ops.paged_attention import paged_attention
@@ -201,6 +219,13 @@ CASES = {
     "bias_gelu_bwd": (_case_gelu, True),
     "fused_update_sqnorm": (_case_sqnorm, None),
     "fused_update_apply_bf16_sr": (_case_apply, None),
+    "fused_update_leaf_wte": (_case_update_leaf, (50304, 1280)),
+    "fused_update_leaf_wte_dp8_shard": (_case_update_leaf, (6288, 1280)),
+    "fused_update_leaf_wpe": (_case_update_leaf, (1024, 1280)),
+    "fused_update_leaf_qkv": (_case_update_leaf, (36, 1280, 3840)),
+    "fused_update_leaf_proj_f32_cast": (_case_update_leaf, (36, 1280, 1280)),
+    "fused_update_leaf_fc": (_case_update_leaf, (36, 1280, 5120)),
+    "fused_update_leaf_fc2": (_case_update_leaf, (36, 5120, 1280)),
     "paged_attention_decode_k1": (_case_paged, 1),
     "paged_attention_verify_k5": (_case_paged, 5),
     "paged_attention_prefill_k32": (_case_paged, 32),
@@ -215,3 +240,103 @@ def test_kernel_compiles_for_v5e(name, one_chip, as_tpu):
     build, arg = CASES[name]
     fn, shapes = build(arg)
     _compile(fn, one_chip, *shapes)
+
+
+# ------------------------------------------------------------------ #
+# The fused optimizer's whole one-pass step at gpt2-large's shapes: what
+# the chip compiler makes of the in-place plan (ops/fused_update.py).
+# ------------------------------------------------------------------ #
+_BIG = 1 << 20          # elements: every in-place gpt2 leaf is larger
+
+
+@pytest.fixture(scope="module")
+def optimizer_step(topo):
+    """(plan summary, lowered text, compiled) of fused_step over the
+    scanned gpt2-large tree: bf16 params with stochastic rounding, f32
+    grads, clip 1.0 — cell 1's optimizer — params and state donated."""
+    from deepspeed_tpu.models import GPT2_CONFIGS, gpt2_init
+    from deepspeed_tpu.ops import autotune, fused_update
+    one = SingleDeviceSharding(topo.devices[0])
+    shapes = jax.eval_shape(
+        lambda k: gpt2_init(k, GPT2_CONFIGS["gpt2-large"]),
+        jax.random.PRNGKey(0))
+
+    def tree(dtype):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, dtype, sharding=one),
+            shapes)
+
+    tx = fused_update.fused_adam(lambda c: jnp.float32(1e-4),
+                                 weight_decay=0.01)
+    state = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+        jax.eval_shape(tx.init, tree(jnp.float32)))
+
+    def step(g, s, p, key):
+        with jax.named_scope("optimizer"):
+            out = tx.fused_step(g, s, p, clip=1.0, sr_key=key)
+        return out.params, out.state, out.grad_norm
+
+    from jax.experimental.compilation_cache import compilation_cache
+    mp = pytest.MonkeyPatch()
+    prev = jax.config.jax_enable_compilation_cache
+    try:
+        mp.setattr(jax, "default_backend", lambda *a, **k: "tpu")
+        mp.setenv("DS_AUTOTUNE", "0")
+        autotune.reset()
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        lowered = jax.jit(step, donate_argnums=(1, 2)).lower(
+            tree(jnp.float32), state, tree(jnp.bfloat16),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one))
+        compiled = lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+        mp.undo()
+        autotune.reset()
+    return (fused_update.plan_summary(tree(jnp.bfloat16)),
+            lowered.as_text(), compiled)
+
+
+def test_optimizer_step_lowers_one_kernel_program_per_geometry(
+        optimizer_step):
+    """Start-up budget: the lowered step holds at most (distinct large
+    leaf geometries + 1) Adam kernel programs and ONE norm kernel (the
+    packed group's; in-place leaves reduce in plain XLA)."""
+    plan, lowered, _ = optimizer_step
+    assert plan["leaves_in_place"] == 6 and plan["kernel_programs"] == 7
+    assert lowered.count("_fused_adam_kernel") <= plan["kernel_programs"]
+    assert lowered.count("_sqnorm_kernel") == 1
+
+
+def test_optimizer_step_assembles_no_large_leaf(optimizer_step):
+    """The compiled step holds no ``concatenate``, ``copy`` or
+    ``transpose`` of a buffer the size of a large leaf: what is still
+    assembled is the packed group (0.66M elements at gpt2-large)."""
+    _, _, compiled = optimizer_step
+    import re
+    seen = []
+    for line in compiled.as_text().splitlines():
+        m = re.search(r"= \w+\[([\d,]*)\]\S* "
+                      r"(copy|concatenate|transpose)\(", line)
+        if not m:
+            continue
+        n = int(np.prod([int(d) for d in m.group(1).split(",") if d]))
+        seen.append((m.group(2), n))
+    assert any(op == "concatenate" for op, _ in seen), \
+        "the packed group's assembly should still be there"
+    assert all(n < _BIG for _, n in seen), \
+        [(op, n) for op, n in seen if n >= _BIG]
+
+
+def test_optimizer_step_updates_donated_buffers_in_place(optimizer_step):
+    """Parameters and both moments of the in-place leaves are donated and
+    aliased to the outputs (no second copy: this is what keeps the step's
+    peak HBM where it was), and the step needs next to no scratch."""
+    plan, _, compiled = optimizer_step
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= plan["bytes_in_place"]
+    # parent: 6.2 GB of flat gradient and parameter buffers lived here
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20, mem.temp_size_in_bytes
+

@@ -95,6 +95,9 @@ def report(name, compiled, seconds):
 
 def train_step(devices, cfg, gas: int):
     import deepspeed_tpu
+    # Imported here, before jax.jit is stood in for: the module wraps its
+    # per-leaf update in a jit of its own when it is imported.
+    import deepspeed_tpu.ops.fused_update  # noqa: F401
     from deepspeed_tpu.models import gpt2_init, gpt2_loss_fn
     from deepspeed_tpu.parallel.topology import build_mesh
 
