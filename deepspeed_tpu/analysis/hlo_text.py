@@ -171,6 +171,27 @@ def iter_instructions(hlo_text: str) -> Iterator[Instruction]:
                               om.group(1) if om else "")
 
 
+def ops_in_units_of(hlo_text: str, unit: int) -> List[Tuple[str, int]]:
+    """(opcode, elements) of every instruction whose largest output array
+    holds a whole number (>= 1) of ``unit`` elements — e.g. ``unit`` = one
+    layer of a KV pool finds whatever handles the pool, or a layer of it,
+    as a whole. In a program that updates the pool in place these are
+    parameters, tuples, the layer ``while``, bitcasts and the aliased
+    kernels' custom calls (tests/test_tpu_compile.py,
+    tools/compile_rehearsal.py)."""
+    found = []
+    for ins in iter_instructions(hlo_text):
+        n = 0
+        for _, dims in SHAPE_RE.findall(ins.shape_str):
+            size = 1
+            for d in dims.split(","):
+                size *= int(d) if d else 1
+            n = max(n, size)
+        if n >= unit and n % unit == 0:
+            found.append((ins.opcode, n))
+    return found
+
+
 def while_trip_counts(hlo_text: str) -> List[int]:
     """Best-effort static trip counts: the integer constants appearing in
     each ``while`` instruction's CONDITION computation (a ``lax.scan``'s

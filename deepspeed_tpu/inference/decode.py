@@ -5,9 +5,12 @@ PR-7 parity baseline).
 
 The PAGED programs (``gpt2_decode_paged`` / ``gpt2_verify_paged`` /
 ``gpt2_prefill_chunk_paged`` / ``gpt2_prefill_full_paged``) route every
-cache access through the block-table one-hot primitives in
+cache access through the block-table primitives in
 ``inference/kv_cache.py``: group-batched over the mesh data axis, one
 compiled shape whatever the tables hold, no full-pool gather. The
+stacked pools ride the layer loop as a CARRY beside the layer index —
+new rows are written into them in place and the attend reads them where
+they lie, so no program slices, relays or rewrites the pool. The
 verify step generalizes decode to K tokens per slot and, with
 ``spec_accept``, implements draft-then-verify speculative decoding
 whose greedy output is bit-identical to single-token decode.
@@ -282,21 +285,22 @@ def _group_shape(arr: jax.Array, num_groups: int) -> jax.Array:
                        + arr.shape[1:])
 
 
-def _paged_attn_block(p, x, kc, vc, bt_g, cfg: GPT2Config,
-                      num_groups: int, write_pos: jax.Array,
+def _paged_attn_block(p, x, kc, vc, layer, bt_g, cfg: GPT2Config,
+                      num_groups: int, blk: jax.Array, off: jax.Array,
                       pos_g: jax.Array, sel, pos_mask,
                       paged_kernel: bool = False, mesh=None):
     """Shared attention step of the paged decode/verify/prefill paths.
 
     x: [S, K, H] — K tokens for each of S per-slot query streams, with
-    S = G * Sg (Sg = 1 stream per group for prefill); kc/vc: one
-    layer's [G, B, nH, bs, D]; bt_g: [G, Sg, J]; write_pos: [G, Sg*K]
-    token positions to write; pos_g: [G, Sg, K] inclusive last
-    attendable position per query row. ``sel`` [G, Sg, J, B] /
-    ``pos_mask`` [G, Sg, K, J*bs] drive the one-hot baseline and are
-    None when ``paged_kernel`` routes the attend through the Pallas
-    kernel (the writes stay one-hot either way — they are O(written
-    rows), not O(pool)). Returns (x', kc', vc').
+    S = G * Sg (Sg = 1 stream per group for prefill); kc/vc: the WHOLE
+    stacked pools as held ([L, G, B, nH, bs/f, f*D]) and ``layer`` the
+    layer of them this block owns; bt_g: [G, Sg, J]; blk/off: [G, Sg*K]
+    where each new row goes (``positions_to_blocks``); pos_g: [G, Sg, K]
+    inclusive last attendable position per query row. ``sel``
+    [G, Sg, J, B] / ``pos_mask`` [G, Sg, K, J*bs] drive the one-hot
+    baseline and are None when ``paged_kernel`` routes the attend
+    through the Pallas kernel (the write is the same in-place one either
+    way). Returns (x', kc', vc').
     """
     S, K, H = x.shape
     G = num_groups
@@ -305,29 +309,73 @@ def _paged_attn_block(p, x, kc, vc, bt_g, cfg: GPT2Config,
     nH, D = cfg.num_heads, cfg.head_dim
     with jax.named_scope("attn"):
         q, k, v = _qkv(p, x, cfg)                    # [S, K, nH, D]
-        bs = kc.shape[3]
         with jax.named_scope("kv_write"):
-            bt_rows = jnp.broadcast_to(bt_g[:, :, None, :],
-                                       (G, Sg, K, bt_g.shape[-1])
-                                       ).reshape(G, R, -1)
-            blk, off = kv_cache.positions_to_blocks(bt_rows, write_pos,
-                                                    bs)
-            kc = kv_cache.paged_write_rows(kc, k.reshape(G, R, nH, D),
-                                           blk, off)
-            vc = kv_cache.paged_write_rows(vc, v.reshape(G, R, nH, D),
-                                           blk, off)
+            kc, vc = kv_cache.paged_write_rows(
+                kc, vc, k.reshape(G, R, nH, D), v.reshape(G, R, nH, D),
+                layer, blk, off, mesh=mesh)
         with jax.named_scope("attend"):
             if paged_kernel:
                 attn = paged_attn_ops.paged_attention(
-                    q.reshape(G, Sg, K, nH, D), kc, vc, bt_g, pos_g,
-                    scale=1.0 / math.sqrt(D), mesh=mesh)
+                    q.reshape(G, Sg, K, nH, D), kc, vc, layer, bt_g,
+                    pos_g, scale=1.0 / math.sqrt(D), mesh=mesh)
             else:
                 attn = kv_cache.paged_attend(
-                    q.reshape(G, Sg, K, nH, D), kc, vc, sel, pos_mask,
-                    1.0 / math.sqrt(D), NEG_INF)
+                    q.reshape(G, Sg, K, nH, D),
+                    kv_cache.paged_layer_view(kc, layer, D),
+                    kv_cache.paged_layer_view(vc, layer, D), sel,
+                    pos_mask, 1.0 / math.sqrt(D), NEG_INF)
         attn = attn.reshape(S, K, H).astype(x.dtype)
         x = x + dense(attn, p["proj_kernel"], p["proj_bias"])
     return _ffn(p, x, cfg), kc, vc
+
+
+def _paged_layers(params, x, kc, vc, block_fn):
+    """Run ``block_fn(p, x, kc, vc, layer)`` over the stacked blocks
+    with the pools as a CARRY (scan xs/ys would slice a layer out of the
+    pool and stack it back: a pool-sized copy per layer)."""
+    num_layers = kc.shape[0]
+
+    def body(carry, layer_in):
+        p, layer = layer_in
+        return block_fn(p, *carry, layer), None
+
+    (x, kc, vc), _ = lax.scan(
+        body, (x, kc, vc),
+        (params["blocks"], jnp.arange(num_layers, dtype=jnp.int32)))
+    return x, kc, vc
+
+
+def _write_targets(bt_g: jax.Array, pos_g: jax.Array, block_size: int
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """(block, offset) of every new row: bt_g [G, Sg, J], pos_g
+    [G, Sg, K] -> two [G, Sg*K]."""
+    G, Sg, K = pos_g.shape
+    bt_rows = jnp.broadcast_to(bt_g[:, :, None, :],
+                               (G, Sg, K, bt_g.shape[-1]))
+    blk, off = kv_cache.positions_to_blocks(bt_rows, pos_g, block_size)
+    return blk.reshape(G, Sg * K), off.reshape(G, Sg * K)
+
+
+def _paged_forward(params, x, kc, vc, bt_g, pos_g, cfg: GPT2Config,
+                   paged_kernel: bool, mesh):
+    """All layers of the table-driven paths (decode / verify / chunked
+    prefill): x [S, K, H] with its streams' tables bt_g [G, Sg, J] and
+    row positions pos_g [G, Sg, K]. Returns (x', kc', vc')."""
+    G, _, J = bt_g.shape
+    bs = kv_cache.paged_block_size(kc, cfg.head_dim)
+    sel = pos_mask = None
+    if not paged_kernel:
+        sel = kv_cache.block_select(bt_g, kc.shape[2])
+        grid = lax.broadcasted_iota(jnp.int32, (1, 1, 1, J * bs), 3)
+        pos_mask = grid <= pos_g[..., None]          # [G, Sg, K, J*bs]
+    blk, off = _write_targets(bt_g, pos_g, bs)
+
+    def block(p, h, kc, vc, layer):
+        return _paged_attn_block(p, h, kc, vc, layer, bt_g, cfg, G, blk,
+                                 off, pos_g, sel, pos_mask, paged_kernel,
+                                 mesh)
+
+    return _paged_layers(params, x, kc, vc, block)
 
 
 def gpt2_verify_paged(params: Dict[str, Any], kc: jax.Array,
@@ -343,36 +391,18 @@ def gpt2_verify_paged(params: Dict[str, Any], kc: jax.Array,
     lengths[s] + i. Writes all K tokens' K/V through the block table,
     attends each under its own causal row, and returns fp32 logits
     [S, K, V] (the K-bounded spec-decode analogue of last-position-only
-    logits — never a [max_len, vocab] tensor). kc/vc: the full pool
-    [L, G, B, nH, bs, D]. ``paged_kernel`` swaps the one-hot pool
+    logits — never a [max_len, vocab] tensor). kc/vc: the full pool as
+    held, [L, G, B, nH, bs/f, f*D]. ``paged_kernel`` swaps the one-hot pool
     contraction for the Pallas table-sliced kernel (ops/
     paged_attention.py) — same logits, O(context) work.
     """
     _check_cfg(cfg)
-    S, K = tokens.shape
-    G = num_groups
-    Sg = S // G
-    J = block_tables.shape[-1]
-    bs = kc.shape[4]
+    K = tokens.shape[1]
     pos = lengths[:, None] + jnp.arange(K, dtype=jnp.int32)[None]  # [S,K]
     x = _embed(params, tokens, pos, cfg)
-    bt_g = _group_shape(block_tables, G)             # [G, Sg, J]
-    pos_g = _group_shape(pos, G)                     # [G, Sg, K]
-    sel = pos_mask = None
-    if not paged_kernel:
-        sel = kv_cache.block_select(bt_g, kc.shape[2])
-        grid = lax.broadcasted_iota(jnp.int32, (1, 1, 1, J * bs), 3)
-        pos_mask = grid <= pos_g[..., None]          # [G, Sg, K, J*bs]
-    write_pos = pos_g.reshape(G, Sg * K)
-
-    def body(h, layer):
-        p, kcl, vcl = layer
-        h, kcl, vcl = _paged_attn_block(p, h, kcl, vcl, bt_g, cfg, G,
-                                        write_pos, pos_g, sel, pos_mask,
-                                        paged_kernel, mesh)
-        return h, (kcl, vcl)
-
-    x, (kc, vc) = lax.scan(body, x, (params["blocks"], kc, vc))
+    x, kc, vc = _paged_forward(
+        params, x, kc, vc, _group_shape(block_tables, num_groups),
+        _group_shape(pos, num_groups), cfg, paged_kernel, mesh)
     x = layer_norm_fn(cfg)(x, params["ln_f_scale"], params["ln_f_bias"])
     logits = _unembed(params, x, cfg)
     return logits, kc, vc
@@ -414,28 +444,12 @@ def gpt2_prefill_chunk_paged(params: Dict[str, Any], kc: jax.Array,
     """
     _check_cfg(cfg)
     G, C = tokens.shape
-    J = bt_rows.shape[-1]
-    bs = kc.shape[4]
     pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]  # [G, C]
     x = _embed(params, tokens, pos, cfg)         # [G, C, H]
     bt_g = jnp.where(active[:, None, None] > 0, bt_rows[:, None],
                      kv_cache.DEAD_BLOCK)            # [G, 1, J]
-    pos_g = pos[:, None, :]                          # [G, 1, C]
-    sel = pos_mask = None
-    if not paged_kernel:
-        sel = kv_cache.block_select(bt_g, kc.shape[2])
-        grid = lax.broadcasted_iota(jnp.int32, (1, 1, 1, J * bs), 3)
-        pos_mask = grid <= pos[:, None, :, None]     # [G, 1, C, J*bs]
-    write_pos = pos                                  # [G, C]
-
-    def body(h, layer):
-        p, kcl, vcl = layer
-        h, kcl, vcl = _paged_attn_block(p, h, kcl, vcl, bt_g, cfg, G,
-                                        write_pos, pos_g, sel, pos_mask,
-                                        paged_kernel, mesh)
-        return h, (kcl, vcl)
-
-    x, (kc, vc) = lax.scan(body, x, (params["blocks"], kc, vc))
+    x, kc, vc = _paged_forward(params, x, kc, vc, bt_g, pos[:, None, :],
+                               cfg, paged_kernel, mesh)
     x = layer_norm_fn(cfg)(x, params["ln_f_scale"], params["ln_f_bias"])
     oh = (lax.broadcasted_iota(jnp.int32, (G, C), 1) ==
           last_idx[:, None]).astype(x.dtype)
@@ -448,50 +462,44 @@ def gpt2_prefill_full_paged(params: Dict[str, Any], kc: jax.Array,
                             vc: jax.Array, tokens: jax.Array,
                             bt_rows: jax.Array, last_idx: jax.Array,
                             cfg: GPT2Config,
-                            attention_fn: Optional[Callable] = None
+                            attention_fn: Optional[Callable] = None,
+                            mesh=None
                             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Whole-prompt single-shot prefill (``prefill_chunk: 0``) into the
     block pool: the same pluggable-attention forward as
     ``gpt2_prefill_full`` (ring attention plugs in identically), with
-    the per-layer K/V splice routed through the target slot's block
-    table instead of a slot-major ``dynamic_update_slice``. tokens: [T]
-    padded to max_len; bt_rows: [G, J] — the slot's row in its own
-    group, DEAD_BLOCK rows elsewhere, so the write lands only in the
-    owning dp shard."""
+    each layer's K/V rows written through the target slot's block table
+    by the same in-place write the decode step uses (R = the whole
+    padded prompt). tokens: [T] padded to max_len; bt_rows: [G, J] — the
+    slot's row in its own group, DEAD_BLOCK rows elsewhere, so the write
+    lands only in the owning dp shard."""
     _check_cfg(cfg)
     if attention_fn is None:
         from ..ops.flash_attention import auto_attention
         attention_fn = auto_attention
     T = tokens.shape[0]
     G = bt_rows.shape[0]
-    bs = kc.shape[4]
+    bs = kv_cache.paged_block_size(kc, cfg.head_dim)
     x = _embed(params, tokens, slice(T), cfg)[None]        # [1, T, H]
+    pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (G, T))
+    blk, off = _write_targets(bt_rows[:, None], pos[:, None], bs)
 
-    def body(h, p):
+    def block(p, h, kc, vc, layer):
         with jax.named_scope("attn"):
             q, k, v = _qkv(p, h, cfg)              # [1, T, nH, D]
+            with jax.named_scope("kv_write"):
+                kc, vc = kv_cache.paged_write_rows(
+                    kc, vc, jnp.broadcast_to(k, (G,) + k.shape[1:]),
+                    jnp.broadcast_to(v, (G,) + v.shape[1:]), layer, blk,
+                    off, mesh=mesh)
             with jax.named_scope("attend"):
                 attn = attention_fn(q, k, v, mask=None, causal=True,
                                     deterministic=True)
             attn = attn.reshape(h.shape).astype(h.dtype)
             h = h + dense(attn, p["proj_kernel"], p["proj_bias"])
-        return _ffn(p, h, cfg), (k[0], v[0])       # ys: [T, nH, D]
+        return _ffn(p, h, cfg), kc, vc
 
-    x, (ks, vs) = lax.scan(body, x, params["blocks"])
-    with jax.named_scope("kv_write"):
-        pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None],
-                               (G, T))
-        bt_per_row = jnp.broadcast_to(bt_rows[:, None, :],
-                                      (G, T, bt_rows.shape[-1]))
-        blk, off = kv_cache.positions_to_blocks(bt_per_row, pos, bs)
-
-        def splice(pool, rows):
-            return kv_cache.paged_write_rows(
-                pool, jnp.broadcast_to(rows[None], (G,) + rows.shape),
-                blk, off)
-
-        kc = jax.vmap(splice)(kc, ks)
-        vc = jax.vmap(splice)(vc, vs)
+    x, kc, vc = _paged_layers(params, x, kc, vc, block)
     x = layer_norm_fn(cfg)(x[0], params["ln_f_scale"],
                            params["ln_f_bias"])
     h_last = lax.dynamic_slice(x, (last_idx.astype(jnp.int32),

@@ -12,7 +12,10 @@ Architecture (mirrors the training engine's discipline):
   and eviction never touch a compiled shape.
 - The KV cache (inference/kv_cache.py) is born sharded: slots over the
   mesh data axis, heads over the model axis. Its buffers are DONATED
-  through every step, so the cache exists once.
+  through every step, so the cache exists once — and a paged step
+  writes its new K/V rows into those buffers where they lie (no program
+  slices, relays or rewrites the pool: a step's cost does not depend on
+  ``num_blocks``).
 - Host-side per-slot counters (lengths, active, last token) are the
   scheduler's state; they enter each step as tiny int arrays. The one
   device fetch per decode iteration is the sampled-token readback — the
@@ -223,6 +226,16 @@ class InferenceEngine:
             sp_ = self.cache_spec
             kvi = jnp.dtype(sp_.dtype).itemsize
             tel_meta["paged_kernel"] = self.paged_kernel
+            # The write always engages (one path), so its counter is
+            # static: rows go into the donated pool in place, one block
+            # tile read and written per row and pool. What the compiled
+            # programs make of it (pools aliased, no pool-sized op) is
+            # asserted in tests/test_tpu_compile.py.
+            tel_meta["kv_write"] = {
+                "mode": "in_place", "fold": sp_.fold,
+                "pool_shape": list(sp_.shape),
+                "tile_bytes": sp_.block_nbytes() // (2 * sp_.num_layers),
+                "rows_per_decode": self.max_slots * (self.spec_k + 1)}
             tel_meta["attend_flops_per_token"] = {
                 "live_ctx_max": paged_attn_ops.attend_flops_per_token(
                     sp_.num_heads, sp_.head_dim, sp_.block_size,
@@ -338,7 +351,7 @@ class InferenceEngine:
                 p = self._runtime_params(params)
                 logits, kc, vc = decode_mod.gpt2_prefill_full_paged(
                     p, kc, vc, tokens, bt_rows, last_idx, cfg,
-                    attention_fn=attention_fn)
+                    attention_fn=attention_fn, mesh=self.mesh)
                 sampled = decode_mod.sample_tokens(logits, key,
                                                    temperature)
                 return kc, vc, sampled, logits

@@ -3,21 +3,31 @@
 Two layouts share this module:
 
 **Paged (the production layout — the PagedAttention/vLLM design).** The
-cache is a pool of fixed-size blocks,
+cache is a pool of fixed-size blocks, logically
 
     k, v : [layers, groups, blocks_per_group, heads, block_size, head_dim]
 
-and a request owns a list of BLOCK IDS (its block table row), not a
+and HELD lane-dense: when ``head_dim`` is under the TPU's 128 lanes,
+``f = 128 // head_dim`` consecutive positions of a block lie side by
+side in the minor dimension (``[..., heads, block_size/f, f*head_dim]``,
+see ``kv_fold``). The bytes are the logical array's own, row-major — the
+logical view is a reshape — but in this shape the compiler's default
+device layout is row-major and unpadded too, so the kernels read the
+pool as it lies and nothing ever relays it. The pool is born in that
+shape, donated to every step, and stays whole for the engine's life. A
+request owns a list of BLOCK IDS (its block table row), not a
 ``max_seq_len`` reservation: short and long requests share HBM, blocks
 allocate lazily as a context grows, and common prompt prefixes are
 shared copy-on-write across requests — full-block granularity, keyed by
 a position-dependent chain hash, reference-counted by the host-side
 ``BlockAllocator``. The ``groups`` axis is the mesh data axis: a slot's
 blocks always live in the slot's own dp shard (the allocator enforces
-it), so every decode-step gather through the block table is a
-GROUP-BATCHED one-hot contraction — GSPMD partitions it with zero
-communication and no per-device transient ever exceeds the pool shard
-(the ``materialization`` lint gate proves it: no full-pool gather).
+it), so every access through the block table is GROUP-LOCAL — the
+Pallas kernels run under ``shard_map`` with group-local block ids, the
+one-hot baseline is a group-batched contraction GSPMD partitions with
+zero communication — and no per-device transient ever exceeds the pool
+shard (the ``materialization`` lint gate proves it: no full-pool
+gather).
 
 **Slot-major (the PR-7 layout, ``block_size: 0``).** One
 ``[slots, max_len]`` row per slot — kept as the parity baseline the
@@ -31,17 +41,23 @@ axes: slots/groups over the data axis, ``heads`` over the model axis
 (Megatron TP head sharding, matching
 ``models/transformer.block_param_shardings``).
 
-Appends and block gathers are one-hot selects/contractions rather than
-scatters/gathers: GSPMD partitions them trivially along groups and
-heads, while a scatter or gather with per-slot indices risks the exact
-full-pool gather the lint gate forbids. The cost is a pool-shard
-read+write per layer per step — the honest CPU-mesh tradeoff; a Pallas
-paged-attention kernel with real dynamic slices is the optimized path
-on TPU hardware (see docs/tutorials/inference.md).
+Paged APPENDS are written into the donated pool where it lies
+(``paged_write_rows`` -> ``ops.paged_attention.paged_write``): an aliased
+Pallas call whose scalar-prefetched (layer, block, offset) pick each new
+row's block tile, rewrite that tile in VMEM and put it back. A step
+touches R tiles for R rows — its cost does not depend on ``num_blocks``
+— and the stacked pool goes through the layer loop as a carry beside
+the layer index, never sliced. Block GATHERS are the Pallas
+paged-attention kernel on TPU; the one-hot ``paged_attend`` contraction
+(which slices its layer out and reads it whole) stays as the CPU-mesh
+and parity baseline, and ``paged_copy_block`` (copy-on-write, rare) is
+still a one-hot select over the pool. The slot-major layout's appends
+are one-hot selects over a slot's row (see docs/tutorials/inference.md).
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -51,6 +67,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..ops import paged_attention as paged_attn_ops
 from ..parallel.topology import DP_AXIS, MP_AXIS
 
 
@@ -216,16 +233,26 @@ class PagedKVCacheSpec:
         return self.max_len // self.block_size
 
     @property
+    def fold(self) -> int:
+        """Positions of a block side by side in the lanes (``kv_fold``)."""
+        return kv_fold(self.head_dim, self.block_size)
+
+    @property
     def shape(self) -> Tuple[int, int, int, int, int, int]:
+        """The pool as it is held: ``logical_shape`` with ``fold``
+        positions folded into the minor dimension (a reshape)."""
+        f = self.fold
+        return self.logical_shape[:4] + (self.block_size // f,
+                                         f * self.head_dim)
+
+    @property
+    def logical_shape(self) -> Tuple[int, int, int, int, int, int]:
         return (self.num_layers, self.num_groups, self.blocks_per_group,
                 self.num_heads, self.block_size, self.head_dim)
 
     def nbytes(self) -> int:
         """Total K+V pool bytes (global, unsharded)."""
-        n = 1
-        for d in self.shape:
-            n *= d
-        return 2 * n * jnp.dtype(self.dtype).itemsize
+        return 2 * math.prod(self.shape) * jnp.dtype(self.dtype).itemsize
 
     def block_nbytes(self) -> int:
         """K+V bytes one block holds across all layers — the unit of
@@ -261,8 +288,47 @@ class PagedKVCacheSpec:
                     f"mesh model axis ({mp}) for TP head sharding")
 
 
+def kv_fold(head_dim: int, block_size: int) -> int:
+    """How many positions of a block share the 128 lanes of the pool's
+    minor dimension: ``128 // head_dim`` where ``head_dim`` divides 128
+    (as far as ``block_size`` divides by it), 1 where ``head_dim``
+    already fills the lanes. Computed from the shapes; there is no
+    option."""
+    if head_dim >= 128 or 128 % head_dim:
+        return 1
+    return math.gcd(128 // head_dim, block_size)
+
+
+def paged_logical_view(pool: jax.Array, head_dim: int) -> jax.Array:
+    """``[..., nH, bs/f, f*D]`` (as held) -> ``[..., nH, bs, D]``: the
+    same bytes, for tests, the one-hot baseline and anything else that
+    wants to index positions."""
+    f = pool.shape[-1] // head_dim
+    return pool.reshape(pool.shape[:-2] + (pool.shape[-2] * f, head_dim))
+
+
+def paged_folded_view(logical: jax.Array) -> jax.Array:
+    """Inverse of ``paged_logical_view``: ``[..., nH, bs, D]`` -> the
+    lane-dense shape the pool is held in."""
+    bs, D = logical.shape[-2:]
+    f = kv_fold(D, bs)
+    return logical.reshape(logical.shape[:-2] + (bs // f, f * D))
+
+
+def paged_block_size(pool: jax.Array, head_dim: int) -> int:
+    """Positions a block of the pool (as held) spans."""
+    return pool.shape[-2] * pool.shape[-1] // head_dim
+
+
+def paged_layer_view(pool: jax.Array, layer, head_dim: int) -> jax.Array:
+    """One layer of the stacked pool, logical: ``[G, B, nH, bs, D]``.
+    A slice — what the one-hot baseline reads; the kernels never do."""
+    return paged_logical_view(
+        lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False), head_dim)
+
+
 def paged_partition_spec() -> P:
-    """[layers, groups, blocks, heads, block_size, head_dim]: groups
+    """[layers, groups, blocks, heads, block_size/f, f*head_dim]: groups
     over dp, heads over mp."""
     return P(None, DP_AXIS, None, MP_AXIS, None, None)
 
@@ -288,8 +354,8 @@ def init_paged_cache(spec: PagedKVCacheSpec,
 
 # --------------------------------------------------------------------- #
 # In-graph paged primitives. All of them are group-batched: every array
-# carries the [G, ...] group axis so GSPMD partitions over dp with zero
-# communication. ``pool`` here is ONE layer's [G, B, nH, bs, D].
+# carries the [G, ...] group axis so the work partitions over dp with
+# zero communication.
 # --------------------------------------------------------------------- #
 def positions_to_blocks(bt: jax.Array, pos: jax.Array, block_size: int
                         ) -> Tuple[jax.Array, jax.Array]:
@@ -318,38 +384,34 @@ def block_select(bt: jax.Array, blocks_per_group: int) -> jax.Array:
     return (bt[..., None] == iota).astype(jnp.float32)
 
 
-def paged_write_rows(pool: jax.Array, new: jax.Array, blk: jax.Array,
-                     off: jax.Array) -> jax.Array:
-    """Write R rows per group into the pool at (block, offset).
+def paged_write_rows(pool_k: jax.Array, pool_v: jax.Array,
+                     k_new: jax.Array, v_new: jax.Array, layer,
+                     blk: jax.Array, off: jax.Array, mesh=None
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """Write R rows per group into layer ``layer`` of both pools at
+    (block, offset), in place.
 
-    pool: [G, B, nH, bs, D]; new: [G, R, nH, D]; blk/off: [G, R].
-    One-hot select over (B, bs) — the paged analogue of ``write_token``'s
-    length-axis select (see module docstring for why not scatter). Rows
-    with blk == DEAD_BLOCK write nowhere. Distinct live rows always
-    target distinct (block, offset) cells — slots never share a
-    writable block (the allocator's copy-on-write invariant) — so the
-    one-hot sum never accumulates two sources into one cell.
-    """
-    G, B = pool.shape[0], pool.shape[1]
-    bs = pool.shape[3]
-    ohb = blk[..., None] == lax.broadcasted_iota(
-        jnp.int32, blk.shape + (B,), blk.ndim)               # [G, R, B]
-    oht = off[..., None] == lax.broadcasted_iota(
-        jnp.int32, off.shape + (bs,), off.ndim)              # [G, R, bs]
-    oh = ohb[..., :, None] & oht[..., None, :]               # [G, R, B, bs]
-    vals = jnp.einsum("grbt,grnd->gbntd", oh.astype(pool.dtype),
-                      new.astype(pool.dtype))
-    mask = oh.any(1)                                         # [G, B, bs]
-    return jnp.where(mask[:, :, None, :, None], vals, pool)
+    pool_k/pool_v: the stacked pools as held, [L, G, B, nH, bs/f, f*D];
+    k_new/v_new: [G, R, nH, D]; blk/off: [G, R]. Rows with blk ==
+    DEAD_BLOCK write nowhere. Distinct live rows always target distinct
+    (block, offset) cells — slots never share a writable block (the
+    allocator's copy-on-write invariant) — and the rows of one block are
+    consecutive (a stream's positions ascend). The cost is R block
+    tiles read and written per pool, whatever the pool's size: see
+    ``ops.paged_attention.paged_write``."""
+    return paged_attn_ops.paged_write(pool_k, pool_v, k_new, v_new, layer,
+                                      blk, off, mesh=mesh)
 
 
 def paged_attend(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                  sel: jax.Array, pos_mask: jax.Array, scale: float,
                  neg_inf) -> jax.Array:
-    """Attention through the block table, group-batched.
+    """Attention through the block table, group-batched — the one-hot
+    CPU-mesh / parity baseline of the Pallas kernel.
 
     q: [G, Q, K, nH, D] (Q query streams per group, K tokens each);
-    pool_k/pool_v: [G, B, nH, bs, D]; sel: [G, Q, J, B] one-hot block
+    pool_k/pool_v: ONE layer, logical (``paged_layer_view``):
+    [G, B, nH, bs, D]; sel: [G, Q, J, B] one-hot block
     selector; pos_mask: [G, Q, K, J*bs] bool (True = attendable).
     Returns [G, Q, K, nH, D].
 
@@ -390,7 +452,8 @@ def paged_copy_block(pool: jax.Array, src_onehot: jax.Array,
                      dst_onehot: jax.Array) -> jax.Array:
     """Copy one block's rows to another block of the SAME group, for
     every layer at once: the device half of copy-on-write. pool:
-    [L, G, B, nH, bs, D]; src_onehot [G, B] f32; dst_onehot [G, B]
+    [L, G, B, nH, bs/f, f*D] (as held; a block's tile is copied whole);
+    src_onehot [G, B] f32; dst_onehot [G, B]
     bool. Groups with all-zero one-hots pass through untouched."""
     src = jnp.einsum("gb,lgbntd->lgntd", src_onehot.astype(pool.dtype),
                      pool)
@@ -656,7 +719,10 @@ __all__ = ["KVCacheSpec", "cache_partition_spec", "cache_shardings",
            "init_cache", "write_token", "write_chunk", "slot_rows",
            "length_mask",
            "DEAD_BLOCK", "PagedKVCacheSpec", "paged_partition_spec",
-           "paged_shardings", "init_paged_cache", "positions_to_blocks",
+           "paged_shardings", "init_paged_cache", "kv_fold",
+           "paged_logical_view", "paged_folded_view", "paged_block_size",
+           "paged_layer_view",
+           "positions_to_blocks",
            "block_select", "paged_write_rows", "paged_attend",
            "copy_block_onehots", "paged_copy_block", "chain_hash",
            "PoolExhausted", "BlockAllocator", "AdmitPlan"]

@@ -82,12 +82,16 @@ class TestPagedPrimitives:
         assert int(blk2) == kv_cache.DEAD_BLOCK
 
     def test_paged_write_rows_lands_and_dead_rows_dont(self):
-        pool = jnp.zeros((2, 4, 2, 4, 3), jnp.float32)  # [G,B,nH,bs,D]
+        pool = jnp.zeros((1, 2, 4, 2, 4, 3), jnp.float32)  # [L,G,B,nH,bs,D]
         new = jnp.ones((2, 2, 2, 3), jnp.float32) * \
             jnp.asarray([1.0, 2.0])[None, :, None, None]
         blk = jnp.asarray([[1, kv_cache.DEAD_BLOCK], [3, 0]], jnp.int32)
         off = jnp.asarray([[2, 0], [0, 3]], jnp.int32)
-        out = np.array(kv_cache.paged_write_rows(pool, new, blk, off))
+        out_k, out_v = kv_cache.paged_write_rows(pool, pool, new, 2 * new,
+                                                 0, blk, off)
+        np.testing.assert_array_equal(np.asarray(out_v),
+                                      2 * np.asarray(out_k))
+        out = np.array(out_k)[0]
         assert (out[0, 1, :, 2] == 1.0).all()       # row 0 of group 0
         assert (out[1, 3, :, 0] == 1.0).all()       # row 0 of group 1
         assert (out[1, 0, :, 3] == 2.0).all()       # row 1 of group 1
@@ -122,6 +126,240 @@ class TestPagedPrimitives:
             PagedKVCacheSpec(num_layers=1, num_slots=4, num_blocks=7,
                              block_size=2, max_len=8, num_heads=2,
                              head_dim=4, num_groups=2).validate()
+
+
+# --------------------------------------------------------------------- #
+# The in-place write (interpret mode here; tests/test_tpu_compile.py holds
+# what the chip compiler makes of it) against a plain NumPy loop
+# --------------------------------------------------------------------- #
+def _write_case(kind, D, *, G=1, nH=2, seed=0):
+    """(logical K pool, logical V pool, k rows, v rows, block tables
+    [G, Sg, J], positions [G, Sg, K]) for one of the four shapes the
+    serving programs write: R = slots (decode, K=1), slots x 5 (verify),
+    a 128-row prefill chunk, a whole padded prompt. Tables hold dead
+    slots, unallocated tails and positions past the table."""
+    bs, L = 16, 2
+    Sg, K, J = {"decode": (6, 1, 4), "verify": (4, 5, 4),
+                "chunk": (1, 128, 12), "whole_prompt": (1, 256, 16)}[kind]
+    B = Sg * J + 3
+    rng = np.random.default_rng(seed)
+    pool_k = rng.standard_normal((L, G, B, nH, bs, D)).astype(np.float32)
+    pool_v = rng.standard_normal((L, G, B, nH, bs, D)).astype(np.float32)
+    bt = np.full((G, Sg, J), kv_cache.DEAD_BLOCK, np.int32)
+    pos = np.zeros((G, Sg, K), np.int32)
+    for g in range(G):
+        free = list(rng.permutation(B))
+        for s in range(Sg):
+            if kind in ("decode", "verify") and s == 1:
+                continue                        # an inactive slot
+            if kind == "whole_prompt" and g == G - 1 and G > 1:
+                continue                        # not this group's slot
+            if kind in ("decode", "verify"):
+                # last stream sits at the table's end: verify's tail
+                # rows fall past it and must write nowhere
+                start = J * bs - 2 if s == Sg - 1 else \
+                    int(rng.integers(0, (J - 1) * bs))
+            else:
+                start = 32 if kind == "chunk" else 0
+            last = min(start + K - 1, J * bs - 1)
+            # a whole prompt is padded to the table; only the prompt's
+            # own blocks are allocated, the padding rows are dead
+            if kind == "whole_prompt":
+                last = 150
+            for j in range(last // bs + 1):
+                bt[g, s, j] = free.pop()
+            pos[g, s] = start + np.arange(K)
+    rows_k = rng.standard_normal((G, Sg * K, nH, D)).astype(np.float32)
+    rows_v = rng.standard_normal((G, Sg * K, nH, D)).astype(np.float32)
+    return pool_k, pool_v, rows_k, rows_v, bt, pos
+
+
+def _numpy_write(pool, rows, layer, bt, pos, bs):
+    """The plain reference: one row at a time."""
+    out = pool.copy()
+    G, Sg, K = pos.shape
+    J = bt.shape[-1]
+    for g in range(G):
+        for s in range(Sg):
+            for k in range(K):
+                j, off = divmod(int(pos[g, s, k]), bs)
+                if j >= J or bt[g, s, j] == kv_cache.DEAD_BLOCK:
+                    continue
+                out[layer, g, bt[g, s, j], :, off, :] = rows[g, s * K + k]
+    return out
+
+
+def _device_write(pool_k, pool_v, rows_k, rows_v, layer, bt, pos, bs,
+                  dtype=jnp.float32, mesh=None):
+    from deepspeed_tpu.inference.decode import _write_targets
+    D = pool_k.shape[-1]
+
+    def step(kc, vc, rk, rv, bt, pos):
+        blk, off = _write_targets(bt, pos, bs)
+        return kv_cache.paged_write_rows(kc, vc, rk, rv, layer, blk, off,
+                                         mesh=mesh)
+
+    held = lambda a: kv_cache.paged_folded_view(jnp.asarray(a, dtype))
+    kc, vc = jax.jit(step, donate_argnums=(0, 1))(
+        held(pool_k), held(pool_v), jnp.asarray(rows_k),
+        jnp.asarray(rows_v), jnp.asarray(bt), jnp.asarray(pos))
+    return (np.asarray(kv_cache.paged_logical_view(kc, D), np.float32),
+            np.asarray(kv_cache.paged_logical_view(vc, D), np.float32))
+
+
+class TestInPlaceWrite:
+    @pytest.mark.parametrize("head_dim", [64, 128])
+    @pytest.mark.parametrize("kind", ["decode", "verify", "chunk",
+                                      "whole_prompt"])
+    def test_write_matches_a_numpy_loop_over_rows(self, kind, head_dim):
+        """Every written row lands with the same bits at (layer, block,
+        :, offset, :) and every other cell of BOTH pools — the other
+        layer, dead slots' blocks, blocks past a table — is
+        bit-identical to what it was. head_dim 64 is held folded (two
+        positions a lane row), 128 is not."""
+        pk, pv, rk, rv, bt, pos = _write_case(kind, head_dim)
+        assert kv_cache.kv_fold(head_dim, 16) == 128 // head_dim
+        got_k, got_v = _device_write(pk, pv, rk, rv, 1, bt, pos, 16)
+        np.testing.assert_array_equal(
+            got_k, _numpy_write(pk, rk, 1, bt, pos, 16))
+        np.testing.assert_array_equal(
+            got_v, _numpy_write(pv, rv, 1, bt, pos, 16))
+        assert not np.array_equal(got_k, pk)        # something landed
+
+    @pytest.mark.parametrize("head_dim", [64, 128])
+    def test_bf16_rows_land_with_their_own_bits(self, head_dim):
+        """The serving pool is bf16: a written row is the bf16 rounding
+        of the new row, nothing more (the kernel selects in fp32, which
+        holds every bf16 exactly)."""
+        pk, pv, rk, rv, bt, pos = _write_case("verify", head_dim, seed=3)
+        rnd = lambda a: np.asarray(jnp.asarray(a, jnp.bfloat16),
+                                   np.float32)
+        got_k, got_v = _device_write(pk, pv, rk, rv, 0, bt, pos, 16,
+                                     dtype=jnp.bfloat16)
+        np.testing.assert_array_equal(
+            got_k, _numpy_write(rnd(pk), rnd(rk), 0, bt, pos, 16))
+        np.testing.assert_array_equal(
+            got_v, _numpy_write(rnd(pv), rnd(rv), 0, bt, pos, 16))
+
+    @pytest.mark.parametrize("head_dim,block_size,fold", [
+        (64, 16, 2), (128, 16, 1), (32, 16, 4), (16, 4, 4), (80, 16, 1),
+        (256, 8, 1)])
+    def test_logical_view_round_trips(self, head_dim, block_size, fold):
+        """``PagedKVCacheSpec.shape`` is the logical shape with ``fold``
+        positions side by side in the lanes; the views are reshapes of
+        the same bytes, both ways."""
+        spec = PagedKVCacheSpec(num_layers=2, num_slots=2, num_blocks=6,
+                                block_size=block_size,
+                                max_len=2 * block_size, num_heads=3,
+                                head_dim=head_dim, dtype=jnp.float32)
+        assert spec.fold == fold == kv_cache.kv_fold(head_dim, block_size)
+        assert spec.shape == (2, 1, 6, 3, block_size // fold,
+                              fold * head_dim)
+        assert spec.nbytes() == 2 * 4 * int(np.prod(spec.logical_shape))
+        logical = jnp.arange(np.prod(spec.logical_shape),
+                             dtype=jnp.float32).reshape(spec.logical_shape)
+        held = kv_cache.paged_folded_view(logical)
+        assert held.shape == spec.shape
+        np.testing.assert_array_equal(
+            np.asarray(held).ravel(), np.asarray(logical).ravel())
+        np.testing.assert_array_equal(
+            np.asarray(kv_cache.paged_logical_view(held, head_dim)),
+            np.asarray(logical))
+        np.testing.assert_array_equal(
+            np.asarray(kv_cache.paged_layer_view(held, 1, head_dim)),
+            np.asarray(logical)[1])
+        # position t of a block: row t // fold, lanes (t % fold) * D ..
+        t = block_size - 1
+        np.testing.assert_array_equal(
+            np.asarray(held)[1, 0, 4, 2, t // fold,
+                             (t % fold) * head_dim:][:head_dim],
+            np.asarray(logical)[1, 0, 4, 2, t])
+
+    @pytest.mark.parametrize("head_dim", [64, 128])
+    def test_dead_rows_and_positions_past_the_table_write_nowhere(
+            self, head_dim):
+        """All-dead tables (an inactive prefill group, an empty batch)
+        and positions past the table leave the pools bit-identical."""
+        pk, pv, rk, rv, bt, pos = _write_case("verify", head_dim, seed=5)
+        dead = np.full_like(bt, kv_cache.DEAD_BLOCK)
+        got_k, got_v = _device_write(pk, pv, rk, rv, 1, dead, pos, 16)
+        np.testing.assert_array_equal(got_k, pk)
+        np.testing.assert_array_equal(got_v, pv)
+        past = pos + bt.shape[-1] * 16          # every position past it
+        got_k, got_v = _device_write(pk, pv, rk, rv, 1, bt, past, 16)
+        np.testing.assert_array_equal(got_k, pk)
+        np.testing.assert_array_equal(got_v, pv)
+
+    @pytest.mark.parametrize("kind", ["decode", "verify", "whole_prompt"])
+    def test_two_groups_and_two_head_shards_on_a_mesh(self, kind):
+        """dp=2 groups x mp=2 heads on four host devices: the write runs
+        per shard (shard_map, group-local block ids) and the sharded
+        pools hold what the NumPy loop holds."""
+        from jax.sharding import NamedSharding
+        from deepspeed_tpu.parallel.topology import build_mesh
+        mesh = build_mesh(dp=2, mp=2, devices=jax.devices()[:4])
+        pk, pv, rk, rv, bt, pos = _write_case(kind, 64, G=2, nH=4, seed=7)
+        from deepspeed_tpu.inference.decode import _write_targets
+
+        def step(kc, vc, rk, rv, bt, pos):
+            blk, off = _write_targets(bt, pos, 16)
+            return kv_cache.paged_write_rows(kc, vc, rk, rv, 1, blk, off,
+                                             mesh=mesh)
+
+        sh = kv_cache.paged_shardings(mesh)
+        put = lambda a: jax.device_put(
+            kv_cache.paged_folded_view(jnp.asarray(a)), sh["k"])
+        kc, vc = jax.jit(step, donate_argnums=(0, 1),
+                         out_shardings=(sh["k"], sh["v"]))(
+            put(pk), put(pv), jnp.asarray(rk), jnp.asarray(rv),
+            jnp.asarray(bt), jnp.asarray(pos))
+        assert kc.sharding.is_equivalent_to(sh["k"], kc.ndim)
+        np.testing.assert_array_equal(
+            np.asarray(kv_cache.paged_logical_view(kc, 64)),
+            _numpy_write(pk, rk, 1, bt, pos, 16))
+        np.testing.assert_array_equal(
+            np.asarray(kv_cache.paged_logical_view(vc, 64)),
+            _numpy_write(pv, rv, 1, bt, pos, 16))
+
+    @pytest.mark.parametrize("program", ["decode_step", "prefill_step"])
+    def test_pool_buffers_are_donated_and_reused(self, params32, program):
+        """The engine's step hands back the SAME device buffers it was
+        given: the compiled program aliases both pools to its outputs,
+        and (where the platform reports it) the buffer address does not
+        move across an execution."""
+        eng = _engine(params32, slots=8, chunk=8)
+        tok, _ = eng.prefill(_prompt(11), slot=0)
+        eng.activate_slot(0, 11, tok)
+        G, J = eng.dp, eng.cache_spec.max_blocks_per_slot
+        where = lambda: [s.data.unsafe_buffer_pointer() for n in "kv"
+                         for s in eng.cache[n].addressable_shards]
+        before = where()
+        if program == "decode_step":
+            eng.decode_once()
+            fn, args = eng._decode_fn, (
+                eng._params, eng.cache["k"], eng.cache["v"],
+                jnp.zeros(8, jnp.int32), jnp.zeros(8, jnp.int32),
+                jnp.asarray(eng.block_tables), eng._next_key(),
+                jnp.float32(0))
+        else:
+            eng.prefill(_prompt(9, seed=1), slot=1)
+            fn, args = eng._prefill_fn, (
+                eng._params, eng.cache["k"], eng.cache["v"],
+                jnp.zeros((G, 8), jnp.int32), jnp.zeros((G, J), jnp.int32),
+                jnp.zeros(G, jnp.int32), jnp.zeros(G, jnp.int32),
+                jnp.ones(G, jnp.int32), eng._next_key(), jnp.float32(0))
+        after = where()
+        if not hasattr(fn, "lower"):        # the recompile sentinel's wrap
+            fn = fn.__wrapped__
+        compiled = fn.lower(*args).compile()
+        from deepspeed_tpu.analysis.hlo_text import (
+            input_output_alias_params)
+        n = len(jax.tree_util.tree_leaves(eng._params))     # pools follow
+        assert sorted(input_output_alias_params(compiled.as_text())) == \
+            [n, n + 1]
+        assert compiled.memory_analysis().alias_size_in_bytes >= \
+            eng.cache_spec.nbytes() // eng.dp           # per device
+        assert after == before
 
 
 # --------------------------------------------------------------------- #

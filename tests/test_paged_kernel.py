@@ -90,13 +90,25 @@ def _case(seed, lengths, *, K=1, nH=4, D=16, B=12, bs=8, J=4,
             jnp.asarray(bt), jnp.asarray(pos), 1.0 / math.sqrt(D))
 
 
+def _kernel(q, pool_k, pool_v, bt, pos, layer=1, **kw):
+    """The kernel on the pool AS HELD: ``pool_k`` / ``pool_v`` (one
+    layer, logical [G, B, nH, bs, D]) become layer ``layer`` of a
+    three-layer lane-dense stack whose other layers hold junk."""
+    def held(pool):
+        junk = jnp.full_like(pool, 7.0)
+        stack = jnp.stack([pool if l == layer else junk for l in range(3)])
+        return kv_cache.paged_folded_view(stack)
+    return pa.paged_attention(q, held(pool_k), held(pool_v), layer, bt,
+                              pos, **kw)
+
+
 class TestKernelParity:
     def test_fp32_ragged_contexts_and_partial_blocks(self):
         # Lengths straddle block boundaries: full final block (16),
         # one-row final block (17), mid-block (13), single token (1),
         # and a dead stream — the shapes the serving batch actually has.
         q, pk, pv, bt, pos, sc = _case(0, [[16, 17, 13, 1], [25, 0, 8, 5]])
-        out = pa.paged_attention(q, pk, pv, bt, pos, scale=sc)
+        out = _kernel(q, pk, pv, bt, pos, scale=sc)
         ref = _ref_attend(q, pk, pv, bt, pos, sc)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
@@ -106,7 +118,7 @@ class TestKernelParity:
         # position ctx-1+k — the final row can spill into a block the
         # earlier rows must not see.
         q, pk, pv, bt, pos, sc = _case(1, [[7, 15, 21], [3, 12, 0]], K=4)
-        out = pa.paged_attention(q, pk, pv, bt, pos, scale=sc)
+        out = _kernel(q, pk, pv, bt, pos, scale=sc)
         ref = _ref_attend(q, pk, pv, bt, pos, sc)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
@@ -117,7 +129,7 @@ class TestKernelParity:
         # bf16 resolution (the kernel side is the more accurate one).
         q, pk, pv, bt, pos, sc = _case(2, [[9, 18, 24, 2]],
                                        kv_dtype=jnp.bfloat16)
-        out = pa.paged_attention(q, pk, pv, bt, pos, scale=sc)
+        out = _kernel(q, pk, pv, bt, pos, scale=sc)
         ref = _ref_attend(q, pk, pv, bt, pos, sc)
         np.testing.assert_allclose(np.asarray(out, np.float32),
                                    np.asarray(ref, np.float32),
@@ -130,14 +142,33 @@ class TestKernelParity:
             3, [[17, 20, 25]], shared_prefix_blocks=2)
         assert (np.asarray(bt)[0, :, :2] ==
                 np.asarray(bt)[0, 0, :2]).all()
-        out = pa.paged_attention(q, pk, pv, bt, pos, scale=sc)
+        out = _kernel(q, pk, pv, bt, pos, scale=sc)
+        ref = _ref_attend(q, pk, pv, bt, pos, sc)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("K", [1, 4])
+    @pytest.mark.parametrize("head_dim", [32, 64, 128])
+    def test_lane_dense_folds_agree_with_onehot(self, head_dim, K):
+        # The pool as the engine holds it: 128 // head_dim positions of
+        # a block side by side in the lanes (4 / 2 / none). The kernel
+        # reads those tiles as they lie — each query row comes once per
+        # folded position and the copies' softmax states are merged at
+        # the end — and must still agree with the one-hot baseline on
+        # contexts that end on either parity and on a one-token context
+        # (whose odd copies never see an attendable position).
+        q, pk, pv, bt, pos, sc = _case(
+            6, [[33, 1, 20, 0], [2, 47, 16, 7]], K=K, D=head_dim, bs=16,
+            B=16)
+        assert kv_cache.kv_fold(head_dim, 16) == 128 // head_dim
+        out = _kernel(q, pk, pv, bt, pos, scale=sc)
         ref = _ref_attend(q, pk, pv, bt, pos, sc)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
     def test_dead_streams_emit_exact_zeros(self):
         q, pk, pv, bt, pos, sc = _case(4, [[11, 0, 0, 6]])
-        out = np.asarray(pa.paged_attention(q, pk, pv, bt, pos, scale=sc))
+        out = np.asarray(_kernel(q, pk, pv, bt, pos, scale=sc))
         assert (out[0, 1] == 0.0).all() and (out[0, 2] == 0.0).all()
         assert np.abs(out[0, 0]).sum() > 0
 
@@ -146,8 +177,8 @@ class TestKernelParity:
         # bh dividing nH must reproduce bh=1 bit-for-bit (fp32 scratch
         # accumulation order per head is unchanged by head grouping).
         q, pk, pv, bt, pos, sc = _case(5, [[14, 22, 5, 0]])
-        outs = [np.asarray(pa.paged_attention(q, pk, pv, bt, pos,
-                                              scale=sc, block_heads=bh))
+        outs = [np.asarray(_kernel(q, pk, pv, bt, pos, scale=sc,
+                                   block_heads=bh))
                 for bh in (1, 2, 4)]
         np.testing.assert_array_equal(outs[0], outs[1])
         np.testing.assert_array_equal(outs[0], outs[2])

@@ -29,6 +29,7 @@ becomes shapes with the described devices' shardings).  That — and the
 is scaffolding of this script only; no program option exists for it.
 """
 import collections
+import math
 import os
 import sys
 import tempfile
@@ -159,11 +160,15 @@ def serving_steps(device, cfg):
         lambda k: jax.tree_util.tree_map(
             lambda p: p.astype(cfg.dtype), gpt2_init(k, cfg)),
         jax.random.PRNGKey(0)))
+    from deepspeed_tpu.analysis.hlo_text import ops_in_units_of
+    from deepspeed_tpu.inference import kv_cache
     slots, bs, max_len, spec_k, chunk = 8, 16, 1024, 4, 32
     J = max_len // bs
-    pool = on_chip(jax.ShapeDtypeStruct(
-        (cfg.num_layers, 1, slots * J, cfg.num_heads, bs, cfg.head_dim),
-        cfg.dtype))
+    spec = kv_cache.PagedKVCacheSpec(
+        num_layers=cfg.num_layers, num_slots=slots, num_blocks=slots * J,
+        block_size=bs, max_len=max_len, num_heads=cfg.num_heads,
+        head_dim=cfg.head_dim, dtype=cfg.dtype)
+    pool = on_chip(jax.ShapeDtypeStruct(spec.shape, spec.dtype))
     i32 = lambda *shape: on_chip(jax.ShapeDtypeStruct(shape, jnp.int32))
 
     programs = {
@@ -186,7 +191,18 @@ def serving_steps(device, cfg):
     for name, (fn, args) in programs.items():
         t0 = time.perf_counter()
         compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile()
-        report(name, compiled, time.perf_counter() - t0)
+        text = report(name, compiled, time.perf_counter() - t0)
+        # The write is in place: nothing but parameters, tuples, the layer
+        # loop, bitcasts and the aliased kernels is as large as a layer
+        # of the pool, and both pools are aliased.
+        handled = collections.Counter(
+            op for op, _ in ops_in_units_of(text, math.prod(spec.shape[2:])))
+        print(f"[{name}] pool-sized instructions: {dict(handled)}; pool "
+              f"{spec.nbytes():,} B, aliased "
+              f"{compiled.memory_analysis().alias_size_in_bytes:,} B",
+              flush=True)
+        assert not set(handled) - {"parameter", "tuple", "while", "bitcast",
+                                   "get-tuple-element", "custom-call"}
 
 
 def main():
