@@ -33,7 +33,7 @@ def pick_model():
     full fp32 Adam state fits one chip's HBM (gpt2-xl at 1.5B needs
     18.7 GB of optimizer state alone — the reference pairs 1.5B with
     ZeRO-Offload for the same reason, BASELINE.json configs[3]).
-    Unrolled layers + chunked CE head (see ablate.py history)."""
+    Unrolled layers + chunked CE head."""
     from deepspeed_tpu.models import GPT2_CONFIGS
     return dataclasses.replace(
         GPT2_CONFIGS["gpt2-large"], max_seq_length=1024,
@@ -389,8 +389,7 @@ def main():
         # DS_BENCH_FUSED (default on): single-pass Pallas multi-tensor
         # optimizer apply (ops/fused_update.py) — one HBM pass over
         # grad+param+m+v with clip + SR folded in, vs the optax chain's
-        # per-leaf fusions. Parity: tests/test_fused_update.py; apply-only
-        # delta: ablate_fused_update.py.
+        # per-leaf fusions. Parity: tests/test_fused_update.py.
         "optimizer": {"type": "AdamW",
                       "params": {"lr": 1e-4, "fused": os.environ.get(
                           "DS_BENCH_FUSED", "1") == "1"}},

@@ -384,8 +384,14 @@ def collective_placement_pass(ctx: LintContext) -> List[LintFinding]:
     if not scatterable or ctx.audit is None:
         return out
     expects_rs = mode in ("explicit", "declarative")
+    # A gradient is told by what the op carries, not by its bytes alone:
+    # XLA's all-reduce combiner merges scalar statistics (the health
+    # tap's per-leaf sums, a loss mean) into one tuple all-reduce whose
+    # bytes can equal a small leaf's (four f32[] = one f32[4]), and a
+    # scatterable leaf is never rank 0.
     grad_ars = [o for o in ctx.audit.of_kind("all-reduce")
-                if o.payload_bytes in scatterable]
+                if o.payload_bytes in scatterable
+                and not all(s.endswith("[]") for s in o.out_shapes)]
     grad_rs = [o for o in ctx.audit.of_kind("reduce-scatter")
                if o.payload_bytes in scatterable]
     # Factored replica hierarchy (multislice slices > 1, or the MoE

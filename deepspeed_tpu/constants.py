@@ -320,16 +320,15 @@ INFERENCE_PREFILL_CHUNK_DEFAULT = 32
 # fixed-size blocks and a slot holds a list of block ids, so short and
 # long requests share HBM and common prompt prefixes are shared
 # copy-on-write across requests (full-block granularity, chain-hashed).
-# block_size is the tokens-per-block page size; 0 = the PR-7 slot-major
-# layout (one max_seq_len row per slot, no sharing). Must divide
-# max_seq_len.
+# block_size is the tokens-per-block page size: a positive int that
+# divides max_seq_len.
 INFERENCE_BLOCK_SIZE = "block_size"
 INFERENCE_BLOCK_SIZE_DEFAULT = 16
 # Total blocks in the pool; 0 = full provisioning (max_slots *
 # max_seq_len / block_size — every slot can reach max_seq_len, so
 # admission never blocks on HBM). Smaller pools oversubscribe: the
-# scheduler's admission gate then accounts free blocks, and the HBM
-# saved is what SERVE_BENCH.json's hbm_bytes_per_token measures. Must
+# scheduler's admission gate then accounts free blocks (the serving
+# snapshot's hbm_bytes_per_token is the HBM held per context token). Must
 # be divisible by the mesh dp-axis size (blocks are born sharded over
 # dp alongside the slots they serve).
 INFERENCE_NUM_BLOCKS = "num_blocks"
@@ -341,7 +340,6 @@ INFERENCE_NUM_BLOCKS_DEFAULT = 0
 # corrected token. Greedy output is bit-identical to non-speculative
 # greedy decode; the scheduler falls back to plain decode when
 # temperature > 0 (exact rejection sampling is not implemented).
-# Requires the paged cache (block_size > 0).
 INFERENCE_SPEC_K = "spec_k"
 INFERENCE_SPEC_K_DEFAULT = 0
 # n-gram context length the drafter matches against the slot's token
@@ -366,8 +364,7 @@ INFERENCE_REPLICA_DEFAULT = ""
 # False force it; "auto" enables on TPU only (the DS_PAGED_KERNEL env
 # var overrides "auto"). Forced on without a TPU the kernel runs in
 # interpret mode — same program, pure XLA — which is how the CPU-mesh
-# tier-1 proves logit parity. Ignored by slot-major engines
-# (block_size == 0).
+# tier-1 proves logit parity.
 INFERENCE_PAGED_KERNEL = "paged_kernel"
 INFERENCE_PAGED_KERNEL_DEFAULT = "auto"
 # inference.slo — serving SLO targets (monitor/serving_slo.py). A

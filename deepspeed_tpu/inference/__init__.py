@@ -18,8 +18,7 @@ multi-replica admission router (router.py). See
 docs/tutorials/inference.md.
 """
 from .engine import InferenceEngine
-from .kv_cache import (BlockAllocator, KVCacheSpec, PagedKVCacheSpec,
-                       PoolExhausted, cache_partition_spec, init_cache,
+from .kv_cache import (BlockAllocator, PagedKVCacheSpec, PoolExhausted,
                        init_paged_cache, paged_partition_spec)
 from .quantize import dequantize, quantize_params
 from .router import ReplicaRouter
@@ -28,9 +27,8 @@ from .scheduler import (ContinuousBatchingScheduler, Request,
 from .spec import NGramDrafter
 
 __all__ = [
-    "InferenceEngine", "KVCacheSpec", "PagedKVCacheSpec",
-    "BlockAllocator", "PoolExhausted", "cache_partition_spec",
-    "paged_partition_spec", "init_cache", "init_paged_cache",
+    "InferenceEngine", "PagedKVCacheSpec", "BlockAllocator",
+    "PoolExhausted", "paged_partition_spec", "init_paged_cache",
     "quantize_params", "dequantize", "Request", "synthetic_requests",
     "shared_prefix_requests", "ContinuousBatchingScheduler",
     "ReplicaRouter", "NGramDrafter",

@@ -1,41 +1,36 @@
-"""Incremental GPT-2 forward paths: single-token decode, chunked /
-whole-prompt prefill, and the speculative verify step — against the
-paged block pool (the production layout) or the slot-major cache (the
-PR-7 parity baseline).
+"""Incremental GPT-2 forward paths against the paged block pool:
+single-token decode, chunked / whole-prompt prefill, and the
+speculative verify step.
 
-The PAGED programs (``gpt2_decode_paged`` / ``gpt2_verify_paged`` /
-``gpt2_prefill_chunk_paged`` / ``gpt2_prefill_full_paged``) route every
-cache access through the block-table primitives in
+Four programs, each with a FIXED abstract signature (the recompile
+sentinel wraps all of them):
+
+- ``gpt2_verify_paged``: K tokens per slot, for every slot at once —
+  token i of slot s sits at position lengths[s] + i. Writes the K new
+  rows, attends each under its own causal row, and returns K-bounded
+  logits; with ``spec_accept`` it implements draft-then-verify
+  speculative decoding whose greedy output is bit-identical to
+  single-token decode.
+- ``gpt2_decode_paged``: the K=1 verify — one token per slot, LAST-
+  position logits only (the same tied-unembedding contraction
+  ``models.gpt2.gpt2_logits_at`` exposes for the batch path).
+- ``gpt2_prefill_chunk_paged``: one prompt chunk for ONE slot per group,
+  attended against the slot's whole cached row under a global-position
+  causal mask — so any chunk length divides any prompt without shape
+  polymorphism. Prefill and decode are separate programs on purpose
+  (prefill/decode disaggregation): a long admission never changes the
+  decode signature.
+- ``gpt2_prefill_full_paged``: the whole (padded) prompt in one shot
+  through the standard block math with a pluggable ``attention_fn`` —
+  this is where ring attention plugs in for long-context prefill when
+  the mesh has a sequence axis (``ops/ring_attention.ring_attention_fn``).
+
+Every cache access goes through the block-table primitives in
 ``inference/kv_cache.py``: group-batched over the mesh data axis, one
 compiled shape whatever the tables hold, no full-pool gather. The
 stacked pools ride the layer loop as a CARRY beside the layer index —
 new rows are written into them in place and the attend reads them where
-they lie, so no program slices, relays or rewrites the pool. The
-verify step generalizes decode to K tokens per slot and, with
-``spec_accept``, implements draft-then-verify speculative decoding
-whose greedy output is bit-identical to single-token decode.
-
-The SLOT-MAJOR programs below make up the PR-7 data plane, each with a
-FIXED abstract signature (the recompile sentinel wraps all of them):
-
-- ``gpt2_decode``: one token per slot, for every slot at once. Attends
-  against the cache only, computes LAST-position logits only (via the
-  same tied-unembedding contraction ``models.gpt2.gpt2_logits_at``
-  exposes for the batch path), and samples in-graph with a threaded
-  PRNG. Slots are independent along the leading axis, so GSPMD
-  partitions the step over the data axis without touching another
-  slot's cache.
-- ``gpt2_prefill_chunk``: one prompt chunk for ONE slot. Writes the
-  chunk's K/V into the slot via ``dynamic_update_slice`` and attends
-  against the slot's full cache row (prefix + the chunk itself) under a
-  global-position causal mask — so any chunk length divides any prompt
-  without shape polymorphism. Prefill and decode are separate programs
-  on purpose (prefill/decode disaggregation): a long admission never
-  changes the decode signature.
-- ``gpt2_prefill_full``: the whole (padded) prompt in one shot through
-  the standard block math with a pluggable ``attention_fn`` — this is
-  where ring attention plugs in for long-context prefill when the mesh
-  has a sequence axis (``ops/ring_attention.ring_attention_fn``).
+they lie, so no program slices, relays or rewrites the pool.
 
 All block math mirrors ``models/transformer.transformer_block`` for the
 deterministic pre-LN case (fp32 softmax, compute-dtype matmuls, same
@@ -111,173 +106,6 @@ def _qkv(p: Dict[str, jax.Array], x: jax.Array, cfg: GPT2Config
     return q.reshape(split), k.reshape(split), v.reshape(split)
 
 
-# --------------------------------------------------------------------- #
-# Decode: one token per slot, all slots at once
-# --------------------------------------------------------------------- #
-def _decode_block(p, x, kc, vc, lengths, cfg: GPT2Config):
-    """x [S, H]; kc/vc [S, nH, T, D]; lengths [S]. Returns (x', kc', vc').
-
-    The current token sits at position lengths[s]: its K/V are written
-    first, then attention runs over positions 0..lengths[s] inclusive —
-    exactly the causal row the full forward computes at that position.
-    """
-    S, H = x.shape
-    with jax.named_scope("attn"):
-        q, k, v = _qkv(p, x, cfg)                   # [S, nH, D] each
-        with jax.named_scope("kv_write"):
-            kc = kv_cache.write_token(kc, k, lengths)
-            vc = kv_cache.write_token(vc, v, lengths)
-        with jax.named_scope("attend"):
-            s = jnp.einsum("snd,sntd->snt", q, kc).astype(jnp.float32)
-            s = s / math.sqrt(cfg.head_dim)
-            mask = kv_cache.length_mask(lengths, kc.shape[2])   # [S, T]
-            s = jnp.where(mask[:, None, :], s, NEG_INF)
-            w = jax.nn.softmax(s, axis=-1)
-            attn = jnp.einsum("snt,sntd->snd", w.astype(vc.dtype), vc)
-        attn = attn.reshape(S, H).astype(x.dtype)
-        x = x + dense(attn, p["proj_kernel"], p["proj_bias"])
-    return _ffn(p, x, cfg), kc, vc
-
-
-def gpt2_decode(params: Dict[str, Any], kc: jax.Array, vc: jax.Array,
-                tokens: jax.Array, lengths: jax.Array, cfg: GPT2Config
-                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One decode step for every slot: tokens/lengths [S] → (logits
-    [S, V] fp32, kc', vc'). The caller advances lengths for the slots it
-    considers active; position = lengths[s] by construction."""
-    _check_cfg(cfg)
-    x = _embed(params, tokens, lengths, cfg)
-
-    def body(h, layer):
-        p, kcl, vcl = layer
-        h, kcl, vcl = _decode_block(p, h, kcl, vcl, lengths, cfg)
-        return h, (kcl, vcl)
-
-    x, (kc, vc) = lax.scan(body, x, (params["blocks"], kc, vc))
-    x = layer_norm_fn(cfg)(x, params["ln_f_scale"], params["ln_f_bias"])
-    logits = _unembed(params, x, cfg)
-    return logits, kc, vc
-
-
-# --------------------------------------------------------------------- #
-# Chunked prefill: one chunk of one slot's prompt
-# --------------------------------------------------------------------- #
-def _prefill_block(p, x, kc, vc, slot, start, cfg: GPT2Config):
-    """x [C, H]; writes the chunk's K/V at (slot, start) then attends
-    the chunk against the slot's whole cache row under the global causal
-    mask (col <= start + row)."""
-    C, H = x.shape
-    with jax.named_scope("attn"):
-        q, k, v = _qkv(p, x, cfg)                   # [C, nH, D]
-        with jax.named_scope("kv_write"):
-            kc = kv_cache.write_chunk(kc, k, slot, start)
-            vc = kv_cache.write_chunk(vc, v, slot, start)
-        with jax.named_scope("attend"):
-            krow = kv_cache.slot_rows(kc, slot)     # [nH, T, D]
-            vrow = kv_cache.slot_rows(vc, slot)
-            s = jnp.einsum("cnd,ntd->nct", q, krow).astype(jnp.float32)
-            s = s / math.sqrt(cfg.head_dim)
-            T = krow.shape[1]
-            rows = start + lax.broadcasted_iota(jnp.int32, (C, 1), 0)
-            cols = lax.broadcasted_iota(jnp.int32, (1, T), 1)
-            s = jnp.where((cols <= rows)[None], s, NEG_INF)
-            w = jax.nn.softmax(s, axis=-1)
-            attn = jnp.einsum("nct,ntd->cnd", w.astype(vrow.dtype), vrow)
-        attn = attn.reshape(C, H).astype(x.dtype)
-        x = x + dense(attn, p["proj_kernel"], p["proj_bias"])
-    return _ffn(p, x, cfg), kc, vc
-
-
-def gpt2_prefill_chunk(params: Dict[str, Any], kc: jax.Array,
-                       vc: jax.Array, tokens: jax.Array, slot: jax.Array,
-                       start: jax.Array, last_idx: jax.Array,
-                       cfg: GPT2Config
-                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Run one prompt chunk (tokens [C]) for one slot. Returns (logits
-    [V] fp32 at chunk position ``last_idx``, kc', vc').
-
-    Only ONE position projects through the unembedding (the
-    gpt2_logits_at memory contract: never a [C, vocab] tensor) — the
-    scheduler uses it on the final chunk to sample the first token;
-    earlier chunks compute it too (uniform program) and discard it.
-    Padding rows beyond the prompt inside the final chunk produce
-    garbage that nothing reads: causal masking keeps them out of every
-    real row, and the next token's decode write overwrites their cache
-    rows before any attend reaches them.
-    """
-    _check_cfg(cfg)
-    C = tokens.shape[0]
-    pos = start + jnp.arange(C, dtype=jnp.int32)
-    x = _embed(params, tokens, pos, cfg)
-
-    def body(h, layer):
-        p, kcl, vcl = layer
-        h, kcl, vcl = _prefill_block(p, h, kcl, vcl, slot, start, cfg)
-        return h, (kcl, vcl)
-
-    x, (kc, vc) = lax.scan(body, x, (params["blocks"], kc, vc))
-    x = layer_norm_fn(cfg)(x, params["ln_f_scale"], params["ln_f_bias"])
-    h_last = lax.dynamic_slice(x, (last_idx.astype(jnp.int32),
-                                   jnp.int32(0)), (1, x.shape[1]))[0]
-    logits = _unembed(params, h_last, cfg)
-    return logits, kc, vc
-
-
-# --------------------------------------------------------------------- #
-# Whole-prompt prefill (prefill_chunk: 0) — the long-context path
-# --------------------------------------------------------------------- #
-def gpt2_prefill_full(params: Dict[str, Any], kc: jax.Array,
-                      vc: jax.Array, tokens: jax.Array, slot: jax.Array,
-                      last_idx: jax.Array, cfg: GPT2Config,
-                      attention_fn: Optional[Callable] = None
-                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Single-shot prefill of one slot: tokens [T] padded to the cache's
-    max_len. The self-attention over the prompt runs through the
-    pluggable ``attention_fn`` — ring attention when the mesh has a
-    sequence axis (exact long-context prefill at 1/sp memory per chip),
-    the dense/flash default otherwise. Per-layer K/V come out of the
-    same scan as the hidden states and splice into the cache with one
-    dynamic_update_slice over all layers."""
-    _check_cfg(cfg)
-    if attention_fn is None:
-        from ..ops.flash_attention import auto_attention
-        attention_fn = auto_attention
-    T = tokens.shape[0]
-    x = _embed(params, tokens, slice(T), cfg)[None]        # [1, T, H]
-
-    def body(h, p):
-        with jax.named_scope("attn"):
-            q, k, v = _qkv(p, h, cfg)              # [1, T, nH, D]
-            with jax.named_scope("attend"):
-                attn = attention_fn(q, k, v, mask=None, causal=True,
-                                    deterministic=True)
-            attn = attn.reshape(h.shape).astype(h.dtype)
-            h = h + dense(attn, p["proj_kernel"], p["proj_bias"])
-        return _ffn(p, h, cfg), (k[0], v[0])       # ys: [T, nH, D]
-
-    x, (ks, vs) = lax.scan(body, x, params["blocks"])
-    # ks/vs [L, T, nH, D] → cache block [L, 1, nH, T, D] at slot.
-    zero = jnp.int32(0)
-    at = (zero, slot.astype(jnp.int32), zero, zero, zero)
-    with jax.named_scope("kv_write"):
-        kc = lax.dynamic_update_slice(
-            kc, ks.transpose(0, 2, 1, 3)[:, None].astype(kc.dtype), at)
-        vc = lax.dynamic_update_slice(
-            vc, vs.transpose(0, 2, 1, 3)[:, None].astype(vc.dtype), at)
-    x = layer_norm_fn(cfg)(x[0], params["ln_f_scale"],
-                           params["ln_f_bias"])
-    h_last = lax.dynamic_slice(x, (last_idx.astype(jnp.int32),
-                                   jnp.int32(0)), (1, x.shape[1]))[0]
-    logits = _unembed(params, h_last, cfg)
-    return logits, kc, vc
-
-
-# ===================================================================== #
-# Paged paths: decode / chunked prefill / speculative verify through the
-# block-table indirection (inference/kv_cache.py paged primitives).
-# Everything is group-batched over the mesh data axis; ONE compiled
-# shape each, whatever the block tables hold.
-# ===================================================================== #
 def _group_shape(arr: jax.Array, num_groups: int) -> jax.Array:
     """[S, ...] → [G, S/G, ...]: split the slot axis into (group,
     slot-in-group) — a local reshape under the slots-over-dp sharding."""
@@ -415,8 +243,8 @@ def gpt2_decode_paged(params: Dict[str, Any], kc: jax.Array,
                       paged_kernel: bool = False, mesh=None
                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One paged decode step for every slot: the K=1 verify. Returns
-    (logits [S, V] fp32, kc', vc') — same contract as ``gpt2_decode``
-    with the block table standing in for the slot-major rows."""
+    (logits [S, V] fp32, kc', vc'). The caller advances lengths for the
+    slots it considers active; position = lengths[s] by construction."""
     logits, kc, vc = gpt2_verify_paged(params, kc, vc, tokens[:, None],
                                        lengths, block_tables, cfg,
                                        num_groups, paged_kernel, mesh)
@@ -431,7 +259,7 @@ def gpt2_prefill_chunk_paged(params: Dict[str, Any], kc: jax.Array,
                              paged_kernel: bool = False, mesh=None
                              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Group-batched chunked prefill: one prompt chunk for ONE slot per
-    group (the paged twin of ``gpt2_prefill_chunk``).
+    group.
 
     tokens: [G, C]; bt_rows: [G, J] — each group's target slot's block
     table row (DEAD_BLOCK rows for groups with nothing to prefill);
@@ -441,6 +269,15 @@ def gpt2_prefill_chunk_paged(params: Dict[str, Any], kc: jax.Array,
     ``last_idx``, kc', vc'). Inactive groups compute garbage that
     writes nowhere — the uniform-program rule that keeps ONE compiled
     shape for any admission pattern.
+
+    Only ONE position per group projects through the unembedding (the
+    gpt2_logits_at memory contract: never a [C, vocab] tensor) — the
+    scheduler uses it on the final chunk to sample the first token;
+    earlier chunks compute it too (uniform program) and discard it.
+    Padding rows beyond the prompt inside the final chunk produce
+    garbage that nothing reads: causal masking keeps them out of every
+    real row, and the next token's decode write overwrites their cache
+    rows before any attend reaches them.
     """
     _check_cfg(cfg)
     G, C = tokens.shape
@@ -466,13 +303,15 @@ def gpt2_prefill_full_paged(params: Dict[str, Any], kc: jax.Array,
                             mesh=None
                             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Whole-prompt single-shot prefill (``prefill_chunk: 0``) into the
-    block pool: the same pluggable-attention forward as
-    ``gpt2_prefill_full`` (ring attention plugs in identically), with
-    each layer's K/V rows written through the target slot's block table
-    by the same in-place write the decode step uses (R = the whole
-    padded prompt). tokens: [T] padded to max_len; bt_rows: [G, J] — the
-    slot's row in its own group, DEAD_BLOCK rows elsewhere, so the write
-    lands only in the owning dp shard."""
+    block pool. The self-attention over the prompt runs through the
+    pluggable ``attention_fn`` — ring attention when the mesh has a
+    sequence axis (exact long-context prefill at 1/sp memory per chip),
+    the dense/flash default otherwise — with each layer's K/V rows
+    written through the target slot's block table by the same in-place
+    write the decode step uses (R = the whole padded prompt). tokens:
+    [T] padded to max_len; bt_rows: [G, J] — the slot's row in its own
+    group, DEAD_BLOCK rows elsewhere, so the write lands only in the
+    owning dp shard."""
     _check_cfg(cfg)
     if attention_fn is None:
         from ..ops.flash_attention import auto_attention
@@ -548,7 +387,6 @@ def sample_tokens(logits: jax.Array, key: jax.Array,
     return jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
 
 
-__all__ = ["gpt2_decode", "gpt2_prefill_chunk", "gpt2_prefill_full",
-           "gpt2_decode_paged", "gpt2_verify_paged",
+__all__ = ["gpt2_decode_paged", "gpt2_verify_paged",
            "gpt2_prefill_chunk_paged", "gpt2_prefill_full_paged",
            "spec_accept", "sample_tokens"]

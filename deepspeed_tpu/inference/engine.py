@@ -12,8 +12,8 @@ Architecture (mirrors the training engine's discipline):
   and eviction never touch a compiled shape.
 - The KV cache (inference/kv_cache.py) is born sharded: slots over the
   mesh data axis, heads over the model axis. Its buffers are DONATED
-  through every step, so the cache exists once — and a paged step
-  writes its new K/V rows into those buffers where they lie (no program
+  through every step, so the cache exists once — and a step writes
+  its new K/V rows into those buffers where they lie (no program
   slices, relays or rewrites the pool: a step's cost does not depend on
   ``num_blocks``).
 - Host-side per-slot counters (lengths, active, last token) are the
@@ -105,23 +105,20 @@ class InferenceEngine:
                 f"whole-prompt prefill with a seq axis needs max_seq_len "
                 f"({self.max_len}) divisible by sp={self.sp}")
         self.block_size = int(self.icfg.block_size)
-        self.paged = self.block_size > 0
-        if self.paged and self.max_len % self.block_size:
+        if self.max_len % self.block_size:
             raise ValueError(
                 f"inference.block_size={self.block_size} must divide "
-                f"inference.max_seq_len ({self.max_len}); set "
-                "block_size: 0 for the slot-major layout")
+                f"inference.max_seq_len ({self.max_len})")
         self.spec_k = int(self.icfg.spec_k)
         self.replica = str(self.icfg.replica)
         self.num_blocks = int(self.icfg.num_blocks)
-        if self.paged and self.num_blocks == 0:
+        if self.num_blocks == 0:
             # Full provisioning: every slot can reach max_len, so
-            # admission never blocks on HBM (the PR-7-equivalent
-            # capacity); smaller pools oversubscribe and the admission
-            # gate accounts free blocks.
+            # admission never blocks on HBM; smaller pools oversubscribe
+            # and the admission gate accounts free blocks.
             self.num_blocks = self.max_slots * \
                 (self.max_len // self.block_size)
-        if self.paged and self.num_blocks % self.dp:
+        if self.num_blocks % self.dp:
             raise ValueError(
                 f"inference.num_blocks={self.num_blocks} must be "
                 f"divisible by the mesh data axis ({self.dp}) — blocks "
@@ -129,9 +126,8 @@ class InferenceEngine:
         # Pallas paged-attention kernel vs the one-hot pool contraction.
         # Resolved ONCE here: the compiled paths bake the choice in, so
         # flipping the env var mid-flight cannot desync the sentinel.
-        self.paged_kernel = bool(
-            self.paged and paged_attn_ops.paged_kernel_enabled(
-                self.icfg.paged_kernel))
+        self.paged_kernel = bool(paged_attn_ops.paged_kernel_enabled(
+            self.icfg.paged_kernel))
 
         # --- weights: quantize, then commit to the mesh ---
         self.quantize = self.icfg.quantize
@@ -151,35 +147,22 @@ class InferenceEngine:
         self._params = jax.device_put(params, shardings)
         self.param_bytes = quantized_bytes(self._params)
 
-        # --- the KV cache, born sharded: paged block pool (production)
-        # or the PR-7 slot-major rows (block_size: 0 — the parity
-        # baseline) ---
+        # --- the KV cache: the paged block pool, born sharded ---
         kv_dtype = resolve_kv_dtype(self.icfg.kv_cache_dtype,
                                     model_cfg.dtype)
-        if self.paged:
-            self.cache_spec = kv_cache.PagedKVCacheSpec(
-                num_layers=model_cfg.num_layers,
-                num_slots=self.max_slots, num_blocks=self.num_blocks,
-                block_size=self.block_size, max_len=self.max_len,
-                num_heads=model_cfg.num_heads,
-                head_dim=model_cfg.head_dim, num_groups=self.dp,
-                dtype=kv_dtype)
-            self.cache = kv_cache.init_paged_cache(self.cache_spec,
-                                                   self.mesh)
-            self._cache_sh = kv_cache.paged_shardings(self.mesh)
-            self.allocator = kv_cache.BlockAllocator(self.cache_spec)
-            self.block_tables = np.full(
-                (self.max_slots, self.cache_spec.max_blocks_per_slot),
-                kv_cache.DEAD_BLOCK, np.int32)
-        else:
-            self.cache_spec = kv_cache.KVCacheSpec(
-                num_layers=model_cfg.num_layers, num_slots=self.max_slots,
-                num_heads=model_cfg.num_heads, max_len=self.max_len,
-                head_dim=model_cfg.head_dim, dtype=kv_dtype)
-            self.cache = kv_cache.init_cache(self.cache_spec, self.mesh)
-            self._cache_sh = kv_cache.cache_shardings(self.mesh)
-            self.allocator = None
-            self.block_tables = None
+        self.cache_spec = kv_cache.PagedKVCacheSpec(
+            num_layers=model_cfg.num_layers,
+            num_slots=self.max_slots, num_blocks=self.num_blocks,
+            block_size=self.block_size, max_len=self.max_len,
+            num_heads=model_cfg.num_heads,
+            head_dim=model_cfg.head_dim, num_groups=self.dp,
+            dtype=kv_dtype)
+        self.cache = kv_cache.init_paged_cache(self.cache_spec, self.mesh)
+        self._cache_sh = kv_cache.paged_shardings(self.mesh)
+        self.allocator = kv_cache.BlockAllocator(self.cache_spec)
+        self.block_tables = np.full(
+            (self.max_slots, self.cache_spec.max_blocks_per_slot),
+            kv_cache.DEAD_BLOCK, np.int32)
         self.drafter = NGramDrafter(self.spec_k, self.icfg.spec_ngram) \
             if self.spec_k > 0 else None
         self._spec_proposed = 0
@@ -208,53 +191,52 @@ class InferenceEngine:
                         max_slots=self.max_slots, max_seq_len=self.max_len,
                         prefill_chunk=self.prefill_chunk,
                         block_size=self.block_size,
-                        num_blocks=self.num_blocks if self.paged else 0,
+                        num_blocks=self.num_blocks,
                         spec_k=self.spec_k,
                         replica=self.replica,
                         quantize=self.quantize,
                         precision=jnp.dtype(model_cfg.dtype).name,
                         param_bytes=self.param_bytes,
                         kv_cache_bytes=self.cache_spec.nbytes())
-        if self.paged:
-            # Analytic attend pricing (both ways, per generated token at
-            # the bounds): the kernel term scales with live context
-            # (ceil(ctx/bs)*bs — quoted at ctx = max_seq_len), the
-            # one-hot term with pool CAPACITY. Projections, not device
-            # measurements — the structural ratio SERVE_BENCH reports.
-            self.serving.attend_mode = ("kernel" if self.paged_kernel
-                                        else "onehot")
-            sp_ = self.cache_spec
-            kvi = jnp.dtype(sp_.dtype).itemsize
-            tel_meta["paged_kernel"] = self.paged_kernel
-            # The write always engages (one path), so its counter is
-            # static: rows go into the donated pool in place, one block
-            # tile read and written per row and pool. What the compiled
-            # programs make of it (pools aliased, no pool-sized op) is
-            # asserted in tests/test_tpu_compile.py.
-            tel_meta["kv_write"] = {
-                "mode": "in_place", "fold": sp_.fold,
-                "pool_shape": list(sp_.shape),
-                "tile_bytes": sp_.block_nbytes() // (2 * sp_.num_layers),
-                "rows_per_decode": self.max_slots * (self.spec_k + 1)}
-            tel_meta["attend_flops_per_token"] = {
-                "live_ctx_max": paged_attn_ops.attend_flops_per_token(
-                    sp_.num_heads, sp_.head_dim, sp_.block_size,
-                    context=sp_.max_len, num_layers=sp_.num_layers),
-                "pool_capacity": paged_attn_ops.attend_flops_per_token(
-                    sp_.num_heads, sp_.head_dim, sp_.block_size,
-                    pool_blocks=sp_.blocks_per_group,
-                    num_layers=sp_.num_layers),
-                "projection": "analytic"}
-            tel_meta["attend_hbm_bytes_per_token"] = {
-                "live_ctx_max": paged_attn_ops.attend_hbm_bytes_per_token(
-                    sp_.num_heads, sp_.head_dim, sp_.block_size,
-                    context=sp_.max_len, kv_itemsize=kvi,
-                    num_layers=sp_.num_layers),
-                "pool_capacity": paged_attn_ops.attend_hbm_bytes_per_token(
-                    sp_.num_heads, sp_.head_dim, sp_.block_size,
-                    pool_blocks=sp_.blocks_per_group, kv_itemsize=kvi,
-                    num_layers=sp_.num_layers),
-                "projection": "analytic"}
+        # Analytic attend pricing (both ways, per generated token at
+        # the bounds): the kernel term scales with live context
+        # (ceil(ctx/bs)*bs — quoted at ctx = max_seq_len), the
+        # one-hot term with pool CAPACITY. Projections, not device
+        # measurements.
+        self.serving.attend_mode = ("kernel" if self.paged_kernel
+                                    else "onehot")
+        sp_ = self.cache_spec
+        kvi = jnp.dtype(sp_.dtype).itemsize
+        tel_meta["paged_kernel"] = self.paged_kernel
+        # The write always engages (one path), so its counter is
+        # static: rows go into the donated pool in place, one block
+        # tile read and written per row and pool. What the compiled
+        # programs make of it (pools aliased, no pool-sized op) is
+        # asserted in tests/test_tpu_compile.py.
+        tel_meta["kv_write"] = {
+            "mode": "in_place", "fold": sp_.fold,
+            "pool_shape": list(sp_.shape),
+            "tile_bytes": sp_.block_nbytes() // (2 * sp_.num_layers),
+            "rows_per_decode": self.max_slots * (self.spec_k + 1)}
+        tel_meta["attend_flops_per_token"] = {
+            "live_ctx_max": paged_attn_ops.attend_flops_per_token(
+                sp_.num_heads, sp_.head_dim, sp_.block_size,
+                context=sp_.max_len, num_layers=sp_.num_layers),
+            "pool_capacity": paged_attn_ops.attend_flops_per_token(
+                sp_.num_heads, sp_.head_dim, sp_.block_size,
+                pool_blocks=sp_.blocks_per_group,
+                num_layers=sp_.num_layers),
+            "projection": "analytic"}
+        tel_meta["attend_hbm_bytes_per_token"] = {
+            "live_ctx_max": paged_attn_ops.attend_hbm_bytes_per_token(
+                sp_.num_heads, sp_.head_dim, sp_.block_size,
+                context=sp_.max_len, kv_itemsize=kvi,
+                num_layers=sp_.num_layers),
+            "pool_capacity": paged_attn_ops.attend_hbm_bytes_per_token(
+                sp_.num_heads, sp_.head_dim, sp_.block_size,
+                pool_blocks=sp_.blocks_per_group, kv_itemsize=kvi,
+                num_layers=sp_.num_layers),
+            "projection": "analytic"}
         self.telemetry = Telemetry(
             self.tcfg, default_report_steps=50, meta=tel_meta)
         _ref = weakref.ref(self)
@@ -263,27 +245,25 @@ class InferenceEngine:
         self.telemetry.set_analytic_footprint(analytic_state_bytes(
             {"params": self._params, "cache": self.cache}))
 
-        # --- the compiled paths (sentinel-instrumented): decode +
-        # prefill always; paged engines add the copy-on-write block copy
-        # and, with spec_k > 0, the speculative verify step. Each has
-        # ONE abstract signature for the engine's lifetime ---
+        # --- the compiled paths (sentinel-instrumented): decode, prefill,
+        # the copy-on-write block copy and, with spec_k > 0, the
+        # speculative verify step. Each has ONE abstract signature for
+        # the engine's lifetime ---
         self._decode_fn = self.telemetry.instrument_step_fn(
             "decode_step", self._build_decode_step())
         self._prefill_fn = self.telemetry.instrument_step_fn(
             "prefill_step", self._build_prefill_step())
-        if self.paged:
-            self._copy_fn = self.telemetry.instrument_step_fn(
-                "copy_block", self._build_copy_block())
-        if self.paged and self.spec_k > 0:
+        self._copy_fn = self.telemetry.instrument_step_fn(
+            "copy_block", self._build_copy_block())
+        if self.spec_k > 0:
             self._verify_fn = self.telemetry.instrument_step_fn(
                 "verify_step", self._build_verify_step())
 
-        layout = (f"paged bs={self.block_size} x{self.num_blocks} blocks"
-                  if self.paged else "slot-major")
         log_dist(
             f"InferenceEngine initialized: {model_cfg.name}, "
             f"slots={self.max_slots} (dp={self.dp}), "
-            f"cache={layout} {self.max_len}x{model_cfg.num_heads}h "
+            f"cache=paged bs={self.block_size} x{self.num_blocks} blocks "
+            f"{self.max_len}x{model_cfg.num_heads}h "
             f"({self.cache_spec.nbytes() / 2 ** 20:.1f} MiB K+V), "
             f"prefill={'full' if self.prefill_chunk == 0 else f'chunk {self.prefill_chunk}'}, "
             f"spec_k={self.spec_k}, quantize={self.quantize}"
@@ -307,14 +287,9 @@ class InferenceEngine:
         def decode_step(params, kc, vc, tokens, lengths, bt, key,
                         temperature):
             p = self._runtime_params(params)
-            if self.paged:
-                logits, kc, vc = decode_mod.gpt2_decode_paged(
-                    p, kc, vc, tokens, lengths, bt, cfg, dp,
-                    paged_kernel=self.paged_kernel, mesh=self.mesh)
-            else:
-                logits, kc, vc = decode_mod.gpt2_decode(p, kc, vc,
-                                                        tokens, lengths,
-                                                        cfg)
+            logits, kc, vc = decode_mod.gpt2_decode_paged(
+                p, kc, vc, tokens, lengths, bt, cfg, dp,
+                paged_kernel=self.paged_kernel, mesh=self.mesh)
             sampled = decode_mod.sample_tokens(logits, key, temperature)
             return kc, vc, sampled, logits
 
@@ -331,7 +306,7 @@ class InferenceEngine:
             attention_fn = ring_attention_fn(self.mesh)
         sh = self._cache_sh
 
-        if self.paged and self.prefill_chunk > 0:
+        if self.prefill_chunk > 0:
             # Group-batched chunked prefill: one chunk of one slot per
             # dp group (single admissions leave the other groups' rows
             # DEAD — uniform program, writes land nowhere).
@@ -345,27 +320,13 @@ class InferenceEngine:
                 sampled = decode_mod.sample_tokens(logits, key,
                                                    temperature)
                 return kc, vc, sampled, logits
-        elif self.paged:
+        else:
             def prefill_step(params, kc, vc, tokens, bt_rows, last_idx,
                              key, temperature):
                 p = self._runtime_params(params)
                 logits, kc, vc = decode_mod.gpt2_prefill_full_paged(
                     p, kc, vc, tokens, bt_rows, last_idx, cfg,
                     attention_fn=attention_fn, mesh=self.mesh)
-                sampled = decode_mod.sample_tokens(logits, key,
-                                                   temperature)
-                return kc, vc, sampled, logits
-        else:
-            def prefill_step(params, kc, vc, tokens, slot, start,
-                             last_idx, key, temperature):
-                p = self._runtime_params(params)
-                if self.prefill_chunk == 0:
-                    logits, kc, vc = decode_mod.gpt2_prefill_full(
-                        p, kc, vc, tokens, slot, last_idx, cfg,
-                        attention_fn=attention_fn)
-                else:
-                    logits, kc, vc = decode_mod.gpt2_prefill_chunk(
-                        p, kc, vc, tokens, slot, start, last_idx, cfg)
                 sampled = decode_mod.sample_tokens(logits, key,
                                                    temperature)
                 return kc, vc, sampled, logits
@@ -426,19 +387,18 @@ class InferenceEngine:
             self.drafter.observe(slot, [int(last_token)])
 
     def release_slot(self, slot: int) -> None:
-        """Evict: counters clear and (paged) every block reference
-        drops — private blocks return to the free list, prefix blocks
-        whose refcount hits zero are LRU-retained for future hits. The
-        stale rows are dead by masking either way."""
+        """Evict: counters clear and every block reference drops —
+        private blocks return to the free list, prefix blocks whose
+        refcount hits zero are LRU-retained for future hits. The stale
+        rows are dead by masking."""
         self.active[slot] = False
         self.lengths[slot] = 0
         self.last_tokens[slot] = 0
         self._held.discard(slot)
-        if self.paged:
-            row = self.block_tables[slot]
-            self.allocator.release(
-                slot, [int(b) for b in row if b != kv_cache.DEAD_BLOCK])
-            row[:] = kv_cache.DEAD_BLOCK
+        row = self.block_tables[slot]
+        self.allocator.release(
+            slot, [int(b) for b in row if b != kv_cache.DEAD_BLOCK])
+        row[:] = kv_cache.DEAD_BLOCK
         if self.drafter is not None:
             self.drafter.reset(slot)
 
@@ -451,7 +411,7 @@ class InferenceEngine:
 
     @property
     def spec_enabled(self) -> bool:
-        return self.paged and self.spec_k > 0
+        return self.spec_k > 0
 
     def _ensure_blocks(self, slot: int, upto_pos: int) -> None:
         """Lazily allocate table entries so ``slot`` can write token
@@ -470,8 +430,7 @@ class InferenceEngine:
     # ------------------------------------------------------------------ #
     def group_of(self, slot: int) -> int:
         """The dp group (pool shard) a slot's blocks live in."""
-        return slot // self.cache_spec.slots_per_group if self.paged \
-            else 0
+        return slot // self.cache_spec.slots_per_group
 
     def select_slot(self, prompt: Sequence[int],
                     max_new_tokens: int = 0,
@@ -480,9 +439,9 @@ class InferenceEngine:
         """Pick and HOLD a free slot for this prompt, or None when the
         engine cannot admit it now.
 
-        Paged engines extend the gate from slot occupancy to HBM
-        accounting: a group must cover the request's worst-case block
-        need (``BlockAllocator.can_admit``), and among admissible
+        The gate is slot occupancy AND HBM accounting: a group must
+        cover the request's worst-case block need
+        (``BlockAllocator.can_admit``), and among admissible
         groups the one already holding the longest cached prefix of
         this prompt wins (prefix affinity — the request lands where its
         blocks live), ties broken toward the most available HBM. The
@@ -496,9 +455,6 @@ class InferenceEngine:
         if not free:
             self.last_admit_block = "no_slot"
             return None
-        if not self.paged:
-            self._held.add(free[0])
-            return free[0]
         share = self.prefill_chunk > 0
         Sg = self.cache_spec.slots_per_group
         first_free: Dict[int, int] = {}
@@ -527,7 +483,8 @@ class InferenceEngine:
 
     def last_admit_info(self, slot: int) -> Dict[str, Any]:
         """Prefix-cache/CoW detail of the most recent admission into
-        ``slot`` (for the request trace); empty for slot-major paths."""
+        ``slot`` (for the request trace); empty for whole-prompt
+        prefill, which shares nothing."""
         return self._last_admit.get(slot, {})
 
     def note_admission_reject(self, rid: Any, reason: str, attempt: int,
@@ -547,7 +504,7 @@ class InferenceEngine:
         """Longest cached prompt prefix (tokens) resident anywhere in
         this engine's block pool — the router's affinity signal. Host
         hash walk only; zero device work."""
-        if not self.paged or self.prefill_chunk == 0:
+        if self.prefill_chunk == 0:
             return 0
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         best = 0
@@ -569,9 +526,9 @@ class InferenceEngine:
         per-admission [V] fetch would be a wasted host transfer). The
         caller activates the slot (scheduler owns admission ordering).
 
-        Paged engines first admit the prompt through the block
-        allocator: cached full-block prefixes are shared by refcount
-        (only the tail re-prefills — the TTFT win), an exactly-matched
+        The prompt is first admitted through the block allocator:
+        cached full-block prefixes are shared by refcount (only the
+        tail re-prefills — the TTFT win), an exactly-matched
         chain forks its final block copy-on-write before the first
         write, and ``max_new_tokens`` (the scheduler passes the
         request's) books the worst-case HBM reservation so mid-flight
@@ -580,7 +537,7 @@ class InferenceEngine:
 
         ``rid`` only labels the ``prefill`` host span (see
         ``prefill_many``)."""
-        if self.paged and self.prefill_chunk > 0:
+        if self.prefill_chunk > 0:
             return self.prefill_many(
                 [(slot, prompt, int(max_new_tokens or 0))], temperature,
                 return_logits=return_logits,
@@ -596,46 +553,31 @@ class InferenceEngine:
                 f"prompt length {plen} leaves no room to generate in a "
                 f"{self.max_len}-token slot")
         kc, vc = self.cache["k"], self.cache["v"]
-        temp = np.float32(temperature)
-        chunk = self.prefill_chunk or self.max_len
-        n_chunks = -(-plen // chunk) if self.prefill_chunk else 1
         with tl.span("prefill", slots=1, prompt_tokens=plen,
-                     cached_tokens=0, chunks=n_chunks,
+                     cached_tokens=0, chunks=1,
                      rids=ids_arg(None if rid is None else [rid])):
-            padded = np.zeros(n_chunks * chunk, np.int32)
+            padded = np.zeros(self.max_len, np.int32)
             padded[:plen] = prompt
-            if not self.paged:
-                tok = logits = None
-                for ci in range(n_chunks):
-                    start = ci * chunk
-                    last = ci == n_chunks - 1
-                    last_idx = (plen - 1 - start) if last else 0
-                    with tl.span("prefill_chunk", ci=ci, active_groups=1):
-                        kc, vc, tok, logits = self._prefill_fn(
-                            self._params, kc, vc,
-                            padded[start:start + chunk], np.int32(slot),
-                            np.int32(start), np.int32(last_idx),
-                            self._next_key(), temp)
-            else:
-                G = self.dp
-                J = self.cache_spec.max_blocks_per_slot
-                group = slot // self.cache_spec.slots_per_group
-                with tl.span("prefill_plan"):
-                    plan = self.allocator.admit_prompt(
-                        slot, group, prompt, int(max_new_tokens or 0),
-                        self.spec_k, share=False)
-                    row = np.full(J, kv_cache.DEAD_BLOCK, np.int32)
-                    row[:len(plan.table)] = plan.table
-                    self.block_tables[slot] = row
-                    bt_rows = np.full((G, J), kv_cache.DEAD_BLOCK, np.int32)
-                    bt_rows[group] = row
-                with tl.span("prefill_chunk", ci=0, active_groups=1):
-                    kc, vc, tok, logits = self._prefill_fn(
-                        self._params, kc, vc, padded, bt_rows,
-                        np.int32(plen - 1), self._next_key(), temp)
-                if self.drafter is not None:
-                    self.drafter.begin(slot, prompt)
-                self.serving.note_admit(plen, 0)
+            G = self.dp
+            J = self.cache_spec.max_blocks_per_slot
+            group = slot // self.cache_spec.slots_per_group
+            with tl.span("prefill_plan"):
+                plan = self.allocator.admit_prompt(
+                    slot, group, prompt, int(max_new_tokens or 0),
+                    self.spec_k, share=False)
+                row = np.full(J, kv_cache.DEAD_BLOCK, np.int32)
+                row[:len(plan.table)] = plan.table
+                self.block_tables[slot] = row
+                bt_rows = np.full((G, J), kv_cache.DEAD_BLOCK, np.int32)
+                bt_rows[group] = row
+            with tl.span("prefill_chunk", ci=0, active_groups=1):
+                kc, vc, tok, logits = self._prefill_fn(
+                    self._params, kc, vc, padded, bt_rows,
+                    np.int32(plen - 1), self._next_key(),
+                    np.float32(temperature))
+            if self.drafter is not None:
+                self.drafter.begin(slot, prompt)
+            self.serving.note_admit(plen, 0)
             self.cache["k"], self.cache["v"] = kc, vc
             tl.raise_pending()
             with tl.span("prefill_fetch"):
@@ -671,9 +613,8 @@ class InferenceEngine:
         copy-on-write fork), one ``prefill_chunk`` (``ci``,
         ``active_groups``) per chunk program dispatched, and
         ``prefill_fetch`` (the first tokens' ``device_get``)."""
-        if not (self.paged and self.prefill_chunk > 0):
-            raise RuntimeError("prefill_many needs the paged cache and "
-                               "chunked prefill")
+        if self.prefill_chunk == 0:
+            raise RuntimeError("prefill_many needs chunked prefill")
         t_pf0 = time.perf_counter()
         tl = self.telemetry
         with tl.span("prefill", slots=len(admissions),
@@ -801,14 +742,11 @@ class InferenceEngine:
     def _cache_accounting(self) -> Tuple[int, int, int]:
         """(live blocks, cache bytes held, context tokens cached) this
         iteration — the hbm_bytes_per_token sample, and the ``decode``
-        span's ``live_blocks`` / ``context_tokens``. Slot-major reserves
-        the full cache whatever the contexts hold (no blocks: 0); paged
-        holds only live blocks."""
+        span's ``live_blocks`` / ``context_tokens``. Only live blocks
+        are held."""
         tokens = int(self.lengths[self.active].sum())
-        if self.paged:
-            live = self.allocator.blocks_in_use()
-            return live, live * self.cache_spec.block_nbytes(), tokens
-        return 0, self.cache_spec.nbytes(), tokens
+        live = self.allocator.blocks_in_use()
+        return live, live * self.cache_spec.block_nbytes(), tokens
 
     def _attend_work(self, k_rows: int) -> Tuple[int, int, int, int]:
         """Analytic attend work of the iteration just run, priced BOTH
@@ -852,17 +790,13 @@ class InferenceEngine:
         with tl.span("decode", iteration=self.iterations,
                      active=n_active) as span:
             with tl.span("decode_tables"):
-                if self.paged:
-                    for s in np.flatnonzero(self.active):
-                        self._ensure_blocks(int(s), int(self.lengths[s]))
-                    bt = self.block_tables
-                else:
-                    bt = np.int32(0)    # unused by the slot-major path
+                for s in np.flatnonzero(self.active):
+                    self._ensure_blocks(int(s), int(self.lengths[s]))
             with tl.span("decode_dispatch"):
                 kc, vc, sampled, logits = self._decode_fn(
                     self._params, self.cache["k"], self.cache["v"],
-                    self.last_tokens, self.lengths, bt, self._next_key(),
-                    np.float32(temperature))
+                    self.last_tokens, self.lengths, self.block_tables,
+                    self._next_key(), np.float32(temperature))
                 self.cache["k"], self.cache["v"] = kc, vc
                 tl.raise_pending()
             # THE serving sync: the host needs the tokens (EOS detection
@@ -885,7 +819,7 @@ class InferenceEngine:
                                             context_tokens=ctx_tokens)
                 if self.serving.ledger is not None:
                     self.serving.ledger.note("decode_useful", wall)
-                if self.paged and n_active:
+                if n_active:
                     self.serving.note_attend(*self._attend_work(1),
                                              n_active)
                 if tl.enabled:
@@ -916,7 +850,7 @@ class InferenceEngine:
         leading emitted tokens are real per slot; 0 for inactive)."""
         if not self.spec_enabled:
             raise RuntimeError("spec_decode_once needs inference.spec_k "
-                               "> 0 and the paged cache")
+                               "> 0")
         if float(temperature) > 0.0:
             raise ValueError(
                 "spec_decode_once is greedy-only (the acceptance rule "
@@ -1020,9 +954,8 @@ class InferenceEngine:
         stream — both sides of a comparison warm the same way)."""
         self.serving = ServingAggregator(self.max_slots,
                                          label=self.replica or None)
-        if self.paged:
-            self.serving.attend_mode = ("kernel" if self.paged_kernel
-                                        else "onehot")
+        self.serving.attend_mode = ("kernel" if self.paged_kernel
+                                    else "onehot")
         self._attach_slo_overlays()
         self._spec_proposed = 0
         self._spec_accepted = 0
@@ -1173,7 +1106,7 @@ class InferenceEngine:
             per_dev_leaves.append(
                 int(np.prod(shape)) * jnp.dtype(leaf.dtype).itemsize)
         score_bytes = 0
-        if self.paged and not self.paged_kernel:
+        if not self.paged_kernel:
             sp_ = self.cache_spec
             q_streams = {"decode_step": (sp_.slots_per_group, 1),
                          "verify_step": (sp_.slots_per_group,

@@ -1,9 +1,7 @@
 """KV cache — the static-shape memory plane of the serving tier.
 
-Two layouts share this module:
-
-**Paged (the production layout — the PagedAttention/vLLM design).** The
-cache is a pool of fixed-size blocks, logically
+The cache is a pool of fixed-size blocks (the PagedAttention/vLLM
+design), logically
 
     k, v : [layers, groups, blocks_per_group, heads, block_size, head_dim]
 
@@ -29,19 +27,13 @@ zero communication — and no per-device transient ever exceeds the pool
 shard (the ``materialization`` lint gate proves it: no full-pool
 gather).
 
-**Slot-major (the PR-7 layout, ``block_size: 0``).** One
-``[slots, max_len]`` row per slot — kept as the parity baseline the
-paged tests diff against and as the fallback for models whose
-``max_seq_len`` the page size does not divide.
+Nothing about admission, progress, or eviction changes a compiled
+signature — that is the property the recompile sentinel gates in the
+serving tests. Sharding is born on the training mesh's axes: groups
+over the data axis, ``heads`` over the model axis (Megatron TP head
+sharding, matching ``models/transformer.block_param_shardings``).
 
-In both layouts nothing about admission, progress, or eviction changes
-a compiled signature — that is the property the recompile sentinel
-gates in the serving tests. Sharding is born on the training mesh's
-axes: slots/groups over the data axis, ``heads`` over the model axis
-(Megatron TP head sharding, matching
-``models/transformer.block_param_shardings``).
-
-Paged APPENDS are written into the donated pool where it lies
+APPENDS are written into the donated pool where it lies
 (``paged_write_rows`` -> ``ops.paged_attention.paged_write``): an aliased
 Pallas call whose scalar-prefetched (layer, block, offset) pick each new
 row's block tile, rewrite that tile in VMEM and put it back. A step
@@ -50,9 +42,9 @@ touches R tiles for R rows — its cost does not depend on ``num_blocks``
 the layer index, never sliced. Block GATHERS are the Pallas
 paged-attention kernel on TPU; the one-hot ``paged_attend`` contraction
 (which slices its layer out and reads it whole) stays as the CPU-mesh
-and parity baseline, and ``paged_copy_block`` (copy-on-write, rare) is
-still a one-hot select over the pool. The slot-major layout's appends
-are one-hot selects over a slot's row (see docs/tutorials/inference.md).
+path and the reference the kernel is tested against, and
+``paged_copy_block`` (copy-on-write, rare) is still a one-hot select
+over the pool (see docs/tutorials/inference.md).
 """
 from __future__ import annotations
 
@@ -71,130 +63,6 @@ from ..ops import paged_attention as paged_attn_ops
 from ..parallel.topology import DP_AXIS, MP_AXIS
 
 
-@dataclasses.dataclass(frozen=True)
-class KVCacheSpec:
-    """Static geometry of the cache: fixed at engine construction."""
-    num_layers: int
-    num_slots: int
-    num_heads: int
-    max_len: int
-    head_dim: int
-    dtype: Any = jnp.bfloat16
-
-    @property
-    def shape(self) -> Tuple[int, int, int, int, int]:
-        return (self.num_layers, self.num_slots, self.num_heads,
-                self.max_len, self.head_dim)
-
-    def nbytes(self) -> int:
-        """Total K+V bytes (global, unsharded)."""
-        n = 1
-        for d in self.shape:
-            n *= d
-        return 2 * n * jnp.dtype(self.dtype).itemsize
-
-    def validate(self, mesh: Optional[Mesh] = None) -> None:
-        for name in ("num_layers", "num_slots", "num_heads", "max_len",
-                     "head_dim"):
-            if int(getattr(self, name)) <= 0:
-                raise ValueError(f"KVCacheSpec.{name} must be positive, "
-                                 f"got {getattr(self, name)}")
-        if mesh is not None:
-            dp = int(mesh.shape.get(DP_AXIS, 1))
-            mp = int(mesh.shape.get(MP_AXIS, 1))
-            if self.num_slots % dp != 0:
-                raise ValueError(
-                    f"inference.max_slots={self.num_slots} must be "
-                    f"divisible by the mesh data axis ({dp}) — slots are "
-                    "the data-parallel dimension of serving")
-            if self.num_heads % mp != 0:
-                raise ValueError(
-                    f"model heads ({self.num_heads}) not divisible by the "
-                    f"mesh model axis ({mp}) for TP head sharding")
-
-
-def cache_partition_spec() -> P:
-    """[layers, slots, heads, max_len, head_dim]: slots over dp, heads
-    over mp (the TP head sharding the training blocks already use)."""
-    return P(None, DP_AXIS, MP_AXIS, None, None)
-
-
-def cache_shardings(mesh: Mesh) -> Dict[str, NamedSharding]:
-    spec = cache_partition_spec()
-    return {"k": NamedSharding(mesh, spec), "v": NamedSharding(mesh, spec)}
-
-
-def init_cache(spec: KVCacheSpec,
-               mesh: Optional[Mesh] = None) -> Dict[str, jax.Array]:
-    """Zero-initialized cache, born sharded when a mesh is given (the
-    zeros are created directly at the declared sharding — no host-side
-    full-size array ever exists)."""
-    spec.validate(mesh)
-
-    def make():
-        return {"k": jnp.zeros(spec.shape, spec.dtype),
-                "v": jnp.zeros(spec.shape, spec.dtype)}
-
-    if mesh is None:
-        return make()
-    return jax.jit(make, out_shardings=cache_shardings(mesh))()
-
-
-# --------------------------------------------------------------------- #
-# Per-layer update primitives (used inside the jitted decode/prefill
-# programs; kc/vc here are ONE layer's [slots, heads, max_len, head_dim])
-# --------------------------------------------------------------------- #
-def write_token(kc: jax.Array, k_new: jax.Array,
-                lengths: jax.Array) -> jax.Array:
-    """Append one token's K (or V) per slot at that slot's own length.
-
-    kc: [S, nH, T, D]; k_new: [S, nH, D]; lengths: [S] int32 — slot s
-    writes at position lengths[s]. One-hot select over T (see module
-    docstring for why not scatter); positions beyond a slot's length are
-    dead by masking, so an out-of-range length (a full slot) writes
-    nowhere.
-    """
-    T = kc.shape[2]
-    onehot = lax.broadcasted_iota(jnp.int32, (1, T), 1) == \
-        lengths[:, None]                                   # [S, T]
-    return jnp.where(onehot[:, None, :, None],
-                     k_new[:, :, None, :].astype(kc.dtype), kc)
-
-
-def write_chunk(kc: jax.Array, k_new: jax.Array, slot: jax.Array,
-                start: jax.Array) -> jax.Array:
-    """Insert a prefilled chunk into one slot: pure dynamic_update_slice.
-
-    kc: [S, nH, T, D]; k_new: [C, nH, D] (chunk-of-tokens layout);
-    slot/start: traced scalars. The update block is [1, nH, C, D] at
-    (slot, 0, start, 0).
-    """
-    upd = k_new.transpose(1, 0, 2)[None].astype(kc.dtype)  # [1, nH, C, D]
-    return lax.dynamic_update_slice(
-        kc, upd, (slot.astype(jnp.int32), jnp.int32(0),
-                  start.astype(jnp.int32), jnp.int32(0)))
-
-
-def slot_rows(kc: jax.Array, slot: jax.Array) -> jax.Array:
-    """One slot's [nH, T, D] view (dynamic_slice; the prefill chunk
-    attends against its own slot's context only)."""
-    sizes = (1,) + tuple(kc.shape[1:])
-    return lax.dynamic_slice(
-        kc, (slot.astype(jnp.int32), jnp.int32(0), jnp.int32(0),
-             jnp.int32(0)), sizes)[0]
-
-
-def length_mask(lengths: jax.Array, max_len: int) -> jax.Array:
-    """[S, T] bool: position t of slot s is live iff t <= lengths[s]
-    (inclusive — the decode step masks AFTER writing the current token
-    at position lengths[s])."""
-    pos = lax.broadcasted_iota(jnp.int32, (1, max_len), 1)
-    return pos <= lengths[:, None]
-
-
-# ===================================================================== #
-# Paged layout: block pool + block-table indirection
-# ===================================================================== #
 DEAD_BLOCK = -1     # block-table entry for "unallocated" — writes through
                     # it land nowhere and gathers through it read zeros
 
@@ -715,10 +583,7 @@ class AdmitPlan:
     cow_dst: Optional[int] = None
 
 
-__all__ = ["KVCacheSpec", "cache_partition_spec", "cache_shardings",
-           "init_cache", "write_token", "write_chunk", "slot_rows",
-           "length_mask",
-           "DEAD_BLOCK", "PagedKVCacheSpec", "paged_partition_spec",
+__all__ = ["DEAD_BLOCK", "PagedKVCacheSpec", "paged_partition_spec",
            "paged_shardings", "init_paged_cache", "kv_fold",
            "paged_logical_view", "paged_folded_view", "paged_block_size",
            "paged_layer_view",

@@ -193,9 +193,8 @@ class ReplicaRouter:
             stepped = False
             for i, eng in enumerate(self.engines):
                 # 2. per-replica admissions (FCFS within the replica) —
-                # batched one-slot-per-group when the engine pages.
-                batched = getattr(eng, "paged", False) and \
-                    eng.prefill_chunk > 0
+                # batched one-slot-per-group under chunked prefill.
+                batched = eng.prefill_chunk > 0
                 while queues[i]:
                     batch = []
                     used: set = set()

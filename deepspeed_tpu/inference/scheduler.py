@@ -210,25 +210,12 @@ class ContinuousBatchingScheduler:
         pending = deque(sorted(requests, key=lambda r: r.arrival_s))
         queue: deque = deque()
         active: Dict[int, Request] = {}
-        # Engines with a block pool own slot selection (prefix-affinity
-        # group choice + HBM admission gate); the scheduler keeps its
-        # own free list for engines that predate it (slot-major, test
-        # fakes) — slot occupancy is then the whole gate.
-        select = getattr(eng, "select_slot", None)
-        free: deque = deque(() if select else
-                            (i for i in range(eng.max_slots)
-                             if not eng.active[i]))
         # Speculative decoding emits 1..k+1 tokens per slot per
         # iteration; greedy only — exact rejection sampling for
         # temperature > 0 is not implemented, so sampling streams fall
         # back to plain decode.
         spec = bool(getattr(eng, "spec_enabled", False)) and \
             self.temperature == 0.0
-
-        def _release(slot):
-            eng.release_slot(slot)
-            if not select:
-                free.append(slot)
 
         while pending or queue or active:
             now = time.perf_counter() - t0
@@ -245,7 +232,7 @@ class ContinuousBatchingScheduler:
                                     telemetry=eng.telemetry)
                     if abort is not None:
                         abort(req.rid, "max_wall")
-                    _release(slot)
+                    eng.release_slot(slot)
                     del active[slot]
                 for req in queue:
                     # Enqueued but never admitted: starved, not served —
@@ -271,11 +258,10 @@ class ContinuousBatchingScheduler:
             # head of the queue cannot be admitted (no slot, or the
             # block pool cannot cover its worst case), everything
             # behind it waits; pool exhaustion rejects admission here
-            # and NEVER touches a live slot. Paged engines admit in
+            # and NEVER touches a live slot. Chunked prefill admits in
             # one-slot-per-group BATCHES (engine.prefill_many): a full
             # batch prefills G admissions for one admission's wall.
-            batched = select is not None and \
-                getattr(eng, "paged", False) and eng.prefill_chunk > 0
+            batched = eng.prefill_chunk > 0
             while queue:
                 if batched:
                     with tel.span("admit", queued=len(queue),
@@ -285,8 +271,9 @@ class ContinuousBatchingScheduler:
                         rejected = ""
                         while queue:
                             req = queue[0]
-                            slot = select(req.prompt, req.max_new_tokens,
-                                          exclude_groups=used)
+                            slot = eng.select_slot(
+                                req.prompt, req.max_new_tokens,
+                                exclude_groups=used)
                             if slot is None:
                                 # Only a rejection with NO exclusions is
                                 # the gate refusing the head (with
@@ -320,7 +307,7 @@ class ContinuousBatchingScheduler:
                         self._admit_trace(req, slot)
                         if self._finished(req, eng.context_len(slot)):
                             self._complete(req)
-                            _release(slot)
+                            eng.release_slot(slot)
                         else:
                             active[slot] = req
                     continue
@@ -328,14 +315,10 @@ class ContinuousBatchingScheduler:
                 with tel.span("admit", queued=len(queue),
                               late_ms=late_ms) as span:
                     rejected = ""
-                    if select is not None:
-                        slot = select(req.prompt, req.max_new_tokens)
-                        if slot is None:
-                            rejected = self._reject(req, len(queue))
-                    else:
-                        slot = free.popleft() if free else None
-                        if slot is None:
-                            rejected = "no_slot"
+                    slot = eng.select_slot(req.prompt,
+                                           req.max_new_tokens)
+                    if slot is None:
+                        rejected = self._reject(req, len(queue))
                     span.set_metadata(admitted=int(slot is not None),
                                       rejected=rejected, rids=str(req.rid))
                 if slot is None:
@@ -353,7 +336,7 @@ class ContinuousBatchingScheduler:
                 self._admit_trace(req, slot)
                 if self._finished(req, eng.context_len(slot)):
                     self._complete(req)
-                    _release(slot)
+                    eng.release_slot(slot)
                 else:
                     active[slot] = req
             # 3. one decode (or draft-then-verify) iteration for every
@@ -380,7 +363,7 @@ class ContinuousBatchingScheduler:
                                        accepted=max(n - 1, 0))
                         if self._finished(req, eng.context_len(slot)):
                             self._complete(req)
-                            _release(slot)
+                            eng.release_slot(slot)
                             del active[slot]
                             finished.append(req.rid)
                     span.set_metadata(finished=ids_arg(finished))
@@ -398,7 +381,7 @@ class ContinuousBatchingScheduler:
                             trace.tick(req.rid, occ, 1, t=t_now)
                         if self._finished(req, eng.context_len(slot)):
                             self._complete(req)
-                            _release(slot)
+                            eng.release_slot(slot)
                             del active[slot]
                             finished.append(req.rid)
                     span.set_metadata(finished=ids_arg(finished))
@@ -422,8 +405,7 @@ class ContinuousBatchingScheduler:
                 # nothing can EVER free the capacity the head request
                 # needs (an over-sized request on an idle engine), which
                 # must fail loudly, not hang.
-                if select is not None and not active and not pending \
-                        and not eng.active.any():
+                if not active and not pending and not eng.active.any():
                     req = queue[0]
                     raise RuntimeError(
                         f"request {req.rid} can never be admitted: "

@@ -56,15 +56,15 @@ class ServingAggregator:
         self.prefill_tokens = 0
         self.completed = 0
         # Paged-cache accounting (engine-fed; stays empty — and out of
-        # the snapshot — on slot-major engines that predate it).
+        # the snapshot — until the engine feeds it: the scheduler tests'
+        # fake engines never do).
         self.prompt_tokens_admitted = 0
         self.cached_tokens_admitted = 0
         self.spec_proposed = 0
         self.spec_accepted = 0
-        # Analytic attend-work accounting (engine-fed, paged engines
-        # only): the same iterations priced BOTH ways — the Pallas
-        # kernel's live-context term vs the one-hot contraction's
-        # pool-capacity term. ``attend_mode`` names which one actually
+        # Analytic attend-work accounting (engine-fed): the same
+        # iterations priced BOTH ways — the Pallas kernel's live-context
+        # term vs the one-hot contraction's pool-capacity term. ``attend_mode`` names which one actually
         # ran; the totals are host arithmetic (projections), never
         # device measurements.
         self.attend_mode: Optional[str] = None

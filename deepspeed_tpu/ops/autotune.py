@@ -3,8 +3,8 @@
 Every Pallas kernel in the tree (``fused_elementwise``, ``fused_update``,
 ``flash_attention``/``sparse_flash``, ``grouped_gemm``) used to pick its
 tiles from a scattered set of static heuristics — a fixed VMEM budget
-loop here, a hand-set ``_BLOCK_TARGET`` there — and ``ablate_flash.py``
-existed precisely because no one value wins across shapes.  This module
+loop here, a hand-set ``_BLOCK_TARGET`` there — though no one value
+wins across shapes.  This module
 replaces those call-site constants with ONE resolver:
 
     tile = autotune.resolve(kernel, shape, dtype, heuristic,
@@ -30,8 +30,7 @@ Semantics (the determinism contract, in priority order):
    there and ``block_until_ready`` returns at once, so the clock would
    read tracing, not the device.  The registry answers, else the
    heuristic.  A search therefore happens where a kernel entry point is
-   called eagerly on concrete arrays (``chip_smoke.py``'s kernel phase,
-   ``ablate_autotune.py``).
+   called eagerly on concrete arrays (``chip_smoke.py``'s kernel phase).
 
 The registry is keyed like the recompile sentinel's abstract signatures
 (``kernel|dtype[dims]|chip``, host metadata only — never tracers) and

@@ -1,9 +1,8 @@
 """Fused elementwise Pallas kernels: residual-add+LayerNorm and the
 bias+GELU epilogue of the FFN up-projection.
 
-Why these exist (ROADMAP item 2, the non-GEMM third of the step):
-``profile_matmul_bound.py`` puts the pure-GEMM floor of the bench step at
-~2/3 of the achieved time; part of the rest is elementwise passes XLA
+Why these exist (the non-GEMM part of the step): part of what a train
+step spends outside its GEMMs is elementwise passes XLA
 schedules as separate HBM round-trips — LayerNorm reads the residual
 stream, computes mean/var in fp32, and writes it back; the residual add
 that feeds it is another full read+write; GELU and its bias add are two

@@ -467,11 +467,11 @@ class InferenceConfig:
                 f"{C.INFERENCE}.{C.INFERENCE_PREFILL_CHUNK} must be a "
                 f"non-negative int (0 = whole-prompt prefill), got "
                 f"{self.prefill_chunk!r}")
-        if not isinstance(self.block_size, int) or self.block_size < 0:
+        if not isinstance(self.block_size, int) or self.block_size <= 0:
             raise DeepSpeedConfigError(
                 f"{C.INFERENCE}.{C.INFERENCE_BLOCK_SIZE} must be a "
-                f"non-negative int (0 = slot-major layout), got "
-                f"{self.block_size!r}")
+                f"positive int (the paged block pool is the only KV "
+                f"layout), got {self.block_size!r}")
         if not isinstance(self.num_blocks, int) or self.num_blocks < 0:
             raise DeepSpeedConfigError(
                 f"{C.INFERENCE}.{C.INFERENCE_NUM_BLOCKS} must be a "
@@ -482,11 +482,6 @@ class InferenceConfig:
                 f"{C.INFERENCE}.{C.INFERENCE_SPEC_K} must be a "
                 f"non-negative int (0 = speculative decoding off), got "
                 f"{self.spec_k!r}")
-        if self.spec_k > 0 and self.block_size == 0:
-            raise DeepSpeedConfigError(
-                f"{C.INFERENCE}.{C.INFERENCE_SPEC_K} requires the paged "
-                f"cache ({C.INFERENCE_BLOCK_SIZE} > 0) — the verify step "
-                "writes draft K/V through the block table")
         if not isinstance(self.spec_ngram, int) or self.spec_ngram < 1:
             raise DeepSpeedConfigError(
                 f"{C.INFERENCE}.{C.INFERENCE_SPEC_NGRAM} must be a "

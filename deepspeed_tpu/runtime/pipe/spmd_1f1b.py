@@ -51,7 +51,7 @@ Wall-clock: in a lockstep pipeline the off-stage work that remains (the
 head on non-last ranks during the M central ticks) runs in PARALLEL with
 the real head on the last rank — it wastes chip-FLOPs, not tick latency.
 The reclaimable latency is the warmup/drain sub-ticks, which the uniform
-gates remove; ablate_1f1b_gate.py measures it. ``gate_offstage=False``
+gates remove. ``gate_offstage=False``
 recovers the ungated run-everything-and-select variant.
 
 fp16 loss scaling: the engine passes its (traced) loss scale; the head
@@ -120,8 +120,7 @@ def spmd_pipeline_1f1b_grads(embed_fn: Callable, stage_fn: Callable,
 
     ``gate_offstage``: cond-skip warmup/drain sub-ticks via tick-uniform
     gates (default). False runs every sub-tick everywhere and
-    select-masks — only for measuring the gating win
-    (ablate_1f1b_gate.py).
+    select-masks — only for measuring the gating win.
     """
     M, Pstages = num_micro_batches, num_stages
     T = M + 2 * (Pstages - 1)

@@ -550,7 +550,7 @@ class TestBenchGate:
         assert bg.main([old, new, "--mfu-drop", "0.10"]) == 1
 
     def test_gate_tile_speedup(self, tmp_path):
-        """--tile-drop gates kernels.tile_speedup (ablate_autotune.py);
+        """--tile-drop gates kernels.tile_speedup;
         pre-autotune rounds skip, never fail."""
         bg = load_bench_gate()
         old = self._write(tmp_path, "old.json",

@@ -1,10 +1,10 @@
-"""Serving tier tests: KV cache, incremental decode parity, continuous
+"""Serving tier tests: incremental decode parity, continuous
 batching under the recompile-sentinel gate, quantization, and the
 training-checkpoint handoff.
 
 The two load-bearing invariants:
 
-1. **Exactness** — decode against the slot cache produces the SAME
+1. **Exactness** — decode against the paged cache produces the SAME
    logits as the full forward at the growing sequence's final position,
    asserted per step (fp32 config, float tolerance: the incremental
    path contracts in a different order).
@@ -25,7 +25,6 @@ import pytest
 
 from deepspeed_tpu.inference import (ContinuousBatchingScheduler,
                                      InferenceEngine, synthetic_requests)
-from deepspeed_tpu.inference import kv_cache
 from deepspeed_tpu.inference.quantize import (dequantize,
                                               quantize_leaf_int8,
                                               quantize_params)
@@ -92,60 +91,6 @@ class TestGpt2LogitsAt:
         full_shape = (1, 16, CFG32.vocab_size)
         assert all(getattr(v.aval, "shape", None) != full_shape
                    for eqn in jaxpr.jaxpr.eqns for v in eqn.outvars)
-
-
-# --------------------------------------------------------------------- #
-# KV cache units
-# --------------------------------------------------------------------- #
-class TestKVCache:
-    SPEC = kv_cache.KVCacheSpec(num_layers=1, num_slots=4, num_heads=2,
-                                max_len=8, head_dim=3, dtype=jnp.float32)
-
-    def test_write_token_at_per_slot_lengths(self):
-        kc = jnp.zeros(self.SPEC.shape[1:], jnp.float32)   # [S,nH,T,D]
-        new = jnp.ones((4, 2, 3), jnp.float32) * \
-            jnp.arange(1, 5, dtype=jnp.float32)[:, None, None]
-        lengths = jnp.asarray([0, 3, 7, 5], jnp.int32)
-        out = np.asarray(kv_cache.write_token(kc, new, lengths))
-        for s, l in enumerate([0, 3, 7, 5]):
-            assert (out[s, :, l] == s + 1).all()
-            mask = np.ones(8, bool)
-            mask[l] = False
-            assert (out[s][:, mask] == 0).all(), "only one row written"
-
-    def test_write_token_full_slot_is_noop(self):
-        """length == max_len (slot full): the write lands nowhere."""
-        kc = jnp.zeros(self.SPEC.shape[1:], jnp.float32)
-        new = jnp.ones((4, 2, 3), jnp.float32)
-        out = kv_cache.write_token(kc, new,
-                                   jnp.full((4,), 8, jnp.int32))
-        assert (np.asarray(out) == 0).all()
-
-    def test_write_chunk_is_slot_isolated(self):
-        kc = jnp.zeros(self.SPEC.shape[1:], jnp.float32)
-        chunk = jnp.ones((4, 2, 3), jnp.float32) * 7.0     # C=4 tokens
-        out = np.asarray(kv_cache.write_chunk(
-            kc, chunk, jnp.int32(2), jnp.int32(3)))
-        assert (out[2, :, 3:7] == 7.0).all()
-        assert (out[2, :, :3] == 0).all() and (out[2, :, 7:] == 0).all()
-        assert (out[[0, 1, 3]] == 0).all(), "other slots untouched"
-
-    def test_length_mask_inclusive(self):
-        m = np.asarray(kv_cache.length_mask(
-            jnp.asarray([0, 2], jnp.int32), 4))
-        assert m.tolist() == [[True, False, False, False],
-                              [True, True, True, False]]
-
-    def test_spec_validation(self, mesh8):
-        with pytest.raises(ValueError, match="divisible"):
-            dataclasses.replace(self.SPEC, num_slots=6).validate(mesh8)
-        with pytest.raises(ValueError, match="positive"):
-            dataclasses.replace(self.SPEC, max_len=0).validate()
-        spec = dataclasses.replace(self.SPEC, num_slots=8)
-        cache = kv_cache.init_cache(spec, mesh8)
-        assert cache["k"].shape == spec.shape
-        assert str(cache["k"].sharding.spec) == \
-            str(kv_cache.cache_partition_spec())
 
 
 # --------------------------------------------------------------------- #
@@ -324,13 +269,17 @@ class TestServingStream:
                 return jax.profiler.TraceAnnotation(*a, **k)
 
         class _FakeEngine:
-            max_slots, max_len = 2, 1000
+            max_slots, max_len, prefill_chunk = 2, 1000, 0
             telemetry = _FakeTelemetry()
 
             def __init__(self):
                 self.active = np.zeros(2, bool)
                 from deepspeed_tpu.monitor.serving import ServingAggregator
                 self.serving = ServingAggregator(2)
+
+            def select_slot(self, prompt, max_new_tokens=0):
+                free = np.flatnonzero(~self.active)
+                return int(free[0]) if len(free) else None
 
             def prefill(self, prompt, slot, temperature=0.0, **kw):
                 return 1, None

@@ -223,6 +223,36 @@ class TestSeededViolations:
             deepspeed_tpu.parallel.hlo_audit.ring_wire_bytes(
                 "reduce-scatter", n * 4, 8)
 
+    def test_scalar_tuple_allreduce_is_not_a_gradient(self):
+        """The health tap's four per-leaf sums leave XLA's all-reduce
+        combiner as ONE all-reduce of four f32[] — 16 B, the bytes of an
+        f32[4] leaf. What the op carries decides: the scalar tuple is a
+        statistic, the f32[4] is a gradient materializing unpartitioned
+        and is still reported."""
+        from deepspeed_tpu.analysis.findings import LintContext
+        from deepspeed_tpu.analysis.passes import \
+            collective_placement_pass
+        from deepspeed_tpu.parallel.hlo_audit import (CollectiveOp,
+                                                      CommAudit)
+
+        def allreduce(shapes, op_name):
+            return CollectiveOp(
+                kind="all-reduce", name="x", computation="", out_bytes=16,
+                in_bytes=16, out_shapes=shapes, in_shapes=shapes,
+                group_size=4, num_groups=2, source_target_pairs=None,
+                op_name=op_name)
+
+        tap = allreduce(["f32[]"] * 4,
+                        "jit(train_step)/health_tap/reduce_sum")
+        grad = allreduce(["f32[4]"], "jit(train_step)/fwd_bwd/psum")
+        meta = {"grad_sync_path": True, "grad_sync_mode": "explicit",
+                "gas": 2, "scatterable_leaf_bytes": [16], "dp": 4}
+        ctx = LintContext(name="train_step", jaxpr=None,
+                          donated_invars=(), in_avals=(), hlo_text="",
+                          audit=CommAudit([tap, grad]), meta=meta)
+        assert [f.key for f in collective_placement_pass(ctx)] == \
+            ["grad-allreduce:f32[4]"]
+
     def test_allreduce_trapped_in_gas_scan_caught_dense(self, mesh8):
         """Dense mode's misplacement: the gradient all-reduce INSIDE the
         gas=2 accumulation scan pays gas x the wire it needs (accumulate

@@ -103,6 +103,23 @@ def _audit(engine, gas=1, n=16):
                                mb, engine._base_rng)
 
 
+def _assert_dcn_hop_carries_the_shards(audit, model, slices):
+    """The inter-slice hop as the program guarantees it: all-reduces
+    over groups of ``slices`` under the ``grad_sync`` scope, outside the
+    gas scan, carrying in TOTAL the 1/dp residual the wire model prices
+    (``dcn_payload_bytes``: every leaf's shard once) — as one op a leaf
+    or as fewer, merged by XLA's all-reduce combiner. Scalars the
+    combiner folds in beside them (the loss's sum over slices follows
+    the hop) are not gradient bytes."""
+    dcn = [o for o in audit.of_kind("all-reduce")
+           if o.group_size == slices and "grad_sync" in o.op_name]
+    assert dcn
+    assert all(not o.in_loop for o in dcn)
+    scalars = sum(s == "f32[]" for o in dcn for s in o.out_shapes)
+    assert sum(o.payload_bytes for o in dcn) - 4 * scalars == \
+        model["dcn_payload_bytes"], [(o.out_shapes, o.op_name) for o in dcn]
+
+
 # ------------------------------------------------------------------ #
 # Mesh / topology
 # ------------------------------------------------------------------ #
@@ -382,16 +399,7 @@ class TestMultisliceEngine:
 
         # Inter-slice hop: groups of `slices`, shard payloads, outside
         # the scan (ONE DCN exchange per step, not per micro-step).
-        dcn_ars = [o for o in audit.of_kind("all-reduce")
-                   if o.group_size == slices and o.payload_bytes >= 16]
-        assert dcn_ars
-        assert all(not o.in_loop for o in dcn_ars)
-        shard_sizes = {int(np.prod(l.shape)) // dp * 4 for l in
-                       jax.tree_util.tree_leaves(
-                           jax.device_get(e.state.params))}
-        for o in dcn_ars:
-            assert o.payload_bytes in shard_sizes, \
-                (o.payload_bytes, shard_sizes)
+        _assert_dcn_hop_carries_the_shards(audit, model, slices)
 
         # Never a grad-sized flat collective over the joint axes.
         flat = [o for o in audit.ops
@@ -1097,15 +1105,7 @@ class TestZero3Multislice:
             0.05 * model["reduce_scatter_wire_bytes"]
 
         # ONE residual-sized DCN exchange per step, outside the scan.
-        dcn_ars = [o for o in audit.of_kind("all-reduce")
-                   if o.group_size == slices and o.payload_bytes >= 16]
-        assert dcn_ars
-        assert all(not o.in_loop for o in dcn_ars)
-        shard_sizes = {int(np.prod(l.shape)) // dp * 4 for l in
-                       jax.tree_util.tree_leaves(params)}
-        for o in dcn_ars:
-            assert o.payload_bytes in shard_sizes, \
-                (o.payload_bytes, shard_sizes)
+        _assert_dcn_hop_carries_the_shards(audit, model, slices)
         tiers = two_tier_wire_summary(audit.ops, slices, dp,
                                       min_payload_bytes=1)
         assert abs(tiers["dcn"] - model["dcn_wire_bytes"]) <= \

@@ -3,9 +3,9 @@ routing (PR-12 tentpole).
 
 The load-bearing invariants:
 
-1. **Parity** — block-table decode produces the same logits as the PR-7
-   slot-major decode (and as the full batch forward) at fp32 tolerance;
-   prefix-shared admissions see bit-identical prefill logits.
+1. **Parity** — block-table decode produces the same logits as the
+   full batch forward at fp32 tolerance; prefix-shared admissions see
+   bit-identical prefill logits.
 2. **Bit-identity** — speculative greedy decode emits exactly the same
    token streams as non-speculative greedy decode (the acceptance-rule
    guarantee), whatever the n-gram drafter proposes.
@@ -53,11 +53,11 @@ def _prompt(n, seed=0, vocab=None):
                         size=n).astype(np.int32)
 
 
-def _engine(params, *, paged=True, slots=8, max_len=64, chunk=8,
+def _engine(params, *, slots=8, max_len=64, chunk=8,
             block_size=16, num_blocks=0, spec_k=0, cfg=CFG32, **tel):
     config = {"inference": {"max_slots": slots, "max_seq_len": max_len,
                             "prefill_chunk": chunk,
-                            "block_size": block_size if paged else 0,
+                            "block_size": block_size,
                             "num_blocks": num_blocks,
                             "spec_k": spec_k}}
     config.update(tel)
@@ -430,33 +430,36 @@ class TestBlockAllocator:
 
 
 # --------------------------------------------------------------------- #
-# Paged vs slot-major logit parity (fp32) — the PR-7 diff
+# What one stream's writes may touch (fp32)
 # --------------------------------------------------------------------- #
 class TestPagedParity:
-    def test_block_table_decode_matches_slot_major(self, params32):
-        paged = _engine(params32, paged=True, block_size=16)
-        slot_major = _engine(params32, paged=False)
-        prompt = _prompt(11, seed=8)
-        tok_p, lg_p = paged.prefill(prompt, slot=0, return_logits=True)
-        tok_s, lg_s = slot_major.prefill(prompt, slot=0,
-                                         return_logits=True)
-        np.testing.assert_allclose(lg_p, lg_s, atol=1e-4)
-        assert tok_p == tok_s
-        paged.activate_slot(0, len(prompt), tok_p)
-        slot_major.activate_slot(0, len(prompt), tok_s)
-        seq = list(prompt) + [tok_p]
-        for _ in range(6):
-            sp, lp = paged.decode_once(return_logits=True)
-            ss, ls = slot_major.decode_once(return_logits=True)
-            np.testing.assert_allclose(lp[0], ls[0], atol=1e-4)
-            ref = np.asarray(gpt2_apply(
-                params32, jnp.asarray(np.asarray(seq, np.int32))[None],
-                CFG32))[0, -1]
-            np.testing.assert_allclose(lp[0], ref, atol=1e-4)
-            assert int(sp[0]) == int(ss[0])
-            seq.append(int(sp[0]))
-        paged.close()
-        slot_major.close()
+    def test_full_context_stream_writes_no_row(self, params32):
+        """A stream whose context is at max_seq_len has nowhere to put
+        another row: its position resolves past its block table, so the
+        decode step writes nothing for it — not into its own blocks, not
+        into its group neighbour's, whose own row still lands."""
+        eng = _engine(params32, slots=16, max_len=32, block_size=16)
+        assert eng.group_of(0) == eng.group_of(1)
+        for slot in (0, 1):
+            prompt = _prompt(20, seed=30 + slot)
+            tok, _ = eng.prefill(prompt, slot=slot)
+            eng.activate_slot(slot, len(prompt), tok)
+        eng.lengths[0] = eng.max_len                # slot 0 is full
+        pos1 = int(eng.lengths[1])
+
+        def pools():
+            return [np.array(kv_cache.paged_logical_view(
+                eng.cache[n], CFG32.head_dim)) for n in ("k", "v")]
+
+        before = pools()
+        eng.decode_once()
+        for was, now in zip(before, pools()):
+            # [L, G, B, nH, bs, D] -> which (group, block, offset) moved
+            moved = (was != now).any(axis=(0, 3, 5))
+            assert np.argwhere(moved).tolist() == [
+                [eng.group_of(1), int(eng.block_tables[1][pos1 // 16]),
+                 pos1 % 16]]
+        eng.close()
 
     def test_cow_fork_isolates_divergent_decode(self, params32):
         """The copy-on-write fork: two identical prompts share all full
@@ -633,11 +636,6 @@ class TestSpeculativeDecoding:
 
         assert run(0) == run(4)
 
-    def test_spec_requires_paged(self, params32):
-        from deepspeed_tpu.runtime.config import DeepSpeedConfigError
-        with pytest.raises(DeepSpeedConfigError, match="paged"):
-            _engine(params32, paged=False, spec_k=4)
-
     def test_temperature_falls_back_to_plain_decode(self, params):
         eng = _engine(params, cfg=CFG, spec_k=4)
         with pytest.raises(ValueError, match="greedy-only"):
@@ -742,18 +740,25 @@ class TestWorkloadsAndConfig:
         assert all((a.prompt == b.prompt).all()
                    for a, b in zip(reqs, again))
 
-    def test_new_inference_knobs_validate(self):
-        from deepspeed_tpu.runtime.config import (DeepSpeedConfigError,
-                                                  InferenceConfig)
+    def test_inference_knob_defaults(self):
+        from deepspeed_tpu.runtime.config import InferenceConfig
         inf = InferenceConfig(None)
         assert inf.block_size == 16 and inf.num_blocks == 0
         assert inf.spec_k == 0 and inf.kv_cache_dtype == "model"
-        for bad in ({"block_size": -1}, {"spec_k": -2},
-                    {"spec_k": 2, "block_size": 0},
-                    {"kv_cache_dtype": "fp8"}, {"replica": 3},
-                    {"num_blocks": -4}, {"spec_ngram": 0}):
-            with pytest.raises(DeepSpeedConfigError):
-                InferenceConfig({"inference": bad})
+
+    @pytest.mark.parametrize("bad,match", [
+        ({"block_size": 0}, "paged block pool is the only KV layout"),
+        ({"block_size": -1}, "paged block pool is the only KV layout"),
+        ({"spec_k": -2}, "spec_k"),
+        ({"kv_cache_dtype": "fp8"}, "kv_cache_dtype"),
+        ({"replica": 3}, "replica"),
+        ({"num_blocks": -4}, "num_blocks"),
+        ({"spec_ngram": 0}, "spec_ngram")])
+    def test_bad_inference_knob_is_refused(self, bad, match):
+        from deepspeed_tpu.runtime.config import (DeepSpeedConfigError,
+                                                  InferenceConfig)
+        with pytest.raises(DeepSpeedConfigError, match=match):
+            InferenceConfig({"inference": bad})
 
     def test_engine_geometry_validation(self, params32):
         with pytest.raises(ValueError, match="block_size"):

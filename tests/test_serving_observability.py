@@ -504,12 +504,16 @@ class TestServingObservabilityStream:
                 return jax.profiler.TraceAnnotation(*a, **k)
 
         class _FakeEngine:
-            max_slots, max_len = 2, 1000
+            max_slots, max_len, prefill_chunk = 2, 1000, 0
             telemetry = _FakeTel()
 
             def __init__(self):
                 self.active = np.zeros(2, bool)
                 self.serving = ServingAggregator(2)
+
+            def select_slot(self, prompt, max_new_tokens=0):
+                free = np.flatnonzero(~self.active)
+                return int(free[0]) if len(free) else None
 
             def prefill(self, prompt, slot, temperature=0.0, **kw):
                 return 1, None

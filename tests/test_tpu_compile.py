@@ -197,7 +197,7 @@ def _case_grouped(bwd):
 
 
 def _case_sparse(_):
-    """bench_sparse.py's shape: BigBird, 4 heads, S=32768, D=64,
+    """The long-context sparse shape: BigBird, 4 heads, S=32768, D=64,
     forward + backward."""
     from deepspeed_tpu.ops.sparse_attention.sparsity_config import (
         BigBirdSparsityConfig)
@@ -393,7 +393,7 @@ def _serve_program(topo, program, head_dim):
         head_dim=cfg.head_dim, dtype=jnp.bfloat16)
     eng = object.__new__(InferenceEngine)
     eng.model_cfg, eng.dp, eng.sp, eng.mesh = cfg, 1, 1, None
-    eng.paged = eng.paged_kernel = True
+    eng.paged_kernel = True
     eng.quantize = "none"
     eng.prefill_chunk = SERVE["prefill_chunk"]
     eng._cache_sh = {"k": one, "v": one}

@@ -15,7 +15,7 @@ Accepted file shapes (auto-detected per file):
 - a ``TELEMETRY.json`` from tools/telemetry_report.py — MFU is the
   fenced ``window_mfu`` (per-step p50 as fallback), goodput is the
   ledger's ``goodput_fraction``;
-- a ``SERVE_BENCH.json`` from tools/serve_bench.py (or a serving-mode
+- a ``SERVE_BENCH.json``-shaped serving record (or a serving-mode
   TELEMETRY.json) — serving throughput is generated ``tokens_per_s``,
   serving latency is ``ttft_ms.p95``.
 
@@ -29,7 +29,7 @@ step changes, not jitter); the fused-kernel ablation speedup (the
 ``kernels.fused_speedup`` field a DS_BENCH_KERNELS=1 bench or
 the BENCH_r06/r07 projections record) regresses on a relative drop beyond
 ``--kernel-drop`` (default 10%); the autotuned-tile speedup (the
-``kernels.tile_speedup`` field ``ablate_autotune.py --record`` writes
+``kernels.tile_speedup`` field of an autotune ablation record
 — geomean of the per-kernel winner-over-heuristic ratios) regresses on
 a relative drop beyond ``--tile-drop`` (default 10%), and pre-autotune
 rounds skip, never fail; the ZeRO-3 prefetch overlap fraction
@@ -138,7 +138,7 @@ def extract_metrics(doc: Dict[str, Any]) -> Dict[str, Optional[float]]:
     krn = doc.get("kernels")
     if isinstance(krn, dict) and krn.get("fused_speedup") is not None:
         kernel_speedup = float(krn["fused_speedup"])
-    # Autotune ablation record (ablate_autotune.py): geomean step-level
+    # Autotune ablation record: geomean step-level
     # speedup of the autotuned tiles over the static heuristics.
     # Pre-autotune rounds carry no field -> skipped, never failed.
     tile_speedup: Optional[float] = None
