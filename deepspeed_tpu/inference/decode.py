@@ -113,22 +113,20 @@ def _group_shape(arr: jax.Array, num_groups: int) -> jax.Array:
                        + arr.shape[1:])
 
 
-def _paged_attn_block(p, x, kc, vc, layer, bt_g, cfg: GPT2Config,
+def _paged_attn_block(p, x, kc, vc, layer, cfg: GPT2Config,
                       num_groups: int, blk: jax.Array, off: jax.Array,
-                      pos_g: jax.Array, sel, pos_mask,
-                      paged_kernel: bool = False, mesh=None):
+                      sel, pos_mask, plan=None, mesh=None):
     """Shared attention step of the paged decode/verify/prefill paths.
 
     x: [S, K, H] — K tokens for each of S per-slot query streams, with
     S = G * Sg (Sg = 1 stream per group for prefill); kc/vc: the WHOLE
     stacked pools as held ([L, G, B, nH, bs/f, f*D]) and ``layer`` the
-    layer of them this block owns; bt_g: [G, Sg, J]; blk/off: [G, Sg*K]
-    where each new row goes (``positions_to_blocks``); pos_g: [G, Sg, K]
-    inclusive last attendable position per query row. ``sel``
-    [G, Sg, J, B] / ``pos_mask`` [G, Sg, K, J*bs] drive the one-hot
-    baseline and are None when ``paged_kernel`` routes the attend
-    through the Pallas kernel (the write is the same in-place one either
-    way). Returns (x', kc', vc').
+    layer of them this block owns; blk/off: [G, Sg*K] where each new row
+    goes (``positions_to_blocks``). ``sel`` [G, Sg, J, B] / ``pos_mask``
+    [G, Sg, K, J*bs] drive the one-hot baseline; ``plan`` (the streams'
+    tables and row positions as ``ops.paged_attention.attend_plan``
+    reads them) routes the attend through the Pallas kernel instead (the
+    write is the same in-place one either way). Returns (x', kc', vc').
     """
     S, K, H = x.shape
     G = num_groups
@@ -142,10 +140,10 @@ def _paged_attn_block(p, x, kc, vc, layer, bt_g, cfg: GPT2Config,
                 kc, vc, k.reshape(G, R, nH, D), v.reshape(G, R, nH, D),
                 layer, blk, off, mesh=mesh)
         with jax.named_scope("attend"):
-            if paged_kernel:
+            if plan is not None:
                 attn = paged_attn_ops.paged_attention(
-                    q.reshape(G, Sg, K, nH, D), kc, vc, layer, bt_g,
-                    pos_g, scale=1.0 / math.sqrt(D), mesh=mesh)
+                    q.reshape(G, Sg, K, nH, D), kc, vc, layer, plan=plan,
+                    scale=1.0 / math.sqrt(D), mesh=mesh)
             else:
                 attn = kv_cache.paged_attend(
                     q.reshape(G, Sg, K, nH, D),
@@ -191,17 +189,22 @@ def _paged_forward(params, x, kc, vc, bt_g, pos_g, cfg: GPT2Config,
     row positions pos_g [G, Sg, K]. Returns (x', kc', vc')."""
     G, _, J = bt_g.shape
     bs = kv_cache.paged_block_size(kc, cfg.head_dim)
-    sel = pos_mask = None
-    if not paged_kernel:
+    sel = pos_mask = plan = None
+    if paged_kernel:
+        # What the attend reads of the tables and positions is the same
+        # for every layer: built once, outside the layer loop.
+        with jax.named_scope("attn"), jax.named_scope("attend"):
+            plan = paged_attn_ops.attend_plan(bt_g, pos_g, kc,
+                                              cfg.head_dim, mesh=mesh)
+    else:
         sel = kv_cache.block_select(bt_g, kc.shape[2])
         grid = lax.broadcasted_iota(jnp.int32, (1, 1, 1, J * bs), 3)
         pos_mask = grid <= pos_g[..., None]          # [G, Sg, K, J*bs]
     blk, off = _write_targets(bt_g, pos_g, bs)
 
     def block(p, h, kc, vc, layer):
-        return _paged_attn_block(p, h, kc, vc, layer, bt_g, cfg, G, blk,
-                                 off, pos_g, sel, pos_mask, paged_kernel,
-                                 mesh)
+        return _paged_attn_block(p, h, kc, vc, layer, cfg, G, blk, off,
+                                 sel, pos_mask, plan, mesh)
 
     return _paged_layers(params, x, kc, vc, block)
 

@@ -748,6 +748,26 @@ class InferenceEngine:
         live = self.allocator.blocks_in_use()
         return live, live * self.cache_spec.block_nbytes(), tokens
 
+    def _attend_steps(self, k_rows: int) -> Tuple[int, int]:
+        """(steps, live steps) a layer of the paged kernel's attend in
+        the execution about to run — the ``decode`` span's
+        ``attend_steps`` / ``attend_live_steps`` (see
+        ``ops.paged_attention.attend_step_counts``), from the lengths
+        and tables the host holds. (0, 0) on the one-hot path, which has
+        no steps."""
+        if not self.paged_kernel:
+            return 0, 0
+        sp_ = self.cache_spec
+        reach = (self.lengths + k_rows - 1) // sp_.block_size + 1
+        live = np.minimum(np.minimum(reach, sp_.max_blocks_per_slot),
+                          (self.block_tables >= 0).sum(axis=1))
+        return paged_attn_ops.attend_step_counts(
+            live, K=k_rows, num_heads=max(1, sp_.num_heads // self.mp),
+            head_dim=sp_.head_dim, block_size=sp_.block_size,
+            table_width=sp_.max_blocks_per_slot,
+            kv_itemsize=int(jnp.dtype(sp_.dtype).itemsize),
+            q_itemsize=int(jnp.dtype(self.model_cfg.dtype).itemsize))
+
     def _attend_work(self, k_rows: int) -> Tuple[int, int, int, int]:
         """Analytic attend work of the iteration just run, priced BOTH
         ways: (flops_kernel, flops_onehot, bytes_kernel, bytes_onehot).
@@ -792,6 +812,7 @@ class InferenceEngine:
             with tl.span("decode_tables"):
                 for s in np.flatnonzero(self.active):
                     self._ensure_blocks(int(s), int(self.lengths[s]))
+                steps = self._attend_steps(1)
             with tl.span("decode_dispatch"):
                 kc, vc, sampled, logits = self._decode_fn(
                     self._params, self.cache["k"], self.cache["v"],
@@ -814,6 +835,7 @@ class InferenceEngine:
                 self.iterations += 1
                 live_blocks, cache_bytes, ctx_tokens = \
                     self._cache_accounting()
+                self.serving.note_attend_steps(*steps)
                 self.serving.note_iteration(n_active, wall,
                                             cache_bytes=cache_bytes,
                                             context_tokens=ctx_tokens)
@@ -831,7 +853,9 @@ class InferenceEngine:
                     tl.maybe_drain(self.iterations,
                                    extra_fn=self._report_extra)
             span.set_metadata(live_blocks=live_blocks,
-                              context_tokens=ctx_tokens)
+                              context_tokens=ctx_tokens,
+                              attend_steps=steps[0],
+                              attend_live_steps=steps[1])
         out_logits = np.asarray(jax.device_get(logits)) \
             if return_logits else None
         return sampled, out_logits
@@ -873,6 +897,7 @@ class InferenceEngine:
                     toks[s, 1:] = self.drafter.propose(s)
                     self._ensure_blocks(
                         s, min(int(self.lengths[s]) + k, self.max_len - 1))
+                steps = self._attend_steps(k + 1)
             with tl.span("decode_dispatch"):
                 kc, vc, out, logits = self._verify_fn(
                     self._params, self.cache["k"], self.cache["v"], toks,
@@ -905,6 +930,7 @@ class InferenceEngine:
                 self.iterations += 1
                 live_blocks, cache_bytes, ctx_tokens = \
                     self._cache_accounting()
+                self.serving.note_attend_steps(*steps)
                 self.serving.note_iteration(n_active, wall,
                                             cache_bytes=cache_bytes,
                                             context_tokens=ctx_tokens,
@@ -934,7 +960,9 @@ class InferenceEngine:
                     tl.maybe_drain(self.iterations,
                                    extra_fn=self._report_extra)
             span.set_metadata(live_blocks=live_blocks,
-                              context_tokens=ctx_tokens)
+                              context_tokens=ctx_tokens,
+                              attend_steps=steps[0],
+                              attend_live_steps=steps[1])
         return emitted, n_new
 
     def _attach_slo_overlays(self) -> None:

@@ -73,6 +73,10 @@ class ServingAggregator:
         self.attend_bytes_kernel = 0
         self.attend_bytes_onehot = 0
         self.attend_tokens = 0
+        # The paged kernel's sequencing steps a layer, and those of them
+        # that touched a live block (the ``decode`` span's counters).
+        self.attend_steps = 0
+        self.attend_live_steps = 0
         # Admission-rejection accounting (the reservation gate's retries
         # used to be invisible): total rejected reservations plus the
         # per-completed-request attempt counts.
@@ -138,6 +142,12 @@ class ServingAggregator:
         self.attend_bytes_onehot += int(bytes_onehot)
         self.attend_tokens += int(tokens)
 
+    def note_attend_steps(self, steps: int, live_steps: int) -> None:
+        """One iteration's attend steps a layer and the live ones among
+        them (InferenceEngine._attend_steps; zeros off the kernel)."""
+        self.attend_steps += int(steps)
+        self.attend_live_steps += int(live_steps)
+
     def note_reject(self) -> None:
         """One reservation-gate / slot-pool admission rejection."""
         self.reservations_rejected += 1
@@ -174,7 +184,10 @@ class ServingAggregator:
         are reported separately, not inflated into throughput. Fields
         the engine never fed (no paged cache, no spec decode) are
         omitted so pre-paging consumers and the bench gate's
-        skip-never-fail rule keep working."""
+        skip-never-fail rule keep working. ``attend_live_step_share``
+        (paged kernel only) is the share of the attend's sequencing
+        steps that touched a live block, over all iterations so far: the
+        rest are the empty steps of dead streams."""
         wall = wall_s if wall_s is not None \
             else time.perf_counter() - self.t0
         snap = {
@@ -249,6 +262,9 @@ class ServingAggregator:
                 snap["attend_work_ratio"] = round(
                     self.attend_bytes_onehot / self.attend_bytes_kernel,
                     4)
+        if self.attend_steps:
+            snap["attend_live_step_share"] = round(
+                self.attend_live_steps / self.attend_steps, 4)
         return snap
 
     @classmethod
@@ -271,6 +287,8 @@ class ServingAggregator:
             out.attend_bytes_kernel += a.attend_bytes_kernel
             out.attend_bytes_onehot += a.attend_bytes_onehot
             out.attend_tokens += a.attend_tokens
+            out.attend_steps += a.attend_steps
+            out.attend_live_steps += a.attend_live_steps
             if out.attend_mode is None:
                 out.attend_mode = a.attend_mode
             # Occupancy normalizes per-replica (active/its own slots):

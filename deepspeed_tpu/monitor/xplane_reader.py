@@ -22,7 +22,7 @@ import re
 import struct
 from typing import Any, Dict, Iterator, List, Tuple
 
-__all__ = ["SCOPES", "SPANS", "read_xspace", "event_args", "scope_of",
+__all__ = ["SCOPES", "SPANS", "SPAN_ARGS", "read_xspace", "event_args", "scope_of",
            "instruction_name",
            "device_op_events", "DEVICE_PLANE", "OPS_LINE"]
 
@@ -37,6 +37,20 @@ SPANS = ("train_batch", "data_prep", "step_dispatch", "offload_step",
          "step_log", "admit", "prefill", "prefill_plan", "prefill_chunk",
          "prefill_fetch", "decode", "decode_tables", "decode_dispatch",
          "decode_fetch", "decode_advance", "emit", "serve_idle")
+# The args those spans carry (the ones with none are left out).
+SPAN_ARGS = {
+    "train_batch": ("step_num",), "data_prep": ("step",),
+    "step_dispatch": ("step",), "step_log": ("step",),
+    "admit": ("queued", "late_ms", "admitted", "rejected", "rids"),
+    "prefill": ("slots", "prompt_tokens", "rids", "cached_tokens",
+                "chunks"),
+    "prefill_chunk": ("ci", "active_groups"),
+    # attend_steps / attend_live_steps: the paged kernel's sequencing
+    # steps a layer in this execution and those that touch a live block
+    # (ops.paged_attention.attend_step_counts; zeros on the one-hot path)
+    "decode": ("iteration", "active", "live_blocks", "context_tokens",
+               "attend_steps", "attend_live_steps"),
+    "emit": ("finished",), "serve_idle": ("why",)}
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 _INNER = re.compile(r"^(?:[\w.-]+\()*([\w.-]*)\)*$")

@@ -8,36 +8,38 @@ block-table selector — per-token attend FLOPs and HBM bytes scale with
 pool CAPACITY, not the request's live context. This module is the real
 kernel the one-hot contraction stood in for: the host-built block tables
 ride in as scalar-prefetch indices (the sparse_flash.py flattened-LUT
-pattern) and the grid iterates, per (stream, head block), only that
-stream's ceil(context/bs) live blocks — each step a dynamic-slice load of
-one block's K/V tile straight from the WHOLE stacked pool (one more
-scalar-prefetch table names each (stream, slot)'s tile with the layer
-folded in: no layer is ever sliced out of the pool, relaid or copied
-around the call), online-softmax
-accumulation in fp32 scratch, and an inclusive position mask so the final
-partial block contributes exactly its written rows.
+pattern) and a grid step takes ONE stream (and head block) through ALL its
+ceil(context/bs) live blocks, P table slots at a time: the WHOLE stacked
+pool stays in HBM (``pl.ANY``) and the step copies each live block's tile
+— every head of the block, one contiguous DMA, named by one precomputed
+index with the layer's offset added (no layer is ever sliced out of the
+pool, relaid or copied around the call) — into one half of a VMEM buffer
+while it attends the previous P tiles in the other: scores and the weighted
+sum over P*bs positions at once, online-softmax accumulation in fp32
+scratch updated once a group, and an inclusive position mask (one vector
+compare) so the final partial block contributes exactly its written rows.
 
 Shapes follow the one-hot path exactly: q is ``[G, Q, K, nH, D]`` where K
 is the query rows PER STREAM — 1 for plain decode, k+1 for speculative
 verify, the chunk width for chunked prefill. All K rows of a stream share
 its block table; ``positions[g, q, k]`` is each row's inclusive last
 attendable position (per-row causal offsets), so all three serving paths
-run the SAME kernel with no specialization.
+run the SAME kernel with no specialization: heads a step and slots a group
+come from the shapes (``_tile_rule``), under a VMEM budget.
 
-Static-shape discipline: the grid is ``(G*Q, nH/bh, J)`` with J the block-
-table WIDTH (max_blocks_per_slot) — a compile-time constant — and steps
-beyond a stream's live count are predicated off with ``pl.when`` while
-their index maps clamp to the last live block (the TPU pipeline elides
-the repeated copy). Compute and HBM traffic scale with ceil(context/bs);
-the compiled shape never changes, so the serving engine's zero-recompile
-sentinel holds. bf16 pools (``kv_cache_dtype: bf16``) dequantize in-VMEM:
-tiles are upcast to fp32 at the register level, accumulation is fp32, and
-only the final output drops back to q's dtype.
+Static-shape discipline: the grid is ``(G*Q, nH/bh)`` — compile-time
+constants — and the loop over a stream's groups runs to its LIVE count, a
+scalar the table state decides: a dead stream's step copies and attends
+nothing and emits zeros. Compute and HBM traffic scale with
+ceil(context/bs); the compiled shape never changes, so the serving
+engine's zero-recompile sentinel holds. bf16 pools (``kv_cache_dtype:
+bf16``) dequantize in-VMEM: tiles are upcast to fp32 at the register
+level, accumulation is fp32, and only the final output drops back to q's
+dtype. What no layer changes — live counts, tile rows, the mask's row
+limits — is an ``AttendPlan`` the caller builds once an execution.
 
-The head-block tile ``bh`` resolves through the PR-16 autotuner
-(``resolve("paged_attn", ...)``); on CPU the heuristic answers and the
-kernel runs in interpret mode — which is how the dp=8 CPU-mesh tier-1
-proves logit parity against the one-hot baseline.
+On CPU the kernel runs in interpret mode — which is how the dp=8 CPU-mesh
+tier-1 proves logit parity against the one-hot baseline.
 
 ``paged_write`` is the other half of the same mechanism: new K/V rows are
 written INTO the donated pool where it lies (an aliased ``pallas_call``
@@ -50,14 +52,14 @@ from __future__ import annotations
 import functools
 import math
 import os
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
 
-from . import autotune
 from .flash_attention import NEG_INF, _interpret
 from ..parallel import comm
 from ..parallel.topology import DP_AXIS, MP_AXIS
@@ -68,6 +70,12 @@ except Exception:  # pragma: no cover
     pltpu = None
 
 _ENV_KNOB = "DS_PAGED_KERNEL"
+# How many heads of a step the kernel's head loop lays side by side (the
+# scheduler interleaves their MXU / VPU chains). Timed on the v5e at the
+# serve cell's decode shape: 1 / 2 / 4 / 10 / 20 heads 30.6 / 18.1 / 14.4 /
+# 12.7 / 12.3 ms an execution; past four every start pays for it in
+# lowering time (0.18 / 0.38 / 0.72 s at 4 / 10 / 20).
+_HEAD_UNROLL = 4
 
 
 def paged_kernel_enabled(flag="auto") -> bool:
@@ -110,7 +118,9 @@ def attend_flops_per_token(num_heads: int, head_dim: int, block_size: int,
     """Analytic attend FLOPs to decode ONE token: 2*nH*D per key row for
     the QK^T scores plus the same for the PV combine. Dominant terms
     only (softmax and the one-hot selector contractions are excluded on
-    both sides, so the kernel/one-hot ratio is conservative)."""
+    both sides, so the kernel/one-hot ratio is conservative). The kernel
+    contracts a group of P blocks at once and masks what lies past the
+    context: block-rounded keys are what it is charged, as before."""
     keys = _attend_keys(block_size, context, pool_blocks)
     return 4 * int(num_heads) * int(head_dim) * keys * int(num_layers)
 
@@ -123,128 +133,230 @@ def attend_hbm_bytes_per_token(num_heads: int, head_dim: int,
                                num_layers: int = 1) -> int:
     """Analytic K+V HBM bytes one decode attend streams: 2 (K and V)
     planes of ``keys * nH * D`` elements per layer. The one-hot side
-    reads the whole pool; the kernel reads ceil(ctx/bs) tiles."""
+    reads the whole pool; the kernel copies ceil(ctx/bs) block tiles of
+    ``nH * bs * D`` elements (one DMA each: a LIVE slot of a group is
+    copied, a dead one is not), so the count is exact for it."""
     keys = _attend_keys(block_size, context, pool_blocks)
     return (2 * keys * int(num_heads) * int(head_dim)
             * int(kv_itemsize) * int(num_layers))
+
+
+def attend_step_counts(live_blocks, *, K: int, num_heads: int,
+                       head_dim: int, block_size: int, table_width: int,
+                       kv_itemsize: int, q_itemsize: int = 2):
+    """(steps, live steps) the kernel sequences for ONE layer's attend,
+    from each stream's live block count — host integers, no device work.
+    A step is one pass of the kernel's sequencing for one head block: a
+    group of P table slots of a live stream (live: it copies and attends
+    at least one block), or the empty grid step of a dead stream.
+    ``num_heads`` is what one shard holds."""
+    bh, P_ = _tile_rule(K, num_heads, head_dim, block_size, table_width,
+                        kv_itemsize, q_itemsize)
+    groups = -(-np.asarray(live_blocks, np.int64) // P_)
+    per = num_heads // bh
+    return int(np.maximum(groups, 1).sum()) * per, int(groups.sum()) * per
 
 
 # --------------------------------------------------------------------- #
 # Kernel
 # --------------------------------------------------------------------- #
 
-def _pattn_kernel(bt_ref, pos_ref, nlive_ref, rows_ref, q_ref, k_ref, v_ref,
-                  o_ref, m_scr, l_scr, acc_scr, *, scale, bs, bh, K, D):
-    """One grid step = one (stream, head block, table slot j). Scratch
-    rows persist across the j sweep (innermost grid axis), the standard
-    online-softmax carry.
+def _head_loop(bh, body):
+    """``body(h)`` for every head of the step: a loop whose body holds
+    up to ``_HEAD_UNROLL`` heads side by side (their chains are
+    independent, so the scheduler interleaves them) rather than bh
+    copies of the body."""
+    u = max(d for d in range(1, min(bh, _HEAD_UNROLL) + 1) if bh % d == 0)
 
-    A head's K/V tile arrives as the pool holds it, lane-dense
-    ``[bs/f, f*D]``: position t of the block at row t // f, lanes
-    (t % f)*D.. . Nothing re-tiles it. Instead each query row comes f
-    times (``_paged_call`` lays copy i into lanes i*D.. of an otherwise
-    zero ``f*D``-wide row), so ONE full-lane contraction against the tile
-    gives copy i the scores of the positions with t % f == i, and one
-    against the V tile gives it their weighted sum in lanes i*D.. . Each
-    copy keeps its own online-softmax state — head h2's f*K rows live at
-    ``h2*f*K + i*K + k`` — and the copies are merged when the stream's
-    last block is done. With f == 1 (head_dim >= 128) this is the plain
-    kernel."""
-    s_idx = pl.program_id(0)
-    j = pl.program_id(2)
-    nlive = nlive_ref[s_idx, 0]
-    active = jnp.logical_and(j < nlive, bt_ref[s_idx, j] >= 0)
-    f = k_ref.shape[-1] // D
-    bsf, fK = bs // f, f * K
+    def group(g, carry):
+        for i in range(u):
+            body(g * u + i)
+        return carry
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    jax.lax.fori_loop(0, bh // u, group, 0)
 
-    @pl.when(active)
-    def _compute():
-        # Inclusive per-row position mask: column c of copy i is
-        # position j*bs + c*f + i of the stream; query row k attends it
-        # iff it is <= pos[k]. The final partial block contributes
-        # exactly its written rows, and verify's K=k+1 rows get their
-        # per-row causal offsets here. (The per-row positions are SMEM
-        # scalars; they are laid into an int32 tile by row select —
-        # Mosaic cannot concatenate K boolean rows.)
-        row = jax.lax.broadcasted_iota(jnp.int32, (fK, bsf), 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (fK, bsf), 1)
-        if f > 1:
-            col = col * f + jax.lax.div(row, K)
-            row = jax.lax.rem(row, K)
-        col = col + j * bs
-        pos = jnp.zeros((fK, bsf), jnp.int32)
-        for kk in range(K):
-            pos = jnp.where(row == kk, pos_ref[s_idx, kk], pos)
-        allowed = col <= pos
-        qs = q_ref[0]           # [bh, f*K, f*D]
-        ks = k_ref[0]           # [bh, bs/f, f*D]
-        vs = v_ref[0]
-        for h2 in range(bh):
+
+def _pattn_kernel(nlive_ref, rows_ref, base_ref, lim_ref, q_ref, k_hbm,
+                  v_hbm, o_ref, k_buf, v_buf, sem, m_scr, l_scr, acc_scr, *,
+                  scale, bs, K, D, P):
+    """One grid step = one (stream, head block): ALL of the stream's live
+    blocks, P table slots at a time. The pools stay in HBM (``pl.ANY``);
+    the step copies group g + 1's block tiles (every head of the block:
+    one contiguous DMA a tile) into one half of ``k_buf`` / ``v_buf``
+    while it computes on group g in the other, and issues a copy for a
+    LIVE block only: a dead stream's step moves and computes nothing.
+    m / l / acc are the standard online-softmax carry, updated once a
+    group per head.
+
+    A head's K/V tile lies in the buffer as the pool holds it,
+    lane-dense ``[bs/f, f*D]``: position t of the block at row t // f,
+    lanes (t % f)*D.. . Nothing re-tiles it; a group's P tiles are
+    stacked into one ``[P*bs/f, f*D]`` operand (whole vregs once
+    widened). Each query row comes f times (``_paged_local`` lays copy i
+    into lanes i*D.. of an otherwise zero ``f*D``-wide row), so ONE
+    full-lane contraction against the stack gives copy i the scores of
+    the positions with t % f == i — column c of copy i is position
+    ``g*P*bs + c*f + i`` of the stream — and one against the V stack
+    gives it their weighted sum in lanes i*D.. . Each copy keeps its own
+    online-softmax state (row ``i*K + k`` of its head) and the copies
+    are merged when the stream's last group is done. With f == 1
+    (head_dim >= 128) this is the plain kernel.
+
+    The mask is one vector compare: ``lim_ref`` holds, per score row,
+    the last attendable position less the copy's lane offset i, so a
+    column is allowed iff ``g*P*bs + c*f <= lim``. The slots of the last
+    group past the live count lie wholly behind it; their K rows are
+    whatever the buffer held and their V rows are zeroed (0 x finite)."""
+    s_idx, hb = pl.program_id(0), pl.program_id(1)
+    bh, fK, fD = q_ref.shape[1:]
+    f = fD // D
+    N = P * (bs // f)
+    nlive = nlive_ref[s_idx]
+    groups = pl.cdiv(nlive, P)
+    heads = pl.ds(hb * bh, bh)
+
+    def tiles_of(g, slot, act):
+        """``act`` on the K and V copy of every live slot of group g
+        (into buffer half ``slot``)."""
+        def one(p, carry):
+            row = rows_ref[s_idx, g * P + p] + base_ref[0]
+            for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                act(pltpu.make_async_copy(hbm.at[row, heads],
+                                          buf.at[slot, p], sem.at[slot]))
+            return carry
+        jax.lax.fori_loop(0, jnp.minimum(P, nlive - g * P), one, 0)
+
+    def group(g, carry):
+        slot = jax.lax.rem(g, 2)
+
+        @pl.when(g + 1 < groups)
+        def _next():
+            tiles_of(g + 1, 1 - slot, lambda dma: dma.start())
+
+        tiles_of(g, slot, lambda dma: dma.wait())
+
+        def zero_v(p, carry):
+            v_buf[slot, p] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
+            return carry
+        jax.lax.fori_loop(jnp.minimum(P, nlive - g * P), P, zero_v, 0)
+
+        # Inclusive per-row position mask; the final partial block
+        # contributes exactly its written rows, and verify's K=k+1 rows
+        # get their per-row causal offsets here.
+        col = jax.lax.broadcasted_iota(jnp.int32, (fK, N), 1) * f \
+            + g * (P * bs)
+        allowed = col <= lim_ref[0]                   # [fK, 1] -> [fK, N]
+
+        def stack(buf, h):
             # In-VMEM dequant: bf16 pool tiles upcast at the registers,
             # scores and the accumulator stay fp32 throughout.
-            q_h = qs[h2].astype(jnp.float32)
-            k_h = ks[h2].astype(jnp.float32)
-            v_h = vs[h2].astype(jnp.float32)
+            return jnp.concatenate(
+                [buf[slot, p, h].astype(jnp.float32) for p in range(P)],
+                axis=0)
+
+        def head(h):
+            q_h = q_ref[0, h].astype(jnp.float32)     # [f*K, f*D]
             s = jax.lax.dot_general(
-                q_h, k_h, (((1,), (1,)), ((), ())),
+                q_h, stack(k_buf, h), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
             s = jnp.where(allowed, s, NEG_INF)
-            rows = slice(h2 * fK, (h2 + 1) * fK)
-            m_prev = m_scr[rows, 0:1]
+            m_prev = m_scr[h, :, 0:1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
             # A copy may have seen no attendable position yet (context
             # shorter than its first one): keep its state empty rather
             # than exp(NEG_INF - NEG_INF) = 1 a column.
             p = jnp.where(allowed, jnp.exp(s - m_new), 0.0)
-            l_new = (l_scr[rows, 0:1] * alpha
-                     + jnp.sum(p, axis=1, keepdims=True))
+            l_scr[h, :, 0:1] = (l_scr[h, :, 0:1] * alpha
+                                + jnp.sum(p, axis=1, keepdims=True))
             pv = jax.lax.dot_general(
-                p, v_h, (((1,), (0,)), ((), ())),
+                p, stack(v_buf, h), (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            acc_scr[rows] = acc_scr[rows] * alpha + pv
-            m_scr[rows, 0:1] = m_new
-            l_scr[rows, 0:1] = l_new
+            acc_scr[h] = acc_scr[h] * alpha + pv
+            m_scr[h, :, 0:1] = m_new
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finalize():
-        # Merge each query row's f copies (softmax over the union of
-        # their positions). Streams with no live blocks (dead table rows
-        # — inactive slots in the uniform group-batched program) keep
-        # l == 0 and emit zeros, matching the one-hot baseline's
-        # all-masked selector.
-        for h2 in range(bh):
-            copies = [slice(h2 * fK + i * K, h2 * fK + (i + 1) * K)
-                      for i in range(f)]
-            m_fin = m_scr[copies[0], 0:1]
-            for rows in copies[1:]:
-                m_fin = jnp.maximum(m_fin, m_scr[rows, 0:1])
-            l_fin = jnp.zeros_like(m_fin)
-            out = jnp.zeros((K, D), jnp.float32)
-            for i, rows in enumerate(copies):
-                w = jnp.exp(m_scr[rows, 0:1] - m_fin)
-                l_fin = l_fin + w * l_scr[rows, 0:1]
-                out = out + w * acc_scr[rows, i * D:(i + 1) * D]
-            l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
-            o_ref[0, h2] = (out / l_safe).astype(o_ref.dtype)
+        _head_loop(bh, head)
+        return carry
+
+    # Merge each query row's f copies (softmax over the union of their
+    # positions); a row that could attend nothing keeps l == 0 and emits
+    # zeros.
+    def merge(h):
+        copies = [slice(i * K, (i + 1) * K) for i in range(f)]
+        m_fin = m_scr[h, copies[0], 0:1]
+        for rows in copies[1:]:
+            m_fin = jnp.maximum(m_fin, m_scr[h, rows, 0:1])
+        l_fin = jnp.zeros_like(m_fin)
+        out = jnp.zeros((K, D), jnp.float32)
+        for i, rows in enumerate(copies):
+            w = jnp.exp(m_scr[h, rows, 0:1] - m_fin)
+            l_fin = l_fin + w * l_scr[h, rows, 0:1]
+            out = out + w * acc_scr[h, rows, i * D:(i + 1) * D]
+        l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
+        o_ref[0, h] = (out / l_safe).astype(o_ref.dtype)
+
+    @pl.when(groups == 0)
+    def _dead():
+        # A stream with no live blocks (a dead table row — an inactive
+        # slot of the uniform group-batched program) emits exact zeros,
+        # matching the one-hot baseline's all-masked selector.
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(groups > 0)
+    def _live():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+        tiles_of(0, 0, lambda dma: dma.start())
+        jax.lax.fori_loop(0, groups, group, 0)
+        _head_loop(bh, merge)
 
 
-def _heuristic_bh(num_heads: int, K: int) -> int:
-    """Head-block tile default: fold heads into one grid step while the
-    fp32 scratch stays within one sublane tile (bh*K <= 8 rows) — small
-    K (plain decode) amortizes per-step sequencing across heads, large K
-    (chunked prefill) already fills the step."""
-    bh = 1
-    while (bh * 2 <= num_heads and num_heads % (bh * 2) == 0
-           and bh * 2 * K <= 8):
-        bh *= 2
-    return bh
+# One step's VMEM: the rule below keeps its reckoning under this, well
+# inside the 16 MiB a v5e kernel gets without asking for more.
+_VMEM_BUDGET = 8 * 2 ** 20
+# ... and the kernel asks for no more than that default.
+_VMEM_LIMIT = 16 * 2 ** 20
+
+
+def _fold(D: int, bs: int) -> int:
+    """Positions of a block side by side in the pool's 128 lanes
+    (``kv_cache.kv_fold``, which imports this module)."""
+    return math.gcd(128 // D, bs) if D < 128 and 128 % D == 0 else 1
+
+
+def _step_vmem_bytes(bh: int, P: int, K: int, D: int, bs: int,
+                     itemsize: int, q_itemsize: int) -> int:
+    """What one grid step holds in VMEM at tile (bh, P): both halves of
+    the K and V buffers and the pipeline's two buffers of the q / limit /
+    output blocks (sublanes padded to the packed tile, lanes to 128),
+    the fp32 scratch, and the fp32 values of one head in flight."""
+    f = _fold(D, bs)
+    fK, fD, bsf = f * K, max(f * D, 128), bs // f
+    pad = lambda rows, size: -(-rows // (32 // size)) * (32 // size)  # noqa: E731
+    kv = 2 * P * bh * pad(bsf, itemsize) * fD * itemsize
+    q = bh * pad(fK, q_itemsize) * fD * q_itemsize
+    out = bh * pad(K, q_itemsize) * 128 * q_itemsize
+    lim = pad(fK, 4) * 128 * 4
+    scratch = 3 * bh * pad(fK, 4) * fD * 4
+    live = (2 * P * bsf * fD + 4 * pad(fK, 4) * max(P * bsf, 128)) * 4
+    return 2 * (kv + q + out + lim) + scratch + live
+
+
+def _tile_rule(K: int, nH: int, D: int, bs: int, J: int, itemsize: int,
+               q_itemsize: int = 2):
+    """(heads a step, table slots a step) from the shapes. Slots: as
+    many as give the score tile its 128 lanes (P * bs/f positions-rows),
+    no more than the table is wide. Heads: the most that divide nH and
+    keep the step under ``_VMEM_BUDGET`` — all of them for decode and
+    verify, fewer for a prefill chunk whose f*K query rows carry 128-lane
+    fp32 state each."""
+    P = max(1, min(J, 128 // max(1, bs // _fold(D, bs))))
+    bh = nH
+    while bh > 1 and (nH % bh or _step_vmem_bytes(
+            bh, P, K, D, bs, itemsize, q_itemsize) > _VMEM_BUDGET):
+        bh -= 1
+    return bh, P
 
 
 def _pool_rows(pool):
@@ -252,123 +364,126 @@ def _pool_rows(pool):
     tile of the stacked pool as one row of a 4-D array (a bitcast), so a
     kernel's index map names a tile by ONE precomputed index."""
     return pool.reshape((-1,) + pool.shape[3:])
+class AttendPlan(NamedTuple):
+    """What the attend needs of the tables and positions, which no layer
+    changes: ``_paged_forward`` builds it once an execution.
+
+    nlive [G, Q]: live blocks per stream (0 = dead stream).
+    rows  [G, Q, J]: the pool tile (``group*B + block``, layer 0) of
+          every table slot.
+    lim   [G, Q, f*K, 1]: per score row, last attendable position less
+          the copy's lane offset (see ``_pattn_kernel``)."""
+    nlive: jax.Array
+    rows: jax.Array
+    lim: jax.Array
 
 
-def _paged_call(q, pool_k, pool_v, layer, bt, pos, nlive, *, scale, bh):
-    """The pallas_call on flattened streams: q [GQ, K, nH, D], the
-    stacked lane-dense pools [L, G, B, nH, bs/f, f*D], ``layer`` a
-    traced int32 scalar, scalar-prefetch bt [GQ, J] / pos [GQ, K] /
-    nlive [GQ, 1] (all int32, group-LOCAL block ids).
+def _plan_local(block_tables, positions, *, B, bs, f):
+    """Per-shard plan: G = groups this shard owns, so ``group*B`` is the
+    shard's own tile row (block ids are group-local by construction)."""
+    G, Q, J = block_tables.shape
+    bt = block_tables.astype(jnp.int32)
+    pos = positions.astype(jnp.int32)
+    # Live block count per stream: the table's rows are a dense prefix
+    # (blocks append in order), so ceil((max pos + 1)/bs) of them are
+    # live, as far as the prefix goes; a dead leading entry marks the
+    # whole stream inactive.
+    dead = bt < 0
+    prefix = jnp.where(dead.any(axis=2), jnp.argmax(dead, axis=2), J)
+    nlive = jnp.minimum(jnp.clip(jnp.max(pos, axis=2) // bs + 1, 0, J),
+                        prefix)
+    group = jnp.arange(G, dtype=jnp.int32)[:, None, None]
+    rows = group * B + jnp.maximum(bt, 0)
+    # A row attends no further than the stream's live blocks reach (a
+    # prefill chunk's padding rows lie past the table: they attend what
+    # there is, stay finite, and nothing reads them).
+    reach = jnp.minimum(pos, (nlive * bs - 1)[:, :, None])
+    lim = reach[:, :, None, :] - jnp.arange(f, dtype=jnp.int32)[:, None]
+    return AttendPlan(nlive, rows, lim.reshape(G, Q, -1, 1))
+
+
+def _pool_geometry(pool_shape, D):
+    """(B, bs, f) of a pool as held, [L, G, B, nH, bs/f, f*D]."""
+    f = pool_shape[5] // D
+    return pool_shape[2], pool_shape[4] * f, f
+
+
+def attend_plan(block_tables, positions, pool, head_dim: int, *,
+                mesh=None) -> AttendPlan:
+    """The per-execution index work of ``paged_attention``: block_tables
+    [G, Q, J], positions [G, Q, K], ``pool`` the stacked pool as held.
+    Under a dp mesh each shard plans its own groups."""
+    B, bs, f = _pool_geometry(pool.shape, head_dim)
+    fn = _on_mesh(
+        functools.partial(_plan_local, B=B, bs=bs, f=f), mesh,
+        lambda dpn, mpn: (P(dpn), P(dpn)),
+        lambda dpn, mpn: AttendPlan(P(dpn), P(dpn), P(dpn)))
+    return fn(block_tables, positions)
+
+
+def _paged_local(q, pool_k, pool_v, layer, nlive, rows, lim, *, scale,
+                 tiles):
+    """Per-shard kernel entry: shapes are LOCAL (G = groups this shard
+    owns, nH = heads this shard owns). q [G, Q, K, nH, D], the stacked
+    lane-dense pools [L, G, B, nH, bs/f, f*D], ``layer`` a traced int32
+    scalar, the shard's ``AttendPlan``.
 
     q and the output ride head-major ([GQ, nH, K, D]) so a (bh, K, D)
     tile's last two dims span the array's: a head block in the
     second-minor position must be a multiple of 8 or all of nH on the
-    TPU, which nH=20 / bh=4 is not."""
-    GQ, K, nH, D = q.shape
-    _, G, B, _, bsf, fD = pool_k.shape
-    f = fD // D
-    bs = bsf * f
-    J = bt.shape[1]
-    Q = GQ // G
+    TPU."""
+    G, Q, K, nH, D = q.shape
+    B, bs, f = _pool_geometry(pool_k.shape, D)
+    bsf, fD = pool_k.shape[4:]
+    J = rows.shape[2]
+    GQ = G * Q
+    bh, P_ = tiles or _tile_rule(K, nH, D, bs, J, pool_k.dtype.itemsize,
+                                 q.dtype.itemsize)
     # Each query row f times, copy i in lanes i*D.. of a zero row (see
     # the kernel): [GQ, nH, f*K, f*D].
-    q = jnp.swapaxes(q, 1, 2)
+    q = jnp.swapaxes(q.reshape(GQ, K, nH, D), 1, 2)
     q = (q[:, :, None, :, None, :] *
          jnp.eye(f, dtype=q.dtype)[:, None, :, None]
          ).reshape(GQ, nH, f * K, fD)
-    # The pool tile of every (stream, table slot), as a fourth
-    # scalar-prefetch table, so the K/V index maps are one SMEM read a
-    # step. Steps past the live count clamp to the LAST live block — the
-    # revisited index lets the TPU pipeline skip the HBM copy, so masked
-    # steps cost sequencing only, not bandwidth. max(.., 0) guards dead
-    # rows (nlive == 0 streams never compute anyway).
-    jj = jnp.minimum(jnp.arange(J, dtype=jnp.int32)[None],
-                     jnp.maximum(nlive - 1, 0))
-    blk = jnp.maximum(jnp.take_along_axis(bt, jj, axis=1), 0)
-    group = (jnp.arange(GQ, dtype=jnp.int32) // Q)[:, None]
-    rows = (layer * G + group) * B + blk                     # [GQ, J]
+    # The layer's offset into the tile rows rides as one more scalar; a
+    # last group may name slots past the table (never live: padded).
+    base = (layer * (G * B)).astype(jnp.int32).reshape(1)
+    rows = jnp.pad(rows.reshape(GQ, J), ((0, 0), (0, -J % P_)))
 
-    def _kv_map(s, h, j, bt_p, pos_p, nl_p, rows_p):
-        return (rows_p[s, j], h, 0, 0)
-
-    def _q_map(s, h, j, bt_p, pos_p, nl_p, rows_p):
+    def _stream_map(s, h, nl_p, rows_p, base_p):
         return (s, h, 0, 0)
 
-    grid = (GQ, nH // bh, J)
+    def _lim_map(s, h, nl_p, rows_p, base_p):
+        return (s, 0, 0)
+
+    # The K/V head block (``heads`` in the kernel) is named apart from
+    # the query's: today the same, one K/V head per query head.
+    kv_buf = pltpu.VMEM((2, P_, bh, bsf, fD), pool_k.dtype)
     out = pl.pallas_call(
-        functools.partial(_pattn_kernel, scale=scale, bs=bs, bh=bh, K=K,
-                          D=D),
+        functools.partial(_pattn_kernel, scale=scale, bs=bs, K=K, D=D,
+                          P=P_),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, bh, f * K, fD), _q_map),
-                pl.BlockSpec((1, bh, bsf, fD), _kv_map),
-                pl.BlockSpec((1, bh, bsf, fD), _kv_map),
-            ],
-            out_specs=[pl.BlockSpec((1, bh, K, D), _q_map)],
+            num_scalar_prefetch=3,
+            grid=(GQ, nH // bh),
+            in_specs=[pl.BlockSpec((1, f * K, 1), _lim_map),
+                      pl.BlockSpec((1, bh, f * K, fD), _stream_map),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((1, bh, K, D), _stream_map)],
             scratch_shapes=[
-                pltpu.VMEM((bh * f * K, 128), jnp.float32),
-                pltpu.VMEM((bh * f * K, 128), jnp.float32),
-                pltpu.VMEM((bh * f * K, fD), jnp.float32),
+                kv_buf, kv_buf, pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((bh, f * K, 128), jnp.float32),
+                pltpu.VMEM((bh, f * K, 128), jnp.float32),
+                pltpu.VMEM((bh, f * K, fD), jnp.float32),
             ]),
         out_shape=[jax.ShapeDtypeStruct((GQ, nH, K, D), q.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT),
         name="_pattn_kernel",
         interpret=_interpret(),
-    )(bt, pos, nlive, rows.astype(jnp.int32), q, _pool_rows(pool_k),
-      _pool_rows(pool_v))
-    return jnp.swapaxes(out[0], 1, 2)
-
-
-def _paged_local(q, pool_k, pool_v, layer, block_tables, positions, *,
-                 scale, block_heads):
-    """Per-shard kernel entry: shapes are LOCAL (G = groups this shard
-    owns, nH = heads this shard owns). Block-table ids are group-local
-    by construction (the allocator only hands a slot blocks from its own
-    group), so no cross-shard indexing exists to fix up."""
-    G, Q, K, nH, D = q.shape
-    B, bsf, fD = pool_k.shape[2], pool_k.shape[4], pool_k.shape[5]
-    bs = bsf * (fD // D)
-    J = block_tables.shape[2]
-    GQ = G * Q
-    q2 = q.reshape(GQ, K, nH, D)
-    bt2 = block_tables.reshape(GQ, J).astype(jnp.int32)
-    pos2 = positions.reshape(GQ, K).astype(jnp.int32)
-    # Live block count per stream: the table's rows are a dense prefix
-    # (blocks append in order), so ceil((max pos + 1)/bs) of them are
-    # live; a dead leading entry marks the whole stream inactive.
-    nblk = jnp.clip(jnp.max(pos2, axis=1) // bs + 1, 0, J)
-    nlive = jnp.where(bt2[:, 0] < 0, 0, nblk)[:, None].astype(jnp.int32)
-
-    if block_heads:
-        bh = int(block_heads)
-    else:
-        heur = _heuristic_bh(nH, K)
-        cands = [c for c in (1, 2, 4, 8, 16)
-                 if c <= nH and nH % c == 0 and c * K <= 512]
-        measure = None
-        if autotune.search_allowed():
-            one_layer = (1,) + tuple(pool_k.shape[1:])
-
-            def run_at(v):
-                # Concrete stand-ins, never the (possibly traced)
-                # operands: one layer of pool, every table slot live, so
-                # all J blocks load.
-                return _paged_call(
-                    jnp.zeros(q2.shape, q2.dtype),
-                    jnp.zeros(one_layer, pool_k.dtype),
-                    jnp.zeros(one_layer, pool_v.dtype),
-                    jnp.int32(0),
-                    jnp.zeros(bt2.shape, jnp.int32),
-                    jnp.full(pos2.shape, J * bs - 1, jnp.int32),
-                    jnp.full(nlive.shape, J, jnp.int32),
-                    scale=scale, bh=v)
-            measure = autotune.measure_from_runner(run_at)
-        bh = autotune.resolve("paged_attn", (GQ, K, nH, D, B, bs, J),
-                              str(q.dtype), heur, cands, measure)
-    out = _paged_call(q2, pool_k, pool_v, layer, bt2, pos2, nlive,
-                      scale=scale, bh=bh)
-    return out.reshape(G, Q, K, nH, D)
+    )(nlive.reshape(GQ), rows, base, lim.reshape(GQ, f * K, 1), q,
+      _pool_rows(pool_k), _pool_rows(pool_v))
+    return jnp.swapaxes(out[0], 1, 2).reshape(G, Q, K, nH, D)
 
 
 def _on_mesh(local_fn, mesh, in_specs, out_specs):
@@ -394,8 +509,9 @@ def _pool_spec(dpn, mpn):
     return P(None, dpn, None, mpn, None, None)
 
 
-def paged_attention(q, pool_k, pool_v, layer, block_tables, positions, *,
-                    scale, block_heads: int = 0, mesh=None):
+def paged_attention(q, pool_k, pool_v, layer, block_tables=None,
+                    positions=None, *, scale, tiles=None, mesh=None,
+                    plan: Optional[AttendPlan] = None):
     """Table-driven paged attention over one layer of the block pool.
 
     q:            [G, Q, K, nH, D] — Q streams per group, K query rows
@@ -408,21 +524,27 @@ def paged_attention(q, pool_k, pool_v, layer, block_tables, positions, *,
                   unallocated tail entries).
     positions:    [G, Q, K] int32 inclusive last attendable position per
                   query row.
+    plan:         ``attend_plan(block_tables, positions, ...)`` where the
+                  caller built it once for all its layers (then the two
+                  are not read here).
+    tiles:        (heads a step, table slots a step) instead of the
+                  shape rule's: the tests' handle on the tiling.
 
     Returns [G, Q, K, nH, D] in q's dtype. When ``mesh`` spans dp/mp the
     call runs under shard_map (see ``_on_mesh``)."""
     if pltpu is None:  # pragma: no cover - pallas TPU support missing
         raise RuntimeError("pallas TPU backend unavailable; run with "
                            "inference.paged_kernel=false")
+    if plan is None:
+        plan = attend_plan(block_tables, positions, pool_k, q.shape[-1],
+                           mesh=mesh)
     fn = _on_mesh(
-        functools.partial(_paged_local, scale=scale,
-                          block_heads=block_heads), mesh,
+        functools.partial(_paged_local, scale=scale, tiles=tiles), mesh,
         lambda dpn, mpn: (P(dpn, None, None, mpn, None),
                           _pool_spec(dpn, mpn), _pool_spec(dpn, mpn), P(),
-                          P(dpn), P(dpn)),
+                          P(dpn), P(dpn), P(dpn)),
         lambda dpn, mpn: P(dpn, None, None, mpn, None))
-    return fn(q, pool_k, pool_v, jnp.asarray(layer, jnp.int32),
-              block_tables, positions)
+    return fn(q, pool_k, pool_v, jnp.asarray(layer, jnp.int32), *plan)
 
 
 # --------------------------------------------------------------------- #
@@ -542,5 +664,6 @@ def paged_write(pool_k, pool_v, k_new, v_new, layer, blk, off, *,
     return out[0], out[1]
 
 
-__all__ = ["paged_attention", "paged_write", "paged_kernel_enabled",
+__all__ = ["paged_attention", "attend_plan", "AttendPlan", "paged_write",
+           "paged_kernel_enabled", "attend_step_counts",
            "attend_flops_per_token", "attend_hbm_bytes_per_token"]

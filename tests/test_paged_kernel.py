@@ -172,16 +172,91 @@ class TestKernelParity:
         assert (out[0, 1] == 0.0).all() and (out[0, 2] == 0.0).all()
         assert np.abs(out[0, 0]).sum() > 0
 
-    def test_head_block_tilings_agree(self):
-        # The autotuner's candidates are tilings of the SAME math: any
-        # bh dividing nH must reproduce bh=1 bit-for-bit (fp32 scratch
-        # accumulation order per head is unchanged by head grouping).
-        q, pk, pv, bt, pos, sc = _case(5, [[14, 22, 5, 0]])
-        outs = [np.asarray(_kernel(q, pk, pv, bt, pos, scale=sc,
-                                   block_heads=bh))
-                for bh in (1, 2, 4)]
-        np.testing.assert_array_equal(outs[0], outs[1])
-        np.testing.assert_array_equal(outs[0], outs[2])
+    @pytest.mark.parametrize("tiles", [
+        (1, 1), (2, 1), (4, 1),     # one table slot a step: heads only
+        (4, 2), (1, 2),
+        (4, 3),                     # divides neither live count nor J
+        (2, 4),                     # J itself: one group a stream
+        (4, 8),                     # wider than the table
+    ])
+    def test_tilings_agree(self, tiles):
+        # Tilings of the SAME math: (heads a step, table slots a step).
+        # Every one reproduces the one-hot baseline at the tolerance of
+        # the default tiling; heads a step never changes a bit (a head's
+        # fp32 accumulation order is its own), slots a step regroups the
+        # online-softmax updates and agrees to rounding.
+        q, pk, pv, bt, pos, sc = _case(5, [[14, 22, 5, 0, 32, 9]])
+        ref = np.asarray(_ref_attend(q, pk, pv, bt, pos, sc))
+        out = np.asarray(_kernel(q, pk, pv, bt, pos, scale=sc,
+                                 tiles=tiles))
+        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+        bh, P = tiles
+        per_head = np.asarray(_kernel(q, pk, pv, bt, pos, scale=sc,
+                                      tiles=(1, P)))
+        np.testing.assert_array_equal(out, per_head)
+        per_slot = np.asarray(_kernel(q, pk, pv, bt, pos, scale=sc,
+                                      tiles=(bh, 1)))
+        np.testing.assert_allclose(out, per_slot, atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("name,lengths,kw,tiles", [
+        # a prefill chunk (K = 128 rows) whose last block is ragged and
+        # whose last group holds one live slot of four
+        ("chunk_k128_ragged_last_block", [[7]],
+         dict(K=128, D=64, bs=16, B=16, J=12), (2, 4)),
+        # live counts 6 and 2 at P = 4: a half-dead group, and a stream
+        # with fewer live blocks than a group holds
+        ("group_half_dead", [[44, 12, 0, 31]], dict(J=8, B=24), (4, 4)),
+        # two streams name the SAME two prefix tiles inside one group
+        ("shared_prefix_inside_one_group", [[17, 20, 25]],
+         dict(shared_prefix_blocks=2), (4, 4)),
+        # verify rows k = 0..3 at positions 14..17: rows 2 and 3 reach
+        # into the second group (P*bs = 16), rows 0 and 1 must not
+        ("verify_rows_cross_a_group_boundary", [[15, 16, 29, 3]],
+         dict(K=4), (4, 2)),
+        # the same under the shape rule's own tiles
+        ("verify_rows_rule_tiles", [[15, 16, 29, 3]], dict(K=4), None),
+    ])
+    def test_groups_of_table_slots(self, name, lengths, kw, tiles):
+        q, pk, pv, bt, pos, sc = _case(7, lengths, **kw)
+        out = _kernel(q, pk, pv, bt, pos, scale=sc, tiles=tiles)
+        ref = _ref_attend(q, pk, pv, bt, pos, sc)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
+    def test_rows_past_the_table_attend_what_there_is(self):
+        # A prefill chunk's last rows are padding whose positions lie
+        # past the blocks the slot holds (the engine allocates for the
+        # prompt, not for the chunk): they attend the live blocks only
+        # and stay finite — a NaN there would reach the real rows through
+        # the next layer's cache rows — and the real rows are exact.
+        q, pk, pv, bt, pos, sc = _case(8, [[33]], K=32, D=32, bs=16,
+                                       B=64, J=16)
+        bt = jnp.asarray(np.asarray(bt)).at[0, 0, 3:].set(
+            kv_cache.DEAD_BLOCK)                  # positions 0..47 held
+        out = np.asarray(_kernel(q, pk, pv, bt, pos, scale=sc))
+        ref = np.asarray(_ref_attend(q, pk, pv, bt, pos, sc))
+        assert np.isfinite(out).all()
+        np.testing.assert_allclose(out[0, 0, :16], ref[0, 0, :16],
+                                   atol=2e-5, rtol=2e-5)
+
+    def test_tile_rule_from_shapes(self):
+        # The serve cell's shapes (gpt2-large, bf16 pool, block 16,
+        # table 64): every head of sixteen slots a step for decode and
+        # verify; a prefill chunk's 256 score rows a head carry 128-lane
+        # fp32 state, so fewer heads under the same VMEM budget. Local
+        # heads are what the rule sees (gpt2-xl: 25; mp splits them).
+        assert pa._tile_rule(1, 20, 64, 16, 64, 2) == (20, 16)
+        assert pa._tile_rule(5, 20, 64, 16, 64, 2) == (20, 16)
+        bh, P = pa._tile_rule(128, 20, 64, 16, 64, 2)
+        assert P == 16 and 20 % bh == 0 and 1 < bh < 20
+        assert pa._tile_rule(128, 25, 64, 16, 64, 2)[0] in (1, 5)
+        # a table narrower than a group; head_dim 128 does not fold
+        assert pa._tile_rule(1, 4, 16, 8, 4, 4) == (4, 4)
+        assert pa._tile_rule(1, 16, 128, 16, 64, 4, 4) == (16, 8)
+        for K in (1, 5, 32, 128):
+            bh, P = pa._tile_rule(K, 20, 64, 16, 64, 2)
+            assert pa._step_vmem_bytes(bh, P, K, 64, 16, 2, 2) \
+                <= pa._VMEM_BUDGET
 
 
 # --------------------------------------------------------------------- #

@@ -169,7 +169,8 @@ def test_backward_ops_sit_under_fwd_bwd_and_optimizer_ops_do_not(
 def serve_engine(params):
     eng = InferenceEngine(CFG, params, config={"inference": {
         "max_slots": 4, "max_seq_len": 64, "prefill_chunk": 8,
-        "block_size": 16}}, mesh=build_mesh(devices=jax.devices()[:1]))
+        "block_size": 16, "paged_kernel": True}},
+        mesh=build_mesh(devices=jax.devices()[:1]))
     yield eng
     eng.close()
 
@@ -219,7 +220,8 @@ SERVE_SPANS = {
                 "rids"},
     "prefill_plan": set(), "prefill_chunk": {"ci", "active_groups"},
     "prefill_fetch": set(),
-    "decode": {"iteration", "active", "live_blocks", "context_tokens"},
+    "decode": {"iteration", "active", "live_blocks", "context_tokens",
+               "attend_steps", "attend_live_steps"},
     "decode_tables": set(), "decode_dispatch": set(),
     "decode_fetch": set(), "decode_advance": set(),
     "emit": set(), "serve_idle": {"why"}}
@@ -300,6 +302,44 @@ def test_serve_spans_nest_and_follow_a_request(serve_annotations):
     # decode's end-of-span args: what _cache_accounting read
     assert all(a["live_blocks"] > 0 and a["context_tokens"] > 0
                for _, _, a in found["decode"])
+
+
+def test_decode_span_counts_the_attend_steps(serve_annotations,
+                                             serve_engine):
+    """By hand: every context here is under one block of 16 positions,
+    so a stream that holds a block is ONE group of table slots (a live
+    step) and each of the other slots is one empty grid step; the tiny
+    model's heads are one head block. The running ratio is in the
+    serving snapshot."""
+    found, report = serve_annotations
+    args = [a for _, _, a in found["decode"]]
+    assert all(a["attend_steps"] == serve_engine.max_slots for a in args)
+    assert all(a["attend_live_steps"] == a["active"] for a in args)
+    assert {a["active"] for a in args} == {1, 2}
+    assert report["attend_live_step_share"] == pytest.approx(
+        sum(a["attend_live_steps"] for a in args) /
+        sum(a["attend_steps"] for a in args), abs=1e-4)
+
+
+def test_the_readers_list_names_the_same_args():
+    from deepspeed_tpu.monitor.xplane_reader import SPAN_ARGS, SPANS
+    assert set(SPAN_ARGS) <= set(SPANS)
+    for span, args in {**SERVE_SPANS, **TRAIN_SPANS}.items():
+        assert args <= set(SPAN_ARGS.get(span, ())), span
+    assert {"attend_steps", "attend_live_steps"} <= set(SPAN_ARGS["decode"])
+
+
+def test_attend_step_counts_from_live_blocks():
+    from deepspeed_tpu.ops.paged_attention import attend_step_counts
+    # the serve cell's decode: all 20 heads of 16 table slots a step;
+    # 25 live blocks are two groups, 16 one, 33 three, 0 an empty step
+    cell = dict(num_heads=20, head_dim=64, block_size=16, table_width=64,
+                kv_itemsize=2)
+    assert attend_step_counts([25, 16, 0, 0, 33], K=1, **cell) == (8, 6)
+    assert attend_step_counts([0, 0], K=1, **cell) == (2, 0)
+    # a prefill chunk runs fewer heads a step: more head blocks
+    steps, live = attend_step_counts([16], K=128, **cell)
+    assert steps == live and steps > 1 and 20 % steps == 0
 
 
 @pytest.fixture(scope="module")

@@ -247,6 +247,50 @@ def test_kernel_compiles_for_v5e(name, one_chip, as_tpu):
     _compile(fn, one_chip, *shapes)
 
 
+@pytest.mark.parametrize("K,Q", [(1, 64), (128, 1)],
+                         ids=["decode_k1_64_streams", "prefill_k128"])
+def test_paged_attention_at_the_serve_cells_shape(K, Q, one_chip, as_tpu):
+    """The attend ALONE at `serve.gpt2-large.chat-over`'s shapes (64
+    slots or one 128-row chunk, 20 heads x 64, 1280 blocks of 16, table
+    64, 36 layers, bf16) under the shape rule's own tiles: nothing in
+    the program but parameters, bitcasts and the kernel holds the pool
+    or a layer of it, its temporaries are a few MiB at most, and the
+    kernel fits the scoped VMEM it asks for (the compiler refuses one
+    that does not) — the default 16 MiB, with the rule's own reckoning
+    of a step inside its budget."""
+    from deepspeed_tpu.analysis.hlo_text import ops_in_units_of
+    from deepspeed_tpu.inference.kv_cache import PagedKVCacheSpec
+    from deepspeed_tpu.ops import paged_attention as pa
+    spec = PagedKVCacheSpec(
+        num_layers=36, num_slots=SERVE["max_slots"],
+        num_blocks=SERVE["num_blocks"], block_size=SERVE["block_size"],
+        max_len=SERVE["max_len"], num_heads=NH, head_dim=D,
+        dtype=jnp.bfloat16)
+    J = spec.max_blocks_per_slot
+    pool = jax.ShapeDtypeStruct(spec.shape, spec.dtype, sharding=one_chip)
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in (((1, Q, K, NH, D), jnp.bfloat16),
+                                 ((), jnp.int32), ((1, Q, J), jnp.int32),
+                                 ((1, Q, K), jnp.int32))]
+    compiled = jax.jit(functools.partial(
+        pa.paged_attention, scale=1.0 / math.sqrt(D))).lower(
+        args[0], pool, pool, *args[1:]).compile()
+    text = compiled.as_text()
+    seen = ops_in_units_of(text, math.prod(spec.shape[2:]))
+    assert {op for op, _ in seen} <= {"parameter", "bitcast",
+                                      "custom-call"}, seen
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 20
+    calls = [line for line in text.splitlines()
+             if "%_pattn_kernel" in line.split(" = ")[0]
+             and " custom-call(" in line]
+    assert len(calls) == 1 and "tpu_custom_call" in calls[0]
+    assert f'"size":"{pa._VMEM_LIMIT}"' in calls[0]
+    assert pa._VMEM_LIMIT <= 16 * 2 ** 20
+    bh, P = pa._tile_rule(K, NH, D, spec.block_size, J, 2, 2)
+    assert pa._step_vmem_bytes(bh, P, K, D, spec.block_size, 2, 2) \
+        <= pa._VMEM_BUDGET < pa._VMEM_LIMIT
+
+
 # ------------------------------------------------------------------ #
 # The fused optimizer's whole one-pass step at gpt2-large's shapes: what
 # the chip compiler makes of the in-place plan (ops/fused_update.py).
