@@ -48,6 +48,7 @@ import numpy as np
 from jax import lax
 
 from . import kv_cache
+from .served import ServedModel, register
 from ..models.gpt2 import GPT2Config
 from ..ops import paged_attention as paged_attn_ops
 from ..models.transformer import (dense, gelu_dense_fn, layer_norm,
@@ -390,6 +391,88 @@ def sample_tokens(logits: jax.Array, key: jax.Array,
     return jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
 
 
+# --------------------------------------------------------------------- #
+# GPT-2 as a served model (inference/served.py): the first
+# implementation of the interface the engine serves through
+# --------------------------------------------------------------------- #
+class GPT2Served(ServedModel):
+    """Per-head K and V rows in two pools; the four programs above."""
+
+    def __init__(self, cfg: GPT2Config):
+        _check_cfg(cfg)
+        super().__init__(cfg)
+
+    @property
+    def max_positions(self) -> int:
+        return int(self.cfg.max_seq_length)
+
+    @property
+    def init_fn(self) -> Callable:
+        from ..models.gpt2 import gpt2_init
+        return gpt2_init
+
+    @property
+    def cache_layers(self) -> int:
+        return int(self.cfg.num_layers)
+
+    @property
+    def cache_heads(self) -> int:
+        return int(self.cfg.num_heads)
+
+    @property
+    def cache_row_width(self) -> int:
+        return int(self.cfg.head_dim)
+
+    def cache_pools(self, block_size: int):
+        D = self.cache_row_width
+        f = kv_cache.kv_fold(D, block_size)
+        tile = (self.cache_heads, block_size // f, f * D)
+        return (("k", tile), ("v", tile))
+
+    @property
+    def attend_dims(self) -> Tuple[int, int, int]:
+        return self.cache_heads, self.cache_row_width, self.cache_row_width
+
+    def attend_step_counts(self, live_blocks, *, K, spec, mp, q_itemsize):
+        return paged_attn_ops.attend_step_counts(
+            live_blocks, K=K, num_heads=max(1, spec.num_heads // mp),
+            head_dim=spec.head_dim, block_size=spec.block_size,
+            table_width=spec.max_blocks_per_slot,
+            kv_itemsize=int(jnp.dtype(spec.dtype).itemsize),
+            q_itemsize=q_itemsize)
+
+    def decode(self, params, pools, tokens, lengths, block_tables, *,
+               num_groups, paged_kernel, mesh=None):
+        logits, kc, vc = gpt2_decode_paged(
+            params, *pools, tokens, lengths, block_tables, self.cfg,
+            num_groups, paged_kernel=paged_kernel, mesh=mesh)
+        return logits, (kc, vc), ()
+
+    def verify(self, params, pools, tokens, lengths, block_tables, *,
+               num_groups, paged_kernel, mesh=None):
+        logits, kc, vc = gpt2_verify_paged(
+            params, *pools, tokens, lengths, block_tables, self.cfg,
+            num_groups, paged_kernel=paged_kernel, mesh=mesh)
+        return logits, (kc, vc), ()
+
+    def prefill_chunk(self, params, pools, tokens, bt_rows, start,
+                      last_idx, active, *, paged_kernel, mesh=None):
+        logits, kc, vc = gpt2_prefill_chunk_paged(
+            params, *pools, tokens, bt_rows, start, last_idx, active,
+            self.cfg, paged_kernel=paged_kernel, mesh=mesh)
+        return logits, (kc, vc), ()
+
+    def prefill_full(self, params, pools, tokens, bt_rows, last_idx, *,
+                     attention_fn=None, mesh=None):
+        logits, kc, vc = gpt2_prefill_full_paged(
+            params, *pools, tokens, bt_rows, last_idx, self.cfg,
+            attention_fn=attention_fn, mesh=mesh)
+        return logits, (kc, vc), ()
+
+
+register(GPT2Config, GPT2Served)
+
+
 __all__ = ["gpt2_decode_paged", "gpt2_verify_paged",
            "gpt2_prefill_chunk_paged", "gpt2_prefill_full_paged",
-           "spec_accept", "sample_tokens"]
+           "spec_accept", "sample_tokens", "GPT2Served"]

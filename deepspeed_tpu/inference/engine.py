@@ -3,6 +3,10 @@ engine: checkpoint/params in, continuously-batched tokens out.
 
 Architecture (mirrors the training engine's discipline):
 
+- The MODEL comes in as a served model (inference/served.py): what it
+  keeps per token and layer in the paged pool, and its decode / verify /
+  prefill programs. Everything below — slots, admission, the allocator,
+  the prefix cache, sampling, spans — is the same for every model.
 - TWO compiled programs serve everything: ``decode_step`` (one token for
   every slot at once) and ``prefill_step`` (one chunk of one slot's
   prompt — or the whole padded prompt when ``prefill_chunk: 0``). Both
@@ -47,9 +51,9 @@ from . import decode as decode_mod
 from . import kv_cache
 from .quantize import (dequantize, quantize_params, quantized_bytes,
                        resolve_kv_dtype)
+from .served import ServedModel, served_model, split_counters, with_counters
 from .spec import NGramDrafter
 from .. import constants as C
-from ..models.gpt2 import GPT2Config
 from ..monitor import Telemetry
 from ..monitor.telemetry import ids_arg
 from ..monitor.memory import analytic_state_bytes
@@ -70,14 +74,17 @@ except Exception:  # pragma: no cover
 class InferenceEngine:
     """Batched autoregressive serving over a device mesh."""
 
-    def __init__(self, model_cfg: GPT2Config, params: Any,
+    def __init__(self, model_cfg: Any, params: Any,
                  config: Any = None, mesh: Optional[Mesh] = None,
                  rng: Optional[jax.Array] = None,
                  param_shardings: Any = None):
         if isinstance(config, str):
             config = load_config_json(config)
         config = dict(config or {})
+        # A ServedModel, or a model config an implementation is
+        # registered for (GPT-2's: inference/decode.py).
         self.model_cfg = model_cfg
+        served = self._served = served_model(model_cfg)
         self.icfg = InferenceConfig(config)
         self.tcfg = TelemetryConfig(config)
         self.mesh = mesh if mesh is not None else build_mesh()
@@ -88,11 +95,11 @@ class InferenceEngine:
         # --- static serving geometry (all of it compiled-program shape) ---
         self.max_slots = int(self.icfg.max_slots)
         self.max_len = int(self.icfg.max_seq_len) or \
-            int(model_cfg.max_seq_length)
-        if self.max_len > model_cfg.max_seq_length:
+            int(served.max_positions)
+        if self.max_len > served.max_positions:
             raise ValueError(
                 f"inference.max_seq_len={self.max_len} exceeds the model's "
-                f"position table ({model_cfg.max_seq_length})")
+                f"position table ({served.max_positions})")
         self.prefill_chunk = int(self.icfg.prefill_chunk)
         if self.prefill_chunk > 0 and self.max_len % self.prefill_chunk:
             raise ValueError(
@@ -149,16 +156,17 @@ class InferenceEngine:
 
         # --- the KV cache: the paged block pool, born sharded ---
         kv_dtype = resolve_kv_dtype(self.icfg.kv_cache_dtype,
-                                    model_cfg.dtype)
+                                    served.dtype)
         self.cache_spec = kv_cache.PagedKVCacheSpec(
-            num_layers=model_cfg.num_layers,
+            num_layers=served.cache_layers,
             num_slots=self.max_slots, num_blocks=self.num_blocks,
             block_size=self.block_size, max_len=self.max_len,
-            num_heads=model_cfg.num_heads,
-            head_dim=model_cfg.head_dim, num_groups=self.dp,
-            dtype=kv_dtype)
+            num_heads=served.cache_heads,
+            head_dim=served.cache_row_width, num_groups=self.dp,
+            dtype=kv_dtype, pools=served.cache_pools(self.block_size))
         self.cache = kv_cache.init_paged_cache(self.cache_spec, self.mesh)
-        self._cache_sh = kv_cache.paged_shardings(self.mesh)
+        self._cache_sh = kv_cache.paged_shardings(
+            self.mesh, self.cache_spec.pool_names)
         self.allocator = kv_cache.BlockAllocator(self.cache_spec)
         self.block_tables = np.full(
             (self.max_slots, self.cache_spec.max_blocks_per_slot),
@@ -186,7 +194,7 @@ class InferenceEngine:
         self.serving = ServingAggregator(self.max_slots,
                                          label=self.replica or None)
         self._attach_slo_overlays()
-        tel_meta = dict(mode="serving", model=model_cfg.name,
+        tel_meta = dict(mode="serving", model=served.name,
                         dp=self.dp, mp=self.mp, sp=self.sp,
                         max_slots=self.max_slots, max_seq_len=self.max_len,
                         prefill_chunk=self.prefill_chunk,
@@ -195,7 +203,7 @@ class InferenceEngine:
                         spec_k=self.spec_k,
                         replica=self.replica,
                         quantize=self.quantize,
-                        precision=jnp.dtype(model_cfg.dtype).name,
+                        precision=jnp.dtype(served.dtype).name,
                         param_bytes=self.param_bytes,
                         kv_cache_bytes=self.cache_spec.nbytes())
         # Analytic attend pricing (both ways, per generated token at
@@ -206,7 +214,6 @@ class InferenceEngine:
         self.serving.attend_mode = ("kernel" if self.paged_kernel
                                     else "onehot")
         sp_ = self.cache_spec
-        kvi = jnp.dtype(sp_.dtype).itemsize
         tel_meta["paged_kernel"] = self.paged_kernel
         # The write always engages (one path), so its counter is
         # static: rows go into the donated pool in place, one block
@@ -216,26 +223,19 @@ class InferenceEngine:
         tel_meta["kv_write"] = {
             "mode": "in_place", "fold": sp_.fold,
             "pool_shape": list(sp_.shape),
-            "tile_bytes": sp_.block_nbytes() // (2 * sp_.num_layers),
+            "pools": {n: list(sh) for n, sh in sp_.pool_shapes.items()},
+            "tile_bytes": sp_.block_nbytes()
+            // (len(sp_.pool_names) * sp_.num_layers),
             "rows_per_decode": self.max_slots * (self.spec_k + 1)}
         tel_meta["attend_flops_per_token"] = {
-            "live_ctx_max": paged_attn_ops.attend_flops_per_token(
-                sp_.num_heads, sp_.head_dim, sp_.block_size,
-                context=sp_.max_len, num_layers=sp_.num_layers),
-            "pool_capacity": paged_attn_ops.attend_flops_per_token(
-                sp_.num_heads, sp_.head_dim, sp_.block_size,
-                pool_blocks=sp_.blocks_per_group,
-                num_layers=sp_.num_layers),
+            "live_ctx_max": self._attend_cost(context=sp_.max_len)[0],
+            "pool_capacity": self._attend_cost(
+                pool_blocks=sp_.blocks_per_group)[0],
             "projection": "analytic"}
         tel_meta["attend_hbm_bytes_per_token"] = {
-            "live_ctx_max": paged_attn_ops.attend_hbm_bytes_per_token(
-                sp_.num_heads, sp_.head_dim, sp_.block_size,
-                context=sp_.max_len, kv_itemsize=kvi,
-                num_layers=sp_.num_layers),
-            "pool_capacity": paged_attn_ops.attend_hbm_bytes_per_token(
-                sp_.num_heads, sp_.head_dim, sp_.block_size,
-                pool_blocks=sp_.blocks_per_group, kv_itemsize=kvi,
-                num_layers=sp_.num_layers),
+            "live_ctx_max": self._attend_cost(context=sp_.max_len)[1],
+            "pool_capacity": self._attend_cost(
+                pool_blocks=sp_.blocks_per_group)[1],
             "projection": "analytic"}
         self.telemetry = Telemetry(
             self.tcfg, default_report_steps=50, meta=tel_meta)
@@ -260,11 +260,12 @@ class InferenceEngine:
                 "verify_step", self._build_verify_step())
 
         log_dist(
-            f"InferenceEngine initialized: {model_cfg.name}, "
+            f"InferenceEngine initialized: {served.name}, "
             f"slots={self.max_slots} (dp={self.dp}), "
             f"cache=paged bs={self.block_size} x{self.num_blocks} blocks "
-            f"{self.max_len}x{model_cfg.num_heads}h "
-            f"({self.cache_spec.nbytes() / 2 ** 20:.1f} MiB K+V), "
+            f"{self.max_len}x{served.cache_heads}h "
+            f"({self.cache_spec.nbytes() / 2 ** 20:.1f} MiB "
+            f"{'+'.join(self.cache_spec.pool_names)}), "
             f"prefill={'full' if self.prefill_chunk == 0 else f'chunk {self.prefill_chunk}'}, "
             f"spec_k={self.spec_k}, quantize={self.quantize}"
             + (f", replica={self.replica}" if self.replica else ""),
@@ -273,99 +274,121 @@ class InferenceEngine:
     # ------------------------------------------------------------------ #
     # Compiled-path builders
     # ------------------------------------------------------------------ #
+    @property
+    def served(self) -> ServedModel:
+        """The served model behind ``model_cfg`` (resolved where asked:
+        the builders run on engine shells that hold a config only)."""
+        return self.__dict__.get("_served") or served_model(self.model_cfg)
+
+    def _pools(self) -> Tuple[jax.Array, ...]:
+        """The cache's pools in the served model's order."""
+        return tuple(self.cache.values())
+
+    def _store_pools(self, pools) -> None:
+        for name, pool in zip(list(self.cache), pools):
+            self.cache[name] = pool
+
     def _runtime_params(self, params):
         """Dequantize inside the compiled program (int8 at rest,
         compute-dtype transients); identity for none/bf16."""
         if self.quantize == "int8":
-            return dequantize(params, self.model_cfg.dtype)
+            return dequantize(params, self.served.dtype)
         return params
 
-    def _build_decode_step(self) -> Callable:
-        cfg = self.model_cfg
-        dp = self.dp
+    def _jit_step(self, step: Callable) -> Callable:
+        """``step(params, *pools, ...) -> (*pools, fetch, logits)``: the
+        pools donated and returned where they lie."""
+        sh = tuple(self._cache_sh.values())
+        return jax.jit(step, donate_argnums=tuple(range(1, 1 + len(sh))),
+                       out_shardings=sh + (None, None))
 
-        def decode_step(params, kc, vc, tokens, lengths, bt, key,
-                        temperature):
+    def _build_decode_step(self) -> Callable:
+        served = self.served
+        n = len(self._cache_sh)
+
+        def decode_step(params, *args):
+            pools, (tokens, lengths, bt, key, temperature) = \
+                args[:n], args[n:]
             p = self._runtime_params(params)
-            logits, kc, vc = decode_mod.gpt2_decode_paged(
-                p, kc, vc, tokens, lengths, bt, cfg, dp,
+            logits, pools, counters = served.decode(
+                p, pools, tokens, lengths, bt, num_groups=self.dp,
                 paged_kernel=self.paged_kernel, mesh=self.mesh)
             sampled = decode_mod.sample_tokens(logits, key, temperature)
-            return kc, vc, sampled, logits
+            return (*pools, with_counters(sampled, counters), logits)
 
-        sh = self._cache_sh
-        return jax.jit(decode_step, donate_argnums=(1, 2),
-                       out_shardings=(sh["k"], sh["v"], None, None))
+        return self._jit_step(decode_step)
 
     def _build_prefill_step(self) -> Callable:
-        cfg = self.model_cfg
-        dp = self.dp
+        served = self.served
+        n = len(self._cache_sh)
         attention_fn = None
         if self.prefill_chunk == 0 and self.sp > 1:
             from ..ops.ring_attention import ring_attention_fn
             attention_fn = ring_attention_fn(self.mesh)
-        sh = self._cache_sh
 
         if self.prefill_chunk > 0:
             # Group-batched chunked prefill: one chunk of one slot per
             # dp group (single admissions leave the other groups' rows
             # DEAD — uniform program, writes land nowhere).
-            def prefill_step(params, kc, vc, tokens, bt_rows, start,
-                             last_idx, active, key, temperature):
+            def prefill_step(params, *args):
+                pools, (tokens, bt_rows, start, last_idx, active, key,
+                        temperature) = args[:n], args[n:]
                 p = self._runtime_params(params)
-                logits, kc, vc = decode_mod.gpt2_prefill_chunk_paged(
-                    p, kc, vc, tokens, bt_rows, start, last_idx,
-                    active, cfg, paged_kernel=self.paged_kernel,
-                    mesh=self.mesh)
+                logits, pools, counters = served.prefill_chunk(
+                    p, pools, tokens, bt_rows, start, last_idx, active,
+                    paged_kernel=self.paged_kernel, mesh=self.mesh)
                 sampled = decode_mod.sample_tokens(logits, key,
                                                    temperature)
-                return kc, vc, sampled, logits
+                return (*pools, with_counters(sampled, counters), logits)
         else:
-            def prefill_step(params, kc, vc, tokens, bt_rows, last_idx,
-                             key, temperature):
+            def prefill_step(params, *args):
+                pools, (tokens, bt_rows, last_idx, key, temperature) = \
+                    args[:n], args[n:]
                 p = self._runtime_params(params)
-                logits, kc, vc = decode_mod.gpt2_prefill_full_paged(
-                    p, kc, vc, tokens, bt_rows, last_idx, cfg,
+                logits, pools, counters = served.prefill_full(
+                    p, pools, tokens, bt_rows, last_idx,
                     attention_fn=attention_fn, mesh=self.mesh)
                 sampled = decode_mod.sample_tokens(logits, key,
                                                    temperature)
-                return kc, vc, sampled, logits
+                return (*pools, with_counters(sampled, counters), logits)
 
-        return jax.jit(prefill_step, donate_argnums=(1, 2),
-                       out_shardings=(sh["k"], sh["v"], None, None))
+        return self._jit_step(prefill_step)
 
     def _build_verify_step(self) -> Callable:
         """Speculative draft-then-verify: one batched K=spec_k+1 step,
         in-graph acceptance (decode.spec_accept), ONE [S, K+2] int32
         readback — the same single host fetch per iteration plain
         decode pays."""
-        cfg = self.model_cfg
-        dp = self.dp
+        served = self.served
+        n = len(self._cache_sh)
 
-        def verify_step(params, kc, vc, tokens, lengths, bt, key,
-                        temperature):
+        def verify_step(params, *args):
+            pools, (tokens, lengths, bt, key, temperature) = \
+                args[:n], args[n:]
             p = self._runtime_params(params)
-            logits, kc, vc = decode_mod.gpt2_verify_paged(
-                p, kc, vc, tokens, lengths, bt, cfg, dp,
+            # (A model's counters are not fetched on this path.)
+            logits, pools, _ = served.verify(
+                p, pools, tokens, lengths, bt, num_groups=self.dp,
                 paged_kernel=self.paged_kernel, mesh=self.mesh)
             out = decode_mod.spec_accept(logits, tokens, key, temperature)
-            return kc, vc, out, logits
+            return (*pools, out, logits)
 
-        sh = self._cache_sh
-        return jax.jit(verify_step, donate_argnums=(1, 2),
-                       out_shardings=(sh["k"], sh["v"], None, None))
+        return self._jit_step(verify_step)
 
     def _build_copy_block(self) -> Callable:
         """The device half of copy-on-write: duplicate one block's K/V
         rows (all layers) into a private block of the same group."""
-        @jax.named_scope("cow_copy")
-        def copy_block(kc, vc, src_onehot, dst_onehot):
-            return (kv_cache.paged_copy_block(kc, src_onehot, dst_onehot),
-                    kv_cache.paged_copy_block(vc, src_onehot, dst_onehot))
+        sh = tuple(self._cache_sh.values())
 
-        sh = self._cache_sh
-        return jax.jit(copy_block, donate_argnums=(0, 1),
-                       out_shardings=(sh["k"], sh["v"]))
+        @jax.named_scope("cow_copy")
+        def copy_block(*args):
+            pools, (src_onehot, dst_onehot) = args[:len(sh)], args[len(sh):]
+            return tuple(kv_cache.paged_copy_block(pool, src_onehot,
+                                                   dst_onehot)
+                         for pool in pools)
+
+        return jax.jit(copy_block, donate_argnums=tuple(range(len(sh))),
+                       out_shardings=sh)
 
     def _next_key(self) -> jax.Array:
         self._rng_calls += 1
@@ -552,10 +575,10 @@ class InferenceEngine:
             raise ValueError(
                 f"prompt length {plen} leaves no room to generate in a "
                 f"{self.max_len}-token slot")
-        kc, vc = self.cache["k"], self.cache["v"]
+        n_ctr = len(self.served.counter_names)
         with tl.span("prefill", slots=1, prompt_tokens=plen,
                      cached_tokens=0, chunks=1,
-                     rids=ids_arg(None if rid is None else [rid])):
+                     rids=ids_arg(None if rid is None else [rid])) as span:
             padded = np.zeros(self.max_len, np.int32)
             padded[:plen] = prompt
             G = self.dp
@@ -571,19 +594,22 @@ class InferenceEngine:
                 bt_rows = np.full((G, J), kv_cache.DEAD_BLOCK, np.int32)
                 bt_rows[group] = row
             with tl.span("prefill_chunk", ci=0, active_groups=1):
-                kc, vc, tok, logits = self._prefill_fn(
-                    self._params, kc, vc, padded, bt_rows,
+                *pools, tok, logits = self._prefill_fn(
+                    self._params, *self._pools(), padded, bt_rows,
                     np.int32(plen - 1), self._next_key(),
                     np.float32(temperature))
             if self.drafter is not None:
                 self.drafter.begin(slot, prompt)
             self.serving.note_admit(plen, 0)
-            self.cache["k"], self.cache["v"] = kc, vc
+            self._store_pools(pools)
             tl.raise_pending()
             with tl.span("prefill_fetch"):
                 out_logits = np.asarray(jax.device_get(logits)) \
                     if return_logits else None
-                tok = int(jax.device_get(tok))
+                tok, counters = split_counters(
+                    np.asarray(jax.device_get(tok)).reshape(-1), n_ctr)
+                tok = int(tok[0])
+            self._note_counters(span, counters)
         if self.serving.ledger is not None:
             self.serving.ledger.note("prefill", time.perf_counter() - t0)
         return tok, out_logits
@@ -621,15 +647,21 @@ class InferenceEngine:
                      prompt_tokens=sum(len(p) for _, p, _ in admissions),
                      rids=ids_arg(rids)) as span:
             with tl.span("prefill_plan"):
-                kc, vc, plans, tails = self._plan_prefill(admissions)
-            steps, held = self._run_prefill_chunks(kc, vc, plans, tails,
+                pools, plans, tails = self._plan_prefill(admissions)
+            steps, held = self._run_prefill_chunks(pools, plans, tails,
                                                    np.float32(temperature))
             tl.raise_pending()
             out = []
+            n_ctr = len(self.served.counter_names)
             with tl.span("prefill_fetch"):
+                # One fetch a chunk program that ended a prompt; the
+                # model's counters (of that execution) ride it.
+                fetched = {ci: split_counters(np.asarray(jax.device_get(
+                    steps[ci][0])), n_ctr) for ci, _ in held.values()}
+                self._note_counters(span, [c for _, c in fetched.values()])
                 for slot, group, plan, prompt, plen in plans:
                     ci, g = held[slot]
-                    tok = int(jax.device_get(steps[ci][0][g]))
+                    tok = int(fetched[ci][0][g])
                     logits = np.asarray(jax.device_get(steps[ci][1][g])) \
                         if return_logits else None
                     if self.drafter is not None:
@@ -647,14 +679,14 @@ class InferenceEngine:
     def _plan_prefill(self, admissions):
         """prefill_many's ``prefill_plan``: admit every prompt through
         the block allocator, run the merged copy-on-write fork, and lay
-        out each admission's unshared tail in chunks. Returns (kc, vc,
+        out each admission's unshared tail in chunks. Returns (pools,
         [(slot, group, plan, prompt, plen)], [(padded tail, chunks,
         tail length)])."""
         G = self.dp
         J = self.cache_spec.max_blocks_per_slot
         Sg = self.cache_spec.slots_per_group
         chunk = self.prefill_chunk
-        kc, vc = self.cache["k"], self.cache["v"]
+        pools = self._pools()
         plans = []
         seen_groups = set()
         cow_src = np.zeros((G, self.cache_spec.blocks_per_group),
@@ -687,7 +719,7 @@ class InferenceEngine:
                 any_cow = True
             plans.append((slot, group, plan, prompt, plen))
         if any_cow:
-            kc, vc = self._copy_fn(kc, vc, cow_src, cow_dst)
+            pools = self._copy_fn(*pools, cow_src, cow_dst)
         # Chunk schedule: admission a runs chunks over its unshared
         # tail; all admissions advance together, groups whose tail is
         # done go inactive (writes land nowhere).
@@ -701,9 +733,9 @@ class InferenceEngine:
             self._last_admit[slot] = {
                 "cached_tokens": int(plan.matched), "chunks": n_chunks,
                 "cow_fork": plan.cow_src is not None}
-        return kc, vc, plans, tails
+        return pools, plans, tails
 
-    def _run_prefill_chunks(self, kc, vc, plans, tails, temp):
+    def _run_prefill_chunks(self, pools, plans, tails, temp):
         """Dispatch one group-batched chunk program per chunk index (a
         ``prefill_chunk`` span each) and store the cache. Returns
         ([(tok_g, logits_g) device arrays per chunk index], {slot: (ci,
@@ -727,16 +759,21 @@ class InferenceEngine:
                 bt_rows[group] = self.block_tables[slot]
                 starts[group] = plan.matched + ci * chunk
                 act[group] = 1
+                # The chunk's last row that belongs to the prompt: every
+                # row of a chunk before the last (whose logits nobody
+                # reads), the prompt's last token in the last one. Rows
+                # past it are padding a served model may skip.
+                last_idxs[group] = chunk - 1
                 if ci == n_chunks - 1:
                     last_idxs[group] = tlen - 1 - ci * chunk
                     held[slot] = (ci, group)
             with self.telemetry.span("prefill_chunk", ci=ci,
                                      active_groups=int(act.sum())):
-                kc, vc, tok_g, logits_g = self._prefill_fn(
-                    self._params, kc, vc, toks, bt_rows, starts,
+                *pools, tok_g, logits_g = self._prefill_fn(
+                    self._params, *pools, toks, bt_rows, starts,
                     last_idxs, act, self._next_key(), temp)
             steps.append((tok_g, logits_g))
-        self.cache["k"], self.cache["v"] = kc, vc
+        self._store_pools(pools)
         return steps, held
 
     def _cache_accounting(self) -> Tuple[int, int, int]:
@@ -761,12 +798,28 @@ class InferenceEngine:
         reach = (self.lengths + k_rows - 1) // sp_.block_size + 1
         live = np.minimum(np.minimum(reach, sp_.max_blocks_per_slot),
                           (self.block_tables >= 0).sum(axis=1))
-        return paged_attn_ops.attend_step_counts(
-            live, K=k_rows, num_heads=max(1, sp_.num_heads // self.mp),
-            head_dim=sp_.head_dim, block_size=sp_.block_size,
-            table_width=sp_.max_blocks_per_slot,
-            kv_itemsize=int(jnp.dtype(sp_.dtype).itemsize),
-            q_itemsize=int(jnp.dtype(self.model_cfg.dtype).itemsize))
+        served = self.served
+        return served.attend_step_counts(
+            live, K=k_rows, spec=sp_, mp=self.mp,
+            q_itemsize=int(jnp.dtype(served.dtype).itemsize))
+
+    def _attend_cost(self, context: Optional[int] = None,
+                     pool_blocks: Optional[int] = None) -> Tuple[int, int]:
+        """Analytic (FLOPs, cache bytes) of ONE token's attend over all
+        layers: live-context term (``context``: the stream's own blocks,
+        the last one whole) or pool-capacity term (``pool_blocks``: every
+        row of a group's pool, what the one-hot contraction reads)."""
+        sp_ = self.cache_spec
+        per_key = self.__dict__.get("_attend_per_key")
+        if per_key is None:         # both are linear in the key rows
+            served = self.served
+            per_key = self._attend_per_key = (
+                served.attend_flops(1) * sp_.num_layers,
+                served.attend_bytes(1, sp_.block_size, int(jnp.dtype(
+                    sp_.dtype).itemsize)) * sp_.num_layers)
+        keys = paged_attn_ops._attend_keys(sp_.block_size, context,
+                                           pool_blocks)
+        return per_key[0] * keys, per_key[1] * keys
 
     def _attend_work(self, k_rows: int) -> Tuple[int, int, int, int]:
         """Analytic attend work of the iteration just run, priced BOTH
@@ -778,22 +831,26 @@ class InferenceEngine:
         per layer, occupancy notwithstanding. Projections — host
         arithmetic, no device work."""
         sp_ = self.cache_spec
-        kvi = int(jnp.dtype(sp_.dtype).itemsize)
-        args = (sp_.num_heads, sp_.head_dim, sp_.block_size)
-        ctxs = [max(1, int(c)) for c in self.lengths[self.active]]
-        fk = sum(paged_attn_ops.attend_flops_per_token(
-            *args, context=c, num_layers=sp_.num_layers)
-            for c in ctxs) * k_rows
-        bk = sum(paged_attn_ops.attend_hbm_bytes_per_token(
-            *args, context=c, kv_itemsize=kvi,
-            num_layers=sp_.num_layers) for c in ctxs)
-        fo = paged_attn_ops.attend_flops_per_token(
-            *args, pool_blocks=sp_.blocks_per_group,
-            num_layers=sp_.num_layers) * k_rows * self.max_slots
-        bo = paged_attn_ops.attend_hbm_bytes_per_token(
-            *args, pool_blocks=sp_.blocks_per_group, kv_itemsize=kvi,
-            num_layers=sp_.num_layers) * sp_.num_groups
-        return fk, fo, bk, bo
+        live = [self._attend_cost(context=max(1, int(c)))
+                for c in self.lengths[self.active]]
+        pool = self._attend_cost(pool_blocks=sp_.blocks_per_group)
+        return (sum(f for f, _ in live) * k_rows,
+                pool[0] * k_rows * self.max_slots,
+                sum(b for _, b in live), pool[1] * sp_.num_groups)
+
+    def _note_counters(self, span, counters) -> None:
+        """The served model's counters of the execution(s) just fetched
+        (they rode the token fetch): onto the host span and into the
+        aggregator's running means. Nothing for a model without."""
+        names = self.served.counter_names
+        if not names or counters is None:
+            return
+        rows = np.asarray(counters, np.int64).reshape(-1, len(names))
+        if not len(rows):
+            return
+        args = self.served.counter_args(rows)
+        span.set_metadata(**args)
+        self.serving.note_model_counters(args)
 
     def decode_once(self, temperature: float = 0.0,
                     return_logits: bool = False
@@ -814,16 +871,19 @@ class InferenceEngine:
                     self._ensure_blocks(int(s), int(self.lengths[s]))
                 steps = self._attend_steps(1)
             with tl.span("decode_dispatch"):
-                kc, vc, sampled, logits = self._decode_fn(
-                    self._params, self.cache["k"], self.cache["v"],
+                *pools, sampled, logits = self._decode_fn(
+                    self._params, *self._pools(),
                     self.last_tokens, self.lengths, self.block_tables,
                     self._next_key(), np.float32(temperature))
-                self.cache["k"], self.cache["v"] = kc, vc
+                self._store_pools(pools)
                 tl.raise_pending()
             # THE serving sync: the host needs the tokens (EOS detection
-            # + next step's inputs). One batched [S] fetch per iteration.
+            # + next step's inputs). One batched [S] fetch per iteration
+            # (the served model's counters, if it has any, ride it).
             with tl.span("decode_fetch"):
-                sampled = np.asarray(jax.device_get(sampled))
+                sampled, counters = split_counters(
+                    np.asarray(jax.device_get(sampled)),
+                    len(self.served.counter_names))
             with tl.span("decode_advance"):
                 adv = self.active
                 self.lengths[adv] += 1
@@ -856,6 +916,7 @@ class InferenceEngine:
                               context_tokens=ctx_tokens,
                               attend_steps=steps[0],
                               attend_live_steps=steps[1])
+            self._note_counters(span, counters)
         out_logits = np.asarray(jax.device_get(logits)) \
             if return_logits else None
         return sampled, out_logits
@@ -899,11 +960,11 @@ class InferenceEngine:
                         s, min(int(self.lengths[s]) + k, self.max_len - 1))
                 steps = self._attend_steps(k + 1)
             with tl.span("decode_dispatch"):
-                kc, vc, out, logits = self._verify_fn(
-                    self._params, self.cache["k"], self.cache["v"], toks,
+                *pools, out, logits = self._verify_fn(
+                    self._params, *self._pools(), toks,
                     self.lengths, self.block_tables, self._next_key(),
                     np.float32(temperature))
-                self.cache["k"], self.cache["v"] = kc, vc
+                self._store_pools(pools)
                 tl.raise_pending()
             with tl.span("decode_fetch"):
                 out = np.asarray(jax.device_get(out))    # [S, k+2]
@@ -1058,7 +1119,7 @@ class InferenceEngine:
     # Training-checkpoint handoff
     # ------------------------------------------------------------------ #
     @classmethod
-    def from_train_checkpoint(cls, load_dir: str, model_cfg: GPT2Config,
+    def from_train_checkpoint(cls, load_dir: str, model_cfg: Any,
                               config: Any = None, tag: Optional[str] = None,
                               mesh: Optional[Mesh] = None,
                               rng: Optional[jax.Array] = None,
@@ -1067,8 +1128,9 @@ class InferenceEngine:
         """Build a serving engine from a training engine's checkpoint
         directory (the ``latest``-pointer + ``mp_rank_00`` layout
         runtime/engine.py saves). ``init_fn(rng, cfg) -> params``
-        defaults to ``models.gpt2.gpt2_init`` and is only used for its
-        tree STRUCTURE (eval_shape — no real init runs)."""
+        defaults to the served model's own (``models.gpt2.gpt2_init``
+        for GPT-2's config) and is only used for its tree STRUCTURE
+        (eval_shape — no real init runs)."""
         if flax_serialization is None:
             raise RuntimeError("flax is required to read checkpoints")
         if tag is None:
@@ -1084,12 +1146,12 @@ class InferenceEngine:
                 f"{model_file} not found — TP-sharded (mp_rank_XX) "
                 "checkpoints need assembly, load them through the "
                 "training engine and pass raw params instead")
+        served = served_model(model_cfg)
         if init_fn is None:
-            from ..models.gpt2 import gpt2_init
-            init_fn = gpt2_init
+            init_fn = served.init_fn
         template = jax.tree_util.tree_map(
             lambda s: np.zeros(s.shape, s.dtype),
-            jax.eval_shape(lambda r: init_fn(r, model_cfg),
+            jax.eval_shape(lambda r: init_fn(r, served.cfg),
                            jax.random.PRNGKey(0)))
         with open(model_file, "rb") as f:
             blob = flax_serialization.from_bytes({"module": template},

@@ -86,6 +86,31 @@ class PagedKVCacheSpec:
     head_dim: int
     num_groups: int = 1
     dtype: Any = jnp.bfloat16
+    # What the served model keeps, where it is not GPT-2's per-head K and
+    # V: ((pool name, ONE block's tile as held [heads, rows, lanes]), ...)
+    # (``inference.served.ServedModel.cache_pools``).  ``num_heads`` /
+    # ``head_dim`` then describe a cache row's heads and logical width.
+    pools: Optional[Tuple[Tuple[str, Tuple[int, int, int]], ...]] = None
+
+    @property
+    def pool_tiles(self) -> Tuple[Tuple[str, Tuple[int, int, int]], ...]:
+        """((pool name, one block's tile as held), ...): ``pools``, or
+        the K and V pools ``num_heads`` / ``head_dim`` give."""
+        if self.pools is not None:
+            return self.pools
+        f = self.fold
+        tile = (self.num_heads, self.block_size // f, f * self.head_dim)
+        return (("k", tile), ("v", tile))
+
+    @property
+    def pool_names(self) -> Tuple[str, ...]:
+        return tuple(name for name, _ in self.pool_tiles)
+
+    @property
+    def pool_shapes(self) -> Dict[str, Tuple[int, ...]]:
+        """Every pool as it is held: ``[L, G, B, heads, rows, lanes]``."""
+        lead = (self.num_layers, self.num_groups, self.blocks_per_group)
+        return {name: lead + tuple(tile) for name, tile in self.pool_tiles}
 
     @property
     def blocks_per_group(self) -> int:
@@ -107,11 +132,10 @@ class PagedKVCacheSpec:
 
     @property
     def shape(self) -> Tuple[int, int, int, int, int, int]:
-        """The pool as it is held: ``logical_shape`` with ``fold``
-        positions folded into the minor dimension (a reshape)."""
-        f = self.fold
-        return self.logical_shape[:4] + (self.block_size // f,
-                                         f * self.head_dim)
+        """The (first) pool as it is held: for K and V ``logical_shape``
+        with ``fold`` positions folded into the minor dimension (a
+        reshape)."""
+        return self.pool_shapes[self.pool_names[0]]
 
     @property
     def logical_shape(self) -> Tuple[int, int, int, int, int, int]:
@@ -119,14 +143,14 @@ class PagedKVCacheSpec:
                 self.num_heads, self.block_size, self.head_dim)
 
     def nbytes(self) -> int:
-        """Total K+V pool bytes (global, unsharded)."""
-        return 2 * math.prod(self.shape) * jnp.dtype(self.dtype).itemsize
+        """Total pool bytes, every pool (global, unsharded)."""
+        return self.block_nbytes() * self.num_blocks
 
     def block_nbytes(self) -> int:
-        """K+V bytes one block holds across all layers — the unit of
-        the hbm_bytes_per_token accounting."""
-        return (2 * self.num_layers * self.num_heads * self.block_size *
-                self.head_dim * jnp.dtype(self.dtype).itemsize)
+        """Bytes one block holds across all layers and pools — the unit
+        of the hbm_bytes_per_token accounting."""
+        return (self.num_layers * jnp.dtype(self.dtype).itemsize
+                * sum(math.prod(tile) for _, tile in self.pool_tiles))
 
     def validate(self, mesh: Optional[Mesh] = None) -> None:
         for name in ("num_layers", "num_slots", "num_blocks", "block_size",
@@ -201,9 +225,10 @@ def paged_partition_spec() -> P:
     return P(None, DP_AXIS, None, MP_AXIS, None, None)
 
 
-def paged_shardings(mesh: Mesh) -> Dict[str, NamedSharding]:
+def paged_shardings(mesh: Mesh, names: Sequence[str] = ("k", "v")
+                    ) -> Dict[str, NamedSharding]:
     spec = paged_partition_spec()
-    return {"k": NamedSharding(mesh, spec), "v": NamedSharding(mesh, spec)}
+    return {name: NamedSharding(mesh, spec) for name in names}
 
 
 def init_paged_cache(spec: PagedKVCacheSpec,
@@ -212,12 +237,13 @@ def init_paged_cache(spec: PagedKVCacheSpec,
     spec.validate(mesh)
 
     def make():
-        return {"k": jnp.zeros(spec.shape, spec.dtype),
-                "v": jnp.zeros(spec.shape, spec.dtype)}
+        return {name: jnp.zeros(shape, spec.dtype)
+                for name, shape in spec.pool_shapes.items()}
 
     if mesh is None:
         return make()
-    return jax.jit(make, out_shardings=paged_shardings(mesh))()
+    return jax.jit(make, out_shardings=paged_shardings(
+        mesh, spec.pool_names))()
 
 
 # --------------------------------------------------------------------- #

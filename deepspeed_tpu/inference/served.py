@@ -1,0 +1,183 @@
+"""What ``InferenceEngine`` needs of a model: the served-model interface.
+
+The engine, the scheduler, the block allocator, the prefix cache,
+admission, sampling and the spans know nothing of a model's block.  They
+ask a ``ServedModel`` for:
+
+- **what it keeps per token and layer in the paged pool** —
+  ``cache_layers`` and ``cache_pools(block_size)``: the pools by name,
+  each with the shape of ONE block's tile as held (``[heads, rows,
+  lanes]``, lane-dense) — from which ``PagedKVCacheSpec``, the pool
+  arrays, ``block_nbytes`` and the copy-on-write copy follow.  GPT-2
+  keeps two, ``k`` and ``v`` (per-head rows); the latent-attention family
+  keeps one, ``latent`` (a ``[ckv | k_rope]`` row shared by every head);
+- **its programs**, each a pure function over ``(params, pools, ...)``
+  that writes the new rows into the pools in place and returns ``(logits,
+  pools)`` (and the model's counters, see below): ``decode`` (one token a
+  slot), ``verify`` (K tokens a slot, speculative), ``prefill_chunk`` (one
+  chunk of one slot a group), ``prefill_full`` (a whole padded prompt);
+- **the attend's cost** for the engine's analytic counters:
+  ``attend_dims`` = (query heads, score width, value width) and
+  ``attend_step_counts``;
+- **counters** a program returns beside its logits (``counter_names``):
+  int32 scalars that ride the token fetch — the expert layer's held-row
+  counts for the latent family, none for GPT-2.
+
+``served_model(x)`` resolves what a caller hands the engine: a
+``ServedModel`` as it is; a model config through the registry
+(``register``), which an implementation fills at import; a config that
+names its implementation's module (``serving_module``) has that module
+imported first — so a model nobody serves costs no import.
+"""
+from __future__ import annotations
+
+import importlib
+from typing import Any, Callable, Dict, Sequence, Tuple
+
+import jax.numpy as jnp
+
+_IMPLEMENTATIONS: Dict[type, Callable[[Any], "ServedModel"]] = {}
+
+
+class ServedModel:
+    """Base of the implementations; see the module docstring."""
+    cfg: Any
+    counter_names: Tuple[str, ...] = ()
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+
+    # -- identity and limits ------------------------------------------ #
+    @property
+    def name(self) -> str:
+        return self.cfg.name
+
+    @property
+    def dtype(self):
+        return self.cfg.dtype
+
+    @property
+    def max_positions(self) -> int:
+        raise NotImplementedError
+
+    @property
+    def init_fn(self) -> Callable:
+        """``init_fn(rng, cfg) -> params``: the tree's structure, for
+        ``from_train_checkpoint``."""
+        raise NotImplementedError
+
+    # -- the cache ----------------------------------------------------- #
+    @property
+    def cache_layers(self) -> int:
+        raise NotImplementedError
+
+    @property
+    def cache_heads(self) -> int:
+        """Heads of a cache row (sharded over the model axis)."""
+        raise NotImplementedError
+
+    @property
+    def cache_row_width(self) -> int:
+        """Values a head keeps per token, layer and pool."""
+        raise NotImplementedError
+
+    def cache_pools(self, block_size: int
+                    ) -> Tuple[Tuple[str, Tuple[int, int, int]], ...]:
+        """((pool name, one block's tile as held [heads, rows, lanes]),
+        ...)."""
+        raise NotImplementedError
+
+    # -- the attend's analytic cost ------------------------------------ #
+    @property
+    def attend_dims(self) -> Tuple[int, int, int]:
+        """(query heads, width a score contracts, width a value row
+        has)."""
+        raise NotImplementedError
+
+    def attend_flops(self, keys: int) -> int:
+        """FLOPs a layer's attend spends on ONE query token over
+        ``keys`` key rows: scores + weighted values."""
+        nq, ws, wv = self.attend_dims
+        return 2 * nq * (ws + wv) * int(keys)
+
+    def attend_bytes(self, keys: int, block_size: int, itemsize: int
+                     ) -> int:
+        """Cache bytes a layer's attend streams for ``keys`` key rows."""
+        per_block = sum(h * r * l for _, (h, r, l) in
+                        self.cache_pools(block_size))
+        return per_block * int(keys) * int(itemsize) // block_size
+
+    def attend_step_counts(self, live_blocks, *, K: int, spec, mp: int,
+                           q_itemsize: int) -> Tuple[int, int]:
+        """(steps, live steps) the attend kernel sequences for one layer
+        (host integers)."""
+        raise NotImplementedError
+
+    def counter_args(self, rows) -> Dict[str, Any]:
+        """Span args / running-mean samples from fetched counters ``rows
+        [executions, len(counter_names)]`` (int64)."""
+        return {}
+
+    # -- the programs -------------------------------------------------- #
+    def decode(self, params, pools: Sequence, tokens, lengths,
+               block_tables, *, num_groups: int, paged_kernel: bool,
+               mesh=None):
+        raise NotImplementedError
+
+    def verify(self, params, pools: Sequence, tokens, lengths,
+               block_tables, *, num_groups: int, paged_kernel: bool,
+               mesh=None):
+        raise NotImplementedError
+
+    def prefill_chunk(self, params, pools: Sequence, tokens, bt_rows,
+                      start, last_idx, active, *, paged_kernel: bool,
+                      mesh=None):
+        raise NotImplementedError
+
+    def prefill_full(self, params, pools: Sequence, tokens, bt_rows,
+                     last_idx, *, attention_fn=None, mesh=None):
+        raise NotImplementedError(
+            f"{type(self).__name__} has no whole-prompt prefill: set "
+            "inference.prefill_chunk > 0")
+
+
+def register(config_type: type,
+             factory: Callable[[Any], ServedModel]) -> None:
+    """An implementation's module calls this at import."""
+    _IMPLEMENTATIONS[config_type] = factory
+
+
+def served_model(model: Any) -> ServedModel:
+    """The served model of what the engine was handed (module
+    docstring)."""
+    if isinstance(model, ServedModel):
+        return model
+    module = getattr(model, "serving_module", None)
+    if module and type(model) not in _IMPLEMENTATIONS:
+        importlib.import_module(module)
+    for config_type, factory in _IMPLEMENTATIONS.items():
+        if isinstance(model, config_type):
+            return factory(model)
+    raise TypeError(
+        f"no served-model implementation for {type(model).__name__}: pass "
+        "a ServedModel, or a config whose implementation is registered "
+        "(inference.served.register)")
+
+
+def split_counters(fetched, n: int):
+    """A program's int32 fetch ``[tokens..., counters...]`` -> (tokens,
+    counters or None)."""
+    return (fetched[:-n], fetched[-n:]) if n else (fetched, None)
+
+
+def with_counters(sampled, counters):
+    """The one array a program's fetch rides: the sampled tokens with the
+    model's counters appended (nothing for a model without)."""
+    if counters is None or not len(counters):
+        return sampled
+    return jnp.concatenate([sampled.reshape(-1).astype(jnp.int32),
+                            jnp.stack(list(counters)).astype(jnp.int32)])
+
+
+__all__ = ["ServedModel", "register", "served_model", "split_counters",
+           "with_counters"]

@@ -1,0 +1,182 @@
+"""An expert layer that holds a SHARE of its experts, routed as the
+``deepseek_v3`` family publishes it.
+
+Beside ``moe/layer.py`` (GShard: softmax top-1/2 into ``[E, C, H]``
+capacity buffers, overflow dropped, all-to-all) this is the layer a
+serving chip of an expert-parallel deployment runs:
+
+- **Routing** over ALL ``n_routed_experts`` exactly as published
+  (``noaux_tc``): ``s = sigmoid(x Wg)``; ``c = s + b`` (the selection
+  bias, for choosing only); a group's score is the sum of its two largest
+  ``c``; the ``topk_group`` best groups stay; the ``num_experts_per_tok``
+  largest ``c`` inside them are chosen; the weights are ``s`` at the
+  chosen, divided by their sum (+1e-20) when ``norm_topk_prob``, times
+  ``routed_scaling_factor``.  fp32 throughout.
+- **The share**: told ``held = (first, count)``, the layer computes the
+  weighted outputs of the pairs (token, expert) whose expert it holds —
+  every one of them: rows are grouped by expert (a counting sort, each
+  group padded to the row tile) and go through one grouped gated-SiLU
+  product per layer (``ops.grouped_gemm.grouped_swiglu``).  There is no
+  capacity, no ``[E, C, H]`` buffer and no dropped token: the row buffer
+  is sized for the worst routing (every pair held).  What the absent
+  experts would add is left out; no code stands in for the other chips or
+  their exchange.  ``held = (0, n_routed_experts)`` is the whole layer.
+- **The shared expert** is added for every token (each chip computes it
+  alike; the sum over shares counts it once).
+
+``routed_share`` returns, beside the output, the held experts' row
+counts: the engine's ``decode`` / ``prefill`` span args and the
+benchmark's load metrics read them.
+"""
+from __future__ import annotations
+
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..models.deepseek_v3 import DeepseekV3Config, swiglu
+from ..ops import grouped_gemm
+
+
+def route(x: jax.Array, router: jax.Array, bias: jax.Array,
+          cfg: DeepseekV3Config) -> Tuple[jax.Array, jax.Array]:
+    """x [T, H] -> (expert ids [T, k] int32, weights [T, k] fp32)."""
+    E, n_group, k = (cfg.n_routed_experts, cfg.n_group,
+                     cfg.num_experts_per_tok)
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)                                 # [T, E]
+    c = s + bias.astype(jnp.float32)
+    grouped = c.reshape(-1, n_group, E // n_group)
+    group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)         # [T, n_group]
+    kept = jax.lax.top_k(group_score, cfg.topk_group)[1]       # [T, topk_g]
+    keep = jnp.zeros(group_score.shape, bool).at[
+        jnp.arange(kept.shape[0])[:, None], kept].set(True)
+    # As published: the dropped groups' scores are masked to 0 (not
+    # -inf) before the top-k.
+    cand = jnp.where(jnp.repeat(keep, E // n_group, axis=1), c, 0.0)
+    idx = jax.lax.top_k(cand, k)[1].astype(jnp.int32)          # [T, k]
+    w = jnp.take_along_axis(s, idx, axis=1)
+    if cfg.norm_topk_prob:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return idx, w * cfg.routed_scaling_factor
+
+
+def _row_tile(tokens: int, cfg: DeepseekV3Config) -> int:
+    """Rows a grouped-product tile holds: twice the mean rows an expert
+    gets, as a power of two within [16, 128] (16 = a packed bf16 tile)."""
+    mean = tokens * cfg.num_experts_per_tok / cfg.n_routed_experts
+    tm = 16
+    while tm < 128 and tm < 2 * mean:
+        tm *= 2
+    return tm
+
+
+def dispatch(idx: jax.Array, cfg: DeepseekV3Config, tm: int,
+             row_live=None):
+    """Group the held pairs by expert (of the rows ``row_live [T]`` marks,
+    where given: a serving program's dead slots and padding rows are not
+    traffic and get no buffer row).  idx [T, k] -> dict:
+    ``src`` [M] token of each buffer row (0 for an empty row), ``pos``
+    [T, k] buffer row of each pair (0 where not held), ``on`` [T, k] the
+    pair's expert is held, ``tile_expert`` [M / tm], ``n_live_tiles``,
+    ``counts`` [count] rows per held expert.  M = the worst case: every
+    pair held, each expert's group padded to ``tm``."""
+    first, count = cfg.held
+    T, k = idx.shape
+    P = T * k
+    M = -(-P // tm) * tm + count * tm
+    local = idx.reshape(P) - first
+    on = (local >= 0) & (local < count)
+    if row_live is not None:
+        on = on & jnp.repeat(row_live, k)
+    oh = (jnp.where(on, local, count)[:, None]
+          == jnp.arange(count, dtype=jnp.int32)[None]).astype(jnp.int32)
+    ranks = jnp.cumsum(oh, axis=0)                             # [P, count]
+    counts = ranks[-1]
+    size = -(-counts // tm) * tm                               # padded
+    end = jnp.cumsum(size)
+    start = end - size
+    pos = ((start[None] + ranks - 1) * oh).sum(-1)             # [P]
+    token = jnp.arange(P, dtype=jnp.int32) // k
+    src = jnp.zeros((M,), jnp.int32).at[jnp.where(on, pos, M)].set(
+        token, mode="drop")
+    tile_expert = jnp.searchsorted(
+        end, jnp.arange(M // tm, dtype=jnp.int32) * tm, side="right")
+    return {"src": src, "pos": pos.reshape(T, k), "on": on.reshape(T, k),
+            "tile_expert": jnp.minimum(tile_expert, count - 1),
+            "n_live_tiles": end[-1] // tm, "counts": counts}
+
+
+def _experts_jnp(xs, p, tile_expert, n_live_tiles, tm):
+    """The grouped product without the kernel (off-TPU path and the
+    kernel's test reference): each tile against its expert's weights."""
+    M, H = xs.shape
+    xt = xs.reshape(M // tm, tm, H)
+    nt = (((2,), (2,)), ((0,), (0,)))
+
+    def prod(w):
+        return jax.lax.dot_general(xt, w[tile_expert].astype(xs.dtype), nt,
+                                   preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(prod(p["w_gate"])) * prod(p["w_up"])).astype(xs.dtype)
+    out = jnp.einsum("ntf,nfh->nth", h,
+                     p["w_down"][tile_expert].astype(xs.dtype),
+                     preferred_element_type=jnp.float32)
+    live = jnp.arange(M // tm)[:, None, None] < n_live_tiles
+    return jnp.where(live, out, 0.0).astype(xs.dtype).reshape(M, H)
+
+
+def routed_share(p: Dict[str, jax.Array], x: jax.Array,
+                 cfg: DeepseekV3Config, kernel: Optional[bool] = None,
+                 layer=None, row_live=None) -> Tuple[jax.Array, jax.Array]:
+    """x [T, H] -> (what the held experts add [T, H], rows per held
+    expert [count]; ``row_live [T]``: see ``dispatch``).  ``kernel``: the Pallas grouped product (default: on a
+    TPU; a serving program passes its ``paged_kernel``).  With ``layer`` (a traced
+    index) the expert weights in ``p`` are the STACK of all layers'
+    ``[Le, E_held, F, H]`` and the product names an expert by ``layer *
+    E_held + e``: a layer sliced out of the stack for a kernel would be
+    copied, 1.4 GB a layer at the published widths."""
+    if kernel is None:
+        kernel = grouped_gemm.grouped_gemm_enabled("auto")
+    experts = {k: p[k].reshape((-1,) + p[k].shape[-2:])
+               for k in ("w_gate", "w_up", "w_down")}
+    with jax.named_scope("router"):
+        idx, w = route(x, p["router"], p["router_bias"], cfg)
+    with jax.named_scope("dispatch"):
+        tm = _row_tile(x.shape[0], cfg)
+        d = dispatch(idx, cfg, tm, row_live)
+        xs = x[d["src"]]
+        tile_expert = d["tile_expert"] if layer is None else \
+            d["tile_expert"] + layer * cfg.held[1]
+    with jax.named_scope("experts"):
+        if kernel:
+            out = grouped_gemm.grouped_swiglu(
+                xs, experts["w_gate"], experts["w_up"], experts["w_down"],
+                tile_expert, d["n_live_tiles"], tm=tm)
+        else:
+            out = _experts_jnp(xs, experts, tile_expert,
+                               d["n_live_tiles"], tm)
+    with jax.named_scope("combine"):
+        rows = out[d["pos"]].astype(jnp.float32)               # [T, k, H]
+        y = jnp.einsum("tk,tkh->th", jnp.where(d["on"], w, 0.0),
+                       jnp.where(d["on"][..., None], rows, 0.0))
+    return y.astype(x.dtype), d["counts"]
+
+
+def expert_layer(p: Dict[str, jax.Array], x: jax.Array,
+                 cfg: DeepseekV3Config, kernel: Optional[bool] = None,
+                 layer=None, row_live=None) -> Tuple[jax.Array, jax.Array]:
+    """The whole FFN of an expert layer for normed ``x [T, H]``: the held
+    share of the routed experts plus the shared expert.  Returns (y,
+    rows per held expert).  ``layer``, ``row_live``: see
+    ``routed_share``."""
+    with jax.named_scope("moe"):
+        y, counts = routed_share(p, x, cfg, kernel, layer, row_live)
+        with jax.named_scope("shared"):
+            y = y + swiglu(x, p["shared_gate"], p["shared_up"],
+                           p["shared_down"])
+    return y, counts
+
+
+__all__ = ["route", "dispatch", "routed_share", "expert_layer"]

@@ -62,6 +62,7 @@ class ServingAggregator:
         self.cached_tokens_admitted = 0
         self.spec_proposed = 0
         self.spec_accepted = 0
+        self._model_counters: Dict[str, Any] = {}   # name -> (sum, n)
         # Analytic attend-work accounting (engine-fed): the same
         # iterations priced BOTH ways — the Pallas kernel's live-context
         # term vs the one-hot contraction's pool-capacity term. ``attend_mode`` names which one actually
@@ -125,6 +126,16 @@ class ServingAggregator:
         prompt's tokens rode already-resident blocks."""
         self.prompt_tokens_admitted += int(prompt_tokens)
         self.cached_tokens_admitted += int(cached_tokens)
+
+    def note_model_counters(self, args: Dict[str, Any]) -> None:
+        """One fetch's worth of the served model's own counters (the
+        ``decode`` / ``prefill`` span args of a model that has any: the
+        expert layer's held-row counts). Numeric ones keep a running
+        mean in the snapshot (``model_counters``)."""
+        for name, value in args.items():
+            if isinstance(value, (int, float)):
+                tot, n = self._model_counters.get(name, (0.0, 0))
+                self._model_counters[name] = (tot + float(value), n + 1)
 
     def note_spec(self, proposed: int, accepted: int) -> None:
         self.spec_proposed += int(proposed)
@@ -242,6 +253,10 @@ class ServingAggregator:
                 "acceptance_rate": round(self.spec_accepted /
                                          self.spec_proposed, 4),
             }
+        if self._model_counters:
+            snap["model_counters"] = {
+                name: round(tot / n, 4)
+                for name, (tot, n) in self._model_counters.items()}
         if self.attend_tokens:
             t = self.attend_tokens
             snap["attend"] = {

@@ -30,7 +30,11 @@ __all__ = ["SCOPES", "SPANS", "SPAN_ARGS", "read_xspace", "event_args", "scope_o
 # readers and operators' dashboards key on them).
 SCOPES = ("fwd_bwd", "grad_sync", "health_tap", "optimizer", "flatten",
           "norm", "kernel", "unflatten", "embed", "attn", "mlp", "lm_head",
-          "kv_write", "attend", "sample", "cow_copy")
+          "kv_write", "attend", "sample", "cow_copy",
+          # the latent-attention family (inference/latent.py): the
+          # projections round the attend, and the expert layer's stages
+          "latent_proj", "moe", "router", "dispatch", "experts", "combine",
+          "shared")
 # The host spans ``Telemetry.span`` opens (runtime/engine.py,
 # inference/engine.py, inference/scheduler.py), same contract.
 SPANS = ("train_batch", "data_prep", "step_dispatch", "offload_step",
@@ -42,14 +46,23 @@ SPAN_ARGS = {
     "train_batch": ("step_num",), "data_prep": ("step",),
     "step_dispatch": ("step",), "step_log": ("step",),
     "admit": ("queued", "late_ms", "admitted", "rejected", "rids"),
+    # moe_*: the served model's counters where it has an expert layer
+    # (inference/latent.py): routed pairs that landed on held experts in
+    # the execution(s) the span fetched, the largest and the mean rows a
+    # held expert got in a layer, held experts (x layers) that got none,
+    # the pairs' share of all the live rows routed. They ride the token
+    # fetch. Absent for a model without counters (GPT-2).
     "prefill": ("slots", "prompt_tokens", "rids", "cached_tokens",
-                "chunks"),
+                "chunks", "moe_held_pairs", "moe_held_max",
+                "moe_held_mean", "moe_held_empty", "moe_held_pair_share"),
     "prefill_chunk": ("ci", "active_groups"),
     # attend_steps / attend_live_steps: the paged kernel's sequencing
     # steps a layer in this execution and those that touch a live block
     # (ops.paged_attention.attend_step_counts; zeros on the one-hot path)
     "decode": ("iteration", "active", "live_blocks", "context_tokens",
-               "attend_steps", "attend_live_steps"),
+               "attend_steps", "attend_live_steps", "moe_held_pairs",
+               "moe_held_max", "moe_held_mean", "moe_held_empty",
+               "moe_held_pair_share"),
     "emit": ("finished",), "serve_idle": ("why",)}
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"

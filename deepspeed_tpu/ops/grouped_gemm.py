@@ -265,4 +265,105 @@ def _gff_bwd(exact, res, dy):
 grouped_ffn.defvjp(_gff_fwd, _gff_bwd)
 
 
-__all__ = ["grouped_ffn", "grouped_gemm_enabled"]
+# --------------------------------------------------------------------- #
+# Dropless grouped gated-SiLU FFN (serving: an expert layer's held share)
+# --------------------------------------------------------------------- #
+_SWIGLU_VMEM_LIMIT = 100 * 2 ** 20
+
+
+def _gswiglu_kernel(te_ref, nl_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref,
+                    acc_ref):
+    """One grid step = one (row tile, F tile): the tile's rows all belong
+    to expert ``te_ref[i]``; ``acc += (silu(x Wg^T) * (x Wu^T)) Wd`` over
+    the F tiles, written out at the last.  A tile past the live count
+    (``nl_ref[0]``) computes nothing and emits zeros."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    last = pl.num_programs(1) - 1
+    live = i < nl_ref[0]
+
+    @pl.when(live)
+    def _compute():
+        x = x_ref[...]
+        nt = (((1,), (1,)), ((), ()))                    # x [tm,H] . w [tf,H]
+        g = jax.lax.dot_general(x, wg_ref[0], nt,
+                                preferred_element_type=jnp.float32)
+        u = jax.lax.dot_general(x, wu_ref[0], nt,
+                                preferred_element_type=jnp.float32)
+        h = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
+        part = jnp.dot(h, wd_ref[0], preferred_element_type=jnp.float32)
+
+        @pl.when(j == 0)
+        def _first():
+            acc_ref[...] = part
+
+        @pl.when(j > 0)
+        def _rest():
+            acc_ref[...] += part
+
+        @pl.when(j == last)
+        def _out():
+            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_and(jnp.logical_not(live), j == last))
+    def _dead():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _swiglu_f_tile(F: int, H: int, itemsize: int) -> int:
+    """The widest F tile (a multiple of 128 that divides F, or F) whose
+    three weight blocks, double-buffered, stay under 48 MiB."""
+    tf = F
+    while tf > 128 and (F % tf or tf % 128
+                        or 6 * tf * H * itemsize > 48 * 2 ** 20):
+        tf -= 128
+    return tf if F % tf == 0 else F
+
+
+def grouped_swiglu(xs, w_gate, w_up, w_down, tile_expert, n_live_tiles, *,
+                   tm: int):
+    """``out[r] = down_e(silu(gate_e xs[r]) * up_e xs[r])`` for rows
+    grouped by expert: ``xs [M, H]`` whose row tile ``t`` (``tm`` rows)
+    belongs to expert ``tile_expert[t]``; only the first
+    ``n_live_tiles`` tiles hold rows (the rest emit zeros and move no
+    weight).  Weights ``[E, F, H]`` (an expert's ``[tf, H]`` tile is one
+    contiguous run).  No capacity and nothing dropped: the caller lays
+    every routed row into ``xs`` (``moe/share.py``).  Returns ``[M, H]``
+    in xs's dtype, fp32 accumulation."""
+    M, H = xs.shape
+    E, F, _ = w_gate.shape
+    assert M % tm == 0, (M, tm)
+    nt = M // tm
+    tf = _swiglu_f_tile(F, H, jnp.dtype(w_gate.dtype).itemsize)
+    nf = F // tf
+    te = tile_expert.astype(jnp.int32)
+    nl = jnp.asarray(n_live_tiles, jnp.int32).reshape(1)
+
+    def tile_of(i, nl_p):
+        return jnp.maximum(jnp.minimum(i, nl_p[0] - 1), 0)
+
+    def x_map(i, j, te_p, nl_p):
+        return (tile_of(i, nl_p), 0)
+
+    def w_map(i, j, te_p, nl_p):
+        # A dead tile names the block the last live step left in VMEM.
+        return (te_p[tile_of(i, nl_p)],
+                jnp.where(i < nl_p[0], j, nf - 1), 0)
+
+    w_spec = pl.BlockSpec((1, tf, H), w_map)
+    return pl.pallas_call(
+        _gswiglu_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(nt, nf),
+            in_specs=[pl.BlockSpec((tm, H), x_map), w_spec, w_spec, w_spec],
+            out_specs=pl.BlockSpec((tm, H), lambda i, j, te_p, nl_p: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((tm, H), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((M, H), xs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_SWIGLU_VMEM_LIMIT),
+        name="_gswiglu_kernel",
+        interpret=_interpret(),
+    )(te, nl, xs, w_gate, w_up, w_down)
+
+
+__all__ = ["grouped_ffn", "grouped_gemm_enabled", "grouped_swiglu"]

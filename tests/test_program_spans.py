@@ -365,3 +365,89 @@ def test_train_span_is_in_the_profile_once_per_step(train_annotations,
         outer = found["train_batch"]
         assert all(any(o[0] <= e[0] and e[0] + e[1] <= o[0] + o[1]
                        for o in outer) for e in found[span])
+
+
+# --------------------------------------------------------------------- #
+# (d) a served model with an expert layer (PR 32): scopes and counters
+# --------------------------------------------------------------------- #
+@pytest.fixture(scope="module")
+def latent_engine():
+    from deepspeed_tpu.models.deepseek_v3 import deepseek_v3_init
+    from test_latent_serving import tiny
+    cfg = tiny(held=(4, 8))
+    eng = InferenceEngine(
+        cfg, deepseek_v3_init(jax.random.PRNGKey(0), cfg),
+        config={"inference": {"max_slots": 4, "max_seq_len": 64,
+                              "prefill_chunk": 8, "block_size": 16,
+                              "paged_kernel": True}},
+        mesh=build_mesh(devices=jax.devices()[:1]))
+    yield eng
+    eng.close()
+
+
+@pytest.fixture(scope="module")
+def latent_op_names(latent_engine):
+    eng = latent_engine
+    G, J = eng.dp, eng.cache_spec.max_blocks_per_slot
+    key, temp = eng._next_key(), np.float32(0.0)
+    pool = eng.cache["latent"]
+    return {
+        "decode": _op_names(eng._decode_fn, eng._params, pool,
+                            eng.last_tokens, eng.lengths, eng.block_tables,
+                            key, temp),
+        "prefill": _op_names(
+            eng._prefill_fn, eng._params, pool,
+            np.zeros((G, eng.prefill_chunk), np.int32),
+            np.zeros((G, J), np.int32), np.zeros(G, np.int32),
+            np.zeros(G, np.int32), np.ones(G, np.int32), key, temp)}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("scope", [
+    "embed", "attn/latent_proj", "attn/kv_write", "attn/attend", "mlp",
+    "moe/router", "moe/dispatch", "moe/experts", "moe/combine",
+    "moe/shared", "lm_head", "sample"])
+def test_latent_program_carries_scope(latent_op_names, program, scope):
+    assert any(f"/{scope}" in n for n in latent_op_names[program]), \
+        (program, scope)
+
+
+def test_the_readers_list_names_the_latent_scopes_and_counters():
+    from deepspeed_tpu.monitor.xplane_reader import (SCOPES, SPAN_ARGS,
+                                                     scope_of)
+    assert {"latent_proj", "moe", "router", "dispatch", "experts",
+            "combine", "shared"} <= set(SCOPES)
+    assert scope_of("jit(decode_step)/while/body/moe/experts/x")[0] == \
+        ("moe", "experts")
+    moe = {"moe_held_pairs", "moe_held_max", "moe_held_mean",
+           "moe_held_empty", "moe_held_pair_share"}
+    assert moe <= set(SPAN_ARGS["decode"]) and moe <= set(SPAN_ARGS["prefill"])
+
+
+def test_decode_and_prefill_spans_carry_the_expert_counters(tmp_path,
+                                                            latent_engine):
+    """The counters ride the token fetch: the same number of device
+    fetches an iteration as GPT-2 pays, and the spans carry what the held
+    experts got."""
+    eng = latent_engine
+    rng = np.random.default_rng(0)
+    reqs = [Request(rid=i, prompt=rng.integers(0, 250, size=11 + i,
+                                               dtype=np.int32),
+                    max_new_tokens=5, arrival_s=0.0) for i in range(3)]
+    found = _session(tmp_path, lambda: eng.serve(reqs))
+    cfg = eng.model_cfg
+    for span in ("decode", "prefill"):
+        args = [a for _, _, a in found[span]]
+        assert args and all(a["moe_held_pairs"] > 0 for a in args)
+        for a in args:
+            assert a["moe_held_max"] >= a["moe_held_mean"] > 0
+            assert 0 < a["moe_held_pair_share"] <= 1
+            assert 0 <= a["moe_held_empty"] <= \
+                cfg.num_moe_layers * cfg.held[1] * 8
+    # decode: rows = active slots, so pairs <= active x k x expert layers
+    for _, _, a in found["decode"]:
+        assert a["moe_held_pairs"] <= a["active"] * 4 * cfg.num_moe_layers
+    means = eng.serving.snapshot()["model_counters"]
+    assert set(means) >= {"moe_held_pairs", "moe_held_max",
+                          "moe_held_mean", "moe_held_empty",
+                          "moe_held_pair_share"}

@@ -552,3 +552,163 @@ def test_serve_step_kernels_lower_to_tpu_custom_calls(serve_programs,
                  if f"%{kernel}" in line.split(" = ")[0]
                  and " custom-call(" in line]
         assert calls and all("tpu_custom_call" in c for c in calls), kernel
+
+
+# ------------------------------------------------------------------ #
+# The latent-attention configuration (PR 32): its three kernels at the
+# published widths, and the engine's decode / prefill programs at the
+# benchmark cell's shape (perfbench/configs/gigachat3.1-702b-a36b.json:
+# 128 slots, blocks of 64, 7,168 blocks, chunk 512, five layers, 16 of
+# 256 experts held).
+# ------------------------------------------------------------------ #
+LATENT = dict(L=5, B=7168, h=32, W=1152, J=272, nH=64, C=512, R=64)
+
+
+def _latent_pool():
+    c = LATENT
+    return _sds((c["L"], 1, c["B"], 1, c["h"], c["W"]), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("Q,K", [(128, 1), (1, 512), (128, 3)],
+                         ids=["decode", "prefill-chunk", "verify"])
+def test_latent_attention_compiles_at_the_published_widths(Q, K, one_chip,
+                                                           as_tpu):
+    from deepspeed_tpu.ops import latent_attention as la
+    c = LATENT
+
+    def fn(qa, qr, pool, layer, bt, pos):
+        plan = la.latent_plan(bt, pos, pool)
+        return la.latent_attention(qa, qr, pool, layer, plan=plan,
+                                   scale=0.14)
+    _compile(fn, one_chip,
+             _sds((1, Q, K, c["nH"], c["C"]), jnp.bfloat16),
+             _sds((1, Q, K, c["nH"], c["R"]), jnp.bfloat16), _latent_pool(),
+             _sds((), jnp.int32), _sds((1, Q, c["J"]), jnp.int32),
+             _sds((1, Q, K), jnp.int32))
+
+
+@pytest.mark.parametrize("rows", [128, 512])
+def test_latent_write_compiles_at_the_published_widths(rows, one_chip,
+                                                       as_tpu):
+    from deepspeed_tpu.ops import latent_attention as la
+    c = LATENT
+    _compile(lambda pool, new, layer, blk, off: la.latent_write(
+        pool, new, layer, blk, off, kv_lora=c["C"]), one_chip,
+        _latent_pool(), _sds((1, rows, c["C"] + c["R"]), jnp.bfloat16),
+        _sds((), jnp.int32), _sds((1, rows), jnp.int32),
+        _sds((1, rows), jnp.int32))
+
+
+@pytest.mark.parametrize("tokens", [128, 512], ids=["decode", "prefill"])
+def test_grouped_swiglu_compiles_at_the_published_widths(tokens, one_chip,
+                                                         as_tpu):
+    from deepspeed_tpu.models.deepseek_v3 import DeepseekV3Config
+    from deepspeed_tpu.moe import share
+    from deepspeed_tpu.ops import grouped_gemm as gg
+    cfg = DeepseekV3Config(held=(0, 16))
+    tm = share._row_tile(tokens, cfg)
+    assert tm == (16 if tokens == 128 else 32)
+    M = -(-tokens * 8 // tm) * tm + 16 * tm         # the worst routing
+    w = _sds((4 * 16, 2048, 7168), jnp.bfloat16)    # four layers' experts
+    _compile(lambda xs, wg, wu, wd, te, nl: gg.grouped_swiglu(
+        xs, wg, wu, wd, te, nl, tm=tm), one_chip,
+        _sds((M, 7168), jnp.bfloat16), w, w, w, _sds((M // tm,), jnp.int32),
+        _sds((), jnp.int32))
+
+
+@pytest.fixture(scope="module")
+def latent_programs(topo):
+    """{program: (spec, params bytes, compiled)} of the ENGINE's own step
+    builders for the latent-attention cell, on an engine shell (see
+    ``_serve_program``)."""
+    import json
+    import os
+    from deepspeed_tpu.inference import kv_cache
+    from deepspeed_tpu.inference.engine import InferenceEngine
+    from deepspeed_tpu.inference.latent import LatentServed
+    from deepspeed_tpu.models.deepseek_v3 import (DeepseekV3Config,
+                                                  deepseek_v3_init)
+    from jax.experimental.compilation_cache import compilation_cache
+    sizes = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "perfbench", "configs", "gigachat3.1-702b-a36b.json")))
+    inf = sizes["serve"]["inference"]
+    cfg = DeepseekV3Config.from_hf(
+        sizes, n_routed_experts=sizes["n_routed_experts_published"],
+        held=(0, sizes["n_routed_experts"]),
+        vocab_rows_held=sizes["assumed"]["vocab_rows_held"])
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+            tree)
+    params = on_chip(jax.eval_shape(lambda k: deepseek_v3_init(k, cfg),
+                                    jax.random.PRNGKey(0)))
+    param_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(params))
+    spec = kv_cache.PagedKVCacheSpec(
+        num_layers=cfg.num_hidden_layers, num_slots=inf["max_slots"],
+        num_blocks=inf["num_blocks"], block_size=inf["block_size"],
+        max_len=inf["max_seq_len"], num_heads=1, head_dim=cfg.latent_width,
+        dtype=jnp.bfloat16,
+        pools=LatentServed(cfg).cache_pools(inf["block_size"]))
+    eng = object.__new__(InferenceEngine)
+    eng.model_cfg, eng.dp, eng.sp, eng.mesh = cfg, 1, 1, None
+    eng.paged_kernel, eng.quantize = True, "none"
+    eng.prefill_chunk = inf["prefill_chunk"]
+    eng._cache_sh = {"latent": one}
+    S, J, C = spec.num_slots, spec.max_blocks_per_slot, eng.prefill_chunk
+    pool = on_chip(jax.ShapeDtypeStruct(spec.pool_shapes["latent"],
+                                        spec.dtype))
+    i32 = lambda *shape: on_chip(jax.ShapeDtypeStruct(shape, jnp.int32))
+    key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
+    temp = on_chip(jax.ShapeDtypeStruct((), jnp.float32))
+    mp = pytest.MonkeyPatch()
+    prev = jax.config.jax_enable_compilation_cache
+    out = {}
+    try:
+        mp.setattr(jax, "default_backend", lambda *a, **k: "tpu")
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        out["decode_step"] = eng._build_decode_step().lower(
+            params, pool, i32(S), i32(S), i32(S, J), key, temp).compile()
+        out["prefill_step"] = eng._build_prefill_step().lower(
+            params, pool, i32(1, C), i32(1, J), i32(1), i32(1), i32(1), key,
+            temp).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+        mp.undo()
+    return spec, param_bytes, out
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_step"])
+def test_latent_serve_step_fits_and_updates_the_pool_in_place(
+        latent_programs, program):
+    """Weights 8.59 GB + latent pool 2.64 GB, the pool aliased to the
+    output, next to no scratch, every kernel a TPU custom call, and no
+    expert weight (1.4 GB a layer) sliced out of its stack and copied."""
+    from deepspeed_tpu.analysis.hlo_text import ops_in_units_of
+    spec, param_bytes, programs = latent_programs
+    compiled = programs[program]
+    assert abs(param_bytes - 8.585e9) < 0.01e9
+    assert spec.nbytes() == 7168 * 64 * 5 * 1152
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= spec.nbytes()
+    assert mem.temp_size_in_bytes < 256 * 2 ** 20, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes - param_bytes - spec.nbytes() \
+        < 16 * 2 ** 20
+    text = compiled.as_text()
+    for kernel in ("_latent_attn_kernel", "_latent_write_kernel",
+                   "_gswiglu_kernel"):
+        calls = [line for line in text.splitlines()
+                 if f"%{kernel}" in line.split(" = ")[0]
+                 and " custom-call(" in line]
+        assert calls and all("tpu_custom_call" in c for c in calls), kernel
+    seen = ops_in_units_of(text, math.prod(spec.pool_shapes["latent"][2:]))
+    assert not [(op, n) for op, n in seen if op not in _POOL_OPS_ALLOWED]
+    one_layers_experts = 16 * 2048 * 7168
+    assert not [(op, n) for op, n in ops_in_units_of(
+        text, one_layers_experts) if op not in ("parameter", "bitcast",
+                                                 "get-tuple-element")]
