@@ -40,6 +40,14 @@ class TransformerConfig:
     Mirrors DeepSpeedTransformerConfig (reference transformer.py:39-151):
     batch/seq/hidden/heads/pre_layer_norm/dropout knobs; the checkpointing
     booleans map onto ``remat_policy``.
+
+    ``hidden_dropout`` (after the attention projection and after the FFN)
+    and ``dense_attention``'s ``attn_dropout`` draw their keep-masks from
+    a counter hash of (the site's key, the element's GLOBAL index), not
+    from a per-element threefry draw (``dropout`` below): every layout of an
+    activation gets one mask, forward and rematerialized forward agree,
+    and nothing per element is drawn from the key. Loss values under
+    dropout differ from releases before PR 33, as under a reseeding.
     """
     hidden_size: int = 768
     num_heads: int = 12
@@ -171,12 +179,60 @@ def gelu_dense_fn(cfg: "TransformerConfig") -> Callable:
         dense(h, kernel, bias), approximate=not cfg.gelu_exact)
 
 
+_INDEX_SPACE = 1 << 32      # flat indices are ``uint32``
+
+
+def _element_index(shape: Tuple[int, ...]
+                   ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
+    """``(low, high)``: every element's row-major index within the
+    longest run of trailing dims that holds under 2**32 elements, and its
+    index over the dims before them (``None`` where there are none).
+    Built from iotas of the GLOBAL shape, so under GSPMD every layout of
+    ``x`` numbers an element alike."""
+    split, count = len(shape), 1
+    while split and count * shape[split - 1] < _INDEX_SPACE:
+        split -= 1
+        count *= shape[split]
+
+    def ravel(dims):
+        idx, stride = None, 1
+        for d in reversed(dims):
+            term = lax.broadcasted_iota(jnp.uint32, shape, d) \
+                * jnp.uint32(stride)
+            idx = term if idx is None else idx + term
+            stride *= shape[d]
+        return idx
+
+    low = ravel(range(split, len(shape)))
+    if low is None:                         # a scalar
+        low = jnp.zeros(shape, jnp.uint32)
+    return low, ravel(range(split))
+
+
 def dropout(x: jnp.ndarray, rate: float, rng: Optional[jax.Array],
             deterministic: bool) -> jnp.ndarray:
+    """Inverted dropout whose keep-mask is a counter hash of (``rng``,
+    the element's global index): two murmur3 finalizers keyed by two
+    words drawn ONCE from ``rng``, an integer compare against
+    ``rate * 2**32``. Nothing is drawn per element, so XLA fuses the
+    mask into whatever consumes it and the bits never reach HBM; forward
+    and rematerialized forward agree because both are this pure function
+    of the same key. The second word enters between the rounds: with one
+    round, two call sites' masks would be one sequence read at two
+    offsets."""
     if deterministic or rate == 0.0 or rng is None:
         return x
-    keep = jax.random.bernoulli(rng, 1.0 - rate, x.shape)
-    return jnp.where(keep, x / (1.0 - rate), jnp.zeros_like(x))
+    from ..ops.counter_hash import hash_u32
+    with jax.named_scope("dropout"):
+        words = jax.random.bits(rng, (2,), jnp.uint32)
+        low, high = _element_index(x.shape)
+        w0, w1 = words[0], words[1]
+        if high is not None:
+            w0 = w0 ^ (high * jnp.uint32(0x9E3779B9))
+            w1 = w1 + high * jnp.uint32(0x85EBCA6B)
+        h = hash_u32(hash_u32(low ^ w0) + w1)
+        keep = h >= jnp.uint32(min(round(rate * 2 ** 32), 2 ** 32 - 1))
+        return jnp.where(keep, x / (1.0 - rate), jnp.zeros_like(x))
 
 
 def dense_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

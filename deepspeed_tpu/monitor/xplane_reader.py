@@ -31,6 +31,9 @@ __all__ = ["SCOPES", "SPANS", "SPAN_ARGS", "read_xspace", "event_args", "scope_o
 SCOPES = ("fwd_bwd", "grad_sync", "health_tap", "optimizer", "flatten",
           "norm", "kernel", "unflatten", "embed", "attn", "mlp", "lm_head",
           "kv_write", "attend", "sample", "cow_copy",
+          # the residual-dropout masks (models/transformer.dropout), under
+          # fwd_bwd > attn / mlp
+          "dropout",
           # the latent-attention family (inference/latent.py): the
           # projections round the attend, and the expert layer's stages
           "latent_proj", "moe", "router", "dispatch", "experts", "combine",

@@ -143,6 +143,7 @@ except Exception:  # pragma: no cover
     pltpu = None
 
 from . import autotune
+from .counter_hash import hash_u32
 
 ScheduleOrFloat = Union[Callable, float]
 
@@ -426,16 +427,6 @@ def leaf_moment_views(state: "FusedAdamState", params: Any,
             jax.tree_util.tree_unflatten(treedef, v_out))
 
 
-def _hash_u32(x: jax.Array) -> jax.Array:
-    """murmur3 finalizer: a stateless counter hash good enough for the
-    rounding noise (16 low bits used), identical on TPU and interpret."""
-    x = x ^ (x >> jnp.uint32(16))
-    x = x * jnp.uint32(0x85EBCA6B)
-    x = x ^ (x >> jnp.uint32(13))
-    x = x * jnp.uint32(0xC2B2AE35)
-    return x ^ (x >> jnp.uint32(16))
-
-
 # --------------------------------------------------------------------- #
 # Kernels
 # --------------------------------------------------------------------- #
@@ -509,7 +500,7 @@ def _fused_adam_kernel(scal_ref, seed_ref, g_ref, p_ref, m_ref, v_ref,
             (pl.program_id(0).astype(jnp.uint32) * jnp.uint32(R) + rows) \
             * jnp.uint32(ncols) + \
             pl.program_id(1).astype(jnp.uint32) * jnp.uint32(W) + cols
-        noise = _hash_u32(idx ^ seed_ref[0, 0].astype(jnp.uint32)) \
+        noise = hash_u32(idx ^ seed_ref[0, 0].astype(jnp.uint32)) \
             & jnp.uint32(0xFFFF)
         bits = lax.bitcast_convert_type(new_p, jnp.uint32)
         rounded = (bits + noise) & jnp.uint32(0xFFFF0000)
@@ -933,8 +924,8 @@ def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
                 # a seed per leaf, itself hashed, keeps two leaves' noise
                 # streams from being shifts of one another.
                 seed = jnp.stack([
-                    _hash_u32((seed0 + jnp.int32(len(groups) + k))
-                              .astype(jnp.uint32)).astype(jnp.int32),
+                    hash_u32((seed0 + jnp.int32(len(groups) + k))
+                             .astype(jnp.uint32)).astype(jnp.int32),
                     first_index(int(lps[k].size), sharded)])[None]
                 outs = _update_leaf(lgs[k], lps[k], lms[k], lvs[k], scalars,
                                     seed, sr=sr, cast=cast, out_dtype=dt,

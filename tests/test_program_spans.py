@@ -151,8 +151,8 @@ def train_op_names(train_engine):
 
 @pytest.mark.parametrize("scope", [
     "fwd_bwd", "transpose(", "fwd_bwd/transpose(", "embed", "attn", "mlp",
-    "lm_head", "optimizer/flatten", "optimizer/norm", "optimizer/kernel",
-    "optimizer/unflatten"])
+    "lm_head", "attn/dropout", "mlp/dropout", "optimizer/flatten",
+    "optimizer/norm", "optimizer/kernel", "optimizer/unflatten"])
 def test_train_step_carries_scope(train_op_names, scope):
     assert any(scope in n for n in train_op_names), scope
 
