@@ -4,8 +4,10 @@ engine: checkpoint/params in, continuously-batched tokens out.
 Architecture (mirrors the training engine's discipline):
 
 - The MODEL comes in as a served model (inference/served.py): what it
-  keeps per token and layer in the paged pool, and its decode / verify /
-  prefill programs. Everything below — slots, admission, the allocator,
+  keeps in the paged pool — rows a token and layer, or a fixed-size
+  state a stream (then a block is a page, and the prefix cache keeps
+  snapshots: inference/kv_cache.py) — and its decode / verify / prefill
+  programs. Everything below — slots, admission, the allocator,
   the prefix cache, sampling, spans — is the same for every model.
 - TWO compiled programs serve everything: ``decode_step`` (one token for
   every slot at once) and ``prefill_step`` (one chunk of one slot's
@@ -123,8 +125,9 @@ class InferenceEngine:
             # Full provisioning: every slot can reach max_len, so
             # admission never blocks on HBM; smaller pools oversubscribe
             # and the admission gate accounts free blocks.
-            self.num_blocks = self.max_slots * \
-                (self.max_len // self.block_size)
+            self.num_blocks = self.max_slots * (
+                1 if served.cache_per_stream
+                else self.max_len // self.block_size)
         if self.num_blocks % self.dp:
             raise ValueError(
                 f"inference.num_blocks={self.num_blocks} must be "
@@ -155,15 +158,21 @@ class InferenceEngine:
         self.param_bytes = quantized_bytes(self._params)
 
         # --- the KV cache: the paged block pool, born sharded ---
-        kv_dtype = resolve_kv_dtype(self.icfg.kv_cache_dtype,
-                                    served.dtype)
+        if served.cache_per_stream and self.spec_k > 0:
+            raise ValueError(
+                "inference.spec_k > 0 needs a cache that can drop rejected "
+                f"rows; {served.name} keeps a state per stream")
+        kv_dtype = served.cache_dtype or resolve_kv_dtype(
+            self.icfg.kv_cache_dtype, served.dtype)
         self.cache_spec = kv_cache.PagedKVCacheSpec(
             num_layers=served.cache_layers,
             num_slots=self.max_slots, num_blocks=self.num_blocks,
             block_size=self.block_size, max_len=self.max_len,
             num_heads=served.cache_heads,
             head_dim=served.cache_row_width, num_groups=self.dp,
-            dtype=kv_dtype, pools=served.cache_pools(self.block_size))
+            dtype=kv_dtype, pools=served.cache_pools(self.block_size),
+            per_stream=served.cache_per_stream,
+            token_row_bytes=served.token_row_bytes)
         self.cache = kv_cache.init_paged_cache(self.cache_spec, self.mesh)
         self._cache_sh = kv_cache.paged_shardings(
             self.mesh, self.cache_spec.pool_names)
@@ -254,7 +263,9 @@ class InferenceEngine:
         self._prefill_fn = self.telemetry.instrument_step_fn(
             "prefill_step", self._build_prefill_step())
         self._copy_fn = self.telemetry.instrument_step_fn(
-            "copy_block", self._build_copy_block())
+            *(("state_copy", self._build_state_copy())
+              if served.cache_per_stream
+              else ("copy_block", self._build_copy_block())))
         if self.spec_k > 0:
             self._verify_fn = self.telemetry.instrument_step_fn(
                 "verify_step", self._build_verify_step())
@@ -282,10 +293,10 @@ class InferenceEngine:
 
     def _pools(self) -> Tuple[jax.Array, ...]:
         """The cache's pools in the served model's order."""
-        return tuple(self.cache.values())
+        return tuple(self.cache[name] for name in self._cache_sh)
 
     def _store_pools(self, pools) -> None:
-        for name, pool in zip(list(self.cache), pools):
+        for name, pool in zip(self._cache_sh, pools):
             self.cache[name] = pool
 
     def _runtime_params(self, params):
@@ -388,6 +399,22 @@ class InferenceEngine:
                          for pool in pools)
 
         return jax.jit(copy_block, donate_argnums=tuple(range(len(sh))),
+                       out_shardings=sh)
+
+    def _build_state_copy(self) -> Callable:
+        """A per-stream pool's copy: page ``src[g]`` to page ``dst[g]``
+        of every group, page to page in the donated pools — a snapshot
+        into a stream's own page at admission, a stream's page into a
+        snapshot when prefill reaches its boundary."""
+        sh = tuple(self._cache_sh.values())
+
+        @jax.named_scope("state_copy")
+        def state_copy(*args):
+            pools, (src, dst) = args[:len(sh)], args[len(sh):]
+            return tuple(kv_cache.copy_pages(pool, src, dst)
+                         for pool in pools)
+
+        return jax.jit(state_copy, donate_argnums=tuple(range(len(sh))),
                        out_shardings=sh)
 
     def _next_key(self) -> jax.Array:
@@ -493,7 +520,7 @@ class InferenceEngine:
                                             int(max_new_tokens),
                                             self.spec_k, share=share):
                 continue
-            matched = len(self.allocator.match_prefix(g, prompt)[0]) \
+            matched = self.allocator.matched_blocks(g, prompt) \
                 if share else 0
             key = (matched, self.allocator.available(g))
             if best_key is None or key > best_key:
@@ -532,8 +559,7 @@ class InferenceEngine:
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         best = 0
         for g in range(self.dp):
-            best = max(best,
-                       len(self.allocator.match_prefix(g, prompt)[0]))
+            best = max(best, self.allocator.matched_blocks(g, prompt))
         return best * self.block_size
 
     # ------------------------------------------------------------------ #
@@ -648,8 +674,13 @@ class InferenceEngine:
                      rids=ids_arg(rids)) as span:
             with tl.span("prefill_plan"):
                 pools, plans, tails = self._plan_prefill(admissions)
-            steps, held = self._run_prefill_chunks(pools, plans, tails,
-                                                   np.float32(temperature))
+            try:
+                steps, held = self._run_prefill_chunks(
+                    pools, plans, tails, np.float32(temperature))
+            except BaseException:
+                for plan in plans:
+                    self.allocator.abandon_snapshot(plan[2])
+                raise
             tl.raise_pending()
             out = []
             n_ctr = len(self.served.counter_names)
@@ -671,17 +702,34 @@ class InferenceEngine:
             span.set_metadata(
                 cached_tokens=sum(int(p[2].matched) for p in plans),
                 chunks=len(steps))
+            if self.cache_spec.per_stream:
+                span.set_metadata(**self._note_state_admissions(plans))
         if self.serving.ledger is not None:
             self.serving.ledger.note("prefill",
                                      time.perf_counter() - t_pf0)
         return out
 
+    def _copy_blocks(self, pools, pairs):
+        """Dispatch the copy program for ``{group: (src, dst)}`` block
+        ids: page ids as they are for a per-stream pool (-1: nothing to
+        copy in the group), one-hots over the group's blocks otherwise."""
+        G, B = self.dp, self.cache_spec.blocks_per_group
+        if self.cache_spec.per_stream:
+            src, dst = np.full(G, -1, np.int32), np.full(G, -1, np.int32)
+            for g, (s, d) in pairs.items():
+                src[g], dst[g] = s, d
+        else:
+            src, dst = np.zeros((G, B), np.float32), np.zeros((G, B), bool)
+            for g, (s, d) in pairs.items():
+                src[g, s], dst[g, d] = 1.0, True
+        return self._copy_fn(*pools, src, dst)
+
     def _plan_prefill(self, admissions):
         """prefill_many's ``prefill_plan``: admit every prompt through
         the block allocator, run the merged copy-on-write fork, and lay
         out each admission's unshared tail in chunks. Returns (pools,
-        [(slot, group, plan, prompt, plen)], [(padded tail, chunks,
-        tail length)])."""
+        [(slot, group, plan, prompt, plen)], [([(first token, tokens) per
+        chunk], index of the chunk a snapshot follows or None)])."""
         G = self.dp
         J = self.cache_spec.max_blocks_per_slot
         Sg = self.cache_spec.slots_per_group
@@ -689,10 +737,7 @@ class InferenceEngine:
         pools = self._pools()
         plans = []
         seen_groups = set()
-        cow_src = np.zeros((G, self.cache_spec.blocks_per_group),
-                           np.float32)
-        cow_dst = np.zeros((G, self.cache_spec.blocks_per_group), bool)
-        any_cow = False
+        forks = {}       # group -> (src, dst): copied before any chunk
         for slot, prompt, max_new in admissions:
             prompt = np.asarray(prompt, np.int32).reshape(-1)
             plen = int(prompt.shape[0])
@@ -714,24 +759,27 @@ class InferenceEngine:
             row[:len(plan.table)] = plan.table
             self.block_tables[slot] = row
             if plan.cow_src is not None:
-                cow_src[group, plan.cow_src] = 1.0
-                cow_dst[group, plan.cow_dst] = True
-                any_cow = True
+                forks[group] = (plan.cow_src, plan.cow_dst)
             plans.append((slot, group, plan, prompt, plen))
-        if any_cow:
-            pools = self._copy_fn(*pools, cow_src, cow_dst)
+        if forks:
+            pools = self._copy_blocks(pools, forks)
         # Chunk schedule: admission a runs chunks over its unshared
         # tail; all admissions advance together, groups whose tail is
-        # done go inactive (writes land nowhere).
+        # done go inactive (writes land nowhere).  A tail is cut where a
+        # snapshot is due (``plan.snapshot_at``): a state can only be
+        # frozen at the end of a chunk program.
         tails = []
         for slot, group, plan, prompt, plen in plans:
-            tlen = plen - plan.matched
-            n_chunks = -(-tlen // chunk)
-            padded = np.zeros(n_chunks * chunk, np.int32)
-            padded[:tlen] = prompt[plan.matched:]
-            tails.append((padded, n_chunks, tlen))
+            cuts = [plan.matched, plen]
+            if plan.matched < plan.snapshot_at < plen:
+                cuts.insert(1, plan.snapshot_at)
+            chunks = [(a, min(chunk, hi - a)) for lo, hi in
+                      zip(cuts, cuts[1:]) for a in range(lo, hi, chunk)]
+            snap_after = next((i for i, (a, n) in enumerate(chunks)
+                               if a + n == plan.snapshot_at), None)
+            tails.append((chunks, snap_after))
             self._last_admit[slot] = {
-                "cached_tokens": int(plan.matched), "chunks": n_chunks,
+                "cached_tokens": int(plan.matched), "chunks": len(chunks),
                 "cow_fork": plan.cow_src is not None}
         return pools, plans, tails
 
@@ -745,36 +793,68 @@ class InferenceEngine:
         chunk = self.prefill_chunk
         held = {}
         steps = []
-        for ci in range(max(n for _, n, _ in tails)):
-            toks = np.zeros((G, chunk), np.int32)
-            bt_rows = np.full((G, J), kv_cache.DEAD_BLOCK, np.int32)
-            starts = np.zeros(G, np.int32)
-            last_idxs = np.zeros(G, np.int32)
-            act = np.zeros(G, np.int32)
-            for (slot, group, plan, prompt, plen), \
-                    (padded, n_chunks, tlen) in zip(plans, tails):
-                if ci >= n_chunks:
-                    continue
-                toks[group] = padded[ci * chunk:(ci + 1) * chunk]
-                bt_rows[group] = self.block_tables[slot]
-                starts[group] = plan.matched + ci * chunk
-                act[group] = 1
-                # The chunk's last row that belongs to the prompt: every
-                # row of a chunk before the last (whose logits nobody
-                # reads), the prompt's last token in the last one. Rows
-                # past it are padding a served model may skip.
-                last_idxs[group] = chunk - 1
-                if ci == n_chunks - 1:
-                    last_idxs[group] = tlen - 1 - ci * chunk
-                    held[slot] = (ci, group)
-            with self.telemetry.span("prefill_chunk", ci=ci,
-                                     active_groups=int(act.sum())):
-                *pools, tok_g, logits_g = self._prefill_fn(
-                    self._params, *pools, toks, bt_rows, starts,
-                    last_idxs, act, self._next_key(), temp)
-            steps.append((tok_g, logits_g))
-        self._store_pools(pools)
+        try:
+            for ci in range(max(len(chunks) for chunks, _ in tails)):
+                toks = np.zeros((G, chunk), np.int32)
+                bt_rows = np.full((G, J), kv_cache.DEAD_BLOCK, np.int32)
+                starts = np.zeros(G, np.int32)
+                last_idxs = np.zeros(G, np.int32)
+                act = np.zeros(G, np.int32)
+                snaps = {}   # group -> (own page, snapshot page)
+                frozen = []  # their plans: committed once the copy is out
+                for (slot, group, plan, prompt, plen), (chunks, snap_after) \
+                        in zip(plans, tails):
+                    if ci >= len(chunks):
+                        continue
+                    first, n = chunks[ci]
+                    toks[group, :n] = prompt[first:first + n]
+                    bt_rows[group] = self.block_tables[slot]
+                    starts[group] = first
+                    act[group] = 1
+                    # The chunk's last row that belongs to the prompt (the
+                    # prompt's last token in the last chunk). Rows past it
+                    # are padding a served model may skip.
+                    last_idxs[group] = n - 1
+                    if ci == len(chunks) - 1:
+                        held[slot] = (ci, group)
+                    if ci == snap_after:
+                        snaps[group] = (plan.table[0], plan.snapshot_page)
+                        frozen.append(plan)
+                with self.telemetry.span("prefill_chunk", ci=ci,
+                                         active_groups=int(act.sum())):
+                    *pools, tok_g, logits_g = self._prefill_fn(
+                        self._params, *pools, toks, bt_rows, starts,
+                        last_idxs, act, self._next_key(), temp)
+                if snaps:
+                    pools = self._copy_blocks(pools, snaps)
+                    for plan in frozen:
+                        self.allocator.commit_snapshot(plan)
+                steps.append((tok_g, logits_g))
+        finally:
+            # also where a chunk raised: the pools before it were donated
+            self._store_pools(pools)
         return steps, held
+
+    def _note_state_admissions(self, plans) -> Dict[str, int]:
+        """A per-stream pool's ``prefill`` span args, also summed into
+        ``snapshot()["state"]``: tokens resumed from a snapshot (what
+        ``cached_tokens`` means here), snapshots this admission took, and
+        the bytes its page copies moved (read + written)."""
+        page = self.cache_spec.block_nbytes()
+        args = {
+            "resumed_tokens": sum(int(p[2].matched) for p in plans),
+            "snapshot_taken": sum(p[2].snapshot_page is not None
+                                  for p in plans),
+            "state_copy_bytes": 2 * page * sum(
+                (p[2].cow_src is not None)
+                + (p[2].snapshot_page is not None) for p in plans)}
+        self.serving.note_state(
+            resumed_tokens=args["resumed_tokens"],
+            state_copy_bytes=args["state_copy_bytes"],
+            snapshots_taken=self.allocator.snapshots_taken,
+            snapshot_hits=self.allocator.snapshot_hits,
+            snapshots_evicted=self.allocator.reclaimed)
+        return args
 
     def _cache_accounting(self) -> Tuple[int, int, int]:
         """(live blocks, cache bytes held, context tokens cached) this
@@ -810,16 +890,14 @@ class InferenceEngine:
         the last one whole) or pool-capacity term (``pool_blocks``: every
         row of a group's pool, what the one-hot contraction reads)."""
         sp_ = self.cache_spec
-        per_key = self.__dict__.get("_attend_per_key")
-        if per_key is None:         # both are linear in the key rows
-            served = self.served
-            per_key = self._attend_per_key = (
-                served.attend_flops(1) * sp_.num_layers,
-                served.attend_bytes(1, sp_.block_size, int(jnp.dtype(
-                    sp_.dtype).itemsize)) * sp_.num_layers)
         keys = paged_attn_ops._attend_keys(sp_.block_size, context,
                                            pool_blocks)
-        return per_key[0] * keys, per_key[1] * keys
+        cost = self.__dict__.setdefault("_cache_costs", {})
+        if keys not in cost:
+            flops, nbytes = self.served.cache_cost(
+                keys, sp_.block_size, int(jnp.dtype(sp_.dtype).itemsize))
+            cost[keys] = (flops * sp_.num_layers, nbytes * sp_.num_layers)
+        return cost[keys]
 
     def _attend_work(self, k_rows: int) -> Tuple[int, int, int, int]:
         """Analytic attend work of the iteration just run, priced BOTH
@@ -916,6 +994,8 @@ class InferenceEngine:
                               context_tokens=ctx_tokens,
                               attend_steps=steps[0],
                               attend_live_steps=steps[1])
+            if self.cache_spec.per_stream:
+                span.set_metadata(state_pages_live=n_active)
             self._note_counters(span, counters)
         out_logits = np.asarray(jax.device_get(logits)) \
             if return_logits else None

@@ -45,6 +45,18 @@ paged-attention kernel on TPU; the one-hot ``paged_attend`` contraction
 path and the reference the kernel is tested against, and
 ``paged_copy_block`` (copy-on-write, rare) is still a one-hot select
 over the pool (see docs/tutorials/inference.md).
+
+A served model may instead keep a fixed-size STATE per stream (a
+retention layer's recurrent state: ``PagedKVCacheSpec.per_stream``).  The
+pool, the allocator, the hash chain and the LRU are the same; a block is
+then a PAGE — one stream's whole state, every layer — and a block table is
+one page wide.  A stream owns one page and rewrites it in place, so
+nothing can be shared by reference count: the prefix cache keeps SNAPSHOTS
+(a page frozen at a block boundary of a prompt, keyed by the chain hash
+there, retained LRU like a cached block), ``match_snapshot`` finds the
+longest boundary that has one, and admission COPIES it into the stream's
+own page (``copy_pages``: page to page, never a select over the pool).
+Which boundaries are worth a page is ``BlockAllocator.snapshot_boundary``.
 """
 from __future__ import annotations
 
@@ -91,6 +103,12 @@ class PagedKVCacheSpec:
     # (``inference.served.ServedModel.cache_pools``).  ``num_heads`` /
     # ``head_dim`` then describe a cache row's heads and logical width.
     pools: Optional[Tuple[Tuple[str, Tuple[int, int, int]], ...]] = None
+    # A tile is one STREAM's state of a layer, of fixed size (a page; see
+    # the module docstring), not ``block_size`` tokens' rows; and what a
+    # token of this model would keep a layer as K/V rows, in bytes — the
+    # yardstick of ``BlockAllocator.snapshot_boundary``.
+    per_stream: bool = False
+    token_row_bytes: int = 0
 
     @property
     def pool_tiles(self) -> Tuple[Tuple[str, Tuple[int, int, int]], ...]:
@@ -122,8 +140,16 @@ class PagedKVCacheSpec:
 
     @property
     def max_blocks_per_slot(self) -> int:
-        """Block-table width J: logical blocks a full slot spans."""
-        return self.max_len // self.block_size
+        """Block-table width J: logical blocks a full slot spans (one
+        page for a per-stream pool)."""
+        return 1 if self.per_stream else self.max_len // self.block_size
+
+    @property
+    def page_tokens(self) -> int:
+        """Tokens whose K/V rows would fill a page of a per-stream pool:
+        a snapshot of fewer is dearer than what it saves."""
+        return -(-self.block_nbytes()
+                 // (self.num_layers * max(1, self.token_row_bytes)))
 
     @property
     def fold(self) -> int:
@@ -229,6 +255,25 @@ def paged_shardings(mesh: Mesh, names: Sequence[str] = ("k", "v")
                     ) -> Dict[str, NamedSharding]:
     spec = paged_partition_spec()
     return {name: NamedSharding(mesh, spec) for name in names}
+
+
+def copy_pages(pool: jax.Array, src: jax.Array, dst: jax.Array
+               ) -> jax.Array:
+    """Copy page ``src[g]`` to page ``dst[g]`` of every group ``g`` (all
+    layers), page to page: a slice read and a ``dynamic_update_slice``
+    into the donated pool, whatever the pool's size.  pool ``[L, G, B,
+    ...]``; src / dst ``[G]`` int32, a group with ``dst < 0`` copies
+    nothing (its page ``src`` onto itself)."""
+    L, G = pool.shape[:2]
+    tile = pool.shape[3:]
+    zeros = (0,) * len(tile)
+    for g in range(G):
+        s_ = jnp.maximum(src[g], 0)
+        d_ = jnp.where(dst[g] >= 0, dst[g], s_)     # nothing: onto itself
+        page = lax.dynamic_slice(pool, (0, g, s_) + zeros,
+                                 (L, 1, 1) + tile)
+        pool = lax.dynamic_update_slice(pool, page, (0, g, d_) + zeros)
+    return pool
 
 
 def init_paged_cache(spec: PagedKVCacheSpec,
@@ -408,6 +453,8 @@ class BlockAllocator:
         # Cumulative telemetry the aggregator snapshots.
         self.cow_copies = 0
         self.reclaimed = 0
+        self.snapshots_taken = 0        # per-stream pools only
+        self.snapshot_hits = 0
 
     # ---- accounting ---- #
     def blocks_in_use(self) -> int:
@@ -427,7 +474,10 @@ class BlockAllocator:
     def need_blocks(self, prompt_len: int, max_new: int,
                     spec_k: int = 0) -> int:
         """Worst-case logical blocks a request spans (capped at the
-        table width)."""
+        table width): one page of a per-stream pool, whatever the
+        lengths."""
+        if self.spec.per_stream:
+            return 1
         tokens = prompt_len + max_new + spec_k
         need = -(-tokens // self.spec.block_size)
         return min(need, self.spec.max_blocks_per_slot)
@@ -452,9 +502,51 @@ class BlockAllocator:
             hashes.append(h)
         return blocks, hashes
 
+    def matched_blocks(self, group: int, prompt: np.ndarray) -> int:
+        """Full blocks of ``prompt`` the prefix cache of this group
+        covers: the cached chain's length, or (per-stream pools) the
+        longest boundary that has a snapshot."""
+        if self.spec.per_stream:
+            return self.match_snapshot(group, prompt)[0]
+        return len(self.match_prefix(group, prompt)[0])
+
+    def match_snapshot(self, group: int, prompt: np.ndarray
+                       ) -> Tuple[int, Optional[int], int]:
+        """Per-stream pools: the LONGEST block boundary of ``prompt``
+        that has a snapshot and leaves at least the last token to
+        prefill -> (blocks it covers, its page or None, the chain hash at
+        the prompt's last full block).  A state is valid at ONE position,
+        so the walk goes on past boundaries that have none."""
+        bs = self.spec.block_size
+        idx = self._hash_index[group]
+        best, page, h = 0, None, 0
+        for j in range(len(prompt) // bs):
+            h = chain_hash(h, prompt[j * bs:(j + 1) * bs])
+            b = idx.get(h)
+            if b is not None and (j + 1) * bs <= len(prompt) - 1:
+                best, page = j + 1, b
+        return best, page, h
+
+    def snapshot_boundary(self, prompt_len: int, resumed: int) -> int:
+        """THE RULE of which boundaries get a page: the prompt's last
+        full block, when the tokens it adds beyond the snapshot it
+        resumed from would fill at least a page as K/V rows of this model
+        (``PagedKVCacheSpec.page_tokens``) — below that the page is
+        dearer than what it saves.  So a document served once leaves a
+        snapshot; a short question over it does not, and cannot push a
+        document out.  Returns the boundary in tokens, or 0."""
+        boundary = prompt_len // self.spec.block_size * self.spec.block_size
+        return boundary if boundary - resumed >= self.spec.page_tokens else 0
+
     def can_admit(self, group: int, prompt: np.ndarray, max_new: int,
                   spec_k: int = 0, share: bool = True) -> bool:
         need = self.need_blocks(len(prompt), max_new, spec_k)
+        if self.spec.per_stream:
+            # The stream's own page, drawn while the snapshot it resumes
+            # from (if retained) is held out of reach.
+            page = self.match_snapshot(group, prompt)[1] if share else None
+            return self.available(group) - int(
+                page is not None and page in self._lru[group]) >= need
         matched = self.match_prefix(group, prompt)[0] if share else []
         # Only LIVE shared blocks are a free ride; reviving an
         # LRU-retained block consumes reclaimable capacity like any
@@ -520,6 +612,8 @@ class BlockAllocator:
                 f"group {group}: {self.available(group)} block(s) "
                 f"available < worst-case need for a "
                 f"{len(prompt)}+{max_new}-token request")
+        if self.spec.per_stream:
+            return self._admit_stream(slot, group, prompt, share)
         bs = self.spec.block_size
         plen = len(prompt)
         matched_blocks, hashes = self.match_prefix(group, prompt) \
@@ -569,6 +663,61 @@ class BlockAllocator:
                          cow_dst=table[n_keep] if cow_src is not None
                          else None)
 
+    def _admit_stream(self, slot: int, group: int, prompt: np.ndarray,
+                      share: bool) -> "AdmitPlan":
+        """``admit_prompt`` for a per-stream pool: the stream's own page;
+        the snapshot to copy into it first (``cow_src`` -> ``cow_dst``:
+        the same device copy a copy-on-write fork asks for) and the
+        position prefill resumes at; and, where ``snapshot_boundary``
+        says so and a page can be had, the page that will hold this
+        prompt's own snapshot (``snapshot_at``, ``snapshot_page``: the
+        engine freezes the state there when prefill reaches it and THEN
+        enters it into the prefix cache, ``commit_snapshot``; until then
+        the page is out of every list and nothing can match it)."""
+        bs = self.spec.block_size
+        n, src, h_last = self.match_snapshot(group, prompt) \
+            if share else (0, None, 0)
+        if src is not None:
+            self._incref(group, src)            # out of the LRU's reach
+        own = self._draw(group, slot)
+        at = self.snapshot_boundary(len(prompt), n * bs) if share else 0
+        snap = None
+        if at and h_last not in self._hash_index[group] \
+                and self.available(group) > 0:
+            snap = self._pop_block(group)
+        if src is not None:
+            self._decref(group, src)            # back, most recently used
+            self.snapshot_hits += 1
+        self._slot_reserved[slot] = 0
+        self._slot_group[slot] = group
+        return AdmitPlan(slot=slot, group=group, table=[own],
+                         matched=n * bs, cow_src=src,
+                         cow_dst=own if src is not None else None,
+                         snapshot_at=at if snap is not None else 0,
+                         snapshot_page=snap, snapshot_hash=h_last)
+
+    def commit_snapshot(self, plan: "AdmitPlan") -> None:
+        """The engine has frozen ``plan``'s state into its snapshot page
+        (the copy is dispatched): key the page by the chain hash of its
+        boundary and retain it, most recently used."""
+        g, page, h = plan.group, plan.snapshot_page, plan.snapshot_hash
+        if h in self._hash_index[g]:            # another admission's is in
+            self._free[g].append(page)
+            return
+        self._hash_index[g][h] = page
+        self._block_hash[g][page] = h
+        self._lru[g][page] = None
+        self.snapshots_taken += 1
+
+    def abandon_snapshot(self, plan: "AdmitPlan") -> None:
+        """Prefill failed: a snapshot page that was never committed goes
+        back to the free list (a committed one holds a whole state and
+        stays)."""
+        g, page = plan.group, plan.snapshot_page
+        if page is not None \
+                and self._block_hash[g].get(page) != plan.snapshot_hash:
+            self._free[g].append(page)
+
     def alloc_block(self, slot: int) -> int:
         """Lazily allocate one more block for a live slot (a decode or
         verify append crossing a block boundary), drawing down the
@@ -607,12 +756,18 @@ class AdmitPlan:
     matched: int
     cow_src: Optional[int] = None
     cow_dst: Optional[int] = None
+    # Per-stream pools: freeze the stream's page into ``snapshot_page``
+    # when prefill has consumed ``snapshot_at`` tokens (0: no snapshot),
+    # then ``commit_snapshot`` it under ``snapshot_hash``.
+    snapshot_at: int = 0
+    snapshot_page: Optional[int] = None
+    snapshot_hash: int = 0
 
 
 __all__ = ["DEAD_BLOCK", "PagedKVCacheSpec", "paged_partition_spec",
            "paged_shardings", "init_paged_cache", "kv_fold",
            "paged_logical_view", "paged_folded_view", "paged_block_size",
-           "paged_layer_view",
+           "paged_layer_view", "copy_pages",
            "positions_to_blocks",
            "block_select", "paged_write_rows", "paged_attend",
            "copy_block_onehots", "paged_copy_block", "chain_hash",

@@ -4,21 +4,28 @@ The engine, the scheduler, the block allocator, the prefix cache,
 admission, sampling and the spans know nothing of a model's block.  They
 ask a ``ServedModel`` for:
 
-- **what it keeps per token and layer in the paged pool** —
-  ``cache_layers`` and ``cache_pools(block_size)``: the pools by name,
-  each with the shape of ONE block's tile as held (``[heads, rows,
-  lanes]``, lane-dense) — from which ``PagedKVCacheSpec``, the pool
-  arrays, ``block_nbytes`` and the copy-on-write copy follow.  GPT-2
-  keeps two, ``k`` and ``v`` (per-head rows); the latent-attention family
-  keeps one, ``latent`` (a ``[ckv | k_rope]`` row shared by every head);
+- **what it keeps in the paged pool** — ``cache_layers`` and
+  ``cache_pools(block_size)``: the pools by name, each with the shape of
+  ONE block's tile as held (``[heads, rows, lanes]``, lane-dense) — from
+  which ``PagedKVCacheSpec``, the pool arrays, ``block_nbytes``,
+  admission's block count and the copy follow.  A pool is declared **per
+  token** (the default: a block is ``block_size`` tokens' rows of a
+  layer; GPT-2 keeps two, ``k`` and ``v``, per-head rows; the
+  latent-attention family one, ``latent``, a ``[ckv | k_rope]`` row
+  shared by every head) or **per stream** (``cache_per_stream``: a block
+  is a PAGE, one stream's fixed-size state of a layer — the retention
+  family's ``state`` and ``norm`` — a block table is one page wide, and
+  the prefix cache keeps snapshots: ``inference/kv_cache.py``);
 - **its programs**, each a pure function over ``(params, pools, ...)``
   that writes the new rows into the pools in place and returns ``(logits,
   pools)`` (and the model's counters, see below): ``decode`` (one token a
   slot), ``verify`` (K tokens a slot, speculative), ``prefill_chunk`` (one
   chunk of one slot a group), ``prefill_full`` (a whole padded prompt);
-- **the attend's cost** for the engine's analytic counters:
-  ``attend_dims`` = (query heads, score width, value width) and
-  ``attend_step_counts``;
+- **the cache's cost a token** for the engine's analytic counters:
+  ``cache_cost(keys, ...)`` = (FLOPs, cache bytes) a layer spends on one
+  query token, which MAY depend on the ``keys`` rows in reach (an attend:
+  ``attend_dims`` = (query heads, score width, value width), linear in
+  ``keys``; a state: a constant), and ``attend_step_counts``;
 - **counters** a program returns beside its logits (``counter_names``):
   int32 scalars that ride the token fetch — the expert layer's held-row
   counts for the latent family, none for GPT-2.
@@ -43,6 +50,12 @@ class ServedModel:
     """Base of the implementations; see the module docstring."""
     cfg: Any
     counter_names: Tuple[str, ...] = ()
+    # ``cache_pools`` declares a stream's whole state of a layer, of fixed
+    # size (a page), not a block of tokens' rows.
+    cache_per_stream: bool = False
+    # What the pools hold where that is not ``inference.kv_cache_dtype``'s
+    # to choose (a recurrent state is fp32 whatever the rows' dtype).
+    cache_dtype: Any = None
 
     def __init__(self, cfg):
         self.cfg = cfg
@@ -87,7 +100,20 @@ class ServedModel:
         ...)."""
         raise NotImplementedError
 
-    # -- the attend's analytic cost ------------------------------------ #
+    @property
+    def token_row_bytes(self) -> int:
+        """Per-stream pools: bytes a token of this model would keep a
+        layer as K/V rows (what a snapshot page is weighed against)."""
+        return 0
+
+    # -- the cache's analytic cost a token ------------------------------ #
+    def cache_cost(self, keys: int, block_size: int, itemsize: int
+                   ) -> Tuple[int, int]:
+        """(FLOPs, cache bytes) a layer spends on ONE query token with
+        ``keys`` key rows in reach."""
+        return (self.attend_flops(keys),
+                self.attend_bytes(keys, block_size, itemsize))
+
     @property
     def attend_dims(self) -> Tuple[int, int, int]:
         """(query heads, width a score contracts, width a value row

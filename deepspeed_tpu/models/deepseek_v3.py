@@ -174,15 +174,22 @@ def yarn_inv_freq(cfg: DeepseekV3Config) -> np.ndarray:
     return freq / cfg.rope_factor * (1.0 - keep) + freq * keep
 
 
+def rotary_cos_sin(inv_freq, positions: jax.Array, scale: float = 1.0):
+    """fp32 ``scale`` x cos, sin ``[..., len(inv_freq)]`` of ``positions``
+    x ``inv_freq``: rotary positions with whatever frequencies a family
+    states."""
+    ang = positions.astype(jnp.float32)[..., None] \
+        * jnp.asarray(inv_freq, jnp.float32)
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
+
+
 def rope_cos_sin(cfg: DeepseekV3Config, positions: jax.Array):
     """fp32 cos, sin ``[..., rope_dim / 2]`` at integer ``positions``;
     scaled by yarn_mscale(factor, mscale) / yarn_mscale(factor,
     mscale_all_dim) (1 for the published values)."""
-    inv = jnp.asarray(yarn_inv_freq(cfg), jnp.float32)
-    ang = positions.astype(jnp.float32)[..., None] * inv
     att = yarn_mscale(cfg.rope_factor, cfg.rope_mscale) \
         / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
-    return jnp.cos(ang) * att, jnp.sin(ang) * att
+    return rotary_cos_sin(yarn_inv_freq(cfg), positions, att)
 
 
 def rope_interleaved(x: jax.Array, cos: jax.Array, sin: jax.Array
@@ -314,5 +321,6 @@ def deepseek_v3_init(rng: jax.Array, cfg: DeepseekV3Config
 
 
 __all__ = ["DeepseekV3Config", "deepseek_v3_init", "yarn_inv_freq",
-           "yarn_mscale", "rope_cos_sin", "rope_interleaved", "rms_norm",
-           "matmul", "swiglu", "latent_projections", "wkv_b_split"]
+           "yarn_mscale", "rotary_cos_sin", "rope_cos_sin",
+           "rope_interleaved", "rms_norm", "matmul", "swiglu",
+           "latent_projections", "wkv_b_split"]

@@ -63,6 +63,7 @@ class ServingAggregator:
         self.spec_proposed = 0
         self.spec_accepted = 0
         self._model_counters: Dict[str, Any] = {}   # name -> (sum, n)
+        self._state: Dict[str, int] = {}    # a per-stream state pool's
         # Analytic attend-work accounting (engine-fed): the same
         # iterations priced BOTH ways — the Pallas kernel's live-context
         # term vs the one-hot contraction's pool-capacity term. ``attend_mode`` names which one actually
@@ -136,6 +137,22 @@ class ServingAggregator:
             if isinstance(value, (int, float)):
                 tot, n = self._model_counters.get(name, (0.0, 0))
                 self._model_counters[name] = (tot + float(value), n + 1)
+
+    def note_state(self, *, resumed_tokens: int, state_copy_bytes: int,
+                   snapshots_taken: int, snapshot_hits: int,
+                   snapshots_evicted: int) -> None:
+        """One admission batch into a per-stream state pool (the
+        ``prefill`` span's ``resumed_tokens`` / ``state_copy_bytes``,
+        summed) and the allocator's running totals of snapshots taken /
+        hit / evicted."""
+        st = self._state
+        st["resumed_tokens"] = st.get("resumed_tokens", 0) \
+            + int(resumed_tokens)
+        st["state_copy_bytes"] = st.get("state_copy_bytes", 0) \
+            + int(state_copy_bytes)
+        st.update(snapshots_taken=int(snapshots_taken),
+                  snapshot_hits=int(snapshot_hits),
+                  snapshots_evicted=int(snapshots_evicted))
 
     def note_spec(self, proposed: int, accepted: int) -> None:
         self.spec_proposed += int(proposed)
@@ -253,6 +270,8 @@ class ServingAggregator:
                 "acceptance_rate": round(self.spec_accepted /
                                          self.spec_proposed, 4),
             }
+        if self._state:
+            snap["state"] = dict(self._state)
         if self._model_counters:
             snap["model_counters"] = {
                 name: round(tot / n, 4)

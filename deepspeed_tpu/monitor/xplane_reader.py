@@ -37,7 +37,13 @@ SCOPES = ("fwd_bwd", "grad_sync", "health_tap", "optimizer", "flatten",
           # the latent-attention family (inference/latent.py): the
           # projections round the attend, and the expert layer's stages
           "latent_proj", "moe", "router", "dispatch", "experts", "combine",
-          "shared")
+          "shared",
+          # the retention family (inference/retention.py): under attn, the
+          # projections, the decode kernel over the state pool / the
+          # chunked form in prefill, the output projection; and the page
+          # copy of a per-stream pool (a program of its own)
+          "qkv_proj", "state_update", "retention_chunk", "out_proj",
+          "state_copy")
 # The host spans ``Telemetry.span`` opens (runtime/engine.py,
 # inference/engine.py, inference/scheduler.py), same contract.
 SPANS = ("train_batch", "data_prep", "step_dispatch", "offload_step",
@@ -55,9 +61,14 @@ SPAN_ARGS = {
     # held expert got in a layer, held experts (x layers) that got none,
     # the pairs' share of all the live rows routed. They ride the token
     # fetch. Absent for a model without counters (GPT-2).
+    # resumed_tokens / snapshot_taken / state_copy_bytes: a per-stream
+    # state pool's admission (inference/kv_cache.py): prompt tokens the
+    # snapshot it resumed from covers (what cached_tokens means there),
+    # snapshots the admission left, bytes its page copies read + wrote.
     "prefill": ("slots", "prompt_tokens", "rids", "cached_tokens",
                 "chunks", "moe_held_pairs", "moe_held_max",
-                "moe_held_mean", "moe_held_empty", "moe_held_pair_share"),
+                "moe_held_mean", "moe_held_empty", "moe_held_pair_share",
+                "resumed_tokens", "snapshot_taken", "state_copy_bytes"),
     "prefill_chunk": ("ci", "active_groups"),
     # attend_steps / attend_live_steps: the paged kernel's sequencing
     # steps a layer in this execution and those that touch a live block
@@ -65,7 +76,10 @@ SPAN_ARGS = {
     "decode": ("iteration", "active", "live_blocks", "context_tokens",
                "attend_steps", "attend_live_steps", "moe_held_pairs",
                "moe_held_max", "moe_held_mean", "moe_held_empty",
-               "moe_held_pair_share"),
+               "moe_held_pair_share",
+               # pages of a per-stream pool the state-update kernel
+               # rewrote in this execution (one a live stream)
+               "state_pages_live"),
     "emit": ("finished",), "serve_idle": ("why",)}
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"

@@ -451,3 +451,65 @@ def test_decode_and_prefill_spans_carry_the_expert_counters(tmp_path,
     assert set(means) >= {"moe_held_pairs", "moe_held_max",
                           "moe_held_mean", "moe_held_empty",
                           "moe_held_pair_share"}
+
+
+# --------------------------------------------------------------------- #
+# (g) the retention family's scopes (PR 34): one case a program and scope
+# --------------------------------------------------------------------- #
+@pytest.fixture(scope="module")
+def retention_op_names():
+    import jax.numpy as jnp
+    from deepspeed_tpu.models.brumby import BrumbyConfig, brumby_init
+    cfg = BrumbyConfig(vocab_size=128, hidden_size=32, intermediate_size=64,
+                       num_hidden_layers=2, num_attention_heads=4,
+                       num_key_value_heads=2, head_dim=16,
+                       max_position_embeddings=128, dtype=jnp.float32)
+    eng = InferenceEngine(
+        cfg, brumby_init(jax.random.PRNGKey(0), cfg),
+        config={"inference": {"max_slots": 4, "max_seq_len": 128,
+                              "prefill_chunk": 16, "block_size": 8,
+                              "num_blocks": 6, "paged_kernel": True}},
+        mesh=build_mesh(devices=jax.devices()[:1]))
+    G, J = eng.dp, eng.cache_spec.max_blocks_per_slot
+    assert J == 1 and list(eng._cache_sh) == ["state", "norm"]
+    key, temp = eng._next_key(), np.float32(0.0)
+    pools = eng._pools()
+    names = {
+        "decode": _op_names(eng._decode_fn, eng._params, *pools,
+                            eng.last_tokens, eng.lengths, eng.block_tables,
+                            key, temp),
+        "prefill": _op_names(
+            eng._prefill_fn, eng._params, *pools,
+            np.zeros((G, eng.prefill_chunk), np.int32),
+            np.zeros((G, J), np.int32), np.zeros(G, np.int32),
+            np.zeros(G, np.int32), np.ones(G, np.int32), key, temp),
+        "copy": _op_names(eng._copy_fn, *pools, np.zeros(G, np.int32),
+                          np.ones(G, np.int32))}
+    eng.close()
+    return names
+
+
+@pytest.mark.parametrize("program,scope", [
+    ("decode", "embed"), ("decode", "attn/qkv_proj"),
+    ("decode", "attn/state_update"), ("decode", "attn/out_proj"),
+    ("decode", "mlp"), ("decode", "lm_head"), ("decode", "sample"),
+    ("prefill", "embed"), ("prefill", "attn/qkv_proj"),
+    ("prefill", "attn/retention_chunk"), ("prefill", "attn/out_proj"),
+    ("prefill", "mlp"), ("prefill", "lm_head"), ("prefill", "sample"),
+    ("copy", "state_copy")])
+def test_retention_program_carries_scope(retention_op_names, program,
+                                         scope):
+    assert any(f"/{scope}" in n for n in retention_op_names[program]), \
+        (program, scope)
+
+
+def test_retention_forms_stay_in_their_own_program(retention_op_names):
+    assert not any("retention_chunk" in n
+                   for n in retention_op_names["decode"])
+    assert not any("state_update" in n
+                   for n in retention_op_names["prefill"])
+    from deepspeed_tpu.monitor.xplane_reader import scope_of
+    assert scope_of("jit(decode_step)/while/body/attn/state_update/x")[0] \
+        == ("attn", "state_update")
+    assert scope_of("jit(state_copy)/state_copy/dynamic_update_slice")[0] \
+        == ("state_copy",)

@@ -712,3 +712,156 @@ def test_latent_serve_step_fits_and_updates_the_pool_in_place(
     assert not [(op, n) for op, n in ops_in_units_of(
         text, one_layers_experts) if op not in ("parameter", "bitcast",
                                                  "get-tuple-element")]
+
+
+# ------------------------------------------------------------------ #
+# The retention family (PR 34): the state-update kernel at the published
+# head width, and the ENGINE's programs over the per-stream state pool at
+# the benchmark cell's shape
+# ------------------------------------------------------------------ #
+@pytest.mark.parametrize("streams", [32, 1])
+def test_state_update_kernel_compiles_at_the_published_widths(streams,
+                                                              one_chip,
+                                                              as_tpu):
+    from deepspeed_tpu.ops import power_retention as pr
+    nKV, nH, Dh, L, B = 8, 40, 128, 4, 8
+    tiles = dict(pr.state_tiles(nKV, Dh))
+    text = _compile(
+        lambda st, nm, layer, pages, q, k, v, lg: pr.state_update(
+            st, nm, layer, pages, q, k, v, lg, eps=1e-6), one_chip,
+        _sds((L, 1, B) + tiles["state"], jnp.float32),
+        _sds((L, 1, B) + tiles["norm"], jnp.float32), _sds((), jnp.int32),
+        _sds((1, streams), jnp.int32),
+        _sds((1, streams, nH, Dh), jnp.bfloat16),
+        _sds((1, streams, nKV, Dh), jnp.bfloat16),
+        _sds((1, streams, nKV, Dh), jnp.bfloat16),
+        _sds((1, streams, nKV), jnp.float32))
+    assert "_state_update_kernel" in text
+
+
+@pytest.fixture(scope="module")
+def retention_programs(topo):
+    """(spec, params bytes, {program: compiled}) of the engine's own step
+    builders for the retention cell, on an engine shell (see
+    ``_serve_program``)."""
+    import json
+    import os
+    from deepspeed_tpu.inference import kv_cache
+    from deepspeed_tpu.inference.engine import InferenceEngine
+    from deepspeed_tpu.inference.retention import RetentionServed
+    from deepspeed_tpu.models.brumby import BrumbyConfig, brumby_init
+    from jax.experimental.compilation_cache import compilation_cache
+    sizes = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "perfbench", "configs", "brumby-14b-base.json")))
+    inf = sizes["serve"]["inference"]
+    cfg = BrumbyConfig.from_hf(sizes)
+    served = RetentionServed(cfg)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+            tree)
+    params = on_chip(jax.eval_shape(lambda k: brumby_init(k, cfg),
+                                    jax.random.PRNGKey(0)))
+    param_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(params))
+    spec = kv_cache.PagedKVCacheSpec(
+        num_layers=served.cache_layers, num_slots=inf["max_slots"],
+        num_blocks=inf["num_blocks"], block_size=inf["block_size"],
+        max_len=inf["max_seq_len"], num_heads=served.cache_heads,
+        head_dim=served.cache_row_width, dtype=served.cache_dtype,
+        pools=served.cache_pools(inf["block_size"]), per_stream=True,
+        token_row_bytes=served.token_row_bytes)
+    eng = object.__new__(InferenceEngine)
+    eng.model_cfg, eng.dp, eng.sp, eng.mesh = cfg, 1, 1, None
+    eng.paged_kernel, eng.quantize = True, "none"
+    eng.prefill_chunk = inf["prefill_chunk"]
+    eng._cache_sh = {name: one for name in spec.pool_names}
+    S, J, C = spec.num_slots, spec.max_blocks_per_slot, eng.prefill_chunk
+    pools = [on_chip(jax.ShapeDtypeStruct(spec.pool_shapes[n], spec.dtype))
+             for n in spec.pool_names]
+    i32 = lambda *shape: on_chip(jax.ShapeDtypeStruct(shape, jnp.int32))
+    key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
+    temp = on_chip(jax.ShapeDtypeStruct((), jnp.float32))
+    mp = pytest.MonkeyPatch()
+    prev = jax.config.jax_enable_compilation_cache
+    out = {}
+    try:
+        mp.setattr(jax, "default_backend", lambda *a, **k: "tpu")
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        out["decode_step"] = eng._build_decode_step().lower(
+            params, *pools, i32(S), i32(S), i32(S, J), key, temp).compile()
+        out["prefill_step"] = eng._build_prefill_step().lower(
+            params, *pools, i32(1, C), i32(1, J), i32(1), i32(1), i32(1),
+            key, temp).compile()
+        out["state_copy"] = eng._build_state_copy().lower(
+            *pools, i32(1), i32(1)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+        mp.undo()
+    return spec, param_bytes, out
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_step",
+                                     "state_copy"])
+def test_retention_programs_fit_and_rewrite_the_state_pool_in_place(
+        retention_programs, program):
+    """Weights 5.75 GB + a 7.16 GB pool of 52 pages, a page one block wide,
+    the pools aliased to the outputs, scratch of a page or less: decode
+    hands the pool to the aliased kernel and to nothing else; prefill and
+    the page copy touch it through ONE in-place ``dynamic-update-slice``
+    each (never a select over the pool)."""
+    from deepspeed_tpu.analysis.hlo_text import ops_in_units_of
+    spec, param_bytes, programs = retention_programs
+    compiled = programs[program]
+    assert abs(param_bytes - 5.754e9) < 0.01e9
+    assert spec.max_blocks_per_slot == 1 and spec.page_tokens == 8400
+    assert spec.nbytes() == 52 * 4 * 8 * (8320 + 80) * 128 * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= spec.nbytes()
+    assert mem.temp_size_in_bytes < 160 * 2 ** 20, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    seen = ops_in_units_of(text, math.prod(spec.pool_shapes["state"][1:]))
+    in_place = {"dynamic-update-slice", "fusion"} \
+        if program != "decode_step" else set()
+    assert not [(op, n) for op, n in seen
+                if op not in _POOL_OPS_ALLOWED | in_place]
+    assert "select(" not in "".join(
+        l for l in text.splitlines() if "f32[4,1,52," in l.split(" = ")[0])
+    if program == "decode_step":
+        calls = [line for line in text.splitlines()
+                 if "%_state_update_kernel" in line.split(" = ")[0]
+                 and " custom-call(" in line]
+        assert calls and all("tpu_custom_call" in c for c in calls)
+
+
+@pytest.mark.parametrize("shape", [(1, 8, 5, 128), (512, 5, 128)])
+def test_pair_products_compile_alone_for_v5e(shape, one_chip,
+                                             no_persistent_cache):
+    """The feature map as a program of its own (what an eager call
+    makes): a plain ``jnp.roll`` by half the lanes over a group's five
+    heads aborts the TPU compiler; ``power_retention._rotated`` does
+    not."""
+    from deepspeed_tpu.ops import power_retention as pr
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    jax.jit(pr.pair_products).lower(x).compile()
+    jax.jit(pr.key_features).lower(x).compile()
+
+
+def test_pair_tensor_of_a_page_compiles_for_v5e(one_chip,
+                                                no_persistent_cache):
+    """What the benchmark reads a page through (a layer's state and
+    normaliser as held, at the published widths): two scatters into 129
+    faces of 128 x 128, well inside a page's bytes."""
+    from deepspeed_tpu.ops import power_retention as pr
+    tiles = dict(pr.state_tiles(8, 128))
+    S, z = (jax.ShapeDtypeStruct(tiles[n], jnp.float32, sharding=one_chip)
+            for n in ("state", "norm"))
+    compiled = jax.jit(pr.pair_tensor).lower(S, z).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == 8 * 129 * 128 * 128 * 4
+    assert mem.temp_size_in_bytes < 3 * mem.output_size_in_bytes
