@@ -13,14 +13,16 @@ from the saved logsumexp — the same memory story as the reference's
 
 One kernel family serves three modes via static specialization:
 - dense bidirectional (layout=None, causal=False)
-- causal (block-skip above the diagonal band)
+- causal (score tiles above the diagonal are skipped: by static row bands
+  inside the one-block kernels, by grid steps on the multi-block path)
 - block-sparse (an int32 layout [H, nQ, nK] gates each (q-block, k-block)
   pair — the splash-attention pattern; masked blocks skip their matmuls)
 
 Layout: kernels run over [BH, S, D] (batch×heads flattened, head_dim last).
 Grid is (BH, q_blocks, k_blocks); the innermost (k) dimension iterates
 sequentially on TPU so VMEM scratch carries the running softmax state
-across k-blocks of one q-block.
+across k-blocks of one q-block.  A sequence one block covers (S = 1024
+and below, `_pick_block`) is one grid step a head with no running state.
 """
 from __future__ import annotations
 
@@ -30,6 +32,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 
 try:  # TPU backend bits are importable everywhere; interpret=True runs on CPU
@@ -47,14 +51,64 @@ def _interpret() -> bool:
 import os
 
 _BLOCK_TARGET = int(os.environ.get("DS_FLASH_BLOCK", "1024"))
-# Backward block for CAUSAL kernels. The dq/dkv grids skip above-diagonal
-# blocks entirely, so finer blocks trade per-grid-step overhead for real
-# compute skipped; 512 measured best on v5e (gpt2-large bench sweep:
-# bwd 1024/512/256/128 -> 207.5/201.7/215.5/259.2 ms fwd+bwd). The forward
-# stays at DS_FLASH_BLOCK: it runs TWICE under remat and its per-step
-# overhead dominates the causal saving (fwd 512 -> +14 ms).
+# Which kernels a dense call runs.  A sequence `_pick_block` covers with
+# ONE block (S of 128, 256, 512 or DS_FLASH_BLOCK; every cell of the
+# benchmark: S = 1024) has no grid over positions: the forward is
+# `_fwd_kernel`'s whole-row body (``band`` > 0: one grid step a head, a
+# direct softmax) and the backward is `_bwd_fused_kernel`; both skip the
+# masked half of a causal square by static row bands (`_BAND`, below), not
+# by grid steps.  The multi-block grid path (running softmax in scratch,
+# `_run_pred` skipping whole blocks above the diagonal) and the split
+# `_bwd_dq_kernel` / `_bwd_dkv_kernel` serve a longer S (or one only
+# smaller blocks divide), a layout, or a non-default autotuned tile only.
+# DS_FLASH_BLOCK_BWD is the block of those SPLIT causal backward kernels
+# (finer blocks trade grid-step overhead for blocks skipped).  Its default
+# of 512 comes from a gpt2-large sweep on a v5e (commit 5ade0a3) from
+# before the fused backward was the default (bwd 1024/512/256/128 ->
+# 207.5/201.7/215.5/259.2 ms fwd+bwd; a forward at 512 cost +14 ms in grid
+# steps); no cell runs that path and it is unmeasured on this tree.
 # 0 = follow DS_FLASH_BLOCK.
 _BLOCK_TARGET_BWD = int(os.environ.get("DS_FLASH_BLOCK_BWD", "512"))
+
+# Rows of one band of the whole-sequence causal kernels.  Band i holds the
+# query rows [i*_BAND, (i+1)*_BAND) and reads the keys [0, (i+1)*_BAND)
+# only, so of T = S/_BAND bands' T*T score tiles T*(T+1)/2 are computed:
+# at S = 1024 a band of 128 / 256 / 512 computes 56.25 / 62.5 / 75% of the
+# square.  Chosen once on the chip (PERF.md section 6, PR 35); the choice
+# follows what the code can observe (``causal`` and S), nothing a user sets.
+_BAND = 256
+
+
+def _row_band(S: int, Sk: int, causal: bool) -> int:
+    """Rows a band when ONE block covers the sequence: `_BAND` for a causal
+    self-attention square of at least two bands, else S (one band: the
+    whole rectangle, every non-causal caller)."""
+    if causal and S == Sk and S % _BAND == 0 and S >= 2 * _BAND:
+        return _BAND
+    return S
+
+
+def computed_scores(S: int, Sk: int, causal: bool, blocks=None) -> int:
+    """Scores one head tile COMPUTES in each of the forward and the
+    backward (what the MXU and the vector unit pass over, masked or not):
+    the bands' rectangles where one block covers the sequence, else the
+    (bq, bk) blocks the grid does not skip."""
+    bq, bk = blocks or (_pick_block(S), _pick_block(Sk))
+    if (bq, bk) == (S, Sk):
+        band = _row_band(S, Sk, causal)
+        return sum(band * (r0 + band if band < S else Sk)
+                   for r0 in range(0, S, band))
+    return bq * bk * sum(1 for qi in range(S // bq) for kj in range(Sk // bk)
+                         if not causal or kj * bk < (qi + 1) * bq)
+
+
+def _cost(BH: int, D: int, scores: int, matmuls: int, operands):
+    """A call's `pl.CostEstimate`, for XLA's scheduler: ``matmuls`` MXU
+    passes and one exp a computed score, every operand moved once."""
+    return pl.CostEstimate(
+        flops=2 * matmuls * BH * scores * D, transcendentals=BH * scores,
+        bytes_accessed=sum(math.prod(x.shape) * jnp.dtype(x.dtype).itemsize
+                           for x in operands))
 
 
 def _pick_block(s: int, target: int = 0) -> int:
@@ -123,30 +177,38 @@ def _dropout_keep(seed, bh, qi, kj, bq: int, bk: int, rate: float,
     dropout masks replayed in backward (ops/transformer/transformer.py:
     330-466, csrc/transformer/dropout_kernels.cu).
     """
+    # Written in lax primitives: a kernel body is traced on every start, a
+    # `jnp` operator on a tracer costs ~0.3 ms of dispatch where the
+    # primitive costs 0.05, and the banded kernels call this once a band.
+    u32 = np.uint32
+
+    def position(dim, block, size):
+        at = lax.broadcasted_iota(jnp.uint32, shape, dim)
+        if isinstance(block, int):           # a static band: no add at 0
+            return lax.add(at, u32(block * size)) if block else at
+        return lax.add(at, lax.convert_element_type(
+            lax.mul(block, np.int32(size)), jnp.uint32))
     shape = (bk, bq) if transposed else (bq, bk)
-    rows = jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
-    cols = jax.lax.broadcasted_iota(jnp.uint32, shape, 1)
-    if transposed:
-        qpos = cols + jnp.uint32(qi * bq)
-        kpos = rows + jnp.uint32(kj * bk)
-    else:
-        qpos = rows + jnp.uint32(qi * bq)
-        kpos = cols + jnp.uint32(kj * bk)
+    qdim, kdim = (1, 0) if transposed else (0, 1)
+    qpos, kpos = position(qdim, qi, bq), position(kdim, kj, bk)
     # Element id mixed with the (seed, head) stream id; uint32 wraparound is
     # fine (stays deterministic).
-    stream = seed.astype(jnp.uint32) ^ (bh.astype(jnp.uint32) *
-                                        jnp.uint32(0x85EBCA6B))
-    x = qpos * jnp.uint32(0x9E3779B9) + kpos
-    x = x + stream
-    x = x ^ (x >> 16)
-    x = x * jnp.uint32(0x85EBCA6B)
-    x = x ^ (x >> 13)
-    x = x * jnp.uint32(0xC2B2AE35)
-    x = x ^ (x >> 16)
+    stream = lax.bitwise_xor(
+        lax.convert_element_type(seed, jnp.uint32),
+        lax.mul(lax.convert_element_type(bh, jnp.uint32), u32(0x85EBCA6B)))
+    x = lax.add(lax.add(lax.mul(qpos, u32(0x9E3779B9)), kpos), stream)
+    x = lax.bitwise_xor(x, lax.shift_right_logical(x, u32(16)))
+    x = lax.mul(x, u32(0x85EBCA6B))
+    x = lax.bitwise_xor(x, lax.shift_right_logical(x, u32(13)))
+    x = lax.mul(x, u32(0xC2B2AE35))
+    x = lax.bitwise_xor(x, lax.shift_right_logical(x, u32(16)))
     # keep iff uniform[0,1) >= rate. Mosaic has no uint32->f32 cast; use the
     # top 24 bits via int32 (exact in f32).
-    u = (x >> 8).astype(jnp.int32).astype(jnp.float32) * (1.0 / 16777216.0)
-    return u >= rate
+    top = lax.convert_element_type(lax.shift_right_logical(x, u32(8)),
+                                   jnp.int32)
+    u = lax.mul(lax.convert_element_type(top, jnp.float32),
+                np.float32(1.0 / 16777216.0))
+    return lax.ge(u, np.float32(rate))
 
 
 def _causal_mask(s, qi, kj, bq: int, bk: int, transposed: bool = False):
@@ -164,9 +226,40 @@ def _causal_mask(s, qi, kj, bq: int, bk: int, transposed: bool = False):
 # --------------------------------------------------------------------- #
 # Forward kernel
 # --------------------------------------------------------------------- #
+# The unrolled band bodies below are written in lax primitives for the
+# reason `_dropout_keep` gives: what they emit is what the `jnp` spelling
+# emitted, at a sixth of the tracing time a start.
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _keep_scaled(keep, x, dropout: float):
+    """x / (1 - dropout) where kept, 0 where dropped."""
+    return lax.select(keep, lax.mul(x, np.float32(1.0 / (1.0 - dropout))),
+                      lax.full_like(x, 0.0))
+
+
+def _causal_mask_band(s, r0: int):
+    """Causal mask of one row band: ``s`` [band, r0 + band] holds query rows
+    r0.. against keys 0.., so only its last [band, band] sub-tile crosses
+    the diagonal; the columns left of it are kept by construction."""
+    band, w = s.shape
+    below = lax.ge(lax.broadcasted_iota(jnp.int32, (band, 1), 0),
+                   lax.broadcasted_iota(jnp.int32, (1, band), 1))
+    tile = lax.slice_in_dim(s, r0, w, axis=1)
+    diag = lax.select(below, tile, lax.full_like(tile, NEG_INF))
+    if r0 == 0:
+        return diag
+    return lax.concatenate([lax.slice_in_dim(s, 0, r0, axis=1), diag], 1)
+
+
 def _fwd_kernel(*refs, scale: float, causal: bool, bq: int, bk: int,
-                has_layout: bool, dropout: float = 0.0,
-                single_k: bool = False):
+                has_layout: bool, dropout: float = 0.0, band: int = 0):
     if has_layout and dropout > 0.0:
         (layout_ref, seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
          m_scr, l_scr, acc_scr) = refs
@@ -181,28 +274,56 @@ def _fwd_kernel(*refs, scale: float, causal: bool, bq: int, bk: int,
     bh, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
 
-    if single_k:
-        # One k-block covers the whole row: no running-softmax state, no
-        # scratch round-trips — direct softmax + PV (saves several VPU
-        # passes; with S<=DS_FLASH_BLOCK this is the only fwd shape).
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, kj, bq, bk)
-        m = jnp.max(s, axis=1, keepdims=True)
-        p = jnp.exp(s - m)
-        l = jnp.sum(p, axis=1, keepdims=True)
-        if dropout > 0.0:
-            keep = _dropout_keep(seed_ref[0, 0], bh, qi, kj, bq, bk, dropout)
-            p = jnp.where(keep, p * (1.0 / (1.0 - dropout)), 0.0)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (pv / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = m[:, 0] + jnp.log(l_safe[:, 0])
+    if band:
+        # One k-block covers the whole row (S = 1024 and below): each
+        # band of query rows sees all of its keys at once, so no running
+        # softmax, no scratch round-trips — a direct softmax + PV, one grid
+        # step a head.  ``band`` < bq (`_row_band`: causal, the q-block is
+        # the whole square) unrolls static row bands that read the keys up
+        # to their own diagonal tile only; ``band`` == bq is one band over
+        # the whole [bq, bk] rectangle.
+        # Refs are [rows, D] here and lse [rows] (`_flash_fwd`: no head dim).
+        seed = seed_ref[0, 0] if dropout > 0.0 else None
+
+        def scores(r0):
+            w = r0 + band if band < bq else bk
+            s = lax.mul(_dot(q_ref[r0:r0 + band], k_ref[:w], _NT),
+                        np.float32(scale))
+            if causal:
+                s = _causal_mask_band(s, r0) if band < bq else \
+                    _causal_mask(s, qi, kj, bq, bk)
+            return s
+
+        def finish(r0, s):
+            w = s.shape[1]
+            v = v_ref[:w]
+            m = lax.expand_dims(lax.reduce_max(s, (1,)), (1,))
+            p = lax.exp(lax.sub(s, m))
+            l = lax.expand_dims(lax.reduce_sum(p, (1,)), (1,))
+            if dropout > 0.0:
+                # Under bands the grid is one step a head: (qi, kj) is
+                # (0, 0) and the tile starts at global (r0, 0).
+                at = (r0 // band, 0) if band < bq else (qi, kj)
+                keep = _dropout_keep(seed, bh, *at, band, w, dropout)
+                p = _keep_scaled(keep, p, dropout)
+            pv = _dot(lax.convert_element_type(p, v.dtype), v, _NN)
+            l_safe = lax.select(lax.eq(l, np.float32(0.0)),
+                                lax.full_like(l, 1.0), l)
+            o_ref[r0:r0 + band] = lax.convert_element_type(
+                lax.div(pv, l_safe), o_ref.dtype)
+            lse_ref[r0:r0 + band] = lax.add(
+                lax.squeeze(m, (1,)), lax.log(lax.squeeze(l_safe, (1,))))
+
+        # The row max is a barrier a band (all of s before any exp): issue
+        # each band's score matmul ahead of the band before's softmax and
+        # the MXU works through it (one band ahead measured best at this
+        # `_BAND`).  Widest band first, as the backward.
+        starts = range(0, bq, band)[::-1]
+        s = scores(starts[0])
+        for r0, ahead in zip(starts, [*starts[1:], None]):
+            s_ahead = None if ahead is None else scores(ahead)
+            finish(r0, s)
+            s = s_ahead
         return
 
     @pl.when(kj == 0)
@@ -295,10 +416,11 @@ def _layout_spec(num_heads: int, role: str):
                         lambda b, j, i: (b % num_heads, i // 8, j // 128))
 
 
-def _qkv_spec(blk: int, D: int, role: str):
+def _qkv_spec(blk: int, D: int, role: str, head=1):
     """Block spec for a q/k/v/do/dq/dk/dv operand over [BH, S, D] arrays.
     ``role``: 'q' indexes the q-block dim, 'k' the k-block dim; '*T'
     variants are for the dkv grid whose program ids are (bh, kj, qi).
+    ``head=None`` squeezes the head dim out of the kernel's ref ([blk, D]).
 
     NOTE a native-4D [B, S, nH, D] variant (per-head blocks (1, blk, 1, D)
     to skip the host-side transposes) was tried and REVERTED: Mosaic
@@ -308,7 +430,7 @@ def _qkv_spec(blk: int, D: int, role: str):
            "k": lambda b, i, j: (b, j, 0),
            "qT": lambda b, j, i: (b, i, 0),
            "kT": lambda b, j, i: (b, j, 0)}[role]
-    return pl.BlockSpec((1, blk, D), idx)
+    return pl.BlockSpec((head, blk, D), idx)
 
 
 def _flash_fwd(q, k, v, layout, scale: float, causal: bool,
@@ -333,15 +455,21 @@ def _flash_fwd(q, k, v, layout, scale: float, causal: bool,
             "flash_fwd", q, k, causal,
             (_pick_block(S), _pick_block(Sk)), run_at)
     grid = (BH, S // bq, Sk // bk)
+    # One k-block: the whole-row body, in `_row_band` row bands where the
+    # one q-block is the causal square too.
+    band = 0 if has_layout or bk != Sk else \
+        _row_band(S, Sk, causal) if bq == S else bq
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                bq=bq, bk=bk, has_layout=has_layout,
-                               dropout=dropout,
-                               single_k=(Sk // bk == 1 and not has_layout))
+                               dropout=dropout, band=band)
+    # The band path sees [rows, D] refs (no head dim: it slices rows a band
+    # and an int index is five times a slice's tracing cost).
+    head = None if band else 1
     in_specs = [
-        _qkv_spec(bq, D, "q"),
-        _qkv_spec(bk, D, "k"),
-        _qkv_spec(bk, D, "k"),
+        _qkv_spec(bq, D, "q", head),
+        _qkv_spec(bk, D, "k", head),
+        _qkv_spec(bk, D, "k", head),
     ]
     args = (q, k, v)
     if dropout > 0.0:
@@ -355,8 +483,8 @@ def _flash_fwd(q, k, v, layout, scale: float, causal: bool,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            _qkv_spec(bq, D, "q"),
-            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
+            _qkv_spec(bq, D, "q", head),
+            pl.BlockSpec((head, head, bq), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, S, D), q.dtype),
@@ -367,6 +495,8 @@ def _flash_fwd(q, k, v, layout, scale: float, causal: bool,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
+        cost_estimate=None if has_layout else _cost(
+            BH, D, computed_scores(S, Sk, causal, (bq, bk)), 2, (q, k, v, q)),
         name="_fwd_kernel",
         interpret=_interpret(),
     )(*args)
@@ -480,53 +610,70 @@ def _bwd_dkv_kernel(*refs, scale: float, causal: bool, bq: int, bk: int,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd_fused_kernel(*refs, scale: float, causal: bool, S: int,
+def _bwd_fused_kernel(*refs, scale: float, causal: bool, S: int, band: int,
                       dropout: float = 0.0):
     """Whole-sequence fused backward: when one block covers S, compute the
     score/softmax replay ONCE and emit dq, dk, dv together — the split
     dq/dkv kernels each redo the s/p/exp work in their own iteration
-    order (6 matmuls + 2 softmax replays vs 5 + 1 here)."""
+    order (6 matmuls + 2 softmax replays vs 5 + 1 here).
+
+    ``band`` < S (`_row_band`: causal) unrolls static row bands as the
+    forward does: band r0 replays [band, r0 + band] scores, writes its dq
+    rows once and adds its dk / dv rows [0, r0 + band) into float32
+    scratch, cast once at the end.  ``band`` == S is one band over the whole
+    square and writes all three directly."""
     refs = list(refs)
     seed_ref = refs.pop(0) if dropout > 0.0 else None
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-     dq_ref, dk_ref, dv_ref) = refs
+     dq_ref, dk_ref, dv_ref) = refs[:9]
     bh = pl.program_id(0)
-    q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-    lse = lse_ref[0, 0][:, None]                       # [S, 1]
-    delta = delta_ref[0, 0][:, None]                   # [S, 1]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale    # [S, S]
-    if causal:
-        s = _causal_mask(s, 0, 0, S, S)
-    p = jnp.exp(s - lse)                               # softmax replay
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)            # [S, S]
-    if dropout > 0.0:
-        keep = _dropout_keep(seed_ref[0, 0], bh, 0, 0, S, S, dropout)
-        inv = 1.0 / (1.0 - dropout)
-        p_drop = jnp.where(keep, p * inv, 0.0)
-        dp = jnp.where(keep, dp * inv, 0.0)
-    else:
-        p_drop = p
-    dv_ref[0] = jax.lax.dot_general(
-        p_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dv_ref.dtype)
-    ds = p * (dp - delta) * scale                      # [S, S]
-    dsc = ds.astype(q.dtype)
-    dq_ref[0] = jax.lax.dot_general(
-        dsc, k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-    dk_ref[0] = jax.lax.dot_general(
-        dsc, q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dk_ref.dtype)
+    seed = seed_ref[0, 0] if dropout > 0.0 else None
+    # Widest band first: it covers every key row, so it ASSIGNS the
+    # accumulators and nothing has to zero them.
+    for r0 in reversed(range(0, S, band)):
+        w = r0 + band
+        q, do = q_ref[r0:w], do_ref[r0:w]
+        k, v = k_ref[:w], v_ref[:w]
+        lse = lax.expand_dims(lse_ref[r0:w], (1,))             # [band, 1]
+        delta = lax.expand_dims(delta_ref[r0:w], (1,))         # [band, 1]
+        s = lax.mul(_dot(q, k, _NT), np.float32(scale))        # [band, w]
+        if causal:
+            s = _causal_mask_band(s, r0)
+        p = lax.exp(lax.sub(s, lse))                           # the replay
+        dp = _dot(do, v, _NT)                                  # [band, w]
+        if dropout > 0.0:
+            keep = _dropout_keep(seed, bh, r0 // band, 0, band, w, dropout)
+            p_drop = _keep_scaled(keep, p, dropout)
+            dp = _keep_scaled(keep, dp, dropout)
+        else:
+            p_drop = p
+        dv = _dot(lax.convert_element_type(p_drop, do.dtype), do, _TN)
+        ds = lax.mul(lax.mul(p, lax.sub(dp, delta)), np.float32(scale))
+        dsc = lax.convert_element_type(ds, q.dtype)
+        dq_ref[r0:w] = lax.convert_element_type(_dot(dsc, k, _NN),
+                                                dq_ref.dtype)
+        dk = _dot(dsc, q, _TN)                                 # [w, D]
+        if band == S:
+            dk_ref[:] = lax.convert_element_type(dk, dk_ref.dtype)
+            dv_ref[:] = lax.convert_element_type(dv, dv_ref.dtype)
+            return
+        dk_scr, dv_scr = refs[9:]
+        if w == S:
+            dk_scr[:] = dk
+            dv_scr[:] = dv
+        else:
+            dk_scr[:w] = lax.add(dk_scr[:w], dk)
+            dv_scr[:w] = lax.add(dv_scr[:w], dv)
+    dk_ref[:] = lax.convert_element_type(dk_scr[:], dk_ref.dtype)
+    dv_ref[:] = lax.convert_element_type(dv_scr[:], dv_ref.dtype)
 
 
 def _flash_bwd_fused(q, k, v, lse, do, delta, scale, causal, dropout, seed):
     BH, S, D = q.shape
-    full = pl.BlockSpec((1, S, D), lambda b: (b, 0, 0))
-    row = pl.BlockSpec((1, 1, S), lambda b: (b, 0, 0))
+    band = _row_band(S, S, causal)
+    # No head dim in the kernel's refs: [S, D] and, for lse / delta, [S].
+    full = pl.BlockSpec((None, S, D), lambda b: (b, 0, 0))
+    row = pl.BlockSpec((None, None, S), lambda b: (b, 0, 0))
     in_specs = [full, full, full, full, row, row]
     args = (q, k, v, do, lse, delta)
     if dropout > 0.0:
@@ -534,13 +681,17 @@ def _flash_bwd_fused(q, k, v, lse, do, delta, scale, causal, dropout, seed):
         args = (_seed_arr(seed),) + args
     return pl.pallas_call(
         functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
-                          S=S, dropout=dropout),
+                          S=S, dropout=dropout, band=band),
         grid=(BH,),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, S, D), lambda b: (b, 0, 0))] * 3,
+        out_specs=[full] * 3,
         out_shape=[jax.ShapeDtypeStruct((BH, S, D), q.dtype),
                    jax.ShapeDtypeStruct((BH, S, D), k.dtype),
                    jax.ShapeDtypeStruct((BH, S, D), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)] * 2
+        if band < S else [],
+        cost_estimate=_cost(BH, D, computed_scores(S, S, causal, (S, S)), 5,
+                            (q, k, v, do, q, k, v)),
         name="_bwd_fused_kernel",
         interpret=_interpret(),
     )(*args)

@@ -238,11 +238,13 @@ def serve_annotations(serve_engine, tmp_path_factory):
                                  vocab_size=CFG.vocab_size))
     # two requests at once (the second arrival late enough for an idle
     # sleep), 3 tokens each: one prefill token + two decode iterations
-    # for the first batch, then the same for the late one.
+    # for the first batch, then the same for the late one.  The first
+    # batch takes ~30 ms alone; at a 0.3 s gap two whole six-worker runs
+    # found no idle span (a batch that overruns the gap never idles).
     reqs = [Request(rid=100 + i, max_new_tokens=3, arrival_s=a,
                     prompt=rng.integers(0, CFG.vocab_size, 12,
                                         dtype=np.int32))
-            for i, a in enumerate((0.0, 0.0, 0.3))]
+            for i, a in enumerate((0.0, 0.0, 2.0))]
     eng.reset_serving_stats()
     report = {}
     found = _session(tmp_path_factory.mktemp("serve_prof"),

@@ -98,13 +98,18 @@ def _grad_of(fn, argnums):
 # Kernel cases: name -> (function, shapes).  Built lazily (inside the
 # test) so nothing touches jax at collection time.
 # ------------------------------------------------------------------ #
-def _case_flash(bwd):
+def _case_flash(bwd, mbs=MBS, nh=NH, dropout=0.0):
+    """Causal flash at a train cell's tile.  With ``dropout`` the call is
+    the cells' own (attn_pdrop 0.1, a traced rng): the row-banded bodies
+    with the keep-mask hash in them."""
     from deepspeed_tpu.ops.flash_attention import flash_attention
-    fn = functools.partial(flash_attention, causal=True)
-    if bwd:
-        fn = _grad_of(fn, (0, 1, 2))
-    x = _sds((MBS, S, NH, D), jnp.bfloat16)
-    return fn, (x, x, x)
+
+    def fn(q, k, v, rng):
+        return flash_attention(q, k, v, causal=True, attn_dropout=dropout,
+                               rng=rng, deterministic=not dropout)
+    x = _sds((mbs, S, nh, D), jnp.bfloat16)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    return (_grad_of(fn, (0, 1, 2)) if bwd else fn), (x, x, x, key)
 
 
 def _case_ln(bwd):
@@ -213,9 +218,18 @@ def _case_sparse(_):
     return fn, (x, x, x)
 
 
+# What the two train cells run in every layer: micro-batch x heads of
+# 1024 x 64, attn_pdrop 0.1.
+_FLASH_LARGE = functools.partial(_case_flash, mbs=4, nh=20, dropout=0.1)
+_FLASH_MEDIUM = functools.partial(_case_flash, mbs=8, nh=16, dropout=0.1)
+
 CASES = {
     "flash_fwd": (_case_flash, False),
     "flash_bwd": (_case_flash, True),
+    "flash_fwd_dropout_gpt2_large": (_FLASH_LARGE, False),
+    "flash_bwd_dropout_gpt2_large": (_FLASH_LARGE, True),
+    "flash_fwd_dropout_gpt2_medium": (_FLASH_MEDIUM, False),
+    "flash_bwd_dropout_gpt2_medium": (_FLASH_MEDIUM, True),
     "fused_ln_fwd": (_case_ln, False),
     "fused_ln_bwd": (_case_ln, True),
     "fused_residual_ln_fwd": (_case_resid_ln, False),
