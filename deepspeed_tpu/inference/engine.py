@@ -40,7 +40,6 @@ Architecture (mirrors the training engine's discipline):
 from __future__ import annotations
 
 import os
-import time
 import weakref
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
@@ -591,7 +590,7 @@ class InferenceEngine:
                 [(slot, prompt, int(max_new_tokens or 0))], temperature,
                 return_logits=return_logits,
                 rids=None if rid is None else [rid])[0]
-        t0 = time.perf_counter()
+        t0 = self.serving.lap("admit_s")
         tl = self.telemetry
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         plen = int(prompt.shape[0])
@@ -603,7 +602,7 @@ class InferenceEngine:
                 f"{self.max_len}-token slot")
         n_ctr = len(self.served.counter_names)
         with tl.span("prefill", slots=1, prompt_tokens=plen,
-                     cached_tokens=0, chunks=1,
+                     cached_tokens=0, chunks=1, rows_computed=self.max_len,
                      rids=ids_arg(None if rid is None else [rid])) as span:
             padded = np.zeros(self.max_len, np.int32)
             padded[:plen] = prompt
@@ -636,8 +635,9 @@ class InferenceEngine:
                     np.asarray(jax.device_get(tok)).reshape(-1), n_ctr)
                 tok = int(tok[0])
             self._note_counters(span, counters)
+        wall = self.serving.note_prefill_pass(1, plen, self.max_len) - t0
         if self.serving.ledger is not None:
-            self.serving.ledger.note("prefill", time.perf_counter() - t0)
+            self.serving.ledger.note("prefill", wall)
         return tok, out_logits
 
     def prefill_many(self, admissions: Sequence[Tuple[int, Any, int]],
@@ -661,13 +661,14 @@ class InferenceEngine:
         Host spans: ``prefill`` (args ``slots``, ``prompt_tokens``,
         ``rids`` — the scheduler's request ids, so a request can be
         followed through a trace — and, once planned, ``cached_tokens``,
-        ``chunks``) > ``prefill_plan`` (allocator admission + the
+        ``chunks``, ``rows_computed``: the ``[G, chunk]`` rows of every
+        chunk program) > ``prefill_plan`` (allocator admission + the
         copy-on-write fork), one ``prefill_chunk`` (``ci``,
         ``active_groups``) per chunk program dispatched, and
         ``prefill_fetch`` (the first tokens' ``device_get``)."""
         if self.prefill_chunk == 0:
             raise RuntimeError("prefill_many needs chunked prefill")
-        t_pf0 = time.perf_counter()
+        t_pf0 = self.serving.lap("admit_s")
         tl = self.telemetry
         with tl.span("prefill", slots=len(admissions),
                      prompt_tokens=sum(len(p) for _, p, _ in admissions),
@@ -699,14 +700,16 @@ class InferenceEngine:
                         self.drafter.begin(slot, prompt)
                     self.serving.note_admit(plen, plan.matched)
                     out.append((tok, logits))
-            span.set_metadata(
-                cached_tokens=sum(int(p[2].matched) for p in plans),
-                chunks=len(steps))
+            cached = sum(int(p[2].matched) for p in plans)
+            computed = len(steps) * self.dp * self.prefill_chunk
+            span.set_metadata(cached_tokens=cached, chunks=len(steps),
+                              rows_computed=computed)
             if self.cache_spec.per_stream:
                 span.set_metadata(**self._note_state_admissions(plans))
+        wall = self.serving.note_prefill_pass(
+            len(steps), sum(p[4] for p in plans) - cached, computed) - t_pf0
         if self.serving.ledger is not None:
-            self.serving.ledger.note("prefill",
-                                     time.perf_counter() - t_pf0)
+            self.serving.ledger.note("prefill", wall)
         return out
 
     def _copy_blocks(self, pools, pairs):
@@ -722,7 +725,10 @@ class InferenceEngine:
             src, dst = np.zeros((G, B), np.float32), np.zeros((G, B), bool)
             for g, (s, d) in pairs.items():
                 src[g, s], dst[g, d] = 1.0, True
-        return self._copy_fn(*pools, src, dst)
+        self.serving.lap("prefill_s")
+        pools = self._copy_fn(*pools, src, dst)
+        self.serving.lap("copy_s")       # the host's part: the dispatch
+        return pools
 
     def _plan_prefill(self, admissions):
         """prefill_many's ``prefill_plan``: admit every prompt through
@@ -907,14 +913,20 @@ class InferenceEngine:
         by k_rows); one-hot terms are structural: every slot stream
         scores the whole pool and each dp group streams its full pool
         per layer, occupancy notwithstanding. Projections — host
-        arithmetic, no device work."""
+        arithmetic, no device work. A served model's cost grows by the
+        same amount with every block in reach (or not at all: a state),
+        so the live slots' sum is one expression over their lengths."""
         sp_ = self.cache_spec
-        live = [self._attend_cost(context=max(1, int(c)))
-                for c in self.lengths[self.active]]
+        bs = sp_.block_size
+        f1, b1 = self._attend_cost(context=bs)
+        f2, b2 = self._attend_cost(context=2 * bs)
+        blocks = -(-np.maximum(self.lengths[self.active], 1) // bs)
+        n, reach = int(blocks.size), int(blocks.sum())
         pool = self._attend_cost(pool_blocks=sp_.blocks_per_group)
-        return (sum(f for f, _ in live) * k_rows,
+        return ((n * (2 * f1 - f2) + reach * (f2 - f1)) * k_rows,
                 pool[0] * k_rows * self.max_slots,
-                sum(b for _, b in live), pool[1] * sp_.num_groups)
+                n * (2 * b1 - b2) + reach * (b2 - b1),
+                pool[1] * sp_.num_groups)
 
     def _note_counters(self, span, counters) -> None:
         """The served model's counters of the execution(s) just fetched
@@ -938,7 +950,8 @@ class InferenceEngine:
         counters just don't advance). Returns the sampled token per slot
         (and the [S, V] logits when asked — tests only; the extra fetch
         is not part of the serving loop)."""
-        t0 = time.perf_counter()
+        lap = self.serving.lap       # the timeline's clock
+        t0 = lap("other_s")
         tl = self.telemetry
         tl.profiler_tick(self.iterations)
         n_active = self.active_slots
@@ -948,6 +961,7 @@ class InferenceEngine:
                 for s in np.flatnonzero(self.active):
                     self._ensure_blocks(int(s), int(self.lengths[s]))
                 steps = self._attend_steps(1)
+            lap("tables_s")
             with tl.span("decode_dispatch"):
                 *pools, sampled, logits = self._decode_fn(
                     self._params, *self._pools(),
@@ -955,6 +969,7 @@ class InferenceEngine:
                     self._next_key(), np.float32(temperature))
                 self._store_pools(pools)
                 tl.raise_pending()
+            lap("dispatch_s")
             # THE serving sync: the host needs the tokens (EOS detection
             # + next step's inputs). One batched [S] fetch per iteration
             # (the served model's counters, if it has any, ride it).
@@ -962,6 +977,7 @@ class InferenceEngine:
                 sampled, counters = split_counters(
                     np.asarray(jax.device_get(sampled)),
                     len(self.served.counter_names))
+            lap("fetch_s")
             with tl.span("decode_advance"):
                 adv = self.active
                 self.lengths[adv] += 1
@@ -969,19 +985,19 @@ class InferenceEngine:
                 if self.drafter is not None:
                     for s in np.flatnonzero(adv):
                         self.drafter.observe(int(s), [int(sampled[s])])
-                wall = time.perf_counter() - t0
+                wall = lap("advance_s") - t0
                 self.iterations += 1
                 live_blocks, cache_bytes, ctx_tokens = \
                     self._cache_accounting()
                 self.serving.note_attend_steps(*steps)
+                if n_active:
+                    self.serving.note_attend(*self._attend_work(1),
+                                             n_active)
                 self.serving.note_iteration(n_active, wall,
                                             cache_bytes=cache_bytes,
                                             context_tokens=ctx_tokens)
                 if self.serving.ledger is not None:
                     self.serving.ledger.note("decode_useful", wall)
-                if n_active:
-                    self.serving.note_attend(*self._attend_work(1),
-                                             n_active)
                 if tl.enabled:
                     tl.record_step(
                         self.iterations, {}, wall_ms=wall * 1e3,
@@ -1022,7 +1038,8 @@ class InferenceEngine:
                 "has no rejection-sampling correction); use "
                 "decode_once for temperature > 0 — the scheduler falls "
                 "back automatically")
-        t0 = time.perf_counter()
+        lap = self.serving.lap       # the timeline's clock
+        t0 = lap("other_s")
         tl = self.telemetry
         tl.profiler_tick(self.iterations)
         k = self.spec_k
@@ -1039,6 +1056,7 @@ class InferenceEngine:
                     self._ensure_blocks(
                         s, min(int(self.lengths[s]) + k, self.max_len - 1))
                 steps = self._attend_steps(k + 1)
+            lap("tables_s")
             with tl.span("decode_dispatch"):
                 *pools, out, logits = self._verify_fn(
                     self._params, *self._pools(), toks,
@@ -1046,8 +1064,10 @@ class InferenceEngine:
                     np.float32(temperature))
                 self._store_pools(pools)
                 tl.raise_pending()
+            lap("dispatch_s")
             with tl.span("decode_fetch"):
                 out = np.asarray(jax.device_get(out))    # [S, k+2]
+            lap("fetch_s")
             with tl.span("decode_advance"):
                 n_new = out[:, 0].copy()
                 emitted = out[:, 1:]
@@ -1067,11 +1087,14 @@ class InferenceEngine:
                 emitted_total = int(n_new.sum())
                 self._spec_proposed += k * len(live)
                 self._spec_accepted += accepted
-                wall = time.perf_counter() - t0
+                wall = lap("advance_s") - t0
                 self.iterations += 1
                 live_blocks, cache_bytes, ctx_tokens = \
                     self._cache_accounting()
                 self.serving.note_attend_steps(*steps)
+                if n_active and emitted_total:
+                    self.serving.note_attend(*self._attend_work(k + 1),
+                                             emitted_total)
                 self.serving.note_iteration(n_active, wall,
                                             cache_bytes=cache_bytes,
                                             context_tokens=ctx_tokens,
@@ -1088,9 +1111,6 @@ class InferenceEngine:
                     self.serving.ledger.note("spec_wasted", wasted)
                     self.serving.ledger.note("decode_useful",
                                              wall - wasted)
-                if n_active and emitted_total:
-                    self.serving.note_attend(*self._attend_work(k + 1),
-                                             emitted_total)
                 self.serving.note_spec(k * len(live), accepted)
                 if tl.enabled:
                     tl.record_step(
@@ -1122,7 +1142,8 @@ class InferenceEngine:
         pass so compile time never pollutes the measured TTFT/TPOT
         stream — both sides of a comparison warm the same way)."""
         self.serving = ServingAggregator(self.max_slots,
-                                         label=self.replica or None)
+                                         label=self.replica or None,
+                                         clock=self.serving.clock)
         self.serving.attend_mode = ("kernel" if self.paged_kernel
                                     else "onehot")
         self._attach_slo_overlays()

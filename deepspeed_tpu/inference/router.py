@@ -119,6 +119,8 @@ class ReplicaRouter:
         per-request records (each naming its replica)."""
         n_rep = len(self.engines)
         t0 = time.perf_counter()
+        for eng in self.engines:     # each replica's ``stalls`` from here
+            eng.serving.note_serve_start()
         pending = deque(sorted(requests, key=lambda r: r.arrival_s))
         queues: List[deque] = [deque() for _ in range(n_rep)]
         active: List[Dict[int, Request]] = [{} for _ in range(n_rep)]
@@ -149,6 +151,8 @@ class ReplicaRouter:
             return eng.context_len(slot) >= eng.max_len
 
         def complete(req: Request, eng) -> None:
+            if req.row_first >= 0:       # the latest row is its last
+                req.row_last = eng.serving.rows - 1
             eng.complete_request(req.rid, req.ttft_s or 0.0, req.tpot_s,
                                  prompt_tokens=len(req.prompt),
                                  new_tokens=len(req.out_tokens),
@@ -167,6 +171,8 @@ class ReplicaRouter:
                     abort = getattr(eng, "abort_request", None)
                     for slot in list(active[i]):
                         req = active[i][slot]
+                        if req.row_first >= 0:
+                            req.row_last = eng.serving.rows - 1
                         if trace is not None:
                             trace.abort(req.rid, "max_wall", t=t_ab,
                                         telemetry=eng.telemetry)
@@ -241,7 +247,8 @@ class ReplicaRouter:
                             req.prompt, slot, self.temperature,
                             max_new_tokens=req.max_new_tokens,
                             rid=req.rid) for req, slot in batch]
-                    t_now = time.perf_counter()
+                    # the clock of the timeline the first tokens join
+                    t_now = eng.serving.clock()
                     for (req, slot), (tok, _) in zip(batch, results):
                         req.slot = slot
                         req.t_first = req.t_last = t_now
@@ -266,6 +273,11 @@ class ReplicaRouter:
                             complete(req, eng)
                             eng.release_slot(slot)
                         else:
+                            # From here it emits in every row of its
+                            # replica's timeline (monitor/serving.py).
+                            req.timeline = eng.serving
+                            req.row_first = eng.serving.rows
+                            eng.serving.note_first_token(t_now)
                             active[i][slot] = req
                 # 3. one iteration for this replica's live slots.
                 if not active[i]:
@@ -274,8 +286,8 @@ class ReplicaRouter:
                 if spec[i]:
                     emitted, n_new = eng.spec_decode_once(
                         self.temperature)
-                    t_now = time.perf_counter()
                     occ = len(active[i])
+                    t_now, row = eng.serving.note_emit(occ)
                     for slot in list(active[i]):
                         req = active[i][slot]
                         budget = req.max_new_tokens - len(req.out_tokens)
@@ -289,21 +301,21 @@ class ReplicaRouter:
                         if trace is not None:
                             trace.tick(req.rid, occ, n, t=t_now,
                                        proposed=eng.spec_k,
-                                       accepted=max(n - 1, 0))
+                                       accepted=max(n - 1, 0), row=row)
                         if finished(req, eng, slot):
                             complete(req, eng)
                             eng.release_slot(slot)
                             del active[i][slot]
                 else:
                     sampled, _ = eng.decode_once(self.temperature)
-                    t_now = time.perf_counter()
                     occ = len(active[i])
+                    t_now, row = eng.serving.note_emit(occ)
                     for slot in list(active[i]):
                         req = active[i][slot]
                         req.out_tokens.append(int(sampled[slot]))
                         req.t_last = t_now
                         if trace is not None:
-                            trace.tick(req.rid, occ, 1, t=t_now)
+                            trace.tick(req.rid, occ, 1, t=t_now, row=row)
                         if finished(req, eng, slot):
                             complete(req, eng)
                             eng.release_slot(slot)

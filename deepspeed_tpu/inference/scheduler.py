@@ -24,7 +24,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..monitor.telemetry import ids_arg
+from ..monitor.telemetry import ids_arg, spans_recorded
+from ..utils.logging import logger
 
 
 @dataclasses.dataclass
@@ -42,6 +43,13 @@ class Request:
     t_first: Optional[float] = None     # first token produced (TTFT end)
     t_last: Optional[float] = None      # latest token produced
     admission_attempts: int = 0         # head-of-queue rejections
+    # Rows of the serving timeline (monitor/serving.py) this request was
+    # live in: every live stream emits in every row between them
+    # (``row_last`` stays -1 while it is).
+    row_first: int = -1
+    row_last: int = -1
+    timeline: Any = dataclasses.field(default=None, repr=False,
+                                      compare=False)
 
     @property
     def ttft_s(self) -> Optional[float]:
@@ -71,6 +79,19 @@ class Request:
                 or len(self.out_tokens) < 2:
             return None
         return (self.t_last - self.t_first) / (len(self.out_tokens) - 1)
+
+    def token_times(self) -> np.ndarray:
+        """The host clock at each delivery of tokens to this request:
+        ``t_first`` (out of its prefill), then the emission of every
+        row it was live in (one token a row; under speculative decoding
+        the 1..k+1 tokens of a row arrive together).  Rows the ring no
+        longer holds are left out."""
+        if self.t_first is None:
+            return np.zeros(0)
+        if self.row_first < 0:
+            return np.array([self.t_first])
+        return np.concatenate([[self.t_first], self.timeline.t_emit(
+            self.row_first, self.row_last)])
 
 
 def synthetic_requests(n: int, prompt_len: Tuple[int, int] = (8, 16),
@@ -157,7 +178,13 @@ class ContinuousBatchingScheduler:
         # Slot full: the next decode would have nowhere to write.
         return slot_len >= self.engine.max_len
 
+    def _leave_rows(self, req: Request) -> None:
+        """The latest row is the last this request emitted in."""
+        if req.row_first >= 0:
+            req.row_last = self.engine.serving.rows - 1
+
     def _complete(self, req: Request) -> None:
+        self._leave_rows(req)
         self.engine.complete_request(
             req.rid, req.ttft_s or 0.0, req.tpot_s,
             prompt_tokens=len(req.prompt),
@@ -183,6 +210,26 @@ class ContinuousBatchingScheduler:
             note(req.rid, reason, req.admission_attempts, queue_len)
         return reason
 
+    def _activate(self, req: Request, slot: int, tok: int, t_first: float,
+                  active: Dict[int, Request]) -> None:
+        """A prefilled request takes its slot (or is done with its first
+        token): from here it emits in every row of the timeline."""
+        eng = self.engine
+        req.slot = slot
+        req.t_first = req.t_last = t_first
+        req.out_tokens = [tok]
+        eng.activate_slot(slot, len(req.prompt), tok)
+        eng.serving.note_prefill(len(req.prompt))
+        self._admit_trace(req, slot)
+        if self._finished(req, eng.context_len(slot)):
+            self._complete(req)
+            eng.release_slot(slot)
+        else:
+            req.timeline = eng.serving
+            req.row_first = eng.serving.rows     # the next to be written
+            eng.serving.note_first_token(req.t_first)
+            active[slot] = req
+
     def _admit_trace(self, req: Request, slot: int) -> None:
         if self.trace is None:
             return
@@ -204,9 +251,11 @@ class ContinuousBatchingScheduler:
         (the aggregator snapshot + per-request records)."""
         eng = self.engine
         tel = eng.telemetry
-        t0 = time.perf_counter()
+        agg = eng.serving
+        clock = agg.clock
+        t0 = agg.note_serve_start()
         trace = self.trace
-        ledger = getattr(eng.serving, "ledger", None)
+        ledger = getattr(agg, "ledger", None)
         pending = deque(sorted(requests, key=lambda r: r.arrival_s))
         queue: deque = deque()
         active: Dict[int, Request] = {}
@@ -218,15 +267,16 @@ class ContinuousBatchingScheduler:
             self.temperature == 0.0
 
         while pending or queue or active:
-            now = time.perf_counter() - t0
+            now = clock() - t0
             if self.max_wall_s is not None and now > self.max_wall_s:
                 # Abandon the run WITHOUT leaking capacity: mid-flight
                 # slots must come back, or the engine's next serve()
                 # starts with no free slots and spins forever.
                 abort = getattr(eng, "abort_request", None)
-                t_ab = time.perf_counter()
+                t_ab = clock()
                 for slot in list(active):
                     req = active[slot]
+                    self._leave_rows(req)
                     if trace is not None:
                         trace.abort(req.rid, "max_wall", t=t_ab,
                                     telemetry=eng.telemetry)
@@ -262,6 +312,9 @@ class ContinuousBatchingScheduler:
             # one-slot-per-group BATCHES (engine.prefill_many): a full
             # batch prefills G admissions for one admission's wall.
             batched = eng.prefill_chunk > 0
+            admitting = bool(queue)
+            if admitting:
+                agg.lap("other_s")
             while queue:
                 if batched:
                     with tel.span("admit", queued=len(queue),
@@ -284,7 +337,7 @@ class ContinuousBatchingScheduler:
                                                             len(queue))
                                 break
                             queue.popleft()
-                            req.t_admit = time.perf_counter()
+                            req.t_admit = clock()
                             used.add(eng.group_of(slot))
                             batch.append((req, slot))
                         rids = [req.rid for req, _ in batch]
@@ -297,19 +350,9 @@ class ContinuousBatchingScheduler:
                         [(slot, req.prompt, req.max_new_tokens)
                          for req, slot in batch], self.temperature,
                         rids=rids)
-                    t_now = time.perf_counter()
+                    t_now = clock()
                     for (req, slot), (tok, _) in zip(batch, results):
-                        req.slot = slot
-                        req.t_first = req.t_last = t_now
-                        req.out_tokens = [tok]
-                        eng.activate_slot(slot, len(req.prompt), tok)
-                        eng.serving.note_prefill(len(req.prompt))
-                        self._admit_trace(req, slot)
-                        if self._finished(req, eng.context_len(slot)):
-                            self._complete(req)
-                            eng.release_slot(slot)
-                        else:
-                            active[slot] = req
+                        self._activate(req, slot, tok, t_now, active)
                     continue
                 req = queue[0]
                 with tel.span("admit", queued=len(queue),
@@ -324,28 +367,20 @@ class ContinuousBatchingScheduler:
                 if slot is None:
                     break
                 queue.popleft()
-                req.t_admit = time.perf_counter()
+                req.t_admit = clock()
                 tok, _ = eng.prefill(
                     req.prompt, slot, self.temperature,
                     max_new_tokens=req.max_new_tokens, rid=req.rid)
-                req.slot = slot
-                req.t_first = req.t_last = time.perf_counter()
-                req.out_tokens = [tok]
-                eng.activate_slot(slot, len(req.prompt), tok)
-                eng.serving.note_prefill(len(req.prompt))
-                self._admit_trace(req, slot)
-                if self._finished(req, eng.context_len(slot)):
-                    self._complete(req)
-                    eng.release_slot(slot)
-                else:
-                    active[slot] = req
+                self._activate(req, slot, tok, clock(), active)
+            if admitting:
+                agg.lap("admit_s")
             # 3. one decode (or draft-then-verify) iteration for every
             # live slot.
             if active and spec:
                 emitted, n_new = eng.spec_decode_once(self.temperature)
                 with tel.span("emit") as span:
-                    t_now = time.perf_counter()
                     occ = len(active)
+                    t_now, row = agg.note_emit(occ)
                     finished = []
                     for slot in list(active):
                         req = active[slot]
@@ -360,44 +395,50 @@ class ContinuousBatchingScheduler:
                         if trace is not None:
                             trace.tick(req.rid, occ, n, t=t_now,
                                        proposed=eng.spec_k,
-                                       accepted=max(n - 1, 0))
+                                       accepted=max(n - 1, 0), row=row)
                         if self._finished(req, eng.context_len(slot)):
                             self._complete(req)
                             eng.release_slot(slot)
                             del active[slot]
                             finished.append(req.rid)
-                    span.set_metadata(finished=ids_arg(finished))
+                    if spans_recorded(tel):
+                        span.set_metadata(finished=ids_arg(finished),
+                                          **agg.emit_args(occ))
+                agg.lap("emit_s")
             elif active:
                 sampled, _ = eng.decode_once(self.temperature)
                 with tel.span("emit") as span:
-                    t_now = time.perf_counter()
                     occ = len(active)
+                    t_now, row = agg.note_emit(occ)
                     finished = []
                     for slot in list(active):
                         req = active[slot]
                         req.out_tokens.append(int(sampled[slot]))
                         req.t_last = t_now
                         if trace is not None:
-                            trace.tick(req.rid, occ, 1, t=t_now)
+                            trace.tick(req.rid, occ, 1, t=t_now, row=row)
                         if self._finished(req, eng.context_len(slot)):
                             self._complete(req)
                             eng.release_slot(slot)
                             del active[slot]
                             finished.append(req.rid)
-                    span.set_metadata(finished=ids_arg(finished))
+                    if spans_recorded(tel):
+                        span.set_metadata(finished=ids_arg(finished),
+                                          **agg.emit_args(occ))
+                agg.lap("emit_s")
             elif pending and not queue:
                 # Idle ahead of the next arrival — open-loop wait. The
                 # watchdog heartbeat says "idle, not hung": a sparse
                 # arrival stream must not read as a decode-loop stall.
                 tel.heartbeat()
-                gap = pending[0].arrival_s - (time.perf_counter() - t0)
+                gap = pending[0].arrival_s - (clock() - t0)
                 if gap > 0:
                     with tel.span("serve_idle", why="no_arrival"):
-                        t_sl = time.perf_counter()
+                        t_sl = clock()
                         time.sleep(min(gap, self.idle_sleep_s))
                         if ledger is not None:
                             ledger.note("idle",
-                                        time.perf_counter() - t_sl)
+                                        clock() - t_sl)
             elif queue:
                 # Queued work but no free slot and nothing decoding:
                 # capacity is held outside this serve (caller-activated
@@ -414,13 +455,13 @@ class ContinuousBatchingScheduler:
                         "block pool's per-group capacity")
                 tel.heartbeat()
                 with tel.span("serve_idle", why="admission_blocked"):
-                    t_sl = time.perf_counter()
+                    t_sl = clock()
                     time.sleep(self.idle_sleep_s)
                     if ledger is not None:
                         ledger.note("admission_blocked",
-                                    time.perf_counter() - t_sl)
+                                    clock() - t_sl)
 
-        wall = time.perf_counter() - t0
+        wall = clock() - t0
         # Final drain with a SERVE-WALL-anchored snapshot: a run shorter
         # than report_steps iterations would otherwise never put the
         # aggregator snapshot (tokens/s, decode-step percentiles) into
@@ -432,6 +473,14 @@ class ContinuousBatchingScheduler:
             tel.drain({"serving": eng.serving.snapshot(
                 wall_s=wall)})
         report = dict(eng.serving.snapshot(wall_s=wall))
+        if report.get("stalls"):
+            # An untraced run that lost seconds says where its thread
+            # stood (docs/tutorials/inference.md).
+            logger.warning("serve: %d stalled interval(s): %s", len(
+                report["stalls"]), "; ".join(
+                    f"row {st['row']} at {st['at_s']} s waited "
+                    f"{st['gap_ms']} ms, {st['in_ms']} ms over the usual "
+                    f"in {st['in']}" for st in report["stalls"]))
         report["recompiles"] = eng.telemetry.recompile_count
         report["unfinished"] = len(pending) + len(queue) + len(active)
         if trace is not None:

@@ -145,8 +145,9 @@ class RequestTrace:
 
     def tick(self, rid: int, occupancy: int, emitted: int,
              proposed: int = 0, accepted: int = 0,
-             t: Optional[float] = None) -> None:
-        """One decode/verify iteration this request participated in."""
+             t: Optional[float] = None, row: Optional[int] = None) -> None:
+        """One decode/verify iteration this request participated in
+        (``row``: its row of the serving timeline, monitor/serving.py)."""
         rec = self._live.get(rid)
         if rec is None:
             return
@@ -159,6 +160,8 @@ class RequestTrace:
         if proposed:
             mark["proposed"] = int(proposed)
             mark["accepted"] = int(accepted)
+        if row is not None:
+            mark["row"] = int(row)
         rec.ticks.append(mark)
 
     # ---------------------------------------------------------- lifecycle

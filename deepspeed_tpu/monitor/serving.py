@@ -11,11 +11,53 @@ All inputs are host wall-clock and host counters — aggregation adds
 zero device syncs. ``ServingAggregator.snapshot()`` is the one shape
 every consumer speaks: the engine's drain extra, SERVE_BENCH.json, and
 ``tools/telemetry_report.py``'s ``serving`` section.
+
+The per-iteration numbers are ROWS of one timeline (``COLUMNS``): the
+loop hands the clock to ``lap`` at the boundaries its spans already
+have, so the time between two token emissions is split, without a
+remainder, into the columns of the row the later emission closes.  A
+stream's inter-token intervals, what filled them and the run's worst
+stalls are read from the rows (``snapshot()``: ``itl_ms``,
+``itl_split_ms``, ``stalls``); a request finds its own token times by
+the rows it was live in (``Request.token_times``).
 """
 from __future__ import annotations
 
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
+
+import numpy as np
+
+# One row per decode iteration (= one scheduler pass that emits tokens).
+# Seconds, as the host clock read them, between the emission before and
+# this one: ``emit_s`` (the emit loop of the pass before), ``other_s``
+# (between spans: arrivals, the loop itself, an idle wait), ``admit_s``,
+# ``prefill_s`` + ``copy_s`` (OTHER requests' admissions: every live
+# stream stands still), and the ``decode`` span's four parts; their sum
+# is ``gap_s``.  Counts of the same interval: ``admitted`` streams (their
+# own first intervals, from their first tokens, sum to ``first_gap_s``,
+# of which ``first_stall_s`` in later admissions), prefill dispatches,
+# the rows they needed (prompt - cached) and the rows they computed.
+# ``continuing`` streams waited the whole ``gap_s`` (none where no
+# scheduler handed the tokens out: nobody said who waited).  The rest is
+# the iteration's own sample.
+COLUMNS = ("t_emit", "gap_s", "emit_s", "other_s", "admit_s", "prefill_s",
+           "copy_s", "tables_s", "dispatch_s", "fetch_s", "advance_s",
+           "first_gap_s", "first_stall_s", "continuing", "admitted",
+           "prefill_dispatches", "prefill_rows", "prefill_rows_computed",
+           "occupancy", "decode_ms", "cache_bytes", "context_tokens")
+COL = {name: i for i, name in enumerate(COLUMNS)}
+# The columns a gap is made of, under the names an operator knows them
+# by (the host spans'; ``other_s`` is no span's).
+GAP_PARTS = {"emit_s": "emit", "other_s": "between_spans",
+             "admit_s": "admit", "prefill_s": "prefill", "copy_s": "copy",
+             "tables_s": "decode_tables", "dispatch_s": "decode_dispatch",
+             "fetch_s": "decode_fetch", "advance_s": "decode_advance"}
+RING = 65536                 # rows kept: a 51 s window of the fastest
+#                              benchmark cell is ~6,000
+STALL_FLOOR_S = 0.25
+STALL_TIMES_MEDIAN = 10.0
+STALLS_KEPT = 8
 
 
 def percentile(sorted_vals: List[float], q: float) -> float:
@@ -36,6 +78,18 @@ def _pcts(vals: List[float]) -> Dict[str, float]:
             "n": len(s)}
 
 
+def weighted_percentile(values: np.ndarray, weights: np.ndarray,
+                        q: float) -> float:
+    """``percentile``'s nearest-rank rule over ``values`` repeated
+    ``weights`` times each (whole, non-negative counts)."""
+    order = np.argsort(values, kind="stable")
+    cum = np.cumsum(weights[order])
+    if not len(cum) or cum[-1] <= 0:
+        return 0.0
+    k = int(round(q / 100.0 * (cum[-1] - 1)))
+    return float(values[order][np.searchsorted(cum, k, side="right")])
+
+
 class ServingAggregator:
     """Accumulates per-iteration and per-request serving metrics.
 
@@ -45,12 +99,36 @@ class ServingAggregator:
     SERVE_BENCH.json) never interleave two replicas' percentile streams
     into one misleading distribution. ``ServingAggregator.merged``
     builds the honest aggregate view by POOLING the raw samples.
+
+    ``clock`` is the one clock of the serving loop: the scheduler and
+    the engine read it through this object (``lap``), so a test that
+    drives it drives every stamp.
     """
 
-    def __init__(self, max_slots: int, label: Optional[str] = None):
+    def __init__(self, max_slots: int, label: Optional[str] = None,
+                 clock: Callable[[], float] = time.perf_counter):
         self.max_slots = max(1, int(max_slots))
         self.label = label
-        self.t0 = time.perf_counter()
+        self.clock = clock
+        self.t0 = clock()
+        # The timeline: a ring of rows, ``rows`` of them written so far;
+        # ``_pend`` gathers the row the next iteration closes.
+        self._rows = np.zeros((RING, len(COLUMNS)))
+        self._n = 0
+        self._pend = [0.0] * len(COLUMNS)
+        self._written = self._pend   # the latest row written, as a list
+        self._last = self.t0         # the clock at the latest lap
+        self._t_prev = self.t0       # t_emit of the row before
+        # An iteration's row is written when its tokens are handed out
+        # (``note_emit``); until then the clock when the iteration
+        # ended, for a loop that has no scheduler to say so.
+        self._staged: Optional[float] = None
+        # First tokens since the last row: how many, the sum of their
+        # times, and of the stall seconds the interval held before each.
+        self._first = [0, 0.0, 0.0]
+        # Where the latest ``serve()`` began (``stalls`` are its own).
+        self._serve_row0 = 0
+        self._serve_t0 = self.t0
         self.iterations = 0
         self.decode_tokens = 0
         self.prefill_tokens = 0
@@ -90,34 +168,152 @@ class ServingAggregator:
         # the snapshot omits the sections (skip-never-fail downstream).
         self.ledger: Optional[Any] = None
         self.slo: Optional[Any] = None
-        self._occupancy: List[float] = []
-        self._decode_ms: List[float] = []
         self._ttft_ms: List[float] = []
         self._tpot_ms: List[float] = []
         self._queue_wait_ms: List[float] = []
         self._service_ttft_ms: List[float] = []
-        self._hbm_per_token: List[float] = []
-        self._cache_bytes: List[int] = []
+
+    # ---- the timeline ---- #
+    @property
+    def rows(self) -> int:
+        """Rows written so far (the index of the next)."""
+        self._flush()
+        return self._n
+
+    def lap(self, column: str) -> float:
+        """Read the clock and file the time since the last lap under
+        ``column`` of the row being gathered; returns the reading."""
+        if self._staged is not None:
+            self._flush()
+        now = self.clock()
+        self._pend[COL[column]] += now - self._last
+        self._last = now
+        return now
+
+    def note_serve_start(self) -> float:
+        """A ``serve()`` begins: its ``stalls`` are counted from here."""
+        self._serve_row0 = self.rows
+        self._serve_t0 = self.clock()
+        return self._serve_t0
+
+    def note_prefill_pass(self, dispatches: int, rows: int,
+                          rows_computed: int) -> float:
+        """One admission batch's prefill ends here (a lap of
+        ``prefill_s``): the chunk programs it dispatched, the prompt
+        rows it needed (prompt - cached) and the rows those programs
+        computed."""
+        p = self._pend
+        p[COL["prefill_dispatches"]] += dispatches
+        p[COL["prefill_rows"]] += rows
+        p[COL["prefill_rows_computed"]] += rows_computed
+        return self.lap("prefill_s")
+
+    def note_first_token(self, t_first: float) -> None:
+        """A stream joins the rows: its first interval runs from its
+        first token (``t_first``, out of its prefill) to the next
+        emission, and the stall in it is only what later admissions
+        add."""
+        p, f = self._pend, self._first
+        f[0] += 1
+        f[1] += t_first
+        f[2] += p[COL["prefill_s"]] + p[COL["copy_s"]]
+
+    def _write_row(self, t_emit: float, streams: int) -> None:
+        """The gathered row, emitted at ``t_emit`` to ``streams`` (0: to
+        nobody a scheduler knows of), goes into the ring."""
+        p, (n, t_sum, stall_before) = self._pend, self._first
+        p[COL["t_emit"]] = t_emit
+        p[COL["gap_s"]] = t_emit - self._t_prev
+        p[COL["continuing"]] = max(streams - n, 0) if self._n else 0
+        p[COL["admitted"]] = n
+        p[COL["first_gap_s"]] = n * t_emit - t_sum
+        p[COL["first_stall_s"]] = \
+            n * (p[COL["prefill_s"]] + p[COL["copy_s"]]) - stall_before
+        self._rows[self._n % RING] = p
+        self._n += 1
+        self._t_prev = t_emit
+        self._written = p            # the latest row, still a list
+        self._pend = [0.0] * len(COLUMNS)
+        self._first = [0, 0.0, 0.0]
+
+    def _flush(self) -> None:
+        """Write an iteration's row that no scheduler emitted: it has
+        its times and its sample, and no stream's interval."""
+        if self._staged is not None:
+            t_end, self._staged = self._staged, None
+            self._write_row(t_end, 0)
+
+    def note_emit(self, streams: int) -> "tuple[float, int]":
+        """The scheduler hands this iteration's tokens to ``streams``
+        requests NOW: the row's emission time (and with it the interval
+        since the row before).  Returns the clock and the row's index."""
+        self._staged = None
+        now = self.lap("advance_s")
+        self._write_row(now, streams)
+        return now, self._n - 1
+
+    def emit_args(self, streams: int) -> Dict[str, Any]:
+        """The ``emit`` span's args of the row ``note_emit(streams)`` has
+        just written (for a span that something records: the loop does
+        not build them otherwise): ``row``, ``streams``, those of them
+        that waited the whole interval, ``continuing``, and in ms this
+        row's interval ``gap_ms`` with the part of it in other requests'
+        prefill and copies, ``stall_ms``, and on the host outside the
+        wait for the device, ``host_ms``."""
+        p = self._written
+        gap = p[COL["gap_s"]]
+        stall = p[COL["prefill_s"]] + p[COL["copy_s"]]
+        wait = p[COL["dispatch_s"]] + p[COL["fetch_s"]]
+        return {"row": self._n - 1, "streams": int(streams),
+                "continuing": int(p[COL["continuing"]]),
+                "gap_ms": round(gap * 1e3, 4),
+                "stall_ms": round(stall * 1e3, 4),
+                "host_ms": round((gap - stall - wait) * 1e3, 4)}
+
+    def t_emit(self, row_first: int, row_last: int = -1) -> np.ndarray:
+        """Emission times of rows ``row_first..row_last`` (to the latest
+        row for -1), those of them the ring still holds."""
+        n = self.rows
+        hi = n - 1 if row_last < 0 else min(row_last, n - 1)
+        idx = np.arange(max(row_first, n - RING, 0), hi + 1)
+        return self._rows[idx % RING, COL["t_emit"]]
+
+    def _table(self) -> np.ndarray:
+        """The rows held, oldest first."""
+        n = self.rows
+        if n <= RING:
+            return self._rows[:n]
+        return np.roll(self._rows, -(n % RING), axis=0)
+
+    def _extend_rows(self, table: np.ndarray) -> None:
+        """Append another aggregator's rows (``merged``)."""
+        table = table[-RING:]
+        self._rows[(self._n + np.arange(len(table))) % RING] = table
+        self._n += len(table)
 
     # ---- per decode iteration ---- #
     def note_iteration(self, active_slots: int, decode_s: float,
                        cache_bytes: Optional[int] = None,
                        context_tokens: Optional[int] = None,
                        emitted_tokens: Optional[int] = None) -> None:
-        """``emitted_tokens`` defaults to one per active slot (plain
-        decode); the speculative verify step passes the real count.
-        ``cache_bytes`` / ``context_tokens`` sample the HBM the cache
-        holds against the tokens it serves — the hbm_bytes_per_token
-        series the paging win is measured on."""
+        """Ends the iteration's row (written when its tokens are handed
+        out, or when the next begins).  ``emitted_tokens`` defaults to
+        one per active slot (plain decode); the speculative verify step
+        passes the real count.  ``cache_bytes`` / ``context_tokens``
+        sample the HBM the cache holds against the tokens it serves —
+        the hbm_bytes_per_token series the paging win is measured on."""
+        self._flush()                # the one before, if nobody emitted it
+        tokens = int(emitted_tokens if emitted_tokens is not None
+                     else active_slots)
         self.iterations += 1
-        self.decode_tokens += int(emitted_tokens
-                                  if emitted_tokens is not None
-                                  else active_slots)
-        self._occupancy.append(active_slots / self.max_slots)
-        self._decode_ms.append(decode_s * 1e3)
+        self.decode_tokens += tokens
+        p = self._pend
+        p[COL["occupancy"]] = active_slots / self.max_slots
+        p[COL["decode_ms"]] = decode_s * 1e3
         if cache_bytes is not None and context_tokens:
-            self._cache_bytes.append(int(cache_bytes))
-            self._hbm_per_token.append(cache_bytes / context_tokens)
+            p[COL["cache_bytes"]] = int(cache_bytes)
+            p[COL["context_tokens"]] = int(context_tokens)
+        self._staged = self._last
 
     def note_prefill(self, prompt_tokens: int) -> None:
         self.prefill_tokens += int(prompt_tokens)
@@ -202,9 +398,69 @@ class ServingAggregator:
 
     @property
     def occupancy_mean(self) -> float:
-        if not self._occupancy:
-            return 0.0
-        return sum(self._occupancy) / len(self._occupancy)
+        occ = self._table()[:, COL["occupancy"]]
+        return float(occ.mean()) if len(occ) else 0.0
+
+    def intervals(self, table: Optional[np.ndarray] = None
+                  ) -> Dict[str, Any]:
+        """Every (stream, consecutive token pair) of the rows held, not
+        rounded: ``values`` / ``weights`` (seconds; a row's interval for
+        its continuing streams, and the mean first interval of those it
+        admitted), their count ``n`` and ``total_s``, the total's parts
+        ``decode_wait_s`` (``dispatch_s`` + ``fetch_s``), ``stall_s``
+        (``prefill_s`` + ``copy_s``: other requests' admissions) and
+        ``host_s`` (the rest), and ``stalled``: how many held a prefill
+        dispatch."""
+        t = self._table() if table is None else table
+
+        def col(name):
+            return t[:, COL[name]]
+        cont, adm = col("continuing"), col("admitted")
+        first = np.divide(col("first_gap_s"), adm,
+                          out=np.zeros(len(t)), where=adm > 0)
+        total = float(cont @ col("gap_s") + col("first_gap_s").sum())
+        wait = float((cont + adm) @ (col("dispatch_s") + col("fetch_s")))
+        stall = float(cont @ (col("prefill_s") + col("copy_s"))
+                      + col("first_stall_s").sum())
+        held = col("prefill_dispatches") > 0
+        return {"values": np.concatenate([col("gap_s"), first]),
+                "weights": np.concatenate([cont, adm]),
+                "n": int(cont.sum() + adm.sum()), "total_s": total,
+                "decode_wait_s": wait, "stall_s": stall,
+                "host_s": total - wait - stall,
+                "stalled": int(cont[held].sum()
+                               + adm[col("first_stall_s") > 0].sum())}
+
+    def stalls(self, table: Optional[np.ndarray] = None
+               ) -> List[Dict[str, Any]]:
+        """The latest ``serve()``'s worst intervals: at most
+        ``STALLS_KEPT`` rows (the longest, in order of time) whose
+        interval, waited by at least one stream, exceeded the larger of
+        ``STALL_TIMES_MEDIAN`` x the median interval and
+        ``STALL_FLOOR_S``; each with its row, the seconds since the
+        serve began, and the part of the interval (``GAP_PARTS``) that
+        held most of the excess over that part's median."""
+        t = self._table() if table is None else table
+        index = np.arange(self._n - len(t), self._n)
+        keep = (index >= self._serve_row0) & (t[:, COL["continuing"]] > 0)
+        t, index = t[keep], index[keep]
+        if not len(t):
+            return []
+        gap = t[:, COL["gap_s"]]
+        limit = max(STALL_TIMES_MEDIAN * float(np.median(gap)),
+                    STALL_FLOOR_S)
+        worst = np.flatnonzero(gap > limit)
+        worst = np.sort(worst[np.argsort(-gap[worst])][:STALLS_KEPT])
+        parts = [COL[c] for c in GAP_PARTS]
+        excess = t[:, parts] - np.median(t[:, parts], axis=0)
+        names = list(GAP_PARTS.values())
+        return [{"row": int(index[i]),
+                 "at_s": round(float(t[i, COL["t_emit"]])
+                               - self._serve_t0, 3),
+                 "gap_ms": round(float(gap[i]) * 1e3, 3),
+                 "in": names[int(np.argmax(excess[i]))],
+                 "in_ms": round(float(excess[i].max()) * 1e3, 3)}
+                for i in worst]
 
     def snapshot(self, wall_s: Optional[float] = None) -> Dict[str, Any]:
         """The canonical serving summary. ``tokens_per_s`` counts
@@ -215,15 +471,28 @@ class ServingAggregator:
         skip-never-fail rule keep working. ``attend_live_step_share``
         (paged kernel only) is the share of the attend's sequencing
         steps that touched a live block, over all iterations so far: the
-        rest are the empty steps of dead streams."""
-        wall = wall_s if wall_s is not None \
-            else time.perf_counter() - self.t0
+        rest are the empty steps of dead streams.
+
+        From the rows (the last ``RING`` iterations): ``occupancy_*``,
+        ``decode_step_ms``, ``hbm_bytes_per_token``, ``cache_bytes_p95``
+        and, once a stream waited an interval, ``itl_ms`` (every
+        stream's inter-token interval: p50 / p95 / p99 / max / mean over
+        ``n`` of them), ``itl_split_ms`` (the mean interval's parts
+        ``decode_wait``, ``stall``, ``host``: they sum to it),
+        ``itl_stalled_share`` (intervals that held a prefill dispatch),
+        ``prefill_row_fill`` (rows the prefills needed / rows their
+        dispatches computed) and ``stalls`` (see ``stalls()``)."""
+        wall = wall_s if wall_s is not None else self.clock() - self.t0
+        table = self._table()
+        occupancy = table[:, COL["occupancy"]].tolist()
+        sampled = table[table[:, COL["context_tokens"]] > 0]
         snap = {
             "iterations": self.iterations,
             "completed": self.completed,
-            "occupancy_mean": round(self.occupancy_mean, 4),
+            "occupancy_mean": round(
+                sum(occupancy) / len(occupancy) if occupancy else 0.0, 4),
             "occupancy_p50": round(
-                percentile(sorted(self._occupancy), 50), 4),
+                percentile(sorted(occupancy), 50), 4),
             "decode_tokens": self.decode_tokens,
             "prefill_tokens": self.prefill_tokens,
             "tokens_per_s": round(self.decode_tokens / wall, 3)
@@ -231,7 +500,7 @@ class ServingAggregator:
             "wall_s": round(wall, 6),
             "ttft_ms": _pcts(self._ttft_ms),
             "tpot_ms": _pcts(self._tpot_ms),
-            "decode_step_ms": _pcts(self._decode_ms),
+            "decode_step_ms": _pcts(table[:, COL["decode_ms"]].tolist()),
         }
         if self.label is not None:
             snap["replica"] = self.label
@@ -252,10 +521,30 @@ class ServingAggregator:
                 else self.slo
             if slo is not None:
                 snap["slo"] = slo
-        if self._hbm_per_token:
-            snap["hbm_bytes_per_token"] = _pcts(self._hbm_per_token)
+        if len(sampled):
+            held = sampled[:, COL["cache_bytes"]]
+            snap["hbm_bytes_per_token"] = _pcts(
+                (held / sampled[:, COL["context_tokens"]]).tolist())
             snap["cache_bytes_p95"] = int(percentile(
-                sorted(self._cache_bytes), 95))
+                sorted(held.tolist()), 95))
+        itl = self.intervals(table)
+        if itl["n"]:
+            v, w, n = itl["values"], itl["weights"], itl["n"]
+            snap["itl_ms"] = {
+                **{f"p{q}": round(1e3 * weighted_percentile(v, w, q), 3)
+                   for q in (50, 95, 99)},
+                "max": round(1e3 * float(v[w > 0].max()), 3),
+                "mean": 1e3 * itl["total_s"] / n, "n": n}
+            snap["itl_split_ms"] = {
+                "decode_wait": 1e3 * itl["decode_wait_s"] / n,
+                "stall": 1e3 * itl["stall_s"] / n,
+                "host": 1e3 * itl["host_s"] / n}
+            snap["itl_stalled_share"] = round(itl["stalled"] / n, 4)
+            snap["stalls"] = self.stalls(table)
+        computed = float(table[:, COL["prefill_rows_computed"]].sum())
+        if computed:
+            snap["prefill_row_fill"] = round(
+                float(table[:, COL["prefill_rows"]].sum()) / computed, 4)
         if self.prompt_tokens_admitted:
             snap["prefix"] = {
                 "prompt_tokens": self.prompt_tokens_admitted,
@@ -326,18 +615,16 @@ class ServingAggregator:
             if out.attend_mode is None:
                 out.attend_mode = a.attend_mode
             # Occupancy normalizes per-replica (active/its own slots):
-            # pooling the normalized samples keeps the mean meaningful
+            # pooling the normalized rows keeps the mean meaningful
             # as "fraction of owned capacity busy".
-            out._occupancy.extend(a._occupancy)
-            out._decode_ms.extend(a._decode_ms)
+            out._extend_rows(a._table())
             out._ttft_ms.extend(a._ttft_ms)
             out._tpot_ms.extend(a._tpot_ms)
             out._queue_wait_ms.extend(a._queue_wait_ms)
             out._service_ttft_ms.extend(a._service_ttft_ms)
             out._admission_attempts.extend(a._admission_attempts)
             out.reservations_rejected += a.reservations_rejected
-            out._hbm_per_token.extend(a._hbm_per_token)
-            out._cache_bytes.extend(a._cache_bytes)
+        out._serve_row0 = out.rows      # a replica's stalls are its own
         # Fleet-level SLO/ledger views: pooled outcomes and bucket-wise
         # sums, stored as settled dicts (a merged aggregator keeps
         # accumulating nothing).
@@ -353,4 +640,4 @@ class ServingAggregator:
         return out
 
 
-__all__ = ["ServingAggregator", "percentile"]
+__all__ = ["ServingAggregator", "percentile", "COLUMNS", "GAP_PARTS"]

@@ -165,6 +165,14 @@ def ids_arg(ids) -> str:
     return " ".join(map(str, ids)) if ids else ""
 
 
+def spans_recorded(telemetry) -> bool:
+    """Whether a span's args go anywhere now: a profiler session is open
+    or ``telemetry`` writes a Chrome trace.  A loop asks before it builds
+    args that cost something to build."""
+    return TraceAnnotation.is_enabled() or \
+        getattr(telemetry, "tracer", None) is not None
+
+
 class _SpanHandle:
     """What ``with telemetry.span(...) as sp`` binds when the span also
     feeds the Chrome-trace writer: ``set_metadata`` (the annotation's own
@@ -791,5 +799,6 @@ class Telemetry:
             self.sink.close()
 
 
-__all__ = ["Telemetry", "JsonlSink", "ids_arg", "analytic_state_bytes",
+__all__ = ["Telemetry", "JsonlSink", "ids_arg", "spans_recorded",
+           "analytic_state_bytes",
            "device_memory_stats"]

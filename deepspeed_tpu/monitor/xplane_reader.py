@@ -65,8 +65,10 @@ SPAN_ARGS = {
     # state pool's admission (inference/kv_cache.py): prompt tokens the
     # snapshot it resumed from covers (what cached_tokens means there),
     # snapshots the admission left, bytes its page copies read + wrote.
+    # rows_computed: the [G, chunk] rows of every chunk program the
+    # admission dispatched (prompt_tokens - cached_tokens of them needed).
     "prefill": ("slots", "prompt_tokens", "rids", "cached_tokens",
-                "chunks", "moe_held_pairs", "moe_held_max",
+                "chunks", "rows_computed", "moe_held_pairs", "moe_held_max",
                 "moe_held_mean", "moe_held_empty", "moe_held_pair_share",
                 "resumed_tokens", "snapshot_taken", "state_copy_bytes"),
     "prefill_chunk": ("ci", "active_groups"),
@@ -80,7 +82,14 @@ SPAN_ARGS = {
                # pages of a per-stream pool the state-update kernel
                # rewrote in this execution (one a live stream)
                "state_pages_live"),
-    "emit": ("finished",), "serve_idle": ("why",)}
+    # The emission's row of the serving timeline (monitor/serving.py),
+    # the streams it hands tokens to and those of them that waited the
+    # whole interval since the emission before, that interval, the part
+    # of it in other requests' prefill and copies, and the part on the
+    # host outside decode_dispatch + decode_fetch.
+    "emit": ("finished", "row", "streams", "continuing", "gap_ms",
+             "stall_ms", "host_ms"),
+    "serve_idle": ("why",)}
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 _INNER = re.compile(r"^(?:[\w.-]+\()*([\w.-]*)\)*$")

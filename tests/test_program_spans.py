@@ -18,6 +18,7 @@ import glob
 import json
 import os
 import re
+import types
 
 import jax
 import numpy as np
@@ -26,6 +27,7 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.inference import (InferenceEngine, Request,
                                      synthetic_requests)
+from deepspeed_tpu.inference import scheduler as scheduler_mod
 from deepspeed_tpu.models.gpt2 import (GPT2_CONFIGS, gpt2_init,
                                        gpt2_loss_fn)
 from deepspeed_tpu.monitor.telemetry import Telemetry
@@ -217,14 +219,18 @@ def test_kv_write_and_attend_nest_under_attn(serve_op_names):
 SERVE_SPANS = {
     "admit": {"queued", "admitted", "late_ms", "rids"},
     "prefill": {"slots", "prompt_tokens", "cached_tokens", "chunks",
-                "rids"},
+                "rows_computed", "rids"},
     "prefill_plan": set(), "prefill_chunk": {"ci", "active_groups"},
     "prefill_fetch": set(),
     "decode": {"iteration", "active", "live_blocks", "context_tokens",
                "attend_steps", "attend_live_steps"},
     "decode_tables": set(), "decode_dispatch": set(),
     "decode_fetch": set(), "decode_advance": set(),
-    "emit": set(), "serve_idle": {"why"}}
+    # (``finished`` too, where a request finished: an empty arg is not
+    # recorded)
+    "emit": {"row", "streams", "continuing", "gap_ms", "stall_ms",
+             "host_ms"},
+    "serve_idle": {"why"}}
 TRAIN_SPANS = {"train_batch": {"step_num"}, "data_prep": {"step"},
                "step_dispatch": {"step"}, "step_log": {"step"}}
 
@@ -238,18 +244,37 @@ def serve_annotations(serve_engine, tmp_path_factory):
                                  vocab_size=CFG.vocab_size))
     # two requests at once (the second arrival late enough for an idle
     # sleep), 3 tokens each: one prefill token + two decode iterations
-    # for the first batch, then the same for the late one.  The first
-    # batch takes ~30 ms alone; at a 0.3 s gap two whole six-worker runs
-    # found no idle span (a batch that overruns the gap never idles).
+    # for the first batch, then the same for the late one.  The serving
+    # loop's clock is driven (a tick a reading, a jump a sleep), so the
+    # first batch is over long before the late arrival however loaded
+    # the machine is, and nothing waits.
     reqs = [Request(rid=100 + i, max_new_tokens=3, arrival_s=a,
                     prompt=rng.integers(0, CFG.vocab_size, 12,
                                         dtype=np.int32))
             for i, a in enumerate((0.0, 0.0, 2.0))]
-    eng.reset_serving_stats()
+    now = [0.0]
+
+    def clock():
+        now[0] += 1e-5
+        return now[0]
+
+    def sleep(s):
+        now[0] += s
+    real = eng.serving.clock
+    eng.serving.clock = clock
+    eng.reset_serving_stats()        # a new aggregator on the same clock
     report = {}
-    found = _session(tmp_path_factory.mktemp("serve_prof"),
-                     lambda: report.update(eng.serve(reqs)))
-    assert report["completed"] == 3
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            # the idle wait is all the scheduler asks of ``time``
+            mp.setattr(scheduler_mod, "time",
+                       types.SimpleNamespace(sleep=sleep))
+            found = _session(
+                tmp_path_factory.mktemp("serve_prof"),
+                lambda: report.update(eng.serve(reqs, idle_sleep_s=0.5)))
+    finally:
+        eng.serving.clock = real
+    assert report["completed"] == 3 and now[0] < 3.0
     return found, report
 
 
@@ -304,6 +329,35 @@ def test_serve_spans_nest_and_follow_a_request(serve_annotations):
     # decode's end-of-span args: what _cache_accounting read
     assert all(a["live_blocks"] > 0 and a["context_tokens"] > 0
                for _, _, a in found["decode"])
+
+
+def test_emit_and_prefill_spans_carry_the_timeline(serve_annotations,
+                                                   serve_engine):
+    """The rows of ``monitor/serving.py`` on the profiler's clock: an
+    ``emit`` names its row, and a ``prefill`` the rows its chunk programs
+    computed (two chunks of 8 for a 12-token prompt, one group)."""
+    found, report = serve_annotations
+    emits = [a for _, _, a in found["emit"]]
+    assert [a["row"] for a in emits] == list(range(report["iterations"]))
+    # a row is the ``decode`` before it (the engine counts its iterations
+    # from its start, the rows from ``reset_serving_stats``)
+    assert len({d[2]["iteration"] - a["row"]
+                for d, a in zip(found["decode"], emits)}) == 1
+    assert all(a["gap_ms"] > 0 and a["stall_ms"] >= 0 for a in emits)
+    assert [(a["streams"], a["continuing"]) for a in emits] == \
+        [(2, 0), (2, 2), (1, 0), (1, 1)]
+    # the prefills lie in the interval of the row they precede; the idle
+    # wait ahead of the late arrival is nobody's interval
+    assert emits[0]["stall_ms"] > 0 and emits[1]["stall_ms"] == 0
+    assert emits[2]["gap_ms"] > 1000 and emits[2]["continuing"] == 0
+    for _, _, a in found["prefill"]:
+        assert a["rows_computed"] == a["chunks"] * serve_engine.prefill_chunk
+        assert a["prompt_tokens"] - a["cached_tokens"] == 12
+    assert report["prefill_row_fill"] == 0.75
+    assert report["itl_ms"]["n"] == 3 * 2 and report["stalls"] == []
+    split = report["itl_split_ms"]
+    assert sum(split.values()) == pytest.approx(report["itl_ms"]["mean"],
+                                                abs=1e-9)
 
 
 def test_decode_span_counts_the_attend_steps(serve_annotations,

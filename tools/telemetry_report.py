@@ -496,7 +496,10 @@ def summarize(jsonl_path: str) -> Dict[str, Any]:
         # (pre-paging streams carry none; ``attend`` is the analytic
         # kernel-vs-one-hot pricing, projection-labeled at the source).
         for sec in ("hbm_bytes_per_token", "prefix", "spec", "replica",
-                    "attend", "attend_work_ratio", "admission"):
+                    "attend", "attend_work_ratio", "admission",
+                    # the serving timeline's figures (monitor/serving.py)
+                    "itl_ms", "itl_split_ms", "itl_stalled_share",
+                    "prefill_row_fill", "stalls"):
             if serve_snap.get(sec) is not None:
                 serving[sec] = serve_snap[sec]
         # Multi-replica streams: request_complete events carry replica
