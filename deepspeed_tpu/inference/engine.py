@@ -24,9 +24,14 @@ Architecture (mirrors the training engine's discipline):
   ``num_blocks``).
 - Host-side per-slot counters (lengths, active, last token) are the
   scheduler's state; they enter each step as tiny int arrays. The one
-  device fetch per decode iteration is the sampled-token readback — the
-  inherent serving sync (the host must see tokens to detect EOS and
-  feed the next step), and it is the ONLY one.
+  device fetch per decode iteration is the sampled-token readback (the
+  host must see tokens to hand them out and to detect EOS), and it is
+  the ONLY one. The next step does not wait for it: ``decode_step``
+  takes its tokens where they lie — the previous execution's fetch
+  array, still on the device — so the scheduler's loop dispatches
+  iteration n+1 BEFORE it fetches n's tokens (``decode_once`` with
+  ``continuing``), and the fetch, the emission and the host's
+  bookkeeping run under n+1's device time.
 - Telemetry rides the training spine unchanged: per-iteration step
   records (occupancy, active slots, fenced step wall), ``prefill``
   spans, ``request_complete`` events, and the ``ServingAggregator``
@@ -39,6 +44,7 @@ Architecture (mirrors the training engine's discipline):
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 import weakref
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
@@ -70,6 +76,21 @@ try:
     from flax import serialization as flax_serialization
 except Exception:  # pragma: no cover
     flax_serialization = None
+
+
+@dataclasses.dataclass
+class _Flight:
+    """One decode iteration between its dispatch and its token fetch."""
+    fetch: Any                  # device: tokens (+ the model's counters)
+    logits: Any                 # device: [S, V]
+    mask: np.ndarray            # [S] bool: slots in it and still owed its token
+    n_active: int               # slots it was dispatched for
+    t0: float                   # the clock when its host work began ...
+    prefill_s: float            # ... and the prefill seconds spent until then
+    ahead: int                  # dispatched with the one before unfetched
+    cache_bytes: int
+    context_tokens: int
+    dropped: int = 0            # rows computed for streams released since
 
 
 class InferenceEngine:
@@ -188,6 +209,23 @@ class InferenceEngine:
         self.lengths = np.zeros(self.max_slots, np.int32)
         self.active = np.zeros(self.max_slots, bool)
         self.last_tokens = np.zeros(self.max_slots, np.int32)
+        # The decode iteration dispatched and not yet fetched (at most
+        # one: ``decode_once`` with ``continuing``), the slots activated
+        # since the last dispatch (their token is on the host, out of
+        # ``prefill_fetch``), and what a first dispatch takes for the
+        # previous execution's fetch array: zeros, committed like a
+        # program's output so that every dispatch is one compiled form.
+        self._inflight: Optional[_Flight] = None
+        self._fresh = np.zeros(self.max_slots, bool)
+        self._fetch_sh = NamedSharding(self.mesh, P())
+        self._no_fetch = jax.device_put(
+            np.zeros(self.max_slots + len(served.counter_names), np.int32),
+            self._fetch_sh)
+        # Clock of the latest iteration's tokens and the prefill seconds
+        # spent by then (``decode_step_ms`` runs from there, less the
+        # admissions between).
+        self._t_tokens = (float("-inf"), 0.0)
+        self._prefill_wall = 0.0
         self._held = set()               # acquired, not yet activated
         self._last_admit: Dict[int, Dict[str, Any]] = {}
         # Why the most recent select_slot returned None ("no_slot" =
@@ -305,20 +343,30 @@ class InferenceEngine:
             return dequantize(params, self.served.dtype)
         return params
 
-    def _jit_step(self, step: Callable) -> Callable:
+    def _jit_step(self, step: Callable, fetch_sharding=None) -> Callable:
         """``step(params, *pools, ...) -> (*pools, fetch, logits)``: the
         pools donated and returned where they lie."""
         sh = tuple(self._cache_sh.values())
         return jax.jit(step, donate_argnums=tuple(range(1, 1 + len(sh))),
-                       out_shardings=sh + (None, None))
+                       out_shardings=sh + (fetch_sharding, None))
 
     def _build_decode_step(self) -> Callable:
+        """``decode_step(params, *pools, previous, tokens, fresh, lengths,
+        block tables, key, temperature)``: a slot's input token is the
+        host's (``tokens``) where ``fresh`` marks it, else the one the
+        previous execution sampled for it, read from that execution's
+        fetch array where it lies (``previous``: tokens, then the
+        model's counters). The fetch array leaves the program under the
+        sharding a first dispatch's zeros are committed with, so every
+        dispatch runs the one compiled form."""
         served = self.served
         n = len(self._cache_sh)
 
         def decode_step(params, *args):
-            pools, (tokens, lengths, bt, key, temperature) = \
-                args[:n], args[n:]
+            pools, (previous, tokens, fresh, lengths, bt, key,
+                    temperature) = args[:n], args[n:]
+            tokens = jnp.where(fresh, tokens,
+                               previous[:tokens.shape[0]])
             p = self._runtime_params(params)
             logits, pools, counters = served.decode(
                 p, pools, tokens, lengths, bt, num_groups=self.dp,
@@ -326,7 +374,7 @@ class InferenceEngine:
             sampled = decode_mod.sample_tokens(logits, key, temperature)
             return (*pools, with_counters(sampled, counters), logits)
 
-        return self._jit_step(decode_step)
+        return self._jit_step(decode_step, self.__dict__.get("_fetch_sh"))
 
     def _build_prefill_step(self) -> Callable:
         served = self.served
@@ -417,6 +465,12 @@ class InferenceEngine:
                        out_shardings=sh)
 
     def _next_key(self) -> jax.Array:
+        """The key of the next program DISPATCHED: ``fold_in(base, call
+        number)``. (A prefill and a decode that swap their order of
+        dispatch swap their keys: with ``temperature`` > 0 the draws of
+        a loop that dispatches ahead come from the same sequence of
+        keys as a synchronous loop's, not key for key to the same
+        program.)"""
         self._rng_calls += 1
         return jax.random.fold_in(self._base_rng, self._rng_calls)
 
@@ -431,6 +485,7 @@ class InferenceEngine:
         self.lengths[slot] = int(context_len)
         self.active[slot] = True
         self.last_tokens[slot] = int(last_token)
+        self._fresh[slot] = True
         self._held.discard(slot)
         if self.drafter is not None:
             self.drafter.observe(slot, [int(last_token)])
@@ -439,7 +494,14 @@ class InferenceEngine:
         """Evict: counters clear and every block reference drops —
         private blocks return to the free list, prefix blocks whose
         refcount hits zero are LRU-retained for future hits. The stale
-        rows are dead by masking."""
+        rows are dead by masking. A row the iteration in flight computes
+        for it is DROPPED: nobody is owed its token (it wrote into the
+        stream's own block or page, which dies here; whatever takes the
+        block next is dispatched behind it)."""
+        flight = self._inflight
+        if flight is not None and flight.mask[slot]:
+            flight.mask[slot] = False
+            flight.dropped += 1
         self.active[slot] = False
         self.lengths[slot] = 0
         self.last_tokens[slot] = 0
@@ -628,6 +690,7 @@ class InferenceEngine:
             self.serving.note_admit(plen, 0)
             self._store_pools(pools)
             tl.raise_pending()
+            waited = self._await_decode()
             with tl.span("prefill_fetch"):
                 out_logits = np.asarray(jax.device_get(logits)) \
                     if return_logits else None
@@ -635,7 +698,9 @@ class InferenceEngine:
                     np.asarray(jax.device_get(tok)).reshape(-1), n_ctr)
                 tok = int(tok[0])
             self._note_counters(span, counters)
-        wall = self.serving.note_prefill_pass(1, plen, self.max_len) - t0
+        wall = self.serving.note_prefill_pass(1, plen, self.max_len) - t0 \
+            - waited
+        self._prefill_wall += wall
         if self.serving.ledger is not None:
             self.serving.ledger.note("prefill", wall)
         return tok, out_logits
@@ -683,6 +748,7 @@ class InferenceEngine:
                     self.allocator.abandon_snapshot(plan[2])
                 raise
             tl.raise_pending()
+            waited = self._await_decode()
             out = []
             n_ctr = len(self.served.counter_names)
             with tl.span("prefill_fetch"):
@@ -707,10 +773,26 @@ class InferenceEngine:
             if self.cache_spec.per_stream:
                 span.set_metadata(**self._note_state_admissions(plans))
         wall = self.serving.note_prefill_pass(
-            len(steps), sum(p[4] for p in plans) - cached, computed) - t_pf0
+            len(steps), sum(p[4] for p in plans) - cached, computed) \
+            - t_pf0 - waited
+        self._prefill_wall += wall
         if self.serving.ledger is not None:
             self.serving.ledger.note("prefill", wall)
         return out
+
+    def _await_decode(self) -> float:
+        """An admission's programs queue on the device behind the decode
+        iteration in flight, and its token fetch would wait for both.
+        Wait for THAT iteration first (ready on the device: its tokens
+        stay there), so its wait is filed as the decode's (``fetch_s``)
+        and what is left of the admission's fetch is the admission's
+        own. Returns the seconds waited."""
+        flight = self._inflight
+        if flight is None:
+            return 0.0
+        t0 = self.serving.lap("prefill_s")
+        jax.block_until_ready(flight.fetch)
+        return self.serving.lap("fetch_s") - t0
 
     def _copy_blocks(self, pools, pairs):
         """Dispatch the copy program for ``{group: (src, dst)}`` block
@@ -862,28 +944,36 @@ class InferenceEngine:
             snapshots_evicted=self.allocator.reclaimed)
         return args
 
-    def _cache_accounting(self) -> Tuple[int, int, int]:
+    def _cache_accounting(self, mask: Optional[np.ndarray] = None
+                          ) -> Tuple[int, int, int]:
         """(live blocks, cache bytes held, context tokens cached) this
         iteration — the hbm_bytes_per_token sample, and the ``decode``
-        span's ``live_blocks`` / ``context_tokens``. Only live blocks
-        are held."""
-        tokens = int(self.lengths[self.active].sum())
+        span's ``live_blocks`` / ``context_tokens`` (of the slots in
+        ``mask``; every active one by default). Only live blocks are
+        held."""
+        tokens = int(self.lengths[self.active if mask is None
+                                  else mask].sum())
         live = self.allocator.blocks_in_use()
         return live, live * self.cache_spec.block_nbytes(), tokens
 
-    def _attend_steps(self, k_rows: int) -> Tuple[int, int]:
+    def _attend_steps(self, k_rows: int,
+                      lengths: Optional[np.ndarray] = None,
+                      tables: Optional[np.ndarray] = None
+                      ) -> Tuple[int, int]:
         """(steps, live steps) a layer of the paged kernel's attend in
         the execution about to run — the ``decode`` span's
         ``attend_steps`` / ``attend_live_steps`` (see
         ``ops.paged_attention.attend_step_counts``), from the lengths
-        and tables the host holds. (0, 0) on the one-hot path, which has
-        no steps."""
+        and tables the execution is handed (the host's own by default).
+        (0, 0) on the one-hot path, which has no steps."""
         if not self.paged_kernel:
             return 0, 0
         sp_ = self.cache_spec
-        reach = (self.lengths + k_rows - 1) // sp_.block_size + 1
+        lengths = self.lengths if lengths is None else lengths
+        tables = self.block_tables if tables is None else tables
+        reach = (lengths + k_rows - 1) // sp_.block_size + 1
         live = np.minimum(np.minimum(reach, sp_.max_blocks_per_slot),
-                          (self.block_tables >= 0).sum(axis=1))
+                          (tables >= 0).sum(axis=1))
         served = self.served
         return served.attend_step_counts(
             live, K=k_rows, spec=sp_, mp=self.mp,
@@ -905,8 +995,10 @@ class InferenceEngine:
             cost[keys] = (flops * sp_.num_layers, nbytes * sp_.num_layers)
         return cost[keys]
 
-    def _attend_work(self, k_rows: int) -> Tuple[int, int, int, int]:
-        """Analytic attend work of the iteration just run, priced BOTH
+    def _attend_work(self, k_rows: int, mask: Optional[np.ndarray] = None
+                     ) -> Tuple[int, int, int, int]:
+        """Analytic attend work of the iteration just run (over the
+        slots in ``mask``; every active one by default), priced BOTH
         ways: (flops_kernel, flops_onehot, bytes_kernel, bytes_onehot).
         Kernel terms sum each live slot's ceil(ctx/bs)*bs keys (the K
         query rows share the block loads, so HBM bytes don't multiply
@@ -920,7 +1012,8 @@ class InferenceEngine:
         bs = sp_.block_size
         f1, b1 = self._attend_cost(context=bs)
         f2, b2 = self._attend_cost(context=2 * bs)
-        blocks = -(-np.maximum(self.lengths[self.active], 1) // bs)
+        blocks = -(-np.maximum(
+            self.lengths[self.active if mask is None else mask], 1) // bs)
         n, reach = int(blocks.size), int(blocks.sum())
         pool = self._attend_cost(pool_blocks=sp_.blocks_per_group)
         return ((n * (2 * f1 - f2) + reach * (f2 - f1)) * k_rows,
@@ -943,79 +1036,188 @@ class InferenceEngine:
         self.serving.note_model_counters(args)
 
     def decode_once(self, temperature: float = 0.0,
-                    return_logits: bool = False
-                    ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
-        """One decode iteration for every slot (inactive slots compute
-        too — a uniform program is what keeps the signature fixed; their
-        counters just don't advance). Returns the sampled token per slot
-        (and the [S, V] logits when asked — tests only; the extra fetch
-        is not part of the serving loop)."""
-        lap = self.serving.lap       # the timeline's clock
-        t0 = lap("other_s")
+                    return_logits: bool = False,
+                    continuing: Optional[Sequence[int]] = None):
+        """One pass of the decode loop: an iteration is one token for
+        every slot in it (the other slots compute too — a uniform
+        program is what keeps the signature fixed; their rows are dead
+        and their counters do not advance).
+
+        Called bare it is SYNCHRONOUS: it dispatches an iteration over
+        every active slot, fetches it, and returns (the sampled token
+        per slot, the [S, V] logits when asked — tests only; the extra
+        fetch is not part of the serving loop).
+
+        With ``continuing`` — the slots that take part in the iteration
+        dispatched NOW: every live stream whose reply is not complete
+        with the token it has in flight — it runs one iteration AHEAD
+        of its token fetch: it dispatches that iteration (none for an
+        empty set), fed by the tokens of the one in flight where they
+        lie on the device (a slot activated since takes the host's),
+        and only then fetches the one in flight. Returns (its tokens
+        [S], the [S] mask of the slots that were in it and are still
+        owed them), or (None, None) when nothing was in flight (the
+        first call after a synchronous stretch: the next returns this
+        one's). A slot released while a row of it is in flight (an EOS
+        found one iteration late) is left out of that mask and counted
+        as ``dropped``; that is the only work ever done in vain. The
+        caller ends with a call whose set is empty, or with
+        ``decode_discard``.
+
+        The host's state moves in two steps: lengths, blocks and tables
+        advance at the DISPATCH (they do not depend on the tokens);
+        ``last_tokens``, the drafter, the model's counters and the
+        iteration's sample on the timeline at the FETCH.
+
+        Host spans: ``decode`` (``iteration``, ``active``,
+        ``live_blocks``, ``context_tokens``, ``attend_*`` of the
+        iteration dispatched; the model's counters of the one fetched;
+        ``ahead``: 1 when the dispatch went out while the iteration
+        before was unfetched; ``dropped`` rows of the one fetched) >
+        ``decode_tables``, ``decode_dispatch`` (a dispatch),
+        ``decode_fetch``, ``decode_advance`` (a fetch)."""
+        ahead = continuing is not None
+        if ahead and return_logits:
+            raise ValueError("decode_once: logits are fetched by the "
+                             "synchronous form only")
+        prev = self._inflight
+        if prev is not None and not ahead:
+            raise RuntimeError(
+                "decode_once: an iteration is in flight — fetch it "
+                "(continuing=()) or decode_discard() it first")
+        if ahead:
+            mask = np.zeros(self.max_slots, bool)
+            mask[np.fromiter(continuing, np.int64)] = True
+            if (mask & ~self.active).any():
+                raise ValueError("decode_once: continuing names a slot "
+                                 "that is not active")
+        else:
+            mask = self.active.copy()
+        t0 = self.serving.lap("other_s")     # the timeline's clock
         tl = self.telemetry
         tl.profiler_tick(self.iterations)
-        n_active = self.active_slots
-        with tl.span("decode", iteration=self.iterations,
+        n_active = int(mask.sum())
+        dispatch = bool(n_active) or not ahead
+        with tl.span("decode",
+                     iteration=self.iterations + (dispatch and
+                                                  prev is not None),
                      active=n_active) as span:
-            with tl.span("decode_tables"):
-                for s in np.flatnonzero(self.active):
-                    self._ensure_blocks(int(s), int(self.lengths[s]))
-                steps = self._attend_steps(1)
-            lap("tables_s")
-            with tl.span("decode_dispatch"):
-                *pools, sampled, logits = self._decode_fn(
-                    self._params, *self._pools(),
-                    self.last_tokens, self.lengths, self.block_tables,
-                    self._next_key(), np.float32(temperature))
-                self._store_pools(pools)
-                tl.raise_pending()
-            lap("dispatch_s")
-            # THE serving sync: the host needs the tokens (EOS detection
-            # + next step's inputs). One batched [S] fetch per iteration
-            # (the served model's counters, if it has any, ride it).
-            with tl.span("decode_fetch"):
-                sampled, counters = split_counters(
-                    np.asarray(jax.device_get(sampled)),
-                    len(self.served.counter_names))
-            lap("fetch_s")
-            with tl.span("decode_advance"):
-                adv = self.active
-                self.lengths[adv] += 1
-                self.last_tokens[adv] = sampled[adv]
-                if self.drafter is not None:
-                    for s in np.flatnonzero(adv):
-                        self.drafter.observe(int(s), [int(sampled[s])])
-                wall = lap("advance_s") - t0
-                self.iterations += 1
-                live_blocks, cache_bytes, ctx_tokens = \
-                    self._cache_accounting()
-                self.serving.note_attend_steps(*steps)
-                if n_active:
-                    self.serving.note_attend(*self._attend_work(1),
-                                             n_active)
-                self.serving.note_iteration(n_active, wall,
-                                            cache_bytes=cache_bytes,
-                                            context_tokens=ctx_tokens)
-                if self.serving.ledger is not None:
-                    self.serving.ledger.note("decode_useful", wall)
-                if tl.enabled:
-                    tl.record_step(
-                        self.iterations, {}, wall_ms=wall * 1e3,
-                        active_slots=n_active,
-                        occupancy=round(n_active / self.max_slots, 4),
-                        tokens=n_active)
-                    tl.maybe_drain(self.iterations,
-                                   extra_fn=self._report_extra)
+            flight = self._decode_dispatch(span, mask, n_active, prev,
+                                           temperature, t0) \
+                if dispatch else None
+            self._inflight = flight if ahead else None
+            due = prev if ahead else flight
+            sampled = took = None
+            if due is not None:
+                sampled = self._decode_fetch(span, due)
+                took = due.mask
+            span.set_metadata(
+                ahead=flight.ahead if flight is not None else 0,
+                dropped=due.dropped if due is not None else 0)
+        if ahead:
+            return sampled, took
+        out_logits = np.asarray(jax.device_get(due.logits)) \
+            if return_logits else None
+        return sampled, out_logits
+
+    def _decode_dispatch(self, span, mask: np.ndarray, n_active: int,
+                         prev: Optional[_Flight], temperature: float,
+                         t0: float) -> _Flight:
+        """Dispatch one iteration over the slots in ``mask`` and advance
+        the host's lengths and tables by it."""
+        tl, lap = self.telemetry, self.serving.lap
+        with tl.span("decode_tables"):
+            for s in np.flatnonzero(mask):
+                self._ensure_blocks(int(s), int(self.lengths[s]))
+            # What the execution is handed: COPIES (the host's arrays
+            # move on under it), dead rows for the slots not in it, and
+            # the host's token for a slot the execution before did not
+            # decode (all of them when none is unfetched).
+            lengths = np.where(mask, self.lengths, np.int32(0))
+            tables = np.where(mask[:, None], self.block_tables,
+                              np.int32(kv_cache.DEAD_BLOCK))
+            fresh = np.ones_like(mask) if prev is None \
+                else self._fresh | ~prev.mask
+            steps = self._attend_steps(1, lengths, tables)
+        lap("tables_s")
+        with tl.span("decode_dispatch"):
+            *pools, fetch, logits = self._decode_fn(
+                self._params, *self._pools(),
+                self._no_fetch if prev is None else prev.fetch,
+                self.last_tokens.copy(), fresh, lengths, tables,
+                self._next_key(), np.float32(temperature))
+            self._store_pools(pools)
+            tl.raise_pending()
+            self._fresh[:] = False
+            self.lengths[mask] += 1
+            live_blocks, cache_bytes, ctx_tokens = \
+                self._cache_accounting(mask)
+            self.serving.note_attend_steps(*steps)
+            if n_active:
+                self.serving.note_attend(*self._attend_work(1, mask),
+                                         n_active)
             span.set_metadata(live_blocks=live_blocks,
                               context_tokens=ctx_tokens,
                               attend_steps=steps[0],
                               attend_live_steps=steps[1])
             if self.cache_spec.per_stream:
                 span.set_metadata(state_pages_live=n_active)
-            self._note_counters(span, counters)
-        out_logits = np.asarray(jax.device_get(logits)) \
-            if return_logits else None
-        return sampled, out_logits
+        lap("dispatch_s")
+        return _Flight(fetch=fetch, logits=logits, mask=mask,
+                       n_active=n_active, t0=t0,
+                       prefill_s=self._prefill_wall,
+                       ahead=int(prev is not None),
+                       cache_bytes=cache_bytes, context_tokens=ctx_tokens)
+
+    def _decode_fetch(self, span, flight: _Flight) -> np.ndarray:
+        """Fetch an iteration's tokens — the serving loop's one sync, the
+        served model's counters riding it — and do what waited for
+        them."""
+        tl, lap = self.telemetry, self.serving.lap
+        with tl.span("decode_fetch"):
+            sampled, counters = split_counters(
+                np.asarray(jax.device_get(flight.fetch)),
+                len(self.served.counter_names))
+        lap("fetch_s")
+        with tl.span("decode_advance"):
+            adv = flight.mask
+            self.last_tokens[adv] = sampled[adv]
+            if self.drafter is not None:
+                for s in np.flatnonzero(adv):
+                    self.drafter.observe(int(s), [int(sampled[s])])
+            # From the later of this iteration's dispatch and the tokens
+            # of the one before, less the admissions between.
+            end = lap("advance_s")
+            start, prefill_s = max((flight.t0, flight.prefill_s),
+                                   self._t_tokens)
+            wall = end - start - (self._prefill_wall - prefill_s)
+            self._t_tokens = (end, self._prefill_wall)
+            self.iterations += 1
+            n_active, tokens = flight.n_active, int(adv.sum())
+            self.serving.note_iteration(
+                n_active, wall, cache_bytes=flight.cache_bytes,
+                context_tokens=flight.context_tokens,
+                emitted_tokens=tokens, ahead=flight.ahead,
+                dropped=flight.dropped)
+            if self.serving.ledger is not None:
+                self.serving.ledger.note("decode_useful", wall)
+            if tl.enabled:
+                tl.record_step(
+                    self.iterations, {}, wall_ms=wall * 1e3,
+                    active_slots=n_active,
+                    occupancy=round(n_active / self.max_slots, 4),
+                    tokens=tokens)
+                tl.maybe_drain(self.iterations,
+                               extra_fn=self._report_extra)
+        self._note_counters(span, counters)
+        return sampled
+
+    def decode_discard(self) -> None:
+        """Forget the iteration in flight without fetching it (a serve
+        cut short). The lengths of the slots it held moved with its
+        dispatch and their tokens are lost: the caller releases every
+        one of them."""
+        self._inflight = None
 
     def spec_decode_once(self, temperature: float = 0.0
                          ) -> Tuple[np.ndarray, np.ndarray]:
@@ -1038,6 +1240,11 @@ class InferenceEngine:
                 "has no rejection-sampling correction); use "
                 "decode_once for temperature > 0 — the scheduler falls "
                 "back automatically")
+        if self._inflight is not None:
+            # The accepted count decides the lengths: nothing here can
+            # be dispatched ahead of its fetch.
+            raise RuntimeError("spec_decode_once: a decode iteration is "
+                               "in flight")
         lap = self.serving.lap       # the timeline's clock
         t0 = lap("other_s")
         tl = self.telemetry
@@ -1149,6 +1356,7 @@ class InferenceEngine:
         self._attach_slo_overlays()
         self._spec_proposed = 0
         self._spec_accepted = 0
+        self._t_tokens = (float("-inf"), 0.0)   # the clock may be another
 
     def _report_extra(self) -> Dict[str, Any]:
         return {"serving": self.serving.snapshot()}

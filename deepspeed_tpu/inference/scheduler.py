@@ -9,6 +9,18 @@ that finished. Requests join and leave mid-flight; the compiled decode
 program never notices, because admission and eviction are counter
 updates plus a dynamic_update_slice splice (inference/kv_cache.py).
 
+The plain decode loop runs ONE ITERATION AHEAD of its token fetch: a
+pass dispatches iteration n+1 — who is in it follows from the lengths
+of the replies, not from n's tokens, and the program reads n's tokens
+where they lie on the device — and only then fetches, emits and
+accounts for iteration n, under n+1's device time (dispatch(n+1) ->
+fetch(n) -> emit(n); ``InferenceEngine.decode_once`` with
+``continuing``). A stream that stops on an EOS is found out one
+iteration late: the row computed for it meanwhile is dropped and
+counted, and the user sees the same tokens. An admission's programs
+queue on the device behind the iteration in flight. The speculative
+loop stays synchronous (the accepted count decides the lengths).
+
 The arrival process is OPEN-LOOP: requests carry absolute arrival
 offsets and join the queue when the wall clock passes them, whether or
 not the engine has capacity — so TTFT honestly includes queue wait, and
@@ -43,6 +55,7 @@ class Request:
     t_first: Optional[float] = None     # first token produced (TTFT end)
     t_last: Optional[float] = None      # latest token produced
     admission_attempts: int = 0         # head-of-queue rejections
+    flying: int = 0                     # tokens dispatched, not yet fetched
     # Rows of the serving timeline (monitor/serving.py) this request was
     # live in: every live stream emits in every row between them
     # (``row_last`` stays -1 while it is).
@@ -178,6 +191,14 @@ class ContinuousBatchingScheduler:
         # Slot full: the next decode would have nowhere to write.
         return slot_len >= self.engine.max_len
 
+    def _continues(self, req: Request, slot: int) -> bool:
+        """Whether ``req`` takes part in the iteration dispatched next:
+        not when the tokens it has in flight complete its reply or fill
+        its slot (the engine's lengths move at the dispatch, so they
+        hold those already). An EOS among them cannot be known yet."""
+        return len(req.out_tokens) + req.flying < req.max_new_tokens \
+            and self.engine.context_len(slot) < self.engine.max_len
+
     def _leave_rows(self, req: Request) -> None:
         """The latest row is the last this request emitted in."""
         if req.row_first >= 0:
@@ -225,9 +246,12 @@ class ContinuousBatchingScheduler:
             self._complete(req)
             eng.release_slot(slot)
         else:
+            # Its first row is the next to be written, or the one after
+            # where that is an iteration dispatched without it.
+            later = int(any(r.flying for r in active.values()))
             req.timeline = eng.serving
-            req.row_first = eng.serving.rows     # the next to be written
-            eng.serving.note_first_token(req.t_first)
+            req.row_first = eng.serving.rows + later
+            eng.serving.note_first_token(req.t_first, later)
             active[slot] = req
 
     def _admit_trace(self, req: Request, slot: int) -> None:
@@ -245,6 +269,38 @@ class ContinuousBatchingScheduler:
                            cow_fork=info.get("cow_fork", False))
         self.trace.first_token(req.rid, t=req.t_first)
 
+    def _emit(self, sampled: np.ndarray, took: np.ndarray,
+              active: Dict[int, Request]) -> None:
+        """Hand an iteration's tokens to the streams that were in it
+        (``took``; a fetched iteration is a row of the timeline even
+        where every stream it held has ended since) and evict the ones
+        that finished."""
+        eng, tel, agg, trace = (self.engine, self.engine.telemetry,
+                                self.engine.serving, self.trace)
+        with tel.span("emit") as span:
+            slots = [slot for slot in active if took[slot]]
+            occ = len(slots)
+            t_now, row = agg.note_emit(occ)
+            finished = []
+            for slot in slots:
+                req = active[slot]
+                req.flying -= 1
+                req.out_tokens.append(int(sampled[slot]))
+                req.t_last = t_now
+                if trace is not None:
+                    trace.tick(req.rid, occ, 1, t=t_now, row=row)
+                # The engine's length holds the tokens in flight too.
+                if self._finished(req,
+                                  eng.context_len(slot) - req.flying):
+                    self._complete(req)
+                    eng.release_slot(slot)
+                    del active[slot]
+                    finished.append(req.rid)
+            if spans_recorded(tel):
+                span.set_metadata(finished=ids_arg(finished),
+                                  **agg.emit_args(occ))
+        agg.lap("emit_s")
+
     # ------------------------------------------------------------------ #
     def serve(self, requests: Sequence[Request]) -> Dict[str, Any]:
         """Run the stream to completion; returns the serving report
@@ -255,7 +311,6 @@ class ContinuousBatchingScheduler:
         clock = agg.clock
         t0 = agg.note_serve_start()
         trace = self.trace
-        ledger = getattr(agg, "ledger", None)
         pending = deque(sorted(requests, key=lambda r: r.arrival_s))
         queue: deque = deque()
         active: Dict[int, Request] = {}
@@ -266,12 +321,75 @@ class ContinuousBatchingScheduler:
         spec = bool(getattr(eng, "spec_enabled", False)) and \
             self.temperature == 0.0
 
+        try:
+            self._loop(pending, queue, active, t0, spec)
+        except BaseException:
+            # Nothing stays in flight and no slot stays held.
+            self._discard_in_flight()
+            for slot in list(active):
+                eng.release_slot(slot)
+            raise
+
+        wall = clock() - t0
+        # Final drain with a SERVE-WALL-anchored snapshot: a run shorter
+        # than report_steps iterations would otherwise never put the
+        # aggregator snapshot (tokens/s, decode-step percentiles) into
+        # any report record, and telemetry_report's serving section
+        # would carry nulls; the last report record wins there, so this
+        # also pins the figure benches compare to the same wall
+        # SERVE_BENCH.json uses.
+        if tel.enabled:
+            tel.drain({"serving": eng.serving.snapshot(
+                wall_s=wall)})
+        report = dict(eng.serving.snapshot(wall_s=wall))
+        if report.get("stalls"):
+            # An untraced run that lost seconds says where its thread
+            # stood (docs/tutorials/inference.md).
+            logger.warning("serve: %d stalled interval(s): %s", len(
+                report["stalls"]), "; ".join(
+                    f"row {st['row']} at {st['at_s']} s waited "
+                    f"{st['gap_ms']} ms, {st['in_ms']} ms over the usual "
+                    f"in {st['in']}" for st in report["stalls"]))
+        report["recompiles"] = eng.telemetry.recompile_count
+        report["unfinished"] = len(pending) + len(queue) + len(active)
+        if trace is not None:
+            report["trace"] = trace.summary()
+        report["requests"] = [
+            {"rid": r.rid, "prompt_tokens": len(r.prompt),
+             "new_tokens": len(r.out_tokens),
+             "ttft_ms": round(r.ttft_s * 1e3, 3)
+             if r.ttft_s is not None else None,
+             "tpot_ms": round(r.tpot_s * 1e3, 3)
+             if r.tpot_s is not None else None,
+             "tokens": list(map(int, r.out_tokens))}
+            for r in sorted(requests, key=lambda r: r.rid)]
+        return report
+
+    def _discard_in_flight(self) -> None:
+        """A serve that ends early forgets the iteration in flight (its
+        tokens would be emitted after the end); the caller releases the
+        slots."""
+        discard = getattr(self.engine, "decode_discard", None)
+        if discard is not None:
+            discard()
+
+    def _loop(self, pending: deque, queue: deque,
+              active: Dict[int, Request], t0: float, spec: bool) -> None:
+        """``serve``'s passes, until the stream is through or
+        ``max_wall_s`` cuts it."""
+        eng = self.engine
+        tel = eng.telemetry
+        agg = eng.serving
+        clock = agg.clock
+        trace = self.trace
+        ledger = getattr(agg, "ledger", None)
         while pending or queue or active:
             now = clock() - t0
             if self.max_wall_s is not None and now > self.max_wall_s:
                 # Abandon the run WITHOUT leaking capacity: mid-flight
                 # slots must come back, or the engine's next serve()
                 # starts with no free slots and spins forever.
+                self._discard_in_flight()
                 abort = getattr(eng, "abort_request", None)
                 t_ab = clock()
                 for slot in list(active):
@@ -406,26 +524,18 @@ class ContinuousBatchingScheduler:
                                           **agg.emit_args(occ))
                 agg.lap("emit_s")
             elif active:
-                sampled, _ = eng.decode_once(self.temperature)
-                with tel.span("emit") as span:
-                    occ = len(active)
-                    t_now, row = agg.note_emit(occ)
-                    finished = []
-                    for slot in list(active):
-                        req = active[slot]
-                        req.out_tokens.append(int(sampled[slot]))
-                        req.t_last = t_now
-                        if trace is not None:
-                            trace.tick(req.rid, occ, 1, t=t_now, row=row)
-                        if self._finished(req, eng.context_len(slot)):
-                            self._complete(req)
-                            eng.release_slot(slot)
-                            del active[slot]
-                            finished.append(req.rid)
-                    if spans_recorded(tel):
-                        span.set_metadata(finished=ids_arg(finished),
-                                          **agg.emit_args(occ))
-                agg.lap("emit_s")
+                # One pass of the loop that runs an iteration ahead:
+                # dispatch the next iteration for every stream whose
+                # reply the tokens in flight do not complete, THEN fetch
+                # and hand out the tokens of the iteration in flight.
+                going = [slot for slot, req in active.items()
+                         if self._continues(req, slot)]
+                sampled, took = eng.decode_once(self.temperature,
+                                                continuing=going)
+                for slot in going:
+                    active[slot].flying += 1
+                if took is not None:
+                    self._emit(sampled, took, active)
             elif pending and not queue:
                 # Idle ahead of the next arrival — open-loop wait. The
                 # watchdog heartbeat says "idle, not hung": a sparse
@@ -460,42 +570,6 @@ class ContinuousBatchingScheduler:
                     if ledger is not None:
                         ledger.note("admission_blocked",
                                     clock() - t_sl)
-
-        wall = clock() - t0
-        # Final drain with a SERVE-WALL-anchored snapshot: a run shorter
-        # than report_steps iterations would otherwise never put the
-        # aggregator snapshot (tokens/s, decode-step percentiles) into
-        # any report record, and telemetry_report's serving section
-        # would carry nulls; the last report record wins there, so this
-        # also pins the figure benches compare to the same wall
-        # SERVE_BENCH.json uses.
-        if tel.enabled:
-            tel.drain({"serving": eng.serving.snapshot(
-                wall_s=wall)})
-        report = dict(eng.serving.snapshot(wall_s=wall))
-        if report.get("stalls"):
-            # An untraced run that lost seconds says where its thread
-            # stood (docs/tutorials/inference.md).
-            logger.warning("serve: %d stalled interval(s): %s", len(
-                report["stalls"]), "; ".join(
-                    f"row {st['row']} at {st['at_s']} s waited "
-                    f"{st['gap_ms']} ms, {st['in_ms']} ms over the usual "
-                    f"in {st['in']}" for st in report["stalls"]))
-        report["recompiles"] = eng.telemetry.recompile_count
-        report["unfinished"] = len(pending) + len(queue) + len(active)
-        if trace is not None:
-            report["trace"] = trace.summary()
-        report["requests"] = [
-            {"rid": r.rid, "prompt_tokens": len(r.prompt),
-             "new_tokens": len(r.out_tokens),
-             "ttft_ms": round(r.ttft_s * 1e3, 3)
-             if r.ttft_s is not None else None,
-             "tpot_ms": round(r.tpot_s * 1e3, 3)
-             if r.tpot_s is not None else None,
-             "tokens": list(map(int, r.out_tokens))}
-            for r in sorted(requests, key=lambda r: r.rid)]
-        return report
-
 
 __all__ = ["Request", "synthetic_requests", "shared_prefix_requests",
            "ContinuousBatchingScheduler"]

@@ -40,12 +40,17 @@ import numpy as np
 # the rows they needed (prompt - cached) and the rows they computed.
 # ``continuing`` streams waited the whole ``gap_s`` (none where no
 # scheduler handed the tokens out: nobody said who waited).  The rest is
-# the iteration's own sample.
+# the iteration's own sample: ``ahead`` is 1 where it was dispatched
+# while the iteration before was still unfetched (the loop runs one
+# iteration ahead of its token fetch: between two emissions lie the
+# dispatch of the NEXT iteration and the fetch of this one), ``dropped``
+# the rows it computed for streams that had ended by then.
 COLUMNS = ("t_emit", "gap_s", "emit_s", "other_s", "admit_s", "prefill_s",
            "copy_s", "tables_s", "dispatch_s", "fetch_s", "advance_s",
            "first_gap_s", "first_stall_s", "continuing", "admitted",
            "prefill_dispatches", "prefill_rows", "prefill_rows_computed",
-           "occupancy", "decode_ms", "cache_bytes", "context_tokens")
+           "occupancy", "decode_ms", "cache_bytes", "context_tokens",
+           "ahead", "dropped")
 COL = {name: i for i, name in enumerate(COLUMNS)}
 # The columns a gap is made of, under the names an operator knows them
 # by (the host spans'; ``other_s`` is no span's).
@@ -124,8 +129,11 @@ class ServingAggregator:
         # ended, for a loop that has no scheduler to say so.
         self._staged: Optional[float] = None
         # First tokens since the last row: how many, the sum of their
-        # times, and of the stall seconds the interval held before each.
+        # times, and of the stall seconds the interval held before each;
+        # ``_first_later`` the same for streams whose first row is the
+        # one AFTER the next (an iteration without them is in flight).
         self._first = [0, 0.0, 0.0]
+        self._first_later = [0, 0.0, 0.0]
         # Where the latest ``serve()`` began (``stalls`` are its own).
         self._serve_row0 = 0
         self._serve_t0 = self.t0
@@ -208,12 +216,13 @@ class ServingAggregator:
         p[COL["prefill_rows_computed"]] += rows_computed
         return self.lap("prefill_s")
 
-    def note_first_token(self, t_first: float) -> None:
+    def note_first_token(self, t_first: float, rows_ahead: int = 0) -> None:
         """A stream joins the rows: its first interval runs from its
         first token (``t_first``, out of its prefill) to the next
-        emission, and the stall in it is only what later admissions
-        add."""
-        p, f = self._pend, self._first
+        emission — to the one after with ``rows_ahead`` 1: the next row
+        is an iteration dispatched without it — and the stall in it is
+        only what later admissions add."""
+        p, f = self._pend, self._first_later if rows_ahead else self._first
         f[0] += 1
         f[1] += t_first
         f[2] += p[COL["prefill_s"]] + p[COL["copy_s"]]
@@ -234,7 +243,12 @@ class ServingAggregator:
         self._t_prev = t_emit
         self._written = p            # the latest row, still a list
         self._pend = [0.0] * len(COLUMNS)
-        self._first = [0, 0.0, 0.0]
+        # Streams due one row later: what this interval stalled them
+        # after their first tokens goes with them.
+        n, t_sum, stall_before = self._first_later
+        self._first = [n, t_sum, stall_before - n * (
+            p[COL["prefill_s"]] + p[COL["copy_s"]])]
+        self._first_later = [0, 0.0, 0.0]
 
     def _flush(self) -> None:
         """Write an iteration's row that no scheduler emitted: it has
@@ -295,13 +309,15 @@ class ServingAggregator:
     def note_iteration(self, active_slots: int, decode_s: float,
                        cache_bytes: Optional[int] = None,
                        context_tokens: Optional[int] = None,
-                       emitted_tokens: Optional[int] = None) -> None:
+                       emitted_tokens: Optional[int] = None,
+                       ahead: int = 0, dropped: int = 0) -> None:
         """Ends the iteration's row (written when its tokens are handed
         out, or when the next begins).  ``emitted_tokens`` defaults to
         one per active slot (plain decode); the speculative verify step
         passes the real count.  ``cache_bytes`` / ``context_tokens``
         sample the HBM the cache holds against the tokens it serves —
-        the hbm_bytes_per_token series the paging win is measured on."""
+        the hbm_bytes_per_token series the paging win is measured on.
+        ``ahead`` / ``dropped``: see ``COLUMNS``."""
         self._flush()                # the one before, if nobody emitted it
         tokens = int(emitted_tokens if emitted_tokens is not None
                      else active_slots)
@@ -310,6 +326,8 @@ class ServingAggregator:
         p = self._pend
         p[COL["occupancy"]] = active_slots / self.max_slots
         p[COL["decode_ms"]] = decode_s * 1e3
+        p[COL["ahead"]] = ahead
+        p[COL["dropped"]] = dropped
         if cache_bytes is not None and context_tokens:
             p[COL["cache_bytes"]] = int(cache_bytes)
             p[COL["context_tokens"]] = int(context_tokens)
@@ -481,7 +499,11 @@ class ServingAggregator:
         ``decode_wait``, ``stall``, ``host``: they sum to it),
         ``itl_stalled_share`` (intervals that held a prefill dispatch),
         ``prefill_row_fill`` (rows the prefills needed / rows their
-        dispatches computed) and ``stalls`` (see ``stalls()``)."""
+        dispatches computed), ``stalls`` (see ``stalls()``),
+        ``lookahead_share`` (iterations dispatched while the one before
+        was still unfetched: the host's pass hid under the device) and
+        ``lookahead_dropped_rows`` (rows computed for streams that had
+        ended: at most one a stream that stops on an EOS)."""
         wall = wall_s if wall_s is not None else self.clock() - self.t0
         table = self._table()
         occupancy = table[:, COL["occupancy"]].tolist()
@@ -541,6 +563,11 @@ class ServingAggregator:
                 "host": 1e3 * itl["host_s"] / n}
             snap["itl_stalled_share"] = round(itl["stalled"] / n, 4)
             snap["stalls"] = self.stalls(table)
+        if len(table):
+            snap["lookahead_share"] = round(
+                float(table[:, COL["ahead"]].mean()), 4)
+            snap["lookahead_dropped_rows"] = int(
+                table[:, COL["dropped"]].sum())
         computed = float(table[:, COL["prefill_rows_computed"]].sum())
         if computed:
             snap["prefill_row_fill"] = round(

@@ -72,6 +72,11 @@ SPAN_ARGS = {
                 "moe_held_mean", "moe_held_empty", "moe_held_pair_share",
                 "resumed_tokens", "snapshot_taken", "state_copy_bytes"),
     "prefill_chunk": ("ci", "active_groups"),
+    # A decode span holds the DISPATCH of one iteration and the FETCH of
+    # the one before (the loop runs an iteration ahead of its token
+    # fetch): iteration .. attend_* and state_pages_live describe the one
+    # dispatched (absent where the span dispatched none), the moe_*
+    # counters the one fetched, each iteration once.
     # attend_steps / attend_live_steps: the paged kernel's sequencing
     # steps a layer in this execution and those that touch a live block
     # (ops.paged_attention.attend_step_counts; zeros on the one-hot path)
@@ -81,7 +86,11 @@ SPAN_ARGS = {
                "moe_held_pair_share",
                # pages of a per-stream pool the state-update kernel
                # rewrote in this execution (one a live stream)
-               "state_pages_live"),
+               "state_pages_live",
+               # 1 where the dispatch went out while the iteration
+               # before was still unfetched; rows the iteration fetched
+               # computed for streams that had ended by then
+               "ahead", "dropped"),
     # The emission's row of the serving timeline (monitor/serving.py),
     # the streams it hands tokens to and those of them that waited the
     # whole interval since the emission before, that interval, the part

@@ -214,9 +214,11 @@ class TestServingStream:
                                   vocab_size=cfg.vocab_size, seed=2)
         # Vary generation length too: slots free at different iterations
         # (a 3-deep saturation backlog keeps refills instant, so the
-        # drain tail doesn't swamp the occupancy average).
+        # drain tail doesn't swamp the occupancy average). The loop runs
+        # an iteration ahead of its token fetch, so a freed slot sits out
+        # the one iteration already in flight: one row of 13 a reply.
         for i, r in enumerate(reqs):
-            r.max_new_tokens = 6 + (i % 3)
+            r.max_new_tokens = 12 + (i % 3)
         report = eng.serve(reqs)
 
         assert report["completed"] == 24 and report["unfinished"] == 0
@@ -227,7 +229,7 @@ class TestServingStream:
             assert report[sec]["n"] > 0
             assert report[sec]["p95"] >= report[sec]["p50"] > 0
         for r in report["requests"]:
-            assert r["new_tokens"] == 6 + (r["rid"] % 3)
+            assert r["new_tokens"] == 12 + (r["rid"] % 3)
         # Every slot drained.
         assert not eng.active.any() and (eng.lengths == 0).all()
 
@@ -293,10 +295,11 @@ class TestServingStream:
             def context_len(self, slot):
                 return 10
 
-            def decode_once(self, temperature=0.0):
+            def decode_once(self, temperature=0.0, continuing=()):
+                # a synchronous engine: the iteration fetched is its own
                 self.serving.note_iteration(int(self.active.sum()), 1e-4)
                 _time.sleep(0.001)
-                return np.ones(2, np.int32), None
+                return np.ones(2, np.int32), self.active.copy()
 
             def complete_request(self, *a, **k):
                 self.serving.note_request(0.01, None, 1)

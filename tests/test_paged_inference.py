@@ -338,7 +338,8 @@ class TestInPlaceWrite:
             eng.decode_once()
             fn, args = eng._decode_fn, (
                 eng._params, eng.cache["k"], eng.cache["v"],
-                jnp.zeros(8, jnp.int32), jnp.zeros(8, jnp.int32),
+                eng._no_fetch, jnp.zeros(8, jnp.int32),
+                jnp.ones(8, bool), jnp.zeros(8, jnp.int32),
                 jnp.asarray(eng.block_tables), eng._next_key(),
                 jnp.float32(0))
         else:

@@ -183,7 +183,8 @@ def serve_op_names(serve_engine):
     G, J = eng.dp, eng.cache_spec.max_blocks_per_slot
     key, temp = eng._next_key(), np.float32(0.0)
     k, v = eng.cache["k"], eng.cache["v"]
-    decode = _op_names(eng._decode_fn, eng._params, k, v, eng.last_tokens,
+    decode = _op_names(eng._decode_fn, eng._params, k, v, eng._no_fetch,
+                       eng.last_tokens, np.ones(eng.max_slots, bool),
                        eng.lengths, eng.block_tables, key, temp)
     prefill = _op_names(
         eng._prefill_fn, eng._params, k, v,
@@ -222,8 +223,11 @@ SERVE_SPANS = {
                 "rows_computed", "rids"},
     "prefill_plan": set(), "prefill_chunk": {"ci", "active_groups"},
     "prefill_fetch": set(),
-    "decode": {"iteration", "active", "live_blocks", "context_tokens",
-               "attend_steps", "attend_live_steps"},
+    # (of every ``decode`` span; the ones that dispatched an iteration
+    # carry DISPATCHED too: the loop runs an iteration ahead of its token
+    # fetch, so a span holds the dispatch of one iteration and the fetch
+    # of the one before, or only one of the two at a stretch's ends)
+    "decode": {"iteration", "active", "ahead", "dropped"},
     "decode_tables": set(), "decode_dispatch": set(),
     "decode_fetch": set(), "decode_advance": set(),
     # (``finished`` too, where a request finished: an empty arg is not
@@ -231,8 +235,15 @@ SERVE_SPANS = {
     "emit": {"row", "streams", "continuing", "gap_ms", "stall_ms",
              "host_ms"},
     "serve_idle": {"why"}}
+DISPATCHED = {"live_blocks", "context_tokens", "attend_steps",
+              "attend_live_steps"}
 TRAIN_SPANS = {"train_batch": {"step_num"}, "data_prep": {"step"},
                "step_dispatch": {"step"}, "step_log": {"step"}}
+
+
+def _dispatched(found):
+    """The args of the ``decode`` spans that dispatched an iteration."""
+    return [a for _, _, a in found["decode"] if "context_tokens" in a]
 
 
 @pytest.fixture(scope="module")
@@ -291,9 +302,22 @@ def test_serve_spans_occur_once_per_occurrence(serve_annotations):
     found, report = serve_annotations
     iters = report["iterations"]
     assert iters >= 3
-    for name in ("decode", "decode_tables", "decode_dispatch",
-                 "decode_fetch", "decode_advance", "emit"):
+    for name in ("decode_tables", "decode_dispatch", "decode_fetch",
+                 "decode_advance", "emit"):
         assert len(found[name]) == iters, (name, len(found[name]), iters)
+    # Two stretches (the pair at once, the late arrival), each of two
+    # iterations: dispatch A | dispatch B + fetch A | fetch B. Each
+    # iteration is described once, by the span that dispatched it.
+    decodes = [a for _, _, a in found["decode"]]
+    assert len(decodes) == iters + 2
+    assert all(DISPATCHED <= set(a) for a in _dispatched(found))
+    assert [a["iteration"] for a in _dispatched(found)] == \
+        list(range(decodes[0]["iteration"], decodes[0]["iteration"] + iters))
+    assert [a["ahead"] for a in decodes] == [0, 1, 0] * 2
+    assert [DISPATCHED <= set(a) for a in decodes] == [True, True, False] * 2
+    assert all(a["dropped"] == 0 for a in decodes)
+    assert report["lookahead_share"] == 0.5      # B of A, B; D of C, D
+    assert report["lookahead_dropped_rows"] == 0
     # one prefill per admission batch (one chip: one slot per batch)
     admitted = [a for _, _, a in found["admit"] if a["admitted"]]
     assert len(admitted) == len(found["prefill"]) == 3
@@ -326,9 +350,9 @@ def test_serve_spans_nest_and_follow_a_request(serve_annotations):
     # the late arrival was polled for at or after its due time
     assert all(a["late_ms"] >= 0 for _, _, a in found["admit"])
     assert {a["why"] for _, _, a in found["serve_idle"]} == {"no_arrival"}
-    # decode's end-of-span args: what _cache_accounting read
+    # the dispatched iteration's args: what _cache_accounting read
     assert all(a["live_blocks"] > 0 and a["context_tokens"] > 0
-               for _, _, a in found["decode"])
+               for a in _dispatched(found))
 
 
 def test_emit_and_prefill_spans_carry_the_timeline(serve_annotations,
@@ -339,10 +363,11 @@ def test_emit_and_prefill_spans_carry_the_timeline(serve_annotations,
     found, report = serve_annotations
     emits = [a for _, _, a in found["emit"]]
     assert [a["row"] for a in emits] == list(range(report["iterations"]))
-    # a row is the ``decode`` before it (the engine counts its iterations
-    # from its start, the rows from ``reset_serving_stats``)
-    assert len({d[2]["iteration"] - a["row"]
-                for d, a in zip(found["decode"], emits)}) == 1
+    # a row is an iteration, in the order of their dispatch (the engine
+    # counts its iterations from its start, the rows from
+    # ``reset_serving_stats``)
+    assert len({d["iteration"] - a["row"]
+                for d, a in zip(_dispatched(found), emits)}) == 1
     assert all(a["gap_ms"] > 0 and a["stall_ms"] >= 0 for a in emits)
     assert [(a["streams"], a["continuing"]) for a in emits] == \
         [(2, 0), (2, 2), (1, 0), (1, 1)]
@@ -368,7 +393,7 @@ def test_decode_span_counts_the_attend_steps(serve_annotations,
     model's heads are one head block. The running ratio is in the
     serving snapshot."""
     found, report = serve_annotations
-    args = [a for _, _, a in found["decode"]]
+    args = _dispatched(found)
     assert all(a["attend_steps"] == serve_engine.max_slots for a in args)
     assert all(a["attend_live_steps"] == a["active"] for a in args)
     assert {a["active"] for a in args} == {1, 2}
@@ -382,7 +407,7 @@ def test_the_readers_list_names_the_same_args():
     assert set(SPAN_ARGS) <= set(SPANS)
     for span, args in {**SERVE_SPANS, **TRAIN_SPANS}.items():
         assert args <= set(SPAN_ARGS.get(span, ())), span
-    assert {"attend_steps", "attend_live_steps"} <= set(SPAN_ARGS["decode"])
+    assert DISPATCHED <= set(SPAN_ARGS["decode"])
 
 
 def test_attend_step_counts_from_live_blocks():
@@ -449,8 +474,9 @@ def latent_op_names(latent_engine):
     pool = eng.cache["latent"]
     return {
         "decode": _op_names(eng._decode_fn, eng._params, pool,
-                            eng.last_tokens, eng.lengths, eng.block_tables,
-                            key, temp),
+                            eng._no_fetch, eng.last_tokens,
+                            np.ones(eng.max_slots, bool), eng.lengths,
+                            eng.block_tables, key, temp),
         "prefill": _op_names(
             eng._prefill_fn, eng._params, pool,
             np.zeros((G, eng.prefill_chunk), np.int32),
@@ -490,10 +516,14 @@ def test_decode_and_prefill_spans_carry_the_expert_counters(tmp_path,
     reqs = [Request(rid=i, prompt=rng.integers(0, 250, size=11 + i,
                                                dtype=np.int32),
                     max_new_tokens=5, arrival_s=0.0) for i in range(3)]
-    found = _session(tmp_path, lambda: eng.serve(reqs))
+    report = {}
+    found = _session(tmp_path, lambda: report.update(eng.serve(reqs)))
     cfg = eng.model_cfg
-    for span in ("decode", "prefill"):
-        args = [a for _, _, a in found[span]]
+    # a ``decode`` span carries the counters of the iteration it FETCHED
+    # (the first of a stretch fetched none): each iteration's once
+    fetched = [a for _, _, a in found["decode"] if "moe_held_pairs" in a]
+    assert len(fetched) == report["iterations"] == len(_dispatched(found))
+    for args in (fetched, [a for _, _, a in found["prefill"]]):
         assert args and all(a["moe_held_pairs"] > 0 for a in args)
         for a in args:
             assert a["moe_held_max"] >= a["moe_held_mean"] > 0
@@ -501,8 +531,9 @@ def test_decode_and_prefill_spans_carry_the_expert_counters(tmp_path,
             assert 0 <= a["moe_held_empty"] <= \
                 cfg.num_moe_layers * cfg.held[1] * 8
     # decode: rows = active slots, so pairs <= active x k x expert layers
-    for _, _, a in found["decode"]:
-        assert a["moe_held_pairs"] <= a["active"] * 4 * cfg.num_moe_layers
+    # (``active`` is on the span that dispatched the iteration)
+    for a, d in zip(fetched, _dispatched(found)):
+        assert a["moe_held_pairs"] <= d["active"] * 4 * cfg.num_moe_layers
     means = eng.serving.snapshot()["model_counters"]
     assert set(means) >= {"moe_held_pairs", "moe_held_max",
                           "moe_held_mean", "moe_held_empty",
@@ -532,8 +563,9 @@ def retention_op_names():
     pools = eng._pools()
     names = {
         "decode": _op_names(eng._decode_fn, eng._params, *pools,
-                            eng.last_tokens, eng.lengths, eng.block_tables,
-                            key, temp),
+                            eng._no_fetch, eng.last_tokens,
+                            np.ones(eng.max_slots, bool), eng.lengths,
+                            eng.block_tables, key, temp),
         "prefill": _op_names(
             eng._prefill_fn, eng._params, *pools,
             np.zeros((G, eng.prefill_chunk), np.int32),

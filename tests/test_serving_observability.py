@@ -527,10 +527,11 @@ class TestServingObservabilityStream:
             def context_len(self, slot):
                 return 10
 
-            def decode_once(self, temperature=0.0):
+            def decode_once(self, temperature=0.0, continuing=()):
+                # a synchronous engine: the iteration fetched is its own
                 self.serving.note_iteration(int(self.active.sum()), 1e-4)
                 _time.sleep(0.001)
-                return np.ones(2, np.int32), None
+                return np.ones(2, np.int32), self.active.copy()
 
             def complete_request(self, *a, **k):
                 self.serving.note_request(0.01, None, 1)

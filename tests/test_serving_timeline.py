@@ -67,13 +67,17 @@ class FakeEngine:
     """Scheduler-facing surface of ``InferenceEngine`` over a driven
     clock: each operation takes a fixed time, filed in the timeline where
     the real engine files it.  ``stall`` = {iteration: (part, seconds)}
-    adds seconds to one part of one iteration."""
+    adds seconds to one part of one iteration.  ``ahead``: the real
+    engine's order — a call dispatches the next iteration and only then
+    fetches the one in flight — where the plain fake is synchronous."""
     max_len, dp = 10_000, 1
     COST = {"tables_s": 0.0004, "dispatch_s": 0.0011, "fetch_s": 0.0080,
             "advance_s": 0.0006, "prefill_s": 0.0200, "copy_s": 0.0003}
 
-    def __init__(self, clock, slots=4, chunk=0, spec_k=0, stall=None):
+    def __init__(self, clock, slots=4, chunk=0, spec_k=0, stall=None,
+                 ahead=False):
         self.clock, self.max_slots = clock, slots
+        self.ahead, self.flight = ahead, None
         self.prefill_chunk, self.spec_k = chunk, spec_k
         self.spec_enabled = spec_k > 0
         self.telemetry = _Tel()
@@ -152,9 +156,42 @@ class FakeEngine:
             self.token_times[self.slot_rid[slot]] += \
                 [self.clock.t] * int(counts[slot])
 
-    def decode_once(self, temperature=0.0):
+    def decode_once(self, temperature=0.0, continuing=()):
+        if self.ahead:
+            return self._ahead(list(continuing))
+        # synchronous: the iteration it fetches is the one it dispatched
         self._iterate(np.ones(self.max_slots, int))
-        return np.full(self.max_slots, 5, np.int32), None
+        return np.full(self.max_slots, 5, np.int32), self.active.copy()
+
+    def _ahead(self, continuing):
+        lap = self.serving.lap
+        t0 = lap("other_s")
+        due, self.flight = self.flight, None
+        if continuing:
+            for part in ("tables_s", "dispatch_s"):
+                self.clock.t += self.COST[part]
+                lap(part)
+            mask = np.zeros(self.max_slots, bool)
+            mask[continuing] = True
+            self.flight = (mask, t0, int(due is not None))
+        if due is None:
+            return None, None
+        mask, t0, ahead = due
+        for part in ("fetch_s", "advance_s"):
+            self.clock.t += self.COST[part]
+            now = lap(part)
+        self.iterations += 1
+        self.serving.note_iteration(
+            int(mask.sum()), now - t0,
+            cache_bytes=1000 * int(mask.sum()) + 17 * self.iterations,
+            context_tokens=int(self.lengths[mask].sum()), ahead=ahead)
+        for slot in np.flatnonzero(mask):
+            self.lengths[slot] += 1
+            self.token_times[self.slot_rid[slot]].append(self.clock.t)
+        return np.full(self.max_slots, 5, np.int32), mask
+
+    def decode_discard(self):
+        self.flight = None
 
     def spec_decode_once(self, temperature=0.0):
         k = self.spec_k
@@ -181,7 +218,8 @@ def _serve(engine, reqs, **kw):
 
 MODES = [pytest.param(dict(chunk=0), id="plain"),
          pytest.param(dict(chunk=64), id="plain-batched-prefill"),
-         pytest.param(dict(chunk=64, spec_k=3), id="speculative")]
+         pytest.param(dict(chunk=64, spec_k=3), id="speculative"),
+         pytest.param(dict(chunk=64, ahead=True), id="ahead")]
 
 
 @pytest.fixture(params=MODES)
@@ -204,8 +242,9 @@ def test_token_times_equal_the_engines_own_recording(run):
         assert r.row_last - r.row_first + 2 == len(r.token_times())
 
 
-def test_requests_cut_by_the_windows_end_keep_their_rows():
-    eng = FakeEngine(Clock())
+@pytest.mark.parametrize("ahead", [False, True])
+def test_requests_cut_by_the_windows_end_keep_their_rows(ahead):
+    eng = FakeEngine(Clock(), ahead=ahead)
     reqs = _requests(6, new=(40, 50))
     report = _serve(eng, reqs, max_wall_s=0.2)
     assert report["unfinished"] == 2 and report["completed"] == 0
@@ -229,8 +268,11 @@ def test_a_request_admitted_between_rows_starts_from_its_first_token(run):
         t = r.token_times()
         row = agg._rows[r.row_first]
         assert t[0] == r.t_first and t[1] == row[COL["t_emit"]]
-        # shorter than the interval the continuing streams waited
-        assert 0 < t[1] - t[0] < row[COL["gap_s"]]
+        # shorter than the interval the continuing streams waited (than
+        # that and the one before, where its first row is the one after
+        # the next: an iteration without it was in flight)
+        before = agg._rows[r.row_first - 1][COL["gap_s"]] if eng.ahead else 0
+        assert 0 < t[1] - t[0] < row[COL["gap_s"]] + before
         assert row[COL["admitted"]] >= 1
 
 
@@ -441,10 +483,10 @@ def test_a_stall_between_spans_and_the_cap_of_eight():
     reqs = _requests(4, gap_s=0.0, new=(60, 61))
     orig = eng.decode_once
 
-    def slow(temperature=0.0):
+    def slow(temperature=0.0, **kw):
         if eng.iterations % 5 == 4:      # the scheduler's own thread stood
             clock.t += 0.3 + 0.01 * eng.iterations
-        return orig(temperature)
+        return orig(temperature, **kw)
     eng.decode_once = slow
     report = _serve(eng, reqs)
     assert len(report["stalls"]) == 8

@@ -458,6 +458,7 @@ def _serve_program(topo, program, head_dim):
     S, J, C = spec.num_slots, spec.max_blocks_per_slot, eng.prefill_chunk
     pool = on_chip(jax.ShapeDtypeStruct(spec.shape, spec.dtype))
     i32 = lambda *shape: on_chip(jax.ShapeDtypeStruct(shape, jnp.int32))
+    fresh = lambda n: on_chip(jax.ShapeDtypeStruct((n,), jnp.bool_))
     key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
     temp = on_chip(jax.ShapeDtypeStruct((), jnp.float32))
     mp = pytest.MonkeyPatch()
@@ -470,8 +471,8 @@ def _serve_program(topo, program, head_dim):
         compilation_cache.reset_cache()
         if program == "decode_step":
             step = eng._build_decode_step()
-            args = (params, pool, pool, i32(S), i32(S), i32(S, J), key,
-                    temp)
+            args = (params, pool, pool, i32(S), i32(S), fresh(S), i32(S),
+                    i32(S, J), key, temp)
         else:
             step = eng._build_prefill_step()
             args = (params, pool, pool, i32(1, C), i32(1, J), i32(1),
@@ -676,6 +677,7 @@ def latent_programs(topo):
     pool = on_chip(jax.ShapeDtypeStruct(spec.pool_shapes["latent"],
                                         spec.dtype))
     i32 = lambda *shape: on_chip(jax.ShapeDtypeStruct(shape, jnp.int32))
+    fresh = lambda n: on_chip(jax.ShapeDtypeStruct((n,), jnp.bool_))
     key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
     temp = on_chip(jax.ShapeDtypeStruct((), jnp.float32))
     mp = pytest.MonkeyPatch()
@@ -686,7 +688,8 @@ def latent_programs(topo):
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         out["decode_step"] = eng._build_decode_step().lower(
-            params, pool, i32(S), i32(S), i32(S, J), key, temp).compile()
+            params, pool, i32(S + len(eng.served.counter_names)), i32(S),
+            fresh(S), i32(S), i32(S, J), key, temp).compile()
         out["prefill_step"] = eng._build_prefill_step().lower(
             params, pool, i32(1, C), i32(1, J), i32(1), i32(1), i32(1), key,
             temp).compile()
@@ -797,6 +800,7 @@ def retention_programs(topo):
     pools = [on_chip(jax.ShapeDtypeStruct(spec.pool_shapes[n], spec.dtype))
              for n in spec.pool_names]
     i32 = lambda *shape: on_chip(jax.ShapeDtypeStruct(shape, jnp.int32))
+    fresh = lambda n: on_chip(jax.ShapeDtypeStruct((n,), jnp.bool_))
     key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
     temp = on_chip(jax.ShapeDtypeStruct((), jnp.float32))
     mp = pytest.MonkeyPatch()
@@ -807,7 +811,8 @@ def retention_programs(topo):
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         out["decode_step"] = eng._build_decode_step().lower(
-            params, *pools, i32(S), i32(S), i32(S, J), key, temp).compile()
+            params, *pools, i32(S + len(eng.served.counter_names)), i32(S),
+            fresh(S), i32(S), i32(S, J), key, temp).compile()
         out["prefill_step"] = eng._build_prefill_step().lower(
             params, *pools, i32(1, C), i32(1, J), i32(1), i32(1), i32(1),
             key, temp).compile()

@@ -499,7 +499,8 @@ def summarize(jsonl_path: str) -> Dict[str, Any]:
                     "attend", "attend_work_ratio", "admission",
                     # the serving timeline's figures (monitor/serving.py)
                     "itl_ms", "itl_split_ms", "itl_stalled_share",
-                    "prefill_row_fill", "stalls"):
+                    "prefill_row_fill", "stalls", "lookahead_share",
+                    "lookahead_dropped_rows"):
             if serve_snap.get(sec) is not None:
                 serving[sec] = serve_snap[sec]
         # Multi-replica streams: request_complete events carry replica
