@@ -330,7 +330,10 @@ INFERENCE_BLOCK_SIZE_DEFAULT = 16
 # scheduler's admission gate then accounts free blocks (the serving
 # snapshot's hbm_bytes_per_token is the HBM held per context token). Must
 # be divisible by the mesh dp-axis size (blocks are born sharded over
-# dp alongside the slots they serve).
+# dp alongside the slots they serve). A served model that keeps its layers
+# in several CLASSES (say full and sliding-window attention;
+# inference/kv_cache.py) has a pool a class and takes {class name: blocks};
+# a class left out, or at 0, is fully provisioned (every slot's table full).
 INFERENCE_NUM_BLOCKS = "num_blocks"
 INFERENCE_NUM_BLOCKS_DEFAULT = 0
 # Speculative decoding (draft-then-verify, Leviathan et al. 2023):

@@ -6,9 +6,11 @@ Architecture (mirrors the training engine's discipline):
 - The MODEL comes in as a served model (inference/served.py): what it
   keeps in the paged pool — rows a token and layer, or a fixed-size
   state a stream (then a block is a page, and the prefix cache keeps
-  snapshots: inference/kv_cache.py) — and its decode / verify / prefill
-  programs. Everything below — slots, admission, the allocator,
-  the prefix cache, sampling, spans — is the same for every model.
+  snapshots: inference/kv_cache.py), in one class of layers or several
+  (window layers keep only what is in reach: a pool, a table and an
+  allocator a class) — and its decode / verify / prefill programs.
+  Everything below — slots, admission, the allocator, the prefix cache,
+  sampling, spans — is the same for every model.
 - TWO compiled programs serve everything: ``decode_step`` (one token for
   every slot at once) and ``prefill_step`` (one chunk of one slot's
   prompt — or the whole padded prompt when ``prefill_chunk: 0``). Both
@@ -140,19 +142,6 @@ class InferenceEngine:
                 f"inference.max_seq_len ({self.max_len})")
         self.spec_k = int(self.icfg.spec_k)
         self.replica = str(self.icfg.replica)
-        self.num_blocks = int(self.icfg.num_blocks)
-        if self.num_blocks == 0:
-            # Full provisioning: every slot can reach max_len, so
-            # admission never blocks on HBM; smaller pools oversubscribe
-            # and the admission gate accounts free blocks.
-            self.num_blocks = self.max_slots * (
-                1 if served.cache_per_stream
-                else self.max_len // self.block_size)
-        if self.num_blocks % self.dp:
-            raise ValueError(
-                f"inference.num_blocks={self.num_blocks} must be "
-                f"divisible by the mesh data axis ({self.dp}) — blocks "
-                "are born sharded over dp alongside their slots")
         # Pallas paged-attention kernel vs the one-hot pool contraction.
         # Resolved ONCE here: the compiled paths bake the choice in, so
         # flipping the env var mid-flight cannot desync the sentinel.
@@ -184,21 +173,60 @@ class InferenceEngine:
                 f"rows; {served.name} keeps a state per stream")
         kv_dtype = served.cache_dtype or resolve_kv_dtype(
             self.icfg.kv_cache_dtype, served.dtype)
-        self.cache_spec = kv_cache.PagedKVCacheSpec(
-            num_layers=served.cache_layers,
-            num_slots=self.max_slots, num_blocks=self.num_blocks,
-            block_size=self.block_size, max_len=self.max_len,
-            num_heads=served.cache_heads,
-            head_dim=served.cache_row_width, num_groups=self.dp,
-            dtype=kv_dtype, pools=served.cache_pools(self.block_size),
-            per_stream=served.cache_per_stream,
-            token_row_bytes=served.token_row_bytes)
-        self.cache = kv_cache.init_paged_cache(self.cache_spec, self.mesh)
-        self._cache_sh = kv_cache.paged_shardings(
-            self.mesh, self.cache_spec.pool_names)
-        self.allocator = kv_cache.BlockAllocator(self.cache_spec)
+        # One spec, pool set and allocator a CLASS of cache layers (one
+        # class for most models; kv_cache.py's docstring). A bounded
+        # class's table is a ring as wide as one program's queries reach.
+        rows = max(self.prefill_chunk or self.max_len, self.spec_k + 1)
+        classes = served.cache_classes
+        asked = self.icfg.num_blocks
+        if asked and isinstance(asked, dict) != (len(classes) > 1):
+            raise ValueError(
+                f"inference.num_blocks={asked!r}: {served.name} keeps "
+                + (f"its cache layers in classes "
+                   f"{[c.name for c in classes]} and takes "
+                   "{class name: blocks}" if len(classes) > 1 else
+                   "one class of cache layers and takes an int"))
+        specs = []
+        for cls in classes:
+            ring = 0 if cls.reach is None else min(
+                self.max_len // self.block_size,
+                (cls.reach + rows - 2) // self.block_size + 2)
+            # 0 (or a class left out): full provisioning — every slot's
+            # table full, so admission never blocks on HBM; smaller pools
+            # oversubscribe and the admission gate accounts free blocks.
+            blocks = int((asked.get(cls.name, 0) if isinstance(asked, dict)
+                          else asked) or self.max_slots * (
+                1 if served.cache_per_stream
+                else ring or self.max_len // self.block_size))
+            if blocks % self.dp:
+                raise ValueError(
+                    f"inference.num_blocks={blocks} must be "
+                    f"divisible by the mesh data axis ({self.dp}) — blocks "
+                    "are born sharded over dp alongside their slots")
+            specs.append(kv_cache.PagedKVCacheSpec(
+                num_layers=cls.layers,
+                num_slots=self.max_slots, num_blocks=blocks,
+                block_size=self.block_size, max_len=self.max_len,
+                num_heads=served.cache_heads,
+                head_dim=served.cache_row_width, num_groups=self.dp,
+                dtype=kv_dtype, pools=served.cache_pools(self.block_size),
+                per_stream=served.cache_per_stream,
+                token_row_bytes=served.token_row_bytes,
+                name=cls.name, reach=cls.reach, table_blocks=ring))
+        self.cache_specs = tuple(specs)
+        self.cache_spec = specs[0]
+        self.num_blocks = sum(sp.num_blocks for sp in specs)
+        served.table_widths = tuple(sp.max_blocks_per_slot for sp in specs)
+        self.cache, self._cache_sh = {}, {}
+        for spec in specs:
+            self.cache.update(kv_cache.init_paged_cache(spec, self.mesh))
+            self._cache_sh.update(kv_cache.paged_shardings(
+                self.mesh, spec.pool_names))
+        self.allocator = kv_cache.BlockAllocator(specs[0]) \
+            if len(specs) == 1 and specs[0].reach is None \
+            else kv_cache.ClassAllocators(specs)
         self.block_tables = np.full(
-            (self.max_slots, self.cache_spec.max_blocks_per_slot),
+            (self.max_slots, self.allocator.table_width),
             kv_cache.DEAD_BLOCK, np.int32)
         self.drafter = NGramDrafter(self.spec_k, self.icfg.spec_ngram) \
             if self.spec_k > 0 else None
@@ -251,7 +279,13 @@ class InferenceEngine:
                         quantize=self.quantize,
                         precision=jnp.dtype(served.dtype).name,
                         param_bytes=self.param_bytes,
-                        kv_cache_bytes=self.cache_spec.nbytes())
+                        kv_cache_bytes=sum(sp.nbytes() for sp in specs))
+        if len(specs) > 1:
+            tel_meta["cache_classes"] = {
+                sp.name: {"layers": sp.num_layers, "reach": sp.reach,
+                          "num_blocks": sp.num_blocks,
+                          "table_blocks": sp.max_blocks_per_slot,
+                          "bytes": sp.nbytes()} for sp in specs}
         # Analytic attend pricing (both ways, per generated token at
         # the bounds): the kernel term scales with live context
         # (ceil(ctx/bs)*bs — quoted at ctx = max_seq_len), the
@@ -312,8 +346,8 @@ class InferenceEngine:
             f"slots={self.max_slots} (dp={self.dp}), "
             f"cache=paged bs={self.block_size} x{self.num_blocks} blocks "
             f"{self.max_len}x{served.cache_heads}h "
-            f"({self.cache_spec.nbytes() / 2 ** 20:.1f} MiB "
-            f"{'+'.join(self.cache_spec.pool_names)}), "
+            f"({sum(sp.nbytes() for sp in specs) / 2 ** 20:.1f} MiB "
+            f"{'+'.join(self._cache_sh)}), "
             f"prefill={'full' if self.prefill_chunk == 0 else f'chunk {self.prefill_chunk}'}, "
             f"spec_k={self.spec_k}, quantize={self.quantize}"
             + (f", replica={self.replica}" if self.replica else ""),
@@ -507,8 +541,7 @@ class InferenceEngine:
         self.last_tokens[slot] = 0
         self._held.discard(slot)
         row = self.block_tables[slot]
-        self.allocator.release(
-            slot, [int(b) for b in row if b != kv_cache.DEAD_BLOCK])
+        self.allocator.release(slot, row)
         row[:] = kv_cache.DEAD_BLOCK
         if self.drafter is not None:
             self.drafter.reset(slot)
@@ -524,17 +557,14 @@ class InferenceEngine:
     def spec_enabled(self) -> bool:
         return self.spec_k > 0
 
-    def _ensure_blocks(self, slot: int, upto_pos: int) -> None:
-        """Lazily allocate table entries so ``slot`` can write token
-        positions up to ``upto_pos`` — the per-iteration HBM growth the
-        hbm_bytes_per_token metric tracks."""
-        J = self.cache_spec.max_blocks_per_slot
-        need_j = min(upto_pos // self.block_size, J - 1)
-        row = self.block_tables[slot]
-        j = int((row != kv_cache.DEAD_BLOCK).sum())
-        while j <= need_j:
-            row[j] = self.allocator.alloc_block(slot)
-            j += 1
+    def _ensure_blocks(self, slot: int, first_pos: int,
+                       upto_pos: int) -> None:
+        """Make ``slot``'s table ready for a program whose queries span
+        positions ``[first_pos, upto_pos]``: blocks are drawn lazily, and
+        a class of window layers first returns what lies behind
+        ``first_pos``'s reach (``BlockAllocator.extend``)."""
+        self.allocator.extend(slot, self.block_tables[slot], first_pos,
+                              upto_pos)
 
     # ------------------------------------------------------------------ #
     # Admission (the scheduler's gate): slot occupancy AND HBM blocks
@@ -669,7 +699,7 @@ class InferenceEngine:
             padded = np.zeros(self.max_len, np.int32)
             padded[:plen] = prompt
             G = self.dp
-            J = self.cache_spec.max_blocks_per_slot
+            J = self.allocator.table_width
             group = slot // self.cache_spec.slots_per_group
             with tl.span("prefill_plan"):
                 plan = self.allocator.admit_prompt(
@@ -678,8 +708,9 @@ class InferenceEngine:
                 row = np.full(J, kv_cache.DEAD_BLOCK, np.int32)
                 row[:len(plan.table)] = plan.table
                 self.block_tables[slot] = row
+                self._ensure_blocks(slot, 0, plen - 1)
                 bt_rows = np.full((G, J), kv_cache.DEAD_BLOCK, np.int32)
-                bt_rows[group] = row
+                bt_rows[group] = self.block_tables[slot]
             with tl.span("prefill_chunk", ci=0, active_groups=1):
                 *pools, tok, logits = self._prefill_fn(
                     self._params, *self._pools(), padded, bt_rows,
@@ -772,6 +803,12 @@ class InferenceEngine:
                               rows_computed=computed)
             if self.cache_spec.per_stream:
                 span.set_metadata(**self._note_state_admissions(plans))
+            by_class: Dict[str, int] = {}
+            for p in plans:
+                for name, n in (p[2].cached_by_class or {}).items():
+                    by_class["cached_tokens_" + name] = \
+                        by_class.get("cached_tokens_" + name, 0) + n
+            span.set_metadata(**by_class)
         wall = self.serving.note_prefill_pass(
             len(steps), sum(p[4] for p in plans) - cached, computed) \
             - t_pf0 - waited
@@ -819,7 +856,7 @@ class InferenceEngine:
         [(slot, group, plan, prompt, plen)], [([(first token, tokens) per
         chunk], index of the chunk a snapshot follows or None)])."""
         G = self.dp
-        J = self.cache_spec.max_blocks_per_slot
+        J = self.allocator.table_width
         Sg = self.cache_spec.slots_per_group
         chunk = self.prefill_chunk
         pools = self._pools()
@@ -869,6 +906,9 @@ class InferenceEngine:
             self._last_admit[slot] = {
                 "cached_tokens": int(plan.matched), "chunks": len(chunks),
                 "cow_fork": plan.cow_src is not None}
+            if plan.cached_by_class:
+                self._last_admit[slot]["cached_by_class"] = dict(
+                    plan.cached_by_class)
         return pools, plans, tails
 
     def _run_prefill_chunks(self, pools, plans, tails, temp):
@@ -877,7 +917,7 @@ class InferenceEngine:
         ([(tok_g, logits_g) device arrays per chunk index], {slot: (ci,
         group) of its last chunk})."""
         G = self.dp
-        J = self.cache_spec.max_blocks_per_slot
+        J = self.allocator.table_width
         chunk = self.prefill_chunk
         held = {}
         steps = []
@@ -896,6 +936,7 @@ class InferenceEngine:
                         continue
                     first, n = chunks[ci]
                     toks[group, :n] = prompt[first:first + n]
+                    self._ensure_blocks(slot, first, first + n - 1)
                     bt_rows[group] = self.block_tables[slot]
                     starts[group] = first
                     act[group] = 1
@@ -953,8 +994,30 @@ class InferenceEngine:
         held."""
         tokens = int(self.lengths[self.active if mask is None
                                   else mask].sum())
-        live = self.allocator.blocks_in_use()
-        return live, live * self.cache_spec.block_nbytes(), tokens
+        return (self.allocator.blocks_in_use(),
+                self.allocator.bytes_in_use(), tokens)
+
+    def _class_args(self, mask: np.ndarray) -> Dict[str, int]:
+        """The ``decode`` span's args of a model with NAMED classes of
+        cache layers (none otherwise): every class's blocks in use and
+        blocks its streams returned so far, and the key rows the iteration
+        may read (``context_tokens_in_reach``: over the slots in ``mask``,
+        classes and layers, a stream's context as far as the class
+        reaches); the classes' totals also go into ``snapshot()``."""
+        stats = self.allocator.class_stats()
+        if not stats:
+            return {}
+        self.serving.note_cache_classes(stats)
+        args = {}
+        for name, st in stats.items():
+            args[name + "_blocks_live"] = st["live"]
+            args[name + "_blocks_returned"] = st["returned"]
+        lens = self.lengths[mask].astype(np.int64)
+        args["context_tokens_in_reach"] = int(sum(
+            sp.num_layers * (lens if sp.reach is None
+                             else np.minimum(lens, sp.reach)).sum()
+            for sp in self.cache_specs))
+        return args
 
     def _attend_steps(self, k_rows: int,
                       lengths: Optional[np.ndarray] = None,
@@ -972,28 +1035,38 @@ class InferenceEngine:
         lengths = self.lengths if lengths is None else lengths
         tables = self.block_tables if tables is None else tables
         reach = (lengths + k_rows - 1) // sp_.block_size + 1
-        live = np.minimum(np.minimum(reach, sp_.max_blocks_per_slot),
-                          (tables >= 0).sum(axis=1))
+        live = np.minimum(
+            np.minimum(reach, sp_.max_blocks_per_slot),
+            (tables[:, :sp_.max_blocks_per_slot] >= 0).sum(axis=1))
         served = self.served
         return served.attend_step_counts(
             live, K=k_rows, spec=sp_, mp=self.mp,
             q_itemsize=int(jnp.dtype(served.dtype).itemsize))
 
     def _attend_cost(self, context: Optional[int] = None,
-                     pool_blocks: Optional[int] = None) -> Tuple[int, int]:
+                     pool_blocks: Optional[int] = None,
+                     spec=None) -> Tuple[int, int]:
         """Analytic (FLOPs, cache bytes) of ONE token's attend over all
-        layers: live-context term (``context``: the stream's own blocks,
-        the last one whole) or pool-capacity term (``pool_blocks``: every
+        layers (of the class ``spec`` where given): live-context term
+        (``context``: the stream's own blocks, the last one whole, as far
+        as a class reaches) or pool-capacity term (``pool_blocks``: every
         row of a group's pool, what the one-hot contraction reads)."""
-        sp_ = self.cache_spec
-        keys = paged_attn_ops._attend_keys(sp_.block_size, context,
+        if spec is None:
+            costs = [self._attend_cost(
+                context, pool_blocks and sp.blocks_per_group, sp)
+                for sp in self.cache_specs]
+            return sum(c[0] for c in costs), sum(c[1] for c in costs)
+        if context is not None and spec.reach is not None:
+            context = min(context, spec.reach)
+        keys = paged_attn_ops._attend_keys(spec.block_size, context,
                                            pool_blocks)
         cost = self.__dict__.setdefault("_cache_costs", {})
-        if keys not in cost:
+        if (spec.name, keys) not in cost:
             flops, nbytes = self.served.cache_cost(
-                keys, sp_.block_size, int(jnp.dtype(sp_.dtype).itemsize))
-            cost[keys] = (flops * sp_.num_layers, nbytes * sp_.num_layers)
-        return cost[keys]
+                keys, spec.block_size, int(jnp.dtype(spec.dtype).itemsize))
+            cost[spec.name, keys] = (flops * spec.num_layers,
+                                     nbytes * spec.num_layers)
+        return cost[spec.name, keys]
 
     def _attend_work(self, k_rows: int, mask: Optional[np.ndarray] = None
                      ) -> Tuple[int, int, int, int]:
@@ -1007,19 +1080,26 @@ class InferenceEngine:
         per layer, occupancy notwithstanding. Projections — host
         arithmetic, no device work. A served model's cost grows by the
         same amount with every block in reach (or not at all: a state),
-        so the live slots' sum is one expression over their lengths."""
-        sp_ = self.cache_spec
-        bs = sp_.block_size
-        f1, b1 = self._attend_cost(context=bs)
-        f2, b2 = self._attend_cost(context=2 * bs)
+        so the live slots' sum is one expression over their lengths, a
+        class of layers at a time (a bounded class stops growing at its
+        reach)."""
+        bs = self.cache_spec.block_size
         blocks = -(-np.maximum(
             self.lengths[self.active if mask is None else mask], 1) // bs)
-        n, reach = int(blocks.size), int(blocks.sum())
-        pool = self._attend_cost(pool_blocks=sp_.blocks_per_group)
-        return ((n * (2 * f1 - f2) + reach * (f2 - f1)) * k_rows,
-                pool[0] * k_rows * self.max_slots,
-                n * (2 * b1 - b2) + reach * (b2 - b1),
-                pool[1] * sp_.num_groups)
+        n = int(blocks.size)
+        out = np.zeros(4, np.int64)
+        for sp_ in self.cache_specs:
+            f1, b1 = self._attend_cost(context=bs, spec=sp_)
+            f2, b2 = self._attend_cost(context=2 * bs, spec=sp_)
+            reach = int((blocks if sp_.reach is None else np.minimum(
+                blocks, -(-sp_.reach // bs))).sum())
+            pool = self._attend_cost(pool_blocks=sp_.blocks_per_group,
+                                     spec=sp_)
+            out += ((n * (2 * f1 - f2) + reach * (f2 - f1)) * k_rows,
+                    pool[0] * k_rows * self.max_slots,
+                    n * (2 * b1 - b2) + reach * (b2 - b1),
+                    pool[1] * sp_.num_groups)
+        return tuple(int(v) for v in out)
 
     def _note_counters(self, span, counters) -> None:
         """The served model's counters of the execution(s) just fetched
@@ -1128,7 +1208,8 @@ class InferenceEngine:
         tl, lap = self.telemetry, self.serving.lap
         with tl.span("decode_tables"):
             for s in np.flatnonzero(mask):
-                self._ensure_blocks(int(s), int(self.lengths[s]))
+                self._ensure_blocks(int(s), int(self.lengths[s]),
+                                    int(self.lengths[s]))
             # What the execution is handed: COPIES (the host's arrays
             # move on under it), dead rows for the slots not in it, and
             # the host's token for a slot the execution before did not
@@ -1159,7 +1240,8 @@ class InferenceEngine:
             span.set_metadata(live_blocks=live_blocks,
                               context_tokens=ctx_tokens,
                               attend_steps=steps[0],
-                              attend_live_steps=steps[1])
+                              attend_live_steps=steps[1],
+                              **self._class_args(mask))
             if self.cache_spec.per_stream:
                 span.set_metadata(state_pages_live=n_active)
         lap("dispatch_s")
@@ -1261,7 +1343,8 @@ class InferenceEngine:
                     s = int(s)
                     toks[s, 1:] = self.drafter.propose(s)
                     self._ensure_blocks(
-                        s, min(int(self.lengths[s]) + k, self.max_len - 1))
+                        s, int(self.lengths[s]),
+                        min(int(self.lengths[s]) + k, self.max_len - 1))
                 steps = self._attend_steps(k + 1)
             lap("tables_s")
             with tl.span("decode_dispatch"):

@@ -57,6 +57,26 @@ there, retained LRU like a cached block), ``match_snapshot`` finds the
 longest boundary that has one, and admission COPIES it into the stream's
 own page (``copy_pages``: page to page, never a select over the pool).
 Which boundaries are worth a page is ``BlockAllocator.snapshot_boundary``.
+
+A served model may also keep its layers in several CLASSES, each with its
+own pools, block table, free list, reference counts and prefix index (one
+``PagedKVCacheSpec`` and one allocator a class, ``ClassAllocators`` over
+them; a stream's table row is the classes' rows side by side).  A class has
+a ``reach``: unbounded (every layer of GPT-2: the above), or a number of
+tokens a query reads back, itself included (sliding-window layers:
+``BoundedBlockAllocator``).  A bounded class keeps only what is in reach:
+its table is a RING ``table_blocks`` wide, logical block j of a stream at
+slot ``j % width``; before every program the stream RETURNS the blocks that
+lie wholly behind the program's first query less the reach (``extend``) and
+draws the ones its new rows need; the rows a block holds never move.
+Admission counts ``min(tokens, reach + chunk) / block_size`` blocks for it,
+and charges the stream all of them until its release, whatever it shares: a
+shared block is given up by each stream as ITS window slides, so it is no
+free ride.  A prefix hit at token P needs the unbounded classes' blocks over
+``[0, P)`` and a bounded class's over ``[P - reach, P)`` only
+(``match_limit``), and a bounded class enters into its index only the blocks
+a hit at the prompt's own end would need: a cached document keeps its whole
+length in the one and a ``reach``-long tail in the other.
 """
 from __future__ import annotations
 
@@ -109,16 +129,28 @@ class PagedKVCacheSpec:
     # yardstick of ``BlockAllocator.snapshot_boundary``.
     per_stream: bool = False
     token_row_bytes: int = 0
+    # The CLASS of cache layers this pool serves (module docstring): its
+    # name ("" for a model's only class), how many tokens back a query of
+    # these layers reads, itself included (None: all of them), and the
+    # width of the ring a bounded class's table is.
+    name: str = ""
+    reach: Optional[int] = None
+    table_blocks: int = 0
 
     @property
     def pool_tiles(self) -> Tuple[Tuple[str, Tuple[int, int, int]], ...]:
         """((pool name, one block's tile as held), ...): ``pools``, or
         the K and V pools ``num_heads`` / ``head_dim`` give."""
         if self.pools is not None:
-            return self.pools
-        f = self.fold
-        tile = (self.num_heads, self.block_size // f, f * self.head_dim)
-        return (("k", tile), ("v", tile))
+            tiles = self.pools
+        else:
+            f = self.fold
+            tile = (self.num_heads, self.block_size // f, f * self.head_dim)
+            tiles = (("k", tile), ("v", tile))
+        if not self.name:
+            return tiles
+        # A named class's pools carry its name: one cache dict holds all.
+        return tuple((f"{pool}.{self.name}", tile) for pool, tile in tiles)
 
     @property
     def pool_names(self) -> Tuple[str, ...]:
@@ -141,8 +173,18 @@ class PagedKVCacheSpec:
     @property
     def max_blocks_per_slot(self) -> int:
         """Block-table width J: logical blocks a full slot spans (one
-        page for a per-stream pool)."""
-        return 1 if self.per_stream else self.max_len // self.block_size
+        page for a per-stream pool; the ring of a bounded class)."""
+        if self.per_stream:
+            return 1
+        if self.reach is not None:
+            return self.table_blocks
+        return self.max_len // self.block_size
+
+    def first_block(self, pos: int) -> int:
+        """The first logical block a query at position ``pos`` reads."""
+        if self.reach is None:
+            return 0
+        return max(0, int(pos) - self.reach + 1) // self.block_size
 
     @property
     def page_tokens(self) -> int:
@@ -189,6 +231,14 @@ class PagedKVCacheSpec:
                 f"inference.block_size={self.block_size} must divide the "
                 f"cache capacity ({self.max_len}) — a slot's last logical "
                 "block would otherwise overhang the position table")
+        if self.reach is not None and not (
+                self.reach > 0 and not self.per_stream
+                and -(-self.reach // self.block_size) < self.table_blocks
+                <= self.max_len // self.block_size):
+            raise ValueError(
+                f"a class of reach {self.reach} needs a ring of more than "
+                f"reach / block_size and at most max_len / block_size "
+                f"blocks, got table_blocks={self.table_blocks}")
         if self.num_blocks % self.num_groups:
             raise ValueError(
                 f"inference.num_blocks={self.num_blocks} must be divisible "
@@ -296,9 +346,11 @@ def init_paged_cache(spec: PagedKVCacheSpec,
 # carries the [G, ...] group axis so the work partitions over dp with
 # zero communication.
 # --------------------------------------------------------------------- #
-def positions_to_blocks(bt: jax.Array, pos: jax.Array, block_size: int
+def positions_to_blocks(bt: jax.Array, pos: jax.Array, block_size: int,
+                        ring: bool = False
                         ) -> Tuple[jax.Array, jax.Array]:
-    """Resolve token positions through a block table.
+    """Resolve token positions through a block table (``ring``: a bounded
+    class's, logical block j at slot ``j % J``).
 
     bt: [..., J] physical block ids (DEAD_BLOCK where unallocated);
     pos: [...] int32 token positions, same leading shape. Returns
@@ -308,6 +360,8 @@ def positions_to_blocks(bt: jax.Array, pos: jax.Array, block_size: int
     """
     J = bt.shape[-1]
     j = pos // block_size
+    if ring:
+        j = jnp.where(pos >= 0, j % J, -1)
     off = pos % block_size
     jm = j[..., None] == lax.broadcasted_iota(
         jnp.int32, j.shape + (J,), j.ndim)                   # [..., J]
@@ -410,6 +464,15 @@ def chain_hash(prev: int, tokens: np.ndarray) -> int:
     return hash((prev, tokens.astype(np.int64).tobytes()))
 
 
+def chain_hashes(prompt: np.ndarray, block_size: int) -> List[int]:
+    """The chain hash at every full block of ``prompt``."""
+    out, h = [], 0
+    for j in range(len(prompt) // block_size):
+        h = chain_hash(h, prompt[j * block_size:(j + 1) * block_size])
+        out.append(h)
+    return out
+
+
 class PoolExhausted(RuntimeError):
     """No free or reclaimable block in the group — admission must be
     rejected (the scheduler keeps the request queued; a live slot is
@@ -455,6 +518,9 @@ class BlockAllocator:
         self.reclaimed = 0
         self.snapshots_taken = 0        # per-stream pools only
         self.snapshot_hits = 0
+        # Blocks live streams gave back before their release: a bounded
+        # class's (``BoundedBlockAllocator``); nothing else returns any.
+        self.returned = 0
 
     # ---- accounting ---- #
     def blocks_in_use(self) -> int:
@@ -483,17 +549,23 @@ class BlockAllocator:
         return min(need, self.spec.max_blocks_per_slot)
 
     # ---- prefix cache ---- #
-    def match_prefix(self, group: int, prompt: np.ndarray
+    @property
+    def table_width(self) -> int:
+        return self.spec.max_blocks_per_slot
+
+    def match_prefix(self, group: int, prompt: np.ndarray,
+                     limit: Optional[int] = None
                      ) -> Tuple[List[int], List[int]]:
         """Longest cached full-block chain matching ``prompt`` in this
-        group → (block ids, chain hashes). Walks the chain hash; stops
-        at the first miss."""
+        group (its first ``limit`` blocks at most) → (block ids, chain
+        hashes). Walks the chain hash; stops at the first miss."""
         bs = self.spec.block_size
         idx = self._hash_index[group]
         blocks: List[int] = []
         hashes: List[int] = []
         h = 0
-        for j in range(len(prompt) // bs):
+        n = len(prompt) // bs
+        for j in range(n if limit is None else min(n, limit)):
             h = chain_hash(h, prompt[j * bs:(j + 1) * bs])
             b = idx.get(h)
             if b is None:
@@ -509,6 +581,16 @@ class BlockAllocator:
         if self.spec.per_stream:
             return self.match_snapshot(group, prompt)[0]
         return len(self.match_prefix(group, prompt)[0])
+
+    def match_limit(self, group: int, hashes: Sequence[int], n: int) -> int:
+        """The most blocks ``m <= n`` of a prompt (its chain ``hashes``)
+        this class can serve a prefix hit at: all of ``[0, m)`` cached."""
+        idx = self._hash_index[group]
+        n = min(n, len(hashes))
+        m = 0
+        while m < n and hashes[m] in idx:
+            m += 1
+        return m
 
     def match_snapshot(self, group: int, prompt: np.ndarray
                        ) -> Tuple[int, Optional[int], int]:
@@ -539,7 +621,10 @@ class BlockAllocator:
         return boundary if boundary - resumed >= self.spec.page_tokens else 0
 
     def can_admit(self, group: int, prompt: np.ndarray, max_new: int,
-                  spec_k: int = 0, share: bool = True) -> bool:
+                  spec_k: int = 0, share: bool = True,
+                  limit: Optional[int] = None) -> bool:
+        """``limit``: the prefix match is cut to that many blocks (what a
+        model's classes agreed on, ``ClassAllocators``)."""
         need = self.need_blocks(len(prompt), max_new, spec_k)
         if self.spec.per_stream:
             # The stream's own page, drawn while the snapshot it resumes
@@ -547,7 +632,8 @@ class BlockAllocator:
             page = self.match_snapshot(group, prompt)[1] if share else None
             return self.available(group) - int(
                 page is not None and page in self._lru[group]) >= need
-        matched = self.match_prefix(group, prompt)[0] if share else []
+        matched = self.match_prefix(group, prompt, limit)[0] if share \
+            else []
         # Only LIVE shared blocks are a free ride; reviving an
         # LRU-retained block consumes reclaimable capacity like any
         # fresh allocation does.
@@ -599,15 +685,16 @@ class BlockAllocator:
     # ---- request lifecycle ---- #
     def admit_prompt(self, slot: int, group: int, prompt: np.ndarray,
                      max_new: int, spec_k: int = 0,
-                     share: bool = True) -> "AdmitPlan":
+                     share: bool = True,
+                     limit: Optional[int] = None) -> "AdmitPlan":
         """Allocate/share the prompt's blocks and book the request's
         worst-case reservation. Returns the plan the engine prefills
         from. Raises PoolExhausted when ``can_admit`` would be False.
         ``share=False`` (the whole-prompt prefill path, which rewrites
         every position) opts out of the prefix cache entirely — no
-        matching, no registration."""
+        matching, no registration.  ``limit``: see ``can_admit``."""
         if not self.can_admit(group, prompt, max_new, spec_k,
-                              share=share):
+                              share=share, limit=limit):
             raise PoolExhausted(
                 f"group {group}: {self.available(group)} block(s) "
                 f"available < worst-case need for a "
@@ -616,7 +703,7 @@ class BlockAllocator:
             return self._admit_stream(slot, group, prompt, share)
         bs = self.spec.block_size
         plen = len(prompt)
-        matched_blocks, hashes = self.match_prefix(group, prompt) \
+        matched_blocks, hashes = self.match_prefix(group, prompt, limit) \
             if share else ([], [])
         # Always re-prefill at least the prompt's last token: its
         # logits seed the first sampled token, and the block holding it
@@ -662,6 +749,31 @@ class BlockAllocator:
                          matched=matched, cow_src=cow_src,
                          cow_dst=table[n_keep] if cow_src is not None
                          else None)
+
+    def extend(self, slot: int, row: np.ndarray, first_pos: int,
+               upto_pos: int) -> None:
+        """Make ``row`` (the slot's table row, edited in place) ready for a
+        program whose queries span positions ``[first_pos, upto_pos]``:
+        draw the blocks its new rows need — the per-iteration HBM growth
+        the hbm_bytes_per_token metric tracks."""
+        if self.spec.per_stream:
+            return
+        j = int((row != DEAD_BLOCK).sum())
+        while j <= min(upto_pos // self.spec.block_size,
+                       self.table_width - 1):
+            row[j] = self.alloc_block(slot)
+            j += 1
+
+    def class_stats(self) -> Dict[str, Dict[str, int]]:
+        """{class name: reach, blocks, in use, returned by sliding,
+        reclaimed} for a NAMED class (a model's only class has no name
+        and adds nothing to the spans)."""
+        if not self.spec.name:
+            return {}
+        return {self.spec.name: {
+            "reach": self.spec.reach, "blocks": self.spec.num_blocks,
+            "live": self.blocks_in_use(), "returned": self.returned,
+            "reclaimed": self.reclaimed}}
 
     def _admit_stream(self, slot: int, group: int, prompt: np.ndarray,
                       share: bool) -> "AdmitPlan":
@@ -762,6 +874,260 @@ class AdmitPlan:
     snapshot_at: int = 0
     snapshot_page: Optional[int] = None
     snapshot_hash: int = 0
+    # A bounded class: how many cached blocks the stream shares (those in
+    # reach of ``matched``); several classes: {class name: tokens it took
+    # from its cache}.
+    shared_blocks: int = 0
+    cached_by_class: Optional[Dict[str, int]] = None
+
+
+class BoundedBlockAllocator(BlockAllocator):
+    """The allocator of a BOUNDED class (window layers: ``spec.reach``
+    tokens; module docstring).  A stream's table is a ring, logical block j
+    at slot ``j % width``; ``extend`` returns what lies behind a program's
+    reach before it draws what the program writes; the prefix cache holds a
+    prompt's tail, and a hit shares the cached blocks in reach of the
+    resume point — never the block of the prompt's last token, so nothing
+    shared is ever written (no copy-on-write here).
+
+    Admission is by COMMITMENT, not by reservation less a free ride: a
+    stream is charged ``need_blocks`` — the most it holds at once — from
+    admission to release, whatever it shares.  A block two streams share
+    is given up by each as ITS window slides on, and the one that lets go
+    first then draws a block of its own while the other still holds the
+    shared one: a free ride would have to be paid back mid-flight, with
+    nothing to pay it from.  The streams' blocks in use never exceed the
+    sum of their needs, that sum never exceeds the pool, so a draw always
+    finds a free or a retained block; what sharing saves is the prefill,
+    and room for more retained tails."""
+
+    def __init__(self, spec: PagedKVCacheSpec):
+        super().__init__(spec)
+        self._committed: List[int] = [0] * spec.num_groups
+        self._slot_need: Dict[int, int] = {}
+        # per slot [lowest logical block held, next to draw], and the
+        # prompt blocks to enter into the prefix cache once drawn
+        self._slot_span: Dict[int, List[int]] = {}
+        self._slot_hashes: Dict[int, Dict[int, int]] = {}
+
+    def available(self, group: int) -> int:
+        """Blocks of this group no admitted stream is charged for."""
+        return self.spec.blocks_per_group - self._committed[group]
+
+    # ---- prefix cache ---- #
+    def matched_blocks(self, group: int, prompt: np.ndarray) -> int:
+        """The longest boundary whose blocks in reach are all cached."""
+        bs = self.spec.block_size
+        return self.match_limit(group, chain_hashes(prompt, bs),
+                                (len(prompt) - 1) // bs)
+
+    def match_limit(self, group: int, hashes: Sequence[int], n: int) -> int:
+        """The most blocks ``m <= n`` a hit can be served at: only what a
+        query at the boundary reads has to be cached,
+        ``[first_block(m * block_size), m)``."""
+        idx = self._hash_index[group]
+        n = min(n, len(hashes))
+        runs, run = [], 0           # cached blocks in a row, ending at j
+        for j in range(n):
+            run = run + 1 if hashes[j] in idx else 0
+            runs.append(run)
+        bs = self.spec.block_size
+        for m in range(n, 0, -1):
+            if runs[m - 1] >= m - self.spec.first_block(m * bs):
+                return m
+        return 0
+
+    def _match(self, group: int, prompt: np.ndarray, share: bool,
+               limit: Optional[int]):
+        """(blocks matched n, the cached blocks a stream resuming at
+        ``n * block_size`` shares: logical ``[first block in reach, n)``,
+        the prompt's chain hashes)."""
+        if not share:
+            return 0, [], []
+        bs = self.spec.block_size
+        hashes = chain_hashes(prompt, bs)
+        n = self.match_limit(group, hashes, min(
+            (len(prompt) - 1) // bs, len(hashes) if limit is None else limit))
+        idx = self._hash_index[group]
+        return n, [idx[hashes[j]] for j in
+                   range(self.spec.first_block(n * bs), n)], hashes
+
+    # ---- request lifecycle ---- #
+    def can_admit(self, group: int, prompt: np.ndarray, max_new: int,
+                  spec_k: int = 0, share: bool = True,
+                  limit: Optional[int] = None) -> bool:
+        return self.available(group) >= self.need_blocks(
+            len(prompt), max_new, spec_k)
+
+    def admit_prompt(self, slot: int, group: int, prompt: np.ndarray,
+                     max_new: int, spec_k: int = 0, share: bool = True,
+                     limit: Optional[int] = None) -> "AdmitPlan":
+        """The ring holds the cached blocks in reach of the resume point
+        and nothing else yet (``extend`` draws a program's blocks when it
+        is dispatched); of the prompt's own blocks only those a hit at the
+        PROMPT's end would read are entered into the prefix cache, as they
+        are drawn."""
+        # (a hit anywhere earlier in the prompt finds the full classes'
+        # blocks and not this one's: it is no hit)
+        need = self.need_blocks(len(prompt), max_new, spec_k)
+        if self.available(group) < need:
+            raise PoolExhausted(
+                f"group {group}: {self.available(group)} block(s) of class "
+                f"{self.spec.name!r} uncommitted < the {need} a "
+                f"{len(prompt)}+{max_new}-token request may hold at once")
+        bs, J = self.spec.block_size, self.table_width
+        n, shared, hashes = self._match(group, prompt, share, limit)
+        lo = n - len(shared)
+        row = [DEAD_BLOCK] * J
+        for j, b in zip(range(lo, n), shared):
+            self._incref(group, b)
+            row[j % J] = b
+        self._slot_group[slot] = group
+        self._slot_need[slot] = need
+        self._committed[group] += need
+        self._slot_span[slot] = [lo, n]
+        # ... from the first block a hit at the longest boundary an
+        # IDENTICAL prompt may resume at would read (its last token is
+        # always prefilled again), which is no later than what a longer
+        # prompt's hit at this one's last full block reads.
+        full = len(prompt) // bs
+        tail = self.spec.first_block((len(prompt) - 1) // bs * bs)
+        self._slot_hashes[slot] = {
+            j: hashes[j] for j in range(max(n, tail), full)} \
+            if share else {}
+        return AdmitPlan(slot=slot, group=group, table=row,
+                         matched=n * bs, shared_blocks=len(shared))
+
+    def extend(self, slot: int, row: np.ndarray, first_pos: int,
+               upto_pos: int) -> None:
+        """``BlockAllocator.extend`` for a ring: first RETURN the blocks
+        that lie wholly behind ``first_pos``'s reach (shared ones lose a
+        reference, the stream's own go back to the free list or, entered
+        into the prefix cache, are retained), then draw."""
+        group = self._slot_group[slot]
+        J = self.table_width
+        span = self._slot_span[slot]
+        keep = self.spec.first_block(first_pos)
+        while span[0] < min(keep, span[1]):
+            c = span[0] % J
+            self._decref(group, int(row[c]))
+            row[c] = DEAD_BLOCK
+            self.returned += 1
+            span[0] += 1
+        pending = self._slot_hashes[slot]
+        while span[1] <= upto_pos // self.spec.block_size:
+            j = span[1]
+            assert row[j % J] == DEAD_BLOCK, "a window's ring overran"
+            b = row[j % J] = self._draw(group, slot)
+            h = pending.pop(j, None)
+            if h is not None and h not in self._hash_index[group]:
+                self._hash_index[group][h] = b
+                self._block_hash[group][b] = h
+            span[1] += 1
+
+    def release(self, slot: int, table: Sequence[int]) -> None:
+        if slot in self._slot_need:
+            self._committed[self._slot_group[slot]] -= \
+                self._slot_need.pop(slot)
+            del self._slot_span[slot], self._slot_hashes[slot]
+        super().release(slot, table)
+
+
+class ClassAllocators:
+    """The allocators of a model's classes of cache layers behind the one
+    interface the engine uses (module docstring).  A stream's table row is
+    the classes' rows side by side (``columns``); a prefix hit is the
+    longest one EVERY class can serve; admission needs every class to
+    cover its own worst case.  No class forks a block copy-on-write: a
+    match stops short of the block that holds the prompt's last token."""
+
+    def __init__(self, specs: Sequence[PagedKVCacheSpec]):
+        self.classes = [
+            (BlockAllocator if s.reach is None else BoundedBlockAllocator)(s)
+            for s in specs]
+        self.spec = specs[0]
+        self.columns, at = [], 0
+        for a in self.classes:
+            self.columns.append(slice(at, at + a.table_width))
+            at += a.table_width
+        self.table_width = at
+
+    # ---- accounting ---- #
+    def blocks_in_use(self) -> int:
+        return sum(a.blocks_in_use() for a in self.classes)
+
+    def bytes_in_use(self) -> int:
+        return sum(a.bytes_in_use() for a in self.classes)
+
+    def available(self, group: int) -> int:
+        """The scarcest class's."""
+        return min(a.available(group) for a in self.classes)
+
+    @property
+    def reclaimed(self) -> int:
+        return sum(a.reclaimed for a in self.classes)
+
+    def class_stats(self) -> Dict[str, Dict[str, int]]:
+        out: Dict[str, Dict[str, int]] = {}
+        for a in self.classes:
+            out.update(a.class_stats())
+        return out
+
+    # ---- the prefix cache ---- #
+    def _agreed(self, group: int, prompt: np.ndarray) -> int:
+        bs = self.spec.block_size
+        hashes = chain_hashes(prompt, bs)
+        n, before = (len(prompt) - 1) // bs, None
+        while n != before:
+            before = n
+            for a in self.classes:
+                n = a.match_limit(group, hashes, n)
+        return n
+
+    def matched_blocks(self, group: int, prompt: np.ndarray) -> int:
+        return self._agreed(group, prompt)
+
+    def can_admit(self, group: int, prompt: np.ndarray, max_new: int,
+                  spec_k: int = 0, share: bool = True) -> bool:
+        n = self._agreed(group, prompt) if share else 0
+        return all(a.can_admit(group, prompt, max_new, spec_k, share=share,
+                               limit=n) for a in self.classes)
+
+    # ---- request lifecycle ---- #
+    def admit_prompt(self, slot: int, group: int, prompt: np.ndarray,
+                     max_new: int, spec_k: int = 0,
+                     share: bool = True) -> AdmitPlan:
+        n = self._agreed(group, prompt) if share else 0
+        row = np.full(self.table_width, DEAD_BLOCK, np.int32)
+        cached: Dict[str, int] = {}
+        done = []
+        try:
+            for a, cols in zip(self.classes, self.columns):
+                plan = a.admit_prompt(slot, group, prompt, max_new, spec_k,
+                                      share=share, limit=n)
+                done.append((a, cols))
+                row[cols][:len(plan.table)] = plan.table
+                assert plan.matched == n * self.spec.block_size \
+                    and plan.cow_src is None
+                cached[a.spec.name] = self.spec.block_size * (
+                    plan.shared_blocks if a.spec.reach is not None else n)
+        except PoolExhausted:
+            for a, cols in done:
+                a.release(slot, row[cols])
+            raise
+        return AdmitPlan(slot=slot, group=group, table=list(row),
+                         matched=n * self.spec.block_size,
+                         cached_by_class=cached)
+
+    def extend(self, slot: int, row: np.ndarray, first_pos: int,
+               upto_pos: int) -> None:
+        for a, cols in zip(self.classes, self.columns):
+            a.extend(slot, row[cols], first_pos, upto_pos)
+
+    def release(self, slot: int, table: Sequence[int]) -> None:
+        table = np.asarray(table, np.int32)
+        for a, cols in zip(self.classes, self.columns):
+            a.release(slot, table[cols])
 
 
 __all__ = ["DEAD_BLOCK", "PagedKVCacheSpec", "paged_partition_spec",
@@ -771,4 +1137,5 @@ __all__ = ["DEAD_BLOCK", "PagedKVCacheSpec", "paged_partition_spec",
            "positions_to_blocks",
            "block_select", "paged_write_rows", "paged_attend",
            "copy_block_onehots", "paged_copy_block", "chain_hash",
-           "PoolExhausted", "BlockAllocator", "AdmitPlan"]
+           "chain_hashes", "PoolExhausted", "BlockAllocator", "AdmitPlan",
+           "BoundedBlockAllocator", "ClassAllocators"]

@@ -141,7 +141,7 @@ def _forward(params, pool, x, bt_g, pos_g, live, cfg: DeepseekV3Config,
         # ``paged_kernel`` is "this path runs its Pallas kernels": the
         # attend, the row write and the grouped expert product alike.
         y, counts = share.expert_layer(
-            dict(p, **experts), h.reshape(S * K, H), cfg,
+            dict(p, **experts), h.reshape(S * K, H), cfg.routing,
             kernel=paged_kernel, layer=l, row_live=row_live)
         x = x + y.reshape(S, K, H)
         stats = (pairs + counts.sum(), jnp.maximum(most, counts.max()),

@@ -4,7 +4,9 @@ The engine, the scheduler, the block allocator, the prefix cache,
 admission, sampling and the spans know nothing of a model's block.  They
 ask a ``ServedModel`` for:
 
-- **what it keeps in the paged pool** — ``cache_layers`` and
+- **what it keeps in the paged pool** — ``cache_layers`` (or, where its
+  layers differ in how far back they read, ``cache_classes``: the layers
+  by class and each class's reach) and
   ``cache_pools(block_size)``: the pools by name, each with the shape of
   ONE block's tile as held (``[heads, rows, lanes]``, lane-dense) — from
   which ``PagedKVCacheSpec``, the pool arrays, ``block_nbytes``,
@@ -39,11 +41,20 @@ imported first — so a model nobody serves costs no import.
 from __future__ import annotations
 
 import importlib
-from typing import Any, Callable, Dict, Sequence, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 
 _IMPLEMENTATIONS: Dict[type, Callable[[Any], "ServedModel"]] = {}
+
+
+class CacheClass(NamedTuple):
+    """A class of a model's cache layers: its name ("" for a model's only
+    class), how many layers it holds, and how many tokens back a query of
+    these layers reads, itself included (None: all of them)."""
+    name: str
+    layers: int
+    reach: Optional[int] = None
 
 
 class ServedModel:
@@ -56,6 +67,10 @@ class ServedModel:
     # What the pools hold where that is not ``inference.kv_cache_dtype``'s
     # to choose (a recurrent state is fp32 whatever the rows' dtype).
     cache_dtype: Any = None
+    # Each class's table width, in ``cache_classes`` order: the ENGINE sets
+    # it when it has sized the tables (a window's ring depends on its
+    # prefill chunk); a model of several classes splits a table row by it.
+    table_widths: Optional[Tuple[int, ...]] = None
 
     def __init__(self, cfg):
         self.cfg = cfg
@@ -99,6 +114,16 @@ class ServedModel:
         """((pool name, one block's tile as held [heads, rows, lanes]),
         ...)."""
         raise NotImplementedError
+
+    @property
+    def cache_classes(self) -> Tuple[CacheClass, ...]:
+        """The model's cache layers by CLASS (``inference/kv_cache.py``):
+        each class gets ``cache_pools`` of its own (named ``<pool>.<class>``),
+        its own block table, free list and prefix index; the programs get
+        the pools class by class and each table row as the classes' rows
+        side by side.  One unnamed, unbounded class of ``cache_layers``
+        layers unless a model says otherwise."""
+        return (CacheClass("", self.cache_layers),)
 
     @property
     def token_row_bytes(self) -> int:
@@ -205,5 +230,5 @@ def with_counters(sampled, counters):
                             jnp.stack(list(counters)).astype(jnp.int32)])
 
 
-__all__ = ["ServedModel", "register", "served_model", "split_counters",
-           "with_counters"]
+__all__ = ["CacheClass", "ServedModel", "register", "served_model",
+           "split_counters", "with_counters"]

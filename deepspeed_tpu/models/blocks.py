@@ -1,0 +1,63 @@
+"""Block primitives more than one model family is written in: RMS norm,
+rotary angles, the gated-SiLU FFN, the compute-dtype product, and the
+description of an expert layer's routing that ``moe/share.py`` reads.  A
+family's own file (``deepseek_v3.py``, ``brumby.py``, ``afmoe.py``) holds
+its config, its init and what only it has; none imports these from a
+sibling.
+"""
+from __future__ import annotations
+
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+
+
+class Routing(NamedTuple):
+    """An expert layer's rule (sigmoid scores with a selection bias,
+    ``moe/share.py``) in numbers: ``experts`` routed experts in ``n_group``
+    groups of which the ``topk_group`` best stay (1 / 1: no group limit),
+    ``per_tok`` chosen a token, the weights divided by their sum where
+    ``norm``, times ``scale``; and ``held`` = (first, count), the share
+    this program holds."""
+    experts: int
+    per_tok: int
+    n_group: int
+    topk_group: int
+    norm: bool
+    scale: float
+    held: Tuple[int, int]
+
+
+def rotary_cos_sin(inv_freq, positions: jax.Array, scale: float = 1.0):
+    """fp32 ``scale`` x cos, sin ``[..., len(inv_freq)]`` of ``positions``
+    x ``inv_freq``: rotary positions with whatever frequencies a family
+    states."""
+    ang = positions.astype(jnp.float32)[..., None] \
+        * jnp.asarray(inv_freq, jnp.float32)
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
+
+
+def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+    """``x * rsqrt(mean(x^2) + eps) * w`` in fp32, x's dtype out."""
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(var + eps)
+            * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def matmul(x: jax.Array, w: jax.Array) -> jax.Array:
+    """Compute-dtype product, fp32 accumulation, x's dtype out."""
+    return jnp.dot(x, w.astype(x.dtype),
+                   preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def swiglu(x: jax.Array, gate: jax.Array, up: jax.Array, down: jax.Array
+           ) -> jax.Array:
+    """``down(silu(gate x) * up x)``, weights ``[in, out]``."""
+    g = jnp.dot(x, gate.astype(x.dtype), preferred_element_type=jnp.float32)
+    u = jnp.dot(x, up.astype(x.dtype), preferred_element_type=jnp.float32)
+    return matmul((jax.nn.silu(g) * u).astype(x.dtype), down)
+
+
+__all__ = ["rotary_cos_sin", "rms_norm", "matmul", "swiglu"]

@@ -50,7 +50,7 @@ import jax.numpy as jnp
 
 import numpy as np
 
-from .deepseek_v3 import matmul, rms_norm, rotary_cos_sin, swiglu  # noqa: F401
+from .blocks import matmul, rms_norm, rotary_cos_sin, swiglu  # noqa: F401
 
 
 @dataclasses.dataclass(frozen=True)

@@ -4,9 +4,10 @@ SwiGLU layers, then expert layers routed by sigmoid scores with a
 selection bias and a group limit, plus a shared expert.
 
 This module is the MODEL: its config from the published ``config.json``
-keys, a seeded init and the layer pieces every path shares (RMS norm,
-YaRN frequencies, the rotary rotation, the gated FFN, the latent
-projections).  How it is served (the paged latent cache, the absorbed
+keys, a seeded init and the layer pieces every path shares (YaRN
+frequencies, the rotary rotation, the latent projections; RMS norm, the
+gated FFN and the plain products are ``models/blocks.py``'s, shared with
+the other families).  How it is served (the paged latent cache, the absorbed
 attend) is ``inference/latent.py``; how an expert layer that holds a
 share of the experts routes and computes is ``moe/share.py``.  Nothing
 here is imported unless a configuration asks for it.
@@ -35,6 +36,8 @@ from typing import Any, ClassVar, Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .blocks import Routing, matmul, rms_norm, rotary_cos_sin, swiglu
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,6 +124,16 @@ class DeepseekV3Config:
         return self.vocab_rows_held or self.vocab_size
 
     @property
+    def routing(self) -> Routing:
+        """The expert layers' routing rule and share, as ``moe/share.py``
+        reads it."""
+        return Routing(experts=self.n_routed_experts,
+                       per_tok=self.num_experts_per_tok,
+                       n_group=self.n_group, topk_group=self.topk_group,
+                       norm=self.norm_topk_prob,
+                       scale=self.routed_scaling_factor, held=self.held)
+
+    @property
     def num_dense_layers(self) -> int:
         return self.first_k_dense_replace
 
@@ -174,15 +187,6 @@ def yarn_inv_freq(cfg: DeepseekV3Config) -> np.ndarray:
     return freq / cfg.rope_factor * (1.0 - keep) + freq * keep
 
 
-def rotary_cos_sin(inv_freq, positions: jax.Array, scale: float = 1.0):
-    """fp32 ``scale`` x cos, sin ``[..., len(inv_freq)]`` of ``positions``
-    x ``inv_freq``: rotary positions with whatever frequencies a family
-    states."""
-    ang = positions.astype(jnp.float32)[..., None] \
-        * jnp.asarray(inv_freq, jnp.float32)
-    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
-
-
 def rope_cos_sin(cfg: DeepseekV3Config, positions: jax.Array):
     """fp32 cos, sin ``[..., rope_dim / 2]`` at integer ``positions``;
     scaled by yarn_mscale(factor, mscale) / yarn_mscale(factor,
@@ -207,28 +211,6 @@ def rope_interleaved(x: jax.Array, cos: jax.Array, sin: jax.Array
 # ------------------------------------------------------------------ #
 # Layer pieces
 # ------------------------------------------------------------------ #
-def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
-    """``x * rsqrt(mean(x^2) + eps) * w`` in fp32, x's dtype out."""
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    return (xf * jax.lax.rsqrt(var + eps)
-            * weight.astype(jnp.float32)).astype(x.dtype)
-
-
-def matmul(x: jax.Array, w: jax.Array) -> jax.Array:
-    """Compute-dtype product, fp32 accumulation, x's dtype out."""
-    return jnp.dot(x, w.astype(x.dtype),
-                   preferred_element_type=jnp.float32).astype(x.dtype)
-
-
-def swiglu(x: jax.Array, gate: jax.Array, up: jax.Array, down: jax.Array
-           ) -> jax.Array:
-    """``down(silu(gate x) * up x)``, weights ``[in, out]``."""
-    g = jnp.dot(x, gate.astype(x.dtype), preferred_element_type=jnp.float32)
-    u = jnp.dot(x, up.astype(x.dtype), preferred_element_type=jnp.float32)
-    return matmul((jax.nn.silu(g) * u).astype(x.dtype), down)
-
-
 def latent_projections(p: Dict[str, jax.Array], h: jax.Array,
                        positions: jax.Array, cfg: DeepseekV3Config):
     """The projections ahead of the attend, for normed input ``h

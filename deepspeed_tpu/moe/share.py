@@ -1,5 +1,6 @@
-"""An expert layer that holds a SHARE of its experts, routed as the
-``deepseek_v3`` family publishes it.
+"""An expert layer that holds a SHARE of its experts, routed by sigmoid
+scores with a selection bias, as the ``deepseek_v3`` family publishes it
+(and ``afmoe``, the same rule with one group).
 
 Beside ``moe/layer.py`` (GShard: softmax top-1/2 into ``[E, C, H]``
 capacity buffers, overflow dropped, all-to-all) this is the layer a
@@ -27,6 +28,10 @@ serving chip of an expert-parallel deployment runs:
 ``routed_share`` returns, beside the output, the held experts' row
 counts: the engine's ``decode`` / ``prefill`` span args and the
 benchmark's load metrics read them.
+
+What the layer needs of a model is a ``Routing`` (``models/blocks.py``):
+the numbers of the rule and the share.  A family's config has one
+(``cfg.routing``) and its served model passes it.
 """
 from __future__ import annotations
 
@@ -35,46 +40,45 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..models.deepseek_v3 import DeepseekV3Config, swiglu
+from ..models.blocks import Routing, swiglu
 from ..ops import grouped_gemm
 
 
 def route(x: jax.Array, router: jax.Array, bias: jax.Array,
-          cfg: DeepseekV3Config) -> Tuple[jax.Array, jax.Array]:
+          r: Routing) -> Tuple[jax.Array, jax.Array]:
     """x [T, H] -> (expert ids [T, k] int32, weights [T, k] fp32)."""
-    E, n_group, k = (cfg.n_routed_experts, cfg.n_group,
-                     cfg.num_experts_per_tok)
+    E, n_group, k = r.experts, r.n_group, r.per_tok
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     s = jax.nn.sigmoid(logits)                                 # [T, E]
-    c = s + bias.astype(jnp.float32)
-    grouped = c.reshape(-1, n_group, E // n_group)
-    group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)         # [T, n_group]
-    kept = jax.lax.top_k(group_score, cfg.topk_group)[1]       # [T, topk_g]
-    keep = jnp.zeros(group_score.shape, bool).at[
-        jnp.arange(kept.shape[0])[:, None], kept].set(True)
-    # As published: the dropped groups' scores are masked to 0 (not
-    # -inf) before the top-k.
-    cand = jnp.where(jnp.repeat(keep, E // n_group, axis=1), c, 0.0)
+    cand = s + bias.astype(jnp.float32)
+    if r.topk_group < n_group:
+        grouped = cand.reshape(-1, n_group, E // n_group)
+        group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)     # [T, n_group]
+        kept = jax.lax.top_k(group_score, r.topk_group)[1]     # [T, topk_g]
+        keep = jnp.zeros(group_score.shape, bool).at[
+            jnp.arange(kept.shape[0])[:, None], kept].set(True)
+        # As published: the dropped groups' scores are masked to 0 (not
+        # -inf) before the top-k.
+        cand = jnp.where(jnp.repeat(keep, E // n_group, axis=1), cand, 0.0)
     idx = jax.lax.top_k(cand, k)[1].astype(jnp.int32)          # [T, k]
     w = jnp.take_along_axis(s, idx, axis=1)
-    if cfg.norm_topk_prob:
+    if r.norm:
         w = w / (w.sum(-1, keepdims=True) + 1e-20)
-    return idx, w * cfg.routed_scaling_factor
+    return idx, w * r.scale
 
 
-def _row_tile(tokens: int, cfg: DeepseekV3Config) -> int:
+def _row_tile(tokens: int, r: Routing) -> int:
     """Rows a grouped-product tile holds: twice the mean rows an expert
     gets, as a power of two within [16, 128] (16 = a packed bf16 tile)."""
-    mean = tokens * cfg.num_experts_per_tok / cfg.n_routed_experts
+    mean = tokens * r.per_tok / r.experts
     tm = 16
     while tm < 128 and tm < 2 * mean:
         tm *= 2
     return tm
 
 
-def dispatch(idx: jax.Array, cfg: DeepseekV3Config, tm: int,
-             row_live=None):
+def dispatch(idx: jax.Array, r: Routing, tm: int, row_live=None):
     """Group the held pairs by expert (of the rows ``row_live [T]`` marks,
     where given: a serving program's dead slots and padding rows are not
     traffic and get no buffer row).  idx [T, k] -> dict:
@@ -83,7 +87,7 @@ def dispatch(idx: jax.Array, cfg: DeepseekV3Config, tm: int,
     pair's expert is held, ``tile_expert`` [M / tm], ``n_live_tiles``,
     ``counts`` [count] rows per held expert.  M = the worst case: every
     pair held, each expert's group padded to ``tm``."""
-    first, count = cfg.held
+    first, count = r.held
     T, k = idx.shape
     P = T * k
     M = -(-P // tm) * tm + count * tm
@@ -128,7 +132,7 @@ def _experts_jnp(xs, p, tile_expert, n_live_tiles, tm):
 
 
 def routed_share(p: Dict[str, jax.Array], x: jax.Array,
-                 cfg: DeepseekV3Config, kernel: Optional[bool] = None,
+                 r: Routing, kernel: Optional[bool] = None,
                  layer=None, row_live=None) -> Tuple[jax.Array, jax.Array]:
     """x [T, H] -> (what the held experts add [T, H], rows per held
     expert [count]; ``row_live [T]``: see ``dispatch``).  ``kernel``: the Pallas grouped product (default: on a
@@ -142,13 +146,13 @@ def routed_share(p: Dict[str, jax.Array], x: jax.Array,
     experts = {k: p[k].reshape((-1,) + p[k].shape[-2:])
                for k in ("w_gate", "w_up", "w_down")}
     with jax.named_scope("router"):
-        idx, w = route(x, p["router"], p["router_bias"], cfg)
+        idx, w = route(x, p["router"], p["router_bias"], r)
     with jax.named_scope("dispatch"):
-        tm = _row_tile(x.shape[0], cfg)
-        d = dispatch(idx, cfg, tm, row_live)
+        tm = _row_tile(x.shape[0], r)
+        d = dispatch(idx, r, tm, row_live)
         xs = x[d["src"]]
         tile_expert = d["tile_expert"] if layer is None else \
-            d["tile_expert"] + layer * cfg.held[1]
+            d["tile_expert"] + layer * r.held[1]
     with jax.named_scope("experts"):
         if kernel:
             out = grouped_gemm.grouped_swiglu(
@@ -165,14 +169,14 @@ def routed_share(p: Dict[str, jax.Array], x: jax.Array,
 
 
 def expert_layer(p: Dict[str, jax.Array], x: jax.Array,
-                 cfg: DeepseekV3Config, kernel: Optional[bool] = None,
+                 r: Routing, kernel: Optional[bool] = None,
                  layer=None, row_live=None) -> Tuple[jax.Array, jax.Array]:
     """The whole FFN of an expert layer for normed ``x [T, H]``: the held
     share of the routed experts plus the shared expert.  Returns (y,
     rows per held expert).  ``layer``, ``row_live``: see
     ``routed_share``."""
     with jax.named_scope("moe"):
-        y, counts = routed_share(p, x, cfg, kernel, layer, row_live)
+        y, counts = routed_share(p, x, r, kernel, layer, row_live)
         with jax.named_scope("shared"):
             y = y + swiglu(x, p["shared_gate"], p["shared_up"],
                            p["shared_down"])

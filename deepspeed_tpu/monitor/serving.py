@@ -149,6 +149,7 @@ class ServingAggregator:
         self.spec_proposed = 0
         self.spec_accepted = 0
         self._model_counters: Dict[str, Any] = {}   # name -> (sum, n)
+        self._classes: Dict[str, Dict[str, int]] = {}   # cache classes'
         self._state: Dict[str, int] = {}    # a per-stream state pool's
         # Analytic attend-work accounting (engine-fed): the same
         # iterations priced BOTH ways — the Pallas kernel's live-context
@@ -367,6 +368,12 @@ class ServingAggregator:
         st.update(snapshots_taken=int(snapshots_taken),
                   snapshot_hits=int(snapshot_hits),
                   snapshots_evicted=int(snapshots_evicted))
+
+    def note_cache_classes(self, stats: Dict[str, Dict[str, int]]) -> None:
+        """A model's classes of cache layers, by name: blocks, blocks in
+        use, blocks live streams returned as their window slid (the
+        allocator's running totals, ``BlockAllocator.class_stats``)."""
+        self._classes = {name: dict(st) for name, st in stats.items()}
 
     def note_spec(self, proposed: int, accepted: int) -> None:
         self.spec_proposed += int(proposed)
@@ -588,6 +595,9 @@ class ServingAggregator:
             }
         if self._state:
             snap["state"] = dict(self._state)
+        if self._classes:
+            snap["cache_classes"] = {n: dict(st)
+                                     for n, st in self._classes.items()}
         if self._model_counters:
             snap["model_counters"] = {
                 name: round(tot / n, 4)

@@ -22,7 +22,8 @@ import re
 import struct
 from typing import Any, Dict, Iterator, List, Tuple
 
-__all__ = ["SCOPES", "SPANS", "SPAN_ARGS", "read_xspace", "event_args", "scope_of",
+__all__ = ["SCOPES", "SPANS", "SPAN_ARGS", "CLASS_SPAN_ARGS", "span_args",
+           "read_xspace", "event_args", "scope_of",
            "instruction_name",
            "device_op_events", "DEVICE_PLANE", "OPS_LINE"]
 
@@ -43,7 +44,11 @@ SCOPES = ("fwd_bwd", "grad_sync", "health_tap", "optimizer", "flatten",
           # chunked form in prefill, the output projection; and the page
           # copy of a per-stream pool (a program of its own)
           "qkv_proj", "state_update", "retention_chunk", "out_proj",
-          "state_copy")
+          "state_copy",
+          # a model with two classes of cache layers (inference/afmoe.py):
+          # under attn, the paged attend of a sliding-window layer and of a
+          # full-attention layer (beside qkv_proj, kv_write, out_proj)
+          "attend_window", "attend_full")
 # The host spans ``Telemetry.span`` opens (runtime/engine.py,
 # inference/engine.py, inference/scheduler.py), same contract.
 SPANS = ("train_batch", "data_prep", "step_dispatch", "offload_step",
@@ -90,7 +95,12 @@ SPAN_ARGS = {
                # 1 where the dispatch went out while the iteration
                # before was still unfetched; rows the iteration fetched
                # computed for streams that had ended by then
-               "ahead", "dropped"),
+               "ahead", "dropped",
+               # a model with NAMED classes of cache layers
+               # (inference/kv_cache.py): the key rows the iteration may
+               # read, summed over streams, classes and layers (a class
+               # counts a stream's context as far as it reaches)
+               "context_tokens_in_reach"),
     # The emission's row of the serving timeline (monitor/serving.py),
     # the streams it hands tokens to and those of them that waited the
     # whole interval since the emission before, that interval, the part
@@ -99,9 +109,27 @@ SPAN_ARGS = {
     "emit": ("finished", "row", "streams", "continuing", "gap_ms",
              "stall_ms", "host_ms"),
     "serve_idle": ("why",)}
+# ... and the args such a model adds ONE A CLASS, under the names its
+# ``ServedModel.cache_classes`` declare (``<class>`` below; this file knows
+# no model's): of a prefill's cached_tokens what each class took from ITS
+# cache (an unbounded class all of them, a window class its reach's worth);
+# each class's blocks in use and the blocks its live streams have returned
+# so far as their window slid (the allocator's running total).
+CLASS_SPAN_ARGS = {
+    "prefill": ("cached_tokens_<class>",),
+    "decode": ("<class>_blocks_live", "<class>_blocks_returned"),
+}
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 _INNER = re.compile(r"^(?:[\w.-]+\()*([\w.-]*)\)*$")
+
+
+def span_args(span: str, classes=()) -> Tuple[str, ...]:
+    """Every arg ``span`` may carry, for a model whose classes of cache
+    layers are named ``classes``."""
+    return tuple(SPAN_ARGS.get(span, ())) + tuple(
+        pattern.replace("<class>", name)
+        for pattern in CLASS_SPAN_ARGS.get(span, ()) for name in classes)
 
 
 def _varint(buf, i: int) -> Tuple[int, int]:

@@ -204,9 +204,19 @@ def _pattn_kernel(nlive_ref, rows_ref, base_ref, lim_ref, q_ref, k_hbm,
 
     The mask is one vector compare: ``lim_ref`` holds, per score row,
     the last attendable position less the copy's lane offset i, so a
-    column is allowed iff ``g*P*bs + c*f <= lim``. The slots of the last
-    group past the live count lie wholly behind it; their K rows are
-    whatever the buffer held and their V rows are zeroed (0 x finite)."""
+    column is allowed iff ``g*P*bs + c*f <= lim`` (and, where the plan
+    has a window, a second column with the FIRST attendable position:
+    one more compare). The slots of the last group past the live count
+    lie wholly behind it; their K rows are whatever the buffer held and
+    their V rows are zeroed (0 x finite).
+
+    Positions here count from the first block the plan lists: the whole
+    context for an unbounded table, the first block in reach for a
+    window's ring. Grouped heads never show here: a K/V head's ``group``
+    query heads ride as ``group * K`` query rows of that one head
+    (``_paged_local``), so the contraction is ``[group*K, D] x [D,
+    keys]`` a K/V head and a block's tile is copied once for all of
+    them."""
     s_idx, hb = pl.program_id(0), pl.program_id(1)
     bh, fK, fD = q_ref.shape[1:]
     f = fD // D
@@ -245,7 +255,14 @@ def _pattn_kernel(nlive_ref, rows_ref, base_ref, lim_ref, q_ref, k_hbm,
         # get their per-row causal offsets here.
         col = jax.lax.broadcasted_iota(jnp.int32, (fK, N), 1) * f \
             + g * (P * bs)
-        allowed = col <= lim_ref[0]                   # [fK, 1] -> [fK, N]
+        if lim_ref.shape[2] == 1:
+            allowed = col <= lim_ref[0]               # [fK, 1] -> [fK, N]
+        else:
+            # A window: the row's first attendable position beside its
+            # last (``_plan_window``).
+            edges = lim_ref[0]
+            allowed = jnp.logical_and(col <= edges[:, 0:1],
+                                      col >= edges[:, 1:2])
 
         def stack(buf, h):
             # In-VMEM dequant: bf16 pool tiles upcast at the registers,
@@ -370,20 +387,29 @@ class AttendPlan(NamedTuple):
 
     nlive [G, Q]: live blocks per stream (0 = dead stream).
     rows  [G, Q, J]: the pool tile (``group*B + block``, layer 0) of
-          every table slot.
+          every block the stream walks, in walking order.
     lim   [G, Q, f*K, 1]: per score row, last attendable position less
-          the copy's lane offset (see ``_pattn_kernel``)."""
+          the copy's lane offset (see ``_pattn_kernel``), counted from
+          the first block of ``rows``; ``[..., 2]`` with a window: the
+          first attendable position beside it."""
     nlive: jax.Array
     rows: jax.Array
     lim: jax.Array
 
 
-def _plan_local(block_tables, positions, *, B, bs, f):
+def _plan_local(block_tables, positions, *, B, bs, f, reach=None, group=1):
     """Per-shard plan: G = groups this shard owns, so ``group*B`` is the
-    shard's own tile row (block ids are group-local by construction)."""
+    shard's own tile row (block ids are group-local by construction).
+    ``group`` query heads a K/V head: each position's row comes that many
+    times (row ``m*K + k`` of a K/V head is query head ``m`` of its group
+    at position k).  ``reach``: see ``_plan_window``."""
     G, Q, J = block_tables.shape
     bt = block_tables.astype(jnp.int32)
     pos = positions.astype(jnp.int32)
+    if group > 1:
+        pos = jnp.tile(pos, (1, 1, group))
+    if reach is not None:
+        return _plan_window(bt, pos, B=B, bs=bs, f=f, reach=reach)
     # Live block count per stream: the table's rows are a dense prefix
     # (blocks append in order), so ceil((max pos + 1)/bs) of them are
     # live, as far as the prefix goes; a dead leading entry marks the
@@ -392,14 +418,45 @@ def _plan_local(block_tables, positions, *, B, bs, f):
     prefix = jnp.where(dead.any(axis=2), jnp.argmax(dead, axis=2), J)
     nlive = jnp.minimum(jnp.clip(jnp.max(pos, axis=2) // bs + 1, 0, J),
                         prefix)
-    group = jnp.arange(G, dtype=jnp.int32)[:, None, None]
-    rows = group * B + jnp.maximum(bt, 0)
+    shard_group = jnp.arange(G, dtype=jnp.int32)[:, None, None]
+    rows = shard_group * B + jnp.maximum(bt, 0)
     # A row attends no further than the stream's live blocks reach (a
     # prefill chunk's padding rows lie past the table: they attend what
     # there is, stay finite, and nothing reads them).
     reach = jnp.minimum(pos, (nlive * bs - 1)[:, :, None])
     lim = reach[:, :, None, :] - jnp.arange(f, dtype=jnp.int32)[:, None]
     return AttendPlan(nlive, rows, lim.reshape(G, Q, -1, 1))
+
+
+def _plan_window(bt, pos, *, B, bs, f, reach):
+    """The plan of a class of layers whose rows attend ``reach`` positions
+    back, themselves included (a sliding window): the table is a RING,
+    logical block j of a stream at slot ``j % J``, and holds only blocks in
+    reach.  The walk starts at the first block any live row (``pos >= 0``)
+    reaches and ends at the last row's; ``rows`` lists those blocks in
+    order, and both edges of the mask count from the first."""
+    G, Q, J = bt.shape
+    alive = pos >= 0
+    last = jnp.max(pos, axis=2) // bs                           # -1: dead
+    low = jnp.min(jnp.where(alive, jnp.maximum(pos - reach + 1, 0),
+                            jnp.iinfo(jnp.int32).max), axis=2)
+    first = jnp.where(last >= 0, low // bs, 0)
+    walk = (first[:, :, None] + jnp.arange(J, dtype=jnp.int32)) % J
+    blocks = jnp.take_along_axis(bt, walk, axis=2)
+    # A stream whose newest block is dead is inactive (a dead table row).
+    newest = jnp.take_along_axis(bt, (jnp.maximum(last, 0) % J)[:, :, None],
+                                 axis=2)[:, :, 0]
+    nlive = jnp.where((last >= 0) & (newest >= 0),
+                      jnp.clip(last - first + 1, 0, J), 0)
+    shard_group = jnp.arange(G, dtype=jnp.int32)[:, None, None]
+    rows = shard_group * B + jnp.maximum(blocks, 0)
+    origin = (first * bs)[:, :, None]
+    copy = jnp.arange(f, dtype=jnp.int32)[:, None]
+    hi = jnp.minimum(pos - origin, (nlive * bs - 1)[:, :, None])
+    lo = pos - reach + 1 - origin
+    lim = jnp.stack([hi[:, :, None, :] - copy, lo[:, :, None, :] - copy],
+                    axis=-1)
+    return AttendPlan(nlive, rows, lim.reshape(G, Q, -1, 2))
 
 
 def _pool_geometry(pool_shape, D):
@@ -409,13 +466,18 @@ def _pool_geometry(pool_shape, D):
 
 
 def attend_plan(block_tables, positions, pool, head_dim: int, *,
-                mesh=None) -> AttendPlan:
+                mesh=None, reach: Optional[int] = None,
+                group: int = 1) -> AttendPlan:
     """The per-execution index work of ``paged_attention``: block_tables
-    [G, Q, J], positions [G, Q, K], ``pool`` the stacked pool as held.
-    Under a dp mesh each shard plans its own groups."""
+    [G, Q, J], positions [G, Q, K] (-1: a row that attends nothing),
+    ``pool`` the stacked pool as held.  ``group``: query heads a K/V head
+    of the pool.  ``reach``: the table is a window's ring
+    (``_plan_window``).  Under a dp mesh each shard plans its own
+    groups."""
     B, bs, f = _pool_geometry(pool.shape, head_dim)
     fn = _on_mesh(
-        functools.partial(_plan_local, B=B, bs=bs, f=f), mesh,
+        functools.partial(_plan_local, B=B, bs=bs, f=f, reach=reach,
+                          group=group), mesh,
         lambda dpn, mpn: (P(dpn), P(dpn)),
         lambda dpn, mpn: AttendPlan(P(dpn), P(dpn), P(dpn)))
     return fn(block_tables, positions)
@@ -432,16 +494,24 @@ def _paged_local(q, pool_k, pool_v, layer, nlive, rows, lim, *, scale,
     tile's last two dims span the array's: a head block in the
     second-minor position must be a multiple of 8 or all of nH on the
     TPU."""
-    G, Q, K, nH, D = q.shape
+    G, Q, K0, nQ, D = q.shape
     B, bs, f = _pool_geometry(pool_k.shape, D)
-    bsf, fD = pool_k.shape[4:]
+    nH, bsf, fD = pool_k.shape[3:]
     J = rows.shape[2]
     GQ = G * Q
+    # Grouped heads: the ``grp`` query heads of a K/V head are grp * K0
+    # query rows of that head (row m*K0 + k, as the plan lays ``lim``).
+    grp = nQ // nH
+    K = grp * K0
     bh, P_ = tiles or _tile_rule(K, nH, D, bs, J, pool_k.dtype.itemsize,
                                  q.dtype.itemsize)
     # Each query row f times, copy i in lanes i*D.. of a zero row (see
     # the kernel): [GQ, nH, f*K, f*D].
-    q = jnp.swapaxes(q.reshape(GQ, K, nH, D), 1, 2)
+    if grp == 1:
+        q = jnp.swapaxes(q.reshape(GQ, K, nH, D), 1, 2)
+    else:
+        q = q.reshape(GQ, K0, nH, grp, D).transpose(0, 2, 3, 1, 4) \
+            .reshape(GQ, nH, K, D)
     q = (q[:, :, None, :, None, :] *
          jnp.eye(f, dtype=q.dtype)[:, None, :, None]
          ).reshape(GQ, nH, f * K, fD)
@@ -456,16 +526,15 @@ def _paged_local(q, pool_k, pool_v, layer, nlive, rows, lim, *, scale,
     def _lim_map(s, h, nl_p, rows_p, base_p):
         return (s, 0, 0)
 
-    # The K/V head block (``heads`` in the kernel) is named apart from
-    # the query's: today the same, one K/V head per query head.
     kv_buf = pltpu.VMEM((2, P_, bh, bsf, fD), pool_k.dtype)
+    edges = lim.shape[-1]            # 1, or 2 with a window's lower edge
     out = pl.pallas_call(
         functools.partial(_pattn_kernel, scale=scale, bs=bs, K=K, D=D,
                           P=P_),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(GQ, nH // bh),
-            in_specs=[pl.BlockSpec((1, f * K, 1), _lim_map),
+            in_specs=[pl.BlockSpec((1, f * K, edges), _lim_map),
                       pl.BlockSpec((1, bh, f * K, fD), _stream_map),
                       pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
@@ -481,9 +550,12 @@ def _paged_local(q, pool_k, pool_v, layer, nlive, rows, lim, *, scale,
             vmem_limit_bytes=_VMEM_LIMIT),
         name="_pattn_kernel",
         interpret=_interpret(),
-    )(nlive.reshape(GQ), rows, base, lim.reshape(GQ, f * K, 1), q,
+    )(nlive.reshape(GQ), rows, base, lim.reshape(GQ, f * K, edges), q,
       _pool_rows(pool_k), _pool_rows(pool_v))
-    return jnp.swapaxes(out[0], 1, 2).reshape(G, Q, K, nH, D)
+    if grp == 1:
+        return jnp.swapaxes(out[0], 1, 2).reshape(G, Q, K, nH, D)
+    return out[0].reshape(GQ, nH, grp, K0, D).transpose(0, 3, 1, 2, 4) \
+        .reshape(G, Q, K0, nQ, D)
 
 
 def _on_mesh(local_fn, mesh, in_specs, out_specs):
@@ -514,11 +586,13 @@ def paged_attention(q, pool_k, pool_v, layer, block_tables=None,
                     plan: Optional[AttendPlan] = None):
     """Table-driven paged attention over one layer of the block pool.
 
-    q:            [G, Q, K, nH, D] — Q streams per group, K query rows
+    q:            [G, Q, K, nQ, D] — Q streams per group, K query rows
                   per stream (1 decode / k+1 verify / chunk prefill).
     pool_k/v:     [L, G, B, nH, bs/f, f*D] — the WHOLE stacked pool in
                   the lane-dense layout it is born in
-                  (``kv_cache.PagedKVCacheSpec.shape``).
+                  (``kv_cache.PagedKVCacheSpec.shape``); nH K/V heads,
+                  ``nQ // nH`` query heads each (head h reads K/V head
+                  ``h // group``).
     layer:        int32 scalar — which layer of the pool to read.
     block_tables: [G, Q, J] int32 group-local block ids (DEAD_BLOCK for
                   unallocated tail entries).
@@ -526,7 +600,8 @@ def paged_attention(q, pool_k, pool_v, layer, block_tables=None,
                   query row.
     plan:         ``attend_plan(block_tables, positions, ...)`` where the
                   caller built it once for all its layers (then the two
-                  are not read here).
+                  are not read here); a window or grouped heads come
+                  through it (its ``reach`` / ``group``).
     tiles:        (heads a step, table slots a step) instead of the
                   shape rule's: the tests' handle on the tiling.
 
@@ -537,7 +612,7 @@ def paged_attention(q, pool_k, pool_v, layer, block_tables=None,
                            "inference.paged_kernel=false")
     if plan is None:
         plan = attend_plan(block_tables, positions, pool_k, q.shape[-1],
-                           mesh=mesh)
+                           mesh=mesh, group=q.shape[3] // pool_k.shape[3])
     fn = _on_mesh(
         functools.partial(_paged_local, scale=scale, tiles=tiles), mesh,
         lambda dpn, mpn: (P(dpn, None, None, mpn, None),

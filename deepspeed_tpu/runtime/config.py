@@ -472,11 +472,15 @@ class InferenceConfig:
                 f"{C.INFERENCE}.{C.INFERENCE_BLOCK_SIZE} must be a "
                 f"positive int (the paged block pool is the only KV "
                 f"layout), got {self.block_size!r}")
-        if not isinstance(self.num_blocks, int) or self.num_blocks < 0:
+        sizes = self.num_blocks if isinstance(self.num_blocks, dict) \
+            else {"": self.num_blocks}
+        if not all(isinstance(k, str) and isinstance(n, int) and n >= 0
+                   for k, n in sizes.items()):
             raise DeepSpeedConfigError(
                 f"{C.INFERENCE}.{C.INFERENCE_NUM_BLOCKS} must be a "
-                f"non-negative int (0 = full provisioning), got "
-                f"{self.num_blocks!r}")
+                f"non-negative int (0 = full provisioning), or for a model "
+                f"with classes of cache layers {{class name: such an int}}, "
+                f"got {self.num_blocks!r}")
         if not isinstance(self.spec_k, int) or self.spec_k < 0:
             raise DeepSpeedConfigError(
                 f"{C.INFERENCE}.{C.INFERENCE_SPEC_K} must be a "

@@ -371,7 +371,7 @@ def _router_case(seed=0, T=64, bias_std=0.1, **kw):
 
 def test_router_is_group_limited_and_weights_sum_to_the_scale():
     cfg, x, router, bias = _router_case()
-    idx, w = share.route(x, router, bias, cfg)
+    idx, w = share.route(x, router, bias, cfg.routing)
     idx, w = np.asarray(idx), np.asarray(w)
     assert idx.shape == (64, 4) and all(len(set(r)) == 4 for r in idx)
     np.testing.assert_allclose(w.sum(-1), cfg.routed_scaling_factor,
@@ -394,9 +394,9 @@ def test_router_is_group_limited_and_weights_sum_to_the_scale():
 
 def test_bias_moves_selection_and_not_weights():
     cfg, x, router, bias = _router_case(bias_std=0.0)
-    idx0, w0 = share.route(x, router, bias, cfg)
+    idx0, w0 = share.route(x, router, bias, cfg.routing)
     push = jnp.zeros_like(bias).at[5].set(10.0)      # expert 5 always wins
-    idx1, w1 = share.route(x, router, push, cfg)
+    idx1, w1 = share.route(x, router, push, cfg.routing)
     assert (np.asarray(idx1) == 5).any(-1).all()
     assert not (np.asarray(idx0) == 5).any(-1).all()
     # weights are s at the chosen, renormalised: the bias is not in them
@@ -415,9 +415,10 @@ def test_dispatch_is_dropless(skew, held):
     every token and still nothing is dropped."""
     cfg, x, router, bias = _router_case(held=held)
     bias = bias.at[held[0]].add(skew)
-    idx, _ = share.route(x, router, bias, cfg)
+    idx, _ = share.route(x, router, bias, cfg.routing)
     tm = 16
-    d = {k: np.asarray(v) for k, v in share.dispatch(idx, cfg, tm).items()}
+    d = {k: np.asarray(v)
+         for k, v in share.dispatch(idx, cfg.routing, tm).items()}
     idx = np.asarray(idx)
     on = (idx >= held[0]) & (idx < held[0] + held[1])
     assert (d["on"] == on).all()
@@ -455,7 +456,7 @@ def test_grouped_swiglu_kernel_equals_its_jnp_form(dtype, atol):
 # --------------------------------------------------------------------- #
 def _sum_form(p, x, cfg):
     """The uncut layer as a plain sum over all experts (HF's loop)."""
-    idx, w = share.route(x, p["router"], p["router_bias"], cfg)
+    idx, w = share.route(x, p["router"], p["router_bias"], cfg.routing)
     y = jnp.zeros_like(x)
     for e in range(cfg.n_routed_experts):
         we = jnp.where(idx == e, w, 0.0).sum(-1)
@@ -475,7 +476,7 @@ def test_the_shares_add_up_to_the_uncut_layer(kernel):
     p = jax.tree_util.tree_map(lambda a: a[0], params["moe"])   # one layer
     x = jnp.asarray(np.random.default_rng(3).standard_normal((40, 64)),
                     jnp.float32)
-    whole, counts = share.routed_share(p, x, cfg, kernel=kernel)
+    whole, counts = share.routed_share(p, x, cfg.routing, kernel=kernel)
     np.testing.assert_allclose(np.asarray(whole),
                                np.asarray(_sum_form(p, x, cfg)), atol=2e-5)
     assert int(counts.sum()) == 40 * cfg.num_experts_per_tok
@@ -484,14 +485,14 @@ def test_the_shares_add_up_to_the_uncut_layer(kernel):
         c = dataclasses.replace(cfg, held=(first, 4))
         pp = dict(p, **{k: p[k][first:first + 4]
                         for k in ("w_gate", "w_up", "w_down")})
-        parts.append(share.routed_share(pp, x, c, kernel=kernel)[0])
+        parts.append(share.routed_share(pp, x, c.routing, kernel=kernel)[0])
     shared = dsv3.swiglu(x, p["shared_gate"], p["shared_up"],
                          p["shared_down"])
     total = sum(parts) + shared
     np.testing.assert_allclose(
         np.asarray(total), np.asarray(whole + shared), atol=2e-5)
     # ... and the reference's expert layer, given the whole layer
-    full, _ = share.expert_layer(p, x, cfg, kernel=kernel)
+    full, _ = share.expert_layer(p, x, cfg.routing, kernel=kernel)
     np.testing.assert_allclose(np.asarray(total), np.asarray(full),
                                atol=2e-5)
 
@@ -505,8 +506,9 @@ def test_stacked_expert_weights_name_the_layer():
         one = jax.tree_util.tree_map(lambda a: a[l], params["moe"])
         stacked = dict(one, **{k: params["moe"][k]
                                for k in ("w_gate", "w_up", "w_down")})
-        a, _ = share.routed_share(one, x, cfg, kernel=False)
-        b, _ = share.routed_share(stacked, x, cfg, kernel=False, layer=l)
+        a, _ = share.routed_share(one, x, cfg.routing, kernel=False)
+        b, _ = share.routed_share(stacked, x, cfg.routing, kernel=False,
+                                 layer=l)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
 

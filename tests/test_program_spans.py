@@ -601,3 +601,103 @@ def test_retention_forms_stay_in_their_own_program(retention_op_names):
         == ("attn", "state_update")
     assert scope_of("jit(state_copy)/state_copy/dynamic_update_slice")[0] \
         == ("state_copy",)
+
+
+# --------------------------------------------------------------------- #
+# (h) a model with two classes of cache layers (PR 38): the scopes of the
+# window and the full attend, the classes' span args and the snapshot
+# --------------------------------------------------------------------- #
+@pytest.fixture(scope="module")
+def classes_engine():
+    from test_afmoe_serving import engine_of, seeded, tiny
+    cfg = tiny()
+    eng = engine_of(cfg, seeded(cfg), True)
+    yield eng
+    eng.close()
+
+
+@pytest.fixture(scope="module")
+def classes_op_names(classes_engine):
+    eng = classes_engine
+    G, W = eng.dp, eng.allocator.table_width
+    key, temp = eng._next_key(), np.float32(0.0)
+    return {
+        "decode": _op_names(eng._decode_fn, eng._params, *eng._pools(),
+                            eng._no_fetch, eng.last_tokens,
+                            np.ones(eng.max_slots, bool), eng.lengths,
+                            eng.block_tables, key, temp),
+        "prefill": _op_names(
+            eng._prefill_fn, eng._params, *eng._pools(),
+            np.zeros((G, eng.prefill_chunk), np.int32),
+            np.zeros((G, W), np.int32), np.zeros(G, np.int32),
+            np.zeros(G, np.int32), np.ones(G, np.int32), key, temp)}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("scope", [
+    "embed", "attn/qkv_proj", "attn/kv_write", "attn/attend_window",
+    "attn/attend_full", "attn/out_proj", "mlp", "moe/router",
+    "moe/dispatch", "moe/experts", "moe/combine", "moe/shared", "lm_head",
+    "sample"])
+def test_classes_program_carries_scope(classes_op_names, program, scope):
+    assert any(f"/{scope}" in n for n in classes_op_names[program]), \
+        (program, scope)
+
+
+def test_the_readers_list_names_the_classes_scopes_and_args():
+    from deepspeed_tpu.monitor.xplane_reader import (SCOPES, SPAN_ARGS,
+                                                     scope_of, span_args)
+    assert {"attend_window", "attend_full", "qkv_proj", "out_proj"} \
+        <= set(SCOPES)
+    assert scope_of("jit(decode_step)/attn/attend_window/pallas_call")[0] \
+        == ("attn", "attend_window")
+    # the args a class adds go by the names the MODEL declares
+    assert span_args("decode") == SPAN_ARGS["decode"]
+    assert "context_tokens_in_reach" in SPAN_ARGS["decode"]
+    assert set(span_args("decode", ("full", "window"))) \
+        - set(SPAN_ARGS["decode"]) == {
+            "full_blocks_live", "window_blocks_live",
+            "window_blocks_returned", "full_blocks_returned"}
+    assert set(span_args("prefill", ("full", "window"))) \
+        - set(SPAN_ARGS["prefill"]) == {"cached_tokens_full",
+                                        "cached_tokens_window"}
+    assert not any("full" in a or "window" in a
+                   for args in SPAN_ARGS.values() for a in args)
+
+
+def test_decode_and_prefill_spans_carry_the_classes(tmp_path, classes_engine):
+    """Every class's blocks in use and returned ride the ``decode`` span
+    with the key rows in reach; a ``prefill`` span says what each class
+    took from its cache; ``snapshot()`` carries the classes' totals."""
+    from deepspeed_tpu.monitor.xplane_reader import span_args
+    eng = classes_engine
+    names = [c.name for c in eng.served.cache_classes]
+    rng = np.random.default_rng(0)
+    doc = rng.integers(0, 128, size=40, dtype=np.int32)
+    eng.serve([Request(rid=-1, prompt=doc, max_new_tokens=1, arrival_s=0.0)])
+    reqs = [Request(rid=i, prompt=np.concatenate(
+        [doc, rng.integers(0, 128, size=6 + i, dtype=np.int32)]),
+        max_new_tokens=12, arrival_s=0.0) for i in range(2)]
+    report = {}
+    found = _session(tmp_path, lambda: report.update(eng.serve(reqs)))
+    dispatched = _dispatched(found)
+    assert dispatched and all(set(a) <= set(span_args("decode", names))
+                              for a in dispatched)
+    for a in dispatched:
+        assert a["full_blocks_live"] > a["window_blocks_live"] > 0
+        assert a["full_blocks_returned"] == 0
+        # one full layer reads all of a stream, four window layers 8 each
+        assert a["context_tokens_in_reach"] < 5 * a["context_tokens"]
+        assert a["context_tokens_in_reach"] \
+            == a["context_tokens"] + 4 * 8 * a["active"]
+    assert dispatched[-1]["window_blocks_returned"] \
+        > dispatched[0]["window_blocks_returned"]
+    first = found["prefill"][0][2]
+    assert set(first) <= set(span_args("prefill", names))
+    assert first["cached_tokens_full"] == 40 * first["slots"]
+    assert first["cached_tokens_window"] == 8 * first["slots"]
+    classes = report["cache_classes"]
+    assert classes["window"]["returned"] \
+        == dispatched[-1]["window_blocks_returned"]
+    assert classes["full"]["reach"] is None and classes["window"]["reach"] == 8
+

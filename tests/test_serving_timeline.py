@@ -404,21 +404,22 @@ def test_attend_keys_equal_the_per_slot_loop():
 
     class Spec:
         block_size, blocks_per_group, num_groups, num_layers = 16, 40, 2, 3
+        reach = None
 
     def shell(cost):
         eng = object.__new__(InferenceEngine)
-        eng.cache_spec, eng.max_slots = Spec, 12
+        eng.cache_spec, eng.cache_specs, eng.max_slots = Spec, (Spec,), 12
         eng.lengths = np.array([0, 1, 15, 16, 17, 200, 33, 64, 5, 0, 9, 640])
         eng.active = eng.lengths % 2 == 1
         eng._attend_cost = cost
         return eng
 
-    def per_key(context=None, pool_blocks=None):     # a K/V pool
+    def per_key(context=None, pool_blocks=None, spec=None):  # a K/V pool
         keys = pool_blocks * 16 if pool_blocks is not None \
             else -(-max(1, context) // 16) * 16
         return 4 * 20 * 64 * keys * 3, 2 * keys * 20 * 64 * 2 * 3
 
-    def constant(context=None, pool_blocks=None):    # a state
+    def constant(context=None, pool_blocks=None, spec=None):  # a state
         return 123_456, 7_890
 
     for cost in (per_key, constant):

@@ -621,7 +621,7 @@ def test_grouped_swiglu_compiles_at_the_published_widths(tokens, one_chip,
     from deepspeed_tpu.moe import share
     from deepspeed_tpu.ops import grouped_gemm as gg
     cfg = DeepseekV3Config(held=(0, 16))
-    tm = share._row_tile(tokens, cfg)
+    tm = share._row_tile(tokens, cfg.routing)
     assert tm == (16 if tokens == 128 else 32)
     M = -(-tokens * 8 // tm) * tm + 16 * tm         # the worst routing
     w = _sds((4 * 16, 2048, 7168), jnp.bfloat16)    # four layers' experts
