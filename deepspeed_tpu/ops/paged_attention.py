@@ -14,10 +14,11 @@ pool stays in HBM (``pl.ANY``) and the step copies each live block's tile
 — every head of the block, one contiguous DMA, named by one precomputed
 index with the layer's offset added (no layer is ever sliced out of the
 pool, relaid or copied around the call) — into one half of a VMEM buffer
-while it attends the previous P tiles in the other: scores and the weighted
-sum over P*bs positions at once, online-softmax accumulation in fp32
-scratch updated once a group, and an inclusive position mask (one vector
-compare) so the final partial block contributes exactly its written rows.
+(during a stream's last group: the NEXT stream's first tiles) while it
+attends the previous P tiles in the other: scores and the weighted sum over
+P*bs positions at once, online-softmax accumulation in fp32 scratch updated
+once a group, and an inclusive position mask (one vector compare) so the
+final partial block contributes exactly its written rows.
 
 Shapes follow the one-hot path exactly: q is ``[G, Q, K, nH, D]`` where K
 is the query rows PER STREAM — 1 for plain decode, k+1 for speculative
@@ -71,10 +72,14 @@ except Exception:  # pragma: no cover
 
 _ENV_KNOB = "DS_PAGED_KERNEL"
 # How many heads of a step the kernel's head loop lays side by side (the
-# scheduler interleaves their MXU / VPU chains). Timed on the v5e at the
-# serve cell's decode shape: 1 / 2 / 4 / 10 / 20 heads 30.6 / 18.1 / 14.4 /
-# 12.7 / 12.3 ms an execution; past four every start pays for it in
-# lowering time (0.18 / 0.38 / 0.72 s at 4 / 10 / 20).
+# scheduler interleaves their MXU / VPU chains). Timed on the v5e at
+# gpt2-large's decode shape (20 heads of 64, a group of 16 slots x 16
+# positions): 1 / 2 / 4 / 10 / 20 heads 30.6 / 18.1 / 14.4 / 12.7 / 12.3 ms
+# an execution; past four every start pays for it in lowering time (0.18 /
+# 0.38 / 0.72 s at 4 / 10 / 20). A group is what ``_tile_rule`` makes it by
+# shape (4 K/V heads of 128 in blocks of 64: 16 slots x 64 positions, all
+# four heads side by side); a head's body costs the same ops whatever the
+# group's width (``stack`` is one load).
 _HEAD_UNROLL = 4
 
 
@@ -177,8 +182,8 @@ def _head_loop(bh, body):
 
 
 def _pattn_kernel(nlive_ref, rows_ref, base_ref, lim_ref, q_ref, k_hbm,
-                  v_hbm, o_ref, k_buf, v_buf, sem, m_scr, l_scr, acc_scr, *,
-                  scale, bs, K, D, P):
+                  v_hbm, o_ref, k_buf, v_buf, sem, ahead, m_scr, l_scr,
+                  acc_scr, *, scale, bs, K, D, P):
     """One grid step = one (stream, head block): ALL of the stream's live
     blocks, P table slots at a time. The pools stay in HBM (``pl.ANY``);
     the step copies group g + 1's block tiles (every head of the block:
@@ -187,6 +192,15 @@ def _pattn_kernel(nlive_ref, rows_ref, base_ref, lim_ref, q_ref, k_hbm,
     LIVE block only: a dead stream's step moves and computes nothing.
     m / l / acc are the standard online-softmax carry, updated once a
     group per head.
+
+    The copies run across grid steps too: during its LAST group a step
+    starts the first group of the NEXT grid step (the next head block,
+    else the next stream, where that one is live) into the half it has
+    left, so a stream's first copies lie behind the stream before's
+    compute and only a stream after a dead one starts cold. The grid is
+    sequential and the buffers, the semaphores and ``ahead`` (SMEM:
+    whether this step's first group is already in flight, and in which
+    half) live across its steps.
 
     A head's K/V tile lies in the buffer as the pool holds it,
     lane-dense ``[bs/f, f*D]``: position t of the block at row t // f,
@@ -223,27 +237,45 @@ def _pattn_kernel(nlive_ref, rows_ref, base_ref, lim_ref, q_ref, k_hbm,
     N = P * (bs // f)
     nlive = nlive_ref[s_idx]
     groups = pl.cdiv(nlive, P)
-    heads = pl.ds(hb * bh, bh)
 
-    def tiles_of(g, slot, act):
-        """``act`` on the K and V copy of every live slot of group g
-        (into buffer half ``slot``)."""
+    @pl.when(jnp.logical_and(s_idx == 0, hb == 0))
+    def _first_step():
+        ahead[0] = 0
+        ahead[1] = 0
+
+    def tiles_of(s, hb, g, slot, act):
+        """``act`` on the K and V copy of every live slot of group g of
+        stream s, head block hb (into buffer half ``slot``)."""
+        heads = pl.ds(hb * bh, bh)
+
         def one(p, carry):
-            row = rows_ref[s_idx, g * P + p] + base_ref[0]
+            row = rows_ref[s, g * P + p] + base_ref[0]
             for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
                 act(pltpu.make_async_copy(hbm.at[row, heads],
                                           buf.at[slot, p], sem.at[slot]))
             return carry
-        jax.lax.fori_loop(0, jnp.minimum(P, nlive - g * P), one, 0)
+        jax.lax.fori_loop(0, jnp.minimum(P, nlive_ref[s] - g * P), one, 0)
+
+    # The grid step after this one, and whether it has anything to copy.
+    streams = pl.num_programs(0)
+    wrap = hb + 1 == pl.num_programs(1)
+    s_next = jnp.where(wrap, s_idx + 1, s_idx)
+    hb_next = jnp.where(wrap, 0, hb + 1)
+    next_live = jnp.logical_and(
+        s_next < streams, nlive_ref[jnp.minimum(s_next, streams - 1)] > 0)
 
     def group(g, carry):
-        slot = jax.lax.rem(g, 2)
+        slot = jax.lax.rem(ahead[1] + g, 2)
 
         @pl.when(g + 1 < groups)
         def _next():
-            tiles_of(g + 1, 1 - slot, lambda dma: dma.start())
+            tiles_of(s_idx, hb, g + 1, 1 - slot, lambda dma: dma.start())
 
-        tiles_of(g, slot, lambda dma: dma.wait())
+        @pl.when(jnp.logical_and(g + 1 == groups, next_live))
+        def _next_step():
+            tiles_of(s_next, hb_next, 0, 1 - slot, lambda dma: dma.start())
+
+        tiles_of(s_idx, hb, g, slot, lambda dma: dma.wait())
 
         def zero_v(p, carry):
             v_buf[slot, p] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
@@ -266,10 +298,10 @@ def _pattn_kernel(nlive_ref, rows_ref, base_ref, lim_ref, q_ref, k_hbm,
 
         def stack(buf, h):
             # In-VMEM dequant: bf16 pool tiles upcast at the registers,
-            # scores and the accumulator stay fp32 throughout.
-            return jnp.concatenate(
-                [buf[slot, p, h].astype(jnp.float32) for p in range(P)],
-                axis=0)
+            # scores and the accumulator stay fp32 throughout. One load
+            # of the head's P tiles whatever P is (a kernel body is traced
+            # op by op on every start).
+            return buf[slot, :, h].astype(jnp.float32).reshape(N, fD)
 
         def head(h):
             q_h = q_ref[0, h].astype(jnp.float32)     # [f*K, f*D]
@@ -324,8 +356,15 @@ def _pattn_kernel(nlive_ref, rows_ref, base_ref, lim_ref, q_ref, k_hbm,
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
-        tiles_of(0, 0, lambda dma: dma.start())
+
+        @pl.when(ahead[0] == 0)
+        def _cold():
+            tiles_of(s_idx, hb, 0, ahead[1], lambda dma: dma.start())
+
         jax.lax.fori_loop(0, groups, group, 0)
+        # What the last group started for the next step, and where.
+        ahead[1] = jax.lax.rem(ahead[1] + groups, 2)
+        ahead[0] = next_live.astype(jnp.int32)
         _head_loop(bh, merge)
 
 
@@ -360,6 +399,18 @@ def _step_vmem_bytes(bh: int, P: int, K: int, D: int, bs: int,
     return 2 * (kv + q + out + lim) + scratch + live
 
 
+# What a group of a step of few query rows copies, K and V tiles together,
+# where the table is that long. On the v5e at head_dim 128, blocks of 64,
+# 4 K/V heads of 8 query rows (PERF.md section 6, PR 40): 0.25 / 0.5 / 1 /
+# 2 MiB a group (2 / 4 / 8 / 16 slots) read 44 / 49 / 78 / 86% of the
+# bandwidth over long tables and 39 / 41 / 59 / 60% over a window's walks
+# of 33 blocks; the copies alone stop at 89%, and 4 MiB would not fit.
+_GROUP_BYTES = 2 ** 21
+# Query rows from which a step's products fill the MXU: such a step (a
+# prefill chunk) is bound by them, not by the copies behind them.
+_DENSE_ROWS = 128
+
+
 def _tile_rule(K: int, nH: int, D: int, bs: int, J: int, itemsize: int,
                q_itemsize: int = 2):
     """(heads a step, table slots a step) from the shapes. Slots: as
@@ -367,12 +418,24 @@ def _tile_rule(K: int, nH: int, D: int, bs: int, J: int, itemsize: int,
     no more than the table is wide. Heads: the most that divide nH and
     keep the step under ``_VMEM_BUDGET`` — all of them for decode and
     verify, fewer for a prefill chunk whose f*K query rows carry 128-lane
-    fp32 state each."""
-    P = max(1, min(J, 128 // max(1, bs // _fold(D, bs))))
+    fp32 state each. Then, for a step of few query rows (decode, verify),
+    slots again: doubled while a group copies under ``_GROUP_BYTES``, the
+    table has them and the step stays under the budget — what such a step
+    costs is its sequencing and the latency of its copies, a group each,
+    so it wants its bytes in few groups (wide heads in long blocks reach
+    128 lanes with a fifth of the bytes narrow ones in short blocks
+    do)."""
+    f = _fold(D, bs)
+    fits = lambda bh, P: _step_vmem_bytes(  # noqa: E731
+        bh, P, K, D, bs, itemsize, q_itemsize) <= _VMEM_BUDGET
+    P = max(1, min(J, 128 // max(1, bs // f)))
     bh = nH
-    while bh > 1 and (nH % bh or _step_vmem_bytes(
-            bh, P, K, D, bs, itemsize, q_itemsize) > _VMEM_BUDGET):
+    while bh > 1 and (nH % bh or not fits(bh, P)):
         bh -= 1
+    tile = 2 * bh * bs * D * itemsize            # a slot's K and V tiles
+    while (f * K < _DENSE_ROWS and P * tile < _GROUP_BYTES
+           and 2 * P <= J and fits(bh, 2 * P)):
+        P *= 2
     return bh, P
 
 
@@ -541,6 +604,7 @@ def _paged_local(q, pool_k, pool_v, layer, nlive, rows, lim, *, scale,
             out_specs=[pl.BlockSpec((1, bh, K, D), _stream_map)],
             scratch_shapes=[
                 kv_buf, kv_buf, pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((2,), jnp.int32),
                 pltpu.VMEM((bh, f * K, 128), jnp.float32),
                 pltpu.VMEM((bh, f * K, 128), jnp.float32),
                 pltpu.VMEM((bh, f * K, fD), jnp.float32),

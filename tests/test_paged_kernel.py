@@ -257,6 +257,185 @@ class TestKernelParity:
             bh, P = pa._tile_rule(K, 20, 64, 16, 64, 2)
             assert pa._step_vmem_bytes(bh, P, K, 64, 16, 2, 2) \
                 <= pa._VMEM_BUDGET
+        # gpt2-large's tiles are what they were: a decode group of
+        # sixteen slots already copies 1.25 MiB and thirty-two would not
+        # fit; a chunk's rows fill the MXU and ask for no more bytes.
+        assert pa._tile_rule(128, 20, 64, 16, 64, 2) == (5, 16)
+        assert pa._step_vmem_bytes(20, 32, 1, 64, 16, 2, 2) \
+            > pa._VMEM_BUDGET
+
+    @pytest.mark.parametrize("what,K,J,itemsize,tiles", [
+        # 4 K/V heads of 128 under 32 query heads, blocks of 64
+        # (Trinity-Mini, `serve.trinity-mini.mixed-docqa-over`): 128
+        # lanes are TWO slots = 0.25 MiB a group; a step of few query
+        # rows takes slots until a group copies 2 MiB ...
+        ("decode_full_table", 8, 528, 2, (4, 16)),
+        ("decode_window_ring", 8, 41, 2, (4, 16)),
+        ("verify_k5", 40, 528, 2, (4, 16)),
+        # ... no more than the table has (a power of two of them) ...
+        ("decode_short_ring", 8, 9, 2, (4, 8)),
+        ("decode_table_of_three", 8, 3, 2, (4, 2)),
+        # ... a float32 pool's slot is twice the bytes ...
+        ("decode_f32_pool", 8, 528, 4, (4, 8)),
+        # ... and a prefill run of 64 rows x 8 heads = 512 query rows a
+        # K/V head keeps two: its products fill the MXU, and four slots
+        # would not fit beside its fp32 state.
+        ("prefill_run_512_rows", 512, 528, 2, (4, 2)),
+        ("prefill_run_window", 512, 41, 2, (4, 2)),
+    ])
+    def test_tile_rule_at_wide_grouped_heads(self, what, K, J, itemsize,
+                                             tiles):
+        got = pa._tile_rule(K, 4, 128, 64, J, itemsize, 2)
+        assert got == tiles
+        bh, P = got
+        assert pa._step_vmem_bytes(bh, P, K, 128, 64, itemsize, 2) \
+            <= pa._VMEM_BUDGET
+        if K < pa._DENSE_ROWS and 2 * P <= J:
+            # the rule stopped because the group is large enough, or
+            # because twice the slots would not fit
+            assert 2 * bh * P * 64 * 128 * itemsize >= pa._GROUP_BYTES \
+                or pa._step_vmem_bytes(bh, 2 * P, K, 128, 64, itemsize, 2) \
+                > pa._VMEM_BUDGET
+
+
+# --------------------------------------------------------------------- #
+# Wide, grouped heads in long blocks (head_dim 128, blocks of 64, 8 query
+# heads a K/V head), with and without a window: the group step by bytes
+# --------------------------------------------------------------------- #
+def _wide_case(seed, contexts, *, reach=None, J, nKV=2, grp=8, D=128, bs=64,
+               dtype=jnp.float32):
+    """One group of streams at the given contexts (0: dead) over a
+    two-layer pool as held; a window's table is a ring of J slots that
+    holds the blocks in reach.  Returns q, pools, the tables, positions."""
+    rng = np.random.default_rng(seed)
+    Q = len(contexts)
+    need = sum((c - 1) // bs + 1 if reach is None
+               else (c - 1) // bs - max(0, c - reach) // bs + 1
+               for c in contexts if c)
+    B = need + 3
+    pools = [jnp.asarray(rng.normal(size=(2, 1, B, nKV, bs, D)), dtype)
+             for _ in range(2)]
+    q = jnp.asarray(rng.normal(size=(1, Q, 1, nKV * grp, D)), dtype)
+    bt = np.full((1, Q, J), kv_cache.DEAD_BLOCK, np.int32)
+    pos = np.full((1, Q, 1), -1, np.int32)
+    free = list(rng.permutation(B))
+    for s_, c in enumerate(contexts):
+        if not c:
+            continue
+        pos[0, s_, 0] = c - 1
+        first = 0 if reach is None else max(0, c - reach) // bs
+        for j in range(first, (c - 1) // bs + 1):
+            bt[0, s_, j % J] = free.pop()
+    return q, pools, jnp.asarray(bt), jnp.asarray(pos)
+
+
+def _wide_kernel(q, pools, bt, pos, reach, tiles, grp=8):
+    """The kernel's layer 1 at ``tiles`` (None: the shape rule's)."""
+    D = q.shape[-1]
+    plan = pa.attend_plan(bt, pos, pools[0], D, reach=reach, group=grp)
+    return np.asarray(pa.paged_attention(
+        q, pools[0], pools[1], 1, plan=plan, scale=D ** -0.5, tiles=tiles),
+        np.float32)
+
+
+def _wide_baseline(q, pools, bt, pos, reach):
+    """The served model's own attend without the kernel: blocks gathered,
+    a mask from positions."""
+    from deepspeed_tpu.inference import afmoe as afmoe_serving
+    return np.asarray(afmoe_serving._gather_attend(
+        q, pools[0], pools[1], 1, bt, pos, reach, q.shape[-1] ** -0.5),
+        np.float32)
+
+
+class TestWideGroupedHeads:
+    # live blocks a stream: 33, 1, 16, dead, 5, 9 — a last group part dead
+    # at every P, P = 3 and 5 divide neither a live count nor J = 40
+    FULL = [2100, 30, 1024, 0, 300, 520]
+    # a window of 200 positions behind a ring of 5: a stream inside its
+    # first block, one whose ring has wrapped many times, one that ends
+    # on a block's last row, a dead one, one a row into a new block
+    WINDOW = [30, 1500, 640, 0, 321]
+
+    @pytest.mark.parametrize("P", [1, 2, 3, 4, 5, 8, 16])
+    def test_slots_a_group_over_a_long_table(self, P):
+        case = _wide_case(11, self.FULL, J=40)
+        got = _wide_kernel(*case, None, (2, P))
+        np.testing.assert_allclose(got, _wide_baseline(*case, None),
+                                   atol=2e-5, rtol=2e-5)
+        assert not got[0, 3].any()
+        # regrouping the online softmax moves a float32 sum's order, no more
+        np.testing.assert_allclose(got, _wide_kernel(*case, None, (2, 2)),
+                                   atol=2e-6, rtol=2e-6)
+        # ... and heads a step not a bit
+        np.testing.assert_array_equal(got,
+                                      _wide_kernel(*case, None, (1, P)))
+
+    @pytest.mark.parametrize("P", [1, 2, 3, 4])
+    def test_slots_a_group_over_a_windows_ring(self, P):
+        case = _wide_case(12, self.WINDOW, reach=200, J=5)
+        got = _wide_kernel(*case, 200, (2, P))
+        np.testing.assert_allclose(got, _wide_baseline(*case, 200),
+                                   atol=2e-5, rtol=2e-5)
+        assert not got[0, 3].any()
+        np.testing.assert_allclose(got, _wide_kernel(*case, 200, (2, 2)),
+                                   atol=2e-6, rtol=2e-6)
+
+    @pytest.mark.parametrize("reach,J", [(None, 528), (2048, 41)],
+                             ids=["full", "window"])
+    def test_the_rules_own_tiles(self, reach, J):
+        # the cell's tables at the rule's own slots a group (sixteen for two
+        # K/V heads of float32): a long stream beside one of one block, a
+        # dead one between them (the stream after it starts cold, the
+        # others find their first group in flight)
+        assert pa._tile_rule(8, 2, 128, 64, J, 4, 4) == (2, 16)
+        case = _wide_case(13, [2400, 0, 40, 2049, 700], reach=reach, J=J)
+        got = _wide_kernel(*case, reach, None)
+        np.testing.assert_allclose(got, _wide_baseline(*case, reach),
+                                   atol=2e-5, rtol=2e-5)
+        assert not got[0, 1].any()
+
+    def test_one_block_beside_five_hundred(self):
+        case = _wide_case(14, [500 * 64 - 7, 9], J=512, nKV=1)
+        want = _wide_baseline(*case, None)
+        for tiles in ((1, 16), (1, 8)):
+            np.testing.assert_allclose(_wide_kernel(*case, None, tiles),
+                                       want, atol=2e-5, rtol=2e-5)
+
+    def test_every_order_of_live_and_dead_steps_runs_ahead_alike(self):
+        # The copies run ahead across grid steps (a step's last group
+        # starts the next live step's first): a stream's output is its
+        # own whatever came before it — live, dead, or nothing — and
+        # whichever buffer half its first group landed in.
+        ctx = [130, 0, 0, 700, 64, 65, 0, 1]
+        q, pools, bt, pos = case = _wide_case(15, ctx, J=12)
+        got = _wide_kernel(*case, None, (2, 2))
+        np.testing.assert_allclose(got, _wide_baseline(*case, None),
+                                   atol=2e-5, rtol=2e-5)
+        for s_ in (0, 3, 4, 5, 7):
+            alone = _wide_kernel(q[:, s_:s_ + 1], pools, bt[:, s_:s_ + 1],
+                                 pos[:, s_:s_ + 1], None, (2, 2))
+            np.testing.assert_array_equal(got[:, s_], alone[:, 0])
+        # head blocks are grid steps too: one head a step
+        np.testing.assert_array_equal(got,
+                                      _wide_kernel(*case, None, (1, 2)))
+
+    def test_bf16_pool_and_queries_at_the_cells_widths(self):
+        # bf16 q and pools as the cell holds them. In interpret mode the
+        # kernel widens them and contracts in float32, so against the
+        # baseline on the same values it differs by the output's
+        # rounding to bf16 alone: half an ulp, 2**-9 relative. (On the
+        # chip the MXU takes the operands as bf16 in one pass and rounds
+        # the probabilities on the way in, which a contraction written
+        # on bf16 operands reproduces bit for bit: PERF.md section 6,
+        # PR 40.)
+        q, pools, bt, pos = _wide_case(16, self.FULL, J=40,
+                                       dtype=jnp.bfloat16)
+        want = _wide_baseline(q.astype(jnp.float32),
+                              [x.astype(jnp.float32) for x in pools], bt,
+                              pos, None)
+        np.testing.assert_allclose(_wide_kernel(q, pools, bt, pos, None,
+                                                None),
+                                   want, rtol=2 ** -8, atol=2 ** -10)
 
 
 # --------------------------------------------------------------------- #

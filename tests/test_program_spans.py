@@ -423,6 +423,26 @@ def test_attend_step_counts_from_live_blocks():
     assert steps == live and steps > 1 and 20 % steps == 0
 
 
+@pytest.mark.parametrize("K,slots,by_hand", [
+    # `serve.trinity-mini.mixed-docqa-over`'s full class: 4 K/V heads of
+    # 128 (one head block), blocks of 64, a table of 528, bf16. Decode's 8
+    # query rows a K/V head walk SIXTEEN slots a group (2 MiB of K and V
+    # tiles): 500 live blocks are 32 groups, 33 three, 16 and 1 one, a
+    # dead stream one empty step ...
+    (8, 16, (38, 37)),
+    # ... a prefill run's 512 rows keep two slots a group (the parent's
+    # rule for decode too: 250 + 17 + 8 + 1 groups).
+    (512, 2, (277, 276)),
+])
+def test_attend_step_counts_at_wide_grouped_heads(K, slots, by_hand):
+    from deepspeed_tpu.ops.paged_attention import (_tile_rule,
+                                                   attend_step_counts)
+    cell = dict(num_heads=4, head_dim=128, block_size=64, table_width=528,
+                kv_itemsize=2)
+    assert _tile_rule(K, 4, 128, 64, 528, 2) == (4, slots)
+    assert attend_step_counts([500, 33, 16, 0, 1], K=K, **cell) == by_hand
+
+
 @pytest.fixture(scope="module")
 def train_annotations(train_engine, tmp_path_factory):
     train_engine.train_batch(_batch())          # compiled before

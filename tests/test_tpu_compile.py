@@ -305,6 +305,52 @@ def test_paged_attention_at_the_serve_cells_shape(K, Q, one_chip, as_tpu):
         <= pa._VMEM_BUDGET < pa._VMEM_LIMIT
 
 
+@pytest.mark.parametrize("cls,blocks,layers,J,reach", [
+    ("full", 16384, 1, 528, None), ("window", 6144, 4, 41, 2048)])
+@pytest.mark.parametrize("K,Q,tiles", [(1, 128, (4, 16)), (64, 8, (4, 2))],
+                         ids=["decode_128_streams", "prefill_run_512_rows"])
+def test_paged_attention_at_the_mixed_cells_shapes(K, Q, tiles, cls, blocks,
+                                                   layers, J, reach,
+                                                   one_chip, as_tpu):
+    """The attend ALONE at `serve.trinity-mini.mixed-docqa-over`'s shapes
+    (4 K/V heads of 128 under 32 query heads, blocks of 64, bf16; the full
+    class's 16,384 blocks x 1 layer behind a table of 528 and the window
+    class's 6,144 x 4 behind a ring of 41): decode's 8 query rows a K/V
+    head walk SIXTEEN slots a group (2 MiB of K and V tiles in flight), a
+    prefill run's 512 rows keep two; scoped VMEM asked and kept at the
+    default 16 MiB, and nothing but parameters, bitcasts and the kernel
+    holds a pool or a layer of it."""
+    from deepspeed_tpu.analysis.hlo_text import ops_in_units_of
+    from deepspeed_tpu.ops import paged_attention as pa
+    nKV, grp, Dh, bs = 4, 8, 128, 64
+    assert pa._tile_rule(grp * K, nKV, Dh, bs, J, 2, 2) == tiles
+    assert pa._step_vmem_bytes(*tiles, grp * K, Dh, bs, 2, 2) \
+        <= pa._VMEM_BUDGET < pa._VMEM_LIMIT <= 16 * 2 ** 20
+    shape = (layers, 1, blocks, nKV, bs, Dh)
+    pool = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    args = [jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+            for sh, dt in (((1, Q, K, nKV * grp, Dh), jnp.bfloat16),
+                           ((), jnp.int32), ((1, Q, J), jnp.int32),
+                           ((1, Q, K), jnp.int32))]
+
+    def attend(q, pk, pv, layer, bt, pos):
+        plan = pa.attend_plan(bt, pos, pk, Dh, reach=reach, group=grp)
+        return pa.paged_attention(q, pk, pv, layer, plan=plan,
+                                  scale=Dh ** -0.5)
+    compiled = jax.jit(attend).lower(args[0], pool, pool,
+                                     *args[1:]).compile()
+    text = compiled.as_text()
+    seen = ops_in_units_of(text, math.prod(shape[2:]))
+    assert {op for op, _ in seen} <= {"parameter", "bitcast",
+                                      "custom-call"}, seen
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 20
+    calls = [line for line in text.splitlines()
+             if "%_pattn_kernel" in line.split(" = ")[0]
+             and " custom-call(" in line]
+    assert len(calls) == 1 and "tpu_custom_call" in calls[0]
+    assert f'"size":"{pa._VMEM_LIMIT}"' in calls[0]
+
+
 # ------------------------------------------------------------------ #
 # The fused optimizer's whole one-pass step at gpt2-large's shapes: what
 # the chip compiler makes of the in-place plan (ops/fused_update.py).
