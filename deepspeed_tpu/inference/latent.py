@@ -17,9 +17,24 @@ beside the layer index, as GPT-2's does; the expert weights ride whole
 ``kv_write``, ``attend``; ``mlp`` (dense layers); ``moe`` > ``router``,
 ``dispatch``, ``experts``, ``combine``, ``shared``.  Each program also
 returns the expert layers' counters, which ride the token fetch.
+
+With ``hc_mult`` = n (the ``xing4_0`` keys; ``models/hyper_connections.py``)
+both scans carry ``X [S, K, n, H]``: ``embed`` > ``hc_expand`` copies the
+embedding to n streams, every sublayer reads ``h = sum_j H_pre[j] X[j]``
+and writes ``X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y`` under ``hc`` >
+``hc_maps`` (norm over nH values, the ``[nH, 2n + n*n]`` product, sigmoids,
+Sinkhorn), ``hc_pre``, ``hc_post`` INSIDE the sublayer's own scope
+(``attn``, ``mlp``, ``moe``), and ``lm_head`` > ``hc_collapse`` sums the
+streams.  The maps are per token: the cache, chunked prefill, prefix hits
+and copy-on-write are what they are without them.  One more counter rides
+the fetch, ``hc_res_err_max`` (a float's bits): the largest deviation of a
+row or column sum of ``H_res`` from 1 over the execution's live rows.
+Without the key none of this is traced: the programs are the one-stream
+ones.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable, Dict, Tuple
 
 import jax
@@ -31,6 +46,7 @@ from . import kv_cache
 from .decode import NEG_INF, _group_shape, _write_targets
 from .served import ServedModel, register
 from ..models import deepseek_v3 as dsv3
+from ..models import hyper_connections as hyper
 from ..models.deepseek_v3 import DeepseekV3Config
 from ..moe import share
 from ..ops import latent_attention as latent_ops
@@ -58,15 +74,20 @@ def _onehot_attend(q_abs, q_rope, pool, layer, sel, pos_mask, scale, C):
                       ).astype(q_abs.dtype)
 
 
+def _scope(name):
+    return jax.named_scope(name) if name else contextlib.nullcontext()
+
+
 def _forward(params, pool, x, bt_g, pos_g, live, cfg: DeepseekV3Config,
              paged_kernel: bool, mesh):
-    """All layers: x [S, K, H] with its streams' tables bt_g [G, Sg, J],
+    """All layers: x [S, K, H] (``[S, K, n, H]`` with ``hc_mult`` = n
+    residual streams) with its streams' tables bt_g [G, Sg, J],
     row positions pos_g [G, Sg, K] and ``live`` [S, K]: the rows that are
     traffic (a live stream's, and no padding).  The others write no cache
     row, attend nothing, get no expert row and are not counted; what they
     compute nobody reads.  Returns (x', pool', counters)."""
     G, Sg, J = bt_g.shape
-    S, K, H = x.shape
+    S, K, H = x.shape[:2] + x.shape[-1:]
     nH, C = cfg.num_attention_heads, cfg.kv_lora_rank
     bs = 2 * pool.shape[4]
     pos = pos_g.reshape(S, K)
@@ -83,16 +104,48 @@ def _forward(params, pool, x, bt_g, pos_g, live, cfg: DeepseekV3Config,
     blk, off = _write_targets(bt_g, pos_g, bs)
     blk = jnp.where(live_g.reshape(G, Sg * K), blk, kv_cache.DEAD_BLOCK)
 
+    # The residual path.  One stream: a sublayer reads x and adds to it.
+    # ``hc_mult`` streams: it reads a mixture of them and writes back
+    # through two more maps (models/hyper_connections.py), per token, so
+    # nothing of it enters the cache.  ``outer`` names the sublayer's scope
+    # where the caller is not inside it; ``plain`` is where the one-stream
+    # add is filed.
+    def read(p, sub, x, outer=None):
+        if cfg.hyper is None:
+            return x, None
+        with _scope(outer), jax.named_scope("hc"):
+            with jax.named_scope("hc_maps"):
+                m = dsv3.hc_maps(p, sub, x, cfg)
+            with jax.named_scope("hc_pre"):
+                return hyper.mix_in(m, x), m
+
+    def write(m, x, y, outer=None, plain=None):
+        if m is None:
+            with _scope(plain):
+                return x + y
+        with _scope(outer), jax.named_scope("hc"), \
+                jax.named_scope("hc_post"):
+            return hyper.mix_out(m, x, y)
+
+    def res_error(m_attn, m_ffn):
+        """A layer's scan output: what Sinkhorn left of its two maps (None
+        on one stream)."""
+        if cfg.hyper is None:
+            return None
+        return jnp.maximum(hyper.res_error(m_attn, live),
+                           hyper.res_error(m_ffn, live))
+
     def attention(p, x, pool, layer):
         with jax.named_scope("attn"):
+            h, m = read(p, "attn", x)
             with jax.named_scope("latent_proj"):
-                h = dsv3.rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
+                h = dsv3.rms_norm(h, p["input_norm"], cfg.rms_norm_eps)
                 q_nope, q_rope, ckv, k_rope = dsv3.latent_projections(
                     p, h, pos, cfg)
                 wk, wv = dsv3.wkv_b_split(p, cfg)
                 q_abs = jnp.einsum(
-                    "sknd,cnd->sknc", q_nope, wk.astype(x.dtype),
-                    preferred_element_type=jnp.float32).astype(x.dtype)
+                    "sknd,cnd->sknc", q_nope, wk.astype(h.dtype),
+                    preferred_element_type=jnp.float32).astype(h.dtype)
                 row = jnp.concatenate([ckv, k_rope], axis=-1)
             with jax.named_scope("kv_write"):
                 pool = latent_ops.latent_write(
@@ -111,22 +164,24 @@ def _forward(params, pool, x, bt_g, pos_g, live, cfg: DeepseekV3Config,
             with jax.named_scope("latent_proj"):
                 o = jnp.einsum(
                     "sknc,cnv->sknv", u.reshape(S, K, nH, C),
-                    wv.astype(x.dtype), preferred_element_type=jnp.float32
-                ).astype(x.dtype).reshape(S, K, nH * cfg.v_head_dim)
-                x = x + dsv3.matmul(o, p["wo"])
-        return x, pool
+                    wv.astype(h.dtype), preferred_element_type=jnp.float32
+                ).astype(h.dtype).reshape(S, K, nH * cfg.v_head_dim)
+                y = dsv3.matmul(o, p["wo"])
+            x = write(m, x, y, plain="latent_proj")
+        return x, pool, m
 
     def dense_layer(carry, layer_in):
         p, layer = layer_in
-        x, pool = attention(p, *carry, layer)
+        x, pool, m_attn = attention(p, *carry, layer)
         with jax.named_scope("mlp"):
-            h = dsv3.rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
-            x = x + dsv3.swiglu(h, p["mlp_gate"], p["mlp_up"],
-                                p["mlp_down"])
-        return (x, pool), None
+            h, m = read(p, "ffn", x)
+            h = dsv3.rms_norm(h, p["post_norm"], cfg.rms_norm_eps)
+            x = write(m, x, dsv3.swiglu(h, p["mlp_gate"], p["mlp_up"],
+                                        p["mlp_down"]))
+        return (x, pool), res_error(m_attn, m)
 
     Ld, Le = cfg.num_dense_layers, cfg.num_moe_layers
-    (x, pool), _ = lax.scan(
+    (x, pool), err_dense = lax.scan(
         dense_layer, (x, pool),
         (params["dense"], jnp.arange(Ld, dtype=jnp.int32)))
 
@@ -136,28 +191,38 @@ def _forward(params, pool, x, bt_g, pos_g, live, cfg: DeepseekV3Config,
     def moe_layer(carry, layer_in):
         p, l = layer_in
         x, pool, (pairs, most, empty) = carry
-        x, pool = attention(p, x, pool, Ld + l)
-        h = dsv3.rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
+        x, pool, m_attn = attention(p, x, pool, Ld + l)
+        h, m = read(p, "ffn", x, outer="moe")
+        h = dsv3.rms_norm(h, p["post_norm"], cfg.rms_norm_eps)
         # ``paged_kernel`` is "this path runs its Pallas kernels": the
         # attend, the row write and the grouped expert product alike.
         y, counts = share.expert_layer(
             dict(p, **experts), h.reshape(S * K, H), cfg.routing,
             kernel=paged_kernel, layer=l, row_live=row_live)
-        x = x + y.reshape(S, K, H)
+        x = write(m, x, y.reshape(S, K, H), outer="moe")
         stats = (pairs + counts.sum(), jnp.maximum(most, counts.max()),
                  empty + (counts == 0).sum())
-        return (x, pool, stats), None
+        return (x, pool, stats), res_error(m_attn, m)
 
     zero = jnp.zeros((), jnp.int32)
-    (x, pool, stats), _ = lax.scan(
+    (x, pool, stats), err_moe = lax.scan(
         moe_layer, (x, pool, (zero, zero, zero)),
         ({k: v for k, v in params["moe"].items() if k not in _EXPERT_KEYS},
          jnp.arange(Le, dtype=jnp.int32)))
-    return x, pool, stats + (row_live.sum().astype(jnp.int32),)
+    stats += (row_live.sum().astype(jnp.int32),)
+    if cfg.hyper is not None:
+        # A float among the int32 counters: its bits ride the token fetch.
+        err = jnp.maximum(err_dense.max(), err_moe.max())
+        stats += (lax.bitcast_convert_type(err, jnp.int32),)
+    return x, pool, stats
 
 
 @jax.named_scope("lm_head")
 def _head(params, h, cfg):
+    """Logits of ``h [..., H]`` (``[..., n, H]``: the streams' sum)."""
+    if cfg.hyper is not None:
+        with jax.named_scope("hc_collapse"):
+            h = hyper.collapse(h)
     h = dsv3.rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
     logits = jnp.dot(h, params["lm_head"].astype(h.dtype).T,
                      preferred_element_type=jnp.float32)
@@ -170,13 +235,22 @@ def _head(params, h, cfg):
 
 @jax.named_scope("embed")
 def _embed(params, tokens, cfg):
-    return params["embed"].astype(cfg.dtype)[tokens]
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    if cfg.hyper is None:
+        return x
+    with jax.named_scope("hc_expand"):
+        return hyper.expand(x, cfg.hyper.mult)
 
 
 class LatentServed(ServedModel):
     """See the module docstring."""
     counter_names = ("moe_held_pairs", "moe_held_max", "moe_held_empty",
                      "moe_rows")
+
+    def __init__(self, cfg):
+        super().__init__(cfg)
+        if cfg.hyper is not None:
+            self.counter_names += ("hc_res_err_max",)
 
     @property
     def max_positions(self) -> int:
@@ -224,11 +298,17 @@ class LatentServed(ServedModel):
         cells = len(rows) * cfg.num_moe_layers * cfg.held[1]
         routed = int(rows[:, 3].sum()) * cfg.num_experts_per_tok \
             * cfg.num_moe_layers
-        return {"moe_held_pairs": pairs,
+        args = {"moe_held_pairs": pairs,
                 "moe_held_max": int(rows[:, 1].max()),
                 "moe_held_mean": pairs / cells,
                 "moe_held_empty": int(rows[:, 2].sum()),
                 "moe_held_pair_share": pairs / routed if routed else 0.0}
+        if cfg.hyper is not None:
+            # The largest |row or column sum of H_res - 1| over the live
+            # rows of every sublayer: what the Sinkhorn iterations left.
+            args["hc_res_err_max"] = float(
+                rows[:, 4].astype(np.int32).view(np.float32).max())
+        return args
 
     # -- programs ------------------------------------------------------ #
     def verify(self, params, pools, tokens, lengths, block_tables, *,
@@ -266,8 +346,9 @@ class LatentServed(ServedModel):
             pos[:, None, :], live, cfg, paged_kernel, mesh)
         oh = (lax.broadcasted_iota(jnp.int32, (G, Cn), 1)
               == last_idx[:, None]).astype(x.dtype)
-        h_last = jnp.einsum("gc,gch->gh", oh, x)
-        return _head(params, h_last, cfg), (pool,), counters
+        h_last = jnp.einsum("gc,gch->gh", oh, x.reshape(G, Cn, -1))
+        return _head(params, h_last.reshape((G,) + x.shape[2:]), cfg), \
+            (pool,), counters
 
 
 register(DeepseekV3Config, LatentServed)

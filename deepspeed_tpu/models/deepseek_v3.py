@@ -26,17 +26,31 @@ tile is one contiguous run of HBM):
       moe:   router [H, E]  router_bias [E] (fp32)
              w_gate / w_up / w_down [E_held, F, H]
              shared_gate [H, Fs]  shared_up [H, Fs]  shared_down [Fs, H]
+      with ``hc_mult`` = n > 0 (the ``xing4_0`` keys), for sub in attn, ffn:
+             hc_<sub>_phi [n*H, 2n + n*n]  hc_<sub>_b [2n + n*n]
+             hc_<sub>_alpha [3]  (all fp32)
+
+``model_type: xing4_0`` is this family with FOUR residual streams: every
+sublayer (attention after ``input_norm``, the FFN or expert layer after
+``post_norm``) reads a learned mixture of the streams and writes back
+through two more maps, one of them projected onto the doubly stochastic
+matrices by Sinkhorn-Knopp iterations (``models/hyper_connections.py``
+has the equations).  A config asks for it with ``hc_mult`` (+
+``hc_sinkhorn_iters``, ``hc_eps``, ``mhc_h_res_clamp_min`` / ``_max``);
+without the key the block is ``x + f(norm(x))`` on one stream and the
+parameter tree has no ``hc_*`` leaf.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, ClassVar, Dict, Tuple
+from typing import Any, ClassVar, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import hyper_connections as hc
 from .blocks import Routing, matmul, rms_norm, rotary_cos_sin, swiglu
 
 
@@ -80,6 +94,14 @@ class DeepseekV3Config:
     max_position_embeddings: int = 262144
     initializer_range: float = 0.02
     router_bias_std: float = 0.1
+    # The ``xing4_0`` keys: ``hc_mult`` residual streams mixed by
+    # Sinkhorn-projected maps round every sublayer
+    # (``models/hyper_connections.py``); 0 = one stream, ``x + f(norm(x))``.
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
     dtype: Any = jnp.bfloat16
     # Where the family's served-model implementation registers itself
     # (``inference.served.served_model`` imports it on first use).
@@ -97,11 +119,15 @@ class DeepseekV3Config:
             raise ValueError("the stack is a dense prefix, then expert "
                              "layers: 0 < first_k_dense_replace < "
                              "num_hidden_layers")
+        if self.hc_mult < 0 or self.hc_mult == 1:
+            raise ValueError("hc_mult is 0 (one residual stream) or the "
+                             "number of streams, at least 2")
 
     @classmethod
     def from_hf(cls, cfg: Dict[str, Any], **overrides) -> "DeepseekV3Config":
         """From a ``config.json`` dict: every key this class names is
-        taken as published; ``rope_scaling`` is flattened."""
+        taken as published; ``rope_scaling`` is flattened; every routed
+        expert is held unless ``held`` says otherwise."""
         names = {f.name for f in dataclasses.fields(cls)}
         kw = {k: v for k, v in cfg.items() if k in names}
         rs = cfg.get("rope_scaling") or {}
@@ -112,12 +138,25 @@ class DeepseekV3Config:
             if k in rs:
                 kw["rope_" + k] = rs[k]
         kw.update(overrides)
+        if "n_routed_experts" in kw:
+            kw.setdefault("held", (0, kw["n_routed_experts"]))
         return cls(**kw)
 
     @property
     def name(self) -> str:
         return (f"deepseek_v3-h{self.hidden_size}-l{self.num_hidden_layers}"
-                f"-e{self.held[1]}of{self.n_routed_experts}")
+                f"-e{self.held[1]}of{self.n_routed_experts}"
+                + (f"-hc{self.hc_mult}" if self.hc_mult else ""))
+
+    @property
+    def hyper(self) -> Optional[hc.HyperConnections]:
+        """The residual maps' constants, or None for one stream."""
+        if not self.hc_mult:
+            return None
+        return hc.HyperConnections(
+            mult=self.hc_mult, iters=self.hc_sinkhorn_iters,
+            eps=self.hc_eps, clamp=(float(self.mhc_h_res_clamp_min),
+                                    float(self.mhc_h_res_clamp_max)))
 
     @property
     def vocab_rows(self) -> int:
@@ -259,12 +298,47 @@ def _norm_shapes(cfg: DeepseekV3Config) -> Dict[str, Tuple[int, ...]]:
             "kv_norm": (cfg.kv_lora_rank,), "post_norm": (cfg.hidden_size,)}
 
 
+_HC_SUBLAYERS = ("attn", "ffn")
+_HC_BIAS_STD = 1.0
+
+
+def _hc_init(key: jax.Array, n_layers: int, cfg: DeepseekV3Config
+             ) -> Dict[str, jax.Array]:
+    """The residual maps of ``n_layers`` layers, fp32: ``phi`` normal(0,
+    initializer_range), ``b`` normal(0, 1), ``alpha`` 1 — ALIVE
+    on purpose, as the router's bias is: under the papers' near-identity
+    start (``alpha`` 0.01, ``H_res`` = I) a wrong or skipped map would pass
+    every comparison."""
+    out = {}
+    shapes = hc.param_shapes(cfg.hyper, cfg.hidden_size)
+    for i, sub in enumerate(_HC_SUBLAYERS):
+        k_phi, k_b = jax.random.split(jax.random.fold_in(key, i))
+        out[f"hc_{sub}_phi"] = jax.random.normal(
+            k_phi, (n_layers,) + shapes["phi"], jnp.float32) \
+            * cfg.initializer_range
+        out[f"hc_{sub}_b"] = jax.random.normal(
+            k_b, (n_layers,) + shapes["b"], jnp.float32) * _HC_BIAS_STD
+        out[f"hc_{sub}_alpha"] = jnp.ones((n_layers,) + shapes["alpha"],
+                                          jnp.float32)
+    return out
+
+
+def hc_maps(p: Dict[str, jax.Array], sub: str, X: jax.Array,
+            cfg: DeepseekV3Config) -> hc.Maps:
+    """The maps of layer ``p``'s sublayer ``sub`` ("attn" / "ffn") for
+    ``X [..., n, H]``."""
+    return hc.maps(p[f"hc_{sub}_phi"], p[f"hc_{sub}_b"],
+                      p[f"hc_{sub}_alpha"], X, cfg.hyper)
+
+
 def deepseek_v3_init(rng: jax.Array, cfg: DeepseekV3Config
                      ) -> Dict[str, Any]:
     """Weights normal(0, initializer_range) in ``cfg.dtype``, norms 1,
     and the router's selection bias normal(0, router_bias_std) in fp32:
     NON-zero on purpose, so that choosing by ``s + b`` and weighting by
-    ``s`` are distinguishable in every comparison."""
+    ``s`` are distinguishable in every comparison.  With ``hc_mult`` the
+    residual maps' parameters (``_hc_init``), from keys of their own: the
+    rest of the tree is what it is without them."""
     H, I, F = cfg.hidden_size, cfg.intermediate_size, \
         cfg.moe_intermediate_size
     Fs = F * cfg.n_shared_experts
@@ -291,6 +365,10 @@ def deepseek_v3_init(rng: jax.Array, cfg: DeepseekV3Config
         shared_up=(H, Fs), shared_down=(Fs, H)))
     moe["router_bias"] = jax.random.normal(
         k_bias, (Le, E), jnp.float32) * cfg.router_bias_std
+    if cfg.hyper is not None:
+        k_hc = jax.random.fold_in(rng, 5)
+        dense.update(_hc_init(jax.random.fold_in(k_hc, 0), Ld, cfg))
+        moe.update(_hc_init(jax.random.fold_in(k_hc, 1), Le, cfg))
     return {
         "embed": (jax.random.normal(k_emb, (cfg.vocab_rows, H), jnp.float32)
                   * std).astype(cfg.dtype),
@@ -305,4 +383,4 @@ def deepseek_v3_init(rng: jax.Array, cfg: DeepseekV3Config
 __all__ = ["DeepseekV3Config", "deepseek_v3_init", "yarn_inv_freq",
            "yarn_mscale", "rotary_cos_sin", "rope_cos_sin",
            "rope_interleaved", "rms_norm", "matmul", "swiglu",
-           "latent_projections", "wkv_b_split"]
+           "latent_projections", "wkv_b_split", "hc_maps"]

@@ -48,7 +48,14 @@ SCOPES = ("fwd_bwd", "grad_sync", "health_tap", "optimizer", "flatten",
           # a model with two classes of cache layers (inference/afmoe.py):
           # under attn, the paged attend of a sliding-window layer and of a
           # full-attention layer (beside qkv_proj, kv_write, out_proj)
-          "attend_window", "attend_full")
+          "attend_window", "attend_full",
+          # several residual streams (models/hyper_connections.py, the
+          # latent family with ``hc_mult``): under attn / mlp / moe, hc >
+          # hc_maps (the norm, the maps' product, sigmoids, Sinkhorn),
+          # hc_pre (the sublayer's input mixed from the streams), hc_post
+          # (its output written back); embed > hc_expand, lm_head >
+          # hc_collapse
+          "hc", "hc_maps", "hc_pre", "hc_post", "hc_expand", "hc_collapse")
 # The host spans ``Telemetry.span`` opens (runtime/engine.py,
 # inference/engine.py, inference/scheduler.py), same contract.
 SPANS = ("train_batch", "data_prep", "step_dispatch", "offload_step",
@@ -66,6 +73,9 @@ SPAN_ARGS = {
     # held expert got in a layer, held experts (x layers) that got none,
     # the pairs' share of all the live rows routed. They ride the token
     # fetch. Absent for a model without counters (GPT-2).
+    # hc_res_err_max: a model with several residual streams: the largest
+    # deviation of a row or column sum of H_res from 1 over the live rows
+    # of the execution(s) fetched (what the Sinkhorn iterations left).
     # resumed_tokens / snapshot_taken / state_copy_bytes: a per-stream
     # state pool's admission (inference/kv_cache.py): prompt tokens the
     # snapshot it resumed from covers (what cached_tokens means there),
@@ -75,6 +85,7 @@ SPAN_ARGS = {
     "prefill": ("slots", "prompt_tokens", "rids", "cached_tokens",
                 "chunks", "rows_computed", "moe_held_pairs", "moe_held_max",
                 "moe_held_mean", "moe_held_empty", "moe_held_pair_share",
+                "hc_res_err_max",
                 "resumed_tokens", "snapshot_taken", "state_copy_bytes"),
     "prefill_chunk": ("ci", "active_groups"),
     # A decode span holds the DISPATCH of one iteration and the FETCH of
@@ -88,7 +99,7 @@ SPAN_ARGS = {
     "decode": ("iteration", "active", "live_blocks", "context_tokens",
                "attend_steps", "attend_live_steps", "moe_held_pairs",
                "moe_held_max", "moe_held_mean", "moe_held_empty",
-               "moe_held_pair_share",
+               "moe_held_pair_share", "hc_res_err_max",
                # pages of a per-stream pool the state-update kernel
                # rewrote in this execution (one a live stream)
                "state_pages_live",

@@ -677,11 +677,10 @@ def test_grouped_swiglu_compiles_at_the_published_widths(tokens, one_chip,
         _sds((), jnp.int32))
 
 
-@pytest.fixture(scope="module")
-def latent_programs(topo):
-    """{program: (spec, params bytes, compiled)} of the ENGINE's own step
-    builders for the latent-attention cell, on an engine shell (see
-    ``_serve_program``)."""
+def _latent_cell_programs(topo, config_file, **overrides):
+    """(spec, params bytes, {program: compiled}) of the ENGINE's own step
+    builders for a latent-attention cell's configuration file, on an
+    engine shell (see ``_serve_program``)."""
     import json
     import os
     from deepspeed_tpu.inference import kv_cache
@@ -692,12 +691,12 @@ def latent_programs(topo):
     from jax.experimental.compilation_cache import compilation_cache
     sizes = json.load(open(os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "perfbench", "configs", "gigachat3.1-702b-a36b.json")))
+        "perfbench", "configs", config_file)))
     inf = sizes["serve"]["inference"]
     cfg = DeepseekV3Config.from_hf(
         sizes, n_routed_experts=sizes["n_routed_experts_published"],
         held=(0, sizes["n_routed_experts"]),
-        vocab_rows_held=sizes["assumed"]["vocab_rows_held"])
+        vocab_rows_held=sizes["assumed"]["vocab_rows_held"], **overrides)
     one = SingleDeviceSharding(topo.devices[0])
 
     def on_chip(tree):
@@ -746,6 +745,17 @@ def latent_programs(topo):
     return spec, param_bytes, out
 
 
+@pytest.fixture(scope="module")
+def latent_programs(topo):
+    return _latent_cell_programs(topo, "gigachat3.1-702b-a36b.json")
+
+
+@pytest.fixture(scope="module")
+def hyper_connected_programs(topo):
+    """... for the cell whose blocks keep four residual streams (PR 41)."""
+    return _latent_cell_programs(topo, "xing4.0-29b-a4b.json")
+
+
 @pytest.mark.parametrize("program", ["decode_step", "prefill_step"])
 def test_latent_serve_step_fits_and_updates_the_pool_in_place(
         latent_programs, program):
@@ -775,6 +785,44 @@ def test_latent_serve_step_fits_and_updates_the_pool_in_place(
     assert not [(op, n) for op, n in ops_in_units_of(
         text, one_layers_experts) if op not in ("parameter", "bitcast",
                                                  "get-tuple-element")]
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_step"])
+def test_hyper_connected_serve_step_fits_beside_every_expert_and_row(
+        hyper_connected_programs, program):
+    """Weights 9.59 GB (64 of 64 experts, 131,072 vocabulary rows, the
+    fp32 residual maps) + latent pool 5.44 GB, the pool aliased to the
+    output; what the program needs beside them (the [256, 131072] fp32
+    logits, four 3,584-wide residual streams) leaves it inside the chip's
+    16 GiB; every kernel a TPU custom call; no layer's experts (1.41 GB)
+    sliced out of their stack and copied; the maps' Sinkhorn iterations
+    are traced (``hc`` in the op names)."""
+    from deepspeed_tpu.analysis.hlo_text import ops_in_units_of
+    spec, param_bytes, programs = hyper_connected_programs
+    compiled = programs[program]
+    assert abs(param_bytes - 9.594e9) < 0.01e9
+    assert spec.nbytes() == 12288 * 64 * 6 * 1152
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= spec.nbytes()
+    assert param_bytes + spec.nbytes() + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes \
+        < 15.75 * 2 ** 30, (mem.temp_size_in_bytes, mem.output_size_in_bytes)
+    text = compiled.as_text()
+    for kernel in ("_latent_attn_kernel", "_latent_write_kernel",
+                   "_gswiglu_kernel"):
+        calls = [line for line in text.splitlines()
+                 if f"%{kernel}" in line.split(" = ")[0]
+                 and " custom-call(" in line]
+        assert calls and all("tpu_custom_call" in c for c in calls), kernel
+    seen = ops_in_units_of(text, math.prod(spec.pool_shapes["latent"][2:]))
+    assert not [(op, n) for op, n in seen if op not in _POOL_OPS_ALLOWED]
+    # (the head's 131,072 x 3,584 happens to be two layers' worth of one
+    # expert matrix: what a fusion does on it in place is the head's)
+    one_layers_experts, head = 64 * 1024 * 3584, 131072 * 3584
+    assert not [(op, n) for op, n in ops_in_units_of(
+        text, one_layers_experts) if n != head and op not in (
+            "parameter", "bitcast", "get-tuple-element")]
+    assert "/hc/hc_maps" in text and "/hc/hc_post" in text
 
 
 # ------------------------------------------------------------------ #
