@@ -1022,15 +1022,17 @@ class InferenceEngine:
     def _attend_steps(self, k_rows: int,
                       lengths: Optional[np.ndarray] = None,
                       tables: Optional[np.ndarray] = None
-                      ) -> Tuple[int, int]:
-        """(steps, live steps) a layer of the paged kernel's attend in
-        the execution about to run — the ``decode`` span's
-        ``attend_steps`` / ``attend_live_steps`` (see
-        ``ops.paged_attention.attend_step_counts``), from the lengths
-        and tables the execution is handed (the host's own by default).
-        (0, 0) on the one-hot path, which has no steps."""
+                      ) -> Tuple[int, int, int]:
+        """(steps, live steps, cold steps) a layer of the paged kernel's
+        attend in the execution about to run — the ``decode`` span's
+        ``attend_steps`` / ``attend_live_steps`` / ``attend_cold_steps``
+        (see ``ops.paged_attention.attend_step_counts`` and
+        ``attend_cold_steps``), from the lengths and tables the execution
+        is handed (the host's own by default). Zeros on the one-hot path,
+        which has no steps; no cold ones for a state a stream, which no
+        attend walks."""
         if not self.paged_kernel:
-            return 0, 0
+            return 0, 0, 0
         sp_ = self.cache_spec
         lengths = self.lengths if lengths is None else lengths
         tables = self.block_tables if tables is None else tables
@@ -1039,9 +1041,11 @@ class InferenceEngine:
             np.minimum(reach, sp_.max_blocks_per_slot),
             (tables[:, :sp_.max_blocks_per_slot] >= 0).sum(axis=1))
         served = self.served
+        cold = 0 if sp_.per_stream else paged_attn_ops.attend_cold_steps(
+            live, calls=self.dp)
         return served.attend_step_counts(
             live, K=k_rows, spec=sp_, mp=self.mp,
-            q_itemsize=int(jnp.dtype(served.dtype).itemsize))
+            q_itemsize=int(jnp.dtype(served.dtype).itemsize)) + (cold,)
 
     def _attend_cost(self, context: Optional[int] = None,
                      pool_blocks: Optional[int] = None,
@@ -1241,6 +1245,7 @@ class InferenceEngine:
                               context_tokens=ctx_tokens,
                               attend_steps=steps[0],
                               attend_live_steps=steps[1],
+                              attend_cold_steps=steps[2],
                               **self._class_args(mask))
             if self.cache_spec.per_stream:
                 span.set_metadata(state_pages_live=n_active)
@@ -1413,7 +1418,8 @@ class InferenceEngine:
             span.set_metadata(live_blocks=live_blocks,
                               context_tokens=ctx_tokens,
                               attend_steps=steps[0],
-                              attend_live_steps=steps[1])
+                              attend_live_steps=steps[1],
+                              attend_cold_steps=steps[2])
         return emitted, n_new
 
     def _attach_slo_overlays(self) -> None:

@@ -282,9 +282,13 @@ class LatentServed(ServedModel):
                 self.cfg.kv_lora_rank)
 
     def attend_step_counts(self, live_blocks, *, K, spec, mp, q_itemsize):
-        tiles = -(-K // latent_ops.row_tokens(K))
-        groups = -(-np.asarray(live_blocks, np.int64)
-                   // latent_ops.slots_a_step(spec.max_blocks_per_slot))
+        kt = latent_ops.row_tokens(K)
+        tiles = -(-K // kt)
+        slots, _ = latent_ops.slots_a_step(
+            kt * self.cfg.num_attention_heads, spec.max_blocks_per_slot,
+            latent_ops.latent_tile(spec.block_size, self.cfg.latent_width),
+            self.cfg.kv_lora_rank, int(jnp.dtype(spec.dtype).itemsize))
+        groups = -(-np.asarray(live_blocks, np.int64) // slots)
         return (int(np.maximum(groups, 1).sum()) * tiles,
                 int(groups.sum()) * tiles)
 

@@ -162,10 +162,12 @@ class ServingAggregator:
         self.attend_bytes_kernel = 0
         self.attend_bytes_onehot = 0
         self.attend_tokens = 0
-        # The paged kernel's sequencing steps a layer, and those of them
-        # that touched a live block (the ``decode`` span's counters).
+        # The paged kernel's sequencing steps a layer, those of them that
+        # touched a live block, and the live ones whose first copies
+        # nothing had started (the ``decode`` span's counters).
         self.attend_steps = 0
         self.attend_live_steps = 0
+        self.attend_cold_steps = 0
         # Admission-rejection accounting (the reservation gate's retries
         # used to be invisible): total rejected reservations plus the
         # per-completed-request attempt counts.
@@ -391,11 +393,14 @@ class ServingAggregator:
         self.attend_bytes_onehot += int(bytes_onehot)
         self.attend_tokens += int(tokens)
 
-    def note_attend_steps(self, steps: int, live_steps: int) -> None:
-        """One iteration's attend steps a layer and the live ones among
-        them (InferenceEngine._attend_steps; zeros off the kernel)."""
+    def note_attend_steps(self, steps: int, live_steps: int,
+                          cold_steps: int) -> None:
+        """One iteration's attend steps a layer, the live ones among them
+        and the cold ones among those (InferenceEngine._attend_steps;
+        zeros off the kernel)."""
         self.attend_steps += int(steps)
         self.attend_live_steps += int(live_steps)
+        self.attend_cold_steps += int(cold_steps)
 
     def note_reject(self) -> None:
         """One reservation-gate / slot-pool admission rejection."""
@@ -496,7 +501,10 @@ class ServingAggregator:
         skip-never-fail rule keep working. ``attend_live_step_share``
         (paged kernel only) is the share of the attend's sequencing
         steps that touched a live block, over all iterations so far: the
-        rest are the empty steps of dead streams.
+        rest are the empty steps of dead streams;
+        ``attend_cold_step_share`` the share of those live steps whose
+        first copies the step before had not started (the first of a
+        call, and one after a dead stream).
 
         From the rows (the last ``RING`` iterations): ``occupancy_*``,
         ``decode_step_ms``, ``hbm_bytes_per_token``, ``cache_bytes_p95``
@@ -625,6 +633,9 @@ class ServingAggregator:
         if self.attend_steps:
             snap["attend_live_step_share"] = round(
                 self.attend_live_steps / self.attend_steps, 4)
+        if self.attend_live_steps:
+            snap["attend_cold_step_share"] = round(
+                self.attend_cold_steps / self.attend_live_steps, 4)
         return snap
 
     @classmethod
@@ -649,6 +660,7 @@ class ServingAggregator:
             out.attend_tokens += a.attend_tokens
             out.attend_steps += a.attend_steps
             out.attend_live_steps += a.attend_live_steps
+            out.attend_cold_steps += a.attend_cold_steps
             if out.attend_mode is None:
                 out.attend_mode = a.attend_mode
             # Occupancy normalizes per-replica (active/its own slots):

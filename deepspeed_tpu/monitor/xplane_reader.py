@@ -93,13 +93,15 @@ SPAN_ARGS = {
     # fetch): iteration .. attend_* and state_pages_live describe the one
     # dispatched (absent where the span dispatched none), the moe_*
     # counters the one fetched, each iteration once.
-    # attend_steps / attend_live_steps: the paged kernel's sequencing
-    # steps a layer in this execution and those that touch a live block
-    # (ops.paged_attention.attend_step_counts; zeros on the one-hot path)
+    # attend_steps / attend_live_steps / attend_cold_steps: the paged
+    # kernel's sequencing steps a layer in this execution, those that
+    # touch a live block, and the live ones whose first copies the step
+    # before had not started (ops.paged_attention.attend_step_counts,
+    # attend_cold_steps; zeros on the one-hot path)
     "decode": ("iteration", "active", "live_blocks", "context_tokens",
-               "attend_steps", "attend_live_steps", "moe_held_pairs",
-               "moe_held_max", "moe_held_mean", "moe_held_empty",
-               "moe_held_pair_share", "hc_res_err_max",
+               "attend_steps", "attend_live_steps", "attend_cold_steps",
+               "moe_held_pairs", "moe_held_max", "moe_held_mean",
+               "moe_held_empty", "moe_held_pair_share", "hc_res_err_max",
                # pages of a per-stream pool the state-update kernel
                # rewrote in this execution (one a live stream)
                "state_pages_live",

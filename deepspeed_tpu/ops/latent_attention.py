@@ -18,13 +18,29 @@ same six axes as GPT-2's pools (``inference/kv_cache.py``), one "head".
 design: the stacked pool stays in HBM, a grid step takes ONE stream (and
 one tile of its query rows) through its live blocks, P blocks at a time,
 copying group g + 1's tiles into one half of a VMEM buffer while it
-attends group g in the other.  All heads of a query token are ROWS of one
+attends group g in the other — and, during its last group, the first
+group of the NEXT grid step (the stream's next row tile, else the next
+stream, where that one is live) into the half it has left, so only the
+first step of a call and a step after a dead one start their copies
+cold (the grid is sequential; the buffers, the semaphores and an SMEM
+carry live across its steps).  All heads of a query token are ROWS of one
 product (``[K x nH, C + R] x [C + R, P x bs]`` scores, ``[K x nH, P x bs] x
 [P x bs, C]`` values): one shared "K/V head" for every query head.  The
 two halves of a tile are two column sets of one online softmax, so
 nothing is ever re-tiled.  bf16 products, fp32 scores, softmax and
 accumulation.  Which rows a step holds follows from the shapes (K rows a
-stream: 1 decode, k + 1 verify, the chunk in prefill).
+stream: 1 decode, k + 1 verify, the chunk in prefill), and so does the
+group (``slots_a_step``): a prefill row tile's sixteen slots computed
+whole; for a step of few rows as many as copy
+``paged_attention._GROUP_BYTES`` (32 at the published tile), waited for
+by bytes (one wait stands for several tiles), and computed in ONE
+online-softmax update over the narrowest of 32 / 16 / 8 / 4 slots that
+holds the group's live ones.  What the v5e showed (PERF.md section 6, PR
+42): the cache's columns are the MXU's weights, so a group costs 0.047
+us a slot computed, live or not, whatever the query rows (32 or 64); an
+update costs 0.35 us of latency whatever its width, so a loop of narrow
+updates loses what it saves; starting and waiting for a tile cost the
+scalar core 0.04 us, in series with the products.
 
 ``latent_write`` is ``paged_write``'s twin for this tile: new rows go
 into the donated pool in place (aliased call, scalar-prefetched tile and
@@ -47,9 +63,7 @@ try:
 except Exception:  # pragma: no cover
     pltpu = None
 
-# Table slots a step attends at once (P x bs positions: 1,024 at the
-# published block of 64) and query tokens a row tile holds.
-_SLOTS = 16
+# Query tokens a row tile holds, and the VMEM a step may ask for.
 _ROW_TOKENS = 8
 _VMEM_LIMIT = 48 * 2 ** 20
 
@@ -79,8 +93,37 @@ def logical_rows(tiles: jax.Array, C: int) -> jax.Array:
     return jnp.concatenate([lo, hi], axis=-2)
 
 
-def slots_a_step(table_width: int) -> int:
-    return max(1, min(_SLOTS, table_width))
+def slots_a_step(rows: int, table_width: int, tile, kv_lora: int,
+                 itemsize: int):
+    """(table slots a group copies, the widths a group is computed at) for
+    a step of ``rows`` query rows over a table ``table_width`` wide whose
+    blocks are held as ``tile`` = ``latent_tile(...)``.
+
+    A step whose rows fill the MXU (a prefill chunk's row tile) is bound
+    by its products: a group whose score tile is as wide as its value
+    tile (``kv_lora`` columns a half), computed whole.  A step of few rows
+    (decode, verify) is bound by the latency and the sequencing of its
+    copies, a group each: slots doubled from there while a group copies
+    under ``paged._GROUP_BYTES``, the table has them and both halves of
+    the buffer stay under half of ``_VMEM_LIMIT``.  Such a step computes a
+    group in ONE online-softmax update (a chain of products and
+    reductions whose latency, 0.35 us on the v5e, is paid whatever its
+    width) over the narrowest of the widths that holds the group's live
+    slots: the group halved down to the slots that give the score tile
+    its 128 lanes."""
+    h, W = tile
+    slots = max(1, min(kv_lora // h, table_width))
+    if rows >= paged._DENSE_ROWS:
+        return slots, (slots,)
+    tile_bytes = h * W * itemsize
+    while (slots * tile_bytes < paged._GROUP_BYTES
+           and 2 * slots <= table_width
+           and 8 * slots * tile_bytes <= _VMEM_LIMIT):
+        slots *= 2
+    widths = [slots]
+    while widths[-1] % 2 == 0 and widths[-1] * h > 128:
+        widths.append(widths[-1] // 2)
+    return slots, tuple(widths)
 
 
 def row_tokens(K: int) -> int:
@@ -90,85 +133,134 @@ def row_tokens(K: int) -> int:
 # --------------------------------------------------------------------- #
 # The attend
 # --------------------------------------------------------------------- #
+def _attend_columns(q_ref, lim_ref, buf, m_scr, l_scr, acc_scr, half, pos0,
+                    *, scale, h, C, R, N):
+    """One online-softmax update over the first ``N // h`` slots of
+    ``buf[half]``.  Column c of the lo set is position ``pos0 + (c // h)*2h
+    + c % h`` of the stream, of the hi set that + h."""
+    shift = h.bit_length() - 1
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, N), 1)
+    pos = pos0 + jax.lax.shift_left(
+        jax.lax.shift_right_logical(col, shift), shift + 1) \
+        + jnp.bitwise_and(col, h - 1)
+    lim = lim_ref[0]                                       # [rows, 1]
+    ok_lo, ok_hi = pos <= lim, pos + h <= lim              # [rows, N]
+
+    qa = q_ref[0, :, 0:C]
+    q_lo = q_ref[0, :, C:C + 2 * R]
+    q_hi = q_ref[0, :, C + 2 * R:C + 4 * R]
+    held = buf[half, 0:N, :]                               # one load
+    c_lo, c_hi = held[:, 0:C], held[:, C:2 * C]            # [N, C]
+    kr = held[:, 2 * C:2 * C + 2 * R]                      # [N, 2R]
+    nt = (((1,), (1,)), ((), ()))
+
+    def scores(c, q_r, ok):
+        s = jax.lax.dot_general(qa, c, nt,
+                                preferred_element_type=jnp.float32) \
+            + jax.lax.dot_general(q_r, kr, nt,
+                                  preferred_element_type=jnp.float32)
+        return jnp.where(ok, s * scale, NEG_INF)
+    s_lo, s_hi = scores(c_lo, q_lo, ok_lo), scores(c_hi, q_hi, ok_hi)
+    m_prev = m_scr[:, 0:1]
+    m_new = jnp.maximum(m_prev, jnp.maximum(
+        jnp.max(s_lo, axis=1, keepdims=True),
+        jnp.max(s_hi, axis=1, keepdims=True)))
+    alpha = jnp.exp(m_prev - m_new)
+    p_lo = jnp.where(ok_lo, jnp.exp(s_lo - m_new), 0.0)
+    p_hi = jnp.where(ok_hi, jnp.exp(s_hi - m_new), 0.0)
+    l_scr[:, 0:1] = l_scr[:, 0:1] * alpha \
+        + jnp.sum(p_lo, axis=1, keepdims=True) \
+        + jnp.sum(p_hi, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha \
+        + jnp.dot(p_lo.astype(c_lo.dtype), c_lo,
+                  preferred_element_type=jnp.float32) \
+        + jnp.dot(p_hi.astype(c_hi.dtype), c_hi,
+                  preferred_element_type=jnp.float32)
+    m_scr[:, 0:1] = m_new
+
+
 def _latent_kernel(nl_ref, rows_ref, base_ref, lim_ref, q_ref, pool_hbm,
-                   o_ref, buf, sem, m_scr, l_scr, acc_scr, *, scale, h, C,
-                   R, P):
+                   o_ref, buf, sem, ahead, m_scr, l_scr, acc_scr, *, scale,
+                   h, C, R, widths):
     """One grid step = one (stream, tile of query rows).  ``buf`` is
-    ``[2, P*h, 2C+2R]``: half ``slot`` holds the P tiles of the group in
-    flight, stacked.  Column c of the lo set is position ``g*P*bs +
-    (c // h)*bs + c % h`` of the stream, of the hi set that + h."""
+    ``[2, P*h, 2C+2R]``: a half holds the P = ``widths[0]`` tiles of the
+    group in flight, stacked, and a group is attended over the narrowest
+    of ``widths`` that holds its live slots.  ``ahead`` (SMEM) is
+    ``_pattn_kernel``'s carry: whether this step's first group is already
+    in flight, and in which half."""
     s_idx, t_idx = pl.program_id(0), pl.program_id(1)
+    P, narrow = widths[0], widths[-1]
     nlive = nl_ref[s_idx, t_idx]
     groups = pl.cdiv(nlive, P)
-    N = P * h
-    bs = 2 * h
-    shift = h.bit_length() - 1
 
-    def tiles_of(g, slot, act):
+    @pl.when(jnp.logical_and(s_idx == 0, t_idx == 0))
+    def _first_step():
+        ahead[0] = 0
+        ahead[1] = 0
+
+    def slots(first, n):
+        return pl.ds(pl.multiple_of(first * h, h), n * h)
+
+    def start_tiles(s, t, g, half):
+        """Start the copy of every live slot of group g of step (s, t)
+        into buffer half ``half``."""
         def one(p, carry):
-            row = rows_ref[s_idx, g * P + p] + base_ref[0]
-            act(pltpu.make_async_copy(
-                pool_hbm.at[row],
-                buf.at[slot, pl.ds(pl.multiple_of(p * h, h), h)],
-                sem.at[slot]))
+            row = rows_ref[s, g * P + p] + base_ref[0]
+            pltpu.make_async_copy(pool_hbm.at[row], buf.at[half, slots(p, 1)],
+                                  sem.at[half]).start()
             return carry
-        jax.lax.fori_loop(0, jnp.minimum(P, nlive - g * P), one, 0)
+        jax.lax.fori_loop(0, jnp.minimum(P, nl_ref[s, t] - g * P), one, 0)
+
+    def wait_tiles(half, live):
+        """Wait for ``live`` tiles' bytes in ``half``: a DMA semaphore
+        counts bytes, so one wait sized as ``narrow`` tiles stands for
+        that many tiles' (a descriptor only sizes it)."""
+        def some(n):
+            def one(p, carry):
+                held = buf.at[half, slots(0, n)]
+                pltpu.make_async_copy(held, held, sem.at[half]).wait()
+                return carry
+            return one
+        jax.lax.fori_loop(0, live // narrow, some(narrow), 0)
+        jax.lax.fori_loop(0, jax.lax.rem(live, narrow), some(1), 0)
+
+    # The grid step after this one, and whether it has anything to copy.
+    streams = pl.num_programs(0)
+    wrap = t_idx + 1 == pl.num_programs(1)
+    s_next = jnp.where(wrap, s_idx + 1, s_idx)
+    t_next = jnp.where(wrap, 0, t_idx + 1)
+    next_live = jnp.logical_and(
+        s_next < streams,
+        nl_ref[jnp.minimum(s_next, streams - 1), t_next] > 0)
 
     def group(g, carry):
-        slot = jax.lax.rem(g, 2)
+        half = jax.lax.rem(ahead[1] + g, 2)
 
         @pl.when(g + 1 < groups)
         def _next():
-            tiles_of(g + 1, 1 - slot, lambda dma: dma.start())
+            start_tiles(s_idx, t_idx, g + 1, 1 - half)
 
-        tiles_of(g, slot, lambda dma: dma.wait())
+        @pl.when(jnp.logical_and(g + 1 == groups, next_live))
+        def _next_step():
+            start_tiles(s_next, t_next, 0, 1 - half)
+
+        live = jnp.minimum(P, nlive - g * P)
+        wait_tiles(half, live)
 
         def zero(p, carry):
-            # Slots past the live count: masked columns, but 0 x stale
-            # VMEM is not 0.
-            buf[slot, pl.ds(pl.multiple_of(p * h, h), h), :] = jnp.zeros(
-                (h, buf.shape[2]), buf.dtype)
+            # Slots of the width past the live count: masked columns,
+            # but 0 x stale VMEM is not 0.
+            buf[half, slots(p, 1), :] = jnp.zeros((h, buf.shape[2]),
+                                                  buf.dtype)
             return carry
-        jax.lax.fori_loop(jnp.minimum(P, nlive - g * P), P, zero, 0)
 
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, N), 1)
-        pos = g * (P * bs) + jax.lax.shift_left(
-            jax.lax.shift_right_logical(col, shift), shift + 1) \
-            + jnp.bitwise_and(col, h - 1)
-        lim = lim_ref[0]                                   # [rows, 1]
-        ok_lo, ok_hi = pos <= lim, pos + h <= lim          # [rows, N]
-
-        qa = q_ref[0, :, 0:C]
-        q_lo = q_ref[0, :, C:C + 2 * R]
-        q_hi = q_ref[0, :, C + 2 * R:C + 4 * R]
-        c_lo = buf[slot, :, 0:C]                           # [N, C]
-        c_hi = buf[slot, :, C:2 * C]
-        kr = buf[slot, :, 2 * C:2 * C + 2 * R]             # [N, 2R]
-        nt = (((1,), (1,)), ((), ()))
-
-        def scores(c, q_r, ok):
-            s = jax.lax.dot_general(qa, c, nt,
-                                    preferred_element_type=jnp.float32) \
-                + jax.lax.dot_general(q_r, kr, nt,
-                                      preferred_element_type=jnp.float32)
-            return jnp.where(ok, s * scale, NEG_INF)
-        s_lo, s_hi = scores(c_lo, q_lo, ok_lo), scores(c_hi, q_hi, ok_hi)
-        m_prev = m_scr[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.maximum(
-            jnp.max(s_lo, axis=1, keepdims=True),
-            jnp.max(s_hi, axis=1, keepdims=True)))
-        alpha = jnp.exp(m_prev - m_new)
-        p_lo = jnp.where(ok_lo, jnp.exp(s_lo - m_new), 0.0)
-        p_hi = jnp.where(ok_hi, jnp.exp(s_hi - m_new), 0.0)
-        l_scr[:, 0:1] = l_scr[:, 0:1] * alpha \
-            + jnp.sum(p_lo, axis=1, keepdims=True) \
-            + jnp.sum(p_hi, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha \
-            + jnp.dot(p_lo.astype(c_lo.dtype), c_lo,
-                      preferred_element_type=jnp.float32) \
-            + jnp.dot(p_hi.astype(c_hi.dtype), c_hi,
-                      preferred_element_type=jnp.float32)
-        m_scr[:, 0:1] = m_new
+        for n, below in zip(widths, widths[1:] + (0,)):
+            @pl.when(jnp.logical_and(live > below, live <= n))
+            def _attend(n=n):
+                jax.lax.fori_loop(live, n, zero, 0)
+                _attend_columns(
+                    q_ref, lim_ref, buf, m_scr, l_scr, acc_scr, half,
+                    g * (P * 2 * h), scale=scale, h=h, C=C, R=R, N=n * h)
         return carry
 
     @pl.when(groups == 0)
@@ -180,8 +272,15 @@ def _latent_kernel(nl_ref, rows_ref, base_ref, lim_ref, q_ref, pool_hbm,
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
-        tiles_of(0, 0, lambda dma: dma.start())
+
+        @pl.when(ahead[0] == 0)
+        def _cold():
+            start_tiles(s_idx, t_idx, 0, ahead[1])
+
         jax.lax.fori_loop(0, groups, group, 0)
+        # What the last group started for the next step, and where.
+        ahead[1] = jax.lax.rem(ahead[1] + groups, 2)
+        ahead[0] = next_live.astype(jnp.int32)
         l_fin = l_scr[:, 0:1]
         o_ref[0] = (acc_scr[...] / jnp.where(l_fin == 0.0, 1.0, l_fin)
                     ).astype(o_ref.dtype)
@@ -199,7 +298,7 @@ def _latent_local(q_abs, q_rope, pool, layer, nlive, rows, lim, *, scale):
     kt = row_tokens(K)
     Kp = -(-K // kt) * kt
     nT, rt = Kp // kt, kt * nH
-    P_ = slots_a_step(J)
+    P_, widths = slots_a_step(rt, J, (h, W), C, pool.dtype.itemsize)
     zeros = jnp.zeros_like(q_rope)
     q = jnp.concatenate([q_abs, q_rope, zeros, zeros, q_rope], axis=-1)
     q = jnp.pad(q.reshape(GQ, K, nH, C + 4 * R),
@@ -221,7 +320,7 @@ def _latent_local(q_abs, q_rope, pool, layer, nlive, rows, lim, *, scale):
 
     out = pl.pallas_call(
         functools.partial(_latent_kernel, scale=scale, h=h, C=C, R=R,
-                          P=P_),
+                          widths=widths),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(GQ, nT),
@@ -232,6 +331,7 @@ def _latent_local(q_abs, q_rope, pool, layer, nlive, rows, lim, *, scale):
             scratch_shapes=[
                 pltpu.VMEM((2, P_ * h, W), pool.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((2,), jnp.int32),
                 pltpu.VMEM((rt, 128), jnp.float32),
                 pltpu.VMEM((rt, 128), jnp.float32),
                 pltpu.VMEM((rt, C), jnp.float32),

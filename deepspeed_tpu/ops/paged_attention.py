@@ -162,6 +162,17 @@ def attend_step_counts(live_blocks, *, K: int, num_heads: int,
     return int(np.maximum(groups, 1).sum()) * per, int(groups.sum()) * per
 
 
+def attend_cold_steps(live_blocks, *, calls: int = 1) -> int:
+    """Live steps of ONE layer's attend whose first group nothing started
+    (host integers): both attend kernels start a step's first copies
+    during the step before it, so a live stream starts cold only as the
+    first of its call (``calls``: the shards of a dp mesh, each a call
+    over its own run of streams) or after a dead stream.  Further head
+    blocks or row tiles of a live stream follow a live step."""
+    live = np.asarray(live_blocks).reshape(calls, -1) > 0
+    return int(live[:, 0].sum() + (live[:, 1:] & ~live[:, :-1]).sum())
+
+
 # --------------------------------------------------------------------- #
 # Kernel
 # --------------------------------------------------------------------- #
@@ -804,5 +815,5 @@ def paged_write(pool_k, pool_v, k_new, v_new, layer, blk, off, *,
 
 
 __all__ = ["paged_attention", "attend_plan", "AttendPlan", "paged_write",
-           "paged_kernel_enabled", "attend_step_counts",
+           "paged_kernel_enabled", "attend_step_counts", "attend_cold_steps",
            "attend_flops_per_token", "attend_hbm_bytes_per_token"]

@@ -161,11 +161,20 @@ def test_a_batch_through_the_scheduler_matches_one_by_one():
 # 2. The attend and the write
 # --------------------------------------------------------------------- #
 def _latent_case(seed, lengths, *, K=1, nH=4, C=32, R=8, B=12, bs=16, J=4,
-                 dtype=jnp.float32, past_table=False):
+                 dtype=jnp.float32, past_table=False, live_rows=None):
     """q_abs / q_rope [G, Q, K, nH, .], one layer's logical rows [G, B, bs,
     C + R], tables and positions from per-stream context lengths (<= 0:
-    a dead stream)."""
+    a dead stream; a callable: lengths in BLOCKS from the slot rule's
+    group and its narrowest width for this tile and dtype).  ``live_rows``:
+    the rows of a chunk from there on are padding (position -1)."""
     rng = np.random.default_rng(seed)
+    if callable(lengths):
+        P, widths = la.slots_a_step(
+            la.row_tokens(K) * nH, J, la.latent_tile(bs, C + R), C,
+            jnp.dtype(dtype).itemsize)
+        assert J > 2 * P and widths[-1] < P, (P, widths, J)
+        lengths = [[n * bs - 3 for n in group]
+                   for group in lengths(P, widths[-1])]
     G, Q = len(lengths), len(lengths[0])
     rows = rng.standard_normal((G, B, bs, C + R)).astype(np.float32)
     qa = rng.standard_normal((G, Q, K, nH, C)).astype(np.float32)
@@ -182,6 +191,8 @@ def _latent_case(seed, lengths, *, K=1, nH=4, C=32, R=8, B=12, bs=16, J=4,
             nblk = min(last // bs + 1, J)
             bt[g, s, :nblk] = [free.pop() for _ in range(nblk)]
             pos[g, s] = ctx - 1 + np.arange(K)
+            if live_rows is not None:
+                pos[g, s, live_rows:] = -1
     if past_table:
         assert pos.max() >= J * bs
     return (jnp.asarray(qa), jnp.asarray(qr), jnp.asarray(rows, dtype),
@@ -207,6 +218,7 @@ def _kernel(qa, qr, rows, bt, pos, scale, layer=1):
     return la.latent_attention(qa, qr, pool, layer, plan=plan, scale=scale)
 
 
+_WIDE = dict(C=512, R=64, bs=64, J=72, B=70, nH=2)
 LATENT_CASES = {
     "ragged_and_partial_blocks": ([[16, 17, 13, 1], [33, 0, 8, 5]], {}),
     "dead_streams": ([[0, 0, 9], [0, 20, 0]], {}),
@@ -215,28 +227,81 @@ LATENT_CASES = {
     "chunk_not_a_multiple_of_the_row_tile": ([[9], [30]], {"K": 11}),
     "long_table_several_groups": ([[300, 120]], {"J": 20, "B": 40}),
     "rows_past_the_table": ([[60, 10]], {"K": 8, "past_table": True}),
+    # cold and warm starts side by side: the first stream of a call and
+    # the one after a dead stream start their own copies, the others'
+    # are in flight when their step begins
+    "live_dead_live_live": ([[9, 0, 20, 5], [0, 7, 3, 0]], {}),
+    "verify_rows_dead_stream_between": ([[7, 0, 21], [3, 12, 0]], {"K": 4}),
+    # a chunk of three row tiles whose last two are padding: the next
+    # stream's first tile has nothing started for it
+    "chunk_ends_in_dead_row_tiles": ([[17, 30]], {"K": 24, "live_rows": 8}),
+    # at the published tile (blocks of 64 x 576) a decode group is what
+    # copies 2 MiB, narrower than the table, and is computed at the
+    # narrowest width that holds its live slots
+    "exactly_one_group": (lambda P, n: [[P, 1]], _WIDE),
+    "one_group_and_a_block": (lambda P, n: [[P + 1, 2]], _WIDE),
+    "a_narrow_width_and_a_block": (lambda P, n: [[n + 1, 2 * n + 1]], _WIDE),
+    "table_wider_than_two_groups": (lambda P, n: [[2 * P + 3]], _WIDE),
 }
 
 
 @pytest.mark.parametrize("name", sorted(LATENT_CASES))
-def test_latent_kernel_equals_the_onehot_attend(name):
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 0.06)],
+                         ids=["fp32", "bf16-pool"])
+def test_latent_kernel_equals_the_onehot_attend(name, dtype, tol):
     lengths, kw = LATENT_CASES[name]
-    qa, qr, rows, bt, pos = _latent_case(3, lengths, **kw)
-    out = _kernel(qa, qr, rows, bt, pos, 0.11)
-    ref = _onehot(qa, qr, rows, bt, pos, 0.11)
-    live = np.asarray(bt[..., 0] >= 0)
-    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(ref)[live],
-                               atol=2e-5, rtol=2e-5)
-    assert not np.asarray(out)[~live].any()       # dead streams: zeros
-
-
-def test_latent_kernel_bf16_pool():
-    qa, qr, rows, bt, pos = _latent_case(
-        4, [[16, 17, 13, 1], [33, 0, 8, 5]], dtype=jnp.bfloat16)
-    qa, qr = qa.astype(jnp.bfloat16), qr.astype(jnp.bfloat16)
+    qa, qr, rows, bt, pos = _latent_case(3, lengths, dtype=dtype, **kw)
+    qa, qr = qa.astype(dtype), qr.astype(dtype)
     out = _kernel(qa, qr, rows, bt, pos, 0.11).astype(jnp.float32)
     ref = _onehot(qa, qr, rows, bt, pos, 0.11).astype(jnp.float32)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=0.06)
+    live = np.asarray((bt[..., 0] >= 0)[..., None] & (pos >= 0))
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(ref)[live],
+                               atol=tol, rtol=2e-5)
+    # dead streams and a chunk's padding rows: zeros
+    assert not np.asarray(out)[~live].any()
+
+
+@pytest.mark.parametrize("heads,table", [(32, 96), (64, 272)],
+                         ids=["32-heads-table-96", "64-heads-table-272"])
+def test_the_slot_rule_at_the_published_widths(heads, table):
+    """By hand, blocks of 64 positions x 576 bf16 values (73,728 B a
+    tile): decode's rows copy 32 slots a group (the first size that
+    reaches 2 MiB) and compute the narrowest of 32 / 16 / 8 / 4 slots
+    that holds a group's live ones (4 x 32 columns a half: 128 lanes);
+    a prefill chunk's row tile of 8 tokens, and 4 verify tokens of 32
+    heads or more, fill the MXU: 512 / 32 = 16 slots, computed whole."""
+    rule = lambda K: la.slots_a_step(                       # noqa: E731
+        la.row_tokens(K) * heads, table, la.latent_tile(64, 576), 512, 2)
+    assert rule(1) == (32, (32, 16, 8, 4))
+    assert rule(4) == (16, (16,))
+    assert rule(512) == (16, (16,))
+    # a float32 pool's tile is twice the bytes: half the slots a group
+    assert la.slots_a_step(heads, table, la.latent_tile(64, 576), 512,
+                           4) == (16, (16, 8, 4))
+    # never wider than the table
+    assert la.slots_a_step(heads, 3, la.latent_tile(64, 576), 512, 2) \
+        == (3, (3,))
+
+
+def test_attend_step_counts_equal_a_count_by_hand():
+    """A layer's steps at cell 4's shape (64 heads, a table of 272, bf16):
+    groups of 32 slots, so 200 / 33 / 32 / 1 live blocks are 7 / 2 / 1 /
+    1 live steps and the dead stream one empty step; the first stream and
+    the one after the dead one start cold."""
+    from types import SimpleNamespace
+    from deepspeed_tpu.inference.served import served_model
+    from deepspeed_tpu.ops.paged_attention import attend_cold_steps
+    served = served_model(DeepseekV3Config(held=(0, 16)))
+    spec = SimpleNamespace(max_blocks_per_slot=272, block_size=64,
+                           dtype=jnp.bfloat16)
+    live = [200, 33, 32, 0, 1]
+    count = lambda K: served.attend_step_counts(            # noqa: E731
+        live, K=K, spec=spec, mp=1, q_itemsize=2)
+    assert count(1) == (12, 11)
+    # three verify tokens of 64 heads fill the MXU: groups of 16 slots
+    assert count(3) == (13 + 3 + 2 + 1 + 1, 13 + 3 + 2 + 1)
+    assert attend_cold_steps(live) == 2
 
 
 def test_absorbed_attend_equals_expanded_attend():

@@ -236,7 +236,7 @@ SERVE_SPANS = {
              "host_ms"},
     "serve_idle": {"why"}}
 DISPATCHED = {"live_blocks", "context_tokens", "attend_steps",
-              "attend_live_steps"}
+              "attend_live_steps", "attend_cold_steps"}
 TRAIN_SPANS = {"train_batch": {"step_num"}, "data_prep": {"step"},
                "step_dispatch": {"step"}, "step_log": {"step"}}
 
@@ -397,9 +397,14 @@ def test_decode_span_counts_the_attend_steps(serve_annotations,
     assert all(a["attend_steps"] == serve_engine.max_slots for a in args)
     assert all(a["attend_live_steps"] == a["active"] for a in args)
     assert {a["active"] for a in args} == {1, 2}
+    # the active slots are neighbours from slot 0 on: the first of the
+    # call starts cold, the one after it behind it
+    assert all(a["attend_cold_steps"] == 1 for a in args)
     assert report["attend_live_step_share"] == pytest.approx(
         sum(a["attend_live_steps"] for a in args) /
         sum(a["attend_steps"] for a in args), abs=1e-4)
+    assert report["attend_cold_step_share"] == pytest.approx(
+        len(args) / sum(a["attend_live_steps"] for a in args), abs=1e-4)
 
 
 def test_the_readers_list_names_the_same_args():
@@ -421,6 +426,18 @@ def test_attend_step_counts_from_live_blocks():
     # a prefill chunk runs fewer heads a step: more head blocks
     steps, live = attend_step_counts([16], K=128, **cell)
     assert steps == live and steps > 1 and 20 % steps == 0
+
+
+def test_attend_cold_steps_by_hand():
+    """A live stream starts cold as the first of its call or after a
+    dead one; every shard of a dp mesh is a call of its own."""
+    from deepspeed_tpu.ops.paged_attention import attend_cold_steps
+    live = [25, 16, 3, 0, 33, 1, 0, 7]
+    assert attend_cold_steps(live) == 3                  # slots 0, 4, 7
+    assert attend_cold_steps(live, calls=2) == 3         # 0 | 4, 7
+    assert attend_cold_steps(live, calls=4) == 4         # 0 | 2 | 4 | 7
+    assert attend_cold_steps([0, 0, 0]) == 0
+    assert attend_cold_steps([3] * 256) == 1
 
 
 @pytest.mark.parametrize("K,slots,by_hand", [

@@ -623,19 +623,28 @@ def test_serve_step_kernels_lower_to_tpu_custom_calls(serve_programs,
 # 256 experts held).
 # ------------------------------------------------------------------ #
 LATENT = dict(L=5, B=7168, h=32, W=1152, J=272, nH=64, C=512, R=64)
+# ... and the other published shape that runs them (PR 41,
+# perfbench/configs/xing4.0-29b-a4b.json: 256 slots of 32 heads, a table
+# of 96, 12,288 blocks over six layers).
+LATENT_32_HEADS = dict(LATENT, L=6, B=12288, J=96, nH=32)
 
 
-def _latent_pool():
-    c = LATENT
+def _latent_pool(c=LATENT):
     return _sds((c["L"], 1, c["B"], 1, c["h"], c["W"]), jnp.bfloat16)
 
 
-@pytest.mark.parametrize("Q,K", [(128, 1), (1, 512), (128, 3)],
-                         ids=["decode", "prefill-chunk", "verify"])
-def test_latent_attention_compiles_at_the_published_widths(Q, K, one_chip,
-                                                           as_tpu):
+@pytest.mark.parametrize("c,Q,K", [
+    (LATENT, 128, 1), (LATENT, 1, 512), (LATENT, 128, 3),
+    (LATENT_32_HEADS, 256, 1), (LATENT_32_HEADS, 1, 512)],
+    ids=["decode", "prefill-chunk", "verify", "decode-32-heads-table-96",
+         "prefill-chunk-32-heads"])
+def test_latent_attention_compiles_at_the_published_widths(c, Q, K,
+                                                           one_chip, as_tpu):
+    """Both published decode shapes (groups of 32 slots computed at 32 /
+    16 / 8 / 4: 4.7 MB of buffer), the prefill row tile and verify, inside
+    the VMEM the call asks for (the compiler refuses what does not
+    fit)."""
     from deepspeed_tpu.ops import latent_attention as la
-    c = LATENT
 
     def fn(qa, qr, pool, layer, bt, pos):
         plan = la.latent_plan(bt, pos, pool)
@@ -643,7 +652,7 @@ def test_latent_attention_compiles_at_the_published_widths(Q, K, one_chip,
                                    scale=0.14)
     _compile(fn, one_chip,
              _sds((1, Q, K, c["nH"], c["C"]), jnp.bfloat16),
-             _sds((1, Q, K, c["nH"], c["R"]), jnp.bfloat16), _latent_pool(),
+             _sds((1, Q, K, c["nH"], c["R"]), jnp.bfloat16), _latent_pool(c),
              _sds((), jnp.int32), _sds((1, Q, c["J"]), jnp.int32),
              _sds((1, Q, K), jnp.int32))
 
