@@ -13,11 +13,16 @@ Architecture (mirrors the training engine's discipline):
   sampling, spans — is the same for every model.
 - TWO compiled programs serve everything: ``decode_step`` (one token for
   every slot at once) and ``prefill_step`` (one chunk of one slot's
-  prompt — or the whole padded prompt when ``prefill_chunk: 0``). Both
-  have fixed abstract signatures for the lifetime of the engine and both
+  prompt — or the whole padded prompt when ``prefill_chunk: 0``).
+  ``decode_step`` has one abstract signature for the lifetime of the
+  engine; a chunked ``prefill_step`` has one a ROW WIDTH of
+  ``prefill_widths`` (``prefill_chunk`` and its half, no narrower than
+  128 rows: 512 -> 256, 512), every one compiled before the first
+  admission, and a dispatch takes the narrowest that holds its rows (a
+  tail's last chunk is short; its dead rows would run every GEMM). Both
   are wrapped by the recompile sentinel; ``fail_on_recompile`` turns any
-  post-warmup retrace into a hard error. Request admission, progress,
-  and eviction never touch a compiled shape.
+  post-warmup retrace — any other shape — into a hard error. Request
+  admission, progress, and eviction never touch a compiled shape.
 - The KV cache (inference/kv_cache.py) is born sharded: slots over the
   mesh data axis, heads over the model axis. Its buffers are DONATED
   through every step, so the cache exists once — and a step writes
@@ -47,7 +52,11 @@ Architecture (mirrors the training engine's discipline):
 from __future__ import annotations
 
 import dataclasses
+import functools
+import hashlib
 import os
+import pickle
+import tempfile
 import weakref
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
@@ -72,12 +81,142 @@ from ..ops import paged_attention as paged_attn_ops
 from ..parallel.topology import build_mesh, DP_AXIS, MP_AXIS, SP_AXIS
 from ..runtime.config import InferenceConfig, TelemetryConfig
 from ..runtime.config_utils import load_config_json
-from ..utils.logging import log_dist
+from ..utils.logging import log_dist, logger
 
 try:
     from flax import serialization as flax_serialization
 except Exception:  # pragma: no cover
     flax_serialization = None
+
+
+# The least row width of a chunked-prefill program. v5e's ridge is 197
+# TFLOP/s over 819 GB/s = 240 FLOP a byte = 240 rows of a bf16 GEMM: at
+# 128 rows a GEMM is already bound by its weights' bytes, so a narrower
+# program would cost the same and only add a compile.
+MIN_PREFILL_WIDTH = 128
+# How many widths the program is built at. Every width is a whole
+# program: compiled at the first start (10-16 s on the chip), kept
+# (14-23 MB beside the compile cache, ``_WidthPrograms``) and loaded at
+# every later one — or traced and lowered again, 0.75-7 s by served
+# family, where nothing is kept (PERF.md section 6, PR 43). The first
+# halving is the one that pays: it holds nine short tails in ten and
+# takes most of what the dead rows cost.
+MAX_PREFILL_WIDTHS = 2
+
+
+def prefill_widths(prefill_chunk: int, block_size: int) -> Tuple[int, ...]:
+    """The row widths ``prefill_step`` is compiled at, ascending:
+    ``prefill_chunk``, then its halvings — ``MAX_PREFILL_WIDTHS`` widths
+    at most — while the result is at least ``MIN_PREFILL_WIDTH`` and a
+    multiple of ``block_size`` (512 -> (256, 512); 128 or 96 ->
+    themselves). () for whole-prompt prefill."""
+    if prefill_chunk <= 0:
+        return ()
+    widths = [prefill_chunk]
+    while len(widths) < MAX_PREFILL_WIDTHS:
+        half, odd = divmod(widths[-1], 2)
+        if odd or half < MIN_PREFILL_WIDTH or half % block_size:
+            break
+        widths.append(half)
+    return tuple(reversed(widths))
+
+
+@functools.lru_cache(maxsize=None)
+def _build_digest() -> str:
+    """What a compiled program depends on besides its own module: this
+    package's sources and the compiler's versions."""
+    import jaxlib
+    h = hashlib.sha256(repr((
+        jax.__version__, jaxlib.__version__,
+        jax.devices()[0].client.platform_version)).encode())
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for d, dirs, files in os.walk(root):
+        dirs.sort()
+        for f in sorted(files):
+            if f.endswith(".py"):
+                path = os.path.join(d, f)
+                h.update(os.path.relpath(path, root).encode())
+                with open(path, "rb") as fh:
+                    h.update(fh.read())
+    return h.hexdigest()
+
+
+def _programs_dir() -> Optional[str]:
+    """Where ``_WidthPrograms`` keeps executables: beside the compile
+    cache, if the program turned that on."""
+    cache = jax.config.jax_compilation_cache_dir
+    return os.path.join(cache, "prefill_widths") if cache else None
+
+
+class _WidthPrograms:
+    """``prefill_step`` at several row widths, called like the jitted
+    function it holds: one compiled executable a width, kept here and
+    not in ``jax.jit``'s own cache, because a further width is a WHOLE
+    program traced and lowered again at every start — seconds of Python
+    that the compile cache does not save (4.7 s of cell 6's 63 s of
+    set-up on the chip: PERF.md section 6, PR 43). So where the
+    compile cache is on, a width after the first leaves its executable
+    serialized beside it (``<cache>/prefill_widths/``) and the next
+    start loads that: no trace, no lowering. The file's name holds what
+    the program depends on: the FIRST width's lowered module — built as
+    ``jax.jit`` would at every start, and changed by whatever changes
+    the function, its constants, the shapes and shardings of its
+    arguments or the flags it is traced under — with this package's
+    sources and the compiler's versions (``_build_digest``). A file that
+    does not load is built again. ``_cache_size`` (the widths built so
+    far) is what the recompile sentinel watches, ``lower`` what the
+    lint audit re-lowers with."""
+
+    def __init__(self, jitted: Callable, width_arg: int, devices):
+        self.jitted, self.width_arg = jitted, width_arg
+        self.devices = list(devices)
+        self.lower = jitted.lower
+        self.__name__ = getattr(jitted, "__name__", "prefill_step")
+        self.execs: Dict[int, Any] = {}
+        self.first: Optional[str] = None     # digest of the first module
+
+    def _cache_size(self) -> int:
+        return len(self.execs)
+
+    def __call__(self, *args):
+        tokens = args[self.width_arg]
+        if isinstance(tokens, jax.core.Tracer):
+            return self.jitted(*args)    # (an audit tracing through it)
+        exe = self.execs.get(tokens.shape[1])
+        if exe is None:
+            exe = self.execs[tokens.shape[1]] = self._build(args)
+        return exe(*args)
+
+    def _build(self, args):
+        if self.first is None:
+            lowered = self.jitted.lower(*args)
+            self.first = hashlib.sha256(
+                (_build_digest() + lowered.as_text()).encode()).hexdigest()
+            return lowered.compile()
+        where = _programs_dir()
+        if where is None:
+            return self.jitted.lower(*args).compile()
+        from jax.experimental import serialize_executable
+        path = os.path.join(
+            where, f"{self.first}-{args[self.width_arg].shape[1]}")
+        try:
+            with open(path, "rb") as fh:
+                return serialize_executable.deserialize_and_load(
+                    *pickle.load(fh), execution_devices=self.devices)
+        except FileNotFoundError:
+            pass
+        except Exception as e:               # unreadable: build it again
+            logger.warning(f"prefill_step: {path} did not load ({e!r})")
+        exe = self.jitted.lower(*args).compile()
+        try:
+            os.makedirs(where, exist_ok=True)
+            fd, tmp = tempfile.mkstemp(dir=where)
+            with os.fdopen(fd, "wb") as fh:
+                pickle.dump(serialize_executable.serialize(exe), fh)
+            os.replace(tmp, path)
+        except Exception as e:               # a cache, not a dependency
+            logger.warning(f"prefill_step: {path} not written ({e!r})")
+        return exe
 
 
 @dataclasses.dataclass
@@ -140,6 +279,9 @@ class InferenceEngine:
             raise ValueError(
                 f"inference.block_size={self.block_size} must divide "
                 f"inference.max_seq_len ({self.max_len})")
+        self.prefill_widths = prefill_widths(self.prefill_chunk,
+                                             self.block_size)
+        self._prefill_warmed = len(self.prefill_widths) < 2
         self.spec_k = int(self.icfg.spec_k)
         self.replica = str(self.icfg.replica)
         # Pallas paged-attention kernel vs the one-hot pool contraction.
@@ -328,11 +470,18 @@ class InferenceEngine:
         # --- the compiled paths (sentinel-instrumented): decode, prefill,
         # the copy-on-write block copy and, with spec_k > 0, the
         # speculative verify step. Each has ONE abstract signature for
-        # the engine's lifetime ---
+        # the engine's lifetime; chunked prefill one a width of
+        # ``prefill_widths`` ---
         self._decode_fn = self.telemetry.instrument_step_fn(
             "decode_step", self._build_decode_step())
+        prefill_step = self._build_prefill_step()
+        if len(self.prefill_widths) > 1:     # tokens follow params, pools
+            prefill_step = _WidthPrograms(
+                prefill_step, 1 + len(self._cache_sh),
+                self.mesh.devices.flat)
         self._prefill_fn = self.telemetry.instrument_step_fn(
-            "prefill_step", self._build_prefill_step())
+            "prefill_step", prefill_step,
+            signatures=len(self.prefill_widths))
         self._copy_fn = self.telemetry.instrument_step_fn(
             *(("state_copy", self._build_state_copy())
               if served.cache_per_stream
@@ -421,7 +570,9 @@ class InferenceEngine:
         if self.prefill_chunk > 0:
             # Group-batched chunked prefill: one chunk of one slot per
             # dp group (single admissions leave the other groups' rows
-            # DEAD — uniform program, writes land nowhere).
+            # DEAD — uniform program, writes land nowhere). ``tokens``
+            # is ``[G, width]``, one of ``prefill_widths``: the same
+            # function, compiled once a width (``_warm_prefill_widths``).
             def prefill_step(params, *args):
                 pools, (tokens, bt_rows, start, last_idx, active, key,
                         temperature) = args[:n], args[n:]
@@ -757,13 +908,16 @@ class InferenceEngine:
         Host spans: ``prefill`` (args ``slots``, ``prompt_tokens``,
         ``rids`` — the scheduler's request ids, so a request can be
         followed through a trace — and, once planned, ``cached_tokens``,
-        ``chunks``, ``rows_computed``: the ``[G, chunk]`` rows of every
-        chunk program) > ``prefill_plan`` (allocator admission + the
-        copy-on-write fork), one ``prefill_chunk`` (``ci``,
-        ``active_groups``) per chunk program dispatched, and
-        ``prefill_fetch`` (the first tokens' ``device_get``)."""
+        ``chunks``, ``rows_computed``: the ``[G, width]`` rows of every
+        chunk program, each as wide as it was dispatched) >
+        ``prefill_plan`` (allocator admission + the copy-on-write fork),
+        one ``prefill_chunk`` (``ci``, ``active_groups``, ``rows``: the
+        width) per chunk program dispatched, and ``prefill_fetch`` (the
+        first tokens' ``device_get``)."""
         if self.prefill_chunk == 0:
             raise RuntimeError("prefill_many needs chunked prefill")
+        if not self._prefill_warmed:
+            self._warm_prefill_widths()
         t_pf0 = self.serving.lap("admit_s")
         tl = self.telemetry
         with tl.span("prefill", slots=len(admissions),
@@ -772,7 +926,7 @@ class InferenceEngine:
             with tl.span("prefill_plan"):
                 pools, plans, tails = self._plan_prefill(admissions)
             try:
-                steps, held = self._run_prefill_chunks(
+                steps, held, widths = self._run_prefill_chunks(
                     pools, plans, tails, np.float32(temperature))
             except BaseException:
                 for plan in plans:
@@ -798,7 +952,7 @@ class InferenceEngine:
                     self.serving.note_admit(plen, plan.matched)
                     out.append((tok, logits))
             cached = sum(int(p[2].matched) for p in plans)
-            computed = len(steps) * self.dp * self.prefill_chunk
+            computed = self.dp * sum(widths)
             span.set_metadata(cached_tokens=cached, chunks=len(steps),
                               rows_computed=computed)
             if self.cache_spec.per_stream:
@@ -810,8 +964,8 @@ class InferenceEngine:
                         by_class.get("cached_tokens_" + name, 0) + n
             span.set_metadata(**by_class)
         wall = self.serving.note_prefill_pass(
-            len(steps), sum(p[4] for p in plans) - cached, computed) \
-            - t_pf0 - waited
+            len(steps), sum(p[4] for p in plans) - cached, computed,
+            widths) - t_pf0 - waited
         self._prefill_wall += wall
         if self.serving.ledger is not None:
             self.serving.ledger.note("prefill", wall)
@@ -913,17 +1067,23 @@ class InferenceEngine:
 
     def _run_prefill_chunks(self, pools, plans, tails, temp):
         """Dispatch one group-batched chunk program per chunk index (a
-        ``prefill_chunk`` span each) and store the cache. Returns
-        ([(tok_g, logits_g) device arrays per chunk index], {slot: (ci,
-        group) of its last chunk})."""
+        ``prefill_chunk`` span each), as wide as the narrowest of
+        ``prefill_widths`` that holds the longest active group's rows
+        (only a tail's last chunk, or the one before a snapshot cut, is
+        short), and store the cache. Returns ([(tok_g, logits_g) device
+        arrays per chunk index], {slot: (ci, group) of its last chunk},
+        [width per chunk index])."""
         G = self.dp
         J = self.allocator.table_width
-        chunk = self.prefill_chunk
         held = {}
         steps = []
+        widths = []
         try:
             for ci in range(max(len(chunks) for chunks, _ in tails)):
-                toks = np.zeros((G, chunk), np.int32)
+                rows = max(chunks[ci][1] for chunks, _ in tails
+                           if ci < len(chunks))
+                width = next(w for w in self.prefill_widths if w >= rows)
+                toks = np.zeros((G, width), np.int32)
                 bt_rows = np.full((G, J), kv_cache.DEAD_BLOCK, np.int32)
                 starts = np.zeros(G, np.int32)
                 last_idxs = np.zeros(G, np.int32)
@@ -950,7 +1110,8 @@ class InferenceEngine:
                         snaps[group] = (plan.table[0], plan.snapshot_page)
                         frozen.append(plan)
                 with self.telemetry.span("prefill_chunk", ci=ci,
-                                         active_groups=int(act.sum())):
+                                         active_groups=int(act.sum()),
+                                         rows=width):
                     *pools, tok_g, logits_g = self._prefill_fn(
                         self._params, *pools, toks, bt_rows, starts,
                         last_idxs, act, self._next_key(), temp)
@@ -959,10 +1120,34 @@ class InferenceEngine:
                     for plan in frozen:
                         self.allocator.commit_snapshot(plan)
                 steps.append((tok_g, logits_g))
+                widths.append(width)
         finally:
             # also where a chunk raised: the pools before it were donated
             self._store_pools(pools)
-        return steps, held
+        return steps, held, widths
+
+    def _warm_prefill_widths(self) -> None:
+        """Build ``prefill_step`` at every width of ``prefill_widths``
+        before the first admission, so that traffic never compiles:
+        each is dispatched once with every group inactive (dead rows and
+        tables: nothing is written, attended or routed), widest first
+        (``_WidthPrograms`` names the others' files by the first).
+        Takes no key from the sampling sequence."""
+        G = self.dp
+        dead = np.full((G, self.allocator.table_width),
+                       kv_cache.DEAD_BLOCK, np.int32)
+        zeros = np.zeros(G, np.int32)
+        pools = self._pools()
+        try:
+            for width in reversed(self.prefill_widths):
+                *pools, _, _ = self._prefill_fn(
+                    self._params, *pools, np.zeros((G, width), np.int32),
+                    dead, zeros, zeros, zeros, self._base_rng,
+                    np.float32(0.0))
+        finally:
+            self._store_pools(pools)
+        self._prefill_warmed = True
+        self.telemetry.raise_pending()
 
     def _note_state_admissions(self, plans) -> Dict[str, int]:
         """A per-stream pool's ``prefill`` span args, also summed into
@@ -1599,6 +1784,9 @@ class InferenceEngine:
             q_streams = {"decode_step": (sp_.slots_per_group, 1),
                          "verify_step": (sp_.slots_per_group,
                                          self.spec_k + 1),
+                         # (the widest of ``prefill_widths``; the
+                         # registry holds the last that compiled, the
+                         # narrowest, which this covers)
                          "prefill_step": (1, self.prefill_chunk)}
             q_, k_ = q_streams.get(name, (0, 0))
             if q_ and k_:

@@ -12,9 +12,11 @@ loop says so. The sentinel wraps each jitted step function:
   (growth across the call == a compile happened), falling back to
   signature-set membership when that private API is absent;
 - the first ``warmup_calls`` compiles per function are expected (cold
-  start); any later miss emits a structured event naming the function and
-  the signature delta vs the previous call, and raises ``RecompileError``
-  when ``telemetry.fail_on_recompile`` is set.
+  start; one more for each further signature its owner declares it is
+  compiled at, ``instrument(..., signatures=n)``); any later miss emits
+  a structured event naming the function and the signature delta vs the
+  previous call, and raises ``RecompileError`` when
+  ``telemetry.fail_on_recompile`` is set.
 """
 from __future__ import annotations
 
@@ -122,10 +124,18 @@ class RecompileSentinel:
                 out[name] = (fn, ab[0], ab[1])
         return out
 
-    def instrument(self, name: str, fn: Callable) -> Callable:
+    def instrument(self, name: str, fn: Callable,
+                   signatures: int = 1) -> Callable:
         """Wrap ``fn`` (typically a jitted callable). The wrapper preserves
         call/donation semantics; the raw function stays reachable via
-        ``__wrapped__`` for introspection (flops profiler, hlo audit)."""
+        ``__wrapped__`` for introspection (flops profiler, hlo audit).
+
+        ``signatures``: how many abstract signatures the owner compiles
+        ``fn`` at before it serves (a ladder of row widths): its first
+        calls make them, so the warmup is that many calls longer, less
+        the one it already holds. Any miss after it — any other shape —
+        is a violation as ever."""
+        warmup_calls = self.warmup_calls + max(1, int(signatures)) - 1
         st = self._fns.setdefault(
             name, {"calls": 0, "compiles": 0, "seen": set(), "descs": None,
                    "compile_wall_s": 0.0, "fn": fn, "abstract_args": None})
@@ -170,7 +180,7 @@ class RecompileSentinel:
                 st["abstract_args"] = abstract_args_of(args, kwargs)
                 prev_descs, st["descs"] = st["descs"], descs
                 st["compiles"] += 1
-                if prior_calls >= self.warmup_calls:
+                if prior_calls >= warmup_calls:
                     self._violation(name, st, prev_descs, descs)
             return out
 

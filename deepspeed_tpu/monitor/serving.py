@@ -23,8 +23,9 @@ the rows it was live in (``Request.token_times``).
 """
 from __future__ import annotations
 
+import collections
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -140,6 +141,7 @@ class ServingAggregator:
         self.iterations = 0
         self.decode_tokens = 0
         self.prefill_tokens = 0
+        self.prefill_width_dispatches = collections.Counter()  # by width
         self.completed = 0
         # Paged-cache accounting (engine-fed; stays empty — and out of
         # the snapshot — until the engine feeds it: the scheduler tests'
@@ -208,11 +210,13 @@ class ServingAggregator:
         return self._serve_t0
 
     def note_prefill_pass(self, dispatches: int, rows: int,
-                          rows_computed: int) -> float:
+                          rows_computed: int,
+                          widths: Sequence[int] = ()) -> float:
         """One admission batch's prefill ends here (a lap of
         ``prefill_s``): the chunk programs it dispatched, the prompt
-        rows it needed (prompt - cached) and the rows those programs
-        computed."""
+        rows it needed (prompt - cached), the rows those programs
+        computed and each one's row width."""
+        self.prefill_width_dispatches.update(int(w) for w in widths)
         p = self._pend
         p[COL["prefill_dispatches"]] += dispatches
         p[COL["prefill_rows"]] += rows
@@ -514,7 +518,9 @@ class ServingAggregator:
         ``decode_wait``, ``stall``, ``host``: they sum to it),
         ``itl_stalled_share`` (intervals that held a prefill dispatch),
         ``prefill_row_fill`` (rows the prefills needed / rows their
-        dispatches computed), ``stalls`` (see ``stalls()``),
+        dispatches computed), ``prefill_width_dispatches`` (``{row
+        width: chunk programs dispatched at it}``, over the whole run),
+        ``stalls`` (see ``stalls()``),
         ``lookahead_share`` (iterations dispatched while the one before
         was still unfetched: the host's pass hid under the device) and
         ``lookahead_dropped_rows`` (rows computed for streams that had
@@ -587,6 +593,9 @@ class ServingAggregator:
         if computed:
             snap["prefill_row_fill"] = round(
                 float(table[:, COL["prefill_rows"]].sum()) / computed, 4)
+        if self.prefill_width_dispatches:
+            snap["prefill_width_dispatches"] = dict(sorted(
+                self.prefill_width_dispatches.items()))
         if self.prompt_tokens_admitted:
             snap["prefix"] = {
                 "prompt_tokens": self.prompt_tokens_admitted,
@@ -648,6 +657,7 @@ class ServingAggregator:
             out.iterations += a.iterations
             out.decode_tokens += a.decode_tokens
             out.prefill_tokens += a.prefill_tokens
+            out.prefill_width_dispatches += a.prefill_width_dispatches
             out.completed += a.completed
             out.prompt_tokens_admitted += a.prompt_tokens_admitted
             out.cached_tokens_admitted += a.cached_tokens_admitted

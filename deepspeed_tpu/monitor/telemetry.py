@@ -462,15 +462,18 @@ class Telemetry:
         if self.ledger is not None:
             self.ledger.note_background("checkpoint_write", seconds)
 
-    def instrument_step_fn(self, name: str, fn: Callable) -> Callable:
-        """Recompile-sentinel wrapping for a compiled step function;
+    def instrument_step_fn(self, name: str, fn: Callable,
+                           signatures: int = 1) -> Callable:
+        """Recompile-sentinel wrapping for a compiled step function
+        (``signatures``: the abstract signatures its owner compiles it
+        at in its first calls, ``RecompileSentinel.instrument``);
         identity when telemetry is disabled. With the hang watchdog on,
         each dispatch also records the pending step signature (one
         attribute store) so a watchdog fire can name what the run was
         stuck on."""
         if self.sentinel is None:
             return fn
-        wrapped = self.sentinel.instrument(name, fn)
+        wrapped = self.sentinel.instrument(name, fn, signatures)
         wd = self.watchdog
         if wd is None:
             return wrapped

@@ -80,14 +80,16 @@ SPAN_ARGS = {
     # state pool's admission (inference/kv_cache.py): prompt tokens the
     # snapshot it resumed from covers (what cached_tokens means there),
     # snapshots the admission left, bytes its page copies read + wrote.
-    # rows_computed: the [G, chunk] rows of every chunk program the
-    # admission dispatched (prompt_tokens - cached_tokens of them needed).
+    # rows_computed: the [G, width] rows of every chunk program the
+    # admission dispatched, each at the width it took (the narrowest of
+    # the engine's prefill_widths that held its rows: prefill_chunk's
+    # "rows"); prompt_tokens - cached_tokens of them were needed.
     "prefill": ("slots", "prompt_tokens", "rids", "cached_tokens",
                 "chunks", "rows_computed", "moe_held_pairs", "moe_held_max",
                 "moe_held_mean", "moe_held_empty", "moe_held_pair_share",
                 "hc_res_err_max",
                 "resumed_tokens", "snapshot_taken", "state_copy_bytes"),
-    "prefill_chunk": ("ci", "active_groups"),
+    "prefill_chunk": ("ci", "active_groups", "rows"),
     # A decode span holds the DISPATCH of one iteration and the FETCH of
     # the one before (the loop runs an iteration ahead of its token
     # fetch): iteration .. attend_* and state_pages_live describe the one

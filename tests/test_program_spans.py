@@ -221,7 +221,8 @@ SERVE_SPANS = {
     "admit": {"queued", "admitted", "late_ms", "rids"},
     "prefill": {"slots", "prompt_tokens", "cached_tokens", "chunks",
                 "rows_computed", "rids"},
-    "prefill_plan": set(), "prefill_chunk": {"ci", "active_groups"},
+    "prefill_plan": set(),
+    "prefill_chunk": {"ci", "active_groups", "rows"},
     "prefill_fetch": set(),
     # (of every ``decode`` span; the ones that dispatched an iteration
     # carry DISPATCHED too: the loop runs an iteration ahead of its token
@@ -376,6 +377,8 @@ def test_emit_and_prefill_spans_carry_the_timeline(serve_annotations,
     assert emits[0]["stall_ms"] > 0 and emits[1]["stall_ms"] == 0
     assert emits[2]["gap_ms"] > 1000 and emits[2]["continuing"] == 0
     for _, _, a in found["prefill"]:
+        # (a chunk of 8 is its own one width: inference/engine.py's
+        # ``prefill_widths``; tests/test_prefill_widths.py has the ladder)
         assert a["rows_computed"] == a["chunks"] * serve_engine.prefill_chunk
         assert a["prompt_tokens"] - a["cached_tokens"] == 12
     assert report["prefill_row_fill"] == 0.75
