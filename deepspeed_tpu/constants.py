@@ -311,9 +311,7 @@ INFERENCE_QUANTIZE_DEFAULT = "none"
 INFERENCE_QUANTIZE_MODES = ("none", "bf16", "int8")
 # Prefill chunk length: prompts are right-padded to a multiple and run
 # chunk-by-chunk against the cache (static shapes at every prompt
-# length). 0 = whole-prompt single-shot prefill padded to max_seq_len —
-# the long-context path that composes with ring attention when the mesh
-# has a sequence axis.
+# length): a positive int, the only admission path.
 INFERENCE_PREFILL_CHUNK = "prefill_chunk"
 INFERENCE_PREFILL_CHUNK_DEFAULT = 32
 # Paged KV cache (the PagedAttention design): the cache is a pool of

@@ -5,11 +5,11 @@ is what stands between that checkpoint and heavy traffic: a paged,
 prefix-shared KV cache born sharded over the training mesh — fixed-size
 blocks behind a block-table indirection, copy-on-write prefix sharing,
 reservation-gated admission (kv_cache.py) — jitted single-token decode,
-chunked/whole-prompt prefill, and the speculative draft-then-verify
-step over the GPT-2 family (decode.py), the self-drafting n-gram
-proposer (spec.py), iteration-level continuous batching with an
-open-loop request queue (scheduler.py), weight quantization via the
-stochastic-rounding machinery (quantize.py), the InferenceEngine tying
+chunked prefill and the speculative draft-then-verify step behind the
+served-model interface (served.py; GPT-2's in decode.py), the
+self-drafting n-gram proposer (spec.py), iteration-level continuous
+batching with an open-loop request queue (scheduler.py), weight
+quantization via stochastic rounding (quantize.py), the InferenceEngine tying
 it to the telemetry spine — decode-step JSONL records, prefill spans,
 the recompile sentinel over every compiled path, per-request
 TTFT/TPOT/occupancy goodput plus HBM-bytes-per-token, prefix-hit and

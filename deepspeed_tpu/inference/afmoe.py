@@ -34,8 +34,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import kv_cache
-from .decode import NEG_INF, _group_shape
-from .served import CacheClass, ServedModel, register
+from .served import (NEG_INF, CacheClass, ServedModel, group_shape,
+                     register)
 from ..models import afmoe
 from ..models.afmoe import AfmoeConfig, SLIDING
 from ..models.blocks import matmul, rms_norm, swiglu
@@ -270,7 +270,8 @@ class AfmoeServed(ServedModel):
         return (self.cfg.num_attention_heads, self.cfg.head_dim,
                 self.cfg.head_dim)
 
-    def attend_step_counts(self, live_blocks, *, K, spec, mp, q_itemsize):
+    def attend_step_counts(self, live_blocks, *, K, spec, mp, q_itemsize,
+                           calls=1):
         """Of a layer of the FIRST class (``spec``), the K/V head's
         ``group * K`` query rows as the kernel takes them."""
         return paged_attn_ops.attend_step_counts(
@@ -279,7 +280,8 @@ class AfmoeServed(ServedModel):
             block_size=spec.block_size,
             table_width=spec.max_blocks_per_slot,
             kv_itemsize=int(jnp.dtype(spec.dtype).itemsize),
-            q_itemsize=q_itemsize)
+            q_itemsize=q_itemsize) + (
+                paged_attn_ops.attend_cold_steps(live_blocks, calls=calls),)
 
     def counter_args(self, rows) -> Dict[str, Any]:
         """Of the executions fetched: routed pairs (every expert is held:
@@ -319,8 +321,8 @@ class AfmoeServed(ServedModel):
             (block_tables >= 0).any(axis=1, keepdims=True), tokens.shape)
         x, pools, counters = _forward(
             params, pools, _embed(params, tokens, cfg),
-            _group_shape(block_tables, num_groups),
-            _group_shape(pos, num_groups), live, cfg,
+            group_shape(block_tables, num_groups),
+            group_shape(pos, num_groups), live, cfg,
             self._widths(block_tables), paged_kernel, mesh)
         return _head(params, x, cfg), pools, counters
 

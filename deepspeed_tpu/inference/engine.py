@@ -13,9 +13,8 @@ Architecture (mirrors the training engine's discipline):
   sampling, spans — is the same for every model.
 - TWO compiled programs serve everything: ``decode_step`` (one token for
   every slot at once) and ``prefill_step`` (one chunk of one slot's
-  prompt — or the whole padded prompt when ``prefill_chunk: 0``).
-  ``decode_step`` has one abstract signature for the lifetime of the
-  engine; a chunked ``prefill_step`` has one a ROW WIDTH of
+  prompt a dp group). ``decode_step`` has one abstract signature for
+  the lifetime of the engine; ``prefill_step`` has one a ROW WIDTH of
   ``prefill_widths`` (``prefill_chunk`` and its half, no narrower than
   128 rows: 512 -> 256, 512), every one compiled before the first
   admission, and a dispatch takes the narrowest that holds its rows (a
@@ -65,11 +64,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import decode as decode_mod
 from . import kv_cache
 from .quantize import (dequantize, quantize_params, quantized_bytes,
                        resolve_kv_dtype)
-from .served import ServedModel, served_model, split_counters, with_counters
+from .served import (ServedModel, sample_tokens, served_model, spec_accept,
+                     split_counters, with_counters)
 from .spec import NGramDrafter
 from .. import constants as C
 from ..monitor import Telemetry
@@ -109,9 +108,7 @@ def prefill_widths(prefill_chunk: int, block_size: int) -> Tuple[int, ...]:
     ``prefill_chunk``, then its halvings — ``MAX_PREFILL_WIDTHS`` widths
     at most — while the result is at least ``MIN_PREFILL_WIDTH`` and a
     multiple of ``block_size`` (512 -> (256, 512); 128 or 96 ->
-    themselves). () for whole-prompt prefill."""
-    if prefill_chunk <= 0:
-        return ()
+    themselves)."""
     widths = [prefill_chunk]
     while len(widths) < MAX_PREFILL_WIDTHS:
         half, odd = divmod(widths[-1], 2)
@@ -245,7 +242,7 @@ class InferenceEngine:
             config = load_config_json(config)
         config = dict(config or {})
         # A ServedModel, or a model config an implementation is
-        # registered for (GPT-2's: inference/decode.py).
+        # registered for.
         self.model_cfg = model_cfg
         served = self._served = served_model(model_cfg)
         self.icfg = InferenceConfig(config)
@@ -264,21 +261,12 @@ class InferenceEngine:
                 f"inference.max_seq_len={self.max_len} exceeds the model's "
                 f"position table ({served.max_positions})")
         self.prefill_chunk = int(self.icfg.prefill_chunk)
-        if self.prefill_chunk > 0 and self.max_len % self.prefill_chunk:
+        if self.max_len % self.prefill_chunk:
             raise ValueError(
                 f"inference.prefill_chunk={self.prefill_chunk} must divide "
                 f"the cache capacity ({self.max_len}) — padded prompts "
                 "would otherwise overrun the slot")
-        if self.prefill_chunk == 0 and self.sp > 1 \
-                and self.max_len % self.sp:
-            raise ValueError(
-                f"whole-prompt prefill with a seq axis needs max_seq_len "
-                f"({self.max_len}) divisible by sp={self.sp}")
         self.block_size = int(self.icfg.block_size)
-        if self.max_len % self.block_size:
-            raise ValueError(
-                f"inference.block_size={self.block_size} must divide "
-                f"inference.max_seq_len ({self.max_len})")
         self.prefill_widths = prefill_widths(self.prefill_chunk,
                                              self.block_size)
         self._prefill_warmed = len(self.prefill_widths) < 2
@@ -309,16 +297,10 @@ class InferenceEngine:
         self.param_bytes = quantized_bytes(self._params)
 
         # --- the KV cache: the paged block pool, born sharded ---
-        if served.cache_per_stream and self.spec_k > 0:
-            raise ValueError(
-                "inference.spec_k > 0 needs a cache that can drop rejected "
-                f"rows; {served.name} keeps a state per stream")
         kv_dtype = served.cache_dtype or resolve_kv_dtype(
             self.icfg.kv_cache_dtype, served.dtype)
         # One spec, pool set and allocator a CLASS of cache layers (one
-        # class for most models; kv_cache.py's docstring). A bounded
-        # class's table is a ring as wide as one program's queries reach.
-        rows = max(self.prefill_chunk or self.max_len, self.spec_k + 1)
+        # class for most models; kv_cache.py's docstring).
         classes = served.cache_classes
         asked = self.icfg.num_blocks
         if asked and isinstance(asked, dict) != (len(classes) > 1):
@@ -328,34 +310,15 @@ class InferenceEngine:
                    f"{[c.name for c in classes]} and takes "
                    "{class name: blocks}" if len(classes) > 1 else
                    "one class of cache layers and takes an int"))
-        specs = []
-        for cls in classes:
-            ring = 0 if cls.reach is None else min(
-                self.max_len // self.block_size,
-                (cls.reach + rows - 2) // self.block_size + 2)
-            # 0 (or a class left out): full provisioning — every slot's
-            # table full, so admission never blocks on HBM; smaller pools
-            # oversubscribe and the admission gate accounts free blocks.
-            blocks = int((asked.get(cls.name, 0) if isinstance(asked, dict)
-                          else asked) or self.max_slots * (
-                1 if served.cache_per_stream
-                else ring or self.max_len // self.block_size))
-            if blocks % self.dp:
-                raise ValueError(
-                    f"inference.num_blocks={blocks} must be "
-                    f"divisible by the mesh data axis ({self.dp}) — blocks "
-                    "are born sharded over dp alongside their slots")
-            specs.append(kv_cache.PagedKVCacheSpec(
-                num_layers=cls.layers,
-                num_slots=self.max_slots, num_blocks=blocks,
-                block_size=self.block_size, max_len=self.max_len,
-                num_heads=served.cache_heads,
-                head_dim=served.cache_row_width, num_groups=self.dp,
-                dtype=kv_dtype, pools=served.cache_pools(self.block_size),
-                per_stream=served.cache_per_stream,
-                token_row_bytes=served.token_row_bytes,
-                name=cls.name, reach=cls.reach, table_blocks=ring))
-        self.cache_specs = tuple(specs)
+        specs = kv_cache.class_specs(
+            classes, asked, rows=max(self.prefill_chunk, self.spec_k + 1),
+            num_slots=self.max_slots, block_size=self.block_size,
+            max_len=self.max_len, num_heads=served.cache_heads,
+            head_dim=served.cache_row_width, num_groups=self.dp,
+            dtype=kv_dtype, pools=served.cache_pools(self.block_size),
+            token_row_bytes=served.token_row_bytes)
+        self.allocator = kv_cache.allocator_for(specs, self.spec_k)
+        self.cache_specs = specs
         self.cache_spec = specs[0]
         self.num_blocks = sum(sp.num_blocks for sp in specs)
         served.table_widths = tuple(sp.max_blocks_per_slot for sp in specs)
@@ -364,9 +327,6 @@ class InferenceEngine:
             self.cache.update(kv_cache.init_paged_cache(spec, self.mesh))
             self._cache_sh.update(kv_cache.paged_shardings(
                 self.mesh, spec.pool_names))
-        self.allocator = kv_cache.BlockAllocator(specs[0]) \
-            if len(specs) == 1 and specs[0].reach is None \
-            else kv_cache.ClassAllocators(specs)
         self.block_tables = np.full(
             (self.max_slots, self.allocator.table_width),
             kv_cache.DEAD_BLOCK, np.int32)
@@ -483,9 +443,8 @@ class InferenceEngine:
             "prefill_step", prefill_step,
             signatures=len(self.prefill_widths))
         self._copy_fn = self.telemetry.instrument_step_fn(
-            *(("state_copy", self._build_state_copy())
-              if served.cache_per_stream
-              else ("copy_block", self._build_copy_block())))
+            self.allocator.copy_program[0],
+            self._build_copy(*self.allocator.copy_program))
         if self.spec_k > 0:
             self._verify_fn = self.telemetry.instrument_step_fn(
                 "verify_step", self._build_verify_step())
@@ -497,7 +456,7 @@ class InferenceEngine:
             f"{self.max_len}x{served.cache_heads}h "
             f"({sum(sp.nbytes() for sp in specs) / 2 ** 20:.1f} MiB "
             f"{'+'.join(self._cache_sh)}), "
-            f"prefill={'full' if self.prefill_chunk == 0 else f'chunk {self.prefill_chunk}'}, "
+            f"prefill=chunk {self.prefill_chunk}, "
             f"spec_k={self.spec_k}, quantize={self.quantize}"
             + (f", replica={self.replica}" if self.replica else ""),
             ranks=[0])
@@ -554,52 +513,35 @@ class InferenceEngine:
             logits, pools, counters = served.decode(
                 p, pools, tokens, lengths, bt, num_groups=self.dp,
                 paged_kernel=self.paged_kernel, mesh=self.mesh)
-            sampled = decode_mod.sample_tokens(logits, key, temperature)
+            sampled = sample_tokens(logits, key, temperature)
             return (*pools, with_counters(sampled, counters), logits)
 
         return self._jit_step(decode_step, self.__dict__.get("_fetch_sh"))
 
     def _build_prefill_step(self) -> Callable:
+        """Group-batched chunked prefill: one chunk of one slot per dp
+        group (single admissions leave the other groups' rows DEAD —
+        uniform program, writes land nowhere). ``tokens`` is ``[G,
+        width]``, one of ``prefill_widths``: the same function, compiled
+        once a width (``_warm_prefill_widths``)."""
         served = self.served
         n = len(self._cache_sh)
-        attention_fn = None
-        if self.prefill_chunk == 0 and self.sp > 1:
-            from ..ops.ring_attention import ring_attention_fn
-            attention_fn = ring_attention_fn(self.mesh)
 
-        if self.prefill_chunk > 0:
-            # Group-batched chunked prefill: one chunk of one slot per
-            # dp group (single admissions leave the other groups' rows
-            # DEAD — uniform program, writes land nowhere). ``tokens``
-            # is ``[G, width]``, one of ``prefill_widths``: the same
-            # function, compiled once a width (``_warm_prefill_widths``).
-            def prefill_step(params, *args):
-                pools, (tokens, bt_rows, start, last_idx, active, key,
-                        temperature) = args[:n], args[n:]
-                p = self._runtime_params(params)
-                logits, pools, counters = served.prefill_chunk(
-                    p, pools, tokens, bt_rows, start, last_idx, active,
-                    paged_kernel=self.paged_kernel, mesh=self.mesh)
-                sampled = decode_mod.sample_tokens(logits, key,
-                                                   temperature)
-                return (*pools, with_counters(sampled, counters), logits)
-        else:
-            def prefill_step(params, *args):
-                pools, (tokens, bt_rows, last_idx, key, temperature) = \
-                    args[:n], args[n:]
-                p = self._runtime_params(params)
-                logits, pools, counters = served.prefill_full(
-                    p, pools, tokens, bt_rows, last_idx,
-                    attention_fn=attention_fn, mesh=self.mesh)
-                sampled = decode_mod.sample_tokens(logits, key,
-                                                   temperature)
-                return (*pools, with_counters(sampled, counters), logits)
+        def prefill_step(params, *args):
+            pools, (tokens, bt_rows, start, last_idx, active, key,
+                    temperature) = args[:n], args[n:]
+            p = self._runtime_params(params)
+            logits, pools, counters = served.prefill_chunk(
+                p, pools, tokens, bt_rows, start, last_idx, active,
+                paged_kernel=self.paged_kernel, mesh=self.mesh)
+            sampled = sample_tokens(logits, key, temperature)
+            return (*pools, with_counters(sampled, counters), logits)
 
         return self._jit_step(prefill_step)
 
     def _build_verify_step(self) -> Callable:
         """Speculative draft-then-verify: one batched K=spec_k+1 step,
-        in-graph acceptance (decode.spec_accept), ONE [S, K+2] int32
+        in-graph acceptance (served.spec_accept), ONE [S, K+2] int32
         readback — the same single host fetch per iteration plain
         decode pays."""
         served = self.served
@@ -613,40 +555,28 @@ class InferenceEngine:
             logits, pools, _ = served.verify(
                 p, pools, tokens, lengths, bt, num_groups=self.dp,
                 paged_kernel=self.paged_kernel, mesh=self.mesh)
-            out = decode_mod.spec_accept(logits, tokens, key, temperature)
+            out = spec_accept(logits, tokens, key, temperature)
             return (*pools, out, logits)
 
         return self._jit_step(verify_step)
 
-    def _build_copy_block(self) -> Callable:
-        """The device half of copy-on-write: duplicate one block's K/V
-        rows (all layers) into a private block of the same group."""
-        sh = tuple(self._cache_sh.values())
-
-        @jax.named_scope("cow_copy")
-        def copy_block(*args):
-            pools, (src_onehot, dst_onehot) = args[:len(sh)], args[len(sh):]
-            return tuple(kv_cache.paged_copy_block(pool, src_onehot,
-                                                   dst_onehot)
-                         for pool in pools)
-
-        return jax.jit(copy_block, donate_argnums=tuple(range(len(sh))),
-                       out_shardings=sh)
-
-    def _build_state_copy(self) -> Callable:
-        """A per-stream pool's copy: page ``src[g]`` to page ``dst[g]``
-        of every group, page to page in the donated pools — a snapshot
+    def _build_copy(self, name: str, scope: str) -> Callable:
+        """The cache's one device copy (``kv_cache.copy_pages``): block
+        ``src[g]`` to block ``dst[g]`` of every group, every layer, in
+        the donated pools — a copy-on-write fork of a block, a snapshot
         into a stream's own page at admission, a stream's page into a
-        snapshot when prefill reaches its boundary."""
+        snapshot when prefill reaches its boundary. ``name`` and ``scope``
+        are the allocator's (``copy_program``)."""
         sh = tuple(self._cache_sh.values())
 
-        @jax.named_scope("state_copy")
-        def state_copy(*args):
+        def copy(*args):
             pools, (src, dst) = args[:len(sh)], args[len(sh):]
-            return tuple(kv_cache.copy_pages(pool, src, dst)
-                         for pool in pools)
+            with jax.named_scope(scope):
+                return tuple(kv_cache.copy_pages(pool, src, dst, self.mesh)
+                             for pool in pools)
 
-        return jax.jit(state_copy, donate_argnums=tuple(range(len(sh))),
+        copy.__name__ = name
+        return jax.jit(copy, donate_argnums=tuple(range(len(sh))),
                        out_shardings=sh)
 
     def _next_key(self) -> jax.Array:
@@ -708,15 +638,6 @@ class InferenceEngine:
     def spec_enabled(self) -> bool:
         return self.spec_k > 0
 
-    def _ensure_blocks(self, slot: int, first_pos: int,
-                       upto_pos: int) -> None:
-        """Make ``slot``'s table ready for a program whose queries span
-        positions ``[first_pos, upto_pos]``: blocks are drawn lazily, and
-        a class of window layers first returns what lies behind
-        ``first_pos``'s reach (``BlockAllocator.extend``)."""
-        self.allocator.extend(slot, self.block_tables[slot], first_pos,
-                              upto_pos)
-
     # ------------------------------------------------------------------ #
     # Admission (the scheduler's gate): slot occupancy AND HBM blocks
     # ------------------------------------------------------------------ #
@@ -733,7 +654,7 @@ class InferenceEngine:
 
         The gate is slot occupancy AND HBM accounting: a group must
         cover the request's worst-case block need
-        (``BlockAllocator.can_admit``), and among admissible
+        (the allocator's ``can_admit``), and among admissible
         groups the one already holding the longest cached prefix of
         this prompt wins (prefix affinity — the request lands where its
         blocks live), ties broken toward the most available HBM. The
@@ -747,7 +668,6 @@ class InferenceEngine:
         if not free:
             self.last_admit_block = "no_slot"
             return None
-        share = self.prefill_chunk > 0
         Sg = self.cache_spec.slots_per_group
         first_free: Dict[int, int] = {}
         for s in free:
@@ -760,11 +680,10 @@ class InferenceEngine:
         for g, s in first_free.items():
             if not self.allocator.can_admit(g, prompt,
                                             int(max_new_tokens),
-                                            self.spec_k, share=share):
+                                            self.spec_k):
                 continue
-            matched = self.allocator.matched_blocks(g, prompt) \
-                if share else 0
-            key = (matched, self.allocator.available(g))
+            key = (self.allocator.matched_blocks(g, prompt),
+                   self.allocator.available(g))
             if best_key is None or key > best_key:
                 best, best_key = s, key
         if best is not None:
@@ -775,8 +694,7 @@ class InferenceEngine:
 
     def last_admit_info(self, slot: int) -> Dict[str, Any]:
         """Prefix-cache/CoW detail of the most recent admission into
-        ``slot`` (for the request trace); empty for whole-prompt
-        prefill, which shares nothing."""
+        ``slot`` (for the request trace)."""
         return self._last_admit.get(slot, {})
 
     def note_admission_reject(self, rid: Any, reason: str, attempt: int,
@@ -796,13 +714,9 @@ class InferenceEngine:
         """Longest cached prompt prefix (tokens) resident anywhere in
         this engine's block pool — the router's affinity signal. Host
         hash walk only; zero device work."""
-        if self.prefill_chunk == 0:
-            return 0
         prompt = np.asarray(prompt, np.int32).reshape(-1)
-        best = 0
-        for g in range(self.dp):
-            best = max(best, self.allocator.matched_blocks(g, prompt))
-        return best * self.block_size
+        return self.block_size * max(
+            self.allocator.matched_blocks(g, prompt) for g in range(self.dp))
 
     # ------------------------------------------------------------------ #
     # The two serving operations
@@ -828,64 +742,10 @@ class InferenceEngine:
 
         ``rid`` only labels the ``prefill`` host span (see
         ``prefill_many``)."""
-        if self.prefill_chunk > 0:
-            return self.prefill_many(
-                [(slot, prompt, int(max_new_tokens or 0))], temperature,
-                return_logits=return_logits,
-                rids=None if rid is None else [rid])[0]
-        t0 = self.serving.lap("admit_s")
-        tl = self.telemetry
-        prompt = np.asarray(prompt, np.int32).reshape(-1)
-        plen = int(prompt.shape[0])
-        if plen < 1:
-            raise ValueError("empty prompt")
-        if plen >= self.max_len:
-            raise ValueError(
-                f"prompt length {plen} leaves no room to generate in a "
-                f"{self.max_len}-token slot")
-        n_ctr = len(self.served.counter_names)
-        with tl.span("prefill", slots=1, prompt_tokens=plen,
-                     cached_tokens=0, chunks=1, rows_computed=self.max_len,
-                     rids=ids_arg(None if rid is None else [rid])) as span:
-            padded = np.zeros(self.max_len, np.int32)
-            padded[:plen] = prompt
-            G = self.dp
-            J = self.allocator.table_width
-            group = slot // self.cache_spec.slots_per_group
-            with tl.span("prefill_plan"):
-                plan = self.allocator.admit_prompt(
-                    slot, group, prompt, int(max_new_tokens or 0),
-                    self.spec_k, share=False)
-                row = np.full(J, kv_cache.DEAD_BLOCK, np.int32)
-                row[:len(plan.table)] = plan.table
-                self.block_tables[slot] = row
-                self._ensure_blocks(slot, 0, plen - 1)
-                bt_rows = np.full((G, J), kv_cache.DEAD_BLOCK, np.int32)
-                bt_rows[group] = self.block_tables[slot]
-            with tl.span("prefill_chunk", ci=0, active_groups=1):
-                *pools, tok, logits = self._prefill_fn(
-                    self._params, *self._pools(), padded, bt_rows,
-                    np.int32(plen - 1), self._next_key(),
-                    np.float32(temperature))
-            if self.drafter is not None:
-                self.drafter.begin(slot, prompt)
-            self.serving.note_admit(plen, 0)
-            self._store_pools(pools)
-            tl.raise_pending()
-            waited = self._await_decode()
-            with tl.span("prefill_fetch"):
-                out_logits = np.asarray(jax.device_get(logits)) \
-                    if return_logits else None
-                tok, counters = split_counters(
-                    np.asarray(jax.device_get(tok)).reshape(-1), n_ctr)
-                tok = int(tok[0])
-            self._note_counters(span, counters)
-        wall = self.serving.note_prefill_pass(1, plen, self.max_len) - t0 \
-            - waited
-        self._prefill_wall += wall
-        if self.serving.ledger is not None:
-            self.serving.ledger.note("prefill", wall)
-        return tok, out_logits
+        return self.prefill_many(
+            [(slot, prompt, int(max_new_tokens or 0))], temperature,
+            return_logits=return_logits,
+            rids=None if rid is None else [rid])[0]
 
     def prefill_many(self, admissions: Sequence[Tuple[int, Any, int]],
                      temperature: float = 0.0,
@@ -914,8 +774,6 @@ class InferenceEngine:
         one ``prefill_chunk`` (``ci``, ``active_groups``, ``rows``: the
         width) per chunk program dispatched, and ``prefill_fetch`` (the
         first tokens' ``device_get``)."""
-        if self.prefill_chunk == 0:
-            raise RuntimeError("prefill_many needs chunked prefill")
         if not self._prefill_warmed:
             self._warm_prefill_widths()
         t_pf0 = self.serving.lap("admit_s")
@@ -955,8 +813,11 @@ class InferenceEngine:
             computed = self.dp * sum(widths)
             span.set_metadata(cached_tokens=cached, chunks=len(steps),
                               rows_computed=computed)
-            if self.cache_spec.per_stream:
-                span.set_metadata(**self._note_state_admissions(plans))
+            state = self.allocator.span_args(plans=[p[2] for p in plans])
+            if state:
+                span.set_metadata(**state)
+                self.serving.note_state(state,
+                                        self.allocator.snapshot_totals())
             by_class: Dict[str, int] = {}
             for p in plans:
                 for name, n in (p[2].cached_by_class or {}).items():
@@ -987,17 +848,11 @@ class InferenceEngine:
 
     def _copy_blocks(self, pools, pairs):
         """Dispatch the copy program for ``{group: (src, dst)}`` block
-        ids: page ids as they are for a per-stream pool (-1: nothing to
-        copy in the group), one-hots over the group's blocks otherwise."""
-        G, B = self.dp, self.cache_spec.blocks_per_group
-        if self.cache_spec.per_stream:
-            src, dst = np.full(G, -1, np.int32), np.full(G, -1, np.int32)
-            for g, (s, d) in pairs.items():
-                src[g], dst[g] = s, d
-        else:
-            src, dst = np.zeros((G, B), np.float32), np.zeros((G, B), bool)
-            for g, (s, d) in pairs.items():
-                src[g, s], dst[g, d] = 1.0, True
+        ids (-1: nothing to copy in the group)."""
+        src = np.full(self.dp, -1, np.int32)
+        dst = np.full(self.dp, -1, np.int32)
+        for g, (s, d) in pairs.items():
+            src[g], dst[g] = s, d
         self.serving.lap("prefill_s")
         pools = self._copy_fn(*pools, src, dst)
         self.serving.lap("copy_s")       # the host's part: the dispatch
@@ -1096,7 +951,9 @@ class InferenceEngine:
                         continue
                     first, n = chunks[ci]
                     toks[group, :n] = prompt[first:first + n]
-                    self._ensure_blocks(slot, first, first + n - 1)
+                    # (blocks are drawn lazily, program by program)
+                    self.allocator.extend(slot, self.block_tables[slot],
+                                          first, first + n - 1)
                     bt_rows[group] = self.block_tables[slot]
                     starts[group] = first
                     act[group] = 1
@@ -1149,27 +1006,6 @@ class InferenceEngine:
         self._prefill_warmed = True
         self.telemetry.raise_pending()
 
-    def _note_state_admissions(self, plans) -> Dict[str, int]:
-        """A per-stream pool's ``prefill`` span args, also summed into
-        ``snapshot()["state"]``: tokens resumed from a snapshot (what
-        ``cached_tokens`` means here), snapshots this admission took, and
-        the bytes its page copies moved (read + written)."""
-        page = self.cache_spec.block_nbytes()
-        args = {
-            "resumed_tokens": sum(int(p[2].matched) for p in plans),
-            "snapshot_taken": sum(p[2].snapshot_page is not None
-                                  for p in plans),
-            "state_copy_bytes": 2 * page * sum(
-                (p[2].cow_src is not None)
-                + (p[2].snapshot_page is not None) for p in plans)}
-        self.serving.note_state(
-            resumed_tokens=args["resumed_tokens"],
-            state_copy_bytes=args["state_copy_bytes"],
-            snapshots_taken=self.allocator.snapshots_taken,
-            snapshot_hits=self.allocator.snapshot_hits,
-            snapshots_evicted=self.allocator.reclaimed)
-        return args
-
     def _cache_accounting(self, mask: Optional[np.ndarray] = None
                           ) -> Tuple[int, int, int]:
         """(live blocks, cache bytes held, context tokens cached) this
@@ -1214,8 +1050,7 @@ class InferenceEngine:
         (see ``ops.paged_attention.attend_step_counts`` and
         ``attend_cold_steps``), from the lengths and tables the execution
         is handed (the host's own by default). Zeros on the one-hot path,
-        which has no steps; no cold ones for a state a stream, which no
-        attend walks."""
+        which has no steps."""
         if not self.paged_kernel:
             return 0, 0, 0
         sp_ = self.cache_spec
@@ -1226,11 +1061,9 @@ class InferenceEngine:
             np.minimum(reach, sp_.max_blocks_per_slot),
             (tables[:, :sp_.max_blocks_per_slot] >= 0).sum(axis=1))
         served = self.served
-        cold = 0 if sp_.per_stream else paged_attn_ops.attend_cold_steps(
-            live, calls=self.dp)
         return served.attend_step_counts(
-            live, K=k_rows, spec=sp_, mp=self.mp,
-            q_itemsize=int(jnp.dtype(served.dtype).itemsize)) + (cold,)
+            live, K=k_rows, spec=sp_, mp=self.mp, calls=self.dp,
+            q_itemsize=int(jnp.dtype(served.dtype).itemsize))
 
     def _attend_cost(self, context: Optional[int] = None,
                      pool_blocks: Optional[int] = None,
@@ -1397,8 +1230,8 @@ class InferenceEngine:
         tl, lap = self.telemetry, self.serving.lap
         with tl.span("decode_tables"):
             for s in np.flatnonzero(mask):
-                self._ensure_blocks(int(s), int(self.lengths[s]),
-                                    int(self.lengths[s]))
+                at = int(self.lengths[s])
+                self.allocator.extend(int(s), self.block_tables[s], at, at)
             # What the execution is handed: COPIES (the host's arrays
             # move on under it), dead rows for the slots not in it, and
             # the host's token for a slot the execution before did not
@@ -1431,9 +1264,8 @@ class InferenceEngine:
                               attend_steps=steps[0],
                               attend_live_steps=steps[1],
                               attend_cold_steps=steps[2],
-                              **self._class_args(mask))
-            if self.cache_spec.per_stream:
-                span.set_metadata(state_pages_live=n_active)
+                              **self._class_args(mask),
+                              **self.allocator.span_args(live=n_active))
         lap("dispatch_s")
         return _Flight(fetch=fetch, logits=logits, mask=mask,
                        n_active=n_active, t0=t0,
@@ -1532,8 +1364,8 @@ class InferenceEngine:
                 for s in live:
                     s = int(s)
                     toks[s, 1:] = self.drafter.propose(s)
-                    self._ensure_blocks(
-                        s, int(self.lengths[s]),
+                    self.allocator.extend(
+                        s, self.block_tables[s], int(self.lengths[s]),
                         min(int(self.lengths[s]) + k, self.max_len - 1))
                 steps = self._attend_steps(k + 1)
             lap("tables_s")

@@ -42,21 +42,30 @@ touches R tiles for R rows — its cost does not depend on ``num_blocks``
 the layer index, never sliced. Block GATHERS are the Pallas
 paged-attention kernel on TPU; the one-hot ``paged_attend`` contraction
 (which slices its layer out and reads it whole) stays as the CPU-mesh
-path and the reference the kernel is tested against, and
-``paged_copy_block`` (copy-on-write, rare) is still a one-hot select
-over the pool (see docs/tutorials/inference.md).
+path and the reference the kernel is tested against.  ONE copy serves
+every pool (``copy_pages``: block to block of a group, every layer, a
+slice read and an update in place in the donated pool, whatever the
+pool's size): a copy-on-write fork of a block, a snapshot into or out of
+a stream's page.
+
+Three POLICIES manage a pool, one class each behind one interface
+(``allocator_for`` picks; the engine asks none of them what it is):
+``BlockAllocator`` (pages of tokens' rows, shared by reference count),
+``BoundedBlockAllocator`` (the same in a ring: window layers) and
+``StateAllocator`` (a page a stream, snapshots for a prefix cache);
+``ClassAllocators`` is several of them side by side.
 
 A served model may instead keep a fixed-size STATE per stream (a
-retention layer's recurrent state: ``PagedKVCacheSpec.per_stream``).  The
-pool, the allocator, the hash chain and the LRU are the same; a block is
-then a PAGE — one stream's whole state, every layer — and a block table is
-one page wide.  A stream owns one page and rewrites it in place, so
+retention layer's recurrent state: ``StateAllocator``).  The pool, the
+hash chain and the LRU are the same; a block is then a PAGE — one stream's
+whole state, every layer — and a block table is one page wide.  A stream
+owns one page and rewrites it in place, so
 nothing can be shared by reference count: the prefix cache keeps SNAPSHOTS
 (a page frozen at a block boundary of a prompt, keyed by the chain hash
 there, retained LRU like a cached block), ``match_snapshot`` finds the
 longest boundary that has one, and admission COPIES it into the stream's
-own page (``copy_pages``: page to page, never a select over the pool).
-Which boundaries are worth a page is ``BlockAllocator.snapshot_boundary``.
+own page (``copy_pages``).  Which boundaries are worth a page is
+``StateAllocator.snapshot_boundary``.
 
 A served model may also keep its layers in several CLASSES, each with its
 own pools, block table, free list, reference counts and prefix index (one
@@ -126,7 +135,7 @@ class PagedKVCacheSpec:
     # A tile is one STREAM's state of a layer, of fixed size (a page; see
     # the module docstring), not ``block_size`` tokens' rows; and what a
     # token of this model would keep a layer as K/V rows, in bytes — the
-    # yardstick of ``BlockAllocator.snapshot_boundary``.
+    # yardstick of ``StateAllocator.snapshot_boundary``.
     per_stream: bool = False
     token_row_bytes: int = 0
     # The CLASS of cache layers this pool serves (module docstring): its
@@ -136,6 +145,14 @@ class PagedKVCacheSpec:
     name: str = ""
     reach: Optional[int] = None
     table_blocks: int = 0
+
+    def __post_init__(self):
+        if not self.num_blocks:
+            # Full provisioning: every slot's table full, so admission
+            # never blocks on HBM; a smaller pool oversubscribes and the
+            # admission gate accounts free blocks.
+            object.__setattr__(self, "num_blocks", self.num_slots
+                               * self.max_blocks_per_slot)
 
     @property
     def pool_tiles(self) -> Tuple[Tuple[str, Tuple[int, int, int]], ...]:
@@ -307,23 +324,31 @@ def paged_shardings(mesh: Mesh, names: Sequence[str] = ("k", "v")
     return {name: NamedSharding(mesh, spec) for name in names}
 
 
-def copy_pages(pool: jax.Array, src: jax.Array, dst: jax.Array
-               ) -> jax.Array:
-    """Copy page ``src[g]`` to page ``dst[g]`` of every group ``g`` (all
-    layers), page to page: a slice read and a ``dynamic_update_slice``
-    into the donated pool, whatever the pool's size.  pool ``[L, G, B,
-    ...]``; src / dst ``[G]`` int32, a group with ``dst < 0`` copies
-    nothing (its page ``src`` onto itself)."""
-    L, G = pool.shape[:2]
-    tile = pool.shape[3:]
-    zeros = (0,) * len(tile)
-    for g in range(G):
-        s_ = jnp.maximum(src[g], 0)
-        d_ = jnp.where(dst[g] >= 0, dst[g], s_)     # nothing: onto itself
-        page = lax.dynamic_slice(pool, (0, g, s_) + zeros,
-                                 (L, 1, 1) + tile)
-        pool = lax.dynamic_update_slice(pool, page, (0, g, d_) + zeros)
-    return pool
+def copy_pages(pool: jax.Array, src: jax.Array, dst: jax.Array,
+               mesh: Optional[Mesh] = None) -> jax.Array:
+    """Copy block (page) ``src[g]`` to block ``dst[g]`` of every group
+    ``g`` (all layers), block to block: a slice read and a
+    ``dynamic_update_slice`` into the donated pool, whatever the pool's
+    size and whatever a block's tile holds.  pool ``[L, G, B, ...]``;
+    src / dst ``[G]`` int32, a group with ``dst < 0`` copies nothing (its
+    block ``src`` onto itself).  Over a ``mesh`` every shard copies within
+    its own groups (``shard_map``, as the write and the attend run)."""
+    def local(pool, src, dst):
+        L, G = pool.shape[:2]
+        tile = pool.shape[3:]
+        zeros = (0,) * len(tile)
+        for g in range(G):
+            s_ = jnp.maximum(src[g], 0)
+            d_ = jnp.where(dst[g] >= 0, dst[g], s_)  # nothing: onto itself
+            page = lax.dynamic_slice(pool, (0, g, s_) + zeros,
+                                     (L, 1, 1) + tile)
+            pool = lax.dynamic_update_slice(pool, page, (0, g, d_) + zeros)
+        return pool
+
+    pool_spec = paged_attn_ops._pool_spec
+    return paged_attn_ops._on_mesh(
+        local, mesh, lambda dpn, mpn: (pool_spec(dpn, mpn), P(dpn), P(dpn)),
+        pool_spec)(pool, src, dst)
 
 
 def init_paged_cache(spec: PagedKVCacheSpec,
@@ -429,31 +454,6 @@ def paged_attend(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                       pool_v)
 
 
-def copy_block_onehots(spec: PagedKVCacheSpec, group: int, src: int,
-                       dst: int) -> Tuple[np.ndarray, np.ndarray]:
-    """Host-built [G, B] one-hots selecting the copy-on-write source and
-    destination blocks (local ids within ``group``)."""
-    G, B = spec.num_groups, spec.blocks_per_group
-    s = np.zeros((G, B), np.float32)
-    d = np.zeros((G, B), bool)
-    s[group, src] = 1.0
-    d[group, dst] = True
-    return s, d
-
-
-def paged_copy_block(pool: jax.Array, src_onehot: jax.Array,
-                     dst_onehot: jax.Array) -> jax.Array:
-    """Copy one block's rows to another block of the SAME group, for
-    every layer at once: the device half of copy-on-write. pool:
-    [L, G, B, nH, bs/f, f*D] (as held; a block's tile is copied whole);
-    src_onehot [G, B] f32; dst_onehot [G, B]
-    bool. Groups with all-zero one-hots pass through untouched."""
-    src = jnp.einsum("gb,lgbntd->lgntd", src_onehot.astype(pool.dtype),
-                     pool)
-    return jnp.where(dst_onehot[None, :, :, None, None, None],
-                     src[:, :, None], pool)
-
-
 # --------------------------------------------------------------------- #
 # Host-side block allocator: free lists, refcounts, prefix cache, CoW
 # --------------------------------------------------------------------- #
@@ -480,7 +480,8 @@ class PoolExhausted(RuntimeError):
 
 
 class BlockAllocator:
-    """Host-authoritative state of the block pool.
+    """Host-authoritative state of a pool of blocks of tokens' rows — and
+    the interface of every policy (module docstring).
 
     Per group (dp shard): a free list, per-block refcounts, and the
     prefix cache — a chain-hash index over full PROMPT blocks plus an
@@ -497,6 +498,10 @@ class BlockAllocator:
     preempt-and-recompute, and it never corrupts a running request —
     the tradeoff docs/tutorials/inference.md spells out.
     """
+
+    # (program name, profiler scope) of the device copy a plan's
+    # ``cow_src -> cow_dst`` asks for: here a copy-on-write fork.
+    copy_program = ("copy_block", "cow_copy")
 
     def __init__(self, spec: PagedKVCacheSpec):
         self.spec = spec
@@ -516,8 +521,6 @@ class BlockAllocator:
         # Cumulative telemetry the aggregator snapshots.
         self.cow_copies = 0
         self.reclaimed = 0
-        self.snapshots_taken = 0        # per-stream pools only
-        self.snapshot_hits = 0
         # Blocks live streams gave back before their release: a bounded
         # class's (``BoundedBlockAllocator``); nothing else returns any.
         self.returned = 0
@@ -540,10 +543,7 @@ class BlockAllocator:
     def need_blocks(self, prompt_len: int, max_new: int,
                     spec_k: int = 0) -> int:
         """Worst-case logical blocks a request spans (capped at the
-        table width): one page of a per-stream pool, whatever the
-        lengths."""
-        if self.spec.per_stream:
-            return 1
+        table width)."""
         tokens = prompt_len + max_new + spec_k
         need = -(-tokens // self.spec.block_size)
         return min(need, self.spec.max_blocks_per_slot)
@@ -576,10 +576,7 @@ class BlockAllocator:
 
     def matched_blocks(self, group: int, prompt: np.ndarray) -> int:
         """Full blocks of ``prompt`` the prefix cache of this group
-        covers: the cached chain's length, or (per-stream pools) the
-        longest boundary that has a snapshot."""
-        if self.spec.per_stream:
-            return self.match_snapshot(group, prompt)[0]
+        covers: the cached chain's length."""
         return len(self.match_prefix(group, prompt)[0])
 
     def match_limit(self, group: int, hashes: Sequence[int], n: int) -> int:
@@ -592,48 +589,12 @@ class BlockAllocator:
             m += 1
         return m
 
-    def match_snapshot(self, group: int, prompt: np.ndarray
-                       ) -> Tuple[int, Optional[int], int]:
-        """Per-stream pools: the LONGEST block boundary of ``prompt``
-        that has a snapshot and leaves at least the last token to
-        prefill -> (blocks it covers, its page or None, the chain hash at
-        the prompt's last full block).  A state is valid at ONE position,
-        so the walk goes on past boundaries that have none."""
-        bs = self.spec.block_size
-        idx = self._hash_index[group]
-        best, page, h = 0, None, 0
-        for j in range(len(prompt) // bs):
-            h = chain_hash(h, prompt[j * bs:(j + 1) * bs])
-            b = idx.get(h)
-            if b is not None and (j + 1) * bs <= len(prompt) - 1:
-                best, page = j + 1, b
-        return best, page, h
-
-    def snapshot_boundary(self, prompt_len: int, resumed: int) -> int:
-        """THE RULE of which boundaries get a page: the prompt's last
-        full block, when the tokens it adds beyond the snapshot it
-        resumed from would fill at least a page as K/V rows of this model
-        (``PagedKVCacheSpec.page_tokens``) — below that the page is
-        dearer than what it saves.  So a document served once leaves a
-        snapshot; a short question over it does not, and cannot push a
-        document out.  Returns the boundary in tokens, or 0."""
-        boundary = prompt_len // self.spec.block_size * self.spec.block_size
-        return boundary if boundary - resumed >= self.spec.page_tokens else 0
-
     def can_admit(self, group: int, prompt: np.ndarray, max_new: int,
-                  spec_k: int = 0, share: bool = True,
-                  limit: Optional[int] = None) -> bool:
+                  spec_k: int = 0, limit: Optional[int] = None) -> bool:
         """``limit``: the prefix match is cut to that many blocks (what a
         model's classes agreed on, ``ClassAllocators``)."""
         need = self.need_blocks(len(prompt), max_new, spec_k)
-        if self.spec.per_stream:
-            # The stream's own page, drawn while the snapshot it resumes
-            # from (if retained) is held out of reach.
-            page = self.match_snapshot(group, prompt)[1] if share else None
-            return self.available(group) - int(
-                page is not None and page in self._lru[group]) >= need
-        matched = self.match_prefix(group, prompt, limit)[0] if share \
-            else []
+        matched = self.match_prefix(group, prompt, limit)[0]
         # Only LIVE shared blocks are a free ride; reviving an
         # LRU-retained block consumes reclaimable capacity like any
         # fresh allocation does.
@@ -683,34 +644,31 @@ class BlockAllocator:
                 self._free[group].append(b)
 
     # ---- request lifecycle ---- #
-    def admit_prompt(self, slot: int, group: int, prompt: np.ndarray,
-                     max_new: int, spec_k: int = 0,
-                     share: bool = True,
-                     limit: Optional[int] = None) -> "AdmitPlan":
-        """Allocate/share the prompt's blocks and book the request's
-        worst-case reservation. Returns the plan the engine prefills
-        from. Raises PoolExhausted when ``can_admit`` would be False.
-        ``share=False`` (the whole-prompt prefill path, which rewrites
-        every position) opts out of the prefix cache entirely — no
-        matching, no registration.  ``limit``: see ``can_admit``."""
-        if not self.can_admit(group, prompt, max_new, spec_k,
-                              share=share, limit=limit):
+    def _gate(self, group: int, prompt: np.ndarray, max_new: int,
+              spec_k: int, limit: Optional[int]) -> None:
+        if not self.can_admit(group, prompt, max_new, spec_k, limit=limit):
             raise PoolExhausted(
                 f"group {group}: {self.available(group)} block(s) "
                 f"available < worst-case need for a "
                 f"{len(prompt)}+{max_new}-token request")
-        if self.spec.per_stream:
-            return self._admit_stream(slot, group, prompt, share)
+
+    def admit_prompt(self, slot: int, group: int, prompt: np.ndarray,
+                     max_new: int, spec_k: int = 0,
+                     limit: Optional[int] = None) -> "AdmitPlan":
+        """Allocate/share the prompt's blocks and book the request's
+        worst-case reservation. Returns the plan the engine prefills
+        from. Raises PoolExhausted when ``can_admit`` would be False.
+        ``limit``: see ``can_admit``."""
+        self._gate(group, prompt, max_new, spec_k, limit)
         bs = self.spec.block_size
         plen = len(prompt)
-        matched_blocks, hashes = self.match_prefix(group, prompt, limit) \
-            if share else ([], [])
+        matched_blocks, hashes = self.match_prefix(group, prompt, limit)
         # Always re-prefill at least the prompt's last token: its
         # logits seed the first sampled token, and the block holding it
         # must be privately writable for the decode appends that follow.
         matched = min(len(matched_blocks) * bs, plen - 1)
         n_keep = matched // bs                   # fully shared blocks
-        cow_src: Optional[int] = None
+        cow_src = cow_dst = None
         for b in matched_blocks[:n_keep]:
             self._incref(group, b)
         table: List[int] = list(matched_blocks[:n_keep])
@@ -718,8 +676,8 @@ class BlockAllocator:
             # The chain covered the whole prompt; the final shared block
             # must be written (re-prefilled last token + decode appends)
             # → fork it copy-on-write into a private block.
-            cow_src = matched_blocks[n_keep]
-            table.append(self._draw(group, slot))
+            cow_src, cow_dst = matched_blocks[n_keep], self._draw(group, slot)
+            table.append(cow_dst)
             self.cow_copies += 1
         # Private blocks for the unshared prompt tail.
         while len(table) * bs < plen:
@@ -736,19 +694,14 @@ class BlockAllocator:
         # it holds exactly the cached chain's tokens until then; keep it
         # out of the index so the cached original stays authoritative).
         h = hashes[n_keep - 1] if n_keep else 0
-        for j in range(n_keep, plen // bs) if share else ():
-            if cow_src is not None and j == n_keep:
-                h = chain_hash(h, prompt[j * bs:(j + 1) * bs])
-                continue
+        for j in range(n_keep, plen // bs):
             h = chain_hash(h, prompt[j * bs:(j + 1) * bs])
             b = table[j]
-            if h not in self._hash_index[group]:
+            if b != cow_dst and h not in self._hash_index[group]:
                 self._hash_index[group][h] = b
                 self._block_hash[group][b] = h
         return AdmitPlan(slot=slot, group=group, table=table,
-                         matched=matched, cow_src=cow_src,
-                         cow_dst=table[n_keep] if cow_src is not None
-                         else None)
+                         matched=matched, cow_src=cow_src, cow_dst=cow_dst)
 
     def extend(self, slot: int, row: np.ndarray, first_pos: int,
                upto_pos: int) -> None:
@@ -756,8 +709,6 @@ class BlockAllocator:
         program whose queries span positions ``[first_pos, upto_pos]``:
         draw the blocks its new rows need — the per-iteration HBM growth
         the hbm_bytes_per_token metric tracks."""
-        if self.spec.per_stream:
-            return
         j = int((row != DEAD_BLOCK).sum())
         while j <= min(upto_pos // self.spec.block_size,
                        self.table_width - 1):
@@ -775,60 +726,24 @@ class BlockAllocator:
             "live": self.blocks_in_use(), "returned": self.returned,
             "reclaimed": self.reclaimed}}
 
-    def _admit_stream(self, slot: int, group: int, prompt: np.ndarray,
-                      share: bool) -> "AdmitPlan":
-        """``admit_prompt`` for a per-stream pool: the stream's own page;
-        the snapshot to copy into it first (``cow_src`` -> ``cow_dst``:
-        the same device copy a copy-on-write fork asks for) and the
-        position prefill resumes at; and, where ``snapshot_boundary``
-        says so and a page can be had, the page that will hold this
-        prompt's own snapshot (``snapshot_at``, ``snapshot_page``: the
-        engine freezes the state there when prefill reaches it and THEN
-        enters it into the prefix cache, ``commit_snapshot``; until then
-        the page is out of every list and nothing can match it)."""
-        bs = self.spec.block_size
-        n, src, h_last = self.match_snapshot(group, prompt) \
-            if share else (0, None, 0)
-        if src is not None:
-            self._incref(group, src)            # out of the LRU's reach
-        own = self._draw(group, slot)
-        at = self.snapshot_boundary(len(prompt), n * bs) if share else 0
-        snap = None
-        if at and h_last not in self._hash_index[group] \
-                and self.available(group) > 0:
-            snap = self._pop_block(group)
-        if src is not None:
-            self._decref(group, src)            # back, most recently used
-            self.snapshot_hits += 1
-        self._slot_reserved[slot] = 0
-        self._slot_group[slot] = group
-        return AdmitPlan(slot=slot, group=group, table=[own],
-                         matched=n * bs, cow_src=src,
-                         cow_dst=own if src is not None else None,
-                         snapshot_at=at if snap is not None else 0,
-                         snapshot_page=snap, snapshot_hash=h_last)
+    def span_args(self, plans: Optional[Sequence["AdmitPlan"]] = None,
+                  live: int = 0) -> Dict[str, int]:
+        """What this KIND of cache adds to the engine's spans (as
+        ``class_stats`` does for named classes): to ``prefill`` for the
+        admissions ``plans``, else to ``decode`` over ``live`` streams.
+        Pages of rows add nothing."""
+        return {}
+
+    def snapshot_totals(self) -> Dict[str, int]:
+        """Running totals for ``snapshot()["state"]``; none here."""
+        return {}
 
     def commit_snapshot(self, plan: "AdmitPlan") -> None:
-        """The engine has frozen ``plan``'s state into its snapshot page
-        (the copy is dispatched): key the page by the chain hash of its
-        boundary and retain it, most recently used."""
-        g, page, h = plan.group, plan.snapshot_page, plan.snapshot_hash
-        if h in self._hash_index[g]:            # another admission's is in
-            self._free[g].append(page)
-            return
-        self._hash_index[g][h] = page
-        self._block_hash[g][page] = h
-        self._lru[g][page] = None
-        self.snapshots_taken += 1
+        """The engine has frozen ``plan``'s state at its boundary: only a
+        policy that keeps snapshots has anything to do."""
 
     def abandon_snapshot(self, plan: "AdmitPlan") -> None:
-        """Prefill failed: a snapshot page that was never committed goes
-        back to the free list (a committed one holds a whole state and
-        stays)."""
-        g, page = plan.group, plan.snapshot_page
-        if page is not None \
-                and self._block_hash[g].get(page) != plan.snapshot_hash:
-            self._free[g].append(page)
+        """``plan``'s prefill failed (the engine tells every policy)."""
 
     def alloc_block(self, slot: int) -> int:
         """Lazily allocate one more block for a live slot (a decode or
@@ -879,6 +794,150 @@ class AdmitPlan:
     # from its cache}.
     shared_blocks: int = 0
     cached_by_class: Optional[Dict[str, int]] = None
+
+
+class StateAllocator(BlockAllocator):
+    """The allocator of a ``per_stream`` pool (module docstring): a block
+    is a PAGE, a stream owns one and rewrites it in place, and the prefix
+    cache keeps SNAPSHOTS — a page frozen at a block boundary of a prompt,
+    keyed by the chain hash there, retained LRU like a cached block."""
+
+    # a snapshot into a stream's own page at admission, a stream's page
+    # into a snapshot when prefill reaches its boundary
+    copy_program = ("state_copy", "state_copy")
+
+    def __init__(self, spec: PagedKVCacheSpec):
+        super().__init__(spec)
+        self.snapshots_taken = 0
+        self.snapshot_hits = 0
+
+    def need_blocks(self, prompt_len: int, max_new: int,
+                    spec_k: int = 0) -> int:
+        """One page, whatever the lengths."""
+        return 1
+
+    def extend(self, slot: int, row: np.ndarray, first_pos: int,
+               upto_pos: int) -> None:
+        """A stream's page is all it ever holds."""
+
+    # ---- the prefix cache: snapshots ---- #
+    def matched_blocks(self, group: int, prompt: np.ndarray) -> int:
+        """The longest boundary of ``prompt`` that has a snapshot."""
+        return self.match_snapshot(group, prompt)[0]
+
+    def match_snapshot(self, group: int, prompt: np.ndarray
+                       ) -> Tuple[int, Optional[int], int]:
+        """The LONGEST block boundary of ``prompt`` that has a snapshot
+        and leaves at least the last token to prefill -> (blocks it
+        covers, its page or None, the chain hash at the prompt's last
+        full block).  A state is valid at ONE position, so the walk goes
+        on past boundaries that have none."""
+        bs = self.spec.block_size
+        idx = self._hash_index[group]
+        best, page, h = 0, None, 0
+        for j in range(len(prompt) // bs):
+            h = chain_hash(h, prompt[j * bs:(j + 1) * bs])
+            b = idx.get(h)
+            if b is not None and (j + 1) * bs <= len(prompt) - 1:
+                best, page = j + 1, b
+        return best, page, h
+
+    def snapshot_boundary(self, prompt_len: int, resumed: int) -> int:
+        """THE RULE of which boundaries get a page: the prompt's last
+        full block, when the tokens it adds beyond the snapshot it
+        resumed from would fill at least a page as K/V rows of this model
+        (``PagedKVCacheSpec.page_tokens``) — below that the page is
+        dearer than what it saves.  So a document served once leaves a
+        snapshot; a short question over it does not, and cannot push a
+        document out.  Returns the boundary in tokens, or 0."""
+        boundary = prompt_len // self.spec.block_size * self.spec.block_size
+        return boundary if boundary - resumed >= self.spec.page_tokens else 0
+
+    # ---- request lifecycle ---- #
+    def can_admit(self, group: int, prompt: np.ndarray, max_new: int,
+                  spec_k: int = 0, limit: Optional[int] = None) -> bool:
+        """The stream's own page, drawn while the snapshot it resumes
+        from (if retained) is held out of reach."""
+        page = self.match_snapshot(group, prompt)[1]
+        return self.available(group) - int(
+            page is not None and page in self._lru[group]) >= 1
+
+    def admit_prompt(self, slot: int, group: int, prompt: np.ndarray,
+                     max_new: int, spec_k: int = 0,
+                     limit: Optional[int] = None) -> "AdmitPlan":
+        """The stream's own page; the snapshot to copy into it first
+        (``cow_src`` -> ``cow_dst``) and the position prefill resumes at;
+        and, where ``snapshot_boundary`` says so and a page can be had,
+        the page that will hold this prompt's own snapshot
+        (``snapshot_at``, ``snapshot_page``: the engine freezes the state
+        there when prefill reaches it and THEN enters it into the prefix
+        cache, ``commit_snapshot``; until then the page is out of every
+        list and nothing can match it)."""
+        self._gate(group, prompt, max_new, spec_k, limit)
+        bs = self.spec.block_size
+        n, src, h_last = self.match_snapshot(group, prompt)
+        if src is not None:
+            self._incref(group, src)            # out of the LRU's reach
+        own = self._draw(group, slot)
+        at = self.snapshot_boundary(len(prompt), n * bs)
+        snap = None
+        if at and h_last not in self._hash_index[group] \
+                and self.available(group) > 0:
+            snap = self._pop_block(group)
+        if src is not None:
+            self._decref(group, src)            # back, most recently used
+            self.snapshot_hits += 1
+        self._slot_reserved[slot] = 0
+        self._slot_group[slot] = group
+        return AdmitPlan(slot=slot, group=group, table=[own],
+                         matched=n * bs, cow_src=src,
+                         cow_dst=own if src is not None else None,
+                         snapshot_at=at if snap is not None else 0,
+                         snapshot_page=snap, snapshot_hash=h_last)
+
+    def commit_snapshot(self, plan: "AdmitPlan") -> None:
+        """The engine has frozen ``plan``'s state into its snapshot page
+        (the copy is dispatched): key the page by the chain hash of its
+        boundary and retain it, most recently used."""
+        g, page, h = plan.group, plan.snapshot_page, plan.snapshot_hash
+        if h in self._hash_index[g]:            # another admission's is in
+            self._free[g].append(page)
+            return
+        self._hash_index[g][h] = page
+        self._block_hash[g][page] = h
+        self._lru[g][page] = None
+        self.snapshots_taken += 1
+
+    def abandon_snapshot(self, plan: "AdmitPlan") -> None:
+        """Prefill failed: a snapshot page that was never committed goes
+        back to the free list (a committed one holds a whole state and
+        stays)."""
+        g, page = plan.group, plan.snapshot_page
+        if page is not None \
+                and self._block_hash[g].get(page) != plan.snapshot_hash:
+            self._free[g].append(page)
+
+    # ---- what the spans say of a state ---- #
+    def span_args(self, plans: Optional[Sequence["AdmitPlan"]] = None,
+                  live: int = 0) -> Dict[str, int]:
+        """``prefill``: tokens resumed from a snapshot (what
+        ``cached_tokens`` means here), snapshots the admissions took, and
+        the bytes their page copies moved (read + written); ``decode``:
+        the pages its streams rewrite."""
+        if plans is None:
+            return {"state_pages_live": int(live)}
+        return {
+            "resumed_tokens": sum(int(p.matched) for p in plans),
+            "snapshot_taken": sum(p.snapshot_page is not None
+                                  for p in plans),
+            "state_copy_bytes": 2 * self.spec.block_nbytes() * sum(
+                (p.cow_src is not None) + (p.snapshot_page is not None)
+                for p in plans)}
+
+    def snapshot_totals(self) -> Dict[str, int]:
+        return {"snapshots_taken": self.snapshots_taken,
+                "snapshot_hits": self.snapshot_hits,
+                "snapshots_evicted": self.reclaimed}
 
 
 class BoundedBlockAllocator(BlockAllocator):
@@ -937,13 +996,11 @@ class BoundedBlockAllocator(BlockAllocator):
                 return m
         return 0
 
-    def _match(self, group: int, prompt: np.ndarray, share: bool,
+    def _match(self, group: int, prompt: np.ndarray,
                limit: Optional[int]):
         """(blocks matched n, the cached blocks a stream resuming at
         ``n * block_size`` shares: logical ``[first block in reach, n)``,
         the prompt's chain hashes)."""
-        if not share:
-            return 0, [], []
         bs = self.spec.block_size
         hashes = chain_hashes(prompt, bs)
         n = self.match_limit(group, hashes, min(
@@ -954,13 +1011,12 @@ class BoundedBlockAllocator(BlockAllocator):
 
     # ---- request lifecycle ---- #
     def can_admit(self, group: int, prompt: np.ndarray, max_new: int,
-                  spec_k: int = 0, share: bool = True,
-                  limit: Optional[int] = None) -> bool:
+                  spec_k: int = 0, limit: Optional[int] = None) -> bool:
         return self.available(group) >= self.need_blocks(
             len(prompt), max_new, spec_k)
 
     def admit_prompt(self, slot: int, group: int, prompt: np.ndarray,
-                     max_new: int, spec_k: int = 0, share: bool = True,
+                     max_new: int, spec_k: int = 0,
                      limit: Optional[int] = None) -> "AdmitPlan":
         """The ring holds the cached blocks in reach of the resume point
         and nothing else yet (``extend`` draws a program's blocks when it
@@ -976,7 +1032,7 @@ class BoundedBlockAllocator(BlockAllocator):
                 f"{self.spec.name!r} uncommitted < the {need} a "
                 f"{len(prompt)}+{max_new}-token request may hold at once")
         bs, J = self.spec.block_size, self.table_width
-        n, shared, hashes = self._match(group, prompt, share, limit)
+        n, shared, hashes = self._match(group, prompt, limit)
         lo = n - len(shared)
         row = [DEAD_BLOCK] * J
         for j, b in zip(range(lo, n), shared):
@@ -993,8 +1049,7 @@ class BoundedBlockAllocator(BlockAllocator):
         full = len(prompt) // bs
         tail = self.spec.first_block((len(prompt) - 1) // bs * bs)
         self._slot_hashes[slot] = {
-            j: hashes[j] for j in range(max(n, tail), full)} \
-            if share else {}
+            j: hashes[j] for j in range(max(n, tail), full)}
         return AdmitPlan(slot=slot, group=group, table=row,
                          matched=n * bs, shared_blocks=len(shared))
 
@@ -1042,10 +1097,9 @@ class ClassAllocators:
     match stops short of the block that holds the prompt's last token."""
 
     def __init__(self, specs: Sequence[PagedKVCacheSpec]):
-        self.classes = [
-            (BlockAllocator if s.reach is None else BoundedBlockAllocator)(s)
-            for s in specs]
+        self.classes = [_policy(s)(s) for s in specs]
         self.spec = specs[0]
+        self.copy_program = self.classes[0].copy_program
         self.columns, at = [], 0
         for a in self.classes:
             self.columns.append(slice(at, at + a.table_width))
@@ -1067,11 +1121,20 @@ class ClassAllocators:
     def reclaimed(self) -> int:
         return sum(a.reclaimed for a in self.classes)
 
-    def class_stats(self) -> Dict[str, Dict[str, int]]:
-        out: Dict[str, Dict[str, int]] = {}
+    def _merged(self, method: str, *args, **kw) -> Dict[str, Any]:
+        out: Dict[str, Any] = {}
         for a in self.classes:
-            out.update(a.class_stats())
+            out.update(getattr(a, method)(*args, **kw))
         return out
+
+    def class_stats(self) -> Dict[str, Dict[str, int]]:
+        return self._merged("class_stats")
+
+    def span_args(self, plans=None, live: int = 0) -> Dict[str, int]:
+        return self._merged("span_args", plans, live)
+
+    def snapshot_totals(self) -> Dict[str, int]:
+        return self._merged("snapshot_totals")
 
     # ---- the prefix cache ---- #
     def _agreed(self, group: int, prompt: np.ndarray) -> int:
@@ -1088,23 +1151,22 @@ class ClassAllocators:
         return self._agreed(group, prompt)
 
     def can_admit(self, group: int, prompt: np.ndarray, max_new: int,
-                  spec_k: int = 0, share: bool = True) -> bool:
-        n = self._agreed(group, prompt) if share else 0
-        return all(a.can_admit(group, prompt, max_new, spec_k, share=share,
-                               limit=n) for a in self.classes)
+                  spec_k: int = 0) -> bool:
+        n = self._agreed(group, prompt)
+        return all(a.can_admit(group, prompt, max_new, spec_k, limit=n)
+                   for a in self.classes)
 
     # ---- request lifecycle ---- #
     def admit_prompt(self, slot: int, group: int, prompt: np.ndarray,
-                     max_new: int, spec_k: int = 0,
-                     share: bool = True) -> AdmitPlan:
-        n = self._agreed(group, prompt) if share else 0
+                     max_new: int, spec_k: int = 0) -> AdmitPlan:
+        n = self._agreed(group, prompt)
         row = np.full(self.table_width, DEAD_BLOCK, np.int32)
         cached: Dict[str, int] = {}
         done = []
         try:
             for a, cols in zip(self.classes, self.columns):
                 plan = a.admit_prompt(slot, group, prompt, max_new, spec_k,
-                                      share=share, limit=n)
+                                      limit=n)
                 done.append((a, cols))
                 row[cols][:len(plan.table)] = plan.table
                 assert plan.matched == n * self.spec.block_size \
@@ -1129,6 +1191,60 @@ class ClassAllocators:
         for a, cols in zip(self.classes, self.columns):
             a.release(slot, table[cols])
 
+    def commit_snapshot(self, plan: AdmitPlan) -> None:
+        for a in self.classes:
+            a.commit_snapshot(plan)
+
+    def abandon_snapshot(self, plan: AdmitPlan) -> None:
+        for a in self.classes:
+            a.abandon_snapshot(plan)
+
+
+def class_specs(classes, asked, rows: int, **geometry
+                ) -> Tuple[PagedKVCacheSpec, ...]:
+    """One spec a CLASS of a model's cache layers (``served.CacheClass``:
+    name, layers, reach, per_stream) over the engine's ``geometry`` (the
+    spec's other fields).  ``asked``: ``inference.num_blocks``, an int or
+    {class name: blocks} (0 or a class left out: full provisioning);
+    ``rows``: the most query rows of a stream one program holds — a
+    bounded class's table is a ring as wide as they reach."""
+    table = geometry["max_len"] // geometry["block_size"]
+    return tuple(PagedKVCacheSpec(
+        num_layers=cls.layers, name=cls.name, reach=cls.reach,
+        per_stream=cls.per_stream,
+        num_blocks=int(asked.get(cls.name, 0) if isinstance(asked, dict)
+                       else asked),
+        table_blocks=0 if cls.reach is None else min(
+            table, (cls.reach + rows - 2) // geometry["block_size"] + 2),
+        **geometry) for cls in classes)
+
+
+def _policy(spec: PagedKVCacheSpec) -> type:
+    """The allocator class of a pool, from what its spec declares."""
+    if spec.per_stream:
+        return StateAllocator
+    return BlockAllocator if spec.reach is None else BoundedBlockAllocator
+
+
+def allocator_for(specs: Sequence[PagedKVCacheSpec], spec_k: int = 0):
+    """The cache manager of a model's classes of cache layers: the bare
+    policy of a model's only class (a ring is always behind the
+    composite: its admission is the composite's), else
+    ``ClassAllocators`` over one each.  ``spec_k``: the engine's
+    speculation depth, refused where a class cannot drop rejected rows."""
+    per_stream = [s for s in specs if s.per_stream]
+    if per_stream and spec_k > 0:
+        raise ValueError(
+            "inference.spec_k > 0 needs a cache that can drop rejected "
+            "rows; this model keeps a state per stream")
+    if per_stream and len(specs) > 1:
+        raise NotImplementedError(
+            "a per-stream class beside other classes: the prefix rule "
+            "across kinds is not written (ROADMAP M12)")
+    if len(specs) == 1 and specs[0].reach is None:
+        return _policy(specs[0])(specs[0])
+    return ClassAllocators(specs)
+
 
 __all__ = ["DEAD_BLOCK", "PagedKVCacheSpec", "paged_partition_spec",
            "paged_shardings", "init_paged_cache", "kv_fold",
@@ -1136,6 +1252,6 @@ __all__ = ["DEAD_BLOCK", "PagedKVCacheSpec", "paged_partition_spec",
            "paged_layer_view", "copy_pages",
            "positions_to_blocks",
            "block_select", "paged_write_rows", "paged_attend",
-           "copy_block_onehots", "paged_copy_block", "chain_hash",
-           "chain_hashes", "PoolExhausted", "BlockAllocator", "AdmitPlan",
-           "BoundedBlockAllocator", "ClassAllocators"]
+           "chain_hash", "chain_hashes", "PoolExhausted", "BlockAllocator",
+           "AdmitPlan", "StateAllocator", "BoundedBlockAllocator",
+           "ClassAllocators", "class_specs", "allocator_for"]

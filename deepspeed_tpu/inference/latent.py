@@ -43,13 +43,14 @@ import numpy as np
 from jax import lax
 
 from . import kv_cache
-from .decode import NEG_INF, _group_shape, _write_targets
-from .served import ServedModel, register
+from .served import (NEG_INF, ServedModel, group_shape, register,
+                     write_targets)
 from ..models import deepseek_v3 as dsv3
 from ..models import hyper_connections as hyper
 from ..models.deepseek_v3 import DeepseekV3Config
 from ..moe import share
 from ..ops import latent_attention as latent_ops
+from ..ops.paged_attention import attend_cold_steps
 
 _EXPERT_KEYS = ("w_gate", "w_up", "w_down")
 
@@ -101,7 +102,7 @@ def _forward(params, pool, x, bt_g, pos_g, live, cfg: DeepseekV3Config,
         sel = kv_cache.block_select(bt_g, pool.shape[2])
         grid = lax.broadcasted_iota(jnp.int32, (1, 1, 1, J * bs), 3)
         pos_mask = grid <= reach[..., None]
-    blk, off = _write_targets(bt_g, pos_g, bs)
+    blk, off = write_targets(bt_g, pos_g, bs)
     blk = jnp.where(live_g.reshape(G, Sg * K), blk, kv_cache.DEAD_BLOCK)
 
     # The residual path.  One stream: a sublayer reads x and adds to it.
@@ -281,7 +282,8 @@ class LatentServed(ServedModel):
         return (self.cfg.num_attention_heads, self.cfg.latent_width,
                 self.cfg.kv_lora_rank)
 
-    def attend_step_counts(self, live_blocks, *, K, spec, mp, q_itemsize):
+    def attend_step_counts(self, live_blocks, *, K, spec, mp, q_itemsize,
+                           calls=1):
         kt = latent_ops.row_tokens(K)
         tiles = -(-K // kt)
         slots, _ = latent_ops.slots_a_step(
@@ -290,7 +292,8 @@ class LatentServed(ServedModel):
             self.cfg.kv_lora_rank, int(jnp.dtype(spec.dtype).itemsize))
         groups = -(-np.asarray(live_blocks, np.int64) // slots)
         return (int(np.maximum(groups, 1).sum()) * tiles,
-                int(groups.sum()) * tiles)
+                int(groups.sum()) * tiles,
+                attend_cold_steps(live_blocks, calls=calls))
 
     def counter_args(self, rows) -> Dict[str, Any]:
         """Of the executions fetched: routed pairs that landed on held
@@ -323,8 +326,8 @@ class LatentServed(ServedModel):
         live = jnp.broadcast_to(block_tables[:, :1] >= 0, tokens.shape)
         x, pool, counters = _forward(
             params, pools[0], _embed(params, tokens, cfg),
-            _group_shape(block_tables, num_groups),
-            _group_shape(pos, num_groups), live, cfg, paged_kernel, mesh)
+            group_shape(block_tables, num_groups),
+            group_shape(pos, num_groups), live, cfg, paged_kernel, mesh)
         return _head(params, x, cfg), (pool,), counters
 
     def decode(self, params, pools, tokens, lengths, block_tables, *,

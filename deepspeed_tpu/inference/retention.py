@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .served import ServedModel, register
+from .served import CacheClass, ServedModel, register
 from ..models import brumby
 from ..models.brumby import BrumbyConfig
 from ..ops import power_retention as pr
@@ -112,7 +112,6 @@ def _embed(params, tokens, cfg):
 
 class RetentionServed(ServedModel):
     """See the module docstring."""
-    cache_per_stream = True
     cache_dtype = jnp.float32       # the state and its normaliser
 
     @property
@@ -126,6 +125,11 @@ class RetentionServed(ServedModel):
     @property
     def cache_layers(self) -> int:
         return int(self.cfg.num_hidden_layers)
+
+    @property
+    def cache_classes(self) -> Tuple[CacheClass, ...]:
+        """One class, a fixed-size state a stream (a page, not rows)."""
+        return (CacheClass("", self.cache_layers, per_stream=True),)
 
     @property
     def cache_heads(self) -> int:
@@ -156,11 +160,13 @@ class RetentionServed(ServedModel):
                                    + cfg.num_key_value_heads)
         return flops, 2 * cfg.num_key_value_heads * F * (D + 1) * 4
 
-    def attend_step_counts(self, live_blocks, *, K, spec, mp, q_itemsize):
+    def attend_step_counts(self, live_blocks, *, K, spec, mp, q_itemsize,
+                           calls=1):
+        """The state update's steps; none cold: no attend walks a state."""
         live = np.asarray(live_blocks)
         return pr.state_update_steps(int((live > 0).sum()), live.size,
                                      self.cfg.num_key_value_heads,
-                                     self.cfg.head_dim)
+                                     self.cfg.head_dim) + (0,)
 
     # -- programs ------------------------------------------------------ #
     def verify(self, params, pools, tokens, lengths, block_tables, *,

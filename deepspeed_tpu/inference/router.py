@@ -198,9 +198,8 @@ class ReplicaRouter:
                 queues[i].append(req)
             stepped = False
             for i, eng in enumerate(self.engines):
-                # 2. per-replica admissions (FCFS within the replica) —
-                # batched one-slot-per-group under chunked prefill.
-                batched = eng.prefill_chunk > 0
+                # 2. per-replica admissions (FCFS within the replica),
+                # in one-slot-per-group batches.
                 while queues[i]:
                     batch = []
                     used: set = set()
@@ -208,7 +207,7 @@ class ReplicaRouter:
                         req = queues[i][0]
                         slot = eng.select_slot(
                             req.prompt, req.max_new_tokens,
-                            exclude_groups=used if batched else None)
+                            exclude_groups=used)
                         if slot is None:
                             # Genuine head-of-queue rejection only when
                             # no batch exclusions could explain it.
@@ -231,22 +230,13 @@ class ReplicaRouter:
                         req.t_admit = time.perf_counter()
                         used.add(eng.group_of(slot))
                         batch.append((req, slot))
-                        if not batched:
-                            break
                     if not batch:
                         break
                     # The engine opens the ``prefill`` host span itself.
-                    if batched:
-                        results = eng.prefill_many(
-                            [(slot, req.prompt, req.max_new_tokens)
-                             for req, slot in batch],
-                            self.temperature,
-                            rids=[req.rid for req, _ in batch])
-                    else:
-                        results = [eng.prefill(
-                            req.prompt, slot, self.temperature,
-                            max_new_tokens=req.max_new_tokens,
-                            rid=req.rid) for req, slot in batch]
+                    results = eng.prefill_many(
+                        [(slot, req.prompt, req.max_new_tokens)
+                         for req, slot in batch], self.temperature,
+                        rids=[req.rid for req, _ in batch])
                     # the clock of the timeline the first tokens join
                     t_now = eng.serving.clock()
                     for (req, slot), (tok, _) in zip(batch, results):

@@ -426,70 +426,48 @@ class ContinuousBatchingScheduler:
             # head of the queue cannot be admitted (no slot, or the
             # block pool cannot cover its worst case), everything
             # behind it waits; pool exhaustion rejects admission here
-            # and NEVER touches a live slot. Chunked prefill admits in
+            # and NEVER touches a live slot. Admission is in
             # one-slot-per-group BATCHES (engine.prefill_many): a full
             # batch prefills G admissions for one admission's wall.
-            batched = eng.prefill_chunk > 0
             admitting = bool(queue)
             if admitting:
                 agg.lap("other_s")
             while queue:
-                if batched:
-                    with tel.span("admit", queued=len(queue),
-                                  late_ms=late_ms) as span:
-                        batch = []
-                        used: set = set()
-                        rejected = ""
-                        while queue:
-                            req = queue[0]
-                            slot = eng.select_slot(
-                                req.prompt, req.max_new_tokens,
-                                exclude_groups=used)
-                            if slot is None:
-                                # Only a rejection with NO exclusions is
-                                # the gate refusing the head (with
-                                # exclusions it may just be this batch's
-                                # one-slot-per-group shape).
-                                if not used:
-                                    rejected = self._reject(req,
-                                                            len(queue))
-                                break
-                            queue.popleft()
-                            req.t_admit = clock()
-                            used.add(eng.group_of(slot))
-                            batch.append((req, slot))
-                        rids = [req.rid for req, _ in batch]
-                        span.set_metadata(admitted=len(batch),
-                                          rejected=rejected,
-                                          rids=ids_arg(rids))
-                    if not batch:
-                        break
-                    results = eng.prefill_many(
-                        [(slot, req.prompt, req.max_new_tokens)
-                         for req, slot in batch], self.temperature,
-                        rids=rids)
-                    t_now = clock()
-                    for (req, slot), (tok, _) in zip(batch, results):
-                        self._activate(req, slot, tok, t_now, active)
-                    continue
-                req = queue[0]
                 with tel.span("admit", queued=len(queue),
                               late_ms=late_ms) as span:
+                    batch = []
+                    used: set = set()
                     rejected = ""
-                    slot = eng.select_slot(req.prompt,
-                                           req.max_new_tokens)
-                    if slot is None:
-                        rejected = self._reject(req, len(queue))
-                    span.set_metadata(admitted=int(slot is not None),
-                                      rejected=rejected, rids=str(req.rid))
-                if slot is None:
+                    while queue:
+                        req = queue[0]
+                        slot = eng.select_slot(
+                            req.prompt, req.max_new_tokens,
+                            exclude_groups=used)
+                        if slot is None:
+                            # Only a rejection with NO exclusions is the
+                            # gate refusing the head (with exclusions it
+                            # may just be this batch's one-slot-per-group
+                            # shape).
+                            if not used:
+                                rejected = self._reject(req, len(queue))
+                            break
+                        queue.popleft()
+                        req.t_admit = clock()
+                        used.add(eng.group_of(slot))
+                        batch.append((req, slot))
+                    rids = [req.rid for req, _ in batch]
+                    span.set_metadata(admitted=len(batch),
+                                      rejected=rejected,
+                                      rids=ids_arg(rids))
+                if not batch:
                     break
-                queue.popleft()
-                req.t_admit = clock()
-                tok, _ = eng.prefill(
-                    req.prompt, slot, self.temperature,
-                    max_new_tokens=req.max_new_tokens, rid=req.rid)
-                self._activate(req, slot, tok, clock(), active)
+                results = eng.prefill_many(
+                    [(slot, req.prompt, req.max_new_tokens)
+                     for req, slot in batch], self.temperature,
+                    rids=rids)
+                t_now = clock()
+                for (req, slot), (tok, _) in zip(batch, results):
+                    self._activate(req, slot, tok, t_now, active)
             if admitting:
                 agg.lap("admit_s")
             # 3. one decode (or draft-then-verify) iteration for every

@@ -14,15 +14,15 @@ ask a ``ServedModel`` for:
   token** (the default: a block is ``block_size`` tokens' rows of a
   layer; GPT-2 keeps two, ``k`` and ``v``, per-head rows; the
   latent-attention family one, ``latent``, a ``[ckv | k_rope]`` row
-  shared by every head) or **per stream** (``cache_per_stream``: a block
-  is a PAGE, one stream's fixed-size state of a layer — the retention
+  shared by every head) or **per stream** (``CacheClass.per_stream``: a
+  block is a PAGE, one stream's fixed-size state of a layer — the retention
   family's ``state`` and ``norm`` — a block table is one page wide, and
   the prefix cache keeps snapshots: ``inference/kv_cache.py``);
 - **its programs**, each a pure function over ``(params, pools, ...)``
   that writes the new rows into the pools in place and returns ``(logits,
   pools)`` (and the model's counters, see below): ``decode`` (one token a
   slot), ``verify`` (K tokens a slot, speculative), ``prefill_chunk`` (one
-  chunk of one slot a group), ``prefill_full`` (a whole padded prompt);
+  chunk of one slot a group);
 - **the cache's cost a token** for the engine's analytic counters:
   ``cache_cost(keys, ...)`` = (FLOPs, cache bytes) a layer spends on one
   query token, which MAY depend on the ``keys`` rows in reach (an attend:
@@ -43,27 +43,31 @@ from __future__ import annotations
 import importlib
 from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
+import numpy as np
+
+from . import kv_cache
 
 _IMPLEMENTATIONS: Dict[type, Callable[[Any], "ServedModel"]] = {}
 
 
 class CacheClass(NamedTuple):
     """A class of a model's cache layers: its name ("" for a model's only
-    class), how many layers it holds, and how many tokens back a query of
-    these layers reads, itself included (None: all of them)."""
+    class), how many layers it holds, how many tokens back a query of
+    these layers reads, itself included (None: all of them), and whether
+    ``cache_pools`` declares a stream's whole state of a layer, of fixed
+    size (a page), not a block of tokens' rows."""
     name: str
     layers: int
     reach: Optional[int] = None
+    per_stream: bool = False
 
 
 class ServedModel:
     """Base of the implementations; see the module docstring."""
     cfg: Any
     counter_names: Tuple[str, ...] = ()
-    # ``cache_pools`` declares a stream's whole state of a layer, of fixed
-    # size (a page), not a block of tokens' rows.
-    cache_per_stream: bool = False
     # What the pools hold where that is not ``inference.kv_cache_dtype``'s
     # to choose (a recurrent state is fp32 whatever the rows' dtype).
     cache_dtype: Any = None
@@ -159,9 +163,11 @@ class ServedModel:
         return per_block * int(keys) * int(itemsize) // block_size
 
     def attend_step_counts(self, live_blocks, *, K: int, spec, mp: int,
-                           q_itemsize: int) -> Tuple[int, int]:
-        """(steps, live steps) the attend kernel sequences for one layer
-        (host integers)."""
+                           q_itemsize: int, calls: int = 1
+                           ) -> Tuple[int, int, int]:
+        """(steps, live steps, live steps nothing started: cold) the
+        attend kernel sequences for one layer, in ``calls`` calls (the
+        shards of a dp mesh), host integers."""
         raise NotImplementedError
 
     def counter_args(self, rows) -> Dict[str, Any]:
@@ -184,12 +190,6 @@ class ServedModel:
                       start, last_idx, active, *, paged_kernel: bool,
                       mesh=None):
         raise NotImplementedError
-
-    def prefill_full(self, params, pools: Sequence, tokens, bt_rows,
-                     last_idx, *, attention_fn=None, mesh=None):
-        raise NotImplementedError(
-            f"{type(self).__name__} has no whole-prompt prefill: set "
-            "inference.prefill_chunk > 0")
 
 
 def register(config_type: type,
@@ -230,5 +230,70 @@ def with_counters(sampled, counters):
                             jnp.stack(list(counters)).astype(jnp.int32)])
 
 
+# --------------------------------------------------------------------- #
+# What every implementation's programs share
+# --------------------------------------------------------------------- #
+# Same masking constant as dense_attention. A NumPy scalar: a jnp one
+# would initialise a JAX backend (and take the chip) at import.
+NEG_INF = np.float32(-1e9)
+
+
+def group_shape(arr: jax.Array, num_groups: int) -> jax.Array:
+    """[S, ...] → [G, S/G, ...]: split the slot axis into (group,
+    slot-in-group) — a local reshape under the slots-over-dp sharding."""
+    return arr.reshape((num_groups, arr.shape[0] // num_groups)
+                       + arr.shape[1:])
+
+
+def write_targets(bt_g: jax.Array, pos_g: jax.Array, block_size: int
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """(block, offset) of every new row: bt_g [G, Sg, J], pos_g
+    [G, Sg, K] -> two [G, Sg*K]."""
+    G, Sg, K = pos_g.shape
+    bt_rows = jnp.broadcast_to(bt_g[:, :, None, :],
+                               (G, Sg, K, bt_g.shape[-1]))
+    blk, off = kv_cache.positions_to_blocks(bt_rows, pos_g, block_size)
+    return blk.reshape(G, Sg * K), off.reshape(G, Sg * K)
+
+
+# Sampling (in-graph; PRNG threaded by the engine per iteration)
+@jax.named_scope("sample")
+def sample_tokens(logits: jax.Array, key: jax.Array,
+                  temperature: jax.Array) -> jax.Array:
+    """Greedy (temperature == 0) or temperature sampling; logits
+    [..., V] fp32. Temperature is a TRACED scalar so changing it never
+    recompiles; both branches are cheap relative to the step, so a
+    select beats a cond."""
+    greedy = jnp.argmax(logits, axis=-1)
+    t = jnp.maximum(temperature.astype(jnp.float32), 1e-6)
+    sampled = jax.random.categorical(key, logits / t, axis=-1)
+    return jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
+
+
+@jax.named_scope("sample")
+def spec_accept(logits: jax.Array, tokens: jax.Array, key: jax.Array,
+                temperature: jax.Array) -> jax.Array:
+    """In-graph draft acceptance: the longest agreeing prefix rule.
+
+    logits: [S, K, V] from the verify step over [last, d_1..d_{K-1}];
+    tokens: the [S, K] verify input. Greedy target g[s,i] =
+    argmax(logits[s,i]); draft d_i is accepted iff every d_{i'<=i}
+    matched g at its position, and the emitted stream is g[s, :m+1]
+    (accepted drafts ARE the greedy tokens, plus the first correction /
+    bonus) — which is exactly what non-speculative greedy decode would
+    have produced token by token. Returns [S, K+1] int32: column 0 is
+    n_new (how many of the following tokens are real), columns 1..K the
+    emitted tokens — one array, ONE host fetch per iteration.
+    """
+    S, K = tokens.shape
+    g = sample_tokens(logits, key, temperature)          # [S, K]
+    match = (tokens[:, 1:] == g[:, :-1]).astype(jnp.int32)   # [S, K-1]
+    acc = jnp.cumprod(match, axis=-1).sum(-1) if K > 1 else \
+        jnp.zeros((S,), jnp.int32)
+    n_new = (acc + 1).astype(jnp.int32)                  # [S]
+    return jnp.concatenate([n_new[:, None], g], axis=-1)
+
+
 __all__ = ["CacheClass", "ServedModel", "register", "served_model",
-           "split_counters", "with_counters"]
+           "split_counters", "with_counters", "NEG_INF", "group_shape",
+           "write_targets", "sample_tokens", "spec_accept"]

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple, Union
+from typing import Any, ClassVar, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +33,7 @@ class GPT2Config(TransformerConfig):
     pre_layer_norm: bool = True
     max_seq_length: int = 1024
     vocab_size: int = 50304            # padded to a multiple of 128 for MXU tiling
+    serving_module: ClassVar[str] = "deepspeed_tpu.inference.decode"
 
     @property
     def name(self) -> str:

@@ -359,21 +359,16 @@ class ServingAggregator:
                 tot, n = self._model_counters.get(name, (0.0, 0))
                 self._model_counters[name] = (tot + float(value), n + 1)
 
-    def note_state(self, *, resumed_tokens: int, state_copy_bytes: int,
-                   snapshots_taken: int, snapshot_hits: int,
-                   snapshots_evicted: int) -> None:
+    def note_state(self, admitted: Dict[str, int],
+                   totals: Dict[str, int]) -> None:
         """One admission batch into a per-stream state pool (the
         ``prefill`` span's ``resumed_tokens`` / ``state_copy_bytes``,
         summed) and the allocator's running totals of snapshots taken /
         hit / evicted."""
         st = self._state
-        st["resumed_tokens"] = st.get("resumed_tokens", 0) \
-            + int(resumed_tokens)
-        st["state_copy_bytes"] = st.get("state_copy_bytes", 0) \
-            + int(state_copy_bytes)
-        st.update(snapshots_taken=int(snapshots_taken),
-                  snapshot_hits=int(snapshot_hits),
-                  snapshots_evicted=int(snapshots_evicted))
+        for name in ("resumed_tokens", "state_copy_bytes"):
+            st[name] = st.get(name, 0) + int(admitted[name])
+        st.update(totals)
 
     def note_cache_classes(self, stats: Dict[str, Dict[str, int]]) -> None:
         """A model's classes of cache layers, by name: blocks, blocks in

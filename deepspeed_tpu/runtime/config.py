@@ -462,11 +462,11 @@ class InferenceConfig:
             raise DeepSpeedConfigError(
                 f"{C.INFERENCE}.{C.INFERENCE_QUANTIZE} must be one of "
                 f"{C.INFERENCE_QUANTIZE_MODES}, got {self.quantize!r}")
-        if not isinstance(self.prefill_chunk, int) or self.prefill_chunk < 0:
+        if not isinstance(self.prefill_chunk, int) or self.prefill_chunk <= 0:
             raise DeepSpeedConfigError(
                 f"{C.INFERENCE}.{C.INFERENCE_PREFILL_CHUNK} must be a "
-                f"non-negative int (0 = whole-prompt prefill), got "
-                f"{self.prefill_chunk!r}")
+                f"positive int (chunked prefill is the only admission "
+                f"path), got {self.prefill_chunk!r}")
         if not isinstance(self.block_size, int) or self.block_size <= 0:
             raise DeepSpeedConfigError(
                 f"{C.INFERENCE}.{C.INFERENCE_BLOCK_SIZE} must be a "

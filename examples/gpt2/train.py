@@ -70,7 +70,7 @@ def main():
         # Byte-level LM on real text: every UTF-8 byte is a token
         # (vocab 256 fits every config), windowed into [N, S+1] rows of
         # next-byte prediction. The reference for "the examples train
-        # on REAL data", closing VERDICT.md's synthetic-tokens gap.
+        # on REAL data" (they once trained on synthetic tokens only).
         raw = np.frombuffer(open(args.data, "rb").read(), dtype=np.uint8)
         n_rows = len(raw) // (S + 1)
         assert n_rows >= bs, f"corpus too small: {len(raw)} bytes"

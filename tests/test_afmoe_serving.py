@@ -42,6 +42,7 @@ from deepspeed_tpu.inference import InferenceEngine, kv_cache  # noqa: E402
 from deepspeed_tpu.inference import afmoe as afmoe_serving      # noqa: E402
 from deepspeed_tpu.inference.kv_cache import (                  # noqa: E402
     DEAD_BLOCK, BlockAllocator, ClassAllocators, PagedKVCacheSpec,
+    allocator_for,
     PoolExhausted)
 from deepspeed_tpu.models.afmoe import (                        # noqa: E402
     FULL, SLIDING, AfmoeConfig, afmoe_init)
@@ -114,7 +115,8 @@ def test_layer_types_follow_the_published_rule():
     assert cfg.group == 8 and cfg.routing.held == (0, 128)
     assert cfg.routing.n_group == cfg.routing.topk_group == 1
     served = afmoe_serving.AfmoeServed(cfg)
-    assert served.cache_classes == (("full", 1, None), ("window", 4, 2048))
+    assert [tuple(c) for c in served.cache_classes] == [
+        ("full", 1, None, False), ("window", 4, 2048, False)]
     assert served.cache_pools(64) == (("k", (4, 64, 128)),
                                       ("v", (4, 64, 128)))
     with pytest.raises(ValueError):
@@ -554,7 +556,8 @@ def test_one_class_is_the_allocator_it_was():
     """A model with one unbounded class gets the plain allocator: the same
     tables, copy-on-write and ``alloc_block`` as before."""
     spec = _spec("", None, 16)
-    alloc = BlockAllocator(spec)
+    alloc = allocator_for([spec])
+    assert type(alloc) is BlockAllocator
     assert alloc.table_width == 16 and alloc.class_stats() == {}
     doc = tokens(3, 8)
     a = alloc.admit_prompt(0, 0, doc, 2)

@@ -259,15 +259,11 @@ class TestInferenceConfig:
         {"max_seq_len": -1},
         {"quantize": "fp4"}, {"quantize": True},
         {"prefill_chunk": -8}, {"prefill_chunk": "auto"},
+        {"prefill_chunk": 0},
     ])
     def test_invalid_values_raise(self, bad):
         with pytest.raises(DeepSpeedConfigError):
             make_cfg({"train_batch_size": 8, "inference": bad})
-
-    def test_chunk_zero_is_whole_prompt(self):
-        cfg = make_cfg({"train_batch_size": 8,
-                        "inference": {"prefill_chunk": 0}})
-        assert cfg.inference_config.prefill_chunk == 0
 
 
 class TestOptimizerScheduler:

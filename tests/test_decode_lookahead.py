@@ -5,12 +5,17 @@ by n's tokens where they lie on the device, before the host reads n's
 tokens.
 
 What has to hold, for every served family (GPT-2's paged K/V, the latent
-family, the retention family's state a stream): each request gets, token
+family, the retention family's state a stream, the two classes of window
+and full-attention layers): each request gets, token
 for token, what a loop of SYNCHRONOUS ``decode_once()`` calls gives it on
 a second engine with the same weights — through admissions into freed
 slots, an EOS found one iteration late (its extra row dropped and
 counted), a serve cut with an iteration in flight, and a dp = 2 mesh —
 and ``serve()`` never returns with anything in flight or held.
+
+The same engines (one a family) also show that the engine asks its cache
+manager no kind: an allocator out of the one factory, a miss and a prefix
+hit, and a prefill that fails with its own cause, whatever the family.
 """
 import dataclasses
 
@@ -19,13 +24,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.inference import InferenceEngine
+from deepspeed_tpu.inference import InferenceEngine, kv_cache
+from deepspeed_tpu.inference import engine as engine_mod
 from deepspeed_tpu.inference.scheduler import Request
 from deepspeed_tpu.parallel.topology import build_mesh
 
 
 # ------------------------------------------------------------------ #
-# The three families at toy sizes
+# The four families at toy sizes
 # ------------------------------------------------------------------ #
 def _gpt2():
     from deepspeed_tpu.models.gpt2 import GPT2_CONFIGS, gpt2_init
@@ -55,7 +61,17 @@ def _retention():
         "block_size": 8, "num_blocks": 8}
 
 
-FAMILIES = {"gpt2": _gpt2, "latent": _latent, "retention": _retention}
+def _two_class():
+    from deepspeed_tpu.models.afmoe import afmoe_init
+    from test_afmoe_serving import tiny
+    cfg = tiny()
+    return cfg, afmoe_init(jax.random.PRNGKey(0), cfg), 128, {
+        "max_slots": 4, "max_seq_len": 128, "prefill_chunk": 8,
+        "block_size": 4, "num_blocks": {"full": 64, "window": 40}}
+
+
+FAMILIES = {"gpt2": _gpt2, "latent": _latent, "retention": _retention,
+            "two_class": _two_class}
 _BUILT = {}
 
 
@@ -285,3 +301,96 @@ def test_the_bare_call_is_synchronous():
     finally:
         eng.decode_discard()
         eng.release_slot(slot)
+
+
+# ------------------------------------------------------------------ #
+# The engine asks its cache manager no kind
+# ------------------------------------------------------------------ #
+def _document(eng, vocab, seed):
+    """A prompt long enough to be worth caching whatever the family keeps
+    (a state's snapshot wants a page's worth of tokens), with room left
+    for a question and a reply."""
+    rng = np.random.default_rng(seed)
+    return rng.integers(0, vocab, size=min(100, eng.max_len - 24),
+                        dtype=np.int32)
+
+
+def _through(eng, prompt):
+    """(first token, its logits, the admission's detail) of ``prompt``
+    through the engine's one admission path, the slot given back."""
+    slot = eng.select_slot(prompt, 4)
+    tok, logits = eng.prefill(prompt, slot, return_logits=True,
+                              max_new_tokens=4)
+    info = dict(eng.last_admit_info(slot))
+    eng.release_slot(slot)
+    return tok, logits, info
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_engine_asks_no_cache_kind(family):
+    """Pages, a ring beside pages, a state: the engine builds each from
+    the one factory and admits a miss and a prefix hit through the same
+    calls; the hit's logits are a cold engine's."""
+    eng, ref, vocab = _engines(family, 1)
+    made = kv_cache.allocator_for(eng.cache_specs, eng.spec_k)
+    assert type(eng.allocator) is type(made)
+    assert [type(a) for a in getattr(eng.allocator, "classes", [])] \
+        == [type(a) for a in getattr(made, "classes", [])]
+    free0 = _free(eng)
+    doc = _document(eng, vocab, seed=11)
+    question = np.concatenate([doc, _document(eng, vocab, seed=12)[:5]])
+    assert eng.prefix_match_tokens(question) == 0
+    _, _, info = _through(eng, doc)
+    assert info["cached_tokens"] == 0 and info["chunks"] > 1
+    cached = eng.prefix_match_tokens(question)
+    assert 0 < cached <= len(doc)
+    tok, logits, info = _through(eng, question)
+    assert info["cached_tokens"] == cached
+    cold_tok, cold, info = _through(ref, question)
+    assert info["cached_tokens"] == 0 and tok == cold_tok
+    np.testing.assert_allclose(logits, cold, atol=2e-4)
+    _left_clean(eng, free0)
+    _left_clean(ref, _free(ref))
+
+
+def test_the_engines_source_names_no_cache_kind():
+    import inspect
+    source = inspect.getsource(engine_mod)
+    for word in ("per_stream", "BlockAllocator", "BoundedBlockAllocator",
+                 "StateAllocator", "ClassAllocators"):
+        assert word not in source, word
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_a_failed_prefill_reraises_its_cause(family):
+    """A chunk program that raises surfaces as what it raised, whatever
+    the cache manager (every one takes ``abandon_snapshot``); a page set
+    aside for a snapshot that was never taken is back in its free list;
+    and the engine serves on."""
+    eng, ref, vocab = _engines(family, 1)
+    free0 = _free(eng)
+    doc = _document(eng, vocab, seed=21)
+    real, calls = eng._prefill_fn, []
+
+    def failing(*args):
+        calls.append(1)
+        if len(calls) == 2:
+            raise RuntimeError("a bad chunk")
+        return real(*args)
+    eng._prefill_fn = failing
+    slot = eng.select_slot(doc, 4)
+    try:
+        with pytest.raises(RuntimeError, match="a bad chunk"):
+            eng.prefill(doc, slot, max_new_tokens=4)
+    finally:
+        eng._prefill_fn = real
+        eng.release_slot(slot)
+    _left_clean(eng, free0)
+    # (a page pool's index keeps the failed prompt's blocks, as it always
+    # has: ROADMAP; an unrelated prompt is served as a cold engine would)
+    other = _document(eng, vocab, seed=22)
+    tok, logits, _ = _through(eng, other)
+    cold_tok, cold, _ = _through(ref, other)
+    assert tok == cold_tok
+    np.testing.assert_allclose(logits, cold, atol=2e-4)
+    _left_clean(eng, free0)

@@ -13,7 +13,7 @@ the reference's func tests compare full loss curves).
 The gpt2 flagship configs (ZeRO-2, ZeRO-Offload, 1-bit Adam, 1F1B
 pipeline) train on REAL text — byte-level LM over the vendored
 license-clean corpus (examples/data/corpus.txt, see its README) — with
-loss-curve gates, closing VERDICT.md's top gap (every e2e example used
+loss-curve gates, closing an early review's top gap (every e2e example used
 to train on synthetic random tokens). A byte-level model starts at the
 ln(256) ~= 5.5 uniform floor and must cut into genuine English
 statistics to pass.

@@ -144,22 +144,18 @@ class TestDecodeParity:
         engine.release_slot(1)
         engine.release_slot(5)
 
-    def test_whole_prompt_prefill_matches(self, params32):
-        """prefill_chunk: 0 — the single-shot long-context path."""
-        eng = InferenceEngine(CFG32, params32, config={
-            "inference": {"max_slots": 8, "max_seq_len": 32,
-                          "prefill_chunk": 0}})
-        prompt = _prompt(9, seed=7)
-        tok, logits = eng.prefill(prompt, slot=2, return_logits=True)
-        np.testing.assert_allclose(logits,
-                                   _ref_last_logits(params32, prompt),
-                                   atol=1e-4)
-        eng.activate_slot(2, len(prompt), tok)
-        seq = list(prompt) + [tok]
-        sampled, lg = eng.decode_once(return_logits=True)
-        np.testing.assert_allclose(lg[2], _ref_last_logits(params32, seq),
-                                   atol=1e-4)
-        eng.close()
+    def test_prefill_chunk_zero_is_refused(self, params32):
+        """Chunked prefill is the only admission path: ``prefill_chunk:
+        0`` (once whole-prompt prefill) is refused by the config and so
+        by the engine, which parses it."""
+        from deepspeed_tpu.runtime.config import (DeepSpeedConfigError,
+                                                  InferenceConfig)
+        bad = {"inference": {"max_slots": 8, "max_seq_len": 32,
+                             "prefill_chunk": 0}}
+        with pytest.raises(DeepSpeedConfigError, match="positive int"):
+            InferenceConfig(bad)
+        with pytest.raises(DeepSpeedConfigError, match="only admission"):
+            InferenceEngine(CFG32, params32, config=bad)
 
     def test_temperature_sampling_reproducible(self, engine):
         """Threaded PRNG: temperature > 0 samples; the in-graph
@@ -271,7 +267,7 @@ class TestServingStream:
                 return jax.profiler.TraceAnnotation(*a, **k)
 
         class _FakeEngine:
-            max_slots, max_len, prefill_chunk = 2, 1000, 0
+            max_slots, max_len, prefill_chunk = 2, 1000, 8
             telemetry = _FakeTelemetry()
 
             def __init__(self):
@@ -279,12 +275,21 @@ class TestServingStream:
                 from deepspeed_tpu.monitor.serving import ServingAggregator
                 self.serving = ServingAggregator(2)
 
-            def select_slot(self, prompt, max_new_tokens=0):
+            def group_of(self, slot):
+                return 0                 # one dp group: one admission a batch
+
+            def select_slot(self, prompt, max_new_tokens=0,
+                            exclude_groups=None):
                 free = np.flatnonzero(~self.active)
-                return int(free[0]) if len(free) else None
+                return int(free[0]) if len(free) and not exclude_groups \
+                    else None
 
             def prefill(self, prompt, slot, temperature=0.0, **kw):
                 return 1, None
+
+            def prefill_many(self, admissions, temperature=0.0, rids=None):
+                return [self.prefill(prompt, slot, temperature)
+                        for slot, prompt, _ in admissions]
 
             def activate_slot(self, slot, n, tok):
                 self.active[slot] = True

@@ -291,17 +291,19 @@ def test_attend_step_counts_equal_a_count_by_hand():
     the one after the dead one start cold."""
     from types import SimpleNamespace
     from deepspeed_tpu.inference.served import served_model
-    from deepspeed_tpu.ops.paged_attention import attend_cold_steps
     served = served_model(DeepseekV3Config(held=(0, 16)))
     spec = SimpleNamespace(max_blocks_per_slot=272, block_size=64,
                            dtype=jnp.bfloat16)
     live = [200, 33, 32, 0, 1]
     count = lambda K: served.attend_step_counts(            # noqa: E731
         live, K=K, spec=spec, mp=1, q_itemsize=2)
-    assert count(1) == (12, 11)
+    assert count(1) == (12, 11, 2)
     # three verify tokens of 64 heads fill the MXU: groups of 16 slots
-    assert count(3) == (13 + 3 + 2 + 1 + 1, 13 + 3 + 2 + 1)
-    assert attend_cold_steps(live) == 2
+    assert count(3) == (13 + 3 + 2 + 1 + 1, 13 + 3 + 2 + 1, 2)
+    # as three calls (a dp mesh of three: 200 33 | 32 0 | 1 0) each
+    # call's first stream starts cold
+    assert served.attend_step_counts(
+        live + [0], K=1, spec=spec, mp=1, q_itemsize=2, calls=3)[2] == 3
 
 
 def test_absorbed_attend_equals_expanded_attend():

@@ -32,6 +32,7 @@ from deepspeed_tpu.inference import (InferenceEngine, NGramDrafter,
 from deepspeed_tpu.inference import kv_cache
 from deepspeed_tpu.models.gpt2 import GPT2_CONFIGS, gpt2_apply, gpt2_init
 from deepspeed_tpu.monitor.serving import ServingAggregator
+from deepspeed_tpu.parallel.topology import build_mesh
 
 CFG32 = dataclasses.replace(GPT2_CONFIGS["gpt2-tiny"], dtype=jnp.float32)
 CFG = GPT2_CONFIGS["gpt2-tiny"]
@@ -101,21 +102,47 @@ class TestPagedPrimitives:
         out[1, 0, :, 3] = 0
         assert (out == 0).all()
 
-    def test_copy_block_copies_one_group_only(self):
-        pool = jnp.arange(2 * 2 * 3 * 1 * 2 * 2, dtype=jnp.float32
-                          ).reshape(2, 2, 3, 1, 2, 2)  # [L,G,B,nH,bs,D]
-        spec = PagedKVCacheSpec(num_layers=2, num_slots=2, num_blocks=6,
-                                block_size=2, max_len=4, num_heads=1,
-                                head_dim=2, num_groups=2,
-                                dtype=jnp.float32)
-        src, dst = kv_cache.copy_block_onehots(spec, group=1, src=0,
-                                               dst=2)
-        out = np.array(kv_cache.paged_copy_block(pool, jnp.asarray(src),
-                                                 jnp.asarray(dst)))
-        ref = np.asarray(pool)
-        np.testing.assert_array_equal(out[:, 1, 2], ref[:, 1, 0])
-        out[:, 1, 2] = ref[:, 1, 2]
-        np.testing.assert_array_equal(out, ref)
+    @pytest.mark.parametrize("groups", [1, 2, "dp2"])
+    @pytest.mark.parametrize("layout", ["gpt2", "latent", "window_full",
+                                        "state"])
+    def test_copy_pages_equals_a_numpy_copy(self, layout, groups):
+        """The one device copy of every pool (a copy-on-write fork, a
+        snapshot into or out of a stream's page) over the four pool
+        layouts the cells hold — GPT-2's ``head_dim`` 64 folded into the
+        lanes, the latent row, the window + full pair (a pool a class,
+        block ids of its own), the fp32 state page and its normaliser —
+        with one group, two, and two over a dp mesh (every shard copies
+        within its own groups); the last group has nothing to copy."""
+        bf16, f32 = jnp.bfloat16, jnp.float32
+        pools = {   # name -> (blocks a group, one block's tile, dtype)
+            "gpt2": {"k": (5, (4, 8, 128), bf16), "v": (5, (4, 8, 128), bf16)},
+            "latent": {"latent": (6, (1, 16, 576), bf16)},
+            "window_full": {"k.full": (7, (2, 16, 128), bf16),
+                            "k.window": (4, (2, 16, 128), bf16)},
+            "state": {"state": (5, (2, 144, 16), f32),
+                      "norm": (5, (2, 16, 16), f32)}}[layout]
+        G = 1 if groups == 1 else 2
+        mesh = build_mesh(devices=jax.devices()[:2]) if groups == "dp2" \
+            else None
+        rng = np.random.default_rng(7)
+        for name, (B, tile, dtype) in pools.items():
+            before = rng.standard_normal((3, G, B) + tile).astype(np.float32)
+            pool = jnp.asarray(before, dtype)
+            before = np.asarray(pool.astype(f32))
+            src = np.array([1, B - 1][:G], np.int32)
+            dst = np.array([B - 2, -1][:G], np.int32)
+            if G == 1:
+                dst[:] = B - 2
+            if mesh is not None:
+                pool = jax.device_put(pool, kv_cache.paged_shardings(
+                    mesh, [name])[name])
+            out = jax.jit(lambda p, s, d: kv_cache.copy_pages(
+                p, s, d, mesh))(pool, src, dst)
+            assert out.dtype == dtype and out.shape == pool.shape
+            want = before.copy()
+            want[:, 0, B - 2] = before[:, 0, 1]
+            np.testing.assert_array_equal(
+                np.asarray(out.astype(f32)), want, err_msg=name)
 
     def test_spec_validation(self):
         with pytest.raises(ValueError, match="divide"):
@@ -191,11 +218,11 @@ def _numpy_write(pool, rows, layer, bt, pos, bs):
 
 def _device_write(pool_k, pool_v, rows_k, rows_v, layer, bt, pos, bs,
                   dtype=jnp.float32, mesh=None):
-    from deepspeed_tpu.inference.decode import _write_targets
+    from deepspeed_tpu.inference.served import write_targets
     D = pool_k.shape[-1]
 
     def step(kc, vc, rk, rv, bt, pos):
-        blk, off = _write_targets(bt, pos, bs)
+        blk, off = write_targets(bt, pos, bs)
         return kv_cache.paged_write_rows(kc, vc, rk, rv, layer, blk, off,
                                          mesh=mesh)
 
@@ -299,10 +326,10 @@ class TestInPlaceWrite:
         from deepspeed_tpu.parallel.topology import build_mesh
         mesh = build_mesh(dp=2, mp=2, devices=jax.devices()[:4])
         pk, pv, rk, rv, bt, pos = _write_case(kind, 64, G=2, nH=4, seed=7)
-        from deepspeed_tpu.inference.decode import _write_targets
+        from deepspeed_tpu.inference.served import write_targets
 
         def step(kc, vc, rk, rv, bt, pos):
-            blk, off = _write_targets(bt, pos, 16)
+            blk, off = write_targets(bt, pos, 16)
             return kv_cache.paged_write_rows(kc, vc, rk, rv, 1, blk, off,
                                              mesh=mesh)
 
@@ -510,20 +537,25 @@ class TestPagedParity:
         batched.close()
         seq.close()
 
-    def test_whole_prompt_prefill_paged(self, params32):
-        eng = _engine(params32, max_len=32, chunk=0, block_size=16)
-        prompt = _prompt(9, seed=10)
-        tok, logits = eng.prefill(prompt, slot=2, return_logits=True)
+    def test_prefill_is_prefill_many_of_one(self, params32):
+        """A prompt enters the cache one way: ``prefill`` is the
+        one-admission form of ``prefill_many`` (several chunks here),
+        and its logits are the full forward's."""
+        eng = _engine(params32, max_len=32, chunk=8, block_size=16)
+        prompt = _prompt(19, seed=10)
+        tok, logits = eng.prefill(prompt, slot=2, return_logits=True,
+                                  max_new_tokens=3)
         ref = np.asarray(gpt2_apply(
             params32, jnp.asarray(prompt)[None], CFG32))[0, -1]
         np.testing.assert_allclose(logits, ref, atol=1e-4)
-        eng.activate_slot(2, len(prompt), tok)
-        sampled, lg = eng.decode_once(return_logits=True)
-        ref2 = np.asarray(gpt2_apply(
-            params32, jnp.asarray(np.asarray(list(prompt) + [tok],
-                                             np.int32))[None],
-            CFG32))[0, -1]
-        np.testing.assert_allclose(lg[2], ref2, atol=1e-4)
+        assert eng.last_admit_info(2)["chunks"] == 3
+        eng.release_slot(2)
+        (tok2, logits2), = eng.prefill_many([(2, prompt, 3)],
+                                            return_logits=True)
+        assert tok2 == tok
+        # the second admission rides the first's cached block
+        assert eng.last_admit_info(2)["cached_tokens"] == 16
+        np.testing.assert_allclose(logits2, logits, atol=1e-5)
         eng.close()
 
 

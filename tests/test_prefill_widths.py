@@ -59,7 +59,7 @@ def no_programs_dir(monkeypatch):
     (384, 64, (192, 384)),
     (640, 64, (320, 640)),
     (640, 128, (640,)),             # 320 is no multiple of 128
-    (0, 16, ()),                    # whole-prompt prefill: no ladder
+    (2048, 64, (1024, 2048)),       # the first halving, whatever the chunk
 ])
 def test_the_ladder_from_the_shapes(chunk, block, want):
     assert engine_mod.prefill_widths(chunk, block) == want

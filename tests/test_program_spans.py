@@ -191,9 +191,8 @@ def serve_op_names(serve_engine):
         np.zeros((G, eng.prefill_chunk), np.int32),
         np.zeros((G, J), np.int32), np.zeros(G, np.int32),
         np.zeros(G, np.int32), np.ones(G, np.int32), key, temp)
-    B = eng.cache_spec.blocks_per_group
-    copy = _op_names(eng._copy_fn, k, v, np.zeros((G, B), np.float32),
-                     np.zeros((G, B), bool))
+    copy = _op_names(eng._copy_fn, k, v, np.zeros(G, np.int32),
+                     np.ones(G, np.int32))
     return {"decode": decode, "prefill": prefill, "copy": copy}
 
 

@@ -223,23 +223,6 @@ def test_state_update_steps_count_live_streams_only():
 
 
 # --------------------------------------------------------------------- #
-# 3. The page copy
-# --------------------------------------------------------------------- #
-def test_page_copy_leaves_source_and_every_other_page_unchanged():
-    rng = np.random.default_rng(3)
-    pool = jnp.asarray(rng.normal(size=(2, 2, 5, 2, 8, 16)), jnp.float32)
-    out = np.asarray(kv_cache.copy_pages(
-        pool, jnp.asarray([1, 4], jnp.int32), jnp.asarray([3, -1],
-                                                          jnp.int32)))
-    before = np.asarray(pool)
-    assert (out[:, 0, 3] == before[:, 0, 1]).all()
-    keep = np.ones((2, 5), bool)
-    keep[0, 3] = False
-    assert (out.transpose(1, 2, 0, 3, 4, 5)[keep]
-            == before.transpose(1, 2, 0, 3, 4, 5)[keep]).all()
-
-
-# --------------------------------------------------------------------- #
 # 4. Served logits against the reference
 # --------------------------------------------------------------------- #
 @pytest.mark.parametrize("kernel", [False, True])
@@ -423,7 +406,8 @@ def test_a_per_stream_pool_is_pages_one_a_stream():
     assert spec.max_blocks_per_slot == 1 and spec.page_tokens == 80
     assert spec.pool_shapes == {"state": (2, 1, 6, 2, 144, 16),
                                 "norm": (2, 1, 6, 2, 16, 16)}
-    alloc = kv_cache.BlockAllocator(spec)
+    alloc = kv_cache.allocator_for([spec])
+    assert type(alloc) is kv_cache.StateAllocator
     assert alloc.need_blocks(400, 100) == 1
     plan = alloc.admit_prompt(0, 0, tokens(0, 40), 100)
     assert len(plan.table) == 1 and plan.matched == 0
@@ -436,12 +420,12 @@ def test_a_per_stream_pool_is_pages_one_a_stream():
     (300, 0, 296), (80, 0, 80), (79, 0, 0), (87, 0, 80), (313, 296, 0),
     (400, 296, 400), (375, 296, 0)])
 def test_the_snapshot_rule(plen, resumed, at):
-    alloc = kv_cache.BlockAllocator(_spec())
+    alloc = kv_cache.allocator_for([_spec()])
     assert alloc.snapshot_boundary(plen, resumed) == at
 
 
 def test_a_document_leaves_a_snapshot_and_a_question_does_not():
-    alloc = kv_cache.BlockAllocator(_spec())
+    alloc = kv_cache.allocator_for([_spec()])
     doc = tokens(0, 300)
     plan = alloc.admit_prompt(0, 0, doc, 1)
     assert plan.snapshot_at == 296 and plan.snapshot_page is not None
@@ -467,7 +451,7 @@ def test_a_document_leaves_a_snapshot_and_a_question_does_not():
 
 
 def test_questions_never_evict_a_document_and_lru_evicts_the_oldest():
-    alloc = kv_cache.BlockAllocator(_spec(num_blocks=4))
+    alloc = kv_cache.allocator_for([_spec(num_blocks=4)])
     docs = [tokens(i, 120) for i in range(3)]
     pages = []
     for i, d in enumerate(docs[:2]):
@@ -506,7 +490,7 @@ def test_questions_never_evict_a_document_and_lru_evicts_the_oldest():
 
 
 def test_a_snapshot_whose_prefill_failed_is_never_matched(params):
-    alloc = kv_cache.BlockAllocator(_spec())
+    alloc = kv_cache.allocator_for([_spec()])
     doc = tokens(0, 300)
     plan = alloc.admit_prompt(0, 0, doc, 1)
     alloc.abandon_snapshot(plan)
@@ -546,7 +530,7 @@ def test_a_snapshot_whose_prefill_failed_is_never_matched(params):
 
 
 def test_a_snapshot_is_skipped_when_every_page_is_live():
-    alloc = kv_cache.BlockAllocator(_spec(num_blocks=2))
+    alloc = kv_cache.allocator_for([_spec(num_blocks=2)])
     first = alloc.admit_prompt(0, 0, tokens(0, 40), 5)
     plan = alloc.admit_prompt(1, 0, tokens(1, 200), 5)
     assert plan.snapshot_page is None and plan.snapshot_at == 0
@@ -558,7 +542,8 @@ def test_engine_refuses_speculation_and_verify_raises(params):
         engine(params, spec_k=2)
     from deepspeed_tpu.inference.served import served_model
     served = served_model(tiny())
-    assert served.cache_per_stream and served.token_row_bytes == 256
+    assert served.cache_classes == (("", 2, None, True),)
+    assert served.token_row_bytes == 256
     with pytest.raises(NotImplementedError):
         served.verify(None, None, None, None, None, num_groups=1,
                       paged_kernel=False)

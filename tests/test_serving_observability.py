@@ -504,19 +504,28 @@ class TestServingObservabilityStream:
                 return jax.profiler.TraceAnnotation(*a, **k)
 
         class _FakeEngine:
-            max_slots, max_len, prefill_chunk = 2, 1000, 0
+            max_slots, max_len, prefill_chunk = 2, 1000, 8
             telemetry = _FakeTel()
 
             def __init__(self):
                 self.active = np.zeros(2, bool)
                 self.serving = ServingAggregator(2)
 
-            def select_slot(self, prompt, max_new_tokens=0):
+            def group_of(self, slot):
+                return 0                 # one dp group: one admission a batch
+
+            def select_slot(self, prompt, max_new_tokens=0,
+                            exclude_groups=None):
                 free = np.flatnonzero(~self.active)
-                return int(free[0]) if len(free) else None
+                return int(free[0]) if len(free) and not exclude_groups \
+                    else None
 
             def prefill(self, prompt, slot, temperature=0.0, **kw):
                 return 1, None
+
+            def prefill_many(self, admissions, temperature=0.0, rids=None):
+                return [self.prefill(prompt, slot, temperature)
+                        for slot, prompt, _ in admissions]
 
             def activate_slot(self, slot, n, tok):
                 self.active[slot] = True

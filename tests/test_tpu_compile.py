@@ -519,6 +519,9 @@ def _serve_program(topo, program, head_dim):
             step = eng._build_decode_step()
             args = (params, pool, pool, i32(S), i32(S), fresh(S), i32(S),
                     i32(S, J), key, temp)
+        elif program == "copy_block":
+            step = eng._build_copy("copy_block", "cow_copy")
+            args = (pool, pool, i32(1), i32(1))
         else:
             step = eng._build_prefill_step()
             args = (params, pool, pool, i32(1, C), i32(1, J), i32(1),
@@ -580,6 +583,24 @@ def test_serve_step_updates_the_donated_pools_in_place(serve_programs,
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= spec.nbytes()
     assert mem.temp_size_in_bytes < 256 * 2 ** 20, mem.temp_size_in_bytes
+
+
+def test_the_block_copy_updates_one_block_in_place(serve_programs):
+    """A copy-on-write fork at the serve cell's shape is the page copy
+    every pool has: both pools aliased, a block's rows of scratch, ONE
+    in-place ``dynamic-update-slice`` a pool and no select or contraction
+    over the pool (what the one-hot copy was)."""
+    from deepspeed_tpu.analysis.hlo_text import ops_in_units_of
+    spec, _, compiled = serve_programs("copy_block", 64)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= spec.nbytes()
+    assert mem.temp_size_in_bytes <= 4 * spec.block_nbytes(), \
+        mem.temp_size_in_bytes
+    seen = ops_in_units_of(compiled.as_text(), math.prod(spec.shape[2:]))
+    found = [(op, n) for op, n in seen if op not in _POOL_OPS_ALLOWED
+             | {"dynamic-update-slice", "fusion"}]
+    assert not found, found
+    assert any(op == "dynamic-update-slice" for op, _ in seen)
 
 
 @_serve_cases
@@ -919,7 +940,7 @@ def retention_programs(topo):
         out["prefill_step"] = eng._build_prefill_step().lower(
             params, *pools, i32(1, C), i32(1, J), i32(1), i32(1), i32(1),
             key, temp).compile()
-        out["state_copy"] = eng._build_state_copy().lower(
+        out["state_copy"] = eng._build_copy("state_copy", "state_copy").lower(
             *pools, i32(1), i32(1)).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", prev)

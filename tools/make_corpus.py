@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Vendor a small license-clean REAL-TEXT corpus for the e2e examples.
 
-VERDICT.md's top gap: every end-to-end example trained on synthetic
+An early review's top gap: every end-to-end example trained on synthetic
 random tokens, so the loss-curve gates never saw real language. This
 script assembles a few hundred KB of genuine English prose from the
 RUNNING interpreter's standard-library documentation strings — text
