@@ -300,7 +300,9 @@ class InferenceEngine:
         kv_dtype = served.cache_dtype or resolve_kv_dtype(
             self.icfg.kv_cache_dtype, served.dtype)
         # One spec, pool set and allocator a CLASS of cache layers (one
-        # class for most models; kv_cache.py's docstring).
+        # class for most models; kv_cache.py's docstring), each built
+        # from the model's answer for THAT class: a state's page is not a
+        # K/V block's tile.
         classes = served.cache_classes
         asked = self.icfg.num_blocks
         if asked and isinstance(asked, dict) != (len(classes) > 1):
@@ -312,11 +314,9 @@ class InferenceEngine:
                    "one class of cache layers and takes an int"))
         specs = kv_cache.class_specs(
             classes, asked, rows=max(self.prefill_chunk, self.spec_k + 1),
+            of_class=lambda cls: served.class_geometry(cls, self.block_size),
             num_slots=self.max_slots, block_size=self.block_size,
-            max_len=self.max_len, num_heads=served.cache_heads,
-            head_dim=served.cache_row_width, num_groups=self.dp,
-            dtype=kv_dtype, pools=served.cache_pools(self.block_size),
-            token_row_bytes=served.token_row_bytes)
+            max_len=self.max_len, num_groups=self.dp, dtype=kv_dtype)
         self.allocator = kv_cache.allocator_for(specs, self.spec_k)
         self.cache_specs = specs
         self.cache_spec = specs[0]
@@ -470,6 +470,12 @@ class InferenceEngine:
         the builders run on engine shells that hold a config only)."""
         return self.__dict__.get("_served") or served_model(self.model_cfg)
 
+    @property
+    def _attend_specs(self) -> Tuple[kv_cache.PagedKVCacheSpec, ...]:
+        """The classes an attend walks: what the analytic attend counters
+        and ``context_tokens_in_reach`` price."""
+        return kv_cache.attended_specs(self.cache_specs)
+
     def _pools(self) -> Tuple[jax.Array, ...]:
         """The cache's pools in the served model's order."""
         return tuple(self.cache[name] for name in self._cache_sh)
@@ -566,14 +572,21 @@ class InferenceEngine:
         the donated pools — a copy-on-write fork of a block, a snapshot
         into a stream's own page at admission, a stream's page into a
         snapshot when prefill reaches its boundary. ``name`` and ``scope``
-        are the allocator's (``copy_program``)."""
+        are the allocator's (``copy_program``), and so are the pools it
+        copies in (``copy_pools``: of a model's classes the one that
+        copies; a block id means nothing in another class's pools), the
+        others passing through where they lie."""
         sh = tuple(self._cache_sh.values())
+        allocator = self.__dict__.get("allocator")   # (none: a shell)
+        mine = [allocator is None or name in allocator.copy_pools
+                for name in self._cache_sh]
 
         def copy(*args):
             pools, (src, dst) = args[:len(sh)], args[len(sh):]
             with jax.named_scope(scope):
                 return tuple(kv_cache.copy_pages(pool, src, dst, self.mesh)
-                             for pool in pools)
+                             if copied else pool
+                             for pool, copied in zip(pools, mine))
 
         copy.__name__ = name
         return jax.jit(copy, donate_argnums=tuple(range(len(sh))),
@@ -918,6 +931,10 @@ class InferenceEngine:
             if plan.cached_by_class:
                 self._last_admit[slot]["cached_by_class"] = dict(
                     plan.cached_by_class)
+            if plan.copy_class is not None:
+                self._last_admit[slot].update(
+                    snapshot_at=int(plan.snapshot_at),
+                    lost_to_kind_tokens=int(plan.lost_to_kind))
         return pools, plans, tails
 
     def _run_prefill_chunks(self, pools, plans, tails, temp):
@@ -964,7 +981,7 @@ class InferenceEngine:
                     if ci == len(chunks) - 1:
                         held[slot] = (ci, group)
                     if ci == snap_after:
-                        snaps[group] = (plan.table[0], plan.snapshot_page)
+                        snaps[group] = (plan.page, plan.snapshot_page)
                         frozen.append(plan)
                 with self.telemetry.span("prefill_chunk", ci=ci,
                                          active_groups=int(act.sum()),
@@ -1023,8 +1040,9 @@ class InferenceEngine:
         cache layers (none otherwise): every class's blocks in use and
         blocks its streams returned so far, and the key rows the iteration
         may read (``context_tokens_in_reach``: over the slots in ``mask``,
-        classes and layers, a stream's context as far as the class
-        reaches); the classes' totals also go into ``snapshot()``."""
+        the classes of pages and their layers, a stream's context as far
+        as the class reaches; a state holds no key rows); the classes'
+        totals also go into ``snapshot()``."""
         stats = self.allocator.class_stats()
         if not stats:
             return {}
@@ -1037,7 +1055,7 @@ class InferenceEngine:
         args["context_tokens_in_reach"] = int(sum(
             sp.num_layers * (lens if sp.reach is None
                              else np.minimum(lens, sp.reach)).sum()
-            for sp in self.cache_specs))
+            for sp in self._attend_specs))
         return args
 
     def _attend_steps(self, k_rows: int,
@@ -1076,7 +1094,7 @@ class InferenceEngine:
         if spec is None:
             costs = [self._attend_cost(
                 context, pool_blocks and sp.blocks_per_group, sp)
-                for sp in self.cache_specs]
+                for sp in self._attend_specs]
             return sum(c[0] for c in costs), sum(c[1] for c in costs)
         if context is not None and spec.reach is not None:
             context = min(context, spec.reach)
@@ -1110,7 +1128,7 @@ class InferenceEngine:
             self.lengths[self.active if mask is None else mask], 1) // bs)
         n = int(blocks.size)
         out = np.zeros(4, np.int64)
-        for sp_ in self.cache_specs:
+        for sp_ in self._attend_specs:
             f1, b1 = self._attend_cost(context=bs, spec=sp_)
             f2, b2 = self._attend_cost(context=2 * bs, spec=sp_)
             reach = int((blocks if sp_.reach is None else np.minimum(

@@ -86,6 +86,19 @@ free ride.  A prefix hit at token P needs the unbounded classes' blocks over
 (``match_limit``), and a bounded class enters into its index only the blocks
 a hit at the prompt's own end would need: a cached document keeps its whole
 length in the one and a ``reach``-long tail in the other.
+
+The classes of one model may differ in KIND: K/V pages of its attention
+layers BESIDE a state a stream of its recurrent or convolution layers.  THE
+PREFIX RULE is one across kinds: a hit of ``n`` blocks needs every page
+class to hold blocks ``0 .. n-1`` (as far as it reaches) AND every state
+class to hold a snapshot AT boundary ``n`` — a state is valid at one
+position — so ``match_limit`` of a state class answers the longest boundary
+``<= n`` that has a snapshot and ``ClassAllocators._agreed`` iterates to the
+boundary every class can serve.  What the page classes had cached beyond it
+is counted (``AdmitPlan.lost_to_kind``), not hidden.  The composite's plan
+carries the state class's copy (snapshot -> the stream's own page), the
+snapshot it is to leave and the class that owns both (``copy_class``): the
+device copy runs on that class's pools alone (``copy_pools``).
 """
 from __future__ import annotations
 
@@ -505,6 +518,8 @@ class BlockAllocator:
 
     def __init__(self, spec: PagedKVCacheSpec):
         self.spec = spec
+        # the pools that copy runs on: block ids mean nothing elsewhere
+        self.copy_pools = spec.pool_names
         G, B = spec.num_groups, spec.blocks_per_group
         self._free: List[List[int]] = [list(range(B)) for _ in range(G)]
         self._ref = np.zeros((G, B), np.int64)
@@ -783,9 +798,11 @@ class AdmitPlan:
     matched: int
     cow_src: Optional[int] = None
     cow_dst: Optional[int] = None
-    # Per-stream pools: freeze the stream's page into ``snapshot_page``
-    # when prefill has consumed ``snapshot_at`` tokens (0: no snapshot),
-    # then ``commit_snapshot`` it under ``snapshot_hash``.
+    # Per-stream pools: the stream's own ``page``; freeze it into
+    # ``snapshot_page`` when prefill has consumed ``snapshot_at`` tokens
+    # (0: no snapshot), then ``commit_snapshot`` it under
+    # ``snapshot_hash``.
+    page: Optional[int] = None
     snapshot_at: int = 0
     snapshot_page: Optional[int] = None
     snapshot_hash: int = 0
@@ -794,6 +811,12 @@ class AdmitPlan:
     # from its cache}.
     shared_blocks: int = 0
     cached_by_class: Optional[Dict[str, int]] = None
+    # Classes of several KINDS: the class whose pools ``cow_src ->
+    # cow_dst`` and the snapshot's copy run on (its name), and the tokens
+    # the page classes had cached beyond the boundary the state class
+    # could resume at.
+    copy_class: Optional[str] = None
+    lost_to_kind: int = 0
 
 
 class StateAllocator(BlockAllocator):
@@ -825,22 +848,31 @@ class StateAllocator(BlockAllocator):
         """The longest boundary of ``prompt`` that has a snapshot."""
         return self.match_snapshot(group, prompt)[0]
 
-    def match_snapshot(self, group: int, prompt: np.ndarray
-                       ) -> Tuple[int, Optional[int], int]:
-        """The LONGEST block boundary of ``prompt`` that has a snapshot
-        and leaves at least the last token to prefill -> (blocks it
-        covers, its page or None, the chain hash at the prompt's last
-        full block).  A state is valid at ONE position, so the walk goes
-        on past boundaries that have none."""
-        bs = self.spec.block_size
+    def match_limit(self, group: int, hashes: Sequence[int], n: int) -> int:
+        """The longest boundary ``m <= n`` (in blocks) of a prompt (its
+        chain ``hashes``) that has a snapshot, 0 if none: a state is valid
+        at ONE position, so the blocks before it count for nothing."""
         idx = self._hash_index[group]
-        best, page, h = 0, None, 0
-        for j in range(len(prompt) // bs):
-            h = chain_hash(h, prompt[j * bs:(j + 1) * bs])
-            b = idx.get(h)
-            if b is not None and (j + 1) * bs <= len(prompt) - 1:
-                best, page = j + 1, b
-        return best, page, h
+        for m in range(min(n, len(hashes)), 0, -1):
+            if hashes[m - 1] in idx:
+                return m
+        return 0
+
+    def match_snapshot(self, group: int, prompt: np.ndarray,
+                       limit: Optional[int] = None
+                       ) -> Tuple[int, Optional[int], int]:
+        """The LONGEST block boundary of ``prompt`` (``limit`` blocks at
+        most: what a model's classes agreed on) that has a snapshot and
+        leaves at least the last token to prefill -> (blocks it covers,
+        its page or None, the chain hash at the prompt's last full
+        block)."""
+        bs = self.spec.block_size
+        hashes = chain_hashes(prompt, bs)
+        n = (len(prompt) - 1) // bs
+        best = self.match_limit(group, hashes,
+                                n if limit is None else min(n, limit))
+        page = self._hash_index[group][hashes[best - 1]] if best else None
+        return best, page, hashes[-1] if hashes else 0
 
     def snapshot_boundary(self, prompt_len: int, resumed: int) -> int:
         """THE RULE of which boundaries get a page: the prompt's last
@@ -858,7 +890,7 @@ class StateAllocator(BlockAllocator):
                   spec_k: int = 0, limit: Optional[int] = None) -> bool:
         """The stream's own page, drawn while the snapshot it resumes
         from (if retained) is held out of reach."""
-        page = self.match_snapshot(group, prompt)[1]
+        page = self.match_snapshot(group, prompt, limit)[1]
         return self.available(group) - int(
             page is not None and page in self._lru[group]) >= 1
 
@@ -866,7 +898,8 @@ class StateAllocator(BlockAllocator):
                      max_new: int, spec_k: int = 0,
                      limit: Optional[int] = None) -> "AdmitPlan":
         """The stream's own page; the snapshot to copy into it first
-        (``cow_src`` -> ``cow_dst``) and the position prefill resumes at;
+        (``cow_src`` -> ``cow_dst``: the one AT the longest boundary within
+        ``limit``) and the position prefill resumes at;
         and, where ``snapshot_boundary`` says so and a page can be had,
         the page that will hold this prompt's own snapshot
         (``snapshot_at``, ``snapshot_page``: the engine freezes the state
@@ -875,7 +908,7 @@ class StateAllocator(BlockAllocator):
         list and nothing can match it)."""
         self._gate(group, prompt, max_new, spec_k, limit)
         bs = self.spec.block_size
-        n, src, h_last = self.match_snapshot(group, prompt)
+        n, src, h_last = self.match_snapshot(group, prompt, limit)
         if src is not None:
             self._incref(group, src)            # out of the LRU's reach
         own = self._draw(group, slot)
@@ -889,7 +922,7 @@ class StateAllocator(BlockAllocator):
             self.snapshot_hits += 1
         self._slot_reserved[slot] = 0
         self._slot_group[slot] = group
-        return AdmitPlan(slot=slot, group=group, table=[own],
+        return AdmitPlan(slot=slot, group=group, table=[own], page=own,
                          matched=n * bs, cow_src=src,
                          cow_dst=own if src is not None else None,
                          snapshot_at=at if snap is not None else 0,
@@ -1092,14 +1125,27 @@ class ClassAllocators:
     """The allocators of a model's classes of cache layers behind the one
     interface the engine uses (module docstring).  A stream's table row is
     the classes' rows side by side (``columns``); a prefix hit is the
-    longest one EVERY class can serve; admission needs every class to
-    cover its own worst case.  No class forks a block copy-on-write: a
-    match stops short of the block that holds the prompt's last token."""
+    longest one EVERY class can serve — all of ``[0, n)`` (as far as it
+    reaches) cached in a class of pages, a snapshot AT ``n`` in a class of
+    states; admission needs every class to cover its own worst case.  No
+    class of pages forks a block copy-on-write: a match stops short of the
+    block that holds the prompt's last token.  The one class that copies is
+    a class of states (``_copier``: a snapshot into the stream's page, the
+    page into a snapshot): the plan carries its copy and its snapshot, and
+    ``copy_pools`` names the pools they run on."""
 
     def __init__(self, specs: Sequence[PagedKVCacheSpec]):
         self.classes = [_policy(s)(s) for s in specs]
         self.spec = specs[0]
-        self.copy_program = self.classes[0].copy_program
+        states = [a for a in self.classes if a.spec.per_stream]
+        if len(states) > 1:
+            raise NotImplementedError(
+                "two per-stream classes in one model: a plan carries one "
+                "page copy")
+        self._copier = states[0] if states else None
+        self.copy_program = (self._copier or self.classes[0]).copy_program
+        self.copy_pools = self._copier.copy_pools if states else tuple(
+            name for a in self.classes for name in a.copy_pools)
         self.columns, at = [], 0
         for a in self.classes:
             self.columns.append(slice(at, at + a.table_width))
@@ -1131,19 +1177,32 @@ class ClassAllocators:
         return self._merged("class_stats")
 
     def span_args(self, plans=None, live: int = 0) -> Dict[str, int]:
-        return self._merged("span_args", plans, live)
+        """The classes' own, and for admissions into a model of several
+        kinds ``prefix_lost_to_kind_tokens``: what the classes of pages
+        had cached beyond the boundary the class of states could resume
+        at."""
+        args = self._merged("span_args", plans, live)
+        if plans is not None and self._copier is not None:
+            args["prefix_lost_to_kind_tokens"] = sum(
+                int(p.lost_to_kind) for p in plans)
+        return args
 
     def snapshot_totals(self) -> Dict[str, int]:
         return self._merged("snapshot_totals")
 
     # ---- the prefix cache ---- #
-    def _agreed(self, group: int, prompt: np.ndarray) -> int:
+    def _agreed(self, group: int, prompt: np.ndarray, classes=None,
+                hashes=None) -> int:
+        """The longest boundary (in blocks) every class of ``classes``
+        (all of them by default) can serve a hit at (``hashes``: the
+        prompt's chain, where the caller has it)."""
         bs = self.spec.block_size
-        hashes = chain_hashes(prompt, bs)
+        if hashes is None:
+            hashes = chain_hashes(prompt, bs)
         n, before = (len(prompt) - 1) // bs, None
         while n != before:
             before = n
-            for a in self.classes:
+            for a in self.classes if classes is None else classes:
                 n = a.match_limit(group, hashes, n)
         return n
 
@@ -1159,7 +1218,13 @@ class ClassAllocators:
     # ---- request lifecycle ---- #
     def admit_prompt(self, slot: int, group: int, prompt: np.ndarray,
                      max_new: int, spec_k: int = 0) -> AdmitPlan:
-        n = self._agreed(group, prompt)
+        bs = self.spec.block_size
+        hashes = chain_hashes(prompt, bs)
+        n = self._agreed(group, prompt, hashes=hashes)
+        # (before any class enters this prompt's own blocks in its index)
+        pages = [a for a in self.classes if a is not self._copier]
+        lost = bs * (self._agreed(group, prompt, pages, hashes) - n) \
+            if pages and self._copier is not None else 0
         row = np.full(self.table_width, DEAD_BLOCK, np.int32)
         cached: Dict[str, int] = {}
         done = []
@@ -1167,19 +1232,27 @@ class ClassAllocators:
             for a, cols in zip(self.classes, self.columns):
                 plan = a.admit_prompt(slot, group, prompt, max_new, spec_k,
                                       limit=n)
-                done.append((a, cols))
+                done.append((a, cols, plan))
                 row[cols][:len(plan.table)] = plan.table
-                assert plan.matched == n * self.spec.block_size \
-                    and plan.cow_src is None
-                cached[a.spec.name] = self.spec.block_size * (
+                assert plan.matched == n * bs \
+                    and (plan.cow_src is None or a is self._copier)
+                cached[a.spec.name] = bs * (
                     plan.shared_blocks if a.spec.reach is not None else n)
         except PoolExhausted:
-            for a, cols in done:
+            # what the classes before drew goes back, a state class's
+            # uncommitted snapshot page with it
+            for a, cols, plan in done:
+                a.abandon_snapshot(plan)
                 a.release(slot, row[cols])
             raise
-        return AdmitPlan(slot=slot, group=group, table=list(row),
-                         matched=n * self.spec.block_size,
-                         cached_by_class=cached)
+        out = AdmitPlan(slot=slot, group=group, table=list(row),
+                        matched=n * bs, cached_by_class=cached)
+        if self._copier is not None:
+            own = next(p for a, _, p in done if a is self._copier)
+            out = dataclasses.replace(
+                own, table=out.table, cached_by_class=cached,
+                copy_class=self._copier.spec.name, lost_to_kind=lost)
+        return out
 
     def extend(self, slot: int, row: np.ndarray, first_pos: int,
                upto_pos: int) -> None:
@@ -1192,22 +1265,25 @@ class ClassAllocators:
             a.release(slot, table[cols])
 
     def commit_snapshot(self, plan: AdmitPlan) -> None:
-        for a in self.classes:
-            a.commit_snapshot(plan)
+        """To the class that owns the plan's snapshot page."""
+        if self._copier is not None:
+            self._copier.commit_snapshot(plan)
 
     def abandon_snapshot(self, plan: AdmitPlan) -> None:
-        for a in self.classes:
-            a.abandon_snapshot(plan)
+        if self._copier is not None:
+            self._copier.abandon_snapshot(plan)
 
 
-def class_specs(classes, asked, rows: int, **geometry
+def class_specs(classes, asked, rows: int, of_class=None, **geometry
                 ) -> Tuple[PagedKVCacheSpec, ...]:
     """One spec a CLASS of a model's cache layers (``served.CacheClass``:
     name, layers, reach, per_stream) over the engine's ``geometry`` (the
-    spec's other fields).  ``asked``: ``inference.num_blocks``, an int or
-    {class name: blocks} (0 or a class left out: full provisioning);
-    ``rows``: the most query rows of a stream one program holds — a
-    bounded class's table is a ring as wide as they reach."""
+    spec's other fields) and what ``of_class(cls)`` adds for that class
+    (its pools' tiles, heads, row width: ``ServedModel.class_geometry``).
+    ``asked``: ``inference.num_blocks``, an int or {class name: blocks} (0
+    or a class left out: full provisioning); ``rows``: the most query rows
+    of a stream one program holds — a bounded class's table is a ring as
+    wide as they reach."""
     table = geometry["max_len"] // geometry["block_size"]
     return tuple(PagedKVCacheSpec(
         num_layers=cls.layers, name=cls.name, reach=cls.reach,
@@ -1216,7 +1292,18 @@ def class_specs(classes, asked, rows: int, **geometry
                        else asked),
         table_blocks=0 if cls.reach is None else min(
             table, (cls.reach + rows - 2) // geometry["block_size"] + 2),
-        **geometry) for cls in classes)
+        **{**geometry, **(of_class(cls) if of_class else {})})
+        for cls in classes)
+
+
+def attended_specs(specs: Sequence[PagedKVCacheSpec]
+                   ) -> Tuple[PagedKVCacheSpec, ...]:
+    """Of a model's classes those whose cost grows with the context (an
+    attend walks their rows): a state a stream BESIDE classes of pages
+    holds no key rows; a model's only class is priced whatever it is (a
+    state's constant)."""
+    specs = tuple(specs)
+    return tuple(sp for sp in specs if not sp.per_stream) or specs
 
 
 def _policy(spec: PagedKVCacheSpec) -> type:
@@ -1230,17 +1317,13 @@ def allocator_for(specs: Sequence[PagedKVCacheSpec], spec_k: int = 0):
     """The cache manager of a model's classes of cache layers: the bare
     policy of a model's only class (a ring is always behind the
     composite: its admission is the composite's), else
-    ``ClassAllocators`` over one each.  ``spec_k``: the engine's
+    ``ClassAllocators`` over one each, of whatever kinds.  ``spec_k``: the engine's
     speculation depth, refused where a class cannot drop rejected rows."""
     per_stream = [s for s in specs if s.per_stream]
     if per_stream and spec_k > 0:
         raise ValueError(
             "inference.spec_k > 0 needs a cache that can drop rejected "
             "rows; this model keeps a state per stream")
-    if per_stream and len(specs) > 1:
-        raise NotImplementedError(
-            "a per-stream class beside other classes: the prefix rule "
-            "across kinds is not written (ROADMAP M12)")
     if len(specs) == 1 and specs[0].reach is None:
         return _policy(specs[0])(specs[0])
     return ClassAllocators(specs)
@@ -1254,4 +1337,5 @@ __all__ = ["DEAD_BLOCK", "PagedKVCacheSpec", "paged_partition_spec",
            "block_select", "paged_write_rows", "paged_attend",
            "chain_hash", "chain_hashes", "PoolExhausted", "BlockAllocator",
            "AdmitPlan", "StateAllocator", "BoundedBlockAllocator",
-           "ClassAllocators", "class_specs", "allocator_for"]
+           "ClassAllocators", "class_specs", "attended_specs",
+           "allocator_for"]

@@ -5,8 +5,9 @@ admission, sampling and the spans know nothing of a model's block.  They
 ask a ``ServedModel`` for:
 
 - **what it keeps in the paged pool** — ``cache_layers`` (or, where its
-  layers differ in how far back they read, ``cache_classes``: the layers
-  by class and each class's reach) and
+  layers differ in how far back they read or in KIND, ``cache_classes``:
+  the layers by class, each class's reach and whether it is per stream;
+  ``class_geometry`` then answers class by class) and
   ``cache_pools(block_size)``: the pools by name, each with the shape of
   ONE block's tile as held (``[heads, rows, lanes]``, lane-dense) — from
   which ``PagedKVCacheSpec``, the pool arrays, ``block_nbytes``,
@@ -134,6 +135,19 @@ class ServedModel:
         """Per-stream pools: bytes a token of this model would keep a
         layer as K/V rows (what a snapshot page is weighed against)."""
         return 0
+
+    def class_geometry(self, cls: CacheClass, block_size: int
+                       ) -> Dict[str, Any]:
+        """What the pools of class ``cls`` hold, as its
+        ``PagedKVCacheSpec`` takes it: ``pools`` (``cache_pools``),
+        ``num_heads`` / ``head_dim`` (a cache row's heads and logical
+        width) and ``token_row_bytes``.  One answer for every class unless
+        a model's classes differ in KIND (K/V pages beside a state a
+        stream: a page's tile is not a block's)."""
+        return dict(pools=self.cache_pools(block_size),
+                    num_heads=self.cache_heads,
+                    head_dim=self.cache_row_width,
+                    token_row_bytes=self.token_row_bytes)
 
     # -- the cache's analytic cost a token ------------------------------ #
     def cache_cost(self, keys: int, block_size: int, itemsize: int

@@ -18,8 +18,9 @@ class Routing(NamedTuple):
     ``moe/share.py``) in numbers: ``experts`` routed experts in ``n_group``
     groups of which the ``topk_group`` best stay (1 / 1: no group limit),
     ``per_tok`` chosen a token, the weights divided by their sum where
-    ``norm``, times ``scale``; and ``held`` = (first, count), the share
-    this program holds."""
+    ``norm`` (their sum + ``norm_eps``: 1e-20 as ``deepseek_v3`` and
+    ``afmoe`` publish it, 1e-6 ``lfm2_moe``), times ``scale``; and ``held``
+    = (first, count), the share this program holds."""
     experts: int
     per_tok: int
     n_group: int
@@ -27,6 +28,7 @@ class Routing(NamedTuple):
     norm: bool
     scale: float
     held: Tuple[int, int]
+    norm_eps: float = 1e-20
 
 
 def rotary_cos_sin(inv_freq, positions: jax.Array, scale: float = 1.0):

@@ -11,7 +11,8 @@ serving chip of an expert-parallel deployment runs:
   bias, for choosing only); a group's score is the sum of its two largest
   ``c``; the ``topk_group`` best groups stay; the ``num_experts_per_tok``
   largest ``c`` inside them are chosen; the weights are ``s`` at the
-  chosen, divided by their sum (+1e-20) when ``norm_topk_prob``, times
+  chosen, divided by their sum (+ ``norm_eps``: 1e-20 there, 1e-6 in
+  ``lfm2_moe``) when ``norm_topk_prob``, times
   ``routed_scaling_factor``.  fp32 throughout.
 - **The share**: told ``held = (first, count)``, the layer computes the
   weighted outputs of the pairs (token, expert) whose expert it holds —
@@ -23,7 +24,8 @@ serving chip of an expert-parallel deployment runs:
   experts would add is left out; no code stands in for the other chips or
   their exchange.  ``held = (0, n_routed_experts)`` is the whole layer.
 - **The shared expert** is added for every token (each chip computes it
-  alike; the sum over shares counts it once).
+  alike; the sum over shares counts it once) by ``expert_layer``; a
+  family without one (``lfm2_moe``) calls ``routed_share`` alone.
 
 ``routed_share`` returns, beside the output, the held experts' row
 counts: the engine's ``decode`` / ``prefill`` span args and the
@@ -64,7 +66,7 @@ def route(x: jax.Array, router: jax.Array, bias: jax.Array,
     idx = jax.lax.top_k(cand, k)[1].astype(jnp.int32)          # [T, k]
     w = jnp.take_along_axis(s, idx, axis=1)
     if r.norm:
-        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+        w = w / (w.sum(-1, keepdims=True) + r.norm_eps)
     return idx, w * r.scale
 
 

@@ -362,12 +362,15 @@ class ServingAggregator:
     def note_state(self, admitted: Dict[str, int],
                    totals: Dict[str, int]) -> None:
         """One admission batch into a per-stream state pool (the
-        ``prefill`` span's ``resumed_tokens`` / ``state_copy_bytes``,
-        summed) and the allocator's running totals of snapshots taken /
-        hit / evicted."""
+        ``prefill`` span's ``resumed_tokens`` / ``state_copy_bytes`` and,
+        of a model that keeps pages beside the state,
+        ``prefix_lost_to_kind_tokens``, summed) and the allocator's running
+        totals of snapshots taken / hit / evicted."""
         st = self._state
-        for name in ("resumed_tokens", "state_copy_bytes"):
-            st[name] = st.get(name, 0) + int(admitted[name])
+        for name in ("resumed_tokens", "state_copy_bytes",
+                     "prefix_lost_to_kind_tokens"):
+            if name in admitted:
+                st[name] = st.get(name, 0) + int(admitted[name])
         st.update(totals)
 
     def note_cache_classes(self, stats: Dict[str, Dict[str, int]]) -> None:

@@ -55,7 +55,12 @@ SCOPES = ("fwd_bwd", "grad_sync", "health_tap", "optimizer", "flatten",
           # hc_pre (the sublayer's input mixed from the streams), hc_post
           # (its output written back); embed > hc_expand, lm_head >
           # hc_collapse
-          "hc", "hc_maps", "hc_pre", "hc_post", "hc_expand", "hc_collapse")
+          "hc", "hc_maps", "hc_pre", "hc_post", "hc_expand", "hc_collapse",
+          # gated short-convolution layers (inference/lfm2.py): conv >
+          # conv_in_proj (norm, the gates' projection), conv_mix (the
+          # gates' product, the filter, the rewrite of the stream's page),
+          # conv_out_proj
+          "conv", "conv_in_proj", "conv_mix", "conv_out_proj")
 # The host spans ``Telemetry.span`` opens (runtime/engine.py,
 # inference/engine.py, inference/scheduler.py), same contract.
 SPANS = ("train_batch", "data_prep", "step_dispatch", "offload_step",
@@ -80,6 +85,9 @@ SPAN_ARGS = {
     # state pool's admission (inference/kv_cache.py): prompt tokens the
     # snapshot it resumed from covers (what cached_tokens means there),
     # snapshots the admission left, bytes its page copies read + wrote.
+    # prefix_lost_to_kind_tokens: a model that keeps pages BESIDE a state
+    # a stream: prompt tokens its page classes had cached beyond the
+    # boundary the state class had a snapshot at (prefilled again).
     # rows_computed: the [G, width] rows of every chunk program the
     # admission dispatched, each at the width it took (the narrowest of
     # the engine's prefill_widths that held its rows: prefill_chunk's
@@ -88,7 +96,8 @@ SPAN_ARGS = {
                 "chunks", "rows_computed", "moe_held_pairs", "moe_held_max",
                 "moe_held_mean", "moe_held_empty", "moe_held_pair_share",
                 "hc_res_err_max",
-                "resumed_tokens", "snapshot_taken", "state_copy_bytes"),
+                "resumed_tokens", "snapshot_taken", "state_copy_bytes",
+                "prefix_lost_to_kind_tokens"),
     "prefill_chunk": ("ci", "active_groups", "rows"),
     # A decode span holds the DISPATCH of one iteration and the FETCH of
     # the one before (the loop runs an iteration ahead of its token

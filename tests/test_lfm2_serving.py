@@ -1,0 +1,546 @@
+"""The ``lfm2_moe`` family (LFM2-24B-A2B) through the normal serving path (PR
+45): gated short-convolution layers keep a fixed state a stream BESIDE the
+K/V pages of the grouped-query attention layers, two KINDS of cache in one
+manager with one prefix rule.
+
+What is held to what:
+1. Served logits and conv pages — prefill chunks and decode through both
+   kinds of cache, a second request through the prefix-hit path (pages by
+   reference + a snapshot copied at the same boundary) — against the plain
+   float32 reference the benchmark keeps (``perfbench/lib/lfm2_reference.py``),
+   kernels on and off; however a prompt is cut into chunks.
+2. THE RULE, on the allocators alone: a hit needs the pages AND a snapshot
+   at its boundary; what the pages had beyond it is counted; a reclaimed
+   snapshot falls back; exhaustion in either class leaves the other as it
+   was; ``commit_snapshot`` / ``abandon_snapshot`` reach the owner.
+3. What the engine builds for the four families that were there is what it
+   built before (``class_geometry`` is one answer for them).
+4. The controls the benchmark's ``correct`` relies on: the reference with
+   the conv state zeroed at the resume boundary, or in 8 bits, is far from
+   the served path.
+"""
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from deepspeed_tpu.inference import InferenceEngine, kv_cache   # noqa: E402
+from deepspeed_tpu.inference import lfm2 as lfm2_serving        # noqa: E402
+from deepspeed_tpu.inference.kv_cache import (                  # noqa: E402
+    BlockAllocator, ClassAllocators, PoolExhausted, StateAllocator,
+    allocator_for, class_specs)
+from deepspeed_tpu.inference.served import served_model         # noqa: E402
+from deepspeed_tpu.models.lfm2 import (                         # noqa: E402
+    CONV, FULL, Lfm2Config, lfm2_init)
+from deepspeed_tpu.parallel.topology import build_mesh          # noqa: E402
+from perfbench.lib import lfm2_reference as reference           # noqa: E402
+
+BS, WIDTH, N_OUT = 4, 64, 6
+
+
+def tiny(**kw):
+    """1 dense conv layer + one period A C C C: 4 conv layers, 1 attention
+    layer of 4 / 2 heads of 16, 8 experts top-2."""
+    base = dict(
+        vocab_size=128, hidden_size=64, intermediate_size=96,
+        moe_intermediate_size=32, num_hidden_layers=5, num_dense_layers=1,
+        num_attention_heads=4, num_key_value_heads=2, num_experts=8,
+        num_experts_per_tok=2, layer_types=(CONV, FULL, CONV, CONV, CONV),
+        max_position_embeddings=256, dtype=jnp.float32,
+        initializer_range=0.08)
+    base.update(kw)
+    return Lfm2Config(**base)
+
+
+def sizes_of(cfg):
+    """The configuration file's keys for the reference."""
+    d = {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
+    d["layer_types"] = list(cfg.layer_types)
+    d["rope_parameters"] = {"rope_theta": cfg.rope_theta}
+    return d
+
+
+def seeded(cfg, seed=0):
+    """The seeded init with the norms' weights moved off 1, so that a norm
+    left out or applied on the wrong side shows."""
+    params = lfm2_init(jax.random.PRNGKey(seed), cfg)
+    rng = np.random.default_rng(seed + 1)
+    leaves, tree = jax.tree_util.tree_flatten_with_path(params)
+    return jax.tree_util.tree_unflatten(tree, [
+        a * jnp.asarray(rng.uniform(0.6, 1.4, a.shape), a.dtype)
+        if "norm" in str(path[-1]) else a for path, a in leaves])
+
+
+CFG = tiny()
+_MADE = {}
+
+
+def params():
+    if "params" not in _MADE:
+        _MADE["params"] = seeded(CFG)
+    return _MADE["params"]
+
+
+def engine(name):
+    """The file's engines, built once: ``chunked`` (chunks of 8 rows, the
+    kernels off), ``kernels`` (the same with the Pallas kernels in interpret
+    mode), ``whole`` (a chunk of 32 covers a prompt; a conv pool with a page
+    a slot and none to spare, so that no snapshot cuts a prompt)."""
+    if name not in _MADE:
+        conf = dict(max_slots=4, max_seq_len=128, block_size=BS,
+                    prefill_chunk=8, paged_kernel=name == "kernels",
+                    num_blocks={"full": 96, "conv": 16})
+        if name == "whole":
+            conf.update(prefill_chunk=32, num_blocks={"full": 96, "conv": 4})
+        _MADE[name] = InferenceEngine(
+            CFG, params(), config={"inference": conf},
+            mesh=build_mesh(devices=jax.devices()[:1]))
+    return _MADE[name]
+
+
+def ref(tokens, positions, state_at=0, **kw):
+    """(logits, margin, conv states at ``state_at``) of the reference, one
+    compiled function a variant for rows padded to WIDTH."""
+    key = tuple(sorted((k, str(v)) for k, v in kw.items()
+                       if k != "zero_state_at"))
+    if ("ref", key) not in _MADE:
+        static = {k: v for k, v in kw.items() if k != "zero_state_at"}
+        _MADE["ref", key] = jax.jit(
+            lambda p, t, out, at, cut: reference.forward(
+                p, t, sizes_of(CFG), out_positions=out, q_block=16,
+                state_at=at, zero_state_at=cut, **static))
+    row = np.zeros(WIDTH, np.int32)
+    row[:len(tokens)] = tokens
+    out = np.zeros(N_OUT, np.int32)
+    out[:len(positions)] = positions
+    lg, margin, states = _MADE["ref", key](
+        params(), jnp.asarray(row), jnp.asarray(out), jnp.int32(state_at),
+        jnp.int32(kw.get("zero_state_at", 0)))
+    n = len(positions)
+    return np.asarray(lg)[:n], np.asarray(margin)[:n], np.asarray(states)
+
+
+def page_of(eng, slot):
+    """The stream's conv page, every layer: [conv layers, L - 1, H]."""
+    page = int(eng.block_tables[slot][-1])
+    pool = np.asarray(eng.cache["conv.conv"])
+    return pool[:, 0, page].reshape(pool.shape[0], CFG.conv_L_cache - 1,
+                                    CFG.hidden_size)
+
+
+def through(eng, prompt, steps=2, keep=False):
+    """(tokens, logits of the prefill and of ``steps`` decode iterations,
+    admission info, the conv page after prefill and after the last
+    iteration) of ``prompt`` served alone."""
+    slot = eng.select_slot(prompt, steps + 1)
+    tok, pre = eng.prefill(prompt, slot, return_logits=True,
+                           max_new_tokens=steps + 1)
+    info = dict(eng.last_admit_info(slot))
+    page0 = page_of(eng, slot)
+    eng.activate_slot(slot, len(prompt), tok)
+    toks, got = [tok], [np.asarray(pre)]
+    for _ in range(steps):
+        sampled, lg = eng.decode_once(return_logits=True)
+        toks.append(int(sampled[slot]))
+        got.append(np.asarray(lg[slot]))
+    page1 = page_of(eng, slot)
+    if not keep:
+        eng.release_slot(slot)
+    return toks, np.stack(got), info, page0, page1
+
+
+def prompt_of(seed, n):
+    return np.random.default_rng(seed).integers(0, CFG.vocab_size, size=n,
+                                                dtype=np.int32)
+
+
+# --------------------------------------------------------------------- #
+# 0. The config and what the model declares
+# --------------------------------------------------------------------- #
+def test_a_cut_in_depth_takes_the_dense_layer_and_whole_periods():
+    types = [CONV, CONV] + [FULL, CONV, CONV, CONV] * 9 + [FULL, CONV]
+    cfg = Lfm2Config.from_hf({
+        "num_hidden_layers": 9, "num_dense_layers": 1, "layer_types": types,
+        "published": {"num_hidden_layers": 40, "num_dense_layers": 2},
+        "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
+        "norm_eps": 1e-5})
+    assert cfg.layer_types == (CONV,) + (FULL, CONV, CONV, CONV) * 2
+    assert reference.layer_types({
+        "num_hidden_layers": 9, "num_dense_layers": 1, "layer_types": types,
+        "published": {"num_dense_layers": 2}}) == list(cfg.layer_types)
+    assert (cfg.num_conv_layers, cfg.num_attention_layers) == (7, 2)
+    assert cfg.head_dim == 64 and cfg.group == 4
+    assert cfg.routing.held == (0, 64) and cfg.routing.norm_eps == 1e-6
+    uncut = Lfm2Config.from_hf({"layer_types": types})
+    assert uncut.layer_types == tuple(types)
+
+
+def test_the_model_declares_two_kinds_of_cache():
+    cfg = Lfm2Config(layer_types=tuple(
+        [CONV, CONV] + [FULL, CONV, CONV, CONV] * 9 + [FULL, CONV]))
+    served = lfm2_serving.Lfm2Served(cfg)
+    full, conv = served.cache_classes
+    assert tuple(full) == ("full", 10, None, False)
+    assert tuple(conv) == ("conv", 30, None, True)
+    assert served.class_geometry(full, 64) == dict(
+        pools=(("k", (8, 32, 128)), ("v", (8, 32, 128))), num_heads=8,
+        head_dim=64, token_row_bytes=0)
+    geometry = served.class_geometry(conv, 64)
+    assert geometry["pools"] == (("conv", (1, 32, 128)),)
+    # the yardstick: a token's K/V rows in the ATTENTION layers, a conv
+    # layer's share
+    assert geometry["token_row_bytes"] == -(-10 * 2048 // 30)
+    with pytest.raises(NotImplementedError):
+        served.verify(None, (), None, None, None, num_groups=1,
+                      paged_kernel=False)
+
+
+def test_the_engine_builds_a_spec_a_class_from_the_models_own_answer():
+    eng = engine("chunked")
+    full, conv = eng.cache_specs
+    assert (full.name, full.per_stream, full.num_layers) == ("full", False, 1)
+    assert (conv.name, conv.per_stream, conv.num_layers) == ("conv", True, 4)
+    assert full.pool_shapes == {"k.full": (1, 1, 96, 2, 1, 64),
+                                "v.full": (1, 1, 96, 2, 1, 64)}
+    assert conv.pool_shapes == {"conv.conv": (4, 1, 16, 1, 1, 128)}
+    assert conv.max_blocks_per_slot == 1 and conv.page_tokens == 8
+    assert eng.allocator.table_width == 128 // BS + 1
+    assert isinstance(eng.allocator, ClassAllocators)
+    assert [type(a) for a in eng.allocator.classes] == [BlockAllocator,
+                                                        StateAllocator]
+    assert eng.allocator.copy_pools == ("conv.conv",)
+    assert eng.allocator.copy_program == ("state_copy", "state_copy")
+    with pytest.raises(ValueError, match="spec_k"):
+        allocator_for(eng.cache_specs, spec_k=2)
+
+
+# --------------------------------------------------------------------- #
+# 1. The program against the reference
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("name", ["chunked", "kernels"])
+def test_served_logits_and_pages_match_the_reference(name):
+    """Four chunks (the third ends at the snapshot's boundary, the last
+    holds 3 live rows of 8), then decode, through both kinds of cache."""
+    eng = engine(name)
+    prompt = prompt_of(1, 27)
+    toks, got, info, page0, page1 = through(eng, prompt)
+    assert info["cached_tokens"] == 0 and info["snapshot_at"] == 24
+    seq = np.concatenate([prompt, toks[:-1]])
+    want, _, state = ref(seq, [26, 27, 28], state_at=26)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    np.testing.assert_allclose(page0, state, atol=1e-5)
+    np.testing.assert_allclose(page1, ref(seq, [28], state_at=28)[2],
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("name", ["chunked", "kernels"])
+def test_a_stream_resumed_from_pages_and_a_snapshot_equals_a_cold_one(name):
+    """The second request shares the first's blocks by reference and copies
+    its snapshot at the same boundary; its logits and pages are those of an
+    engine that never saw the first."""
+    eng, cold = engine(name), engine("whole")
+    seed = 200 + 2 * (name == "kernels")       # (the cold engine is shared)
+    first = prompt_of(seed, 18)
+    through(eng, first, steps=1)
+    turn = np.concatenate([first, prompt_of(seed + 1, 11)])
+    assert eng.prefix_match_tokens(turn) == 16
+    toks, got, info, page0, page1 = through(eng, turn)
+    assert info["cached_tokens"] == 16 and info["cow_fork"]
+    assert info["cached_by_class"] == {"full": 16, "conv": 16}
+    assert info["lost_to_kind_tokens"] == 0 and info["snapshot_at"] == 28
+    ctoks, cgot, cinfo, cpage0, cpage1 = through(cold, turn)
+    assert cinfo["cached_tokens"] == 0 and ctoks == toks
+    np.testing.assert_allclose(got, cgot, atol=2e-5)
+    np.testing.assert_allclose(page0, cpage0, atol=1e-5)
+    np.testing.assert_allclose(page1, cpage1, atol=1e-5)
+    seq = np.concatenate([turn, toks[:-1]])
+    want, _, state = ref(seq, [28, 29, 30], state_at=30)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    np.testing.assert_allclose(page1, state, atol=1e-5)
+
+
+@pytest.mark.parametrize("n", [25, 27, 31, 32, 5, 1])
+def test_chunks_and_cuts_change_nothing(n):
+    """A prompt in chunks of 8 with a cut at its snapshot's boundary (n =
+    25: a last chunk of ONE live row; 32: the snapshot after the last
+    chunk; 5, 1: no snapshot, a padded chunk) against the same prompt in
+    one padded chunk with no snapshot."""
+    chunked, whole = engine("chunked"), engine("whole")
+    held = [through(whole, prompt_of(40 + i, 3), steps=0, keep=True)
+            for i in range(3)]            # the pages a snapshot would take
+    try:
+        prompt = prompt_of(10 + n, n)
+        toks, got, info, page0, page1 = through(chunked, prompt)
+        wtoks, wgot, winfo, wpage0, wpage1 = through(whole, prompt)
+    finally:
+        for s in np.flatnonzero(whole.active):
+            whole.release_slot(int(s))
+    at = n // BS * BS if n >= 8 else 0        # (a page is worth 8 tokens)
+    assert info["snapshot_at"] == at
+    assert info["chunks"] == -(-at // 8) + -(-(n - at) // 8)
+    assert winfo["chunks"] == 1 and winfo["snapshot_at"] == 0
+    assert toks == wtoks
+    np.testing.assert_allclose(got, wgot, atol=2e-5)
+    np.testing.assert_allclose(page0, wpage0, atol=1e-5)
+    np.testing.assert_allclose(page1, wpage1, atol=1e-5)
+    del held
+
+
+def test_dead_slots_and_inactive_groups_write_no_page():
+    """A decode iteration rewrites the pages of LIVE slots only, and a
+    warm-up chunk (every group inactive) none at all."""
+    eng = engine("chunked")
+    before = np.asarray(eng.cache["conv.conv"]).copy()
+    eng._warm_prefill_widths()
+    np.testing.assert_array_equal(np.asarray(eng.cache["conv.conv"]), before)
+    slot = eng.select_slot(prompt_of(5, 6), 3)
+    tok, _ = eng.prefill(prompt_of(5, 6), slot, max_new_tokens=3)
+    eng.activate_slot(slot, 6, tok)
+    before = np.asarray(eng.cache["conv.conv"]).copy()
+    eng.decode_once()
+    after = np.asarray(eng.cache["conv.conv"])
+    own = int(eng.block_tables[slot][-1])
+    others = [b for b in range(after.shape[2]) if b != own]
+    np.testing.assert_array_equal(after[:, :, others], before[:, :, others])
+    assert np.abs(after[:, 0, own] - before[:, 0, own]).max() > 0
+    eng.release_slot(slot)
+
+
+def test_the_controls_are_far_from_the_served_path():
+    """What the benchmark's ``correct`` leans on: the reference with the
+    conv state zeroed at the resume boundary, and in 8-bit operands, is far
+    from the served path where the true reference is near."""
+    eng = engine("chunked")
+    first = prompt_of(6, 20)
+    through(eng, first, steps=0)
+    turn = np.concatenate([first, prompt_of(7, 9)])
+    toks, got, info, _, page1 = through(eng, turn)
+    assert info["cached_tokens"] == 20
+    seq = np.concatenate([turn, toks[:-1]])
+    at = [28, 29, 30]
+    want, _, state = ref(seq, at, state_at=30)
+    assert np.abs(got - want).max() < 2e-5
+    zeroed = ref(seq, at, state_at=30, zero_state_at=20)[0]
+    assert np.abs(got - zeroed).max() > 100 * 2e-5
+    low = ref(seq, at, cast=jnp.float8_e4m3fn)[0]
+    assert np.abs(got - low).max() > 100 * 2e-5
+    # a zeroed state at ANOTHER boundary than the one resumed at differs too
+    assert np.abs(zeroed - ref(seq, at, zero_state_at=16)[0]).max() > 1e-3
+
+
+def test_the_look_ahead_loop_serves_sessions_turn_by_turn():
+    """``engine.serve`` over turns that extend each other: every later turn
+    is a hit across kinds, a snapshot a turn, tokens those of a synchronous
+    loop on a cold engine."""
+    from deepspeed_tpu.inference.scheduler import Request
+    eng, cold = engine("chunked"), engine("whole")
+    history = prompt_of(8, 21)
+    turns = [np.concatenate([history] + [prompt_of(80 + j, 9)
+                                         for j in range(k + 1)])
+             for k in range(3)]
+    taken0 = eng.allocator.snapshot_totals()["snapshots_taken"]
+    eng.reset_serving_stats()
+    for k, prompt in enumerate(turns):      # a round apart, as the cell's
+        report = eng.serve([Request(rid=k, prompt=prompt, max_new_tokens=4,
+                                    arrival_s=0.0)])
+        assert report["completed"] == k + 1
+    assert eng._inflight is None and not eng.active.any()
+    state = report["state"]
+    assert state["snapshots_taken"] - taken0 == 3
+    assert state["resumed_tokens"] == 28 + 36       # turn 1 at 28, 2 at 36
+    assert state["prefix_lost_to_kind_tokens"] == 0
+    assert report["prefix"]["cached_tokens"] == 28 + 36
+    toks = through(cold, turns[-1], steps=3)[0]
+    slot = eng.select_slot(turns[-1], 4)
+    tok, _ = eng.prefill(turns[-1], slot, max_new_tokens=4)
+    assert tok == toks[0] and eng.last_admit_info(slot)["cached_tokens"] == 36
+    eng.release_slot(slot)
+
+
+# --------------------------------------------------------------------- #
+# 2. The rule, on the allocators alone
+# --------------------------------------------------------------------- #
+def manager(full=40, conv=8, order=("full", "conv")):
+    served = lfm2_serving.Lfm2Served(CFG)
+    classes = sorted(served.cache_classes, key=lambda c: order.index(c.name))
+    specs = class_specs(
+        classes, {"full": full, "conv": conv}, rows=8,
+        of_class=lambda cls: served.class_geometry(cls, BS), num_slots=4,
+        block_size=BS, max_len=128, num_groups=1, dtype=jnp.float32)
+    return allocator_for(specs)
+
+
+def admitted(alloc, slot, prompt, commit=True):
+    plan = alloc.admit_prompt(slot, 0, prompt, 4)
+    if commit and plan.snapshot_page is not None:
+        alloc.commit_snapshot(plan)
+    return plan
+
+
+def test_a_hit_needs_the_pages_and_a_snapshot_at_its_boundary():
+    alloc = manager()
+    full, conv = alloc.classes
+    base = prompt_of(20, 27)
+    plan = admitted(alloc, 0, base)
+    assert (plan.matched, plan.snapshot_at, plan.copy_class) == (0, 24, "conv")
+    assert plan.page == plan.table[-1] and plan.cow_src is None
+    longer = np.concatenate([base, prompt_of(21, 13)])         # 40 tokens
+    plan = admitted(alloc, 1, longer)
+    assert (plan.matched, plan.snapshot_at) == (24, 40)
+    assert plan.cow_src is not None and plan.cow_dst == plan.page
+    assert plan.cached_by_class == {"full": 24, "conv": 24}
+    assert plan.lost_to_kind == 0
+    # The pages hold 8 blocks of this prompt, the snapshots lie at 6 and
+    # 10: the hit is at 6, and 2 blocks of pages were there for nothing.
+    forked = np.concatenate([longer[:32], prompt_of(22, 9)])
+    assert full.matched_blocks(0, forked) == 8
+    assert conv.match_limit(0, kv_cache.chain_hashes(forked, BS), 8) == 6
+    assert alloc.matched_blocks(0, forked) == 6
+    plan = admitted(alloc, 2, forked)
+    assert (plan.matched, plan.lost_to_kind) == (24, 8)
+    assert alloc.span_args(plans=[plan])["prefix_lost_to_kind_tokens"] == 8
+    assert alloc.span_args(plans=[plan])["resumed_tokens"] == 24
+    # Pages of 5 blocks and no snapshot on this chain at or before them:
+    # nothing to resume from, all 5 blocks' worth lost to the kind.
+    other = np.concatenate([base[:20], prompt_of(23, 10)])
+    assert alloc.matched_blocks(0, other) == 0
+    plan = admitted(alloc, 3, other)
+    assert (plan.matched, plan.lost_to_kind, plan.cow_src) == (0, 20, None)
+
+
+def test_a_reclaimed_snapshot_falls_back_and_the_pages_stay():
+    alloc = manager(conv=3)
+    full, conv = alloc.classes
+    base = prompt_of(24, 27)
+    plan = admitted(alloc, 0, base)
+    alloc.release(0, plan.table)
+    assert alloc.matched_blocks(0, np.concatenate([base, [1]])) == 6
+    # two strangers' pages and snapshots push the retained snapshot out
+    for slot, seed in ((1, 25), (2, 26)):
+        p = admitted(alloc, slot, prompt_of(seed, 9))
+        alloc.release(slot, p.table)
+    assert conv.reclaimed >= 1
+    again = np.concatenate([base, prompt_of(27, 6)])
+    assert full.matched_blocks(0, again) == 6          # the pages stayed
+    assert alloc.matched_blocks(0, again) == 0         # the hit did not
+    plan = admitted(alloc, 0, again)
+    assert (plan.matched, plan.lost_to_kind, plan.cow_src) == (0, 24, None)
+    assert alloc.snapshot_totals()["snapshots_evicted"] == conv.reclaimed
+
+
+@pytest.mark.parametrize("order", [("full", "conv"), ("conv", "full")])
+@pytest.mark.parametrize("short", ["full", "conv"])
+def test_exhaustion_in_one_class_leaves_the_other_as_it_was(order, short):
+    """The composite's gate says no, and an admission tried all the same
+    gives back what the classes before the dry one drew — a state class's
+    uncommitted snapshot page too."""
+    alloc = manager(full=4 if short == "full" else 40,
+                    conv=1 if short == "conv" else 8, order=order)
+    prompt = prompt_of(28, 27)
+    if short == "conv":
+        holder = admitted(alloc, 3, prompt_of(29, 3))
+    by_name = {a.spec.name: a for a in alloc.classes}
+    before = {n: (a.available(0), a.blocks_in_use())
+              for n, a in by_name.items()}
+    assert not alloc.can_admit(0, prompt, 20)
+    with pytest.raises(PoolExhausted):
+        alloc.admit_prompt(0, 0, prompt, 20)
+    other = by_name["conv" if short == "full" else "full"]
+    assert (other.available(0), other.blocks_in_use()) \
+        == before[other.spec.name]
+    assert len(by_name["conv"]._free[0]) + len(by_name["conv"]._lru[0]) \
+        + by_name["conv"].blocks_in_use() == by_name["conv"].spec.num_blocks
+    if short == "conv":
+        alloc.release(3, holder.table)
+
+
+def test_commit_and_abandon_reach_the_class_that_owns_the_page():
+    alloc = manager()
+    full, conv = alloc.classes
+    prompt = prompt_of(30, 27)
+    free0 = len(conv._free[0])
+    plan = admitted(alloc, 0, prompt, commit=False)
+    assert plan.snapshot_page is not None
+    assert len(conv._free[0]) == free0 - 2             # own page + snapshot
+    alloc.abandon_snapshot(plan)                       # prefill failed
+    alloc.release(0, plan.table)
+    assert len(conv._free[0]) == free0 and conv.snapshots_taken == 0
+    probe = np.concatenate([prompt, [0]])
+    assert alloc.matched_blocks(0, probe) == 0
+    plan = admitted(alloc, 0, prompt, commit=False)
+    alloc.commit_snapshot(plan)
+    assert conv.snapshots_taken == 1 and full.snapshot_totals() == {}
+    assert alloc.matched_blocks(0, probe) == 6
+    alloc.abandon_snapshot(plan)               # committed: it holds a state
+    assert alloc.matched_blocks(0, probe) == 6
+    alloc.release(0, plan.table)
+
+
+def test_a_state_class_alone_resumes_from_its_longest_boundary_as_before():
+    """``StateAllocator`` outside a composite (the retention family): no
+    limit, the longest boundary that has a snapshot."""
+    served = lfm2_serving.Lfm2Served(CFG)
+    conv = [c for c in served.cache_classes if c.per_stream]
+    spec, = class_specs(conv, 6, rows=8,
+                        of_class=lambda c: served.class_geometry(c, BS),
+                        num_slots=2, block_size=BS, max_len=128,
+                        num_groups=1, dtype=jnp.float32)
+    alloc = allocator_for([spec])
+    assert type(alloc) is StateAllocator
+    base = prompt_of(31, 27)
+    plan = alloc.admit_prompt(0, 0, base, 4)
+    alloc.commit_snapshot(plan)
+    alloc.release(0, plan.table)
+    assert alloc.match_snapshot(0, np.concatenate([base, [3, 4]]))[0] == 6
+    assert alloc.match_snapshot(0, np.concatenate([base, [3, 4]]),
+                                limit=5)[0] == 0
+    assert alloc.match_limit(0, kv_cache.chain_hashes(base, BS), 6) == 6
+
+
+# --------------------------------------------------------------------- #
+# 3. The four families that were there
+# --------------------------------------------------------------------- #
+def _family(name):
+    if name == "gpt2":
+        from deepspeed_tpu.models import GPT2Config
+        return GPT2Config(hidden_size=64, num_heads=4, num_layers=2,
+                          max_seq_length=128, vocab_size=128)
+    if name == "latent":
+        from test_latent_serving import tiny as latent_tiny
+        return latent_tiny()
+    if name == "retention":
+        from test_retention_serving import tiny as retention_tiny
+        return retention_tiny()
+    from test_afmoe_serving import tiny as afmoe_tiny
+    return afmoe_tiny()
+
+
+@pytest.mark.parametrize("family", ["gpt2", "latent", "retention", "afmoe"])
+def test_the_four_families_specs_are_what_the_engine_built_before(family):
+    """One answer for every class: the specs built class by class equal the
+    specs built from the model's one set of pools (the engine's call before
+    this PR)."""
+    served = served_model(_family(family))
+    geometry = dict(num_slots=4, block_size=4, max_len=128, num_groups=1,
+                    dtype=served.cache_dtype or jnp.float32)
+    asked = {c.name: 24 for c in served.cache_classes} \
+        if len(served.cache_classes) > 1 else 24
+    new = class_specs(served.cache_classes, asked, rows=8,
+                      of_class=lambda cls: served.class_geometry(cls, 4),
+                      **geometry)
+    old = class_specs(served.cache_classes, asked, rows=8,
+                      num_heads=served.cache_heads,
+                      head_dim=served.cache_row_width,
+                      pools=served.cache_pools(4),
+                      token_row_bytes=served.token_row_bytes, **geometry)
+    assert new == old and len(new) == len(served.cache_classes)
+    alloc = allocator_for(new)
+    names = tuple(n for sp in new for n in sp.pool_names)
+    assert alloc.copy_pools == names          # a copy runs on every pool

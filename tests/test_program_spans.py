@@ -740,3 +740,126 @@ def test_decode_and_prefill_spans_carry_the_classes(tmp_path, classes_engine):
         == dispatched[-1]["window_blocks_returned"]
     assert classes["full"]["reach"] is None and classes["window"]["reach"] == 8
 
+
+
+# --------------------------------------------------------------------- #
+# (i) a model that keeps K/V pages BESIDE a state a stream (PR 45): the conv
+# layers' scopes, the page copy's own program, the span args of an
+# admission across kinds and the snapshot
+# --------------------------------------------------------------------- #
+@pytest.fixture(scope="module")
+def kinds_engine():
+    import jax.numpy as jnp
+    from deepspeed_tpu.models.lfm2 import CONV, FULL, Lfm2Config, lfm2_init
+    cfg = Lfm2Config(
+        vocab_size=128, hidden_size=64, intermediate_size=96,
+        moe_intermediate_size=32, num_hidden_layers=5, num_dense_layers=1,
+        num_attention_heads=4, num_key_value_heads=2, num_experts=8,
+        num_experts_per_tok=2, layer_types=(CONV, FULL, CONV, CONV, CONV),
+        max_position_embeddings=256, dtype=jnp.float32,
+        initializer_range=0.08)
+    eng = InferenceEngine(
+        cfg, lfm2_init(jax.random.PRNGKey(0), cfg),
+        config={"inference": {"max_slots": 4, "max_seq_len": 128,
+                              "prefill_chunk": 8, "block_size": 4,
+                              "num_blocks": {"full": 96, "conv": 12},
+                              "paged_kernel": True}},
+        mesh=build_mesh(devices=jax.devices()[:1]))
+    yield eng
+    eng.close()
+
+
+@pytest.fixture(scope="module")
+def kinds_op_names(kinds_engine):
+    eng = kinds_engine
+    G, W = eng.dp, eng.allocator.table_width
+    key, temp = eng._next_key(), np.float32(0.0)
+    return {
+        "decode": _op_names(eng._decode_fn, eng._params, *eng._pools(),
+                            eng._no_fetch, eng.last_tokens,
+                            np.ones(eng.max_slots, bool), eng.lengths,
+                            eng.block_tables, key, temp),
+        "prefill": _op_names(
+            eng._prefill_fn, eng._params, *eng._pools(),
+            np.zeros((G, eng.prefill_chunk), np.int32),
+            np.zeros((G, W), np.int32), np.zeros(G, np.int32),
+            np.zeros(G, np.int32), np.ones(G, np.int32), key, temp),
+        "copy": _op_names(eng._copy_fn, *eng._pools(),
+                          np.zeros(G, np.int32), np.ones(G, np.int32))}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("scope", [
+    "embed", "conv/conv_in_proj", "conv/conv_mix", "conv/conv_out_proj",
+    "attn/qkv_proj", "attn/kv_write", "attn/attend_full", "attn/out_proj",
+    "mlp", "moe/router", "moe/dispatch", "moe/experts", "moe/combine",
+    "lm_head", "sample"])
+def test_kinds_program_carries_scope(kinds_op_names, program, scope):
+    assert any(f"/{scope}" in n for n in kinds_op_names[program]), \
+        (program, scope)
+
+
+def test_the_page_copy_is_a_program_of_its_own_scope(kinds_op_names,
+                                                     kinds_engine):
+    assert any("/state_copy" in n for n in kinds_op_names["copy"])
+    assert kinds_engine._copy_fn.__name__ == "state_copy"
+    assert not any("/moe/shared" in n for n in kinds_op_names["decode"])
+
+
+def test_the_readers_list_names_the_kinds_scopes_and_args():
+    from deepspeed_tpu.monitor.xplane_reader import (SCOPES, SPAN_ARGS,
+                                                     scope_of, span_args)
+    assert {"conv", "conv_in_proj", "conv_mix", "conv_out_proj",
+            "state_copy", "attend_full"} <= set(SCOPES)
+    assert scope_of("jit(decode_step)/conv/conv_mix/scatter")[0] \
+        == ("conv", "conv_mix")
+    assert "prefix_lost_to_kind_tokens" in SPAN_ARGS["prefill"]
+    assert "state_pages_live" in SPAN_ARGS["decode"]
+    assert set(span_args("decode", ("full", "conv"))) \
+        - set(SPAN_ARGS["decode"]) == {
+            "full_blocks_live", "conv_blocks_live", "full_blocks_returned",
+            "conv_blocks_returned"}
+
+
+def test_decode_and_prefill_spans_carry_both_kinds(tmp_path, kinds_engine):
+    """A ``prefill`` span of an admission across kinds says what each class
+    took from its cache, what the state class resumed from, left and
+    copied, and what the pages had for nothing; a ``decode`` span carries
+    the pages in use beside the state pages rewritten, and counts key rows
+    in the attention layers only; ``snapshot()`` carries the state's sums
+    and the allocator's totals."""
+    from deepspeed_tpu.monitor.xplane_reader import span_args
+    eng = kinds_engine
+    names = [c.name for c in eng.served.cache_classes]
+    assert names == ["full", "conv"]
+    rng = np.random.default_rng(0)
+    doc = rng.integers(0, 128, size=41, dtype=np.int32)
+    eng.reset_serving_stats()
+    eng.serve([Request(rid=-1, prompt=doc, max_new_tokens=1, arrival_s=0.0)])
+    reqs = [Request(rid=i, prompt=np.concatenate(
+        [doc, rng.integers(0, 128, size=10 + i, dtype=np.int32)]),
+        max_new_tokens=8, arrival_s=0.0) for i in range(2)]  # (a page: 8)
+    report = {}
+    found = _session(tmp_path, lambda: report.update(eng.serve(reqs)))
+    dispatched = _dispatched(found)
+    assert dispatched and all(set(a) <= set(span_args("decode", names))
+                              for a in dispatched)
+    for a in dispatched:
+        assert a["state_pages_live"] == a["active"] \
+            == a["conv_blocks_live"]
+        assert a["full_blocks_live"] > a["conv_blocks_live"]
+        # one attention layer reads all of a stream; a state has no rows
+        assert a["context_tokens_in_reach"] == a["context_tokens"]
+    first = found["prefill"][0][2]
+    assert set(first) <= set(span_args("prefill", names))
+    page = eng.cache_specs[-1].block_nbytes()
+    assert first["cached_tokens_full"] == first["cached_tokens_conv"] \
+        == first["resumed_tokens"] == 40 * first["slots"]
+    assert first["snapshot_taken"] == first["slots"]
+    assert first["state_copy_bytes"] == 2 * page * 2 * first["slots"]
+    assert first["prefix_lost_to_kind_tokens"] == 0
+    state = report["state"]
+    assert state["resumed_tokens"] == 80 and state["snapshot_hits"] == 2
+    assert state["snapshots_taken"] >= 2     # the document's + a turn's
+    assert state["prefix_lost_to_kind_tokens"] == 0
+    assert set(report["cache_classes"]) == {"full", "conv"}

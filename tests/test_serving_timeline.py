@@ -404,7 +404,7 @@ def test_attend_keys_equal_the_per_slot_loop():
 
     class Spec:
         block_size, blocks_per_group, num_groups, num_layers = 16, 40, 2, 3
-        reach = None
+        reach, per_stream = None, False
 
     def shell(cost):
         eng = object.__new__(InferenceEngine)

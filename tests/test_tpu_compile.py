@@ -1008,3 +1008,142 @@ def test_pair_tensor_of_a_page_compiles_for_v5e(one_chip,
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes == 8 * 129 * 128 * 128 * 4
     assert mem.temp_size_in_bytes < 3 * mem.output_size_in_bytes
+
+
+# ------------------------------------------------------------------ #
+# The lfm2_moe family (PR 45): the ENGINE's programs over TWO KINDS of
+# cache — K/V pages of 4 query heads a K/V head on heads of 64 and a conv
+# state a stream — at the benchmark cell's shape
+# ------------------------------------------------------------------ #
+@pytest.fixture(scope="module")
+def mixed_kind_programs(topo):
+    """(specs, params bytes, {program: compiled}) of the engine's own step
+    builders for ``perfbench/configs/lfm2-24b-a2b.json`` on an engine shell
+    (see ``_serve_program``): ``decode_step``, ``prefill_step`` at both of
+    ``prefill_widths`` and the page copy."""
+    import json
+    import os
+    from types import SimpleNamespace
+    from deepspeed_tpu.inference import kv_cache
+    from deepspeed_tpu.inference.engine import (InferenceEngine,
+                                                prefill_widths)
+    from deepspeed_tpu.inference.served import served_model
+    from deepspeed_tpu.models.lfm2 import Lfm2Config, lfm2_init
+    from jax.experimental.compilation_cache import compilation_cache
+    sizes = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "perfbench", "configs", "lfm2-24b-a2b.json")))
+    inf = sizes["serve"]["inference"]
+    cfg = Lfm2Config.from_hf(
+        sizes, initializer_range=sizes["assumed"]["initializer_range"])
+    served = served_model(cfg)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+            tree)
+    params = on_chip(jax.eval_shape(lambda k: lfm2_init(k, cfg),
+                                    jax.random.PRNGKey(0)))
+    param_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(params))
+    specs = kv_cache.class_specs(
+        served.cache_classes, inf["num_blocks"], rows=inf["prefill_chunk"],
+        of_class=lambda c: served.class_geometry(c, inf["block_size"]),
+        num_slots=inf["max_slots"], block_size=inf["block_size"],
+        max_len=inf["max_seq_len"], num_groups=1, dtype=jnp.bfloat16)
+    served.table_widths = tuple(sp.max_blocks_per_slot for sp in specs)
+    eng = object.__new__(InferenceEngine)
+    eng.model_cfg, eng.dp, eng.sp, eng.mesh = served, 1, 1, None
+    eng.paged_kernel, eng.quantize = True, "none"
+    eng.prefill_chunk = inf["prefill_chunk"]
+    eng._cache_sh = {n: one for sp in specs for n in sp.pool_names}
+    eng.allocator = SimpleNamespace(copy_pools=specs[-1].pool_names)
+    pools = [on_chip(jax.ShapeDtypeStruct(sp.pool_shapes[n], sp.dtype))
+             for sp in specs for n in sp.pool_names]
+    S, J = inf["max_slots"], sum(served.table_widths)
+    i32 = lambda *shape: on_chip(jax.ShapeDtypeStruct(shape, jnp.int32))
+    fresh = lambda n: on_chip(jax.ShapeDtypeStruct((n,), jnp.bool_))
+    key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
+    temp = on_chip(jax.ShapeDtypeStruct((), jnp.float32))
+    mp = pytest.MonkeyPatch()
+    prev = jax.config.jax_enable_compilation_cache
+    out = {}
+    try:
+        mp.setattr(jax, "default_backend", lambda *a, **k: "tpu")
+        mp.setenv("DS_AUTOTUNE", "0")
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        out["decode_step"] = eng._build_decode_step().lower(
+            params, *pools, i32(S + len(served.counter_names)), i32(S),
+            fresh(S), i32(S), i32(S, J), key, temp).compile()
+        for C in prefill_widths(inf["prefill_chunk"], inf["block_size"]):
+            out[f"prefill_step.{C}"] = eng._build_prefill_step().lower(
+                params, *pools, i32(1, C), i32(1, J), i32(1), i32(1), i32(1),
+                key, temp).compile()
+        out["state_copy"] = eng._build_copy("state_copy", "state_copy").lower(
+            *pools, i32(1), i32(1)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+        mp.undo()
+    return specs, param_bytes, out
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_step.256",
+                                     "prefill_step.512"])
+def test_mixed_kind_serve_step_fits_and_updates_both_kinds_in_place(
+        mixed_kind_programs, program):
+    """Weights 10.36 GB (64 of 64 experts, 65,536 vocabulary rows, tied
+    head) + K/V pools 4.29 GB + 512 conv pages of 57,344 B, every pool
+    aliased to its output, scratch far under what is left of the chip's 16
+    GiB; the attend (4 query heads a K/V head, heads of 64: two positions a
+    lane row), the row write and the grouped expert product TPU custom
+    calls; no K/V-pool-sized op, and no layer's experts (0.6 GB) copied."""
+    from deepspeed_tpu.analysis.hlo_text import ops_in_units_of
+    specs, param_bytes, programs = mixed_kind_programs
+    full, conv = specs
+    compiled = programs[program]
+    assert abs(param_bytes - 10.356e9) < 0.01e9
+    assert full.nbytes() == 2 * 16384 * 64 * 2048
+    assert conv.block_nbytes() == 7 * 2 * 2048 * 2 and conv.page_tokens == 14
+    assert conv.pool_shapes == {"conv.conv": (7, 1, 512, 1, 32, 128)}
+    pool_bytes = full.nbytes() + conv.nbytes()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 128 * 2 ** 20, mem.temp_size_in_bytes
+    assert param_bytes + pool_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes \
+        < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    for kernel in ("_pattn_kernel", "_kv_write_kernel", "_gswiglu_kernel"):
+        calls = [line for line in text.splitlines()
+                 if f"%{kernel}" in line.split(" = ")[0]
+                 and " custom-call(" in line]
+        assert calls and all("tpu_custom_call" in c for c in calls), kernel
+    seen = ops_in_units_of(text, math.prod(full.pool_shapes["k.full"][2:]))
+    assert not [(op, n) for op, n in seen if op not in _POOL_OPS_ALLOWED]
+    one_layers_experts = 64 * 1536 * 2048
+    assert not [(op, n) for op, n in ops_in_units_of(
+        text, one_layers_experts) if op not in ("parameter", "bitcast",
+                                                 "get-tuple-element")]
+    assert "/conv/conv_mix" in text and "/attn/attend_full" in text
+
+
+def test_the_page_copy_of_a_mixed_model_leaves_the_kv_pools_alone(
+        mixed_kind_programs):
+    """``state_copy`` copies a page in the conv class's pool and hands the
+    K/V pools through where they lie: a page id means nothing there."""
+    specs, _, programs = mixed_kind_programs
+    full, conv = specs
+    compiled = programs["state_copy"]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= full.nbytes() + conv.nbytes()
+    assert mem.temp_size_in_bytes < 2 ** 20
+    text = compiled.as_text()
+    touched = [line for line in text.splitlines()
+               if " = " in line
+               and "bf16[2,1,16384,8,32,128]" in line.split(" = ")[1][:40]
+               and " parameter(" not in line and "tuple(" not in line]
+    assert not touched, touched[:3]
+    assert "dynamic-update-slice" in text
