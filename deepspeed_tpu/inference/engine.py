@@ -270,6 +270,9 @@ class InferenceEngine:
         self.prefill_widths = prefill_widths(self.prefill_chunk,
                                              self.block_size)
         self._prefill_warmed = len(self.prefill_widths) < 2
+        # The model's own word, read once: its chunk program leaves a
+        # snapshot at a row inside the chunk (else the plan cuts there).
+        self._freeze_in_chunk = bool(served.freezes_in_chunk)
         self.spec_k = int(self.icfg.spec_k)
         self.replica = str(self.icfg.replica)
         # Pallas paged-attention kernel vs the one-hot pool contraction.
@@ -529,16 +532,19 @@ class InferenceEngine:
         group (single admissions leave the other groups' rows DEAD —
         uniform program, writes land nowhere). ``tokens`` is ``[G,
         width]``, one of ``prefill_widths``: the same function, compiled
-        once a width (``_warm_prefill_widths``)."""
+        once a width (``_warm_prefill_widths``). A model that freezes its
+        state inside a chunk (``ServedModel.freezes_in_chunk``) takes two
+        more ``[G]`` operands after ``active``, the snapshot's row and
+        page; no other model's program has them."""
         served = self.served
         n = len(self._cache_sh)
 
         def prefill_step(params, *args):
-            pools, (tokens, bt_rows, start, last_idx, active, key,
+            pools, (tokens, bt_rows, start, last_idx, active, *freeze, key,
                     temperature) = args[:n], args[n:]
             p = self._runtime_params(params)
             logits, pools, counters = served.prefill_chunk(
-                p, pools, tokens, bt_rows, start, last_idx, active,
+                p, pools, tokens, bt_rows, start, last_idx, active, *freeze,
                 paged_kernel=self.paged_kernel, mesh=self.mesh)
             sampled = sample_tokens(logits, key, temperature)
             return (*pools, with_counters(sampled, counters), logits)
@@ -571,7 +577,8 @@ class InferenceEngine:
         ``src[g]`` to block ``dst[g]`` of every group, every layer, in
         the donated pools — a copy-on-write fork of a block, a snapshot
         into a stream's own page at admission, a stream's page into a
-        snapshot when prefill reaches its boundary. ``name`` and ``scope``
+        snapshot when prefill reaches its boundary (a model whose chunk
+        program cannot leave it itself). ``name`` and ``scope``
         are the allocator's (``copy_program``), and so are the pools it
         copies in (``copy_pools``: of a model's classes the one that
         copies; a block id means nothing in another class's pools), the
@@ -876,7 +883,8 @@ class InferenceEngine:
         the block allocator, run the merged copy-on-write fork, and lay
         out each admission's unshared tail in chunks. Returns (pools,
         [(slot, group, plan, prompt, plen)], [([(first token, tokens) per
-        chunk], index of the chunk a snapshot follows or None)])."""
+        chunk], index of the chunk that reaches the snapshot's boundary —
+        with its last row, where the tail was cut there — or None)])."""
         G = self.dp
         J = self.allocator.table_width
         Sg = self.cache_spec.slots_per_group
@@ -912,19 +920,22 @@ class InferenceEngine:
             pools = self._copy_blocks(pools, forks)
         # Chunk schedule: admission a runs chunks over its unshared
         # tail; all admissions advance together, groups whose tail is
-        # done go inactive (writes land nowhere).  A tail is cut where a
-        # snapshot is due (``plan.snapshot_at``): a state can only be
-        # frozen at the end of a chunk program.
+        # done go inactive (writes land nowhere).  Where a snapshot is due
+        # (``plan.snapshot_at``) the chunk program that passes its
+        # boundary leaves it, if the model's can
+        # (``ServedModel.freezes_in_chunk``); else the tail is cut there,
+        # since such a state can only be frozen at the end of a program.
         tails = []
         for slot, group, plan, prompt, plen in plans:
             cuts = [plan.matched, plen]
-            if plan.matched < plan.snapshot_at < plen:
+            if not self._freeze_in_chunk \
+                    and plan.matched < plan.snapshot_at < plen:
                 cuts.insert(1, plan.snapshot_at)
             chunks = [(a, min(chunk, hi - a)) for lo, hi in
                       zip(cuts, cuts[1:]) for a in range(lo, hi, chunk)]
-            snap_after = next((i for i, (a, n) in enumerate(chunks)
-                               if a + n == plan.snapshot_at), None)
-            tails.append((chunks, snap_after))
+            snap_in = next((i for i, (a, n) in enumerate(chunks)
+                            if a < plan.snapshot_at <= a + n), None)
+            tails.append((chunks, snap_in))
             self._last_admit[slot] = {
                 "cached_tokens": int(plan.matched), "chunks": len(chunks),
                 "cow_fork": plan.cow_src is not None}
@@ -942,7 +953,10 @@ class InferenceEngine:
         ``prefill_chunk`` span each), as wide as the narrowest of
         ``prefill_widths`` that holds the longest active group's rows
         (only a tail's last chunk, or the one before a snapshot cut, is
-        short), and store the cache. Returns ([(tok_g, logits_g) device
+        short), and store the cache. A snapshot due is left by the chunk
+        program that reaches its boundary (``_freeze_in_chunk``: its row
+        and page are the program's operands) or by a copy of the
+        stream's page behind it. Returns ([(tok_g, logits_g) device
         arrays per chunk index], {slot: (ci, group) of its last chunk},
         [width per chunk index])."""
         G = self.dp
@@ -960,9 +974,10 @@ class InferenceEngine:
                 starts = np.zeros(G, np.int32)
                 last_idxs = np.zeros(G, np.int32)
                 act = np.zeros(G, np.int32)
-                snaps = {}   # group -> (own page, snapshot page)
-                frozen = []  # their plans: committed once the copy is out
-                for (slot, group, plan, prompt, plen), (chunks, snap_after) \
+                freeze = self._no_freeze()   # (row, page) [G], or ()
+                snaps = {}   # group -> (own page, snapshot page): copied
+                frozen = []  # plans whose snapshot this dispatch leaves
+                for (slot, group, plan, prompt, plen), (chunks, snap_in) \
                         in zip(plans, tails):
                     if ci >= len(chunks):
                         continue
@@ -980,25 +995,41 @@ class InferenceEngine:
                     last_idxs[group] = n - 1
                     if ci == len(chunks) - 1:
                         held[slot] = (ci, group)
-                    if ci == snap_after:
-                        snaps[group] = (plan.page, plan.snapshot_page)
+                    if ci == snap_in:
                         frozen.append(plan)
+                        if freeze:
+                            freeze[0][group] = plan.snapshot_at - first - 1
+                            freeze[1][group] = plan.snapshot_page
+                            plan.snapshot_in_program = True
+                        else:
+                            snaps[group] = (plan.page, plan.snapshot_page)
                 with self.telemetry.span("prefill_chunk", ci=ci,
                                          active_groups=int(act.sum()),
                                          rows=width):
                     *pools, tok_g, logits_g = self._prefill_fn(
                         self._params, *pools, toks, bt_rows, starts,
-                        last_idxs, act, self._next_key(), temp)
+                        last_idxs, act, *freeze, self._next_key(), temp)
                 if snaps:
                     pools = self._copy_blocks(pools, snaps)
-                    for plan in frozen:
-                        self.allocator.commit_snapshot(plan)
+                # (dispatched: the device's order makes the page whole
+                # before anything can resume from it)
+                for plan in frozen:
+                    self.allocator.commit_snapshot(plan)
                 steps.append((tok_g, logits_g))
                 widths.append(width)
         finally:
             # also where a chunk raised: the pools before it were donated
             self._store_pools(pools)
         return steps, held, widths
+
+    def _no_freeze(self) -> Tuple[np.ndarray, ...]:
+        """``prefill_step``'s operands for a snapshot left in the program,
+        no group leaving one yet: (chunk row [G], page [G]) — or none at
+        all for a model whose program takes none."""
+        if not self._freeze_in_chunk:
+            return ()
+        return (np.zeros(self.dp, np.int32),
+                np.full(self.dp, kv_cache.DEAD_BLOCK, np.int32))
 
     def _warm_prefill_widths(self) -> None:
         """Build ``prefill_step`` at every width of ``prefill_widths``
@@ -1016,8 +1047,8 @@ class InferenceEngine:
             for width in reversed(self.prefill_widths):
                 *pools, _, _ = self._prefill_fn(
                     self._params, *pools, np.zeros((G, width), np.int32),
-                    dead, zeros, zeros, zeros, self._base_rng,
-                    np.float32(0.0))
+                    dead, zeros, zeros, zeros, *self._no_freeze(),
+                    self._base_rng, np.float32(0.0))
         finally:
             self._store_pools(pools)
         self._prefill_warmed = True

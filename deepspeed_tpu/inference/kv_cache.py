@@ -65,7 +65,13 @@ nothing can be shared by reference count: the prefix cache keeps SNAPSHOTS
 there, retained LRU like a cached block), ``match_snapshot`` finds the
 longest boundary that has one, and admission COPIES it into the stream's
 own page (``copy_pages``).  Which boundaries are worth a page is
-``StateAllocator.snapshot_boundary``.
+``StateAllocator.snapshot_boundary``.  HOW a snapshot is frozen is the
+model's: a state that only the end of a chunk program can freeze (a
+retention layer's) has its prompt cut at the boundary and its page copied
+there; one that is a gather of the chunk's rows (a conv layer's) is written
+into the snapshot's page by the chunk program that reaches the boundary
+(``ServedModel.freezes_in_chunk``; ``AdmitPlan.snapshot_in_program``), and
+``span_args`` counts a snapshot's copy only where one was dispatched.
 
 A served model may also keep its layers in several CLASSES, each with its
 own pools, block table, free list, reference counts and prefix index (one
@@ -798,7 +804,7 @@ class AdmitPlan:
     matched: int
     cow_src: Optional[int] = None
     cow_dst: Optional[int] = None
-    # Per-stream pools: the stream's own ``page``; freeze it into
+    # Per-stream pools: the stream's own ``page``; freeze its state into
     # ``snapshot_page`` when prefill has consumed ``snapshot_at`` tokens
     # (0: no snapshot), then ``commit_snapshot`` it under
     # ``snapshot_hash``.
@@ -806,6 +812,9 @@ class AdmitPlan:
     snapshot_at: int = 0
     snapshot_page: Optional[int] = None
     snapshot_hash: int = 0
+    # Set by the engine where the chunk program that reached the boundary
+    # froze the state into ``snapshot_page`` itself: no copy was dispatched.
+    snapshot_in_program: bool = False
     # A bounded class: how many cached blocks the stream shares (those in
     # reach of ``matched``); several classes: {class name: tokens it took
     # from its cache}.
@@ -832,6 +841,7 @@ class StateAllocator(BlockAllocator):
     def __init__(self, spec: PagedKVCacheSpec):
         super().__init__(spec)
         self.snapshots_taken = 0
+        self.snapshots_in_program = 0
         self.snapshot_hits = 0
 
     def need_blocks(self, prompt_len: int, max_new: int,
@@ -903,9 +913,10 @@ class StateAllocator(BlockAllocator):
         and, where ``snapshot_boundary`` says so and a page can be had,
         the page that will hold this prompt's own snapshot
         (``snapshot_at``, ``snapshot_page``: the engine freezes the state
-        there when prefill reaches it and THEN enters it into the prefix
-        cache, ``commit_snapshot``; until then the page is out of every
-        list and nothing can match it)."""
+        there when prefill reaches it — a copy of the stream's page behind
+        a cut, or the chunk program's own second write — and THEN enters
+        it into the prefix cache, ``commit_snapshot``; until then the page
+        is out of every list and nothing can match it)."""
         self._gate(group, prompt, max_new, spec_k, limit)
         bs = self.spec.block_size
         n, src, h_last = self.match_snapshot(group, prompt, limit)
@@ -930,8 +941,9 @@ class StateAllocator(BlockAllocator):
 
     def commit_snapshot(self, plan: "AdmitPlan") -> None:
         """The engine has frozen ``plan``'s state into its snapshot page
-        (the copy is dispatched): key the page by the chain hash of its
-        boundary and retain it, most recently used."""
+        (the copy, or the chunk program that writes it, is dispatched):
+        key the page by the chain hash of its boundary and retain it, most
+        recently used."""
         g, page, h = plan.group, plan.snapshot_page, plan.snapshot_hash
         if h in self._hash_index[g]:            # another admission's is in
             self._free[g].append(page)
@@ -940,6 +952,7 @@ class StateAllocator(BlockAllocator):
         self._block_hash[g][page] = h
         self._lru[g][page] = None
         self.snapshots_taken += 1
+        self.snapshots_in_program += bool(plan.snapshot_in_program)
 
     def abandon_snapshot(self, plan: "AdmitPlan") -> None:
         """Prefill failed: a snapshot page that was never committed goes
@@ -954,21 +967,27 @@ class StateAllocator(BlockAllocator):
     def span_args(self, plans: Optional[Sequence["AdmitPlan"]] = None,
                   live: int = 0) -> Dict[str, int]:
         """``prefill``: tokens resumed from a snapshot (what
-        ``cached_tokens`` means here), snapshots the admissions took, and
-        the bytes their page copies moved (read + written); ``decode``:
-        the pages its streams rewrite."""
+        ``cached_tokens`` means here), snapshots the admissions took,
+        those of them a chunk program froze itself, and the bytes the
+        page copies that WERE dispatched moved (read + written: a
+        snapshot into the stream's page, the page into a snapshot no
+        program froze); ``decode``: the pages its streams rewrite."""
         if plans is None:
             return {"state_pages_live": int(live)}
         return {
             "resumed_tokens": sum(int(p.matched) for p in plans),
             "snapshot_taken": sum(p.snapshot_page is not None
                                   for p in plans),
+            "snapshot_in_program": sum(p.snapshot_in_program
+                                       for p in plans),
             "state_copy_bytes": 2 * self.spec.block_nbytes() * sum(
-                (p.cow_src is not None) + (p.snapshot_page is not None)
+                (p.cow_src is not None) + (p.snapshot_page is not None
+                                           and not p.snapshot_in_program)
                 for p in plans)}
 
     def snapshot_totals(self) -> Dict[str, int]:
         return {"snapshots_taken": self.snapshots_taken,
+                "snapshots_in_program": self.snapshots_in_program,
                 "snapshot_hits": self.snapshot_hits,
                 "snapshots_evicted": self.reclaimed}
 

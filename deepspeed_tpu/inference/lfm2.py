@@ -23,7 +23,14 @@ over each run of ``conv_L_cache`` of them, and the page rewritten with the
 rows that end at the stream's last LIVE row — in place in the donated pool,
 for live streams only (a dead slot, an inactive group or padding writes
 nothing).  ``decode`` has one row a stream, ``prefill_chunk`` a chunk of
-one stream a group.  A state cannot be rolled back over rejected drafts:
+one stream a group.  The state after ANY row of a chunk is a gather of the
+chunk's ``z`` rows, so the model declares ``freezes_in_chunk``: the chunk
+that reaches a snapshot's boundary is handed the boundary's row and the
+snapshot's page and writes the rows that end THERE into that page as well —
+the same gather at a second index, a second scatter into the donated pool —
+and the engine neither cuts the prompt at the boundary nor copies the
+stream's page (a turn of a session is one pass over the experts, not two).
+A state cannot be rolled back over rejected drafts:
 ``verify`` raises, and ``inference.spec_k`` must be 0.  The attention
 layers run ``ops.paged_attention`` as ``inference/afmoe.py``'s unbounded
 class does (``group`` query heads a K/V head as query rows).
@@ -77,15 +84,17 @@ def conv_tile(cfg: Lfm2Config) -> Tuple[int, int, int]:
 
 
 def _forward(params, pools, x, bt_g, pos_g, live, cfg: Lfm2Config,
-             widths, paged_kernel: bool, mesh):
+             widths, paged_kernel: bool, mesh, freeze=None):
     """All layers: x [S, K, H] with its streams' table rows bt_g [G, Sg,
     W] (the classes' rows side by side, ``widths`` wide), row positions
     pos_g [G, Sg, K] and ``live`` [S, K]: the rows that are traffic (a
     live stream's, and no padding; a stream's live rows come first).  The
     others write no cache row and no page, attend nothing, get no expert
     row and are not counted; what they compute nobody reads.  ``pools``:
-    every class's in ``cache_classes`` order.  Returns (x', pools',
-    counters)."""
+    every class's in ``cache_classes`` order.  ``freeze``: (row [S], page
+    [S]) — a stream's conv state as it stands after chunk row ``row`` goes
+    into ``page`` too (a snapshot; ``DEAD_BLOCK``: none), or None.
+    Returns (x', pools', counters)."""
     G, Sg, K = pos_g.shape
     S, H = G * Sg, x.shape[-1]
     nH, D, grp = cfg.num_attention_heads, cfg.head_dim, cfg.group
@@ -99,17 +108,29 @@ def _forward(params, pools, x, bt_g, pos_g, live, cfg: Lfm2Config,
         if cls.per_stream:
             page = bt[:, :, 0].reshape(S)
             n_live = live.sum(axis=1).astype(jnp.int32)          # [S]
+            nowhere = pools[at_pool].shape[2]
+
+            def ending_after(n):
+                """A stream's state once ``n`` of its rows are consumed: the
+                ``conv_L_cache - 1`` rows up to there, in [page | rows]."""
+                return n[:, None] + jnp.arange(cfg.conv_L_cache - 1,
+                                               dtype=jnp.int32)[None]
             conv = dict(
                 at=at_pool, layer=0, g=jnp.arange(S, dtype=jnp.int32) // Sg,
                 page=jnp.maximum(page, 0),
                 # where the page goes back: nowhere for a stream without a
                 # live row (index B is out of range: dropped)
-                to=jnp.where((page >= 0) & (n_live > 0), page,
-                             pools[at_pool].shape[2]),
+                to=[jnp.where((page >= 0) & (n_live > 0), page, nowhere)],
                 carried=(pos[:, 0] > 0)[:, None, None],
-                # the rows that end at the last live one, in [page | rows]
-                keep=jnp.maximum(n_live, 1)[:, None]
-                + jnp.arange(cfg.conv_L_cache - 1, dtype=jnp.int32)[None])
+                # the rows that end at the last live one
+                keep=[ending_after(jnp.maximum(n_live, 1))])
+            if freeze is not None:
+                # a second write: the rows that end at the snapshot's row,
+                # to the snapshot's page
+                row, snap = freeze
+                conv["to"].append(jnp.where(
+                    (page >= 0) & (n_live > 0) & (snap >= 0), snap, nowhere))
+                conv["keep"].append(ending_after(jnp.clip(row + 1, 1, K)))
             at_pool += 1
             continue
         kc = pools[at_pool]
@@ -181,10 +202,11 @@ def _forward(params, pools, x, bt_g, pos_g, live, cfg: Lfm2Config,
                 taps = p["conv_k"].astype(jnp.float32)
                 mixed = sum(zc[:, j:j + K].astype(jnp.float32) * taps[:, j]
                             for j in range(L))
-                new = jnp.take_along_axis(zc, c["keep"][:, :, None], axis=1)
-                pool = pool.at[layer, c["g"], c["to"]].set(
-                    new.reshape((S,) + pool.shape[3:]).astype(pool.dtype),
-                    mode="drop")
+                for keep, to in zip(c["keep"], c["to"]):
+                    new = jnp.take_along_axis(zc, keep[:, :, None], axis=1)
+                    pool = pool.at[layer, c["g"], to].set(
+                        new.reshape((S,) + pool.shape[3:]).astype(pool.dtype),
+                        mode="drop")
                 y = (gate.astype(jnp.float32) * mixed).astype(x.dtype)
             with jax.named_scope("conv_out_proj"):
                 x = x + matmul(y, p["w_out"])
@@ -236,6 +258,9 @@ class Lfm2Served(AfmoeServed):
     and expert layers that hold every expert answers is ``AfmoeServed``'s
     (the K/V tiles, the attend's dimensions and step counts, the expert
     counters); this family's own is the second KIND of cache."""
+    # A conv layer's state at ANY row of a chunk is a gather of the chunk's
+    # ``z`` rows: the program that passes a snapshot's boundary leaves it.
+    freezes_in_chunk = True
 
     @property
     def init_fn(self) -> Callable:
@@ -282,12 +307,17 @@ class Lfm2Served(AfmoeServed):
         return _head(params, x[:, 0], cfg), pools, counters
 
     def prefill_chunk(self, params, pools, tokens, bt_rows, start,
-                      last_idx, active, *, paged_kernel, mesh=None):
+                      last_idx, active, freeze_idx=None, freeze_page=None,
+                      *, paged_kernel, mesh=None):
         """``decode.gpt2_prefill_chunk_paged``'s contract; rows past
         ``last_idx`` (a last chunk's padding) are dead rows.  The page a
         chunk starts from is whatever the stream's own holds — a snapshot
         the engine copied there, or the chunk before — and zeros at
-        position 0."""
+        position 0.  ``freezes_in_chunk``: a group's state as it stands
+        after chunk row ``freeze_idx`` (a live one) goes into page
+        ``freeze_page`` as well, a snapshot at that row's boundary
+        (``DEAD_BLOCK``: the group leaves none in this chunk; without the
+        operands the program writes the stream's own page only)."""
         cfg = self.cfg
         G, Cn = tokens.shape
         cols = lax.broadcasted_iota(jnp.int32, (G, Cn), 1)
@@ -298,7 +328,8 @@ class Lfm2Served(AfmoeServed):
         x, pools, counters = _forward(
             params, pools, _embed(params, tokens, cfg), bt_g,
             pos[:, None, :], live, cfg, self._widths(bt_rows), paged_kernel,
-            mesh)
+            mesh, freeze=None if freeze_idx is None
+            else (freeze_idx, freeze_page))
         oh = (cols == last_idx[:, None]).astype(x.dtype)
         h_last = jnp.einsum("gc,gch->gh", oh, x)
         return _head(params, h_last, cfg), pools, counters

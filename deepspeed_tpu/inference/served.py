@@ -23,7 +23,9 @@ ask a ``ServedModel`` for:
   that writes the new rows into the pools in place and returns ``(logits,
   pools)`` (and the model's counters, see below): ``decode`` (one token a
   slot), ``verify`` (K tokens a slot, speculative), ``prefill_chunk`` (one
-  chunk of one slot a group);
+  chunk of one slot a group; a model whose per-stream state is a gather of
+  a chunk's rows may also freeze it at a row INSIDE the chunk into a second
+  page, and says so: ``freezes_in_chunk``);
 - **the cache's cost a token** for the engine's analytic counters:
   ``cache_cost(keys, ...)`` = (FLOPs, cache bytes) a layer spends on one
   query token, which MAY depend on the ``keys`` rows in reach (an attend:
@@ -76,6 +78,14 @@ class ServedModel:
     # it when it has sized the tables (a window's ring depends on its
     # prefill chunk); a model of several classes splits a table row by it.
     table_widths: Optional[Tuple[int, ...]] = None
+    # Whether ``prefill_chunk`` can FREEZE a ``per_stream`` state at a row
+    # inside its chunk into a second page (it then takes two more ``[G]``
+    # int32 operands after ``active``: the chunk row the frozen state ends
+    # at, and the page that receives it, ``DEAD_BLOCK`` for none).  The
+    # engine then leaves a snapshot from the chunk program that passes its
+    # boundary; where a model says no (the default) it cuts the prompt
+    # there and copies the stream's page.
+    freezes_in_chunk: bool = False
 
     def __init__(self, cfg):
         self.cfg = cfg

@@ -81,10 +81,13 @@ SPAN_ARGS = {
     # hc_res_err_max: a model with several residual streams: the largest
     # deviation of a row or column sum of H_res from 1 over the live rows
     # of the execution(s) fetched (what the Sinkhorn iterations left).
-    # resumed_tokens / snapshot_taken / state_copy_bytes: a per-stream
-    # state pool's admission (inference/kv_cache.py): prompt tokens the
-    # snapshot it resumed from covers (what cached_tokens means there),
-    # snapshots the admission left, bytes its page copies read + wrote.
+    # resumed_tokens / snapshot_taken / snapshot_in_program /
+    # state_copy_bytes: a per-stream state pool's admission
+    # (inference/kv_cache.py): prompt tokens the snapshot it resumed from
+    # covers (what cached_tokens means there), snapshots the admission
+    # left, those of them the chunk program that reached the boundary
+    # froze itself (a model that can: no cut, no copy), bytes the page
+    # copies it did dispatch read + wrote.
     # prefix_lost_to_kind_tokens: a model that keeps pages BESIDE a state
     # a stream: prompt tokens its page classes had cached beyond the
     # boundary the state class had a snapshot at (prefilled again).
@@ -96,7 +99,8 @@ SPAN_ARGS = {
                 "chunks", "rows_computed", "moe_held_pairs", "moe_held_max",
                 "moe_held_mean", "moe_held_empty", "moe_held_pair_share",
                 "hc_res_err_max",
-                "resumed_tokens", "snapshot_taken", "state_copy_bytes",
+                "resumed_tokens", "snapshot_taken", "snapshot_in_program",
+                "state_copy_bytes",
                 "prefix_lost_to_kind_tokens"),
     "prefill_chunk": ("ci", "active_groups", "rows"),
     # A decode span holds the DISPATCH of one iteration and the FETCH of

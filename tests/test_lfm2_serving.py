@@ -268,10 +268,11 @@ def test_a_stream_resumed_from_pages_and_a_snapshot_equals_a_cold_one(name):
 
 @pytest.mark.parametrize("n", [25, 27, 31, 32, 5, 1])
 def test_chunks_and_cuts_change_nothing(n):
-    """A prompt in chunks of 8 with a cut at its snapshot's boundary (n =
-    25: a last chunk of ONE live row; 32: the snapshot after the last
-    chunk; 5, 1: no snapshot, a padded chunk) against the same prompt in
-    one padded chunk with no snapshot."""
+    """A prompt in chunks of 8 whose snapshot a chunk program leaves on its
+    way (n = 25: a last chunk of ONE live row, the snapshot at the last row
+    of the chunk before, as for 27; 31: inside the last chunk; 32: at the
+    prompt's last row; 5, 1: no snapshot, a padded chunk) against the same
+    prompt in one padded chunk with no snapshot."""
     chunked, whole = engine("chunked"), engine("whole")
     held = [through(whole, prompt_of(40 + i, 3), steps=0, keep=True)
             for i in range(3)]            # the pages a snapshot would take
@@ -284,7 +285,7 @@ def test_chunks_and_cuts_change_nothing(n):
             whole.release_slot(int(s))
     at = n // BS * BS if n >= 8 else 0        # (a page is worth 8 tokens)
     assert info["snapshot_at"] == at
-    assert info["chunks"] == -(-at // 8) + -(-(n - at) // 8)
+    assert info["chunks"] == -(-n // 8)         # (no cut at the boundary)
     assert winfo["chunks"] == 1 and winfo["snapshot_at"] == 0
     assert toks == wtoks
     np.testing.assert_allclose(got, wgot, atol=2e-5)
@@ -502,6 +503,318 @@ def test_a_state_class_alone_resumes_from_its_longest_boundary_as_before():
     assert alloc.match_snapshot(0, np.concatenate([base, [3, 4]]),
                                 limit=5)[0] == 0
     assert alloc.match_limit(0, kv_cache.chain_hashes(base, BS), 6) == 6
+
+
+# --------------------------------------------------------------------- #
+# 2b. The snapshot a chunk program leaves on its way (PR 46)
+# --------------------------------------------------------------------- #
+class CutServed(lfm2_serving.Lfm2Served):
+    """The family as it was served before: the model says its state cannot
+    be frozen inside a chunk, so the engine cuts the prompt at the
+    snapshot's boundary and copies the stream's page."""
+    freezes_in_chunk = False
+
+
+def pair(chunk, dp=1):
+    """Two engines alike but for the model's word — the chunk program
+    freezes the snapshot, or a cut + ``state_copy`` does — built once a
+    chunk size (3: a boundary can be the FIRST row of a chunk, which a
+    chunk that shares a factor with the block size never sees)."""
+    key = ("pair", chunk, dp)
+    if key not in _MADE:
+        conf = dict(max_slots=2 * dp, max_seq_len=132 if chunk == 3 else 128,
+                    block_size=BS, prefill_chunk=chunk, paged_kernel=False,
+                    num_blocks={"full": 96 * dp, "conv": 16 * dp})
+        mesh = build_mesh(dp=dp, devices=jax.devices()[:dp])
+        _MADE[key] = tuple(
+            InferenceEngine(model, params(), config={"inference": conf},
+                            mesh=mesh)
+            for model in (CFG, CutServed(CFG)))
+    return _MADE[key]
+
+
+def snapshot_page_of(eng, prompt, group=0):
+    """The conv page (every layer) that holds the snapshot at ``prompt``'s
+    last full block, and its index."""
+    conv = eng.allocator.classes[-1]
+    page = conv._hash_index[group][kv_cache.chain_hashes(prompt, BS)[-1]]
+    return np.asarray(eng.cache["conv.conv"])[:, group, page], page
+
+
+@pytest.mark.parametrize("chunk,history,n,row", [
+    (8, 0, 12, 3),       # the middle of the second of two chunks
+    (8, 0, 8, 7),        # a chunk's last row, the prompt's last too
+    (8, 0, 11, 7),       # a chunk's last row, three rows behind it
+    (8, 0, 31, 3),       # inside the fourth chunk
+    (8, 18, 13, 3),      # a turn resumed at 16: inside its second chunk
+    (8, 18, 7, 7),       # a turn resumed at 16: its one chunk's last row
+    (3, 0, 17, 0),       # the FIRST row of a chunk (chunks of 3)
+    (3, 0, 10, 1),       # the middle row
+    (3, 0, 13, 2),       # the last row, one more chunk behind it
+])
+def test_a_snapshot_frozen_in_the_program_is_the_cut_and_copys(
+        chunk, history, n, row):
+    """The page a chunk program freezes at the snapshot's row, bit for bit
+    the page the cut + ``state_copy`` path leaves; first token, logits and
+    every pool row of the two engines agree."""
+    frozen, cut = pair(chunk)
+    assert frozen._freeze_in_chunk and not cut._freeze_in_chunk
+    seed = 1000 * chunk + 10 * n + history
+    prompt = prompt_of(seed, n)
+    if history:
+        first = prompt_of(seed + 1, history)
+        for eng in (frozen, cut):
+            through(eng, first, steps=0)
+        prompt = np.concatenate([first, prompt])
+    resumed = history // BS * BS
+    at = len(prompt) // BS * BS
+    assert (at - resumed - 1) % chunk == row
+    got = through(frozen, prompt)
+    want = through(cut, prompt)
+    assert got[2]["snapshot_at"] == want[2]["snapshot_at"] == at
+    assert got[2]["cached_tokens"] == want[2]["cached_tokens"] == resumed
+    # one program fewer wherever the boundary is not a chunk's last row
+    assert got[2]["chunks"] == -(-(len(prompt) - resumed) // chunk)
+    assert want[2]["chunks"] == -(-(at - resumed) // chunk) \
+        + -(-(len(prompt) - at) // chunk)
+    page, index = snapshot_page_of(frozen, prompt)
+    cpage, cindex = snapshot_page_of(cut, prompt)
+    assert index == cindex
+    np.testing.assert_array_equal(page, cpage)
+    assert got[0] == want[0]
+    np.testing.assert_allclose(got[1], want[1], atol=2e-5)
+    for name in frozen.cache:
+        np.testing.assert_allclose(np.asarray(frozen.cache[name]),
+                                   np.asarray(cut.cache[name]), atol=1e-5,
+                                   err_msg=name)
+    # and it IS the state at the boundary
+    state = ref(prompt, [at - 1], state_at=at - 1)[2]
+    np.testing.assert_allclose(
+        page.reshape(state.shape), state, atol=1e-5)
+    taken = frozen.allocator.snapshot_totals()
+    assert taken["snapshots_in_program"] == taken["snapshots_taken"]
+    assert cut.allocator.snapshot_totals()["snapshots_in_program"] == 0
+
+
+@pytest.mark.parametrize("name", ["chunked", "kernels"])
+def test_a_turn_resumed_from_a_snapshot_frozen_in_a_program_equals_a_cold_one(
+        name):
+    """The first turn's snapshot (at 20) lies INSIDE its third chunk; the
+    next turn copies it and resumes there: logits and pages of an engine
+    that never saw the first, and the reference's."""
+    eng, cold = engine(name), engine("whole")
+    seed = 300 + 2 * (name == "kernels")
+    first = prompt_of(seed, 21)
+    info = through(eng, first, steps=1)[2]
+    assert info["snapshot_at"] == 20 and info["chunks"] == 3
+    turn = np.concatenate([first, prompt_of(seed + 1, 10)])
+    assert eng.prefix_match_tokens(turn) == 20
+    toks, got, info, page0, page1 = through(eng, turn)
+    assert info["cached_tokens"] == 20 and info["cow_fork"]
+    assert info["cached_by_class"] == {"full": 20, "conv": 20}
+    assert info["chunks"] == 2 and info["snapshot_at"] == 28
+    ctoks, cgot, cinfo, cpage0, cpage1 = through(cold, turn)
+    assert cinfo["cached_tokens"] == 0 and ctoks == toks
+    np.testing.assert_allclose(got, cgot, atol=2e-5)
+    np.testing.assert_allclose(page0, cpage0, atol=1e-5)
+    np.testing.assert_allclose(page1, cpage1, atol=1e-5)
+    seq = np.concatenate([turn, toks[:-1]])
+    want, _, state = ref(seq, [30, 31, 32], state_at=32)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    np.testing.assert_allclose(page1, state, atol=1e-5)
+    # the reference resumed WITHOUT the state at 20 is far from it
+    zeroed = ref(seq, [30, 31, 32], zero_state_at=20)[0]
+    assert np.abs(got - zeroed).max() > 100 * 2e-5
+
+
+def _chunk_call(eng, prompt, slot_page, **operands):
+    """One hand-made dispatch of ``prefill_step`` over ``prompt`` at
+    position 0 into conv page ``slot_page`` (and no K/V block: the rows'
+    writes land nowhere); returns the conv pool before and after."""
+    G, J = eng.dp, eng.allocator.table_width
+    toks = np.zeros((G, eng.prefill_chunk), np.int32)
+    toks[0, :len(prompt)] = prompt
+    bt = np.full((G, J), kv_cache.DEAD_BLOCK, np.int32)
+    bt[0, -1] = slot_page
+    args = dict(start=np.zeros(G, np.int32),
+                last_idx=np.full(G, len(prompt) - 1, np.int32),
+                active=np.ones(G, np.int32),
+                freeze_idx=np.zeros(G, np.int32),
+                freeze_page=np.full(G, kv_cache.DEAD_BLOCK, np.int32))
+    args.update({k: np.asarray(v, np.int32) for k, v in operands.items()})
+    before = np.asarray(eng.cache["conv.conv"]).copy()
+    *pools, _, _ = eng._prefill_fn(
+        eng._params, *eng._pools(), toks, bt, *args.values(),
+        eng._base_rng, np.float32(0.0))
+    eng._store_pools(pools)
+    return before, np.asarray(eng.cache["conv.conv"])
+
+
+def test_an_inactive_group_or_one_without_a_snapshot_due_writes_no_second_page():
+    """The program's second write lands only where an ACTIVE group names a
+    page: a group that leaves no snapshot in the chunk rewrites its own page
+    alone, an inactive one nothing, whatever the operands hold."""
+    eng = pair(8)[0]
+    prompt = prompt_of(70, 8)
+    own, snap = 12, 14               # (free pages: nothing resumes from them)
+    others = [b for b in range(16) if b not in (own, snap)]
+    before, after = _chunk_call(eng, prompt, own)          # no page named
+    np.testing.assert_array_equal(after[:, :, others + [snap]],
+                                  before[:, :, others + [snap]])
+    assert np.abs(after[:, 0, own] - before[:, 0, own]).max() > 0
+    before, after = _chunk_call(eng, prompt, own, active=[0],
+                                freeze_idx=[3], freeze_page=[snap])
+    np.testing.assert_array_equal(after, before)            # inactive
+    before, after = _chunk_call(eng, prompt, own, freeze_idx=[3],
+                                freeze_page=[snap])
+    np.testing.assert_array_equal(after[:, :, others], before[:, :, others])
+    # the snapshot's page: what a chunk of rows 0-3 alone leaves in its own
+    # (no K/V block here, so only the layer ahead of the attention layer is
+    # the reference's); the own page: the state after row 7
+    np.testing.assert_allclose(
+        after[0, 0, snap].reshape(2, 64),
+        ref(prompt, [3], state_at=3)[2][0], atol=1e-5)
+    np.testing.assert_allclose(
+        after[0, 0, own].reshape(2, 64),
+        ref(prompt, [7], state_at=7)[2][0], atol=1e-5)
+    frozen = after[:, 0, snap].copy()
+    _, after = _chunk_call(eng, prompt[:4], own)
+    np.testing.assert_array_equal(after[:, 0, own], frozen)
+    # a boundary that IS the last live row: the same rows, to two pages
+    before, after = _chunk_call(eng, prompt, own, freeze_idx=[7],
+                                freeze_page=[snap])
+    np.testing.assert_array_equal(after[:, 0, snap], after[:, 0, own])
+
+
+def test_a_failed_prefill_returns_the_snapshot_page_no_program_froze():
+    """The chunk that would leave the snapshot raises: its page goes back
+    (``abandon_snapshot``), nothing can resume there, and the prompt served
+    again leaves it."""
+    eng = pair(8)[0]
+    prompt = prompt_of(71, 21)
+    conv = eng.allocator.classes[-1]
+    free0, taken0 = conv.available(0), conv.snapshots_taken
+    calls, real = [], eng._prefill_fn
+
+    def failing(*args):
+        calls.append(1)
+        if len(calls) == 3:                  # the chunk that holds row 19
+            raise RuntimeError("a bad chunk")
+        return real(*args)
+    eng._prefill_fn = failing
+    slot = eng.select_slot(prompt, 2)
+    try:
+        with pytest.raises(RuntimeError, match="a bad chunk"):
+            eng.prefill(prompt, slot, max_new_tokens=2)
+    finally:
+        eng._prefill_fn = real
+    eng.release_slot(slot)
+    probe = np.concatenate([prompt, [0]])
+    assert eng.prefix_match_tokens(probe) == 0
+    assert conv.available(0) == free0 and conv.snapshots_taken == taken0
+    info = through(eng, prompt)[2]
+    assert info["cached_tokens"] == 0 and info["snapshot_at"] == 20
+    assert eng.prefix_match_tokens(probe) == 20
+    assert conv.snapshots_taken == taken0 + 1
+
+
+def test_one_group_freezes_while_the_other_does_not():
+    """``dp`` = 2, one admission a group in one pass of chunk programs: the
+    first group's prompt leaves a snapshot inside its third chunk, the
+    second's is too short for one; each is what a one-device engine gives,
+    and the second group's pool holds its stream's own page and no other."""
+    if len(jax.devices()) < 2:
+        pytest.skip("needs two host devices")
+    eng = pair(8, dp=2)[0]
+    one, cut = pair(8)
+    assert eng.dp == 2 and eng._freeze_in_chunk
+    prompts = [prompt_of(72, 21), prompt_of(73, 6)]
+    slots = []
+    for p in prompts:
+        slots.append(eng.select_slot(p, 2, exclude_groups={
+            eng.group_of(s) for s in slots}))
+    assert [eng.group_of(s) for s in slots] == [0, 1]
+    before = np.asarray(eng.cache["conv.conv"]).copy()
+    out = eng.prefill_many([(s, p, 2) for s, p in zip(slots, prompts)],
+                           return_logits=True)
+    after = np.asarray(eng.cache["conv.conv"])
+    infos = [eng.last_admit_info(s) for s in slots]
+    assert [i["snapshot_at"] for i in infos] == [20, 0]
+    assert [i["chunks"] for i in infos] == [3, 1]
+    for (tok, logits), p in zip(out, prompts):
+        wtoks, wgot, *_ = through(one, p, steps=0)
+        assert tok == wtoks[0]
+        np.testing.assert_allclose(logits, wgot[0], atol=2e-5)
+    through(cut, prompts[0], steps=0)
+    np.testing.assert_allclose(snapshot_page_of(eng, prompts[0])[0],
+                               snapshot_page_of(cut, prompts[0])[0],
+                               atol=1e-6)
+    own = int(eng.block_tables[slots[1]][-1])
+    others = [b for b in range(after.shape[2]) if b != own]
+    np.testing.assert_array_equal(after[:, 1, others], before[:, 1, others])
+    assert np.abs(after[:, 1, own] - before[:, 1, own]).max() > 0
+    # group 0: its own page and the snapshot's, no third
+    changed = [b for b in range(after.shape[2])
+               if np.abs(after[:, 0, b] - before[:, 0, b]).max() > 0]
+    assert sorted(changed) == sorted(
+        [int(eng.block_tables[slots[0]][-1]),
+         snapshot_page_of(eng, prompts[0])[1]])
+    for s, p, (tok, _) in zip(slots, prompts, out):
+        eng.activate_slot(s, len(p), tok)
+        eng.release_slot(s)
+
+
+def test_nothing_compiles_in_a_window_that_mixes_turns_with_and_without_a_snapshot():
+    """Whether a group leaves a snapshot in a chunk is an OPERAND: turns
+    that leave one inside a chunk, at a chunk's end and none at all run the
+    programs the first serve built."""
+    import jax.monitoring
+    from jax._src import monitoring
+    from deepspeed_tpu.inference.scheduler import Request
+    eng = InferenceEngine(
+        CFG, params(), config={
+            "inference": dict(max_slots=4, max_seq_len=128, block_size=BS,
+                              prefill_chunk=8, paged_kernel=False,
+                              num_blocks={"full": 96, "conv": 16}),
+            "telemetry": {"enabled": True, "fail_on_recompile": True,
+                          "report_steps": 10 ** 6}},
+        mesh=build_mesh(devices=jax.devices()[:1]))
+    history = prompt_of(90, 21)
+    # (a turn over it as well: the first copy of a snapshot into a stream's
+    # page builds ``state_copy``, which no cold prompt dispatches any more)
+    for rid, prompt in enumerate(
+            [history, np.concatenate([history, prompt_of(89, 2)])]):
+        report = eng.serve([Request(rid=-1 - rid, prompt=prompt,
+                                    max_new_tokens=3, arrival_s=0.0)])
+    assert report["completed"] == 2
+    counts = eng.telemetry.sentinel.compile_counts()
+    assert counts["prefill_step"] == 1 and counts["state_copy"] == 1
+    compiles = []
+
+    def listener(name, *_, **__):
+        if "backend_compile" in name:
+            compiles.append(name)
+    # 13 more: a snapshot inside the turn's second chunk; 3: none (under a
+    # page's worth); 11 behind 32: at a chunk's last row; a cold 5: none
+    lengths = [13, 3, 11]
+    reqs = [Request(rid=1 + i, prompt=np.concatenate(
+        [history, prompt_of(91 + i, n)]), max_new_tokens=3, arrival_s=0.0)
+        for i, n in enumerate(lengths)]
+    reqs.append(Request(rid=9, prompt=prompt_of(99, 5), max_new_tokens=3,
+                        arrival_s=0.0))
+    taken0 = eng.allocator.snapshot_totals()
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        report = eng.serve(reqs)
+    finally:
+        monitoring.unregister_event_duration_listener(listener)
+    assert report["completed"] == 2 + len(reqs)
+    assert report["recompiles"] == 0 and compiles == []
+    assert eng.telemetry.sentinel.compile_counts() == counts
+    state = report["state"]
+    assert state["snapshots_taken"] - taken0["snapshots_taken"] == 2
+    assert state["snapshots_in_program"] == state["snapshots_taken"]
+    eng.close()
 
 
 # --------------------------------------------------------------------- #

@@ -6,9 +6,10 @@ holds its rows.
 What is held to what, all on CPU at tiny model widths but ``prefill_chunk``
 512, so that the ladder is the real one:
 (a) the ladder from the shapes;
-(b) the four served families: prompts whose tails select each width give the
+(b) the five served families: prompts whose tails select each width give the
     first token, the logits and the cache of the same engine pinned to the
-    single width 512;
+    single width 512; a model that freezes its state inside a chunk (ISSUE
+    46) runs a turn as ONE program where the cut ran two;
 (c) ``dp`` = 2: a dispatch is as wide as its longest active group needs;
 (d) once the first ``serve()`` has begun nothing compiles, whatever the
     widths the prompts select, and another shape still raises;
@@ -73,7 +74,7 @@ def test_the_floor_is_the_chips_gemm_ridge():
 
 
 # --------------------------------------------------------------------- #
-# The four served families at tiny widths
+# The five served families at tiny widths
 # --------------------------------------------------------------------- #
 def _gpt2():
     from deepspeed_tpu.models.gpt2 import GPT2_CONFIGS, gpt2_init
@@ -127,8 +128,16 @@ def _classes():
         {"num_blocks": {"full": 160, "window": 136}}
 
 
+def _kinds():
+    from deepspeed_tpu.models.lfm2 import lfm2_init
+    from test_lfm2_serving import tiny
+    cfg = tiny(max_position_embeddings=MAX_LEN)
+    return cfg, lfm2_init(jax.random.PRNGKey(0), cfg), 128, \
+        {"num_blocks": {"full": 160, "conv": 8}}
+
+
 FAMILIES = {"gpt2": _gpt2, "latent": _latent, "retention": _retention,
-            "two_classes": _classes}
+            "two_classes": _classes, "two_kinds": _kinds}
 
 
 def _engine(cfg, params, extra, mesh=None, telemetry=None, **inference):
@@ -176,11 +185,14 @@ def test_every_width_gives_what_the_single_width_gives(family, monkeypatch):
     assert pinned.prefill_widths == (CHUNK,)
 
     # A document of 700 tokens runs 512 + 188 rows (widths 512, 256) — with
-    # a per-stream state its snapshot is due at 688 and the tail is cut
+    # a per-stream state that only the end of a program can freeze (the
+    # retention family's) its snapshot is due at 688 and the tail is cut
     # there: 512, 176 and 12 rows (512, 256, 256). Then tails that select
-    # each width alone (a state's 200 rows are cut at 192 for a snapshot:
-    # 256, 256), and a question over the cached document (its prefix, or
-    # its snapshot: the tail is the remainder + the question).
+    # each width alone (such a state's 200 rows are cut at 192 for a
+    # snapshot: 256, 256), and a question over the cached document (its
+    # prefix, or its snapshot: the tail is the remainder + the question).
+    # A conv state beside pages (``two_kinds``) is frozen by the program
+    # that passes the boundary: no cut, the widths of a model without one.
     state = family == "retention"
     doc = _tokens(1, 700, vocab)
     prompts = [doc, _tokens(2, 70, vocab), _tokens(3, 200, vocab),
@@ -223,6 +235,63 @@ def test_a_question_over_a_snapshot_resumes_and_takes_the_narrow_program():
     assert info["cow_fork"]                  # the snapshot's page, copied
     assert eng.serving.prefill_width_dispatches == {512: 1, 256: 3}
     eng.close()
+
+
+def test_a_turn_over_a_conv_state_is_one_program_and_no_snapshot_copy(
+        tmp_path):
+    """A model that freezes its state inside a chunk (ISSUE 46): a turn of
+    up to ``prefill_chunk`` new tokens dispatches ONE chunk program, which
+    leaves the snapshot, and one page copy (the snapshot it resumed from,
+    into its own page) — where the same model made to say no runs the cut's
+    second program and a second copy."""
+    import json
+    from test_lfm2_serving import CutServed as Cut
+    cfg, params, vocab, extra = _kinds()
+    history = _tokens(1, 300, vocab)
+    turn = np.concatenate([history, _tokens(2, 400, vocab)])
+    found = {}
+    for name, model in (("program", cfg), ("cut", Cut(cfg))):
+        trace_path = str(tmp_path / f"{name}.trace.json")
+        eng = _engine(model, params, extra, telemetry={
+            "enabled": True, "output_path": str(tmp_path), "job_name": name,
+            "report_steps": 10 ** 6, "trace_path": trace_path})
+        out = [_through(eng, p) for p in (history, turn)]
+        page = eng.cache_specs[-1].block_nbytes()
+        state = eng.serving.snapshot()["state"]
+        found[name] = (out, dict(eng.serving.prefill_width_dispatches),
+                       state, page)
+        eng.close()
+        found[name] += ([e["args"] for e in json.load(open(trace_path))
+                         if e.get("name") == "prefill"],)
+    out, ran, state, page, spans = found["program"]
+    cout, cran, cstate, _, cspans = found["cut"]
+    for (tok, pre, dec, info), (ctok, cpre, cdec, cinfo) in zip(out, cout):
+        assert tok == ctok
+        np.testing.assert_allclose(pre, cpre, atol=2e-5, rtol=1e-5)
+        np.testing.assert_allclose(dec, cdec, atol=2e-5, rtol=1e-5)
+        assert info["snapshot_at"] == cinfo["snapshot_at"] > 0
+        assert info["cached_tokens"] == cinfo["cached_tokens"]
+    assert [o[3]["snapshot_at"] for o in out] == [288, 688]
+    assert out[1][3]["cached_tokens"] == 288 and out[1][3]["cow_fork"]
+    # the history's 300 rows and the turn's 412: one 512-row program each;
+    # cut at 288 / 688, 12 rows more in a program of their own
+    assert [o[3]["chunks"] for o in out] == [1, 1] and ran == {512: 2}
+    assert [o[3]["chunks"] for o in cout] == [2, 2] \
+        and cran == {512: 2, 256: 2}
+    assert [a["chunks"] for a in spans] == [1, 1]
+    assert [a["chunks"] for a in cspans] == [2, 2]
+    assert [(a["snapshot_taken"], a["snapshot_in_program"]) for a in spans] \
+        == [(1, 1), (1, 1)]
+    assert [(a["snapshot_taken"], a["snapshot_in_program"])
+            for a in cspans] == [(1, 0), (1, 0)]
+    # bytes of the copies DISPATCHED: the turn's halve, the history's none
+    assert [a["state_copy_bytes"] for a in spans] == [0, 2 * page]
+    assert [a["state_copy_bytes"] for a in cspans] == [2 * page, 4 * page]
+    assert (state["snapshots_taken"], state["snapshots_in_program"]) == (2, 2)
+    assert (cstate["snapshots_taken"], cstate["snapshots_in_program"]) \
+        == (2, 0)
+    assert state["state_copy_bytes"] == 2 * page \
+        and cstate["state_copy_bytes"] == 6 * page
 
 
 # --------------------------------------------------------------------- #

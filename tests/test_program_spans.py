@@ -855,8 +855,11 @@ def test_decode_and_prefill_spans_carry_both_kinds(tmp_path, kinds_engine):
     page = eng.cache_specs[-1].block_nbytes()
     assert first["cached_tokens_full"] == first["cached_tokens_conv"] \
         == first["resumed_tokens"] == 40 * first["slots"]
-    assert first["snapshot_taken"] == first["slots"]
-    assert first["state_copy_bytes"] == 2 * page * 2 * first["slots"]
+    # every turn's snapshot frozen by its own chunk program: the one copy
+    # an admission dispatches is the snapshot it resumed from
+    assert first["snapshot_taken"] == first["snapshot_in_program"] \
+        == first["slots"]
+    assert first["state_copy_bytes"] == 2 * page * first["slots"]
     assert first["prefix_lost_to_kind_tokens"] == 0
     state = report["state"]
     assert state["resumed_tokens"] == 80 and state["snapshot_hits"] == 2

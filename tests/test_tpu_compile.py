@@ -1078,9 +1078,10 @@ def mixed_kind_programs(topo):
             params, *pools, i32(S + len(served.counter_names)), i32(S),
             fresh(S), i32(S), i32(S, J), key, temp).compile()
         for C in prefill_widths(inf["prefill_chunk"], inf["block_size"]):
+            # (+ the snapshot's row and page: the program freezes it)
             out[f"prefill_step.{C}"] = eng._build_prefill_step().lower(
                 params, *pools, i32(1, C), i32(1, J), i32(1), i32(1), i32(1),
-                key, temp).compile()
+                i32(1), i32(1), key, temp).compile()
         out["state_copy"] = eng._build_copy("state_copy", "state_copy").lower(
             *pools, i32(1), i32(1)).compile()
     finally:
