@@ -365,9 +365,6 @@ def transformer_block(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
     the block returns ``(x, moe_stats_or_None)`` instead of ``x``;
     ``mesh`` feeds the ep > 1 all-to-all shard_map.
     """
-    if attention_fn is None:
-        from ..ops.flash_attention import auto_attention
-        attention_fn = auto_attention
     B, S, H = x.shape
     nH, dH = cfg.num_heads, cfg.head_dim
     r1 = r2 = r3 = None
@@ -386,14 +383,23 @@ def transformer_block(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
         h = ln(x, params["ln1_scale"], params["ln1_bias"]) \
             if cfg.pre_layer_norm else x
         qkv = dense(h, params["qkv_kernel"], params["qkv_bias"])
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(B, S, nH, dH)
-        k = k.reshape(B, S, nH, dH)
-        v = v.reshape(B, S, nH, dH)
-        attn = attention_fn(q, k, v, mask=mask, causal=cfg.causal,
-                            attn_dropout=cfg.attn_dropout, rng=r1,
-                            deterministic=deterministic)
-        attn = attn.reshape(B, S, H)
+        if attention_fn is None:
+            # The default takes the projection as the GEMM leaves it: on
+            # TPU the flash kernels read q, k, v out of ``qkv`` in place.
+            from ..ops.flash_attention import auto_attention_qkv
+            attn = auto_attention_qkv(
+                qkv, nH, mask=mask, causal=cfg.causal,
+                attn_dropout=cfg.attn_dropout, rng=r1,
+                deterministic=deterministic)
+        else:
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(B, S, nH, dH)
+            k = k.reshape(B, S, nH, dH)
+            v = v.reshape(B, S, nH, dH)
+            attn = attention_fn(q, k, v, mask=mask, causal=cfg.causal,
+                                attn_dropout=cfg.attn_dropout, rng=r1,
+                                deterministic=deterministic)
+            attn = attn.reshape(B, S, H)
         attn = dense(attn, params["proj_kernel"], params["proj_bias"])
         attn = dropout(attn, cfg.hidden_dropout, r2, deterministic)
     with jax.named_scope("mlp"):

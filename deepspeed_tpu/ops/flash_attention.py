@@ -18,11 +18,27 @@ One kernel family serves three modes via static specialization:
 - block-sparse (an int32 layout [H, nQ, nK] gates each (q-block, k-block)
   pair — the splash-attention pattern; masked blocks skip their matmuls)
 
-Layout: kernels run over [BH, S, D] (batch×heads flattened, head_dim last).
-Grid is (BH, q_blocks, k_blocks); the innermost (k) dimension iterates
-sequentially on TPU so VMEM scratch carries the running softmax state
-across k-blocks of one q-block.  A sequence one block covers (S = 1024
-and below, `_pick_block`) is one grid step a head with no running state.
+Layout, two forms, chosen from (S, Sk, nH, dH, layout) alone (`tile_lanes`):
+- IN PLACE over [B, S, nH*dH], which is the projections' own [B, S, nH, dH]
+  for free: where ONE block covers the sequence (S = 1024 and below,
+  `_pick_block`: every cell of the benchmark, BERT's 128 / 512) and a lane
+  tile of 128 holds whole heads — two 64-wide heads side by side (nH
+  even), or one head of 128 or 256.  A grid step is one tile of one batch
+  row, its heads run through one traced body (`_each_head`), a head's
+  q k^T and do v^T contract over all the tile's lanes with the neighbour's
+  lanes zeroed in one operand, and its p v, ds k, ds^T q, p^T do come out
+  tile-wide.  Nothing is transposed on the way in or out, and a fused
+  [B, S, 3H] projection is read three times at three lane offsets
+  (`flash_attention_qkv`).  A single 64-wide head cannot be a block of
+  [B, S, nH, 64]: Mosaic wants a block's last two dims divisible by
+  (8, 128) or equal to the array's, and (1, 64) of (nH, 64) is neither.
+- [BH, S, D] (batch×heads flattened by `_to_bh`, a transpose each way) for
+  everything else: a layout, a longer S, an odd head count, dH 32 or 96.
+  Grid is (BH, q_blocks, k_blocks); the innermost (k) dimension iterates
+  sequentially on TPU so VMEM scratch carries the running softmax state
+  across k-blocks of one q-block; one block is one grid step a head with
+  no running state.
+`lowered` counts the calls by form.
 """
 from __future__ import annotations
 
@@ -258,8 +274,97 @@ def _causal_mask_band(s, r0: int):
     return lax.concatenate([lax.slice_in_dim(s, 0, r0, axis=1), diag], 1)
 
 
+def _own_lanes(head, head_dim: int, lanes: int):
+    """[1, lanes] mask of the lanes head ``head`` of a tile holds
+    (``lanes // head_dim`` heads side by side); None where the tile is one
+    head and there is nothing to part."""
+    if head_dim == lanes:
+        return None
+    lane = lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    return lane // head_dim == head
+
+
+def _own(mine, x, other=None):
+    """``x`` on the head's own lanes of a tile and zeros (or ``other``) on
+    its neighbour's.  A contraction over the tile's lanes with ONE operand
+    so zeroed is the head's own product: the zeros add nothing."""
+    if mine is None:
+        return x
+    return lax.select(lax.broadcast_in_dim(mine, x.shape, (0, 1)), x,
+                      lax.full_like(x, 0) if other is None else other)
+
+
+def _fwd_bands(q_ref, k_ref, v_ref, o_ref, lse_ref, seed, bh, mine, qi, kj, *,
+               scale: float, causal: bool, bq: int, bk: int, dropout: float,
+               band: int):
+    """One head through the whole-row forward.  Refs are [rows, lanes] and
+    lse [rows] (no head dim); ``mine`` (`_own_lanes`) is None where the
+    lanes are the head's alone ([BH, S, D] operands, or a head of a tile's
+    width) and else parts the head from its neighbour in the tile."""
+    def scores(r0):
+        w = r0 + band if band < bq else bk
+        s = lax.mul(_dot(_own(mine, q_ref[r0:r0 + band]), k_ref[:w], _NT),
+                    np.float32(scale))
+        if causal:
+            s = _causal_mask_band(s, r0) if band < bq else \
+                _causal_mask(s, qi, kj, bq, bk)
+        return s
+
+    def finish(r0, s):
+        w = s.shape[1]
+        v = v_ref[:w]
+        m = lax.expand_dims(lax.reduce_max(s, (1,)), (1,))
+        p = lax.exp(lax.sub(s, m))
+        l = lax.expand_dims(lax.reduce_sum(p, (1,)), (1,))
+        if dropout > 0.0:
+            # Under bands the grid is one step a head: (qi, kj) is
+            # (0, 0) and the tile starts at global (r0, 0).
+            at = (r0 // band, 0) if band < bq else (qi, kj)
+            keep = _dropout_keep(seed, bh, *at, band, w, dropout)
+            p = _keep_scaled(keep, p, dropout)
+        pv = _dot(lax.convert_element_type(p, v.dtype), v, _NN)
+        l_safe = lax.select(lax.eq(l, np.float32(0.0)),
+                            lax.full_like(l, 1.0), l)
+        o = lax.convert_element_type(lax.div(pv, l_safe), o_ref.dtype)
+        # ``pv`` is as wide as the tile: the head's lanes go into the
+        # output tile, the neighbour's stay what they are.
+        o_ref[r0:r0 + band] = o if mine is None else \
+            _own(mine, o, o_ref[r0:r0 + band])
+        lse_ref[r0:r0 + band] = lax.add(
+            lax.squeeze(m, (1,)), lax.log(lax.squeeze(l_safe, (1,))))
+
+    # The row max is a barrier a band (all of s before any exp): issue
+    # each band's score matmul ahead of the band before's softmax and
+    # the MXU works through it (one band ahead measured best at this
+    # `_BAND`).  Widest band first, as the backward.
+    starts = range(0, bq, band)[::-1]
+    s = scores(starts[0])
+    for r0, ahead in zip(starts, [*starts[1:], None]):
+        s_ahead = None if ahead is None else scores(ahead)
+        finish(r0, s)
+        s = s_ahead
+
+
+def _each_head(tile, tile_heads: int, lanes: int, body):
+    """Run ``body(head, bh, mine)`` for the heads of lane tile ``tile``
+    through ONE traced body: a loop over the head-in-tile index, the lane
+    mask computed from it.  The loop is unrolled where it is LOWERED (the
+    body is still traced once): the scheduler then runs the second head's
+    first matmuls under the first head's tail (-0.35 us a head in the
+    backward on a v5e, PERF.md section 6, PR 47)."""
+    def step(head, carry):
+        body(head, tile * tile_heads + head,
+             _own_lanes(head, lanes // tile_heads, lanes))
+        return carry
+    if tile_heads == 1:
+        step(0, None)
+    else:
+        lax.fori_loop(0, tile_heads, step, None, unroll=True)
+
+
 def _fwd_kernel(*refs, scale: float, causal: bool, bq: int, bk: int,
-                has_layout: bool, dropout: float = 0.0, band: int = 0):
+                has_layout: bool, dropout: float = 0.0, band: int = 0,
+                tile_heads: int = 0):
     if has_layout and dropout > 0.0:
         (layout_ref, seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
          m_scr, l_scr, acc_scr) = refs
@@ -278,52 +383,23 @@ def _fwd_kernel(*refs, scale: float, causal: bool, bq: int, bk: int,
         # One k-block covers the whole row (S = 1024 and below): each
         # band of query rows sees all of its keys at once, so no running
         # softmax, no scratch round-trips — a direct softmax + PV, one grid
-        # step a head.  ``band`` < bq (`_row_band`: causal, the q-block is
-        # the whole square) unrolls static row bands that read the keys up
-        # to their own diagonal tile only; ``band`` == bq is one band over
-        # the whole [bq, bk] rectangle.
-        # Refs are [rows, D] here and lse [rows] (`_flash_fwd`: no head dim).
+        # step a head (or a lane tile of heads, `_flash_fwd_in_place`).
+        # ``band`` < bq (`_row_band`: causal, the q-block is the whole
+        # square) unrolls static row bands that read the keys up to their
+        # own diagonal tile only; ``band`` == bq is one band over the whole
+        # [bq, bk] rectangle.
         seed = seed_ref[0, 0] if dropout > 0.0 else None
-
-        def scores(r0):
-            w = r0 + band if band < bq else bk
-            s = lax.mul(_dot(q_ref[r0:r0 + band], k_ref[:w], _NT),
-                        np.float32(scale))
-            if causal:
-                s = _causal_mask_band(s, r0) if band < bq else \
-                    _causal_mask(s, qi, kj, bq, bk)
-            return s
-
-        def finish(r0, s):
-            w = s.shape[1]
-            v = v_ref[:w]
-            m = lax.expand_dims(lax.reduce_max(s, (1,)), (1,))
-            p = lax.exp(lax.sub(s, m))
-            l = lax.expand_dims(lax.reduce_sum(p, (1,)), (1,))
-            if dropout > 0.0:
-                # Under bands the grid is one step a head: (qi, kj) is
-                # (0, 0) and the tile starts at global (r0, 0).
-                at = (r0 // band, 0) if band < bq else (qi, kj)
-                keep = _dropout_keep(seed, bh, *at, band, w, dropout)
-                p = _keep_scaled(keep, p, dropout)
-            pv = _dot(lax.convert_element_type(p, v.dtype), v, _NN)
-            l_safe = lax.select(lax.eq(l, np.float32(0.0)),
-                                lax.full_like(l, 1.0), l)
-            o_ref[r0:r0 + band] = lax.convert_element_type(
-                lax.div(pv, l_safe), o_ref.dtype)
-            lse_ref[r0:r0 + band] = lax.add(
-                lax.squeeze(m, (1,)), lax.log(lax.squeeze(l_safe, (1,))))
-
-        # The row max is a barrier a band (all of s before any exp): issue
-        # each band's score matmul ahead of the band before's softmax and
-        # the MXU works through it (one band ahead measured best at this
-        # `_BAND`).  Widest band first, as the backward.
-        starts = range(0, bq, band)[::-1]
-        s = scores(starts[0])
-        for r0, ahead in zip(starts, [*starts[1:], None]):
-            s_ahead = None if ahead is None else scores(ahead)
-            finish(r0, s)
-            s = s_ahead
+        bands = functools.partial(
+            _fwd_bands, scale=scale, causal=causal, bq=bq, bk=bk,
+            dropout=dropout, band=band)
+        if not tile_heads:      # [BH, S, D] operands: the step is the head
+            bands(q_ref, k_ref, v_ref, o_ref, lse_ref, seed, bh, None,
+                  qi, kj)
+            return
+        _each_head(bh, tile_heads, q_ref.shape[-1],
+                   lambda head, bh, mine: bands(
+                       q_ref, k_ref, v_ref, o_ref, lse_ref.at[head], seed,
+                       bh, mine, qi, kj))
         return
 
     @pl.when(kj == 0)
@@ -422,15 +498,31 @@ def _qkv_spec(blk: int, D: int, role: str, head=1):
     variants are for the dkv grid whose program ids are (bh, kj, qi).
     ``head=None`` squeezes the head dim out of the kernel's ref ([blk, D]).
 
-    NOTE a native-4D [B, S, nH, D] variant (per-head blocks (1, blk, 1, D)
-    to skip the host-side transposes) was tried and REVERTED: Mosaic
-    requires the last two block dims divisible by (8, 128) or equal to the
-    array dims, which a 1-of-nH head block can never satisfy."""
+    The operands came through `_to_bh`.  A per-head block (1, blk, 1, D) of
+    the native [B, S, nH, D] is not open to Mosaic (a block's last two
+    dims: divisible by (8, 128) or the array's own); a block of 128 LANES
+    of [B, S, nH*D] is, and where one block covers the sequence the
+    kernels take that instead (`_tile_spec`)."""
     idx = {"q": lambda b, i, j: (b, i, 0),
            "k": lambda b, i, j: (b, j, 0),
            "qT": lambda b, j, i: (b, i, 0),
            "kT": lambda b, j, i: (b, j, 0)}[role]
     return pl.BlockSpec((head, blk, D), idx)
+
+
+def _fwd_blocks(BH: int, S: int, Sk: int, D: int, dtype, scale: float,
+                causal: bool):
+    """(bq, bk) of a dense forward over [BH, S, D]: the autotune registry's
+    pick, else one block as far as `_pick_block` goes."""
+    def run_at(tile):
+        return _flash_fwd(jnp.zeros((BH, S, D), dtype),
+                          jnp.zeros((BH, Sk, D), dtype),
+                          jnp.zeros((BH, Sk, D), dtype),
+                          None, scale, causal, _blocks=tile)
+    return _resolve_blocks(
+        "flash_fwd", jax.ShapeDtypeStruct((BH, S, D), dtype),
+        jax.ShapeDtypeStruct((BH, Sk, D), dtype), causal,
+        (_pick_block(S), _pick_block(Sk)), run_at)
 
 
 def _flash_fwd(q, k, v, layout, scale: float, causal: bool,
@@ -446,14 +538,7 @@ def _flash_fwd(q, k, v, layout, scale: float, causal: bool,
     elif _blocks is not None:
         bq, bk = _blocks
     else:
-        def run_at(tile):
-            return _flash_fwd(jnp.zeros((BH, S, D), q.dtype),
-                              jnp.zeros((BH, Sk, D), k.dtype),
-                              jnp.zeros((BH, Sk, D), v.dtype),
-                              None, scale, causal, _blocks=tile)
-        bq, bk = _resolve_blocks(
-            "flash_fwd", q, k, causal,
-            (_pick_block(S), _pick_block(Sk)), run_at)
+        bq, bk = _fwd_blocks(BH, S, Sk, D, q.dtype, scale, causal)
     grid = (BH, S // bq, Sk // bk)
     # One k-block: the whole-row body, in `_row_band` row bands where the
     # one q-block is the causal square too.
@@ -610,32 +695,34 @@ def _bwd_dkv_kernel(*refs, scale: float, causal: bool, bq: int, bk: int,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd_fused_kernel(*refs, scale: float, causal: bool, S: int, band: int,
-                      dropout: float = 0.0):
-    """Whole-sequence fused backward: when one block covers S, compute the
-    score/softmax replay ONCE and emit dq, dk, dv together — the split
-    dq/dkv kernels each redo the s/p/exp work in their own iteration
-    order (6 matmuls + 2 softmax replays vs 5 + 1 here).
-
-    ``band`` < S (`_row_band`: causal) unrolls static row bands as the
-    forward does: band r0 replays [band, r0 + band] scores, writes its dq
-    rows once and adds its dk / dv rows [0, r0 + band) into float32
-    scratch, cast once at the end.  ``band`` == S is one band over the whole
-    square and writes all three directly."""
-    refs = list(refs)
-    seed_ref = refs.pop(0) if dropout > 0.0 else None
-    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-     dq_ref, dk_ref, dv_ref) = refs[:9]
-    bh = pl.program_id(0)
-    seed = seed_ref[0, 0] if dropout > 0.0 else None
-    # Widest band first: it covers every key row, so it ASSIGNS the
-    # accumulators and nothing has to zero them.
+def _bwd_bands(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+               dk_acc, dv_acc, seed, bh, mine, *, scale: float, causal: bool,
+               S: int, band: int, dropout: float, assign: bool,
+               delta_from_o: bool = False):
+    """One head through the whole-sequence backward: band r0 replays
+    [band, r0 + band] scores, writes its dq rows once and adds its dk / dv
+    rows [0, r0 + band) into ``dk_acc`` / ``dv_acc`` (float32 scratch, or
+    the outputs themselves where one band is the whole square).
+    ``delta_ref`` holds delta = rowsum(do * o) [S], or (``delta_from_o``)
+    o itself as wide as do, and the band takes the sum.
+    ``assign``: nothing has written the accumulators, so the widest band,
+    which covers every key row, assigns them.  ``mine`` as in `_fwd_bands`:
+    q and do carry the head's lanes only, so dk and dv come out zero on
+    the neighbour's lanes and the heads of a tile add into one
+    accumulator; dq is as wide as k and its own lanes are selected."""
+    # Widest band first.
     for r0 in reversed(range(0, S, band)):
         w = r0 + band
-        q, do = q_ref[r0:w], do_ref[r0:w]
+        q, do = _own(mine, q_ref[r0:w]), _own(mine, do_ref[r0:w])
         k, v = k_ref[:w], v_ref[:w]
         lse = lax.expand_dims(lse_ref[r0:w], (1,))             # [band, 1]
-        delta = lax.expand_dims(delta_ref[r0:w], (1,))         # [band, 1]
+        if delta_from_o:    # over the lanes ``do`` kept: the head's own
+            delta = lax.reduce_sum(lax.mul(
+                lax.convert_element_type(do, jnp.float32),
+                lax.convert_element_type(delta_ref[r0:w], jnp.float32)), (1,))
+        else:
+            delta = delta_ref[r0:w]
+        delta = lax.expand_dims(delta, (1,))                   # [band, 1]
         s = lax.mul(_dot(q, k, _NT), np.float32(scale))        # [band, w]
         if causal:
             s = _causal_mask_band(s, r0)
@@ -650,22 +737,58 @@ def _bwd_fused_kernel(*refs, scale: float, causal: bool, S: int, band: int,
         dv = _dot(lax.convert_element_type(p_drop, do.dtype), do, _TN)
         ds = lax.mul(lax.mul(p, lax.sub(dp, delta)), np.float32(scale))
         dsc = lax.convert_element_type(ds, q.dtype)
-        dq_ref[r0:w] = lax.convert_element_type(_dot(dsc, k, _NN),
-                                                dq_ref.dtype)
-        dk = _dot(dsc, q, _TN)                                 # [w, D]
-        if band == S:
-            dk_ref[:] = lax.convert_element_type(dk, dk_ref.dtype)
-            dv_ref[:] = lax.convert_element_type(dv, dv_ref.dtype)
-            return
-        dk_scr, dv_scr = refs[9:]
-        if w == S:
-            dk_scr[:] = dk
-            dv_scr[:] = dv
+        dq = lax.convert_element_type(_dot(dsc, k, _NN), dq_ref.dtype)
+        dq_ref[r0:w] = dq if mine is None else _own(mine, dq, dq_ref[r0:w])
+        dk = _dot(dsc, q, _TN)                                 # [w, lanes]
+        if assign and w == S:
+            dk_acc[:] = lax.convert_element_type(dk, dk_acc.dtype)
+            dv_acc[:] = lax.convert_element_type(dv, dv_acc.dtype)
         else:
-            dk_scr[:w] = lax.add(dk_scr[:w], dk)
-            dv_scr[:w] = lax.add(dv_scr[:w], dv)
-    dk_ref[:] = lax.convert_element_type(dk_scr[:], dk_ref.dtype)
-    dv_ref[:] = lax.convert_element_type(dv_scr[:], dv_ref.dtype)
+            dk_acc[:w] = lax.add(dk_acc[:w], dk)
+            dv_acc[:w] = lax.add(dv_acc[:w], dv)
+
+
+def _bwd_fused_kernel(*refs, scale: float, causal: bool, S: int, band: int,
+                      dropout: float = 0.0, tile_heads: int = 0):
+    """Whole-sequence fused backward: when one block covers S, compute the
+    score/softmax replay ONCE and emit dq, dk, dv together — the split
+    dq/dkv kernels each redo the s/p/exp work in their own iteration
+    order (6 matmuls + 2 softmax replays vs 5 + 1 here).
+
+    ``band`` < S (`_row_band`: causal) unrolls static row bands as the
+    forward does (`_bwd_bands`), dk / dv in float32 scratch cast once at
+    the end.  ``band`` == S is one band over the whole square and writes
+    all three directly.  ``tile_heads`` (`_flash_bwd_in_place`): the refs
+    are a lane tile of that many heads, which share the scratch, and o
+    stands where delta stood."""
+    refs = list(refs)
+    seed_ref = refs.pop(0) if dropout > 0.0 else None
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+     dq_ref, dk_ref, dv_ref) = refs[:9]
+    dk_acc, dv_acc = refs[9:] or (dk_ref, dv_ref)
+    seed = seed_ref[0, 0] if dropout > 0.0 else None
+    bands = functools.partial(
+        _bwd_bands, scale=scale, causal=causal, S=S, band=band,
+        dropout=dropout)
+    if not tile_heads:
+        # [BH, S, D] operands: the step is the head, its lanes are its own,
+        # and its widest band ASSIGNS the accumulators (nothing zeroes
+        # them).  delta [S] is XLA's rowsum(do * o).
+        bands(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+              dk_acc, dv_acc, seed, pl.program_id(0), None, assign=True)
+    else:
+        # A lane tile, and ``delta_ref`` the tile of o itself.
+        if tile_heads > 1:
+            dk_acc[:] = jnp.zeros_like(dk_acc)
+            dv_acc[:] = jnp.zeros_like(dv_acc)
+        _each_head(pl.program_id(0), tile_heads, q_ref.shape[-1],
+                   lambda head, bh, mine: bands(
+                       q_ref, k_ref, v_ref, do_ref, lse_ref.at[head],
+                       delta_ref, dq_ref, dk_acc, dv_acc, seed, bh, mine,
+                       assign=tile_heads == 1, delta_from_o=True))
+    if dk_acc is not dk_ref:
+        dk_ref[:] = lax.convert_element_type(dk_acc[:], dk_ref.dtype)
+        dv_ref[:] = lax.convert_element_type(dv_acc[:], dv_ref.dtype)
 
 
 def _flash_bwd_fused(q, k, v, lse, do, delta, scale, causal, dropout, seed):
@@ -695,6 +818,122 @@ def _flash_bwd_fused(q, k, v, lse, do, delta, scale, causal, dropout, seed):
         name="_bwd_fused_kernel",
         interpret=_interpret(),
     )(*args)
+
+
+# --------------------------------------------------------------------- #
+# The same two kernels IN PLACE over [B, S, nH*dH]
+# --------------------------------------------------------------------- #
+# Trace-time count of the calls that reach a kernel, by the operand layout
+# they were lowered with: `in_place` (below) against `relayout` (`_to_bh`
+# on the way in and its inverse on the way out).
+lowered = {"in_place": 0, "relayout": 0}
+
+
+def tile_lanes(S: int, Sk: int, nH: int, dH: int, layout=None) -> int:
+    """Lanes of a head tile where the whole-sequence kernels read q, k, v
+    and write o where the projections leave them, [B, S, nH*dH]; 0 where
+    they cannot and the operands go through `_to_bh`.
+
+    A block's last dim has to be a multiple of 128 lanes (or the array's
+    own), which ONE 64-wide head of [B, S, nH, 64] never is; two of them
+    side by side are, and so is a head of 128 or 256.  The rest is what
+    these kernels are: no layout, one block over the sequence
+    (`_pick_block`), the fused backward."""
+    if layout is not None or S != Sk or _pick_block(S) != S or \
+            os.environ.get("DS_FLASH_FUSED_BWD", "1") != "1":
+        return 0
+    return 128 if dH == 64 and nH % 2 == 0 else dH if dH % 128 == 0 else 0
+
+
+def _tile_spec(S: int, lanes: int, tiles: int, at: int = 0):
+    """Lane tile ``i % tiles`` of batch row ``i // tiles``: [S, lanes] of a
+    [B, S, n * lanes] array, ``at`` tiles along it (the k and v thirds of a
+    fused [B, S, 3H] projection)."""
+    return pl.BlockSpec((None, S, lanes),
+                        lambda i, *_: (i // tiles, 0, at + i % tiles))
+
+
+def _tile_rows_spec(tile_heads: int, S: int):
+    """The rows of lse / delta of a tile's heads: [tile_heads, S] of
+    [B*nH, 1, S]."""
+    return pl.BlockSpec((tile_heads, None, S), lambda i, *_: (i, 0, 0))
+
+
+def _thirds(qkv, S: int, H: int, lanes: int):
+    """(q, k, v) and their tile specs: three [B, S, H] arrays, or ONE fused
+    [B, S, 3H] projection handed to the kernel three times, at three lane
+    offsets."""
+    tiles = H // lanes
+    at = (0, 0, 0) if len(qkv) == 3 else (0, tiles, 2 * tiles)
+    return tuple(qkv) * (3 // len(qkv)), \
+        [_tile_spec(S, lanes, tiles, a) for a in at]
+
+
+def _flash_fwd_in_place(qkv, nH: int, lanes: int, scale: float, causal: bool,
+                        dropout: float, seed):
+    """``qkv`` as `_thirds` takes it -> (o [B, S, H], lse [B*nH, 1, S]).
+    A grid step is one lane tile: ``tile_heads`` heads of a batch row."""
+    B, S, width = qkv[0].shape
+    H = width * len(qkv) // 3
+    tiles, tile_heads = H // lanes, lanes * nH // H
+    args, in_specs = _thirds(qkv, S, H, lanes)
+    if dropout > 0.0:
+        in_specs = [_seed_spec()] + in_specs
+        args = (_seed_arr(seed),) + args
+    o = jax.ShapeDtypeStruct((B, S, H), qkv[0].dtype)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=S,
+                          bk=S, has_layout=False, dropout=dropout,
+                          band=_row_band(S, S, causal),
+                          tile_heads=tile_heads),
+        grid=(B * tiles, 1, 1),
+        in_specs=in_specs,
+        out_specs=[_tile_spec(S, lanes, tiles),
+                   _tile_rows_spec(tile_heads, S)],
+        out_shape=[o, jax.ShapeDtypeStruct((B * nH, 1, S), jnp.float32)],
+        # The running-softmax scratch of the grid path: not used here.
+        scratch_shapes=[pltpu.VMEM((8, 128), jnp.float32)] * 3,
+        cost_estimate=_cost(B * nH, H // nH,
+                            computed_scores(S, S, causal, (S, S)), 2, [o] * 4),
+        name="_fwd_kernel",
+        interpret=_interpret(),
+    )(*args)
+
+
+def _flash_bwd_in_place(qkv, o, lse, do, nH: int, lanes: int, scale: float,
+                        causal: bool, dropout: float, seed):
+    """-> the gradients in ``qkv``'s own form: (dq, dk, dv), or the one
+    [B, S, 3H] of a fused projection."""
+    B, S, H = o.shape
+    tiles, tile_heads = H // lanes, lanes * nH // H
+    band = _row_band(S, S, causal)
+    tile = _tile_spec(S, lanes, tiles)
+    # No delta = rowsum(do * o) from XLA: over [B, S, nH*dH] it is a
+    # reduction within lanes, which costs XLA a relayout of its own; the
+    # kernel takes o's tile and the sum is a band's side work there.
+    args, in_specs = _thirds(qkv, S, H, lanes)
+    args += (do, lse, o)
+    in_specs += [tile, _tile_rows_spec(tile_heads, S), tile]
+    if dropout > 0.0:
+        in_specs = [_seed_spec()] + in_specs
+        args = (_seed_arr(seed),) + args
+    grads = pl.pallas_call(
+        functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
+                          S=S, dropout=dropout, band=band,
+                          tile_heads=tile_heads),
+        grid=(B * tiles,),
+        in_specs=in_specs,
+        out_specs=[tile] * 3,
+        out_shape=[jax.ShapeDtypeStruct((B, S, H), o.dtype)] * 3,
+        scratch_shapes=[pltpu.VMEM((S, lanes), jnp.float32)] * 2
+        if band < S or tile_heads > 1 else [],
+        cost_estimate=_cost(B * nH, H // nH,
+                            computed_scores(S, S, causal, (S, S)), 5, [o] * 8),
+        name="_bwd_fused_kernel",
+        interpret=_interpret(),
+    )(*args)
+    return tuple(grads) if len(qkv) == 3 else \
+        (jnp.concatenate(grads, axis=-1),)
 
 
 def _flash_bwd(q, k, v, o, lse, do, layout, scale: float, causal: bool,
@@ -827,6 +1066,28 @@ def _flash_vjp_bwd(scale, causal, dropout, res, do):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
+def _flash_in_place(qkv, seed, nH: int, lanes: int, scale: float,
+                    causal: bool, dropout: float = 0.0):
+    return _flash_fwd_in_place(qkv, nH, lanes, scale, causal, dropout,
+                               seed)[0]
+
+
+def _flash_in_place_vjp_fwd(qkv, seed, nH, lanes, scale, causal, dropout):
+    o, lse = _tag_residuals(*_flash_fwd_in_place(
+        qkv, nH, lanes, scale, causal, dropout, seed))
+    return o, (qkv, seed, o, lse)
+
+
+def _flash_in_place_vjp_bwd(nH, lanes, scale, causal, dropout, res, do):
+    qkv, seed, o, lse = res
+    return _flash_bwd_in_place(qkv, o, lse, do, nH, lanes, scale, causal,
+                               dropout, seed), None
+
+
+_flash_in_place.defvjp(_flash_in_place_vjp_fwd, _flash_in_place_vjp_bwd)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def _flash_sparse(q, k, v, layout, seed, scale: float, causal: bool,
                   dropout: float = 0.0):
@@ -873,6 +1134,60 @@ def _to_bh(x):
     return x.transpose(0, 2, 1, 3).reshape(B * nH, S, D)
 
 
+def _split_qkv(qkv, num_heads: int):
+    """q, k, v [B, S, nH, dH] cut out of a fused projection [B, S, 3*nH*dH]."""
+    B, S, _ = qkv.shape
+    return tuple(x.reshape(B, S, num_heads, -1)
+                 for x in jnp.split(qkv, 3, axis=-1))
+
+
+def _dropout_seed(attn_dropout: float, rng, deterministic: bool):
+    """(rate the kernels run at, the keep-mask's int32 seed)."""
+    dropout = float(attn_dropout) if (attn_dropout > 0.0 and not deterministic
+                                      and rng is not None) else 0.0
+    seed = jax.random.bits(rng, (), jnp.uint32).astype(jnp.int32) \
+        if dropout > 0.0 else jnp.zeros((), jnp.int32)
+    return dropout, seed
+
+
+def _in_place(qkv, nH: int, causal: bool, attn_dropout: float, rng,
+              deterministic: bool):
+    """Attention [B, S, H] of ``qkv`` — (q, k, v), each [B, S, H], or the
+    one fused projection ([B, S, 3H],) — read where it lies, or None where
+    the shapes (`tile_lanes`) or a tile the autotuner recorded send the
+    call through `_to_bh`."""
+    B, S, width = qkv[0].shape
+    D = width * len(qkv) // 3 // nH
+    scale = 1.0 / math.sqrt(D)
+    lanes = tile_lanes(S, qkv[-1].shape[1], nH, D)
+    if not lanes or _fwd_blocks(B * nH, S, S, D, qkv[0].dtype, scale,
+                                causal) != (S, S):
+        return None
+    lowered["in_place"] += 1
+    dropout, seed = _dropout_seed(attn_dropout, rng, deterministic)
+    return _flash_in_place(qkv, seed, nH, lanes, scale, causal, dropout)
+
+
+def flash_attention_qkv(qkv: jnp.ndarray, num_heads: int,
+                        mask: Optional[jnp.ndarray] = None,
+                        causal: bool = False, attn_dropout: float = 0.0,
+                        rng=None, deterministic: bool = True) -> jnp.ndarray:
+    """`flash_attention` of a FUSED projection: qkv [B, S, 3*nH*dH] as the
+    projection's GEMM leaves it (q | k | v along the last dim, heads
+    within each) -> [B, S, nH*dH].  Where the kernels run in place the
+    thirds are never cut out: the kernel takes the one array three times,
+    and the gradient comes back as one [B, S, 3*nH*dH]."""
+    B, S, _ = qkv.shape
+    if mask is None and S % 128 == 0:
+        o = _in_place((qkv,), num_heads, causal, attn_dropout, rng,
+                      deterministic)
+        if o is not None:
+            return o
+    return flash_attention(*_split_qkv(qkv, num_heads), mask=mask,
+                           causal=causal, attn_dropout=attn_dropout, rng=rng,
+                           deterministic=deterministic).reshape(B, S, -1)
+
+
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     mask: Optional[jnp.ndarray] = None, causal: bool = False,
                     attn_dropout: float = 0.0, rng=None,
@@ -898,8 +1213,6 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         # The Pallas path needs 128-aligned kernel blocks; a sparse layout
         # fixes the block to S // n_blocks, which must itself be 128-aligned.
         layout_block = S // layout.shape[-1]
-    dropout = float(attn_dropout) if (attn_dropout > 0.0 and not deterministic
-                                      and rng is not None) else 0.0
     if mask is not None or S % 128 != 0 \
             or (layout_block is not None and layout_block % 128 != 0):
         from ..models.transformer import dense_attention
@@ -908,9 +1221,15 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return dense_attention(q, k, v, mask=mask, causal=causal,
                                attn_dropout=attn_dropout, rng=rng,
                                deterministic=deterministic)
+    if layout is None:
+        o = _in_place(tuple(x.reshape(*x.shape[:2], nH * D)
+                            for x in (q, k, v)),
+                      nH, causal, attn_dropout, rng, deterministic)
+        if o is not None:
+            return o.reshape(B, S, nH, D)
+    lowered["relayout"] += 1
     scale = 1.0 / math.sqrt(D)
-    seed = jax.random.bits(rng, (), jnp.uint32).astype(jnp.int32) \
-        if dropout > 0.0 else jnp.zeros((), jnp.int32)
+    dropout, seed = _dropout_seed(attn_dropout, rng, deterministic)
     qt, kt, vt = _to_bh(q), _to_bh(k), _to_bh(v)
     if layout is None:
         o = _flash(qt, kt, vt, seed, scale, causal, dropout)
@@ -962,3 +1281,18 @@ def auto_attention(q, k, v, mask=None, causal=False, attn_dropout=0.0,
     return dense_attention(q, k, v, mask=mask, causal=causal,
                            attn_dropout=attn_dropout, rng=rng,
                            deterministic=deterministic)
+
+
+def auto_attention_qkv(qkv, num_heads: int, mask=None, causal=False,
+                       attn_dropout=0.0, rng=None, deterministic=True):
+    """`auto_attention` of a fused projection qkv [B, S, 3*nH*dH] ->
+    [B, S, nH*dH]: on TPU the flash kernels read it where it lies
+    (`flash_attention_qkv`)."""
+    if jax.default_backend() == "tpu":
+        return flash_attention_qkv(qkv, num_heads, mask=mask, causal=causal,
+                                   attn_dropout=attn_dropout, rng=rng,
+                                   deterministic=deterministic)
+    return auto_attention(*_split_qkv(qkv, num_heads), mask=mask,
+                          causal=causal, attn_dropout=attn_dropout, rng=rng,
+                          deterministic=deterministic
+                          ).reshape(*qkv.shape[:2], -1)

@@ -3000,12 +3000,19 @@ class DeepSpeedEngine:
         t_wall0 = time.perf_counter()
         step = self.global_steps
         tl.profiler_tick(step)
+        first_build = self._train_step_fn is None
         with tl.span("train_batch", step_num=step):
             with tl.span("data_prep", step=step):
                 micro_batches = self._prepare_batch(batch, data_iter)
             with tl.span("offload_step" if self._offload is not None
                          else "step_dispatch", step=step):
                 metrics = self._dispatch_step(micro_batches)
+            if first_build and self._train_step_fn is not None:
+                from ..ops.flash_attention import lowered
+                logger.info(
+                    "train step built: flash attention lowered in_place "
+                    f"{lowered['in_place']}, relayout {lowered['relayout']} "
+                    "call(s) so far in this process")
             with tl.span("step_log", step=step):
                 self._record_telemetry(metrics, t_wall0)
                 self._maybe_log(metrics)

@@ -112,6 +112,19 @@ def _case_flash(bwd, mbs=MBS, nh=NH, dropout=0.0):
     return (_grad_of(fn, (0, 1, 2)) if bwd else fn), (x, x, x, key)
 
 
+def _case_flash_qkv(bwd, mbs=4, nh=20):
+    """A train cell's call as `transformer_block` makes it: the fused
+    [B, S, 3H] projection read in place, attn_pdrop 0.1."""
+    from deepspeed_tpu.ops.flash_attention import flash_attention_qkv
+
+    def fn(qkv, rng):
+        return flash_attention_qkv(qkv, nh, causal=True, attn_dropout=0.1,
+                                   rng=rng, deterministic=False)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    return (_grad_of(fn, (0,)) if bwd else fn), (
+        _sds((mbs, S, 3 * nh * D), jnp.bfloat16), key)
+
+
 def _case_ln(bwd):
     from deepspeed_tpu.ops.fused_elementwise import fused_layer_norm
     fn = fused_layer_norm if not bwd else _grad_of(fused_layer_norm,
@@ -230,6 +243,10 @@ CASES = {
     "flash_bwd_dropout_gpt2_large": (_FLASH_LARGE, True),
     "flash_fwd_dropout_gpt2_medium": (_FLASH_MEDIUM, False),
     "flash_bwd_dropout_gpt2_medium": (_FLASH_MEDIUM, True),
+    "flash_qkv_fwd_gpt2_large": (_case_flash_qkv, False),
+    "flash_qkv_bwd_gpt2_large": (_case_flash_qkv, True),
+    "flash_qkv_bwd_gpt2_medium": (
+        functools.partial(_case_flash_qkv, mbs=8, nh=16), True),
     "fused_ln_fwd": (_case_ln, False),
     "fused_ln_bwd": (_case_ln, True),
     "fused_residual_ln_fwd": (_case_resid_ln, False),
@@ -259,6 +276,29 @@ def test_kernel_compiles_for_v5e(name, one_chip, as_tpu):
     build, arg = CASES[name]
     fn, shapes = build(arg)
     _compile(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("mbs, nh", [(4, 20), (8, 16)],
+                         ids=["gpt2_large", "gpt2_medium"])
+def test_flash_reads_the_fused_projection_in_place(mbs, nh, one_chip, as_tpu):
+    """Forward and backward of a train cell's attention call over the
+    fused [B, S, 3H] projection, compiled: the two kernels, and no copy or
+    transpose of anything as large as q (the relayout path's program holds
+    a dozen); what is left of that size beside them is the concatenate of
+    dq / dk / dv and the test's own loss."""
+    from deepspeed_tpu.analysis.hlo_text import ops_in_units_of
+    from deepspeed_tpu.ops import flash_attention as fa
+    before = dict(fa.lowered)
+    fn, shapes = _case_flash_qkv(True, mbs=mbs, nh=nh)
+    text = _compile(fn, one_chip, *shapes)
+    assert fa.lowered["in_place"] == before["in_place"] + 1
+    assert fa.lowered["relayout"] == before["relayout"]
+    large = {op for op, _ in ops_in_units_of(text, mbs * S * nh * D)}
+    assert not large & {"copy", "transpose", "slice"}, large
+    for kernel in ("_fwd_kernel", "_bwd_fused_kernel"):
+        assert sum(1 for line in text.splitlines()
+                   if kernel in line.split(" = ")[0]
+                   and "tpu_custom_call" in line) == 1, kernel
 
 
 @pytest.mark.parametrize("K,Q", [(1, 64), (128, 1)],
