@@ -231,25 +231,20 @@ def _embed(params, tokens, cfg):
     return x
 
 
-class AfmoeServed(ServedModel):
-    """See the module docstring."""
-    counter_names = ("moe_held_pairs", "moe_held_max", "moe_held_empty",
-                     "moe_rows")
+class GqaPagedServed(ServedModel):
+    """What a model of grouped-query K/V pages answers whatever else its
+    layers hold (experts, a conv state, a state-space mixer): the K/V
+    tiles, the attend's dimensions and step counts (``group`` query heads a
+    K/V head as query rows), and a table row split class by class.  ``cfg``
+    names ``num_attention_heads``, ``num_key_value_heads``, ``head_dim``,
+    ``group``, ``num_hidden_layers`` and ``max_position_embeddings``."""
     @property
     def max_positions(self) -> int:
         return int(self.cfg.max_position_embeddings)
 
     @property
-    def init_fn(self) -> Callable:
-        return afmoe.afmoe_init
-
-    @property
     def cache_layers(self) -> int:
         return int(self.cfg.num_hidden_layers)
-
-    @property
-    def cache_classes(self) -> Tuple[CacheClass, ...]:
-        return _classes(self.cfg)
 
     @property
     def cache_heads(self) -> int:
@@ -283,6 +278,31 @@ class AfmoeServed(ServedModel):
             q_itemsize=q_itemsize) + (
                 paged_attn_ops.attend_cold_steps(live_blocks, calls=calls),)
 
+    def _widths(self, table) -> Tuple[int, ...]:
+        widths = self.table_widths
+        if widths is None and len(self.cache_classes) == 1:
+            widths = (table.shape[-1],)
+        if widths is None or sum(widths) != table.shape[-1]:
+            raise ValueError(
+                f"{type(self).__name__}: a table row {table.shape[-1]} wide "
+                f"against class widths {widths}: the engine sets "
+                "table_widths when it sizes the tables")
+        return widths
+
+
+class AfmoeServed(GqaPagedServed):
+    """See the module docstring."""
+    counter_names = ("moe_held_pairs", "moe_held_max", "moe_held_empty",
+                     "moe_rows")
+
+    @property
+    def init_fn(self) -> Callable:
+        return afmoe.afmoe_init
+
+    @property
+    def cache_classes(self) -> Tuple[CacheClass, ...]:
+        return _classes(self.cfg)
+
     def counter_args(self, rows) -> Dict[str, Any]:
         """Of the executions fetched: routed pairs (every expert is held:
         all of them), the largest and the mean rows an expert got in a
@@ -301,17 +321,6 @@ class AfmoeServed(ServedModel):
                 "moe_held_pair_share": pairs / routed if routed else 0.0}
 
     # -- programs ------------------------------------------------------ #
-    def _widths(self, table) -> Tuple[int, ...]:
-        widths = self.table_widths
-        if widths is None and len(self.cache_classes) == 1:
-            widths = (table.shape[-1],)
-        if widths is None or sum(widths) != table.shape[-1]:
-            raise ValueError(
-                f"{type(self).__name__}: a table row {table.shape[-1]} wide "
-                f"against class widths {widths}: the engine sets "
-                "table_widths when it sizes the tables")
-        return widths
-
     def verify(self, params, pools, tokens, lengths, block_tables, *,
                num_groups, paged_kernel, mesh=None):
         cfg = self.cfg
@@ -358,4 +367,4 @@ class AfmoeServed(ServedModel):
 
 register(AfmoeConfig, AfmoeServed)
 
-__all__ = ["AfmoeServed", "FULL_CLASS", "WINDOW_CLASS"]
+__all__ = ["GqaPagedServed", "AfmoeServed", "FULL_CLASS", "WINDOW_CLASS"]

@@ -300,12 +300,11 @@ class InferenceEngine:
         self.param_bytes = quantized_bytes(self._params)
 
         # --- the KV cache: the paged block pool, born sharded ---
-        kv_dtype = served.cache_dtype or resolve_kv_dtype(
-            self.icfg.kv_cache_dtype, served.dtype)
+        kv_dtype = resolve_kv_dtype(self.icfg.kv_cache_dtype, served.dtype)
         # One spec, pool set and allocator a CLASS of cache layers (one
         # class for most models; kv_cache.py's docstring), each built
         # from the model's answer for THAT class: a state's page is not a
-        # K/V block's tile.
+        # K/V block's tile, nor of its dtype where the model says so.
         classes = served.cache_classes
         asked = self.icfg.num_blocks
         if asked and isinstance(asked, dict) != (len(classes) > 1):

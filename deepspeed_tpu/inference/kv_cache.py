@@ -149,14 +149,20 @@ class PagedKVCacheSpec:
     # What the served model keeps, where it is not GPT-2's per-head K and
     # V: ((pool name, ONE block's tile as held [heads, rows, lanes]), ...)
     # (``inference.served.ServedModel.cache_pools``).  ``num_heads`` /
-    # ``head_dim`` then describe a cache row's heads and logical width.
-    pools: Optional[Tuple[Tuple[str, Tuple[int, int, int]], ...]] = None
+    # ``head_dim`` then describe a cache row's heads and logical width.  A
+    # pool that is not of the spec's ``dtype`` says so in a third entry
+    # ((name, tile, dtype): a float32 state beside bfloat16 rows in one
+    # page).
+    pools: Optional[Tuple[Tuple[Any, ...], ...]] = None
     # A tile is one STREAM's state of a layer, of fixed size (a page; see
-    # the module docstring), not ``block_size`` tokens' rows; and what a
+    # the module docstring), not ``block_size`` tokens' rows; what a
     # token of this model would keep a layer as K/V rows, in bytes — the
-    # yardstick of ``StateAllocator.snapshot_boundary``.
+    # yardstick of ``StateAllocator.snapshot_boundary`` — and, where the
+    # state sits BESIDE classes of pages, the rows of one prefill program
+    # (``class_specs``; 0: the state is the model's only cache).
     per_stream: bool = False
     token_row_bytes: int = 0
+    program_rows: int = 0
     # The CLASS of cache layers this pool serves (module docstring): its
     # name ("" for a model's only class), how many tokens back a query of
     # these layers reads, itself included (None: all of them), and the
@@ -178,7 +184,7 @@ class PagedKVCacheSpec:
         """((pool name, one block's tile as held), ...): ``pools``, or
         the K and V pools ``num_heads`` / ``head_dim`` give."""
         if self.pools is not None:
-            tiles = self.pools
+            tiles = tuple((entry[0], entry[1]) for entry in self.pools)
         else:
             f = self.fold
             tile = (self.num_heads, self.block_size // f, f * self.head_dim)
@@ -191,6 +197,15 @@ class PagedKVCacheSpec:
     @property
     def pool_names(self) -> Tuple[str, ...]:
         return tuple(name for name, _ in self.pool_tiles)
+
+    @property
+    def pool_dtypes(self) -> Dict[str, Any]:
+        """Every pool's dtype, by the name it is held under: the spec's
+        ``dtype`` unless the pool's declaration names its own."""
+        if self.pools is None:
+            return dict.fromkeys(self.pool_names, self.dtype)
+        return {name: entry[2] if len(entry) > 2 else self.dtype
+                for name, entry in zip(self.pool_names, self.pools)}
 
     @property
     def pool_shapes(self) -> Dict[str, Tuple[int, ...]]:
@@ -224,10 +239,17 @@ class PagedKVCacheSpec:
 
     @property
     def page_tokens(self) -> int:
-        """Tokens whose K/V rows would fill a page of a per-stream pool:
-        a snapshot of fewer is dearer than what it saves."""
-        return -(-self.block_nbytes()
-                 // (self.num_layers * max(1, self.token_row_bytes)))
+        """Tokens a prompt has to add for a page of a per-stream pool to
+        be worth keeping as its snapshot: those whose K/V rows would fill
+        the page (a snapshot of fewer is dearer than the rows it stands
+        for) — and, beside classes of pages, one prefill program's rows
+        at most: there the rows ARE cached as well, only a snapshot at
+        their end lets a later prompt use them, and what it then saves is
+        a whole program, whatever the page's bytes."""
+        tokens = -(-self.block_nbytes()
+                   // (self.num_layers * max(1, self.token_row_bytes)))
+        return min(tokens, self.program_rows) if self.program_rows \
+            else tokens
 
     @property
     def fold(self) -> int:
@@ -253,8 +275,10 @@ class PagedKVCacheSpec:
     def block_nbytes(self) -> int:
         """Bytes one block holds across all layers and pools — the unit
         of the hbm_bytes_per_token accounting."""
-        return (self.num_layers * jnp.dtype(self.dtype).itemsize
-                * sum(math.prod(tile) for _, tile in self.pool_tiles))
+        dtypes = self.pool_dtypes
+        return self.num_layers * sum(
+            math.prod(tile) * jnp.dtype(dtypes[name]).itemsize
+            for name, tile in self.pool_tiles)
 
     def validate(self, mesh: Optional[Mesh] = None) -> None:
         for name in ("num_layers", "num_slots", "num_blocks", "block_size",
@@ -376,7 +400,8 @@ def init_paged_cache(spec: PagedKVCacheSpec,
     spec.validate(mesh)
 
     def make():
-        return {name: jnp.zeros(shape, spec.dtype)
+        dtypes = spec.pool_dtypes
+        return {name: jnp.zeros(shape, dtypes[name])
                 for name, shape in spec.pool_shapes.items()}
 
     if mesh is None:
@@ -887,8 +912,9 @@ class StateAllocator(BlockAllocator):
     def snapshot_boundary(self, prompt_len: int, resumed: int) -> int:
         """THE RULE of which boundaries get a page: the prompt's last
         full block, when the tokens it adds beyond the snapshot it
-        resumed from would fill at least a page as K/V rows of this model
-        (``PagedKVCacheSpec.page_tokens``) — below that the page is
+        resumed from are worth one (``PagedKVCacheSpec.page_tokens``: they
+        would fill a page as K/V rows of this model or, beside classes of
+        pages, a whole prefill program) — below that the page is
         dearer than what it saves.  So a document served once leaves a
         snapshot; a short question over it does not, and cannot push a
         document out.  Returns the boundary in tokens, or 0."""
@@ -1298,15 +1324,19 @@ def class_specs(classes, asked, rows: int, of_class=None, **geometry
     """One spec a CLASS of a model's cache layers (``served.CacheClass``:
     name, layers, reach, per_stream) over the engine's ``geometry`` (the
     spec's other fields) and what ``of_class(cls)`` adds for that class
-    (its pools' tiles, heads, row width: ``ServedModel.class_geometry``).
+    (its pools' tiles — and dtypes, where a pool names its own — heads, row
+    width: ``ServedModel.class_geometry``).
     ``asked``: ``inference.num_blocks``, an int or {class name: blocks} (0
     or a class left out: full provisioning); ``rows``: the most query rows
     of a stream one program holds — a bounded class's table is a ring as
-    wide as they reach."""
+    wide as they reach, and a state beside classes of pages is worth a
+    snapshot once a prompt adds that many (``page_tokens``)."""
     table = geometry["max_len"] // geometry["block_size"]
+    beside_pages = not all(cls.per_stream for cls in classes)
     return tuple(PagedKVCacheSpec(
         num_layers=cls.layers, name=cls.name, reach=cls.reach,
         per_stream=cls.per_stream,
+        program_rows=rows if cls.per_stream and beside_pages else 0,
         num_blocks=int(asked.get(cls.name, 0) if isinstance(asked, dict)
                        else asked),
         table_blocks=0 if cls.reach is None else min(

@@ -112,8 +112,6 @@ def _embed(params, tokens, cfg):
 
 class RetentionServed(ServedModel):
     """See the module docstring."""
-    cache_dtype = jnp.float32       # the state and its normaliser
-
     @property
     def max_positions(self) -> int:
         return int(self.cfg.max_position_embeddings)
@@ -140,8 +138,11 @@ class RetentionServed(ServedModel):
         return int(self.cfg.head_dim)
 
     def cache_pools(self, block_size: int):
-        return pr.state_tiles(self.cfg.num_key_value_heads,
-                              self.cfg.head_dim)
+        """The state and its normaliser, float32 whatever
+        ``inference.kv_cache_dtype``."""
+        return tuple((name, tile, jnp.float32) for name, tile in
+                     pr.state_tiles(self.cfg.num_key_value_heads,
+                                    self.cfg.head_dim))
 
     @property
     def token_row_bytes(self) -> int:

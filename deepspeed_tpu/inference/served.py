@@ -44,6 +44,7 @@ imported first — so a model nobody serves costs no import.
 from __future__ import annotations
 
 import importlib
+import math
 from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -71,9 +72,6 @@ class ServedModel:
     """Base of the implementations; see the module docstring."""
     cfg: Any
     counter_names: Tuple[str, ...] = ()
-    # What the pools hold where that is not ``inference.kv_cache_dtype``'s
-    # to choose (a recurrent state is fp32 whatever the rows' dtype).
-    cache_dtype: Any = None
     # Each class's table width, in ``cache_classes`` order: the ENGINE sets
     # it when it has sized the tables (a window's ring depends on its
     # prefill chunk); a model of several classes splits a table row by it.
@@ -127,7 +125,9 @@ class ServedModel:
     def cache_pools(self, block_size: int
                     ) -> Tuple[Tuple[str, Tuple[int, int, int]], ...]:
         """((pool name, one block's tile as held [heads, rows, lanes]),
-        ...)."""
+        ...).  A pool whose dtype is not ``inference.kv_cache_dtype``'s to
+        choose (a recurrent state is fp32 whatever the rows' dtype) names
+        it: (name, tile, dtype)."""
         raise NotImplementedError
 
     @property
@@ -182,8 +182,8 @@ class ServedModel:
     def attend_bytes(self, keys: int, block_size: int, itemsize: int
                      ) -> int:
         """Cache bytes a layer's attend streams for ``keys`` key rows."""
-        per_block = sum(h * r * l for _, (h, r, l) in
-                        self.cache_pools(block_size))
+        per_block = sum(math.prod(pool[1])
+                        for pool in self.cache_pools(block_size))
         return per_block * int(keys) * int(itemsize) // block_size
 
     def attend_step_counts(self, live_blocks, *, K: int, spec, mp: int,

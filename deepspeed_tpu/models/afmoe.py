@@ -55,7 +55,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .blocks import Routing, matmul, rms_norm, rotary_cos_sin
+from .blocks import (Routing, matmul, rms_norm, rope_half, rotary_cos_sin,
+                     rotary_inv_freq)
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -168,20 +169,7 @@ class AfmoeConfig:
 
 def inv_freq(cfg: AfmoeConfig) -> np.ndarray:
     """float64 [head_dim / 2]: ``theta^(-2i / head_dim)``, unscaled."""
-    D = cfg.head_dim
-    return float(cfg.rope_theta) ** (-np.arange(0, D, 2, dtype=np.float64)
-                                     / D)
-
-
-def rope_half(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """Rotate the pairs ``(i, i + D/2)`` of the last axis by frequency i
-    (rotate-half, the pairing the family's modeling code uses).  cos/sin
-    ``[..., D/2]`` broadcast against the halves; fp32 inside, x's dtype
-    out."""
-    xf = x.astype(jnp.float32)
-    a, b = jnp.split(xf, 2, axis=-1)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
+    return rotary_inv_freq(cfg.rope_theta, cfg.head_dim)
 
 
 def qkvg(p: Dict[str, jax.Array], h: jax.Array, positions: jax.Array,

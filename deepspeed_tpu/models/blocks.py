@@ -1,5 +1,5 @@
 """Block primitives more than one model family is written in: RMS norm,
-rotary angles, the gated-SiLU FFN, the compute-dtype product, and the
+rotary angles and the rotate-half rotation, the gated-SiLU FFN, the compute-dtype product, and the
 description of an expert layer's routing that ``moe/share.py`` reads.  A
 family's own file (``deepseek_v3.py``, ``brumby.py``, ``afmoe.py``) holds
 its config, its init and what only it has; none imports these from a
@@ -11,6 +11,7 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 class Routing(NamedTuple):
@@ -40,6 +41,22 @@ def rotary_cos_sin(inv_freq, positions: jax.Array, scale: float = 1.0):
     return jnp.cos(ang) * scale, jnp.sin(ang) * scale
 
 
+def rotary_inv_freq(theta: float, head_dim: int) -> np.ndarray:
+    """float64 [head_dim / 2]: ``theta^(-2i / head_dim)``, unscaled."""
+    return float(theta) ** (-np.arange(0, head_dim, 2, dtype=np.float64)
+                            / head_dim)
+
+
+def rope_half(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """Rotate the pairs ``(i, i + D/2)`` of the last axis by frequency i
+    (rotate-half).  cos/sin ``[..., D/2]`` broadcast against the halves;
+    fp32 inside, x's dtype out."""
+    xf = x.astype(jnp.float32)
+    a, b = jnp.split(xf, 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     """``x * rsqrt(mean(x^2) + eps) * w`` in fp32, x's dtype out."""
     xf = x.astype(jnp.float32)
@@ -62,4 +79,5 @@ def swiglu(x: jax.Array, gate: jax.Array, up: jax.Array, down: jax.Array
     return matmul((jax.nn.silu(g) * u).astype(x.dtype), down)
 
 
-__all__ = ["rotary_cos_sin", "rms_norm", "matmul", "swiglu"]
+__all__ = ["rotary_cos_sin", "rotary_inv_freq", "rope_half", "rms_norm",
+           "matmul", "swiglu"]

@@ -60,7 +60,14 @@ SCOPES = ("fwd_bwd", "grad_sync", "health_tap", "optimizer", "flatten",
           # conv_in_proj (norm, the gates' projection), conv_mix (the
           # gates' product, the filter, the rewrite of the stream's page),
           # conv_out_proj
-          "conv", "conv_in_proj", "conv_mix", "conv_out_proj")
+          "conv", "conv_in_proj", "conv_mix", "conv_out_proj",
+          # a state-space mixer beside attention in one layer
+          # (inference/falcon_h1.py): ssm > ssm_in_proj, ssm_conv (the
+          # filter rows' read, filter and rewrite; dt and the decay),
+          # ssm_state_update (decode: the kernel over the state pool) /
+          # ssm_chunk_scan (prefill), ssm_gate_norm, ssm_out_proj
+          "ssm", "ssm_in_proj", "ssm_conv", "ssm_state_update",
+          "ssm_chunk_scan", "ssm_gate_norm", "ssm_out_proj")
 # The host spans ``Telemetry.span`` opens (runtime/engine.py,
 # inference/engine.py, inference/scheduler.py), same contract.
 SPANS = ("train_batch", "data_prep", "step_dispatch", "offload_step",

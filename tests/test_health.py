@@ -359,7 +359,7 @@ class TestWatchdog:
             ev = fired[0]
             assert ev["pending_fn"] == "train_step"
             assert ev["phase"] == "steady"
-            assert ev["elapsed_s"] > 0.2
+            assert ev["elapsed_s"] >= 0.2     # (reported to the millisecond)
             dump = open(ev["stack_dump_path"]).read()
             assert "Thread" in dump and "watchdog" in dump
             wd.beat(0.01)             # re-arm

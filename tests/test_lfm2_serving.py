@@ -842,7 +842,7 @@ def test_the_four_families_specs_are_what_the_engine_built_before(family):
     this PR)."""
     served = served_model(_family(family))
     geometry = dict(num_slots=4, block_size=4, max_len=128, num_groups=1,
-                    dtype=served.cache_dtype or jnp.float32)
+                    dtype=jnp.float32)
     asked = {c.name: 24 for c in served.cache_classes} \
         if len(served.cache_classes) > 1 else 24
     new = class_specs(served.cache_classes, asked, rows=8,

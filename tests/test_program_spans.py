@@ -866,3 +866,73 @@ def test_decode_and_prefill_spans_carry_both_kinds(tmp_path, kinds_engine):
     assert state["snapshots_taken"] >= 2     # the document's + a turn's
     assert state["prefix_lost_to_kind_tokens"] == 0
     assert set(report["cache_classes"]) == {"full", "conv"}
+
+
+# --------------------------------------------------------------------- #
+# (j) a model whose EVERY layer keeps K/V pages and a state a stream (PR
+# 48): the state-space mixer's scopes beside the attention branch's
+# --------------------------------------------------------------------- #
+@pytest.fixture(scope="module")
+def both_kinds_op_names():
+    import jax.numpy as jnp
+    from deepspeed_tpu.models.falcon_h1 import (FalconH1Config,
+                                                falcon_h1_init)
+    cfg = FalconH1Config(
+        vocab_size=128, hidden_size=64, intermediate_size=96,
+        num_hidden_layers=2, num_attention_heads=10, num_key_value_heads=2,
+        head_dim=16, mamba_d_ssm=32, mamba_n_heads=4, mamba_d_head=8,
+        mamba_d_state=16, mamba_n_groups=2, mamba_chunk_size=8,
+        max_position_embeddings=256, dtype=jnp.float32)
+    eng = InferenceEngine(
+        cfg, falcon_h1_init(jax.random.PRNGKey(0), cfg),
+        config={"inference": {"max_slots": 4, "max_seq_len": 128,
+                              "prefill_chunk": 8, "block_size": 4,
+                              "num_blocks": {"full": 96, "state": 12},
+                              "paged_kernel": True}},
+        mesh=build_mesh(devices=jax.devices()[:1]))
+    G, W = eng.dp, eng.allocator.table_width
+    key, temp = eng._next_key(), np.float32(0.0)
+    names = {
+        "decode": _op_names(eng._decode_fn, eng._params, *eng._pools(),
+                            eng._no_fetch, eng.last_tokens,
+                            np.ones(eng.max_slots, bool), eng.lengths,
+                            eng.block_tables, key, temp),
+        # (+ the snapshot's row and page: the program freezes it)
+        "prefill": _op_names(
+            eng._prefill_fn, eng._params, *eng._pools(),
+            np.zeros((G, eng.prefill_chunk), np.int32),
+            np.zeros((G, W), np.int32), np.zeros(G, np.int32),
+            np.zeros(G, np.int32), np.ones(G, np.int32),
+            np.zeros(G, np.int32), np.zeros(G, np.int32), key, temp)}
+    eng.close()
+    return names
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("scope", [
+    "embed", "attn/qkv_proj", "attn/kv_write", "attn/attend_full",
+    "attn/out_proj", "ssm/ssm_in_proj", "ssm/ssm_conv", "ssm/ssm_gate_norm",
+    "ssm/ssm_out_proj", "mlp", "lm_head", "sample"])
+def test_both_kinds_program_carries_scope(both_kinds_op_names, program,
+                                          scope):
+    assert any(f"/{scope}" in n for n in both_kinds_op_names[program]), \
+        (program, scope)
+
+
+def test_the_state_update_and_the_scan_each_belong_to_one_program(
+        both_kinds_op_names):
+    names = both_kinds_op_names
+    assert any("/ssm/ssm_state_update" in n for n in names["decode"])
+    assert not any("/ssm_chunk_scan" in n for n in names["decode"])
+    assert any("/ssm/ssm_chunk_scan" in n for n in names["prefill"])
+    assert not any("/ssm_state_update" in n for n in names["prefill"])
+
+
+def test_the_readers_list_names_the_state_space_scopes():
+    from deepspeed_tpu.monitor.xplane_reader import SCOPES, scope_of
+    assert {"ssm", "ssm_in_proj", "ssm_conv", "ssm_state_update",
+            "ssm_chunk_scan", "ssm_gate_norm", "ssm_out_proj"} <= set(SCOPES)
+    assert scope_of("jit(decode_step)/ssm/ssm_state_update/pallas_call")[0] \
+        == ("ssm", "ssm_state_update")
+    assert scope_of("jit(prefill_step)/ssm/ssm_chunk_scan/while/dot")[0] \
+        == ("ssm", "ssm_chunk_scan")

@@ -278,6 +278,17 @@ def _question_error(eng, params, doc, ref_params=None):
     return float(np.abs(got - want(ref_params or params, prompt, tok)).max())
 
 
+def _hold_the_state_in(monkeypatch, dtype):
+    """The family's pools declared in ``dtype`` (they name float32)."""
+    from deepspeed_tpu.inference import retention
+    declared = retention.RetentionServed.cache_pools
+    monkeypatch.setattr(
+        retention.RetentionServed, "cache_pools",
+        lambda self, block_size: tuple(
+            (name, tile, dtype) for name, tile, _ in declared(self,
+                                                              block_size)))
+
+
 def _snapshot_page(eng, doc):
     n, page, _ = eng.allocator.match_snapshot(0, np.concatenate([doc, [0]]))
     assert n == len(doc) // 8
@@ -290,8 +301,7 @@ def _snapshot_page(eng, doc):
 def test_the_check_fails_on_each_fault(params, fault, monkeypatch):
     from deepspeed_tpu.inference import retention
     if fault == "bf16_state":
-        monkeypatch.setattr(retention.RetentionServed, "cache_dtype",
-                            jnp.bfloat16)
+        _hold_the_state_in(monkeypatch, jnp.bfloat16)
     served = params
     if fault == "gate_bias_zero":
         served = dict(params, layers=dict(
@@ -326,8 +336,7 @@ def test_the_page_after_a_reply_is_held_to_the_reference_state(
     from deepspeed_tpu.inference import retention
     from perfbench.runners import docqa_state as runner
     if fault == "bf16_state":
-        monkeypatch.setattr(retention.RetentionServed, "cache_dtype",
-                            jnp.bfloat16)
+        _hold_the_state_in(monkeypatch, jnp.bfloat16)
     cfg = BrumbyConfig.from_hf(SIZES, dtype=jnp.float32,
                                gate_half_life_min=64.0,
                                gate_half_life_max=4096.0)

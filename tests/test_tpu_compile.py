@@ -952,7 +952,7 @@ def retention_programs(topo):
         num_layers=served.cache_layers, num_slots=inf["max_slots"],
         num_blocks=inf["num_blocks"], block_size=inf["block_size"],
         max_len=inf["max_seq_len"], num_heads=served.cache_heads,
-        head_dim=served.cache_row_width, dtype=served.cache_dtype,
+        head_dim=served.cache_row_width,
         pools=served.cache_pools(inf["block_size"]), per_stream=True,
         token_row_bytes=served.token_row_bytes)
     eng = object.__new__(InferenceEngine)
@@ -961,7 +961,8 @@ def retention_programs(topo):
     eng.prefill_chunk = inf["prefill_chunk"]
     eng._cache_sh = {name: one for name in spec.pool_names}
     S, J, C = spec.num_slots, spec.max_blocks_per_slot, eng.prefill_chunk
-    pools = [on_chip(jax.ShapeDtypeStruct(spec.pool_shapes[n], spec.dtype))
+    pools = [on_chip(jax.ShapeDtypeStruct(spec.pool_shapes[n],
+                                          spec.pool_dtypes[n]))
              for n in spec.pool_names]
     i32 = lambda *shape: on_chip(jax.ShapeDtypeStruct(shape, jnp.int32))
     fresh = lambda n: on_chip(jax.ShapeDtypeStruct((n,), jnp.bool_))
@@ -1188,3 +1189,163 @@ def test_the_page_copy_of_a_mixed_model_leaves_the_kv_pools_alone(
                and " parameter(" not in line and "tuple(" not in line]
     assert not touched, touched[:3]
     assert "dynamic-update-slice" in text
+
+
+# ------------------------------------------------------------------ #
+# The falcon_h1 family (PR 48): the ENGINE's programs over TWO KINDS of
+# cache in EVERY layer — K/V pages of 5 query heads a K/V head and an fp32
+# state a stream — at the benchmark cell's shape
+# ------------------------------------------------------------------ #
+@pytest.mark.parametrize("streams", [128, 1])
+def test_ssm_state_update_kernel_compiles_at_the_published_widths(
+        streams, one_chip, as_tpu):
+    """32 heads x [256, 128] fp32 a page and layer, 16 heads (one group) a
+    grid step, the pool aliased in and out."""
+    from deepspeed_tpu.ops import ssm_scan
+    S = streams
+    pool = jax.ShapeDtypeStruct((4, 1, 8, 32, 256, 128), jnp.float32,
+                                sharding=one_chip)
+    f = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32,   # noqa: E731
+                                        sharding=one_chip)
+    compiled = jax.jit(
+        lambda pool, pages, x, B, C, dt, da: ssm_scan.state_update(
+            pool, 2, pages, x, B, C, dt, da), donate_argnums=0).lower(
+        pool, jax.ShapeDtypeStruct((1, S), jnp.int32, sharding=one_chip),
+        f(1, S, 32, 128), f(1, S, 2, 256), f(1, S, 2, 256), f(1, S, 32),
+        f(1, S, 32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "_ssm_state_update_kernel" in text
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 4 * 8 * 32 * 256 * 128 * 4
+
+
+@pytest.fixture(scope="module")
+def both_kinds_in_a_layer_programs(topo):
+    """(specs, params bytes, {program: compiled}) of the engine's own step
+    builders for ``perfbench/configs/falcon-h1-34b.json`` on an engine shell
+    (see ``_serve_program``): ``decode_step``, ``prefill_step`` at both of
+    ``prefill_widths`` and the page copy."""
+    import json
+    import os
+    from types import SimpleNamespace
+    from deepspeed_tpu.inference import kv_cache
+    from deepspeed_tpu.inference.engine import (InferenceEngine,
+                                                prefill_widths)
+    from deepspeed_tpu.inference.served import served_model
+    from deepspeed_tpu.models.falcon_h1 import (FalconH1Config,
+                                                falcon_h1_init)
+    from jax.experimental.compilation_cache import compilation_cache
+    sizes = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "perfbench", "configs", "falcon-h1-34b.json")))
+    inf = sizes["serve"]["inference"]
+    cfg = FalconH1Config.from_hf(sizes)
+    served = served_model(cfg)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+            tree)
+    params = on_chip(jax.eval_shape(lambda k: falcon_h1_init(k, cfg),
+                                    jax.random.PRNGKey(0)))
+    param_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(params))
+    specs = kv_cache.class_specs(
+        served.cache_classes, inf["num_blocks"], rows=inf["prefill_chunk"],
+        of_class=lambda c: served.class_geometry(c, inf["block_size"]),
+        num_slots=inf["max_slots"], block_size=inf["block_size"],
+        max_len=inf["max_seq_len"], num_groups=1, dtype=jnp.bfloat16)
+    served.table_widths = tuple(sp.max_blocks_per_slot for sp in specs)
+    eng = object.__new__(InferenceEngine)
+    eng.model_cfg, eng.dp, eng.sp, eng.mesh = served, 1, 1, None
+    eng.paged_kernel, eng.quantize = True, "none"
+    eng.prefill_chunk = inf["prefill_chunk"]
+    eng._cache_sh = {n: one for sp in specs for n in sp.pool_names}
+    eng.allocator = SimpleNamespace(copy_pools=specs[-1].pool_names)
+    pools = [on_chip(jax.ShapeDtypeStruct(sp.pool_shapes[n],
+                                          sp.pool_dtypes[n]))
+             for sp in specs for n in sp.pool_names]
+    S, J = inf["max_slots"], sum(served.table_widths)
+    i32 = lambda *shape: on_chip(jax.ShapeDtypeStruct(shape, jnp.int32))
+    fresh = lambda n: on_chip(jax.ShapeDtypeStruct((n,), jnp.bool_))
+    key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
+    temp = on_chip(jax.ShapeDtypeStruct((), jnp.float32))
+    mp = pytest.MonkeyPatch()
+    prev = jax.config.jax_enable_compilation_cache
+    out = {}
+    try:
+        mp.setattr(jax, "default_backend", lambda *a, **k: "tpu")
+        mp.setenv("DS_AUTOTUNE", "0")
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        out["decode_step"] = eng._build_decode_step().lower(
+            params, *pools, i32(S), i32(S), fresh(S), i32(S), i32(S, J),
+            key, temp).compile()
+        for C in prefill_widths(inf["prefill_chunk"], inf["block_size"]):
+            # (+ the snapshot's row and page: the program freezes it)
+            out[f"prefill_step.{C}"] = eng._build_prefill_step().lower(
+                params, *pools, i32(1, C), i32(1, J), i32(1), i32(1), i32(1),
+                i32(1), i32(1), key, temp).compile()
+        out["state_copy"] = eng._build_copy("state_copy", "state_copy").lower(
+            *pools, i32(1), i32(1)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+        mp.undo()
+    return specs, param_bytes, out
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_step.256",
+                                     "prefill_step.512", "state_copy"])
+def test_both_kinds_in_a_layer_fit_and_are_updated_in_place(
+        both_kinds_in_a_layer_programs, program):
+    """Weights 8.79 GB (4 layers of 430.1 M, 261,120 rows of embedding and
+    of untied head) + K/V pools 1.07 GB (bf16) + 184 state pages of 16.9 MB
+    (fp32 state, bf16 filter rows): every pool aliased to its output,
+    scratch inside what is left of the chip's 16 GiB; the attend (5 query
+    heads a K/V head), the row write and, in decode, the state update are
+    TPU custom calls; no program holds an op the size of a pool."""
+    from deepspeed_tpu.analysis.hlo_text import ops_in_units_of
+    specs, param_bytes, programs = both_kinds_in_a_layer_programs
+    full, state = specs
+    compiled = programs[program]
+    assert abs(param_bytes - 8.794e9) < 0.01e9
+    assert full.nbytes() == 2 * 2048 * 4 * 4 * 64 * 128 * 2
+    assert state.block_nbytes() == 4 * (32 * 256 * 128 * 4 + 3 * 5120 * 2)
+    # (a page is 2,063 tokens of this model's K/V rows; beside the pages a
+    # prompt that adds one prefill program's rows leaves a snapshot)
+    assert state.token_row_bytes == 2048 and state.page_tokens == 512
+    assert state.pool_shapes == {"ssm.state": (4, 1, 184, 32, 256, 128),
+                                 "conv.state": (4, 1, 184, 1, 120, 128)}
+    pool_bytes = full.nbytes() + state.nbytes()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    if program == "state_copy":
+        assert mem.temp_size_in_bytes < 64 * 2 ** 20
+        return
+    assert param_bytes + pool_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes \
+        < 15.75 * 2 ** 30, mem
+    text = compiled.as_text()
+    kernels = ["_pattn_kernel", "_kv_write_kernel"]
+    if program == "decode_step":
+        kernels.append("_ssm_state_update_kernel")
+    for kernel in kernels:
+        calls = [line for line in text.splitlines()
+                 if f"%{kernel}" in line.split(" = ")[0]
+                 and " custom-call(" in line]
+        assert calls and all("tpu_custom_call" in c for c in calls), kernel
+    # (a chunk's state goes back into its page by a dynamic-update-slice
+    # of the donated pool, fused with the page's read: in place, as the
+    # retention family's does)
+    in_place = {"dynamic-update-slice", "fusion"} \
+        if program != "decode_step" else set()
+    for pool in ("k.full", "ssm.state"):
+        spec = full if pool == "k.full" else state
+        seen = ops_in_units_of(text, math.prod(spec.pool_shapes[pool][2:]))
+        assert not [(op, n) for op, n in seen
+                    if op not in _POOL_OPS_ALLOWED | in_place], pool
+    assert "/attn/attend_full" in text and "/ssm/ssm_conv" in text
+    assert ("/ssm/ssm_state_update" if program == "decode_step"
+            else "/ssm/ssm_chunk_scan") in text
