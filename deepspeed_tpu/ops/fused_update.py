@@ -54,10 +54,15 @@ no model name):
   f32 arrays of the leaf's own shape (``FusedAdamState.leaf_m/leaf_v``),
   ZeRO-sharded like the leaf's gradient (zero/partition.py's
   first-divisible-dim rule; stage 3's spec under ZeRO-3). The kernel
-  reads the f32 gradient, the parameter and both moments through the 2-D
-  view with ``input_output_aliases`` on parameter and moments: nothing
-  is copied, concatenated or relaid. The squared norm of these leaves is
-  a plain f32 reduction per leaf (XLA runs it at bandwidth).
+  reads the gradient AT THE WIDTH IT ARRIVES (bf16 from a master-free
+  one-device backward, f32 from every path that summed it: dp > 1, the
+  accumulation scan, 1F1B, the trio), the parameter and both moments
+  through the 2-D view with ``input_output_aliases`` on parameter and
+  moments: nothing is copied, concatenated, relaid or widened in HBM —
+  the kernel's first line widens in registers, which is exact. The
+  squared norm of these leaves is a plain reduction per leaf over the
+  WIDENED values (XLA fuses the convert into it and runs it at
+  bandwidth).
 - **Packed.** Every other float leaf (biases, LayerNorm vectors,
   anything small or off the tiling) goes through ONE flat group buffer
   per dtype with its own flat moments — the layout below. For the
@@ -685,7 +690,7 @@ def _update_leaf(g, p, m, v, scalars, seed, **static):
 
 def apply_hbm_bytes(params: Any, *, one_pass: bool = True,
                     cast_dtype=None, fp16: bool = False,
-                    clip: bool = True) -> Dict[str, int]:
+                    clip: bool = True, grad_dtype=None) -> Dict[str, int]:
     """Analytic HBM bytes one optimizer step's APPLY phase moves, per
     replica (monitor/cost_model.py prices the apply path with this; the
     roofline record carries both modes).
@@ -694,8 +699,12 @@ def apply_hbm_bytes(params: Any, *, one_pass: bool = True,
     REALLY paid are priced, and the one-pass side pays for what it
     really runs:
 
-    - Both modes share the apply kernel's read g(f32)+p+m+v, write
-      p+m+v (+ the compute-dtype cast-copy write).
+    - Both modes share the apply kernel's read g+p+m+v, write p+m+v
+      (+ the compute-dtype cast-copy write). An in-place leaf's gradient
+      is read at ``grad_dtype``'s width — the width it reaches the apply
+      at; default the parameter's own, what a backward through that
+      parameter writes — and a packed leaf's at 4 B (the group buffer
+      flattens in f32 whatever arrives).
     - When a norm is needed (``clip`` or ``fp16``), BOTH modes re-read
       the grads once more: the two-pass path as the separate
       ``global_norm`` pass, the one-pass path as the ``_run_sqnorm``
@@ -721,7 +730,11 @@ def apply_hbm_bytes(params: Any, *, one_pass: bool = True,
     n = sum(int(l.size) for l in leaves)
     p_bytes = sum(int(l.size) * jnp.dtype(l.dtype).itemsize
                   for l in leaves)
-    g_bytes = 4 * n                       # grads flatten in f32
+    inplace = set(update_plan(leaves).inplace)
+    g_bytes = sum(
+        int(l.size) * (jnp.dtype(grad_dtype or l.dtype).itemsize
+                       if i in inplace else 4)   # packed: flat f32
+        for i, l in enumerate(leaves))
     mv_bytes = 2 * 4 * n                  # f32 moments
     cast_bytes = (n * jnp.dtype(cast_dtype).itemsize) if cast_dtype else 0
     kernel = g_bytes + p_bytes + mv_bytes + p_bytes + mv_bytes + cast_bytes
@@ -854,9 +867,11 @@ def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
             with jax.named_scope("norm"):
                 # In-place leaves: a plain f32 reduction per leaf, which
                 # XLA runs at bandwidth — no second pallas_call per
-                # geometry to trace and lower at start-up.
+                # geometry to trace and lower at start-up. A narrow leaf
+                # is widened INSIDE the reduction (XLA fuses that; a
+                # bf16 square would be another result).
                 for g in lgs:
-                    nsq = nsq + jnp.sum(g * g)
+                    nsq = nsq + jnp.sum(jnp.square(g.astype(jnp.float32)))
             if axis is not None:
                 nsq = lax.psum(nsq, axis)
             # norm of the UNSCALED grads: ||g*inv|| == inv * ||g||.
@@ -976,6 +991,8 @@ def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
             # engines hand in f32-accumulated grads over bf16 params,
             # and truncating them here would defeat the kernel's
             # f32-second-moment guarantee before it ever reads them.
+            # (Narrow grads widen in this copy, exactly; the packed
+            # leaves are too few to earn a second kernel geometry.)
             gbufs.append(_flatten_group(g_leaves, idxs, jnp.float32,
                                         shards, Lpad, constrain))
             pbufs.append(_flatten_group(p_leaves, idxs, dt, shards,
@@ -989,9 +1006,11 @@ def fused_adam(learning_rate: ScheduleOrFloat, b1: float = 0.9,
             ms.append(m2)
             vs.append(v2)
             group_modes.append(_modes(dt, sr_key is not None, cast_dtype))
-        # In-place leaves enter as they are: the f32 gradient, the
-        # parameter and both moments, no assembly.
-        lgs = tuple(g_leaves[i].astype(jnp.float32) for i in plan.inplace)
+        # In-place leaves enter as they are: the gradient at the width
+        # it arrives (a widening pass here is opaque to the Pallas call
+        # and would be materialized: 2 B read + 4 B written an element,
+        # then 4 B read again), the parameter and both moments.
+        lgs = tuple(g_leaves[i] for i in plan.inplace)
         lps = tuple(p_leaves[i] for i in plan.inplace)
         specs = _inplace_specs(p_leaves, treedef, plan) if use_shard_map \
             else [(None, False)] * len(plan.inplace)

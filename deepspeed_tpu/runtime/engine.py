@@ -966,6 +966,30 @@ class DeepSpeedEngine:
                 and all(int(s) == 1 for a, s in self.mesh.shape.items()
                         if a != DP_AXIS))
 
+    def _grads_stay_narrow(self) -> bool:
+        """True when the jitted train step hands the one-pass fused
+        apply the gradients at the width the backward wrote them (the
+        compute dtype) instead of widening them to f32 first: one
+        device, one micro-batch, no loss scale. Every path that SUMS
+        gradients (dp > 1, the accumulation scan, 1F1B, the trio's
+        accumulator) sums in f32 and hands that on. The ONE predicate
+        the step builder and the optimizer_apply pricing share."""
+        return (self._fused_step is not None
+                and not self.config.fp16_enabled
+                and self.mesh.devices.size == 1
+                and self._direct_grads_fn is None
+                and self._scan_microbatches() == 1)
+
+    def _apply_grad_dtype(self):
+        """The width at which gradients reach the fused apply: the
+        backward's own (the compute dtype, where the loss differentiates
+        compute-dtype parameters) on the jitted step's narrow path, f32
+        from every path that sums them."""
+        if self._train_step_fn is not None and self._grads_stay_narrow() \
+                and (self._use_cast_cache or self._master_free):
+            return self.compute_dtype
+        return jnp.float32
+
     def _loss_scaler_config(self) -> Dict[str, Any]:
         cfg = self.config
         if cfg.fp16_enabled:
@@ -2688,6 +2712,7 @@ class DeepSpeedEngine:
         tx = self.tx
         fused_apply = self._fused_apply
         fused_step = self._fused_step
+        narrow_grads = self._grads_stay_narrow()
         scaler_kw = self._scaler_kw
         if float(self.config.gradient_predivide_factor or 1.0) != 1.0:
             # Subsumed by design: grads are accumulated in fp32 as the mean
@@ -2785,16 +2810,23 @@ class DeepSpeedEngine:
                     state.dcn_error)
             elif gas == 1:
                 # Fast path: no accumulation scan — saves a full zero-init +
-                # add pass over the fp32 grad tree every step. Master-free
-                # included: grads are promoted to f32 here so the optax
-                # fallback's second moment is (f32 g)^2, never a bf16
-                # square (the fused kernel promotes on read by
-                # construction); XLA folds the widening cast into the
-                # consumer, so no extra materialized pass.
+                # add pass over the fp32 grad tree every step. The optax
+                # fallback gets the grads promoted to f32 here, so its
+                # second moment is (f32 g)^2, never a bf16 square; XLA
+                # folds that cast into its consumer. A Pallas call is
+                # opaque to XLA's fusion — ahead of one the cast is a
+                # materialized pass (7.4 ms a step at gpt2-large,
+                # PERF.md PR 49) — so the one-pass fused apply takes the
+                # grads at the width the backward wrote them: its kernel
+                # and its norm widen on read, which is exact. (fp16's
+                # loss-scaled grads stay wide: the v5e's Mosaic refuses
+                # an f16 load, "Invalid vector type for load".)
                 mb = jax.tree_util.tree_map(lambda x: x[0], micro_batches)
                 (_, (raw_loss, aux)), grads = grad_fn(
                     loss_params, mb, keys[0], scale, theta)
-                grads = constrain_grads(_cast_floats(grads, jnp.float32))
+                if not narrow_grads:
+                    grads = _cast_floats(grads, jnp.float32)
+                grads = constrain_grads(grads)
                 mean_loss = raw_loss.astype(jnp.float32)
             else:
                 def accum(carry, xs):
@@ -3264,7 +3296,8 @@ class DeepSpeedEngine:
             cast_dtype=(self.compute_dtype if self._use_cast_cache
                         else None),
             fp16=self.config.fp16_enabled,
-            clip=bool(self.gradient_clipping()))
+            clip=bool(self.gradient_clipping()),
+            grad_dtype=self._apply_grad_dtype())
         # Per-device bytes divide by dp only where the kernels actually
         # run shard-local — the same predicate that handed the mesh to
         # fused_adam (a live mp/pp axis keeps the plain lowering on

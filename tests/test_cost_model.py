@@ -669,6 +669,26 @@ class TestOptimizerApplyPricing:
         assert cast["one_pass"] - base["one_pass"] == 2 * n
         assert cast["two_pass"] - base["two_pass"] == (2 + 4) * n
 
+    @pytest.mark.parametrize("grad_dtype,g_width", [
+        (None, 2), (jnp.bfloat16, 2), (jnp.float32, 4)],
+        ids=["as_the_parameter", "bf16", "f32"])
+    def test_gradient_priced_at_the_width_it_arrives(self, grad_dtype,
+                                                     g_width):
+        """An in-place leaf's gradient is read at the width it reaches
+        the kernel (default: the parameter's own, what a backward through
+        it writes) — once by the kernel, once more by the norm; a packed
+        leaf's at 4 B whatever arrives (the group buffer flattens in
+        f32)."""
+        from deepspeed_tpu.ops.fused_update import apply_hbm_bytes
+        params = {"w": jnp.zeros((1024, 512), jnp.bfloat16),    # in place
+                  "b": jnp.zeros((1000,), jnp.bfloat16)}        # packed
+        n_w, n_b = 1024 * 512, 1000
+        got = apply_hbm_bytes(params, clip=True, grad_dtype=grad_dtype)
+        assert got["one_pass"] == \
+            n_w * (2 * g_width + 2 + 2 + 16) + n_b * (2 * 4 + 2 + 2 + 16)
+        off = apply_hbm_bytes(params, clip=False, grad_dtype=grad_dtype)
+        assert got["one_pass"] - off["one_pass"] == n_w * g_width + n_b * 4
+
     def test_engine_payload_carries_one_pass_mode(self, tmp_path):
         """The dp=8 ZeRO-2 fused engine's cost model payload reports the
         apply path at one-pass pricing with the ~2x alternative ratio —

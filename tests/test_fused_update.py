@@ -324,6 +324,58 @@ class TestOnePassStep:
                                           np.asarray(p_ref[k]))
 
 
+@pytest.mark.parametrize("sr", [False, True], ids=["round", "sr"])
+@pytest.mark.parametrize("clip", [0.0, 0.05], ids=["noclip", "clip"])
+def test_narrow_grads_equal_their_widening(layout, clip, sr):
+    """Gradients at the width a bf16 backward writes them give the update
+    the same values widened to f32 give: widening is exact, so WHERE it
+    happens (the kernel's first line and the norm's reduction for an
+    in-place leaf, the flatten for a packed one) changes no value. With
+    clip off every bit of p, m and v agrees; with clip on the one
+    permitted difference is the association of the f32 sum of squares, a
+    few ulp of the norm. A second step reads non-zero moments: there the
+    two COMPILED programs may differ by the CPU compiler's FMA
+    contraction of ``(1-b)*g + b*m`` (this file's header; one rounding
+    of a term, seen on in-place leaves), which no operand width causes
+    on the chip — Mosaic's v5e has no fused multiply-add, and
+    PERF.md's PR 49 entry holds the chip's bits over three steps."""
+    params = _tree(21, dtype=jnp.bfloat16)
+    fus = fused_adam(_sched, B1, B2, EPS, WD)
+    step = jax.jit(lambda g, s, p, k: fus.fused_step(
+        g, s, p, clip=clip, compute_norm=clip > 0, sr_key=k))
+
+    def run(width):
+        p, st, outs = params, fus.init(params), []
+        for i in range(2):
+            g = jax.tree_util.tree_map(lambda x: x.astype(width),
+                                       _grads(i, params))
+            out = step(g, st, p, jax.random.PRNGKey(5 + i) if sr else None)
+            p, st = out.params, out.state
+            outs.append((out,) + leaf_moment_views(st, params))
+        return outs
+
+    for i, ((a, ma, va), (b, mb, vb)) in enumerate(
+            zip(run(jnp.bfloat16), run(jnp.float32))):
+        if clip:
+            assert float(a.grad_norm) > clip    # the clip really engaged
+            np.testing.assert_allclose(float(a.grad_norm),
+                                       float(b.grad_norm), rtol=1e-6)
+        for k in params:
+            assert a.params[k].dtype == jnp.bfloat16
+            got = [np.asarray(x[k], np.float32) for x in (a.params, ma, va)]
+            want = [np.asarray(x[k], np.float32)
+                    for x in (b.params, mb, vb)]
+            for x, y, rtol in zip(got, want, (2.0 ** -7, 1e-5, 1e-5)):
+                if clip == 0 and (i == 0 or x is got[0]):
+                    np.testing.assert_array_equal(x, y)
+                else:
+                    # one rounding of a TERM of the moment's sum (clip:
+                    # a few ulp of the coefficient; p: one bf16 ulp)
+                    np.testing.assert_allclose(
+                        x, y, rtol=rtol if clip else 1e-6,
+                        atol=(1e-5 if clip else 1e-6) * np.abs(y).max())
+
+
 # ------------------------------------------------------------------ #
 # Engine tier — 8-device CPU mesh, ZeRO-2
 # ------------------------------------------------------------------ #
@@ -467,3 +519,83 @@ def test_engine_fused_checkpoint_roundtrip(tmp_path):
     l1 = float(jax.device_get(eng.train_batch(make_batch(100))))
     l2 = float(jax.device_get(eng2.train_batch(make_batch(100))))
     assert abs(l1 - l2) < 1e-6, (l1, l2)
+
+
+# ------------------------------------------------------------------ #
+# The one-device step: gradients reach the kernel as the backward wrote them
+# ------------------------------------------------------------------ #
+_WIDE, _DEEP = 1024, 512          # 2**19 elements: the plan's in-place floor
+
+
+def _mlp_loss(params, batch, rng):
+    h = jnp.tanh(batch["x"].astype(params["w1"].dtype) @ params["w1"]
+                 + params["b1"])
+    return jnp.mean(jnp.square((h @ params["w2"]).astype(jnp.float32)
+                               - batch["y"]))
+
+
+def _walk(jaxpr, stack=""):
+    """(eqn, scope path) over a jaxpr and every jaxpr under it; an inner
+    jaxpr's name stack is relative to the equation that holds it."""
+    from deepspeed_tpu.analysis.passes import _subjaxprs
+    for eqn in jaxpr.eqns:
+        here = f"{stack}/{eqn.source_info.name_stack}"
+        yield eqn, here
+        for inner in _subjaxprs(eqn):
+            yield from _walk(inner, here)
+
+
+@pytest.mark.parametrize("gas,narrow", [(1, True), (2, False)],
+                         ids=["one_micro_batch", "accumulation_scan"])
+def test_one_device_step_hands_the_kernel_bf16_grads(gas, narrow):
+    """The master-free one-device step, lowered: with one micro-batch
+    every in-place ``_fused_adam_kernel`` call reads a bf16 gradient and
+    no bf16 -> f32 widening of a parameter-shaped array is left outside
+    the ``optimizer`` scope (a Pallas call is opaque to XLA's fusion: a
+    widening ahead of it is a materialized pass); the accumulation scan
+    sums in f32 and hands the kernel what it did before. The pricing
+    follows the operand."""
+    r = np.random.default_rng(0)
+    params = {
+        "w1": jnp.asarray(r.standard_normal((_DEEP, _WIDE)) * 0.02),
+        "b1": jnp.zeros((_WIDE,)),
+        "w2": jnp.asarray(r.standard_normal((_WIDE, _DEEP)) * 0.02),
+    }
+    cfg = _cfg(True, gas=gas, train_micro_batch_size_per_gpu=8 // gas,
+               train_batch_size=8,
+               bf16={"enabled": True, "stochastic_rounding": True},
+               zero_optimization={"stage": 0})
+    eng = DeepSpeedEngine(model=_mlp_loss, model_params=params, config=cfg,
+                          mesh=build_mesh(devices=jax.devices()[:1]))
+    assert eng._grads_stay_narrow() == narrow
+    batch = {"x": jnp.zeros((8, _DEEP)), "y": jnp.zeros((8, _DEEP))}
+    eng._prepare_batch(batch, None)      # builds the step, runs nothing
+    traced = eng._build_train_step().trace(
+        eng.state, batch, jax.random.PRNGKey(0))
+    big = {tuple(params[k].shape) for k in ("w1", "w2")}
+    leaf_calls, kernel_g, stray = [], [], []
+    for eqn, scope in _walk(traced.jaxpr.jaxpr):
+        name = eqn.primitive.name
+        if name in ("pjit", "jit") and eqn.params["name"] == "_update_leaf":
+            leaf_calls.append(eqn.invars[0].aval.dtype)
+            kernel_g += [e.invars[2].aval.dtype
+                         for e, _ in _walk(eqn.params["jaxpr"].jaxpr)
+                         if e.primitive.name == "pallas_call"]
+        if name == "convert_element_type" and \
+                tuple(eqn.invars[0].aval.shape) in big and \
+                eqn.invars[0].aval.dtype == jnp.bfloat16 and \
+                eqn.outvars[0].aval.dtype == jnp.float32 and \
+                "optimizer" not in scope:
+            stray.append(scope)
+    want = jnp.bfloat16 if narrow else jnp.float32
+    assert leaf_calls == [want, want], leaf_calls
+    assert kernel_g == [want, want], kernel_g
+    if narrow:
+        assert not stray, stray
+    priced = eng._optimizer_apply_pricing()["per_replica"]["one_pass"]
+    n_big, n_small = 2 * _DEEP * _WIDE, _WIDE
+    g_width = 2 if narrow else 4
+    # kernel: g + p read, p written, m and v read and written; then the
+    # norm's second read of g (clip is on); packed leaves flatten in f32
+    assert priced == n_big * (2 * g_width + 2 + 2 + 16) \
+        + n_small * (2 * 4 + 2 + 2 + 16)

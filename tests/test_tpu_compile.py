@@ -172,11 +172,13 @@ def _case_apply(_):
                 _sds((1, 8), jnp.float32), _sds((1, 2), jnp.int32))
 
 
-def _case_update_leaf(shape):
+def _case_update_leaf(shape, gdt=jnp.float32):
     """The same kernel over ONE leaf where it lies (the plan's in-place
     leaves): gpt2-large's matrices through their collapsed 2-D view,
     fp32 masters with the bf16 cast output, and a dp=8 shard of wte whose
-    rows leave a ragged last block."""
+    rows leave a ragged last block. ``gdt``: the gradient operand's
+    width — f32 from every path that sums gradients, bf16 as the
+    one-device backward writes it (the train cells' program)."""
     from deepspeed_tpu.ops.fused_update import _update_leaf
     sr = shape != (36, 1280, 1280)
     pdt = jnp.bfloat16 if sr else jnp.float32
@@ -186,7 +188,7 @@ def _case_update_leaf(shape):
         out_dtype=jnp.dtype(pdt),
         cast_dtype=None if sr else jnp.dtype(jnp.bfloat16))
     f32 = _sds(shape, jnp.float32)
-    return fn, (f32, _sds(shape, pdt), f32, f32,
+    return fn, (_sds(shape, gdt), _sds(shape, pdt), f32, f32,
                 _sds((1, 8), jnp.float32), _sds((1, 2), jnp.int32))
 
 
@@ -262,6 +264,8 @@ CASES = {
     "fused_update_leaf_proj_f32_cast": (_case_update_leaf, (36, 1280, 1280)),
     "fused_update_leaf_fc": (_case_update_leaf, (36, 1280, 5120)),
     "fused_update_leaf_fc2": (_case_update_leaf, (36, 5120, 1280)),
+    "fused_update_leaf_medium_qkv": (_case_update_leaf, (24, 1024, 3072)),
+    "fused_update_leaf_medium_fc2": (_case_update_leaf, (24, 4096, 1024)),
     "paged_attention_decode_k1": (_case_paged, 1),
     "paged_attention_verify_k5": (_case_paged, 5),
     "paged_attention_prefill_k32": (_case_paged, 32),
@@ -269,6 +273,14 @@ CASES = {
     "grouped_gemm_ffn_bwd": (_case_grouped, True),
     "sparse_flash_fwd_bwd": (_case_sparse, None),
 }
+
+
+# The train cells' program: every whole leaf again with the gradient
+# operand as the one-device backward writes it (a dp shard's is f32).
+CASES.update({
+    name + "_bf16_grad": (functools.partial(build, gdt=jnp.bfloat16), arg)
+    for name, (build, arg) in list(CASES.items())
+    if build is _case_update_leaf and "shard" not in name})
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
@@ -398,11 +410,15 @@ def test_paged_attention_at_the_mixed_cells_shapes(K, Q, tiles, cls, blocks,
 _BIG = 1 << 20          # elements: every in-place gpt2 leaf is larger
 
 
-@pytest.fixture(scope="module")
-def optimizer_step(topo):
+@pytest.fixture(scope="module", params=[jnp.float32, jnp.bfloat16],
+                ids=["f32_grads", "bf16_grads"])
+def optimizer_step(request, topo):
     """(plan summary, lowered text, compiled) of fused_step over the
-    scanned gpt2-large tree: bf16 params with stochastic rounding, f32
-    grads, clip 1.0 — cell 1's optimizer — params and state donated."""
+    scanned gpt2-large tree: bf16 params with stochastic rounding, clip
+    1.0, params and state donated; the gradients f32 (what dp > 1 and
+    the accumulation scan hand it) and bf16 (cell 1's optimizer: the
+    one-device backward's own width — a widening pass ahead of the
+    kernels would show as 3 GB of scratch)."""
     from deepspeed_tpu.models import GPT2_CONFIGS, gpt2_init
     from deepspeed_tpu.ops import autotune, fused_update
     one = SingleDeviceSharding(topo.devices[0])
@@ -436,7 +452,7 @@ def optimizer_step(topo):
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         lowered = jax.jit(step, donate_argnums=(1, 2)).lower(
-            tree(jnp.float32), state, tree(jnp.bfloat16),
+            tree(request.param), state, tree(jnp.bfloat16),
             jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one))
         compiled = lowered.compile()
     finally:
