@@ -12,6 +12,9 @@ a goodput ledger attributing every wall-clock second between report
 boundaries, and the measured half of the roofline story: jax.profiler
 trace ingestion into a bucketed per-step wall decomposition
 (profile_ingest) reconciled against the analytic floors (reconcile).
+Always on, telemetry or not: the serving loop's and the training loop's
+timelines (serving, training), one row an iteration / a train_batch call,
+with the stalls they caught.
 See docs/tutorials/telemetry.md.
 """
 from .cost_model import (BOUND_COMPUTE, BOUND_HBM, BOUND_INTERCONNECT,
@@ -35,12 +38,14 @@ from .serving import ServingAggregator
 from .serving_slo import (SERVING_BUCKETS, ServingGoodputLedger, SLOTracker)
 from .telemetry import JsonlSink, Telemetry
 from .trace import ProfilerWindow, TraceWriter
+from .training import TrainingTimeline
 
 __all__ = [
     "Telemetry", "JsonlSink", "TraceWriter", "ProfilerWindow",
     "RecompileSentinel", "RecompileError", "MemoryWatermark",
     "analytic_state_bytes", "device_memory_stats",
     "GoodputLedger", "GOODPUT_BUCKETS", "ServingAggregator",
+    "TrainingTimeline",
     "ServingGoodputLedger", "SLOTracker", "SERVING_BUCKETS",
     "RequestTrace", "validate_timeline",
     "HealthMonitor", "EwmaDetector", "HangWatchdog", "TapSpec",

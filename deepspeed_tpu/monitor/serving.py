@@ -61,6 +61,9 @@ GAP_PARTS = {"emit_s": "emit", "other_s": "between_spans",
              "fetch_s": "decode_fetch", "advance_s": "decode_advance"}
 RING = 65536                 # rows kept: a 51 s window of the fastest
 #                              benchmark cell is ~6,000
+# The stall rule, one for the serving and the training timeline
+# (``stall_rows``): an interval over STALL_TIMES_MEDIAN medians and over
+# STALL_FLOOR_S; the STALLS_KEPT longest are kept.
 STALL_FLOOR_S = 0.25
 STALL_TIMES_MEDIAN = 10.0
 STALLS_KEPT = 8
@@ -94,6 +97,24 @@ def weighted_percentile(values: np.ndarray, weights: np.ndarray,
         return 0.0
     k = int(round(q / 100.0 * (cum[-1] - 1)))
     return float(values[order][np.searchsorted(cum, k, side="right")])
+
+
+def stall_limit(gap_s: np.ndarray) -> float:
+    """The seconds an interval must exceed to be a stall where the usual
+    ones are ``gap_s``: the larger of ``STALL_TIMES_MEDIAN`` x their
+    median and ``STALL_FLOOR_S``."""
+    return max(STALL_TIMES_MEDIAN * float(np.median(gap_s)), STALL_FLOOR_S) \
+        if len(gap_s) else STALL_FLOOR_S
+
+
+def stall_rows(gap_s: np.ndarray, usual=None) -> np.ndarray:
+    """Which of the intervals ``gap_s`` (seconds, in order of time) are
+    kept as stalls: those over ``stall_limit`` of the ``usual`` ones (a
+    mask; all of them by default), the ``STALLS_KEPT`` longest of them,
+    as indices in order of time."""
+    limit = stall_limit(gap_s if usual is None else gap_s[usual])
+    worst = np.flatnonzero(gap_s > limit)
+    return np.sort(worst[np.argsort(-gap_s[worst])][:STALLS_KEPT])
 
 
 class ServingAggregator:
@@ -465,13 +486,14 @@ class ServingAggregator:
 
     def stalls(self, table: Optional[np.ndarray] = None
                ) -> List[Dict[str, Any]]:
-        """The latest ``serve()``'s worst intervals: at most
-        ``STALLS_KEPT`` rows (the longest, in order of time) whose
-        interval, waited by at least one stream, exceeded the larger of
-        ``STALL_TIMES_MEDIAN`` x the median interval and
-        ``STALL_FLOOR_S``; each with its row, the seconds since the
-        serve began, and the part of the interval (``GAP_PARTS``) that
-        held most of the excess over that part's median."""
+        """The latest ``serve()``'s worst intervals: the rows
+        ``stall_rows`` keeps (at most ``STALLS_KEPT``, the longest, in
+        order of time) of those whose interval, waited by at least one
+        stream, exceeded the larger of ``STALL_TIMES_MEDIAN`` x the
+        median interval and ``STALL_FLOOR_S``; each with its row, the
+        seconds since the serve began, and the part of the interval
+        (``GAP_PARTS``) that held most of the excess over that part's
+        median."""
         t = self._table() if table is None else table
         index = np.arange(self._n - len(t), self._n)
         keep = (index >= self._serve_row0) & (t[:, COL["continuing"]] > 0)
@@ -479,10 +501,6 @@ class ServingAggregator:
         if not len(t):
             return []
         gap = t[:, COL["gap_s"]]
-        limit = max(STALL_TIMES_MEDIAN * float(np.median(gap)),
-                    STALL_FLOOR_S)
-        worst = np.flatnonzero(gap > limit)
-        worst = np.sort(worst[np.argsort(-gap[worst])][:STALLS_KEPT])
         parts = [COL[c] for c in GAP_PARTS]
         excess = t[:, parts] - np.median(t[:, parts], axis=0)
         names = list(GAP_PARTS.values())
@@ -492,7 +510,7 @@ class ServingAggregator:
                  "gap_ms": round(float(gap[i]) * 1e3, 3),
                  "in": names[int(np.argmax(excess[i]))],
                  "in_ms": round(float(excess[i].max()) * 1e3, 3)}
-                for i in worst]
+                for i in stall_rows(gap)]
 
     def snapshot(self, wall_s: Optional[float] = None) -> Dict[str, Any]:
         """The canonical serving summary. ``tokens_per_s`` counts
@@ -697,4 +715,5 @@ class ServingAggregator:
         return out
 
 
-__all__ = ["ServingAggregator", "percentile", "COLUMNS", "GAP_PARTS"]
+__all__ = ["ServingAggregator", "percentile", "COLUMNS", "GAP_PARTS",
+           "stall_limit", "stall_rows"]

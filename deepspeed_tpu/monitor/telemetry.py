@@ -212,6 +212,11 @@ class Telemetry:
         self._mfu_arm: Optional[Dict[str, Any]] = None
         self._compile_wall_seen = 0.0
         self._ckpt_depth = 0
+        # Seconds the thread has stood in ``checkpoint_*`` spans so far
+        # (outermost only), telemetry on or off: the same clock reads
+        # feed the goodput ledger's bucket and the training timeline's
+        # ``save_s`` (monitor/training.py).
+        self.checkpoint_exposed_s = 0.0
         self.dropped_records = 0
         self.events: List[Dict[str, Any]] = []
         self._closed = False
@@ -413,13 +418,14 @@ class Telemetry:
         step view). ``args`` ride on the annotation: keep them cheap
         scalars, and strings free of ``,`` ``=`` ``#`` (the annotation's
         own encoding); ``with ... as sp: sp.set_metadata(**late)`` adds
-        what is known only at the span's end. With nothing else
-        configured — telemetry off, or no ``trace_path`` and no ledger
-        bucket — the bare annotation is all that is allocated.
+        what is known only at the span's end. With no ``trace_path``,
+        the bare annotation is all that is allocated for any span but a
+        ``checkpoint_*`` one.
 
         It additionally feeds the Chrome-trace writer (when a trace_path
-        is set) and, for ``checkpoint_*`` spans, the goodput ledger's
-        checkpoint bucket — outermost span only, so the pipeline
+        is set) and, for ``checkpoint_*`` spans, ``checkpoint_exposed_s``
+        and the goodput ledger's checkpoint bucket — outermost span
+        only, so the pipeline
         engine's nested per-layer spans don't double-count. The async
         save path's ``checkpoint_snapshot`` span additionally files its
         wall under the ledger's ``checkpoint_snapshot`` sub-figure — the
@@ -427,7 +433,7 @@ class Telemetry:
         ann = TraceAnnotation(name, **args) if step_num is None \
             else StepTraceAnnotation(name, step_num=step_num, **args)
         bucket = "checkpoint" if name.startswith("checkpoint_") else None
-        if self.tracer is None and (bucket is None or self.ledger is None):
+        if self.tracer is None and bucket is None:
             return ann
         sub = "checkpoint_snapshot" if name == "checkpoint_snapshot" \
             else None
@@ -439,7 +445,7 @@ class Telemetry:
     def _span_ctx(self, ann, name: str, bucket: Optional[str],
                   args: Dict[str, Any], sub: Optional[str] = None):
         outermost = False
-        if bucket is not None and self.ledger is not None:
+        if bucket is not None:
             outermost = self._ckpt_depth == 0
             self._ckpt_depth += 1
         t0 = time.perf_counter()
@@ -450,10 +456,12 @@ class Telemetry:
             dur = time.perf_counter() - t0
             if self.tracer is not None:
                 self.tracer.add_span(name, t0, dur, args=args or None)
-            if bucket is not None and self.ledger is not None:
+            if bucket is not None:
                 self._ckpt_depth -= 1
                 if outermost:
-                    self.ledger.note(bucket, dur, sub=sub)
+                    self.checkpoint_exposed_s += dur
+                    if self.ledger is not None:
+                        self.ledger.note(bucket, dur, sub=sub)
 
     def note_checkpoint_write_bg(self, seconds: float) -> None:
         """Background checkpoint-writer wall (called from the writer

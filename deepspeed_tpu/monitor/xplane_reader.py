@@ -76,7 +76,15 @@ SPANS = ("train_batch", "data_prep", "step_dispatch", "offload_step",
          "decode_fetch", "decode_advance", "emit", "serve_idle")
 # The args those spans carry (the ones with none are left out).
 SPAN_ARGS = {
-    "train_batch": ("step_num",), "data_prep": ("step",),
+    # The call's row of the training timeline (monitor/training.py): the
+    # interval since the entry before and the part of it since that call
+    # returned; this call's three child spans and their sum, host_ms;
+    # earlier steps not yet seen complete at the dispatch, steps first
+    # seen complete since the entry before, step programs the call built.
+    "train_batch": ("step_num", "row", "gap_ms", "outside_ms", "host_ms",
+                    "data_ms", "dispatch_ms", "log_ms", "in_flight",
+                    "completed", "built"),
+    "data_prep": ("step",),
     "step_dispatch": ("step",), "step_log": ("step",),
     "admit": ("queued", "late_ms", "admitted", "rejected", "rids"),
     # moe_*: the served model's counters where it has an expert layer

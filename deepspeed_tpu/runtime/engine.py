@@ -57,6 +57,8 @@ from .zero.partition import zero_shardings
 from .. import constants as C
 from ..monitor import Telemetry
 from ..monitor.memory import analytic_state_bytes
+from ..monitor.telemetry import spans_recorded
+from ..monitor.training import TrainingTimeline
 from ..ops.optimizers import build_optimizer
 from ..parallel import comm
 from ..parallel.topology import (build_mesh, DP_AXIS, EP_AXIS, MP_AXIS,
@@ -695,6 +697,9 @@ class DeepSpeedEngine:
                     capacity_factor=self._moe.capacity_factor,
                     expert_parallel_size=self.ep_size)}
                    if self._moe is not None else {})))
+        # The training timeline: one row a train_batch call, always on
+        # (host clock reads only; monitor/training.py).
+        self.timeline = TrainingTimeline()
         # Weakref, not a bound closure: the Telemetry outlives engines via
         # its atexit flush hook, and a strong closure here would pin the
         # engine's entire device state for process lifetime.
@@ -3027,18 +3032,34 @@ class DeepSpeedEngine:
         Host spans (``Telemetry.span`` — profiler annotations, a flag
         test outside a profiler session): ``train_batch`` > ``data_prep``,
         ``step_dispatch`` (``offload_step`` when offloading), ``step_log``.
+
+        Every call is one row of ``self.timeline`` (monitor/training.py),
+        telemetry on or off: four clock reads at the spans' boundaries
+        and no device sync. While something records spans, ``train_batch``
+        carries the row as args: ``row``, ``gap_ms`` (entry to entry),
+        ``outside_ms`` (since the call before returned), ``host_ms`` =
+        ``data_ms`` + ``dispatch_ms`` + ``log_ms`` (the three child
+        spans), ``in_flight`` (earlier steps not yet seen complete at the
+        dispatch), ``completed`` (steps first seen complete since the
+        entry before) and ``built`` (step programs this call built or
+        compiled: 1 on the first call; later, a recompile).
         """
         tl = self.telemetry
-        t_wall0 = time.perf_counter()
+        tm = self.timeline
         step = self.global_steps
+        tm.enter(step)
+        saved_s = tl.checkpoint_exposed_s
         tl.profiler_tick(step)
         first_build = self._train_step_fn is None
-        with tl.span("train_batch", step_num=step):
+        with tl.span("train_batch", step_num=step) as span:
             with tl.span("data_prep", step=step):
                 micro_batches = self._prepare_batch(batch, data_iter)
+            tm.lap("data_s")
+            programs = self._step_programs()
             with tl.span("offload_step" if self._offload is not None
                          else "step_dispatch", step=step):
                 metrics = self._dispatch_step(micro_batches)
+            tm.dispatched(metrics["loss"], self._step_programs() - programs)
             if first_build and self._train_step_fn is not None:
                 from ..ops.flash_attention import lowered
                 logger.info(
@@ -3046,10 +3067,29 @@ class DeepSpeedEngine:
                     f"{lowered['in_place']}, relayout {lowered['relayout']} "
                     "call(s) so far in this process")
             with tl.span("step_log", step=step):
-                self._record_telemetry(metrics, t_wall0)
+                self._record_telemetry(metrics, tm.wall_s)
                 self._maybe_log(metrics)
                 self._maybe_auto_save()
+            tm.leave(tl.checkpoint_exposed_s - saved_s)
+            if spans_recorded(tl):
+                span.set_metadata(**tm.span_args())
         return metrics["loss"]
+
+    def _step_programs(self) -> int:
+        """Executables ``train_batch``'s step functions hold so far (the
+        jit caches' sizes, the test ``RecompileSentinel`` uses): its
+        growth over a call is the programs the call built or compiled."""
+        n = 0
+        for name in ("_train_step_fn", "_offload_grad_fn",
+                     "_sparse_grad_fn", "_sparse_apply_fn"):
+            fn = getattr(self, name, None)
+            # The sentinel's wrapper keeps the jitted function on
+            # ``__wrapped__`` (a jitted function's own is the Python one).
+            size = getattr(fn, "_cache_size", None) or getattr(
+                getattr(fn, "__wrapped__", None), "_cache_size", None)
+            if callable(size):
+                n += size()
+        return n
 
     def _prepare_batch(self, batch, data_iter):
         """train_batch's ``data_prep``: the iterator pull, the micro-batch
@@ -3150,12 +3190,15 @@ class DeepSpeedEngine:
     # Alias matching common JAX naming.
     train_step = train_batch
 
-    def _record_telemetry(self, metrics, t0: float) -> None:
+    def _record_telemetry(self, metrics, wall_s: float) -> None:
         """Buffer this step's telemetry record — append-only, no device
         access (the metrics dict's jax scalars ride as futures and sync
-        at the next report-boundary drain). ``wall_ms`` is host wall from
-        train_batch entry; on the jitted paths that is DISPATCH wall
-        (steps pipeline asynchronously — the fenced truth is the
+        at the next report-boundary drain). ``wall_s`` becomes the
+        record's ``wall_ms`` and the watchdog's beat: from train_batch it
+        is the timeline row's ``data_s`` + ``dispatch_s`` (entry to the
+        step's dispatch: the same clock reads, ``self.timeline`` holds
+        the call's other figures); on the jitted paths that is DISPATCH
+        wall (steps pipeline asynchronously — the fenced truth is the
         throughput timer's window average in the report record), on the
         host-synchronous offload path it is true step wall."""
         tl = self.telemetry
@@ -3165,9 +3208,8 @@ class DeepSpeedEngine:
         # step's returned state was stored, so a caught RecompileError
         # leaves the engine usable (e.g. to checkpoint before dying).
         tl.raise_pending()
-        t_now = time.perf_counter()
         host: Dict[str, Any] = {
-            "wall_ms": (t_now - t0) * 1e3,
+            "wall_ms": wall_s * 1e3,
             "wire_bytes": self._wire_bytes,
             "samples": self.train_batch_size(),
         }
@@ -3691,7 +3733,7 @@ class DeepSpeedEngine:
         self._accum_grads = None
         self.global_steps += 1
         self.global_samples += self.train_batch_size()
-        self._record_telemetry(metrics, t0)
+        self._record_telemetry(metrics, time.perf_counter() - t0)
         self._maybe_log(metrics)
         self._maybe_auto_save()
 

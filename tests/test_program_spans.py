@@ -239,6 +239,10 @@ DISPATCHED = {"live_blocks", "context_tokens", "attend_steps",
               "attend_live_steps", "attend_cold_steps"}
 TRAIN_SPANS = {"train_batch": {"step_num"}, "data_prep": {"step"},
                "step_dispatch": {"step"}, "step_log": {"step"}}
+# The call's row of the training timeline (monitor/training.py), which
+# ``train_batch`` carries while something records spans.
+TRAIN_ROW_ARGS = ("row", "gap_ms", "outside_ms", "host_ms", "data_ms",
+                  "dispatch_ms", "log_ms", "in_flight", "completed", "built")
 
 
 def _dispatched(found):
@@ -414,6 +418,7 @@ def test_the_readers_list_names_the_same_args():
     assert set(SPAN_ARGS) <= set(SPANS)
     for span, args in {**SERVE_SPANS, **TRAIN_SPANS}.items():
         assert args <= set(SPAN_ARGS.get(span, ())), span
+    assert set(TRAIN_ROW_ARGS) <= set(SPAN_ARGS["train_batch"])
     assert DISPATCHED <= set(SPAN_ARGS["decode"])
 
 
@@ -485,6 +490,47 @@ def test_train_span_is_in_the_profile_once_per_step(train_annotations,
         outer = found["train_batch"]
         assert all(any(o[0] <= e[0] and e[0] + e[1] <= o[0] + o[1]
                        for o in outer) for e in found[span])
+
+
+@pytest.mark.parametrize("arg", TRAIN_ROW_ARGS)
+def test_train_batch_carries_the_timelines_row(train_annotations,
+                                               train_engine, arg):
+    from deepspeed_tpu.monitor.training import COL
+    from deepspeed_tpu.monitor.xplane_reader import SPAN_ARGS
+    found, _ = train_annotations
+    spans = [a for _, _, a in found["train_batch"]]
+    assert len(spans) == 2 and all(arg in a for a in spans), spans
+    assert arg in SPAN_ARGS["train_batch"]
+    table = train_engine.timeline.table()
+    for a in spans:
+        assert isinstance(a[arg], (int, float))
+        r = table[a["row"]]
+        assert r[COL["step"]] == a["step_num"]
+        if arg.endswith("_ms") and arg != "host_ms":
+            assert a[arg] == pytest.approx(r[COL[arg[:-2] + "s"]] * 1e3,
+                                           abs=1e-4)
+        elif arg != "host_ms" and arg != "row":
+            assert a[arg] == r[COL[arg]]
+    if arg == "row":
+        assert spans[1]["row"] == spans[0]["row"] + 1
+    if arg == "host_ms":
+        assert all(a["host_ms"] == pytest.approx(
+            a["data_ms"] + a["dispatch_ms"] + a["log_ms"], abs=1e-3)
+            for a in spans)
+    if arg == "built":
+        assert [a["built"] for a in spans] == [0, 0]     # compiled before
+
+
+def test_the_child_spans_lie_inside_the_rows_clock_reads(train_annotations):
+    """The row's three parts come from the clock reads that bracket the
+    spans: each span's own duration is within the part it is read as."""
+    found, _ = train_annotations
+    for i, (_, _, a) in enumerate(found["train_batch"]):
+        for span, part in (("data_prep", "data_ms"),
+                           ("step_dispatch", "dispatch_ms"),
+                           ("step_log", "log_ms")):
+            assert found[span][i][1] / 1e6 <= a[part] + 1e-3, (span, a)
+        assert a["host_ms"] <= found["train_batch"][i][1] / 1e6 + 1e-3
 
 
 # --------------------------------------------------------------------- #
