@@ -279,8 +279,9 @@ def bench_telemetry_overhead(n_steps: int = 40):
 
 def bench_kernels_ablation(n_steps: int = None):
     """DS_BENCH_KERNELS=1: the ISSUE-8 ablation grid — fused vs unfused
-    elementwise kernels x one-pass vs two-pass optimizer update — on the
-    bench model (gpt2-large, on the TPU).
+    elementwise kernels (LayerNorm's: the FFN's bias + GELU is one
+    expression on both sides) x one-pass vs two-pass optimizer update — on
+    the bench model (gpt2-large, on the TPU).
 
     ``fused_speedup`` (unfused-elementwise two-pass step over fully-fused
     step) is the figure tools/bench_gate.py gates across rounds.

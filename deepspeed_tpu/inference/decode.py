@@ -60,11 +60,11 @@ def _check_cfg(cfg: GPT2Config) -> None:
 @jax.named_scope("mlp")
 def _ffn(p: Dict[str, jax.Array], x: jax.Array, cfg: GPT2Config
          ) -> jax.Array:
-    # layer_norm_fn / gelu_dense_fn resolve to the fused Pallas kernels
-    # when cfg enables them — the SAME static dispatch the training
-    # block uses, so flipping the knob never adds a compiled-signature
-    # variant to the serving paths (sentinel-asserted in
-    # tests/test_fused_ln.py).
+    # layer_norm_fn resolves to the fused Pallas kernel when cfg enables
+    # it — the SAME static dispatch the training block uses, so flipping
+    # the knob never adds a compiled-signature variant to the serving
+    # paths (sentinel-asserted in tests/test_fused_ln.py); gelu_dense_fn
+    # is the training block's one function.
     h = layer_norm_fn(cfg)(x, p["ln2_scale"], p["ln2_bias"])
     h = gelu_dense_fn(cfg)(h, p["fc_kernel"], p["fc_bias"])
     h = dense(h, p["fc_out_kernel"], p["fc_out_bias"])

@@ -86,12 +86,13 @@ class TransformerConfig:
     # (ops/grouped_gemm) — cfg-static, resolved inside _moe_tokens.
     moe: Any = None
     moe_layer_freq: int = 1
-    # Fused elementwise Pallas kernels (ops/fused_elementwise): residual-
-    # add+LayerNorm and the bias+GELU FFN epilogue. "auto" = on when the
-    # backend is TPU (DS_FUSED_ELEMENTWISE=0/1 overrides); True/False
-    # force — True on CPU runs interpret-mode Pallas (how the dp=8
-    # tier-1 mesh tests them). Static per config: flipping it changes
-    # the program, not the compiled signature.
+    # Fused elementwise Pallas kernels (ops/fused_elementwise): LayerNorm
+    # and residual-add+LayerNorm, nothing else (the FFN's bias+GELU is
+    # one jnp expression for every value of this, PR 51). "auto" = on
+    # when the backend is TPU (DS_FUSED_ELEMENTWISE=0/1 overrides);
+    # True/False force — True on CPU runs interpret-mode Pallas (how the
+    # dp=8 tier-1 mesh tests them). Static per config: flipping it
+    # changes the program, not the compiled signature.
     fused_kernels: Any = "auto"
 
     @property
@@ -135,6 +136,7 @@ def gelu(x: jnp.ndarray) -> jnp.ndarray:
 # cfg-resolved fused-kernel dispatch (ops/fused_elementwise)
 # --------------------------------------------------------------------- #
 def use_fused_kernels(cfg: "TransformerConfig") -> bool:
+    """Whether LayerNorm / residual-LayerNorm run as Pallas kernels."""
     from ..ops.fused_elementwise import fused_elementwise_enabled
     return fused_elementwise_enabled(getattr(cfg, "fused_kernels", "auto"))
 
@@ -168,15 +170,15 @@ def residual_layer_norm_fn(cfg: "TransformerConfig") -> Callable:
 
 def gelu_dense_fn(cfg: "TransformerConfig") -> Callable:
     """``(h, kernel, bias) -> gelu(h @ kernel + bias)`` — the FFN
-    up-projection with its bias+GELU epilogue fused when enabled (the
-    matmul stays with XLA's MXU GEMM; the kernel fuses everything
-    after it into one elementwise pass)."""
-    if use_fused_kernels(cfg):
-        from ..ops.fused_elementwise import fused_bias_gelu
-        return lambda h, kernel, bias: fused_bias_gelu(
-            h @ kernel.astype(h.dtype), bias, cfg.gelu_exact)
-    return lambda h, kernel, bias: jax.nn.gelu(
-        dense(h, kernel, bias), approximate=not cfg.gelu_exact)
+    up-projection.  One function whatever ``cfg.fused_kernels`` says:
+    the GEMM's output in the compute dtype (what ``checkpoint_dots``
+    saves), then ``ops.fused_elementwise.bias_gelu``, fp32 arithmetic in
+    ``jax.numpy`` that XLA fuses into this GEMM's output and the next
+    GEMM's operand.  There is no shape at which a pass of its own over
+    ``[rows, F]`` beats no pass, so nothing chooses here."""
+    from ..ops.fused_elementwise import bias_gelu
+    return lambda h, kernel, bias: bias_gelu(
+        h @ kernel.astype(h.dtype), bias, cfg.gelu_exact)
 
 
 _INDEX_SPACE = 1 << 32      # flat indices are ``uint32``
@@ -370,10 +372,10 @@ def transformer_block(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
     r1 = r2 = r3 = None
     if rng is not None:
         r1, r2, r3 = jax.random.split(rng, 3)
-    # cfg-resolved elementwise ops: the fused Pallas kernels when the
-    # config enables them, the reference jnp chain otherwise (identical
-    # math — the fused residual+LN pass computes s = x + delta then
-    # LN(s) exactly like the two separate ops below would).
+    # cfg-resolved LayerNorms: the fused Pallas kernels when the config
+    # enables them, the reference jnp chain otherwise (identical math —
+    # the fused residual+LN pass computes s = x + delta then LN(s)
+    # exactly like the two separate ops below would).
     ln = layer_norm_fn(cfg)
     res_ln = residual_layer_norm_fn(cfg)
     gelu_up = gelu_dense_fn(cfg)

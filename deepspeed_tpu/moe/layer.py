@@ -50,6 +50,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ..ops.fused_elementwise import bias_gelu
 from ..ops.grouped_gemm import grouped_ffn, grouped_gemm_enabled
 from ..parallel import comm
 from ..parallel.topology import DP_AXIS, EP_AXIS
@@ -202,8 +203,10 @@ def _moe_tokens(params: Dict[str, jnp.ndarray], xt: jnp.ndarray,
         # [E/ep, ...] slices — no collective moves for the kernel.
         y = grouped_ffn(b, w1, b1, w2, b2, not gelu_approx)
     else:
-        h = jnp.einsum("ech,ehf->ecf", b, w1) + b1[:, None, :]
-        h = jax.nn.gelu(h, approximate=gelu_approx)
+        # The dense FFN's own function, so that one expert IS the dense
+        # layer bit for bit (tests/test_moe.py::TestDenseParity).
+        h = bias_gelu(jnp.einsum("ech,ehf->ecf", b, w1), b1[:, None, :],
+                      not gelu_approx)
         y = jnp.einsum("ecf,efh->ech", h, w2) + b2[:, None, :]
 
     if ep > 1:

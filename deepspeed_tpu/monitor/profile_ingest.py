@@ -71,6 +71,7 @@ BUCKETS: Tuple[str, ...] = BUCKET_PRIORITY + ("idle",)
 # Keys are the friendly family names that show up in reports.
 PALLAS_KERNEL_PATTERNS: Dict[str, str] = {
     "fused_ln": r"_ln_(fwd|bwd)_kernel|fused_layer_norm",
+    # deleted in PR 51; traces recorded before it still hold them
     "fused_gelu": r"_gelu_(fwd|bwd)_kernel|fused_gelu",
     "sparse_flash": (r"_sfwd_kernel|_sdq_kernel|_sdkv_kernel"
                      r"|_sfused_bwd_kernel|sparse_flash"),

@@ -1,15 +1,20 @@
-"""Fused elementwise Pallas kernels: residual-add+LayerNorm and the
-bias+GELU epilogue of the FFN up-projection.
+"""The elementwise part of a transformer block: residual-add+LayerNorm
+as Pallas row kernels, and the bias+GELU of the FFN up-projection as the
+fp32 expression XLA fuses into the GEMMs beside it.
 
 Why these exist (the non-GEMM part of the step): part of what a train
 step spends outside its GEMMs is elementwise passes XLA
 schedules as separate HBM round-trips — LayerNorm reads the residual
 stream, computes mean/var in fp32, and writes it back; the residual add
-that feeds it is another full read+write; GELU and its bias add are two
-more.  The reference attacked the same class of overhead with fused CUDA
-transformer kernels (``csrc/transformer/normalize_kernels.cu``,
-``gelu_kernels.cu``); the TPU-native answer is Pallas row kernels that
-make the one-pass property structural:
+that feeds it is another full read+write.  The reference attacked the
+same class of overhead with fused CUDA transformer kernels
+(``csrc/transformer/normalize_kernels.cu``, ``gelu_kernels.cu``).  A
+LayerNorm's output is materialised before a GEMM either way, so a row
+kernel that makes the one-pass property structural has something to
+win; a bias+GELU sits BETWEEN two GEMMs, where a kernel of its own is a
+read and a write of the ``[rows, F]`` tensor that fusion into a GEMM's
+operand or output does without (PR 51: the two GELU kernels were 20.7 /
+22.4 ms of the two train cells' steps, and are gone):
 
 - ``fused_layer_norm``: LN over the last axis, fp32 statistics, one read
   of x and one write of y (fwd) — plus a custom-vjp backward kernel that
@@ -20,35 +25,37 @@ make the one-pass property structural:
   pass, returning BOTH (the residual stream continues from ``s``).  The
   backward fuses the LN input-gradient with the pass-through residual
   cotangent, so the residual stream's gradient is also one pass.
-- ``fused_bias_gelu``: ``gelu(y + bias)`` (tanh approximation by
-  default, exact-erf behind a flag) with the analytic derivative in the
-  backward kernel — no saved activations beyond the matmul output that
-  already exists.
+- ``bias_gelu``: ``gelu(y + bias)`` (tanh approximation by default,
+  exact-erf behind a flag) in ``jax.numpy`` with the analytic derivative
+  in its custom vjp — no saved activations beyond the matmul output that
+  already exists.  Not a kernel and under no switch: every caller gets
+  it (``models.transformer.gelu_dense_fn``).
 
 Numerics contract (tests/test_fused_ln.py): all statistics and
 transcendentals evaluate in fp32 exactly like the jnp reference
 (``models.transformer.layer_norm`` / ``jax.nn.gelu``); fp32 tensors
 agree with the reference to <= a few f32 ulp (cross-program reduction
 association — the PR-1 FMA-contraction tolerance class), bf16 tensors to
-<= 2 bf16 ulp (the fused path rounds ONCE at the output where the
-unfused chain rounds per op — the fused value is the more accurate one).
+<= 2 bf16 ulp (these paths round ONCE at the output where the
+per-operation chain rounds per op — theirs is the more accurate value).
 
 Sharding caveat (same class as ``ops/flash_attention``): a
 ``pallas_call`` is opaque to GSPMD, so under a mesh that shards
 activations *declaratively* XLA gathers the operand around the kernel.
-Every hot path that enables these kernels runs them where tensors are
-already device-local: the ZeRO-2 engines' explicit shard_map gradient
-path, the single-chip bench, and the serving decode/prefill programs
-(slot-sharded caches enter via their own shard_map-free slot math).  The
-``materialization`` lint pass is the watchdog: an activation gather
-around the kernel shows up as a tree-scale buffer and fails CI.
+Every hot path that enables the LayerNorm kernels runs them where
+tensors are already device-local: the ZeRO-2 engines' explicit shard_map
+gradient path, the single-chip bench, and the serving decode/prefill
+programs (slot-sharded caches enter via their own shard_map-free slot
+math).  The ``materialization`` lint pass is the watchdog: an activation
+gather around the kernel shows up as a tree-scale buffer and fails CI.
+``bias_gelu`` is ordinary HLO and shards like any elementwise op.
 
-Enable/disable: resolved per model config (``TransformerConfig.
-fused_kernels``): ``"auto"`` = on when the backend is TPU, off on CPU
-(interpret-mode Pallas is a correctness tool, not a fast path);
-``DS_FUSED_ELEMENTWISE=0/1`` overrides "auto" (the bench ablation knob);
-``True``/``False`` force — True on CPU runs the kernels in interpret
-mode, which is how the tier-1 dp=8 mesh tests them.
+Enable/disable (the LayerNorm kernels only): resolved per model config
+(``TransformerConfig.fused_kernels``): ``"auto"`` = on when the backend
+is TPU, off on CPU (interpret-mode Pallas is a correctness tool, not a
+fast path); ``DS_FUSED_ELEMENTWISE=0/1`` overrides "auto" (the bench
+ablation knob); ``True``/``False`` force — True on CPU runs the kernels
+in interpret mode, which is how the tier-1 dp=8 mesh tests them.
 """
 from __future__ import annotations
 
@@ -80,7 +87,9 @@ def _interpret() -> bool:
 
 
 def fused_elementwise_enabled(flag="auto") -> bool:
-    """Resolve a config knob value to on/off.
+    """Resolve a config knob value to on/off: whether LayerNorm and
+    residual-LayerNorm go through the Pallas kernels (nothing else
+    listens to it since PR 51).
 
     ``True``/``False`` are forced; ``"auto"`` (the TransformerConfig
     default) is on exactly when the backend is TPU, overridable with
@@ -374,7 +383,8 @@ fused_residual_layer_norm.defvjp(_frln_fwd, _frln_bwd)
 
 
 # --------------------------------------------------------------------- #
-# Bias + GELU epilogue
+# Bias + GELU: fp32 arithmetic for XLA to fuse (and for
+# ops/grouped_gemm's in-kernel epilogue)
 # --------------------------------------------------------------------- #
 def _gelu_f32(z: jax.Array, exact: bool) -> jax.Array:
     if exact:
@@ -393,98 +403,58 @@ def _dgelu_f32(z: jax.Array, exact: bool) -> jax.Array:
     return 0.5 * (1.0 + t) + 0.5 * z * (1.0 - t * t) * du
 
 
-def _gelu_fwd_kernel(y_ref, b_ref, o_ref, *, exact: bool, out_dtype):
-    z = (y_ref[...].astype(jnp.float32) +
-         b_ref[...].astype(jnp.float32)).astype(out_dtype)
-    o_ref[...] = _gelu_f32(z.astype(jnp.float32), exact).astype(out_dtype)
-
-
-def _gelu_bwd_kernel(y_ref, b_ref, g_ref, dy_ref, db_ref, *, exact: bool,
-                     out_dtype):
-    """dz = g * gelu'(z) with z recomputed from the saved matmul output
-    (no extra residual); db partial = column sum of dz per block."""
-    z = (y_ref[...].astype(jnp.float32) +
-         b_ref[...].astype(jnp.float32)).astype(out_dtype)
-    dz = g_ref[...].astype(jnp.float32) * \
-        _dgelu_f32(z.astype(jnp.float32), exact)
-    dy_ref[...] = dz.astype(out_dtype)
-    db_ref[...] = jnp.sum(dz, axis=0, keepdims=True)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def fused_bias_gelu(y, bias, exact: bool = False):
-    """``gelu(y + bias)`` in one fused pass — the FFN up-projection
-    epilogue (``y`` is the raw matmul output).  ``exact`` selects the
-    erf form; default is the tanh approximation the reference's
-    ``gelu_kernels.cu`` computes (and GPT-2's gelu_new)."""
-    return _gelu_apply(y, bias, exact)
+def bias_gelu(y, bias, exact: bool = False):
+    """``gelu(y + bias)`` where ``y`` is the raw output of the FFN's
+    up-projection GEMM: plain ``jax.numpy``, no kernel.  An elementwise
+    expression is something XLA fuses into the GEMMs on either side of
+    it; a ``pallas_call`` there cost a read and a write of ``y`` that
+    existed only because a custom call cannot be fused (PR 51).  The
+    arithmetic is the deleted kernel's: the bias sum rounds once to the
+    storage dtype (what the unfused ``y + bias`` gives), the GELU is
+    evaluated in fp32 (tanh form; ``exact`` selects erf) and rounds once
+    at the end.  The backward keeps ``(y, bias)`` and nothing else,
+    recomputes ``z``, rounds ``dy`` once and sums ``dbias`` from the
+    fp32 ``dz``.  ``bias`` is ``[F]``, or anything that broadcasts
+    against ``y``'s trailing axes."""
+    return _bias_gelu(y, bias, exact)
 
 
-def _gelu_apply(y, bias, exact, _rb: int = None):
-    shape, dtype = y.shape, y.dtype
-    F = shape[-1]
-    rows = int(math.prod(shape[:-1])) if len(shape) > 1 else 1
-
-    def runner(rb_):
-        return _gelu_apply(jnp.zeros((rows, F), dtype),
-                           jnp.zeros((F,), jnp.float32), exact, _rb=rb_)
-
-    rows_pad, Fpad, rb = _geom(rows, F, n_bufs=4, kernel="fused_gelu_fwd",
-                               dtype=dtype, runner=runner, rb=_rb)
-    y2 = _pad2(y.reshape(rows, F), rows_pad, Fpad)
-    out = pl.pallas_call(
-        functools.partial(_gelu_fwd_kernel, exact=exact, out_dtype=dtype),
-        grid=(rows_pad // rb,),
-        in_specs=[_row_spec(rb, Fpad), _whole_spec(Fpad)],
-        out_specs=_row_spec(rb, Fpad),
-        out_shape=jax.ShapeDtypeStruct((rows_pad, Fpad), dtype),
-        name="_gelu_fwd_kernel",
-        interpret=_interpret(),
-    )(y2, _pad_row(bias.astype(jnp.float32), Fpad))
-    return out[:rows, :F].reshape(shape)
+def _bias_sum_f32(y, bias):
+    return (y.astype(jnp.float32) + bias.astype(jnp.float32)).astype(
+        y.dtype).astype(jnp.float32)
 
 
-def _fbg_fwd(y, bias, exact):
-    return _gelu_apply(y, bias, exact), (y, bias)
+def _bias_gelu(y, bias, exact):
+    return _gelu_f32(_bias_sum_f32(y, bias), exact).astype(y.dtype)
 
 
-def _fbg_bwd(exact, res, g):
+def _bias_gelu_fwd(y, bias, exact):
+    return _bias_gelu(y, bias, exact), (y, bias)
+
+
+def _bias_gelu_bwd(exact, res, g):
     y, bias = res
-    return _fbg_bwd_impl(y, bias, g, exact)
+    dz = g.astype(jnp.float32) * _dgelu_f32(_bias_sum_f32(y, bias), exact)
+    # The bias sum reads the fp32 ``dz``, over the axes the bias was
+    # broadcast along (``[F]`` under ``[rows, F]``; an expert's
+    # ``[E, 1, F]`` under ``[E, C, F]``).
+    lead = dz.ndim - bias.ndim
+    dbias = jnp.sum(dz, axis=tuple(range(lead)) + tuple(
+        lead + i for i, n in enumerate(bias.shape)
+        if n == 1 and dz.shape[lead + i] != 1)).reshape(bias.shape)
+    # ``dy`` is read by two GEMMs (dx and dW).  Left to itself XLA writes
+    # ``g`` out of the GEMM that makes it and evaluates ``g * gelu'(z)``
+    # again in each reader's operand: three tanh passes and four reads of
+    # ``y`` a layer, +3.2 / +1.7 ms a step in the train cells (PR 51).
+    # The barrier makes ``dy`` what that GEMM writes, beside ``dbias``;
+    # nothing of it is left in the compiled program.
+    dy = lax.optimization_barrier(dz.astype(y.dtype))
+    return dy, dbias.astype(bias.dtype)
 
 
-def _fbg_bwd_impl(y, bias, g, exact, _rb: int = None):
-    shape, dtype = y.shape, y.dtype
-    F = shape[-1]
-    rows = int(math.prod(shape[:-1])) if len(shape) > 1 else 1
-
-    def runner(rb_):
-        z = jnp.zeros((rows, F), dtype)
-        return _fbg_bwd_impl(z, jnp.zeros((F,), jnp.float32), z, exact,
-                             _rb=rb_)
-
-    rows_pad, Fpad, rb = _geom(rows, F, n_bufs=5, kernel="fused_gelu_bwd",
-                               dtype=dtype, runner=runner, rb=_rb)
-    grid = rows_pad // rb
-    y2 = _pad2(y.reshape(rows, F), rows_pad, Fpad)
-    g2 = _pad2(g.reshape(rows, F), rows_pad, Fpad)
-    dy, dbp = pl.pallas_call(
-        functools.partial(_gelu_bwd_kernel, exact=exact, out_dtype=dtype),
-        grid=(grid,),
-        in_specs=[_row_spec(rb, Fpad), _whole_spec(Fpad),
-                  _row_spec(rb, Fpad)],
-        out_specs=[_row_spec(rb, Fpad), _part_spec(Fpad)],
-        out_shape=[jax.ShapeDtypeStruct((rows_pad, Fpad), dtype),
-                   jax.ShapeDtypeStruct((grid, 1, Fpad), jnp.float32)],
-        name="_gelu_bwd_kernel",
-        interpret=_interpret(),
-    )(y2, _pad_row(bias.astype(jnp.float32), Fpad), g2)
-    dbias = jnp.sum(dbp[:, 0], axis=0)[:F].astype(bias.dtype)
-    return dy[:rows, :F].reshape(shape), dbias
-
-
-fused_bias_gelu.defvjp(_fbg_fwd, _fbg_bwd)
+bias_gelu.defvjp(_bias_gelu_fwd, _bias_gelu_bwd)
 
 
 __all__ = ["fused_layer_norm", "fused_residual_layer_norm",
-           "fused_bias_gelu", "fused_elementwise_enabled"]
+           "bias_gelu", "fused_elementwise_enabled"]

@@ -17,7 +17,7 @@ Structure:
   ``pallas_call`` (no autodiff).  Block sizes resolve through
   ``ops.autotune`` (kernel key ``grouped_gemm``) with the same 12 MiB
   VMEM budget math as ``fused_elementwise``; ``DS_AUTOTUNE=0`` or CPU
-  pins the heuristic.  Epilogue numerics mirror ``fused_bias_gelu``:
+  pins the heuristic.  Epilogue numerics mirror ``bias_gelu``:
   ``z = round(acc + bias)`` once to the storage dtype, GELU evaluated in
   fp32 on z, rounded once at the output.
 - ``grouped_ffn(x, w1, b1, w2, b2, exact)`` — the expert FFN as a
@@ -132,7 +132,7 @@ def _tile_candidates(M: int, K: int, N: int) -> Tuple[Tuple[int, int], ...]:
 def _gg_kernel(a_ref, b_ref, bias_ref, o_ref, *, act: Optional[str],
                has_bias: bool, out_dtype):
     """One (expert, row-block, col-block) grid step: fp32 MXU dot +
-    fused epilogue. Epilogue rounding mirrors _gelu_fwd_kernel: the
+    fused epilogue. Epilogue rounding mirrors ``bias_gelu``: the
     bias sum rounds ONCE to the storage dtype before GELU reads it."""
     acc = jax.lax.dot_general(
         a_ref[0], b_ref[0], (((1,), (0,)), ((), ())),

@@ -35,7 +35,7 @@ from deepspeed_tpu.models.transformer import (TransformerConfig,
                                               init_block_params,
                                               layer_norm,
                                               transformer_block)
-from deepspeed_tpu.ops.fused_elementwise import (fused_bias_gelu,
+from deepspeed_tpu.ops.fused_elementwise import (bias_gelu,
                                                  fused_elementwise_enabled,
                                                  fused_layer_norm,
                                                  fused_residual_layer_norm)
@@ -142,7 +142,7 @@ class TestBiasGelu:
     def test_fwd_parity(self, dtype, exact):
         F = 512
         y, b = _rand((33, F), 20, dtype), _rand((F,), 21)
-        out = jax.jit(lambda y, b: fused_bias_gelu(y, b, exact))(y, b)
+        out = jax.jit(lambda y, b: bias_gelu(y, b, exact))(y, b)
         ref = jax.nn.gelu(y + b.astype(y.dtype), approximate=not exact)
         assert out.dtype == dtype
         _close(out, ref, dtype)
@@ -157,13 +157,22 @@ class TestBiasGelu:
                 return jnp.sum(fn(y, b).astype(jnp.float32) ** 2)
             return jax.grad(run, argnums=(0, 1))(y, b)
 
-        gf = loss(lambda y, b: fused_bias_gelu(y, b))
+        gf = loss(lambda y, b: bias_gelu(y, b))
         gr = loss(lambda y, b: jax.nn.gelu(y + b.astype(y.dtype),
                                            approximate=True))
         _close(gf[0], gr[0], dtype, scale=4.0)
         # dbias sums dz over ALL rows — bf16 per-op rounding of the
         # unfused chain accumulates linearly with the row count.
         _close(gf[1], gr[1], dtype, scale=16.0)
+        # ... and is summed from the fp32 dz, whatever dy rounds to: a
+        # sum of bf16-rounded terms is off by ~1e-3 of it.
+        from deepspeed_tpu.ops.fused_elementwise import _dgelu_f32
+        z = (y.astype(jnp.float32) + b).astype(dtype).astype(jnp.float32)
+        out = bias_gelu(y, b).astype(jnp.float32)
+        dz = (2.0 * out) * _dgelu_f32(z, False)
+        np.testing.assert_allclose(np.asarray(gf[1]),
+                                   np.asarray(jnp.sum(dz, axis=0)),
+                                   rtol=1e-5, atol=1e-5)
 
 
 class TestKnobResolution:

@@ -142,12 +142,6 @@ def _case_resid_ln(bwd):
     return fn, (x, x, _sds((H,), jnp.float32), _sds((H,), jnp.float32))
 
 
-def _case_gelu(bwd):
-    from deepspeed_tpu.ops.fused_elementwise import fused_bias_gelu
-    fn = fused_bias_gelu if not bwd else _grad_of(fused_bias_gelu, (0, 1))
-    return fn, (_sds((ROWS, F), jnp.bfloat16), _sds((F,), jnp.float32))
-
-
 # Fused update: 400 grid steps' worth of flat buffer at the original
 # (128, 1024) block — the partials array the compiler refused was
 # (400, 128).
@@ -253,8 +247,6 @@ CASES = {
     "fused_ln_bwd": (_case_ln, True),
     "fused_residual_ln_fwd": (_case_resid_ln, False),
     "fused_residual_ln_bwd": (_case_resid_ln, True),
-    "bias_gelu_fwd": (_case_gelu, False),
-    "bias_gelu_bwd": (_case_gelu, True),
     "fused_update_sqnorm": (_case_sqnorm, None),
     "fused_update_apply_bf16_sr": (_case_apply, None),
     "fused_update_leaf_wte": (_case_update_leaf, (50304, 1280)),
@@ -311,6 +303,82 @@ def test_flash_reads_the_fused_projection_in_place(mbs, nh, one_chip, as_tpu):
         assert sum(1 for line in text.splitlines()
                    if kernel in line.split(" = ")[0]
                    and "tpu_custom_call" in line) == 1, kernel
+
+
+def _tanh_carriers(hlo_text):
+    """{instruction: holds a GEMM} for every instruction outside a fused
+    computation whose callees, nested fusions included, evaluate a tanh."""
+    import re
+    from deepspeed_tpu.analysis.hlo_text import split_computations
+    comps = split_computations(hlo_text)
+    calls = re.compile(r"calls=%?([\w.\-]+)")
+    fused = {c for lines in comps.values() for l in lines if " fusion(" in l
+             for c in calls.findall(l)}
+
+    def body(c, seen):
+        if c in seen or c not in comps:
+            return ""
+        seen.add(c)
+        return "\n".join(comps[c]) + "".join(
+            body(d, seen) for l in comps[c] for d in calls.findall(l))
+
+    out = {}
+    for c, lines in comps.items():
+        if c in fused:
+            continue
+        for l in lines:
+            if " fusion(" in l:
+                text = body(calls.search(l).group(1), set())
+                if " tanh(" in text:
+                    out[l.split(" = ")[0].strip()] = " convolution(" in text
+    return out
+
+
+@pytest.mark.parametrize("rows, h, f", [(4096, 1280, 5120),
+                                        (8192, 1024, 4096)],
+                         ids=["gpt2_large", "gpt2_medium"])
+def test_bias_gelu_rides_the_ffn_gemms(rows, h, f, one_chip,
+                                       no_persistent_cache):
+    """Forward and backward of the FFN sublayer as the train cells run it
+    (layers under ``scan``, ``checkpoint_dots``, bf16 compute over fp32
+    params), compiled: no kernel of ours, and every op that evaluates the
+    GELU's tanh is a GEMM — the forward's in the down-projection's
+    operand, the backward's in the output of the GEMM that makes ``da``
+    and in the operand of ``dW_out``'s.  A tanh in an op without
+    a GEMM is a pass of its own over ``[rows, F]``, which is what the
+    deleted Pallas kernels were (PR 51)."""
+    from deepspeed_tpu.models.transformer import (TransformerConfig, dense,
+                                                  gelu_dense_fn)
+    cfg = TransformerConfig(hidden_size=h, intermediate_size=f)
+    up = gelu_dense_fn(cfg)
+
+    def layer(x, p):
+        a = up(x, p["fc_kernel"], p["fc_bias"])
+        return x + dense(a, p["fc_out_kernel"], p["fc_out_bias"]), None
+
+    def loss(params, x):
+        x, _ = jax.lax.scan(jax.checkpoint(
+            layer, policy=jax.checkpoint_policies.checkpoint_dots), x, params)
+        return jnp.sum(x.astype(jnp.float32) ** 2)
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    layers = 4
+    params = {"fc_kernel": sds(layers, h, f), "fc_bias": sds(layers, f),
+              "fc_out_kernel": sds(layers, f, h),
+              "fc_out_bias": sds(layers, h)}
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, sds(rows, h, dtype=jnp.bfloat16)).compile().as_text()
+    assert "tpu_custom_call" not in text
+    carriers = _tanh_carriers(text)
+    # One in the forward and two in the backward: the recomputed GELU in
+    # dW_out's operand, and the GEMM that makes ``da`` writing ``dy`` and
+    # ``dbias``.  Five is ``dy`` evaluated again in the operands of its
+    # two readers (what the barrier in ``_bias_gelu_bwd`` is there for).
+    assert 1 <= len(carriers) <= 3, carriers
+    assert all(carriers.values()), \
+        f"a tanh outside a GEMM: {[k for k, v in carriers.items() if not v]}"
 
 
 @pytest.mark.parametrize("K,Q", [(1, 64), (128, 1)],
