@@ -235,9 +235,9 @@ class GqaPagedServed(ServedModel):
     """What a model of grouped-query K/V pages answers whatever else its
     layers hold (experts, a conv state, a state-space mixer): the K/V
     tiles, the attend's dimensions and step counts (``group`` query heads a
-    K/V head as query rows), and a table row split class by class.  ``cfg``
-    names ``num_attention_heads``, ``num_key_value_heads``, ``head_dim``,
-    ``group``, ``num_hidden_layers`` and ``max_position_embeddings``."""
+    K/V head as query rows).  ``cfg`` names ``num_attention_heads``,
+    ``num_key_value_heads``, ``head_dim``, ``group``, ``num_hidden_layers``
+    and ``max_position_embeddings``."""
     @property
     def max_positions(self) -> int:
         return int(self.cfg.max_position_embeddings)
@@ -277,17 +277,6 @@ class GqaPagedServed(ServedModel):
             kv_itemsize=int(jnp.dtype(spec.dtype).itemsize),
             q_itemsize=q_itemsize) + (
                 paged_attn_ops.attend_cold_steps(live_blocks, calls=calls),)
-
-    def _widths(self, table) -> Tuple[int, ...]:
-        widths = self.table_widths
-        if widths is None and len(self.cache_classes) == 1:
-            widths = (table.shape[-1],)
-        if widths is None or sum(widths) != table.shape[-1]:
-            raise ValueError(
-                f"{type(self).__name__}: a table row {table.shape[-1]} wide "
-                f"against class widths {widths}: the engine sets "
-                "table_widths when it sizes the tables")
-        return widths
 
 
 class AfmoeServed(GqaPagedServed):

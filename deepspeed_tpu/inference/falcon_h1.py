@@ -51,7 +51,8 @@ from jax import lax
 
 from . import kv_cache
 from .afmoe import GqaPagedServed, _attend_rows, _gather_attend
-from .served import CacheClass, group_shape, register
+from .served import (CacheClass, filter_rows, group_shape, register,
+                     stream_pages)
 from ..models import falcon_h1 as fh1
 from ..models.blocks import rms_norm
 from ..models.falcon_h1 import FalconH1Config
@@ -112,30 +113,13 @@ def _forward(params, pools, x, bt_g, pos_g, live, cfg: FalconH1Config,
             plan = paged_attn_ops.attend_plan(bt_runs, seen_runs, kc, D,
                                               mesh=mesh, group=grp)
 
-    # -- the state's page, where it goes back and what a snapshot takes
-    page = bt_g[:, :, w_full].reshape(S)
-    n_live = live.sum(axis=1).astype(jnp.int32)                  # [S]
-    nowhere = ssm.shape[2]      # index B is out of range: a dropped write
-    g_of = jnp.arange(S, dtype=jnp.int32) // Sg
-    at_page = jnp.maximum(page, 0)
-    wrote = (page >= 0) & (n_live > 0)
-    carried = pos[:, 0] > 0
-
-    def ending_after(n):
-        """The conv state once ``n`` of a stream's rows are consumed: the
-        ``taps - 1`` rows up to there, in [page | rows]."""
-        return n[:, None] + jnp.arange(taps - 1, dtype=jnp.int32)[None]
-    to = [jnp.where(wrote, page, nowhere)]
-    keep = [ending_after(jnp.maximum(n_live, 1))]
+    # -- the state's page, where it goes back and what a snapshot takes.
     # The scan's sub-chunk: every block boundary is one of its carried
     # states (a chunk starts at one: the engine's widths are whole blocks).
     q_rows = math.gcd(cfg.mamba_chunk_size, bs, K)
-    keep_chunk = None
-    if freeze is not None:
-        row, snap = freeze
-        to.append(jnp.where(wrote & (snap >= 0), snap, nowhere))
-        keep.append(ending_after(jnp.clip(row + 1, 1, K)))
-        keep_chunk = jnp.clip((row + 1) // q_rows - 1, 0, K // q_rows - 1)
+    page = bt_g[:, :, w_full].reshape(S)
+    sp = stream_pages(page, pos, live, ssm.shape[2], Sg, taps - 1, freeze,
+                      scan_rows=q_rows)
 
     def attention(p, u, layer):
         nonlocal kc, vc
@@ -169,9 +153,10 @@ def _forward(params, pools, x, bt_g, pos_g, live, cfg: FalconH1Config,
                 ssm, layer, page.reshape(G, Sg),
                 *(group_shape(v, G) for v in args), mesh=mesh)
             return y.reshape((S, 1) + y.shape[2:])
-        y, new = ssm_scan.recurrent_update(ssm[layer, g_of, at_page], *args)
-        ssm = ssm.at[layer, g_of, to[0]].set(new, mode="drop")
-        return jnp.where(wrote[:, None, None], y, 0.0)[:, None]
+        y, new = ssm_scan.recurrent_update(ssm[layer, sp.group, sp.page],
+                                           *args)
+        ssm = ssm.at[layer, sp.group, sp.to[0]].set(new, mode="drop")
+        return jnp.where(sp.wrote[:, None, None], y, 0.0)[:, None]
 
     def chunk_states(x_h, B, C, dt, a, layer):
         """A chunk of rows a stream, from the page's state."""
@@ -180,12 +165,14 @@ def _forward(params, pools, x, bt_g, pos_g, live, cfg: FalconH1Config,
         a = jnp.where(live[..., None], a, 0.0)
         ys = []
         for s in range(S):
-            S0 = jnp.where(carried[s], ssm[layer, g_of[s], at_page[s]], 0.0)
+            S0 = jnp.where(sp.carried[s],
+                           ssm[layer, sp.group[s], sp.page[s]], 0.0)
             y, S1, kept = ssm_scan.chunked_scan(
                 S0, x_h[s], B[s], C[s], dt[s], a[s], chunk=q_rows,
-                keep=None if keep_chunk is None else keep_chunk[s])
-            for where, new in zip(to, (S1, kept)):
-                ssm = ssm.at[layer, g_of[s], where[s]].set(new, mode="drop")
+                keep=None if sp.keep_chunk is None else sp.keep_chunk[s])
+            for where, new in zip(sp.to, (S1, kept)):
+                ssm = ssm.at[layer, sp.group[s], where[s]].set(
+                    new, mode="drop")
             ys.append(y)
         return jnp.stack(ys)
 
@@ -195,16 +182,7 @@ def _forward(params, pools, x, bt_g, pos_g, live, cfg: FalconH1Config,
             with jax.named_scope("ssm_in_proj"):
                 z, xbc, dt_raw = fh1.ssm_in(p, u, cfg)
             with jax.named_scope("ssm_conv"):
-                held = conv[layer, g_of, at_page].reshape(S, taps - 1, -1)
-                held = jnp.where(carried[:, None, None], held, 0)
-                rows_in = jnp.concatenate([held.astype(xbc.dtype), xbc],
-                                          axis=1)          # [S, taps-1+K, C]
-                for rows_kept, where in zip(keep, to):
-                    new = jnp.take_along_axis(rows_in, rows_kept[:, :, None],
-                                              axis=1)
-                    conv = conv.at[layer, g_of, where].set(
-                        new.reshape((S,) + conv.shape[3:]).astype(conv.dtype),
-                        mode="drop")
+                rows_in, conv = filter_rows(sp, conv, layer, xbc)
                 x_h, B, C = fh1.ssm_split(fh1.ssm_conv(p, rows_in, cfg), cfg)
                 dt, a = fh1.ssm_steps(p, dt_raw)
             if not chunked:

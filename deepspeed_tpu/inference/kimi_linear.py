@@ -1,0 +1,305 @@
+"""The ``kimi_linear`` family as a served model (inference/served.py): three
+Kimi-Delta-Attention layers, which keep a fixed-size fp32 STATE a stream
+(and the last rows of three short filters), to one latent-attention layer,
+which keeps a ``[ckv | k_pe]`` row a token — two KINDS of cache in different
+layers — then a dense SwiGLU (layer 1) or an expert layer that holds a share
+of its experts.
+
+The kinds are declared, not coded for: ``cache_classes`` names ``latent``
+(the latent layers; one pool ``latent.latent``, the row every head shares as
+``ops.latent_attention`` lays it, unbounded reach) and ``state`` (the KDA
+layers, ``per_stream``; pools ``state.state``, a stream's ``S [nh, dk, dv]``
+of a layer in FLOAT32 as ``ops.kda`` tiles it, and ``conv.state``, the last
+``short_conv_kernel_size - 1`` rows of the projected ``[q~ | k~ | v~]`` in the
+cache's dtype), and ``class_geometry`` answers for each.  The engine gives
+each class its own pools, block table and allocator behind
+``kv_cache.ClassAllocators`` — latent blocks shared by reference, the state
+by snapshots, one prefix rule across both, so a HIT is across kinds: latent
+blocks up to a boundary AND a state snapshot AT it — and a program gets the
+pools class by class and every table row as the classes' rows side by side
+(``table_widths``): the latent columns, then the stream's page.
+
+A latent layer is ``inference/latent.py``'s sublayer called on this model's
+latent class (``latent_context`` once a program, ``latent_sublayer`` a
+layer): absorbed decode and chunk prefill through the same kernels, with no
+rotation and a full-rank query (``models/deepseek_v3.latent_projections``).
+
+``decode`` has one row a stream: the delta-rule update is
+``ops.kda.state_update`` on the chip (every live page's layer read once and
+written once, in place, with the dependent pass in between), else a gather,
+``ops.kda.recurrent_update`` and a scatter that drops dead slots.
+``prefill_chunk`` has a chunk of one stream a group: the CHUNKED delta rule
+from the page's state (zeros at position 0) in sub-chunks of
+gcd(``KDA_CHUNK``, the cache's block, the chunk) rows; rows past
+``last_idx`` neither decay the state nor write to it.  Every block boundary
+of the prompt is one of the scan's carried states: the model declares
+``freezes_in_chunk``, and the chunk that reaches a snapshot's boundary
+writes the state as it stood THERE (and the filter rows that end there) into
+the snapshot's page as well.  A state cannot be rolled back over rejected
+drafts: ``verify`` raises, and ``inference.spec_k`` must be 0.
+
+The layers are walked in a static loop (their kinds differ).  Scopes:
+``embed``; ``attn`` > ``kda_proj``, ``kda_conv``, ``kda_gate``,
+``kda_update`` (decode) / ``kda_chunk`` (prefill), ``kda_out`` in a KDA
+layer, ``latent_proj``, ``kv_write``, ``attend`` in a latent one; ``mlp``
+(layer 1); ``moe`` > ``router``, ``dispatch``, ``experts``, ``combine``,
+``shared``; ``lm_head``.  Each program returns the expert layers' counters
+(``LatentServed``'s), which ride the token fetch.  The chunked form bounds no
+exponent (every one it forms is a difference that cannot be positive:
+``ops/kda.py``), so there is no bound whose bites a counter could count.
+"""
+from __future__ import annotations
+
+import math
+from typing import Any, Callable, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from . import kv_cache
+from .latent import LatentServed, latent_context, latent_sublayer
+from .served import (CacheClass, filter_rows, group_shape, register,
+                     stream_pages)
+from ..models import kimi_linear as kl
+from ..models.blocks import rms_norm, swiglu
+from ..models.kimi_linear import KDA, KimiLinearConfig
+from ..moe import share
+from ..ops import kda
+
+LATENT_CLASS, STATE_CLASS = "latent", "state"
+KDA_CHUNK = 64            # rows a step of the chunked delta rule
+
+
+def conv_tile(cfg: KimiLinearConfig) -> Tuple[int, int, int]:
+    """A page's tile of one KDA layer's filter rows as held: ``taps - 1``
+    rows of ``conv_dim``, row-major, in rows of 128 lanes where they divide
+    (a ``[3, C]`` minor pair would be padded to the sublane tile)."""
+    rows = cfg.short_conv_kernel_size - 1
+    n = rows * cfg.conv_dim
+    return (1, n // 128, 128) if n % 128 == 0 else (1, rows, cfg.conv_dim)
+
+
+def _forward(params, pools, x, bt_g, pos_g, live, cfg: KimiLinearConfig,
+             widths, paged_kernel: bool, mesh, chunked: bool, freeze=None):
+    """All layers: x [S, K, H] with its streams' table rows bt_g [G, Sg, W]
+    (the classes' rows side by side, ``widths`` wide), row positions pos_g
+    [G, Sg, K] and ``live`` [S, K]: the rows that are traffic (a live
+    stream's, and no padding; a stream's live rows come first).  The others
+    write no cache row and no page, attend nothing, get no expert row and
+    are not counted; what they compute nobody reads.  ``pools``: (latent,
+    state, conv).  ``chunked``: a prefill chunk (the chunked delta rule over
+    its K rows), else the decode program (K = 1: the state update).
+    ``freeze``: (row [S], page [S]) — a stream's state as it stands after
+    chunk row ``row`` goes into ``page`` too (a snapshot; ``DEAD_BLOCK``:
+    none), or None.  Returns (x', pools', counters)."""
+    G, Sg, K = pos_g.shape
+    S, H = G * Sg, x.shape[-1]
+    taps = cfg.short_conv_kernel_size
+    pos = pos_g.reshape(S, K)
+    latent, state, conv = pools
+    w_latent, w_state = widths
+    assert w_state == 1, widths
+    ctx = latent_context(bt_g[:, :, :w_latent], pos_g, live, latent,
+                         paged_kernel, mesh)
+
+    # -- the state's page, where it goes back and what a snapshot takes.
+    # The scan's sub-chunk: every block boundary is one of its carried
+    # states (a chunk starts at one: the engine's widths are whole blocks).
+    q_rows = math.gcd(KDA_CHUNK, 2 * latent.shape[4], K)
+    page = bt_g[:, :, w_latent].reshape(S)
+    sp = stream_pages(page, pos, live, state.shape[2], Sg, taps - 1, freeze,
+                      scan_rows=q_rows)
+
+    def decode_states(q, k, v, g, beta, layer):
+        """One row a stream: every live page's layer rewritten in place."""
+        nonlocal state
+        args = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+        if paged_kernel:
+            o, state = kda.state_update(
+                state, layer, page.reshape(G, Sg),
+                *(group_shape(a, G) for a in args), mesh=mesh)
+            return o.reshape((S, 1) + o.shape[2:])
+        o, new = kda.recurrent_update(state[layer, sp.group, sp.page], *args)
+        state = state.at[layer, sp.group, sp.to[0]].set(new, mode="drop")
+        return jnp.where(sp.wrote[:, None, None], o, 0.0)[:, None]
+
+    def chunk_states(q, k, v, g, beta, layer):
+        """A chunk of rows a stream, from the page's state."""
+        nonlocal state
+        g = jnp.where(live[..., None, None], g, 0.0)
+        beta = jnp.where(live[..., None], beta, 0.0)
+        os_ = []
+        for s in range(S):
+            S0 = jnp.where(sp.carried[s],
+                           state[layer, sp.group[s], sp.page[s]], 0.0)
+            o, S1, kept = kda.chunked_delta_rule(
+                S0, q[s], k[s], v[s], g[s], beta[s], chunk=q_rows,
+                keep=None if sp.keep_chunk is None else sp.keep_chunk[s])
+            for where, new in zip(sp.to, (S1, kept)):
+                state = state.at[layer, sp.group[s], where[s]].set(
+                    new, mode="drop")
+            os_.append(o)
+        return jnp.stack(os_)
+
+    def kda_mixer(p, x, layer):
+        nonlocal conv
+        with jax.named_scope("attn"):
+            with jax.named_scope("kda_proj"):
+                u = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
+                qkv = kl.kda_in(p, u, cfg)                  # [S, K, 3 W]
+            with jax.named_scope("kda_conv"):
+                rows_in, conv = filter_rows(sp, conv, layer, qkv)
+                q, k, v = kl.kda_qkv(kl.kda_conv(p, rows_in, cfg), cfg)
+            with jax.named_scope("kda_gate"):
+                g, beta = kl.kda_gates(p, u, cfg)
+            if not chunked:
+                with jax.named_scope("kda_update"):
+                    o = decode_states(q, k, v, g, beta, layer)
+            else:
+                with jax.named_scope("kda_chunk"):
+                    o = chunk_states(q, k, v, g, beta, layer)
+            with jax.named_scope("kda_out"):
+                return x + kl.kda_out(p, o, u, cfg)
+
+    def latent_mixer(p, x, layer):
+        nonlocal latent
+        with jax.named_scope("attn"):
+            y, latent = latent_sublayer(p, x, pos, latent, layer, ctx, cfg,
+                                        mesh)
+            with jax.named_scope("latent_proj"):
+                return x + y
+
+    row_live = live.reshape(S * K)
+    zero = jnp.zeros((), jnp.int32)
+    pairs, most, empty = zero, zero, zero
+    at = {KDA: 0, kl.LATENT: 0}
+    for l, p in enumerate(params["layers"]):
+        kind = cfg.layer_kinds[l]
+        x = (kda_mixer if kind == KDA else latent_mixer)(p, x, at[kind])
+        at[kind] += 1
+        if l < cfg.num_dense_layers:
+            with jax.named_scope("mlp"):
+                h = rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
+                x = x + swiglu(h, p["mlp_gate"], p["mlp_up"], p["mlp_down"])
+            continue
+        h = rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
+        # ``paged_kernel`` is "this path runs its Pallas kernels": the
+        # attend, the state update and the grouped expert product alike.
+        y, counts = share.expert_layer(
+            p, h.reshape(S * K, H), cfg.routing, kernel=paged_kernel,
+            row_live=row_live)
+        x = x + y.reshape(S, K, H)
+        pairs = pairs + counts.sum()
+        most = jnp.maximum(most, counts.max())
+        empty = empty + (counts == 0).sum()
+    return x, (latent, state, conv), (
+        pairs, most, empty, row_live.sum().astype(jnp.int32))
+
+
+@jax.named_scope("lm_head")
+def _head(params, h, cfg):
+    h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+    return jnp.dot(h, params["lm_head"].astype(h.dtype).T,
+                   preferred_element_type=jnp.float32)
+
+
+@jax.named_scope("embed")
+def _embed(params, tokens, cfg):
+    return params["embed"].astype(cfg.dtype)[tokens]
+
+
+class KimiLinearServed(LatentServed):
+    """See the module docstring.  What a model of a latent row a token and
+    expert layers that hold a share answers is ``LatentServed``'s (the
+    latent tile, the attend's dimensions and step counts, the expert
+    counters); this family's own is the second KIND of cache, in most of its
+    layers."""
+    # The chunked delta rule carries the state from sub-chunk to sub-chunk
+    # and every block boundary is one: the program that passes a snapshot's
+    # leaves it.
+    freezes_in_chunk = True
+
+    @property
+    def init_fn(self) -> Callable:
+        return kl.kimi_linear_init
+
+    @property
+    def cache_classes(self) -> Tuple[CacheClass, ...]:
+        cfg = self.cfg
+        return (CacheClass(LATENT_CLASS, cfg.num_latent_layers),
+                CacheClass(STATE_CLASS, cfg.num_kda_layers,
+                           per_stream=True))
+
+    def class_geometry(self, cls: CacheClass, block_size: int
+                       ) -> Dict[str, Any]:
+        """``latent``: the row every head shares, in the engine's cache
+        dtype.  ``state``: ``state``, the stream's ``S`` of a KDA layer in
+        FLOAT32 whatever the cache's dtype, and ``conv``, its filters' rows
+        in the cache's.  Its yardstick (``token_row_bytes``, a KDA layer's
+        share) is what a token keeps as latent rows in this model's latent
+        layers: the blocks a snapshot saves prefilling."""
+        if not cls.per_stream:
+            return super().class_geometry(cls, block_size)
+        cfg = self.cfg
+        tile = kda.state_tile(cfg.kda_num_heads, cfg.kda_head_dim,
+                              cfg.kda_head_dim)
+        latent_token = (cfg.latent_width * cfg.num_latent_layers
+                        * jnp.dtype(cfg.dtype).itemsize)
+        return dict(pools=(("state", tile, jnp.float32),
+                           ("conv", conv_tile(cfg))),
+                    num_heads=cfg.kda_num_heads,
+                    head_dim=cfg.kda_head_dim * cfg.kda_head_dim,
+                    token_row_bytes=-(-latent_token // cls.layers))
+
+    # -- programs ------------------------------------------------------ #
+    def verify(self, params, pools, tokens, lengths, block_tables, *,
+               num_groups, paged_kernel, mesh=None):
+        raise NotImplementedError(
+            "a delta-rule state cannot be rolled back over rejected "
+            "drafts: set inference.spec_k to 0")
+
+    def decode(self, params, pools, tokens, lengths, block_tables, *,
+               num_groups, paged_kernel, mesh=None):
+        cfg = self.cfg
+        live = (block_tables >= 0).any(axis=1, keepdims=True)
+        x, pools, counters = _forward(
+            params, pools, _embed(params, tokens[:, None], cfg),
+            group_shape(block_tables, num_groups),
+            group_shape(lengths[:, None], num_groups), live, cfg,
+            self._widths(block_tables), paged_kernel, mesh, chunked=False)
+        return _head(params, x[:, 0], cfg), pools, counters
+
+    def prefill_chunk(self, params, pools, tokens, bt_rows, start,
+                      last_idx, active, freeze_idx=None, freeze_page=None,
+                      *, paged_kernel, mesh=None):
+        """``decode.gpt2_prefill_chunk_paged``'s contract; rows past
+        ``last_idx`` (a last chunk's padding) are dead rows.  The state a
+        chunk starts from is whatever the stream's own page holds — a
+        snapshot the engine copied there, or the chunk before — and zeros
+        at position 0.  ``freezes_in_chunk``: a group's state as it stands
+        after chunk row ``freeze_idx`` (the last row of a block) goes into
+        page ``freeze_page`` as well (``DEAD_BLOCK``: the group leaves none
+        in this chunk; without the operands the program writes the
+        stream's own page only)."""
+        cfg = self.cfg
+        G, Cn = tokens.shape
+        cols = lax.broadcasted_iota(jnp.int32, (G, Cn), 1)
+        pos = start[:, None] + cols
+        bt_g = jnp.where(active[:, None, None] > 0, bt_rows[:, None],
+                         kv_cache.DEAD_BLOCK)
+        live = (active[:, None] > 0) & (cols <= last_idx[:, None])
+        x, pools, counters = _forward(
+            params, pools, _embed(params, tokens, cfg), bt_g,
+            pos[:, None, :], live, cfg, self._widths(bt_rows), paged_kernel,
+            mesh, chunked=True, freeze=None if freeze_idx is None
+            else (freeze_idx, freeze_page))
+        oh = (cols == last_idx[:, None]).astype(x.dtype)
+        h_last = jnp.einsum("gc,gch->gh", oh, x)
+        return _head(params, h_last, cfg), pools, counters
+
+
+register(KimiLinearConfig, KimiLinearServed)
+
+__all__ = ["KimiLinearServed", "LATENT_CLASS", "STATE_CLASS", "conv_tile",
+           "KDA_CHUNK"]

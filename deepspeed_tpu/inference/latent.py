@@ -35,7 +35,7 @@ ones.
 from __future__ import annotations
 
 import contextlib
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -79,19 +79,27 @@ def _scope(name):
     return jax.named_scope(name) if name else contextlib.nullcontext()
 
 
-def _forward(params, pool, x, bt_g, pos_g, live, cfg: DeepseekV3Config,
-             paged_kernel: bool, mesh):
-    """All layers: x [S, K, H] (``[S, K, n, H]`` with ``hc_mult`` = n
-    residual streams) with its streams' tables bt_g [G, Sg, J],
-    row positions pos_g [G, Sg, K] and ``live`` [S, K]: the rows that are
-    traffic (a live stream's, and no padding).  The others write no cache
-    row, attend nothing, get no expert row and are not counted; what they
-    compute nobody reads.  Returns (x', pool', counters)."""
+class LatentContext(NamedTuple):
+    """What every latent layer of one program shares: the attend's plan (the
+    kernel path) or the block selection and position mask (the one-hot
+    path), and every new row's (block, offset) in the pool."""
+    plan: Any
+    sel: Any
+    pos_mask: Any
+    blk: jax.Array
+    off: jax.Array
+
+
+def latent_context(bt_g, pos_g, live, pool, paged_kernel: bool, mesh
+                   ) -> LatentContext:
+    """For streams' latent tables bt_g [G, Sg, J] (the latent CLASS's
+    columns where a model keeps other classes beside it), row positions
+    pos_g [G, Sg, K] and ``live`` [S, K] (the rows that are traffic: the
+    others write no row and attend nothing), over ``pool`` (any layer's:
+    the geometry is read)."""
     G, Sg, J = bt_g.shape
-    S, K, H = x.shape[:2] + x.shape[-1:]
-    nH, C = cfg.num_attention_heads, cfg.kv_lora_rank
+    K = pos_g.shape[-1]
     bs = 2 * pool.shape[4]
-    pos = pos_g.reshape(S, K)
     sel = pos_mask = plan = None
     live_g = live.reshape(G, Sg, K)
     reach = jnp.where(live_g, pos_g, -1)       # a dead row attends nothing
@@ -104,6 +112,63 @@ def _forward(params, pool, x, bt_g, pos_g, live, cfg: DeepseekV3Config,
         pos_mask = grid <= reach[..., None]
     blk, off = write_targets(bt_g, pos_g, bs)
     blk = jnp.where(live_g.reshape(G, Sg * K), blk, kv_cache.DEAD_BLOCK)
+    return LatentContext(plan, sel, pos_mask, blk, off)
+
+
+def latent_sublayer(p, h, pos, pool, layer, ctx: LatentContext, cfg, mesh):
+    """One latent-attention mixer in the ABSORBED form (module docstring),
+    inside the caller's ``attn`` scope: h [S, K, H] un-normed at positions
+    pos [S, K]; its rows go into ``pool`` at ``layer`` (an index into THIS
+    pool's layers), the attend reads them there.  ``cfg`` names the latent
+    keys (``models.deepseek_v3.latent_projections``) and ``softmax_scale``.
+    Returns (y [S, K, H], pool')."""
+    G = ctx.blk.shape[0]
+    S, K = h.shape[:2]
+    Sg = S // G
+    nH, C = cfg.num_attention_heads, cfg.kv_lora_rank
+    with jax.named_scope("latent_proj"):
+        h = dsv3.rms_norm(h, p["input_norm"], cfg.rms_norm_eps)
+        q_nope, q_rope, ckv, k_rope = dsv3.latent_projections(
+            p, h, pos, cfg)
+        wk, wv = dsv3.wkv_b_split(p, cfg)
+        q_abs = jnp.einsum(
+            "sknd,cnd->sknc", q_nope, wk.astype(h.dtype),
+            preferred_element_type=jnp.float32).astype(h.dtype)
+        row = jnp.concatenate([ckv, k_rope], axis=-1)
+    with jax.named_scope("kv_write"):
+        pool = latent_ops.latent_write(
+            pool, row.reshape(G, Sg * K, -1), layer, ctx.blk, ctx.off,
+            kv_lora=C, mesh=mesh)
+    with jax.named_scope("attend"):
+        qa = q_abs.reshape(G, Sg, K, nH, C)
+        qr = q_rope.reshape(G, Sg, K, nH, -1)
+        if ctx.plan is not None:
+            u = latent_ops.latent_attention(
+                qa, qr, pool, layer, plan=ctx.plan,
+                scale=cfg.softmax_scale, mesh=mesh)
+        else:
+            u = _onehot_attend(qa, qr, pool, layer, ctx.sel, ctx.pos_mask,
+                               cfg.softmax_scale, C)
+    with jax.named_scope("latent_proj"):
+        o = jnp.einsum(
+            "sknc,cnv->sknv", u.reshape(S, K, nH, C),
+            wv.astype(h.dtype), preferred_element_type=jnp.float32
+        ).astype(h.dtype).reshape(S, K, nH * cfg.v_head_dim)
+        return dsv3.matmul(o, p["wo"]), pool
+
+
+def _forward(params, pool, x, bt_g, pos_g, live, cfg: DeepseekV3Config,
+             paged_kernel: bool, mesh):
+    """All layers: x [S, K, H] (``[S, K, n, H]`` with ``hc_mult`` = n
+    residual streams) with its streams' tables bt_g [G, Sg, J],
+    row positions pos_g [G, Sg, K] and ``live`` [S, K]: the rows that are
+    traffic (a live stream's, and no padding).  The others write no cache
+    row, attend nothing, get no expert row and are not counted; what they
+    compute nobody reads.  Returns (x', pool', counters)."""
+    K = pos_g.shape[-1]
+    S, H = x.shape[0], x.shape[-1]
+    pos = pos_g.reshape(S, K)
+    ctx = latent_context(bt_g, pos_g, live, pool, paged_kernel, mesh)
 
     # The residual path.  One stream: a sublayer reads x and adds to it.
     # ``hc_mult`` streams: it reads a mixture of them and writes back
@@ -139,35 +204,8 @@ def _forward(params, pool, x, bt_g, pos_g, live, cfg: DeepseekV3Config,
     def attention(p, x, pool, layer):
         with jax.named_scope("attn"):
             h, m = read(p, "attn", x)
-            with jax.named_scope("latent_proj"):
-                h = dsv3.rms_norm(h, p["input_norm"], cfg.rms_norm_eps)
-                q_nope, q_rope, ckv, k_rope = dsv3.latent_projections(
-                    p, h, pos, cfg)
-                wk, wv = dsv3.wkv_b_split(p, cfg)
-                q_abs = jnp.einsum(
-                    "sknd,cnd->sknc", q_nope, wk.astype(h.dtype),
-                    preferred_element_type=jnp.float32).astype(h.dtype)
-                row = jnp.concatenate([ckv, k_rope], axis=-1)
-            with jax.named_scope("kv_write"):
-                pool = latent_ops.latent_write(
-                    pool, row.reshape(G, Sg * K, -1), layer, blk, off,
-                    kv_lora=C, mesh=mesh)
-            with jax.named_scope("attend"):
-                qa = q_abs.reshape(G, Sg, K, nH, C)
-                qr = q_rope.reshape(G, Sg, K, nH, -1)
-                if plan is not None:
-                    u = latent_ops.latent_attention(
-                        qa, qr, pool, layer, plan=plan,
-                        scale=cfg.softmax_scale, mesh=mesh)
-                else:
-                    u = _onehot_attend(qa, qr, pool, layer, sel, pos_mask,
-                                       cfg.softmax_scale, C)
-            with jax.named_scope("latent_proj"):
-                o = jnp.einsum(
-                    "sknc,cnv->sknv", u.reshape(S, K, nH, C),
-                    wv.astype(h.dtype), preferred_element_type=jnp.float32
-                ).astype(h.dtype).reshape(S, K, nH * cfg.v_head_dim)
-                y = dsv3.matmul(o, p["wo"])
+            y, pool = latent_sublayer(p, h, pos, pool, layer, ctx, cfg,
+                                      mesh)
             x = write(m, x, y, plain="latent_proj")
         return x, pool, m
 
@@ -360,4 +398,5 @@ class LatentServed(ServedModel):
 
 register(DeepseekV3Config, LatentServed)
 
-__all__ = ["LatentServed"]
+__all__ = ["LatentServed", "LatentContext", "latent_context",
+           "latent_sublayer"]

@@ -53,7 +53,8 @@ from jax import lax
 
 from . import kv_cache
 from .afmoe import AfmoeServed, _attend_rows, _gather_attend
-from .served import CacheClass, group_shape, register
+from .served import (CacheClass, filter_rows, group_shape, register,
+                     stream_pages)
 from ..models import lfm2
 from ..models.blocks import matmul, rms_norm, swiglu
 from ..models.lfm2 import CONV, Lfm2Config
@@ -106,31 +107,12 @@ def _forward(params, pools, x, bt_g, pos_g, live, cfg: Lfm2Config,
         bt = bt_g[:, :, at_col:at_col + width]
         at_col += width
         if cls.per_stream:
-            page = bt[:, :, 0].reshape(S)
-            n_live = live.sum(axis=1).astype(jnp.int32)          # [S]
-            nowhere = pools[at_pool].shape[2]
-
-            def ending_after(n):
-                """A stream's state once ``n`` of its rows are consumed: the
-                ``conv_L_cache - 1`` rows up to there, in [page | rows]."""
-                return n[:, None] + jnp.arange(cfg.conv_L_cache - 1,
-                                               dtype=jnp.int32)[None]
-            conv = dict(
-                at=at_pool, layer=0, g=jnp.arange(S, dtype=jnp.int32) // Sg,
-                page=jnp.maximum(page, 0),
-                # where the page goes back: nowhere for a stream without a
-                # live row (index B is out of range: dropped)
-                to=[jnp.where((page >= 0) & (n_live > 0), page, nowhere)],
-                carried=(pos[:, 0] > 0)[:, None, None],
-                # the rows that end at the last live one
-                keep=[ending_after(jnp.maximum(n_live, 1))])
-            if freeze is not None:
-                # a second write: the rows that end at the snapshot's row,
-                # to the snapshot's page
-                row, snap = freeze
-                conv["to"].append(jnp.where(
-                    (page >= 0) & (n_live > 0) & (snap >= 0), snap, nowhere))
-                conv["keep"].append(ending_after(jnp.clip(row + 1, 1, K)))
+            # the conv state's page, where it goes back and what a
+            # snapshot takes (a second write: the rows that end at the
+            # snapshot's row, to the snapshot's page)
+            conv = dict(at=at_pool, layer=0, pages=stream_pages(
+                bt[:, :, 0].reshape(S), pos, live, pools[at_pool].shape[2],
+                Sg, cfg.conv_L_cache - 1, freeze))
             at_pool += 1
             continue
         kc = pools[at_pool]
@@ -196,17 +178,10 @@ def _forward(params, pools, x, bt_g, pos_g, live, cfg: Lfm2Config,
                 u = rms_norm(x, p["op_norm"], cfg.norm_eps)
                 z, gate = lfm2.conv_gates(p, u)                # [S, K, H]
             with jax.named_scope("conv_mix"):
-                held = pool[layer, c["g"], c["page"]].reshape(S, L - 1, H)
-                held = jnp.where(c["carried"], held, 0).astype(z.dtype)
-                zc = jnp.concatenate([held, z], axis=1)    # [S, L-1+K, H]
+                zc, pool = filter_rows(c["pages"], pool, layer, z)
                 taps = p["conv_k"].astype(jnp.float32)
                 mixed = sum(zc[:, j:j + K].astype(jnp.float32) * taps[:, j]
                             for j in range(L))
-                for keep, to in zip(c["keep"], c["to"]):
-                    new = jnp.take_along_axis(zc, keep[:, :, None], axis=1)
-                    pool = pool.at[layer, c["g"], to].set(
-                        new.reshape((S,) + pool.shape[3:]).astype(pool.dtype),
-                        mode="drop")
                 y = (gate.astype(jnp.float32) * mixed).astype(x.dtype)
             with jax.named_scope("conv_out_proj"):
                 x = x + matmul(y, p["w_out"])

@@ -199,6 +199,19 @@ class ServedModel:
         [executions, len(counter_names)]`` (int64)."""
         return {}
 
+    def _widths(self, table) -> Tuple[int, ...]:
+        """``table_widths`` as a program splits a table row by them (a
+        model of one class: the row's own width)."""
+        widths = self.table_widths
+        if widths is None and len(self.cache_classes) == 1:
+            widths = (table.shape[-1],)
+        if widths is None or sum(widths) != table.shape[-1]:
+            raise ValueError(
+                f"{type(self).__name__}: a table row {table.shape[-1]} wide "
+                f"against class widths {widths}: the engine sets "
+                "table_widths when it sizes the tables")
+        return widths
+
     # -- the programs -------------------------------------------------- #
     def decode(self, params, pools: Sequence, tokens, lengths,
                block_tables, *, num_groups: int, paged_kernel: bool,
@@ -280,6 +293,83 @@ def write_targets(bt_g: jax.Array, pos_g: jax.Array, block_size: int
     return blk.reshape(G, Sg * K), off.reshape(G, Sg * K)
 
 
+class StreamPages(NamedTuple):
+    """Where one program reads and writes the pages of a ``per_stream``
+    class, a stream a row (S streams, ``Sg`` a group): ``group`` and
+    ``page`` [S] index a pool's ``[layer, group, page]`` (page 0 for a
+    stream that has none: what is read there nobody uses); ``wrote`` [S]:
+    the stream has a page and a live row; ``carried`` [S]: its rows
+    continue it (position > 0) — else it starts from zeros whatever the
+    page holds; ``to``: the page each copy of the new state goes to — the
+    stream's own and, where the program freezes a snapshot, the
+    snapshot's — with the pool's page COUNT (out of range: a dropped
+    write) for a stream that wrote none; ``keep`` [S, held]: for each of
+    ``to``, the rows of ``[held | new rows]`` that are the filter's state
+    there; ``keep_chunk`` [S]: the sub-chunk of a scan after which the
+    snapshot's state stands (None without one)."""
+    group: jax.Array
+    page: jax.Array
+    wrote: jax.Array
+    carried: jax.Array
+    to: Tuple[jax.Array, ...]
+    keep: Tuple[jax.Array, ...]
+    keep_chunk: Optional[jax.Array] = None
+
+
+def stream_pages(page: jax.Array, pos: jax.Array, live: jax.Array,
+                 num_pages: int, streams_a_group: int, held: int,
+                 freeze=None, scan_rows: Optional[int] = None
+                 ) -> StreamPages:
+    """``page`` [S]: the streams' table column (``DEAD_BLOCK``: none);
+    ``pos`` / ``live`` [S, K]: the rows' positions and which are traffic (a
+    stream's live rows come first); ``held``: the rows a short filter keeps
+    (its taps - 1).  ``freeze``: (row [S], page [S]) — the state as it
+    stands after chunk row ``row`` goes into ``page`` too (``DEAD_BLOCK``:
+    none); ``scan_rows``: the sub-chunk of the model's scan, whose carried
+    states are the only ones a snapshot can take."""
+    S, K = pos.shape
+    n_live = live.sum(axis=1).astype(jnp.int32)                  # [S]
+    wrote = (page >= 0) & (n_live > 0)
+
+    def ending_after(n):
+        """The filter's state once ``n`` of a stream's rows are consumed:
+        the ``held`` rows up to there, in [held | rows]."""
+        return n[:, None] + jnp.arange(held, dtype=jnp.int32)[None]
+    to = [jnp.where(wrote, page, num_pages)]
+    keep = [ending_after(jnp.maximum(n_live, 1))]
+    keep_chunk = None
+    if freeze is not None:
+        row, snap = freeze
+        to.append(jnp.where(wrote & (snap >= 0), snap, num_pages))
+        keep.append(ending_after(jnp.clip(row + 1, 1, K)))
+        if scan_rows is not None:
+            keep_chunk = jnp.clip((row + 1) // scan_rows - 1, 0,
+                                  K // scan_rows - 1)
+    return StreamPages(
+        jnp.arange(S, dtype=jnp.int32) // streams_a_group,
+        jnp.maximum(page, 0), wrote, pos[:, 0] > 0, tuple(to), tuple(keep),
+        keep_chunk)
+
+
+def filter_rows(sp: StreamPages, pool: jax.Array, layer,
+                new: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """A short filter's rows through its pages: ``new`` [S, K, C] behind
+    the ``held`` rows the stream's page of ``pool [layers, groups, pages,
+    *tile]`` holds (zeros for a stream that starts here) -> (``[S, held +
+    K, C]`` in ``new``'s dtype, the pool with the rows that end at the
+    last live row — and at a snapshot's row — written to ``sp.to``)."""
+    S = new.shape[0]
+    old = pool[layer, sp.group, sp.page].reshape(S, sp.keep[0].shape[1], -1)
+    old = jnp.where(sp.carried[:, None, None], old, 0)
+    rows = jnp.concatenate([old.astype(new.dtype), new], axis=1)
+    for kept, where in zip(sp.keep, sp.to):
+        tail = jnp.take_along_axis(rows, kept[:, :, None], axis=1)
+        pool = pool.at[layer, sp.group, where].set(
+            tail.reshape((S,) + pool.shape[3:]).astype(pool.dtype),
+            mode="drop")
+    return rows, pool
+
+
 # Sampling (in-graph; PRNG threaded by the engine per iteration)
 @jax.named_scope("sample")
 def sample_tokens(logits: jax.Array, key: jax.Array,
@@ -320,4 +410,5 @@ def spec_accept(logits: jax.Array, tokens: jax.Array, key: jax.Array,
 
 __all__ = ["CacheClass", "ServedModel", "register", "served_model",
            "split_counters", "with_counters", "NEG_INF", "group_shape",
-           "write_targets", "sample_tokens", "spec_accept"]
+           "write_targets", "StreamPages", "stream_pages", "filter_rows",
+           "sample_tokens", "spec_accept"]

@@ -78,7 +78,8 @@ class DeepseekV3Config:
     topk_group: int = 4
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 2.5
-    q_lora_rank: int = 1536
+    # None (a published ``null``): a full-rank query, ``wq [H, nH*(nope+rope)]``
+    q_lora_rank: Optional[int] = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -91,6 +92,9 @@ class DeepseekV3Config:
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 1.0
     rope_original_max_position_embeddings: int = 4096
+    # NoPE: the ``qk_rope_head_dim`` columns of q and of the cached row stay
+    # in place, unrotated (the ``kimi_linear`` family's latent layers).
+    mla_use_nope: bool = False
     max_position_embeddings: int = 262144
     initializer_range: float = 0.02
     router_bias_std: float = 0.1
@@ -251,18 +255,27 @@ def rope_interleaved(x: jax.Array, cos: jax.Array, sin: jax.Array
 # Layer pieces
 # ------------------------------------------------------------------ #
 def latent_projections(p: Dict[str, jax.Array], h: jax.Array,
-                       positions: jax.Array, cfg: DeepseekV3Config):
+                       positions: jax.Array, cfg):
     """The projections ahead of the attend, for normed input ``h
     [..., H]`` at ``positions [...]``: (q_nope [..., nH, nope], q_rope
     [..., nH, rope] rotated, ckv [..., kv_lora] normed, k_rope [...,
-    rope] rotated) — ``[ckv | k_rope]`` is the row the cache keeps."""
+    rope] rotated) — ``[ckv | k_rope]`` is the row the cache keeps.
+    ``cfg``: a ``DeepseekV3Config``, or any config with its latent keys
+    (``q_lora_rank`` None: ``wq`` alone; ``mla_use_nope``: nothing is
+    rotated and ``positions`` is not read)."""
     nH, dn, dr = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                   cfg.qk_rope_head_dim)
-    cq = rms_norm(matmul(h, p["wq_a"]), p["q_norm"], cfg.rms_norm_eps)
-    q = matmul(cq, p["wq_b"]).reshape(h.shape[:-1] + (nH, dn + dr))
+    if cfg.q_lora_rank:
+        cq = rms_norm(matmul(h, p["wq_a"]), p["q_norm"], cfg.rms_norm_eps)
+        q = matmul(cq, p["wq_b"])
+    else:
+        q = matmul(h, p["wq"])
+    q = q.reshape(h.shape[:-1] + (nH, dn + dr))
     kv = matmul(h, p["wkv_a"])
     ckv = rms_norm(kv[..., :cfg.kv_lora_rank], p["kv_norm"],
                    cfg.rms_norm_eps)
+    if cfg.mla_use_nope:
+        return q[..., :dn], q[..., dn:], ckv, kv[..., cfg.kv_lora_rank:]
     cos, sin = rope_cos_sin(cfg, positions)
     q_rope = rope_interleaved(q[..., dn:], cos[..., None, :],
                               sin[..., None, :])
@@ -270,7 +283,7 @@ def latent_projections(p: Dict[str, jax.Array], h: jax.Array,
     return q[..., :dn], q_rope, ckv, k_rope
 
 
-def wkv_b_split(p: Dict[str, jax.Array], cfg: DeepseekV3Config):
+def wkv_b_split(p: Dict[str, jax.Array], cfg):
     """``wkv_b [kv_lora, nH*(nope+v)]`` -> (k part [kv_lora, nH, nope],
     v part [kv_lora, nH, v])."""
     w = p["wkv_b"].reshape(cfg.kv_lora_rank, cfg.num_attention_heads,
@@ -283,9 +296,11 @@ def wkv_b_split(p: Dict[str, jax.Array], cfg: DeepseekV3Config):
 # ------------------------------------------------------------------ #
 def _attn_shapes(cfg: DeepseekV3Config) -> Dict[str, Tuple[int, ...]]:
     H, nH = cfg.hidden_size, cfg.num_attention_heads
+    query = {"wq_a": (H, cfg.q_lora_rank),
+             "wq_b": (cfg.q_lora_rank, nH * cfg.qk_head_dim)} \
+        if cfg.q_lora_rank else {"wq": (H, nH * cfg.qk_head_dim)}
     return {
-        "wq_a": (H, cfg.q_lora_rank),
-        "wq_b": (cfg.q_lora_rank, nH * cfg.qk_head_dim),
+        **query,
         "wkv_a": (H, cfg.latent_width),
         "wkv_b": (cfg.kv_lora_rank,
                   nH * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
@@ -294,7 +309,8 @@ def _attn_shapes(cfg: DeepseekV3Config) -> Dict[str, Tuple[int, ...]]:
 
 
 def _norm_shapes(cfg: DeepseekV3Config) -> Dict[str, Tuple[int, ...]]:
-    return {"input_norm": (cfg.hidden_size,), "q_norm": (cfg.q_lora_rank,),
+    q_norm = {"q_norm": (cfg.q_lora_rank,)} if cfg.q_lora_rank else {}
+    return {"input_norm": (cfg.hidden_size,), **q_norm,
             "kv_norm": (cfg.kv_lora_rank,), "post_norm": (cfg.hidden_size,)}
 
 

@@ -67,7 +67,16 @@ SCOPES = ("fwd_bwd", "grad_sync", "health_tap", "optimizer", "flatten",
           # ssm_state_update (decode: the kernel over the state pool) /
           # ssm_chunk_scan (prefill), ssm_gate_norm, ssm_out_proj
           "ssm", "ssm_in_proj", "ssm_conv", "ssm_state_update",
-          "ssm_chunk_scan", "ssm_gate_norm", "ssm_out_proj")
+          "ssm_chunk_scan", "ssm_gate_norm", "ssm_out_proj",
+          # Kimi Delta Attention layers (inference/kimi_linear.py): under
+          # attn, kda_proj (norm, the fused q/k/v projection), kda_conv (the
+          # filter rows' read, the three filters, the L2 norms, the rewrite),
+          # kda_gate (the decay a channel and the write strength),
+          # kda_update (decode: the delta-rule kernel over the state pool) /
+          # kda_chunk (prefill: the chunked form), kda_out (the gated head
+          # norm and the output projection)
+          "kda_proj", "kda_conv", "kda_gate", "kda_update", "kda_chunk",
+          "kda_out")
 # The host spans ``Telemetry.span`` opens (runtime/engine.py,
 # inference/engine.py, inference/scheduler.py), same contract.
 SPANS = ("train_batch", "data_prep", "step_dispatch", "offload_step",

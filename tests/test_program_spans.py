@@ -982,3 +982,75 @@ def test_the_readers_list_names_the_state_space_scopes():
         == ("ssm", "ssm_state_update")
     assert scope_of("jit(prefill_step)/ssm/ssm_chunk_scan/while/dot")[0] \
         == ("ssm", "ssm_chunk_scan")
+
+
+# --------------------------------------------------------------------- #
+# (k) a model whose latent-attention layers stand BETWEEN layers that keep
+# a delta-rule state a stream (PR 52): the KDA mixer's scopes beside the
+# latent sublayer's, under one ``attn``
+# --------------------------------------------------------------------- #
+@pytest.fixture(scope="module")
+def kimi_op_names():
+    import jax.numpy as jnp
+    from deepspeed_tpu.models.kimi_linear import (KimiLinearConfig,
+                                                  kimi_linear_init)
+    cfg = KimiLinearConfig(
+        vocab_size=128, hidden_size=64, intermediate_size=96,
+        moe_intermediate_size=32, num_hidden_layers=4, num_attention_heads=4,
+        num_key_value_heads=4, kda_num_heads=2, kda_head_dim=16,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, num_experts=8, held=(0, 4), num_experts_per_token=2,
+        model_max_length=256, dtype=jnp.float32)
+    eng = InferenceEngine(
+        cfg, kimi_linear_init(jax.random.PRNGKey(0), cfg),
+        config={"inference": {"max_slots": 4, "max_seq_len": 128,
+                              "prefill_chunk": 8, "block_size": 4,
+                              "num_blocks": {"latent": 96, "state": 12},
+                              "paged_kernel": True}},
+        mesh=build_mesh(devices=jax.devices()[:1]))
+    G, W = eng.dp, eng.allocator.table_width
+    key, temp = eng._next_key(), np.float32(0.0)
+    names = {
+        "decode": _op_names(eng._decode_fn, eng._params, *eng._pools(),
+                            eng._no_fetch, eng.last_tokens,
+                            np.ones(eng.max_slots, bool), eng.lengths,
+                            eng.block_tables, key, temp),
+        # (+ the snapshot's row and page: the program freezes it)
+        "prefill": _op_names(
+            eng._prefill_fn, eng._params, *eng._pools(),
+            np.zeros((G, eng.prefill_chunk), np.int32),
+            np.zeros((G, W), np.int32), np.zeros(G, np.int32),
+            np.zeros(G, np.int32), np.ones(G, np.int32),
+            np.zeros(G, np.int32), np.zeros(G, np.int32), key, temp)}
+    eng.close()
+    return names
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("scope", [
+    "embed", "attn/kda_proj", "attn/kda_conv", "attn/kda_gate",
+    "attn/kda_out", "attn/latent_proj", "attn/kv_write", "attn/attend",
+    "mlp", "moe/router", "moe/dispatch", "moe/experts", "moe/combine",
+    "moe/shared", "lm_head", "sample"])
+def test_kimi_program_carries_scope(kimi_op_names, program, scope):
+    assert any(f"/{scope}" in n for n in kimi_op_names[program]), \
+        (program, scope)
+
+
+def test_the_delta_rule_update_and_its_chunked_form_each_belong_to_one_program(
+        kimi_op_names):
+    names = kimi_op_names
+    assert any("/attn/kda_update" in n for n in names["decode"])
+    assert not any("/kda_chunk" in n for n in names["decode"])
+    assert any("/attn/kda_chunk" in n for n in names["prefill"])
+    assert not any("/kda_update" in n for n in names["prefill"])
+
+
+def test_the_readers_list_names_the_kda_scopes():
+    from deepspeed_tpu.monitor.xplane_reader import SCOPES, scope_of
+    assert {"kda_proj", "kda_conv", "kda_gate", "kda_update", "kda_chunk",
+            "kda_out"} <= set(SCOPES)
+    assert scope_of("jit(decode_step)/attn/kda_update/pallas_call")[0] \
+        == ("attn", "kda_update")
+    assert scope_of("jit(prefill_step)/attn/kda_chunk/while/dot")[0] \
+        == ("attn", "kda_chunk")

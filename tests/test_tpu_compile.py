@@ -1433,3 +1433,176 @@ def test_both_kinds_in_a_layer_fit_and_are_updated_in_place(
     assert "/attn/attend_full" in text and "/ssm/ssm_conv" in text
     assert ("/ssm/ssm_state_update" if program == "decode_step"
             else "/ssm/ssm_chunk_scan") in text
+
+
+# ------------------------------------------------------------------ #
+# The kimi_linear family (PR 52): the delta-rule decode kernel and the
+# ENGINE's programs over a LATENT class (2 layers) beside a per-stream class
+# (6 KDA layers: an fp32 state + bf16 filter rows) at the benchmark cell's
+# shape
+# ------------------------------------------------------------------ #
+@pytest.mark.parametrize("streams", [256, 1])
+def test_kda_state_update_kernel_compiles_at_the_published_widths(
+        streams, one_chip, as_tpu):
+    """32 heads x [128, 128] fp32 a page and layer, 16 heads (1 MiB) a grid
+    step, the pool aliased in and out."""
+    from deepspeed_tpu.ops import kda
+    S = streams
+    assert kda.tile_heads(32, 128, 128) == 16
+    pool = jax.ShapeDtypeStruct((6, 1, 8, 32, 128, 128), jnp.float32,
+                                sharding=one_chip)
+    f = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32,   # noqa: E731
+                                        sharding=one_chip)
+    compiled = jax.jit(
+        lambda pool, pages, q, k, v, g, beta: kda.state_update(
+            pool, 2, pages, q, k, v, g, beta), donate_argnums=0).lower(
+        pool, jax.ShapeDtypeStruct((1, S), jnp.int32, sharding=one_chip),
+        f(1, S, 32, 128), f(1, S, 32, 128), f(1, S, 32, 128),
+        f(1, S, 32, 128), f(1, S, 32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "_kda_state_update_kernel" in text
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 6 * 8 * 32 * 128 * 128 * 4
+
+
+@pytest.fixture(scope="module")
+def latent_beside_state_programs(topo):
+    """(specs, params bytes, {program: compiled}) of the engine's own step
+    builders for ``perfbench/configs/kimi-linear-48b-a3b.json`` on an engine
+    shell (see ``_serve_program``): ``decode_step``, ``prefill_step`` at both
+    of ``prefill_widths`` and the page copy."""
+    import json
+    import os
+    import sys
+    from types import SimpleNamespace
+    from deepspeed_tpu.inference import kv_cache
+    from deepspeed_tpu.inference.engine import (InferenceEngine,
+                                                prefill_widths)
+    from deepspeed_tpu.inference.served import served_model
+    from deepspeed_tpu.models.kimi_linear import kimi_linear_init
+    from jax.experimental.compilation_cache import compilation_cache
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from perfbench.runners import longgen
+    sizes = json.load(open(os.path.join(
+        root, "perfbench", "configs", "kimi-linear-48b-a3b.json")))
+    inf = sizes["serve"]["inference"]
+    cfg = longgen.model_config(sizes)
+    served = served_model(cfg)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+            tree)
+    params = on_chip(jax.eval_shape(lambda k: kimi_linear_init(k, cfg),
+                                    jax.random.PRNGKey(0)))
+    param_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(params))
+    specs = kv_cache.class_specs(
+        served.cache_classes, inf["num_blocks"], rows=inf["prefill_chunk"],
+        of_class=lambda c: served.class_geometry(c, inf["block_size"]),
+        num_slots=inf["max_slots"], block_size=inf["block_size"],
+        max_len=inf["max_seq_len"], num_groups=1, dtype=jnp.bfloat16)
+    served.table_widths = tuple(sp.max_blocks_per_slot for sp in specs)
+    eng = object.__new__(InferenceEngine)
+    eng.model_cfg, eng.dp, eng.sp, eng.mesh = served, 1, 1, None
+    eng.paged_kernel, eng.quantize = True, "none"
+    eng.prefill_chunk = inf["prefill_chunk"]
+    eng._cache_sh = {n: one for sp in specs for n in sp.pool_names}
+    eng.allocator = SimpleNamespace(copy_pools=specs[-1].pool_names)
+    pools = [on_chip(jax.ShapeDtypeStruct(sp.pool_shapes[n],
+                                          sp.pool_dtypes[n]))
+             for sp in specs for n in sp.pool_names]
+    S, J = inf["max_slots"], sum(served.table_widths)
+    n_ctr = len(served.counter_names)
+    i32 = lambda *shape: on_chip(jax.ShapeDtypeStruct(shape, jnp.int32))
+    fresh = lambda n: on_chip(jax.ShapeDtypeStruct((n,), jnp.bool_))
+    key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
+    temp = on_chip(jax.ShapeDtypeStruct((), jnp.float32))
+    mp = pytest.MonkeyPatch()
+    prev = jax.config.jax_enable_compilation_cache
+    out = {}
+    try:
+        mp.setattr(jax, "default_backend", lambda *a, **k: "tpu")
+        mp.setenv("DS_AUTOTUNE", "0")
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        out["decode_step"] = eng._build_decode_step().lower(
+            params, *pools, i32(S + n_ctr), i32(S), fresh(S), i32(S),
+            i32(S, J), key, temp).compile()
+        for C in prefill_widths(inf["prefill_chunk"], inf["block_size"]):
+            # (+ the snapshot's row and page: the program freezes it)
+            out[f"prefill_step.{C}"] = eng._build_prefill_step().lower(
+                params, *pools, i32(1, C), i32(1, J), i32(1), i32(1), i32(1),
+                i32(1), i32(1), key, temp).compile()
+        out["state_copy"] = eng._build_copy("state_copy", "state_copy").lower(
+            *pools, i32(1), i32(1)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+        mp.undo()
+    return specs, param_bytes, out
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_step.256",
+                                     "prefill_step.512", "state_copy"])
+def test_latent_beside_state_fit_and_are_updated_in_place(
+        latent_beside_state_programs, program):
+    """Weights 2.60 GB (one dense KDA layer, five KDA and two latent expert
+    layers of 16 held experts, 20,480 rows of embedding and of untied head)
+    + the latent pool (2 layers x 1,152 B a token) + the state pages (6
+    layers x (2 MiB fp32 + 72 KiB bf16 filter rows)): every pool aliased to
+    its output, scratch inside what is left of the chip's 16 GiB; the latent
+    attend, the row write, the grouped expert product and, in decode, the
+    delta-rule update are TPU custom calls; no program holds an op the size
+    of a pool."""
+    from deepspeed_tpu.analysis.hlo_text import ops_in_units_of
+    specs, param_bytes, programs = latent_beside_state_programs
+    latent, state = specs
+    compiled = programs[program]
+    assert abs(param_bytes - 2.600e9) < 0.01e9, param_bytes
+    assert (latent.num_layers, state.num_layers) == (2, 6)
+    assert latent.block_nbytes() == 2 * 128 * 576 * 2
+    assert state.block_nbytes() == 6 * (32 * 128 * 128 * 4 + 3 * 12288 * 2) \
+        == 13025280
+    # (a page is 5,654 tokens of this model's latent rows; beside the blocks
+    # a prompt that adds one prefill program's rows leaves a snapshot)
+    assert state.token_row_bytes == 384 and state.page_tokens == 512
+    B = state.num_blocks
+    assert state.pool_shapes == {"state.state": (6, 1, B, 32, 128, 128),
+                                 "conv.state": (6, 1, B, 1, 288, 128)}
+    assert state.pool_dtypes == {"state.state": jnp.float32,
+                                 "conv.state": jnp.bfloat16}
+    assert latent.max_blocks_per_slot == 452
+    pool_bytes = latent.nbytes() + state.nbytes()
+    assert 0.60 < (param_bytes + pool_bytes) / 2 ** 34 < 0.85
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    if program == "state_copy":
+        assert mem.temp_size_in_bytes < 64 * 2 ** 20
+        return
+    assert param_bytes + pool_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes \
+        < 15.75 * 2 ** 30, mem
+    text = compiled.as_text()
+    kernels = ["_latent_attn_kernel", "_latent_write_kernel",
+               "_gswiglu_kernel"]
+    if program == "decode_step":
+        kernels.append("_kda_state_update_kernel")
+    for kernel in kernels:
+        calls = [line for line in text.splitlines()
+                 if f"%{kernel}" in line.split(" = ")[0]
+                 and " custom-call(" in line]
+        assert calls and all("tpu_custom_call" in c for c in calls), kernel
+    # (a chunk's state goes back into its page by a dynamic-update-slice of
+    # the donated pool, fused with the page's read: in place)
+    in_place = {"dynamic-update-slice", "fusion"} \
+        if program != "decode_step" else set()
+    for spec, pool in ((latent, "latent.latent"), (state, "state.state")):
+        seen = ops_in_units_of(text, math.prod(spec.pool_shapes[pool][2:]))
+        assert not [(op, n) for op, n in seen
+                    if op not in _POOL_OPS_ALLOWED | in_place], pool
+    assert "/attn/attend" in text and "/attn/kda_conv" in text
+    assert ("/attn/kda_update" if program == "decode_step"
+            else "/attn/kda_chunk") in text
