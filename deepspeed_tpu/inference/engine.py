@@ -67,8 +67,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from . import kv_cache
 from .quantize import (dequantize, quantize_params, quantized_bytes,
                        resolve_kv_dtype)
-from .served import (ServedModel, sample_tokens, served_model, spec_accept,
-                     split_counters, with_counters)
+from .served import (ServedModel, filter_rows_lowered, sample_tokens,
+                     served_model, spec_accept, split_counters, with_counters)
 from .spec import NGramDrafter
 from .. import constants as C
 from ..monitor import Telemetry
@@ -280,6 +280,10 @@ class InferenceEngine:
         # flipping the env var mid-flight cannot desync the sentinel.
         self.paged_kernel = bool(paged_attn_ops.paged_kernel_enabled(
             self.icfg.paged_kernel))
+        # 1 once the decode program is traced with a per-stream class's
+        # filter rows rewritten in place (``served.filter_rows``); the
+        # ``decode`` span carries it beside ``state_pages_live``.
+        self.filter_rows_in_place = 0
 
         # --- weights: quantize, then commit to the mesh ---
         self.quantize = self.icfg.quantize
@@ -518,9 +522,13 @@ class InferenceEngine:
             tokens = jnp.where(fresh, tokens,
                                previous[:tokens.shape[0]])
             p = self._runtime_params(params)
+            in_place = filter_rows_lowered["in_place"]
             logits, pools, counters = served.decode(
                 p, pools, tokens, lengths, bt, num_groups=self.dp,
                 paged_kernel=self.paged_kernel, mesh=self.mesh)
+            # (this body runs when the program is traced: once)
+            self.filter_rows_in_place = int(
+                filter_rows_lowered["in_place"] > in_place)
             sampled = sample_tokens(logits, key, temperature)
             return (*pools, with_counters(sampled, counters), logits)
 
@@ -1304,6 +1312,9 @@ class InferenceEngine:
             live_blocks, cache_bytes, ctx_tokens = \
                 self._cache_accounting(mask)
             self.serving.note_attend_steps(*steps)
+            state = self.allocator.span_args(live=n_active)
+            if state:       # pages a stream: did their filter rows go
+                state["filter_rows_in_place"] = self.filter_rows_in_place
             if n_active:
                 self.serving.note_attend(*self._attend_work(1, mask),
                                          n_active)
@@ -1312,8 +1323,7 @@ class InferenceEngine:
                               attend_steps=steps[0],
                               attend_live_steps=steps[1],
                               attend_cold_steps=steps[2],
-                              **self._class_args(mask),
-                              **self.allocator.span_args(live=n_active))
+                              **self._class_args(mask), **state)
         lap("dispatch_s")
         return _Flight(fetch=fetch, logits=logits, mask=mask,
                        n_active=n_active, t0=t0,

@@ -38,7 +38,10 @@ The layers are walked in a static loop.  Scopes: ``embed``; ``attn`` >
 ``qkv_proj``, ``kv_write``, ``attend_full``, ``out_proj``; ``ssm`` >
 ``ssm_in_proj``, ``ssm_conv``, ``ssm_state_update`` (decode) /
 ``ssm_chunk_scan`` (prefill), ``ssm_gate_norm``, ``ssm_out_proj``; ``mlp``;
-``lm_head``.
+``lm_head``.  The filter rows go through ``served.filter_rows``, which
+rewrites a decode step's in place where the tile allows: 5,120 channels of
+bf16 are 40 sublane rows a held row, two and a half tiles, so the published
+width keeps the gather and scatter.
 """
 from __future__ import annotations
 
@@ -182,7 +185,9 @@ def _forward(params, pools, x, bt_g, pos_g, live, cfg: FalconH1Config,
             with jax.named_scope("ssm_in_proj"):
                 z, xbc, dt_raw = fh1.ssm_in(p, u, cfg)
             with jax.named_scope("ssm_conv"):
-                rows_in, conv = filter_rows(sp, conv, layer, xbc)
+                rows_in, conv = filter_rows(sp, conv, layer, xbc,
+                                            paged_kernel=paged_kernel,
+                                            mesh=mesh)
                 x_h, B, C = fh1.ssm_split(fh1.ssm_conv(p, rows_in, cfg), cfg)
                 dt, a = fh1.ssm_steps(p, dt_raw)
             if not chunked:

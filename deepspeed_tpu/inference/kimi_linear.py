@@ -27,7 +27,10 @@ rotation and a full-rank query (``models/deepseek_v3.latent_projections``).
 ``decode`` has one row a stream: the delta-rule update is
 ``ops.kda.state_update`` on the chip (every live page's layer read once and
 written once, in place, with the dependent pass in between), else a gather,
-``ops.kda.recurrent_update`` and a scatter that drops dead slots.
+``ops.kda.recurrent_update`` and a scatter that drops dead slots; the
+filters' rows go through their pages the same way, in place by
+``ops.filter_rows.shift_rows`` where ``served.filter_rows`` finds a tile
+the kernel takes (12,288 channels: 96 sublane rows a held row).
 ``prefill_chunk`` has a chunk of one stream a group: the CHUNKED delta rule
 from the page's state (zeros at position 0) in sub-chunks of
 gcd(``KDA_CHUNK``, the cache's block, the chunk) rows; rows past
@@ -149,7 +152,9 @@ def _forward(params, pools, x, bt_g, pos_g, live, cfg: KimiLinearConfig,
                 u = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
                 qkv = kl.kda_in(p, u, cfg)                  # [S, K, 3 W]
             with jax.named_scope("kda_conv"):
-                rows_in, conv = filter_rows(sp, conv, layer, qkv)
+                rows_in, conv = filter_rows(sp, conv, layer, qkv,
+                                            paged_kernel=paged_kernel,
+                                            mesh=mesh)
                 q, k, v = kl.kda_qkv(kl.kda_conv(p, rows_in, cfg), cfg)
             with jax.named_scope("kda_gate"):
                 g, beta = kl.kda_gates(p, u, cfg)

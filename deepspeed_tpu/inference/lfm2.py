@@ -37,7 +37,9 @@ class does (``group`` query heads a K/V head as query rows).
 
 The layers are walked in a static loop (their kinds differ; nothing is
 stacked or sliced).  Scopes: ``embed``; ``conv`` > ``conv_in_proj``,
-``conv_mix`` (gates' product, filter, the page's rewrite), ``conv_out_proj``;
+``conv_mix`` (gates' product, filter, the page's rewrite: in place by
+``ops.filter_rows.shift_rows`` in the decode program on the chip,
+``served.filter_rows``), ``conv_out_proj``;
 ``attn`` > ``qkv_proj``, ``kv_write``, ``attend_full``, ``out_proj``;
 ``mlp`` (dense layers); ``moe`` > ``router``, ``dispatch``, ``experts``,
 ``combine``; ``lm_head``.  Each program also returns the expert layers'
@@ -178,7 +180,9 @@ def _forward(params, pools, x, bt_g, pos_g, live, cfg: Lfm2Config,
                 u = rms_norm(x, p["op_norm"], cfg.norm_eps)
                 z, gate = lfm2.conv_gates(p, u)                # [S, K, H]
             with jax.named_scope("conv_mix"):
-                zc, pool = filter_rows(c["pages"], pool, layer, z)
+                zc, pool = filter_rows(c["pages"], pool, layer, z,
+                                       paged_kernel=paged_kernel,
+                                       mesh=mesh)
                 taps = p["conv_k"].astype(jnp.float32)
                 mixed = sum(zc[:, j:j + K].astype(jnp.float32) * taps[:, j]
                             for j in range(L))

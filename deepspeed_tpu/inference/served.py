@@ -306,7 +306,17 @@ class StreamPages(NamedTuple):
     write) for a stream that wrote none; ``keep`` [S, held]: for each of
     ``to``, the rows of ``[held | new rows]`` that are the filter's state
     there; ``keep_chunk`` [S]: the sub-chunk of a scan after which the
-    snapshot's state stands (None without one)."""
+    snapshot's state stands (None without one).
+
+    Two kinds of lines read it.  Plain ``jax.numpy`` (every prefill chunk,
+    the CPU, a tile no kernel takes) gathers ``page`` and scatters to ``to``:
+    a stream that wrote nothing costs a read of page 0 and a write DROPPED
+    by the out-of-range index, because a scatter has one shape for all S
+    streams.  The decode programs' in-place kernels (``ops.kda`` /
+    ``ops.ssm_scan.state_update``, ``ops.filter_rows.shift_rows``) take
+    ``wrote`` itself and SKIP such a stream: a copy that is never started
+    costs nothing, and page 0 may be another stream's — a kernel that
+    rewrote it with what it read would race that stream's own write."""
     group: jax.Array
     page: jax.Array
     wrote: jax.Array
@@ -351,15 +361,52 @@ def stream_pages(page: jax.Array, pos: jax.Array, live: jax.Array,
         keep_chunk)
 
 
-def filter_rows(sp: StreamPages, pool: jax.Array, layer,
-                new: jax.Array) -> Tuple[jax.Array, jax.Array]:
+# ``filter_rows`` calls that lowered to the in-place kernel.  The choice is
+# made while a program is traced, so this counts traces, not executions: the
+# engine reads it around its decode program's trace for the ``decode``
+# span's ``filter_rows_in_place``.
+filter_rows_lowered = {"in_place": 0}
+
+
+def filter_rows(sp: StreamPages, pool: jax.Array, layer, new: jax.Array, *,
+                paged_kernel: bool = False, mesh=None
+                ) -> Tuple[jax.Array, jax.Array]:
     """A short filter's rows through its pages: ``new`` [S, K, C] behind
     the ``held`` rows the stream's page of ``pool [layers, groups, pages,
     *tile]`` holds (zeros for a stream that starts here) -> (``[S, held +
     K, C]`` in ``new``'s dtype, the pool with the rows that end at the
-    last live row — and at a snapshot's row — written to ``sp.to``)."""
-    S = new.shape[0]
-    old = pool[layer, sp.group, sp.page].reshape(S, sp.keep[0].shape[1], -1)
+    last live row — and at a snapshot's row — written to ``sp.to``).
+
+    Which lines a program takes follows from what it hands over.  A DECODE
+    program on the chip (``paged_kernel``, K = 1, one page to write: no
+    snapshot, and a pool whose tile ``ops.filter_rows.takes``) rewrites the
+    pages in place by one kernel a layer, ``ops.filter_rows.shift_rows``:
+    on the chip the gather, select, concatenate, ``take_along_axis`` and
+    scatter below are a dozen passes over the rows (each a relayout between
+    the page's tile and ``[S, held + K, C]``, or a gather), and a decode
+    step does little else per page.  Every other program keeps the lines
+    below: a prefill chunk (K in the hundreds for one stream a group, where
+    the page is a small share of the rows, and a snapshot's second page), a
+    tile the kernel cannot take, the CPU — and the tests, which hold the
+    kernel to
+    these lines bit for bit.  The two differ only where nobody looks: the
+    kernel hands a stream that ``wrote`` nothing zeros for its old rows and
+    skips its write, where these lines read page 0 for it and drop the
+    write by the out-of-range index in ``sp.to``."""
+    S, K, C = new.shape
+    held = sp.keep[0].shape[1]
+    if paged_kernel and K == 1 and len(sp.to) == 1:
+        from ..ops import filter_rows as in_place
+        if in_place.takes(pool.shape, pool.dtype, held, new.dtype):
+            G = pool.shape[1]
+            filter_rows_lowered["in_place"] += 1
+            rows, pool = in_place.shift_rows(
+                pool, layer,
+                group_shape(jnp.where(sp.wrote, sp.page, -1), G),
+                group_shape(sp.carried, G), group_shape(new[:, 0], G),
+                held=held, mesh=mesh)
+            return jnp.moveaxis(rows.reshape(held + 1, S, C), 0, 1), pool
+    old = pool[layer, sp.group, sp.page].reshape(S, held, -1)
     old = jnp.where(sp.carried[:, None, None], old, 0)
     rows = jnp.concatenate([old.astype(new.dtype), new], axis=1)
     for kept, where in zip(sp.keep, sp.to):
@@ -411,4 +458,5 @@ def spec_accept(logits: jax.Array, tokens: jax.Array, key: jax.Array,
 __all__ = ["CacheClass", "ServedModel", "register", "served_model",
            "split_counters", "with_counters", "NEG_INF", "group_shape",
            "write_targets", "StreamPages", "stream_pages", "filter_rows",
+           "filter_rows_lowered",
            "sample_tokens", "spec_accept"]

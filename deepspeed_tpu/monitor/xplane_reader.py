@@ -144,6 +144,11 @@ SPAN_ARGS = {
                # pages of a per-stream pool the state-update kernel
                # rewrote in this execution (one a live stream)
                "state_pages_live",
+               # beside it: 1 where the decode program rewrites those
+               # streams' short-filter rows in place by a kernel
+               # (ops/filter_rows.py), 0 where it gathers and scatters
+               # them (inference/served.filter_rows picks by shape)
+               "filter_rows_in_place",
                # 1 where the dispatch went out while the iteration
                # before was still unfetched; rows the iteration fetched
                # computed for streams that had ended by then

@@ -541,3 +541,31 @@ def test_the_init_gives_every_branch_a_say():
     assert 0.3 < float(np.std(lg)) < 3.0
     drop, _ = ref(prompt, [39], fault="no_ssm")
     assert float(np.abs(lg - drop).max()) > 0.1
+
+
+# --------------------------------------------------------------------- #
+# A decode step rewrites the filter rows in place where the shape allows
+# (PR 53)
+# --------------------------------------------------------------------- #
+def test_decode_rewrites_the_filter_rows_in_place_and_serves_the_same(
+        monkeypatch):
+    """4 state heads of 128 and 2 groups of 128 state dimensions make 1,024
+    filter channels: 8 sublane rows of fp32 a held row, a whole tile, so
+    ``served.filter_rows`` hands the decode program's rows to
+    ``ops.filter_rows.shift_rows`` (interpret mode here).  The same engine
+    traced with the shape rule answering no keeps the plain lines: the
+    tokens, the logits and both pools of the stream's page are equal bit for
+    bit, and the ``decode`` span's arg says which was which.  The PUBLISHED
+    width (5,120 channels of bf16: 40 sublane rows a held row, two and a
+    half tiles) is one the rule leaves on the plain lines."""
+    from deepspeed_tpu.ops import filter_rows as in_place
+    from test_filter_rows import assert_the_same_stream, served_both_ways
+    cfg = tiny(mamba_d_ssm=512, mamba_d_head=128, mamba_d_state=128)
+    assert cfg.conv_dim == 1024 and serving.conv_tile(cfg) == (1, 24, 128)
+    assert not in_place.takes((4, 1, 184, 1, 120, 128), jnp.bfloat16, 3,
+                              jnp.bfloat16)
+    assert_the_same_stream(*served_both_ways(
+        monkeypatch, cfg, seeded(cfg), {"full": 96, "state": 16},
+        prompt_of(3, 11), ("conv.state", "ssm.state")))
+    # the file's own size (96 channels: a [3, 96] tile) keeps the plain lines
+    assert engine("kernels").filter_rows_in_place == 0

@@ -451,3 +451,25 @@ def test_the_references_row_blocks_carry_the_state_and_the_filter_rows(
                                 dict(state_at=33, zero_state_at=30))):
         for a, b in zip(run(**kw), want):
             np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+# --------------------------------------------------------------------- #
+# 7. A decode step rewrites the filter rows in place (PR 53)
+# --------------------------------------------------------------------- #
+def test_decode_rewrites_the_filter_rows_in_place_and_serves_the_same(
+        monkeypatch):
+    """64 KDA heads of 16 make 3,072 filter channels: 24 sublane rows of
+    fp32 a held row, whole tiles, so ``served.filter_rows`` hands the decode
+    program's rows to ``ops.filter_rows.shift_rows`` (interpret mode here).
+    The same engine traced with the shape rule answering no keeps the plain
+    lines: the tokens, the logits and both pools of the stream's page are
+    equal bit for bit, and the ``decode`` span's arg says which was which."""
+    from test_filter_rows import assert_the_same_stream, served_both_ways
+    cfg = tiny(kda_num_heads=64, kda_head_dim=16)
+    assert serving.conv_tile(cfg) == (1, 72, 128)
+    assert_the_same_stream(*served_both_ways(
+        monkeypatch, cfg, seeded(cfg), {"latent": 96, "state": 16},
+        prompt_of(3, 11), ("conv.state", "state.state")))
+    # the file's own size (96 channels: a [3, 96] tile) keeps the plain lines
+    assert serving.conv_tile(CFG) == (1, 3, 96)
+    assert engine("kernels").filter_rows_in_place == 0

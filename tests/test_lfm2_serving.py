@@ -857,3 +857,26 @@ def test_the_four_families_specs_are_what_the_engine_built_before(family):
     alloc = allocator_for(new)
     names = tuple(n for sp in new for n in sp.pool_names)
     assert alloc.copy_pools == names          # a copy runs on every pool
+
+
+# --------------------------------------------------------------------- #
+# A decode step rewrites the conv rows in place (PR 53)
+# --------------------------------------------------------------------- #
+def test_decode_rewrites_the_conv_rows_in_place_and_serves_the_same(
+        monkeypatch):
+    """A hidden size of 1,024 makes a held row 8 sublane rows of fp32, a
+    whole tile, so ``served.filter_rows`` hands the decode program's rows to
+    ``ops.filter_rows.shift_rows`` (interpret mode here).  The same engine
+    traced with the shape rule answering no keeps the plain lines: the
+    tokens, the logits and the stream's conv page are equal bit for bit,
+    and the ``decode`` span's arg says which was which."""
+    from test_filter_rows import assert_the_same_stream, served_both_ways
+    cfg = tiny(hidden_size=1024, num_attention_heads=4, num_hidden_layers=3,
+               layer_types=(CONV, FULL, CONV), intermediate_size=64,
+               moe_intermediate_size=16)
+    assert lfm2_serving.conv_tile(cfg) == (1, 16, 128)
+    assert_the_same_stream(*served_both_ways(
+        monkeypatch, cfg, seeded(cfg), {"full": 96, "conv": 16},
+        prompt_of(3, 11), ("conv.conv",)))
+    # the file's own size (64 channels: a [1, 128] tile) keeps the plain lines
+    assert engine("kernels").filter_rows_in_place == 0

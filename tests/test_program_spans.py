@@ -860,7 +860,8 @@ def test_the_readers_list_names_the_kinds_scopes_and_args():
     assert scope_of("jit(decode_step)/conv/conv_mix/scatter")[0] \
         == ("conv", "conv_mix")
     assert "prefix_lost_to_kind_tokens" in SPAN_ARGS["prefill"]
-    assert "state_pages_live" in SPAN_ARGS["decode"]
+    assert {"state_pages_live", "filter_rows_in_place"} \
+        <= set(SPAN_ARGS["decode"])
     assert set(span_args("decode", ("full", "conv"))) \
         - set(SPAN_ARGS["decode"]) == {
             "full_blocks_live", "conv_blocks_live", "full_blocks_returned",
@@ -893,6 +894,8 @@ def test_decode_and_prefill_spans_carry_both_kinds(tmp_path, kinds_engine):
     for a in dispatched:
         assert a["state_pages_live"] == a["active"] \
             == a["conv_blocks_live"]
+        # (64 channels: a tile the in-place kernel does not take)
+        assert a["filter_rows_in_place"] == 0
         assert a["full_blocks_live"] > a["conv_blocks_live"]
         # one attention layer reads all of a stream; a state has no rows
         assert a["context_tokens_in_reach"] == a["context_tokens"]

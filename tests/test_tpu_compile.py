@@ -15,6 +15,7 @@ fixture (never at import) and compiled in the test's own process.
 """
 import functools
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -1254,6 +1255,10 @@ def test_mixed_kind_serve_step_fits_and_updates_both_kinds_in_place(
         text, one_layers_experts) if op not in ("parameter", "bitcast",
                                                  "get-tuple-element")]
     assert "/conv/conv_mix" in text and "/attn/attend_full" in text
+    # decode: a conv layer's rows go through the pages by one kernel
+    calls = _filter_rows_calls(text)
+    assert len(calls) == (7 if program == "decode_step" else 0)
+    assert all("/conv/conv_mix/" in c for c in calls)
 
 
 def test_the_page_copy_of_a_mixed_model_leaves_the_kv_pools_alone(
@@ -1433,6 +1438,51 @@ def test_both_kinds_in_a_layer_fit_and_are_updated_in_place(
     assert "/attn/attend_full" in text and "/ssm/ssm_conv" in text
     assert ("/ssm/ssm_state_update" if program == "decode_step"
             else "/ssm/ssm_chunk_scan") in text
+    # 5,120 filter channels are 40 sublane rows of bf16 a held row, two and
+    # a half tiles: ``ops.filter_rows.takes`` leaves every program's rows on
+    # the plain lines
+    assert not _filter_rows_calls(text)
+
+
+# ------------------------------------------------------------------ #
+# A decode step's short-filter rows rewritten in place (PR 53)
+# ------------------------------------------------------------------ #
+def _filter_rows_calls(text):
+    """The compiled program's ``_filter_rows_kernel`` custom calls."""
+    calls = [line for line in text.splitlines()
+             if "%_filter_rows_kernel" in line.split(" = ")[0]
+             and " custom-call(" in line]
+    assert all("tpu_custom_call" in c for c in calls)
+    return calls
+
+
+@pytest.mark.parametrize("layers, pages, streams, tile_rows, held", [
+    (6, 480, 256, 288, 3),          # kimi-linear: 96 sublane rows a held row
+    (7, 512, 128, 32, 2),           # lfm2: 16
+])
+def test_filter_rows_kernel_compiles_at_the_published_widths(
+        layers, pages, streams, tile_rows, held, one_chip, as_tpu):
+    """Copies with a slice of the pipelined output block as their VMEM side,
+    the pool in HBM aliased in and out; the shape rule takes both tiles and
+    leaves falcon-h1's (40 rows of bf16 a held row) alone."""
+    from deepspeed_tpu.ops import filter_rows as in_place
+    C = tile_rows * 128 // held
+    shape = (layers, 1, pages, 1, tile_rows, 128)
+    assert in_place.takes(shape, jnp.bfloat16, held, jnp.bfloat16)
+    assert not in_place.takes((4, 1, 184, 1, 120, 128), jnp.bfloat16, 3,
+                              jnp.bfloat16)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(    # noqa: E731
+        shape, dtype, sharding=one_chip)
+    compiled = jax.jit(
+        lambda pool, pages_, carried, new: in_place.shift_rows(
+            pool, 2, pages_, carried, new, held=held),
+        donate_argnums=0).lower(
+        sds(shape, jnp.bfloat16), sds((1, streams), jnp.int32),
+        sds((1, streams), jnp.bool_),
+        sds((1, streams, C), jnp.bfloat16)).compile()
+    assert len(_filter_rows_calls(compiled.as_text())) == 1
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= math.prod(shape) * 2
 
 
 # ------------------------------------------------------------------ #
@@ -1606,3 +1656,11 @@ def test_latent_beside_state_fit_and_are_updated_in_place(
     assert "/attn/attend" in text and "/attn/kda_conv" in text
     assert ("/attn/kda_update" if program == "decode_step"
             else "/attn/kda_chunk") in text
+    # decode: a KDA layer's filter rows go through the pages by one kernel,
+    # and nothing gathers or scatters them; a chunk keeps the plain lines
+    calls = _filter_rows_calls(text)
+    assert len(calls) == (6 if program == "decode_step" else 0)
+    assert all("/attn/kda_conv/" in c for c in calls)
+    if program == "decode_step":
+        assert not [line for line in text.splitlines() if "/kda_conv/" in line
+                    and re.search(r" (gather|scatter)\(", line)]
