@@ -96,31 +96,26 @@ def _gather_attend(q, pool_k, pool_v, layer, bt, pos, reach, scale):
     return out.reshape(G, Q, K, nH, D).astype(q.dtype)
 
 
-def _forward(params, pools, x, bt_g, pos_g, live, cfg: AfmoeConfig,
-             widths, paged_kernel: bool, mesh):
-    """All layers: x [S, K, H] with its streams' table rows bt_g [G, Sg,
-    W] (the classes' rows side by side, ``widths`` wide), row positions
-    pos_g [G, Sg, K] and ``live`` [S, K]: the rows that are traffic (a
-    live stream's, and no padding).  The others write no cache row, attend
-    nothing, get no expert row and are not counted; what they compute
-    nobody reads.  ``pools``: (k, v) of every class in ``cache_classes``
-    order.  Returns (x', pools', counters)."""
+def paged_classes(classes, widths, pools, bt_g, pos_g, live_g, *,
+                  head_dim: int, group: int, paged_kernel: bool, mesh):
+    """The attention branch's tables, write targets and plans, ONCE for
+    all layers of each class of K/V pages: ``classes`` (``CacheClass``es
+    in ``cache_classes`` order, each with a (k, v) pair in ``pools``) whose
+    table rows lie side by side in bt_g [G, Sg, W], ``widths`` wide; row
+    positions pos_g [G, Sg, K]; ``live_g`` [G, Sg, K] (a dead row writes
+    nothing and attends nothing).  A chunk's rows go in runs
+    (``_attend_rows``), each a stream of the attend with the chunk's table.
+    Returns {class name: what ``write_and_attend`` takes}."""
     G, Sg, K = pos_g.shape
-    S, H = G * Sg, x.shape[-1]
-    nH, D, grp = cfg.num_attention_heads, cfg.head_dim, cfg.group
-    pos = pos_g.reshape(S, K)
-    live_g = live.reshape(G, Sg, K)
     seen = jnp.where(live_g, pos_g, -1)        # a dead row attends nothing
-    rows = _attend_rows(K, grp)
+    rows = _attend_rows(K, group)
     runs = K // rows
-
-    classes, at = {}, 0
-    pools = list(pools)
-    for i, (cls, width) in enumerate(zip(_classes(cfg), widths)):
+    out, at = {}, 0
+    for i, (cls, width) in enumerate(zip(classes, widths)):
         bt = bt_g[:, :, at:at + width]
         at += width
         kc = pools[2 * i]
-        bs = kv_cache.paged_block_size(kc, D)
+        bs = kv_cache.paged_block_size(kc, head_dim)
         table = jnp.broadcast_to(bt[:, :, None, :], (G, Sg, K, width))
         blk, off = kv_cache.positions_to_blocks(
             table, pos_g, bs, ring=cls.reach is not None)
@@ -135,45 +130,75 @@ def _forward(params, pools, x, bt_g, pos_g, live, cfg: AfmoeConfig,
             with jax.named_scope("attn"), \
                     jax.named_scope("attend_" + cls.name):
                 plan = paged_attn_ops.attend_plan(
-                    bt_runs, seen_runs, kc, D, mesh=mesh, reach=cls.reach,
-                    group=grp)
-        classes[cls.name] = dict(
-            at=2 * i, reach=cls.reach, plan=plan, bt=bt_runs,
-            seen=seen_runs, blk=blk.reshape(G, Sg * K),
-            off=off.reshape(G, Sg * K), layer=0)
+                    bt_runs, seen_runs, kc, head_dim, mesh=mesh,
+                    reach=cls.reach, group=group)
+        out[cls.name] = dict(
+            name=cls.name, at=2 * i, reach=cls.reach, plan=plan,
+            bt=bt_runs, seen=seen_runs, blk=blk.reshape(G, Sg * K),
+            off=off.reshape(G, Sg * K), rows=rows, runs=runs, layer=0)
+    return out
+
+
+def write_and_attend(c, pools, q, k, v, *, scale: float, mesh):
+    """The next layer of class ``c`` (one of ``paged_classes``'): its new
+    K/V rows written in place into ``pools`` (a list; scope ``kv_write``),
+    then the attend of q [S, K, nH, D] over the class's pages (scope
+    ``attend_<class>``: the kernel under its plan, or the gather).
+    Returns the attended rows [S, K, nH * D]."""
+    S, K, nH, D = q.shape
+    G = c["blk"].shape[0]
+    Sg = S // G
+    kc, vc = pools[c["at"]], pools[c["at"] + 1]
+    layer = c["layer"]
+    c["layer"] += 1
+    with jax.named_scope("kv_write"):
+        kc, vc = kv_cache.paged_write_rows(
+            kc, vc, k.reshape((G, Sg * K) + k.shape[2:]),
+            v.reshape((G, Sg * K) + v.shape[2:]), layer,
+            c["blk"], c["off"], mesh=mesh)
+    with jax.named_scope("attend_" + c["name"]):
+        qr = q.reshape(G, Sg * c["runs"], c["rows"], nH, D)
+        if c["plan"] is not None:
+            a = paged_attn_ops.paged_attention(
+                qr, kc, vc, layer, plan=c["plan"], scale=scale, mesh=mesh)
+        else:
+            a = _gather_attend(qr, kc, vc, layer, c["bt"], c["seen"],
+                               c["reach"], scale)
+    pools[c["at"]], pools[c["at"] + 1] = kc, vc
+    return a.reshape(S, K, nH * D)
+
+
+def _forward(params, pools, x, bt_g, pos_g, live, cfg: AfmoeConfig,
+             widths, paged_kernel: bool, mesh):
+    """All layers: x [S, K, H] with its streams' table rows bt_g [G, Sg,
+    W] (the classes' rows side by side, ``widths`` wide), row positions
+    pos_g [G, Sg, K] and ``live`` [S, K]: the rows that are traffic (a
+    live stream's, and no padding).  The others write no cache row, attend
+    nothing, get no expert row and are not counted; what they compute
+    nobody reads.  ``pools``: (k, v) of every class in ``cache_classes``
+    order.  Returns (x', pools', counters)."""
+    G, Sg, K = pos_g.shape
+    S, H = G * Sg, x.shape[-1]
+    pos = pos_g.reshape(S, K)
+    pools = list(pools)
+    classes = paged_classes(
+        _classes(cfg), widths, pools, bt_g, pos_g, live.reshape(G, Sg, K),
+        head_dim=cfg.head_dim, group=cfg.group, paged_kernel=paged_kernel,
+        mesh=mesh)
 
     def attention(p, x, sliding: bool):
         c = classes[WINDOW_CLASS if sliding else FULL_CLASS]
-        kc, vc = pools[c["at"]], pools[c["at"] + 1]
-        layer = c["layer"]
-        c["layer"] += 1
         with jax.named_scope("attn"):
             with jax.named_scope("qkv_proj"):
                 h = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
                 q, k, v, gate = afmoe.qkvg(p, h, pos, cfg, sliding)
-            with jax.named_scope("kv_write"):
-                kc, vc = kv_cache.paged_write_rows(
-                    kc, vc, k.reshape((G, Sg * K) + k.shape[2:]),
-                    v.reshape((G, Sg * K) + v.shape[2:]), layer,
-                    c["blk"], c["off"], mesh=mesh)
-            with jax.named_scope("attend_window" if sliding
-                                 else "attend_full"):
-                qr = q.reshape(G, Sg * runs, rows, nH, D)
-                if c["plan"] is not None:
-                    a = paged_attn_ops.paged_attention(
-                        qr, kc, vc, layer, plan=c["plan"],
-                        scale=cfg.softmax_scale, mesh=mesh)
-                else:
-                    a = _gather_attend(qr, kc, vc, layer, c["bt"],
-                                       c["seen"], c["reach"],
-                                       cfg.softmax_scale)
+            a = write_and_attend(c, pools, q, k, v,
+                                 scale=cfg.softmax_scale, mesh=mesh)
             with jax.named_scope("out_proj"):
-                a = a.reshape(S, K, nH * D)
                 a = (a.astype(jnp.float32) * jax.nn.sigmoid(
                     gate.astype(jnp.float32))).astype(x.dtype)
                 x = x + rms_norm(matmul(a, p["wo"]), p["post_attn_norm"],
                                  cfg.rms_norm_eps)
-        pools[c["at"]], pools[c["at"] + 1] = kc, vc
         return x
 
     row_live = live.reshape(S * K)
@@ -280,9 +305,15 @@ class GqaPagedServed(ServedModel):
 
 
 class AfmoeServed(GqaPagedServed):
-    """See the module docstring."""
+    """See the module docstring.  The three programs are written over
+    ``_embed`` / ``_forward`` / ``_head``: a family of the same two classes
+    and counters whose BLOCK differs (``inference/smallthinker.py``) names
+    its own."""
     counter_names = ("moe_held_pairs", "moe_held_max", "moe_held_empty",
                      "moe_rows")
+    _embed = staticmethod(_embed)
+    _forward = staticmethod(_forward)
+    _head = staticmethod(_head)
 
     @property
     def init_fn(self) -> Callable:
@@ -317,12 +348,12 @@ class AfmoeServed(GqaPagedServed):
         pos = lengths[:, None] + jnp.arange(K, dtype=jnp.int32)[None]
         live = jnp.broadcast_to(
             (block_tables >= 0).any(axis=1, keepdims=True), tokens.shape)
-        x, pools, counters = _forward(
-            params, pools, _embed(params, tokens, cfg),
+        x, pools, counters = self._forward(
+            params, pools, self._embed(params, tokens, cfg),
             group_shape(block_tables, num_groups),
             group_shape(pos, num_groups), live, cfg,
             self._widths(block_tables), paged_kernel, mesh)
-        return _head(params, x, cfg), pools, counters
+        return self._head(params, x, cfg), pools, counters
 
     def decode(self, params, pools, tokens, lengths, block_tables, *,
                num_groups, paged_kernel, mesh=None):
@@ -344,16 +375,17 @@ class AfmoeServed(GqaPagedServed):
                          kv_cache.DEAD_BLOCK)
         live = (active[:, None] > 0) & (lax.broadcasted_iota(
             jnp.int32, (G, Cn), 1) <= last_idx[:, None])
-        x, pools, counters = _forward(
-            params, pools, _embed(params, tokens, cfg), bt_g,
+        x, pools, counters = self._forward(
+            params, pools, self._embed(params, tokens, cfg), bt_g,
             pos[:, None, :], live, cfg, self._widths(bt_rows), paged_kernel,
             mesh)
         oh = (lax.broadcasted_iota(jnp.int32, (G, Cn), 1)
               == last_idx[:, None]).astype(x.dtype)
         h_last = jnp.einsum("gc,gch->gh", oh, x)
-        return _head(params, h_last, cfg), pools, counters
+        return self._head(params, h_last, cfg), pools, counters
 
 
 register(AfmoeConfig, AfmoeServed)
 
-__all__ = ["GqaPagedServed", "AfmoeServed", "FULL_CLASS", "WINDOW_CLASS"]
+__all__ = ["GqaPagedServed", "AfmoeServed", "FULL_CLASS", "WINDOW_CLASS",
+           "paged_classes", "write_and_attend"]

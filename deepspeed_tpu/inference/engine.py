@@ -796,8 +796,13 @@ class InferenceEngine:
         ``rids`` — the scheduler's request ids, so a request can be
         followed through a trace — and, once planned, ``cached_tokens``,
         ``chunks``, ``rows_computed``: the ``[G, width]`` rows of every
-        chunk program, each as wide as it was dispatched) >
-        ``prefill_plan`` (allocator admission + the copy-on-write fork),
+        chunk program, each as wide as it was dispatched; of a model with
+        NAMED classes of cache layers also ``cached_tokens_<class>``,
+        ``<class>_blocks_returned`` for a class with a reach — blocks its
+        streams gave back WHILE these admissions' chunks were dispatched,
+        the window sliding during prefill; on the ``decode`` span the same
+        name is the running total — and ``context_tokens_in_reach_<class>``)
+        > ``prefill_plan`` (allocator admission + the copy-on-write fork),
         one ``prefill_chunk`` (``ci``, ``active_groups``, ``rows``: the
         width) per chunk program dispatched, and ``prefill_fetch`` (the
         first tokens' ``device_get``)."""
@@ -808,6 +813,7 @@ class InferenceEngine:
         with tl.span("prefill", slots=len(admissions),
                      prompt_tokens=sum(len(p) for _, p, _ in admissions),
                      rids=ids_arg(rids)) as span:
+            returned0 = self._bounded_returned()
             with tl.span("prefill_plan"):
                 pools, plans, tails = self._plan_prefill(admissions)
             try:
@@ -848,9 +854,17 @@ class InferenceEngine:
             by_class: Dict[str, int] = {}
             for p in plans:
                 for name, n in (p[2].cached_by_class or {}).items():
-                    by_class["cached_tokens_" + name] = \
-                        by_class.get("cached_tokens_" + name, 0) + n
-            span.set_metadata(**by_class)
+                    by_class[name] = by_class.get(name, 0) + n
+            span.set_metadata(**{"cached_tokens_" + name: n
+                                 for name, n in by_class.items()})
+            if returned0 is not None:
+                returned = {name: n - returned0[name] for name, n
+                            in self._bounded_returned().items()}
+                span.set_metadata(
+                    **{name + "_blocks_returned": n
+                       for name, n in returned.items()},
+                    **self._chunk_reach_args(tails))
+                self.serving.note_admit_classes(returned, by_class)
         wall = self.serving.note_prefill_pass(
             len(steps), sum(p[4] for p in plans) - cached, computed,
             widths) - t_pf0 - waited
@@ -858,6 +872,35 @@ class InferenceEngine:
         if self.serving.ledger is not None:
             self.serving.ledger.note("prefill", wall)
         return out
+
+    def _bounded_returned(self) -> Optional[Dict[str, int]]:
+        """{class: blocks its streams returned so far} over a model's
+        NAMED classes that have a reach (None for a model without named
+        classes: its ``prefill`` span gets no class args)."""
+        stats = self.allocator.class_stats()
+        if not stats:
+            return None
+        return {name: st["returned"] for name, st in stats.items()
+                if st["reach"] is not None}
+
+    def _chunk_reach_args(self, tails) -> Dict[str, int]:
+        """The ``prefill`` span's ``context_tokens_in_reach_<class>``: key
+        rows the chunk programs of these admissions may read, a class of
+        pages at a time, summed over its layers — a chunk of ``n`` rows
+        that starts at ``first`` reads ``first + n`` rows back at most, a
+        bounded class no more than ``reach + n - 1`` of them."""
+        args = {}
+        for sp in self._attend_specs:
+            rows = 0
+            for chunks, _ in tails:
+                for first, n in chunks:
+                    seen = first + n
+                    if sp.reach is not None:
+                        seen = min(seen, sp.reach + n - 1)
+                    rows += seen
+            args["context_tokens_in_reach_" + sp.name] = \
+                rows * sp.num_layers
+        return args
 
     def _await_decode(self) -> float:
         """An admission's programs queue on the device behind the decode

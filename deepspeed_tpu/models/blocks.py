@@ -15,13 +15,20 @@ import numpy as np
 
 
 class Routing(NamedTuple):
-    """An expert layer's rule (sigmoid scores with a selection bias,
-    ``moe/share.py``) in numbers: ``experts`` routed experts in ``n_group``
-    groups of which the ``topk_group`` best stay (1 / 1: no group limit),
-    ``per_tok`` chosen a token, the weights divided by their sum where
-    ``norm`` (their sum + ``norm_eps``: 1e-20 as ``deepseek_v3`` and
-    ``afmoe`` publish it, 1e-6 ``lfm2_moe``), times ``scale``; and ``held``
-    = (first, count), the share this program holds."""
+    """An expert layer's rule (``moe/share.py``) in numbers: ``experts``
+    routed experts of which ``per_tok`` are chosen a token, by ``rule``:
+
+    - ``"sigmoid_bias"`` (``deepseek_v3``'s ``noaux_tc``; ``afmoe``,
+      ``lfm2_moe``): sigmoid scores with a selection bias, in ``n_group``
+      groups of which the ``topk_group`` best stay (1 / 1: no group limit);
+      the weights are the scores at the chosen;
+    - ``"softmax_topk"`` (``smallthinker``): the ``per_tok`` largest LOGITS,
+      the weights a softmax over those; no bias, no groups.
+
+    Either way the weights are divided by their sum where ``norm`` (their
+    sum + ``norm_eps``: 1e-20 as ``deepseek_v3`` and ``afmoe`` publish it,
+    1e-6 ``lfm2_moe``, 0 ``smallthinker``), times ``scale``; and ``held`` =
+    (first, count), the share this program holds."""
     experts: int
     per_tok: int
     n_group: int
@@ -30,6 +37,7 @@ class Routing(NamedTuple):
     scale: float
     held: Tuple[int, int]
     norm_eps: float = 1e-20
+    rule: str = "sigmoid_bias"
 
 
 def rotary_cos_sin(inv_freq, positions: jax.Array, scale: float = 1.0):

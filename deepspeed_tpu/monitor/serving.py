@@ -174,6 +174,7 @@ class ServingAggregator:
         self._model_counters: Dict[str, Any] = {}   # name -> (sum, n)
         self._classes: Dict[str, Dict[str, int]] = {}   # cache classes'
         self._state: Dict[str, int] = {}    # a per-stream state pool's
+        self._admit_classes: Dict[str, int] = {}    # admissions, by class
         # Analytic attend-work accounting (engine-fed): the same
         # iterations priced BOTH ways — the Pallas kernel's live-context
         # term vs the one-hot contraction's pool-capacity term. ``attend_mode`` names which one actually
@@ -393,6 +394,21 @@ class ServingAggregator:
             if name in admitted:
                 st[name] = st.get(name, 0) + int(admitted[name])
         st.update(totals)
+
+    def note_admit_classes(self, returned: Dict[str, int],
+                           cached: Dict[str, int]) -> None:
+        """One admission batch into a model of named classes of cache
+        layers: blocks each bounded class gave back while the batch's chunk
+        programs were dispatched (its window slid DURING prefill:
+        ``prefill_<class>_blocks_returned``) and the prompt tokens each
+        class had cached (``cached_tokens_<class>``), summed over the run."""
+        tot = self._admit_classes
+        for name, n in returned.items():
+            key = f"prefill_{name}_blocks_returned"
+            tot[key] = tot.get(key, 0) + int(n)
+        for name, n in cached.items():
+            key = f"cached_tokens_{name}"
+            tot[key] = tot.get(key, 0) + int(n)
 
     def note_cache_classes(self, stats: Dict[str, Dict[str, int]]) -> None:
         """A model's classes of cache layers, by name: blocks, blocks in
@@ -631,6 +647,7 @@ class ServingAggregator:
         if self._classes:
             snap["cache_classes"] = {n: dict(st)
                                      for n, st in self._classes.items()}
+        snap.update(self._admit_classes)
         if self._model_counters:
             snap["model_counters"] = {
                 name: round(tot / n, 4)
@@ -677,6 +694,8 @@ class ServingAggregator:
             out.completed += a.completed
             out.prompt_tokens_admitted += a.prompt_tokens_admitted
             out.cached_tokens_admitted += a.cached_tokens_admitted
+            for key, n in a._admit_classes.items():
+                out._admit_classes[key] = out._admit_classes.get(key, 0) + n
             out.spec_proposed += a.spec_proposed
             out.spec_accepted += a.spec_accepted
             out.attend_flops_kernel += a.attend_flops_kernel

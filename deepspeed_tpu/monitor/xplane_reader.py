@@ -119,10 +119,15 @@ SPAN_ARGS = {
     # admission dispatched, each at the width it took (the narrowest of
     # the engine's prefill_widths that held its rows: prefill_chunk's
     # "rows"); prompt_tokens - cached_tokens of them were needed.
+    # rows: a model whose every layer routes (inference/smallthinker.py):
+    # the live rows the fetched execution(s) routed (a decode iteration's
+    # live streams; on a prefill span the rows that are traffic in the
+    # chunk program that ENDED the prompt, whose fetch the counters ride:
+    # an earlier chunk's rows are all live).
     "prefill": ("slots", "prompt_tokens", "rids", "cached_tokens",
                 "chunks", "rows_computed", "moe_held_pairs", "moe_held_max",
                 "moe_held_mean", "moe_held_empty", "moe_held_pair_share",
-                "hc_res_err_max",
+                "rows", "hc_res_err_max",
                 "resumed_tokens", "snapshot_taken", "snapshot_in_program",
                 "state_copy_bytes",
                 "prefix_lost_to_kind_tokens"),
@@ -140,7 +145,8 @@ SPAN_ARGS = {
     "decode": ("iteration", "active", "live_blocks", "context_tokens",
                "attend_steps", "attend_live_steps", "attend_cold_steps",
                "moe_held_pairs", "moe_held_max", "moe_held_mean",
-               "moe_held_empty", "moe_held_pair_share", "hc_res_err_max",
+               "moe_held_empty", "moe_held_pair_share", "rows",
+               "hc_res_err_max",
                # pages of a per-stream pool the state-update kernel
                # rewrote in this execution (one a live stream)
                "state_pages_live",
@@ -171,9 +177,16 @@ SPAN_ARGS = {
 # no model's): of a prefill's cached_tokens what each class took from ITS
 # cache (an unbounded class all of them, a window class its reach's worth);
 # each class's blocks in use and the blocks its live streams have returned
-# so far as their window slid (the allocator's running total).
+# so far as their window slid (the allocator's running total).  On a
+# ``prefill`` span ``<class>_blocks_returned`` (a class with a reach only)
+# is what the class gave back WHILE the span's admissions were dispatched —
+# its window sliding during prefill — and ``context_tokens_in_reach_<class>``
+# the key rows those chunk programs may read in the class, over its layers
+# (a chunk of n rows from position p: p + n of them, a bounded class no
+# more than reach + n - 1).
 CLASS_SPAN_ARGS = {
-    "prefill": ("cached_tokens_<class>",),
+    "prefill": ("cached_tokens_<class>", "<class>_blocks_returned",
+                "context_tokens_in_reach_<class>"),
     "decode": ("<class>_blocks_live", "<class>_blocks_returned"),
 }
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")

@@ -28,6 +28,15 @@ Structure:
   the moe lint flagship's materialization pass clean), and expresses
   every gradient contraction as the SAME grouped kernel on swapped
   axes.
+- ``grouped_swiglu(xs, w_gate, w_up, w_down, tile_expert, n_live_tiles,
+  tm=, act=)`` — the serving tier's DROPLESS gated FFN (no autodiff, no
+  capacity): rows grouped by expert in tiles of ``tm``, each tile against
+  its expert's ``[F, H]`` matrices, ``down(act(gate x) * up x)`` with the
+  gate's activation static — ``"silu"`` (``_gswiglu_kernel``:
+  ``deepseek_v3``, ``afmoe``, ``lfm2_moe``, ``kimi_linear``) or ``"relu"``
+  (``_greglu_kernel``: ``smallthinker``); one grid, one tile rule, two
+  kernel names so that a trace tells them apart.  ``moe/share.py`` lays
+  the rows out and is its only caller.
 
 Numerics contract (tests/test_moe.py): vs the einsum path, fp32 agrees
 to a few f32 ulp (cross-program dot association — the PR-1 tolerance
@@ -266,16 +275,17 @@ grouped_ffn.defvjp(_gff_fwd, _gff_bwd)
 
 
 # --------------------------------------------------------------------- #
-# Dropless grouped gated-SiLU FFN (serving: an expert layer's held share)
+# Dropless grouped gated FFN, SiLU or ReLU gate (serving: an expert layer's
+# held share)
 # --------------------------------------------------------------------- #
 _SWIGLU_VMEM_LIMIT = 100 * 2 ** 20
 
 
-def _gswiglu_kernel(te_ref, nl_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref,
-                    acc_ref):
+def _gated_step(gate_act, te_ref, nl_ref, x_ref, wg_ref, wu_ref, wd_ref,
+                o_ref, acc_ref):
     """One grid step = one (row tile, F tile): the tile's rows all belong
-    to expert ``te_ref[i]``; ``acc += (silu(x Wg^T) * (x Wu^T)) Wd`` over
-    the F tiles, written out at the last.  A tile past the live count
+    to expert ``te_ref[i]``; ``acc += (gate_act(x Wg^T) * (x Wu^T)) Wd``
+    over the F tiles, written out at the last.  A tile past the live count
     (``nl_ref[0]``) computes nothing and emits zeros."""
     i, j = pl.program_id(0), pl.program_id(1)
     last = pl.num_programs(1) - 1
@@ -289,7 +299,7 @@ def _gswiglu_kernel(te_ref, nl_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref,
                                 preferred_element_type=jnp.float32)
         u = jax.lax.dot_general(x, wu_ref[0], nt,
                                 preferred_element_type=jnp.float32)
-        h = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
+        h = (gate_act(g) * u).astype(x.dtype)
         part = jnp.dot(h, wd_ref[0], preferred_element_type=jnp.float32)
 
         @pl.when(j == 0)
@@ -309,6 +319,21 @@ def _gswiglu_kernel(te_ref, nl_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref,
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
+def _gswiglu_kernel(*refs):
+    """``_gated_step`` with a SiLU gate: ``silu(x Wg^T) * (x Wu^T)``."""
+    _gated_step(lambda g: g * jax.nn.sigmoid(g), *refs)
+
+
+def _greglu_kernel(*refs):
+    """``_gated_step`` with a ReLU gate: ``relu(x Wg^T) * (x Wu^T)``
+    (``smallthinker``'s experts).  Same grid, specs and tile rule; a name of
+    its own, so that a trace tells the two products apart."""
+    _gated_step(lambda g: jnp.maximum(g, 0.0), *refs)
+
+
+_GATED_KERNELS = {"silu": _gswiglu_kernel, "relu": _greglu_kernel}
+
+
 def _swiglu_f_tile(F: int, H: int, itemsize: int) -> int:
     """The widest F tile (a multiple of 128 that divides F, or F) whose
     three weight blocks, double-buffered, stay under 48 MiB."""
@@ -320,8 +345,9 @@ def _swiglu_f_tile(F: int, H: int, itemsize: int) -> int:
 
 
 def grouped_swiglu(xs, w_gate, w_up, w_down, tile_expert, n_live_tiles, *,
-                   tm: int):
-    """``out[r] = down_e(silu(gate_e xs[r]) * up_e xs[r])`` for rows
+                   tm: int, act: str = "silu"):
+    """``out[r] = down_e(act(gate_e xs[r]) * up_e xs[r])`` (``act``: the
+    gate's activation, ``"silu"`` or ``"relu"``, static) for rows
     grouped by expert: ``xs [M, H]`` whose row tile ``t`` (``tm`` rows)
     belongs to expert ``tile_expert[t]``; only the first
     ``n_live_tiles`` tiles hold rows (the rest emit zeros and move no
@@ -350,8 +376,9 @@ def grouped_swiglu(xs, w_gate, w_up, w_down, tile_expert, n_live_tiles, *,
                 jnp.where(i < nl_p[0], j, nf - 1), 0)
 
     w_spec = pl.BlockSpec((1, tf, H), w_map)
+    kernel = _GATED_KERNELS[act]
     return pl.pallas_call(
-        _gswiglu_kernel,
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(nt, nf),
             in_specs=[pl.BlockSpec((tm, H), x_map), w_spec, w_spec, w_spec],
@@ -361,7 +388,7 @@ def grouped_swiglu(xs, w_gate, w_up, w_down, tile_expert, n_live_tiles, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_SWIGLU_VMEM_LIMIT),
-        name="_gswiglu_kernel",
+        name=kernel.__name__,
         interpret=_interpret(),
     )(te, nl, xs, w_gate, w_up, w_down)
 

@@ -1664,3 +1664,201 @@ def test_latent_beside_state_fit_and_are_updated_in_place(
     if program == "decode_step":
         assert not [line for line in text.splitlines() if "/kda_conv/" in line
                     and re.search(r" (gather|scatter)\(", line)]
+
+
+# ------------------------------------------------------------------ #
+# The smallthinker family (PR 54): the ENGINE's programs over two classes
+# of K/V pages at 7 query heads a K/V head, every layer an expert layer
+# routed ahead of its attention, ReLU-gated experts of 768, at the
+# benchmark cell's shape
+# ------------------------------------------------------------------ #
+@pytest.mark.parametrize("cls,blocks,layers,J,reach", [
+    ("full", 10240, 2, 256, None), ("window", 4864, 6, 73, 4096)])
+@pytest.mark.parametrize("K,Q,tiles", [(1, 64, (4, 16)), (64, 8, (4, 2))],
+                         ids=["decode_64_streams", "prefill_run_512_rows"])
+def test_paged_attention_at_the_paste_cells_shapes(K, Q, tiles, cls, blocks,
+                                                   layers, J, reach,
+                                                   one_chip, as_tpu):
+    """The attend ALONE at `serve.smallthinker-21b-a3b.paste-over`'s shapes
+    (4 K/V heads of 128 under 28 query heads: SEVEN query rows a K/V head
+    in decode, odd against the sublane count; 448 a prefill run of 64;
+    blocks of 64, bf16; the full class's 10,240 blocks x 2 layers behind a
+    table of 256 and the window class's 4,864 x 6 behind a ring of 73):
+    scoped VMEM asked and kept at the default 16 MiB, and nothing but
+    parameters, bitcasts and the kernel holds a pool or a layer of it."""
+    from deepspeed_tpu.analysis.hlo_text import ops_in_units_of
+    from deepspeed_tpu.inference.afmoe import _attend_rows
+    from deepspeed_tpu.ops import paged_attention as pa
+    nKV, grp, Dh, bs = 4, 7, 128, 64
+    assert _attend_rows(512, grp) == 64 and _attend_rows(1, grp) == 1
+    assert pa._tile_rule(grp * K, nKV, Dh, bs, J, 2, 2) == tiles
+    assert pa._step_vmem_bytes(*tiles, grp * K, Dh, bs, 2, 2) \
+        <= pa._VMEM_BUDGET < pa._VMEM_LIMIT <= 16 * 2 ** 20
+    shape = (layers, 1, blocks, nKV, bs, Dh)
+    pool = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    args = [jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+            for sh, dt in (((1, Q, K, nKV * grp, Dh), jnp.bfloat16),
+                           ((), jnp.int32), ((1, Q, J), jnp.int32),
+                           ((1, Q, K), jnp.int32))]
+
+    def attend(q, pk, pv, layer, bt, pos):
+        plan = pa.attend_plan(bt, pos, pk, Dh, reach=reach, group=grp)
+        return pa.paged_attention(q, pk, pv, layer, plan=plan,
+                                  scale=Dh ** -0.5)
+    compiled = jax.jit(attend).lower(args[0], pool, pool,
+                                     *args[1:]).compile()
+    text = compiled.as_text()
+    seen = ops_in_units_of(text, math.prod(shape[2:]))
+    assert {op for op, _ in seen} <= {"parameter", "bitcast",
+                                      "custom-call"}, seen
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 20
+    calls = [line for line in text.splitlines()
+             if "%_pattn_kernel" in line.split(" = ")[0]
+             and " custom-call(" in line]
+    assert len(calls) == 1 and "tpu_custom_call" in calls[0]
+    assert f'"size":"{pa._VMEM_LIMIT}"' in calls[0]
+
+
+@pytest.mark.parametrize("tokens", [64, 512])
+def test_grouped_reglu_compiles_at_the_published_widths(tokens, one_chip,
+                                                        as_tpu):
+    """64 experts of [768, 2560]: one F tile (6 x 768 x 2560 x 2 B = 23.6 MB
+    of the 48 MiB rule); a decode iteration's 6 rows an expert in tiles of
+    16, a chunk's 48 in tiles of 128; the worst routing's row buffer."""
+    from deepspeed_tpu.models.smallthinker import SmallthinkerConfig
+    from deepspeed_tpu.moe import share
+    from deepspeed_tpu.ops import grouped_gemm as gg
+    cfg = SmallthinkerConfig()
+    assert gg._swiglu_f_tile(768, 2560, 2) == 768
+    tm = share._row_tile(tokens, cfg.routing)
+    assert tm == (16 if tokens == 64 else 128)
+    M = -(-tokens * 6 // tm) * tm + 64 * tm          # the worst routing
+    w = _sds((64, 768, 2560), jnp.bfloat16)
+    text = _compile(lambda xs, wg, wu, wd, te, nl: gg.grouped_swiglu(
+        xs, wg, wu, wd, te, nl, tm=tm, act="relu"), one_chip,
+        _sds((M, 2560), jnp.bfloat16), w, w, w, _sds((M // tm,), jnp.int32),
+        _sds((), jnp.int32))
+    assert "_greglu_kernel" in text and "_gswiglu_kernel" not in text
+
+
+@pytest.fixture(scope="module")
+def paste_cell_programs(topo):
+    """(specs, params bytes, {program: compiled}) of the engine's own step
+    builders for ``perfbench/configs/smallthinker-21b-a3b.json`` on an
+    engine shell (see ``_serve_program``): ``decode_step`` and
+    ``prefill_step`` at every width of ``prefill_widths``."""
+    import json
+    import os
+    from deepspeed_tpu.inference import kv_cache
+    from deepspeed_tpu.inference.engine import (InferenceEngine,
+                                                prefill_widths)
+    from deepspeed_tpu.inference.served import served_model
+    from deepspeed_tpu.models.smallthinker import (SmallthinkerConfig,
+                                                   smallthinker_init)
+    from jax.experimental.compilation_cache import compilation_cache
+    sizes = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "perfbench", "configs", "smallthinker-21b-a3b.json")))
+    inf = sizes["serve"]["inference"]
+    cfg = SmallthinkerConfig.from_hf(sizes)
+    served = served_model(cfg)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+            tree)
+    params = on_chip(jax.eval_shape(lambda k: smallthinker_init(k, cfg),
+                                    jax.random.PRNGKey(0)))
+    param_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(params))
+    specs = kv_cache.class_specs(
+        served.cache_classes, inf["num_blocks"], rows=inf["prefill_chunk"],
+        of_class=lambda c: served.class_geometry(c, inf["block_size"]),
+        num_slots=inf["max_slots"], block_size=inf["block_size"],
+        max_len=inf["max_seq_len"], num_groups=1, dtype=jnp.bfloat16)
+    served.table_widths = tuple(sp.max_blocks_per_slot for sp in specs)
+    eng = object.__new__(InferenceEngine)
+    eng.model_cfg, eng.dp, eng.sp, eng.mesh = served, 1, 1, None
+    eng.paged_kernel, eng.quantize = True, "none"
+    eng.prefill_chunk = inf["prefill_chunk"]
+    eng._cache_sh = {n: one for sp in specs for n in sp.pool_names}
+    pools = [on_chip(jax.ShapeDtypeStruct(sp.pool_shapes[n], sp.dtype))
+             for sp in specs for n in sp.pool_names]
+    S, J = inf["max_slots"], sum(served.table_widths)
+    i32 = lambda *shape: on_chip(jax.ShapeDtypeStruct(shape, jnp.int32))
+    fresh = lambda n: on_chip(jax.ShapeDtypeStruct((n,), jnp.bool_))
+    key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
+    temp = on_chip(jax.ShapeDtypeStruct((), jnp.float32))
+    mp = pytest.MonkeyPatch()
+    prev = jax.config.jax_enable_compilation_cache
+    out = {}
+    try:
+        mp.setattr(jax, "default_backend", lambda *a, **k: "tpu")
+        mp.setenv("DS_AUTOTUNE", "0")
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        out["decode_step"] = eng._build_decode_step().lower(
+            params, *pools, i32(S + len(served.counter_names)), i32(S),
+            fresh(S), i32(S), i32(S, J), key, temp).compile()
+        for C in prefill_widths(inf["prefill_chunk"], inf["block_size"]):
+            out[f"prefill_step.{C}"] = eng._build_prefill_step().lower(
+                params, *pools, i32(1, C), i32(1, J), i32(1), i32(1), i32(1),
+                key, temp).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+        mp.undo()
+    return specs, param_bytes, out
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_step.256",
+                                     "prefill_step.512"])
+def test_paste_cell_serve_step_fits_and_updates_both_classes_in_place(
+        paste_cell_programs, program):
+    """Weights 7.93 GB (64 of 64 experts in all 8 layers, 151,936 vocabulary
+    rows, untied head) + K/V pools 6.51 GB (full: 10,240 blocks x 2 layers
+    behind a table of 256; window: 4,864 x 6 behind a ring of 73), every
+    pool aliased to its output, scratch far under what is left of the
+    chip's 16 GiB; the attend at 7 query heads a K/V head, the row write
+    and the ReLU-gated grouped product TPU custom calls, eight of each a
+    program; no K/V-pool-sized op and no layer's experts (0.75 GB) copied;
+    the router's and the dispatch's ops named under ``moe`` in both."""
+    from deepspeed_tpu.analysis.hlo_text import ops_in_units_of
+    specs, param_bytes, programs = paste_cell_programs
+    full, window = specs
+    compiled = programs[program]
+    assert param_bytes == 2 * 3_966_937_600
+    assert (full.max_blocks_per_slot, window.max_blocks_per_slot) == (256, 73)
+    assert full.nbytes() == 2 * 10240 * 64 * 2048
+    assert window.nbytes() == 6 * 4864 * 64 * 2048
+    assert window.block_nbytes() == 786_432
+    pool_bytes = full.nbytes() + window.nbytes()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 640 * 2 ** 20, mem.temp_size_in_bytes
+    assert param_bytes + pool_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes \
+        < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    for kernel in ("_pattn_kernel", "_kv_write_kernel", "_greglu_kernel"):
+        calls = [line for line in text.splitlines()
+                 if f"%{kernel}" in line.split(" = ")[0]
+                 and " custom-call(" in line]
+        assert len(calls) == 8 \
+            and all("tpu_custom_call" in c for c in calls), kernel
+    assert "_gswiglu_kernel" not in text
+    for sp in specs:
+        seen = ops_in_units_of(
+            text, math.prod(sp.pool_shapes["k." + sp.name][2:]))
+        assert not [(op, n) for op, n in seen
+                    if op not in _POOL_OPS_ALLOWED], (sp.name, seen)
+    one_layers_experts = 64 * 768 * 2560
+    assert not [(op, n) for op, n in ops_in_units_of(
+        text, one_layers_experts) if op not in ("parameter", "bitcast",
+                                                 "get-tuple-element")]
+    for scope in ("/moe/router", "/moe/dispatch", "/moe/experts",
+                  "/moe/combine", "/attn/attend_full", "/attn/attend_window",
+                  "/attn/qkv_proj", "/attn/kv_write", "/attn/out_proj",
+                  "/lm_head"):
+        assert scope in text, scope
