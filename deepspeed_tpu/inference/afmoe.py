@@ -382,7 +382,10 @@ class AfmoeServed(GqaPagedServed):
         oh = (lax.broadcasted_iota(jnp.int32, (G, Cn), 1)
               == last_idx[:, None]).astype(x.dtype)
         h_last = jnp.einsum("gc,gch->gh", oh, x)
-        return self._head(params, h_last, cfg), pools, counters
+        return h_last, pools, counters
+
+    def head(self, params, h):
+        return self._head(params, h, self.cfg)
 
 
 register(AfmoeConfig, AfmoeServed)

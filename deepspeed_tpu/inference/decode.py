@@ -231,15 +231,17 @@ def gpt2_prefill_chunk_paged(params: Dict[str, Any], kc: jax.Array,
     table row (DEAD_BLOCK rows for groups with nothing to prefill);
     start/last_idx/active: [G]. Writes each chunk's K/V through its
     group's table and attends against the slot's whole cached row under
-    the global-position causal mask. Returns (logits [G, V] fp32 at
-    ``last_idx``, kc', vc'). Inactive groups compute garbage that
-    writes nowhere — the uniform-program rule that keeps ONE compiled
-    shape for any admission pattern.
+    the global-position causal mask. Returns (the final-normed hidden
+    row [G, H] at ``last_idx``, kc', vc'). Inactive groups compute
+    garbage that writes nowhere — the uniform-program rule that keeps
+    ONE compiled shape for any admission pattern.
 
-    Only ONE position per group projects through the unembedding (the
-    gpt2_logits_at memory contract: never a [C, vocab] tensor) — the
-    scheduler uses it on the final chunk to sample the first token;
-    earlier chunks compute it too (uniform program) and discard it.
+    Only ONE position per group ever projects through the unembedding
+    (the gpt2_logits_at memory contract: never a [C, vocab] tensor), and
+    only in the chunk program that ends a prompt: the caller applies
+    ``_unembed`` (``GPT2Served.head``) to the row returned here under a
+    branch (``served.head_and_sample``), where the scheduler samples the
+    first token from it.
     Padding rows beyond the prompt inside the final chunk produce
     garbage that nothing reads: causal masking keeps them out of every
     real row, and the next token's decode write overwrites their cache
@@ -257,8 +259,7 @@ def gpt2_prefill_chunk_paged(params: Dict[str, Any], kc: jax.Array,
     oh = (lax.broadcasted_iota(jnp.int32, (G, C), 1) ==
           last_idx[:, None]).astype(x.dtype)
     h_last = jnp.einsum("gc,gch->gh", oh, x)
-    logits = _unembed(params, h_last, cfg)
-    return logits, kc, vc
+    return h_last, kc, vc
 
 
 # --------------------------------------------------------------------- #
@@ -329,10 +330,13 @@ class GPT2Served(ServedModel):
 
     def prefill_chunk(self, params, pools, tokens, bt_rows, start,
                       last_idx, active, *, paged_kernel, mesh=None):
-        logits, kc, vc = gpt2_prefill_chunk_paged(
+        h_last, kc, vc = gpt2_prefill_chunk_paged(
             params, *pools, tokens, bt_rows, start, last_idx, active,
             self.cfg, paged_kernel=paged_kernel, mesh=mesh)
-        return logits, (kc, vc), ()
+        return h_last, (kc, vc), ()
+
+    def head(self, params, h):
+        return _unembed(params, h, self.cfg)
 
 
 register(GPT2Config, GPT2Served)

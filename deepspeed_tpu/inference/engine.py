@@ -22,6 +22,11 @@ Architecture (mirrors the training engine's discipline):
   are wrapped by the recompile sentinel; ``fail_on_recompile`` turns any
   post-warmup retrace — any other shape — into a hard error. Request
   admission, progress, and eviction never touch a compiled shape.
+  A program's last scopes run only where their result is read: a chunk
+  program computes the model's head and samples only in the dispatch
+  that ends a prompt (a branch on an operand the host sets; the others
+  return zeros nobody fetches), and ``sample_tokens`` branches on the
+  traced temperature (a greedy step draws no noise).
 - The KV cache (inference/kv_cache.py) is born sharded: slots over the
   mesh data axis, heads over the model axis. Its buffers are DONATED
   through every step, so the cache exists once — and a step writes
@@ -67,8 +72,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from . import kv_cache
 from .quantize import (dequantize, quantize_params, quantized_bytes,
                        resolve_kv_dtype)
-from .served import (ServedModel, filter_rows_lowered, sample_tokens,
-                     served_model, spec_accept, split_counters, with_counters)
+from .served import (ServedModel, filter_rows_lowered, head_and_sample,
+                     sample_tokens, served_model, spec_accept, split_counters,
+                     with_counters)
 from .spec import NGramDrafter
 from .. import constants as C
 from ..monitor import Telemetry
@@ -542,18 +548,25 @@ class InferenceEngine:
         once a width (``_warm_prefill_widths``). A model that freezes its
         state inside a chunk (``ServedModel.freezes_in_chunk``) takes two
         more ``[G]`` operands after ``active``, the snapshot's row and
-        page; no other model's program has them."""
+        page; no other model's program has them. ``read`` (a scalar
+        int32) says whether the dispatch ENDS some group's prompt: only
+        then does the program run the model's head and sample
+        (``served.head_and_sample``: a branch on the operand); any other
+        dispatch returns zeros for its tokens and logits, which nobody
+        fetches. The model's counters ride the fetch array either way."""
         served = self.served
         n = len(self._cache_sh)
 
         def prefill_step(params, *args):
-            pools, (tokens, bt_rows, start, last_idx, active, *freeze, key,
-                    temperature) = args[:n], args[n:]
+            pools, (tokens, bt_rows, start, last_idx, active, *freeze, read,
+                    key, temperature) = args[:n], args[n:]
             p = self._runtime_params(params)
-            logits, pools, counters = served.prefill_chunk(
+            h_last, pools, counters = served.prefill_chunk(
                 p, pools, tokens, bt_rows, start, last_idx, active, *freeze,
                 paged_kernel=self.paged_kernel, mesh=self.mesh)
-            sampled = sample_tokens(logits, key, temperature)
+            sampled, logits = head_and_sample(
+                read, functools.partial(served.head, p), h_last, key,
+                temperature)
             return (*pools, with_counters(sampled, counters), logits)
 
         return self._jit_step(prefill_step)
@@ -804,8 +817,9 @@ class InferenceEngine:
         name is the running total — and ``context_tokens_in_reach_<class>``)
         > ``prefill_plan`` (allocator admission + the copy-on-write fork),
         one ``prefill_chunk`` (``ci``, ``active_groups``, ``rows``: the
-        width) per chunk program dispatched, and ``prefill_fetch`` (the
-        first tokens' ``device_get``)."""
+        width, ``head``: 1 where the program ends some prompt and so runs
+        the head and samples, else 0) per chunk program dispatched, and
+        ``prefill_fetch`` (the first tokens' ``device_get``)."""
         if not self._prefill_warmed:
             self._warm_prefill_widths()
         t_pf0 = self.serving.lap("admit_s")
@@ -867,7 +881,7 @@ class InferenceEngine:
                 self.serving.note_admit_classes(returned, by_class)
         wall = self.serving.note_prefill_pass(
             len(steps), sum(p[4] for p in plans) - cached, computed,
-            widths) - t_pf0 - waited
+            widths, len({ci for ci, _ in held.values()})) - t_pf0 - waited
         self._prefill_wall += wall
         if self.serving.ledger is not None:
             self.serving.ledger.note("prefill", wall)
@@ -1008,7 +1022,10 @@ class InferenceEngine:
         and page are the program's operands) or by a copy of the
         stream's page behind it. Returns ([(tok_g, logits_g) device
         arrays per chunk index], {slot: (ci, group) of its last chunk},
-        [width per chunk index])."""
+        [width per chunk index]). A dispatch that ends no group's prompt
+        tells its program so (``read`` 0: no head, no sample, zeros in
+        their place) and is never fetched; the ``prefill_chunk`` span's
+        ``head`` says which a dispatch was."""
         G = self.dp
         J = self.allocator.table_width
         held = {}
@@ -1024,6 +1041,7 @@ class InferenceEngine:
                 starts = np.zeros(G, np.int32)
                 last_idxs = np.zeros(G, np.int32)
                 act = np.zeros(G, np.int32)
+                read = np.int32(0)           # does a group's prompt end here
                 freeze = self._no_freeze()   # (row, page) [G], or ()
                 snaps = {}   # group -> (own page, snapshot page): copied
                 frozen = []  # plans whose snapshot this dispatch leaves
@@ -1045,6 +1063,7 @@ class InferenceEngine:
                     last_idxs[group] = n - 1
                     if ci == len(chunks) - 1:
                         held[slot] = (ci, group)
+                        read = np.int32(1)
                     if ci == snap_in:
                         frozen.append(plan)
                         if freeze:
@@ -1055,10 +1074,11 @@ class InferenceEngine:
                             snaps[group] = (plan.page, plan.snapshot_page)
                 with self.telemetry.span("prefill_chunk", ci=ci,
                                          active_groups=int(act.sum()),
-                                         rows=width):
+                                         rows=width, head=int(read)):
                     *pools, tok_g, logits_g = self._prefill_fn(
                         self._params, *pools, toks, bt_rows, starts,
-                        last_idxs, act, *freeze, self._next_key(), temp)
+                        last_idxs, act, *freeze, read, self._next_key(),
+                        temp)
                 if snaps:
                     pools = self._copy_blocks(pools, snaps)
                 # (dispatched: the device's order makes the page whole
@@ -1098,7 +1118,7 @@ class InferenceEngine:
                 *pools, _, _ = self._prefill_fn(
                     self._params, *pools, np.zeros((G, width), np.int32),
                     dead, zeros, zeros, zeros, *self._no_freeze(),
-                    self._base_rng, np.float32(0.0))
+                    np.int32(0), self._base_rng, np.float32(0.0))
         finally:
             self._store_pools(pools)
         self._prefill_warmed = True

@@ -309,7 +309,10 @@ class FalconH1Served(GqaPagedServed):
             else (freeze_idx, freeze_page))
         oh = (cols == last_idx[:, None]).astype(x.dtype)
         h_last = jnp.einsum("gc,gch->gh", oh, x)
-        return _head(params, h_last, cfg), pools, None
+        return h_last, pools, None
+
+    def head(self, params, h):
+        return _head(params, h, self.cfg)
 
 
 register(FalconH1Config, FalconH1Served)

@@ -392,8 +392,10 @@ class LatentServed(ServedModel):
         oh = (lax.broadcasted_iota(jnp.int32, (G, Cn), 1)
               == last_idx[:, None]).astype(x.dtype)
         h_last = jnp.einsum("gc,gch->gh", oh, x.reshape(G, Cn, -1))
-        return _head(params, h_last.reshape((G,) + x.shape[2:]), cfg), \
-            (pool,), counters
+        return h_last.reshape((G,) + x.shape[2:]), (pool,), counters
+
+    def head(self, params, h):
+        return _head(params, h, self.cfg)
 
 
 register(DeepseekV3Config, LatentServed)

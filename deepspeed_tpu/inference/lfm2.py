@@ -311,7 +311,10 @@ class Lfm2Served(AfmoeServed):
             else (freeze_idx, freeze_page))
         oh = (cols == last_idx[:, None]).astype(x.dtype)
         h_last = jnp.einsum("gc,gch->gh", oh, x)
-        return _head(params, h_last, cfg), pools, counters
+        return h_last, pools, counters
+
+    def head(self, params, h):
+        return _head(params, h, self.cfg)
 
 
 register(Lfm2Config, Lfm2Served)

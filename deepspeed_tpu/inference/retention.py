@@ -234,7 +234,10 @@ class RetentionServed(ServedModel):
                             retention, cfg)
         oh = (cols == last_idx[:, None]).astype(x.dtype)
         h_last = jnp.einsum("gc,gch->gh", oh, x)
-        return _head(params, h_last, cfg), pools, None
+        return h_last, pools, None
+
+    def head(self, params, h):
+        return _head(params, h, self.cfg)
 
 
 register(BrumbyConfig, RetentionServed)

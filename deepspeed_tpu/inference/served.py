@@ -25,7 +25,14 @@ ask a ``ServedModel`` for:
   slot), ``verify`` (K tokens a slot, speculative), ``prefill_chunk`` (one
   chunk of one slot a group; a model whose per-stream state is a gather of
   a chunk's rows may also freeze it at a row INSIDE the chunk into a second
-  page, and says so: ``freezes_in_chunk``);
+  page, and says so: ``freezes_in_chunk``).  ``prefill_chunk`` alone stops
+  short of the logits: it returns the hidden row at ``last_idx``, and the
+  model's ``head`` turns such rows into logits, because only the chunk
+  program that ENDS a prompt has a reader for them.  The engine's
+  ``prefill_step`` applies ``head`` and samples under a branch on an
+  operand the host sets (``head_and_sample``): a program that ends no
+  prompt returns ZEROS for its tokens ``[G]`` and its logits ``[G, V]``,
+  and nothing fetches either;
 - **the cache's cost a token** for the engine's analytic counters:
   ``cache_cost(keys, ...)`` = (FLOPs, cache bytes) a layer spends on one
   query token, which MAY depend on the ``keys`` rows in reach (an attend:
@@ -50,6 +57,7 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from . import kv_cache
 
@@ -226,6 +234,17 @@ class ServedModel:
     def prefill_chunk(self, params, pools: Sequence, tokens, bt_rows,
                       start, last_idx, active, *, paged_kernel: bool,
                       mesh=None):
+        """One chunk of one slot a group: writes the chunk's rows and
+        returns (the hidden row at ``last_idx`` as ``head`` takes it, ``[G,
+        ...]``; pools; counters) — NOT logits: the caller applies ``head``
+        where a prompt ends (``head_and_sample``), and a chunk program
+        that ends none hands back zeros in their place."""
+        raise NotImplementedError
+
+    def head(self, params, h):
+        """fp32 logits ``[..., V]`` of the hidden rows ``prefill_chunk``
+        returns: the model's final norm and unembedding, under the scope
+        ``lm_head`` (what ``decode`` and ``verify`` end in)."""
         raise NotImplementedError
 
 
@@ -422,13 +441,41 @@ def filter_rows(sp: StreamPages, pool: jax.Array, layer, new: jax.Array, *,
 def sample_tokens(logits: jax.Array, key: jax.Array,
                   temperature: jax.Array) -> jax.Array:
     """Greedy (temperature == 0) or temperature sampling; logits
-    [..., V] fp32. Temperature is a TRACED scalar so changing it never
-    recompiles; both branches are cheap relative to the step, so a
-    select beats a cond."""
-    greedy = jnp.argmax(logits, axis=-1)
-    t = jnp.maximum(temperature.astype(jnp.float32), 1e-6)
-    sampled = jax.random.categorical(key, logits / t, axis=-1)
-    return jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
+    [..., V] fp32. Temperature is a TRACED scalar, so changing it never
+    recompiles, and the program BRANCHES on it: a greedy step draws no
+    noise over the vocabulary (at the published vocabularies the draw was
+    most of what ``sample`` cost: PERF.md section 6, PR 55), a sampling
+    step computes no argmax."""
+    def draw(logits):
+        t = jnp.maximum(temperature.astype(jnp.float32), 1e-6)
+        return jax.random.categorical(key, logits / t, axis=-1)
+
+    def greedy(logits):
+        return jnp.argmax(logits, axis=-1)
+
+    return lax.cond(temperature > 0, draw, greedy, logits).astype(jnp.int32)
+
+
+def head_and_sample(read: jax.Array, head: Callable, h: jax.Array,
+                    key: jax.Array, temperature: jax.Array
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """(tokens ``[G]`` int32, logits ``[G, V]`` fp32) of a chunk program's
+    last hidden rows ``h [G, ...]`` — where somebody reads them.  ``read``
+    is a scalar operand of the program: nonzero in a dispatch that ENDS
+    some group's prompt, which then runs ``head(h)`` and ``sample_tokens``
+    as every chunk program used to; any other dispatch takes the other
+    branch and returns ZEROS of the same shapes (nobody fetches them: the
+    host knows which program ended a prompt).  One compiled program either
+    way; on the chip only the taken branch runs."""
+    def live(h):
+        logits = head(h)
+        return sample_tokens(logits, key, temperature), logits
+
+    def unread(h):
+        return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                            jax.eval_shape(live, h))
+
+    return lax.cond(read > 0, live, unread, h)
 
 
 @jax.named_scope("sample")
@@ -459,4 +506,4 @@ __all__ = ["CacheClass", "ServedModel", "register", "served_model",
            "split_counters", "with_counters", "NEG_INF", "group_shape",
            "write_targets", "StreamPages", "stream_pages", "filter_rows",
            "filter_rows_lowered",
-           "sample_tokens", "spec_accept"]
+           "sample_tokens", "head_and_sample", "spec_accept"]

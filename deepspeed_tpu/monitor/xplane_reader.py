@@ -131,7 +131,10 @@ SPAN_ARGS = {
                 "resumed_tokens", "snapshot_taken", "snapshot_in_program",
                 "state_copy_bytes",
                 "prefix_lost_to_kind_tokens"),
-    "prefill_chunk": ("ci", "active_groups", "rows"),
+    # head: 1 where the chunk program ENDS some group's prompt and so runs
+    # the model's head and the sampler, 0 where it skips both (its tokens
+    # and logits are zeros nobody fetches).
+    "prefill_chunk": ("ci", "active_groups", "rows", "head"),
     # A decode span holds the DISPATCH of one iteration and the FETCH of
     # the one before (the loop runs an iteration ahead of its token
     # fetch): iteration .. attend_* and state_pages_live describe the one

@@ -1,8 +1,12 @@
 """``decode_step``'s lowered text, hashed, for a tiny engine of every
 family that was served before PR 54 (not a test file: the golden hashes in
-``tests/data/decode_step_hlo_pr53.json`` were written by running this file
-on the tree at PR 53, ``python tests/decode_step_hlo.py``, its last lines; the test
-that compares is ``test_smallthinker_serving.py``).
+``tests/data/decode_step_hlo_pr55.json`` were written by running this file
+on the tree at PR 55, ``python tests/decode_step_hlo.py``, its last lines; the test
+that compares is ``test_smallthinker_serving.py``).  PR 55 changed the text
+on purpose and in one place: ``sample``'s select between an argmax and a
+draw, both evaluated, became a ``case`` with one of them in each branch —
+the only lines that differ from PR 53's text once value numbers are
+blanked — so the hashes were written again.
 
 The text is ``jax.jit(...).lower(...).as_text()`` of the ENGINE's own
 ``_build_decode_step`` — StableHLO without locations, so a named scope or a

@@ -324,7 +324,8 @@ def _program_op_names(eng):
             eng._prefill_fn, eng._params, pool,
             np.zeros((G, eng.prefill_chunk), np.int32),
             np.zeros((G, J), np.int32), np.zeros(G, np.int32),
-            np.zeros(G, np.int32), np.ones(G, np.int32), key, temp)}
+            np.zeros(G, np.int32), np.ones(G, np.int32), np.int32(1), key,
+            temp)}
 
 
 def test_without_the_keys_the_tree_and_the_programs_are_todays():

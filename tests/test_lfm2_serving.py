@@ -645,7 +645,7 @@ def _chunk_call(eng, prompt, slot_page, **operands):
     before = np.asarray(eng.cache["conv.conv"]).copy()
     *pools, _, _ = eng._prefill_fn(
         eng._params, *eng._pools(), toks, bt, *args.values(),
-        eng._base_rng, np.float32(0.0))
+        np.int32(1), eng._base_rng, np.float32(0.0))
     eng._store_pools(pools)
     return before, np.asarray(eng.cache["conv.conv"])
 

@@ -375,7 +375,8 @@ class TestInPlaceWrite:
                 eng._params, eng.cache["k"], eng.cache["v"],
                 jnp.zeros((G, 8), jnp.int32), jnp.zeros((G, J), jnp.int32),
                 jnp.zeros(G, jnp.int32), jnp.zeros(G, jnp.int32),
-                jnp.ones(G, jnp.int32), eng._next_key(), jnp.float32(0))
+                jnp.ones(G, jnp.int32), jnp.int32(1), eng._next_key(),
+                jnp.float32(0))
         after = where()
         if not hasattr(fn, "lower"):        # the recompile sentinel's wrap
             fn = fn.__wrapped__

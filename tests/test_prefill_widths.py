@@ -392,7 +392,7 @@ def test_no_width_compiles_after_the_first_serve_has_begun(tmp_path):
     *pools, _, _ = eng._prefill_fn(
         eng._params, *eng._pools(), np.zeros((G, 64), np.int32),
         np.full((G, J), -1, np.int32), zeros, zeros, zeros,
-        eng._next_key(), np.float32(0.0))
+        np.int32(0), eng._next_key(), np.float32(0.0))
     eng._store_pools(pools)
     with pytest.raises(RecompileError, match="prefill_step"):
         eng.telemetry.raise_pending()

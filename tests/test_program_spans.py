@@ -190,7 +190,8 @@ def serve_op_names(serve_engine):
         eng._prefill_fn, eng._params, k, v,
         np.zeros((G, eng.prefill_chunk), np.int32),
         np.zeros((G, J), np.int32), np.zeros(G, np.int32),
-        np.zeros(G, np.int32), np.ones(G, np.int32), key, temp)
+        np.zeros(G, np.int32), np.ones(G, np.int32), np.int32(1), key,
+            temp)
     copy = _op_names(eng._copy_fn, k, v, np.zeros(G, np.int32),
                      np.ones(G, np.int32))
     return {"decode": decode, "prefill": prefill, "copy": copy}
@@ -589,7 +590,8 @@ def latent_op_names(latent_engine):
             eng._prefill_fn, eng._params, pool,
             np.zeros((G, eng.prefill_chunk), np.int32),
             np.zeros((G, J), np.int32), np.zeros(G, np.int32),
-            np.zeros(G, np.int32), np.ones(G, np.int32), key, temp)}
+            np.zeros(G, np.int32), np.ones(G, np.int32), np.int32(1), key,
+            temp)}
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -678,7 +680,8 @@ def retention_op_names():
             eng._prefill_fn, eng._params, *pools,
             np.zeros((G, eng.prefill_chunk), np.int32),
             np.zeros((G, J), np.int32), np.zeros(G, np.int32),
-            np.zeros(G, np.int32), np.ones(G, np.int32), key, temp),
+            np.zeros(G, np.int32), np.ones(G, np.int32), np.int32(1), key,
+            temp),
         "copy": _op_names(eng._copy_fn, *pools, np.zeros(G, np.int32),
                           np.ones(G, np.int32))}
     eng.close()
@@ -738,7 +741,8 @@ def classes_op_names(classes_engine):
             eng._prefill_fn, eng._params, *eng._pools(),
             np.zeros((G, eng.prefill_chunk), np.int32),
             np.zeros((G, W), np.int32), np.zeros(G, np.int32),
-            np.zeros(G, np.int32), np.ones(G, np.int32), key, temp)}
+            np.zeros(G, np.int32), np.ones(G, np.int32), np.int32(1), key,
+            temp)}
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -854,7 +858,8 @@ def kinds_op_names(kinds_engine):
             eng._prefill_fn, eng._params, *eng._pools(),
             np.zeros((G, eng.prefill_chunk), np.int32),
             np.zeros((G, W), np.int32), np.zeros(G, np.int32),
-            np.zeros(G, np.int32), np.ones(G, np.int32), key, temp),
+            np.zeros(G, np.int32), np.ones(G, np.int32), np.int32(1), key,
+            temp),
         "copy": _op_names(eng._copy_fn, *eng._pools(),
                           np.zeros(G, np.int32), np.ones(G, np.int32))}
 
@@ -977,7 +982,8 @@ def both_kinds_op_names():
             np.zeros((G, eng.prefill_chunk), np.int32),
             np.zeros((G, W), np.int32), np.zeros(G, np.int32),
             np.zeros(G, np.int32), np.ones(G, np.int32),
-            np.zeros(G, np.int32), np.zeros(G, np.int32), key, temp)}
+            np.zeros(G, np.int32), np.zeros(G, np.int32), np.int32(1), key,
+            temp)}
     eng.close()
     return names
 
@@ -1049,7 +1055,8 @@ def kimi_op_names():
             np.zeros((G, eng.prefill_chunk), np.int32),
             np.zeros((G, W), np.int32), np.zeros(G, np.int32),
             np.zeros(G, np.int32), np.ones(G, np.int32),
-            np.zeros(G, np.int32), np.zeros(G, np.int32), key, temp)}
+            np.zeros(G, np.int32), np.zeros(G, np.int32), np.int32(1), key,
+            temp)}
     eng.close()
     return names
 
@@ -1112,7 +1119,8 @@ def route_ahead_op_names(route_ahead_engine):
             eng._prefill_fn, eng._params, *eng._pools(),
             np.zeros((G, eng.prefill_chunk), np.int32),
             np.zeros((G, W), np.int32), np.zeros(G, np.int32),
-            np.zeros(G, np.int32), np.ones(G, np.int32), key, temp)}
+            np.zeros(G, np.int32), np.ones(G, np.int32), np.int32(1), key,
+            temp)}
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])

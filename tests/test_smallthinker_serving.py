@@ -24,7 +24,8 @@ shorter than the prompts:
    against plain ``jax.numpy``.
 4. The default arguments leave the families that were served before where
    they were: their ``decode_step`` lowers to the text it lowered to at PR
-   53 (``tests/data/decode_step_hlo_pr53.json``).
+   55 (``tests/data/decode_step_hlo_pr55.json``: PR 53's text but for
+   ``sample``'s branch on the temperature).
 """
 import dataclasses
 import json
@@ -471,12 +472,12 @@ sys.path.insert(0, os.path.join(ROOT, "tests"))
 from decode_step_hlo import FAMILIES, decode_step_sha    # noqa: E402
 
 GOLDEN = json.load(open(os.path.join(ROOT, "tests", "data",
-                                     "decode_step_hlo_pr53.json")))
+                                     "decode_step_hlo_pr55.json")))
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_the_default_arguments_leave_decode_step_unchanged(family):
     """Cells 4 / 10, 6, 7, 8 (and 9, which shares the attention branch):
     ``decode_step``'s lowered text, kernels on and off, is what the tree at
-    PR 53 lowered to."""
+    PR 55 lowered to (PR 53's, but for ``sample``'s branch)."""
     assert decode_step_sha(family) == GOLDEN[family]

@@ -650,7 +650,7 @@ def _serve_program(topo, program, head_dim):
         else:
             step = eng._build_prefill_step()
             args = (params, pool, pool, i32(1, C), i32(1, J), i32(1),
-                    i32(1), i32(1), key, temp)
+                    i32(1), i32(1), i32(), key, temp)
         compiled = step.lower(*args).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", prev)
@@ -891,8 +891,8 @@ def _latent_cell_programs(topo, config_file, **overrides):
             params, pool, i32(S + len(eng.served.counter_names)), i32(S),
             fresh(S), i32(S), i32(S, J), key, temp).compile()
         out["prefill_step"] = eng._build_prefill_step().lower(
-            params, pool, i32(1, C), i32(1, J), i32(1), i32(1), i32(1), key,
-            temp).compile()
+            params, pool, i32(1, C), i32(1, J), i32(1), i32(1), i32(1), i32(),
+            key, temp).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", prev)
         compilation_cache.reset_cache()
@@ -1065,7 +1065,7 @@ def retention_programs(topo):
             fresh(S), i32(S), i32(S, J), key, temp).compile()
         out["prefill_step"] = eng._build_prefill_step().lower(
             params, *pools, i32(1, C), i32(1, J), i32(1), i32(1), i32(1),
-            key, temp).compile()
+            i32(), key, temp).compile()
         out["state_copy"] = eng._build_copy("state_copy", "state_copy").lower(
             *pools, i32(1), i32(1)).compile()
     finally:
@@ -1207,7 +1207,7 @@ def mixed_kind_programs(topo):
             # (+ the snapshot's row and page: the program freezes it)
             out[f"prefill_step.{C}"] = eng._build_prefill_step().lower(
                 params, *pools, i32(1, C), i32(1, J), i32(1), i32(1), i32(1),
-                i32(1), i32(1), key, temp).compile()
+                i32(1), i32(1), i32(), key, temp).compile()
         out["state_copy"] = eng._build_copy("state_copy", "state_copy").lower(
             *pools, i32(1), i32(1)).compile()
     finally:
@@ -1375,7 +1375,7 @@ def both_kinds_in_a_layer_programs(topo):
             # (+ the snapshot's row and page: the program freezes it)
             out[f"prefill_step.{C}"] = eng._build_prefill_step().lower(
                 params, *pools, i32(1, C), i32(1, J), i32(1), i32(1), i32(1),
-                i32(1), i32(1), key, temp).compile()
+                i32(1), i32(1), i32(), key, temp).compile()
         out["state_copy"] = eng._build_copy("state_copy", "state_copy").lower(
             *pools, i32(1), i32(1)).compile()
     finally:
@@ -1585,7 +1585,7 @@ def latent_beside_state_programs(topo):
             # (+ the snapshot's row and page: the program freezes it)
             out[f"prefill_step.{C}"] = eng._build_prefill_step().lower(
                 params, *pools, i32(1, C), i32(1, J), i32(1), i32(1), i32(1),
-                i32(1), i32(1), key, temp).compile()
+                i32(1), i32(1), i32(), key, temp).compile()
         out["state_copy"] = eng._build_copy("state_copy", "state_copy").lower(
             *pools, i32(1), i32(1)).compile()
     finally:
@@ -1804,7 +1804,7 @@ def paste_cell_programs(topo):
         for C in prefill_widths(inf["prefill_chunk"], inf["block_size"]):
             out[f"prefill_step.{C}"] = eng._build_prefill_step().lower(
                 params, *pools, i32(1, C), i32(1, J), i32(1), i32(1), i32(1),
-                key, temp).compile()
+                i32(), key, temp).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", prev)
         compilation_cache.reset_cache()

@@ -171,6 +171,13 @@ def serving_steps(device, cfg):
     pool = on_chip(jax.ShapeDtypeStruct(spec.shape, spec.dtype))
     i32 = lambda *shape: on_chip(jax.ShapeDtypeStruct(shape, jnp.int32))
 
+    def prefill_chunk_with_head(p, kc, vc, t, bt, st, li, ac):
+        # (the hidden row, then the head: what a chunk program that ends a
+        # prompt runs — the engine puts the head under a branch)
+        h, kc, vc = dm.gpt2_prefill_chunk_paged(
+            p, kc, vc, t, bt, st, li, ac, cfg, paged_kernel=True)
+        return dm._unembed(p, h, cfg), kc, vc
+
     programs = {
         "paged_decode": (
             lambda p, kc, vc, t, l, bt: dm.gpt2_decode_paged(
@@ -182,9 +189,7 @@ def serving_steps(device, cfg):
             (params, pool, pool, i32(slots, spec_k + 1), i32(slots),
              i32(slots, J))),
         f"paged_prefill_chunk{chunk}": (
-            lambda p, kc, vc, t, bt, st, li, ac:
-            dm.gpt2_prefill_chunk_paged(p, kc, vc, t, bt, st, li, ac, cfg,
-                                        paged_kernel=True),
+            prefill_chunk_with_head,
             (params, pool, pool, i32(1, chunk), i32(1, J), i32(1), i32(1),
              on_chip(jax.ShapeDtypeStruct((1,), jnp.bool_)))),
     }
