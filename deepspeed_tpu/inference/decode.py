@@ -1,24 +1,12 @@
-"""Incremental GPT-2 forward paths against the paged block pool:
-single-token decode, chunked prefill, and the speculative verify step.
-
-Three programs, each with a FIXED abstract signature (the recompile
-sentinel wraps all of them):
-
-- ``gpt2_verify_paged``: K tokens per slot, for every slot at once —
-  token i of slot s sits at position lengths[s] + i. Writes the K new
-  rows, attends each under its own causal row, and returns K-bounded
-  logits; with ``served.spec_accept`` it implements draft-then-verify
-  speculative decoding whose greedy output is bit-identical to
-  single-token decode.
-- ``gpt2_decode_paged``: the K=1 verify — one token per slot, LAST-
-  position logits only (the same tied-unembedding contraction
-  ``models.gpt2.gpt2_logits_at`` exposes for the batch path).
-- ``gpt2_prefill_chunk_paged``: one prompt chunk for ONE slot per group,
-  attended against the slot's whole cached row under a global-position
-  causal mask — so any chunk length divides any prompt without shape
-  polymorphism. Prefill and decode are separate programs on purpose
-  (prefill/decode disaggregation): a long admission never changes the
-  decode signature.
+"""GPT-2 as a served model (inference/served.py): the incremental forward
+against the paged block pool that ``ServedModel``'s three programs —
+single-token decode, the speculative verify step, chunked prefill — are
+written over.  Each program has a FIXED abstract signature (the recompile
+sentinel wraps all of them); prefill and decode are separate programs on
+purpose (prefill/decode disaggregation): a long admission never changes the
+decode signature.  ``decode`` projects LAST-position logits only (the same
+tied-unembedding contraction ``models.gpt2.gpt2_logits_at`` exposes for the
+batch path).
 
 Every cache access goes through the block-table primitives in
 ``inference/kv_cache.py``: group-batched over the mesh data axis, one
@@ -42,8 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import kv_cache
-from .served import (NEG_INF, ServedModel, group_shape, register,
-                     write_targets)
+from .served import NEG_INF, Rows, ServedModel, register, write_targets
 from ..models.gpt2 import GPT2Config
 from ..ops import paged_attention as paged_attn_ops
 from ..models.transformer import (dense, gelu_dense_fn, layer_norm,
@@ -172,102 +159,11 @@ def _paged_forward(params, x, kc, vc, bt_g, pos_g, cfg: GPT2Config,
     return x, kc, vc
 
 
-def gpt2_verify_paged(params: Dict[str, Any], kc: jax.Array,
-                      vc: jax.Array, tokens: jax.Array,
-                      lengths: jax.Array, block_tables: jax.Array,
-                      cfg: GPT2Config, num_groups: int,
-                      paged_kernel: bool = False, mesh=None
-                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """The speculative verify step — and, at K=1, plain paged decode.
-
-    tokens: [S, K] — column 0 is each slot's pending last token,
-    columns 1.. are the drafted continuation; token i sits at position
-    lengths[s] + i. Writes all K tokens' K/V through the block table,
-    attends each under its own causal row, and returns fp32 logits
-    [S, K, V] (the K-bounded spec-decode analogue of last-position-only
-    logits — never a [max_len, vocab] tensor). kc/vc: the full pool as
-    held, [L, G, B, nH, bs/f, f*D]. ``paged_kernel`` swaps the one-hot pool
-    contraction for the Pallas table-sliced kernel (ops/
-    paged_attention.py) — same logits, O(context) work.
-    """
-    _check_cfg(cfg)
-    K = tokens.shape[1]
-    pos = lengths[:, None] + jnp.arange(K, dtype=jnp.int32)[None]  # [S,K]
-    x = _embed(params, tokens, pos, cfg)
-    x, kc, vc = _paged_forward(
-        params, x, kc, vc, group_shape(block_tables, num_groups),
-        group_shape(pos, num_groups), cfg, paged_kernel, mesh)
-    x = layer_norm_fn(cfg)(x, params["ln_f_scale"], params["ln_f_bias"])
-    logits = _unembed(params, x, cfg)
-    return logits, kc, vc
-
-
-def gpt2_decode_paged(params: Dict[str, Any], kc: jax.Array,
-                      vc: jax.Array, tokens: jax.Array,
-                      lengths: jax.Array, block_tables: jax.Array,
-                      cfg: GPT2Config, num_groups: int,
-                      paged_kernel: bool = False, mesh=None
-                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One paged decode step for every slot: the K=1 verify. Returns
-    (logits [S, V] fp32, kc', vc'). The caller advances lengths for the
-    slots it considers active; position = lengths[s] by construction."""
-    logits, kc, vc = gpt2_verify_paged(params, kc, vc, tokens[:, None],
-                                       lengths, block_tables, cfg,
-                                       num_groups, paged_kernel, mesh)
-    return logits[:, 0], kc, vc
-
-
-def gpt2_prefill_chunk_paged(params: Dict[str, Any], kc: jax.Array,
-                             vc: jax.Array, tokens: jax.Array,
-                             bt_rows: jax.Array, start: jax.Array,
-                             last_idx: jax.Array, active: jax.Array,
-                             cfg: GPT2Config,
-                             paged_kernel: bool = False, mesh=None
-                             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Group-batched chunked prefill: one prompt chunk for ONE slot per
-    group.
-
-    tokens: [G, C]; bt_rows: [G, J] — each group's target slot's block
-    table row (DEAD_BLOCK rows for groups with nothing to prefill);
-    start/last_idx/active: [G]. Writes each chunk's K/V through its
-    group's table and attends against the slot's whole cached row under
-    the global-position causal mask. Returns (the final-normed hidden
-    row [G, H] at ``last_idx``, kc', vc'). Inactive groups compute
-    garbage that writes nowhere — the uniform-program rule that keeps
-    ONE compiled shape for any admission pattern.
-
-    Only ONE position per group ever projects through the unembedding
-    (the gpt2_logits_at memory contract: never a [C, vocab] tensor), and
-    only in the chunk program that ends a prompt: the caller applies
-    ``_unembed`` (``GPT2Served.head``) to the row returned here under a
-    branch (``served.head_and_sample``), where the scheduler samples the
-    first token from it.
-    Padding rows beyond the prompt inside the final chunk produce
-    garbage that nothing reads: causal masking keeps them out of every
-    real row, and the next token's decode write overwrites their cache
-    rows before any attend reaches them.
-    """
-    _check_cfg(cfg)
-    G, C = tokens.shape
-    pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]  # [G, C]
-    x = _embed(params, tokens, pos, cfg)         # [G, C, H]
-    bt_g = jnp.where(active[:, None, None] > 0, bt_rows[:, None],
-                     kv_cache.DEAD_BLOCK)            # [G, 1, J]
-    x, kc, vc = _paged_forward(params, x, kc, vc, bt_g, pos[:, None, :],
-                               cfg, paged_kernel, mesh)
-    x = layer_norm_fn(cfg)(x, params["ln_f_scale"], params["ln_f_bias"])
-    oh = (lax.broadcasted_iota(jnp.int32, (G, C), 1) ==
-          last_idx[:, None]).astype(x.dtype)
-    h_last = jnp.einsum("gc,gch->gh", oh, x)
-    return h_last, kc, vc
-
-
 # --------------------------------------------------------------------- #
-# GPT-2 as a served model (inference/served.py): the first
-# implementation of the interface the engine serves through
+# The first implementation of the interface the engine serves through
 # --------------------------------------------------------------------- #
 class GPT2Served(ServedModel):
-    """Per-head K and V rows in two pools; the three programs above."""
+    """Per-head K and V rows in two pools."""
 
     def __init__(self, cfg: GPT2Config):
         _check_cfg(cfg)
@@ -314,26 +210,20 @@ class GPT2Served(ServedModel):
             q_itemsize=q_itemsize) + (
                 paged_attn_ops.attend_cold_steps(live_blocks, calls=calls),)
 
-    def decode(self, params, pools, tokens, lengths, block_tables, *,
-               num_groups, paged_kernel, mesh=None):
-        logits, kc, vc = gpt2_decode_paged(
-            params, *pools, tokens, lengths, block_tables, self.cfg,
-            num_groups, paged_kernel=paged_kernel, mesh=mesh)
-        return logits, (kc, vc), ()
+    def embed(self, params, tokens, pos):
+        return _embed(params, tokens, pos, self.cfg)
 
-    def verify(self, params, pools, tokens, lengths, block_tables, *,
-               num_groups, paged_kernel, mesh=None):
-        logits, kc, vc = gpt2_verify_paged(
-            params, *pools, tokens, lengths, block_tables, self.cfg,
-            num_groups, paged_kernel=paged_kernel, mesh=mesh)
-        return logits, (kc, vc), ()
-
-    def prefill_chunk(self, params, pools, tokens, bt_rows, start,
-                      last_idx, active, *, paged_kernel, mesh=None):
-        h_last, kc, vc = gpt2_prefill_chunk_paged(
-            params, *pools, tokens, bt_rows, start, last_idx, active,
-            self.cfg, paged_kernel=paged_kernel, mesh=mesh)
-        return h_last, (kc, vc), ()
+    def forward(self, params, pools, x, rows: Rows, *, paged_kernel, mesh):
+        """The stack, then the final LayerNorm: the rows as ``head``
+        takes them.  Every row writes (a dead slot's table is
+        ``DEAD_BLOCK``: its rows land nowhere); padding rows past a
+        prompt's end inside its last chunk write garbage the next token's
+        decode write overwrites before any attend reaches it."""
+        cfg = self.cfg
+        x, kc, vc = _paged_forward(params, x, *pools, rows.tables,
+                                   rows.positions, cfg, paged_kernel, mesh)
+        x = layer_norm_fn(cfg)(x, params["ln_f_scale"], params["ln_f_bias"])
+        return x, (kc, vc), ()
 
     def head(self, params, h):
         return _unembed(params, h, self.cfg)
@@ -342,5 +232,4 @@ class GPT2Served(ServedModel):
 register(GPT2Config, GPT2Served)
 
 
-__all__ = ["gpt2_decode_paged", "gpt2_verify_paged",
-           "gpt2_prefill_chunk_paged", "GPT2Served"]
+__all__ = ["GPT2Served"]

@@ -881,7 +881,7 @@ class InferenceEngine:
                 self.serving.note_admit_classes(returned, by_class)
         wall = self.serving.note_prefill_pass(
             len(steps), sum(p[4] for p in plans) - cached, computed,
-            widths, len({ci for ci, _ in held.values()})) - t_pf0 - waited
+            widths) - t_pf0 - waited
         self._prefill_wall += wall
         if self.serving.ledger is not None:
             self.serving.ledger.note("prefill", wall)

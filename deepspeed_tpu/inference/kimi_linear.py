@@ -39,7 +39,8 @@ of the prompt is one of the scan's carried states: the model declares
 ``freezes_in_chunk``, and the chunk that reaches a snapshot's boundary
 writes the state as it stood THERE (and the filter rows that end there) into
 the snapshot's page as well.  A state cannot be rolled back over rejected
-drafts: ``verify`` raises, and ``inference.spec_k`` must be 0.
+drafts (``rolls_back`` is False: ``verify`` raises, and ``inference.spec_k``
+must be 0).
 
 The layers are walked in a static loop (their kinds differ).  Scopes:
 ``embed``; ``attn`` > ``kda_proj``, ``kda_conv``, ``kda_gate``,
@@ -58,12 +59,10 @@ from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
-from . import kv_cache
 from .latent import LatentServed, latent_context, latent_sublayer
-from .served import (CacheClass, filter_rows, group_shape, register,
-                     stream_pages)
+from .served import (CacheClass, Rows, filter_rows, filter_tile,
+                     group_shape, register, stream_pages)
 from ..models import kimi_linear as kl
 from ..models.blocks import rms_norm, swiglu
 from ..models.kimi_linear import KDA, KimiLinearConfig
@@ -72,146 +71,6 @@ from ..ops import kda
 
 LATENT_CLASS, STATE_CLASS = "latent", "state"
 KDA_CHUNK = 64            # rows a step of the chunked delta rule
-
-
-def conv_tile(cfg: KimiLinearConfig) -> Tuple[int, int, int]:
-    """A page's tile of one KDA layer's filter rows as held: ``taps - 1``
-    rows of ``conv_dim``, row-major, in rows of 128 lanes where they divide
-    (a ``[3, C]`` minor pair would be padded to the sublane tile)."""
-    rows = cfg.short_conv_kernel_size - 1
-    n = rows * cfg.conv_dim
-    return (1, n // 128, 128) if n % 128 == 0 else (1, rows, cfg.conv_dim)
-
-
-def _forward(params, pools, x, bt_g, pos_g, live, cfg: KimiLinearConfig,
-             widths, paged_kernel: bool, mesh, chunked: bool, freeze=None):
-    """All layers: x [S, K, H] with its streams' table rows bt_g [G, Sg, W]
-    (the classes' rows side by side, ``widths`` wide), row positions pos_g
-    [G, Sg, K] and ``live`` [S, K]: the rows that are traffic (a live
-    stream's, and no padding; a stream's live rows come first).  The others
-    write no cache row and no page, attend nothing, get no expert row and
-    are not counted; what they compute nobody reads.  ``pools``: (latent,
-    state, conv).  ``chunked``: a prefill chunk (the chunked delta rule over
-    its K rows), else the decode program (K = 1: the state update).
-    ``freeze``: (row [S], page [S]) — a stream's state as it stands after
-    chunk row ``row`` goes into ``page`` too (a snapshot; ``DEAD_BLOCK``:
-    none), or None.  Returns (x', pools', counters)."""
-    G, Sg, K = pos_g.shape
-    S, H = G * Sg, x.shape[-1]
-    taps = cfg.short_conv_kernel_size
-    pos = pos_g.reshape(S, K)
-    latent, state, conv = pools
-    w_latent, w_state = widths
-    assert w_state == 1, widths
-    ctx = latent_context(bt_g[:, :, :w_latent], pos_g, live, latent,
-                         paged_kernel, mesh)
-
-    # -- the state's page, where it goes back and what a snapshot takes.
-    # The scan's sub-chunk: every block boundary is one of its carried
-    # states (a chunk starts at one: the engine's widths are whole blocks).
-    q_rows = math.gcd(KDA_CHUNK, 2 * latent.shape[4], K)
-    page = bt_g[:, :, w_latent].reshape(S)
-    sp = stream_pages(page, pos, live, state.shape[2], Sg, taps - 1, freeze,
-                      scan_rows=q_rows)
-
-    def decode_states(q, k, v, g, beta, layer):
-        """One row a stream: every live page's layer rewritten in place."""
-        nonlocal state
-        args = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
-        if paged_kernel:
-            o, state = kda.state_update(
-                state, layer, page.reshape(G, Sg),
-                *(group_shape(a, G) for a in args), mesh=mesh)
-            return o.reshape((S, 1) + o.shape[2:])
-        o, new = kda.recurrent_update(state[layer, sp.group, sp.page], *args)
-        state = state.at[layer, sp.group, sp.to[0]].set(new, mode="drop")
-        return jnp.where(sp.wrote[:, None, None], o, 0.0)[:, None]
-
-    def chunk_states(q, k, v, g, beta, layer):
-        """A chunk of rows a stream, from the page's state."""
-        nonlocal state
-        g = jnp.where(live[..., None, None], g, 0.0)
-        beta = jnp.where(live[..., None], beta, 0.0)
-        os_ = []
-        for s in range(S):
-            S0 = jnp.where(sp.carried[s],
-                           state[layer, sp.group[s], sp.page[s]], 0.0)
-            o, S1, kept = kda.chunked_delta_rule(
-                S0, q[s], k[s], v[s], g[s], beta[s], chunk=q_rows,
-                keep=None if sp.keep_chunk is None else sp.keep_chunk[s])
-            for where, new in zip(sp.to, (S1, kept)):
-                state = state.at[layer, sp.group[s], where[s]].set(
-                    new, mode="drop")
-            os_.append(o)
-        return jnp.stack(os_)
-
-    def kda_mixer(p, x, layer):
-        nonlocal conv
-        with jax.named_scope("attn"):
-            with jax.named_scope("kda_proj"):
-                u = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
-                qkv = kl.kda_in(p, u, cfg)                  # [S, K, 3 W]
-            with jax.named_scope("kda_conv"):
-                rows_in, conv = filter_rows(sp, conv, layer, qkv,
-                                            paged_kernel=paged_kernel,
-                                            mesh=mesh)
-                q, k, v = kl.kda_qkv(kl.kda_conv(p, rows_in, cfg), cfg)
-            with jax.named_scope("kda_gate"):
-                g, beta = kl.kda_gates(p, u, cfg)
-            if not chunked:
-                with jax.named_scope("kda_update"):
-                    o = decode_states(q, k, v, g, beta, layer)
-            else:
-                with jax.named_scope("kda_chunk"):
-                    o = chunk_states(q, k, v, g, beta, layer)
-            with jax.named_scope("kda_out"):
-                return x + kl.kda_out(p, o, u, cfg)
-
-    def latent_mixer(p, x, layer):
-        nonlocal latent
-        with jax.named_scope("attn"):
-            y, latent = latent_sublayer(p, x, pos, latent, layer, ctx, cfg,
-                                        mesh)
-            with jax.named_scope("latent_proj"):
-                return x + y
-
-    row_live = live.reshape(S * K)
-    zero = jnp.zeros((), jnp.int32)
-    pairs, most, empty = zero, zero, zero
-    at = {KDA: 0, kl.LATENT: 0}
-    for l, p in enumerate(params["layers"]):
-        kind = cfg.layer_kinds[l]
-        x = (kda_mixer if kind == KDA else latent_mixer)(p, x, at[kind])
-        at[kind] += 1
-        if l < cfg.num_dense_layers:
-            with jax.named_scope("mlp"):
-                h = rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
-                x = x + swiglu(h, p["mlp_gate"], p["mlp_up"], p["mlp_down"])
-            continue
-        h = rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
-        # ``paged_kernel`` is "this path runs its Pallas kernels": the
-        # attend, the state update and the grouped expert product alike.
-        y, counts = share.expert_layer(
-            p, h.reshape(S * K, H), cfg.routing, kernel=paged_kernel,
-            row_live=row_live)
-        x = x + y.reshape(S, K, H)
-        pairs = pairs + counts.sum()
-        most = jnp.maximum(most, counts.max())
-        empty = empty + (counts == 0).sum()
-    return x, (latent, state, conv), (
-        pairs, most, empty, row_live.sum().astype(jnp.int32))
-
-
-@jax.named_scope("lm_head")
-def _head(params, h, cfg):
-    h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-    return jnp.dot(h, params["lm_head"].astype(h.dtype).T,
-                   preferred_element_type=jnp.float32)
-
-
-@jax.named_scope("embed")
-def _embed(params, tokens, cfg):
-    return params["embed"].astype(cfg.dtype)[tokens]
 
 
 class KimiLinearServed(LatentServed):
@@ -224,6 +83,7 @@ class KimiLinearServed(LatentServed):
     # and every block boundary is one: the program that passes a snapshot's
     # leaves it.
     freezes_in_chunk = True
+    rolls_back = False
 
     @property
     def init_fn(self) -> Callable:
@@ -252,62 +112,142 @@ class KimiLinearServed(LatentServed):
         latent_token = (cfg.latent_width * cfg.num_latent_layers
                         * jnp.dtype(cfg.dtype).itemsize)
         return dict(pools=(("state", tile, jnp.float32),
-                           ("conv", conv_tile(cfg))),
+                           ("conv", filter_tile(
+                               cfg.short_conv_kernel_size - 1,
+                               cfg.conv_dim))),
                     num_heads=cfg.kda_num_heads,
                     head_dim=cfg.kda_head_dim * cfg.kda_head_dim,
                     token_row_bytes=-(-latent_token // cls.layers))
 
-    # -- programs ------------------------------------------------------ #
-    def verify(self, params, pools, tokens, lengths, block_tables, *,
-               num_groups, paged_kernel, mesh=None):
-        raise NotImplementedError(
-            "a delta-rule state cannot be rolled back over rejected "
-            "drafts: set inference.spec_k to 0")
+    # -- the block ------------------------------------------------------ #
+    @jax.named_scope("embed")
+    def embed(self, params, tokens, pos):
+        return params["embed"].astype(self.cfg.dtype)[tokens]
 
-    def decode(self, params, pools, tokens, lengths, block_tables, *,
-               num_groups, paged_kernel, mesh=None):
+    def forward(self, params, pools, x, rows: Rows, *, paged_kernel, mesh):
+        """``pools``: (latent, state, conv).  ``rows.chunked``: the chunked
+        delta rule over a prefill chunk's K rows, else the decode program's
+        state update (K = 1)."""
         cfg = self.cfg
-        live = (block_tables >= 0).any(axis=1, keepdims=True)
-        x, pools, counters = _forward(
-            params, pools, _embed(params, tokens[:, None], cfg),
-            group_shape(block_tables, num_groups),
-            group_shape(lengths[:, None], num_groups), live, cfg,
-            self._widths(block_tables), paged_kernel, mesh, chunked=False)
-        return _head(params, x[:, 0], cfg), pools, counters
+        G, Sg, K = rows.positions.shape
+        S, H = G * Sg, x.shape[-1]
+        taps = cfg.short_conv_kernel_size
+        pos = rows.positions.reshape(S, K)
+        live = rows.live
+        latent, state, conv = pools
+        w_latent, w_state = rows.widths
+        assert w_state == 1, rows.widths
+        ctx = latent_context(rows.tables[:, :, :w_latent], rows.positions,
+                             live, latent, paged_kernel, mesh)
 
-    def prefill_chunk(self, params, pools, tokens, bt_rows, start,
-                      last_idx, active, freeze_idx=None, freeze_page=None,
-                      *, paged_kernel, mesh=None):
-        """``decode.gpt2_prefill_chunk_paged``'s contract; rows past
-        ``last_idx`` (a last chunk's padding) are dead rows.  The state a
-        chunk starts from is whatever the stream's own page holds — a
-        snapshot the engine copied there, or the chunk before — and zeros
-        at position 0.  ``freezes_in_chunk``: a group's state as it stands
-        after chunk row ``freeze_idx`` (the last row of a block) goes into
-        page ``freeze_page`` as well (``DEAD_BLOCK``: the group leaves none
-        in this chunk; without the operands the program writes the
-        stream's own page only)."""
-        cfg = self.cfg
-        G, Cn = tokens.shape
-        cols = lax.broadcasted_iota(jnp.int32, (G, Cn), 1)
-        pos = start[:, None] + cols
-        bt_g = jnp.where(active[:, None, None] > 0, bt_rows[:, None],
-                         kv_cache.DEAD_BLOCK)
-        live = (active[:, None] > 0) & (cols <= last_idx[:, None])
-        x, pools, counters = _forward(
-            params, pools, _embed(params, tokens, cfg), bt_g,
-            pos[:, None, :], live, cfg, self._widths(bt_rows), paged_kernel,
-            mesh, chunked=True, freeze=None if freeze_idx is None
-            else (freeze_idx, freeze_page))
-        oh = (cols == last_idx[:, None]).astype(x.dtype)
-        h_last = jnp.einsum("gc,gch->gh", oh, x)
-        return h_last, pools, counters
+        # -- the state's page, where it goes back and what a snapshot
+        # takes.  The scan's sub-chunk: every block boundary is one of its
+        # carried states (a chunk starts at one: the engine's widths are
+        # whole blocks).
+        q_rows = math.gcd(KDA_CHUNK, 2 * latent.shape[4], K)
+        page = rows.tables[:, :, w_latent].reshape(S)
+        sp = stream_pages(page, pos, live, state.shape[2], Sg, taps - 1,
+                          rows.freeze, scan_rows=q_rows)
 
+        def decode_states(q, k, v, g, beta, layer):
+            """One row a stream: every live page's layer rewritten in
+            place."""
+            nonlocal state
+            args = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+            if paged_kernel:
+                o, state = kda.state_update(
+                    state, layer, page.reshape(G, Sg),
+                    *(group_shape(a, G) for a in args), mesh=mesh)
+                return o.reshape((S, 1) + o.shape[2:])
+            o, new = kda.recurrent_update(state[layer, sp.group, sp.page],
+                                          *args)
+            state = state.at[layer, sp.group, sp.to[0]].set(new,
+                                                            mode="drop")
+            return jnp.where(sp.wrote[:, None, None], o, 0.0)[:, None]
+
+        def chunk_states(q, k, v, g, beta, layer):
+            """A chunk of rows a stream, from the page's state."""
+            nonlocal state
+            g = jnp.where(live[..., None, None], g, 0.0)
+            beta = jnp.where(live[..., None], beta, 0.0)
+            os_ = []
+            for s in range(S):
+                S0 = jnp.where(sp.carried[s],
+                               state[layer, sp.group[s], sp.page[s]], 0.0)
+                o, S1, kept = kda.chunked_delta_rule(
+                    S0, q[s], k[s], v[s], g[s], beta[s], chunk=q_rows,
+                    keep=None if sp.keep_chunk is None
+                    else sp.keep_chunk[s])
+                for where, new in zip(sp.to, (S1, kept)):
+                    state = state.at[layer, sp.group[s], where[s]].set(
+                        new, mode="drop")
+                os_.append(o)
+            return jnp.stack(os_)
+
+        def kda_mixer(p, x, layer):
+            nonlocal conv
+            with jax.named_scope("attn"):
+                with jax.named_scope("kda_proj"):
+                    u = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
+                    qkv = kl.kda_in(p, u, cfg)              # [S, K, 3 W]
+                with jax.named_scope("kda_conv"):
+                    rows_in, conv = filter_rows(
+                        sp, conv, layer, qkv, paged_kernel=paged_kernel,
+                        mesh=mesh)
+                    q, k, v = kl.kda_qkv(kl.kda_conv(p, rows_in, cfg), cfg)
+                with jax.named_scope("kda_gate"):
+                    g, beta = kl.kda_gates(p, u, cfg)
+                if not rows.chunked:
+                    with jax.named_scope("kda_update"):
+                        o = decode_states(q, k, v, g, beta, layer)
+                else:
+                    with jax.named_scope("kda_chunk"):
+                        o = chunk_states(q, k, v, g, beta, layer)
+                with jax.named_scope("kda_out"):
+                    return x + kl.kda_out(p, o, u, cfg)
+
+        def latent_mixer(p, x, layer):
+            nonlocal latent
+            with jax.named_scope("attn"):
+                y, latent = latent_sublayer(p, x, pos, latent, layer, ctx,
+                                            cfg, mesh)
+                with jax.named_scope("latent_proj"):
+                    return x + y
+
+        row_live = live.reshape(S * K)
+        zero = jnp.zeros((), jnp.int32)
+        pairs, most, empty = zero, zero, zero
+        at = {KDA: 0, kl.LATENT: 0}
+        for l, p in enumerate(params["layers"]):
+            kind = cfg.layer_kinds[l]
+            x = (kda_mixer if kind == KDA else latent_mixer)(p, x, at[kind])
+            at[kind] += 1
+            if l < cfg.num_dense_layers:
+                with jax.named_scope("mlp"):
+                    h = rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
+                    x = x + swiglu(h, p["mlp_gate"], p["mlp_up"],
+                                   p["mlp_down"])
+                continue
+            h = rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
+            # ``paged_kernel`` is "this path runs its Pallas kernels": the
+            # attend, the state update and the grouped expert product alike.
+            y, counts = share.expert_layer(
+                p, h.reshape(S * K, H), cfg.routing, kernel=paged_kernel,
+                row_live=row_live)
+            x = x + y.reshape(S, K, H)
+            pairs = pairs + counts.sum()
+            most = jnp.maximum(most, counts.max())
+            empty = empty + (counts == 0).sum()
+        return x, (latent, state, conv), (
+            pairs, most, empty, row_live.sum().astype(jnp.int32))
+
+    @jax.named_scope("lm_head")
     def head(self, params, h):
-        return _head(params, h, self.cfg)
+        h = rms_norm(h, params["final_norm"], self.cfg.rms_norm_eps)
+        return jnp.dot(h, params["lm_head"].astype(h.dtype).T,
+                       preferred_element_type=jnp.float32)
 
 
 register(KimiLinearConfig, KimiLinearServed)
 
-__all__ = ["KimiLinearServed", "LATENT_CLASS", "STATE_CLASS", "conv_tile",
-           "KDA_CHUNK"]
+__all__ = ["KimiLinearServed", "LATENT_CLASS", "STATE_CLASS", "KDA_CHUNK"]

@@ -43,8 +43,7 @@ import numpy as np
 from jax import lax
 
 from . import kv_cache
-from .served import (NEG_INF, ServedModel, group_shape, register,
-                     write_targets)
+from .served import NEG_INF, Rows, ServedModel, register, write_targets
 from ..models import deepseek_v3 as dsv3
 from ..models import hyper_connections as hyper
 from ..models.deepseek_v3 import DeepseekV3Config
@@ -157,130 +156,6 @@ def latent_sublayer(p, h, pos, pool, layer, ctx: LatentContext, cfg, mesh):
         return dsv3.matmul(o, p["wo"]), pool
 
 
-def _forward(params, pool, x, bt_g, pos_g, live, cfg: DeepseekV3Config,
-             paged_kernel: bool, mesh):
-    """All layers: x [S, K, H] (``[S, K, n, H]`` with ``hc_mult`` = n
-    residual streams) with its streams' tables bt_g [G, Sg, J],
-    row positions pos_g [G, Sg, K] and ``live`` [S, K]: the rows that are
-    traffic (a live stream's, and no padding).  The others write no cache
-    row, attend nothing, get no expert row and are not counted; what they
-    compute nobody reads.  Returns (x', pool', counters)."""
-    K = pos_g.shape[-1]
-    S, H = x.shape[0], x.shape[-1]
-    pos = pos_g.reshape(S, K)
-    ctx = latent_context(bt_g, pos_g, live, pool, paged_kernel, mesh)
-
-    # The residual path.  One stream: a sublayer reads x and adds to it.
-    # ``hc_mult`` streams: it reads a mixture of them and writes back
-    # through two more maps (models/hyper_connections.py), per token, so
-    # nothing of it enters the cache.  ``outer`` names the sublayer's scope
-    # where the caller is not inside it; ``plain`` is where the one-stream
-    # add is filed.
-    def read(p, sub, x, outer=None):
-        if cfg.hyper is None:
-            return x, None
-        with _scope(outer), jax.named_scope("hc"):
-            with jax.named_scope("hc_maps"):
-                m = dsv3.hc_maps(p, sub, x, cfg)
-            with jax.named_scope("hc_pre"):
-                return hyper.mix_in(m, x), m
-
-    def write(m, x, y, outer=None, plain=None):
-        if m is None:
-            with _scope(plain):
-                return x + y
-        with _scope(outer), jax.named_scope("hc"), \
-                jax.named_scope("hc_post"):
-            return hyper.mix_out(m, x, y)
-
-    def res_error(m_attn, m_ffn):
-        """A layer's scan output: what Sinkhorn left of its two maps (None
-        on one stream)."""
-        if cfg.hyper is None:
-            return None
-        return jnp.maximum(hyper.res_error(m_attn, live),
-                           hyper.res_error(m_ffn, live))
-
-    def attention(p, x, pool, layer):
-        with jax.named_scope("attn"):
-            h, m = read(p, "attn", x)
-            y, pool = latent_sublayer(p, h, pos, pool, layer, ctx, cfg,
-                                      mesh)
-            x = write(m, x, y, plain="latent_proj")
-        return x, pool, m
-
-    def dense_layer(carry, layer_in):
-        p, layer = layer_in
-        x, pool, m_attn = attention(p, *carry, layer)
-        with jax.named_scope("mlp"):
-            h, m = read(p, "ffn", x)
-            h = dsv3.rms_norm(h, p["post_norm"], cfg.rms_norm_eps)
-            x = write(m, x, dsv3.swiglu(h, p["mlp_gate"], p["mlp_up"],
-                                        p["mlp_down"]))
-        return (x, pool), res_error(m_attn, m)
-
-    Ld, Le = cfg.num_dense_layers, cfg.num_moe_layers
-    (x, pool), err_dense = lax.scan(
-        dense_layer, (x, pool),
-        (params["dense"], jnp.arange(Ld, dtype=jnp.int32)))
-
-    experts = {k: params["moe"][k] for k in _EXPERT_KEYS}
-    row_live = live.reshape(S * K)
-
-    def moe_layer(carry, layer_in):
-        p, l = layer_in
-        x, pool, (pairs, most, empty) = carry
-        x, pool, m_attn = attention(p, x, pool, Ld + l)
-        h, m = read(p, "ffn", x, outer="moe")
-        h = dsv3.rms_norm(h, p["post_norm"], cfg.rms_norm_eps)
-        # ``paged_kernel`` is "this path runs its Pallas kernels": the
-        # attend, the row write and the grouped expert product alike.
-        y, counts = share.expert_layer(
-            dict(p, **experts), h.reshape(S * K, H), cfg.routing,
-            kernel=paged_kernel, layer=l, row_live=row_live)
-        x = write(m, x, y.reshape(S, K, H), outer="moe")
-        stats = (pairs + counts.sum(), jnp.maximum(most, counts.max()),
-                 empty + (counts == 0).sum())
-        return (x, pool, stats), res_error(m_attn, m)
-
-    zero = jnp.zeros((), jnp.int32)
-    (x, pool, stats), err_moe = lax.scan(
-        moe_layer, (x, pool, (zero, zero, zero)),
-        ({k: v for k, v in params["moe"].items() if k not in _EXPERT_KEYS},
-         jnp.arange(Le, dtype=jnp.int32)))
-    stats += (row_live.sum().astype(jnp.int32),)
-    if cfg.hyper is not None:
-        # A float among the int32 counters: its bits ride the token fetch.
-        err = jnp.maximum(err_dense.max(), err_moe.max())
-        stats += (lax.bitcast_convert_type(err, jnp.int32),)
-    return x, pool, stats
-
-
-@jax.named_scope("lm_head")
-def _head(params, h, cfg):
-    """Logits of ``h [..., H]`` (``[..., n, H]``: the streams' sum)."""
-    if cfg.hyper is not None:
-        with jax.named_scope("hc_collapse"):
-            h = hyper.collapse(h)
-    h = dsv3.rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-    logits = jnp.dot(h, params["lm_head"].astype(h.dtype).T,
-                     preferred_element_type=jnp.float32)
-    if cfg.vocab_rows == cfg.vocab_size:
-        return logits
-    # Padding rows of a sliced vocabulary are no tokens: never sampled.
-    ids = lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
-    return jnp.where(ids < cfg.vocab_size, logits, NEG_INF)
-
-
-@jax.named_scope("embed")
-def _embed(params, tokens, cfg):
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    if cfg.hyper is None:
-        return x
-    with jax.named_scope("hc_expand"):
-        return hyper.expand(x, cfg.hyper.mult)
-
-
 class LatentServed(ServedModel):
     """See the module docstring."""
     counter_names = ("moe_held_pairs", "moe_held_max", "moe_held_empty",
@@ -355,47 +230,127 @@ class LatentServed(ServedModel):
                 rows[:, 4].astype(np.int32).view(np.float32).max())
         return args
 
-    # -- programs ------------------------------------------------------ #
-    def verify(self, params, pools, tokens, lengths, block_tables, *,
-               num_groups, paged_kernel, mesh=None):
+    # -- the block ------------------------------------------------------ #
+    @jax.named_scope("embed")
+    def embed(self, params, tokens, pos):
+        x = params["embed"].astype(self.cfg.dtype)[tokens]
+        if self.cfg.hyper is None:
+            return x
+        with jax.named_scope("hc_expand"):
+            return hyper.expand(x, self.cfg.hyper.mult)
+
+    def forward(self, params, pools, x, rows: Rows, *, paged_kernel, mesh):
+        """x [S, K, H] (``[S, K, n, H]`` with ``hc_mult`` = n residual
+        streams); ``pools``: (latent,)."""
         cfg = self.cfg
-        K = tokens.shape[1]
-        pos = lengths[:, None] + jnp.arange(K, dtype=jnp.int32)[None]
-        live = jnp.broadcast_to(block_tables[:, :1] >= 0, tokens.shape)
-        x, pool, counters = _forward(
-            params, pools[0], _embed(params, tokens, cfg),
-            group_shape(block_tables, num_groups),
-            group_shape(pos, num_groups), live, cfg, paged_kernel, mesh)
-        return _head(params, x, cfg), (pool,), counters
+        pool, = pools
+        live = rows.live
+        K = rows.positions.shape[-1]
+        S, H = x.shape[0], x.shape[-1]
+        pos = rows.positions.reshape(S, K)
+        ctx = latent_context(rows.tables, rows.positions, live, pool,
+                             paged_kernel, mesh)
 
-    def decode(self, params, pools, tokens, lengths, block_tables, *,
-               num_groups, paged_kernel, mesh=None):
-        logits, pools, counters = self.verify(
-            params, pools, tokens[:, None], lengths, block_tables,
-            num_groups=num_groups, paged_kernel=paged_kernel, mesh=mesh)
-        return logits[:, 0], pools, counters
+        # The residual path.  One stream: a sublayer reads x and adds to it.
+        # ``hc_mult`` streams: it reads a mixture of them and writes back
+        # through two more maps (models/hyper_connections.py), per token, so
+        # nothing of it enters the cache.  ``outer`` names the sublayer's scope
+        # where the caller is not inside it; ``plain`` is where the one-stream
+        # add is filed.
+        def read(p, sub, x, outer=None):
+            if cfg.hyper is None:
+                return x, None
+            with _scope(outer), jax.named_scope("hc"):
+                with jax.named_scope("hc_maps"):
+                    m = dsv3.hc_maps(p, sub, x, cfg)
+                with jax.named_scope("hc_pre"):
+                    return hyper.mix_in(m, x), m
 
-    def prefill_chunk(self, params, pools, tokens, bt_rows, start,
-                      last_idx, active, *, paged_kernel, mesh=None):
-        """``decode.gpt2_prefill_chunk_paged``'s contract; rows past
-        ``last_idx`` (a last chunk's padding) are dead rows."""
-        cfg = self.cfg
-        G, Cn = tokens.shape
-        pos = start[:, None] + jnp.arange(Cn, dtype=jnp.int32)[None]
-        bt_g = jnp.where(active[:, None, None] > 0, bt_rows[:, None],
-                         kv_cache.DEAD_BLOCK)
-        live = (active[:, None] > 0) & (lax.broadcasted_iota(
-            jnp.int32, (G, Cn), 1) <= last_idx[:, None])
-        x, pool, counters = _forward(
-            params, pools[0], _embed(params, tokens, cfg), bt_g,
-            pos[:, None, :], live, cfg, paged_kernel, mesh)
-        oh = (lax.broadcasted_iota(jnp.int32, (G, Cn), 1)
-              == last_idx[:, None]).astype(x.dtype)
-        h_last = jnp.einsum("gc,gch->gh", oh, x.reshape(G, Cn, -1))
-        return h_last.reshape((G,) + x.shape[2:]), (pool,), counters
+        def write(m, x, y, outer=None, plain=None):
+            if m is None:
+                with _scope(plain):
+                    return x + y
+            with _scope(outer), jax.named_scope("hc"), \
+                    jax.named_scope("hc_post"):
+                return hyper.mix_out(m, x, y)
 
+        def res_error(m_attn, m_ffn):
+            """A layer's scan output: what Sinkhorn left of its two maps (None
+            on one stream)."""
+            if cfg.hyper is None:
+                return None
+            return jnp.maximum(hyper.res_error(m_attn, live),
+                               hyper.res_error(m_ffn, live))
+
+        def attention(p, x, pool, layer):
+            with jax.named_scope("attn"):
+                h, m = read(p, "attn", x)
+                y, pool = latent_sublayer(p, h, pos, pool, layer, ctx, cfg,
+                                          mesh)
+                x = write(m, x, y, plain="latent_proj")
+            return x, pool, m
+
+        def dense_layer(carry, layer_in):
+            p, layer = layer_in
+            x, pool, m_attn = attention(p, *carry, layer)
+            with jax.named_scope("mlp"):
+                h, m = read(p, "ffn", x)
+                h = dsv3.rms_norm(h, p["post_norm"], cfg.rms_norm_eps)
+                x = write(m, x, dsv3.swiglu(h, p["mlp_gate"], p["mlp_up"],
+                                            p["mlp_down"]))
+            return (x, pool), res_error(m_attn, m)
+
+        Ld, Le = cfg.num_dense_layers, cfg.num_moe_layers
+        (x, pool), err_dense = lax.scan(
+            dense_layer, (x, pool),
+            (params["dense"], jnp.arange(Ld, dtype=jnp.int32)))
+
+        experts = {k: params["moe"][k] for k in _EXPERT_KEYS}
+        row_live = live.reshape(S * K)
+
+        def moe_layer(carry, layer_in):
+            p, l = layer_in
+            x, pool, (pairs, most, empty) = carry
+            x, pool, m_attn = attention(p, x, pool, Ld + l)
+            h, m = read(p, "ffn", x, outer="moe")
+            h = dsv3.rms_norm(h, p["post_norm"], cfg.rms_norm_eps)
+            # ``paged_kernel`` is "this path runs its Pallas kernels": the
+            # attend, the row write and the grouped expert product alike.
+            y, counts = share.expert_layer(
+                dict(p, **experts), h.reshape(S * K, H), cfg.routing,
+                kernel=paged_kernel, layer=l, row_live=row_live)
+            x = write(m, x, y.reshape(S, K, H), outer="moe")
+            stats = (pairs + counts.sum(), jnp.maximum(most, counts.max()),
+                     empty + (counts == 0).sum())
+            return (x, pool, stats), res_error(m_attn, m)
+
+        zero = jnp.zeros((), jnp.int32)
+        (x, pool, stats), err_moe = lax.scan(
+            moe_layer, (x, pool, (zero, zero, zero)),
+            ({k: v for k, v in params["moe"].items() if k not in _EXPERT_KEYS},
+             jnp.arange(Le, dtype=jnp.int32)))
+        stats += (row_live.sum().astype(jnp.int32),)
+        if cfg.hyper is not None:
+            # A float among the int32 counters: its bits ride the token fetch.
+            err = jnp.maximum(err_dense.max(), err_moe.max())
+            stats += (lax.bitcast_convert_type(err, jnp.int32),)
+        return x, (pool,), stats
+
+    @jax.named_scope("lm_head")
     def head(self, params, h):
-        return _head(params, h, self.cfg)
+        """Logits of ``h [..., H]`` (``[..., n, H]``: the streams' sum)."""
+        cfg = self.cfg
+        if cfg.hyper is not None:
+            with jax.named_scope("hc_collapse"):
+                h = hyper.collapse(h)
+        h = dsv3.rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+        logits = jnp.dot(h, params["lm_head"].astype(h.dtype).T,
+                         preferred_element_type=jnp.float32)
+        if cfg.vocab_rows == cfg.vocab_size:
+            return logits
+        # Padding rows of a sliced vocabulary are no tokens: never sampled.
+        ids = lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+        return jnp.where(ids < cfg.vocab_size, logits, NEG_INF)
 
 
 register(DeepseekV3Config, LatentServed)

@@ -10,8 +10,9 @@ plain recurrent form and a scatter that drops dead slots off it);
 ``prefill_chunk`` carries a stream's state from chunk to chunk in the
 chunked form, starting from zeros at position 0 and from whatever the
 page holds otherwise — a snapshot the engine copied there, or the chunk
-before.  A recurrent state cannot be rolled back over rejected drafts:
-``verify`` raises, and ``inference.spec_k`` must be 0.
+before.  A recurrent state cannot be rolled back over rejected drafts
+(``rolls_back`` is False: ``verify`` raises, and ``inference.spec_k`` must
+be 0).
 
 The stack is one scan that carries both pools beside the layer index.
 Scopes: ``embed``; ``attn`` > ``qkv_proj``, ``state_update`` (decode) /
@@ -26,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .served import CacheClass, ServedModel, register
+from .served import CacheClass, Rows, ServedModel, register
 from ..models import brumby
 from ..models.brumby import BrumbyConfig
 from ..ops import power_retention as pr
@@ -67,51 +68,10 @@ def _decode_states(state, norm, layer, pages, q, k, v, log_g, eps):
     return y.reshape(q.shape), state, norm
 
 
-def _forward(params, pools, x, positions, retention, cfg: BrumbyConfig):
-    """All layers: x ``[..., H]`` at ``positions [...]``; ``retention(
-    state, norm, layer, q, k, v, log_g) -> (y, state, norm)`` is the
-    program's own form (one token a stream, or a chunk of one)."""
-    state, norm = pools
-
-    def layer_fn(carry, layer_in):
-        p, layer = layer_in
-        x, state, norm = carry
-        with jax.named_scope("attn"):
-            with jax.named_scope("qkv_proj"):
-                h = brumby.rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
-                q, k, v, log_g = brumby.retention_projections(
-                    p, h, positions, cfg)
-            y, state, norm = retention(state, norm, layer, q, k, v, log_g)
-            with jax.named_scope("out_proj"):
-                y = y.astype(x.dtype).reshape(x.shape[:-1] + (-1,))
-                x = x + brumby.matmul(y, p["wo"])
-        with jax.named_scope("mlp"):
-            h = brumby.rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
-            x = x + brumby.swiglu(h, p["mlp_gate"], p["mlp_up"],
-                                  p["mlp_down"])
-        return (x, state, norm), None
-
-    L = cfg.num_hidden_layers
-    (x, state, norm), _ = lax.scan(
-        layer_fn, (x, state, norm),
-        (params["layers"], jnp.arange(L, dtype=jnp.int32)))
-    return x, (state, norm)
-
-
-@jax.named_scope("lm_head")
-def _head(params, h, cfg):
-    h = brumby.rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-    return jnp.dot(h, params["lm_head"].astype(h.dtype).T,
-                   preferred_element_type=jnp.float32)
-
-
-@jax.named_scope("embed")
-def _embed(params, tokens, cfg):
-    return params["embed"].astype(cfg.dtype)[tokens]
-
-
 class RetentionServed(ServedModel):
     """See the module docstring."""
+    rolls_back = False
+
     @property
     def max_positions(self) -> int:
         return int(self.cfg.max_position_embeddings)
@@ -169,75 +129,90 @@ class RetentionServed(ServedModel):
                                      self.cfg.num_key_value_heads,
                                      self.cfg.head_dim) + (0,)
 
-    # -- programs ------------------------------------------------------ #
-    def verify(self, params, pools, tokens, lengths, block_tables, *,
-               num_groups, paged_kernel, mesh=None):
-        raise NotImplementedError(
-            "a retention layer's state cannot be rolled back over "
-            "rejected drafts: set inference.spec_k to 0")
+    # -- the block ------------------------------------------------------ #
+    @jax.named_scope("embed")
+    def embed(self, params, tokens, pos):
+        return params["embed"].astype(self.cfg.dtype)[tokens]
 
-    def decode(self, params, pools, tokens, lengths, block_tables, *,
-               num_groups, paged_kernel, mesh=None):
+    def forward(self, params, pools, x, rows: Rows, *, paged_kernel, mesh):
+        """``pools``: (state, norm); ``rows.tables [G, Sg, 1]`` is each
+        stream's page.  The decode program (one row a stream) rewrites every
+        live stream's page; a chunk (one stream a group) at position 0
+        starts from zeros, any other from what the page holds, and rows
+        that are not live neither decay the state nor add to it."""
         cfg = self.cfg
-        S = tokens.shape[0]
-        G, Sg = num_groups, S // num_groups
-        pages = block_tables[:, 0].reshape(G, Sg)
-        grouped = lambda a: a.reshape((G, Sg) + a.shape[1:])   # noqa: E731
+        G, Sg, K = rows.positions.shape
+        pages = rows.tables[:, :, 0]                              # [G, Sg]
+        if rows.chunked:
+            positions, live = rows.positions[:, 0], rows.live     # [G, K]
 
-        @jax.named_scope("state_update")
-        def retention(state, norm, layer, q, k, v, log_g):
-            args = (state, norm, layer, pages, grouped(q), grouped(k),
-                    grouped(v), grouped(log_g))
-            if paged_kernel:
-                y, state, norm = pr.state_update(
-                    *args, eps=cfg.retention_eps, mesh=mesh)
-            else:
-                y, state, norm = _decode_states(*args, cfg.retention_eps)
-            return y.reshape(q.shape), state, norm
+            @jax.named_scope("retention_chunk")
+            def retention(state, norm, layer, q, k, v, log_g):
+                ys = []
+                for g in range(G):
+                    page = jnp.maximum(pages[g, 0], 0)
+                    S0 = _page_of(state, layer, g, page)
+                    z0 = _page_of(norm, layer, g, page)
+                    carried = positions[g, 0] > 0
+                    y, S1, z1 = pr.chunked_retention(
+                        jnp.where(carried, S0, 0.0),
+                        jnp.where(carried, z0, 0.0), q[g], k[g], v[g],
+                        log_g[g], live[g], cfg.retention_eps)
+                    ok = pages[g, 0] >= 0         # (an active group's page)
+                    state = _put_page(state, jnp.where(ok, S1, S0), layer,
+                                      g, page)
+                    norm = _put_page(norm, jnp.where(ok, z1, z0), layer, g,
+                                     page)
+                    ys.append(y)
+                return jnp.stack(ys), state, norm
+        else:
+            # one row a stream: the layers run without the K = 1 axis
+            x, positions = x[:, 0], rows.positions.reshape(G * Sg)
+            grouped = lambda a: a.reshape((G, Sg) + a.shape[1:])  # noqa: E731
 
-        x, pools = _forward(params, pools, _embed(params, tokens, cfg),
-                            lengths, retention, cfg)
-        return _head(params, x, cfg), pools, None
+            @jax.named_scope("state_update")
+            def retention(state, norm, layer, q, k, v, log_g):
+                args = (state, norm, layer, pages, grouped(q), grouped(k),
+                        grouped(v), grouped(log_g))
+                if paged_kernel:
+                    y, state, norm = pr.state_update(
+                        *args, eps=cfg.retention_eps, mesh=mesh)
+                else:
+                    y, state, norm = _decode_states(*args, cfg.retention_eps)
+                return y.reshape(q.shape), state, norm
 
-    def prefill_chunk(self, params, pools, tokens, bt_rows, start,
-                      last_idx, active, *, paged_kernel, mesh=None):
-        """``decode.gpt2_prefill_chunk_paged``'s contract; ``bt_rows [G,
-        1]`` is each group's page.  A chunk at position 0 starts from
-        zeros, any other from what the page holds; rows past ``last_idx``
-        neither decay the state nor add to it."""
-        cfg = self.cfg
-        G, Cn = tokens.shape
-        cols = lax.broadcasted_iota(jnp.int32, (G, Cn), 1)
-        pos = start[:, None] + cols
-        live = (active[:, None] > 0) & (cols <= last_idx[:, None])
+        state, norm = pools
 
-        @jax.named_scope("retention_chunk")
-        def retention(state, norm, layer, q, k, v, log_g):
-            ys = []
-            for g in range(G):
-                page = jnp.maximum(bt_rows[g, 0], 0)
-                S0 = _page_of(state, layer, g, page)
-                z0 = _page_of(norm, layer, g, page)
-                carried = start[g] > 0
-                y, S1, z1 = pr.chunked_retention(
-                    jnp.where(carried, S0, 0.0), jnp.where(carried, z0, 0.0),
-                    q[g], k[g], v[g], log_g[g], live[g], cfg.retention_eps)
-                ok = (active[g] > 0) & (bt_rows[g, 0] >= 0)
-                state = _put_page(state, jnp.where(ok, S1, S0), layer, g,
-                                  page)
-                norm = _put_page(norm, jnp.where(ok, z1, z0), layer, g,
-                                 page)
-                ys.append(y)
-            return jnp.stack(ys), state, norm
+        def layer_fn(carry, layer_in):
+            p, layer = layer_in
+            x, state, norm = carry
+            with jax.named_scope("attn"):
+                with jax.named_scope("qkv_proj"):
+                    h = brumby.rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
+                    q, k, v, log_g = brumby.retention_projections(
+                        p, h, positions, cfg)
+                y, state, norm = retention(state, norm, layer, q, k, v,
+                                           log_g)
+                with jax.named_scope("out_proj"):
+                    y = y.astype(x.dtype).reshape(x.shape[:-1] + (-1,))
+                    x = x + brumby.matmul(y, p["wo"])
+            with jax.named_scope("mlp"):
+                h = brumby.rms_norm(x, p["post_norm"], cfg.rms_norm_eps)
+                x = x + brumby.swiglu(h, p["mlp_gate"], p["mlp_up"],
+                                      p["mlp_down"])
+            return (x, state, norm), None
 
-        x, pools = _forward(params, pools, _embed(params, tokens, cfg), pos,
-                            retention, cfg)
-        oh = (cols == last_idx[:, None]).astype(x.dtype)
-        h_last = jnp.einsum("gc,gch->gh", oh, x)
-        return h_last, pools, None
+        (x, state, norm), _ = lax.scan(
+            layer_fn, (x, state, norm),
+            (params["layers"],
+             jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32)))
+        return x if rows.chunked else x[:, None], (state, norm), None
 
+    @jax.named_scope("lm_head")
     def head(self, params, h):
-        return _head(params, h, self.cfg)
+        h = brumby.rms_norm(h, params["final_norm"], self.cfg.rms_norm_eps)
+        return jnp.dot(h, params["lm_head"].astype(h.dtype).T,
+                       preferred_element_type=jnp.float32)
 
 
 register(BrumbyConfig, RetentionServed)

@@ -19,20 +19,32 @@ ask a ``ServedModel`` for:
   block is a PAGE, one stream's fixed-size state of a layer — the retention
   family's ``state`` and ``norm`` — a block table is one page wide, and
   the prefix cache keeps snapshots: ``inference/kv_cache.py``);
-- **its programs**, each a pure function over ``(params, pools, ...)``
-  that writes the new rows into the pools in place and returns ``(logits,
-  pools)`` (and the model's counters, see below): ``decode`` (one token a
-  slot), ``verify`` (K tokens a slot, speculative), ``prefill_chunk`` (one
-  chunk of one slot a group; a model whose per-stream state is a gather of
-  a chunk's rows may also freeze it at a row INSIDE the chunk into a second
-  page, and says so: ``freezes_in_chunk``).  ``prefill_chunk`` alone stops
-  short of the logits: it returns the hidden row at ``last_idx``, and the
-  model's ``head`` turns such rows into logits, because only the chunk
-  program that ENDS a prompt has a reader for them.  The engine's
-  ``prefill_step`` applies ``head`` and samples under a branch on an
-  operand the host sets (``head_and_sample``): a program that ends no
-  prompt returns ZEROS for its tokens ``[G]`` and its logits ``[G, V]``,
-  and nothing fetches either;
+- **its block**, as three hooks the engine's three programs are written
+  over, ONCE, in this class: ``embed(params, tokens, pos)``,
+  ``forward(params, pools, x, rows, *, paged_kernel, mesh) -> (x, pools,
+  counters or None)`` — every layer, writing the new rows into the pools in
+  place — and ``head(params, h)``, the final norm and unembedding.  The
+  programs are ``decode`` (one token a slot), ``verify`` (K tokens a slot,
+  speculative; ``decode`` is its K = 1) and ``prefill_chunk`` (one chunk of
+  one slot a group).  What rows a program hands the layers is decided here
+  and nowhere else, in ``Rows``' two constructors: the streams' tables
+  (dead for an inactive group), every row's position, which rows are
+  traffic (``live``), whether they are a prefill chunk, and the snapshot a
+  chunk freezes (a model whose per-stream state is a gather of a chunk's
+  rows may freeze it at a row INSIDE the chunk into a second page, and
+  says so: ``freezes_in_chunk``; one whose cache cannot be rolled back over
+  rejected drafts says ``rolls_back = False`` and ``verify`` refuses).
+  ``prefill_chunk`` alone stops short of the logits: it returns the hidden
+  row at ``last_idx``, because only the chunk program that ENDS a prompt
+  has a reader for them.  The engine's ``prefill_step`` applies ``head``
+  and samples under a branch on an operand the host sets
+  (``head_and_sample``): a program that ends no prompt returns ZEROS for
+  its tokens ``[G]`` and its logits ``[G, V]``, and nothing fetches either.
+  What several families' ``forward`` share is beside the class:
+  ``stream_pages`` / ``filter_rows`` / ``filter_tile`` (a ``per_stream``
+  class's page bookkeeping and a short filter's rows through it) here, the
+  attention branch over K/V pages of grouped heads in
+  ``inference/kv_pages.py``, the latent sublayer in ``inference/latent.py``;
 - **the cache's cost a token** for the engine's analytic counters:
   ``cache_cost(keys, ...)`` = (FLOPs, cache bytes) a layer spends on one
   query token, which MAY depend on the ``keys`` rows in reach (an attend:
@@ -92,6 +104,11 @@ class ServedModel:
     # boundary; where a model says no (the default) it cuts the prompt
     # there and copies the stream's page.
     freezes_in_chunk: bool = False
+    # Whether the cache can be rolled back over rejected drafts (rows past
+    # the accepted ones are overwritten before anything reads them).  A
+    # fixed-size state a stream cannot: ``verify`` refuses, and
+    # ``inference.spec_k`` must be 0.
+    rolls_back: bool = True
 
     def __init__(self, cfg):
         self.cfg = cfg
@@ -220,32 +237,104 @@ class ServedModel:
                 "table_widths when it sizes the tables")
         return widths
 
+    # -- the model's own: what the programs below are written over ------ #
+    def embed(self, params, tokens, pos):
+        """Hidden rows ``[..., H]`` (or more trailing axes: several residual
+        streams) of ``tokens`` at positions ``pos``, both ``[S, K]`` (a
+        chunk: ``[G, C]``), under the scope ``embed``."""
+        raise NotImplementedError
+
+    def forward(self, params, pools: Sequence, x, rows: "Rows", *,
+                paged_kernel: bool, mesh):
+        """All layers over ``x [S, K, ...]`` and its ``rows``: the new cache
+        rows and pages written into ``pools`` in place, for live rows only
+        — a dead row writes nothing, attends nothing and is not counted;
+        what it computes nobody reads.  Returns (``x'`` as ``head`` takes
+        it, ``pools'``, the model's counters or None)."""
+        raise NotImplementedError
+
+    def head(self, params, h):
+        """fp32 logits ``[..., V]`` of hidden rows as ``forward`` leaves
+        them: the model's final norm and unembedding, under the scope
+        ``lm_head``."""
+        raise NotImplementedError
+
     # -- the programs -------------------------------------------------- #
     def decode(self, params, pools: Sequence, tokens, lengths,
                block_tables, *, num_groups: int, paged_kernel: bool,
                mesh=None):
-        raise NotImplementedError
+        """One token a slot, the K = 1 ``verify``: ``tokens`` / ``lengths``
+        ``[S]`` -> (logits ``[S, V]`` fp32, pools, counters).  The caller
+        advances ``lengths`` for the slots it considers active."""
+        logits, pools, counters = self._rows_a_slot(
+            params, pools, tokens[:, None], lengths, block_tables,
+            num_groups, paged_kernel, mesh)
+        return logits[:, 0], pools, counters
 
     def verify(self, params, pools: Sequence, tokens, lengths,
                block_tables, *, num_groups: int, paged_kernel: bool,
                mesh=None):
-        raise NotImplementedError
+        """The speculative verify step: ``tokens [S, K]`` — column 0 each
+        slot's pending last token, columns 1.. the drafted continuation;
+        token i sits at position ``lengths[s] + i``.  Writes all K tokens'
+        rows through the block table, attends each under its own causal
+        row, and returns fp32 logits ``[S, K, V]`` (never a ``[max_len,
+        vocab]`` tensor); with ``spec_accept`` its greedy output is
+        bit-identical to single-token decode."""
+        if not self.rolls_back:
+            raise NotImplementedError(
+                f"{type(self).__name__}: a state a stream cannot be rolled "
+                "back over rejected drafts: set inference.spec_k to 0")
+        return self._rows_a_slot(params, pools, tokens, lengths,
+                                 block_tables, num_groups, paged_kernel,
+                                 mesh)
+
+    def _rows_a_slot(self, params, pools, tokens, lengths, block_tables,
+                     num_groups, paged_kernel, mesh):
+        rows = Rows.of_slots(lengths, block_tables, tokens.shape[1],
+                             num_groups, self._widths(block_tables))
+        x, pools, counters = self._layers(params, pools, tokens, rows,
+                                          paged_kernel, mesh)
+        return self.head(params, x), pools, counters
+
+    def _layers(self, params, pools, tokens, rows, paged_kernel, mesh):
+        x = self.embed(params, tokens, rows.positions.reshape(tokens.shape))
+        return self.forward(params, pools, x, rows,
+                            paged_kernel=paged_kernel, mesh=mesh)
 
     def prefill_chunk(self, params, pools: Sequence, tokens, bt_rows,
-                      start, last_idx, active, *, paged_kernel: bool,
-                      mesh=None):
-        """One chunk of one slot a group: writes the chunk's rows and
-        returns (the hidden row at ``last_idx`` as ``head`` takes it, ``[G,
-        ...]``; pools; counters) — NOT logits: the caller applies ``head``
-        where a prompt ends (``head_and_sample``), and a chunk program
-        that ends none hands back zeros in their place."""
-        raise NotImplementedError
+                      start, last_idx, active, freeze_idx=None,
+                      freeze_page=None, *, paged_kernel: bool, mesh=None):
+        """Group-batched chunked prefill: one prompt chunk for ONE slot a
+        group.  ``tokens [G, C]``; ``bt_rows [G, W]``, each group's target
+        slot's table row; ``start`` / ``last_idx`` / ``active`` ``[G]``.
+        Writes the chunk's rows through its group's table and attends
+        against the slot's whole cached row under the global-position
+        causal mask, so any chunk length divides any prompt without shape
+        polymorphism.  An inactive group computes garbage that writes
+        nowhere — the uniform-program rule that keeps ONE compiled shape for
+        any admission pattern — and rows past ``last_idx`` (a last chunk's
+        padding) are dead: whatever cache rows a model lets them write, the
+        next token's decode write overwrites before any attend reaches them.
+        A state a stream starts from what the stream's own page holds (a
+        snapshot the engine copied there, or the chunk before) and from
+        zeros at position 0.  ``freezes_in_chunk``: a group's state as it
+        stands after chunk row ``freeze_idx`` (the last row of a block)
+        goes into page ``freeze_page`` as well (``DEAD_BLOCK``: none in
+        this chunk; without the operands: the stream's own page only).
 
-    def head(self, params, h):
-        """fp32 logits ``[..., V]`` of the hidden rows ``prefill_chunk``
-        returns: the model's final norm and unembedding, under the scope
-        ``lm_head`` (what ``decode`` and ``verify`` end in)."""
-        raise NotImplementedError
+        Returns (the hidden row at ``last_idx`` as ``head`` takes it, ``[G,
+        ...]``; pools; counters) — NOT logits: only ONE position a group
+        ever projects through the unembedding (never a ``[C, vocab]``
+        tensor), and only in the chunk program that ends a prompt: the
+        caller applies ``head`` under a branch (``head_and_sample``)."""
+        rows = Rows.of_chunk(
+            bt_rows, start, last_idx, active, tokens.shape[1],
+            self._widths(bt_rows),
+            None if freeze_idx is None else (freeze_idx, freeze_page))
+        x, pools, counters = self._layers(params, pools, tokens, rows,
+                                          paged_kernel, mesh)
+        return Rows.last(x, last_idx), pools, counters
 
 
 def register(config_type: type,
@@ -310,6 +399,64 @@ def write_targets(bt_g: jax.Array, pos_g: jax.Array, block_size: int
                                (G, Sg, K, bt_g.shape[-1]))
     blk, off = kv_cache.positions_to_blocks(bt_rows, pos_g, block_size)
     return blk.reshape(G, Sg * K), off.reshape(G, Sg * K)
+
+
+class Rows(NamedTuple):
+    """What a program hands every layer, for S streams (``Sg`` a group) of
+    K rows each: ``tables`` [G, Sg, W], the streams' table rows
+    (``DEAD_BLOCK`` throughout for a dead slot or an inactive group: its
+    writes land nowhere), the model's classes' columns side by side,
+    ``widths`` wide (``cache_classes`` order); ``positions`` [G, Sg, K];
+    ``live`` [S, K]: the rows that are traffic — a live stream's, and no
+    padding; a stream's live rows come first; ``chunked``: the rows are a
+    prefill chunk of one stream a group (a state advances by a scan over
+    them) and not one row — or K drafted ones — of every slot; ``freeze``:
+    (row [S], page [S]) — a stream's state as it stands after chunk row
+    ``row`` goes into ``page`` too (a snapshot; ``DEAD_BLOCK``: none), or
+    None.  Built by the two constructors below and by nothing else: what
+    rows a program computes is decided here."""
+    tables: jax.Array
+    widths: Tuple[int, ...]
+    positions: jax.Array
+    live: jax.Array
+    chunked: bool = False
+    freeze: Optional[Tuple[jax.Array, jax.Array]] = None
+
+    @classmethod
+    def of_slots(cls, lengths, block_tables, K: int, num_groups: int,
+                 widths) -> "Rows":
+        """K rows of every slot: row i of slot s sits at ``lengths[s] + i``;
+        a slot whose table ``[S, W]`` holds no block is dead."""
+        pos = lengths[:, None] + jnp.arange(K, dtype=jnp.int32)[None]
+        live = jnp.broadcast_to(
+            (block_tables >= 0).any(axis=1, keepdims=True), pos.shape)
+        return cls(group_shape(block_tables, num_groups), widths,
+                   group_shape(pos, num_groups), live)
+
+    @classmethod
+    def of_chunk(cls, bt_rows, start, last_idx, active, width: int, widths,
+                 freeze=None) -> "Rows":
+        """A chunk of ``width`` rows of ONE slot a group, from position
+        ``start``: a group that is not ``active`` is dead, rows past
+        ``last_idx`` are padding."""
+        G = bt_rows.shape[0]
+        pos = start[:, None] + jnp.arange(width, dtype=jnp.int32)[None]
+        tables = jnp.where(active[:, None, None] > 0, bt_rows[:, None],
+                           kv_cache.DEAD_BLOCK)
+        live = (active[:, None] > 0) & (lax.broadcasted_iota(
+            jnp.int32, (G, width), 1) <= last_idx[:, None])
+        return cls(tables, widths, pos[:, None, :], live, True, freeze)
+
+    @staticmethod
+    def last(x, last_idx) -> jax.Array:
+        """``x [G, C, ...]`` -> the row at ``last_idx [G]`` of each group's
+        chunk, ``[G, ...]``, as a one-hot contraction (no gather; any
+        trailing axes: the latent family's several residual streams)."""
+        G, C = x.shape[:2]
+        oh = (lax.broadcasted_iota(jnp.int32, (G, C), 1)
+              == last_idx[:, None]).astype(x.dtype)
+        picked = jnp.einsum("gc,gch->gh", oh, x.reshape(G, C, -1))
+        return picked.reshape((G,) + x.shape[2:])
 
 
 class StreamPages(NamedTuple):
@@ -378,6 +525,15 @@ def stream_pages(page: jax.Array, pos: jax.Array, live: jax.Array,
         jnp.arange(S, dtype=jnp.int32) // streams_a_group,
         jnp.maximum(page, 0), wrote, pos[:, 0] > 0, tuple(to), tuple(keep),
         keep_chunk)
+
+
+def filter_tile(held: int, width: int) -> Tuple[int, int, int]:
+    """A page's tile of one layer's short-filter rows as held: ``held``
+    rows (the filter's taps - 1) of ``width`` channels, row-major, in rows
+    of 128 lanes where they divide (a ``[held, width]`` minor pair would be
+    padded to the sublane tile) — the form ``ops.filter_rows.takes``."""
+    n = held * width
+    return (1, n // 128, 128) if n % 128 == 0 else (1, held, width)
 
 
 # ``filter_rows`` calls that lowered to the in-place kernel.  The choice is
@@ -504,6 +660,6 @@ def spec_accept(logits: jax.Array, tokens: jax.Array, key: jax.Array,
 
 __all__ = ["CacheClass", "ServedModel", "register", "served_model",
            "split_counters", "with_counters", "NEG_INF", "group_shape",
-           "write_targets", "StreamPages", "stream_pages", "filter_rows",
-           "filter_rows_lowered",
+           "write_targets", "Rows", "StreamPages", "stream_pages",
+           "filter_tile", "filter_rows", "filter_rows_lowered",
            "sample_tokens", "head_and_sample", "spec_accept"]

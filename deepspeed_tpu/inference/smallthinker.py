@@ -9,11 +9,11 @@ The cache is ``inference/afmoe.py``'s, declared and not coded for:
 ``cache_classes`` names ``full`` (unbounded) and ``window`` (reach =
 ``sliding_window_size``) from ``sliding_window_layout``, the engine gives
 each its pools, block table and allocator, and the attention branch's
-tables, write targets, plans, row writes and attends are that module's
-``paged_classes`` / ``write_and_attend``, called here (7 query heads a K/V
-head as ``group`` x K query rows of the same kernels).  ``AfmoeServed``'s
-three programs, K/V tiles, step counts and expert counters serve as they
-are; this module is the BLOCK:
+tables, write targets, plans, row writes and attends are
+``inference/kv_pages.py``'s ``paged_classes`` / ``write_and_attend``, called
+here (7 query heads a K/V head as ``group`` x K query rows of the same
+kernels).  ``AfmoeServed``'s K/V tiles, step counts, expert counters and
+head serve as they are; this module is the BLOCK:
 
     x = N_in(h);  plan = route + dispatch FROM x      (moe > router, dispatch)
     h = h + Attn(x)                                   (attn > ...)
@@ -38,9 +38,9 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from .afmoe import (FULL_CLASS, WINDOW_CLASS, AfmoeServed, paged_classes,
-                    write_and_attend)
-from .served import CacheClass, register
+from .afmoe import FULL_CLASS, WINDOW_CLASS, AfmoeServed
+from .kv_pages import write_and_attend
+from .served import CacheClass, Rows, register
 from ..models import smallthinker
 from ..models.blocks import matmul, rms_norm
 from ..models.smallthinker import SmallthinkerConfig
@@ -60,73 +60,8 @@ def _classes(cfg: SmallthinkerConfig) -> Tuple[CacheClass, ...]:
     return tuple(out)
 
 
-def _forward(params, pools, h, bt_g, pos_g, live, cfg: SmallthinkerConfig,
-             widths, paged_kernel: bool, mesh):
-    """All layers: h [S, K, H] with its streams' table rows bt_g [G, Sg,
-    W], row positions pos_g [G, Sg, K] and ``live`` [S, K], as
-    ``inference.afmoe._forward`` takes them.  Returns (h', pools',
-    counters)."""
-    G, Sg, K = pos_g.shape
-    S, H = G * Sg, h.shape[-1]
-    pos = pos_g.reshape(S, K)
-    pools = list(pools)
-    classes = paged_classes(
-        _classes(cfg), widths, pools, bt_g, pos_g, live.reshape(G, Sg, K),
-        head_dim=cfg.head_dim, group=cfg.group, paged_kernel=paged_kernel,
-        mesh=mesh)
-    row_live = live.reshape(S * K)
-    zero = jnp.zeros((), jnp.int32)
-    pairs, most, empty = zero, zero, zero
-    for l, p in enumerate(params["layers"]):
-        with jax.named_scope("attn"), jax.named_scope("qkv_proj"):
-            x = rms_norm(h, p["input_norm"], cfg.rms_norm_eps)
-        # The block's routing, from its INPUT: nothing below feeds it.
-        with jax.named_scope("moe"):
-            routes = share.plan_routes(p, x.reshape(S * K, H), cfg.routing,
-                                       row_live)
-        with jax.named_scope("attn"):
-            with jax.named_scope("qkv_proj"):
-                q, k, v = smallthinker.qkv(p, x, pos, cfg,
-                                           bool(cfg.rope_layout[l]))
-            a = write_and_attend(
-                classes[WINDOW_CLASS if cfg.sliding_window_layout[l]
-                        else FULL_CLASS],
-                pools, q, k, v, scale=cfg.softmax_scale, mesh=mesh)
-            with jax.named_scope("out_proj"):
-                h = h + matmul(a, p["wo"])
-        with jax.named_scope("moe"):
-            z = rms_norm(h, p["post_attn_norm"], cfg.rms_norm_eps)
-            # ``paged_kernel`` is "this path runs its Pallas kernels": the
-            # attend, the row write and the grouped expert product alike.
-            y, counts = share.apply_routes(
-                p, z.reshape(S * K, H), routes, cfg.routing,
-                kernel=paged_kernel, act="relu")
-            h = h + y.reshape(S, K, H)
-        pairs = pairs + counts.sum()
-        most = jnp.maximum(most, counts.max())
-        empty = empty + (counts == 0).sum()
-    return h, tuple(pools), (pairs, most, empty,
-                             row_live.sum().astype(jnp.int32))
-
-
-@jax.named_scope("lm_head")
-def _head(params, h, cfg):
-    h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-    return jnp.dot(h, params["lm_head"].astype(h.dtype).T,
-                   preferred_element_type=jnp.float32)
-
-
-@jax.named_scope("embed")
-def _embed(params, tokens, cfg):
-    return params["embed"].astype(cfg.dtype)[tokens]
-
-
 class SmallthinkerServed(AfmoeServed):
     """See the module docstring."""
-    _embed = staticmethod(_embed)
-    _forward = staticmethod(_forward)
-    _head = staticmethod(_head)
-
     @property
     def init_fn(self) -> Callable:
         return smallthinker.smallthinker_init
@@ -140,6 +75,54 @@ class SmallthinkerServed(AfmoeServed):
         execution(s) routed (on a ``prefill`` span: of the chunk program
         that ended the prompt)."""
         return dict(super().counter_args(rows), rows=int(rows[:, 3].sum()))
+
+    # -- the block ------------------------------------------------------ #
+    @jax.named_scope("embed")
+    def embed(self, params, tokens, pos):
+        return params["embed"].astype(self.cfg.dtype)[tokens]
+
+    def forward(self, params, pools, h, rows: Rows, *, paged_kernel, mesh):
+        cfg = self.cfg
+        G, Sg, K = rows.positions.shape
+        S, H = G * Sg, h.shape[-1]
+        pos = rows.positions.reshape(S, K)
+        pools = list(pools)
+        classes = self.paged_classes(rows, pools, paged_kernel=paged_kernel,
+                                     mesh=mesh)
+        row_live = rows.live.reshape(S * K)
+        zero = jnp.zeros((), jnp.int32)
+        pairs, most, empty = zero, zero, zero
+        for l, p in enumerate(params["layers"]):
+            with jax.named_scope("attn"), jax.named_scope("qkv_proj"):
+                x = rms_norm(h, p["input_norm"], cfg.rms_norm_eps)
+            # The block's routing, from its INPUT: nothing below feeds it.
+            with jax.named_scope("moe"):
+                routes = share.plan_routes(p, x.reshape(S * K, H),
+                                           cfg.routing, row_live)
+            with jax.named_scope("attn"):
+                with jax.named_scope("qkv_proj"):
+                    q, k, v = smallthinker.qkv(p, x, pos, cfg,
+                                               bool(cfg.rope_layout[l]))
+                a = write_and_attend(
+                    classes[WINDOW_CLASS if cfg.sliding_window_layout[l]
+                            else FULL_CLASS],
+                    pools, q, k, v, scale=cfg.softmax_scale, mesh=mesh)
+                with jax.named_scope("out_proj"):
+                    h = h + matmul(a, p["wo"])
+            with jax.named_scope("moe"):
+                z = rms_norm(h, p["post_attn_norm"], cfg.rms_norm_eps)
+                # ``paged_kernel`` is "this path runs its Pallas kernels":
+                # the attend, the row write and the grouped expert product
+                # alike.
+                y, counts = share.apply_routes(
+                    p, z.reshape(S * K, H), routes, cfg.routing,
+                    kernel=paged_kernel, act="relu")
+                h = h + y.reshape(S, K, H)
+            pairs = pairs + counts.sum()
+            most = jnp.maximum(most, counts.max())
+            empty = empty + (counts == 0).sum()
+        return h, tuple(pools), (pairs, most, empty,
+                                 row_live.sum().astype(jnp.int32))
 
 
 register(SmallthinkerConfig, SmallthinkerServed)

@@ -8,7 +8,7 @@ current n-gram suffix, backing off n → n-1 → ... → 1 and falling back
 to repeat-last-token when nothing matches (cheap, and exactly right in
 the repetition regimes greedy decode falls into — which is also where
 speculation pays most). The device half
-(``decode.gpt2_verify_paged`` + ``served.spec_accept``) writes the k
+(``ServedModel.verify`` + ``served.spec_accept``) writes the k
 drafts through the block table in ONE batched verify step and accepts
 the longest agreeing prefix, so greedy output stays bit-identical to
 non-speculative decode whatever this proposer suggests — a bad draft

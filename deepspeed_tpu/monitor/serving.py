@@ -163,7 +163,6 @@ class ServingAggregator:
         self.decode_tokens = 0
         self.prefill_tokens = 0
         self.prefill_width_dispatches = collections.Counter()  # by width
-        self.prefill_head_dispatches = 0     # of them, those with a head
         self.completed = 0
         # Paged-cache accounting (engine-fed; stays empty — and out of
         # the snapshot — until the engine feeds it: the scheduler tests'
@@ -234,15 +233,12 @@ class ServingAggregator:
 
     def note_prefill_pass(self, dispatches: int, rows: int,
                           rows_computed: int,
-                          widths: Sequence[int] = (),
-                          with_head: int = 0) -> float:
+                          widths: Sequence[int] = ()) -> float:
         """One admission batch's prefill ends here (a lap of
         ``prefill_s``): the chunk programs it dispatched, the prompt
         rows it needed (prompt - cached), the rows those programs
-        computed, each one's row width, and how many of them ended a
-        prompt (and so ran the head and sampled)."""
+        computed and each one's row width."""
         self.prefill_width_dispatches.update(int(w) for w in widths)
-        self.prefill_head_dispatches += int(with_head)
         p = self._pend
         p[COL["prefill_dispatches"]] += dispatches
         p[COL["prefill_rows"]] += rows
@@ -556,9 +552,6 @@ class ServingAggregator:
         ``prefill_row_fill`` (rows the prefills needed / rows their
         dispatches computed), ``prefill_width_dispatches`` (``{row
         width: chunk programs dispatched at it}``, over the whole run),
-        ``prefill_head_dispatches`` (of those chunk programs, ``with``:
-        the ones that ended a prompt and ran the head and the sampler,
-        ``without``: the ones that skipped both),
         ``stalls`` (see ``stalls()``),
         ``lookahead_share`` (iterations dispatched while the one before
         was still unfetched: the host's pass hid under the device) and
@@ -635,10 +628,6 @@ class ServingAggregator:
         if self.prefill_width_dispatches:
             snap["prefill_width_dispatches"] = dict(sorted(
                 self.prefill_width_dispatches.items()))
-            snap["prefill_head_dispatches"] = {
-                "with": self.prefill_head_dispatches,
-                "without": sum(self.prefill_width_dispatches.values())
-                - self.prefill_head_dispatches}
         if self.prompt_tokens_admitted:
             snap["prefix"] = {
                 "prompt_tokens": self.prompt_tokens_admitted,
@@ -702,7 +691,6 @@ class ServingAggregator:
             out.decode_tokens += a.decode_tokens
             out.prefill_tokens += a.prefill_tokens
             out.prefill_width_dispatches += a.prefill_width_dispatches
-            out.prefill_head_dispatches += a.prefill_head_dispatches
             out.completed += a.completed
             out.prompt_tokens_admitted += a.prompt_tokens_admitted
             out.cached_tokens_admitted += a.cached_tokens_admitted

@@ -3,12 +3,12 @@
 A ``per_stream`` class keeps, beside its state, the last ``held`` rows of
 what a short (depthwise, causal) filter runs over — one page tile a stream
 and layer, ``held`` rows of ``C`` channels row-major in rows of 128 lanes:
-``[held * C/128, 128]`` (``inference/lfm2.py``, ``falcon_h1.py``,
-``kimi_linear.py``: ``conv_tile``).  A decode step hands every stream ONE new
-row: the filter wants ``[held old rows | the new one]`` and the page must
-hold rows ``1 .. held`` of that afterwards.  As plain ``jax.numpy``
-(``inference/served.filter_rows``) that is a gather of page tiles, a select,
-a concatenate, a ``take_along_axis`` and a scatter a layer, and on the chip
+``[held * C/128, 128]`` (``inference/served.py``: ``filter_tile``, for
+the lfm2, falcon-h1 and kimi-linear families).  A decode step hands every
+stream ONE new row: the filter wants ``[held old rows | the new one]`` and
+the page must hold rows ``1 .. held`` of that afterwards.  As plain
+``jax.numpy`` (``served.filter_rows``) that is a gather of page tiles, a
+select, a concatenate, a ``take_along_axis`` and a scatter a layer, and on the chip
 the page tiles' own gather and scatter are the small part of it: the tile is
 ``[held * C/128, 128]`` and the filter's rows are ``[S, held + 1, C]``, so
 every step between them is a physical relayout, and ``take_along_axis`` is a

@@ -1,29 +1,69 @@
-"""``decode_step``'s lowered text, hashed, for a tiny engine of every
-family that was served before PR 54 (not a test file: the golden hashes in
-``tests/data/decode_step_hlo_pr55.json`` were written by running this file
-on the tree at PR 55, ``python tests/decode_step_hlo.py``, its last lines; the test
-that compares is ``test_smallthinker_serving.py``).  PR 55 changed the text
-on purpose and in one place: ``sample``'s select between an argmax and a
-draw, both evaluated, became a ``case`` with one of them in each branch —
-the only lines that differ from PR 53's text once value numbers are
-blanked — so the hashes were written again.
+"""The serving engine's programs as TEXT and as bits, for a tiny engine of
+every served family (not a test file: ``tests/test_program_text.py``
+compares, and shares the readings taken here).
 
-The text is ``jax.jit(...).lower(...).as_text()`` of the ENGINE's own
-``_build_decode_step`` — StableHLO without locations, so a named scope or a
-moved line of Python changes nothing in it, and an operation added, dropped
-or reordered does — once with the Pallas kernels (interpret mode) and once
-without, concatenated.
+For each of the nine fixtures, with the Pallas kernels off and on (interpret
+mode), ONE engine gives
+
+- the lowered text of the engine's own programs — ``_build_decode_step``,
+  ``_build_prefill_step`` at every width of ``engine.prefill_widths`` and,
+  for a family whose cache can be rolled back over rejected drafts,
+  ``_build_verify_step`` — as ``jax.jit(...).lower(...).as_text()``:
+  StableHLO without locations, so a named scope or a moved line of Python
+  changes nothing in it, and an operation added, dropped or reordered does.
+  Two hashes a program: of the text as it is, and an ORDER-FREE one (SSA
+  value names and functions' numeric suffixes blanked, the lines sorted) —
+  equal order-free hashes and unequal exact ones mean the same operations
+  traced in another order;
+- the sha256 of the bytes of what it computes on the CPU: the first token
+  and logits of a three-chunk prompt, then two decode iterations' tokens
+  and logits.
+
+``python tests/decode_step_hlo.py OUT.json [DIR]`` writes the golden JSON
+(and every program's blanked text into DIR, a line an operation, unsorted,
+for ``diff``); ``tests/data/program_text_pr55.json`` was written by running
+this file in a ``git archive`` of the tree at PR 55 (e2db9ea), BEFORE the
+refactor it guards touched ``inference/``.
 """
+import dataclasses
 import hashlib
 import json
 import os
+import re
 import sys
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
+TESTS = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(TESTS)
+for path in (ROOT, TESTS):
+    if path not in sys.path:
+        sys.path.insert(0, path)
+
+CHUNK = 8
+VERIFY_ROWS = 3                     # K of the lowered ``verify_step``
+ARMS = {"off": False, "on": True}   # the Pallas kernels (interpret mode)
+
+
+# --------------------------------------------------------------------- #
+# The nine fixtures: () -> (config, params, ``inference`` keys of its own)
+# --------------------------------------------------------------------- #
+def _gpt2():
+    from deepspeed_tpu.models.gpt2 import GPT2_CONFIGS, gpt2_init
+    cfg = dataclasses.replace(GPT2_CONFIGS["gpt2-tiny"], dtype=jnp.float32)
+    return cfg, gpt2_init(jax.random.PRNGKey(0), cfg), {"block_size": 16}
+
+
+def _retention():
+    from deepspeed_tpu.models.brumby import BrumbyConfig, brumby_init
+    cfg = BrumbyConfig(vocab_size=128, hidden_size=32, intermediate_size=64,
+                       num_hidden_layers=2, num_attention_heads=4,
+                       num_key_value_heads=2, head_dim=16,
+                       max_position_embeddings=128, dtype=jnp.float32)
+    return cfg, brumby_init(jax.random.PRNGKey(0), cfg), {
+        "block_size": 8, "num_blocks": 8}
 
 
 def _latent(**kw):
@@ -39,7 +79,8 @@ def _latent(**kw):
         v_head_dim=24, max_position_embeddings=256,
         rope_original_max_position_embeddings=32, rope_factor=8.0,
         dtype=jnp.float32, initializer_range=0.08), **kw))
-    return cfg, deepseek_v3_init, {"block_size": 16, "prefill_chunk": 32}
+    return cfg, deepseek_v3_init(jax.random.PRNGKey(0), cfg), {
+        "block_size": 16}
 
 
 def _hyper():
@@ -54,7 +95,24 @@ def _afmoe():
         num_attention_heads=4, num_key_value_heads=2, head_dim=16,
         num_experts=8, num_experts_per_tok=2, sliding_window=8,
         max_position_embeddings=256, dtype=jnp.float32)
-    return cfg, afmoe_init, {"num_blocks": {"full": 64, "window": 40}}
+    return cfg, afmoe_init(jax.random.PRNGKey(0), cfg), {
+        "num_blocks": {"full": 64, "window": 40}}
+
+
+def _smallthinker():
+    """``test_smallthinker_serving.tiny`` cut to five layers (``0 1 1 1 0``:
+    two full layers, three of the window's): a layer costs a second of
+    interpret-mode lowering a program here and adds no kind of line."""
+    from deepspeed_tpu.models.smallthinker import (SmallthinkerConfig,
+                                                   smallthinker_init)
+    cfg = SmallthinkerConfig(
+        vocab_size=128, hidden_size=64, num_hidden_layers=5,
+        num_attention_heads=14, num_key_value_heads=2, head_dim=16,
+        moe_ffn_hidden_size=32, moe_num_primary_experts=8,
+        moe_num_active_primary_experts=3, sliding_window_size=8,
+        max_position_embeddings=256, dtype=jnp.float32)
+    return cfg, smallthinker_init(jax.random.PRNGKey(0), cfg), {
+        "num_blocks": {"full": 64, "window": 40}}
 
 
 def _lfm2():
@@ -65,7 +123,8 @@ def _lfm2():
         num_attention_heads=4, num_key_value_heads=2, num_experts=8,
         num_experts_per_tok=2, layer_types=(CONV, FULL, CONV, CONV, CONV),
         max_position_embeddings=256, dtype=jnp.float32)
-    return cfg, lfm2_init, {"num_blocks": {"full": 96, "conv": 16}}
+    return cfg, lfm2_init(jax.random.PRNGKey(0), cfg), {
+        "num_blocks": {"full": 96, "conv": 16}}
 
 
 def _falcon_h1():
@@ -78,7 +137,8 @@ def _falcon_h1():
         mamba_d_state=16, mamba_n_groups=2, mamba_d_conv=4,
         mamba_chunk_size=8, max_position_embeddings=256, rope_theta=1e4,
         dtype=jnp.float32)
-    return cfg, falcon_h1_init, {"num_blocks": {"full": 96, "state": 16}}
+    return cfg, falcon_h1_init(jax.random.PRNGKey(0), cfg), {
+        "num_blocks": {"full": 96, "state": 16}}
 
 
 def _kimi_linear():
@@ -91,45 +151,165 @@ def _kimi_linear():
         kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
         v_head_dim=16, num_experts=8, held=(0, 4), num_experts_per_token=2,
         model_max_length=256, dtype=jnp.float32)
-    return cfg, kimi_linear_init, {"num_blocks": {"latent": 96, "state": 16}}
+    return cfg, kimi_linear_init(jax.random.PRNGKey(0), cfg), {
+        "num_blocks": {"latent": 96, "state": 16}}
 
 
-# cell 4 (a held share, group-limited), 7 (several residual streams), 6, 8,
-# 9 (no experts: the attention branch only), 10
-FAMILIES = {"latent_share": _latent, "latent_hyper": _hyper,
-            "afmoe": _afmoe, "lfm2": _lfm2, "falcon_h1": _falcon_h1,
-            "kimi_linear": _kimi_linear}
+# Every served family, by the benchmark's cell: GPT-2 (3), the latent family
+# with a held share (4) and with several residual streams (7), retention
+# (5), two classes of pages (6), a router ahead of its attention (11), pages
+# beside a conv state (8), a state-space mixer beside attention in every
+# layer (9), a delta-rule state beside a latent class (10).
+FAMILIES = {"gpt2": _gpt2, "latent_share": _latent, "latent_hyper": _hyper,
+            "retention": _retention, "afmoe": _afmoe,
+            "smallthinker": _smallthinker, "lfm2": _lfm2,
+            "falcon_h1": _falcon_h1, "kimi_linear": _kimi_linear}
 
 
-def decode_step_text(family: str, kernel: bool) -> str:
+def engine(family: str, kernel: bool, dp: int = 1, **extra):
+    """A tiny engine of ``family`` on ``dp`` host devices (``extra``: more
+    top-level config blocks, ``telemetry``)."""
     from deepspeed_tpu.inference import InferenceEngine
     from deepspeed_tpu.parallel.topology import build_mesh
-    cfg, init, inference = FAMILIES[family]()
-    conf = dict(max_slots=4, max_seq_len=128, block_size=4, prefill_chunk=8,
-                paged_kernel=kernel)
+    cfg, params, inference = FAMILIES[family]()
+    conf = dict(max_slots=4, max_seq_len=128, block_size=4,
+                prefill_chunk=CHUNK, paged_kernel=kernel)
     conf.update(inference)
-    eng = InferenceEngine(cfg, init(jax.random.PRNGKey(0), cfg),
-                          config={"inference": conf},
-                          mesh=build_mesh(devices=jax.devices()[:1]))
+    return InferenceEngine(cfg, params, config={"inference": conf, **extra},
+                           mesh=build_mesh(devices=jax.devices()[:dp]))
+
+
+# --------------------------------------------------------------------- #
+# The programs' text
+# --------------------------------------------------------------------- #
+def _i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
+_KEY = jax.ShapeDtypeStruct((2,), jnp.uint32)
+_TEMPERATURE = jax.ShapeDtypeStruct((), jnp.float32)
+
+
+class _Once:
+    """One of an engine's jitted programs, lowered ONCE — at its first call
+    of each width, with the operands the engine hands it: the text kept, the
+    executable called from then on (the engine's own wrapper would trace it
+    a second time to run it, and a trace is what a tiny engine costs)."""
+
+    def __init__(self, jitted, tokens_at: int):
+        self.jitted, self.at = jitted, tokens_at
+        self.texts, self.compiled = {}, {}
+
+    def __call__(self, *args):
+        width = np.shape(args[self.at])[-1]
+        if width not in self.compiled:
+            lowered = self.jitted.lower(*args)
+            self.texts[width] = lowered.as_text()
+            self.compiled[width] = lowered.compile()
+        return self.compiled[width](*args)
+
+
+def programs_and_bytes(eng) -> tuple:
+    """({program: lowered text}, ``served_bytes``) of ``eng``'s own
+    builders: ``decode_step`` and ``prefill_step`` as the scenario
+    dispatched them, ``prefill_step`` at any other width of
+    ``prefill_widths`` and ``verify_step`` (none for a model whose
+    ``verify`` refuses) from abstract operands."""
+    S, J, G = eng.max_slots, eng.block_tables.shape[1], eng.dp
+    head = (eng._params, *eng._pools())
+    eng._decode_fn = decode = _Once(eng._build_decode_step(), len(head))
+    eng._prefill_fn = prefill = _Once(eng._build_prefill_step(), len(head))
+    served = served_bytes(eng)
+    texts = {"decode_step": decode.texts[S + len(eng.served.counter_names)]}
+    head = (eng._params, *eng._pools())
+    for width in eng.prefill_widths:
+        texts[f"prefill_step_{width}"] = prefill.texts.get(width) or \
+            prefill.jitted.lower(
+                *head, _i32(G, width), _i32(G, J), _i32(G), _i32(G), _i32(G),
+                *[_i32(G) for _ in eng._no_freeze()], _i32(), _KEY,
+                _TEMPERATURE).as_text()
     try:
-        S, J = eng.max_slots, eng.block_tables.shape[1]
-        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa
-        return eng._build_decode_step().lower(
-            eng._params, *eng._pools(),
-            i32(S + len(eng.served.counter_names)), i32(S),
-            jax.ShapeDtypeStruct((S,), jnp.bool_), i32(S), i32(S, J),
-            jax.ShapeDtypeStruct((2,), jnp.uint32),
-            jax.ShapeDtypeStruct((), jnp.float32)).as_text()
-    finally:
-        eng.close()
+        texts["verify_step"] = eng._build_verify_step().lower(
+            *head, _i32(S, VERIFY_ROWS), _i32(S), _i32(S, J), _KEY,
+            _TEMPERATURE).as_text()
+    except NotImplementedError:
+        pass
+    return texts, served
 
 
-def decode_step_sha(family: str) -> str:
-    return hashlib.sha256("\n".join(
-        decode_step_text(family, kernel)
-        for kernel in (False, True)).encode()).hexdigest()
+_VALUE = re.compile(r"%[\w.#:]+")
+_SUFFIX = re.compile(r"(@[A-Za-z_][\w.]*?)(_\d+)+\b")
+
+
+def blanked(text: str) -> list:
+    """The text's lines with what tracing ORDER alone decides taken out:
+    SSA value names, and the numeric suffixes of functions' names."""
+    return [_SUFFIX.sub(r"\1", _VALUE.sub("%", line)).strip()
+            for line in text.splitlines()]
+
+
+def _sha(text: str) -> str:
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def text_hashes(text: str) -> dict:
+    return {"exact": _sha(text),
+            "order_free": _sha("\n".join(sorted(blanked(text))))}
+
+
+# --------------------------------------------------------------------- #
+# What the programs compute
+# --------------------------------------------------------------------- #
+def served_bytes(eng) -> bytes:
+    """A prompt of three chunks, then two decode iterations: the first
+    token and its logits, each iteration's token and logits."""
+    vocab = int(eng.model_cfg.vocab_size)
+    prompt = np.random.default_rng(1).integers(
+        0, vocab, size=2 * CHUNK + 3, dtype=np.int32)
+    slot = eng.select_slot(prompt, 4)
+    tok, logits = eng.prefill(prompt, slot, return_logits=True,
+                              max_new_tokens=4)
+    assert eng.last_admit_info(slot)["chunks"] == 3
+    eng.activate_slot(slot, len(prompt), tok)
+    parts = [np.int32(tok).tobytes(), np.asarray(logits).tobytes()]
+    for _ in range(2):
+        sampled, step_logits = eng.decode_once(0.0, return_logits=True)
+        parts += [np.int32(sampled[slot]).tobytes(),
+                  np.asarray(step_logits)[slot].tobytes()]
+    eng.release_slot(slot)
+    return b"".join(parts)
+
+
+_READ = {}
+DUMP = None     # a directory: every program's blanked text is left there
+
+
+def golden(family: str, arm: str) -> dict:
+    """{"programs": {program: its two hashes}, "outputs": sha256} of ONE
+    engine of ``family`` under kernel arm ``arm``, built at the first call
+    and its reading kept (the hashes, not the texts: megabytes a program):
+    every reader in a process shares it."""
+    if (family, arm) not in _READ:
+        eng = engine(family, ARMS[arm])
+        try:
+            texts, served = programs_and_bytes(eng)
+        finally:
+            eng.close()
+        _READ[family, arm] = {
+            "programs": {name: text_hashes(text)
+                         for name, text in texts.items()},
+            "outputs": hashlib.sha256(served).hexdigest()}
+        for name, text in texts.items() if DUMP else ():
+            os.makedirs(DUMP, exist_ok=True)
+            with open(os.path.join(DUMP, f"{family}.{arm}.{name}.txt"),
+                      "w") as f:
+                f.write("\n".join(blanked(text)) + "\n")
+    return _READ[family, arm]
 
 
 if __name__ == "__main__":
-    print(json.dumps({f: decode_step_sha(f) for f in sorted(FAMILIES)},
-                     indent=1))
+    DUMP = sys.argv[2] if len(sys.argv) > 2 else None
+    out = {family: {arm: golden(family, arm) for arm in ARMS}
+           for family in sorted(FAMILIES)}
+    with open(sys.argv[1], "w") as f:
+        f.write(json.dumps(out, indent=1, sort_keys=True) + "\n")

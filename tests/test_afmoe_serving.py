@@ -38,7 +38,8 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-from deepspeed_tpu.inference import InferenceEngine, kv_cache  # noqa: E402
+from deepspeed_tpu.inference import (                           # noqa: E402
+    InferenceEngine, kv_cache, kv_pages)
 from deepspeed_tpu.inference import afmoe as afmoe_serving      # noqa: E402
 from deepspeed_tpu.inference.kv_cache import (                  # noqa: E402
     DEAD_BLOCK, BlockAllocator, ClassAllocators, PagedKVCacheSpec,
@@ -646,7 +647,7 @@ def test_paged_attention_with_grouped_heads_and_a_reach(group, reach, D, bs,
     assert not np.asarray(got)[0, 3].any()           # the dead stream
     if reach is not None:
         # ... and the served model's own attend without the kernel
-        base = afmoe_serving._gather_attend(
+        base = kv_pages.gather_attend(
             jnp.asarray(q), pk, pv, 1, jnp.asarray(bt[None]),
             jnp.asarray(pos[None]), reach, D ** -0.5)
         np.testing.assert_allclose(np.asarray(base)[0], want, atol=2e-5)

@@ -36,10 +36,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from deepspeed_tpu.inference import InferenceEngine             # noqa: E402
-from deepspeed_tpu.inference import falcon_h1 as serving        # noqa: E402
 from deepspeed_tpu.inference.kv_cache import (                  # noqa: E402
     ClassAllocators, StateAllocator, class_specs, init_paged_cache)
-from deepspeed_tpu.inference.served import served_model         # noqa: E402
+from deepspeed_tpu.inference.kv_pages import attend_rows        # noqa: E402
+from deepspeed_tpu.inference.served import (                    # noqa: E402
+    filter_tile, served_model)
 from deepspeed_tpu.models.falcon_h1 import (                    # noqa: E402
     FalconH1Config, falcon_h1_init)
 from deepspeed_tpu.ops import paged_attention as paged_attn_ops  # noqa: E402
@@ -404,8 +405,8 @@ def test_verify_raises_and_speculation_is_refused():
 def test_five_query_rows_a_head_through_the_gather_and_the_plan():
     assert CFG.group == 5 and FalconH1Config().group == 5
     # decode: 5 rows a K/V head; a prefill chunk of 512 in runs of 64
-    assert serving._attend_rows(1, 5) == 1
-    assert serving._attend_rows(512, 5) == 64
+    assert attend_rows(1, 5) == 1
+    assert attend_rows(512, 5) == 64
     pool = jnp.zeros((2, 1, 8, 2, BS * 16 // 128 or 1, 128), jnp.float32)
     bt = jnp.asarray([[[0, 1, -1], [2, -1, -1]]], jnp.int32)
     seen = jnp.asarray([[[5], [2]]], jnp.int32)
@@ -561,7 +562,8 @@ def test_decode_rewrites_the_filter_rows_in_place_and_serves_the_same(
     from deepspeed_tpu.ops import filter_rows as in_place
     from test_filter_rows import assert_the_same_stream, served_both_ways
     cfg = tiny(mamba_d_ssm=512, mamba_d_head=128, mamba_d_state=128)
-    assert cfg.conv_dim == 1024 and serving.conv_tile(cfg) == (1, 24, 128)
+    assert cfg.conv_dim == 1024 and filter_tile(
+        cfg.mamba_d_conv - 1, cfg.conv_dim) == (1, 24, 128)
     assert not in_place.takes((4, 1, 184, 1, 120, 128), jnp.bfloat16, 3,
                               jnp.bfloat16)
     assert_the_same_stream(*served_both_ways(

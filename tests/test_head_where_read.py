@@ -18,8 +18,8 @@ kernels off (here) and on (interpret mode: ``test_head_where_read_kernels.py``):
 (c) ``sample_tokens`` at temperature 0 is ``argmax`` and at 0.7 the draw
     ``jax.random.categorical(key, logits / 0.7)`` gives, and both programs
     compile once across the two temperatures;
-(d) the ``prefill_chunk`` spans' ``head`` and ``snapshot()``'s
-    ``prefill_head_dispatches`` add up to the chunk programs dispatched;
+(d) the ``prefill_chunk`` spans' ``head`` args add up to the chunk programs
+    dispatched: one with a head a prompt, the rest without;
 (e) ``dp`` = 2 and 4 on host devices: a dispatch in which one group ends
     its prompt and another does not serves both what one device serves.
 """
@@ -35,59 +35,20 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from deepspeed_tpu.inference import InferenceEngine             # noqa: E402
 from deepspeed_tpu.inference import engine as engine_mod        # noqa: E402
 from deepspeed_tpu.inference import served as served_mod        # noqa: E402
 from deepspeed_tpu.inference.scheduler import Request           # noqa: E402
-from deepspeed_tpu.parallel.topology import build_mesh          # noqa: E402
 
 import decode_step_hlo                                          # noqa: E402
-import test_decode_lookahead as lookahead                       # noqa: E402
 
-CHUNK = 8
-
-
-def _of_the_golden(family):
-    def build():
-        cfg, init, inference = decode_step_hlo.FAMILIES[family]()
-        return cfg, init(jax.random.PRNGKey(0), cfg), inference
-    return build
-
-
-def _of_the_loop(family):
-    def build():
-        cfg, params, _, inference = lookahead.FAMILIES[family]()
-        return cfg, params, {k: v for k, v in inference.items()
-                             if k in ("num_blocks", "block_size")}
-    return build
-
-
-def _smallthinker():
-    from deepspeed_tpu.models.smallthinker import smallthinker_init
-    from test_smallthinker_serving import tiny
-    cfg = tiny()
-    return cfg, smallthinker_init(jax.random.PRNGKey(0), cfg), {
-        "num_blocks": {"full": 64, "window": 40}}
-
-
+CHUNK = decode_step_hlo.CHUNK
 # Every served family: GPT-2, the latent family with a held share and with
 # several residual streams, retention, two classes of pages, a router ahead
 # of its attention, pages beside a conv state, a state-space mixer beside
-# attention, a delta-rule state beside a latent class.
-FAMILIES = {"gpt2": _of_the_loop("gpt2"),
-            "retention": _of_the_loop("retention"),
-            "smallthinker": _smallthinker,
-            **{name: _of_the_golden(name)
-               for name in decode_step_hlo.FAMILIES}}
-
-
-def _engine(family, kernel, dp=1, **extra):
-    cfg, params, inference = FAMILIES[family]()
-    conf = dict(max_slots=4, max_seq_len=128, block_size=4,
-                prefill_chunk=CHUNK, paged_kernel=kernel)
-    conf.update(inference, prefill_chunk=CHUNK)
-    return InferenceEngine(cfg, params, config={"inference": conf, **extra},
-                           mesh=build_mesh(devices=jax.devices()[:dp]))
+# attention, a delta-rule state beside a latent class — the fixtures
+# ``tests/test_program_text.py`` holds to their programs' text.
+FAMILIES = decode_step_hlo.FAMILIES
+_engine = decode_step_hlo.engine
 
 
 def _prompt(n, vocab, seed):
@@ -108,6 +69,18 @@ def _select_sample(logits, key, temperature):
 def _head_in_every_program(read, head, h, key, temperature):
     logits = head(h)
     return _select_sample(logits, key, temperature), logits
+
+
+def _span_heads(eng):
+    """The ``head`` arg of every ``prefill_chunk`` span from here on."""
+    real, heads = eng.telemetry.span, []
+
+    def span(name, **args):
+        if name == "prefill_chunk":
+            heads.append(args["head"])
+        return real(name, **args)
+    eng.telemetry.span = span
+    return heads
 
 
 def _watched(eng):
@@ -188,7 +161,7 @@ def served_what_the_head_in_every_program_served(family, kernel,
         want = _serve_two(ref, _vocab(ref), decode=0 if kernel else 2)
         ref.close()
     eng = _engine(family, kernel)
-    seen = _watched(eng)
+    seen, heads = _watched(eng), _span_heads(eng)
     got = _serve_two(eng, _vocab(eng), decode=0 if kernel else 2)
     for (tok, logits, toks, last), (wtok, wlogits, wtoks, wlast) \
             in zip(got, want):
@@ -209,8 +182,7 @@ def served_what_the_head_in_every_program_served(family, kernel,
             assert not logits.any() and not fetch[:eng.dp].any()
             assert wlogits.any()         # (the parent computed them)
             np.testing.assert_array_equal(fetch[eng.dp:], wfetch[eng.dp:])
-    assert eng.serving.snapshot()["prefill_head_dispatches"] == {
-        "with": 2, "without": 2}
+    assert heads == [0, 0, 1, 1]
     if not kernel:
         # The head's product — the one contraction of a row with a ``[H,
         # V]`` weight into ``[G, V]`` logits — is inside a ``case`` region,
@@ -290,12 +262,14 @@ def test_the_head_counts_add_up_to_the_chunk_programs(tmp_path):
         for i, n in enumerate(lengths)])
     assert report["completed"] == len(lengths)
     chunks = [-(-n // CHUNK) for n in lengths]
-    assert report["prefill_head_dispatches"] == {
-        "with": len(lengths), "without": sum(chunks) - len(lengths)}
     assert sum(report["prefill_width_dispatches"].values()) == sum(chunks)
     eng.close()
     events = [e for e in json.load(open(trace_path))
               if e.get("name") in ("prefill", "prefill_chunk")]
+    heads = [e["args"]["head"] for e in events
+             if e["name"] == "prefill_chunk"]
+    assert (heads.count(1), heads.count(0)) == (
+        len(lengths), sum(chunks) - len(lengths))
     prefills = [e for e in events if e["name"] == "prefill"]
     assert len(prefills) == len(lengths)
     for pf in prefills:
@@ -321,12 +295,11 @@ def test_groups_that_end_in_different_dispatches(family, dp):
     lengths = {0: 2 * CHUNK + 3, 1: CHUNK - 3, 3: CHUNK + 1}
     admissions = [(g * Sg, _prompt(n, vocab, 30 + g), 4)
                   for g, n in lengths.items() if g < dp]
-    seen = _watched(many)
+    seen, heads = _watched(many), _span_heads(many)
     got = many.prefill_many(admissions, return_logits=True)
     # dispatch 0 ends group 1's prompt, 1 (dp 4) group 3's, 2 group 0's
     assert [read for read, _, _ in seen] == [1, int(dp == 4), 1]
-    assert many.serving.snapshot()["prefill_head_dispatches"] == {
-        "with": 2 + (dp == 4), "without": int(dp == 2)}
+    assert heads == [1, int(dp == 4), 1]
     for (slot, prompt, _), (tok, logits) in zip(admissions, got):
         alone = one.select_slot(prompt, 4)
         wtok, wlogits = one.prefill(prompt, alone, return_logits=True,

@@ -39,7 +39,8 @@ from deepspeed_tpu.inference import InferenceEngine             # noqa: E402
 from deepspeed_tpu.inference import kimi_linear as serving      # noqa: E402
 from deepspeed_tpu.inference.kv_cache import (                  # noqa: E402
     ClassAllocators, class_specs, init_paged_cache)
-from deepspeed_tpu.inference.served import served_model         # noqa: E402
+from deepspeed_tpu.inference.served import (                    # noqa: E402
+    filter_tile, served_model)
 from deepspeed_tpu.models.blocks import rms_norm                # noqa: E402
 from deepspeed_tpu.models.kimi_linear import (                  # noqa: E402
     KDA, LATENT, KimiLinearConfig, kimi_linear_init)
@@ -466,10 +467,12 @@ def test_decode_rewrites_the_filter_rows_in_place_and_serves_the_same(
     equal bit for bit, and the ``decode`` span's arg says which was which."""
     from test_filter_rows import assert_the_same_stream, served_both_ways
     cfg = tiny(kda_num_heads=64, kda_head_dim=16)
-    assert serving.conv_tile(cfg) == (1, 72, 128)
+    assert filter_tile(cfg.short_conv_kernel_size - 1,
+                       cfg.conv_dim) == (1, 72, 128)
     assert_the_same_stream(*served_both_ways(
         monkeypatch, cfg, seeded(cfg), {"latent": 96, "state": 16},
         prompt_of(3, 11), ("conv.state", "state.state")))
     # the file's own size (96 channels: a [3, 96] tile) keeps the plain lines
-    assert serving.conv_tile(CFG) == (1, 3, 96)
+    assert filter_tile(CFG.short_conv_kernel_size - 1,
+                       CFG.conv_dim) == (1, 3, 96)
     assert engine("kernels").filter_rows_in_place == 0

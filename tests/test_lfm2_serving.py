@@ -36,7 +36,8 @@ from deepspeed_tpu.inference import lfm2 as lfm2_serving        # noqa: E402
 from deepspeed_tpu.inference.kv_cache import (                  # noqa: E402
     BlockAllocator, ClassAllocators, PoolExhausted, StateAllocator,
     allocator_for, class_specs)
-from deepspeed_tpu.inference.served import served_model         # noqa: E402
+from deepspeed_tpu.inference.served import (                    # noqa: E402
+    filter_tile, served_model)
 from deepspeed_tpu.models.lfm2 import (                         # noqa: E402
     CONV, FULL, Lfm2Config, lfm2_init)
 from deepspeed_tpu.parallel.topology import build_mesh          # noqa: E402
@@ -874,7 +875,7 @@ def test_decode_rewrites_the_conv_rows_in_place_and_serves_the_same(
     cfg = tiny(hidden_size=1024, num_attention_heads=4, num_hidden_layers=3,
                layer_types=(CONV, FULL, CONV), intermediate_size=64,
                moe_intermediate_size=16)
-    assert lfm2_serving.conv_tile(cfg) == (1, 16, 128)
+    assert filter_tile(cfg.conv_L_cache - 1, cfg.hidden_size) == (1, 16, 128)
     assert_the_same_stream(*served_both_ways(
         monkeypatch, cfg, seeded(cfg), {"full": 96, "conv": 16},
         prompt_of(3, 11), ("conv.conv",)))

@@ -341,8 +341,8 @@ def _wide_kernel(q, pools, bt, pos, reach, tiles, grp=8):
 def _wide_baseline(q, pools, bt, pos, reach):
     """The served model's own attend without the kernel: blocks gathered,
     a mask from positions."""
-    from deepspeed_tpu.inference import afmoe as afmoe_serving
-    return np.asarray(afmoe_serving._gather_attend(
+    from deepspeed_tpu.inference.kv_pages import gather_attend
+    return np.asarray(gather_attend(
         q, pools[0], pools[1], 1, bt, pos, reach, q.shape[-1] ** -0.5),
         np.float32)
 

@@ -1,0 +1,186 @@
+"""A served family writes its block, not its programs (ISSUE 56): the
+engine's three programs are ``ServedModel``'s, written once over a family's
+``embed`` / ``forward`` / ``head`` and the rows ``served.Rows`` hands them.
+
+1. **The programs are what they were.**  For each of the nine fixtures of
+   ``tests/decode_step_hlo.py`` — ONE engine a fixture and kernel arm,
+   built at its first case and read by all the others (kernels off here;
+   on, in interpret mode: ``test_program_text_kernels.py``, a file of its
+   own so that the suite's workers share the two halves) — a case a program
+   kind holds
+
+   - what the engine computes on the CPU (first token and logits of a
+     three-chunk prompt, two decode iterations' tokens and logits) to the
+     bytes the tree at PR 55 computed, hashed: no exception;
+   - each program's lowered text to ``tests/data/program_text_pr55.json``,
+     written on that tree before the programs moved: the same text, or the
+     same operations in another order (equal ORDER-FREE hashes) — or, for
+     the programs ``MOVED`` names with their causes, the text this PR left
+     (``tests/data/program_text_pr56.json``, the same file written on this
+     PR's tree, so that the next change to them shows).  A program that lowers
+     to neither fails: run ``python tests/decode_step_hlo.py OUT.json DIR``
+     on both trees and ``diff`` the blanked texts to see which lines moved;
+   - ``verify`` of a family whose cache is a state a stream to the ONE
+     refusal.
+
+2. **``Rows``' two constructors against plain NumPy**: positions, dead
+   slots and groups, padding past ``last_idx``, the ``freeze`` pair, the
+   last-row pick over trailing axes.
+"""
+import json
+import os
+import sys
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+import decode_step_hlo as harness                               # noqa: E402
+from deepspeed_tpu.inference.kv_cache import DEAD_BLOCK         # noqa: E402
+from deepspeed_tpu.inference.served import Rows, served_model   # noqa: E402
+
+DATA = os.path.join(harness.TESTS, "data")
+GOLDEN = json.load(open(os.path.join(DATA, "program_text_pr55.json")))
+LEFT_BY_PR56 = json.load(open(os.path.join(DATA, "program_text_pr56.json")))
+
+# Why a program's operations are not, line for line, the ones PR 55 lowered
+# (CHANGES.md, PR 56, quotes the lines).  Where the seven copies of the
+# programs differed the one copy picks a side — the one more cells ran:
+LIVE = ("the one rule for `live`: a slot is live where ANY column of its "
+        "table row holds a block (the latent family asked its first column)")
+POS = ("positions reach `embed` through `Rows`: grouped, then shaped like "
+       "their tokens again — one reshape, and GPT-2 alone reads them")
+K1 = ("`decode` is the K = 1 `verify`: `lengths + arange(1)`, the head over "
+      "`[S, 1, H]` and then `[:, 0]` (this family sliced first)")
+COLS = ("a chunk's columns are `arange(width)` for the positions and an "
+        "iota each for `live` and the last-row pick (this family shared one)")
+READS = ("the family's two closures read the program's `Rows` (the table, "
+         "dead for an inactive group; the positions), not the raw operands")
+STATE = {"decode_step": (K1,), "prefill_step": (COLS,)}
+MOVED = {
+    "gpt2": {kind: (POS,) for kind in
+             ("decode_step", "prefill_step", "verify_step")},
+    "latent_share": {"decode_step": (LIVE,), "verify_step": (LIVE,)},
+    "latent_hyper": {"decode_step": (LIVE,), "verify_step": (LIVE,)},
+    "lfm2": STATE, "falcon_h1": STATE, "kimi_linear": STATE,
+    "retention": {"decode_step": (K1, READS),
+                  "prefill_step": (COLS, READS)},
+}
+KINDS = ("decode_step", "prefill_step", "verify_step", "outputs")
+REFUSES = ("retention", "lfm2", "falcon_h1", "kimi_linear")
+
+
+def held_to_the_golden(family, kind, arm):
+    got, want = harness.golden(family, arm), GOLDEN[family][arm]
+    if kind == "outputs":
+        assert got["outputs"] == want["outputs"], (
+            f"{family}, kernels {arm}: the engine computes other bits than "
+            "the tree at PR 55 did")
+        return
+    names = sorted(n for n in want["programs"] if n.startswith(kind))
+    assert names == sorted(n for n in got["programs"] if n.startswith(kind))
+    if kind == "verify_step" and family in REFUSES:
+        assert not names
+        with pytest.raises(NotImplementedError,
+                           match="rolled back.*spec_k"):
+            served_model(harness.FAMILIES[family]()[0]).verify(
+                None, None, None, None, None, num_groups=1,
+                paged_kernel=False)
+        return
+    assert names
+    for name in names:
+        g, w = got["programs"][name], want["programs"][name]
+        if g["order_free"] == w["order_free"]:
+            continue            # the same lines (in another order at most)
+        assert kind in MOVED.get(family, {}), (
+            f"{family}.{arm}.{name}: other operations than at PR 55, and "
+            "no cause on record")
+        assert g["order_free"] == LEFT_BY_PR56[family][arm]["programs"][
+                name]["order_free"], (
+            f"{family}.{arm}.{name}: other operations than PR 56 left "
+            f"(moved then by: {'; '.join(MOVED[family][kind])})")
+
+
+@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("family", sorted(harness.FAMILIES))
+def test_the_programs_are_what_they_were(family, kind):
+    held_to_the_golden(family, kind, "off")
+
+
+def test_the_causes_on_record_are_of_the_programs_that_moved():
+    """``MOVED`` names the programs whose operations PR 56 left other than
+    PR 55's, and no other (an entry would outlive its cause), in both
+    kernel arms; what every fixture computes did not move."""
+    for family in harness.FAMILIES:
+        for arm in harness.ARMS:
+            was, now = GOLDEN[family][arm], LEFT_BY_PR56[family][arm]
+            assert was["outputs"] == now["outputs"]
+            assert sorted(was["programs"]) == sorted(now["programs"])
+            moved = {name.split("_step")[0] + "_step"
+                     for name, hashes in now["programs"].items()
+                     if hashes["order_free"]
+                     != was["programs"][name]["order_free"]}
+            assert moved == set(MOVED.get(family, {})), (family, arm)
+
+
+# --------------------------------------------------------------------- #
+# 2. The two constructors
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("K,groups", [(1, 1), (1, 2), (3, 2)])
+def test_rows_of_slots_against_numpy(K, groups):
+    S, W = 4, 5
+    lengths = np.asarray([7, 0, 12, 3], np.int32)
+    tables = np.full((S, W), DEAD_BLOCK, np.int32)
+    tables[0, :2] = (4, 9)
+    tables[2, 3] = 1            # a block in a LATER column only: live
+    tables[3, 0] = 6            # (slot 1 holds none: dead)
+    rows = Rows.of_slots(jnp.asarray(lengths), jnp.asarray(tables), K,
+                         groups, (3, 2))
+    Sg = S // groups
+    np.testing.assert_array_equal(rows.tables,
+                                  tables.reshape(groups, Sg, W))
+    want = lengths[:, None] + np.arange(K)[None]
+    np.testing.assert_array_equal(rows.positions,
+                                  want.reshape(groups, Sg, K))
+    np.testing.assert_array_equal(
+        rows.live, np.repeat([[True], [False], [True], [True]], K, axis=1))
+    assert rows.widths == (3, 2) and not rows.chunked \
+        and rows.freeze is None
+
+
+@pytest.mark.parametrize("freeze", [False, True])
+def test_rows_of_a_chunk_against_numpy(freeze):
+    G, C, W = 3, 8, 4
+    bt = np.arange(G * W, dtype=np.int32).reshape(G, W)
+    start = np.asarray([0, 16, 8], np.int32)
+    last_idx = np.asarray([7, 2, 5], np.int32)
+    active = np.asarray([1, 1, 0], np.int32)
+    pair = (jnp.asarray([3, -1, -1], jnp.int32),
+            jnp.asarray([5, DEAD_BLOCK, DEAD_BLOCK], jnp.int32))
+    rows = Rows.of_chunk(jnp.asarray(bt), jnp.asarray(start),
+                         jnp.asarray(last_idx), jnp.asarray(active), C,
+                         (W,), pair if freeze else None)
+    assert rows.tables.shape == (G, 1, W) and rows.chunked
+    np.testing.assert_array_equal(rows.tables[:2, 0], bt[:2])
+    assert (np.asarray(rows.tables[2]) == DEAD_BLOCK).all()   # inactive
+    want = start[:, None] + np.arange(C)[None]
+    np.testing.assert_array_equal(rows.positions, want[:, None, :])
+    np.testing.assert_array_equal(
+        rows.live, (active[:, None] > 0)
+        & (np.arange(C)[None] <= last_idx[:, None]))
+    assert not np.asarray(rows.live)[1, 3:].any()     # padding: not traffic
+    assert not np.asarray(rows.live)[2].any()         # the inactive group
+    assert (rows.freeze is pair) if freeze else (rows.freeze is None)
+
+
+@pytest.mark.parametrize("trailing", [(6,), (4, 6)])
+def test_the_last_row_pick_over_trailing_axes(trailing):
+    G, C = 3, 8
+    x = np.random.default_rng(0).normal(size=(G, C) + trailing).astype(
+        np.float32)
+    last_idx = np.asarray([7, 0, 4], np.int32)
+    got = Rows.last(jnp.asarray(x), jnp.asarray(last_idx))
+    assert got.shape == (G,) + trailing
+    np.testing.assert_array_equal(got, x[np.arange(G), last_idx])

@@ -23,9 +23,9 @@ shorter than the prompts:
    the whole layer of the reference; ``_greglu_kernel`` (interpret mode)
    against plain ``jax.numpy``.
 4. The default arguments leave the families that were served before where
-   they were: their ``decode_step`` lowers to the text it lowered to at PR
-   55 (``tests/data/decode_step_hlo_pr55.json``: PR 53's text but for
-   ``sample``'s branch on the temperature).
+   they were: ``tests/test_program_text.py`` holds every family's programs
+   to the text they lowered to at PR 55 (this file's six ``decode_step``
+   cases until PR 56, now cases of that test).
 """
 import dataclasses
 import json
@@ -463,21 +463,3 @@ def test_the_relu_product_has_a_kernel_name_of_its_own():
         and "_greglu_kernel" not in text["silu"]
     assert "_greglu_kernel" in text["relu"] \
         and "_gswiglu_kernel" not in text["relu"]
-
-
-# --------------------------------------------------------------------- #
-# 4. The families served before lower to what they lowered to
-# --------------------------------------------------------------------- #
-sys.path.insert(0, os.path.join(ROOT, "tests"))
-from decode_step_hlo import FAMILIES, decode_step_sha    # noqa: E402
-
-GOLDEN = json.load(open(os.path.join(ROOT, "tests", "data",
-                                     "decode_step_hlo_pr55.json")))
-
-
-@pytest.mark.parametrize("family", sorted(FAMILIES))
-def test_the_default_arguments_leave_decode_step_unchanged(family):
-    """Cells 4 / 10, 6, 7, 8 (and 9, which shares the attention branch):
-    ``decode_step``'s lowered text, kernels on and off, is what the tree at
-    PR 55 lowered to (PR 53's, but for ``sample``'s branch)."""
-    assert decode_step_sha(family) == GOLDEN[family]

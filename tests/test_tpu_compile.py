@@ -1687,10 +1687,10 @@ def test_paged_attention_at_the_paste_cells_shapes(K, Q, tiles, cls, blocks,
     scoped VMEM asked and kept at the default 16 MiB, and nothing but
     parameters, bitcasts and the kernel holds a pool or a layer of it."""
     from deepspeed_tpu.analysis.hlo_text import ops_in_units_of
-    from deepspeed_tpu.inference.afmoe import _attend_rows
+    from deepspeed_tpu.inference.kv_pages import attend_rows
     from deepspeed_tpu.ops import paged_attention as pa
     nKV, grp, Dh, bs = 4, 7, 128, 64
-    assert _attend_rows(512, grp) == 64 and _attend_rows(1, grp) == 1
+    assert attend_rows(512, grp) == 64 and attend_rows(1, grp) == 1
     assert pa._tile_rule(grp * K, nKV, Dh, bs, J, 2, 2) == tiles
     assert pa._step_vmem_bytes(*tiles, grp * K, Dh, bs, 2, 2) \
         <= pa._VMEM_BUDGET < pa._VMEM_LIMIT <= 16 * 2 ** 20

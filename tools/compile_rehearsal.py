@@ -171,21 +171,24 @@ def serving_steps(device, cfg):
     pool = on_chip(jax.ShapeDtypeStruct(spec.shape, spec.dtype))
     i32 = lambda *shape: on_chip(jax.ShapeDtypeStruct(shape, jnp.int32))
 
+    served = dm.GPT2Served(cfg)
+    flat = lambda out, pools, _: (out, *pools)                # noqa: E731
+
     def prefill_chunk_with_head(p, kc, vc, t, bt, st, li, ac):
         # (the hidden row, then the head: what a chunk program that ends a
         # prompt runs — the engine puts the head under a branch)
-        h, kc, vc = dm.gpt2_prefill_chunk_paged(
-            p, kc, vc, t, bt, st, li, ac, cfg, paged_kernel=True)
-        return dm._unembed(p, h, cfg), kc, vc
+        h, pools, _ = served.prefill_chunk(
+            p, (kc, vc), t, bt, st, li, ac, paged_kernel=True)
+        return served.head(p, h), *pools
 
     programs = {
         "paged_decode": (
-            lambda p, kc, vc, t, l, bt: dm.gpt2_decode_paged(
-                p, kc, vc, t, l, bt, cfg, 1, paged_kernel=True),
+            lambda p, kc, vc, t, l, bt: flat(*served.decode(
+                p, (kc, vc), t, l, bt, num_groups=1, paged_kernel=True)),
             (params, pool, pool, i32(slots), i32(slots), i32(slots, J))),
         f"paged_verify_k{spec_k + 1}": (
-            lambda p, kc, vc, t, l, bt: dm.gpt2_verify_paged(
-                p, kc, vc, t, l, bt, cfg, 1, True, None),
+            lambda p, kc, vc, t, l, bt: flat(*served.verify(
+                p, (kc, vc), t, l, bt, num_groups=1, paged_kernel=True)),
             (params, pool, pool, i32(slots, spec_k + 1), i32(slots),
              i32(slots, J))),
         f"paged_prefill_chunk{chunk}": (
