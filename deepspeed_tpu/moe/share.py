@@ -29,7 +29,8 @@ serving chip of an expert-parallel deployment runs:
   weighted outputs of the pairs (token, expert) whose expert it holds —
   every one of them: rows are grouped by expert (a counting sort, each
   group padded to the row tile) and go through one grouped gated product
-  per layer (``ops.grouped_gemm.grouped_swiglu``; the gate's activation
+  per layer (``ops.grouped_gemm.grouped_swiglu``, which reads each tile's
+  rows out of the tokens through the sort's ``src``; the gate's activation
   SiLU, or ReLU for ``smallthinker``).  There is no
   capacity, no ``[E, C, H]`` buffer and no dropped token: the row buffer
   is sized for the worst routing (every pair held).  What the absent
@@ -137,6 +138,17 @@ def dispatch(idx: jax.Array, r: Routing, tm: int, row_live=None):
             "n_live_tiles": end[-1] // tm, "counts": counts}
 
 
+def _tile_rows(d) -> jax.Array:
+    """Rows each tile of the plan ``d`` holds ``[M / tm]``: an expert's
+    ``counts`` rows fill its tiles from the first, the last one in part; a
+    tile past the live ones holds none."""
+    tm, counts, te = d["tm"], d["counts"], d["tile_expert"]
+    size = -(-counts // tm) * tm
+    held_to = jnp.cumsum(size) - size + counts      # past an expert's last row
+    at = jnp.arange(te.shape[0], dtype=jnp.int32) * tm
+    return jnp.clip(held_to[te] - at, 0, tm)
+
+
 def _gate_act(act: str):
     return {"silu": jax.nn.silu, "relu": jax.nn.relu}[act]
 
@@ -187,14 +199,17 @@ def _apply(experts, x, d, r: Routing, kernel, layer, act: str):
         kernel = grouped_gemm.grouped_gemm_enabled("auto")
     tm = d["tm"]
     with jax.named_scope("dispatch"):
-        xs = x[d["src"]]
+        # The kernel reads its rows through the plan; the plain arm gathers.
+        xs = None if kernel else x[d["src"]]
         tile_expert = d["tile_expert"] if layer is None else \
             d["tile_expert"] + layer * r.held[1]
+        tile_rows = _tile_rows(d) if kernel else None
     with jax.named_scope("experts"):
         if kernel:
             out = grouped_gemm.grouped_swiglu(
-                xs, experts["w_gate"], experts["w_up"], experts["w_down"],
-                tile_expert, d["n_live_tiles"], tm=tm, act=act)
+                x, d["src"], experts["w_gate"], experts["w_up"],
+                experts["w_down"], tile_expert, tile_rows,
+                d["n_live_tiles"], tm=tm, act=act)
         else:
             out = _experts_jnp(xs, experts, tile_expert,
                                d["n_live_tiles"], tm, act)
@@ -210,8 +225,10 @@ def apply_routes(p: Dict[str, jax.Array], x: jax.Array,
                  kernel: Optional[bool] = None, layer=None,
                  act: str = "silu") -> Tuple[jax.Array, jax.Array]:
     """The held experts over THEIR input ``x [T, H]`` under the plan ``d``:
-    the rows gathered by expert (scope ``dispatch``), the grouped gated
-    product (``experts``) and the weighted sum (``combine``).  Returns
+    the grouped gated product over the rows the plan names (``experts``:
+    the kernel reads ``x`` through ``d["src"]`` itself; only the plain arm
+    gathers ``x[src]`` first, scope ``dispatch``) and the weighted sum
+    (``combine``).  Returns
     (y [T, H], rows per held expert).  ``kernel``, ``layer``, ``act``: see
     ``routed_share``."""
     return _apply(_expert_stack(p), x, d, r, kernel, layer, act)

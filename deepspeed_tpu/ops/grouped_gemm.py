@@ -28,15 +28,18 @@ Structure:
   the moe lint flagship's materialization pass clean), and expresses
   every gradient contraction as the SAME grouped kernel on swapped
   axes.
-- ``grouped_swiglu(xs, w_gate, w_up, w_down, tile_expert, n_live_tiles,
-  tm=, act=)`` — the serving tier's DROPLESS gated FFN (no autodiff, no
-  capacity): rows grouped by expert in tiles of ``tm``, each tile against
-  its expert's ``[F, H]`` matrices, ``down(act(gate x) * up x)`` with the
-  gate's activation static — ``"silu"`` (``_gswiglu_kernel``:
-  ``deepseek_v3``, ``afmoe``, ``lfm2_moe``, ``kimi_linear``) or ``"relu"``
-  (``_greglu_kernel``: ``smallthinker``); one grid, one tile rule, two
-  kernel names so that a trace tells them apart.  ``moe/share.py`` lays
-  the rows out and is its only caller.
+- ``grouped_swiglu(x, src, w_gate, w_up, w_down, tile_expert, tile_rows,
+  n_live_tiles, tm=, act=)`` — the serving tier's DROPLESS gated FFN (no
+  autodiff, no capacity): rows grouped by expert in tiles of ``tm``, each
+  tile against its expert's ``[F, H]`` matrices, ``down(act(gate x) * up
+  x)`` with the gate's activation static — ``"silu"``
+  (``_gswiglu_kernel``: ``deepseek_v3``, ``afmoe``, ``lfm2_moe``,
+  ``kimi_linear``) or ``"relu"`` (``_greglu_kernel``: ``smallthinker``);
+  one grid, one tile rule, two kernel names so that a trace tells them
+  apart.  The kernel brings a tile's rows in itself, ``x[src[...]]`` row by
+  row out of the tokens held in VMEM: no padded ``[M, H]`` copy of the
+  rows is ever written.  ``moe/share.py`` plans the rows and is its only
+  caller.
 
 Numerics contract (tests/test_moe.py): vs the einsum path, fp32 agrees
 to a few f32 ulp (cross-program dot association — the PR-1 tolerance
@@ -279,21 +282,42 @@ grouped_ffn.defvjp(_gff_fwd, _gff_bwd)
 # held share)
 # --------------------------------------------------------------------- #
 _SWIGLU_VMEM_LIMIT = 100 * 2 ** 20
+_SWIGLU_WEIGHTS_ROOM = 48 * 2 ** 20     # three weight blocks, two buffers each
 
 
-def _gated_step(gate_act, te_ref, nl_ref, x_ref, wg_ref, wu_ref, wd_ref,
-                o_ref, acc_ref):
+def _gated_step(gate_act, te_ref, nl_ref, tr_ref, src_ref, x_ref, wg_ref,
+                wu_ref, wd_ref, o_ref, acc_ref, rows_ref, x32_ref):
     """One grid step = one (row tile, F tile): the tile's rows all belong
     to expert ``te_ref[i]``; ``acc += (gate_act(x Wg^T) * (x Wu^T)) Wd``
-    over the F tiles, written out at the last.  A tile past the live count
-    (``nl_ref[0]``) computes nothing and emits zeros."""
+    over the F tiles, written out at the last.  The first step widens the
+    tokens ``x_ref`` into ``x32_ref`` (float32: one row is one sublane, which
+    a loop can name; exact).  At its first F step a tile copies its
+    ``tr_ref[i]`` live rows ``x32_ref[src_ref[i * tm + r]]`` into
+    ``rows_ref``, and every F step reads them there; the rows past the live
+    count keep whatever an earlier tile left (zeros before the first),
+    which nothing reads.  A tile past the live count (``nl_ref[0]``)
+    computes nothing and emits zeros."""
     i, j = pl.program_id(0), pl.program_id(1)
     last = pl.num_programs(1) - 1
     live = i < nl_ref[0]
+    tm = rows_ref.shape[0]
+
+    @pl.when(jnp.logical_and(i == 0, j == 0))
+    def _first_step():
+        rows_ref[...] = jnp.zeros_like(rows_ref)
+        x32_ref[...] = x_ref[...].astype(jnp.float32)
+
+    @pl.when(jnp.logical_and(live, j == 0))
+    def _rows():
+        def row(r, carry):
+            rows_ref[pl.ds(r, 1), :] = x32_ref[pl.ds(src_ref[i * tm + r], 1),
+                                               :]
+            return carry
+        jax.lax.fori_loop(0, tr_ref[i], row, 0)
 
     @pl.when(live)
     def _compute():
-        x = x_ref[...]
+        x = rows_ref[...].astype(o_ref.dtype)
         nt = (((1,), (1,)), ((), ()))                    # x [tm,H] . w [tf,H]
         g = jax.lax.dot_general(x, wg_ref[0], nt,
                                 preferred_element_type=jnp.float32)
@@ -334,43 +358,59 @@ def _greglu_kernel(*refs):
 _GATED_KERNELS = {"silu": _gswiglu_kernel, "relu": _greglu_kernel}
 
 
-def _swiglu_f_tile(F: int, H: int, itemsize: int) -> int:
+def _swiglu_f_tile(F: int, H: int, itemsize: int,
+                   room: int = _SWIGLU_WEIGHTS_ROOM) -> int:
     """The widest F tile (a multiple of 128 that divides F, or F) whose
-    three weight blocks, double-buffered, stay under 48 MiB."""
+    three weight blocks, double-buffered, stay under ``room`` bytes: 48
+    MiB, or what the tokens held in VMEM beside them leave."""
     tf = F
-    while tf > 128 and (F % tf or tf % 128
-                        or 6 * tf * H * itemsize > 48 * 2 ** 20):
+    while tf > 128 and (F % tf or tf % 128 or 6 * tf * H * itemsize > room):
         tf -= 128
     return tf if F % tf == 0 else F
 
 
-def grouped_swiglu(xs, w_gate, w_up, w_down, tile_expert, n_live_tiles, *,
-                   tm: int, act: str = "silu"):
-    """``out[r] = down_e(act(gate_e xs[r]) * up_e xs[r])`` (``act``: the
-    gate's activation, ``"silu"`` or ``"relu"``, static) for rows
-    grouped by expert: ``xs [M, H]`` whose row tile ``t`` (``tm`` rows)
-    belongs to expert ``tile_expert[t]``; only the first
-    ``n_live_tiles`` tiles hold rows (the rest emit zeros and move no
-    weight).  Weights ``[E, F, H]`` (an expert's ``[tf, H]`` tile is one
-    contiguous run).  No capacity and nothing dropped: the caller lays
-    every routed row into ``xs`` (``moe/share.py``).  Returns ``[M, H]``
-    in xs's dtype, fp32 accumulation."""
-    M, H = xs.shape
+def grouped_swiglu(x, src, w_gate, w_up, w_down, tile_expert, tile_rows,
+                   n_live_tiles, *, tm: int, act: str = "silu"):
+    """``out[r] = down_e(act(gate_e x[src[r]]) * up_e x[src[r]])`` (``act``:
+    the gate's activation, ``"silu"`` or ``"relu"``, static) for rows
+    grouped by expert: buffer row ``r`` of ``M = len(src)`` is token
+    ``src[r]`` of ``x [T, H]``; row tile ``t`` (``tm`` rows) belongs to
+    expert ``tile_expert[t]`` and holds ``tile_rows[t]`` rows, from its
+    first; only the first ``n_live_tiles`` tiles hold any (the rest emit
+    zeros and move no weight).  Weights ``[E, F, H]`` (an expert's ``[tf,
+    H]`` tile is one contiguous run).  No capacity and nothing dropped: the
+    caller plans a row for every routed pair (``moe/share.py``).  The
+    kernel reads the rows through ``src`` itself — ``x`` sits in VMEM whole
+    (and once more as float32, widened by the kernel's first step, so that
+    a row is a sublane a loop can name; the products' operands are the same
+    ``x.dtype`` values a gathered ``x[src]`` would hold) — so the ``[M, H]``
+    copy is never made, and ``x`` itself is the call's operand, as it was
+    the gather's: the program round the call keeps the neighbours it had
+    (a float32 view made OUTSIDE let the compiler fold the producer of ``x``
+    into each of its readers, and one cell's router then read it unrounded).
+    Returns ``[M, H]`` in x's dtype, fp32 accumulation; what a live tile's
+    rows PAST its ``tile_rows`` hold is finite and otherwise unspecified
+    (they were never anybody's: ``share``'s ``pos`` points at held pairs
+    only)."""
+    T, H = x.shape
+    M, = src.shape
     E, F, _ = w_gate.shape
     assert M % tm == 0, (M, tm)
     nt = M // tm
-    tf = _swiglu_f_tile(F, H, jnp.dtype(w_gate.dtype).itemsize)
+    # x (one buffer: its block never moves), its float32 copy and the tiles
+    # (rows, acc, out) come out of the limit first; at the cells' widths the
+    # weights still get their 48 MiB.
+    held = (x.dtype.itemsize + 4) * T * H
+    room = _SWIGLU_VMEM_LIMIT - held - 16 * tm * H - 8 * 2 ** 20
+    tf = _swiglu_f_tile(F, H, jnp.dtype(w_gate.dtype).itemsize,
+                        min(room, _SWIGLU_WEIGHTS_ROOM))
     nf = F // tf
-    te = tile_expert.astype(jnp.int32)
     nl = jnp.asarray(n_live_tiles, jnp.int32).reshape(1)
 
     def tile_of(i, nl_p):
         return jnp.maximum(jnp.minimum(i, nl_p[0] - 1), 0)
 
-    def x_map(i, j, te_p, nl_p):
-        return (tile_of(i, nl_p), 0)
-
-    def w_map(i, j, te_p, nl_p):
+    def w_map(i, j, te_p, nl_p, tr_p, src_p):
         # A dead tile names the block the last live step left in VMEM.
         return (te_p[tile_of(i, nl_p)],
                 jnp.where(i < nl_p[0], j, nf - 1), 0)
@@ -380,17 +420,22 @@ def grouped_swiglu(xs, w_gate, w_up, w_down, tile_expert, n_live_tiles, *,
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(nt, nf),
-            in_specs=[pl.BlockSpec((tm, H), x_map), w_spec, w_spec, w_spec],
-            out_specs=pl.BlockSpec((tm, H), lambda i, j, te_p, nl_p: (i, 0)),
-            scratch_shapes=[pltpu.VMEM((tm, H), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((M, H), xs.dtype),
+            num_scalar_prefetch=4, grid=(nt, nf),
+            in_specs=[pl.BlockSpec((T, H), lambda i, j, *_: (0, 0),
+                                   pipeline_mode=pl.Buffered(1)),
+                      w_spec, w_spec, w_spec],
+            out_specs=pl.BlockSpec((tm, H), lambda i, j, *_: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((tm, H), jnp.float32),
+                            pltpu.VMEM((tm, H), jnp.float32),
+                            pltpu.VMEM((T, H), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((M, H), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_SWIGLU_VMEM_LIMIT),
         name=kernel.__name__,
         interpret=_interpret(),
-    )(te, nl, xs, w_gate, w_up, w_down)
+    )(tile_expert.astype(jnp.int32), nl, tile_rows.astype(jnp.int32),
+      src.astype(jnp.int32), x, w_gate, w_up, w_down)
 
 
 __all__ = ["grouped_ffn", "grouped_gemm_enabled", "grouped_swiglu"]

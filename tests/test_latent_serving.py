@@ -504,17 +504,25 @@ def test_dispatch_is_dropless(skew, held):
 @pytest.mark.parametrize("dtype,atol", [(jnp.float32, 1e-5),
                                         (jnp.bfloat16, 0.03)])
 def test_grouped_swiglu_kernel_equals_its_jnp_form(dtype, atol):
+    """The kernel reads token ``src[r]`` into buffer row ``r`` itself, the
+    rows each tile holds and no more; the plain form takes the gathered
+    copy."""
     rng = np.random.default_rng(2)
-    E, F, H, tm, nt = 6, 256, 128, 16, 7
+    E, F, H, tm, nt, T = 6, 256, 128, 16, 7, 40
     w = {k: jnp.asarray(rng.standard_normal((E, F, H)) * 0.1, dtype)
          for k in ("w_gate", "w_up", "w_down")}
-    xs = jnp.asarray(rng.standard_normal((nt * tm, H)), dtype)
+    x = jnp.asarray(rng.standard_normal((T, H)), dtype)
+    src = jnp.asarray(rng.integers(0, T, nt * tm), jnp.int32)
     te = jnp.asarray([0, 0, 2, 5, 5, 1, 3], jnp.int32)
-    out = grouped_gemm.grouped_swiglu(xs, w["w_gate"], w["w_up"],
-                                      w["w_down"], te, 5, tm=tm)
-    ref = share._experts_jnp(xs, w, te, 5, tm)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), atol=atol)
+    rows = np.asarray([16, 3, 16, 16, 9, 0, 0], np.int32)
+    out = grouped_gemm.grouped_swiglu(x, src, w["w_gate"], w["w_up"],
+                                      w["w_down"], te, jnp.asarray(rows), 5,
+                                      tm=tm)
+    ref = share._experts_jnp(x[src], w, te, 5, tm)
+    held = (np.arange(nt * tm) % tm) < np.repeat(rows, tm)
+    np.testing.assert_allclose(np.asarray(out, np.float32)[held],
+                               np.asarray(ref, np.float32)[held], atol=atol)
+    assert np.isfinite(np.asarray(out, np.float32)).all()
     assert not np.asarray(out[5 * tm:], np.float32).any()   # dead tiles
 
 

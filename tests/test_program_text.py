@@ -17,9 +17,13 @@ engine's three programs are ``ServedModel``'s, written once over a family's
      same operations in another order (equal ORDER-FREE hashes) — or, for
      the programs ``MOVED`` names with their causes, the text this PR left
      (``tests/data/program_text_pr56.json``, the same file written on this
-     PR's tree, so that the next change to them shows).  A program that lowers
-     to neither fails: run ``python tests/decode_step_hlo.py OUT.json DIR``
-     on both trees and ``diff`` the blanked texts to see which lines moved;
+     PR's tree, so that the next change to them shows), and for the programs
+     ``MOVED_BY_PR58`` names — the kernel arm of the six expert-layer
+     families, whose grouped product reads its rows through the plan — the
+     text PR 58 left (``program_text_pr58.json``).  A program that lowers
+     to none of them fails: run ``python tests/decode_step_hlo.py OUT.json
+     DIR`` on both trees and ``diff`` the blanked texts to see which lines
+     moved;
    - ``verify`` of a family whose cache is a state a stream to the ONE
      refusal.
 
@@ -44,6 +48,7 @@ from deepspeed_tpu.inference.served import Rows, served_model   # noqa: E402
 DATA = os.path.join(harness.TESTS, "data")
 GOLDEN = json.load(open(os.path.join(DATA, "program_text_pr55.json")))
 LEFT_BY_PR56 = json.load(open(os.path.join(DATA, "program_text_pr56.json")))
+LEFT_BY_PR58 = json.load(open(os.path.join(DATA, "program_text_pr58.json")))
 
 # Why a program's operations are not, line for line, the ones PR 55 lowered
 # (CHANGES.md, PR 56, quotes the lines).  Where the seven copies of the
@@ -68,6 +73,23 @@ MOVED = {
     "retention": {"decode_step": (K1, READS),
                   "prefill_step": (COLS, READS)},
 }
+# PR 58, kernels ON only: every program of a family with an expert layer
+# (``verify_step`` where the family has one).
+ROWS = ("the grouped product takes the tokens `x [T, H]` and the plan's "
+        "`src` and brings each tile's rows in itself: the `[M, H]` gather "
+        "`x[src]` in front of the kernel is gone, `tile_rows` (the rows each "
+        "tile holds) is new, and the kernel's body widens `x` to float32 "
+        "once and has the row loop")
+MOVED_BY_PR58 = {
+    family: {kind: (ROWS,) for kind in kinds}
+    for family, kinds in {
+        "afmoe": ("decode_step", "prefill_step", "verify_step"),
+        "latent_share": ("decode_step", "prefill_step", "verify_step"),
+        "latent_hyper": ("decode_step", "prefill_step", "verify_step"),
+        "smallthinker": ("decode_step", "prefill_step", "verify_step"),
+        "lfm2": ("decode_step", "prefill_step"),
+        "kimi_linear": ("decode_step", "prefill_step"),
+    }.items()}
 KINDS = ("decode_step", "prefill_step", "verify_step", "outputs")
 REFUSES = ("retention", "lfm2", "falcon_h1", "kimi_linear")
 
@@ -90,8 +112,15 @@ def held_to_the_golden(family, kind, arm):
                 paged_kernel=False)
         return
     assert names
+    by_pr58 = MOVED_BY_PR58.get(family, {}) if arm == "on" else {}
     for name in names:
         g, w = got["programs"][name], want["programs"][name]
+        if kind in by_pr58:
+            assert g["order_free"] == LEFT_BY_PR58[family][arm]["programs"][
+                    name]["order_free"], (
+                f"{family}.{arm}.{name}: other operations than PR 58 left "
+                f"(moved then by: {'; '.join(by_pr58[kind])})")
+            continue
         if g["order_free"] == w["order_free"]:
             continue            # the same lines (in another order at most)
         assert kind in MOVED.get(family, {}), (
@@ -109,20 +138,27 @@ def test_the_programs_are_what_they_were(family, kind):
     held_to_the_golden(family, kind, "off")
 
 
-def test_the_causes_on_record_are_of_the_programs_that_moved():
+@pytest.mark.parametrize("was,now,moved_by", [
+    (GOLDEN, LEFT_BY_PR56, lambda family, arm: MOVED.get(family, {})),
+    (LEFT_BY_PR56, LEFT_BY_PR58, lambda family, arm:
+     MOVED_BY_PR58.get(family, {}) if arm == "on" else {})],
+    ids=["pr56", "pr58"])
+def test_the_causes_on_record_are_of_the_programs_that_moved(was, now,
+                                                             moved_by):
     """``MOVED`` names the programs whose operations PR 56 left other than
-    PR 55's, and no other (an entry would outlive its cause), in both
-    kernel arms; what every fixture computes did not move."""
+    PR 55's, ``MOVED_BY_PR58`` those PR 58 left other than PR 56's (the
+    kernel arm alone), and no other (an entry would outlive its cause);
+    what every fixture computes moved in neither."""
     for family in harness.FAMILIES:
         for arm in harness.ARMS:
-            was, now = GOLDEN[family][arm], LEFT_BY_PR56[family][arm]
-            assert was["outputs"] == now["outputs"]
-            assert sorted(was["programs"]) == sorted(now["programs"])
+            old, new = was[family][arm], now[family][arm]
+            assert old["outputs"] == new["outputs"]
+            assert sorted(old["programs"]) == sorted(new["programs"])
             moved = {name.split("_step")[0] + "_step"
-                     for name, hashes in now["programs"].items()
+                     for name, hashes in new["programs"].items()
                      if hashes["order_free"]
-                     != was["programs"][name]["order_free"]}
-            assert moved == set(MOVED.get(family, {})), (family, arm)
+                     != old["programs"][name]["order_free"]}
+            assert moved == set(moved_by(family, arm)), (family, arm)
 
 
 # --------------------------------------------------------------------- #

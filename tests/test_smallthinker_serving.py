@@ -436,14 +436,18 @@ def test_plan_then_apply_is_routed_share():
 @pytest.mark.parametrize("act", ["silu", "relu"])
 def test_the_gated_kernels_against_plain_jnp(act):
     rng = np.random.default_rng(1)
-    tm, H, F, E = 16, 128, 256, 4
-    xs = jnp.asarray(rng.normal(size=(6 * tm, H)), jnp.float32)
+    tm, H, F, E, T = 16, 128, 256, 4, 30
+    x = jnp.asarray(rng.normal(size=(T, H)), jnp.float32)
+    src = jnp.asarray(rng.integers(0, T, 6 * tm), jnp.int32)
+    xs = x[src]                                     # the plain form's copy
     w = {k: jnp.asarray(rng.normal(size=(E, F, H)) * 0.1, jnp.float32)
          for k in ("w_gate", "w_up", "w_down")}
     te = jnp.asarray([0, 0, 2, 3, 3, 3], jnp.int32)
+    full = jnp.full((6,), tm, jnp.int32)            # every tile's rows held
     with jax.default_matmul_precision("highest"):
         got = grouped_gemm.grouped_swiglu(
-            xs, w["w_gate"], w["w_up"], w["w_down"], te, 5, tm=tm, act=act)
+            x, src, w["w_gate"], w["w_up"], w["w_down"], te, full, 5, tm=tm,
+            act=act)
         want = share._experts_jnp(xs, w, te, 5, tm, act)
     assert np.abs(np.asarray(got - want)).max() < 1e-4
     assert not np.asarray(got)[5 * tm:].any()       # the dead tile
@@ -456,8 +460,9 @@ def test_the_relu_product_has_a_kernel_name_of_its_own():
     xs = jnp.zeros((16, 128), jnp.float32)
     w = jnp.zeros((2, 128, 128), jnp.float32)
     te = jnp.zeros((1,), jnp.int32)
+    src = jnp.arange(16, dtype=jnp.int32)
     text = {act: jax.jit(lambda a, act=act: grouped_gemm.grouped_swiglu(
-        a, w, w, w, te, 1, tm=16, act=act)).lower(xs).as_text(
+        a, src, w, w, w, te, te + 16, 1, tm=16, act=act)).lower(xs).as_text(
             debug_info=True) for act in ("silu", "relu")}
     assert "_gswiglu_kernel" in text["silu"] \
         and "_greglu_kernel" not in text["silu"]

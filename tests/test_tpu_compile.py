@@ -815,21 +815,71 @@ def test_latent_write_compiles_at_the_published_widths(rows, one_chip,
         _sds((1, rows), jnp.int32))
 
 
+def _compile_grouped(one_chip, T, H, M, tm, w, act="silu"):
+    """``grouped_swiglu`` alone over ``T`` tokens of width ``H`` and a plan
+    of ``M`` buffer rows in tiles of ``tm``, weights ``w`` (a shape)."""
+    from deepspeed_tpu.ops import grouped_gemm as gg
+    tiles = _sds((M // tm,), jnp.int32)
+    return _compile(lambda x, src, wg, wu, wd, te, tr, nl: gg.grouped_swiglu(
+        x, src, wg, wu, wd, te, tr, nl, tm=tm, act=act), one_chip,
+        _sds((T, H), jnp.bfloat16), _sds((M,), jnp.int32), w, w, w, tiles,
+        tiles, _sds((), jnp.int32))
+
+
 @pytest.mark.parametrize("tokens", [128, 512], ids=["decode", "prefill"])
 def test_grouped_swiglu_compiles_at_the_published_widths(tokens, one_chip,
                                                          as_tpu):
     from deepspeed_tpu.models.deepseek_v3 import DeepseekV3Config
     from deepspeed_tpu.moe import share
-    from deepspeed_tpu.ops import grouped_gemm as gg
     cfg = DeepseekV3Config(held=(0, 16))
     tm = share._row_tile(tokens, cfg.routing)
     assert tm == (16 if tokens == 128 else 32)
     M = -(-tokens * 8 // tm) * tm + 16 * tm         # the worst routing
     w = _sds((4 * 16, 2048, 7168), jnp.bfloat16)    # four layers' experts
-    _compile(lambda xs, wg, wu, wd, te, nl: gg.grouped_swiglu(
-        xs, wg, wu, wd, te, nl, tm=tm), one_chip,
-        _sds((M, 7168), jnp.bfloat16), w, w, w, _sds((M // tm,), jnp.int32),
-        _sds((), jnp.int32))
+    _compile_grouped(one_chip, tokens, 7168, M, tm, w)
+
+
+# (decode rows, H, F, routed experts, experts held, per token): the six
+# expert-layer cells of BENCHMARK.json, perfbench/configs/<name>.json.
+EXPERT_CELLS = {
+    "gigachat": (128, 7168, 2048, 256, 16, 8),
+    "trinity": (128, 2048, 1024, 128, 128, 8),
+    "xing": (256, 3584, 1024, 64, 64, 4),
+    "lfm2": (128, 2048, 1536, 64, 64, 4),
+    "kimi": (256, 2304, 1024, 256, 16, 8),
+    "smallthinker": (64, 2560, 768, 64, 64, 6),
+}
+
+
+@pytest.mark.parametrize("width", ["decode", "chunk"])
+@pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
+def test_the_grouped_product_reads_its_rows_at_every_cells_widths(
+        cell, width, one_chip, as_tpu):
+    """The product holds the tokens ``[T, H]`` whole in VMEM as float32 and
+    the plan's ``src`` (the worst routing's ``M`` int32: 12,288 in the
+    trinity cell's chunk) in SMEM beside the weights' tiles: every cell's
+    decode iteration and 512-row chunk program must fit the chip's."""
+    from deepspeed_tpu.models.blocks import Routing
+    from deepspeed_tpu.moe import share
+    slots, H, F, experts, held, k = EXPERT_CELLS[cell]
+    T = slots if width == "decode" else 512
+    tm = share._row_tile(T, Routing(experts, k, 1, 1, True, 1.0, (0, held)))
+    M = -(-T * k // tm) * tm + held * tm             # the worst routing
+    w = _sds((2 * held, F, H), jnp.bfloat16)         # two layers' experts
+    text = _compile_grouped(one_chip, T, H, M, tm, w)
+    assert "_gswiglu_kernel" in text
+
+
+def test_the_grouped_product_narrows_its_f_tile_for_a_long_chunk(one_chip,
+                                                                 as_tpu):
+    """A chunk of 1,024 rows at the widest cell's H: the tokens take 44 MB
+    of VMEM (as they are and as float32) and the weights' tiles what is
+    left (an F tile of 256 where 512 rows' chunk has 512)."""
+    T, H, F, held, k, tm = 1024, 7168, 2048, 16, 8, 128
+    M = T * k + held * tm
+    w = _sds((held, F, H), jnp.bfloat16)
+    text = _compile_grouped(one_chip, T, H, M, tm, w)
+    assert "_gswiglu_kernel" in text
 
 
 def _latent_cell_programs(topo, config_file, **overrides):
@@ -1734,10 +1784,7 @@ def test_grouped_reglu_compiles_at_the_published_widths(tokens, one_chip,
     assert tm == (16 if tokens == 64 else 128)
     M = -(-tokens * 6 // tm) * tm + 64 * tm          # the worst routing
     w = _sds((64, 768, 2560), jnp.bfloat16)
-    text = _compile(lambda xs, wg, wu, wd, te, nl: gg.grouped_swiglu(
-        xs, wg, wu, wd, te, nl, tm=tm, act="relu"), one_chip,
-        _sds((M, 2560), jnp.bfloat16), w, w, w, _sds((M // tm,), jnp.int32),
-        _sds((), jnp.int32))
+    text = _compile_grouped(one_chip, tokens, 2560, M, tm, w, act="relu")
     assert "_greglu_kernel" in text and "_gswiglu_kernel" not in text
 
 
