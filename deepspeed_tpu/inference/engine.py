@@ -227,6 +227,7 @@ class _Flight:
     """One decode iteration between its dispatch and its token fetch."""
     fetch: Any                  # device: tokens (+ the model's counters)
     logits: Any                 # device: [S, V]
+    probes: Any                 # device: the model's probes (or None)
     mask: np.ndarray            # [S] bool: slots in it and still owed its token
     n_active: int               # slots it was dispatched for
     t0: float                   # the clock when its host work began ...
@@ -239,6 +240,11 @@ class _Flight:
 
 class InferenceEngine:
     """Batched autoregressive serving over a device mesh."""
+    # The served model's probes (``ServedModel.probe_names``) of the
+    # program(s) whose logits a caller last asked for (``return_logits``),
+    # by name: a row a slot after ``decode_once``, a row an admission after
+    # ``prefill_many``.  None for a model without.
+    last_probes: Optional[Dict[str, np.ndarray]] = None
 
     def __init__(self, model_cfg: Any, params: Any,
                  config: Any = None, mesh: Optional[Mesh] = None,
@@ -504,11 +510,19 @@ class InferenceEngine:
         return params
 
     def _jit_step(self, step: Callable, fetch_sharding=None) -> Callable:
-        """``step(params, *pools, ...) -> (*pools, fetch, logits)``: the
-        pools donated and returned where they lie."""
+        """``step(params, *pools, ...) -> (*pools, fetch, logits[,
+        probes])``: the pools donated and returned where they lie; the
+        last output only from a model with ``probe_names``."""
         sh = tuple(self._cache_sh.values())
+        probes = (None,) * bool(self.served.probe_names)
         return jax.jit(step, donate_argnums=tuple(range(1, 1 + len(sh))),
-                       out_shardings=sh + (fetch_sharding, None))
+                       out_shardings=sh + (fetch_sharding, None) + probes)
+
+    def _outputs(self, out) -> Tuple[Any, Any, Any, Any]:
+        """(pools, fetch, logits, probes or None) of a step's outputs."""
+        n = len(self._cache_sh)
+        return (out[:n], out[n], out[n + 1],
+                out[n + 2] if len(out) > n + 2 else None)
 
     def _build_decode_step(self) -> Callable:
         """``decode_step(params, *pools, previous, tokens, fresh, lengths,
@@ -529,14 +543,15 @@ class InferenceEngine:
                                previous[:tokens.shape[0]])
             p = self._runtime_params(params)
             in_place = filter_rows_lowered["in_place"]
-            logits, pools, counters = served.decode(
+            logits, pools, counters, *probes = served.decode(
                 p, pools, tokens, lengths, bt, num_groups=self.dp,
                 paged_kernel=self.paged_kernel, mesh=self.mesh)
             # (this body runs when the program is traced: once)
             self.filter_rows_in_place = int(
                 filter_rows_lowered["in_place"] > in_place)
             sampled = sample_tokens(logits, key, temperature)
-            return (*pools, with_counters(sampled, counters), logits)
+            return (*pools, with_counters(sampled, counters), logits,
+                    *probes)
 
         return self._jit_step(decode_step, self.__dict__.get("_fetch_sh"))
 
@@ -561,13 +576,14 @@ class InferenceEngine:
             pools, (tokens, bt_rows, start, last_idx, active, *freeze, read,
                     key, temperature) = args[:n], args[n:]
             p = self._runtime_params(params)
-            h_last, pools, counters = served.prefill_chunk(
+            h_last, pools, counters, *probes = served.prefill_chunk(
                 p, pools, tokens, bt_rows, start, last_idx, active, *freeze,
                 paged_kernel=self.paged_kernel, mesh=self.mesh)
             sampled, logits = head_and_sample(
                 read, functools.partial(served.head, p), h_last, key,
                 temperature)
-            return (*pools, with_counters(sampled, counters), logits)
+            return (*pools, with_counters(sampled, counters), logits,
+                    *probes)
 
         return self._jit_step(prefill_step)
 
@@ -856,6 +872,13 @@ class InferenceEngine:
                         self.drafter.begin(slot, prompt)
                     self.serving.note_admit(plen, plan.matched)
                     out.append((tok, logits))
+                if return_logits and self.served.probe_names:
+                    rows = [jax.device_get(jax.tree.map(
+                        lambda p, g=held[slot][1]: p[g],
+                        steps[held[slot][0]][2])) for slot, *_ in plans]
+                    self.last_probes = {
+                        name: np.stack([np.asarray(r[i]) for r in rows])
+                        for i, name in enumerate(self.served.probe_names)}
             cached = sum(int(p[2].matched) for p in plans)
             computed = self.dp * sum(widths)
             span.set_metadata(cached_tokens=cached, chunks=len(steps),
@@ -1075,17 +1098,17 @@ class InferenceEngine:
                 with self.telemetry.span("prefill_chunk", ci=ci,
                                          active_groups=int(act.sum()),
                                          rows=width, head=int(read)):
-                    *pools, tok_g, logits_g = self._prefill_fn(
+                    pools, *step = self._outputs(self._prefill_fn(
                         self._params, *pools, toks, bt_rows, starts,
                         last_idxs, act, *freeze, read, self._next_key(),
-                        temp)
+                        temp))
                 if snaps:
                     pools = self._copy_blocks(pools, snaps)
                 # (dispatched: the device's order makes the page whole
                 # before anything can resume from it)
                 for plan in frozen:
                     self.allocator.commit_snapshot(plan)
-                steps.append((tok_g, logits_g))
+                steps.append(tuple(step))
                 widths.append(width)
         finally:
             # also where a chunk raised: the pools before it were donated
@@ -1115,10 +1138,10 @@ class InferenceEngine:
         pools = self._pools()
         try:
             for width in reversed(self.prefill_widths):
-                *pools, _, _ = self._prefill_fn(
+                pools = self._outputs(self._prefill_fn(
                     self._params, *pools, np.zeros((G, width), np.int32),
                     dead, zeros, zeros, zeros, *self._no_freeze(),
-                    np.int32(0), self._base_rng, np.float32(0.0))
+                    np.int32(0), self._base_rng, np.float32(0.0)))[0]
         finally:
             self._store_pools(pools)
         self._prefill_warmed = True
@@ -1337,8 +1360,13 @@ class InferenceEngine:
                 dropped=due.dropped if due is not None else 0)
         if ahead:
             return sampled, took
-        out_logits = np.asarray(jax.device_get(due.logits)) \
-            if return_logits else None
+        out_logits = None
+        if return_logits:
+            out_logits = np.asarray(jax.device_get(due.logits))
+            if due.probes is not None:
+                self.last_probes = dict(zip(
+                    self.served.probe_names,
+                    map(np.asarray, jax.device_get(due.probes))))
         return sampled, out_logits
 
     def _decode_dispatch(self, span, mask: np.ndarray, n_active: int,
@@ -1363,11 +1391,11 @@ class InferenceEngine:
             steps = self._attend_steps(1, lengths, tables)
         lap("tables_s")
         with tl.span("decode_dispatch"):
-            *pools, fetch, logits = self._decode_fn(
+            pools, fetch, logits, probes = self._outputs(self._decode_fn(
                 self._params, *self._pools(),
                 self._no_fetch if prev is None else prev.fetch,
                 self.last_tokens.copy(), fresh, lengths, tables,
-                self._next_key(), np.float32(temperature))
+                self._next_key(), np.float32(temperature)))
             self._store_pools(pools)
             tl.raise_pending()
             self._fresh[:] = False
@@ -1388,7 +1416,7 @@ class InferenceEngine:
                               attend_cold_steps=steps[2],
                               **self._class_args(mask), **state)
         lap("dispatch_s")
-        return _Flight(fetch=fetch, logits=logits, mask=mask,
+        return _Flight(fetch=fetch, logits=logits, probes=probes, mask=mask,
                        n_active=n_active, t0=t0,
                        prefill_s=self._prefill_wall,
                        ahead=int(prev is not None),

@@ -52,7 +52,15 @@ ask a ``ServedModel`` for:
   ``keys``; a state: a constant), and ``attend_step_counts``;
 - **counters** a program returns beside its logits (``counter_names``):
   int32 scalars that ride the token fetch — the expert layer's held-row
-  counts for the latent family, none for GPT-2.
+  counts for the latent family, none for GPT-2;
+- **probes** (``probe_names``; most models: none): arrays of what a layer
+  DECIDED for a row — the blocks a selection chose — that ``decode`` and
+  ``prefill_chunk`` return behind the counters, one row a slot (a chunk:
+  the row at ``last_idx``, as its logits are), and the engine's programs
+  behind their logits: like the logits they stay on the device unless a
+  check asks (``return_logits`` fetches both: ``engine.last_probes``).  A
+  model with none returns what it returned and its programs are text for
+  text what they were.
 
 ``served_model(x)`` resolves what a caller hands the engine: a
 ``ServedModel`` as it is; a model config through the registry
@@ -92,6 +100,9 @@ class ServedModel:
     """Base of the implementations; see the module docstring."""
     cfg: Any
     counter_names: Tuple[str, ...] = ()
+    # The arrays ``forward`` returns behind its counters (module docstring:
+    # probes), each ``[S, K, ...]``.
+    probe_names: Tuple[str, ...] = ()
     # Each class's table width, in ``cache_classes`` order: the ENGINE sets
     # it when it has sized the tables (a window's ring depends on its
     # prefill chunk); a model of several classes splits a table row by it.
@@ -250,7 +261,9 @@ class ServedModel:
         rows and pages written into ``pools`` in place, for live rows only
         — a dead row writes nothing, attends nothing and is not counted;
         what it computes nobody reads.  Returns (``x'`` as ``head`` takes
-        it, ``pools'``, the model's counters or None)."""
+        it, ``pools'``, the model's counters or None) and, from a model with
+        ``probe_names``, a tuple of those arrays ``[S, K, ...]`` behind
+        them."""
         raise NotImplementedError
 
     def head(self, params, h):
@@ -264,12 +277,14 @@ class ServedModel:
                block_tables, *, num_groups: int, paged_kernel: bool,
                mesh=None):
         """One token a slot, the K = 1 ``verify``: ``tokens`` / ``lengths``
-        ``[S]`` -> (logits ``[S, V]`` fp32, pools, counters).  The caller
-        advances ``lengths`` for the slots it considers active."""
-        logits, pools, counters = self._rows_a_slot(
+        ``[S]`` -> (logits ``[S, V]`` fp32, pools, counters[, probes, a
+        row ``[S, ...]`` each]).  The caller advances ``lengths`` for the
+        slots it considers active."""
+        logits, pools, counters, *probes = self._rows_a_slot(
             params, pools, tokens[:, None], lengths, block_tables,
             num_groups, paged_kernel, mesh)
-        return logits[:, 0], pools, counters
+        return (logits[:, 0], pools, counters,
+                *(tuple(p[:, 0] for p in ps) for ps in probes))
 
     def verify(self, params, pools: Sequence, tokens, lengths,
                block_tables, *, num_groups: int, paged_kernel: bool,
@@ -293,9 +308,9 @@ class ServedModel:
                      num_groups, paged_kernel, mesh):
         rows = Rows.of_slots(lengths, block_tables, tokens.shape[1],
                              num_groups, self._widths(block_tables))
-        x, pools, counters = self._layers(params, pools, tokens, rows,
-                                          paged_kernel, mesh)
-        return self.head(params, x), pools, counters
+        x, pools, counters, *probes = self._layers(
+            params, pools, tokens, rows, paged_kernel, mesh)
+        return (self.head(params, x), pools, counters, *probes)
 
     def _layers(self, params, pools, tokens, rows, paged_kernel, mesh):
         x = self.embed(params, tokens, rows.positions.reshape(tokens.shape))
@@ -324,7 +339,8 @@ class ServedModel:
         this chunk; without the operands: the stream's own page only).
 
         Returns (the hidden row at ``last_idx`` as ``head`` takes it, ``[G,
-        ...]``; pools; counters) — NOT logits: only ONE position a group
+        ...]``; pools; counters[; the probes' rows at ``last_idx``]) — NOT
+        logits: only ONE position a group
         ever projects through the unembedding (never a ``[C, vocab]``
         tensor), and only in the chunk program that ends a prompt: the
         caller applies ``head`` under a branch (``head_and_sample``)."""
@@ -332,9 +348,15 @@ class ServedModel:
             bt_rows, start, last_idx, active, tokens.shape[1],
             self._widths(bt_rows),
             None if freeze_idx is None else (freeze_idx, freeze_page))
-        x, pools, counters = self._layers(params, pools, tokens, rows,
-                                          paged_kernel, mesh)
-        return Rows.last(x, last_idx), pools, counters
+        x, pools, counters, *probes = self._layers(
+            params, pools, tokens, rows, paged_kernel, mesh)
+
+        def row_at_last(p):
+            """``p [G, C, ...]`` (integers: a gather) -> ``[G, ...]``."""
+            at = last_idx.reshape((-1,) + (1,) * (p.ndim - 1))
+            return jnp.take_along_axis(p, at, axis=1)[:, 0]
+        return (Rows.last(x, last_idx), pools, counters,
+                *(tuple(map(row_at_last, ps)) for ps in probes))
 
 
 def register(config_type: type,

@@ -76,7 +76,18 @@ SCOPES = ("fwd_bwd", "grad_sync", "health_tap", "optimizer", "flatten",
           # kda_chunk (prefill: the chunked form), kda_out (the gated head
           # norm and the output projection)
           "kda_proj", "kda_conv", "kda_gate", "kda_update", "kda_chunk",
-          "kda_out")
+          "kda_out",
+          # sparse layers beside Lightning layers (inference/minicpm_sala
+          # .py), all under attn: ck_write (the pooled keys whose windows
+          # end at the new rows), select (their scores, softmax, sums,
+          # maxima, top-k: pool block ids a row and K/V head),
+          # attend_sparse (the per-K/V-head plan and the attend over the
+          # chosen blocks) beside qkv_proj, kv_write, out_proj; la_proj
+          # (q/k norm, rotary), la_state_update (decode: the state kernel,
+          # several one-head groups a step) / la_chunk (prefill: the
+          # chunked form), la_gate_norm, la_out
+          "ck_write", "select", "attend_sparse", "la_proj",
+          "la_state_update", "la_chunk", "la_gate_norm", "la_out")
 # The host spans ``Telemetry.span`` opens (runtime/engine.py,
 # inference/engine.py, inference/scheduler.py), same contract.
 SPANS = ("train_batch", "data_prep", "step_dispatch", "offload_step",
@@ -128,6 +139,14 @@ SPAN_ARGS = {
                 "chunks", "rows_computed", "moe_held_pairs", "moe_held_max",
                 "moe_held_mean", "moe_held_empty", "moe_held_pair_share",
                 "rows", "hc_res_err_max",
+                # a model whose sparse layers select what they read
+                # (inference/minicpm_sala.py), of the execution(s) fetched
+                # (on a prefill span: the chunk program that ENDED the
+                # prompt): blocks the attend walked and blocks a dense
+                # attend would, each per live row, sparse layer and K/V
+                # head; their ratio; pooled rows the selection scored
+                "sparse_blocks_read", "sparse_blocks_in_reach",
+                "sparse_read_share", "ck_rows_scored",
                 "resumed_tokens", "snapshot_taken", "snapshot_in_program",
                 "state_copy_bytes",
                 "prefix_lost_to_kind_tokens"),
@@ -150,6 +169,9 @@ SPAN_ARGS = {
                "moe_held_pairs", "moe_held_max", "moe_held_mean",
                "moe_held_empty", "moe_held_pair_share", "rows",
                "hc_res_err_max",
+               # (see "prefill": the iteration FETCHED)
+               "sparse_blocks_read", "sparse_blocks_in_reach",
+               "sparse_read_share", "ck_rows_scored",
                # pages of a per-stream pool the state-update kernel
                # rewrote in this execution (one a live stream)
                "state_pages_live",

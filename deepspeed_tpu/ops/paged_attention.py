@@ -465,7 +465,11 @@ class AttendPlan(NamedTuple):
     lim   [G, Q, f*K, 1]: per score row, last attendable position less
           the copy's lane offset (see ``_pattn_kernel``), counted from
           the first block of ``rows``; ``[..., 2]`` with a window: the
-          first attendable position beside it."""
+          first attendable position beside it.
+
+    The per-K/V-head form (``_plan_heads_local``) keeps an axis of K/V
+    heads behind Q in all three (``rows [G, Q, nH, J]``): the plan's RANK
+    says which form it is, and ``paged_attention`` reads it there."""
     nlive: jax.Array
     rows: jax.Array
     lim: jax.Array
@@ -539,16 +543,52 @@ def _pool_geometry(pool_shape, D):
     return pool_shape[2], pool_shape[4] * f, f
 
 
+def _plan_heads_local(chosen, count, fill, *, B, bs, f, group):
+    """The plan of an attend whose every K/V HEAD walks blocks of its own
+    (a selection: ``ops/sparse_select.py``): chosen [G, Q, nH, J] the
+    group-local block ids a head of a stream walks, in walking order (its
+    ``count`` [G, Q, nH] first slots; the stream's NEWEST block last, the
+    only one that may be partly filled); fill [G, Q, K]: a query row's
+    offset in that last block (-1: a row that attends nothing).  Each
+    (stream, K/V head) becomes a stream of the kernel over the pool seen as
+    ``B * nH`` one-head tiles (``_paged_heads_local``): a step of a head
+    copies and attends ITS blocks' tiles of that head only, and the mask's
+    one compare bites in the walk's last block alone."""
+    G, Q, nH, J = chosen.shape
+    shard_group = jnp.arange(G, dtype=jnp.int32)[:, None, None, None]
+    head = jnp.arange(nH, dtype=jnp.int32)[None, None, :, None]
+    rows = (shard_group * B + jnp.maximum(chosen.astype(jnp.int32), 0)) \
+        * nH + head
+    fill = jnp.tile(fill.astype(jnp.int32), (1, 1, group))   # [G, Q, grp*K]
+    reach = jnp.where(fill[:, :, None] >= 0,
+                      (count[..., None] - 1) * bs + fill[:, :, None], -1)
+    lim = reach[:, :, :, None, :] - jnp.arange(f, dtype=jnp.int32)[:, None]
+    return AttendPlan(count.astype(jnp.int32), rows,
+                      lim.reshape(G, Q, nH, -1, 1))
+
+
 def attend_plan(block_tables, positions, pool, head_dim: int, *,
                 mesh=None, reach: Optional[int] = None,
-                group: int = 1) -> AttendPlan:
+                group: int = 1, count=None) -> AttendPlan:
     """The per-execution index work of ``paged_attention``: block_tables
     [G, Q, J], positions [G, Q, K] (-1: a row that attends nothing),
     ``pool`` the stacked pool as held.  ``group``: query heads a K/V head
     of the pool.  ``reach``: the table is a window's ring
     (``_plan_window``).  Under a dp mesh each shard plans its own
-    groups."""
+    groups.
+
+    The per-K/V-head form (``count`` given): block_tables [G, Q, nH, J] the
+    blocks EACH head of a stream walks, ``count`` [G, Q, nH] how many, and
+    ``positions`` each query row's offset in the walk's last block
+    (``_plan_heads_local``); the plan says so by its rank."""
     B, bs, f = _pool_geometry(pool.shape, head_dim)
+    if count is not None:
+        fn = _on_mesh(
+            functools.partial(_plan_heads_local, B=B, bs=bs, f=f,
+                              group=group), mesh,
+            lambda dpn, mpn: (P(dpn), P(dpn), P(dpn)),
+            lambda dpn, mpn: AttendPlan(P(dpn), P(dpn), P(dpn)))
+        return fn(block_tables, count, positions)
     fn = _on_mesh(
         functools.partial(_plan_local, B=B, bs=bs, f=f, reach=reach,
                           group=group), mesh,
@@ -633,6 +673,26 @@ def _paged_local(q, pool_k, pool_v, layer, nlive, rows, lim, *, scale,
         .reshape(G, Q, K0, nQ, D)
 
 
+def _paged_heads_local(q, pool_k, pool_v, layer, nlive, rows, lim, *, scale,
+                       tiles):
+    """``_paged_local`` under a per-K/V-head plan (``_plan_heads_local``):
+    the pools seen as ``B * nH`` tiles of ONE head each (a bitcast), every
+    (stream, K/V head) a stream of the kernel with the head's ``group``
+    query heads as its query rows.  The kernel is the same."""
+    G, Q, K0, nQ, D = q.shape
+    L, _, B, nH, bsf, fD = pool_k.shape
+    grp = nQ // nH
+    qh = q.reshape(G, Q, K0, nH, grp, D).transpose(0, 1, 3, 2, 4, 5) \
+        .reshape(G, Q * nH, K0, grp, D)
+    one_head = lambda p: p.reshape(L, G, B * nH, 1, bsf, fD)   # noqa: E731
+    streams = lambda a: a.reshape((G, Q * nH) + a.shape[3:])   # noqa: E731
+    out = _paged_local(qh, one_head(pool_k), one_head(pool_v), layer,
+                       streams(nlive), streams(rows), streams(lim),
+                       scale=scale, tiles=tiles)
+    return out.reshape(G, Q, nH, K0, grp, D).transpose(0, 1, 3, 2, 4, 5) \
+        .reshape(G, Q, K0, nQ, D)
+
+
 def _on_mesh(local_fn, mesh, in_specs, out_specs):
     """``local_fn`` under shard_map (manual over ALL mesh axes) when the
     mesh spans more than one device: GSPMD cannot partition a
@@ -676,7 +736,9 @@ def paged_attention(q, pool_k, pool_v, layer, block_tables=None,
     plan:         ``attend_plan(block_tables, positions, ...)`` where the
                   caller built it once for all its layers (then the two
                   are not read here); a window or grouped heads come
-                  through it (its ``reach`` / ``group``).
+                  through it (its ``reach`` / ``group``), and so does the
+                  per-K/V-head form (its ``count``: a plan with an axis
+                  of heads, ``_paged_heads_local``).
     tiles:        (heads a step, table slots a step) instead of the
                   shape rule's: the tests' handle on the tiling.
 
@@ -688,8 +750,14 @@ def paged_attention(q, pool_k, pool_v, layer, block_tables=None,
     if plan is None:
         plan = attend_plan(block_tables, positions, pool_k, q.shape[-1],
                            mesh=mesh, group=q.shape[3] // pool_k.shape[3])
+    # (a plan of ``attend_plan``'s per-K/V-head form: every head of a stream
+    # walks blocks of its own, and heads are not sharded over a model axis)
+    per_head = plan.rows.ndim == 4
+    if per_head and mesh is not None and mesh.shape.get(MP_AXIS, 1) > 1:
+        raise NotImplementedError("a per-head plan over a model axis")
     fn = _on_mesh(
-        functools.partial(_paged_local, scale=scale, tiles=tiles), mesh,
+        functools.partial(_paged_heads_local if per_head else _paged_local,
+                          scale=scale, tiles=tiles), mesh,
         lambda dpn, mpn: (P(dpn, None, None, mpn, None),
                           _pool_spec(dpn, mpn), _pool_spec(dpn, mpn), P(),
                           P(dpn), P(dpn), P(dpn)),

@@ -1,0 +1,274 @@
+"""Block selection without weights of its own (InfLLM-V2, the ``minicpm4``
+mixer of ``models/minicpm_sala.py``): a query token reads, of its stream's
+K/V blocks, only the ``topk`` whose POOLED keys its own queries score
+highest, a set a K/V head.
+
+**Pooled keys.**  ``c_j`` is the mean of the ``2 * stride`` keys ``k[stride *
+j .. stride * (j + 2) - 1]`` of one K/V head.  It is kept in the pool ``ck``
+BESIDE the K/V pages, ``R = block / stride`` rows a block, in the block where
+its window ENDS: global pooled row ``j + 1`` (row ``(j + 1) % R`` of logical
+block ``(j + 1) // R``; global row 0 is never written and never visible).  A
+block's ``ck`` rows are then a function of the tokens up to the block's end
+and of nothing after it, which is exactly what a shared prefix block
+guarantees: sharing, copy on write and ``copy_pages`` carry them with no rule
+of their own.  ``write_pooled_chunk`` (a prefill chunk's rows: every window
+that ends inside the chunk, from the chunk's own keys and the ``stride`` keys
+before it) and ``write_pooled_rows`` (one row a stream: the window that ends
+at that row, if one does, read back from the K pool) write them, after the
+K rows themselves.
+
+**Selection** (``select_blocks``), for the query at position ``t`` (``n = t +
+1`` tokens, newest block ``b_t``): every block ``0 .. b_t`` where ``n <=
+dense_len``; else, over the visible pooled rows ``1 <= g <= (t + 1) // stride
+- 1``: ``a[i, g] = softmax_g(q_i . c_g / sqrt(D))`` a query head, ``r[h, g]``
+its sum over the ``group`` query heads of K/V head ``h``, ``B[h, b] = max
+r[h, R b .. R b + R]`` (the pooled keys whose windows touch block b),
+``+inf`` for the first ``init_blocks`` and the newest ``window_blocks``
+blocks, and the ``topk`` blocks of largest ``B`` (ties to the lower block) in
+ascending order.  Returned through the stream's table as POOL block ids, a
+row and K/V head: ``[rows, nKV, width]`` with ``width = max(topk, dense_len /
+block)``, dead slots ``-1``, and the live count.
+
+All of it is plain ``jax.numpy``: the pooled keys are gathered through the
+table (``[streams, table width * R, nKV, D]``) and scored by one product, a
+batch of streams or of a chunk's rows at a time (``lax.map``) so that the
+fp32 scores of 256 streams x 32 heads x 8k pooled keys never stand whole.
+fp32 scores, softmax, sums and order; what it reads is the pool's dtype.
+"""
+from __future__ import annotations
+
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+DEAD_BLOCK = -1
+_BATCH_STREAMS = 32         # decode: streams scored at once
+_BATCH_ROWS = 128           # prefill: a chunk's rows scored at once
+
+
+class Sizes(NamedTuple):
+    """The selection's numbers (``models.minicpm_sala.MinicpmSalaConfig``'s
+    ``sparse_*``)."""
+    stride: int
+    block: int
+    topk: int
+    window_blocks: int
+    init_blocks: int
+    dense_len: int
+
+    @property
+    def per_block(self) -> int:
+        return self.block // self.stride
+
+    @property
+    def width(self) -> int:
+        return max(self.topk, self.dense_len // self.block)
+
+    @classmethod
+    def of(cls, cfg) -> "Sizes":
+        return cls(cfg.sparse_kernel_stride, cfg.sparse_block_size,
+                   cfg.sparse_topk,
+                   cfg.sparse_window_size // cfg.sparse_block_size,
+                   cfg.sparse_init_blocks, cfg.sparse_dense_len)
+
+
+# --------------------------------------------------------------------- #
+# Writing the pooled keys
+# --------------------------------------------------------------------- #
+def _k_rows(pool_k, layer, group, blk, off, D: int):
+    """Key rows out of the K pool as held ``[L, G, B, nKV, bs/f, f*D]``
+    (``kv_cache.kv_fold``: f positions side by side in the lanes where D <
+    128): ``[..., nKV, D]`` for (group, block, offset) of equal shapes.  A
+    gather of ROWS — every index of the pool but its lanes is given, the
+    head's too — so that the pool is read in the layout it is held in (a
+    slice over the heads between two gathered dimensions made the compiler
+    re-lay the whole pool, heads next to lanes, ahead of every call: 2.7 ms
+    a layer at 1.07 GB; PERF.md section 6, PR 59); no layer is sliced out."""
+    f = pool_k.shape[-1] // D
+    head = jnp.arange(pool_k.shape[3], dtype=jnp.int32)
+    rows = pool_k[layer, group[..., None], blk[..., None], head,
+                  (off // f)[..., None]]                    # [..., nKV, f*D]
+    if f == 1:
+        return rows
+    rows = rows.reshape(rows.shape[:-1] + (f, D))
+    pick = jnp.broadcast_to((off % f)[..., None, None, None],
+                            rows.shape[:-2] + (1, D))
+    return jnp.take_along_axis(rows, pick, axis=-2)[..., 0, :]
+
+
+def _put_rows(ck, layer, group, blk, row, pooled):
+    """``pooled [..., nKV, D]`` into ``ck [L, G, B, nKV, R, D]`` at (group,
+    block, row) of equal shapes, a block index of ``B`` dropped: a scatter
+    of rows, the head's index given (see ``_k_rows``)."""
+    head = jnp.arange(ck.shape[3], dtype=jnp.int32)
+    return ck.at[layer, group[..., None], blk[..., None], head,
+                 row[..., None]].set(pooled.astype(ck.dtype), mode="drop")
+
+
+def write_pooled_chunk(ck, pool_k, layer, k, table, start, last_idx, active,
+                       sz: Sizes):
+    """A prefill chunk of ONE stream a group.  ck ``[L, G, B, nKV, R, D]``;
+    k ``[G, C, nKV, D]`` the chunk's keys (as the K pool now holds them);
+    table ``[G, W]``; ``start`` [G] (a multiple of ``block``), ``last_idx``
+    [G] the last live row, ``active`` [G].  Window i ends at chunk row
+    ``stride * i + stride - 1`` and takes the ``stride`` rows before the
+    chunk from the pool (the previous block's last)."""
+    G, C = k.shape[:2]
+    s, R = sz.stride, sz.per_block
+    n = C // s
+    f32 = jnp.float32
+    g_idx = jnp.arange(G, dtype=jnp.int32)[:, None]
+    # the ``stride`` keys before the chunk (zeros at position 0: unused)
+    prev_pos = start[:, None] - s + jnp.arange(s, dtype=jnp.int32)[None]
+    prev_blk = jnp.take_along_axis(
+        table, jnp.maximum(prev_pos, 0) // sz.block, axis=1)
+    prev = _k_rows(pool_k, layer, jnp.broadcast_to(g_idx, prev_blk.shape),
+                   jnp.maximum(prev_blk, 0),
+                   jnp.maximum(prev_pos, 0) % sz.block,
+                   k.shape[-1])                             # [G, s, nKV, D]
+    rows = jnp.concatenate([prev.astype(f32),
+                            k.astype(pool_k.dtype).astype(f32)], axis=1)
+    halves = rows.reshape((G, n + 1, s) + rows.shape[2:]).sum(axis=2)
+    pooled = (halves[:, :-1] + halves[:, 1:]) / (2 * s)     # [G, n, nKV, D]
+    end = start[:, None] + s * jnp.arange(n, dtype=jnp.int32)[None] + s - 1
+    ok = (active[:, None] > 0) & (end >= 2 * s - 1) \
+        & (s * jnp.arange(n)[None] + s - 1 <= last_idx[:, None])
+    blk = jnp.take_along_axis(table, end // sz.block, axis=1)
+    blk = jnp.where(ok & (blk >= 0), blk, ck.shape[2])      # dropped
+    return _put_rows(ck, layer, jnp.broadcast_to(g_idx, blk.shape), blk,
+                     (end % sz.block) // s, pooled)
+
+
+def write_pooled_rows(ck, pool_k, layer, table, pos, live, sz: Sizes):
+    """One row a stream (decode).  table ``[G, Sg, W]``, pos / live ``[G,
+    Sg]``: where a window ends at ``pos`` its ``2 * stride`` keys are read
+    back from the K pool (the newest was written just before)."""
+    G, Sg = pos.shape
+    s = sz.stride
+    ends = live & ((pos + 1) % s == 0) & (pos >= 2 * s - 1)
+    at = jnp.maximum(pos[..., None] - (2 * s - 1)
+                     + jnp.arange(2 * s, dtype=jnp.int32), 0)  # [G, Sg, 2s]
+    blk = jnp.take_along_axis(table, at // sz.block, axis=2)
+    g_idx = jnp.arange(G, dtype=jnp.int32)[:, None, None]
+    rows = _k_rows(pool_k, layer, jnp.broadcast_to(g_idx, blk.shape),
+                   jnp.maximum(blk, 0), at % sz.block, ck.shape[-1])
+    pooled = rows.astype(jnp.float32).mean(axis=2)          # [G, Sg, nKV, D]
+    to = jnp.take_along_axis(table, (jnp.maximum(pos, 0) // sz.block
+                                     )[..., None], axis=2)[..., 0]
+    to = jnp.where(ends & (to >= 0), to, ck.shape[2])
+    return _put_rows(ck, layer, jnp.broadcast_to(g_idx[..., 0], to.shape),
+                     to, (jnp.maximum(pos, 0) % sz.block) // s, pooled)
+
+
+# --------------------------------------------------------------------- #
+# Selection
+# --------------------------------------------------------------------- #
+def block_scores(q, pooled, pos, sz: Sizes, scale: float):
+    """``B [rows, nKV, W]`` (fp32; -1 for a block past the newest, +inf
+    for a forced one) of query rows q ``[rows, nKV, group, D]`` at
+    positions ``pos`` [rows] against one stream's pooled rows ``[W, nKV, R,
+    D]`` (global row ``W-index * R + R-index``).  bf16 operands are
+    contracted as they are (their products are exact in fp32), fp32 ones at
+    the highest precision."""
+    W, _, R, _ = pooled.shape
+    f32 = jnp.float32
+    if q.dtype == jnp.bfloat16 and pooled.dtype == jnp.bfloat16:
+        how = dict(preferred_element_type=f32)
+    else:
+        q, pooled = q.astype(f32), pooled.astype(f32)
+        how = dict(precision=lax.Precision.HIGHEST)
+    s = jnp.einsum("tnmd,wnrd->tnmwr", q, pooled, **how) * scale
+    s = s.reshape(s.shape[:3] + (W * R,))
+    g = jnp.arange(W * R, dtype=jnp.int32)
+    seen = (g[None] >= 1) & (g[None] <= (pos[:, None] + 1) // sz.stride - 1)
+    s = jnp.where(seen[:, None, None], s, -jnp.inf)
+    a = jnp.exp(s - jnp.max(jnp.where(seen[:, None, None], s, -1e30),
+                            axis=-1, keepdims=True))
+    a = a / jnp.maximum(a.sum(-1, keepdims=True), 1e-30)
+    r = a.sum(axis=2)                                       # [rows, nKV, WR]
+    own = r.reshape(r.shape[:2] + (W, R)).max(-1)
+    nxt = jnp.pad(r[..., R::R], ((0, 0), (0, 0), (0, 1)))  # next block's first
+    score = jnp.maximum(own, nxt)
+    b = jnp.arange(W, dtype=jnp.int32)
+    newest = (pos // sz.block)[:, None]
+    forced = (b[None] < sz.init_blocks) | (b[None] > newest
+                                           - sz.window_blocks)
+    score = jnp.where(forced[:, None], jnp.inf, score)
+    return jnp.where((b[None] <= newest)[:, None], score, -1.0)
+
+
+def choose(score, pos, sz: Sizes):
+    """(logical blocks ``[rows, nKV, width]`` ascending, -1 past the live
+    count; live count ``[rows, nKV]``) from ``block_scores``'s."""
+    width = sz.width
+    newest = pos // sz.block
+    dense = (pos + 1 <= sz.dense_len)[:, None, None]
+    k = min(sz.topk, score.shape[-1])
+    _, top = lax.top_k(score, k)                 # ties: the lower index
+    top = jnp.sort(top.astype(jnp.int32), axis=-1)
+    top = jnp.pad(top, ((0, 0), (0, 0), (0, width - k)), constant_values=-1)
+    slot = jnp.arange(width, dtype=jnp.int32)[None, None]
+    every = jnp.where(slot <= newest[:, None, None], slot, -1)
+    logical = jnp.where(dense, every, top)
+    n = jnp.where(dense[..., 0], jnp.minimum(newest + 1, width)[:, None],
+                  jnp.minimum(k, newest + 1)[:, None])
+    n = jnp.broadcast_to(n, logical.shape[:2]).astype(jnp.int32)
+    return jnp.where(slot < n[..., None], logical, -1), n
+
+
+def _batches(n: int, size: int) -> int:
+    while n % size:
+        size -= 1
+    return size
+
+
+def select_blocks(q, ck, layer, table, pos, live, sz: Sizes, scale: float
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """q ``[S, K, nH, D]`` (query head ``h * group + m`` reads K/V head h);
+    ck ``[L, G, B, nKV, R, D]``; table ``[S, W]`` (each stream's, group
+    ``s // (S / G)``); pos / live ``[S, K]``.  Returns (pool block ids ``[S,
+    K, nKV, width]`` in ascending logical order, ``DEAD_BLOCK`` past the
+    count; count ``[S, K, nKV]``, 0 for a dead row)."""
+    S, K, nH, D = q.shape
+    G, nKV, R = ck.shape[1], ck.shape[3], ck.shape[4]
+    W = table.shape[1]
+    group = jnp.arange(S, dtype=jnp.int32) // (S // G)
+    qg = q.reshape(S, K, nKV, nH // nKV, D)
+
+    def of_stream(q_s, row, g, pos_s):
+        """One stream's K rows: its pooled rows gathered once."""
+        pooled = ck[layer, g, jnp.maximum(row, 0)]          # [W, nKV, R, D]
+        logical, n = choose(block_scores(q_s, pooled, pos_s, sz, scale),
+                            pos_s, sz)
+        ids = jnp.where(logical >= 0, row[jnp.maximum(logical, 0)],
+                        DEAD_BLOCK)
+        return ids, n
+
+    if K == 1:
+        b = _batches(S, _BATCH_STREAMS)
+        split = lambda v: v.reshape((S // b, b) + v.shape[1:])  # noqa: E731
+        ids, n = lax.map(
+            lambda a: jax.vmap(of_stream)(*a),
+            (split(qg), split(table), split(group), split(pos)))
+        ids, n = ids.reshape((S,) + ids.shape[2:]), n.reshape(
+            (S,) + n.shape[2:])
+    else:
+        b = _batches(K, _BATCH_ROWS)
+
+        def stream(args):
+            q_s, row, g, pos_s = args
+            ids, n = lax.map(
+                lambda a: of_stream(a[0], row, g, a[1]),
+                (q_s.reshape((K // b, b) + q_s.shape[1:]),
+                 pos_s.reshape(K // b, b)))
+            return (ids.reshape((K,) + ids.shape[2:]),
+                    n.reshape((K,) + n.shape[2:]))
+        ids, n = lax.map(stream, (qg, table, group, pos))
+    n = jnp.where(live[..., None], n, 0)
+    return jnp.where(live[..., None, None], ids, DEAD_BLOCK), n
+
+
+__all__ = ["Sizes", "write_pooled_chunk", "write_pooled_rows",
+           "block_scores", "choose", "select_blocks", "DEAD_BLOCK"]

@@ -63,11 +63,15 @@ def state_tile(num_heads: int, d_state: int, d_head: int
 
 def tile_heads(num_heads: int, groups: int, d_state: int, d_head: int
                ) -> int:
-    """Heads a grid step of the kernel holds: the most of ONE group (they
-    share B and C) whose tile stays under ``_TILE_BYTES``."""
+    """Heads a grid step of the kernel holds, the most whose tile stays
+    under ``_TILE_BYTES``: of ONE group where a group has several heads
+    (they share B and C: Mamba-2), else several WHOLE one-head groups (every
+    head its own B and C: a linear attention; a step of one 64 KB head would
+    be all fixed cost)."""
     per = num_heads // groups
-    fit = [t for t in range(1, per + 1)
-           if per % t == 0 and t * d_state * d_head * 4 <= _TILE_BYTES]
+    span = per if per > 1 else num_heads
+    fit = [t for t in range(1, span + 1)
+           if span % t == 0 and t * d_state * d_head * 4 <= _TILE_BYTES]
     return max(fit or [1])
 
 
@@ -152,13 +156,22 @@ def chunked_scan(S0, x, B, C, dt, a, *, chunk: int,
 # The decode kernel over the paged state pool
 # --------------------------------------------------------------------- #
 def _state_update_kernel(tile_ref, row_ref, n_ref, hx_ref, bc_ref, s_in,
-                         s_out, y_out, bcol_scr, ccol_scr, *, N, Ht, Hp, R):
-    """One grid step = (stream s, tile t of Ht heads of one group).
+                         s_out, y_out, *col_scr, N, Ht, Hp, R):
+    """One grid step = (stream s, tile t of Ht heads).
 
     hx_ref [2*Hp, P]: row hh the head's ``dt x`` and row Hp + hh its decay
-    on every lane; bc_ref [8, N]: row 0 the group's B, row 1 its C.  s_in /
-    s_out [Ht*N, P]: this tile of the stream's page and layer (the same
-    HBM: aliased).  y_out [Hp, P]: row hh the head's ``S' C``.
+    on every lane.  s_in / s_out [Ht*N, P]: this tile of the stream's page
+    and layer (the same HBM: aliased).  y_out [Hp, P]: row hh the head's
+    ``S' C``.  B and C, which the update wants as COLUMNS (value n on every
+    lane of sublane n), come in one of two forms, told apart by whether the
+    call brought the two ``[N, P]`` scratches ``col_scr``:
+    - the tile's heads are of ONE group and share them (Mamba-2): bc_ref
+      [8, N], row 0 the group's B, row 1 its C, turned into columns in the
+      scratches once a step;
+    - every head is a group of its own (a linear attention; no scratch):
+      bc_ref [2, N, Hl], ``[0, n, hh]`` head hh's B[n] and ``[1, n, hh]`` its
+      C[n] — state dimension on sublanes, heads on lanes, so a head's B and
+      C arrive as columns and nothing is transposed in the kernel.
     """
     del tile_ref, row_ref
     s = pl.program_id(0)
@@ -174,12 +187,19 @@ def _state_update_kernel(tile_ref, row_ref, n_ref, hx_ref, bc_ref, s_in,
     @pl.when(s < n)
     def _update():
         Pd = s_in.shape[1]
-        # B and C as columns: value n on every lane of sublane n.
-        for r0 in range(0, N, Pd):
-            w = min(Pd, N - r0)
-            for row, scr in ((0, bcol_scr), (1, ccol_scr)):
-                lanes = bc_ref[row:row + 1, r0:r0 + w]             # [1, w]
-                scr[r0:r0 + w, :] = jnp.broadcast_to(lanes, (Pd, w)).T
+        if col_scr:
+            for r0 in range(0, N, Pd):
+                w = min(Pd, N - r0)
+                for row, scr in zip((0, 1), col_scr):
+                    lanes = bc_ref[row:row + 1, r0:r0 + w]         # [1, w]
+                    scr[r0:r0 + w, :] = jnp.broadcast_to(lanes, (Pd, w)).T
+
+        def column(which, hh, r0):
+            """Rows r0 .. r0 + R of head hh's B (0) or C (1) column."""
+            if col_scr:
+                return col_scr[which][r0:r0 + R, :]
+            return bc_ref[which, r0:r0 + R, hh:hh + 1]             # [R, 1]
+
         if Hp > Ht:
             y_out[Ht:, :] = jnp.zeros((Hp - Ht, Pd), jnp.float32)
         for hh in range(Ht):
@@ -188,9 +208,9 @@ def _state_update_kernel(tile_ref, row_ref, n_ref, hx_ref, bc_ref, s_in,
             acc = jnp.zeros((R, Pd), jnp.float32)
             for r0 in range(0, N, R):
                 rows = slice(hh * N + r0, hh * N + r0 + R)
-                new = da * s_in[rows, :] + bcol_scr[r0:r0 + R, :] * dtx
+                new = da * s_in[rows, :] + column(0, hh, r0) * dtx
                 s_out[rows, :] = new
-                acc = acc + new * ccol_scr[r0:r0 + R, :]
+                acc = acc + new * column(1, hh, r0)
             y_out[hh:hh + 1, :] = jnp.sum(acc, axis=0, keepdims=True)
 
 
@@ -241,9 +261,23 @@ def _state_update_local(state, layer, pages, x, B, C, dt, da):
                            (Ns, nT, Ht, Pd))
     pad = ((0, 0), (0, 0), (0, Hp - Ht), (0, 0))
     hx = jnp.concatenate([jnp.pad(dtx, pad), jnp.pad(dal, pad)], axis=2)
-    bc = jnp.stack([B.astype(f32).reshape(Ns, G, N),
-                    C.astype(f32).reshape(Ns, G, N)], axis=2)
-    bc = jnp.pad(bc, ((0, 0), (0, 0), (0, 6), (0, 0)))          # [Ns,G,8,N]
+    if G < nh or Ht == 1:
+        # A tile's heads share one group's B and C: rows of an [8, N] block.
+        bc = jnp.stack([B.astype(f32).reshape(Ns, G, N),
+                        C.astype(f32).reshape(Ns, G, N)], axis=2)
+        bc = jnp.pad(bc, ((0, 0), (0, 0), (0, 6), (0, 0)))      # [Ns,G,8,N]
+        bc_spec = pl.BlockSpec((None, None, 8, N), group_map)
+        col_scr = [pltpu.VMEM((N, Pd), f32), pltpu.VMEM((N, Pd), f32)]
+    else:
+        # Every head its own B and C: a tile's, as columns ([N, heads]).
+        Hl = -(-Ht // 128) * 128
+        bc = jnp.stack([B.astype(f32).reshape(Ns, nT, Ht, N),
+                        C.astype(f32).reshape(Ns, nT, Ht, N)], axis=2)
+        bc = jnp.pad(jnp.swapaxes(bc, 3, 4),
+                     ((0, 0),) * 4 + ((0, Hl - Ht),))       # [Ns,nT,2,N,Hl]
+        bc_spec = pl.BlockSpec((None, None, 2, N, Hl),
+                               lambda *a: head_map(*a) + (0,))
+        col_scr = []
 
     s_flat = state.reshape(L * Gd * Bp * nT, Ht * N, Pd)
     s_spec = pl.BlockSpec((None, Ht * N, Pd), pool_map)
@@ -253,12 +287,10 @@ def _state_update_local(state, layer, pages, x, B, C, dt, da):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(Ns, nT),
             in_specs=[pl.BlockSpec((None, None, 2 * Hp, Pd), head_map),
-                      pl.BlockSpec((None, None, 8, N), group_map),
-                      s_spec],
+                      bc_spec, s_spec],
             out_specs=[s_spec,
                        pl.BlockSpec((None, None, Hp, Pd), head_map)],
-            scratch_shapes=[pltpu.VMEM((N, Pd), f32),
-                            pltpu.VMEM((N, Pd), f32)]),
+            scratch_shapes=col_scr),
         out_shape=[jax.ShapeDtypeStruct(s_flat.shape, f32),
                    jax.ShapeDtypeStruct((Ns, nT, Hp, Pd), f32)],
         # tiles, rows, n, hx, bc, state

@@ -166,12 +166,37 @@ FAMILIES = {"gpt2": _gpt2, "latent_share": _latent, "latent_hyper": _hyper,
             "falcon_h1": _falcon_h1, "kimi_linear": _kimi_linear}
 
 
+def _minicpm_sala():
+    """``test_minicpm_sala_serving.tiny`` at blocks of 8 (pooled keys of 4
+    tokens every 2, top 3, dense up to 16 tokens): the scenario's prompt of
+    19 tokens crosses ``dense_len`` in its third chunk of 8."""
+    from deepspeed_tpu.models.minicpm_sala import (MinicpmSalaConfig,
+                                                   minicpm_sala_init)
+    cfg = MinicpmSalaConfig(
+        vocab_size=128, hidden_size=64, intermediate_size=96,
+        num_hidden_layers=4, num_attention_heads=8, num_key_value_heads=2,
+        head_dim=16, lightning_nh=4, lightning_nkv=4, lightning_head_dim=16,
+        mixer_types=("minicpm4", "lightning-attn", "lightning-attn",
+                     "minicpm4"),
+        depth_scale_layers=8, dim_model_base=32, max_position_embeddings=256,
+        sparse_kernel_size=4, sparse_kernel_stride=2, sparse_block_size=8,
+        sparse_topk=2, sparse_window_size=8, sparse_init_blocks=1,
+        sparse_dense_len=16, dtype=jnp.float32)
+    return cfg, minicpm_sala_init(jax.random.PRNGKey(0), cfg), {
+        "block_size": 8, "num_blocks": {"sparse": 64, "state": 16}}
+
+
+# Families a later PR added, each held to the golden file of ITS PR
+# (``tests/test_program_text.py``): PR 59's.
+ADDED = {"minicpm_sala": _minicpm_sala}
+
+
 def engine(family: str, kernel: bool, dp: int = 1, **extra):
     """A tiny engine of ``family`` on ``dp`` host devices (``extra``: more
     top-level config blocks, ``telemetry``)."""
     from deepspeed_tpu.inference import InferenceEngine
     from deepspeed_tpu.parallel.topology import build_mesh
-    cfg, params, inference = FAMILIES[family]()
+    cfg, params, inference = {**FAMILIES, **ADDED}[family]()
     conf = dict(max_slots=4, max_seq_len=128, block_size=4,
                 prefill_chunk=CHUNK, paged_kernel=kernel)
     conf.update(inference)
@@ -309,7 +334,9 @@ def golden(family: str, arm: str) -> dict:
 
 if __name__ == "__main__":
     DUMP = sys.argv[2] if len(sys.argv) > 2 else None
+    # (``ADDED`` as a third argument: the families later PRs added alone)
+    names = ADDED if "ADDED" in sys.argv[3:] else FAMILIES
     out = {family: {arm: golden(family, arm) for arm in ARMS}
-           for family in sorted(FAMILIES)}
+           for family in sorted(names)}
     with open(sys.argv[1], "w") as f:
         f.write(json.dumps(out, indent=1, sort_keys=True) + "\n")

@@ -1197,3 +1197,95 @@ def test_prefill_spans_carry_what_the_window_returned(tmp_path,
     assert report["cached_tokens_full"] == report["cached_tokens_window"] == 0
     assert report["cache_classes"]["window"]["returned"] \
         > report["prefill_window_blocks_returned"]
+
+
+# --------------------------------------------------------------------- #
+# (m) sparse layers that select what they read beside Lightning layers (PR
+# 59): the pooled keys' write, the selection and the per-head attend, the
+# Lightning mixer's scopes, the selection's counters on the spans
+# --------------------------------------------------------------------- #
+@pytest.fixture(scope="module")
+def sala_engine():
+    from test_minicpm_sala_serving import seeded, tiny
+    cfg = tiny()
+    eng = InferenceEngine(
+        cfg, seeded(cfg),
+        config={"inference": {"max_slots": 4, "max_seq_len": 256,
+                              "prefill_chunk": 16, "block_size": 16,
+                              "num_blocks": {"sparse": 64, "state": 12},
+                              "paged_kernel": True}},
+        mesh=build_mesh(devices=jax.devices()[:1]))
+    yield eng
+    eng.close()
+
+
+@pytest.fixture(scope="module")
+def sala_op_names(sala_engine):
+    eng = sala_engine
+    G, W = eng.dp, eng.allocator.table_width
+    key, temp = eng._next_key(), np.float32(0.0)
+    return {
+        "decode": _op_names(eng._decode_fn, eng._params, *eng._pools(),
+                            eng._no_fetch, eng.last_tokens,
+                            np.ones(eng.max_slots, bool), eng.lengths,
+                            eng.block_tables, key, temp),
+        "prefill": _op_names(
+            eng._prefill_fn, eng._params, *eng._pools(),
+            np.zeros((G, eng.prefill_chunk), np.int32),
+            np.zeros((G, W), np.int32), np.zeros(G, np.int32),
+            np.zeros(G, np.int32), np.ones(G, np.int32),
+            np.zeros(G, np.int32), np.zeros(G, np.int32), np.int32(1), key,
+            temp)}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("scope", [
+    "embed", "attn/qkv_proj", "attn/kv_write", "attn/ck_write",
+    "attn/select", "attn/attend_sparse", "attn/out_proj", "attn/la_proj",
+    "attn/la_gate_norm", "attn/la_out", "mlp", "lm_head", "sample"])
+def test_sala_program_carries_scope(sala_op_names, program, scope):
+    assert any(f"/{scope}" in n for n in sala_op_names[program]), \
+        (program, scope)
+
+
+def test_the_lightning_update_and_chunk_each_belong_to_one_program(
+        sala_op_names):
+    names = sala_op_names
+    assert any("/attn/la_state_update" in n for n in names["decode"])
+    assert not any("/la_chunk" in n for n in names["decode"])
+    assert any("/attn/la_chunk" in n for n in names["prefill"])
+    assert not any("/la_state_update" in n for n in names["prefill"])
+
+
+def test_the_readers_list_names_the_selections_scopes_and_counters():
+    from deepspeed_tpu.monitor.xplane_reader import (SCOPES, SPAN_ARGS,
+                                                     scope_of)
+    assert {"ck_write", "select", "attend_sparse", "la_proj",
+            "la_state_update", "la_chunk", "la_gate_norm",
+            "la_out"} <= set(SCOPES)
+    assert scope_of("jit(decode_step)/attn/select/while/dot")[0] \
+        == ("attn", "select")
+    assert scope_of("jit(decode_step)/attn/la_state_update/pallas_call")[0] \
+        == ("attn", "la_state_update")
+    counters = {"sparse_blocks_read", "sparse_blocks_in_reach",
+                "sparse_read_share", "ck_rows_scored"}
+    assert counters <= set(SPAN_ARGS["decode"])
+    assert counters <= set(SPAN_ARGS["prefill"])
+
+
+def test_the_selections_counters_ride_the_fetch_onto_the_spans(sala_engine):
+    """A prompt past ``dense_len`` (64): its decode iterations walk 4 of 7
+    blocks a sparse layer and K/V head; the report keeps the running
+    means."""
+    from deepspeed_tpu.inference.scheduler import Request
+    eng = sala_engine
+    eng.reset_serving_stats()
+    rng = np.random.default_rng(5)
+    report = eng.serve([Request(rid=1, prompt=rng.integers(
+        0, 128, size=100, dtype=np.int32), max_new_tokens=6,
+        arrival_s=0.0)])
+    c = report["model_counters"]
+    assert 0 < c["sparse_read_share"] < 1
+    # decode at 101-106 tokens: 7 blocks in reach, 4 read
+    assert c["sparse_blocks_read"] >= 4 * 2 * 2
+    assert c["ck_rows_scored"] > 0

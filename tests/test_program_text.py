@@ -49,6 +49,9 @@ DATA = os.path.join(harness.TESTS, "data")
 GOLDEN = json.load(open(os.path.join(DATA, "program_text_pr55.json")))
 LEFT_BY_PR56 = json.load(open(os.path.join(DATA, "program_text_pr56.json")))
 LEFT_BY_PR58 = json.load(open(os.path.join(DATA, "program_text_pr58.json")))
+# The families later PRs ADDED (``harness.ADDED``), each by the tree of its
+# own PR: ``python tests/decode_step_hlo.py OUT.json DIR ADDED``.
+ADDED_BY_PR59 = json.load(open(os.path.join(DATA, "program_text_pr59.json")))
 
 # Why a program's operations are not, line for line, the ones PR 55 lowered
 # (CHANGES.md, PR 56, quotes the lines).  Where the seven copies of the
@@ -136,6 +139,34 @@ def held_to_the_golden(family, kind, arm):
 @pytest.mark.parametrize("family", sorted(harness.FAMILIES))
 def test_the_programs_are_what_they_were(family, kind):
     held_to_the_golden(family, kind, "off")
+
+
+@pytest.mark.parametrize("arm", sorted(harness.ARMS))
+@pytest.mark.parametrize("kind", ["decode_step", "prefill_step", "outputs",
+                                  "verify_step"])
+@pytest.mark.parametrize("family", sorted(harness.ADDED))
+def test_an_added_familys_programs_are_what_its_pr_left(family, kind, arm):
+    """PR 59's family (sparse layers beside Lightning layers): the text its
+    PR lowered, the bits it computed; ``verify`` refuses (a state a
+    stream)."""
+    got, want = harness.golden(family, arm), ADDED_BY_PR59[family][arm]
+    if kind == "outputs":
+        assert got["outputs"] == want["outputs"]
+        return
+    names = sorted(n for n in want["programs"] if n.startswith(kind))
+    assert names == sorted(n for n in got["programs"] if n.startswith(kind))
+    if kind == "verify_step":
+        assert not names
+        with pytest.raises(NotImplementedError,
+                           match="rolled back.*spec_k"):
+            served_model(harness.ADDED[family]()[0]).verify(
+                None, None, None, None, None, num_groups=1,
+                paged_kernel=False)
+        return
+    assert names
+    for name in names:
+        assert got["programs"][name]["order_free"] \
+            == want["programs"][name]["order_free"], (family, arm, name)
 
 
 @pytest.mark.parametrize("was,now,moved_by", [

@@ -1909,3 +1909,168 @@ def test_paste_cell_serve_step_fits_and_updates_both_classes_in_place(
                   "/attn/qkv_proj", "/attn/kv_write", "/attn/out_proj",
                   "/lm_head"):
         assert scope in text, scope
+
+
+# ------------------------------------------------------------------ #
+# A per-K/V-head table in the paged attend, a pool at another rate, several
+# one-head groups a step of the state kernel: MiniCPM-SALA (PR 59) at the
+# benchmark cell's shape
+# ------------------------------------------------------------------ #
+@pytest.mark.parametrize("streams", [256, 1])
+def test_state_update_of_one_head_groups_compiles_at_the_published_widths(
+        streams, one_chip, as_tpu):
+    """32 heads x [128, 128] fp32 a page and layer, every head a group of
+    its own: all 32 a grid step (2 MB), B and C as columns of an [N, heads]
+    block, the pool aliased in and out."""
+    from deepspeed_tpu.ops import ssm_scan
+    assert ssm_scan.tile_heads(32, 32, 128, 128) == 32
+    assert ssm_scan.tile_heads(32, 2, 256, 128) == 16     # cell 9's: as was
+    S = streams
+    pool = jax.ShapeDtypeStruct((6, 1, 8, 32, 128, 128), jnp.float32,
+                                sharding=one_chip)
+    f = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32,   # noqa: E731
+                                        sharding=one_chip)
+    compiled = jax.jit(
+        lambda pool, pages, x, B, C, dt, da: ssm_scan.state_update(
+            pool, 2, pages, x, B, C, dt, da), donate_argnums=0).lower(
+        pool, jax.ShapeDtypeStruct((1, S), jnp.int32, sharding=one_chip),
+        f(1, S, 32, 128), f(1, S, 32, 128), f(1, S, 32, 128), f(1, S, 32),
+        f(1, S, 32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "_ssm_state_update_kernel" in text
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 6 * 8 * 32 * 128 * 128 * 4
+
+
+@pytest.mark.parametrize("streams", [256, 512], ids=["decode", "chunk"])
+def test_paged_attention_under_a_per_head_plan_compiles(streams, one_chip,
+                                                        as_tpu):
+    """16 query heads a K/V head as 16 query rows of a (stream, K/V head)
+    step that walks ITS up to 128 chosen blocks' one-head tiles: decode's 256
+    streams, a chunk's 512 rows each a stream."""
+    from deepspeed_tpu.ops import paged_attention as pa
+    Q, nKV, J, D, B = streams, 2, 128, 128, 1024
+    pool = _sds((2, 1, B, nKV, 64, D), jnp.bfloat16)
+
+    def fn(q, k, v, chosen, count, fill):
+        plan = pa.attend_plan(chosen, fill, k, D, group=16, count=count)
+        return pa.paged_attention(q, k, v, 1, plan=plan, scale=D ** -0.5)
+    text = _compile(fn, one_chip, _sds((1, Q, 1, 32, D), jnp.bfloat16), pool,
+                    pool, _sds((1, Q, nKV, J), jnp.int32),
+                    _sds((1, Q, nKV), jnp.int32), _sds((1, Q, 1), jnp.int32))
+    assert "_pattn_kernel" in text
+    assert pa._tile_rule(16, 1, D, 64, J, 2) == (1, 32)
+
+
+@pytest.fixture(scope="module")
+def sparse_beside_state_programs(topo):
+    """(specs, params bytes, {program: compiled}) of the engine's own step
+    builders for ``perfbench/configs/minicpm-sala.json`` on an engine shell
+    (see ``_serve_program``)."""
+    import json
+    import os
+    from types import SimpleNamespace
+    from deepspeed_tpu.inference import kv_cache
+    from deepspeed_tpu.inference.engine import (InferenceEngine,
+                                                prefill_widths)
+    from deepspeed_tpu.inference.served import served_model
+    from deepspeed_tpu.models.minicpm_sala import (MinicpmSalaConfig,
+                                                   minicpm_sala_init)
+    from jax.experimental.compilation_cache import compilation_cache
+    sizes = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "perfbench", "configs", "minicpm-sala.json")))
+    inf = sizes["serve"]["inference"]
+    cfg = MinicpmSalaConfig.from_hf(sizes)
+    assert cfg.vocab_rows == sizes["assumed"]["vocab_rows_held"]
+    served = served_model(cfg)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+            tree)
+    params = on_chip(jax.eval_shape(lambda k: minicpm_sala_init(k, cfg),
+                                    jax.random.PRNGKey(0)))
+    param_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(params))
+    specs = kv_cache.class_specs(
+        served.cache_classes, inf["num_blocks"], rows=inf["prefill_chunk"],
+        of_class=lambda c: served.class_geometry(c, inf["block_size"]),
+        num_slots=inf["max_slots"], block_size=inf["block_size"],
+        max_len=inf["max_seq_len"], num_groups=1, dtype=jnp.bfloat16)
+    served.table_widths = tuple(sp.max_blocks_per_slot for sp in specs)
+    eng = object.__new__(InferenceEngine)
+    eng.model_cfg, eng.dp, eng.sp, eng.mesh = served, 1, 1, None
+    eng.paged_kernel, eng.quantize = True, "none"
+    eng.prefill_chunk = inf["prefill_chunk"]
+    eng._cache_sh = {n: one for sp in specs for n in sp.pool_names}
+    eng.allocator = SimpleNamespace(copy_pools=specs[-1].pool_names)
+    pools = [on_chip(jax.ShapeDtypeStruct(sp.pool_shapes[n],
+                                          sp.pool_dtypes[n]))
+             for sp in specs for n in sp.pool_names]
+    S, J = inf["max_slots"], sum(served.table_widths)
+    i32 = lambda *shape: on_chip(jax.ShapeDtypeStruct(shape, jnp.int32))
+    fresh = lambda n: on_chip(jax.ShapeDtypeStruct((n,), jnp.bool_))
+    key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
+    temp = on_chip(jax.ShapeDtypeStruct((), jnp.float32))
+    mp = pytest.MonkeyPatch()
+    prev = jax.config.jax_enable_compilation_cache
+    out = {}
+    try:
+        mp.setattr(jax, "default_backend", lambda *a, **k: "tpu")
+        mp.setenv("DS_AUTOTUNE", "0")
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        out["decode_step"] = eng._build_decode_step().lower(
+            params, *pools, i32(S), i32(S), fresh(S), i32(S), i32(S, J),
+            key, temp).compile()
+        for C in prefill_widths(inf["prefill_chunk"], inf["block_size"]):
+            out[f"prefill_step.{C}"] = eng._build_prefill_step().lower(
+                params, *pools, i32(1, C), i32(1, J), i32(1), i32(1), i32(1),
+                i32(1), i32(1), i32(), key, temp).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+        mp.undo()
+    return specs, param_bytes, out
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_step.256",
+                                     "prefill_step.512"])
+def test_sparse_beside_state_fit_and_are_updated_in_place(
+        sparse_beside_state_programs, program):
+    """Weights 5.64 GB + the sparse class's three pools 2.21 GB (K, V and
+    the pooled keys at a sixteenth of their rate) + 288 state pages of 12.6
+    MB, every pool aliased to its output, the rest inside the chip's 16 GiB
+    beside a table 2,073 slots wide x 256 streams; the per-head attend, the
+    row write and, in decode, the state update are TPU custom calls."""
+    specs, param_bytes, programs = sparse_beside_state_programs
+    sparse, state = specs
+    compiled = programs[program]
+    assert param_bytes == 2 * 2_820_741_888
+    assert sparse.max_blocks_per_slot == 2072
+    assert sparse.block_nbytes() == 135_168
+    assert sparse.pool_shapes["ck.sparse"] == (2, 1, 16384, 2, 4, 128)
+    assert state.block_nbytes() == 6 * 32 * 128 * 128 * 4
+    assert state.pool_dtypes["state.state"] == jnp.float32
+    pool_bytes = sparse.nbytes() + state.nbytes()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert param_bytes + pool_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes \
+        < 15.75 * 2 ** 30, mem
+    text = compiled.as_text()
+    kernels = ["_pattn_kernel", "_kv_write_kernel"]
+    if program == "decode_step":
+        kernels.append("_ssm_state_update_kernel")
+    for kernel in kernels:
+        calls = [line for line in text.splitlines()
+                 if f"%{kernel}" in line.split(" = ")[0]
+                 and " custom-call(" in line]
+        assert calls and all("tpu_custom_call" in c for c in calls), kernel
+    for scope in ("/attn/select", "/attn/ck_write", "/attn/attend_sparse",
+                  "/attn/la_proj", "/attn/la_gate_norm", "/attn/la_out",
+                  "/attn/la_state_update" if program == "decode_step"
+                  else "/attn/la_chunk", "/mlp", "/lm_head"):
+        assert scope in text, scope
