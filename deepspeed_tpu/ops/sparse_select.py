@@ -32,7 +32,9 @@ block)``, dead slots ``-1``, and the live count.
 All of it is plain ``jax.numpy``: the pooled keys are gathered through the
 table (``[streams, table width * R, nKV, D]``) and scored by one product, a
 batch of streams or of a chunk's rows at a time (``lax.map``) so that the
-fp32 scores of 256 streams x 32 heads x 8k pooled keys never stand whole.
+fp32 scores of 256 streams x 32 heads x 8k pooled keys never stand whole;
+their block maxima do (4 MB), and the ``topk`` largest of each row are found
+once a program, by a threshold and a count (``choose``: no sort).
 fp32 scores, softmax, sums and order; what it reads is the pool's dtype.
 """
 from __future__ import annotations
@@ -199,23 +201,80 @@ def block_scores(q, pooled, pos, sz: Sizes, scale: float):
     return jnp.where((b[None] <= newest)[:, None], score, -1.0)
 
 
-def choose(score, pos, sz: Sizes):
-    """(logical blocks ``[rows, nKV, width]`` ascending, -1 past the live
-    count; live count ``[rows, nKV]``) from ``block_scores``'s."""
-    width = sz.width
+def _order_keys(score):
+    """fp32 -> int32 whose SIGNED order is the floats' (the sign trick: a
+    negative float's lower 31 bits flipped).  -0 lies below +0 here; no
+    score is -0 (softmax sums, ``+inf``, ``-1.0``)."""
+    bits = lax.bitcast_convert_type(score, jnp.int32)
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+
+def _largest(score, k: int, values):
+    """``values [..., W]`` (int32) at the ``k <= W`` entries of largest
+    ``score [..., W]``, ties to the LOWER index, in ASCENDING order of their
+    indices ``[..., k]`` — ``values[sort(top_k(score, k)[1])]``, found by
+    a threshold and written by counting: on the chip ``lax.top_k`` over
+    2,072 entries is a full sort, a batch of rows at a time (3.5 ms a layer
+    at 256 rows x 2 heads), and a gather of 65,536 ids through the tables
+    costs 0.67 ms more (PERF.md section 6, PR 60).
+
+    The k-th largest key ``kth`` by 32 steps of bisection over the bits
+    (the largest t with ``#{key >= t} >= k``), over the keys held ``[W,
+    rows]`` — the rows along the lanes, so that a step's count is a sum of
+    whole registers: the loop carries its arrays in the layout their shape
+    says, and ``[rows, nKV, W]`` is 2 sublanes of 8 and a reduction across
+    the lanes (0.31 ms for the 32 steps against 0.04; PERF.md section 6, PR
+    60); then ``c[w]``, how many of the entries up to w are chosen — every
+    key above ``kth`` and the first ``k - #above`` of those equal to it — by
+    a product with a triangular 0/1 matrix (exact: counts <= W in an fp32
+    accumulator; a running sum along the lanes is a ``reduce-window`` on
+    the chip); slot j takes the one chosen entry whose count is j + 1."""
+    W = score.shape[-1]
+    key = _order_keys(score)
+    by_row = key.reshape(-1, W).T                            # [W, rows]
+
+    def narrow(i, t):
+        cand = t ^ lax.shift_left(jnp.int32(1), 31 - i)     # bit 31: to 0
+        enough = (by_row >= cand[None]).sum(0, dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, t)
+
+    kth = lax.fori_loop(
+        0, 32, narrow,
+        jnp.full(by_row.shape[1:], jnp.iinfo(jnp.int32).min, jnp.int32)
+    ).reshape(key.shape[:-1])
+    above, equal = key > kth[..., None], key == kth[..., None]
+    w = jnp.arange(W, dtype=jnp.int32)
+    upto = (w[:, None] <= w[None]).astype(jnp.bfloat16)      # [w, v]: w <= v
+    run = jnp.einsum("a...w,wv->a...v",
+                     jnp.stack([above, equal]).astype(jnp.bfloat16), upto,
+                     preferred_element_type=jnp.float32).astype(jnp.int32)
+    need = (k - above.sum(-1, dtype=jnp.int32))[..., None]
+    nth = jnp.where(above | (equal & (run[1] <= need)),
+                    run[0] + jnp.minimum(run[1], need), 0)   # 1 .. k, else 0
+    slot = jnp.arange(1, k + 1, dtype=jnp.int32)
+    return jnp.where(nth[..., None, :] == slot[:, None],
+                     values[..., None, :], 0).sum(-1, dtype=jnp.int32)
+
+
+def choose(score, pos, table, sz: Sizes):
+    """(``table``'s entries ``[rows, nKV, width]`` at the chosen logical
+    blocks in ascending order, -1 past the live count; live count ``[rows,
+    nKV]``) from ``block_scores``'s; ``table [rows, W]`` a row's pool block
+    ids (``arange(W)`` gives the logical blocks themselves)."""
+    width, W = sz.width, score.shape[-1]
     newest = pos // sz.block
     dense = (pos + 1 <= sz.dense_len)[:, None, None]
-    k = min(sz.topk, score.shape[-1])
-    _, top = lax.top_k(score, k)                 # ties: the lower index
-    top = jnp.sort(top.astype(jnp.int32), axis=-1)
-    top = jnp.pad(top, ((0, 0), (0, 0), (0, width - k)), constant_values=-1)
+    k = min(sz.topk, W)
+    top = jnp.pad(_largest(score, k, table[:, None]),
+                  ((0, 0), (0, 0), (0, width - k)), constant_values=-1)
     slot = jnp.arange(width, dtype=jnp.int32)[None, None]
-    every = jnp.where(slot <= newest[:, None, None], slot, -1)
-    logical = jnp.where(dense, every, top)
+    first = jnp.pad(table, ((0, 0), (0, max(width - W, 0))),
+                    constant_values=-1)[:, None, :width]
+    ids = jnp.where(dense, first, top)
     n = jnp.where(dense[..., 0], jnp.minimum(newest + 1, width)[:, None],
                   jnp.minimum(k, newest + 1)[:, None])
-    n = jnp.broadcast_to(n, logical.shape[:2]).astype(jnp.int32)
-    return jnp.where(slot < n[..., None], logical, -1), n
+    n = jnp.broadcast_to(n, ids.shape[:2]).astype(jnp.int32)
+    return jnp.where(slot < n[..., None], ids, -1), n
 
 
 def _batches(n: int, size: int) -> int:
@@ -240,32 +299,28 @@ def select_blocks(q, ck, layer, table, pos, live, sz: Sizes, scale: float
     def of_stream(q_s, row, g, pos_s):
         """One stream's K rows: its pooled rows gathered once."""
         pooled = ck[layer, g, jnp.maximum(row, 0)]          # [W, nKV, R, D]
-        logical, n = choose(block_scores(q_s, pooled, pos_s, sz, scale),
-                            pos_s, sz)
-        ids = jnp.where(logical >= 0, row[jnp.maximum(logical, 0)],
-                        DEAD_BLOCK)
-        return ids, n
+        return block_scores(q_s, pooled, pos_s, sz, scale)
 
     if K == 1:
         b = _batches(S, _BATCH_STREAMS)
         split = lambda v: v.reshape((S // b, b) + v.shape[1:])  # noqa: E731
-        ids, n = lax.map(
+        score = lax.map(
             lambda a: jax.vmap(of_stream)(*a),
             (split(qg), split(table), split(group), split(pos)))
-        ids, n = ids.reshape((S,) + ids.shape[2:]), n.reshape(
-            (S,) + n.shape[2:])
     else:
         b = _batches(K, _BATCH_ROWS)
-
-        def stream(args):
-            q_s, row, g, pos_s = args
-            ids, n = lax.map(
-                lambda a: of_stream(a[0], row, g, a[1]),
-                (q_s.reshape((K // b, b) + q_s.shape[1:]),
-                 pos_s.reshape(K // b, b)))
-            return (ids.reshape((K,) + ids.shape[2:]),
-                    n.reshape((K,) + n.shape[2:]))
-        ids, n = lax.map(stream, (qg, table, group, pos))
+        score = lax.map(
+            lambda s: lax.map(
+                lambda a: of_stream(a[0], s[1], s[2], a[1]),
+                (s[0].reshape((K // b, b) + s[0].shape[1:]),
+                 s[3].reshape(K // b, b))),
+            (qg, table, group, pos))
+    # the block scores of a whole program are small (256 x 2 x 2,072 fp32 =
+    # 4.2 MB where the scores they are the maxima of are batched above): the
+    # order is found once, for every row
+    ids, n = choose(score.reshape(S * K, nKV, W), pos.reshape(S * K),
+                    jnp.repeat(table, K, axis=0), sz)
+    ids, n = ids.reshape(S, K, nKV, sz.width), n.reshape(S, K, nKV)
     n = jnp.where(live[..., None], n, 0)
     return jnp.where(live[..., None, None], ids, DEAD_BLOCK), n
 

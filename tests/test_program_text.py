@@ -20,7 +20,11 @@ engine's three programs are ``ServedModel``'s, written once over a family's
      PR's tree, so that the next change to them shows), and for the programs
      ``MOVED_BY_PR58`` names — the kernel arm of the six expert-layer
      families, whose grouped product reads its rows through the plan — the
-     text PR 58 left (``program_text_pr58.json``).  A program that lowers
+     text PR 58 left (``program_text_pr58.json``); the family PR 59 added
+     is held to its PR's file (``program_text_pr59.json``: what it computes,
+     always) and, for the two programs ``MOVED_BY_PR60`` names — the block
+     selection's order found by a threshold, not by two sorts — to the text
+     PR 60 left (``program_text_pr60.json``).  A program that lowers
      to none of them fails: run ``python tests/decode_step_hlo.py OUT.json
      DIR`` on both trees and ``diff`` the blanked texts to see which lines
      moved;
@@ -52,6 +56,7 @@ LEFT_BY_PR58 = json.load(open(os.path.join(DATA, "program_text_pr58.json")))
 # The families later PRs ADDED (``harness.ADDED``), each by the tree of its
 # own PR: ``python tests/decode_step_hlo.py OUT.json DIR ADDED``.
 ADDED_BY_PR59 = json.load(open(os.path.join(DATA, "program_text_pr59.json")))
+LEFT_BY_PR60 = json.load(open(os.path.join(DATA, "program_text_pr60.json")))
 
 # Why a program's operations are not, line for line, the ones PR 55 lowered
 # (CHANGES.md, PR 56, quotes the lines).  Where the seven copies of the
@@ -93,6 +98,17 @@ MOVED_BY_PR58 = {
         "lfm2": ("decode_step", "prefill_step"),
         "kimi_linear": ("decode_step", "prefill_step"),
     }.items()}
+# PR 60, both arms: the two programs of the family that selects blocks.
+THRESHOLD = ("`sparse_select.choose` finds the `topk` largest block scores by "
+             "a threshold (32 steps of bisection over order-preserving int32 "
+             "keys, one loop) and writes the table's entries there in "
+             "ascending order by counting (a product with a triangular 0/1 "
+             "matrix, a compare-and-sum): the `top_k`, the `sort` of its "
+             "indices and the gather through the table are gone, and the "
+             "order is found once a program, outside the `lax.map`s that "
+             "batch the scores")
+MOVED_BY_PR60 = {"minicpm_sala": {"decode_step": (THRESHOLD,),
+                                  "prefill_step": (THRESHOLD,)}}
 KINDS = ("decode_step", "prefill_step", "verify_step", "outputs")
 REFUSES = ("retention", "lfm2", "falcon_h1", "kimi_linear")
 
@@ -146,9 +162,11 @@ def test_the_programs_are_what_they_were(family, kind):
                                   "verify_step"])
 @pytest.mark.parametrize("family", sorted(harness.ADDED))
 def test_an_added_familys_programs_are_what_its_pr_left(family, kind, arm):
-    """PR 59's family (sparse layers beside Lightning layers): the text its
-    PR lowered, the bits it computed; ``verify`` refuses (a state a
-    stream)."""
+    """PR 59's family (sparse layers beside Lightning layers): the bits its
+    PR computed, and the text its PR lowered — or, for the programs
+    ``MOVED_BY_PR60`` names with their cause, the text PR 60 left
+    (``program_text_pr60.json``: ``python tests/decode_step_hlo.py OUT.json
+    DIR ADDED`` on that tree); ``verify`` refuses (a state a stream)."""
     got, want = harness.golden(family, arm), ADDED_BY_PR59[family][arm]
     if kind == "outputs":
         assert got["outputs"] == want["outputs"]
@@ -164,23 +182,33 @@ def test_an_added_familys_programs_are_what_its_pr_left(family, kind, arm):
                 paged_kernel=False)
         return
     assert names
+    left_by = "its PR"
+    if kind in MOVED_BY_PR60.get(family, {}):
+        want = LEFT_BY_PR60[family][arm]
+        left_by = "PR 60 (moved then by: {})".format(
+            "; ".join(MOVED_BY_PR60[family][kind]))
     for name in names:
         assert got["programs"][name]["order_free"] \
-            == want["programs"][name]["order_free"], (family, arm, name)
+            == want["programs"][name]["order_free"], (
+            f"{family}.{arm}.{name}: other operations than {left_by} left")
 
 
-@pytest.mark.parametrize("was,now,moved_by", [
-    (GOLDEN, LEFT_BY_PR56, lambda family, arm: MOVED.get(family, {})),
-    (LEFT_BY_PR56, LEFT_BY_PR58, lambda family, arm:
-     MOVED_BY_PR58.get(family, {}) if arm == "on" else {})],
-    ids=["pr56", "pr58"])
-def test_the_causes_on_record_are_of_the_programs_that_moved(was, now,
-                                                             moved_by):
+@pytest.mark.parametrize("families,was,now,moved_by", [
+    (harness.FAMILIES, GOLDEN, LEFT_BY_PR56,
+     lambda family, arm: MOVED.get(family, {})),
+    (harness.FAMILIES, LEFT_BY_PR56, LEFT_BY_PR58, lambda family, arm:
+     MOVED_BY_PR58.get(family, {}) if arm == "on" else {}),
+    (harness.ADDED, ADDED_BY_PR59, LEFT_BY_PR60,
+     lambda family, arm: MOVED_BY_PR60.get(family, {}))],
+    ids=["pr56", "pr58", "pr60"])
+def test_the_causes_on_record_are_of_the_programs_that_moved(families, was,
+                                                             now, moved_by):
     """``MOVED`` names the programs whose operations PR 56 left other than
     PR 55's, ``MOVED_BY_PR58`` those PR 58 left other than PR 56's (the
-    kernel arm alone), and no other (an entry would outlive its cause);
-    what every fixture computes moved in neither."""
-    for family in harness.FAMILIES:
+    kernel arm alone), ``MOVED_BY_PR60`` those PR 60 left other than PR
+    59's (the added family's), and no other (an entry would outlive its
+    cause); what every fixture computes moved in none."""
+    for family in families:
         for arm in harness.ARMS:
             old, new = was[family][arm], now[family][arm]
             assert old["outputs"] == new["outputs"]
