@@ -717,13 +717,13 @@ class InferenceEngine:
         hold is released by ``activate_slot`` or ``release_slot``.
         ``exclude_groups`` lets the scheduler gather a one-slot-per-
         group admission batch for ``prefill_many``."""
-        prompt = np.asarray(prompt, np.int32).reshape(-1)
         self.last_admit_block = None
         free = [s for s in range(self.max_slots)
                 if not self.active[s] and s not in self._held]
         if not free:
             self.last_admit_block = "no_slot"
             return None
+        prompt = self._chain(prompt)
         Sg = self.cache_spec.slots_per_group
         first_free: Dict[int, int] = {}
         for s in free:
@@ -748,6 +748,15 @@ class InferenceEngine:
             self.last_admit_block = "reservation"
         return best
 
+    def _chain(self, prompt) -> kv_cache.PromptChain:
+        """``prompt`` (bare, or the chain a ``Request`` keeps) as a chain
+        walked at this engine's block size: where the engine's admission
+        calls start.  A walk made here is the allocator's count and the
+        aggregator's (``snapshot()["prefix"]["chain_walks"]``)."""
+        chain = kv_cache.PromptChain.of(prompt)
+        chain.hashes(self.block_size, by=(self.allocator, self.serving))
+        return chain
+
     def last_admit_info(self, slot: int) -> Dict[str, Any]:
         """Prefix-cache/CoW detail of the most recent admission into
         ``slot`` (for the request trace)."""
@@ -770,7 +779,7 @@ class InferenceEngine:
         """Longest cached prompt prefix (tokens) resident anywhere in
         this engine's block pool — the router's affinity signal. Host
         hash walk only; zero device work."""
-        prompt = np.asarray(prompt, np.int32).reshape(-1)
+        prompt = self._chain(prompt)
         return self.block_size * max(
             self.allocator.matched_blocks(g, prompt) for g in range(self.dp))
 
@@ -882,7 +891,9 @@ class InferenceEngine:
             cached = sum(int(p[2].matched) for p in plans)
             computed = self.dp * sum(widths)
             span.set_metadata(cached_tokens=cached, chunks=len(steps),
-                              rows_computed=computed)
+                              rows_computed=computed, chain_walks=sum(
+                                  self._last_admit[p[0]]["chain_walks"]
+                                  for p in plans))
             state = self.allocator.span_args(plans=[p[2] for p in plans])
             if state:
                 span.set_metadata(**state)
@@ -980,8 +991,10 @@ class InferenceEngine:
         plans = []
         seen_groups = set()
         forks = {}       # group -> (src, dst): copied before any chunk
+        walks = {}
         for slot, prompt, max_new in admissions:
-            prompt = np.asarray(prompt, np.int32).reshape(-1)
+            chain = self._chain(prompt)
+            prompt = chain.tokens
             plen = int(prompt.shape[0])
             if plen < 1:
                 raise ValueError("empty prompt")
@@ -996,7 +1009,8 @@ class InferenceEngine:
                     "batch at most one slot per dp group")
             seen_groups.add(group)
             plan = self.allocator.admit_prompt(
-                slot, group, prompt, int(max_new), self.spec_k)
+                slot, group, chain, int(max_new), self.spec_k)
+            walks[slot] = chain.walks
             row = np.full(J, kv_cache.DEAD_BLOCK, np.int32)
             row[:len(plan.table)] = plan.table
             self.block_tables[slot] = row
@@ -1025,7 +1039,8 @@ class InferenceEngine:
             tails.append((chunks, snap_in))
             self._last_admit[slot] = {
                 "cached_tokens": int(plan.matched), "chunks": len(chunks),
-                "cow_fork": plan.cow_src is not None}
+                "cow_fork": plan.cow_src is not None,
+                "chain_walks": walks[slot]}
             if plan.cached_by_class:
                 self._last_admit[slot]["cached_by_class"] = dict(
                     plan.cached_by_class)

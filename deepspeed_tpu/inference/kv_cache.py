@@ -501,20 +501,66 @@ def paged_attend(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
 # --------------------------------------------------------------------- #
 # Host-side block allocator: free lists, refcounts, prefix cache, CoW
 # --------------------------------------------------------------------- #
-def chain_hash(prev: int, tokens: np.ndarray) -> int:
-    """Position-dependent hash of one full block's tokens given the
-    hash of the preceding chain — two different prefixes never collide
-    on position, only on (astronomically unlikely) hash collision."""
-    return hash((prev, tokens.astype(np.int64).tobytes()))
+def chain_hash(prev: int, block: bytes) -> int:
+    """Position-dependent hash of one full block's tokens (``block``: their
+    int64 bytes) given the hash of the preceding chain — two different
+    prefixes never collide on position, only on (astronomically unlikely)
+    hash collision."""
+    return hash((prev, block))
 
 
 def chain_hashes(prompt: np.ndarray, block_size: int) -> List[int]:
-    """The chain hash at every full block of ``prompt``."""
+    """The chain hash at every full block of ``prompt``: THE walk (the
+    prompt converted once, a block a slice of its bytes)."""
+    data = np.asarray(prompt).astype(np.int64).tobytes()
+    step = 8 * block_size
     out, h = [], 0
-    for j in range(len(prompt) // block_size):
-        h = chain_hash(h, prompt[j * block_size:(j + 1) * block_size])
+    for at in range(0, len(data) - step + 1, step):
+        h = chain_hash(h, data[at:at + step])
         out.append(h)
     return out
+
+
+class PromptChain:
+    """A prompt with its chain of block hashes, walked ONCE: what every
+    question of an admission is answered from (can it be admitted, how
+    much is cached, what is the plan — each allocator method takes a bare
+    prompt or one of these).  The chain is a function of the tokens and the
+    block size alone, no allocator's state, so it lives as long as the
+    request does (``scheduler.Request.chained``); what an INDEX says of it
+    is asked again every time.  ``walks``: the walks made for this prompt
+    (1 once any allocator of one block size has asked)."""
+
+    __slots__ = ("tokens", "walks", "_hashes")
+
+    def __init__(self, prompt):
+        self.tokens = np.asarray(prompt, np.int32).reshape(-1)
+        self.walks = 0
+        self._hashes: Dict[int, List[int]] = {}     # by block size
+
+    @classmethod
+    def of(cls, prompt) -> "PromptChain":
+        return prompt if isinstance(prompt, cls) else cls(prompt)
+
+    def __len__(self) -> int:
+        return len(self.tokens)
+
+    def __array__(self, dtype=None, copy=None):
+        return self.tokens if dtype is None \
+            else self.tokens.astype(dtype, copy=False)
+
+    def hashes(self, block_size: int, by=()) -> List[int]:
+        """The chain at every full block; the call that has to walk counts
+        it here and on each of ``by`` (``chain_walks``: the allocator's,
+        the engine's aggregator's)."""
+        hashes = self._hashes.get(block_size)
+        if hashes is None:
+            hashes = self._hashes[block_size] = chain_hashes(
+                self.tokens, block_size)
+            self.walks += 1
+            for counter in by:
+                counter.chain_walks += 1
+        return hashes
 
 
 class PoolExhausted(RuntimeError):
@@ -567,6 +613,9 @@ class BlockAllocator:
         # Cumulative telemetry the aggregator snapshots.
         self.cow_copies = 0
         self.reclaimed = 0
+        # Whole-prompt chain computations this allocator had to make
+        # (``PromptChain.hashes``): one a request, not one a question.
+        self.chain_walks = 0
         # Blocks live streams gave back before their release: a bounded
         # class's (``BoundedBlockAllocator``); nothing else returns any.
         self.returned = 0
@@ -599,28 +648,30 @@ class BlockAllocator:
     def table_width(self) -> int:
         return self.spec.max_blocks_per_slot
 
-    def match_prefix(self, group: int, prompt: np.ndarray,
+    def _walked(self, prompt) -> Tuple[PromptChain, List[int]]:
+        """``prompt`` (bare, or a ``PromptChain``) as a chain and its
+        hashes at this pool's block size: where every public method
+        starts, and hands the CHAIN on."""
+        chain = PromptChain.of(prompt)
+        return chain, chain.hashes(self.spec.block_size, by=(self,))
+
+    def match_prefix(self, group: int, prompt,
                      limit: Optional[int] = None
                      ) -> Tuple[List[int], List[int]]:
         """Longest cached full-block chain matching ``prompt`` in this
         group (its first ``limit`` blocks at most) → (block ids, chain
-        hashes). Walks the chain hash; stops at the first miss."""
-        bs = self.spec.block_size
+        hashes). Stops at the first miss."""
+        hashes = self._walked(prompt)[1]
         idx = self._hash_index[group]
         blocks: List[int] = []
-        hashes: List[int] = []
-        h = 0
-        n = len(prompt) // bs
-        for j in range(n if limit is None else min(n, limit)):
-            h = chain_hash(h, prompt[j * bs:(j + 1) * bs])
+        for h in hashes if limit is None else hashes[:limit]:
             b = idx.get(h)
             if b is None:
                 break
             blocks.append(b)
-            hashes.append(h)
-        return blocks, hashes
+        return blocks, hashes[:len(blocks)]
 
-    def matched_blocks(self, group: int, prompt: np.ndarray) -> int:
+    def matched_blocks(self, group: int, prompt) -> int:
         """Full blocks of ``prompt`` the prefix cache of this group
         covers: the cached chain's length."""
         return len(self.match_prefix(group, prompt)[0])
@@ -635,16 +686,21 @@ class BlockAllocator:
             m += 1
         return m
 
-    def can_admit(self, group: int, prompt: np.ndarray, max_new: int,
+    def can_admit(self, group: int, prompt, max_new: int,
                   spec_k: int = 0, limit: Optional[int] = None) -> bool:
         """``limit``: the prefix match is cut to that many blocks (what a
         model's classes agreed on, ``ClassAllocators``)."""
-        need = self.need_blocks(len(prompt), max_new, spec_k)
-        matched = self.match_prefix(group, prompt, limit)[0]
+        return self._covers(group, len(prompt), max_new, spec_k,
+                            self.match_prefix(group, prompt, limit)[0])
+
+    def _covers(self, group: int, plen: int, max_new: int, spec_k: int,
+                matched: List[int]) -> bool:
+        """``can_admit`` of a prompt whose cached chain is ``matched``."""
+        need = self.need_blocks(plen, max_new, spec_k)
         # Only LIVE shared blocks are a free ride; reviving an
         # LRU-retained block consumes reclaimable capacity like any
         # fresh allocation does.
-        free_ride = sum(1 for b in matched if self._ref[group, b] > 0)
+        free_ride = int((self._ref[group, matched] > 0).sum())
         return self.available(group) >= need - free_ride
 
     # ---- allocation primitives ---- #
@@ -690,25 +746,25 @@ class BlockAllocator:
                 self._free[group].append(b)
 
     # ---- request lifecycle ---- #
-    def _gate(self, group: int, prompt: np.ndarray, max_new: int,
-              spec_k: int, limit: Optional[int]) -> None:
-        if not self.can_admit(group, prompt, max_new, spec_k, limit=limit):
-            raise PoolExhausted(
-                f"group {group}: {self.available(group)} block(s) "
-                f"available < worst-case need for a "
-                f"{len(prompt)}+{max_new}-token request")
+    def _refuse(self, group: int, plen: int, max_new: int) -> None:
+        raise PoolExhausted(
+            f"group {group}: {self.available(group)} block(s) "
+            f"available < worst-case need for a "
+            f"{plen}+{max_new}-token request")
 
-    def admit_prompt(self, slot: int, group: int, prompt: np.ndarray,
+    def admit_prompt(self, slot: int, group: int, prompt,
                      max_new: int, spec_k: int = 0,
                      limit: Optional[int] = None) -> "AdmitPlan":
         """Allocate/share the prompt's blocks and book the request's
         worst-case reservation. Returns the plan the engine prefills
         from. Raises PoolExhausted when ``can_admit`` would be False.
         ``limit``: see ``can_admit``."""
-        self._gate(group, prompt, max_new, spec_k, limit)
+        chain, hashes = self._walked(prompt)
         bs = self.spec.block_size
-        plen = len(prompt)
-        matched_blocks, hashes = self.match_prefix(group, prompt, limit)
+        plen = len(chain)
+        matched_blocks = self.match_prefix(group, chain, limit)[0]
+        if not self._covers(group, plen, max_new, spec_k, matched_blocks):
+            self._refuse(group, plen, max_new)
         # Always re-prefill at least the prompt's last token: its
         # logits seed the first sampled token, and the block holding it
         # must be privately writable for the decode appends that follow.
@@ -739,10 +795,8 @@ class BlockAllocator:
         # content diverges the moment the slot decodes into it... except
         # it holds exactly the cached chain's tokens until then; keep it
         # out of the index so the cached original stays authoritative).
-        h = hashes[n_keep - 1] if n_keep else 0
         for j in range(n_keep, plen // bs):
-            h = chain_hash(h, prompt[j * bs:(j + 1) * bs])
-            b = table[j]
+            h, b = hashes[j], table[j]
             if b != cow_dst and h not in self._hash_index[group]:
                 self._hash_index[group][h] = b
                 self._block_hash[group][b] = h
@@ -879,7 +933,7 @@ class StateAllocator(BlockAllocator):
         """A stream's page is all it ever holds."""
 
     # ---- the prefix cache: snapshots ---- #
-    def matched_blocks(self, group: int, prompt: np.ndarray) -> int:
+    def matched_blocks(self, group: int, prompt) -> int:
         """The longest boundary of ``prompt`` that has a snapshot."""
         return self.match_snapshot(group, prompt)[0]
 
@@ -893,7 +947,7 @@ class StateAllocator(BlockAllocator):
                 return m
         return 0
 
-    def match_snapshot(self, group: int, prompt: np.ndarray,
+    def match_snapshot(self, group: int, prompt,
                        limit: Optional[int] = None
                        ) -> Tuple[int, Optional[int], int]:
         """The LONGEST block boundary of ``prompt`` (``limit`` blocks at
@@ -901,9 +955,8 @@ class StateAllocator(BlockAllocator):
         leaves at least the last token to prefill -> (blocks it covers,
         its page or None, the chain hash at the prompt's last full
         block)."""
-        bs = self.spec.block_size
-        hashes = chain_hashes(prompt, bs)
-        n = (len(prompt) - 1) // bs
+        chain, hashes = self._walked(prompt)
+        n = (len(chain) - 1) // self.spec.block_size
         best = self.match_limit(group, hashes,
                                 n if limit is None else min(n, limit))
         page = self._hash_index[group][hashes[best - 1]] if best else None
@@ -922,15 +975,18 @@ class StateAllocator(BlockAllocator):
         return boundary if boundary - resumed >= self.spec.page_tokens else 0
 
     # ---- request lifecycle ---- #
-    def can_admit(self, group: int, prompt: np.ndarray, max_new: int,
+    def can_admit(self, group: int, prompt, max_new: int,
                   spec_k: int = 0, limit: Optional[int] = None) -> bool:
         """The stream's own page, drawn while the snapshot it resumes
         from (if retained) is held out of reach."""
-        page = self.match_snapshot(group, prompt, limit)[1]
-        return self.available(group) - int(
-            page is not None and page in self._lru[group]) >= 1
+        return self._page_left(
+            group, self.match_snapshot(group, prompt, limit)[1])
 
-    def admit_prompt(self, slot: int, group: int, prompt: np.ndarray,
+    def _page_left(self, group: int, src: Optional[int]) -> bool:
+        return self.available(group) - int(
+            src is not None and src in self._lru[group]) >= 1
+
+    def admit_prompt(self, slot: int, group: int, prompt,
                      max_new: int, spec_k: int = 0,
                      limit: Optional[int] = None) -> "AdmitPlan":
         """The stream's own page; the snapshot to copy into it first
@@ -943,9 +999,10 @@ class StateAllocator(BlockAllocator):
         a cut, or the chunk program's own second write — and THEN enters
         it into the prefix cache, ``commit_snapshot``; until then the page
         is out of every list and nothing can match it)."""
-        self._gate(group, prompt, max_new, spec_k, limit)
         bs = self.spec.block_size
         n, src, h_last = self.match_snapshot(group, prompt, limit)
+        if not self._page_left(group, src):
+            self._refuse(group, len(prompt), max_new)
         if src is not None:
             self._incref(group, src)            # out of the LRU's reach
         own = self._draw(group, slot)
@@ -1052,11 +1109,11 @@ class BoundedBlockAllocator(BlockAllocator):
         return self.spec.blocks_per_group - self._committed[group]
 
     # ---- prefix cache ---- #
-    def matched_blocks(self, group: int, prompt: np.ndarray) -> int:
+    def matched_blocks(self, group: int, prompt) -> int:
         """The longest boundary whose blocks in reach are all cached."""
-        bs = self.spec.block_size
-        return self.match_limit(group, chain_hashes(prompt, bs),
-                                (len(prompt) - 1) // bs)
+        chain, hashes = self._walked(prompt)
+        return self.match_limit(group, hashes,
+                                (len(chain) - 1) // self.spec.block_size)
 
     def match_limit(self, group: int, hashes: Sequence[int], n: int) -> int:
         """The most blocks ``m <= n`` a hit can be served at: only what a
@@ -1074,26 +1131,25 @@ class BoundedBlockAllocator(BlockAllocator):
                 return m
         return 0
 
-    def _match(self, group: int, prompt: np.ndarray,
-               limit: Optional[int]):
+    def _match(self, group: int, prompt, limit: Optional[int]):
         """(blocks matched n, the cached blocks a stream resuming at
         ``n * block_size`` shares: logical ``[first block in reach, n)``,
         the prompt's chain hashes)."""
         bs = self.spec.block_size
-        hashes = chain_hashes(prompt, bs)
+        chain, hashes = self._walked(prompt)
         n = self.match_limit(group, hashes, min(
-            (len(prompt) - 1) // bs, len(hashes) if limit is None else limit))
+            (len(chain) - 1) // bs, len(hashes) if limit is None else limit))
         idx = self._hash_index[group]
         return n, [idx[hashes[j]] for j in
                    range(self.spec.first_block(n * bs), n)], hashes
 
     # ---- request lifecycle ---- #
-    def can_admit(self, group: int, prompt: np.ndarray, max_new: int,
+    def can_admit(self, group: int, prompt, max_new: int,
                   spec_k: int = 0, limit: Optional[int] = None) -> bool:
         return self.available(group) >= self.need_blocks(
             len(prompt), max_new, spec_k)
 
-    def admit_prompt(self, slot: int, group: int, prompt: np.ndarray,
+    def admit_prompt(self, slot: int, group: int, prompt,
                      max_new: int, spec_k: int = 0,
                      limit: Optional[int] = None) -> "AdmitPlan":
         """The ring holds the cached blocks in reach of the resume point
@@ -1196,6 +1252,7 @@ class ClassAllocators:
             self.columns.append(slice(at, at + a.table_width))
             at += a.table_width
         self.table_width = at
+        self.chain_walks = 0        # as ``BlockAllocator``'s: its own
 
     # ---- accounting ---- #
     def blocks_in_use(self) -> int:
@@ -1236,46 +1293,44 @@ class ClassAllocators:
         return self._merged("snapshot_totals")
 
     # ---- the prefix cache ---- #
-    def _agreed(self, group: int, prompt: np.ndarray, classes=None,
-                hashes=None) -> int:
+    def _agreed(self, group: int, chain: PromptChain, classes=None) -> int:
         """The longest boundary (in blocks) every class of ``classes``
-        (all of them by default) can serve a hit at (``hashes``: the
-        prompt's chain, where the caller has it)."""
+        (all of them by default) can serve a hit at."""
         bs = self.spec.block_size
-        if hashes is None:
-            hashes = chain_hashes(prompt, bs)
-        n, before = (len(prompt) - 1) // bs, None
+        hashes = chain.hashes(bs, by=(self,))
+        n, before = (len(chain) - 1) // bs, None
         while n != before:
             before = n
             for a in self.classes if classes is None else classes:
                 n = a.match_limit(group, hashes, n)
         return n
 
-    def matched_blocks(self, group: int, prompt: np.ndarray) -> int:
-        return self._agreed(group, prompt)
+    def matched_blocks(self, group: int, prompt) -> int:
+        return self._agreed(group, PromptChain.of(prompt))
 
-    def can_admit(self, group: int, prompt: np.ndarray, max_new: int,
+    def can_admit(self, group: int, prompt, max_new: int,
                   spec_k: int = 0) -> bool:
-        n = self._agreed(group, prompt)
-        return all(a.can_admit(group, prompt, max_new, spec_k, limit=n)
+        chain = PromptChain.of(prompt)
+        n = self._agreed(group, chain)
+        return all(a.can_admit(group, chain, max_new, spec_k, limit=n)
                    for a in self.classes)
 
     # ---- request lifecycle ---- #
-    def admit_prompt(self, slot: int, group: int, prompt: np.ndarray,
+    def admit_prompt(self, slot: int, group: int, prompt,
                      max_new: int, spec_k: int = 0) -> AdmitPlan:
         bs = self.spec.block_size
-        hashes = chain_hashes(prompt, bs)
-        n = self._agreed(group, prompt, hashes=hashes)
+        chain = PromptChain.of(prompt)
+        n = self._agreed(group, chain)
         # (before any class enters this prompt's own blocks in its index)
         pages = [a for a in self.classes if a is not self._copier]
-        lost = bs * (self._agreed(group, prompt, pages, hashes) - n) \
+        lost = bs * (self._agreed(group, chain, pages) - n) \
             if pages and self._copier is not None else 0
         row = np.full(self.table_width, DEAD_BLOCK, np.int32)
         cached: Dict[str, int] = {}
         done = []
         try:
             for a, cols in zip(self.classes, self.columns):
-                plan = a.admit_prompt(slot, group, prompt, max_new, spec_k,
+                plan = a.admit_prompt(slot, group, chain, max_new, spec_k,
                                       limit=n)
                 done.append((a, cols, plan))
                 row[cols][:len(plan.table)] = plan.table
@@ -1384,7 +1439,8 @@ __all__ = ["DEAD_BLOCK", "PagedKVCacheSpec", "paged_partition_spec",
            "paged_layer_view", "copy_pages",
            "positions_to_blocks",
            "block_select", "paged_write_rows", "paged_attend",
-           "chain_hash", "chain_hashes", "PoolExhausted", "BlockAllocator",
+           "chain_hash", "chain_hashes", "PromptChain", "PoolExhausted",
+           "BlockAllocator",
            "AdmitPlan", "StateAllocator", "BoundedBlockAllocator",
            "ClassAllocators", "class_specs", "attended_specs",
            "allocator_for"]

@@ -70,7 +70,7 @@ class ReplicaRouter:
         composite score is better — prefix affinity minus load
         (occupancy + normalized queue depth)."""
         plen = max(len(req.prompt), 1)
-        affinity_tokens = eng.prefix_match_tokens(req.prompt)
+        affinity_tokens = eng.prefix_match_tokens(req.chained())
         occupancy = eng.active_slots / eng.max_slots
         queue_load = queue_len / eng.max_slots
         return {
@@ -206,7 +206,7 @@ class ReplicaRouter:
                     while queues[i]:
                         req = queues[i][0]
                         slot = eng.select_slot(
-                            req.prompt, req.max_new_tokens,
+                            req.chained(), req.max_new_tokens,
                             exclude_groups=used)
                         if slot is None:
                             # Genuine head-of-queue rejection only when
@@ -234,7 +234,7 @@ class ReplicaRouter:
                         break
                     # The engine opens the ``prefill`` host span itself.
                     results = eng.prefill_many(
-                        [(slot, req.prompt, req.max_new_tokens)
+                        [(slot, req.chained(), req.max_new_tokens)
                          for req, slot in batch], self.temperature,
                         rids=[req.rid for req, _ in batch])
                     # the clock of the timeline the first tokens join

@@ -38,6 +38,7 @@ import numpy as np
 
 from ..monitor.telemetry import ids_arg, spans_recorded
 from ..utils.logging import logger
+from .kv_cache import PromptChain
 
 
 @dataclasses.dataclass
@@ -63,6 +64,16 @@ class Request:
     row_last: int = -1
     timeline: Any = dataclasses.field(default=None, repr=False,
                                       compare=False)
+    # The prompt with its chain of block hashes (``chained``): walked once,
+    # however many passes ask the engine whether the request can go in.
+    chain: Optional[PromptChain] = dataclasses.field(
+        default=None, repr=False, compare=False)
+
+    def chained(self) -> PromptChain:
+        """The prompt as the engine's admission calls take it."""
+        if self.chain is None:
+            self.chain = PromptChain(self.prompt)
+        return self.chain
 
     @property
     def ttft_s(self) -> Optional[float]:
@@ -441,7 +452,7 @@ class ContinuousBatchingScheduler:
                     while queue:
                         req = queue[0]
                         slot = eng.select_slot(
-                            req.prompt, req.max_new_tokens,
+                            req.chained(), req.max_new_tokens,
                             exclude_groups=used)
                         if slot is None:
                             # Only a rejection with NO exclusions is the
@@ -462,7 +473,7 @@ class ContinuousBatchingScheduler:
                 if not batch:
                     break
                 results = eng.prefill_many(
-                    [(slot, req.prompt, req.max_new_tokens)
+                    [(slot, req.chained(), req.max_new_tokens)
                      for req, slot in batch], self.temperature,
                     rids=rids)
                 t_now = clock()

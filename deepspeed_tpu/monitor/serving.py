@@ -169,6 +169,8 @@ class ServingAggregator:
         # fake engines never do).
         self.prompt_tokens_admitted = 0
         self.cached_tokens_admitted = 0
+        self.admissions = 0
+        self.chain_walks = 0        # whole-prompt hash walks (the engine's)
         self.spec_proposed = 0
         self.spec_accepted = 0
         self._model_counters: Dict[str, Any] = {}   # name -> (sum, n)
@@ -370,6 +372,7 @@ class ServingAggregator:
         prompt's tokens rode already-resident blocks."""
         self.prompt_tokens_admitted += int(prompt_tokens)
         self.cached_tokens_admitted += int(cached_tokens)
+        self.admissions += 1
 
     def note_model_counters(self, args: Dict[str, Any]) -> None:
         """One fetch's worth of the served model's own counters (the
@@ -634,6 +637,8 @@ class ServingAggregator:
                 "cached_tokens": self.cached_tokens_admitted,
                 "hit_rate": round(self.cached_tokens_admitted /
                                   self.prompt_tokens_admitted, 4),
+                "admissions": self.admissions,
+                "chain_walks": self.chain_walks,
             }
         if self.spec_proposed:
             snap["spec"] = {
@@ -694,6 +699,8 @@ class ServingAggregator:
             out.completed += a.completed
             out.prompt_tokens_admitted += a.prompt_tokens_admitted
             out.cached_tokens_admitted += a.cached_tokens_admitted
+            out.admissions += a.admissions
+            out.chain_walks += a.chain_walks
             for key, n in a._admit_classes.items():
                 out._admit_classes[key] = out._admit_classes.get(key, 0) + n
             out.spec_proposed += a.spec_proposed

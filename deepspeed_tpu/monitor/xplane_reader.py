@@ -130,13 +130,18 @@ SPAN_ARGS = {
     # admission dispatched, each at the width it took (the narrowest of
     # the engine's prefill_widths that held its rows: prefill_chunk's
     # "rows"); prompt_tokens - cached_tokens of them were needed.
+    # chain_walks: whole-prompt hash walks made for the span's requests
+    # since each was queued (kv_cache.PromptChain.walks, summed): 1 a
+    # request that came through the scheduler, however many passes asked
+    # whether it could go in.
     # rows: a model whose every layer routes (inference/smallthinker.py):
     # the live rows the fetched execution(s) routed (a decode iteration's
     # live streams; on a prefill span the rows that are traffic in the
     # chunk program that ENDED the prompt, whose fetch the counters ride:
     # an earlier chunk's rows are all live).
     "prefill": ("slots", "prompt_tokens", "rids", "cached_tokens",
-                "chunks", "rows_computed", "moe_held_pairs", "moe_held_max",
+                "chunks", "rows_computed", "chain_walks",
+                "moe_held_pairs", "moe_held_max",
                 "moe_held_mean", "moe_held_empty", "moe_held_pair_share",
                 "rows", "hc_res_err_max",
                 # a model whose sparse layers select what they read

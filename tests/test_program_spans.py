@@ -220,7 +220,7 @@ def test_kv_write_and_attend_nest_under_attn(serve_op_names):
 SERVE_SPANS = {
     "admit": {"queued", "admitted", "late_ms", "rids"},
     "prefill": {"slots", "prompt_tokens", "cached_tokens", "chunks",
-                "rows_computed", "rids"},
+                "rows_computed", "chain_walks", "rids"},
     "prefill_plan": set(),
     "prefill_chunk": {"ci", "active_groups", "rows"},
     "prefill_fetch": set(),
@@ -385,6 +385,8 @@ def test_emit_and_prefill_spans_carry_the_timeline(serve_annotations,
         # ``prefill_widths``; tests/test_prefill_widths.py has the ladder)
         assert a["rows_computed"] == a["chunks"] * serve_engine.prefill_chunk
         assert a["prompt_tokens"] - a["cached_tokens"] == 12
+        # the scheduler's Request keeps its chain: one walk a request
+        assert a["chain_walks"] == a["slots"] == 1
     assert report["prefill_row_fill"] == 0.75
     assert report["itl_ms"]["n"] == 3 * 2 and report["stalls"] == []
     split = report["itl_split_ms"]
