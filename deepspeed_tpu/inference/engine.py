@@ -33,6 +33,16 @@ Architecture (mirrors the training engine's discipline):
   its new K/V rows into those buffers where they lie (no program
   slices, relays or rewrites the pool: a step's cost does not depend on
   ``num_blocks``).
+- A model generated by DIFFUSION OVER BLOCKS (``ServedModel.block_length``;
+  inference/sdar.py) has a ``decode_step`` of ``block_length`` rows a slot:
+  an iteration is a PASS over every live stream's block in progress, and a
+  stream is handed 0 tokens by it (a denoise pass) or its block's (the
+  pass that commits it). The block's tokens and its pass counter ride the
+  fetch array from one pass to the next on the device; lengths advance by
+  a block at a commit; a prefill ends at the prompt's last block boundary
+  and samples nothing. Under the static unmasking rule the host knows from
+  the schedule alone which pass commits, so the loop runs ahead as it does
+  for tokens; under the dynamic rule it waits for each fetch.
 - Host-side per-slot counters (lengths, active, last token) are the
   scheduler's state; they enter each step as tiny int arrays. The one
   device fetch per decode iteration is the sampled-token readback (the
@@ -236,6 +246,10 @@ class _Flight:
     cache_bytes: int
     context_tokens: int
     dropped: int = 0            # rows computed for streams released since
+    # A model of blocks: the reply tokens each slot is handed at the fetch
+    # ([S] int; 0 for a slot whose pass commits nothing). None: one each.
+    yields: Optional[np.ndarray] = None
+    lengths: Optional[np.ndarray] = None    # [S] as the program was handed
 
 
 class InferenceEngine:
@@ -245,6 +259,12 @@ class InferenceEngine:
     # by name: a row a slot after ``decode_once``, a row an admission after
     # ``prefill_many``.  None for a model without.
     last_probes: Optional[Dict[str, np.ndarray]] = None
+    # A model of blocks: called at every fetch with (the pass fetched, a
+    # ``_Flight``: its ``mask``, ``lengths`` and device ``logits [S, B,
+    # V]``; the blocks it ran on ``[S, B]``), ``block_tokens`` /
+    # ``block_passes`` already those after it — for a check that holds the
+    # timed path's own logits to a reference.  None: nothing is called.
+    on_block_pass: Optional[Callable] = None
 
     def __init__(self, model_cfg: Any, params: Any,
                  config: Any = None, mesh: Optional[Mesh] = None,
@@ -286,6 +306,23 @@ class InferenceEngine:
         # snapshot at a row inside the chunk (else the plan cuts there).
         self._freeze_in_chunk = bool(served.freezes_in_chunk)
         self.spec_k = int(self.icfg.spec_k)
+        # Rows a stream's step computes: a model of blocks' block (else 0:
+        # one token a stream a step, or ``spec_k + 1`` drafted ones).
+        self.block_length = B = int(served.block_length or 0)
+        if B and self.spec_k > 0:
+            raise ValueError(
+                f"inference.spec_k={self.spec_k}: {served.name} is "
+                f"generated in blocks of {B} positions a pass; there is "
+                "nothing to draft or accept: set inference.spec_k to 0")
+        if B and (self.block_size % B or self.prefill_chunk % B
+                  or self.max_len % B):
+            raise ValueError(
+                f"inference.block_size={self.block_size}, prefill_chunk="
+                f"{self.prefill_chunk} and max_seq_len={self.max_len} must "
+                f"be multiples of {served.name}'s block_length {B}: a full "
+                "prompt page's K/V is then a function of the tokens up to "
+                "its end (shareable), and no block straddles a page's or a "
+                "chunk's end")
         self.replica = str(self.icfg.replica)
         # Pallas paged-attention kernel vs the one-hot pool contraction.
         # Resolved ONCE here: the compiled paths bake the choice in, so
@@ -357,6 +394,24 @@ class InferenceEngine:
         self.lengths = np.zeros(self.max_slots, np.int32)
         self.active = np.zeros(self.max_slots, bool)
         self.last_tokens = np.zeros(self.max_slots, np.int32)
+        # A model of blocks: every slot's block in progress as of the last
+        # fetch ([S, B] token ids, -1 where undecided) with the denoise
+        # passes behind it, the reply tokens the last fetch handed each
+        # slot, and the static schedule's state as of the next DISPATCH:
+        # denoise passes a slot's block still gets before its commit, and
+        # the leading positions of its block that are its prompt's tail.
+        # (One array, as a fresh slot's rows are handed to the program: the
+        # block, then its passes.)
+        self._blocks = np.full((self.max_slots, B + 1), -1, np.int32)
+        self.block_tokens = self._blocks[:, :B]
+        self.block_passes = self._blocks[:, B]
+        self.block_passes[:] = 0
+        self.last_yield = np.zeros(self.max_slots, np.int32)
+        # ... and what the latest DISPATCH will hand each slot at its fetch
+        # (None: a token each).
+        self.dispatch_yield: Optional[np.ndarray] = None
+        self._to_commit = np.zeros(self.max_slots, np.int32)
+        self._block_tail = np.zeros(self.max_slots, np.int32)
         # The decode iteration dispatched and not yet fetched (at most
         # one: ``decode_once`` with ``continuing``), the slots activated
         # since the last dispatch (their token is on the host, out of
@@ -367,7 +422,8 @@ class InferenceEngine:
         self._fresh = np.zeros(self.max_slots, bool)
         self._fetch_sh = NamedSharding(self.mesh, P())
         self._no_fetch = jax.device_put(
-            np.zeros(self.max_slots + len(served.counter_names), np.int32),
+            np.zeros(self.max_slots * (2 * B + 1 if B else 1)
+                     + len(served.counter_names), np.int32),
             self._fetch_sh)
         # Clock of the latest iteration's tokens and the prefill seconds
         # spent by then (``decode_step_ms`` runs from there, less the
@@ -426,7 +482,7 @@ class InferenceEngine:
             "pools": {n: list(sh) for n, sh in sp_.pool_shapes.items()},
             "tile_bytes": sp_.block_nbytes()
             // (len(sp_.pool_names) * sp_.num_layers),
-            "rows_per_decode": self.max_slots * (self.spec_k + 1)}
+            "rows_per_decode": self.max_slots * (B or self.spec_k + 1)}
         tel_meta["attend_flops_per_token"] = {
             "live_ctx_max": self._attend_cost(context=sp_.max_len)[0],
             "pool_capacity": self._attend_cost(
@@ -535,6 +591,8 @@ class InferenceEngine:
         dispatch runs the one compiled form."""
         served = self.served
         n = len(self._cache_sh)
+        if served.block_length:
+            return self._build_block_step()
 
         def decode_step(params, *args):
             pools, (previous, tokens, fresh, lengths, bt, key,
@@ -552,6 +610,40 @@ class InferenceEngine:
             sampled = sample_tokens(logits, key, temperature)
             return (*pools, with_counters(sampled, counters), logits,
                     *probes)
+
+        return self._jit_step(decode_step, self.__dict__.get("_fetch_sh"))
+
+    def _build_block_step(self) -> Callable:
+        """``decode_step`` of a model generated in blocks
+        (``ServedModel.block_step``), under the same operands: ``tokens``
+        is ``[S, B + 1]``, a slot's block in progress as the host has it (-1
+        where undecided) and the denoise passes behind it, taken where
+        ``fresh`` marks the slot; else the
+        block and its pass counter are the previous execution's, read from
+        its fetch array where it lies: ``[the blocks the pass ran on | the
+        blocks after it | their passes | the model's counters]``, all
+        int32. The first part is what a committing slot is handed."""
+        served = self.served
+        n = len(self._cache_sh)
+
+        def decode_step(params, *args):
+            pools, (previous, tokens, fresh, lengths, bt, key,
+                    temperature) = args[:n], args[n:]
+            S, B = tokens.shape[0], tokens.shape[1] - 1
+            blocks = jnp.where(
+                fresh[:, None], tokens[:, :B],
+                previous[S * B:2 * S * B].reshape(S, B))
+            passes = jnp.where(fresh, tokens[:, B],
+                               previous[2 * S * B:2 * S * B + S])
+            p = self._runtime_params(params)
+            logits, pools, counters, after, passes, _ = served.block_step(
+                p, pools, blocks, passes, lengths, bt, key, temperature,
+                num_groups=self.dp, paged_kernel=self.paged_kernel,
+                mesh=self.mesh)
+            fetch = jnp.concatenate([
+                blocks.reshape(-1), after.reshape(-1), passes,
+                jnp.stack(list(counters))]).astype(jnp.int32)
+            return (*pools, fetch, logits)
 
         return self._jit_step(decode_step, self.__dict__.get("_fetch_sh"))
 
@@ -661,6 +753,24 @@ class InferenceEngine:
         if self.drafter is not None:
             self.drafter.observe(slot, [int(last_token)])
 
+    def activate_block(self, slot: int, prompt) -> None:
+        """Mark a freshly prefilled slot of a model of blocks live: the
+        cache holds the prompt as far as its last block boundary; the
+        tokens past it open the stream's first block, whose other
+        positions are undecided."""
+        B = self.block_length
+        prompt = np.asarray(prompt, np.int32)
+        tail = len(prompt) % B
+        self.lengths[slot] = len(prompt) - tail
+        self.active[slot] = True
+        self.block_tokens[slot] = -1
+        self.block_tokens[slot, :tail] = prompt[len(prompt) - tail:]
+        self.block_passes[slot] = 0
+        self._block_tail[slot] = tail
+        self._to_commit[slot] = self.served.denoise_passes(B - tail)
+        self._fresh[slot] = True
+        self._held.discard(slot)
+
     def release_slot(self, slot: int) -> None:
         """Evict: counters clear and every block reference drops —
         private blocks return to the free list, prefix blocks whose
@@ -676,6 +786,7 @@ class InferenceEngine:
         self.active[slot] = False
         self.lengths[slot] = 0
         self.last_tokens[slot] = 0
+        self.block_tokens[slot] = -1
         self._held.discard(slot)
         row = self.block_tables[slot]
         self.allocator.release(slot, row)
@@ -735,7 +846,7 @@ class InferenceEngine:
         best_key = None
         for g, s in first_free.items():
             if not self.allocator.can_admit(g, prompt,
-                                            int(max_new_tokens),
+                                            self._reserved(max_new_tokens),
                                             self.spec_k):
                 continue
             key = (self.allocator.matched_blocks(g, prompt),
@@ -747,6 +858,11 @@ class InferenceEngine:
         else:
             self.last_admit_block = "reservation"
         return best
+
+    def _reserved(self, max_new_tokens: int) -> int:
+        """The rows past its prompt a request books: its reply, and for a
+        model of blocks the rest of the reply's last block."""
+        return int(max_new_tokens) + max(self.block_length - 1, 0)
 
     def _chain(self, prompt) -> kv_cache.PromptChain:
         """``prompt`` (bare, or the chain a ``Request`` keeps) as a chain
@@ -863,7 +979,9 @@ class InferenceEngine:
                     self.allocator.abandon_snapshot(plan[2])
                 raise
             tl.raise_pending()
-            waited = self._await_decode()
+            # (A model of blocks samples nothing from a prompt: nothing is
+            # fetched, and the admission does not wait for the device.)
+            waited = 0.0 if self.block_length else self._await_decode()
             out = []
             n_ctr = len(self.served.counter_names)
             with tl.span("prefill_fetch"):
@@ -873,6 +991,10 @@ class InferenceEngine:
                     steps[ci][0])), n_ctr) for ci, _ in held.values()}
                 self._note_counters(span, [c for _, c in fetched.values()])
                 for slot, group, plan, prompt, plen in plans:
+                    if slot not in held:     # a model of blocks
+                        self.serving.note_admit(plen, plan.matched)
+                        out.append((None, None))
+                        continue
                     ci, g = held[slot]
                     tok = int(fetched[ci][0][g])
                     logits = np.asarray(jax.device_get(steps[ci][1][g])) \
@@ -1009,7 +1131,7 @@ class InferenceEngine:
                     "batch at most one slot per dp group")
             seen_groups.add(group)
             plan = self.allocator.admit_prompt(
-                slot, group, chain, int(max_new), self.spec_k)
+                slot, group, chain, self._reserved(max_new), self.spec_k)
             walks[slot] = chain.walks
             row = np.full(J, kv_cache.DEAD_BLOCK, np.int32)
             row[:len(plan.table)] = plan.table
@@ -1027,8 +1149,12 @@ class InferenceEngine:
         # (``ServedModel.freezes_in_chunk``); else the tail is cut there,
         # since such a state can only be frozen at the end of a program.
         tails = []
+        B = self.block_length
         for slot, group, plan, prompt, plen in plans:
-            cuts = [plan.matched, plen]
+            # (a model of blocks: as far as the last block boundary; the
+            # rest opens the stream's first block — ``activate_block``)
+            end = plen // B * B if B else plen
+            cuts = [min(plan.matched, end), end]
             if not self._freeze_in_chunk \
                     and plan.matched < plan.snapshot_at < plen:
                 cuts.insert(1, plan.snapshot_at)
@@ -1099,7 +1225,7 @@ class InferenceEngine:
                     # prompt's last token in the last chunk). Rows past it
                     # are padding a served model may skip.
                     last_idxs[group] = n - 1
-                    if ci == len(chunks) - 1:
+                    if ci == len(chunks) - 1 and not self.block_length:
                         held[slot] = (ci, group)
                         read = np.int32(1)
                     if ci == snap_in:
@@ -1334,7 +1460,19 @@ class InferenceEngine:
         ``ahead``: 1 when the dispatch went out while the iteration
         before was unfetched; ``dropped`` rows of the one fetched) >
         ``decode_tables``, ``decode_dispatch`` (a dispatch),
-        ``decode_fetch``, ``decode_advance`` (a fetch)."""
+        ``decode_fetch``, ``decode_advance`` (a fetch).
+
+        A model of blocks (``block_length``): an iteration is a pass over
+        every slot's block in progress. The tokens returned are ``[S,
+        B]``, the blocks the pass ran on (a committing slot's: its block's
+        final tokens), and in place of the mask the form that runs ahead
+        returns the reply tokens each slot is handed (``last_yield``, [S]
+        int: the block's positions past the prompt's tail for a slot whose
+        pass committed, else 0; the synchronous form leaves it there too).
+        Lengths advance by a block at the dispatch of a commit and by
+        nothing at a denoise pass; ``block_tokens`` / ``block_passes``
+        follow at the fetch. The span gains the model's ``block_rows``,
+        ``commits`` and ``unmasked`` of the pass fetched."""
         ahead = continuing is not None
         if ahead and return_logits:
             raise ValueError("decode_once: logits are fetched by the "
@@ -1374,6 +1512,8 @@ class InferenceEngine:
                 ahead=flight.ahead if flight is not None else 0,
                 dropped=due.dropped if due is not None else 0)
         if ahead:
+            if self.block_length and took is not None:
+                took = self.last_yield       # tokens a slot is handed
             return sampled, took
         out_logits = None
         if return_logits:
@@ -1390,10 +1530,13 @@ class InferenceEngine:
         """Dispatch one iteration over the slots in ``mask`` and advance
         the host's lengths and tables by it."""
         tl, lap = self.telemetry, self.serving.lap
+        B = self.block_length
+        K = B or 1                           # rows a slot
         with tl.span("decode_tables"):
             for s in np.flatnonzero(mask):
                 at = int(self.lengths[s])
-                self.allocator.extend(int(s), self.block_tables[s], at, at)
+                self.allocator.extend(int(s), self.block_tables[s], at,
+                                      at + K - 1)
             # What the execution is handed: COPIES (the host's arrays
             # move on under it), dead rows for the slots not in it, and
             # the host's token for a slot the execution before did not
@@ -1403,18 +1546,24 @@ class InferenceEngine:
                               np.int32(kv_cache.DEAD_BLOCK))
             fresh = np.ones_like(mask) if prev is None \
                 else self._fresh | ~prev.mask
-            steps = self._attend_steps(1, lengths, tables)
+            steps = self._attend_steps(K, lengths, tables)
+            yields = self.dispatch_yield = \
+                self._block_yields(mask, prev) if B else None
         lap("tables_s")
         with tl.span("decode_dispatch"):
             pools, fetch, logits, probes = self._outputs(self._decode_fn(
                 self._params, *self._pools(),
                 self._no_fetch if prev is None else prev.fetch,
-                self.last_tokens.copy(), fresh, lengths, tables,
+                (self._blocks if B else self.last_tokens).copy(),
+                fresh, lengths, tables,
                 self._next_key(), np.float32(temperature)))
             self._store_pools(pools)
             tl.raise_pending()
             self._fresh[:] = False
-            self.lengths[mask] += 1
+            if B:
+                self._advance_blocks(mask, yields > 0)
+            else:
+                self.lengths[mask] += 1
             live_blocks, cache_bytes, ctx_tokens = \
                 self._cache_accounting(mask)
             self.serving.note_attend_steps(*steps)
@@ -1422,7 +1571,7 @@ class InferenceEngine:
             if state:       # pages a stream: did their filter rows go
                 state["filter_rows_in_place"] = self.filter_rows_in_place
             if n_active:
-                self.serving.note_attend(*self._attend_work(1, mask),
+                self.serving.note_attend(*self._attend_work(K, mask),
                                          n_active)
             span.set_metadata(live_blocks=live_blocks,
                               context_tokens=ctx_tokens,
@@ -1435,7 +1584,42 @@ class InferenceEngine:
                        n_active=n_active, t0=t0,
                        prefill_s=self._prefill_wall,
                        ahead=int(prev is not None),
-                       cache_bytes=cache_bytes, context_tokens=ctx_tokens)
+                       cache_bytes=cache_bytes, context_tokens=ctx_tokens,
+                       yields=yields, lengths=lengths)
+
+    def _block_yields(self, mask: np.ndarray, prev: Optional[_Flight]
+                      ) -> np.ndarray:
+        """The reply tokens the pass about to be dispatched over the slots
+        in ``mask`` hands each slot ([S] int): the positions of its block
+        past its prompt's tail where the pass COMMITS the block, else 0.
+        Under the static rule the schedule says which pass commits, whatever
+        is still in flight; under the dynamic rule the host must have
+        fetched the pass before: a block commits once the host sees nothing
+        undecided in it."""
+        if self.served.runs_ahead:
+            commits = self._to_commit == 0
+        elif prev is not None:
+            raise RuntimeError(
+                "decode_once: under the dynamic unmasking rule a block's "
+                "commit is the device's news, so a pass cannot be "
+                "dispatched ahead of the fetch before it: fetch first "
+                "(the synchronous form)")
+        else:
+            commits = ~(self.block_tokens < 0).any(axis=1)
+        return np.where(mask & commits,
+                        self.block_length - self._block_tail,
+                        0).astype(np.int32)
+
+    def _advance_blocks(self, mask: np.ndarray, commits: np.ndarray) -> None:
+        """The host's lengths and schedule move with the dispatch of a pass
+        over ``mask`` of which ``commits`` commit their blocks: those grow
+        by a block and start the next one, all undecided."""
+        B = self.block_length
+        if self.served.runs_ahead:
+            self._to_commit[mask & ~commits] -= 1
+            self._to_commit[commits] = self.served.denoise_passes(B)
+        self._block_tail[commits] = 0
+        self.lengths[commits] += B
 
     def _decode_fetch(self, span, flight: _Flight) -> np.ndarray:
         """Fetch an iteration's tokens — the serving loop's one sync, the
@@ -1449,7 +1633,20 @@ class InferenceEngine:
         lap("fetch_s")
         with tl.span("decode_advance"):
             adv = flight.mask
-            self.last_tokens[adv] = sampled[adv]
+            if self.block_length:
+                # [the blocks the pass ran on | after it | their passes]
+                S, B = self.block_tokens.shape
+                sampled, after, passes = (
+                    sampled[:S * B].reshape(S, B),
+                    sampled[S * B:2 * S * B].reshape(S, B),
+                    sampled[2 * S * B:])
+                self.block_tokens[adv] = after[adv]
+                self.block_passes[adv] = passes[adv]
+                self.last_yield = np.where(adv, flight.yields, 0)
+                if self.on_block_pass is not None:
+                    self.on_block_pass(flight, sampled)
+            else:
+                self.last_tokens[adv] = sampled[adv]
             if self.drafter is not None:
                 for s in np.flatnonzero(adv):
                     self.drafter.observe(int(s), [int(sampled[s])])
@@ -1461,7 +1658,9 @@ class InferenceEngine:
             wall = end - start - (self._prefill_wall - prefill_s)
             self._t_tokens = (end, self._prefill_wall)
             self.iterations += 1
-            n_active, tokens = flight.n_active, int(adv.sum())
+            n_active = flight.n_active
+            tokens = int(self.last_yield.sum() if self.block_length
+                         else adv.sum())
             self.serving.note_iteration(
                 n_active, wall, cache_bytes=flight.cache_bytes,
                 context_tokens=flight.context_tokens,

@@ -96,7 +96,7 @@ def paged_classes(classes: Sequence[CacheClass], rows: Rows, pools, *,
     chunk's table.  Returns {class name: what ``write_and_attend`` takes}."""
     G, Sg, K = rows.positions.shape
     pos_g, live_g = rows.positions, rows.live.reshape(G, Sg, K)
-    seen = jnp.where(live_g, pos_g, -1)        # a dead row attends nothing
+    seen = jnp.where(live_g, rows.sees, -1)    # a dead row attends nothing
     n_rows = attend_rows(K, group)
     runs = K // n_rows
     out, at = {}, 0

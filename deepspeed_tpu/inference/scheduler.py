@@ -57,6 +57,10 @@ class Request:
     t_last: Optional[float] = None      # latest token produced
     admission_attempts: int = 0         # head-of-queue rejections
     flying: int = 0                     # tokens dispatched, not yet fetched
+    # A model of blocks hands a stream its tokens a block at a time: the
+    # clock at each block's commit and the tokens it brought.
+    block_times: List[Tuple[float, int]] = dataclasses.field(
+        default_factory=list, repr=False, compare=False)
     # Rows of the serving timeline (monitor/serving.py) this request was
     # live in: every live stream emits in every row between them
     # (``row_last`` stays -1 while it is).
@@ -109,7 +113,11 @@ class Request:
         ``t_first`` (out of its prefill), then the emission of every
         row it was live in (one token a row; under speculative decoding
         the 1..k+1 tokens of a row arrive together).  Rows the ring no
-        longer holds are left out."""
+        longer holds are left out.  A model of blocks: every token at its
+        block's time (the gap between tokens is 0 inside a block)."""
+        if self.block_times:
+            times, counts = zip(*self.block_times)
+            return np.repeat(np.asarray(times, float), counts)
         if self.t_first is None:
             return np.zeros(0)
         if self.row_first < 0:
@@ -191,6 +199,8 @@ class ContinuousBatchingScheduler:
             from ..monitor.request_trace import RequestTrace
             trace = RequestTrace()
         self.trace = trace
+        # Rows a stream's step writes: a model of blocks' block, else one.
+        self._step_rows = getattr(engine, "block_length", 0) or 1
 
     # ------------------------------------------------------------------ #
     def _finished(self, req: Request, slot_len: int) -> bool:
@@ -199,8 +209,8 @@ class ContinuousBatchingScheduler:
         if self.eos_token is not None and req.out_tokens and \
                 req.out_tokens[-1] == self.eos_token:
             return True
-        # Slot full: the next decode would have nowhere to write.
-        return slot_len >= self.engine.max_len
+        # Slot full: the next step would have nowhere to write.
+        return slot_len + self._step_rows > self.engine.max_len
 
     def _continues(self, req: Request, slot: int) -> bool:
         """Whether ``req`` takes part in the iteration dispatched next:
@@ -208,7 +218,8 @@ class ContinuousBatchingScheduler:
         its slot (the engine's lengths move at the dispatch, so they
         hold those already). An EOS among them cannot be known yet."""
         return len(req.out_tokens) + req.flying < req.max_new_tokens \
-            and self.engine.context_len(slot) < self.engine.max_len
+            and self.engine.context_len(slot) + self._step_rows \
+            <= self.engine.max_len
 
     def _leave_rows(self, req: Request) -> None:
         """The latest row is the last this request emitted in."""
@@ -248,6 +259,19 @@ class ContinuousBatchingScheduler:
         token): from here it emits in every row of the timeline."""
         eng = self.engine
         req.slot = slot
+        if self._step_rows > 1:
+            # A model of blocks: the prefill yields no token; the first
+            # arrive with the first block's commit (``_emit``).
+            req.out_tokens = []
+            eng.activate_block(slot, req.prompt)
+            eng.serving.note_prefill(len(req.prompt))
+            self._admit_trace(req, slot, t_first)
+            if self._finished(req, eng.context_len(slot)):
+                self._complete(req)
+                eng.release_slot(slot)
+            else:
+                active[slot] = req
+            return
         req.t_first = req.t_last = t_first
         req.out_tokens = [tok]
         eng.activate_slot(slot, len(req.prompt), tok)
@@ -265,7 +289,11 @@ class ContinuousBatchingScheduler:
             eng.serving.note_first_token(req.t_first, later)
             active[slot] = req
 
-    def _admit_trace(self, req: Request, slot: int) -> None:
+    def _admit_trace(self, req: Request, slot: int,
+                     t_prefilled: Optional[float] = None) -> None:
+        """The request's admission and prefill on its trace, and its first
+        token where the prefill yields it (``t_prefilled``: the prefill's
+        end for a model of blocks, whose first tokens come later)."""
         if self.trace is None:
             return
         eng = self.engine
@@ -273,44 +301,81 @@ class ContinuousBatchingScheduler:
                          replica=getattr(eng, "replica", "") or None)
         info_fn = getattr(eng, "last_admit_info", None)
         info = info_fn(slot) if info_fn is not None else {}
-        self.trace.prefill(req.rid, (req.t_first or req.t_admit)
+        self.trace.prefill(req.rid, (t_prefilled or req.t_first
+                                     or req.t_admit)
                            - req.t_admit, tokens=len(req.prompt),
                            chunks=info.get("chunks", 1),
                            cached_tokens=info.get("cached_tokens", 0),
                            cow_fork=info.get("cow_fork", False))
-        self.trace.first_token(req.rid, t=req.t_first)
+        if t_prefilled is None:
+            self.trace.first_token(req.rid, t=req.t_first)
 
     def _emit(self, sampled: np.ndarray, took: np.ndarray,
               active: Dict[int, Request]) -> None:
         """Hand an iteration's tokens to the streams that were in it
         (``took``; a fetched iteration is a row of the timeline even
         where every stream it held has ended since) and evict the ones
-        that finished."""
+        that finished.  A model of blocks: ``sampled`` is ``[S, B]`` and
+        ``took`` the tokens each slot is handed, the LAST ``took[slot]``
+        of its row (0 for a stream whose pass committed nothing: it is
+        not in this row of the timeline); a stream's first tokens are its
+        first block's, and the gap between its blocks goes to the
+        aggregator's block-gap histogram."""
         eng, tel, agg, trace = (self.engine, self.engine.telemetry,
                                 self.engine.serving, self.trace)
+        blocks = self._step_rows > 1
         with tel.span("emit") as span:
             slots = [slot for slot in active if took[slot]]
             occ = len(slots)
             t_now, row = agg.note_emit(occ)
-            finished = []
+            finished, gaps = [], []
             for slot in slots:
                 req = active[slot]
-                req.flying -= 1
-                req.out_tokens.append(int(sampled[slot]))
+                n = int(took[slot])
+                req.flying -= n
+                if blocks:
+                    self._take_block(req, sampled[slot, -n:], t_now, gaps)
+                else:
+                    req.out_tokens.append(int(sampled[slot]))
                 req.t_last = t_now
                 if trace is not None:
-                    trace.tick(req.rid, occ, 1, t=t_now, row=row)
-                # The engine's length holds the tokens in flight too.
-                if self._finished(req,
-                                  eng.context_len(slot) - req.flying):
+                    trace.tick(req.rid, occ, n, t=t_now, row=row)
+                # The engine's length holds the rows in flight too (a
+                # block's whole, where its commit is in flight).
+                flying = self._step_rows if blocks and req.flying \
+                    else req.flying
+                if self._finished(req, eng.context_len(slot) - flying):
                     self._complete(req)
                     eng.release_slot(slot)
                     del active[slot]
                     finished.append(req.rid)
+            if blocks:
+                agg.note_block_gaps(gaps)
             if spans_recorded(tel):
                 span.set_metadata(finished=ids_arg(finished),
                                   **agg.emit_args(occ))
+                if blocks:
+                    span.set_metadata(blocks=occ, block_gaps_ms=ids_arg(
+                        round(g * 1e3, 3) for g in gaps))
         agg.lap("emit_s")
+
+    def _take_block(self, req: Request, tokens, t_now: float,
+                    gaps: List[float]) -> None:
+        """A committed block's reply tokens reach ``req`` (no further than
+        its budget or an EOS among them); the time since its block before
+        goes into ``gaps``."""
+        toks = [int(t) for t in tokens]
+        if self.eos_token is not None and self.eos_token in toks:
+            toks = toks[:toks.index(self.eos_token) + 1]
+        toks = toks[:max(req.max_new_tokens - len(req.out_tokens), 0)]
+        if req.t_first is None:
+            req.t_first = t_now
+            if self.trace is not None:
+                self.trace.first_token(req.rid, t=t_now)
+        else:
+            gaps.append(t_now - req.t_last)
+        req.out_tokens.extend(toks)
+        req.block_times.append((t_now, len(toks)))
 
     # ------------------------------------------------------------------ #
     def serve(self, requests: Sequence[Request]) -> Dict[str, Any]:
@@ -517,12 +582,25 @@ class ContinuousBatchingScheduler:
                 # dispatch the next iteration for every stream whose
                 # reply the tokens in flight do not complete, THEN fetch
                 # and hand out the tokens of the iteration in flight.
+                if self._step_rows > 1 and not eng.served.runs_ahead:
+                    # Blocks under the dynamic rule: a commit is the
+                    # device's news, so every pass is fetched before the
+                    # next is dispatched.
+                    sampled, _ = eng.decode_once(self.temperature)
+                    for slot, req in active.items():
+                        req.flying += int(eng.last_yield[slot])
+                    self._emit(sampled, eng.last_yield, active)
+                    continue
                 going = [slot for slot, req in active.items()
                          if self._continues(req, slot)]
                 sampled, took = eng.decode_once(self.temperature,
                                                 continuing=going)
+                # (what the dispatch hands each slot at its fetch: a token,
+                # or what a model of blocks' pass commits)
+                yields = getattr(eng, "dispatch_yield", None)
                 for slot in going:
-                    active[slot].flying += 1
+                    active[slot].flying += 1 if yields is None \
+                        else int(yields[slot])
                 if took is not None:
                     self._emit(sampled, took, active)
             elif pending and not queue:

@@ -120,6 +120,12 @@ class ServedModel:
     # fixed-size state a stream cannot: ``verify`` refuses, and
     # ``inference.spec_k`` must be 0.
     rolls_back: bool = True
+    # A model generated by diffusion over blocks says how long its blocks
+    # are: a row then attends through the END of its block
+    # (``Rows.sees``), and a stream's step is a PASS over a block of this
+    # many rows (``block_step``), which the engine's ``decode_step`` is
+    # then built over.  None: a causal model, one token a stream a step.
+    block_length: Optional[int] = None
 
     def __init__(self, cfg):
         self.cfg = cfg
@@ -307,7 +313,8 @@ class ServedModel:
     def _rows_a_slot(self, params, pools, tokens, lengths, block_tables,
                      num_groups, paged_kernel, mesh):
         rows = Rows.of_slots(lengths, block_tables, tokens.shape[1],
-                             num_groups, self._widths(block_tables))
+                             num_groups, self._widths(block_tables),
+                             self.block_length)
         x, pools, counters, *probes = self._layers(
             params, pools, tokens, rows, paged_kernel, mesh)
         return (self.head(params, x), pools, counters, *probes)
@@ -347,7 +354,8 @@ class ServedModel:
         rows = Rows.of_chunk(
             bt_rows, start, last_idx, active, tokens.shape[1],
             self._widths(bt_rows),
-            None if freeze_idx is None else (freeze_idx, freeze_page))
+            None if freeze_idx is None else (freeze_idx, freeze_page),
+            self.block_length)
         x, pools, counters, *probes = self._layers(
             params, pools, tokens, rows, paged_kernel, mesh)
 
@@ -423,6 +431,12 @@ def write_targets(bt_g: jax.Array, pos_g: jax.Array, block_size: int
     return blk.reshape(G, Sg * K), off.reshape(G, Sg * K)
 
 
+def _block_end(pos: jax.Array, block_length: int) -> jax.Array:
+    """The last position of the block of ``block_length`` each of ``pos``
+    lies in."""
+    return pos // block_length * block_length + (block_length - 1)
+
+
 class Rows(NamedTuple):
     """What a program hands every layer, for S streams (``Sg`` a group) of
     K rows each: ``tables`` [G, Sg, W], the streams' table rows
@@ -430,7 +444,11 @@ class Rows(NamedTuple):
     writes land nowhere), the model's classes' columns side by side,
     ``widths`` wide (``cache_classes`` order); ``positions`` [G, Sg, K];
     ``live`` [S, K]: the rows that are traffic — a live stream's, and no
-    padding; a stream's live rows come first; ``chunked``: the rows are a
+    padding; a stream's live rows come first; ``sees`` [G, Sg, K]: the last
+    position each row may ATTEND, inclusive — its own (the same array as
+    ``positions``) under a causal mask; the end of its block of
+    ``block_length`` positions for a model whose rows see each other inside
+    a block (``ServedModel.block_length``); ``chunked``: the rows are a
     prefill chunk of one stream a group (a state advances by a scan over
     them) and not one row — or K drafted ones — of every slot; ``freeze``:
     (row [S], page [S]) — a stream's state as it stands after chunk row
@@ -441,33 +459,42 @@ class Rows(NamedTuple):
     widths: Tuple[int, ...]
     positions: jax.Array
     live: jax.Array
+    sees: jax.Array
     chunked: bool = False
     freeze: Optional[Tuple[jax.Array, jax.Array]] = None
 
     @classmethod
     def of_slots(cls, lengths, block_tables, K: int, num_groups: int,
-                 widths) -> "Rows":
+                 widths, block_length: Optional[int] = None) -> "Rows":
         """K rows of every slot: row i of slot s sits at ``lengths[s] + i``;
         a slot whose table ``[S, W]`` holds no block is dead."""
         pos = lengths[:, None] + jnp.arange(K, dtype=jnp.int32)[None]
         live = jnp.broadcast_to(
             (block_tables >= 0).any(axis=1, keepdims=True), pos.shape)
-        return cls(group_shape(block_tables, num_groups), widths,
-                   group_shape(pos, num_groups), live)
+        tables = group_shape(block_tables, num_groups)
+        pos = group_shape(pos, num_groups)
+        return cls(tables, widths, pos, live, pos if block_length is None
+                   else _block_end(pos, block_length))
 
     @classmethod
     def of_chunk(cls, bt_rows, start, last_idx, active, width: int, widths,
-                 freeze=None) -> "Rows":
+                 freeze=None, block_length: Optional[int] = None) -> "Rows":
         """A chunk of ``width`` rows of ONE slot a group, from position
         ``start``: a group that is not ``active`` is dead, rows past
-        ``last_idx`` are padding."""
+        ``last_idx`` are padding (and nothing sees them: a row of a block
+        the chunk's live rows end in sees no further than the last of
+        them)."""
         G = bt_rows.shape[0]
         pos = start[:, None] + jnp.arange(width, dtype=jnp.int32)[None]
         tables = jnp.where(active[:, None, None] > 0, bt_rows[:, None],
                            kv_cache.DEAD_BLOCK)
         live = (active[:, None] > 0) & (lax.broadcasted_iota(
             jnp.int32, (G, width), 1) <= last_idx[:, None])
-        return cls(tables, widths, pos[:, None, :], live, True, freeze)
+        pos = pos[:, None, :]
+        sees = pos if block_length is None else jnp.minimum(
+            _block_end(pos, block_length),
+            (start + last_idx)[:, None, None])
+        return cls(tables, widths, pos, live, sees, True, freeze)
 
     @staticmethod
     def last(x, last_idx) -> jax.Array:

@@ -205,6 +205,9 @@ class ServingAggregator:
         # the snapshot omits the sections (skip-never-fail downstream).
         self.ledger: Optional[Any] = None
         self.slo: Optional[Any] = None
+        # A model generated in blocks: the seconds between a stream's
+        # consecutive blocks (the gap between tokens is 0 inside one).
+        self._block_gap_s: List[float] = []
         self._ttft_ms: List[float] = []
         self._tpot_ms: List[float] = []
         self._queue_wait_ms: List[float] = []
@@ -363,6 +366,11 @@ class ServingAggregator:
             p[COL["cache_bytes"]] = int(cache_bytes)
             p[COL["context_tokens"]] = int(context_tokens)
         self._staged = self._last
+
+    def note_block_gaps(self, gaps_s: Sequence[float]) -> None:
+        """The streams handed a block now waited these seconds since their
+        block before (a model generated in blocks; the scheduler's)."""
+        self._block_gap_s.extend(gaps_s)
 
     def note_prefill(self, prompt_tokens: int) -> None:
         self.prefill_tokens += int(prompt_tokens)
@@ -619,6 +627,12 @@ class ServingAggregator:
                 "host": 1e3 * itl["host_s"] / n}
             snap["itl_stalled_share"] = round(itl["stalled"] / n, 4)
             snap["stalls"] = self.stalls(table)
+        if self._block_gap_s:
+            gaps = sorted(1e3 * g for g in self._block_gap_s)
+            snap["block_gap_ms"] = {
+                **{f"p{q}": round(percentile(gaps, q), 3)
+                   for q in (50, 95, 99)},
+                "mean": round(sum(gaps) / len(gaps), 3), "n": len(gaps)}
         if len(table):
             snap["lookahead_share"] = round(
                 float(table[:, COL["ahead"]].mean()), 4)
@@ -719,6 +733,7 @@ class ServingAggregator:
             # pooling the normalized rows keeps the mean meaningful
             # as "fraction of owned capacity busy".
             out._extend_rows(a._table())
+            out._block_gap_s.extend(a._block_gap_s)
             out._ttft_ms.extend(a._ttft_ms)
             out._tpot_ms.extend(a._tpot_ms)
             out._queue_wait_ms.extend(a._queue_wait_ms)

@@ -87,7 +87,12 @@ SCOPES = ("fwd_bwd", "grad_sync", "health_tap", "optimizer", "flatten",
           # several one-head groups a step) / la_chunk (prefill: the
           # chunked form), la_gate_norm, la_out
           "ck_write", "select", "attend_sparse", "la_proj",
-          "la_state_update", "la_chunk", "la_gate_norm", "la_out")
+          "la_state_update", "la_chunk", "la_gate_norm", "la_out",
+          # a model generated by diffusion over blocks (inference/sdar.py):
+          # in decode_step, behind lm_head: the proposals over the
+          # vocabulary (> sample), their confidences and the rule's choice
+          # of the positions a pass unmasks, for every slot's block
+          "unmask")
 # The host spans ``Telemetry.span`` opens (runtime/engine.py,
 # inference/engine.py, inference/scheduler.py), same contract.
 SPANS = ("train_batch", "data_prep", "step_dispatch", "offload_step",
@@ -193,14 +198,21 @@ SPAN_ARGS = {
                # (inference/kv_cache.py): the key rows the iteration may
                # read, summed over streams, classes and layers (a class
                # counts a stream's context as far as it reaches)
-               "context_tokens_in_reach"),
+               "context_tokens_in_reach",
+               # a model generated in blocks (inference/sdar.py), of the
+               # pass FETCHED: rows it computed for live streams, slots
+               # whose block it committed, positions it unmasked
+               "block_rows", "commits", "unmasked"),
     # The emission's row of the serving timeline (monitor/serving.py),
     # the streams it hands tokens to and those of them that waited the
     # whole interval since the emission before, that interval, the part
     # of it in other requests' prefill and copies, and the part on the
     # host outside decode_dispatch + decode_fetch.
+    # A model generated in blocks: ``streams`` are those handed a BLOCK
+    # in this row (``blocks``), and ``block_gaps_ms`` the time since each
+    # one's block before, in ms, joined by spaces.
     "emit": ("finished", "row", "streams", "continuing", "gap_ms",
-             "stall_ms", "host_ms"),
+             "stall_ms", "host_ms", "blocks", "block_gaps_ms"),
     "serve_idle": ("why",)}
 # ... and the args such a model adds ONE A CLASS, under the names its
 # ``ServedModel.cache_classes`` declare (``<class>`` below; this file knows

@@ -191,12 +191,35 @@ def _minicpm_sala():
 ADDED = {"minicpm_sala": _minicpm_sala}
 
 
+def _sdar():
+    """``test_sdar_serving.tiny``: blocks of 4 positions in pages of 8, two
+    denoising steps under the static rule; the scenario's prompt of 19
+    tokens is prefilled as far as 16 and its last 3 open the first block."""
+    from deepspeed_tpu.models.sdar import SdarConfig, sdar_init
+    cfg = SdarConfig(
+        vocab_size=128, hidden_size=64, intermediate_size=96,
+        moe_intermediate_size=32, num_hidden_layers=2,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        num_experts=8, num_experts_per_tok=2, max_position_embeddings=256,
+        block_length=4, mask_token_id=127, denoising_steps=2,
+        remasking="low_confidence_static", dtype=jnp.float32,
+        initializer_range=0.3)
+    return cfg, sdar_init(jax.random.PRNGKey(0), cfg), {
+        "block_size": 8, "num_blocks": 64}
+
+
+# PR 62's: a model generated in blocks (its ``decode_step`` is a pass over a
+# block of every slot; ``served_bytes`` drives it by passes).
+ADDED_BY_PR62 = {"sdar": _sdar}
+
+
 def engine(family: str, kernel: bool, dp: int = 1, **extra):
     """A tiny engine of ``family`` on ``dp`` host devices (``extra``: more
     top-level config blocks, ``telemetry``)."""
     from deepspeed_tpu.inference import InferenceEngine
     from deepspeed_tpu.parallel.topology import build_mesh
-    cfg, params, inference = {**FAMILIES, **ADDED}[family]()
+    cfg, params, inference = {**FAMILIES, **ADDED,
+                              **ADDED_BY_PR62}[family]()
     conf = dict(max_slots=4, max_seq_len=128, block_size=4,
                 prefill_chunk=CHUNK, paged_kernel=kernel)
     conf.update(inference)
@@ -245,7 +268,7 @@ def programs_and_bytes(eng) -> tuple:
     eng._decode_fn = decode = _Once(eng._build_decode_step(), len(head))
     eng._prefill_fn = prefill = _Once(eng._build_prefill_step(), len(head))
     served = served_bytes(eng)
-    texts = {"decode_step": decode.texts[S + len(eng.served.counter_names)]}
+    texts = {"decode_step": decode.texts[eng._no_fetch.shape[0]]}
     head = (eng._params, *eng._pools())
     for width in eng.prefill_widths:
         texts[f"prefill_step_{width}"] = prefill.texts.get(width) or \
@@ -294,6 +317,20 @@ def served_bytes(eng) -> bytes:
     slot = eng.select_slot(prompt, 4)
     tok, logits = eng.prefill(prompt, slot, return_logits=True,
                               max_new_tokens=4)
+    if eng.block_length:
+        # A model of blocks: two chunks as far as the last block boundary,
+        # then four passes (a first block of one undecided position: a
+        # denoise pass and its commit; two denoise passes of the next):
+        # each pass's input block and its logits.
+        assert eng.last_admit_info(slot)["chunks"] == 2 and tok is None
+        eng.activate_block(slot, prompt)
+        parts = []
+        for _ in range(4):
+            blocks, step_logits = eng.decode_once(0.0, return_logits=True)
+            parts += [np.asarray(blocks[slot], np.int32).tobytes(),
+                      np.asarray(step_logits)[slot].tobytes()]
+        eng.release_slot(slot)
+        return b"".join(parts)
     assert eng.last_admit_info(slot)["chunks"] == 3
     eng.activate_slot(slot, len(prompt), tok)
     parts = [np.int32(tok).tobytes(), np.asarray(logits).tobytes()]
@@ -334,8 +371,10 @@ def golden(family: str, arm: str) -> dict:
 
 if __name__ == "__main__":
     DUMP = sys.argv[2] if len(sys.argv) > 2 else None
-    # (``ADDED`` as a third argument: the families later PRs added alone)
-    names = ADDED if "ADDED" in sys.argv[3:] else FAMILIES
+    # (``ADDED`` as a third argument: the families later PRs added alone;
+    # ``PR62``: the family PR 62 added)
+    names = ADDED if "ADDED" in sys.argv[3:] else \
+        ADDED_BY_PR62 if "PR62" in sys.argv[3:] else FAMILIES
     out = {family: {arm: golden(family, arm) for arm in ARMS}
            for family in sorted(names)}
     with open(sys.argv[1], "w") as f:

@@ -1291,3 +1291,105 @@ def test_the_selections_counters_ride_the_fetch_onto_the_spans(sala_engine):
     # decode at 101-106 tokens: 7 blocks in reach, 4 read
     assert c["sparse_blocks_read"] >= 4 * 2 * 2
     assert c["ck_rows_scored"] > 0
+
+
+# --------------------------------------------------------------------- #
+# (n) a model generated by diffusion over blocks (PR 62): the ``unmask``
+# scope in the block step and in no chunk program; what a pass computed,
+# committed and unmasked on the ``decode`` span; the blocks an ``emit``
+# hands out and the gaps between a stream's blocks
+# --------------------------------------------------------------------- #
+@pytest.fixture(scope="module")
+def blocks_engine():
+    from test_sdar_serving import CFG, PARAMS, engine_of
+    eng = engine_of(CFG, PARAMS, kernel=True)
+    yield eng
+    eng.close()
+
+
+@pytest.fixture(scope="module")
+def blocks_op_names(blocks_engine):
+    eng = blocks_engine
+    G, W = eng.dp, eng.allocator.table_width
+    key, temp = eng._next_key(), np.float32(0.0)
+    return {
+        "decode": _op_names(
+            eng._decode_fn, eng._params, *eng._pools(), eng._no_fetch,
+            np.zeros((eng.max_slots, eng.block_length + 1), np.int32),
+            np.ones(eng.max_slots, bool), eng.lengths, eng.block_tables,
+            key, temp),
+        "prefill": _op_names(
+            eng._prefill_fn, eng._params, *eng._pools(),
+            np.zeros((G, eng.prefill_chunk), np.int32),
+            np.zeros((G, W), np.int32), np.zeros(G, np.int32),
+            np.zeros(G, np.int32), np.ones(G, np.int32), np.int32(0), key,
+            temp)}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("scope", [
+    "embed", "attn/qkv_proj", "attn/kv_write", "attn/attend_full",
+    "attn/out_proj", "moe/router", "moe/dispatch", "moe/experts",
+    "moe/combine", "lm_head"])
+def test_block_program_carries_scope(blocks_op_names, program, scope):
+    assert any(f"/{scope}" in n for n in blocks_op_names[program]), \
+        (program, scope)
+
+
+def test_unmask_belongs_to_the_block_step_alone(blocks_op_names):
+    """The block's update (and the proposals' ``sample`` inside it) is the
+    block step's; a chunk program samples nothing and has no shared expert
+    or dense layer."""
+    from deepspeed_tpu.monitor.xplane_reader import (SCOPES, SPAN_ARGS,
+                                                     scope_of)
+    names = blocks_op_names
+    assert any("/unmask" in n for n in names["decode"])
+    assert any("/unmask/sample" in n for n in names["decode"])
+    assert not any("/unmask" in n for n in names["prefill"])
+    for program in names.values():
+        assert not any("/mlp" in n or "/moe/shared" in n for n in program)
+    assert "unmask" in SCOPES
+    assert scope_of("jit(decode_step)/unmask/reduce_max")[0] == ("unmask",)
+    assert {"block_rows", "commits", "unmasked"} <= set(SPAN_ARGS["decode"])
+    assert {"blocks", "block_gaps_ms"} <= set(SPAN_ARGS["emit"])
+
+
+def test_block_spans_carry_what_a_pass_did(tmp_path, blocks_engine):
+    """Three requests through the scheduler: every fetched pass's rows,
+    commits and unmasked positions on its ``decode`` span (2 positions a
+    denoise pass of a whole block, none at a commit); every ``emit`` the
+    blocks it handed out and, for a stream's later blocks, the gap since its
+    block before."""
+    from deepspeed_tpu.monitor.xplane_reader import span_args
+    eng = blocks_engine
+    eng.reset_serving_stats()
+    rng = np.random.default_rng(2)
+    reqs = [Request(rid=i, prompt=rng.integers(0, 120, size=n,
+                                               dtype=np.int32),
+                    max_new_tokens=12, arrival_s=0.0)
+            for i, n in enumerate((16, 24, 9))]
+    report = {}
+    found = _session(tmp_path, lambda: report.update(eng.serve(reqs)))
+    names = [c.name for c in eng.served.cache_classes]
+    fetched = [a for _, _, a in found["decode"] if "block_rows" in a]
+    assert fetched and all(set(a) <= set(span_args("decode", names))
+                           for _, _, a in found["decode"])
+    B = eng.block_length
+    for a in fetched:
+        assert a["block_rows"] % B == 0 and 0 <= a["commits"] \
+            <= a["block_rows"] // B
+        assert a["unmasked"] <= 2 * (a["block_rows"] // B - a["commits"])
+        assert a["moe_held_pairs"] == 2 * 2 * a["block_rows"]
+    blocks = sum(a["commits"] for a in fetched)
+    assert blocks == 3 + 3 + 4       # 12 tokens; the third behind a tail of 1
+    assert sum(a["unmasked"] for a in fetched) == 3 * 12 + 3
+    emits = [a for _, _, a in found["emit"]]
+    assert sum(a["blocks"] for a in emits) == blocks
+    gaps = [float(g) for a in emits
+            for g in str(a.get("block_gaps_ms", "")).split()]
+    assert len(gaps) == blocks - 3 and min(gaps) > 0
+    assert report["block_gap_ms"]["n"] == len(gaps)
+    assert all(a["streams"] == a["blocks"] for a in emits)
+    prefills = [a for _, _, a in found["prefill"]]
+    assert prefills and all("block_rows" not in a for a in prefills)
+    assert all(a["head"] == 0 for _, _, a in found["prefill_chunk"])

@@ -57,6 +57,12 @@ LEFT_BY_PR58 = json.load(open(os.path.join(DATA, "program_text_pr58.json")))
 # own PR: ``python tests/decode_step_hlo.py OUT.json DIR ADDED``.
 ADDED_BY_PR59 = json.load(open(os.path.join(DATA, "program_text_pr59.json")))
 LEFT_BY_PR60 = json.load(open(os.path.join(DATA, "program_text_pr60.json")))
+# ... and the family PR 62 added (``harness.ADDED_BY_PR62``: ``python
+# tests/decode_step_hlo.py OUT.json DIR PR62``).  PR 62 gave ``Rows`` each
+# row's last attendable position as a field of its own: for every family
+# above it IS ``positions``, the same array, so their programs are text for
+# text what they were and no ``MOVED_*`` gained an entry.
+ADDED_BY_PR62 = json.load(open(os.path.join(DATA, "program_text_pr62.json")))
 
 # Why a program's operations are not, line for line, the ones PR 55 lowered
 # (CHANGES.md, PR 56, quotes the lines).  Where the seven copies of the
@@ -193,6 +199,36 @@ def test_an_added_familys_programs_are_what_its_pr_left(family, kind, arm):
             f"{family}.{arm}.{name}: other operations than {left_by} left")
 
 
+@pytest.mark.parametrize("arm", sorted(harness.ARMS))
+@pytest.mark.parametrize("kind", ["decode_step", "prefill_step", "outputs",
+                                  "verify_step"])
+@pytest.mark.parametrize("family", sorted(harness.ADDED_BY_PR62))
+def test_the_family_of_blocks_is_what_its_pr_left(family, kind, arm):
+    """PR 62's family (generation by diffusion over blocks): the bits its PR
+    computed — two prefill chunks under the block mask, then four passes
+    over the stream's block — and the text its PR lowered; ``verify``
+    refuses (nothing is drafted in a model of blocks)."""
+    got, want = harness.golden(family, arm), ADDED_BY_PR62[family][arm]
+    if kind == "outputs":
+        assert got["outputs"] == want["outputs"]
+        return
+    names = sorted(n for n in want["programs"] if n.startswith(kind))
+    assert names == sorted(n for n in got["programs"] if n.startswith(kind))
+    if kind == "verify_step":
+        assert not names
+        with pytest.raises(NotImplementedError,
+                           match="rolled back.*spec_k"):
+            served_model(harness.ADDED_BY_PR62[family]()[0]).verify(
+                None, None, None, None, None, num_groups=1,
+                paged_kernel=False)
+        return
+    assert names
+    for name in names:
+        assert got["programs"][name]["order_free"] \
+            == want["programs"][name]["order_free"], (
+            f"{family}.{arm}.{name}: other operations than PR 62 left")
+
+
 @pytest.mark.parametrize("families,was,now,moved_by", [
     (harness.FAMILIES, GOLDEN, LEFT_BY_PR56,
      lambda family, arm: MOVED.get(family, {})),
@@ -243,6 +279,13 @@ def test_rows_of_slots_against_numpy(K, groups):
         rows.live, np.repeat([[True], [False], [True], [True]], K, axis=1))
     assert rows.widths == (3, 2) and not rows.chunked \
         and rows.freeze is None
+    # a causal model's rows see as far as themselves: the SAME array
+    assert rows.sees is rows.positions
+    blocks = Rows.of_slots(jnp.asarray(lengths), jnp.asarray(tables), K,
+                           groups, (3, 2), block_length=4)
+    np.testing.assert_array_equal(
+        blocks.sees, (want // 4 * 4 + 3).reshape(groups, Sg, K))
+    np.testing.assert_array_equal(blocks.positions, rows.positions)
 
 
 @pytest.mark.parametrize("freeze", [False, True])
@@ -268,6 +311,14 @@ def test_rows_of_a_chunk_against_numpy(freeze):
     assert not np.asarray(rows.live)[1, 3:].any()     # padding: not traffic
     assert not np.asarray(rows.live)[2].any()         # the inactive group
     assert (rows.freeze is pair) if freeze else (rows.freeze is None)
+    assert rows.sees is rows.positions
+    blocks = Rows.of_chunk(jnp.asarray(bt), jnp.asarray(start),
+                           jnp.asarray(last_idx), jnp.asarray(active), C,
+                           (W,), pair if freeze else None, block_length=4)
+    # the end of a row's block, no further than the chunk's last live row
+    np.testing.assert_array_equal(
+        blocks.sees[:, 0], np.minimum(want // 4 * 4 + 3,
+                                      (start + last_idx)[:, None]))
 
 
 @pytest.mark.parametrize("trailing", [(6,), (4, 6)])

@@ -2074,3 +2074,127 @@ def test_sparse_beside_state_fit_and_are_updated_in_place(
                   "/attn/la_state_update" if program == "decode_step"
                   else "/attn/la_chunk", "/mlp", "/lm_head"):
         assert scope in text, scope
+
+
+# ------------------------------------------------------------------ #
+# A step that is a pass over a block of 4 rows a slot, written through the
+# paged cache and attended under block-causal limits, the head over S x B
+# rows: SDAR-30B-A3B (PR 62) at the benchmark cell's shape
+# ------------------------------------------------------------------ #
+@pytest.fixture(scope="module")
+def block_step_programs(topo):
+    """(spec, params bytes, {program: compiled}) of the engine's own step
+    builders for ``perfbench/configs/sdar-30b-a3b-chat.json`` on an engine
+    shell: ``decode_step`` (the block step) and ``prefill_step`` at every
+    width of ``prefill_widths``."""
+    import json
+    import os
+    from deepspeed_tpu.inference import kv_cache
+    from deepspeed_tpu.inference.engine import (InferenceEngine,
+                                                prefill_widths)
+    from deepspeed_tpu.inference.served import served_model
+    from deepspeed_tpu.models.sdar import SdarConfig, sdar_init
+    from jax.experimental.compilation_cache import compilation_cache
+    sizes = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "perfbench", "configs", "sdar-30b-a3b-chat.json")))
+    inf = sizes["serve"]["inference"]
+    cfg = SdarConfig.from_hf(sizes, denoising_steps=2,
+                             remasking="low_confidence_static")
+    served = served_model(cfg)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+            tree)
+    params = on_chip(jax.eval_shape(lambda k: sdar_init(k, cfg),
+                                    jax.random.PRNGKey(0)))
+    param_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(params))
+    specs = kv_cache.class_specs(
+        served.cache_classes, inf["num_blocks"], rows=inf["prefill_chunk"],
+        of_class=lambda c: served.class_geometry(c, inf["block_size"]),
+        num_slots=inf["max_slots"], block_size=inf["block_size"],
+        max_len=inf["max_seq_len"], num_groups=1, dtype=jnp.bfloat16)
+    served.table_widths = tuple(sp.max_blocks_per_slot for sp in specs)
+    eng = object.__new__(InferenceEngine)
+    eng.model_cfg, eng.dp, eng.sp, eng.mesh = served, 1, 1, None
+    eng.paged_kernel, eng.quantize = True, "none"
+    eng.prefill_chunk = inf["prefill_chunk"]
+    eng._cache_sh = {n: one for sp in specs for n in sp.pool_names}
+    pools = [on_chip(jax.ShapeDtypeStruct(sp.pool_shapes[n], sp.dtype))
+             for sp in specs for n in sp.pool_names]
+    S, J, B = inf["max_slots"], sum(served.table_widths), cfg.block_length
+    i32 = lambda *shape: on_chip(jax.ShapeDtypeStruct(shape, jnp.int32))
+    fresh = lambda n: on_chip(jax.ShapeDtypeStruct((n,), jnp.bool_))
+    key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
+    temp = on_chip(jax.ShapeDtypeStruct((), jnp.float32))
+    mp = pytest.MonkeyPatch()
+    prev = jax.config.jax_enable_compilation_cache
+    out = {}
+    try:
+        mp.setattr(jax, "default_backend", lambda *a, **k: "tpu")
+        mp.setenv("DS_AUTOTUNE", "0")
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        out["decode_step"] = eng._build_decode_step().lower(
+            params, *pools,
+            i32(S * (2 * B + 1) + len(served.counter_names)),
+            i32(S, B + 1),
+            fresh(S), i32(S), i32(S, J), key, temp).compile()
+        for C in prefill_widths(inf["prefill_chunk"], inf["block_size"]):
+            out[f"prefill_step.{C}"] = eng._build_prefill_step().lower(
+                params, *pools, i32(1, C), i32(1, J), i32(1), i32(1), i32(1),
+                i32(), key, temp).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+        mp.undo()
+    return specs[0], param_bytes, out
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_step.256",
+                                     "prefill_step.512"])
+def test_block_step_fits_and_writes_its_rows_in_place(block_step_programs,
+                                                      program):
+    """Weights 8.72 GB (128 of 128 experts in all 6 layers, 151,936
+    vocabulary rows, untied head) + K/V pools 3.62 GB (4,608 blocks x 6
+    layers behind a table of 48), both pools aliased to their outputs, the
+    step's scratch — 0.62 GB of fp32 logits for 256 x 4 rows among it —
+    inside what is left of the chip's 16 GiB; the attend at 8 query heads x
+    4 rows a K/V head, the row write and the grouped product TPU custom
+    calls, six of each a program; no K/V-pool-sized op and no layer's
+    experts (0.6 GB) copied; the block's update named under ``unmask`` in
+    the block step and in no chunk program."""
+    from deepspeed_tpu.analysis.hlo_text import ops_in_units_of
+    spec, param_bytes, programs = block_step_programs
+    compiled = programs[program]
+    assert param_bytes == 2 * 4_361_055_744
+    assert spec.max_blocks_per_slot == 48 and spec.name == "full"
+    assert spec.nbytes() == 6 * 4608 * 64 * 2048
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= spec.nbytes()
+    assert param_bytes + spec.nbytes() + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes \
+        < 15.75 * 2 ** 30, (mem.temp_size_in_bytes,
+                            mem.output_size_in_bytes)
+    text = compiled.as_text()
+    for kernel in ("_pattn_kernel", "_kv_write_kernel", "_gswiglu_kernel"):
+        calls = [line for line in text.splitlines()
+                 if f"%{kernel}" in line.split(" = ")[0]
+                 and " custom-call(" in line]
+        assert len(calls) == 6 \
+            and all("tpu_custom_call" in c for c in calls), kernel
+    seen = ops_in_units_of(text, math.prod(spec.pool_shapes["k.full"][2:]))
+    assert not [(op, n) for op, n in seen
+                if op not in _POOL_OPS_ALLOWED], seen
+    one_layers_experts = 128 * 768 * 2048
+    assert not [(op, n) for op, n in ops_in_units_of(
+        text, one_layers_experts) if op not in ("parameter", "bitcast",
+                                                 "get-tuple-element")]
+    for scope in ("/moe/router", "/moe/dispatch", "/moe/experts",
+                  "/moe/combine", "/attn/attend_full", "/attn/qkv_proj",
+                  "/attn/kv_write", "/attn/out_proj", "/lm_head"):
+        assert scope in text, scope
+    assert ("/unmask" in text) == (program == "decode_step")
