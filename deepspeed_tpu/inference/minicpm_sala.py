@@ -38,7 +38,11 @@ Scopes: ``embed``; ``attn`` > ``qkv_proj``, ``kv_write``, ``ck_write``,
 ``la_out`` (a Lightning layer); ``mlp``; ``lm_head``.  Counters (they ride
 the token fetch): ``sparse_blocks_read`` and ``sparse_blocks_in_reach`` (per
 live row, sparse layer and K/V head: blocks the attend walked / blocks a
-dense attend would), ``ck_rows_scored`` (pooled rows the selection scored).
+dense attend would), ``ck_rows_scored`` (pooled rows the selection scored),
+``ck_blocks_read`` (blocks of pooled keys the selection gathered to score
+them, a sparse layer and K/V head: a sharing group's table row once a tile
+of its streams where a decode step's tables share, every stream's table
+width where not).
 """
 from __future__ import annotations
 
@@ -99,7 +103,7 @@ class MinicpmSalaServed(GqaPagedServed):
     family's own are the pooled keys, the selection, the per-head attend and
     the Lightning state."""
     counter_names = ("sparse_blocks_read", "sparse_blocks_in_reach",
-                     "ck_rows_scored")
+                     "ck_rows_scored", "ck_blocks_read")
     # What the selection chose INSIDE the program, a row: pool block ids
     # ``[sparse layers, nKV, chosen_width]`` and how many of them are live
     # ``[sparse layers, nKV]`` (a check holds them to the reference's sets).
@@ -169,6 +173,7 @@ class MinicpmSalaServed(GqaPagedServed):
         read, reach = int(rows[:, 0].sum()), int(rows[:, 1].sum())
         return {"sparse_blocks_read": read, "sparse_blocks_in_reach": reach,
                 "ck_rows_scored": int(rows[:, 2].sum()),
+                "ck_blocks_read": int(rows[:, 3].sum()),
                 "sparse_read_share": read / reach if reach else 0.0}
 
     # -- the block ------------------------------------------------------ #
@@ -203,7 +208,8 @@ class MinicpmSalaServed(GqaPagedServed):
         n_sparse = len(cfg.sparse_layers)
         counters = [jnp.zeros((), jnp.int32),
                     (newest.sum() * nKV * n_sparse).astype(jnp.int32),
-                    (seen.sum() * nKV * n_sparse).astype(jnp.int32)]
+                    (seen.sum() * nKV * n_sparse).astype(jnp.int32),
+                    jnp.zeros((), jnp.int32)]
 
         q_rows = math.gcd(sz.block, K)
         page = rows.tables[:, :, w_sparse].reshape(S)
@@ -234,10 +240,11 @@ class MinicpmSalaServed(GqaPagedServed):
                             pos[:, i].reshape(G, Sg),
                             live[:, i].reshape(G, Sg), sz)
             with jax.named_scope("select"):
-                chosen, count = sparse_select.select_blocks(
+                chosen, count, read = sparse_select.select_blocks_counted(
                     q, pools[CK], layer, table.reshape(S, w_sparse), pos,
                     live, sz, cfg.softmax_scale)
                 counters[0] = counters[0] + count.sum().astype(jnp.int32)
+                counters[3] = counters[3] + read
                 picked.append((chosen, count))
             with jax.named_scope("attend_sparse"):
                 width = chosen.shape[-1]

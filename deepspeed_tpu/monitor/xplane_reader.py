@@ -154,9 +154,10 @@ SPAN_ARGS = {
                 # (on a prefill span: the chunk program that ENDED the
                 # prompt): blocks the attend walked and blocks a dense
                 # attend would, each per live row, sparse layer and K/V
-                # head; their ratio; pooled rows the selection scored
+                # head; their ratio; pooled rows the selection scored and
+                # blocks of pooled keys it gathered to score them
                 "sparse_blocks_read", "sparse_blocks_in_reach",
-                "sparse_read_share", "ck_rows_scored",
+                "sparse_read_share", "ck_rows_scored", "ck_blocks_read",
                 "resumed_tokens", "snapshot_taken", "snapshot_in_program",
                 "state_copy_bytes",
                 "prefix_lost_to_kind_tokens"),
@@ -181,7 +182,7 @@ SPAN_ARGS = {
                "hc_res_err_max",
                # (see "prefill": the iteration FETCHED)
                "sparse_blocks_read", "sparse_blocks_in_reach",
-               "sparse_read_share", "ck_rows_scored",
+               "sparse_read_share", "ck_rows_scored", "ck_blocks_read",
                # pages of a per-stream pool the state-update kernel
                # rewrote in this execution (one a live stream)
                "state_pages_live",

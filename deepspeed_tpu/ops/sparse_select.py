@@ -29,13 +29,38 @@ ascending order.  Returned through the stream's table as POOL block ids, a
 row and K/V head: ``[rows, nKV, width]`` with ``width = max(topk, dense_len /
 block)``, dead slots ``-1``, and the live count.
 
-All of it is plain ``jax.numpy``: the pooled keys are gathered through the
-table (``[streams, table width * R, nKV, D]``) and scored by one product, a
-batch of streams or of a chunk's rows at a time (``lax.map``) so that the
-fp32 scores of 256 streams x 32 heads x 8k pooled keys never stand whole;
-their block maxima do (4 MB), and the ``topk`` largest of each row are found
-once a program, by a threshold and a count (``choose``: no sort).
-fp32 scores, softmax, sums and order; what it reads is the pool's dtype.
+All of it is plain ``jax.numpy``, and the fp32 scores of 256 streams x 32
+heads x 8k pooled keys never stand whole (a tile or a batch at a time under
+``lax.map``); their block maxima do (4 MB), and the ``topk`` largest of each
+row are found once a program, by a threshold and a count (``choose``: no
+sort).  fp32 scores, softmax, sums and order; what it reads is the pool's
+dtype.
+
+**How the pooled keys are read.**  A chunk's rows (``K > 1``) are ONE
+stream's: its pooled rows are gathered through its table once and scored a
+batch of rows at a time.  A decode step's rows (``K == 1``) are a stream
+each, and streams of one document hold the SAME blocks at the same leading
+slots of their tables (the prefix cache shares whole leading blocks), so the
+step reads its tables before its keys (``_shared_plan``, on the device: the
+live streams of one pool group whose slot 0 agrees are a sharing group, its
+shared length the leading slots on which every member agrees with the
+longest), deals each group's streams into tiles of ``_TILE_STREAMS`` and,
+a tile (``_tile_scores``, a step of a loop of as many steps as the tables
+need tiles): gathers the group's pooled keys ``[W, nKV, R, D]`` through
+that table row ONCE for the tile's streams, contracts them with the tile's
+``32 x group`` query rows a K/V head in one product, and reads per stream
+only the ``_TAIL_SLOTS``
+slots from the shared length on (a question, a reply, a block copied on
+write), at the stream's own logical blocks — one softmax a query head over
+both parts, the last shared block's score taking the first pooled row of
+the stream's own next block.  Where the tables say there is too little to
+share — more tiles than ``_max_tiles``, or a tail longer than the
+bound — a ``lax.cond`` takes the per-stream arm: every stream's pooled rows
+through its whole table, ``_BATCH_STREAMS`` at a time.  One algorithm chosen
+by its input; the scores agree up to the order of an fp32 sum and the sets
+to the element (``tests/test_sparse_select_shared.py``).
+``select_blocks_counted`` also returns the blocks of pooled keys it gathered
+(the counter ``ck_blocks_read``).
 """
 from __future__ import annotations
 
@@ -46,7 +71,17 @@ import jax.numpy as jnp
 from jax import lax
 
 DEAD_BLOCK = -1
-_BATCH_STREAMS = 32         # decode: streams scored at once
+_BATCH_STREAMS = 16         # decode: streams scored at once, a stream's
+                            # pooled keys each (10.4 ms a layer at 256
+                            # streams x 2,072 slots; 11.8 at 8, 12.2 at 32,
+                            # 11.9 at 64: PERF.md section 6, PR 63)
+_TILE_STREAMS = 32          # decode: streams of one sharing group scored in
+                            # one product, a step of a loop (a step costs
+                            # ~5 us of its own whatever it holds: 1.70 ms a
+                            # layer at 32, 1.61 at 16, 1.65 at 8; PERF.md
+                            # section 6, PR 63)
+_TAIL_SLOTS = 32            # decode: a stream's own slots past what its
+                            # group shares (a question and a reply: 2k tokens)
 _BATCH_ROWS = 128           # prefill: a chunk's rows scored at once
 
 
@@ -167,21 +202,38 @@ def write_pooled_rows(ck, pool_k, layer, table, pos, live, sz: Sizes):
 # --------------------------------------------------------------------- #
 # Selection
 # --------------------------------------------------------------------- #
-def block_scores(q, pooled, pos, sz: Sizes, scale: float):
-    """``B [rows, nKV, W]`` (fp32; -1 for a block past the newest, +inf
-    for a forced one) of query rows q ``[rows, nKV, group, D]`` at
-    positions ``pos`` [rows] against one stream's pooled rows ``[W, nKV, R,
-    D]`` (global row ``W-index * R + R-index``).  bf16 operands are
-    contracted as they are (their products are exact in fp32), fp32 ones at
-    the highest precision."""
-    W, _, R, _ = pooled.shape
+def _contract(spec: str, q, pooled):
+    """Queries against pooled keys, fp32 out: bf16 operands are contracted
+    as they are (their products are exact in fp32), fp32 ones at the
+    highest precision."""
     f32 = jnp.float32
     if q.dtype == jnp.bfloat16 and pooled.dtype == jnp.bfloat16:
         how = dict(preferred_element_type=f32)
     else:
         q, pooled = q.astype(f32), pooled.astype(f32)
         how = dict(precision=lax.Precision.HIGHEST)
-    s = jnp.einsum("tnmd,wnrd->tnmwr", q, pooled, **how) * scale
+    return jnp.einsum(spec, q, pooled, **how)
+
+
+def _dress(score, pos, sz: Sizes):
+    """Raw block scores ``[rows, nKV, W]`` with the rule's ends: ``+inf`` for
+    a forced block (the first ``init_blocks``, the newest
+    ``window_blocks``), -1 for a block past the newest."""
+    b = jnp.arange(score.shape[-1], dtype=jnp.int32)
+    newest = (pos // sz.block)[:, None]
+    forced = (b[None] < sz.init_blocks) | (b[None] > newest
+                                           - sz.window_blocks)
+    score = jnp.where(forced[:, None], jnp.inf, score)
+    return jnp.where((b[None] <= newest)[:, None], score, -1.0)
+
+
+def block_scores(q, pooled, pos, sz: Sizes, scale: float):
+    """``B [rows, nKV, W]`` (fp32; -1 for a block past the newest, +inf
+    for a forced one) of query rows q ``[rows, nKV, group, D]`` at
+    positions ``pos`` [rows] against one stream's pooled rows ``[W, nKV, R,
+    D]`` (global row ``W-index * R + R-index``)."""
+    W, _, R, _ = pooled.shape
+    s = _contract("tnmd,wnrd->tnmwr", q, pooled) * scale
     s = s.reshape(s.shape[:3] + (W * R,))
     g = jnp.arange(W * R, dtype=jnp.int32)
     seen = (g[None] >= 1) & (g[None] <= (pos[:, None] + 1) // sz.stride - 1)
@@ -192,13 +244,7 @@ def block_scores(q, pooled, pos, sz: Sizes, scale: float):
     r = a.sum(axis=2)                                       # [rows, nKV, WR]
     own = r.reshape(r.shape[:2] + (W, R)).max(-1)
     nxt = jnp.pad(r[..., R::R], ((0, 0), (0, 0), (0, 1)))  # next block's first
-    score = jnp.maximum(own, nxt)
-    b = jnp.arange(W, dtype=jnp.int32)
-    newest = (pos // sz.block)[:, None]
-    forced = (b[None] < sz.init_blocks) | (b[None] > newest
-                                           - sz.window_blocks)
-    score = jnp.where(forced[:, None], jnp.inf, score)
-    return jnp.where((b[None] <= newest)[:, None], score, -1.0)
+    return _dress(jnp.maximum(own, nxt), pos, sz)
 
 
 def _order_keys(score):
@@ -283,13 +329,124 @@ def _batches(n: int, size: int) -> int:
     return size
 
 
-def select_blocks(q, ck, layer, table, pos, live, sz: Sizes, scale: float
-                  ) -> Tuple[jax.Array, jax.Array]:
+class _Shared(NamedTuple):
+    """What a decode step's tables say of the blocks its streams share
+    (``_shared_plan``), the streams dealt into tiles of ``_TILE_STREAMS``
+    slots of ONE sharing group each."""
+    fits: jax.Array         # []: the tiles and the tails are inside bounds
+    tiles: jax.Array        # []: tiles in use
+    streams: jax.Array      # [NT, b]: the stream in a slot (any, if none)
+    first: jax.Array        # [NT]: the stream whose table row a tile's
+    #                         group shares ..
+    shared: jax.Array       # [NT]: .. over this many leading slots
+    at: jax.Array           # [S]: a stream's slot, ``tile * b + slot``
+
+
+def _max_tiles(S: int) -> int:
+    """Tiles a decode step of ``S`` streams may need and still share: those
+    its streams fill and a partial one for each of ``S / 8`` groups."""
+    return -(-S // _TILE_STREAMS) + S // 8
+
+
+def _shared_plan(table, group, pos, live, num_blocks: int, sz: Sizes
+                 ) -> _Shared:
+    """A sharing group: the live streams of one pool group whose slot 0 holds
+    the same block (the prefix cache shares whole LEADING blocks); its
+    shared length: the leading slots on which every member agrees with the
+    longest, no further than that one reaches.  Groups in the order of
+    their longest streams, each padded to whole tiles.  All of it compares
+    over ``[S, S]`` and ``[S, W]`` int32: no sort, no scatter.  A live
+    stream whose slot 0 is dead is a group of its own that shares
+    nothing."""
+    S, W = table.shape
+    b, T, NT = _TILE_STREAMS, _TAIL_SLOTS, _max_tiles(S)
+    i32 = jnp.int32
+    s = jnp.arange(S, dtype=i32)
+    key = jnp.where(table[:, 0] >= 0, group * num_blocks + table[:, 0],
+                    -1 - s)
+    same = (key[:, None] == key[None]) & live[:, None] & live[None]
+    # the group's first: its longest stream (the lowest of them), whose
+    # table row the others are held to wherever they hold a block
+    first = jnp.argmax(jnp.where(same, pos[None], -1), axis=1).astype(i32)
+    w = jnp.arange(W, dtype=i32)
+    agree = jnp.min(jnp.where((table != table[first]) & (table >= 0),
+                              w[None], W), axis=1)
+    reach = pos // sz.block + 1                             # blocks held
+    shared = jnp.minimum(
+        jnp.min(jnp.where(same, agree[None], W), axis=1),
+        jnp.max(jnp.where(same, reach[None], 0), axis=1))
+    rank = (same & (s[None] < s[:, None])).sum(1, dtype=i32)
+    leads = live & (first == s)
+    tiles_of = jnp.where(leads, -(-same.sum(1, dtype=i32) // b), 0)
+    base = jnp.where(s[None] < first[:, None], tiles_of[None], 0).sum(1)
+    at = (base + rank // b) * b + rank % b
+    tiles = tiles_of.sum()
+    tail = jnp.max(jnp.where(live, reach - shared, 0))
+    slots = jnp.arange(NT * b, dtype=i32)
+    streams = jnp.argmax((at[None] == slots[:, None]) & live[None],
+                         axis=1).astype(i32).reshape(NT, b)
+    return _Shared((tiles <= NT) & (tail <= T), tiles, streams,
+                   first[streams[:, 0]], shared[streams[:, 0]], at)
+
+
+def _tile_scores(q, their, ck, layer, g, shared, own_table, pos, sz: Sizes,
+                 scale: float):
+    """Raw block scores ``[b, nKV, W]`` of a tile: ``b`` streams of ONE
+    sharing group — q ``[b, nKV, group, D]``, pos [b] — against ``their``
+    ``[W, nKV, R, D]``, the pooled keys of the group's table row (its
+    ``shared`` leading blocks count), contracted with all ``b x group``
+    query rows of a K/V head in one product, and each stream against its
+    OWN ``_TAIL_SLOTS`` slots from ``shared`` on (``own_table [b, W +
+    _TAIL_SLOTS]``, its table padded with dead slots), gathered here.  A
+    query head's maximum and normaliser span both parts; the last shared
+    block's score takes the first pooled row of the stream's own next
+    block."""
+    b, nKV = q.shape[:2]
+    W, T, R = their.shape[0], _TAIL_SLOTS, ck.shape[4]
+    tail = lax.dynamic_slice_in_dim(own_table, shared, T, axis=1)
+    own = ck[layer, g, jnp.maximum(tail, 0)]             # [b, T, nKV, R, D]
+    sp = (_contract("bnmd,wnrd->nbmwr", q, their) * scale).reshape(
+        nKV, b, -1, W * R)
+    st = (_contract("bnmd,bjnrd->nbmjr", q, own) * scale).reshape(
+        nKV, b, -1, T * R)
+    last = ((pos + 1) // sz.stride - 1)[:, None]             # newest seen
+    gp = jnp.arange(W * R, dtype=jnp.int32)[None]
+    gt = shared * R + jnp.arange(T * R, dtype=jnp.int32)[None]
+    seen_p = ((gp >= 1) & (gp <= last) & (gp < shared * R))[None, :, None]
+    seen_t = ((gt >= 1) & (gt <= last))[None, :, None]
+    top = jnp.maximum(
+        jnp.max(jnp.where(seen_p, sp, -1e30), axis=-1, keepdims=True),
+        jnp.max(jnp.where(seen_t, st, -1e30), axis=-1, keepdims=True))
+    ap = jnp.exp(jnp.where(seen_p, sp, -jnp.inf) - top)
+    at = jnp.exp(jnp.where(seen_t, st, -jnp.inf) - top)
+    z = jnp.maximum(ap.sum(-1, keepdims=True) + at.sum(-1, keepdims=True),
+                    1e-30)
+    rp, rt = (ap / z).sum(axis=2), (at / z).sum(axis=2)     # [nKV, b, rows]
+
+    def per_block(r, n):
+        """max over a block's rows and the next block's first."""
+        r = r.reshape(r.shape[:2] + (n, R))
+        nxt = jnp.pad(r[..., 1:, 0], ((0, 0), (0, 0), (0, 1)))
+        return jnp.maximum(r.max(-1), nxt)
+
+    # the tail's blocks shared - 1 (its ``nxt``) .. shared + T - 1, put at
+    # their logical places; scores are >= 0 and 0 where a part sees nothing
+    tail_b = jnp.concatenate([rt[..., :1], per_block(rt, T)], axis=-1)
+    placed = lax.dynamic_update_slice_in_dim(
+        jnp.zeros((nKV, b, 1 + W + T), jnp.float32), tail_b, shared,
+        axis=2)[..., 1:W + 1]
+    return jnp.maximum(per_block(rp, W), placed).swapaxes(0, 1)
+
+
+def select_blocks_counted(q, ck, layer, table, pos, live, sz: Sizes,
+                          scale: float
+                          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """q ``[S, K, nH, D]`` (query head ``h * group + m`` reads K/V head h);
     ck ``[L, G, B, nKV, R, D]``; table ``[S, W]`` (each stream's, group
     ``s // (S / G)``); pos / live ``[S, K]``.  Returns (pool block ids ``[S,
     K, nKV, width]`` in ascending logical order, ``DEAD_BLOCK`` past the
-    count; count ``[S, K, nKV]``, 0 for a dead row)."""
+    count; count ``[S, K, nKV]``, 0 for a dead row; the ``ck`` blocks the
+    scores were read from, a K/V head each: int32 [])."""
     S, K, nH, D = q.shape
     G, nKV, R = ck.shape[1], ck.shape[3], ck.shape[4]
     W = table.shape[1]
@@ -301,12 +458,44 @@ def select_blocks(q, ck, layer, table, pos, live, sz: Sizes, scale: float
         pooled = ck[layer, g, jnp.maximum(row, 0)]          # [W, nKV, R, D]
         return block_scores(q_s, pooled, pos_s, sz, scale)
 
-    if K == 1:
+    def a_stream_each():
         b = _batches(S, _BATCH_STREAMS)
         split = lambda v: v.reshape((S // b, b) + v.shape[1:])  # noqa: E731
         score = lax.map(
             lambda a: jax.vmap(of_stream)(*a),
             (split(qg), split(table), split(group), split(pos)))
+        return score.reshape(S, nKV, W), jnp.int32(S * W * nKV)
+
+    def a_shared_block_once(plan: _Shared):
+        b, T = _TILE_STREAMS, _TAIL_SLOTS
+        own = jnp.pad(table, ((0, 0), (0, T)), constant_values=DEAD_BLOCK)
+        t = plan.streams
+        q_t, own_t, pos_t = qg[t][:, :, 0], own[t], pos[t][..., 0]
+
+        def of_tile(i, raw):
+            """The tile's group's pooled keys, gathered ONCE for its
+            streams through the group's longest stream's table row."""
+            g = group[t[i, 0]]
+            their = ck[layer, g, jnp.maximum(table[plan.first[i]], 0)]
+            return lax.dynamic_update_index_in_dim(raw, _tile_scores(
+                q_t[i], their, ck, layer, g, plan.shared[i], own_t[i],
+                pos_t[i], sz, scale), i, axis=0)
+
+        # (a loop of as many steps as there are tiles in use: the others
+        # cost nothing, and their rows are read by no stream)
+        raw = lax.fori_loop(0, plan.tiles, of_tile, jnp.zeros(
+            (t.shape[0], b, nKV, W), jnp.float32))
+        score = _dress(raw.reshape(-1, nKV, W)[plan.at], pos[:, 0], sz)
+        return score, (plan.tiles * (W + b * T) * nKV).astype(jnp.int32)
+
+    if K == 1:
+        # what the tables say decides: a shared block's pooled keys once a
+        # program, or (nothing shared, or long tails) a stream's each
+        plan = _shared_plan(table, group, pos[:, 0], live[:, 0],
+                            ck.shape[2], sz)
+        score, read = lax.cond(plan.fits,
+                               lambda: a_shared_block_once(plan),
+                               a_stream_each)
     else:
         b = _batches(K, _BATCH_ROWS)
         score = lax.map(
@@ -315,6 +504,7 @@ def select_blocks(q, ck, layer, table, pos, live, sz: Sizes, scale: float
                 (s[0].reshape((K // b, b) + s[0].shape[1:]),
                  s[3].reshape(K // b, b))),
             (qg, table, group, pos))
+        read = jnp.int32(S * (K // b) * W * nKV)
     # the block scores of a whole program are small (256 x 2 x 2,072 fp32 =
     # 4.2 MB where the scores they are the maxima of are batched above): the
     # order is found once, for every row
@@ -322,8 +512,16 @@ def select_blocks(q, ck, layer, table, pos, live, sz: Sizes, scale: float
                     jnp.repeat(table, K, axis=0), sz)
     ids, n = ids.reshape(S, K, nKV, sz.width), n.reshape(S, K, nKV)
     n = jnp.where(live[..., None], n, 0)
-    return jnp.where(live[..., None, None], ids, DEAD_BLOCK), n
+    return jnp.where(live[..., None, None], ids, DEAD_BLOCK), n, read
+
+
+def select_blocks(q, ck, layer, table, pos, live, sz: Sizes, scale: float
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """``select_blocks_counted``'s ids and counts."""
+    return select_blocks_counted(q, ck, layer, table, pos, live, sz,
+                                 scale)[:2]
 
 
 __all__ = ["Sizes", "write_pooled_chunk", "write_pooled_rows",
-           "block_scores", "choose", "select_blocks", "DEAD_BLOCK"]
+           "block_scores", "choose", "select_blocks",
+           "select_blocks_counted", "DEAD_BLOCK"]

@@ -565,7 +565,7 @@ def test_what_the_shared_code_answers_about_this_family():
         served.class_geometry(served.cache_classes[0], 8)
     assert served.counter_names == ("sparse_blocks_read",
                                     "sparse_blocks_in_reach",
-                                    "ck_rows_scored")
+                                    "ck_rows_scored", "ck_blocks_read")
 
 
 @pytest.mark.parametrize("temperature", [0.0, 1.0])

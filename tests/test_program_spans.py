@@ -1270,7 +1270,7 @@ def test_the_readers_list_names_the_selections_scopes_and_counters():
     assert scope_of("jit(decode_step)/attn/la_state_update/pallas_call")[0] \
         == ("attn", "la_state_update")
     counters = {"sparse_blocks_read", "sparse_blocks_in_reach",
-                "sparse_read_share", "ck_rows_scored"}
+                "sparse_read_share", "ck_rows_scored", "ck_blocks_read"}
     assert counters <= set(SPAN_ARGS["decode"])
     assert counters <= set(SPAN_ARGS["prefill"])
 
@@ -1291,6 +1291,8 @@ def test_the_selections_counters_ride_the_fetch_onto_the_spans(sala_engine):
     # decode at 101-106 tokens: 7 blocks in reach, 4 read
     assert c["sparse_blocks_read"] >= 4 * 2 * 2
     assert c["ck_rows_scored"] > 0
+    # blocks of pooled keys the selection gathered (a running mean too)
+    assert c["ck_blocks_read"] > 0
 
 
 # --------------------------------------------------------------------- #

@@ -24,7 +24,11 @@ engine's three programs are ``ServedModel``'s, written once over a family's
      is held to its PR's file (``program_text_pr59.json``: what it computes,
      always) and, for the two programs ``MOVED_BY_PR60`` names — the block
      selection's order found by a threshold, not by two sorts — to the text
-     PR 60 left (``program_text_pr60.json``).  A program that lowers
+     PR 60 left (``program_text_pr60.json``), and since PR 63 — a decode
+     step's selection reads a shared block's pooled keys once, and both
+     programs count the blocks their selection gathered — to the text PR 63
+     left (``program_text_pr63.json``, ``MOVED_BY_PR63``).  A program that
+     lowers
      to none of them fails: run ``python tests/decode_step_hlo.py OUT.json
      DIR`` on both trees and ``diff`` the blanked texts to see which lines
      moved;
@@ -57,6 +61,7 @@ LEFT_BY_PR58 = json.load(open(os.path.join(DATA, "program_text_pr58.json")))
 # own PR: ``python tests/decode_step_hlo.py OUT.json DIR ADDED``.
 ADDED_BY_PR59 = json.load(open(os.path.join(DATA, "program_text_pr59.json")))
 LEFT_BY_PR60 = json.load(open(os.path.join(DATA, "program_text_pr60.json")))
+LEFT_BY_PR63 = json.load(open(os.path.join(DATA, "program_text_pr63.json")))
 # ... and the family PR 62 added (``harness.ADDED_BY_PR62``: ``python
 # tests/decode_step_hlo.py OUT.json DIR PR62``).  PR 62 gave ``Rows`` each
 # row's last attendable position as a field of its own: for every family
@@ -115,6 +120,23 @@ THRESHOLD = ("`sparse_select.choose` finds the `topk` largest block scores by "
              "batch the scores")
 MOVED_BY_PR60 = {"minicpm_sala": {"decode_step": (THRESHOLD,),
                                   "prefill_step": (THRESHOLD,)}}
+# PR 63, both arms: the same two programs.
+SHARED = ("`sparse_select.select_blocks_counted` with one row a stream reads "
+          "its tables first (`_shared_plan`: compares over `[S, S]` and "
+          "`[S, W]`), deals the streams that share leading blocks into tiles "
+          "and, under a `cond`, in a loop of as many steps as there are "
+          "tiles, gathers a tile's group's pooled keys ONCE and contracts "
+          "them with all the tile's query rows in one product "
+          "(`_tile_scores`, a stream's own slots past the prefix beside it, "
+          "one softmax over both); the per-stream gather of every table's "
+          "width is the `cond`'s other arm")
+COUNTER = ("the counter `ck_blocks_read` (blocks of pooled keys the selection "
+           "gathered) rides the fetch: the row of counters is one int32 "
+           "wider, and a chunk program adds its constant a sparse layer — "
+           "nothing else of `prefill_step` moved (the `K > 1` arm of the "
+           "selection is PR 60's line for line)")
+MOVED_BY_PR63 = {"minicpm_sala": {"decode_step": (SHARED, COUNTER),
+                                  "prefill_step": (COUNTER,)}}
 KINDS = ("decode_step", "prefill_step", "verify_step", "outputs")
 REFUSES = ("retention", "lfm2", "falcon_h1", "kimi_linear")
 
@@ -172,7 +194,9 @@ def test_an_added_familys_programs_are_what_its_pr_left(family, kind, arm):
     PR computed, and the text its PR lowered — or, for the programs
     ``MOVED_BY_PR60`` names with their cause, the text PR 60 left
     (``program_text_pr60.json``: ``python tests/decode_step_hlo.py OUT.json
-    DIR ADDED`` on that tree); ``verify`` refuses (a state a stream)."""
+    DIR ADDED`` on that tree), or, for those ``MOVED_BY_PR63`` names, the
+    text PR 63 left (``program_text_pr63.json``, written the same way);
+    ``verify`` refuses (a state a stream)."""
     got, want = harness.golden(family, arm), ADDED_BY_PR59[family][arm]
     if kind == "outputs":
         assert got["outputs"] == want["outputs"]
@@ -189,10 +213,13 @@ def test_an_added_familys_programs_are_what_its_pr_left(family, kind, arm):
         return
     assert names
     left_by = "its PR"
-    if kind in MOVED_BY_PR60.get(family, {}):
-        want = LEFT_BY_PR60[family][arm]
-        left_by = "PR 60 (moved then by: {})".format(
-            "; ".join(MOVED_BY_PR60[family][kind]))
+    for pr, moved, left in ((63, MOVED_BY_PR63, LEFT_BY_PR63),
+                            (60, MOVED_BY_PR60, LEFT_BY_PR60)):
+        if kind in moved.get(family, {}):
+            want = left[family][arm]
+            left_by = "PR {} (moved then by: {})".format(
+                pr, "; ".join(moved[family][kind]))
+            break
     for name in names:
         assert got["programs"][name]["order_free"] \
             == want["programs"][name]["order_free"], (
@@ -235,15 +262,18 @@ def test_the_family_of_blocks_is_what_its_pr_left(family, kind, arm):
     (harness.FAMILIES, LEFT_BY_PR56, LEFT_BY_PR58, lambda family, arm:
      MOVED_BY_PR58.get(family, {}) if arm == "on" else {}),
     (harness.ADDED, ADDED_BY_PR59, LEFT_BY_PR60,
-     lambda family, arm: MOVED_BY_PR60.get(family, {}))],
-    ids=["pr56", "pr58", "pr60"])
+     lambda family, arm: MOVED_BY_PR60.get(family, {})),
+    (harness.ADDED, LEFT_BY_PR60, LEFT_BY_PR63,
+     lambda family, arm: MOVED_BY_PR63.get(family, {}))],
+    ids=["pr56", "pr58", "pr60", "pr63"])
 def test_the_causes_on_record_are_of_the_programs_that_moved(families, was,
                                                              now, moved_by):
     """``MOVED`` names the programs whose operations PR 56 left other than
     PR 55's, ``MOVED_BY_PR58`` those PR 58 left other than PR 56's (the
     kernel arm alone), ``MOVED_BY_PR60`` those PR 60 left other than PR
-    59's (the added family's), and no other (an entry would outlive its
-    cause); what every fixture computes moved in none."""
+    59's (the added family's), ``MOVED_BY_PR63`` those PR 63 left other
+    than PR 60's, and no other (an entry would outlive its cause); what
+    every fixture computes moved in none."""
     for family in families:
         for arm in harness.ARMS:
             old, new = was[family][arm], now[family][arm]

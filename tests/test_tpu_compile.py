@@ -2076,6 +2076,28 @@ def test_sparse_beside_state_fit_and_are_updated_in_place(
         assert scope in text, scope
 
 
+def test_the_decode_steps_selection_gathers_a_shared_prefix_once(
+        sparse_beside_state_programs):
+    """PR 63, at the benchmark cell's shape: the decode step's ``select``
+    gathers pooled keys ``[blocks, 2, 4, 128]`` a TILE of 32 streams at a
+    time — the 2,072 blocks of its sharing group's table row once, and ``32
+    x 32`` blocks of its streams' own — in each of the two sparse layers;
+    the gather of a batch of 32 streams' whole tables
+    (``bf16[66304,2,4,128]``: 1.08 GB a layer) is gone, and the per-stream
+    arm a ``cond`` keeps reads 16 streams' tables a step."""
+    from deepspeed_tpu.ops import sparse_select
+    text = sparse_beside_state_programs[2]["decode_step"].as_text()
+    gathered = {}
+    for line in text.splitlines():
+        m = re.search(r" = bf16\[([\d,]+),2,4,128\]\S* gather\(", line)
+        if m and "/attn/select/" in line:
+            lead = tuple(int(d) for d in m.group(1).split(","))
+            gathered[lead] = gathered.get(lead, 0) + 1
+    tile = (sparse_select._TILE_STREAMS, sparse_select._TAIL_SLOTS)
+    assert gathered == {(2072,): 2, tile: 2,
+                        (sparse_select._BATCH_STREAMS, 2072): 2}, gathered
+
+
 # ------------------------------------------------------------------ #
 # A step that is a pass over a block of 4 rows a slot, written through the
 # paged cache and attended under block-causal limits, the head over S x B
