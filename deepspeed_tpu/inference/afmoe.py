@@ -31,8 +31,8 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from .kv_pages import GqaPagedServed, write_and_attend
-from .served import CacheClass, Rows, register
+from .kv_pages import GqaPagedServed, output_gate, write_and_attend
+from .served import CacheClass, Rows, held_counter_args, register
 from ..models import afmoe
 from ..models.afmoe import AfmoeConfig, SLIDING
 from ..models.blocks import matmul, rms_norm, swiglu
@@ -76,15 +76,10 @@ class AfmoeServed(GqaPagedServed):
         all the live rows routed (1.0 here), under the names the latent
         family's share uses."""
         cfg = self.cfg
-        pairs = int(rows[:, 0].sum())
-        cells = len(rows) * cfg.num_moe_layers * cfg.num_experts
-        routed = int(rows[:, 3].sum()) * cfg.num_experts_per_tok \
-            * cfg.num_moe_layers
-        return {"moe_held_pairs": pairs,
-                "moe_held_max": int(rows[:, 1].max()),
-                "moe_held_mean": pairs / cells if cells else 0.0,
-                "moe_held_empty": int(rows[:, 2].sum()),
-                "moe_held_pair_share": pairs / routed if routed else 0.0}
+        layers = cfg.num_moe_layers
+        return held_counter_args(
+            rows, len(rows) * layers * cfg.num_experts,
+            int(rows[:, 3].sum()) * cfg.num_experts_per_tok * layers)
 
     # -- the block ------------------------------------------------------ #
     @jax.named_scope("embed")
@@ -117,8 +112,7 @@ class AfmoeServed(GqaPagedServed):
                 a = write_and_attend(c, pools, q, k, v,
                                      scale=cfg.softmax_scale, mesh=mesh)
                 with jax.named_scope("out_proj"):
-                    a = (a.astype(jnp.float32) * jax.nn.sigmoid(
-                        gate.astype(jnp.float32))).astype(x.dtype)
+                    a = output_gate(a, gate, x.dtype)
                     x = x + rms_norm(matmul(a, p["wo"]),
                                      p["post_attn_norm"], cfg.rms_norm_eps)
             return x

@@ -1058,18 +1058,32 @@ class InferenceEngine:
         rows the chunk programs of these admissions may read, a class of
         pages at a time, summed over its layers — a chunk of ``n`` rows
         that starts at ``first`` reads ``first + n`` rows back at most, a
-        bounded class no more than ``reach + n - 1`` of them."""
+        bounded class no more than ``reach + n - 1`` of them — and
+        ``attend_rows_read_<class>``: the key rows their attends WALK, a
+        run of query rows at a time (``ServedModel.attend_run_rows`` of the
+        program's width: each run walks its own reach, so a chunk of
+        several runs reads the stream's rows several times)."""
         args = {}
         for sp in self._attend_specs:
-            rows = 0
+            rows = walked = 0
             for chunks, _ in tails:
                 for first, n in chunks:
                     seen = first + n
                     if sp.reach is not None:
                         seen = min(seen, sp.reach + n - 1)
                     rows += seen
+                    run = self.served.attend_run_rows(next(
+                        (w for w in self.prefill_widths if w >= n),
+                        self.prefill_widths[-1]))
+                    for start in range(0, n, run):
+                        end = min(start + run, n)        # the run's live rows
+                        reach = first + end
+                        if sp.reach is not None:
+                            reach = min(reach, sp.reach + end - start - 1)
+                        walked += reach
             args["context_tokens_in_reach_" + sp.name] = \
                 rows * sp.num_layers
+            args["attend_rows_read_" + sp.name] = walked * sp.num_layers
         return args
 
     def _await_decode(self) -> float:

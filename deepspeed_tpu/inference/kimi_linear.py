@@ -24,7 +24,9 @@ latent class (``latent_context`` once a program, ``latent_sublayer`` a
 layer): absorbed decode and chunk prefill through the same kernels, with no
 rotation and a full-rank query (``models/deepseek_v3.latent_projections``).
 
-``decode`` has one row a stream: the delta-rule update is
+The KDA layers over their pages are ``inference/kda_state.py``'s (``KdaPages``:
+shared with every family that has such layers).  ``decode`` has one row a
+stream: the delta-rule update is
 ``ops.kda.state_update`` on the chip (every live page's layer read once and
 written once, in place, with the dependent pass in between), else a gather,
 ``ops.kda.recurrent_update`` and a scatter that drops dead slots; the
@@ -54,23 +56,20 @@ exponent (every one it forms is a difference that cannot be positive:
 """
 from __future__ import annotations
 
-import math
 from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from .kda_state import KDA_CHUNK, KdaPages, scan_rows, state_geometry
 from .latent import LatentServed, latent_context, latent_sublayer
-from .served import (CacheClass, Rows, filter_rows, filter_tile,
-                     group_shape, register, stream_pages)
+from .served import CacheClass, Rows, register
 from ..models import kimi_linear as kl
 from ..models.blocks import rms_norm, swiglu
 from ..models.kimi_linear import KDA, KimiLinearConfig
 from ..moe import share
-from ..ops import kda
 
 LATENT_CLASS, STATE_CLASS = "latent", "state"
-KDA_CHUNK = 64            # rows a step of the chunked delta rule
 
 
 class KimiLinearServed(LatentServed):
@@ -107,17 +106,9 @@ class KimiLinearServed(LatentServed):
         if not cls.per_stream:
             return super().class_geometry(cls, block_size)
         cfg = self.cfg
-        tile = kda.state_tile(cfg.kda_num_heads, cfg.kda_head_dim,
-                              cfg.kda_head_dim)
-        latent_token = (cfg.latent_width * cfg.num_latent_layers
-                        * jnp.dtype(cfg.dtype).itemsize)
-        return dict(pools=(("state", tile, jnp.float32),
-                           ("conv", filter_tile(
-                               cfg.short_conv_kernel_size - 1,
-                               cfg.conv_dim))),
-                    num_heads=cfg.kda_num_heads,
-                    head_dim=cfg.kda_head_dim * cfg.kda_head_dim,
-                    token_row_bytes=-(-latent_token // cls.layers))
+        return state_geometry(
+            cfg, cls.layers, cfg.latent_width * cfg.num_latent_layers
+            * jnp.dtype(cfg.dtype).itemsize)
 
     # -- the block ------------------------------------------------------ #
     @jax.named_scope("embed")
@@ -131,7 +122,6 @@ class KimiLinearServed(LatentServed):
         cfg = self.cfg
         G, Sg, K = rows.positions.shape
         S, H = G * Sg, x.shape[-1]
-        taps = cfg.short_conv_kernel_size
         pos = rows.positions.reshape(S, K)
         live = rows.live
         latent, state, conv = pools
@@ -140,71 +130,13 @@ class KimiLinearServed(LatentServed):
         ctx = latent_context(rows.tables[:, :, :w_latent], rows.positions,
                              live, latent, paged_kernel, mesh)
 
-        # -- the state's page, where it goes back and what a snapshot
-        # takes.  The scan's sub-chunk: every block boundary is one of its
-        # carried states (a chunk starts at one: the engine's widths are
-        # whole blocks).
-        q_rows = math.gcd(KDA_CHUNK, 2 * latent.shape[4], K)
-        page = rows.tables[:, :, w_latent].reshape(S)
-        sp = stream_pages(page, pos, live, state.shape[2], Sg, taps - 1,
-                          rows.freeze, scan_rows=q_rows)
-
-        def decode_states(q, k, v, g, beta, layer):
-            """One row a stream: every live page's layer rewritten in
-            place."""
-            nonlocal state
-            args = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
-            if paged_kernel:
-                o, state = kda.state_update(
-                    state, layer, page.reshape(G, Sg),
-                    *(group_shape(a, G) for a in args), mesh=mesh)
-                return o.reshape((S, 1) + o.shape[2:])
-            o, new = kda.recurrent_update(state[layer, sp.group, sp.page],
-                                          *args)
-            state = state.at[layer, sp.group, sp.to[0]].set(new,
-                                                            mode="drop")
-            return jnp.where(sp.wrote[:, None, None], o, 0.0)[:, None]
-
-        def chunk_states(q, k, v, g, beta, layer):
-            """A chunk of rows a stream, from the page's state."""
-            nonlocal state
-            g = jnp.where(live[..., None, None], g, 0.0)
-            beta = jnp.where(live[..., None], beta, 0.0)
-            os_ = []
-            for s in range(S):
-                S0 = jnp.where(sp.carried[s],
-                               state[layer, sp.group[s], sp.page[s]], 0.0)
-                o, S1, kept = kda.chunked_delta_rule(
-                    S0, q[s], k[s], v[s], g[s], beta[s], chunk=q_rows,
-                    keep=None if sp.keep_chunk is None
-                    else sp.keep_chunk[s])
-                for where, new in zip(sp.to, (S1, kept)):
-                    state = state.at[layer, sp.group[s], where[s]].set(
-                        new, mode="drop")
-                os_.append(o)
-            return jnp.stack(os_)
-
-        def kda_mixer(p, x, layer):
-            nonlocal conv
-            with jax.named_scope("attn"):
-                with jax.named_scope("kda_proj"):
-                    u = rms_norm(x, p["input_norm"], cfg.rms_norm_eps)
-                    qkv = kl.kda_in(p, u, cfg)              # [S, K, 3 W]
-                with jax.named_scope("kda_conv"):
-                    rows_in, conv = filter_rows(
-                        sp, conv, layer, qkv, paged_kernel=paged_kernel,
-                        mesh=mesh)
-                    q, k, v = kl.kda_qkv(kl.kda_conv(p, rows_in, cfg), cfg)
-                with jax.named_scope("kda_gate"):
-                    g, beta = kl.kda_gates(p, u, cfg)
-                if not rows.chunked:
-                    with jax.named_scope("kda_update"):
-                        o = decode_states(q, k, v, g, beta, layer)
-                else:
-                    with jax.named_scope("kda_chunk"):
-                        o = chunk_states(q, k, v, g, beta, layer)
-                with jax.named_scope("kda_out"):
-                    return x + kl.kda_out(p, o, u, cfg)
+        # -- the KDA layers' pages (``inference/kda_state.py``).  The scan's
+        # sub-chunk: every block boundary is one of its carried states (a
+        # chunk starts at one: the engine's widths are whole blocks).
+        pages = KdaPages(
+            cfg, state, conv, rows.tables[:, :, w_latent].reshape(S), rows,
+            q_rows=scan_rows(2 * latent.shape[4], K),
+            paged_kernel=paged_kernel, mesh=mesh)
 
         def latent_mixer(p, x, layer):
             nonlocal latent
@@ -220,7 +152,7 @@ class KimiLinearServed(LatentServed):
         at = {KDA: 0, kl.LATENT: 0}
         for l, p in enumerate(params["layers"]):
             kind = cfg.layer_kinds[l]
-            x = (kda_mixer if kind == KDA else latent_mixer)(p, x, at[kind])
+            x = (pages.mixer if kind == KDA else latent_mixer)(p, x, at[kind])
             at[kind] += 1
             if l < cfg.num_dense_layers:
                 with jax.named_scope("mlp"):
@@ -238,7 +170,7 @@ class KimiLinearServed(LatentServed):
             pairs = pairs + counts.sum()
             most = jnp.maximum(most, counts.max())
             empty = empty + (counts == 0).sum()
-        return x, (latent, state, conv), (
+        return x, (latent, pages.state, pages.conv), (
             pairs, most, empty, row_live.sum().astype(jnp.int32))
 
     @jax.named_scope("lm_head")

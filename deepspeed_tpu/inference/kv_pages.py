@@ -12,7 +12,8 @@ targets, runs of query rows and the kernel's plan, for all layers of the
 class — and ``write_and_attend`` once a layer: the new rows written in place
 (scope ``kv_write``), then the attend over the class's pages (scope
 ``attend_<class>``), ``ops.paged_attention``'s kernel under its plan or the
-gather below.  A class may be a window's RING (``CacheClass.reach``); a
+gather below; a family with an output gate puts ``output_gate`` on what
+comes back.  A class may be a window's RING (``CacheClass.reach``); a
 ``per_stream`` class among a model's classes is its owner's business and is
 passed over.  A prefill chunk is split into runs of query rows
 (``attend_rows``), each a stream of the attend with the chunk's table, so
@@ -160,6 +161,15 @@ def write_and_attend(c, pools, q, k, v, *, scale: float, mesh):
     return a.reshape(S, K, nH * D)
 
 
+def output_gate(a: jax.Array, gate: jax.Array, dtype) -> jax.Array:
+    """The attended rows under a sigmoid OUTPUT GATE, elementwise, ahead of
+    the output projection: ``a`` and the gate's logits ``[..., nH * D]``,
+    fp32 inside, ``dtype`` out.  The families that publish one (``afmoe``,
+    ``solar_open2``) call it on what ``write_and_attend`` returns."""
+    return (a.astype(jnp.float32)
+            * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dtype)
+
+
 class GqaPagedServed(ServedModel):
     """What a model of grouped-query K/V pages answers whatever else its
     layers hold (experts, a conv state, a state-space mixer): the K/V
@@ -207,6 +217,9 @@ class GqaPagedServed(ServedModel):
             q_itemsize=q_itemsize) + (
                 paged_attn_ops.attend_cold_steps(live_blocks, calls=calls),)
 
+    def attend_run_rows(self, K: int) -> int:
+        return attend_rows(K, self.cfg.group)
+
     def paged_classes(self, rows: Rows, pools, *, paged_kernel: bool, mesh):
         """``paged_classes`` of this model's classes and head geometry."""
         return paged_classes(
@@ -215,4 +228,5 @@ class GqaPagedServed(ServedModel):
 
 
 __all__ = ["GqaPagedServed", "MAX_HEAD_ROWS", "attend_rows",
-           "gather_attend", "paged_classes", "write_and_attend"]
+           "gather_attend", "output_gate", "paged_classes",
+           "write_and_attend"]

@@ -43,7 +43,8 @@ import numpy as np
 from jax import lax
 
 from . import kv_cache
-from .served import NEG_INF, Rows, ServedModel, register, write_targets
+from .served import (NEG_INF, Rows, ServedModel, held_counter_args, register,
+                     write_targets)
 from ..models import deepseek_v3 as dsv3
 from ..models import hyper_connections as hyper
 from ..models.deepseek_v3 import DeepseekV3Config
@@ -214,15 +215,10 @@ class LatentServed(ServedModel):
         layer, held experts (x layers) that got no row, and the pairs'
         share of all the live rows routed."""
         cfg = self.cfg
-        pairs = int(rows[:, 0].sum())
-        cells = len(rows) * cfg.num_moe_layers * cfg.held[1]
-        routed = int(rows[:, 3].sum()) * cfg.num_experts_per_tok \
-            * cfg.num_moe_layers
-        args = {"moe_held_pairs": pairs,
-                "moe_held_max": int(rows[:, 1].max()),
-                "moe_held_mean": pairs / cells,
-                "moe_held_empty": int(rows[:, 2].sum()),
-                "moe_held_pair_share": pairs / routed if routed else 0.0}
+        layers = cfg.num_moe_layers
+        args = held_counter_args(
+            rows, len(rows) * layers * cfg.held[1],
+            int(rows[:, 3].sum()) * cfg.num_experts_per_tok * layers)
         if cfg.hyper is not None:
             # The largest |row or column sum of H_res - 1| over the live
             # rows of every sublayer: what the Sinkhorn iterations left.

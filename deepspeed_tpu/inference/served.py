@@ -236,6 +236,13 @@ class ServedModel:
         shards of a dp mesh), host integers."""
         raise NotImplementedError
 
+    def attend_run_rows(self, K: int) -> int:
+        """Query rows of a prefill chunk of K rows that share ONE walk of
+        the stream's key rows: all of them unless a model's attend takes a
+        chunk in runs (``inference/kv_pages.attend_rows``), each of which
+        walks its own reach."""
+        return K
+
     def counter_args(self, rows) -> Dict[str, Any]:
         """Span args / running-mean samples from fetched counters ``rows
         [executions, len(counter_names)]`` (int64)."""
@@ -388,6 +395,23 @@ def served_model(model: Any) -> ServedModel:
         f"no served-model implementation for {type(model).__name__}: pass "
         "a ServedModel, or a config whose implementation is registered "
         "(inference.served.register)")
+
+
+def held_counter_args(rows, cells: int, routed: int) -> Dict[str, Any]:
+    """What an expert layer's four counters say of the executions fetched
+    (``rows [executions, 4+]``: routed pairs that landed on held experts, the
+    largest rows a held expert got in a layer, held experts x layers that got
+    no row, live rows routed): the pairs, the largest and the MEAN rows an
+    expert got over ``cells`` (executions x expert layers x experts held),
+    the empty ones, and the pairs' share of the ``routed`` pairs (live rows x
+    experts a token x expert layers).  One set of names for every family
+    whose layers hold experts, whatever share."""
+    pairs = int(rows[:, 0].sum())
+    return {"moe_held_pairs": pairs,
+            "moe_held_max": int(rows[:, 1].max()),
+            "moe_held_mean": pairs / cells if cells else 0.0,
+            "moe_held_empty": int(rows[:, 2].sum()),
+            "moe_held_pair_share": pairs / routed if routed else 0.0}
 
 
 def split_counters(fetched, n: int):
@@ -708,7 +732,8 @@ def spec_accept(logits: jax.Array, tokens: jax.Array, key: jax.Array,
 
 
 __all__ = ["CacheClass", "ServedModel", "register", "served_model",
-           "split_counters", "with_counters", "NEG_INF", "group_shape",
+           "split_counters", "with_counters", "held_counter_args", "NEG_INF",
+           "group_shape",
            "write_targets", "Rows", "StreamPages", "stream_pages",
            "filter_tile", "filter_rows", "filter_rows_lowered",
            "sample_tokens", "head_and_sample", "spec_accept"]

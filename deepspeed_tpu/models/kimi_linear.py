@@ -245,20 +245,20 @@ class KimiLinearConfig:
 
 
 # ------------------------------------------------------------------ #
-# The KDA mixer's pieces
+# The KDA mixer's pieces.  ``cfg``: any config with the ``kda_*`` fields,
+# ``short_conv_kernel_size`` and ``rms_norm_eps`` (this family's, and
+# ``models/solar_open2.py``'s, whose ``kda_allow_neg_eigval`` is set).
 # ------------------------------------------------------------------ #
 _L2_EPS = 1e-6
 
 
-def kda_in(p: Dict[str, jax.Array], u: jax.Array, cfg: KimiLinearConfig
-           ) -> jax.Array:
+def kda_in(p: Dict[str, jax.Array], u: jax.Array, cfg) -> jax.Array:
     """``[q~ | k~ | v~]`` ``[..., conv_dim]`` of normed ``u [..., H]``: what
     the filters run over and the conv state keeps rows of."""
     return matmul(u, p["w_qkv"])
 
 
-def kda_conv(p: Dict[str, jax.Array], rows: jax.Array, cfg: KimiLinearConfig
-             ) -> jax.Array:
+def kda_conv(p: Dict[str, jax.Array], rows: jax.Array, cfg) -> jax.Array:
     """The three short filters (one depthwise filter over their channels
     side by side) over ``rows [..., taps - 1 + K, conv_dim]`` (a stream's
     kept rows ahead of its K new ones): ``silu(sum_j w[:, j] rows[j : j +
@@ -271,7 +271,7 @@ def kda_conv(p: Dict[str, jax.Array], rows: jax.Array, cfg: KimiLinearConfig
     return jax.nn.silu(mixed).astype(rows.dtype)
 
 
-def kda_qkv(mixed: jax.Array, cfg: KimiLinearConfig):
+def kda_qkv(mixed: jax.Array, cfg):
     """Filtered ``[..., conv_dim]`` -> fp32 (q ``[..., nh, d]`` L2-normed a
     head times ``d^-0.5``, k L2-normed, v as it is)."""
     nh, d = cfg.kda_num_heads, cfg.kda_head_dim
@@ -291,20 +291,24 @@ def _low_rank(u, down, up):
                    preferred_element_type=jnp.float32)
 
 
-def kda_gates(p: Dict[str, jax.Array], u: jax.Array, cfg: KimiLinearConfig):
+def kda_gates(p: Dict[str, jax.Array], u: jax.Array, cfg):
     """fp32 (g ``[..., nh, d]``, the LOG decay a channel, < 0; beta ``[...,
-    nh]`` in (0, 1)) of normed ``u [..., H]``."""
+    nh]`` in (0, 1) — in (0, 2) for a config whose ``kda_allow_neg_eigval``
+    is set: the write's strength then passes 1 and ``I - beta k k^T`` has a
+    negative eigenvalue along the key) of normed ``u [..., H]``."""
     nh, d = cfg.kda_num_heads, cfg.kda_head_dim
     f = _low_rank(u, p["w_f_down"], p["w_f_up"]) + p["dt_bias"]
     g = -jnp.exp(p["A_log"])[:, None] * jax.nn.softplus(
         f.reshape(f.shape[:-1] + (nh, d)))
     beta = jax.nn.sigmoid(jnp.dot(u, p["w_beta"].astype(u.dtype),
                                   preferred_element_type=jnp.float32))
+    if getattr(cfg, "kda_allow_neg_eigval", False):
+        beta = 2.0 * beta
     return g, beta
 
 
 def kda_out(p: Dict[str, jax.Array], o: jax.Array, u: jax.Array,
-            cfg: KimiLinearConfig) -> jax.Array:
+            cfg) -> jax.Array:
     """``(RMSNorm_head(o) . sigmoid((u W_g_down) W_g_up)) W_o``: o fp32
     ``[..., nh, d]``, the norm over each head's d values with one weight of
     d; fp32 inside, u's dtype into the last product."""
@@ -325,6 +329,39 @@ _SCORE_STD = 4.0             # the latent layers' scores (see the init)
 _ROUTER_BIAS_STD = 0.1
 _BRANCH_RMS = 0.5            # a branch's contribution to the residual stream
 
+def kda_stds(cfg) -> Dict[str, Tuple[Tuple[int, ...], float]]:
+    """{tensor: (shape, std)} of a KDA mixer's matrices (any config with the
+    ``kda_*`` fields): see ``kimi_linear_init``."""
+    H, d, W = cfg.hidden_size, cfg.kda_head_dim, cfg.kda_width
+    unit = 1.0 / math.sqrt(H)
+    return {
+        "w_qkv": ((H, cfg.conv_dim), unit),
+        "w_f_down": ((H, d), unit), "w_f_up": ((d, W), d ** -0.5),
+        "w_g_down": ((H, d), unit), "w_g_up": ((d, W), d ** -0.5),
+        "w_beta": ((H, cfg.kda_num_heads), _BETA_LOGIT_STD * unit),
+        # (a unit-RMS head times sigmoid(unit gate) has RMS ~0.55)
+        "wo": ((W, H), _BRANCH_RMS / (0.55 * math.sqrt(W)))}
+
+
+def kda_vectors(k_w, k_a, k_dt, cfg) -> Dict[str, jax.Array]:
+    """A KDA mixer's fp32 leaves and its head norm from three keys: the
+    filters' taps, ``A_log`` a head, ``dt_bias`` a channel, ``o_norm``
+    (ranges: see ``kimi_linear_init``)."""
+    lo, hi = _A_RANGE
+    a_log = jnp.log(jax.random.uniform(k_a, (cfg.kda_num_heads,),
+                                       jnp.float32, lo, hi))
+    lo, hi = _DT_RANGE
+    dt = jnp.exp(jax.random.uniform(k_dt, (cfg.kda_width,), jnp.float32,
+                                    math.log(lo), math.log(hi)))
+    return {
+        "conv_w": jax.random.normal(
+            k_w, (cfg.conv_dim, cfg.short_conv_kernel_size), jnp.float32)
+        * jnp.asarray(_FILTER_STD, jnp.float32),
+        "A_log": a_log,
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),      # softplus^-1(dt)
+        "o_norm": jnp.ones((cfg.kda_head_dim,), cfg.dtype)}
+
+
 def _layer_stds(cfg: KimiLinearConfig, layer: int
                 ) -> Dict[str, Tuple[Tuple[int, ...], float]]:
     """{tensor: (shape, std)} of layer ``layer``'s (0-based) matrices: see
@@ -332,15 +369,8 @@ def _layer_stds(cfg: KimiLinearConfig, layer: int
     H, I, F = (cfg.hidden_size, cfg.intermediate_size,
                cfg.moe_intermediate_size)
     unit, out = 1.0 / math.sqrt(H), _BRANCH_RMS
-    d, W = cfg.kda_head_dim, cfg.kda_width
     if cfg.layer_kinds[layer] == KDA:
-        stds = {
-            "w_qkv": ((H, cfg.conv_dim), unit),
-            "w_f_down": ((H, d), unit), "w_f_up": ((d, W), d ** -0.5),
-            "w_g_down": ((H, d), unit), "w_g_up": ((d, W), d ** -0.5),
-            "w_beta": ((H, cfg.kda_num_heads), _BETA_LOGIT_STD * unit),
-            # (a unit-RMS head times sigmoid(unit gate) has RMS ~0.55)
-            "wo": ((W, H), out / (0.55 * math.sqrt(W)))}
+        stds = kda_stds(cfg)
     else:
         nH, C = cfg.num_attention_heads, cfg.kv_lora_rank
         stds = {
@@ -408,18 +438,7 @@ def kimi_linear_init(rng: jax.Array, cfg: KimiLinearConfig) -> Dict[str, Any]:
              in zip(keys, sorted(stds.items()))}
         k_w, k_a, k_dt, k_bias = keys[len(stds):]
         if cfg.layer_kinds[l] == KDA:
-            p["conv_w"] = normal(
-                k_w, (cfg.conv_dim, cfg.short_conv_kernel_size),
-                _FILTER_STD, jnp.float32)
-            lo, hi = _A_RANGE
-            p["A_log"] = jnp.log(jax.random.uniform(
-                k_a, (cfg.kda_num_heads,), jnp.float32, lo, hi))
-            lo, hi = _DT_RANGE
-            dt = jnp.exp(jax.random.uniform(
-                k_dt, (cfg.kda_width,), jnp.float32, math.log(lo),
-                math.log(hi)))
-            p["dt_bias"] = dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1(dt)
-            p["o_norm"] = jnp.ones((cfg.kda_head_dim,), cfg.dtype)
+            p.update(kda_vectors(k_w, k_a, k_dt, cfg))
         else:
             p["kv_norm"] = jnp.ones((cfg.kv_lora_rank,), cfg.dtype)
         if l >= cfg.num_dense_layers:
@@ -436,4 +455,5 @@ def kimi_linear_init(rng: jax.Array, cfg: KimiLinearConfig) -> Dict[str, Any]:
 
 
 __all__ = ["KimiLinearConfig", "kimi_linear_init", "KDA", "LATENT",
-           "kda_in", "kda_conv", "kda_qkv", "kda_gates", "kda_out"]
+           "kda_in", "kda_conv", "kda_qkv", "kda_gates", "kda_out",
+           "kda_stds", "kda_vectors"]

@@ -77,6 +77,11 @@ SCOPES = ("fwd_bwd", "grad_sync", "health_tap", "optimizer", "flatten",
           # norm and the output projection)
           "kda_proj", "kda_conv", "kda_gate", "kda_update", "kda_chunk",
           "kda_out",
+          # ... beside grouped-query layers under an output gate
+          # (inference/solar_open2.py): attn_gate, between the attend and
+          # out_proj (the gate's sigmoid and its product with the attended
+          # rows)
+          "attn_gate",
           # sparse layers beside Lightning layers (inference/minicpm_sala
           # .py), all under attn: ck_write (the pooled keys whose windows
           # end at the new rows), select (their scores, softmax, sums,
@@ -226,10 +231,14 @@ SPAN_ARGS = {
 # its window sliding during prefill — and ``context_tokens_in_reach_<class>``
 # the key rows those chunk programs may read in the class, over its layers
 # (a chunk of n rows from position p: p + n of them, a bounded class no
-# more than reach + n - 1).
+# more than reach + n - 1), ``attend_rows_read_<class>`` the key rows their
+# attends WALK, a run of query rows at a time (a model whose attend takes a
+# chunk in runs — ``inference/kv_pages.attend_rows`` — reads the stream's
+# rows once a run: the ratio of the two is how often).
 CLASS_SPAN_ARGS = {
     "prefill": ("cached_tokens_<class>", "<class>_blocks_returned",
-                "context_tokens_in_reach_<class>"),
+                "context_tokens_in_reach_<class>",
+                "attend_rows_read_<class>"),
     "decode": ("<class>_blocks_live", "<class>_blocks_returned"),
 }
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")

@@ -5,8 +5,11 @@ fixed-size fp32 state a stream:
     S_t = S' + beta_t k_t (v_t - S'^T k_t)^T    (= (I - beta k k^T) S' + beta k v^T)
     o_t = S_t^T q_t
 
-for every head: ``q_t, k_t, g_t [dk]``, ``v_t [dv]``, ``beta_t`` a scalar,
-``S [dk, dv]``.  Unlike ``ops/ssm_scan.py`` and ``ops/power_retention.py``
+for every head: ``q_t, k_t, g_t [dk]``, ``v_t [dv]``, ``beta_t`` a scalar in
+(0, 1) — or in (0, 2) for a model that allows NEGATIVE EIGENVALUES
+(``models/solar_open2.py``: along a unit key the transition's eigenvalue is
+``1 - beta``, in (-1, 1), so the recurrence stays a contraction) — ``S [dk,
+dv]``.  Unlike ``ops/ssm_scan.py`` and ``ops/power_retention.py``
 (``S <- a S + outer product``) the new state depends on what the old one
 HOLDS along the key: the write reads ``k^T S'`` first, then corrects it.  The
 projections, the short convolutions, the gates and the output norm are the
@@ -49,6 +52,13 @@ A21 T11`` from there up, in float32 at ``Precision.HIGHEST``.  A row that is
 not live has ``g = 0`` and ``beta = 0``: it neither decays the state nor
 writes to it.  The scan's carried states ARE the stream's state at every
 chunk boundary, so one of them can be handed back as a snapshot (``keep``).
+With ``beta`` up to 2 the entries of ``A`` reach 2 and ``T`` grows with them:
+against ``recurrent_update`` the outputs and states read 3-4 times what
+``beta`` < 1 reads in float32 (1e-6 / 4e-6 where it reads 4e-7 / 1e-6; 2e-5
+of a largest entry of 14 at ``beta`` on (1.9, 2) under the slowest decay),
+the same relative to the state, which itself grows under a slow decay
+(``tests/test_kda.py``, PR 64); nothing in the three forms assumes ``beta`` <
+1.
 
 Products against the fp32 state run on the vector unit in the kernel and at
 ``Precision.HIGH`` (three bf16 passes) on the matrix unit in the chunked form;

@@ -213,13 +213,33 @@ def _sdar():
 ADDED_BY_PR62 = {"sdar": _sdar}
 
 
+def _solar_open2():
+    """``test_solar_open2_serving.tiny`` at the harness's blocks of 4: one
+    period G K K K, 4 : 2 attention heads under an output gate, a write
+    strength up to 2."""
+    from deepspeed_tpu.models.solar_open2 import (SolarOpen2Config,
+                                                  solar_open2_init)
+    cfg = SolarOpen2Config(
+        vocab_size=128, hidden_size=64, moe_intermediate_size=32,
+        num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=16, kda_num_heads=4, kda_head_dim=16, n_routed_experts=16,
+        held=(0, 4), num_experts_per_tok=2, max_position_embeddings=256,
+        dtype=jnp.float32)
+    return cfg, solar_open2_init(jax.random.PRNGKey(0), cfg), {
+        "num_blocks": {"full": 96, "state": 16}}
+
+
+# PR 64's: K/V pages under an output gate beside delta-rule states.
+ADDED_BY_PR64 = {"solar_open2": _solar_open2}
+
+
 def engine(family: str, kernel: bool, dp: int = 1, **extra):
     """A tiny engine of ``family`` on ``dp`` host devices (``extra``: more
     top-level config blocks, ``telemetry``)."""
     from deepspeed_tpu.inference import InferenceEngine
     from deepspeed_tpu.parallel.topology import build_mesh
-    cfg, params, inference = {**FAMILIES, **ADDED,
-                              **ADDED_BY_PR62}[family]()
+    cfg, params, inference = {**FAMILIES, **ADDED, **ADDED_BY_PR62,
+                              **ADDED_BY_PR64}[family]()
     conf = dict(max_slots=4, max_seq_len=128, block_size=4,
                 prefill_chunk=CHUNK, paged_kernel=kernel)
     conf.update(inference)
@@ -372,9 +392,10 @@ def golden(family: str, arm: str) -> dict:
 if __name__ == "__main__":
     DUMP = sys.argv[2] if len(sys.argv) > 2 else None
     # (``ADDED`` as a third argument: the families later PRs added alone;
-    # ``PR62``: the family PR 62 added)
+    # ``PR62`` / ``PR64``: the family that PR added)
     names = ADDED if "ADDED" in sys.argv[3:] else \
-        ADDED_BY_PR62 if "PR62" in sys.argv[3:] else FAMILIES
+        ADDED_BY_PR62 if "PR62" in sys.argv[3:] else \
+        ADDED_BY_PR64 if "PR64" in sys.argv[3:] else FAMILIES
     out = {family: {arm: golden(family, arm) for arm in ARMS}
            for family in sorted(names)}
     with open(sys.argv[1], "w") as f:

@@ -4,7 +4,9 @@ against the token-by-token recurrence under decays from 0.999 down to 1e-3 a
 token, across chunk and sub-block edges, from a carried state, past dead
 rows and at every state it can keep; the triangular inverse against numpy's;
 the decode kernel (interpret mode) against the plain update, dead slots
-included."""
+included; and all three where the write strength ``beta`` is drawn on (1, 2)
+(``kda_allow_neg_eigval``: Solar-Open2's layers, PR 64) and at its 64 heads
+of 128."""
 import os
 import sys
 
@@ -70,6 +72,57 @@ def test_the_chunked_form_is_the_recurrence(decay, T, chunk):
     assert np.isfinite(np.asarray(o)).all()
     np.testing.assert_allclose(o, o_want, atol=1e-5)
     np.testing.assert_allclose(S, states[-1], atol=1e-5)
+
+
+# beta on (1, 2): the transition I - beta k k^T has an eigenvalue in (-1, 0)
+# along the key.  The recurrence stays a contraction, but the chunked form's
+# A = beta . tril(K K^T . decay) has entries up to 2 and the unit-lower
+# inverse grows with them; under a slow decay the STATE grows too (to 6-14
+# where beta < 1 leaves 2-3).  Measured here in float32 (PR 64): outputs
+# within 1e-6 (3.4e-6 at beta on (1.9, 2) under the slow decay), states within
+# 4.3e-6 (2.1e-5 there, of a largest entry of 14): 3-4x what beta < 1 reads
+# and the same RELATIVE to the state, so the limits are the file's 1e-5 on
+# outputs and 1e-5 of the state's largest entry on states.
+STRONG = {"over_one": (1.0, 2.0), "near_two": (1.9, 2.0)}
+
+
+def strong(c, name, seed=9):
+    lo, hi = STRONG[name]
+    return dict(c, beta=jax.random.uniform(
+        jax.random.PRNGKey(seed), c["beta"].shape, minval=lo, maxval=hi))
+
+
+@pytest.mark.parametrize("beta", sorted(STRONG))
+@pytest.mark.parametrize("decay", sorted(DECAYS))
+@pytest.mark.parametrize("T,chunk", [(128, 64), (64, 16)])
+def test_the_chunked_form_is_the_recurrence_where_the_write_passes_one(
+        beta, decay, T, chunk):
+    c = strong(case(T, decay=decay), beta)
+    assert float(c["beta"].min()) >= 1.0
+    o_want, states = token_by_token(c)
+    o, S, kept = chunked(c, chunk, keep=jnp.int32(0))
+    scale = max(1.0, float(jnp.abs(states[-1]).max()))
+    assert np.isfinite(np.asarray(o)).all()
+    np.testing.assert_allclose(o, o_want, atol=1e-5)
+    np.testing.assert_allclose(S, states[-1], atol=1e-5 * scale)
+    np.testing.assert_allclose(kept, states[chunk - 1], atol=1e-5 * scale)
+
+
+def test_a_write_of_strength_two_reflects_the_state_along_the_key():
+    """At beta = 2 and no decay, ``I - 2 k k^T`` is a reflection: the state's
+    component along k changes sign (plus the write), its norm is kept — the
+    eigenvalue -1 the published key allows; a second such step undoes it."""
+    k = jnp.zeros((1, 1, 8)).at[0, 0, 2].set(1.0)
+    S0 = jax.random.normal(jax.random.PRNGKey(0), (1, 1, 8, 4))
+    zero_v = jnp.zeros((1, 1, 4))
+    g = jnp.zeros((1, 1, 8))
+    two = jnp.full((1, 1), 2.0)
+    _, S1 = kda.recurrent_update(S0, k * 8 ** -0.5, k, zero_v, g, two)
+    np.testing.assert_allclose(S1[0, 0, 2], -S0[0, 0, 2], atol=1e-6)
+    np.testing.assert_allclose(np.delete(S1[0, 0], 2, 0),
+                               np.delete(S0[0, 0], 2, 0), atol=1e-6)
+    _, S2 = kda.recurrent_update(S1, k * 8 ** -0.5, k, zero_v, g, two)
+    np.testing.assert_allclose(S2, S0, atol=1e-6)
 
 
 def test_a_chunk_that_is_no_multiple_of_the_sub_block_is_one_sub_block():
@@ -141,9 +194,32 @@ def test_the_decode_kernel_is_the_plain_update_in_place(pages, nh, d):
     np.testing.assert_allclose(new, want, atol=1e-6)   # other pages as were
 
 
+@pytest.mark.parametrize("beta", sorted(STRONG))
+def test_the_decode_kernel_at_64_heads_where_the_write_passes_one(beta):
+    """Solar-Open2's tile: 64 heads of 128 x 128, 16 a grid step, four steps
+    a stream; a dead slot between two live ones."""
+    nh, d = 64, 128
+    c = strong(case(3, nh=nh, dk=d, dv=d, decay="mixed", seed=3), beta)
+    pool = jax.random.normal(jax.random.PRNGKey(9), (1, 1, 3, nh, d, d))
+    pages = jnp.asarray([[2, -1, 0]], jnp.int32)
+    args = tuple(c[n][None] for n in ("q", "k", "v", "g", "beta"))
+    o, new = jax.jit(kda.state_update)(pool, 0, pages, *args)
+    live = np.asarray(pages[0] >= 0)
+    at = jnp.maximum(pages[0], 0)
+    o_want, S_want = kda.recurrent_update(pool[0, 0, at],
+                                          *(a[0] for a in args))
+    np.testing.assert_allclose(np.asarray(o[0])[live],
+                               np.asarray(o_want)[live], atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(new)[0, 0, np.asarray(at)[live]],
+        np.asarray(S_want)[live], atol=2e-6)
+    np.testing.assert_array_equal(np.asarray(new)[0, 0, 1], pool[0, 0, 1])
+
+
 def test_the_kernel_tiles_heads_under_its_budget():
     assert kda.state_tile(32, 128, 128) == (32, 128, 128)
     assert kda.tile_heads(32, 128, 128) == 16          # 1 MiB a grid step
+    assert kda.tile_heads(64, 128, 128) == 16          # four steps a stream
     assert kda.tile_heads(2, 16, 16) == 2
     assert kda.tile_heads(7, 16, 16) == 1              # 3 x 7 > 16 columns
 

@@ -776,7 +776,8 @@ def test_the_readers_list_names_the_classes_scopes_and_args():
         - set(SPAN_ARGS["prefill"]) == {
             "cached_tokens_full", "cached_tokens_window",
             "full_blocks_returned", "window_blocks_returned",
-            "context_tokens_in_reach_full", "context_tokens_in_reach_window"}
+            "context_tokens_in_reach_full", "context_tokens_in_reach_window",
+            "attend_rows_read_full", "attend_rows_read_window"}
     assert not any("full" in a or "window" in a
                    for args in SPAN_ARGS.values() for a in args)
 
@@ -1094,6 +1095,86 @@ def test_the_readers_list_names_the_kda_scopes():
 
 
 # --------------------------------------------------------------------- #
+# (k') K/V pages under an output gate beside delta-rule states (PR 64): the
+# gate's scope, both programs' KDA scopes, the decode span's context and the
+# prefill span's walked rows (a chunk of two runs reads its reach twice)
+# --------------------------------------------------------------------- #
+@pytest.fixture(scope="module")
+def solar_engine():
+    import jax.numpy as jnp
+    from deepspeed_tpu.models.solar_open2 import (SolarOpen2Config,
+                                                  solar_open2_init)
+    cfg = SolarOpen2Config(
+        vocab_size=128, hidden_size=64, moe_intermediate_size=32,
+        num_hidden_layers=4, num_attention_heads=8, num_key_value_heads=1,
+        head_dim=16, kda_num_heads=4, kda_head_dim=16, n_routed_experts=16,
+        held=(0, 4), num_experts_per_tok=2, max_position_embeddings=512,
+        dtype=jnp.float32)
+    eng = InferenceEngine(
+        cfg, solar_open2_init(jax.random.PRNGKey(0), cfg),
+        config={"inference": {"max_slots": 2, "max_seq_len": 512,
+                              "prefill_chunk": 128, "block_size": 16,
+                              "num_blocks": {"full": 64, "state": 6},
+                              "paged_kernel": False}},
+        mesh=build_mesh(devices=jax.devices()[:1]))
+    yield eng
+    eng.close()
+
+
+def test_solar_programs_carry_the_gate_and_the_kda_scopes(solar_engine):
+    from deepspeed_tpu.monitor.xplane_reader import SCOPES, scope_of
+    eng = solar_engine
+    G, W = eng.dp, eng.allocator.table_width
+    key, temp = eng._next_key(), np.float32(0.0)
+    decode = _op_names(eng._decode_fn, eng._params, *eng._pools(),
+                       eng._no_fetch, eng.last_tokens,
+                       np.ones(eng.max_slots, bool), eng.lengths,
+                       eng.block_tables, key, temp)
+    prefill = _op_names(
+        eng._prefill_fn, eng._params, *eng._pools(),
+        np.zeros((G, eng.prefill_chunk), np.int32),
+        np.zeros((G, W), np.int32), np.zeros(G, np.int32),
+        np.zeros(G, np.int32), np.ones(G, np.int32), np.zeros(G, np.int32),
+        np.zeros(G, np.int32), np.int32(1), key, temp)
+    for names, own, other in ((decode, "kda_update", "kda_chunk"),
+                              (prefill, "kda_chunk", "kda_update")):
+        for scope in ("embed", "attn/kda_proj", "attn/kda_conv",
+                      "attn/kda_gate", "attn/" + own, "attn/kda_out",
+                      "attn/qkv_proj", "attn/kv_write", "attn/attend_full",
+                      "attn/attn_gate", "attn/out_proj", "moe/router",
+                      "moe/experts", "moe/shared", "lm_head"):
+            assert any(f"/{scope}" in n for n in names), scope
+        assert not any("/" + other in n for n in names)
+    assert "attn_gate" in SCOPES
+    assert scope_of("jit(decode_step)/attn/attn_gate/logistic")[0] \
+        == ("attn", "attn_gate")
+
+
+def test_solar_spans_carry_the_context_and_the_rows_walked(tmp_path,
+                                                           solar_engine):
+    eng = solar_engine
+    rng = np.random.default_rng(0)
+    reqs = [Request(rid=i, prompt=rng.integers(0, 128, size=200 + i,
+                                               dtype=np.int32),
+                    max_new_tokens=4, arrival_s=0.0) for i in range(2)]
+    found = _session(tmp_path, lambda: eng.serve(reqs))
+    dispatched = _dispatched(found)
+    assert dispatched and all(a["context_tokens"] >= 200 * a["active"]
+                              for a in dispatched)
+    # one grouped-query layer: the key rows in reach are the context
+    assert all(a["context_tokens_in_reach"] == a["context_tokens"]
+               for a in dispatched)
+    spans = [a for _, _, a in found["prefill"]]
+    one = next(a for a in spans if a["slots"] == 1
+               and a["prompt_tokens"] == 200)
+    # two chunks of 128 and 72 rows; 8 query heads a K/V head x 128 rows is
+    # two runs of 64: each walks its own reach
+    assert one["context_tokens_in_reach_full"] == 128 + 200
+    assert one["attend_rows_read_full"] == (64 + 128) + (128 + 64 + 200)
+    assert "attend_rows_read_state" not in one
+
+
+# --------------------------------------------------------------------- #
 # (l) a model whose EVERY layer routes, from the block's input, ahead of its
 # attention (PR 54): the same scopes in both programs, the router's ops
 # named before the attend's, the admissions' class args and the totals
@@ -1188,6 +1269,11 @@ def test_prefill_spans_carry_what_the_window_returned(tmp_path,
         == 2 * (8 + 16 + 24 + 32 + 40 + 45)
     assert one["context_tokens_in_reach_window"] \
         == 6 * (8 + 15 + 15 + 15 + 15 + 12)
+    # (7 query heads a K/V head x 8 rows fit one run: a chunk's attend walks
+    # its reach once)
+    assert one["attend_rows_read_full"] == one["context_tokens_in_reach_full"]
+    assert one["attend_rows_read_window"] \
+        == one["context_tokens_in_reach_window"]
     dispatched = _dispatched(found)
     assert dispatched and all(set(a) <= set(span_args("decode", names))
                               for a in dispatched)

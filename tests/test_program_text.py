@@ -68,6 +68,17 @@ LEFT_BY_PR63 = json.load(open(os.path.join(DATA, "program_text_pr63.json")))
 # above it IS ``positions``, the same array, so their programs are text for
 # text what they were and no ``MOVED_*`` gained an entry.
 ADDED_BY_PR62 = json.load(open(os.path.join(DATA, "program_text_pr62.json")))
+# ... and the family PR 64 added (``harness.ADDED_BY_PR64``: ``... PR64``).
+# PR 64 lifted the KDA layers' page bookkeeping out of the kimi_linear
+# family's ``forward`` (``inference/kda_state.py``) and put afmoe's output
+# gate behind ``kv_pages.output_gate``: both families' programs are the same
+# operations and no ``MOVED_*`` gained an entry.
+ADDED_BY_PR64 = json.load(open(os.path.join(DATA, "program_text_pr64.json")))
+# The families added one a PR, each held to its own PR's file.
+ADDED_LATER = [(family, harness.ADDED_BY_PR62, ADDED_BY_PR62, 62)
+               for family in sorted(harness.ADDED_BY_PR62)] \
+    + [(family, harness.ADDED_BY_PR64, ADDED_BY_PR64, 64)
+       for family in sorted(harness.ADDED_BY_PR64)]
 
 # Why a program's operations are not, line for line, the ones PR 55 lowered
 # (CHANGES.md, PR 56, quotes the lines).  Where the seven copies of the
@@ -229,13 +240,17 @@ def test_an_added_familys_programs_are_what_its_pr_left(family, kind, arm):
 @pytest.mark.parametrize("arm", sorted(harness.ARMS))
 @pytest.mark.parametrize("kind", ["decode_step", "prefill_step", "outputs",
                                   "verify_step"])
-@pytest.mark.parametrize("family", sorted(harness.ADDED_BY_PR62))
-def test_the_family_of_blocks_is_what_its_pr_left(family, kind, arm):
+@pytest.mark.parametrize("family,made_by,golden,pr", ADDED_LATER,
+                         ids=[a[0] for a in ADDED_LATER])
+def test_a_family_added_later_is_what_its_pr_left(family, made_by, golden,
+                                                  pr, kind, arm):
     """PR 62's family (generation by diffusion over blocks): the bits its PR
     computed — two prefill chunks under the block mask, then four passes
     over the stream's block — and the text its PR lowered; ``verify``
-    refuses (nothing is drafted in a model of blocks)."""
-    got, want = harness.golden(family, arm), ADDED_BY_PR62[family][arm]
+    refuses (nothing is drafted in a model of blocks).  PR 64's (K/V pages
+    under an output gate beside delta-rule states): the same, by the common
+    scenario; ``verify`` refuses (a state a stream)."""
+    got, want = harness.golden(family, arm), golden[family][arm]
     if kind == "outputs":
         assert got["outputs"] == want["outputs"]
         return
@@ -245,7 +260,7 @@ def test_the_family_of_blocks_is_what_its_pr_left(family, kind, arm):
         assert not names
         with pytest.raises(NotImplementedError,
                            match="rolled back.*spec_k"):
-            served_model(harness.ADDED_BY_PR62[family]()[0]).verify(
+            served_model(made_by[family]()[0]).verify(
                 None, None, None, None, None, num_groups=1,
                 paged_kernel=False)
         return
@@ -253,7 +268,7 @@ def test_the_family_of_blocks_is_what_its_pr_left(family, kind, arm):
     for name in names:
         assert got["programs"][name]["order_free"] \
             == want["programs"][name]["order_free"], (
-            f"{family}.{arm}.{name}: other operations than PR 62 left")
+            f"{family}.{arm}.{name}: other operations than PR {pr} left")
 
 
 @pytest.mark.parametrize("families,was,now,moved_by", [

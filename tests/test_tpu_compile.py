@@ -1565,29 +1565,18 @@ def test_kda_state_update_kernel_compiles_at_the_published_widths(
         >= 6 * 8 * 32 * 128 * 128 * 4
 
 
-@pytest.fixture(scope="module")
-def latent_beside_state_programs(topo):
+def _programs_beside_a_frozen_state(topo, inf, cfg, init_fn):
     """(specs, params bytes, {program: compiled}) of the engine's own step
-    builders for ``perfbench/configs/kimi-linear-48b-a3b.json`` on an engine
-    shell (see ``_serve_program``): ``decode_step``, ``prefill_step`` at both
-    of ``prefill_widths`` and the page copy."""
-    import json
-    import os
-    import sys
+    builders on an engine shell (see ``_serve_program``) for a model whose
+    LAST class is a per-stream state the chunk program freezes: ``decode_step``,
+    ``prefill_step`` at both of ``prefill_widths`` and the page copy, under
+    the configuration file's ``serve.inference`` (``inf``)."""
     from types import SimpleNamespace
     from deepspeed_tpu.inference import kv_cache
     from deepspeed_tpu.inference.engine import (InferenceEngine,
                                                 prefill_widths)
     from deepspeed_tpu.inference.served import served_model
-    from deepspeed_tpu.models.kimi_linear import kimi_linear_init
     from jax.experimental.compilation_cache import compilation_cache
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, root)
-    from perfbench.runners import longgen
-    sizes = json.load(open(os.path.join(
-        root, "perfbench", "configs", "kimi-linear-48b-a3b.json")))
-    inf = sizes["serve"]["inference"]
-    cfg = longgen.model_config(sizes)
     served = served_model(cfg)
     one = SingleDeviceSharding(topo.devices[0])
 
@@ -1595,7 +1584,7 @@ def latent_beside_state_programs(topo):
         return jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
             tree)
-    params = on_chip(jax.eval_shape(lambda k: kimi_linear_init(k, cfg),
+    params = on_chip(jax.eval_shape(lambda k: init_fn(k, cfg),
                                     jax.random.PRNGKey(0)))
     param_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
                       for x in jax.tree_util.tree_leaves(params))
@@ -1643,6 +1632,31 @@ def latent_beside_state_programs(topo):
         compilation_cache.reset_cache()
         mp.undo()
     return specs, param_bytes, out
+
+
+def _cell_sizes(name: str) -> dict:
+    """``perfbench/configs/<name>.json`` (and the checkout on the path, for
+    the cell's runner)."""
+    import json
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    return json.load(open(os.path.join(root, "perfbench", "configs",
+                                       name + ".json")))
+
+
+@pytest.fixture(scope="module")
+def latent_beside_state_programs(topo):
+    """``_programs_beside_a_frozen_state`` for
+    ``perfbench/configs/kimi-linear-48b-a3b.json``."""
+    from deepspeed_tpu.models.kimi_linear import kimi_linear_init
+    sizes = _cell_sizes("kimi-linear-48b-a3b")
+    from perfbench.runners import longgen
+    return _programs_beside_a_frozen_state(
+        topo, sizes["serve"]["inference"], longgen.model_config(sizes),
+        kimi_linear_init)
 
 
 @pytest.mark.parametrize("program", ["decode_step", "prefill_step.256",
@@ -1714,6 +1728,90 @@ def test_latent_beside_state_fit_and_are_updated_in_place(
     if program == "decode_step":
         assert not [line for line in text.splitlines() if "/kda_conv/" in line
                     and re.search(r" (gather|scatter)\(", line)]
+
+
+# ------------------------------------------------------------------ #
+# The solar_open2 family (PR 64): K/V pages under an output gate beside
+# delta-rule states of 64 heads, at the agent-sessions cell's shapes
+# ------------------------------------------------------------------ #
+@pytest.fixture(scope="module")
+def pages_beside_delta_state_programs(topo):
+    """``_programs_beside_a_frozen_state`` for
+    ``perfbench/configs/solar-open2-250b.json``."""
+    from deepspeed_tpu.models.solar_open2 import solar_open2_init
+    sizes = _cell_sizes("solar-open2-250b")
+    from perfbench.runners import agent_sessions
+    return _programs_beside_a_frozen_state(
+        topo, sizes["serve"]["inference"],
+        agent_sessions.model_config(sizes), solar_open2_init)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_step.256",
+                                     "prefill_step.512", "state_copy"])
+def test_pages_beside_delta_states_fit_and_are_updated_in_place(
+        pages_beside_delta_state_programs, program):
+    """Weights 6.62 GB (one gated grouped-query layer and three KDA layers of
+    64 heads, four expert layers of 40 held experts, 24,576 rows of embedding
+    and of untied head) + the K/V pool (one layer x 4,096 B a token) + the
+    state pages (3 layers x (4 MiB fp32 + 144 KiB bf16 filter rows)): every
+    pool aliased to its output, scratch inside what is left of the chip's 16
+    GiB; the attend — whose table of 64 streams x 1,664 blocks of 128 is 426
+    KB of SMEM's 1 MiB — the row write, the grouped expert product and, in
+    decode, the delta-rule update are TPU custom calls; no program holds an
+    op the size of a pool."""
+    from deepspeed_tpu.analysis.hlo_text import ops_in_units_of
+    specs, param_bytes, programs = pages_beside_delta_state_programs
+    full, state = specs
+    compiled = programs[program]
+    assert param_bytes == 6617348608, param_bytes
+    assert (full.num_layers, state.num_layers) == (1, 3)
+    assert full.block_nbytes() == 2 * 128 * 8 * 128 * 2 == 524288
+    assert state.block_nbytes() == 3 * (64 * 128 * 128 * 4 + 3 * 24576 * 2) \
+        == 13025280
+    # (a page is 3,180 tokens of this model's K/V rows; beside the blocks a
+    # prompt that adds one prefill program's rows leaves a snapshot)
+    assert state.token_row_bytes == -(-4096 // 3) and state.page_tokens == 512
+    B = state.num_blocks
+    assert state.pool_shapes == {"state.state": (3, 1, B, 64, 128, 128),
+                                 "conv.state": (3, 1, B, 1, 576, 128)}
+    assert state.pool_dtypes == {"state.state": jnp.float32,
+                                 "conv.state": jnp.bfloat16}
+    assert full.max_blocks_per_slot == 1664
+    assert 64 * 1664 * 4 < 2 ** 19               # the table in SMEM
+    pool_bytes = full.nbytes() + state.nbytes()
+    assert 0.75 < (param_bytes + pool_bytes) / 2 ** 34 < 0.90
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    if program == "state_copy":
+        assert mem.temp_size_in_bytes < 64 * 2 ** 20
+        return
+    assert param_bytes + pool_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes \
+        < 15.75 * 2 ** 30, mem
+    text = compiled.as_text()
+    kernels = ["_pattn_kernel", "_kv_write_kernel", "_gswiglu_kernel"]
+    if program == "decode_step":
+        kernels.append("_kda_state_update_kernel")
+    for kernel in kernels:
+        calls = [line for line in text.splitlines()
+                 if f"%{kernel}" in line.split(" = ")[0]
+                 and " custom-call(" in line]
+        assert calls and all("tpu_custom_call" in c for c in calls), kernel
+    in_place = {"dynamic-update-slice", "fusion"} \
+        if program != "decode_step" else set()
+    for spec, pool in ((full, "k.full"), (full, "v.full"),
+                       (state, "state.state")):
+        seen = ops_in_units_of(text, math.prod(spec.pool_shapes[pool][2:]))
+        assert not [(op, n) for op, n in seen
+                    if op not in _POOL_OPS_ALLOWED | in_place], pool
+    assert "/attn/attend_full" in text and "/attn/attn_gate" in text \
+        and "/attn/kda_conv" in text
+    assert ("/attn/kda_update" if program == "decode_step"
+            else "/attn/kda_chunk") in text
+    # decode: a KDA layer's filter rows (192 sublane rows a held row) go
+    # through the pages by one kernel
+    calls = _filter_rows_calls(text)
+    assert len(calls) == (3 if program == "decode_step" else 0)
 
 
 # ------------------------------------------------------------------ #
