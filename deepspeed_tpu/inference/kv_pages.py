@@ -17,8 +17,13 @@ comes back.  A class may be a window's RING (``CacheClass.reach``); a
 ``per_stream`` class among a model's classes is its owner's business and is
 passed over.  A prefill chunk is split into runs of query rows
 (``attend_rows``), each a stream of the attend with the chunk's table, so
-that a K/V head's query rows fit the kernel's VMEM budget and a run walks
-only ITS reach.
+that a K/V head's query rows and their fp32 state fit the kernel's VMEM
+beside four heads' tiles (``ops.paged_attention._tile_rule``); a run walks
+only ITS reach.  A run of ``_DENSE_ROWS`` query rows a K/V head or more
+takes the attend's chunk-shaped body (``_pattn_chunk_kernel``: bound by its
+products, so that the runs re-read the reach costs nothing that shows —
+cell 14 reads it seven times, 3 ms of bandwidth under 7 ms of products);
+decode and verify keep ``_pattn_kernel``.
 """
 from __future__ import annotations
 
@@ -32,8 +37,11 @@ from .served import NEG_INF, CacheClass, Rows, ServedModel
 from ..ops import paged_attention as paged_attn_ops
 
 # Query rows a K/V head takes in one step of the attend kernel at most
-# (``group`` heads x the rows of a run): what keeps a step's fp32 state
-# inside ``ops.paged_attention._VMEM_BUDGET`` at head_dim 128.
+# (``group`` heads x the rows of a run): what keeps the fp32 state of four
+# heads a step inside ``ops.paged_attention._CHUNK_VMEM_BUDGET`` at head_dim
+# 128.  On the v5e at cell 14's shape a chunk's attend reads 7.35 ms in
+# runs of 512 rows a K/V head (four heads a step) and 7.35 in runs of 1,024
+# (two): the longer run buys nothing (PERF.md section 6, PR 65).
 MAX_HEAD_ROWS = 512
 
 

@@ -78,7 +78,7 @@ PALLAS_KERNEL_PATTERNS: Dict[str, str] = {
     "flash_attention": (r"flash|_fwd_kernel|_bwd_dq_kernel|_bwd_dkv_kernel"
                         r"|_bwd_fused_kernel"),
     "grouped_gemm": r"_gg_kernel|grouped_gemm",
-    "paged_attention": r"_pattn_kernel|paged_att",
+    "paged_attention": r"_pattn_(chunk_)?kernel|paged_att",
     "fused_update": r"_fused_adam_kernel|_sqnorm_kernel|fused_update",
 }
 _PALLAS_RE = {k: re.compile(v) for k, v in PALLAS_KERNEL_PATTERNS.items()}

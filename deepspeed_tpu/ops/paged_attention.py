@@ -25,8 +25,16 @@ is the query rows PER STREAM — 1 for plain decode, k+1 for speculative
 verify, the chunk width for chunked prefill. All K rows of a stream share
 its block table; ``positions[g, q, k]`` is each row's inclusive last
 attendable position (per-row causal offsets), so all three serving paths
-run the SAME kernel with no specialization: heads a step and slots a group
-come from the shapes (``_tile_rule``), under a VMEM budget.
+enter through ONE call with one plan, one walk of the live blocks and one
+mask (``_walk_blocks``).  The arithmetic inside a group has two bodies,
+picked by the shapes alone (``_dense``): a step of few query rows a K/V
+head — decode, verify — is bound by its copies and keeps
+``_pattn_kernel``; a step of ``_DENSE_ROWS`` or more — a run of a prefill
+chunk — is bound by its products and its softmax arithmetic and takes
+``_pattn_chunk_kernel`` (operands as stored, lane-wide row state, groups
+of ``_CHUNK_KEYS`` keys, no mask where no row's edge lies).  Heads a step
+and slots a group come from the shapes too (``_tile_rule``), under a VMEM
+budget.
 
 Static-shape discipline: the grid is ``(G*Q, nH/bh)`` — compile-time
 constants — and the loop over a stream's groups runs to its LIVE count, a
@@ -34,10 +42,12 @@ scalar the table state decides: a dead stream's step copies and attends
 nothing and emits zeros. Compute and HBM traffic scale with
 ceil(context/bs); the compiled shape never changes, so the serving
 engine's zero-recompile sentinel holds. bf16 pools (``kv_cache_dtype:
-bf16``) dequantize in-VMEM: tiles are upcast to fp32 at the register
-level, accumulation is fp32, and only the final output drops back to q's
-dtype. What no layer changes — live counts, tile rows, the mask's row
-limits — is an ``AttendPlan`` the caller builds once an execution.
+bf16``) dequantize in-VMEM: ``_pattn_kernel`` upcasts tiles to fp32 at
+the register level (the chunk body hands them to the MXU as they are:
+the same products on the chip), accumulation is fp32, and only the final
+output drops back to q's dtype. What no layer changes — live counts, tile
+rows, the mask's row limits — is an ``AttendPlan`` the caller builds once
+an execution.
 
 On CPU the kernel runs in interpret mode — which is how the dp=8 CPU-mesh
 tier-1 proves logit parity against the one-hot baseline.
@@ -192,17 +202,19 @@ def _head_loop(bh, body):
     jax.lax.fori_loop(0, bh // u, group, 0)
 
 
-def _pattn_kernel(nlive_ref, rows_ref, base_ref, lim_ref, q_ref, k_hbm,
-                  v_hbm, o_ref, k_buf, v_buf, sem, ahead, m_scr, l_scr,
-                  acc_scr, *, scale, bs, K, D, P):
-    """One grid step = one (stream, head block): ALL of the stream's live
-    blocks, P table slots at a time. The pools stay in HBM (``pl.ANY``);
-    the step copies group g + 1's block tiles (every head of the block:
-    one contiguous DMA a tile) into one half of ``k_buf`` / ``v_buf``
-    while it computes on group g in the other, and issues a copy for a
-    LIVE block only: a dead stream's step moves and computes nothing.
-    m / l / acc are the standard online-softmax carry, updated once a
-    group per head.
+def _walk_blocks(nlive_ref, rows_ref, base_ref, k_hbm, v_hbm, o_ref, k_buf,
+                 v_buf, sem, ahead, *, P, begin, attend, finish):
+    """The part of a grid step = one (stream, head block) that both
+    bodies of the attend share: ALL of the stream's live blocks, P table
+    slots at a time. The pools stay in HBM (``pl.ANY``); the step copies
+    group g + 1's block tiles (every head of the block: one contiguous
+    DMA a tile) into one half of ``k_buf`` / ``v_buf`` while
+    ``attend(g, slot)`` computes on group g in the other, and
+    issues a copy for a LIVE block only: a dead stream's step moves and
+    computes nothing and emits exact zeros, matching the one-hot
+    baseline's all-masked selector.  ``begin()`` empties the body's
+    online-softmax state before a live stream's first group,
+    ``finish()`` writes its output after the last.
 
     The copies run across grid steps too: during its LAST group a step
     starts the first group of the NEXT grid step (the next head block,
@@ -211,41 +223,11 @@ def _pattn_kernel(nlive_ref, rows_ref, base_ref, lim_ref, q_ref, k_hbm,
     compute and only a stream after a dead one starts cold. The grid is
     sequential and the buffers, the semaphores and ``ahead`` (SMEM:
     whether this step's first group is already in flight, and in which
-    half) live across its steps.
-
-    A head's K/V tile lies in the buffer as the pool holds it,
-    lane-dense ``[bs/f, f*D]``: position t of the block at row t // f,
-    lanes (t % f)*D.. . Nothing re-tiles it; a group's P tiles are
-    stacked into one ``[P*bs/f, f*D]`` operand (whole vregs once
-    widened). Each query row comes f times (``_paged_local`` lays copy i
-    into lanes i*D.. of an otherwise zero ``f*D``-wide row), so ONE
-    full-lane contraction against the stack gives copy i the scores of
-    the positions with t % f == i — column c of copy i is position
-    ``g*P*bs + c*f + i`` of the stream — and one against the V stack
-    gives it their weighted sum in lanes i*D.. . Each copy keeps its own
-    online-softmax state (row ``i*K + k`` of its head) and the copies
-    are merged when the stream's last group is done. With f == 1
-    (head_dim >= 128) this is the plain kernel.
-
-    The mask is one vector compare: ``lim_ref`` holds, per score row,
-    the last attendable position less the copy's lane offset i, so a
-    column is allowed iff ``g*P*bs + c*f <= lim`` (and, where the plan
-    has a window, a second column with the FIRST attendable position:
-    one more compare). The slots of the last group past the live count
-    lie wholly behind it; their K rows are whatever the buffer held and
-    their V rows are zeroed (0 x finite).
-
-    Positions here count from the first block the plan lists: the whole
-    context for an unbounded table, the first block in reach for a
-    window's ring. Grouped heads never show here: a K/V head's ``group``
-    query heads ride as ``group * K`` query rows of that one head
-    (``_paged_local``), so the contraction is ``[group*K, D] x [D,
-    keys]`` a K/V head and a block's tile is copied once for all of
-    them."""
+    half) live across its steps.  The slots of the last group past the
+    live count have their V rows zeroed (0 x finite); their K rows are
+    whatever the buffer held, and lie wholly behind the mask."""
     s_idx, hb = pl.program_id(0), pl.program_id(1)
-    bh, fK, fD = q_ref.shape[1:]
-    f = fD // D
-    N = P * (bs // f)
+    bh = k_buf.shape[2]
     nlive = nlive_ref[s_idx]
     groups = pl.cdiv(nlive, P)
 
@@ -293,6 +275,73 @@ def _pattn_kernel(nlive_ref, rows_ref, base_ref, lim_ref, q_ref, k_hbm,
             return carry
         jax.lax.fori_loop(jnp.minimum(P, nlive - g * P), P, zero_v, 0)
 
+        attend(g, slot)
+        return carry
+
+    @pl.when(groups == 0)
+    def _dead():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(groups > 0)
+    def _live():
+        begin()
+
+        @pl.when(ahead[0] == 0)
+        def _cold():
+            tiles_of(s_idx, hb, 0, ahead[1], lambda dma: dma.start())
+
+        jax.lax.fori_loop(0, groups, group, 0)
+        # What the last group started for the next step, and where.
+        ahead[1] = jax.lax.rem(ahead[1] + groups, 2)
+        ahead[0] = next_live.astype(jnp.int32)
+        finish()
+
+
+def _empty_state(m_scr, l_scr, acc_scr):
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+
+def _pattn_kernel(nlive_ref, rows_ref, base_ref, lim_ref, q_ref, k_hbm,
+                  v_hbm, o_ref, k_buf, v_buf, sem, ahead, m_scr, l_scr,
+                  acc_scr, *, scale, bs, K, D, P):
+    """The body of a step of FEW query rows (decode, verify), shaped for
+    its copies: ``_walk_blocks`` with m / l / acc the standard
+    online-softmax carry, updated once a group per head.
+
+    A head's K/V tile lies in the buffer as the pool holds it,
+    lane-dense ``[bs/f, f*D]``: position t of the block at row t // f,
+    lanes (t % f)*D.. . Nothing re-tiles it; a group's P tiles are
+    stacked into one ``[P*bs/f, f*D]`` operand (whole vregs once
+    widened). Each query row comes f times (``_paged_local`` lays copy i
+    into lanes i*D.. of an otherwise zero ``f*D``-wide row), so ONE
+    full-lane contraction against the stack gives copy i the scores of
+    the positions with t % f == i — column c of copy i is position
+    ``g*P*bs + c*f + i`` of the stream — and one against the V stack
+    gives it their weighted sum in lanes i*D.. . Each copy keeps its own
+    online-softmax state (row ``i*K + k`` of its head) and the copies
+    are merged when the stream's last group is done. With f == 1
+    (head_dim >= 128) this is the plain kernel.
+
+    The mask is one vector compare: ``lim_ref`` holds, per score row,
+    the last attendable position less the copy's lane offset i, so a
+    column is allowed iff ``g*P*bs + c*f <= lim`` (and, where the plan
+    has a window, a second column with the FIRST attendable position:
+    one more compare).
+
+    Positions here count from the first block the plan lists: the whole
+    context for an unbounded table, the first block in reach for a
+    window's ring. Grouped heads never show here: a K/V head's ``group``
+    query heads ride as ``group * K`` query rows of that one head
+    (``_paged_local``), so the contraction is ``[group*K, D] x [D,
+    keys]`` a K/V head and a block's tile is copied once for all of
+    them."""
+    bh, fK, fD = q_ref.shape[1:]
+    f = fD // D
+    N = P * (bs // f)
+
+    def attend(g, slot):
         # Inclusive per-row position mask; the final partial block
         # contributes exactly its written rows, and verify's K=k+1 rows
         # get their per-row causal offsets here.
@@ -336,7 +385,6 @@ def _pattn_kernel(nlive_ref, rows_ref, base_ref, lim_ref, q_ref, k_hbm,
             m_scr[h, :, 0:1] = m_new
 
         _head_loop(bh, head)
-        return carry
 
     # Merge each query row's f copies (softmax over the union of their
     # positions); a row that could attend nothing keeps l == 0 and emits
@@ -355,28 +403,123 @@ def _pattn_kernel(nlive_ref, rows_ref, base_ref, lim_ref, q_ref, k_hbm,
         l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
         o_ref[0, h] = (out / l_safe).astype(o_ref.dtype)
 
-    @pl.when(groups == 0)
-    def _dead():
-        # A stream with no live blocks (a dead table row — an inactive
-        # slot of the uniform group-batched program) emits exact zeros,
-        # matching the one-hot baseline's all-masked selector.
-        o_ref[...] = jnp.zeros_like(o_ref)
+    _walk_blocks(nlive_ref, rows_ref, base_ref, k_hbm, v_hbm, o_ref, k_buf,
+                 v_buf, sem, ahead, P=P,
+                 begin=lambda: _empty_state(m_scr, l_scr, acc_scr),
+                 attend=attend, finish=lambda: _head_loop(bh, merge))
 
-    @pl.when(groups > 0)
-    def _live():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
 
-        @pl.when(ahead[0] == 0)
-        def _cold():
-            tiles_of(s_idx, hb, 0, ahead[1], lambda dma: dma.start())
+def _row_bands(R: int) -> int:
+    """Query rows a band of the chunk body holds: two bands a head where
+    each is whole packed tiles (16 rows of bf16) — two independent chains
+    for the scheduler to lay side by side, one's products under the
+    other's exponentials.  On the v5e at 512 rows x 512 keys (cell 14's
+    run, ms a chunk's attend): one band 7.75, two 7.35, four 10.6; at 448
+    rows (cell 11's): 0.531 / 0.522 / 0.728 (PERF.md section 6, PR 65)."""
+    return R // 2 if R % 32 == 0 else R
 
-        jax.lax.fori_loop(0, groups, group, 0)
-        # What the last group started for the next step, and where.
-        ahead[1] = jax.lax.rem(ahead[1] + groups, 2)
-        ahead[0] = next_live.astype(jnp.int32)
-        _head_loop(bh, merge)
+
+def _pattn_chunk_kernel(nlive_ref, rows_ref, base_ref, edge_ref, lim_ref,
+                        q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
+                        ahead, m_scr, l_scr, acc_scr, *, scale, bs, P):
+    """The body of a step of MANY query rows (a run of a prefill chunk:
+    ``_DENSE_ROWS`` or more a K/V head), shaped for its products, not for
+    its copies: ``_walk_blocks`` as ``_pattn_kernel`` — the same plan, the
+    same copies of live blocks only, the same mask and output — and its
+    own arithmetic.  It declines a folded pool (head_dim 64: ``f`` > 1,
+    whose query rows ride ``f`` times in zero-padded lanes); such a chunk
+    stays on ``_pattn_kernel``.
+
+    1. Operands go into the MXU as stored (at the wider of q's dtype and
+       the pool's: bf16 x bf16 wherever both are), fp32 accumulation; the
+       probabilities go in at that dtype too.  On the chip that IS
+       ``_pattn_kernel``'s arithmetic — a float32 product at default
+       precision is one bf16 pass there, its operands rounded on the way
+       in, and at equal tiles the two bodies' outputs are equal bit for
+       bit (PERF.md section 6, PR 65) — without its converts.  m, l, the
+       exponentials, the rescale and the accumulator stay fp32.
+    2. m and l lie with every lane of a row holding the row's value: whole
+       vregs in and out, no one-lane slice of a 128-lane row.
+    3. A group is ``_CHUNK_KEYS`` keys (``_tile_rule``), so the
+       ``[rows, D]`` accumulator's rescale and the m / l update are paid
+       once per that many keys; a head's rows go in two bands
+       (``_row_bands``).
+    4. The mask is applied in the groups some row's edge lies in and
+       nowhere else: ``edge_ref`` holds, per stream, the least last and
+       (a window) the greatest first attendable position of its live
+       rows, so one scalar compare a group picks the body without the
+       iota, the compares and the selects.  A row that has seen nothing
+       yet counts its masked columns (exp(0) each) until its first real
+       score's alpha = 0 drops them; one that never sees any (a dead row
+       of the chunk) is zeroed at the end, as ``_pattn_kernel`` emits
+       it."""
+    s_idx = pl.program_id(0)
+    bh, R, D = q_ref.shape[1:]
+    N = P * bs
+    band = _row_bands(R)
+    window = lim_ref.shape[2] == 2
+    lanes = m_scr.shape[2]
+    dtype = jnp.promote_types(q_ref.dtype, k_buf.dtype)
+
+    def wide(x, n):
+        """``x [rows, lanes]``, every lane its row's value, ``n`` wide."""
+        if n % lanes == 0:
+            return x if n == lanes else pltpu.repeat(x, n // lanes, axis=1)
+        return x[:, 0:1]
+
+    def attend(g, slot):
+        first = g * N
+        clear = first + (N - 1) <= edge_ref[s_idx, 0]
+        if window:
+            clear = jnp.logical_and(clear, first >= edge_ref[s_idx, 1])
+
+        def heads(masked):
+            def head(h):
+                k = k_buf[slot, :, h].reshape(N, D).astype(dtype)
+                v = v_buf[slot, :, h].reshape(N, D).astype(dtype)
+                for r0 in range(0, R, band):
+                    rows = slice(r0, r0 + band)
+                    s = jax.lax.dot_general(
+                        q_ref[0, h, rows].astype(dtype), k,
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+                    if masked:
+                        col = jax.lax.broadcasted_iota(
+                            jnp.int32, (band, N), 1) + first
+                        edges = lim_ref[0, rows]
+                        allowed = col <= edges[:, 0:1]
+                        if window:
+                            allowed = jnp.logical_and(
+                                allowed, col >= edges[:, 1:2])
+                        s = jnp.where(allowed, s, NEG_INF)
+                    m_prev = m_scr[h, rows]
+                    m_new = jnp.maximum(
+                        m_prev, jnp.max(s, axis=1, keepdims=True))
+                    alpha = jnp.exp(m_prev - m_new)
+                    p = jnp.exp(s - wide(m_new, N))
+                    l_scr[h, rows] = l_scr[h, rows] * alpha \
+                        + jnp.sum(p, axis=1, keepdims=True)
+                    pv = jax.lax.dot_general(
+                        p.astype(dtype), v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    acc_scr[h, rows] = acc_scr[h, rows] * wide(alpha, D) + pv
+                    m_scr[h, rows] = m_new
+            _head_loop(bh, head)
+
+        pl.when(clear)(lambda: heads(False))
+        pl.when(jnp.logical_not(clear))(lambda: heads(True))
+
+    def emit(h):
+        seen = jnp.logical_and(m_scr[h, :, 0:1] > 0.5 * NEG_INF,
+                               lim_ref[0][:, 0:1] >= 0)
+        l = l_scr[h, :, 0:1]
+        out = acc_scr[h] / jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, h] = jnp.where(seen, out, 0.0).astype(o_ref.dtype)
+
+    _walk_blocks(nlive_ref, rows_ref, base_ref, k_hbm, v_hbm, o_ref, k_buf,
+                 v_buf, sem, ahead, P=P,
+                 begin=lambda: _empty_state(m_scr, l_scr, acc_scr),
+                 attend=attend, finish=lambda: _head_loop(bh, emit))
 
 
 # One step's VMEM: the rule below keeps its reckoning under this, well
@@ -420,6 +563,44 @@ _GROUP_BYTES = 2 ** 21
 # Query rows from which a step's products fill the MXU: such a step (a
 # prefill chunk) is bound by them, not by the copies behind them.
 _DENSE_ROWS = 128
+# Keys a group of such a step holds (``_pattn_chunk_kernel``): the
+# ``[rows, D]`` accumulator's rescale and the m / l update are paid once a
+# group.  On the v5e at cell 14's run (512 query rows a K/V head, blocks of
+# 128, 70k cached rows; ms a chunk's attend, ``_pattn_kernel`` 28.6), four
+# heads a step: 128 / 256 / 512 keys 12.9 / 9.1 / 7.75 in one row band, 512 /
+# 1,024 keys 7.35 / 7.32 in two; heads a step at 512 keys, 1 / 2 / 4: 10.1 /
+# 8.6 / 7.75 (8: 7.07 at a reckoning of 15.9 MiB, over the budget below);
+# runs of twice the rows, two heads a step: 7.35 (PERF.md section 6, PR 65).
+_CHUNK_KEYS = 512
+# What the chunk body's reckoning may reach (``_chunk_vmem_bytes`` counts a
+# band's score tile and its exponentials too, which ``_step_vmem_bytes``
+# leaves to its margin): compiled for a described v5e inside ``_VMEM_LIMIT``
+# at every cell's shape (tests/test_tpu_compile.py).
+_CHUNK_VMEM_BUDGET = 12 * 2 ** 20
+
+
+def _dense(K: int, D: int, bs: int) -> bool:
+    """Whether a step of K query rows a K/V head takes the chunk body
+    (``_pattn_chunk_kernel``): a function of the shapes alone."""
+    return K >= _DENSE_ROWS and _fold(D, bs) == 1
+
+
+def _chunk_vmem_bytes(bh: int, P: int, K: int, D: int, bs: int,
+                      itemsize: int, q_itemsize: int) -> int:
+    """``_step_vmem_bytes`` of the chunk body: both halves of the K and V
+    buffers, the pipeline's two buffers of the q / limit / output blocks,
+    the fp32 state (m and l a full 128 lanes a row) and a band's values in
+    flight (scores, exponentials, the probabilities as the MXU takes
+    them, their product)."""
+    Dl = -(-D // 128) * 128
+    pad = lambda rows, size: -(-rows // (32 // size)) * (32 // size)  # noqa: E731
+    kv = 2 * P * bh * pad(bs, itemsize) * Dl * itemsize
+    q = bh * pad(K, q_itemsize) * Dl * q_itemsize
+    lim = pad(K, 4) * 128 * 4
+    scratch = bh * pad(K, 4) * (2 * 128 + Dl) * 4
+    live = _row_bands(K) * (P * bs * (8 + max(itemsize, q_itemsize))
+                            + Dl * 4)
+    return 2 * (kv + 2 * q + lim) + scratch + live
 
 
 def _tile_rule(K: int, nH: int, D: int, bs: int, J: int, itemsize: int,
@@ -435,8 +616,19 @@ def _tile_rule(K: int, nH: int, D: int, bs: int, J: int, itemsize: int,
     costs is its sequencing and the latency of its copies, a group each,
     so it wants its bytes in few groups (wide heads in long blocks reach
     128 lanes with a fifth of the bytes narrow ones in short blocks
-    do)."""
+    do).
+
+    A step the chunk body takes (``_dense``) is bound by its products and
+    its softmax arithmetic: slots for ``_CHUNK_KEYS`` keys a group, and
+    the most heads that keep ``_chunk_vmem_bytes`` under
+    ``_CHUNK_VMEM_BUDGET``."""
     f = _fold(D, bs)
+    if _dense(K, D, bs):
+        P = max(1, min(J, _CHUNK_KEYS // bs))
+        return max(bh for bh in range(1, nH + 1) if nH % bh == 0 and (
+            bh == 1 or _chunk_vmem_bytes(bh, P, K, D, bs, itemsize,
+                                         q_itemsize) <= _CHUNK_VMEM_BUDGET
+        )), P
     fits = lambda bh, P: _step_vmem_bytes(  # noqa: E731
         bh, P, K, D, bs, itemsize, q_itemsize) <= _VMEM_BUDGET
     P = max(1, min(J, 128 // max(1, bs // f)))
@@ -455,6 +647,8 @@ def _pool_rows(pool):
     tile of the stacked pool as one row of a 4-D array (a bitcast), so a
     kernel's index map names a tile by ONE precomputed index."""
     return pool.reshape((-1,) + pool.shape[3:])
+
+
 class AttendPlan(NamedTuple):
     """What the attend needs of the tables and positions, which no layer
     changes: ``_paged_forward`` builds it once an execution.
@@ -634,19 +828,30 @@ def _paged_local(q, pool_k, pool_v, layer, nlive, rows, lim, *, scale,
     base = (layer * (G * B)).astype(jnp.int32).reshape(1)
     rows = jnp.pad(rows.reshape(GQ, J), ((0, 0), (0, -J % P_)))
 
-    def _stream_map(s, h, nl_p, rows_p, base_p):
+    def _stream_map(s, h, *scalars_p):
         return (s, h, 0, 0)
 
-    def _lim_map(s, h, nl_p, rows_p, base_p):
+    def _lim_map(s, h, *scalars_p):
         return (s, 0, 0)
 
     kv_buf = pltpu.VMEM((2, P_, bh, bsf, fD), pool_k.dtype)
     edges = lim.shape[-1]            # 1, or 2 with a window's lower edge
+    scalars = [nlive.reshape(GQ), rows, base]
+    body, static = _pattn_kernel, dict(K=K, D=D)
+    if _dense(K, D, bs):
+        # Per stream, where its rows' masks bite: the least last and (a
+        # window) the greatest first attendable position of its live rows.
+        edge = lim.reshape(GQ, K, edges)
+        alive = edge[..., :1] >= 0
+        far = jnp.iinfo(jnp.int32).max
+        scalars.append(jnp.concatenate(
+            [jnp.min(jnp.where(alive, edge[..., :1], far), axis=1),
+             jnp.max(jnp.where(alive, edge[..., 1:], -far), axis=1)], axis=1))
+        body, static = _pattn_chunk_kernel, {}
     out = pl.pallas_call(
-        functools.partial(_pattn_kernel, scale=scale, bs=bs, K=K, D=D,
-                          P=P_),
+        functools.partial(body, scale=scale, bs=bs, P=P_, **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(scalars),
             grid=(GQ, nH // bh),
             in_specs=[pl.BlockSpec((1, f * K, edges), _lim_map),
                       pl.BlockSpec((1, bh, f * K, fD), _stream_map),
@@ -663,9 +868,9 @@ def _paged_local(q, pool_k, pool_v, layer, nlive, rows, lim, *, scale,
         out_shape=[jax.ShapeDtypeStruct((GQ, nH, K, D), q.dtype)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
-        name="_pattn_kernel",
+        name=body.__name__,
         interpret=_interpret(),
-    )(nlive.reshape(GQ), rows, base, lim.reshape(GQ, f * K, edges), q,
+    )(*scalars, lim.reshape(GQ, f * K, edges), q,
       _pool_rows(pool_k), _pool_rows(pool_v))
     if grp == 1:
         return jnp.swapaxes(out[0], 1, 2).reshape(G, Q, K, nH, D)

@@ -233,13 +233,36 @@ def _solar_open2():
 ADDED_BY_PR64 = {"solar_open2": _solar_open2}
 
 
+def _afmoe_dense():
+    """``_afmoe`` at ONE K/V head of 128 under sixteen query heads: a chunk
+    of 8 positions is 128 query rows a K/V head and nothing folds
+    (``ops.paged_attention._dense``), so the kernel arm's ``prefill_step``
+    takes the attend's chunk-shaped body in both classes (the full table
+    and the window's ring) while ``decode_step`` (16 rows) and
+    ``verify_step`` (48) keep ``_pattn_kernel``."""
+    from deepspeed_tpu.models.afmoe import AfmoeConfig, afmoe_init
+    cfg = AfmoeConfig(
+        vocab_size=128, hidden_size=64, intermediate_size=96,
+        moe_intermediate_size=32, num_hidden_layers=5, num_dense_layers=1,
+        num_attention_heads=16, num_key_value_heads=1, head_dim=128,
+        num_experts=8, num_experts_per_tok=2, sliding_window=8,
+        max_position_embeddings=256, dtype=jnp.float32)
+    return cfg, afmoe_init(jax.random.PRNGKey(0), cfg), {
+        "num_blocks": {"full": 64, "window": 40}}
+
+
+# PR 65's: a chunk whose runs are dense (``tests/test_program_text.py``
+# holds it to the text the PARENT of PR 65 lowered for it and to PR 65's).
+ADDED_BY_PR65 = {"afmoe_dense": _afmoe_dense}
+
+
 def engine(family: str, kernel: bool, dp: int = 1, **extra):
     """A tiny engine of ``family`` on ``dp`` host devices (``extra``: more
     top-level config blocks, ``telemetry``)."""
     from deepspeed_tpu.inference import InferenceEngine
     from deepspeed_tpu.parallel.topology import build_mesh
     cfg, params, inference = {**FAMILIES, **ADDED, **ADDED_BY_PR62,
-                              **ADDED_BY_PR64}[family]()
+                              **ADDED_BY_PR64, **ADDED_BY_PR65}[family]()
     conf = dict(max_slots=4, max_seq_len=128, block_size=4,
                 prefill_chunk=CHUNK, paged_kernel=kernel)
     conf.update(inference)
@@ -392,10 +415,11 @@ def golden(family: str, arm: str) -> dict:
 if __name__ == "__main__":
     DUMP = sys.argv[2] if len(sys.argv) > 2 else None
     # (``ADDED`` as a third argument: the families later PRs added alone;
-    # ``PR62`` / ``PR64``: the family that PR added)
+    # ``PR62`` / ``PR64`` / ``PR65``: the fixture that PR added)
     names = ADDED if "ADDED" in sys.argv[3:] else \
         ADDED_BY_PR62 if "PR62" in sys.argv[3:] else \
-        ADDED_BY_PR64 if "PR64" in sys.argv[3:] else FAMILIES
+        ADDED_BY_PR64 if "PR64" in sys.argv[3:] else \
+        ADDED_BY_PR65 if "PR65" in sys.argv[3:] else FAMILIES
     out = {family: {arm: golden(family, arm) for arm in ARMS}
            for family in sorted(names)}
     with open(sys.argv[1], "w") as f:

@@ -278,16 +278,20 @@ class TestKernelParity:
         # ... a float32 pool's slot is twice the bytes ...
         ("decode_f32_pool", 8, 528, 4, (4, 8)),
         # ... and a prefill run of 64 rows x 8 heads = 512 query rows a
-        # K/V head keeps two: its products fill the MXU, and four slots
-        # would not fit beside its fp32 state.
-        ("prefill_run_512_rows", 512, 528, 2, (4, 2)),
-        ("prefill_run_window", 512, 41, 2, (4, 2)),
+        # K/V head takes the chunk body (PR 65): eight slots = 512 keys a
+        # group, reckoned by ``_chunk_vmem_bytes``.
+        ("prefill_run_512_rows", 512, 528, 2, (4, 8)),
+        ("prefill_run_window", 512, 41, 2, (4, 8)),
     ])
     def test_tile_rule_at_wide_grouped_heads(self, what, K, J, itemsize,
                                              tiles):
         got = pa._tile_rule(K, 4, 128, 64, J, itemsize, 2)
         assert got == tiles
         bh, P = got
+        if K >= pa._DENSE_ROWS:
+            assert pa._chunk_vmem_bytes(bh, P, K, 128, 64, itemsize, 2) \
+                <= pa._CHUNK_VMEM_BUDGET
+            return
         assert pa._step_vmem_bytes(bh, P, K, 128, 64, itemsize, 2) \
             <= pa._VMEM_BUDGET
         if K < pa._DENSE_ROWS and 2 * P <= J:
@@ -436,6 +440,262 @@ class TestWideGroupedHeads:
         np.testing.assert_allclose(_wide_kernel(q, pools, bt, pos, None,
                                                 None),
                                    want, rtol=2 ** -8, atol=2 ** -10)
+
+
+# --------------------------------------------------------------------- #
+# A step of many query rows (a run of a prefill chunk: ``_DENSE_ROWS`` or
+# more a K/V head) takes the chunk-shaped body, ``_pattn_chunk_kernel``
+# --------------------------------------------------------------------- #
+def _chunk_case(seed, starts, rows, *, reach=None, J, nKV=2, grp=8, D=128,
+                bs=64, runs=2, dead_rows=0, shared_blocks=0,
+                dtype=jnp.float32, pool_dtype=None):
+    """Prompts mid-prefill as ``kv_pages.paged_classes`` hands them to the
+    attend: prompt i has ``starts[i]`` rows cached (-1: a dead slot) and
+    its chunk of ``runs * rows`` rows at the positions behind them, cut
+    into ``runs`` streams of ``rows`` query rows that share the prompt's
+    table (a window's: a ring of J slots holding the blocks in reach).
+    ``dead_rows``: the chunk's last rows are padding that attends nothing
+    (-1).  ``shared_blocks``: the prompts' first blocks are the same pool
+    blocks.  Returns q, pools, the tables, positions."""
+    rng = np.random.default_rng(seed)
+    chunk = runs * rows
+    first = [0 if reach is None else max(0, s_ - reach + 1) // bs
+             for s_ in starts]
+    B = sum((s_ + chunk - 1) // bs - f0 + 1
+            for s_, f0 in zip(starts, first) if s_ >= 0) + 3
+    pools = [jnp.asarray(rng.normal(size=(2, 1, B, nKV, bs, D)),
+                         pool_dtype or dtype) for _ in range(2)]
+    Q = len(starts) * runs
+    q = jnp.asarray(rng.normal(size=(1, Q, rows, nKV * grp, D)), dtype)
+    bt = np.full((1, Q, J), kv_cache.DEAD_BLOCK, np.int32)
+    pos = np.full((1, Q, rows), -1, np.int32)
+    free = list(rng.permutation(B))
+    shared = [free.pop() for _ in range(shared_blocks)]
+    for i, (s_, f0) in enumerate(zip(starts, first)):
+        if s_ < 0:
+            continue
+        for j in range(f0, (s_ + chunk - 1) // bs + 1):
+            bt[0, i * runs:(i + 1) * runs, j % J] = \
+                shared[j] if j < shared_blocks else free.pop()
+        at = s_ + np.arange(chunk)
+        at[chunk - dead_rows:] = -1
+        pos[0, i * runs:(i + 1) * runs] = at.reshape(runs, rows)
+    return q, pools, jnp.asarray(bt), jnp.asarray(pos)
+
+
+def _kernels_of(fn, *args):
+    """Names of the ``pallas_call``s in ``fn(*args)``'s jaxpr."""
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["name"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return names
+
+
+class TestChunkBody:
+    def _held(self, case, reach, tiles=None, grp=8):
+        got = _wide_kernel(*case, reach, tiles, grp=grp)
+        np.testing.assert_allclose(got, _wide_baseline(*case, reach),
+                                   atol=2e-5, rtol=2e-5)
+        return got
+
+    @pytest.mark.parametrize("rows,grp,name", [
+        (16, 8, "_pattn_chunk_kernel"),      # 128 rows a K/V head
+        (15, 8, "_pattn_kernel"),            # 120
+        (4, 8, "_pattn_kernel"),             # a block of 4 positions (SDAR)
+        (64, 7, "_pattn_chunk_kernel"), (64, 5, "_pattn_chunk_kernel"),
+        (1, 8, "_pattn_kernel"), (5, 8, "_pattn_kernel")])
+    def test_the_body_follows_the_rows_a_kv_head(self, rows, grp, name):
+        q, pools, bt, pos = _chunk_case(20, [100], rows, J=8, grp=grp, runs=1)
+        plan = pa.attend_plan(bt, pos, pools[0], 128, group=grp)
+        assert _kernels_of(lambda q: pa.paged_attention(
+            q, pools[0], pools[1], 1, plan=plan, scale=1.0), q) == [name]
+
+    def test_a_folded_pool_stays_on_the_decode_body(self):
+        # head_dim 64 (GPT-2, lfm2): two positions side by side in the
+        # lanes, each query row twice — the chunk body declines it
+        q, pk, pv, bt, pos, sc = _case(7, [[7]], K=128, D=64, bs=16, B=16,
+                                       J=12)
+        assert not pa._dense(128, 64, 16) and pa._dense(128, 128, 16)
+        held = lambda p: kv_cache.paged_folded_view(p[None])   # noqa: E731
+        assert _kernels_of(lambda q: pa.paged_attention(
+            q, held(pk), held(pv), 0, bt, pos, scale=sc), q) \
+            == ["_pattn_kernel"]
+
+    @pytest.mark.parametrize("tiles", [None, (2, 1), (1, 2), (2, 3), (1, 16)])
+    def test_ragged_contexts_and_partial_last_blocks(self, tiles):
+        # chunks that start inside a block, on a block's first row, at the
+        # prompt's first token; each row's causal limit is its own (row k
+        # of a run reaches its position and no further), the last run's
+        # last block is partly filled, and P = 3 divides no live count
+        case = _chunk_case(21, [1000, 64, 0, 37], 16, J=20)
+        got = self._held(case, None, tiles)
+        # regrouping the online softmax moves a float32 sum's order ...
+        np.testing.assert_allclose(got, _wide_kernel(*case, None, (2, 2)),
+                                   atol=2e-6, rtol=2e-6)
+        # ... and heads a step not a bit
+        if tiles is not None:
+            np.testing.assert_array_equal(
+                got, _wide_kernel(*case, None, (1, tiles[1])))
+
+    @pytest.mark.parametrize("start", [0, 130, 1000, 4000])
+    @pytest.mark.parametrize("tiles", [None, (2, 1), (2, 2)])
+    def test_a_windows_ring(self, start, tiles):
+        # a window of 200 positions behind a ring of 8 blocks: a chunk
+        # inside its first blocks, one whose first rows still see the
+        # prompt's start, ones whose ring has wrapped many times
+        self._held(_chunk_case(22, [start], 16, reach=200, J=8), 200, tiles)
+
+    def test_dead_streams_and_dead_rows_emit_exact_zeros(self):
+        # a dead slot between two prompts (the stream after it starts
+        # cold), and a last chunk whose tail is padding: 21 dead rows end
+        # one run (16 rows) and part of the run before
+        case = _chunk_case(23, [300, -1, 77], 16, J=10, dead_rows=21)
+        got = self._held(case, None)
+        assert not got[0, 2:4].any()                # the dead slot's runs
+        assert not got[0, 1].any() and not got[0, 0, 11:].any()
+        assert got[0, 0, :11].any()
+        alone = _wide_kernel(case[0][:, 4:], case[1], case[2][:, 4:],
+                             case[3][:, 4:], None, None)
+        np.testing.assert_array_equal(got[:, 4:], alone)
+
+    def test_dead_rows_under_a_window(self):
+        case = _chunk_case(24, [500, 90], 16, reach=200, J=8, dead_rows=5)
+        got = self._held(case, 200)
+        assert not got[0, 1, 11:].any() and not got[0, 3, 11:].any()
+
+    def test_shared_prefix_blocks(self):
+        # two prompts whose first three blocks are the SAME pool blocks
+        # (a cached prefix), in one call
+        case = _chunk_case(25, [200, 260], 16, J=8, shared_blocks=3)
+        bt = np.asarray(case[2])
+        assert (bt[0, 0, :3] == bt[0, 2, :3]).all() \
+            and bt[0, 0, 3] != bt[0, 2, 3]
+        self._held(case, None)
+
+    @pytest.mark.parametrize("grp,rows", [(8, 16), (7, 32), (5, 32), (1, 128)])
+    @pytest.mark.parametrize("reach,J", [(None, 12), (256, 8)],
+                             ids=["full", "window"])
+    def test_grouped_heads(self, grp, rows, reach, J):
+        # 8 (Trinity-Mini, SDAR, Solar-Open2), 7 (SmallThinker: 224 rows a
+        # K/V head, two bands of 112) and 5 (Falcon-H1: 160 rows, one
+        # band of them) query heads a K/V head; one (rows alone)
+        case = _chunk_case(26, [333, 70], rows, reach=reach, J=J, grp=grp,
+                           nKV=2 if grp > 1 else 3)
+        self._held(case, reach, grp=grp)
+
+    @pytest.mark.parametrize("what,kw,reach", [
+        # `serve.solar-open2-250b.agent-sessions-over`: 8 heads a K/V head,
+        # blocks of 128, sessions of 18k rows and more
+        ("cell14", dict(starts=[18000, 31000], rows=64, J=256, bs=128), None),
+        # `serve.smallthinker-21b-a3b.paste-over`: 7 heads a K/V head,
+        # blocks of 64, the full class and the window's ring
+        ("cell11_full", dict(starts=[2100, 9000], rows=64, J=160, grp=7),
+         None),
+        ("cell11_window", dict(starts=[2100, 9000], rows=64, J=13, grp=7),
+         512)])
+    def test_bf16_pool_and_queries_at_the_cells_widths(self, what, kw, reach):
+        # bf16 q and pools as the cells hold them, at the cells' runs of
+        # rows and under the shape rule's own tiles, against the baseline
+        # on the same values in float32; the tolerance is
+        # ``TestWideGroupedHeads``' (the output's rounding to bf16).  The
+        # probabilities go into the MXU as bf16 here, which is what the
+        # chip makes of ``_pattn_kernel``'s float32 product too (PERF.md
+        # section 6, PR 65: equal bits at equal tiles); over contexts as
+        # long as the cells' that rounding averages out (the next test
+        # holds it at a short one).
+        grp = kw.get("grp", 8)
+        q, pools, bt, pos = _chunk_case(27, runs=2, dtype=jnp.bfloat16, **kw)
+        want = _wide_baseline(q.astype(jnp.float32),
+                              [x.astype(jnp.float32) for x in pools], bt,
+                              pos, reach)
+        np.testing.assert_allclose(
+            _wide_kernel(q, pools, bt, pos, reach, None, grp=grp), want,
+            rtol=2 ** -8, atol=2 ** -10)
+
+    @pytest.mark.parametrize("grp", [8, 7])
+    def test_the_probabilities_go_in_as_the_mxu_takes_them(self, grp):
+        # A prompt's FIRST chunks (30 rows cached, then 128 of its own: one
+        # group of keys): the output is the softmax whose numerators are
+        # rounded to bf16 on their way into the value product and whose
+        # denominator is not — float32 statistics, bf16 operands — to the
+        # output's own rounding.
+        nKV, D, bs, rows = 2, 128, 64, 64
+        q, pools, bt, pos = _chunk_case(30, [30], rows, J=4, nKV=nKV,
+                                        grp=grp, dtype=jnp.bfloat16)
+        got = _wide_kernel(q, pools, bt, pos, None, None, grp=grp)
+        f32 = lambda x: np.asarray(x.astype(jnp.float32))     # noqa: E731
+        blocks = np.asarray(bt)[0, 0, :3]
+        k, v = (f32(pool)[1, 0, blocks].transpose(1, 0, 2, 3)
+                .reshape(nKV, 3 * bs, D) for pool in pools)
+        qs = f32(q)[0].reshape(2 * rows, nKV, grp, D)
+        s = np.einsum("rngd,ntd->rngt", qs, k) * np.float32(D ** -0.5)
+        seen = np.arange(3 * bs)[None, :] <= np.asarray(pos).reshape(-1, 1)
+        s = np.where(seen[:, None, None, :], s, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        want = np.einsum("rngt,ntd->rngd", f32(jnp.asarray(p, jnp.bfloat16)),
+                         v) / p.sum(-1, keepdims=True)
+        np.testing.assert_allclose(
+            got[0].reshape(2 * rows, nKV, grp, D), want,
+            rtol=2 ** -8, atol=2 ** -10)
+
+    def test_an_fp32_pool_keeps_fp32_operands(self):
+        # float32 rows under float32 queries: the tight tolerance of the
+        # float32 cases above is this test's too; bf16 queries over a
+        # float32 pool go in as float32 (the wider of the two)
+        self._held(_chunk_case(28, [700, 3], 16, J=16), None)
+        q, pools, bt, pos = _chunk_case(28, [700, 3], 16, J=16,
+                                        dtype=jnp.bfloat16,
+                                        pool_dtype=jnp.float32)
+        want = _wide_baseline(q.astype(jnp.float32), pools, bt, pos, None)
+        np.testing.assert_allclose(
+            _wide_kernel(q, pools, bt, pos, None, None), want,
+            rtol=2 ** -8, atol=2 ** -10)
+
+    def test_a_head_wider_than_the_lanes(self):
+        # head_dim 256: the row state is 128 lanes wide, the accumulator
+        # 256 — alpha goes in twice side by side
+        self._held(_chunk_case(29, [150], 16, J=8, D=256, bs=32), None)
+
+    @pytest.mark.parametrize("what,args,tiles", [
+        # cell 14: 512 rows a K/V head, 8 K/V heads, blocks of 128, a
+        # table of 1,664 — four slots = 512 keys a group, four heads a
+        # step (eight would ask 15.9 MiB)
+        ("cell14", (512, 8, 128, 128, 1664, 2, 2), (4, 4)),
+        # cell 6 (Trinity-Mini) and cell 13 (SDAR): 512 rows, blocks of 64
+        ("cell6_full", (512, 4, 128, 64, 528, 2, 2), (4, 8)),
+        # cell 11: 448 rows, 4 K/V heads, blocks of 64, table 256 / ring 73
+        ("cell11_full", (448, 4, 128, 64, 256, 2, 2), (4, 8)),
+        ("cell11_window", (448, 4, 128, 64, 73, 2, 2), (4, 8)),
+        # cell 9 (Falcon-H1): 320 rows
+        ("cell9", (320, 4, 128, 64, 48, 2, 2), (4, 8)),
+        # a table narrower than a group; a float32 pool's tiles are twice
+        # the bytes: fewer heads a step
+        ("short_table", (512, 4, 128, 64, 3, 2, 2), (4, 3)),
+        ("f32_pool", (512, 8, 128, 128, 1664, 4, 4), (2, 4)),
+        # blocks longer than a group's keys: one slot
+        ("long_blocks", (128, 2, 128, 1024, 8, 2, 2), (2, 1)),
+    ])
+    def test_tile_rule_of_a_dense_step(self, what, args, tiles):
+        assert pa._dense(*args[:1], args[2], args[3])
+        assert pa._tile_rule(*args) == tiles
+        K, nH, D, bs, J, itemsize, q_itemsize = args
+        assert pa._chunk_vmem_bytes(*tiles, K, D, bs, itemsize, q_itemsize) \
+            <= pa._CHUNK_VMEM_BUDGET < pa._VMEM_LIMIT
+        bh = tiles[0]
+        if bh < nH:
+            wider = min(b for b in range(bh + 1, nH + 1) if nH % b == 0)
+            assert pa._chunk_vmem_bytes(wider, tiles[1], K, D, bs, itemsize,
+                                        q_itemsize) > pa._CHUNK_VMEM_BUDGET
+
+    def test_row_bands(self):
+        assert [pa._row_bands(R) for R in (512, 448, 320, 128, 136, 1024)] \
+            == [256, 224, 160, 64, 136, 512]
 
 
 # --------------------------------------------------------------------- #

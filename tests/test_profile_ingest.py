@@ -92,6 +92,7 @@ class TestClassifyOp:
                  "_fwd_kernel": "flash_attention",
                  "_gg_kernel": "grouped_gemm",
                  "_pattn_kernel": "paged_attention",
+                 "_pattn_chunk_kernel": "paged_attention",
                  "_fused_adam_kernel": "fused_update"}
         for name, family in cases.items():
             assert classify_op(name) == ("pallas", family), name

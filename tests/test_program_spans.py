@@ -457,9 +457,9 @@ def test_attend_cold_steps_by_hand():
     # tiles): 500 live blocks are 32 groups, 33 three, 16 and 1 one, a
     # dead stream one empty step ...
     (8, 16, (38, 37)),
-    # ... a prefill run's 512 rows keep two slots a group (the parent's
-    # rule for decode too: 250 + 17 + 8 + 1 groups).
-    (512, 2, (277, 276)),
+    # ... a prefill run's 512 rows take the chunk body (PR 65) at eight
+    # slots = 512 keys a group: 63 + 5 + 2 + 1 groups.
+    (512, 8, (72, 71)),
 ])
 def test_attend_step_counts_at_wide_grouped_heads(K, slots, by_hand):
     from deepspeed_tpu.ops.paged_attention import (_tile_rule,

@@ -27,7 +27,10 @@ engine's three programs are ``ServedModel``'s, written once over a family's
      PR 60 left (``program_text_pr60.json``), and since PR 63 — a decode
      step's selection reads a shared block's pooled keys once, and both
      programs count the blocks their selection gathered — to the text PR 63
-     left (``program_text_pr63.json``, ``MOVED_BY_PR63``).  A program that
+     left (``program_text_pr63.json``, ``MOVED_BY_PR63``); PR 65's fixture
+     (a chunk whose runs are dense: the attend's chunk-shaped body) to what
+     its PR left and, but for ``MOVED_BY_PR65``, to what the parent of PR 65
+     lowered for it (``program_text_pr65.json``).  A program that
      lowers
      to none of them fails: run ``python tests/decode_step_hlo.py OUT.json
      DIR`` on both trees and ``diff`` the blanked texts to see which lines
@@ -74,6 +77,26 @@ ADDED_BY_PR62 = json.load(open(os.path.join(DATA, "program_text_pr62.json")))
 # gate behind ``kv_pages.output_gate``: both families' programs are the same
 # operations and no ``MOVED_*`` gained an entry.
 ADDED_BY_PR64 = json.load(open(os.path.join(DATA, "program_text_pr64.json")))
+# PR 65 gave the attend of a step of ``_DENSE_ROWS`` query rows a K/V head or
+# more a body of its own.  No fixture above has such a step (chunks of 8 rows
+# under at most seven query heads a K/V head, and a head_dim of 16 folds):
+# every program of theirs, both arms, is text for text the parent's and no
+# ``MOVED_*`` gained an entry.  ``harness.ADDED_BY_PR65`` is the fixture that
+# HAS one (``python tests/decode_step_hlo.py OUT.json DIR PR65``):
+# ``program_text_pr65.json`` holds what the PARENT of PR 65 lowered for it
+# (``was``: the same harness file over ``git archive`` of 7e6290f) beside what
+# PR 65 left (``left``).
+PR65 = json.load(open(os.path.join(DATA, "program_text_pr65.json")))
+CHUNK_BODY = ("a run of a prefill chunk that is `_DENSE_ROWS` query rows a K/V "
+              "head or more, over a pool that does not fold, takes the "
+              "attend's chunk-shaped body: `_pattn_chunk_kernel` in place of "
+              "`_pattn_kernel` in both classes' attends (bf16 operands as "
+              "stored, lane-wide `m` / `l`, groups of `_CHUNK_KEYS` keys, two "
+              "row bands, the mask only where a row's edge lies) and one "
+              "more scalar-prefetched operand, the streams' mask edges")
+# ... kernels ON only; ``decode_step`` (16 rows a K/V head) and
+# ``verify_step`` (48) keep ``_pattn_kernel``.
+MOVED_BY_PR65 = {"afmoe_dense": {"prefill_step": (CHUNK_BODY,)}}
 # The families added one a PR, each held to its own PR's file.
 ADDED_LATER = [(family, harness.ADDED_BY_PR62, ADDED_BY_PR62, 62)
                for family in sorted(harness.ADDED_BY_PR62)] \
@@ -269,6 +292,36 @@ def test_a_family_added_later_is_what_its_pr_left(family, made_by, golden,
         assert got["programs"][name]["order_free"] \
             == want["programs"][name]["order_free"], (
             f"{family}.{arm}.{name}: other operations than PR {pr} left")
+
+
+@pytest.mark.parametrize("arm", sorted(harness.ARMS))
+@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("family", sorted(harness.ADDED_BY_PR65))
+def test_a_dense_chunk_alone_takes_the_chunk_body(family, kind, arm):
+    """PR 65's fixture (one K/V head of 128 under sixteen query heads: a
+    chunk of 8 positions is 128 query rows a K/V head): every program is the
+    text PR 65 left, and that is the text the PARENT lowered for the same
+    fixture except where ``MOVED_BY_PR65`` names the program with its cause
+    — the kernel arm's ``prefill_step`` and nothing else; what the fixture
+    computes is, to the byte, what the parent computed in both arms."""
+    got = harness.golden(family, arm)
+    was, left = PR65["was"][family][arm], PR65["left"][family][arm]
+    if kind == "outputs":
+        assert got["outputs"] == left["outputs"] == was["outputs"]
+        return
+    names = sorted(n for n in left["programs"] if n.startswith(kind))
+    assert names and names == sorted(n for n in got["programs"]
+                                     if n.startswith(kind))
+    assert names == sorted(n for n in was["programs"] if n.startswith(kind))
+    moved = arm == "on" and kind in MOVED_BY_PR65[family]
+    for name in names:
+        text = got["programs"][name]["order_free"]
+        assert text == left["programs"][name]["order_free"], (
+            f"{family}.{arm}.{name}: other operations than PR 65 left")
+        assert (text != was["programs"][name]["order_free"]) == moved, (
+            f"{family}.{arm}.{name}: "
+            + ("the parent's text, where the chunk body was to move it"
+               if moved else "moved, and no cause on record"))
 
 
 @pytest.mark.parametrize("families,was,now,moved_by", [

@@ -426,9 +426,82 @@ def test_paged_attention_at_the_serve_cells_shape(K, Q, one_chip, as_tpu):
         <= pa._VMEM_BUDGET < pa._VMEM_LIMIT
 
 
+# The attend's body in the engine's programs of a family whose K/V heads are
+# 128 wide (no fold) and whose chunk's runs hold ``_DENSE_ROWS`` query rows a
+# K/V head or more: the chunk-shaped one in ``prefill_step`` alone (PR 65).
+_ATTEND_KERNEL = {"decode_step": "_pattn_kernel",
+                  "verify_step": "_pattn_kernel",
+                  "prefill_step": "_pattn_chunk_kernel"}
+
+
+def _attend_kernel_and_its_vmem(K, tiles, D, bs):
+    """The name of the body a step of K query rows a K/V head takes
+    (``pa._dense``), its own VMEM reckoning held to its own budget."""
+    from deepspeed_tpu.ops import paged_attention as pa
+    if pa._dense(K, D, bs):
+        assert pa._chunk_vmem_bytes(*tiles, K, D, bs, 2, 2) \
+            <= pa._CHUNK_VMEM_BUDGET < pa._VMEM_LIMIT <= 16 * 2 ** 20
+        return "_pattn_chunk_kernel"
+    assert pa._step_vmem_bytes(*tiles, K, D, bs, 2, 2) \
+        <= pa._VMEM_BUDGET < pa._VMEM_LIMIT <= 16 * 2 ** 20
+    return "_pattn_kernel"
+
+
+@pytest.mark.parametrize("what,Q,K,nKV,grp,bs,blocks,J,reach", [
+    # `serve.solar-open2-250b.agent-sessions-over`: a chunk of 512 rows in
+    # eight runs of 64 rows x 8 query heads a K/V head, blocks of 128
+    # behind a table of 1,664 — and its 256-row width's four runs
+    ("cell14_chunk_512", 8, 64, 8, 8, 128, 12288, 1664, None),
+    ("cell14_chunk_256", 4, 64, 8, 8, 128, 12288, 1664, None),
+    # `serve.falcon-h1-34b.chat-short-over`: five query heads a K/V head
+    ("cell9_chunk_512", 8, 64, 4, 5, 64, 2048, 48, None),
+    # one query head a K/V head and rows alone; a short window's ring
+    ("rows_alone", 2, 256, 8, 1, 64, 1024, 64, None),
+    ("short_ring", 8, 64, 4, 8, 64, 1024, 5, 128),
+])
+def test_the_chunk_body_at_the_cells_shapes(what, Q, K, nKV, grp, bs, blocks,
+                                            J, reach, one_chip, as_tpu):
+    """A prefill run's attend ALONE where its rows a K/V head are
+    ``_DENSE_ROWS`` or more (head_dim 128, bf16): ``_pattn_chunk_kernel``
+    under the shape rule's own tiles, scoped VMEM asked and kept at the
+    default 16 MiB, nothing but parameters, bitcasts and the kernel
+    holding a pool or a layer of it.  (Cell 11's runs and cell 6's: the
+    two tests below; cell 13's as cell 6's full class.)"""
+    from deepspeed_tpu.analysis.hlo_text import ops_in_units_of
+    from deepspeed_tpu.ops import paged_attention as pa
+    Dh = 128
+    tiles = pa._tile_rule(grp * K, nKV, Dh, bs, J, 2, 2)
+    assert tiles[1] == min(J, pa._CHUNK_KEYS // bs) and nKV % tiles[0] == 0
+    assert _attend_kernel_and_its_vmem(grp * K, tiles, Dh, bs) \
+        == "_pattn_chunk_kernel"
+    shape = (1, 1, blocks, nKV, bs, Dh)
+    pool = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    args = [jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+            for sh, dt in (((1, Q, K, nKV * grp, Dh), jnp.bfloat16),
+                           ((), jnp.int32), ((1, Q, J), jnp.int32),
+                           ((1, Q, K), jnp.int32))]
+
+    def attend(q, pk, pv, layer, bt, pos):
+        plan = pa.attend_plan(bt, pos, pk, Dh, reach=reach, group=grp)
+        return pa.paged_attention(q, pk, pv, layer, plan=plan,
+                                  scale=Dh ** -0.5)
+    compiled = jax.jit(attend).lower(args[0], pool, pool,
+                                     *args[1:]).compile()
+    text = compiled.as_text()
+    seen = ops_in_units_of(text, math.prod(shape[2:]))
+    assert {op for op, _ in seen} <= {"parameter", "bitcast",
+                                      "custom-call"}, seen
+    calls = [line for line in text.splitlines()
+             if "%_pattn_chunk_kernel" in line.split(" = ")[0]
+             and " custom-call(" in line]
+    assert len(calls) == 1 and "tpu_custom_call" in calls[0]
+    assert f'"size":"{pa._VMEM_LIMIT}"' in calls[0]
+    assert "%_pattn_kernel" not in text
+
+
 @pytest.mark.parametrize("cls,blocks,layers,J,reach", [
     ("full", 16384, 1, 528, None), ("window", 6144, 4, 41, 2048)])
-@pytest.mark.parametrize("K,Q,tiles", [(1, 128, (4, 16)), (64, 8, (4, 2))],
+@pytest.mark.parametrize("K,Q,tiles", [(1, 128, (4, 16)), (64, 8, (4, 8))],
                          ids=["decode_128_streams", "prefill_run_512_rows"])
 def test_paged_attention_at_the_mixed_cells_shapes(K, Q, tiles, cls, blocks,
                                                    layers, J, reach,
@@ -438,15 +511,15 @@ def test_paged_attention_at_the_mixed_cells_shapes(K, Q, tiles, cls, blocks,
     class's 16,384 blocks x 1 layer behind a table of 528 and the window
     class's 6,144 x 4 behind a ring of 41): decode's 8 query rows a K/V
     head walk SIXTEEN slots a group (2 MiB of K and V tiles in flight), a
-    prefill run's 512 rows keep two; scoped VMEM asked and kept at the
-    default 16 MiB, and nothing but parameters, bitcasts and the kernel
+    prefill run's 512 rows take the chunk body (``_pattn_chunk_kernel``,
+    PR 65) at eight slots = 512 keys a group; scoped VMEM asked and kept at
+    the default 16 MiB, and nothing but parameters, bitcasts and the kernel
     holds a pool or a layer of it."""
     from deepspeed_tpu.analysis.hlo_text import ops_in_units_of
     from deepspeed_tpu.ops import paged_attention as pa
     nKV, grp, Dh, bs = 4, 8, 128, 64
     assert pa._tile_rule(grp * K, nKV, Dh, bs, J, 2, 2) == tiles
-    assert pa._step_vmem_bytes(*tiles, grp * K, Dh, bs, 2, 2) \
-        <= pa._VMEM_BUDGET < pa._VMEM_LIMIT <= 16 * 2 ** 20
+    kernel = _attend_kernel_and_its_vmem(grp * K, tiles, Dh, bs)
     shape = (layers, 1, blocks, nKV, bs, Dh)
     pool = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     args = [jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
@@ -466,7 +539,7 @@ def test_paged_attention_at_the_mixed_cells_shapes(K, Q, tiles, cls, blocks,
                                       "custom-call"}, seen
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 20
     calls = [line for line in text.splitlines()
-             if "%_pattn_kernel" in line.split(" = ")[0]
+             if f"%{kernel}" in line.split(" = ")[0]
              and " custom-call(" in line]
     assert len(calls) == 1 and "tpu_custom_call" in calls[0]
     assert f'"size":"{pa._VMEM_LIMIT}"' in calls[0]
@@ -1467,7 +1540,7 @@ def test_both_kinds_in_a_layer_fit_and_are_updated_in_place(
         + mem.output_size_in_bytes - mem.alias_size_in_bytes \
         < 15.75 * 2 ** 30, mem
     text = compiled.as_text()
-    kernels = ["_pattn_kernel", "_kv_write_kernel"]
+    kernels = [_ATTEND_KERNEL[program.split(".")[0]], "_kv_write_kernel"]
     if program == "decode_step":
         kernels.append("_ssm_state_update_kernel")
     for kernel in kernels:
@@ -1789,7 +1862,8 @@ def test_pages_beside_delta_states_fit_and_are_updated_in_place(
         + mem.output_size_in_bytes - mem.alias_size_in_bytes \
         < 15.75 * 2 ** 30, mem
     text = compiled.as_text()
-    kernels = ["_pattn_kernel", "_kv_write_kernel", "_gswiglu_kernel"]
+    kernels = [_ATTEND_KERNEL[program.split(".")[0]], "_kv_write_kernel",
+               "_gswiglu_kernel"]
     if program == "decode_step":
         kernels.append("_kda_state_update_kernel")
     for kernel in kernels:
@@ -1822,14 +1896,15 @@ def test_pages_beside_delta_states_fit_and_are_updated_in_place(
 # ------------------------------------------------------------------ #
 @pytest.mark.parametrize("cls,blocks,layers,J,reach", [
     ("full", 10240, 2, 256, None), ("window", 4864, 6, 73, 4096)])
-@pytest.mark.parametrize("K,Q,tiles", [(1, 64, (4, 16)), (64, 8, (4, 2))],
+@pytest.mark.parametrize("K,Q,tiles", [(1, 64, (4, 16)), (64, 8, (4, 8))],
                          ids=["decode_64_streams", "prefill_run_512_rows"])
 def test_paged_attention_at_the_paste_cells_shapes(K, Q, tiles, cls, blocks,
                                                    layers, J, reach,
                                                    one_chip, as_tpu):
     """The attend ALONE at `serve.smallthinker-21b-a3b.paste-over`'s shapes
     (4 K/V heads of 128 under 28 query heads: SEVEN query rows a K/V head
-    in decode, odd against the sublane count; 448 a prefill run of 64;
+    in decode, odd against the sublane count; 448 a prefill run of 64,
+    which takes the chunk body in two bands of 224 rows (PR 65);
     blocks of 64, bf16; the full class's 10,240 blocks x 2 layers behind a
     table of 256 and the window class's 4,864 x 6 behind a ring of 73):
     scoped VMEM asked and kept at the default 16 MiB, and nothing but
@@ -1840,8 +1915,7 @@ def test_paged_attention_at_the_paste_cells_shapes(K, Q, tiles, cls, blocks,
     nKV, grp, Dh, bs = 4, 7, 128, 64
     assert attend_rows(512, grp) == 64 and attend_rows(1, grp) == 1
     assert pa._tile_rule(grp * K, nKV, Dh, bs, J, 2, 2) == tiles
-    assert pa._step_vmem_bytes(*tiles, grp * K, Dh, bs, 2, 2) \
-        <= pa._VMEM_BUDGET < pa._VMEM_LIMIT <= 16 * 2 ** 20
+    kernel = _attend_kernel_and_its_vmem(grp * K, tiles, Dh, bs)
     shape = (layers, 1, blocks, nKV, bs, Dh)
     pool = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     args = [jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
@@ -1861,7 +1935,7 @@ def test_paged_attention_at_the_paste_cells_shapes(K, Q, tiles, cls, blocks,
                                       "custom-call"}, seen
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 20
     calls = [line for line in text.splitlines()
-             if "%_pattn_kernel" in line.split(" = ")[0]
+             if f"%{kernel}" in line.split(" = ")[0]
              and " custom-call(" in line]
     assert len(calls) == 1 and "tpu_custom_call" in calls[0]
     assert f'"size":"{pa._VMEM_LIMIT}"' in calls[0]
@@ -1986,7 +2060,8 @@ def test_paste_cell_serve_step_fits_and_updates_both_classes_in_place(
         + mem.output_size_in_bytes - mem.alias_size_in_bytes \
         < 15.75 * 2 ** 30
     text = compiled.as_text()
-    for kernel in ("_pattn_kernel", "_kv_write_kernel", "_greglu_kernel"):
+    for kernel in (_ATTEND_KERNEL[program.split(".")[0]], "_kv_write_kernel",
+                   "_greglu_kernel"):
         calls = [line for line in text.splitlines()
                  if f"%{kernel}" in line.split(" = ")[0]
                  and " custom-call(" in line]
@@ -2300,7 +2375,8 @@ def test_block_step_fits_and_writes_its_rows_in_place(block_step_programs,
         < 15.75 * 2 ** 30, (mem.temp_size_in_bytes,
                             mem.output_size_in_bytes)
     text = compiled.as_text()
-    for kernel in ("_pattn_kernel", "_kv_write_kernel", "_gswiglu_kernel"):
+    for kernel in (_ATTEND_KERNEL[program.split(".")[0]], "_kv_write_kernel",
+                   "_gswiglu_kernel"):
         calls = [line for line in text.splitlines()
                  if f"%{kernel}" in line.split(" = ")[0]
                  and " custom-call(" in line]
