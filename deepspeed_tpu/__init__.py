@@ -9,6 +9,11 @@ Public surface parity with the reference ``deepspeed/__init__.py``:
 ``initialize()``, ``add_config_arguments()``, ``init_distributed()``, plus
 the pipeline module, ops, and checkpointing re-exports.
 """
+import time as _time
+# The start-up ledger's clock (monitor/startup.py), taken before anything
+# else of the package runs.
+_IMPORT_CLOCK = _time.perf_counter()
+
 from .version import __version__
 
 from .runtime.config import DeepSpeedConfig
@@ -25,9 +30,13 @@ def initialize(args=None, model=None, optimizer=None, model_params=None,
 
     Returns a tuple of ``(engine, optimizer, dataloader, lr_scheduler)``.
     """
-    from .runtime.engine import DeepSpeedEngine
-    from .runtime.pipe.module import PipelineModule
-    from .runtime.pipe.engine import PipelineEngine
+    # (the engines' modules load at the first call: the start-up ledger
+    # files it with the package's import)
+    with _startup.span("package_import", part="runtime"):
+        from .runtime.engine import DeepSpeedEngine
+        from .runtime.pipe.module import PipelineModule
+        from .runtime.pipe.engine import PipelineEngine
+        from .models.gpt2_pipe import PipeSpec
 
     cfg = config if config is not None else config_params
     if cfg is None and args is not None:
@@ -36,7 +45,6 @@ def initialize(args=None, model=None, optimizer=None, model_params=None,
         raise ValueError("DeepSpeed requires a config via `config=`, "
                          "`config_params=`, or args.deepspeed_config")
 
-    from .models.gpt2_pipe import PipeSpec
     if isinstance(model, (PipelineModule, PipeSpec)):
         pipe_mpu = mpu
         if pipe_mpu is None and isinstance(model, PipelineModule):
@@ -86,3 +94,7 @@ def init_distributed(dist_backend: str = "xla", auto_mpi_discovery: bool = True,
     from .parallel.comm import init_distributed as _init
     return _init(dist_backend=dist_backend, distributed_port=distributed_port,
                  verbose=verbose, init_method=init_method)
+
+
+from .monitor import startup as _startup
+_startup.package_imported()     # closes the ledger's ``package_import`` row

@@ -17,6 +17,9 @@ spec-acceptance accounting (engine.py) — and the prefix-affinity
 multi-replica admission router (router.py). See
 docs/tutorials/inference.md.
 """
+from ..monitor import startup as _startup
+_import_began = _startup.now()      # (this package loads at first use)
+
 from .engine import InferenceEngine
 from .kv_cache import (BlockAllocator, PagedKVCacheSpec, PoolExhausted,
                        init_paged_cache, paged_partition_spec)
@@ -33,3 +36,5 @@ __all__ = [
     "shared_prefix_requests", "ContinuousBatchingScheduler",
     "ReplicaRouter", "NGramDrafter",
 ]
+
+_startup.imported("inference", _import_began)

@@ -71,6 +71,7 @@ import hashlib
 import os
 import pickle
 import tempfile
+import time
 import weakref
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
@@ -87,7 +88,7 @@ from .served import (ServedModel, filter_rows_lowered, head_and_sample,
                      with_counters)
 from .spec import NGramDrafter
 from .. import constants as C
-from ..monitor import Telemetry
+from ..monitor import Telemetry, startup
 from ..monitor.telemetry import ids_arg
 from ..monitor.memory import analytic_state_bytes
 from ..monitor.serving import ServingAggregator
@@ -197,7 +198,9 @@ class _WidthPrograms:
             return self.jitted(*args)    # (an audit tracing through it)
         exe = self.execs.get(tokens.shape[1])
         if exe is None:
-            exe = self.execs[tokens.shape[1]] = self._build(args)
+            # (the start-up ledger's rows of the build carry the width)
+            with startup.build_args(width=int(tokens.shape[1])):
+                exe = self.execs[tokens.shape[1]] = self._build(args)
         return exe(*args)
 
     def _build(self, args):
@@ -210,12 +213,19 @@ class _WidthPrograms:
         if where is None:
             return self.jitted.lower(*args).compile()
         from jax.experimental import serialize_executable
-        path = os.path.join(
-            where, f"{self.first}-{args[self.width_arg].shape[1]}")
+        width = int(args[self.width_arg].shape[1])
+        path = os.path.join(where, f"{self.first}-{width}")
         try:
-            with open(path, "rb") as fh:
-                return serialize_executable.deserialize_and_load(
-                    *pickle.load(fh), execution_devices=self.devices)
+            # A load goes round JAX's compile events: the ledger's
+            # ``program_build`` row of it is written here.
+            with startup.span("executable_load", kind="program_build",
+                              program=self.__name__, width=width) as row:
+                with open(path, "rb") as fh:
+                    row["bytes"] = os.fstat(fh.fileno()).st_size
+                    exe = serialize_executable.deserialize_and_load(
+                        *pickle.load(fh), execution_devices=self.devices)
+                startup.kept_executable(row)
+            return exe
         except FileNotFoundError:
             pass
         except Exception as e:               # unreadable: build it again
@@ -266,6 +276,7 @@ class InferenceEngine:
     # timed path's own logits to a reference.  None: nothing is called.
     on_block_pass: Optional[Callable] = None
 
+    @startup.engine_init("serving")     # a row of the start-up ledger
     def __init__(self, model_cfg: Any, params: Any,
                  config: Any = None, mesh: Optional[Mesh] = None,
                  rng: Optional[jax.Array] = None,
@@ -342,15 +353,16 @@ class InferenceEngine:
                 "inference.quantize does not compose with tensor-parallel "
                 "param_shardings yet (quantized leaves change the tree "
                 "structure the specs address)")
-        params = quantize_params(params, self.quantize, self._base_rng)
-        if param_shardings is not None:
-            shardings = jax.tree_util.tree_map(
-                lambda spec: NamedSharding(self.mesh, spec),
-                param_shardings)
-        else:
-            shardings = NamedSharding(self.mesh, P())
-        self._params = jax.device_put(params, shardings)
-        self.param_bytes = quantized_bytes(self._params)
+        with startup.span("place_params", parent="engine_init"):
+            params = quantize_params(params, self.quantize, self._base_rng)
+            if param_shardings is not None:
+                shardings = jax.tree_util.tree_map(
+                    lambda spec: NamedSharding(self.mesh, spec),
+                    param_shardings)
+            else:
+                shardings = NamedSharding(self.mesh, P())
+            self._params = jax.device_put(params, shardings)
+            self.param_bytes = quantized_bytes(self._params)
 
         # --- the KV cache: the paged block pool, born sharded ---
         kv_dtype = resolve_kv_dtype(self.icfg.kv_cache_dtype, served.dtype)
@@ -378,10 +390,11 @@ class InferenceEngine:
         self.num_blocks = sum(sp.num_blocks for sp in specs)
         served.table_widths = tuple(sp.max_blocks_per_slot for sp in specs)
         self.cache, self._cache_sh = {}, {}
-        for spec in specs:
-            self.cache.update(kv_cache.init_paged_cache(spec, self.mesh))
-            self._cache_sh.update(kv_cache.paged_shardings(
-                self.mesh, spec.pool_names))
+        with startup.span("allocate_cache", parent="engine_init"):
+            for spec in specs:
+                self.cache.update(kv_cache.init_paged_cache(spec, self.mesh))
+                self._cache_sh.update(kv_cache.paged_shardings(
+                    self.mesh, spec.pool_names))
         self.block_tables = np.full(
             (self.max_slots, self.allocator.table_width),
             kv_cache.DEAD_BLOCK, np.int32)
@@ -1292,11 +1305,14 @@ class InferenceEngine:
         zeros = np.zeros(G, np.int32)
         pools = self._pools()
         try:
-            for width in reversed(self.prefill_widths):
-                pools = self._outputs(self._prefill_fn(
-                    self._params, *pools, np.zeros((G, width), np.int32),
-                    dead, zeros, zeros, zeros, *self._no_freeze(),
-                    np.int32(0), self._base_rng, np.float32(0.0)))[0]
+            with startup.span("warm_prefill_widths",
+                              widths=len(self.prefill_widths)):
+                for width in reversed(self.prefill_widths):
+                    pools = self._outputs(self._prefill_fn(
+                        self._params, *pools,
+                        np.zeros((G, width), np.int32),
+                        dead, zeros, zeros, zeros, *self._no_freeze(),
+                        np.int32(0), self._base_rng, np.float32(0.0)))[0]
         finally:
             self._store_pools(pools)
         self._prefill_warmed = True
@@ -1841,8 +1857,13 @@ class InferenceEngine:
         self._spec_accepted = 0
         self._t_tokens = (float("-inf"), 0.0)   # the clock may be another
 
+    def _startup_args(self) -> Dict[str, int]:
+        return {"param_bytes": self.param_bytes,
+                "cache_bytes": sum(sp.nbytes() for sp in self.cache_specs)}
+
     def _report_extra(self) -> Dict[str, Any]:
-        return {"serving": self.serving.snapshot()}
+        return {"serving": self.serving.snapshot(),
+                "startup": startup.snapshot(with_rows=False)}
 
     def profile_window(self, steps: int,
                        start_step: Optional[int] = None) -> Optional[str]:
@@ -1905,7 +1926,21 @@ class InferenceEngine:
         from .scheduler import ContinuousBatchingScheduler
         sched = ContinuousBatchingScheduler(self, temperature=temperature,
                                             **kwargs)
-        return sched.serve(requests)
+        # The call's ``engine_traffic`` row of the start-up ledger, from
+        # what the call leaves behind: nothing is read inside the loop.
+        requests = list(requests)
+        agg, t0 = self.serving, startup.now()
+        iterations, prefilled = self.iterations, agg.prefill_tokens
+        report = sched.serve(requests)
+        firsts = [r.t_first for r in requests if r.t_first is not None]
+        startup.traffic(
+            t0, min(firsts) - startup.PERF_ORIGIN
+            if firsts and agg.clock is time.perf_counter else None,
+            mode="serving", requests=len(requests),
+            iterations=self.iterations - iterations,
+            prompt_tokens=agg.prefill_tokens - prefilled)
+        report["startup"] = startup.snapshot(with_rows=False)
+        return report
 
     # ------------------------------------------------------------------ #
     # Training-checkpoint handoff

@@ -14,7 +14,9 @@ trace ingestion into a bucketed per-step wall decomposition
 (profile_ingest) reconciled against the analytic floors (reconcile).
 Always on, telemetry or not: the serving loop's and the training loop's
 timelines (serving, training), one row an iteration / a train_batch call,
-with the stalls they caught.
+with the stalls they caught; and the start-up ledger (startup): what the
+process built before it served, one row a constructor, a program built or
+loaded and a call served, on the clock of process age.
 See docs/tutorials/telemetry.md.
 """
 from .cost_model import (BOUND_COMPUTE, BOUND_HBM, BOUND_INTERCONNECT,

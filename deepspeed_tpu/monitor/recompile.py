@@ -24,6 +24,8 @@ import functools
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from . import startup
+
 
 class RecompileError(RuntimeError):
     """Raised on a post-warmup jit cache miss under fail_on_recompile."""
@@ -84,10 +86,11 @@ class RecompileSentinel:
         self.events: List[Dict[str, Any]] = []
         self.pending_error: Optional[RecompileError] = None
         self._fns: Dict[str, Dict[str, Any]] = {}
-        # Cumulative wall of cache-miss calls (trace+compile+dispatch;
-        # the dispatch of a missing call blocks through compilation).
-        # Warmup compiles count too — the goodput ledger attributes ALL
-        # compile wall, cold start included.
+        # Cumulative seconds of the builds the missing calls made: the
+        # start-up ledger's rows of them (monitor/startup.py: trace +
+        # lowering + backend) — the one source. Warmup compiles count
+        # too — the goodput ledger attributes ALL compile wall, cold
+        # start included.
         self.compile_wall_s = 0.0
 
     def raise_pending(self) -> None:
@@ -136,9 +139,10 @@ class RecompileSentinel:
         the one it already holds. Any miss after it — any other shape —
         is a violation as ever."""
         warmup_calls = self.warmup_calls + max(1, int(signatures)) - 1
+        startup.register_program(fn, name)
         st = self._fns.setdefault(
             name, {"calls": 0, "compiles": 0, "seen": set(), "descs": None,
-                   "compile_wall_s": 0.0, "fn": fn, "abstract_args": None})
+                   "fn": fn, "abstract_args": None})
         st["fn"] = fn
         cache_size = getattr(fn, "_cache_size", None)
         if not callable(cache_size):
@@ -169,12 +173,17 @@ class RecompileSentinel:
             st["calls"] += 1
             if miss:
                 # Miss-only work: the call just paid seconds of compile,
-                # so clocking it and mirroring the abstract signature
-                # (ShapeDtypeStructs survive buffer donation — the cost
-                # model AOT-relowers from them at report boundaries) is
-                # noise on top.
-                dt = time.perf_counter() - t_call0
-                st["compile_wall_s"] += dt
+                # so looking its build up and mirroring the abstract
+                # signature (ShapeDtypeStructs survive buffer donation —
+                # the cost model AOT-relowers from them at report
+                # boundaries) is noise on top. Only a miss that built no
+                # program (the signature fallback on a function that is
+                # no jitted one; a fast-path miss the lowering cache
+                # served) is clocked here, as the call's wall.
+                dt = startup.build_seconds(
+                    name, t_call0 - startup.PERF_ORIGIN)
+                if dt is None:
+                    dt = time.perf_counter() - t_call0
                 self.compile_wall_s += dt
                 from .cost_model import abstract_args_of
                 st["abstract_args"] = abstract_args_of(args, kwargs)

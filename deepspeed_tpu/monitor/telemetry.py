@@ -65,6 +65,7 @@ from .hostinfo import resolve_writer, shard_path
 from .memory import MemoryWatermark, analytic_state_bytes, device_memory_stats
 from .peaks import ChipPeaks
 from .recompile import RecompileSentinel
+from . import startup
 from .trace import ProfilerWindow, TraceWriter
 from ..utils.logging import log_dist, logger
 
@@ -480,6 +481,9 @@ class Telemetry:
         attribute store) so a watchdog fire can name what the run was
         stuck on."""
         if self.sentinel is None:
+            # (the sentinel registers it itself: its builds are ``own``
+            # rows of the start-up ledger either way)
+            startup.register_program(fn, name)
             return fn
         wrapped = self.sentinel.instrument(name, fn, signatures)
         wd = self.watchdog

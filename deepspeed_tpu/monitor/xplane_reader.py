@@ -24,7 +24,7 @@ from typing import Any, Dict, Iterator, List, Tuple
 
 __all__ = ["SCOPES", "SPANS", "SPAN_ARGS", "CLASS_SPAN_ARGS", "span_args",
            "read_xspace", "event_args", "scope_of",
-           "instruction_name",
+           "instruction_name", "idle_gaps", "named_idle_gaps",
            "device_op_events", "DEVICE_PLANE", "OPS_LINE"]
 
 # The program's named scopes — a stable interface (the benchmark's
@@ -103,7 +103,14 @@ SCOPES = ("fwd_bwd", "grad_sync", "health_tap", "optimizer", "flatten",
 SPANS = ("train_batch", "data_prep", "step_dispatch", "offload_step",
          "step_log", "admit", "prefill", "prefill_plan", "prefill_chunk",
          "prefill_fetch", "decode", "decode_tables", "decode_dispatch",
-         "decode_fetch", "decode_advance", "emit", "serve_idle")
+         "decode_fetch", "decode_advance", "emit", "serve_idle",
+         # start-up (monitor/startup.py: each also a row of the start-up
+         # ledger): an engine's constructor and its children, the build
+         # of the prefill widths, a kept executable's load; and the
+         # instant marker a ``program_build`` row leaves when it closes
+         # inside a profiler session
+         "engine_init", "place_params", "allocate_cache", "shard_state",
+         "warm_prefill_widths", "executable_load", "program_build")
 # The args those spans carry (the ones with none are left out).
 SPAN_ARGS = {
     # The call's row of the training timeline (monitor/training.py): the
@@ -219,7 +226,18 @@ SPAN_ARGS = {
     # one's block before, in ms, joined by spaces.
     "emit": ("finished", "row", "streams", "continuing", "gap_ms",
              "stall_ms", "host_ms", "blocks", "block_gaps_ms"),
-    "serve_idle": ("why",)}
+    "serve_idle": ("why",),
+    # age_s: the span's start in seconds of PROCESS AGE, the start-up
+    # ledger's clock (``named_idle_gaps`` ties the two clocks by it).
+    "engine_init": ("age_s", "mode", "param_bytes", "cache_bytes"),
+    "place_params": ("age_s", "parent"),
+    "allocate_cache": ("age_s", "parent"),
+    "shard_state": ("age_s", "parent"),
+    "warm_prefill_widths": ("age_s", "widths"),
+    "executable_load": ("age_s", "program", "width", "bytes", "trace_s",
+                        "lower_s", "backend_s", "source", "own"),
+    # (the marker: age_s is the build's END, build_s its seconds)
+    "program_build": ("age_s", "program", "build_s", "source")}
 # ... and the args such a model adds ONE A CLASS, under the names its
 # ``ServedModel.cache_classes`` declare (``<class>`` below; this file knows
 # no model's): of a prefill's cached_tokens what each class took from ITS
@@ -446,3 +464,70 @@ def device_op_events(path: str) -> List[Dict[str, Any]]:
                          "scope": "/".join(scope), "backward": backward,
                          "recomputed": recomputed, "device_plane": True}})
     return events
+
+
+def idle_gaps(xspace: Dict[str, Dict[str, Any]], min_ms: float = 1.0
+              ) -> List[Dict[str, Any]]:
+    """The intervals of at least ``min_ms`` in which no operation ran on
+    a device, between its first and its last: ``{"device", "start_ns",
+    "end_ns"}`` on the capture's clock, in order of time."""
+    gaps: List[Dict[str, Any]] = []
+    for pname, plane in xspace.items():
+        m = DEVICE_PLANE.match(pname)
+        if not m:
+            continue
+        reach = None
+        for _, start, dur, _ in sorted(plane["lines"].get(OPS_LINE, []),
+                                       key=lambda e: e[1]):
+            if reach is not None and start - reach >= min_ms * 1e6:
+                gaps.append({"device": int(m.group(1)),
+                             "start_ns": reach, "end_ns": start})
+            reach = start + dur if reach is None else max(reach,
+                                                          start + dur)
+    return sorted(gaps, key=lambda g: g["start_ns"])
+
+
+def ledger_clock_offset_ns(xspace: Dict[str, Dict[str, Any]]) -> Any:
+    """Capture clock minus the start-up ledger's (process age), in ns,
+    from any host event that carries ``age_s`` (the ledger's spans: their
+    start; a ``program_build`` marker: the instant it was left); None
+    for a capture without one."""
+    for pname, plane in xspace.items():
+        if DEVICE_PLANE.match(pname):
+            continue
+        for rows in plane["lines"].values():
+            for mid, start, _, stats in rows:
+                if stats and plane["metadata"].get(mid, ("",))[0] in SPANS:
+                    age = event_args(stats, plane["stat_names"]).get("age_s")
+                    if age is not None:
+                        return start - float(age) * 1e9
+    return None
+
+
+def named_idle_gaps(xspace: Dict[str, Dict[str, Any]],
+                    ledger_rows: List[Dict[str, Any]], min_ms: float = 1.0,
+                    offset_ns: Any = None) -> List[Dict[str, Any]]:
+    """``idle_gaps`` of a capture (``read_xspace``'s), each with
+    ``program``: the programs of the start-up ledger's ``program_build``
+    rows (``monitor.startup.rows()``) that overlap it, space-joined — a
+    recompile inside a traced window is a named gap — and ``build_s``,
+    the seconds of the overlap; both absent from a gap no build touches,
+    and from every gap of a capture that holds none of the ledger's spans
+    or markers to tie the clocks by (``offset_ns``: the capture's clock
+    less the ledger's, where the caller knows it)."""
+    gaps = idle_gaps(xspace, min_ms)
+    if offset_ns is None:
+        offset_ns = ledger_clock_offset_ns(xspace)
+    if offset_ns is None:
+        return gaps
+    builds = [(r["start_s"] * 1e9 + offset_ns, r["end_s"] * 1e9 + offset_ns,
+               r["program"]) for r in ledger_rows
+              if r["kind"] == "program_build"]
+    for g in gaps:
+        hit = [(min(e, g["end_ns"]) - max(s, g["start_ns"]), p)
+               for s, e, p in builds
+               if s < g["end_ns"] and e > g["start_ns"]]
+        if hit:
+            g["program"] = " ".join(dict.fromkeys(p for _, p in hit))
+            g["build_s"] = sum(o for o, _ in hit) / 1e9
+    return gaps

@@ -55,7 +55,7 @@ from .utils import (clip_coefficient, clip_grad_norm_, global_norm,
                     tree_has_inf_or_nan)
 from .zero.partition import zero_shardings
 from .. import constants as C
-from ..monitor import Telemetry
+from ..monitor import Telemetry, startup
 from ..monitor.memory import analytic_state_bytes
 from ..monitor.telemetry import spans_recorded
 from ..monitor.training import TrainingTimeline
@@ -230,6 +230,7 @@ jax.tree_util.register_pytree_node(
 class DeepSpeedEngine:
     """Config-driven training engine over a device mesh."""
 
+    @startup.engine_init("training")    # a row of the start-up ledger
     def __init__(self, args=None, model=None, optimizer=None, model_params=None,
                  training_data=None, lr_scheduler=None, mpu=None,
                  dist_init_required=None, collate_fn=None,
@@ -566,9 +567,11 @@ class DeepSpeedEngine:
                         jnp.float32), params) if dcn_live else None,
             )
 
-        self.state = jax.jit(
-            _init_state, out_shardings=self._state_shardings)(
-            jax.tree_util.tree_map(jnp.asarray, device_params))
+        startup.register_program(_init_state, "init_state")
+        with startup.span("shard_state", parent="engine_init"):
+            self.state = jax.jit(
+                _init_state, out_shardings=self._state_shardings)(
+                jax.tree_util.tree_map(jnp.asarray, device_params))
 
         # Host-side counters (reference engine.py:151-158).
         self.global_steps = 0
@@ -3050,22 +3053,15 @@ class DeepSpeedEngine:
         tm.enter(step)
         saved_s = tl.checkpoint_exposed_s
         tl.profiler_tick(step)
-        first_build = self._train_step_fn is None
         with tl.span("train_batch", step_num=step) as span:
             with tl.span("data_prep", step=step):
                 micro_batches = self._prepare_batch(batch, data_iter)
             tm.lap("data_s")
-            programs = self._step_programs()
+            programs = startup.own_builds()
             with tl.span("offload_step" if self._offload is not None
                          else "step_dispatch", step=step):
                 metrics = self._dispatch_step(micro_batches)
-            tm.dispatched(metrics["loss"], self._step_programs() - programs)
-            if first_build and self._train_step_fn is not None:
-                from ..ops.flash_attention import lowered
-                logger.info(
-                    "train step built: flash attention lowered in_place "
-                    f"{lowered['in_place']}, relayout {lowered['relayout']} "
-                    "call(s) so far in this process")
+            tm.dispatched(metrics["loss"], startup.own_builds() - programs)
             with tl.span("step_log", step=step):
                 self._record_telemetry(metrics, tm.wall_s)
                 self._maybe_log(metrics)
@@ -3074,22 +3070,6 @@ class DeepSpeedEngine:
             if spans_recorded(tl):
                 span.set_metadata(**tm.span_args())
         return metrics["loss"]
-
-    def _step_programs(self) -> int:
-        """Executables ``train_batch``'s step functions hold so far (the
-        jit caches' sizes, the test ``RecompileSentinel`` uses): its
-        growth over a call is the programs the call built or compiled."""
-        n = 0
-        for name in ("_train_step_fn", "_offload_grad_fn",
-                     "_sparse_grad_fn", "_sparse_apply_fn"):
-            fn = getattr(self, name, None)
-            # The sentinel's wrapper keeps the jitted function on
-            # ``__wrapped__`` (a jitted function's own is the Python one).
-            size = getattr(fn, "_cache_size", None) or getattr(
-                getattr(fn, "__wrapped__", None), "_cache_size", None)
-            if callable(size):
-                n += size()
-        return n
 
     def _prepare_batch(self, batch, data_iter):
         """train_batch's ``data_prep``: the iterator pull, the micro-batch
@@ -3226,6 +3206,11 @@ class DeepSpeedEngine:
             tl.add_offload_trace(t)
         tl.record_step(self.global_steps, metrics, **host)
 
+    def _startup_args(self) -> Dict[str, int]:
+        return {"param_bytes": sum(
+            int(getattr(leaf, "nbytes", 0))
+            for leaf in jax.tree_util.tree_leaves(self.state.params))}
+
     def _report_extra(self) -> Dict[str, Any]:
         """Report-boundary fields for the telemetry drain record. Called
         ONLY at a drain boundary (the skipped_steps read is a sync)."""
@@ -3234,6 +3219,7 @@ class DeepSpeedEngine:
             "global_samples": self.global_samples,
             "samples_per_sec": self.tput_timer.avg_samples_per_sec(),
             "samples_per_sec_valid": self.tput_timer.has_samples(),
+            "startup": startup.snapshot(with_rows=False),
         }
         if self._offload is not None:
             extra["skipped_steps"] = self._offload.skipped_steps

@@ -425,6 +425,40 @@ def test_the_readers_list_names_the_same_args():
     assert DISPATCHED <= set(SPAN_ARGS["decode"])
 
 
+# The start-up spans (``monitor/startup.py``: each also a row of the
+# start-up ledger, read back from a profiler session with these args in
+# ``tests/test_startup_ledger.py``), and the marker a build row leaves.
+STARTUP_SPANS = {
+    "engine_init": {"age_s", "mode", "param_bytes"},
+    "place_params": {"age_s", "parent"},
+    "allocate_cache": {"age_s", "parent"},
+    "shard_state": {"age_s", "parent"},
+    "warm_prefill_widths": {"age_s", "widths"},
+    "executable_load": {"age_s", "program", "width", "bytes", "source"},
+    "program_build": {"age_s", "program", "build_s", "source"}}
+
+
+@pytest.mark.parametrize("span", sorted(STARTUP_SPANS))
+def test_a_startup_span_is_on_the_readers_list(span, tmp_path):
+    from deepspeed_tpu.monitor import startup
+    from deepspeed_tpu.monitor.xplane_reader import SPAN_ARGS, SPANS
+    assert span in SPANS and STARTUP_SPANS[span] <= set(SPAN_ARGS[span])
+    if span == "program_build":
+        return                  # (JAX's events write it, not ``span``)
+    args = {a: 1 for a in STARTUP_SPANS[span] - {"age_s"}}
+
+    def use():
+        with startup.span(span, **args) as row:
+            row["late"] = 2
+    found = _session(tmp_path / "prof", use)
+    (_, _, got), = found[span]
+    row = startup.rows()[-1]
+    assert got == {**args, "late": 2, "age_s": pytest.approx(row["start_s"])}
+    assert row == {"kind": span, **args, "late": 2,
+                   "start_s": row["start_s"], "end_s": row["end_s"]}
+    assert row["end_s"] >= row["start_s"]
+
+
 def test_attend_step_counts_from_live_blocks():
     from deepspeed_tpu.ops.paged_attention import attend_step_counts
     # the serve cell's decode: all 20 heads of 16 table slots a step;
