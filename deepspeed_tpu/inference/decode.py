@@ -109,7 +109,7 @@ def _paged_attn_block(p, x, kc, vc, layer, cfg: GPT2Config,
         with jax.named_scope("kv_write"):
             kc, vc = kv_cache.paged_write_rows(
                 kc, vc, k.reshape(G, R, nH, D), v.reshape(G, R, nH, D),
-                layer, blk, off, mesh=mesh)
+                layer, blk, off, mesh=mesh, stream_rows=K)
         with jax.named_scope("attend"):
             if plan is not None:
                 attn = paged_attn_ops.paged_attention(
@@ -209,6 +209,8 @@ class GPT2Served(ServedModel):
             kv_itemsize=int(jnp.dtype(spec.dtype).itemsize),
             q_itemsize=q_itemsize) + (
                 paged_attn_ops.attend_cold_steps(live_blocks, calls=calls),)
+
+    write_step_counts = ServedModel._kv_write_step_counts
 
     def embed(self, params, tokens, pos):
         return _embed(params, tokens, pos, self.cfg)

@@ -1263,9 +1263,11 @@ class InferenceEngine:
                             plan.snapshot_in_program = True
                         else:
                             snaps[group] = (plan.page, plan.snapshot_page)
-                with self.telemetry.span("prefill_chunk", ci=ci,
-                                         active_groups=int(act.sum()),
-                                         rows=width, head=int(read)):
+                with self.telemetry.span(
+                        "prefill_chunk", ci=ci, active_groups=int(act.sum()),
+                        rows=width, head=int(read),
+                        **self._write_args(width, starts[act > 0],
+                                           last_idxs[act > 0] + 1)):
                     pools, *step = self._outputs(self._prefill_fn(
                         self._params, *pools, toks, bt_rows, starts,
                         last_idxs, act, *freeze, read, self._next_key(),
@@ -1352,6 +1354,21 @@ class InferenceEngine:
                              else np.minimum(lens, sp.reach)).sum()
             for sp in self._attend_specs))
         return args
+
+    def _write_args(self, k_rows: int, first_pos: np.ndarray,
+                    rows: np.ndarray) -> Dict[str, int]:
+        """``write_rows`` / ``write_runs``: the rows one layer's K/V write
+        of the execution about to run lands in the first class's pages, and
+        the runs it lands them in (a stream's rows in one page: a live grid
+        step of the write; ``ServedModel.write_step_counts``) — of a
+        ``prefill_chunk`` span and of a model of blocks' ``decode`` span,
+        for streams of ``k_rows`` rows from ``first_pos``, ``rows`` of them
+        live.  Nothing for a model that keeps no K/V pages."""
+        counts = self.served.write_step_counts(
+            first_pos, rows, K=k_rows, spec=self.cache_spec, mp=self.mp)
+        if counts is None:
+            return {}
+        return {"write_rows": counts[0], "write_runs": counts[1]}
 
     def _attend_steps(self, k_rows: int,
                       lengths: Optional[np.ndarray] = None,
@@ -1609,6 +1626,9 @@ class InferenceEngine:
                               attend_live_steps=steps[1],
                               attend_cold_steps=steps[2],
                               **self._class_args(mask), **state)
+            if B:       # (a pass writes every row of every block it is over)
+                span.set_metadata(**self._write_args(
+                    B, lengths[mask], np.full(int(mask.sum()), B)))
         lap("dispatch_s")
         return _Flight(fetch=fetch, logits=logits, probes=probes, mask=mask,
                        n_active=n_active, t0=t0,

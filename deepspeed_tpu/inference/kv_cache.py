@@ -35,11 +35,12 @@ sharding, matching ``models/transformer.block_param_shardings``).
 
 APPENDS are written into the donated pool where it lies
 (``paged_write_rows`` -> ``ops.paged_attention.paged_write``): an aliased
-Pallas call whose scalar-prefetched (layer, block, offset) pick each new
-row's block tile, rewrite that tile in VMEM and put it back. A step
-touches R tiles for R rows — its cost does not depend on ``num_blocks``
-— and the stacked pool goes through the layer loop as a carry beside
-the layer index, never sliced. Block GATHERS are the Pallas
+Pallas call whose scalar-prefetched (layer, block, offset) pick the block
+tile of each RUN of new rows (a stream's consecutive rows in one block; a
+row, where a stream brings one), rewrite that tile in VMEM and put it
+back. A step touches a tile a run — its cost does not depend on
+``num_blocks`` — and the stacked pool goes through the layer loop as a
+carry beside the layer index, never sliced. Block GATHERS are the Pallas
 paged-attention kernel on TPU; the one-hot ``paged_attend`` contraction
 (which slices its layer out and reads it whole) stays as the CPU-mesh
 path and the reference the kernel is tested against.  ONE copy serves
@@ -448,7 +449,8 @@ def block_select(bt: jax.Array, blocks_per_group: int) -> jax.Array:
 
 def paged_write_rows(pool_k: jax.Array, pool_v: jax.Array,
                      k_new: jax.Array, v_new: jax.Array, layer,
-                     blk: jax.Array, off: jax.Array, mesh=None
+                     blk: jax.Array, off: jax.Array, mesh=None, *,
+                     stream_rows: int = 1, one_block: bool = False
                      ) -> Tuple[jax.Array, jax.Array]:
     """Write R rows per group into layer ``layer`` of both pools at
     (block, offset), in place.
@@ -458,11 +460,16 @@ def paged_write_rows(pool_k: jax.Array, pool_v: jax.Array,
     DEAD_BLOCK write nowhere. Distinct live rows always target distinct
     (block, offset) cells — slots never share a writable block (the
     allocator's copy-on-write invariant) — and the rows of one block are
-    consecutive (a stream's positions ascend). The cost is R block
-    tiles read and written per pool, whatever the pool's size: see
+    consecutive (a stream's positions ascend). ``stream_rows``: the rows
+    are streams of this many at consecutive positions each (a program's K
+    rows a stream), ``one_block``: each stream's in ONE block — the write
+    then takes a block tile each way per RUN of a stream's rows in a block
+    and pool, not per row, whatever the pool's size: see
     ``ops.paged_attention.paged_write``."""
     return paged_attn_ops.paged_write(pool_k, pool_v, k_new, v_new, layer,
-                                      blk, off, mesh=mesh)
+                                      blk, off, mesh=mesh,
+                                      stream_rows=stream_rows,
+                                      one_block=one_block)
 
 
 def paged_attend(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,

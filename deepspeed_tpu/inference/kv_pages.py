@@ -136,7 +136,10 @@ def paged_classes(classes: Sequence[CacheClass], rows: Rows, pools, *,
         out[cls.name] = dict(
             name=cls.name, at=2 * i, reach=cls.reach, plan=plan,
             bt=bt_runs, seen=seen_runs, blk=blk.reshape(G, Sg * K),
-            off=off.reshape(G, Sg * K), rows=n_rows, runs=runs, layer=0)
+            off=off.reshape(G, Sg * K), rows=n_rows, runs=runs, layer=0,
+            # the write's: K consecutive rows a stream, in one page where a
+            # model of blocks' block divides it
+            stream_rows=K, one_block=rows.one_block and bs % K == 0)
     return out
 
 
@@ -156,7 +159,8 @@ def write_and_attend(c, pools, q, k, v, *, scale: float, mesh):
         kc, vc = kv_cache.paged_write_rows(
             kc, vc, k.reshape((G, Sg * K) + k.shape[2:]),
             v.reshape((G, Sg * K) + v.shape[2:]), layer,
-            c["blk"], c["off"], mesh=mesh)
+            c["blk"], c["off"], mesh=mesh, stream_rows=c["stream_rows"],
+            one_block=c["one_block"])
     with jax.named_scope("attend_" + c["name"]):
         qr = q.reshape(G, Sg * c["runs"], c["rows"], nH, D)
         if c["plan"] is not None:
@@ -227,6 +231,8 @@ class GqaPagedServed(ServedModel):
 
     def attend_run_rows(self, K: int) -> int:
         return attend_rows(K, self.cfg.group)
+
+    write_step_counts = ServedModel._kv_write_step_counts
 
     def paged_classes(self, rows: Rows, pools, *, paged_kernel: bool, mesh):
         """``paged_classes`` of this model's classes and head geometry."""

@@ -226,7 +226,9 @@ class MinicpmSalaServed(GqaPagedServed):
                     pools[KP], pools[VP],
                     k.reshape((G, Sg * K) + k.shape[2:]),
                     v.reshape((G, Sg * K) + v.shape[2:]), layer,
-                    targets["blk"], targets["off"], mesh=mesh)
+                    targets["blk"], targets["off"], mesh=mesh,
+                    stream_rows=targets["stream_rows"],
+                    one_block=targets["one_block"])
             with jax.named_scope("ck_write"):
                 if rows.chunked:
                     n_live = live.sum(axis=1).astype(jnp.int32)

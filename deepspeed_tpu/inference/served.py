@@ -80,6 +80,7 @@ import numpy as np
 from jax import lax
 
 from . import kv_cache
+from ..ops import paged_attention as paged_attn_ops
 
 _IMPLEMENTATIONS: Dict[type, Callable[[Any], "ServedModel"]] = {}
 
@@ -235,6 +236,25 @@ class ServedModel:
         attend kernel sequences for one layer, in ``calls`` calls (the
         shards of a dp mesh), host integers."""
         raise NotImplementedError
+
+    def write_step_counts(self, first_pos, rows, *, K: int, spec, mp: int
+                          ) -> Optional[Tuple[int, int, int]]:
+        """(rows, runs, grid steps) of one layer's K/V write into the pages
+        of class ``spec`` (``ops.paged_attention.write_step_counts``) for
+        streams of K rows from ``first_pos`` [streams], ``rows`` [streams]
+        of them live — host integers; None: the model keeps no K/V pages
+        that write lands."""
+        return None
+
+    def _kv_write_step_counts(self, first_pos, rows, *, K, spec, mp):
+        """``write_step_counts`` of a model whose ``forward`` lands its K/V
+        rows through ``kv_cache.paged_write_rows``, a program's K rows a
+        stream (a block of a model of blocks in one page where it divides
+        the page: ``Rows.one_block``)."""
+        return paged_attn_ops.write_step_counts(
+            first_pos, rows, K=K, block_size=spec.block_size,
+            one_block=K == self.block_length and spec.block_size % K == 0,
+            num_heads=max(1, spec.num_heads // mp), head_dim=spec.head_dim)
 
     def attend_run_rows(self, K: int) -> int:
         """Query rows of a prefill chunk of K rows that share ONE walk of
@@ -477,8 +497,12 @@ class Rows(NamedTuple):
     them) and not one row — or K drafted ones — of every slot; ``freeze``:
     (row [S], page [S]) — a stream's state as it stands after chunk row
     ``row`` goes into ``page`` too (a snapshot; ``DEAD_BLOCK``: none), or
-    None.  Built by the two constructors below and by nothing else: what
-    rows a program computes is decided here."""
+    None; ``one_block``: a stream's K rows ARE one block of a model of
+    blocks — its first position is a multiple of K (the engine's word: a
+    prompt's prefill ends at a block boundary and a commit advances a
+    length by a block), so where K divides a page the K/V write lands them
+    in one step.  Built by the two constructors below and by nothing else:
+    what rows a program computes is decided here."""
     tables: jax.Array
     widths: Tuple[int, ...]
     positions: jax.Array
@@ -486,6 +510,7 @@ class Rows(NamedTuple):
     sees: jax.Array
     chunked: bool = False
     freeze: Optional[Tuple[jax.Array, jax.Array]] = None
+    one_block: bool = False
 
     @classmethod
     def of_slots(cls, lengths, block_tables, K: int, num_groups: int,
@@ -498,7 +523,8 @@ class Rows(NamedTuple):
         tables = group_shape(block_tables, num_groups)
         pos = group_shape(pos, num_groups)
         return cls(tables, widths, pos, live, pos if block_length is None
-                   else _block_end(pos, block_length))
+                   else _block_end(pos, block_length),
+                   one_block=K == block_length)
 
     @classmethod
     def of_chunk(cls, bt_rows, start, last_idx, active, width: int, widths,

@@ -176,7 +176,13 @@ SPAN_ARGS = {
     # head: 1 where the chunk program ENDS some group's prompt and so runs
     # the model's head and the sampler, 0 where it skips both (its tokens
     # and logits are zeros nobody fetches).
-    "prefill_chunk": ("ci", "active_groups", "rows", "head"),
+    # write_rows / write_runs: the rows one layer's K/V write of the chunk
+    # program lands in the first class's pages, and the runs (a stream's
+    # rows in one page: a live grid step of the write) it lands them in
+    # (ops.paged_attention.write_step_counts; absent for a model that
+    # keeps no K/V pages)
+    "prefill_chunk": ("ci", "active_groups", "rows", "head", "write_rows",
+                      "write_runs"),
     # A decode span holds the DISPATCH of one iteration and the FETCH of
     # the one before (the loop runs an iteration ahead of its token
     # fetch): iteration .. attend_* and state_pages_live describe the one
@@ -215,7 +221,11 @@ SPAN_ARGS = {
                # a model generated in blocks (inference/sdar.py), of the
                # pass FETCHED: rows it computed for live streams, slots
                # whose block it committed, positions it unmasked
-               "block_rows", "commits", "unmasked"),
+               "block_rows", "commits", "unmasked",
+               # ... and of the pass DISPATCHED, as ``prefill_chunk``'s:
+               # rows a run is the block's length where a block lies in a
+               # page
+               "write_rows", "write_runs"),
     # The emission's row of the serving timeline (monitor/serving.py),
     # the streams it hands tokens to and those of them that waited the
     # whole interval since the emission before, that interval, the part

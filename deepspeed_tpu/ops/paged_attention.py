@@ -54,9 +54,11 @@ tier-1 proves logit parity against the one-hot baseline.
 
 ``paged_write`` is the other half of the same mechanism: new K/V rows are
 written INTO the donated pool where it lies (an aliased ``pallas_call``
-whose scalar-prefetched tile index and offset pick each row's block tile;
-read-modify-write of that one tile in VMEM), so a step's cost is O(rows
-written), never O(pool).
+whose scalar-prefetched tile index and offsets pick the block tile of a
+RUN of rows — the consecutive rows of one stream that lie in one block: a
+prefill chunk's pages, a model of blocks' block; one row where a stream
+brings one — read-modify-write of that one tile in VMEM), so a step's cost
+is O(rows written), never O(pool), and its grid steps O(runs).
 """
 from __future__ import annotations
 
@@ -974,110 +976,278 @@ def paged_attention(q, pool_k, pool_v, layer, block_tables=None,
 # The write: new rows into the donated pool, in place
 # --------------------------------------------------------------------- #
 
-def _kv_write_kernel(rows_ref, off_ref, nk_ref, nv_ref, k_in, v_in, k_out,
-                     v_out, *, D):
-    """One grid step = one (group, row): the row's block tile
-    ``[nH, bs/f, f*D]`` is read, the row's position overwritten, the tile
-    written back. Consecutive rows of one block (a prefill chunk, a
-    verify stream) revisit the same output tile, which then stays in
-    VMEM: it is loaded from the pool on the first visit only and goes
-    back when the block changes. Dead rows (offset < 0) ride the block
-    of a live neighbour and change nothing."""
+# Rows of a block tile a run step reads, selects a row into and writes back
+# at a time: the sublanes of one packed bf16 vreg (an fp32 tile's two).
+_WRITE_GROUP = 16
+# A stream's new rows ride a run step's VMEM as ONE block (fp32, both pools,
+# two buffers each): a stream of more rows than this many bytes hold is
+# written as several streams of consecutive rows.
+_WRITE_ROWS_BYTES = 2 ** 20
+
+
+def write_runs(K: int, block_size: int, one_block: bool = False) -> int:
+    """Grid steps a stream of ``K`` consecutive rows takes in the write: one
+    a RUN (the rows of the stream that lie in one block) — every block the
+    rows can touch from any start, or one where the caller says they lie in
+    ONE block (``one_block``); a row a step where a stream brings one."""
+    return 1 if K == 1 or one_block else (K - 1) // block_size + 2
+
+
+def _stream_rows(K: int, num_heads: int, lanes: int) -> int:
+    """Rows of a stream of ``K`` a run step holds in VMEM at once
+    (``_WRITE_ROWS_BYTES``): K, or a divisor of it."""
+    rows = K
+    while num_heads * rows * lanes * 4 > _WRITE_ROWS_BYTES and rows % 2 == 0:
+        rows //= 2
+    return rows
+
+
+def write_step_counts(first_pos, rows, *, K: int, block_size: int,
+                      one_block: bool = False, num_heads: int = 1,
+                      head_dim: int = 128):
+    """(rows, runs, grid steps) of ONE layer's write — host integers, no
+    device work: ``first_pos`` / ``rows`` [streams], the position of each
+    stream's first row and how many of its ``K`` rows are live (0: a dead
+    stream).  A run is the live rows of a stream that lie in one block (what
+    a live step lands); the steps are the static grid's, dead ones included
+    (``write_runs`` a stream, each of at most ``_stream_rows`` rows).
+    ``num_heads`` is what one shard holds."""
+    first_pos = np.asarray(first_pos, np.int64).reshape(-1)
+    rows = np.asarray(rows, np.int64).reshape(-1)
+    last = first_pos + rows - 1
+    runs = np.where(rows > 0, last // block_size - first_pos // block_size
+                    + 1, 0)
+    f = _fold(head_dim, block_size)
+    part = _stream_rows(K, num_heads, f * head_dim)
+    steps = len(rows) * (K // part) * write_runs(part, block_size, one_block)
+    return int(rows.sum()), int(runs.sum()), int(steps)
+
+
+def _kv_write_kernel(tile_ref, off_ref, *refs, D, K, N=1):
+    """One grid step = one (group, RUN): the consecutive rows of a stream
+    that lie in one block (``K`` rows a stream, ``N`` steps each; K = 1: a
+    row).  The run's block tile ``[nH, bs/f, f*D]`` (or the aligned part of
+    it that holds the run, where the caller knows one does: ``off_ref`` then
+    counts from the part's start) is read, the run's rows put in their
+    places, the tile written back.  Steps that name the same tile one after
+    another (a dead step rides the tile of a live neighbour and changes
+    nothing) keep it in VMEM: it is loaded from the pool on the first visit
+    only and goes back when the block changes.
+
+    K = 1 (``refs``: rows k, rows v, the pools in and out): the row
+    ``[nH, 1, f*D]``, f copies side by side in the lanes, is selected into
+    the whole tile at offset ``off_ref[g, r]`` (< 0: a dead row).
+
+    K > 1 (``refs``: start, count[, part], then the same): the stream's rows
+    are ONE fp32 block ``[nH, K, f*D]`` (a row a sublane, which a loop can
+    name; exact) and the run is rows ``start .. start + |count|`` of it.
+    ``count < 0`` says the run IS a block — all of its rows, live, from a
+    sublane-aligned start: one aligned store as the tile lays them, no
+    select.  Else a loop over the run's rows selects each into the
+    ``_WRITE_GROUP`` tile rows that hold its offset ``off_ref[g, row]``
+    (< 0: dead, no hit).  Selected in fp32 (exact for every pool dtype):
+    the v5e has no 16-bit vector select."""
+    *run, nk_ref, nv_ref, k_in, v_in, k_out, v_out = refs
     g, r = pl.program_id(0), pl.program_id(1)
-    off = off_ref[g, r]
+    nH, bsf, fD = k_out.shape[1:]
+    f = fD // D
     first = jnp.logical_or(
-        r == 0, rows_ref[g, r] != rows_ref[g, jnp.maximum(r - 1, 0)])
+        r == 0, tile_ref[g, r] != tile_ref[g, jnp.maximum(r - 1, 0)])
+    for part_ref in run[2:]:            # (which part of the tile, if a part)
+        first = jnp.logical_or(
+            first, part_ref[g, r] != part_ref[g, jnp.maximum(r - 1, 0)])
 
     @pl.when(first)
     def _load():
         k_out[...] = k_in[...]
         v_out[...] = v_in[...]
 
-    @pl.when(off >= 0)
-    def _write():
-        nH, bsf, fD = k_out.shape[1:]
-        f = fD // D
-        hit = jax.lax.broadcasted_iota(jnp.int32, (nH, bsf, fD), 1) == \
-            jax.lax.div(off, f)
+    def select(row_of, off, rows=None):
+        """The row ``row_of(new)`` over the tile's offset ``off``, in the
+        tile rows ``rows`` (a slice; None: the whole tile)."""
+        n, at, at_row = bsf, (0,), jax.lax.div(off, f)
+        if rows is not None:
+            n, at, at_row = rows.size, (0, slice(None), rows), \
+                at_row - rows.start
+        hit = jax.lax.broadcasted_iota(jnp.int32, (nH, n, fD), 1) == at_row
         if f > 1:
-            lane = jax.lax.broadcasted_iota(jnp.int32, (nH, bsf, fD), 2)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (nH, n, fD), 2)
             hit = jnp.logical_and(
                 hit, jax.lax.div(lane, D) == jax.lax.rem(off, f))
         for new, out in ((nk_ref, k_out), (nv_ref, v_out)):
-            # Selected in fp32 (exact for every pool dtype): the v5e has
-            # no 16-bit vector select.
-            row = new[0, 0].astype(jnp.float32)          # [nH, 1, f*D]
-            cur = out[0].astype(jnp.float32)
-            out[0] = jnp.where(hit, row, cur).astype(out.dtype)
+            row = row_of(new).astype(jnp.float32)        # [nH, 1, f*D]
+            cur = out[at].astype(jnp.float32)
+            out[at] = jnp.where(hit, row, cur).astype(out.dtype)
+
+    if K == 1:
+        off = off_ref[g, r]
+
+        @pl.when(off >= 0)
+        def _write():
+            select(lambda new: new[0, 0], off)
+        return
+
+    start, count = run[0][g, r], run[1][g, r]
+    base = (r if N == 1 else r // N) * K    # the stream's first row
+    group = _WRITE_GROUP if bsf % _WRITE_GROUP == 0 else bsf
+
+    if f == 1 and K >= bsf:
+        @pl.when(count < 0)
+        def _whole():
+            rows = pl.ds(pl.multiple_of(start, 8), bsf)
+            for new, out in ((nk_ref, k_out), (nv_ref, v_out)):
+                out[0] = new[0, 0, :, rows, :].astype(out.dtype)
+
+    def one(i, carry):
+        k = start + i
+        off = off_ref[g, base + k]
+
+        @pl.when(off >= 0)
+        def _write():
+            rows = None if group == bsf else pl.ds(
+                pl.multiple_of(jax.lax.div(off, f * group) * group, group),
+                group)
+            select(lambda new: new[0, 0, :, pl.ds(k, 1), :], off, rows)
+        return carry
+
+    jax.lax.fori_loop(0, jnp.maximum(count, 0), one, 0)
 
 
-def _write_local(pool_k, pool_v, k_new, v_new, layer, blk, off):
+def _write_local(pool_k, pool_v, k_new, v_new, layer, blk, off, *,
+                 stream_rows, one_block):
     """Per-shard write: pools [L, G, B, nH, bs/f, f*D]; k_new/v_new
-    [G, R, nH, D]; blk/off [G, R] (group-local ids, DEAD_BLOCK = -1)."""
+    [G, R, nH, D]; blk/off [G, R] (group-local ids, DEAD_BLOCK = -1); R is
+    streams x ``stream_rows`` consecutive rows each."""
     _, G, B, nH, bsf, fD = pool_k.shape
     R, D = blk.shape[1], k_new.shape[-1]
     f = fD // D
-    # Every grid step needs SOME block to hold; a dead row takes the
-    # nearest live row's before it (else the first live row's, else
-    # block 0), so each block is still one contiguous run of steps and
-    # a dead row's tile is written back as it was read.
+    bs = bsf * f
+    K = _stream_rows(stream_rows, nH, fD)
+    N = write_runs(K, bs, one_block)
+    # A step moves the run's block tile both ways — or, where every run lies
+    # in ONE aligned group of ``_WRITE_GROUP`` tile rows (a block of a model
+    # of blocks: K divides the group's positions), that PART of it alone.
+    part = _WRITE_GROUP * f
+    parts = bsf // _WRITE_GROUP if K > 1 and one_block and part % K == 0 \
+        and bsf % _WRITE_GROUP == 0 else 1
     live = blk >= 0
-    idx = jnp.where(live, jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1),
-                    -1)
+    offs = jnp.where(live, off if parts == 1 else off % part, -1) \
+        .astype(jnp.int32)
+    if K == 1:
+        run_live, run_blk, scalars = live, blk, (offs,)
+    else:
+        # The runs of every stream, from its first row's offset alone (the
+        # rows' positions are consecutive): run n holds the rows of the
+        # stream's n-th block, [first, end) of its K.
+        S = R // K
+        n = jax.lax.broadcasted_iota(jnp.int32, (1, 1, N), 2)
+        off0 = off.reshape(G, S, K)[:, :, :1]
+        first = jnp.clip(n * bs - off0, 0, K)
+        end = jnp.clip((n + 1) * bs - off0, 0, K)
+        k = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, K), 3)
+        mine = jnp.logical_and(
+            jnp.logical_and(k >= first[..., None], k < end[..., None]),
+            live.reshape(G, S, 1, K))
+        n_live = mine.sum(-1)                                   # [G, S, N]
+        run_blk = jnp.where(mine, blk.reshape(G, S, 1, K), -1).max(-1) \
+            .reshape(G, S * N)
+        run_live = (n_live > 0).reshape(G, S * N)
+        count = jnp.where(n_live > 0, end - first, 0)
+        if f == 1:
+            # (a run that IS a block, from a start the fp32 rows' sublanes
+            # align with: the kernel's one store)
+            whole = jnp.logical_and(n_live == bs, first % 8 == 0)
+            count = jnp.where(whole, -count, count)
+        scalars = (offs, first.reshape(G, S * N).astype(jnp.int32),
+                   count.reshape(G, S * N).astype(jnp.int32))
+    # Every grid step needs SOME block to hold; a dead step takes the
+    # nearest live one's before it (else the first live one's, else block
+    # 0), so each block is still one contiguous run of steps and a dead
+    # step's tile is written back as it was read.
+    idx = jnp.where(run_live, jax.lax.broadcasted_iota(
+        jnp.int32, run_blk.shape, 1), -1)
     src = jax.lax.cummax(idx, axis=1)
-    src = jnp.where(src >= 0, src, jnp.argmax(live, axis=1)[:, None])
-    eb = jnp.maximum(jnp.take_along_axis(blk, src, axis=1), 0)
+    src = jnp.where(src >= 0, src, jnp.argmax(run_live, axis=1)[:, None])
+    eb = jnp.maximum(jnp.take_along_axis(run_blk, src, axis=1), 0)
     group = jnp.arange(G, dtype=jnp.int32)[:, None]
-    tiles = ((layer * G + group) * B + eb).astype(jnp.int32)   # [G, R]
-    offs = jnp.where(live, off, -1).astype(jnp.int32)
+    tiles = ((layer * G + group) * B + eb).astype(jnp.int32)   # [G, steps]
+    if parts > 1:
+        # (the part of its tile a run lies in; a dead step rides its live
+        # neighbour's part as it rides its tile)
+        scalars += (jnp.take_along_axis(
+            (off0 // part).reshape(G, -1), src, axis=1).astype(jnp.int32),)
 
     def rows(new):
         # The row as it lies in a tile: f copies side by side in the
         # lanes (the kernel's select keeps the one at the row's offset).
         new = new.astype(pool_k.dtype)
-        return jnp.tile(new, (1, 1, 1, f))[:, :, :, None, :]
+        if K == 1:
+            return jnp.tile(new, (1, 1, 1, f))[:, :, :, None, :]
+        new = new.astype(jnp.float32).reshape(G, R // K, K, nH, D)
+        return jnp.tile(new.transpose(0, 1, 3, 2, 4), (1, 1, 1, 1, f))
 
-    def _row_map(g, r, tiles_p, off_p):
-        return (g, r, 0, 0, 0)
+    def _row_map(g, r, *_):
+        return (g, r if N == 1 else r // N, 0, 0, 0)
 
-    def _pool_map(g, r, tiles_p, off_p):
-        return (tiles_p[g, r], 0, 0, 0)
+    def _pool_map(g, r, tiles_p, *more):
+        return (tiles_p[g, r], 0, more[3][g, r] if parts > 1 else 0, 0)
 
-    row_spec = pl.BlockSpec((1, 1, nH, 1, fD), _row_map)
-    pool_spec = pl.BlockSpec((1, nH, bsf, fD), _pool_map)
+    row_spec = pl.BlockSpec((1, 1, nH, K, fD), _row_map)
+    pool_spec = pl.BlockSpec((1, nH, bsf // parts, fD), _pool_map)
     flat_k, flat_v = _pool_rows(pool_k), _pool_rows(pool_v)
     out_k, out_v = pl.pallas_call(
-        functools.partial(_kv_write_kernel, D=D),
+        functools.partial(_kv_write_kernel, D=D, K=K, N=N),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(G, R),
+            num_scalar_prefetch=1 + len(scalars),
+            grid=(G, tiles.shape[1]),
             in_specs=[row_spec, row_spec, pool_spec, pool_spec],
             out_specs=[pool_spec, pool_spec]),
         out_shape=[jax.ShapeDtypeStruct(flat_k.shape, flat_k.dtype),
                    jax.ShapeDtypeStruct(flat_v.shape, flat_v.dtype)],
-        # operands: tiles, offs, rows_k, rows_v, pool_k, pool_v
-        input_output_aliases={4: 0, 5: 1},
+        # operands: tiles, the scalars, rows_k, rows_v, pool_k, pool_v
+        input_output_aliases={3 + len(scalars): 0, 4 + len(scalars): 1},
         name="_kv_write_kernel",
         interpret=_interpret(),
-    )(tiles, offs, rows(k_new), rows(v_new), flat_k, flat_v)
+    )(tiles, *scalars, rows(k_new), rows(v_new), flat_k, flat_v)
     return out_k.reshape(pool_k.shape), out_v.reshape(pool_v.shape)
 
 
+# The layers of a program hand the write the same shapes: under ``jax.jit``
+# the run grid's index work and kernel are traced and lowered ONCE a program
+# and called a layer (XLA inlines the calls) — a start pays a kernel
+# instance's ~0.2 s of host time once, not a layer.  (A row a step is left
+# as it lowers: the programs of a model of tokens keep their text.)
+_write_runs_local = jax.jit(_write_local,
+                            static_argnames=("stream_rows", "one_block"))
+
+
 def paged_write(pool_k, pool_v, k_new, v_new, layer, blk, off, *,
-                mesh=None):
+                mesh=None, stream_rows: int = 1, one_block: bool = False):
     """Write R rows per group into one layer of both pools, in place.
 
     pool_k/v: [L, G, B, nH, bs/f, f*D] (donated by the caller's jit: the
-    call aliases them to its outputs and touches R block tiles each);
-    k_new/v_new: [G, R, nH, D]; layer: int32 scalar; blk/off: [G, R] —
-    rows with blk == DEAD_BLOCK write nowhere. Rows of one block must be
-    consecutive and no block may be named by two separate runs of rows
-    (the allocator's invariant: a writable block has one owner, and a
-    stream's positions ascend). Returns (pool_k', pool_v')."""
+    call aliases them to its outputs and touches a block tile each way per
+    RUN of rows); k_new/v_new: [G, R, nH, D]; layer: int32 scalar; blk/off:
+    [G, R] — rows with blk == DEAD_BLOCK write nowhere. Rows of one block
+    must be consecutive and no block may be named by two separate runs of
+    rows (the allocator's invariant: a writable block has one owner, and a
+    stream's positions ascend).
+
+    ``stream_rows``: the R rows are streams of this many rows each at
+    CONSECUTIVE positions (``off`` counts up by one a row, dead rows'
+    too, and starts again at 0 in the next block: a prefill chunk, a
+    verify's drafts, a block of a model of blocks) — a grid step is then a
+    run of a stream's rows in one block, ``write_runs`` steps a stream,
+    not a row; ``one_block``: every stream's rows lie in ONE block (its
+    first row at a multiple of ``stream_rows``, which divides the block).
+    The pools come out byte for byte what a row a step leaves (1: rows of
+    no stated order).  Returns (pool_k', pool_v')."""
     if pltpu is None:  # pragma: no cover - pallas TPU support missing
         raise RuntimeError("pallas TPU backend unavailable")
     fn = _on_mesh(
-        _write_local, mesh,
+        functools.partial(_write_local if stream_rows == 1
+                          else _write_runs_local, stream_rows=stream_rows,
+                          one_block=one_block), mesh,
         lambda dpn, mpn: (_pool_spec(dpn, mpn), _pool_spec(dpn, mpn),
                           P(dpn, None, mpn, None), P(dpn, None, mpn, None),
                           P(), P(dpn), P(dpn)),
@@ -1088,5 +1258,6 @@ def paged_write(pool_k, pool_v, k_new, v_new, layer, blk, off, *,
 
 
 __all__ = ["paged_attention", "attend_plan", "AttendPlan", "paged_write",
+           "write_runs", "write_step_counts",
            "paged_kernel_enabled", "attend_step_counts", "attend_cold_steps",
            "attend_flops_per_token", "attend_hbm_bytes_per_token"]

@@ -415,8 +415,11 @@ def golden(family: str, arm: str) -> dict:
 if __name__ == "__main__":
     DUMP = sys.argv[2] if len(sys.argv) > 2 else None
     # (``ADDED`` as a third argument: the families later PRs added alone;
-    # ``PR62`` / ``PR64`` / ``PR65``: the fixture that PR added)
-    names = ADDED if "ADDED" in sys.argv[3:] else \
+    # ``PR62`` / ``PR64`` / ``PR65``: the fixture that PR added; ``ALL``:
+    # every fixture, in one file)
+    names = {**FAMILIES, **ADDED, **ADDED_BY_PR62, **ADDED_BY_PR64,
+             **ADDED_BY_PR65} if "ALL" in sys.argv[3:] else \
+        ADDED if "ADDED" in sys.argv[3:] else \
         ADDED_BY_PR62 if "PR62" in sys.argv[3:] else \
         ADDED_BY_PR64 if "PR64" in sys.argv[3:] else \
         ADDED_BY_PR65 if "PR65" in sys.argv[3:] else FAMILIES

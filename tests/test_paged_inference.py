@@ -201,8 +201,10 @@ def _write_case(kind, D, *, G=1, nH=2, seed=0):
     return pool_k, pool_v, rows_k, rows_v, bt, pos
 
 
-def _numpy_write(pool, rows, layer, bt, pos, bs):
-    """The plain reference: one row at a time."""
+def _numpy_write(pool, rows, layer, bt, pos, bs, live=None, ring=False):
+    """The plain reference: one row at a time (``live`` [G, Sg, K]: the
+    rows that are traffic, all by default; ``ring``: a bounded class's
+    table, logical block j at slot ``j % J``)."""
     out = pool.copy()
     G, Sg, K = pos.shape
     J = bt.shape[-1]
@@ -210,6 +212,10 @@ def _numpy_write(pool, rows, layer, bt, pos, bs):
         for s in range(Sg):
             for k in range(K):
                 j, off = divmod(int(pos[g, s, k]), bs)
+                if ring:
+                    j %= J
+                if live is not None and not live[g, s, k]:
+                    continue
                 if j >= J or bt[g, s, j] == kv_cache.DEAD_BLOCK:
                     continue
                 out[layer, g, bt[g, s, j], :, off, :] = rows[g, s * K + k]
@@ -232,6 +238,135 @@ def _device_write(pool_k, pool_v, rows_k, rows_v, layer, bt, pos, bs,
         jnp.asarray(rows_v), jnp.asarray(bt), jnp.asarray(pos))
     return (np.asarray(kv_cache.paged_logical_view(kc, D), np.float32),
             np.asarray(kv_cache.paged_logical_view(vc, D), np.float32))
+
+
+# The run write's cases: pages of ``_BS`` rows, ``J`` table slots a stream;
+# a stream's ``K`` rows start at ``starts[s]`` and its first ``lives[s]`` are
+# live (its table holds exactly the pages those reach).
+_BS = 64
+_CHUNK = dict(K=512, J=12, lives=[512])
+_RUN_CASES = {
+    "a_row_a_stream": dict(K=1, J=4, starts=[5, 70, 0, 130, 200, 255],
+                           lives=[1, 1, 0, 1, 1, 1], rows_a_run=1.0),
+    "a_block_in_a_page": dict(K=4, J=4, starts=[0, 60, 128, 12, 200],
+                              lives=[4, 4, 0, 4, 4], one_block=True,
+                              rows_a_run=4.0),
+    "a_block_in_a_page_unsaid": dict(K=4, J=4, starts=[0, 60, 128, 12, 200],
+                                     lives=[4, 4, 0, 4, 2], rows_a_run=3.5),
+    "a_block_astride_two_pages": dict(K=4, J=4, starts=[62, 126, 0, 190],
+                                      lives=[4, 4, 0, 3], rows_a_run=11 / 6),
+    "chunk_from_a_boundary": dict(_CHUNK, starts=[128], rows_a_run=64.0),
+    "chunk_from_mid_page": dict(_CHUNK, starts=[72], rows_a_run=512 / 9),
+    "chunk_from_an_odd_row": dict(_CHUNK, starts=[69], rows_a_run=512 / 9),
+    "chunk_cut_by_last_idx": dict(_CHUNK, starts=[64], lives=[301],
+                                  rows_a_run=301 / 5),
+    "chunk_past_the_table": dict(_CHUNK, starts=[583], rows_a_run=185 / 3),
+    "chunk_in_parts": dict(_CHUNK, starts=[72], rows_a_run=512 / 9),
+    "ring_wraps": dict(K=128, J=4, starts=[222], lives=[128], ring=True,
+                       rows_a_run=128 / 3),
+    "two_groups_two_head_shards": dict(K=4, J=4, starts=[0, 62, 128, 12],
+                                       lives=[4, 4, 0, 3], mesh=True,
+                                       rows_a_run=11 / 4),
+    "a_chunk_two_groups_two_head_shards": dict(
+        _CHUNK, K=128, starts=[72], lives=[100], mesh=True,
+        rows_a_run=100 / 2),
+}
+_RUN_PARAMS = [
+    pytest.param(case, D, dtype, id=f"{case}-{D}-{jnp.dtype(dtype).name}")
+    for case, c in sorted(_RUN_CASES.items())
+    for D, dtype in ([(64, jnp.float32)] if c.get("mesh") else
+                     [(64, jnp.bfloat16), (128, jnp.bfloat16)]
+                     + ([(64, jnp.float32), (128, jnp.float32)]
+                        if case in ("a_block_astride_two_pages",
+                                    "chunk_from_an_odd_row", "ring_wraps")
+                        else []))]
+
+
+def _run_case(c, D, seed=0):
+    """(logical pools, rows, tables [G, Sg, J], positions and ``live``
+    [G, Sg, K]) of one of ``_RUN_CASES``."""
+    G, nH = (2, 4) if c.get("mesh") else (1, 2)
+    K, J, starts, lives = c["K"], c["J"], c["starts"], c["lives"]
+    Sg, L = len(starts), 2
+    B = Sg * J + 2
+    rng = np.random.default_rng(seed)
+    pk, pv = (rng.standard_normal((L, G, B, nH, _BS, D)).astype(np.float32)
+              for _ in range(2))
+    bt = np.full((G, Sg, J), kv_cache.DEAD_BLOCK, np.int32)
+    pos = np.zeros((G, Sg, K), np.int32)
+    live = np.zeros((G, Sg, K), bool)
+    for g in range(G):
+        free = list(rng.permutation(B))
+        for s, (start, n) in enumerate(zip(starts, lives)):
+            pos[g, s] = start + np.arange(K)
+            live[g, s, :n] = True
+            for j in range(start // _BS, (start + n - 1) // _BS + 1
+                           if n else 0):
+                if c.get("ring") or j < J:
+                    bt[g, s, j % J] = free.pop()
+    rk, rv = (rng.standard_normal((G, Sg * K, nH, D)).astype(np.float32)
+              for _ in range(2))
+    return pk, pv, rk, rv, bt, pos, live
+
+
+def _run_step(c, D, dtype, mesh, by_row=False):
+    """The traced write of a case: one call a run a step, or (``by_row``) a
+    loop of calls of ONE row each."""
+    ring = c.get("ring", False)
+
+    def step(kc, vc, rk, rv, bt, pos, live):
+        G, Sg, K = pos.shape
+        table = jnp.broadcast_to(bt[:, :, None, :], pos.shape + bt.shape[-1:])
+        blk, off = kv_cache.positions_to_blocks(table, pos, _BS, ring=ring)
+        blk = jnp.where(live, blk, kv_cache.DEAD_BLOCK).reshape(G, Sg * K)
+        off = off.reshape(G, Sg * K)
+        if not by_row:
+            return kv_cache.paged_write_rows(
+                kc, vc, rk, rv, 1, blk, off, mesh=mesh, stream_rows=K,
+                one_block=c.get("one_block", False))
+
+        def one(i, pools):
+            at = lambda a: jax.lax.dynamic_slice_in_dim(a, i, 1, axis=1)
+            return kv_cache.paged_write_rows(
+                *pools, at(rk), at(rv), 1, at(blk), at(off), mesh=mesh)
+        return jax.lax.fori_loop(0, Sg * K, one, (kc, vc))
+    return step
+
+
+def _run_write(c, pk, pv, rk, rv, bt, pos, live, dtype, mesh):
+    """([K pool, V pool] a run a step, the same a row a call), logical
+    fp32."""
+    D = pk.shape[-1]
+    held = lambda a: kv_cache.paged_folded_view(jnp.asarray(a, dtype))
+    if mesh is not None:
+        sh = kv_cache.paged_shardings(mesh)
+        held = lambda a: jax.device_put(
+            kv_cache.paged_folded_view(jnp.asarray(a, dtype)), sh["k"])
+    out = []
+    for by_row in (False, True):
+        pools = jax.jit(_run_step(c, D, dtype, mesh, by_row))(
+            held(pk), held(pv), *map(jnp.asarray, (rk, rv, bt, pos, live)))
+        out.append([np.asarray(kv_cache.paged_logical_view(p, D), np.float32)
+                    for p in pools])
+    return out
+
+
+def _in_parts(monkeypatch, request):
+    """A step's VMEM holds 32 of these cases' rows: a chunk goes in parts.
+    (The run write is traced once a shape: forget the traces made under the
+    other limit, before and after.)"""
+    from deepspeed_tpu.ops import paged_attention as pa
+    monkeypatch.setattr(pa, "_WRITE_ROWS_BYTES", 2 ** 15)
+    pa._write_runs_local.clear_cache()
+    request.addfinalizer(pa._write_runs_local.clear_cache)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
 
 
 class TestInPlaceWrite:
@@ -347,6 +482,74 @@ class TestInPlaceWrite:
         np.testing.assert_array_equal(
             np.asarray(kv_cache.paged_logical_view(vc, 64)),
             _numpy_write(pv, rv, 1, bt, pos, 16))
+
+    @pytest.mark.parametrize("case,head_dim,dtype", _RUN_PARAMS)
+    def test_a_run_a_step_leaves_what_a_row_a_step_leaves(
+            self, case, head_dim, dtype, monkeypatch, request):
+        """The write with a RUN of a stream's rows a grid step (``K`` > 1:
+        ``stream_rows``, ``one_block``) against the NumPy loop over rows AND
+        against the same rows written one a call, BYTE for byte, both pools
+        whole: a row a stream; a block of four in a page, said and unsaid,
+        and astride two; a 512-row chunk from a page's start (whole pages:
+        the one store), from mid-page at a sublane-aligned row and at an odd
+        one (a copy-on-write fork's start), cut by ``last_idx``, running
+        past its table, written in parts (a stream too long for the step's
+        VMEM); a ring class whose rows wrap into its first slot — pages of 64
+        rows at head_dim 128 and folded (two positions a lane row, the
+        select's 16-row groups) at 64, bf16 and fp32, and two groups over
+        two head shards on a mesh."""
+        from deepspeed_tpu.ops import paged_attention as pa
+        c = _RUN_CASES[case]
+        if case == "chunk_in_parts":
+            _in_parts(monkeypatch, request)
+        mesh = None
+        if c.get("mesh"):
+            mesh = build_mesh(dp=2, mp=2, devices=jax.devices()[:4])
+        pk, pv, rk, rv, bt, pos, live = _run_case(c, head_dim)
+        got, by_row = _run_write(c, pk, pv, rk, rv, bt, pos, live, dtype,
+                                 mesh)
+        rnd = lambda a: np.asarray(jnp.asarray(a, dtype), np.float32)
+        want = [_numpy_write(rnd(pool), rnd(rows), 1, bt, pos, _BS,
+                             live=live, ring=c.get("ring", False))
+                for pool, rows in ((pk, rk), (pv, rv))]
+        for a, b, w, before in zip(got, by_row, want, (pk, pv)):
+            bits = lambda x: x.view(np.uint32)
+            np.testing.assert_array_equal(bits(a), bits(w))
+            np.testing.assert_array_equal(bits(b), bits(w))
+            assert not np.array_equal(w, rnd(before))   # something landed
+
+    @pytest.mark.parametrize("case", sorted(_RUN_CASES))
+    def test_write_step_counts_are_the_grids(self, case, monkeypatch,
+                                             request):
+        """``write_step_counts`` — host integers from each stream's first
+        position and live rows — against the pools (rows landed, and runs: the
+        (stream, page) pairs the NumPy loop touches) and against the grid the
+        kernel is BUILT with (the traced ``pallas_call``'s)."""
+        from deepspeed_tpu.ops import paged_attention as pa
+        c = _RUN_CASES[case]
+        if case == "chunk_in_parts":
+            _in_parts(monkeypatch, request)
+        pk, pv, rk, rv, bt, pos, live = _run_case(c, 128)
+        G, Sg, K = pos.shape
+        J = bt.shape[-1]
+        landed = live & ((pos < J * _BS) | c.get("ring", False))
+        rows, runs, steps = pa.write_step_counts(
+            pos[..., 0], landed.sum(-1), K=K, block_size=_BS,
+            one_block=c.get("one_block", False), num_heads=pk.shape[3],
+            head_dim=128)
+        assert rows == int(landed.sum())
+        assert runs == len({(g, s, int(p) // _BS)
+                            for (g, s, k), p in np.ndenumerate(pos)
+                            if landed[g, s, k]})
+        step = _run_step(c, 128, jnp.bfloat16, None)
+        held = lambda a: kv_cache.paged_folded_view(
+            jnp.asarray(a, jnp.bfloat16))
+        jaxpr = jax.make_jaxpr(step)(held(pk), held(pv), rk, rv, bt, pos,
+                                     live)
+        grids = [eqn.params["grid_mapping"].grid for eqn in _eqns(jaxpr.jaxpr)
+                 if eqn.primitive.name == "pallas_call"]
+        assert grids == [(G, steps // G)]
+        assert rows / runs == c["rows_a_run"]
 
     @pytest.mark.parametrize("program", ["decode_step", "prefill_step"])
     def test_pool_buffers_are_donated_and_reused(self, params32, program):

@@ -1515,3 +1515,40 @@ def test_block_spans_carry_what_a_pass_did(tmp_path, blocks_engine):
     prefills = [a for _, _, a in found["prefill"]]
     assert prefills and all("block_rows" not in a for a in prefills)
     assert all(a["head"] == 0 for _, _, a in found["prefill_chunk"])
+    # The K/V write of a pass DISPATCHED lands a block a grid step (a block
+    # of 4 lies in a page of 8): rows a run = the block's length ...
+    dispatched = [a for _, _, a in found["decode"] if "write_rows" in a]
+    assert len(dispatched) == sum("attend_steps" in a
+                                  for _, _, a in found["decode"])
+    for a in dispatched:
+        assert a["write_rows"] == B * a["write_runs"] > 0
+    # ... and a chunk program's a page a step: the prompts of 16, 24 and 9
+    # tokens as far as their last block boundary, in chunks of 16 rows.
+    assert sorted((a["write_rows"], a["write_runs"])
+                  for _, _, a in found["prefill_chunk"]) == \
+        [(8, 1), (8, 1), (16, 2), (16, 2)]
+
+
+def test_write_args_are_the_write_kernels_counts(blocks_engine, serve_engine):
+    """``write_rows`` / ``write_runs`` are ``ops.paged_attention
+    .write_step_counts``' of the first class's pages: a block a run where a
+    model of blocks' block lies in a page, a page a run in a chunk from
+    mid-page, a row a run in a decode step of a model of tokens."""
+    from deepspeed_tpu.ops import paged_attention as pa
+    eng = blocks_engine
+    sp = eng.cache_spec
+    kw = dict(block_size=sp.block_size, num_heads=sp.num_heads,
+              head_dim=sp.head_dim)
+    first, rows = np.asarray([8, 12, 40]), np.asarray([4, 4, 4])
+    r, n, steps = pa.write_step_counts(first, rows, K=4, one_block=True, **kw)
+    assert (r, n, steps) == (12, 3, 3)
+    assert eng._write_args(4, first, rows) == {"write_rows": r,
+                                                "write_runs": n}
+    # a chunk of 16 rows from row 3 of a page of 8, 13 of them live: pages
+    # 0 (5 rows), 1 (8) — and every page the 16 could touch is a step
+    assert eng._write_args(16, np.asarray([3]), np.asarray([13])) == \
+        {"write_rows": 13, "write_runs": 2}
+    assert pa.write_step_counts([3], [13], K=16, **kw)[2] == 3
+    tokens = serve_engine        # GPT-2: K/V pages, a row a stream a step
+    assert tokens._write_args(1, np.asarray([5, 70, 9]), np.ones(3, int)) \
+        == {"write_rows": 3, "write_runs": 3}

@@ -30,9 +30,13 @@ engine's three programs are ``ServedModel``'s, written once over a family's
      left (``program_text_pr63.json``, ``MOVED_BY_PR63``); PR 65's fixture
      (a chunk whose runs are dense: the attend's chunk-shaped body) to what
      its PR left and, but for ``MOVED_BY_PR65``, to what the parent of PR 65
-     lowered for it (``program_text_pr65.json``).  A program that
-     lowers
-     to none of them fails: run ``python tests/decode_step_hlo.py OUT.json
+     lowered for it (``program_text_pr65.json``); and since PR 67 — the
+     K/V write takes a RUN of a stream's rows a grid step — every program
+     ``MOVED_BY_PR67`` names (the programs that land MORE than one row a
+     stream in K/V pages, whatever their family and kernel arm) to the text
+     PR 67 left (``program_text_pr67.json``: every fixture in one file,
+     ``python tests/decode_step_hlo.py OUT.json DIR ALL``).  A program that
+     lowers to none of them fails: run ``python tests/decode_step_hlo.py OUT.json
      DIR`` on both trees and ``diff`` the blanked texts to see which lines
      moved;
    - ``verify`` of a family whose cache is a state a stream to the ONE
@@ -97,6 +101,35 @@ CHUNK_BODY = ("a run of a prefill chunk that is `_DENSE_ROWS` query rows a K/V "
 # ... kernels ON only; ``decode_step`` (16 rows a K/V head) and
 # ``verify_step`` (48) keep ``_pattn_kernel``.
 MOVED_BY_PR65 = {"afmoe_dense": {"prefill_step": (CHUNK_BODY,)}}
+# PR 67, BOTH arms (the in-place write is the one write of both since PR 29:
+# ``inference.paged_kernel`` picks the attend): every program whose streams
+# bring MORE than one row to K/V pages — a ``prefill_step`` (the chunk's
+# width), a ``verify_step`` (``spec_k + 1``) and the block ``decode_step`` of
+# a model of blocks.  Every ``decode_step`` of a model of tokens (K = 1: the
+# row grid and body it had), every program of the latent and retention
+# families (``_latent_write_kernel`` / no pages) keeps its text.
+LEFT_BY_PR67 = json.load(open(os.path.join(DATA, "program_text_pr67.json")))
+RUNS = ("`paged_write` is told the rows a stream brings (`stream_rows` = K, "
+        "consecutive positions) and takes a grid step a RUN — a stream's "
+        "rows in one page — not a row: `(K - 1) // block_size + 2` steps a "
+        "stream (one where `Rows.one_block`: a model of blocks' block in a "
+        "page); the runs' first row and count are two more scalar-"
+        "prefetched operands, a stream's rows ride one fp32 block "
+        "`[nH, K, f*D]` (heads major), and the kernel's body stores a run "
+        "that IS a page whole and loops over the rows of any other, "
+        "selecting each into the 16 tile rows that hold its offset")
+MOVED_BY_PR67 = {
+    family: {kind: (RUNS,) for kind in kinds}
+    for family, kinds in {
+        "gpt2": ("prefill_step", "verify_step"),
+        "afmoe": ("prefill_step", "verify_step"),
+        "smallthinker": ("prefill_step", "verify_step"),
+        "lfm2": ("prefill_step",), "falcon_h1": ("prefill_step",),
+        "minicpm_sala": ("prefill_step",),
+        "sdar": ("decode_step", "prefill_step"),
+        "solar_open2": ("prefill_step",),
+        "afmoe_dense": ("prefill_step", "verify_step"),
+    }.items()}
 # The families added one a PR, each held to its own PR's file.
 ADDED_LATER = [(family, harness.ADDED_BY_PR62, ADDED_BY_PR62, 62)
                for family in sorted(harness.ADDED_BY_PR62)] \
@@ -196,6 +229,12 @@ def held_to_the_golden(family, kind, arm):
     by_pr58 = MOVED_BY_PR58.get(family, {}) if arm == "on" else {}
     for name in names:
         g, w = got["programs"][name], want["programs"][name]
+        if kind in MOVED_BY_PR67.get(family, {}):
+            assert g["order_free"] == LEFT_BY_PR67[family][arm]["programs"][
+                    name]["order_free"], (
+                f"{family}.{arm}.{name}: other operations than PR 67 left "
+                f"(moved then by: {'; '.join(MOVED_BY_PR67[family][kind])})")
+            continue
         if kind in by_pr58:
             assert g["order_free"] == LEFT_BY_PR58[family][arm]["programs"][
                     name]["order_free"], (
@@ -247,7 +286,8 @@ def test_an_added_familys_programs_are_what_its_pr_left(family, kind, arm):
         return
     assert names
     left_by = "its PR"
-    for pr, moved, left in ((63, MOVED_BY_PR63, LEFT_BY_PR63),
+    for pr, moved, left in ((67, MOVED_BY_PR67, LEFT_BY_PR67),
+                            (63, MOVED_BY_PR63, LEFT_BY_PR63),
                             (60, MOVED_BY_PR60, LEFT_BY_PR60)):
         if kind in moved.get(family, {}):
             want = left[family][arm]
@@ -288,6 +328,9 @@ def test_a_family_added_later_is_what_its_pr_left(family, made_by, golden,
                 paged_kernel=False)
         return
     assert names
+    if kind in MOVED_BY_PR67.get(family, {}):
+        want, pr = LEFT_BY_PR67[family][arm], "67 (moved then by: {})".format(
+            "; ".join(MOVED_BY_PR67[family][kind]))
     for name in names:
         assert got["programs"][name]["order_free"] \
             == want["programs"][name]["order_free"], (
@@ -314,8 +357,14 @@ def test_a_dense_chunk_alone_takes_the_chunk_body(family, kind, arm):
                                      if n.startswith(kind))
     assert names == sorted(n for n in was["programs"] if n.startswith(kind))
     moved = arm == "on" and kind in MOVED_BY_PR65[family]
+    by_pr67 = kind in MOVED_BY_PR67[family]
     for name in names:
         text = got["programs"][name]["order_free"]
+        if by_pr67:     # (PR 67 moved it in both arms: held to what IT left)
+            assert text == LEFT_BY_PR67[family][arm]["programs"][name][
+                "order_free"], (
+                f"{family}.{arm}.{name}: other operations than PR 67 left")
+            continue
         assert text == left["programs"][name]["order_free"], (
             f"{family}.{arm}.{name}: other operations than PR 65 left")
         assert (text != was["programs"][name]["order_free"]) == moved, (
@@ -332,16 +381,22 @@ def test_a_dense_chunk_alone_takes_the_chunk_body(family, kind, arm):
     (harness.ADDED, ADDED_BY_PR59, LEFT_BY_PR60,
      lambda family, arm: MOVED_BY_PR60.get(family, {})),
     (harness.ADDED, LEFT_BY_PR60, LEFT_BY_PR63,
-     lambda family, arm: MOVED_BY_PR63.get(family, {}))],
-    ids=["pr56", "pr58", "pr60", "pr63"])
+     lambda family, arm: MOVED_BY_PR63.get(family, {})),
+    ({**harness.FAMILIES, **harness.ADDED, **harness.ADDED_BY_PR62,
+      **harness.ADDED_BY_PR64, **harness.ADDED_BY_PR65},
+     {**LEFT_BY_PR58, **LEFT_BY_PR63, **ADDED_BY_PR62, **ADDED_BY_PR64,
+      **PR65["left"]}, LEFT_BY_PR67,
+     lambda family, arm: MOVED_BY_PR67.get(family, {}))],
+    ids=["pr56", "pr58", "pr60", "pr63", "pr67"])
 def test_the_causes_on_record_are_of_the_programs_that_moved(families, was,
                                                              now, moved_by):
     """``MOVED`` names the programs whose operations PR 56 left other than
     PR 55's, ``MOVED_BY_PR58`` those PR 58 left other than PR 56's (the
     kernel arm alone), ``MOVED_BY_PR60`` those PR 60 left other than PR
     59's (the added family's), ``MOVED_BY_PR63`` those PR 63 left other
-    than PR 60's, and no other (an entry would outlive its cause); what
-    every fixture computes moved in none."""
+    than PR 60's, ``MOVED_BY_PR67`` those PR 67 left other than its parent's
+    (every fixture's, both arms), and no other (an entry would outlive its
+    cause); what every fixture computes moved in none."""
     for family in families:
         for arm in harness.ARMS:
             old, new = was[family][arm], now[family][arm]

@@ -888,6 +888,43 @@ def test_latent_write_compiles_at_the_published_widths(rows, one_chip,
         _sds((1, rows), jnp.int32))
 
 
+@pytest.mark.parametrize("what,nH,bs,D,streams,K,one_block", [
+    # cell 13's block pass: 256 slots x a block of 4 rows in a page of 64
+    ("a_block_a_slot", 4, 64, 128, 256, 4, True),
+    # cell 11's 512-row chunk (both classes' pages are [4, 64, 128]) ...
+    ("a_chunk", 4, 64, 128, 1, 512, False),
+    # ... cell 8's at head_dim 64, two positions a lane row (eight heads: in
+    # two parts) ...
+    ("a_folded_chunk", 8, 64, 64, 1, 512, False),
+    # ... cell 14's pages of 128 rows under eight heads (written in parts)
+    ("a_chunk_in_parts", 8, 128, 128, 1, 512, False),
+    # and a row a stream: cell 2's 64 slots (folded), cell 13's 256
+    ("a_row_of_64_slots", 20, 16, 64, 64, 1, False),
+    ("a_row_of_256_slots", 4, 64, 128, 256, 1, False)])
+def test_kv_write_compiles_at_the_cells_shapes(what, nH, bs, D, streams, K,
+                                               one_block, one_chip, as_tpu):
+    """The K/V write at the published shapes it meets, a RUN of a stream's
+    rows a grid step where a stream brings more than one: ONE kernel
+    instance a call, and the grid ``write_step_counts`` says."""
+    from deepspeed_tpu.ops import paged_attention as pa
+    f = pa._fold(D, bs)
+    pool = _sds((6, 1, 600, nH, bs // f, f * D), jnp.bfloat16)
+    new = _sds((1, streams * K, nH, D), jnp.bfloat16)
+    at = _sds((1, streams * K), jnp.int32)
+    text = _compile(
+        lambda pk, pv, k, v, layer, blk, off: pa.paged_write(
+            pk, pv, k, v, layer, blk, off, stream_rows=K,
+            one_block=one_block),
+        one_chip, pool, pool, new, new, _sds((), jnp.int32), at, at)
+    assert text.count("tpu_custom_call") == 1
+    steps = pa.write_step_counts(np.zeros(streams), np.full(streams, K), K=K,
+                                 block_size=bs, one_block=one_block,
+                                 num_heads=nH, head_dim=D)[2]
+    assert steps == {"a_block_a_slot": 256, "a_chunk": 9, "a_folded_chunk": 10,
+                     "a_chunk_in_parts": 6, "a_row_of_64_slots": 64,
+                     "a_row_of_256_slots": 256}[what]
+
+
 def _compile_grouped(one_chip, T, H, M, tm, w, act="silu"):
     """``grouped_swiglu`` alone over ``T`` tokens of width ``H`` and a plan
     of ``M`` buffer rows in tiles of ``tm``, weights ``w`` (a shape)."""
